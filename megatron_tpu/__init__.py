@@ -22,8 +22,6 @@ Design principles (TPU-first, not a port):
 
 __version__ = "0.1.0"
 
-from megatron_tpu import compat as _compat  # installs jax API shims on import
-
 from megatron_tpu.config import (
     ModelConfig,
     ParallelConfig,

@@ -3,7 +3,7 @@
 Two complementary layers (docs/static_analysis.md):
 
   * ``ast_lint`` — a stdlib-only AST linter with repo-specific rules
-    (host syncs inside jitted code, compat-banned APIs, jax._src
+    (host syncs inside jitted code, banned APIs, jax._src
     imports, broad excepts, Python branching on traced arrays). The
     ``tools/jaxlint.py`` CLI loads it by file path so linting never
     pays a jax import.

@@ -7,11 +7,11 @@ and reviewers forget (docs/static_analysis.md):
     ``jax.device_get``, ``block_until_ready``, ``np.asarray`` on traced
     arguments, ``print``, wall clocks) inside code that is jit-traced.
     One stray ``.item()`` in a hot loop serializes every dispatch.
-  * ``banned-api`` — APIs the baked jax 0.4.37 / XLA toolchain cannot
-    run (megatron_tpu/compat.py): partial-auto ``shard_map`` (legacy
-    ``auto=`` kwarg), ``ragged_all_to_all`` (no CPU thunk; gate behind
-    a transport probe), ``jax.experimental.shard_map`` imports (use
-    ``jax.shard_map`` so the compat shim applies), and the deprecated
+  * ``banned-api`` — APIs the code must not reach for: the legacy
+    ``auto=`` kwarg of ``shard_map`` (say ``axis_names=``),
+    ``ragged_all_to_all`` outside the MoE transport probe (no CPU
+    thunk), ``jax.experimental.shard_map`` imports (the public
+    ``jax.shard_map`` is the one spelling), and the deprecated
     ``jax.experimental.host_callback``.
   * ``internal-api`` — ``jax._src`` imports/attributes outside an
     allowlisted site (internals drift between jax versions; every use
@@ -52,7 +52,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 RULES = {
     "host-sync": "host-synchronizing call inside jit-traced code",
-    "banned-api": "API the baked jax/XLA toolchain cannot run (compat.py)",
+    "banned-api": "API the code must not reach for (legacy or CPU-less)",
     "internal-api": "jax._src internals outside an allowlisted shim",
     "broad-except": "bare/broad except without a reasoned allowlist comment",
     "traced-branch": "Python branch on a traced array value",
@@ -349,8 +349,8 @@ def _module_rules(tree: ast.Module, emit) -> None:
             mod = node.module or ""
             if mod.startswith("jax.experimental.shard_map"):
                 emit("banned-api", node,
-                     "import jax.experimental.shard_map bypasses the compat "
-                     "shim — use jax.shard_map (megatron_tpu/compat.py)")
+                     "jax.experimental.shard_map is the legacy spelling — "
+                     "use the public jax.shard_map")
             if mod.startswith("jax.experimental.host_callback"):
                 emit("banned-api", node,
                      "jax.experimental.host_callback is deprecated; use "
@@ -380,9 +380,9 @@ def _module_rules(tree: ast.Module, emit) -> None:
                 for kw in node.keywords:
                     if kw.arg == "auto":
                         emit("banned-api", kw.value,
-                             "partial-auto shard_map (auto=) CHECK-crashes "
-                             "the baked XLA SPMD partitioner — full-manual "
-                             "only (compat.py)")
+                             "partial-auto shard_map through the legacy "
+                             "auto= kwarg — name the manual axes with "
+                             "axis_names= instead")
 
 
 def _traced_rules(idx: _ModuleIndex, emit) -> None:

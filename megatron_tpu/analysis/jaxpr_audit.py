@@ -291,10 +291,16 @@ def _walk(jaxpr, ctx: _Ctx, report: AuditReport, promo_thresh: int) -> None:
 
 
 def _shard_map_manual_axes(eqn) -> Tuple[str, ...]:
+    """The axes the body is manual over: the equation's own
+    `manual_axes` (jax 0.9), in mesh order. A shard_map that names no
+    axes is manual over the whole mesh."""
     mesh = eqn.params.get("mesh")
     names = tuple(getattr(mesh, "axis_names", ()) or ())
-    auto = set(_axis_tuple(eqn.params.get("auto")))
-    return tuple(n for n in names if str(n) not in auto)
+    manual = eqn.params.get("manual_axes")
+    if manual is None:
+        return names
+    manual = set(map(str, manual))
+    return tuple(n for n in names if str(n) in manual)
 
 
 def _shard_map_axis_sizes(eqn) -> Dict[str, int]:

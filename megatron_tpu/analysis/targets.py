@@ -66,8 +66,9 @@ class AuditTarget:
     def compiled_text(self) -> str:
         if not self.can_compile:
             raise RuntimeError(
-                f"{self.name}: compiling this target CHECK-crashes the "
-                "baked XLA (see compat.py); jaxpr-level audit only")
+                f"{self.name}: marked can_compile=False (its compile "
+                "crashed the XLA of jax 0.4.37; not re-tried on jax 0.9, "
+                "ROADMAP D9); jaxpr-level audit only")
         with self._scope():
             return self.lowered().compile().as_text()
 
@@ -312,7 +313,8 @@ def cp_paged_decode_step_target(name: str = "decode_tp2_cp2",
     all-to-all + all_gather inside each subgroup, ppermute hops only
     across subgroups at 1/subgroup payload). jaxpr-only: like moe_ep2,
     compiling the full-manual shard_map output back into GSPMD context
-    RET_CHECK-crashes the baked XLA (compat.py), so can_compile=False."""
+    RET_CHECK-crashed the XLA of jax 0.4.37, so can_compile=False (not
+    re-tried on jax 0.9 — ROADMAP D9)."""
     from megatron_tpu.config import ParallelConfig
     from megatron_tpu.inference.context_parallel import ContextParallelEngine
     from megatron_tpu.models.params import init_params, param_specs
@@ -480,8 +482,9 @@ def ulysses_attention_target(name: str = "ulysses_cp2",
 def moe_block_target(name: str = "moe_ep2", ep: int = 2) -> AuditTarget:
     """Dropless expert-parallel MoE dispatch (CPU transport: all_gather
     reconstruction). jaxpr-only: compiling the shard_map output back
-    into GSPMD context RET_CHECK-crashes this XLA's sharding remover
-    (compat.py / memory notes), so can_compile=False."""
+    into GSPMD context RET_CHECK-crashed the sharding remover of jax
+    0.4.37's XLA, so can_compile=False (not re-tried on jax 0.9 —
+    ROADMAP D9)."""
     from megatron_tpu.parallel.mesh import build_mesh
     from megatron_tpu.ops.moe import moe_block
 

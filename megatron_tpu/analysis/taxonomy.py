@@ -27,9 +27,12 @@ COLLECTIVE_PRIMITIVES = {
     "ragged_all_to_all",
 }
 
-#: host-callback primitives (the train/decode steps must have ZERO)
+#: host-callback primitives (the train/decode steps must have ZERO).
+#: jax.debug.print is its own primitive (debug_print) since jax 0.9 —
+#: before, it traced to debug_callback.
 CALLBACK_PRIMITIVES = {
-    "pure_callback", "io_callback", "debug_callback", "outside_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "outside_call",
 }
 
 #: HLO collective op mnemonics (post-SPMD-partitioning view). These are

@@ -9,6 +9,7 @@ match the native module exactly.
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sysconfig
@@ -21,34 +22,48 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "_helpers.cpp")
 _native = None
 _native_tried = False
+#: how the module in use came to be: "built" (compiled by this process),
+#: "loaded" (a binary of THIS source, compiled earlier on this checkout)
+#: or "python" (no native build; the reference implementations below)
+_native_origin = "python"
 
 
-def _build_native() -> Optional[object]:
+def _build_native() -> Tuple[object, str]:
+    """(module, "built" | "loaded")."""
+    # the binary's name carries a hash of the source it was built from, so
+    # a stale or foreign .so lying in the tree (git ignores *.so, a copied
+    # checkout may not) is never loaded in place of the committed source
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
     ext = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-    out = os.path.join(_HERE, "_helpers_native" + ext)
-    if not os.path.exists(out) or os.path.getmtime(out) < os.path.getmtime(_SRC):
+    out = os.path.join(_HERE, f"_helpers_native_{digest}{ext}")
+    origin = "loaded"
+    if not os.path.exists(out):
         py_inc = sysconfig.get_paths()["include"]
         np_inc = np.get_include()
+        tmp = f"{out}.{os.getpid()}.tmp"
         cmd = [
             "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-            f"-I{py_inc}", f"-I{np_inc}", _SRC, "-o", out,
+            f"-I{py_inc}", f"-I{np_inc}", _SRC, "-o", tmp,
         ]
         subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, out)  # concurrent builders each publish a whole file
+        origin = "built"
     import importlib.util
 
     spec = importlib.util.spec_from_file_location("_helpers_native", out)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod
+    return mod, origin
 
 
 def native_helpers() -> Optional[object]:
     """The compiled module, building it on first use; None if unavailable."""
-    global _native, _native_tried
+    global _native, _native_tried, _native_origin
     if not _native_tried:
         _native_tried = True
         try:
-            _native = _build_native()
+            _native, _native_origin = _build_native()
         except Exception as e:  # noqa: BLE001 - no compiler, bad env,
             # cffi quirks: anything here means "no native build" — fall
             # back to the numpy reference implementations (warned)
@@ -56,6 +71,13 @@ def native_helpers() -> Optional[object]:
                           "using slower Python fallbacks")
             _native = None
     return _native
+
+
+def native_origin() -> str:
+    """"built" | "loaded" | "python" — which index builders this process
+    runs (see _native_origin); resolves the module on first call."""
+    native_helpers()
+    return _native_origin
 
 
 # ---------------------------------------------------------------------------

@@ -169,9 +169,10 @@ class ContextParallelEngine(PagedInferenceEngine):
     def _kv_sharding(self):
         """Pool placement: pages sharded over "context" — each rank holds
         its sequence stripe's pages. Heads stay replicated over "tensor":
-        the ring island is full-manual over every mesh axis (compat.py
-        shard_map shim), so a tensor-sharded heads dim would just be
-        force-gathered at the island boundary each step."""
+        the ring island was written full-manual over every mesh axis (all
+        that jax 0.4.37 offered), where a tensor-sharded heads dim would
+        just be force-gathered at the island boundary each step — not
+        re-tried on jax 0.9 (ROADMAP D9)."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         return NamedSharding(self.mesh,

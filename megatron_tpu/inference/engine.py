@@ -368,15 +368,15 @@ class InferenceEngine:
     # ----- cache + shape policy -------------------------------------------
 
     def _kernel_seq_multiple(self) -> int:
-        """Cache-length divisibility the TPU decode kernel needs. The
-        dense flash-decode kernel rejects caches not divisible by 128
-        (_pick_block -> ValueError) and the dispatcher then SILENTLY
-        falls back to the masked-einsum path — so engines round up
-        instead of quietly losing the kernel. 1 = no constraint (CPU
-        hosts interpret the kernel; the paged engine's grid is per-page
+        """Cache-length divisibility the dense flash-decode kernel needs
+        wherever attention() dispatches it (hardware, or a CPU host with
+        the interpreter forced): a cache not divisible by 128 makes the
+        kernel raise (_pick_block), so engines round up. 1 = no
+        constraint (the XLA path; the paged engine's grid is per-page
         and overrides this)."""
-        if (self.cfg.attention_impl == "pallas"
-                and jax.default_backend() != "cpu"):
+        from megatron_tpu.ops.attention import _kernels_dispatchable
+
+        if self.cfg.attention_impl == "pallas" and _kernels_dispatchable():
             return KERNEL_SEQ_MULTIPLE
         return 1
 
@@ -389,9 +389,8 @@ class InferenceEngine:
 
         warnings.warn(
             f"engine max_seq_len {n} is not a multiple of {m}; rounding "
-            f"up to {rounded} so the fused flash-decode kernel stays "
-            "usable (a non-divisible cache would silently run the dense "
-            "fallback every tick)", stacklevel=3)
+            f"up to {rounded}, the next length the fused flash-decode "
+            "kernel accepts", stacklevel=3)
         return rounded
 
     def _fresh_caches(self):
@@ -723,6 +722,13 @@ class InferenceEngine:
 
     def _bucket(self, p: int) -> int:
         b = self.prefill_bucket
+        m = self._kernel_seq_multiple()
+        if m > 1:
+            # whole-prompt prefill attends its own P-long K/V (q_len ==
+            # kv_len), which is the flash kernel's shape: keep P a length
+            # the kernel tiles (max_seq_len is a multiple of m already)
+            b = -(-b // m) * m
+            return min(self.max_seq_len, -(-p // b) * b)
         return min(self.max_seq_len - 1, max(1, -(-p // b) * b))
 
     def _clear_slot(self, i: int):

@@ -160,17 +160,15 @@ class ReplicaProcess:
 def _build_and_serve(spec: Dict[str, Any]) -> None:
     """Runs in the replica subprocess: build the tiny (or preset) model,
     optionally load committed weights, and serve until signalled."""
-    from megatron_tpu.platform import ensure_platform
-
-    ensure_platform()
-
     import jax
 
     from megatron_tpu.inference.server import run_server
     from megatron_tpu.models import presets
     from megatron_tpu.models.params import init_params
+    from megatron_tpu.platform import enable_compile_cache
     from megatron_tpu.tokenizer.tokenizer import NullTokenizer
 
+    enable_compile_cache()
     if spec.get("telemetry_dir"):
         from megatron_tpu.telemetry.journal import (
             EventJournal, set_global_journal,

@@ -34,9 +34,10 @@ def _llama_base(**kw) -> ModelConfig:
         tie_embed_logits=False,
         layernorm_epsilon=1e-5,
         vocab_size=32000,
-        # flash (splash) attention on the training path, like the reference's
-        # recommended --use_flash_attn configs; dispatch falls back to the
-        # XLA path for shapes the kernel doesn't cover (decode, padding)
+        # the flash kernel family on the training path, like the reference's
+        # recommended --use_flash_attn configs; the XLA path serves what the
+        # kernel does not cover by a condition known before the call
+        # (padding masks, dropout, q_len != kv_len: ops/attention.py)
         attention_impl="pallas",
     )
     base.update(kw)

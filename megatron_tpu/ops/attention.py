@@ -19,8 +19,11 @@ Two implementations behind one dispatch:
     the Sq-small specializations of the same template.
 
 Every pallas path here is an instantiation of that one template; this
-module only picks the instantiation (and the exact dense fallback for
-shapes/features the template doesn't cover).
+module only picks the instantiation. The dense path serves what the
+template does not cover by a condition known BEFORE the call (dropout,
+padding masks, q_len != kv_len, heads that do not divide over the mesh);
+a kernel that was chosen and then fails raises — nothing here turns a
+kernel error into a quiet O(S^2) run.
 
 Layout is [batch, seq, heads, head_dim] throughout (no [s, b, h] flips —
 the reference's seq-first layout is a CUDA-kernel legacy).
@@ -28,6 +31,8 @@ the reference's seq-first layout is a CUDA-kernel legacy).
 
 from __future__ import annotations
 
+import functools
+import math
 import warnings
 from typing import Optional
 
@@ -46,6 +51,70 @@ def _kernels_dispatchable() -> bool:
     from megatron_tpu.ops.pallas.flash_template import interpret_forced
 
     return interpret_forced()
+
+
+def _shard_plan(what: str, batch: int, kv_heads: int):
+    """(use_kernel, plan): how a kernel call maps onto the ambient mesh.
+
+    Mosaic kernels cannot be partitioned by GSPMD ("wrap the call in a
+    shard_map"), so under a mesh of more than one device the kernel runs
+    once per shard: batch over the batch axes, heads over "tensor",
+    nothing gathered. plan is None when the call needs no wrapper, else
+    the (axes, batch_axes, head_axis) that _per_shard takes. Shapes that
+    do not divide over the mesh are the one mesh condition that selects
+    the dense path (use_kernel False) — known before the call, and said
+    out loud at trace time.
+
+    The installed jax lowers a kernel only under ONE shard_map that is
+    manual over EVERY mesh axis, so the plan names them all. Inside
+    somebody else's shard_map (ring, Ulysses, the pipeline schedule: one
+    axis manual) the call is left alone — that shard_map owns the mapping,
+    and nesting a second one over the remaining axes does not satisfy the
+    lowering rule (tried against a described v5e: ROADMAP S7/S8)."""
+    from jax.sharding import get_abstract_mesh
+
+    from megatron_tpu.parallel.mesh import AXIS_TENSOR
+    from megatron_tpu.parallel.sharding import BATCH_AXES
+
+    mesh = get_abstract_mesh()
+    if (mesh is None or not mesh.shape or mesh.size == 1
+            or mesh.manual_axes):
+        return True, None
+    sizes = dict(mesh.shape)
+    batch_axes = tuple(a for a in BATCH_AXES if a in sizes)
+    head_axis = AXIS_TENSOR if AXIS_TENSOR in sizes else None
+    nb = math.prod(sizes[a] for a in batch_axes)
+    nt = sizes.get(head_axis, 1)
+    why = (f"batch {batch} does not divide over {batch_axes}={nb}"
+           if batch % nb else
+           f"{kv_heads} kv heads do not divide over {head_axis}={nt}"
+           if kv_heads % nt else None)
+    if why:
+        warnings.warn(
+            f"attention_impl='pallas' ({what}): {why}; this call runs the "
+            "dense XLA path", stacklevel=3)
+        return False, None
+    return True, (mesh.axis_names, batch_axes or None, head_axis)
+
+
+def _per_shard(plan, kernel, args, paged: bool = False):
+    """kernel(*args) once per shard of the plan's mesh. Each argument's
+    rank says what it is: 4 = [B, S, H, D] activations (batch and heads
+    sharded), 2 = [B, n] per-row table, 1 = [B] per-row scalar. paged:
+    the rank-4 arguments after the first are page POOLS [P, ps, Hkv, D]
+    shared by every row — heads sharded, pages not."""
+    from jax.sharding import PartitionSpec as P
+
+    if plan is None:
+        return kernel(*args)
+    axes, batch_axes, head_axis = plan
+    act = P(batch_axes, None, head_axis, None)
+    by_rank = {4: act, 2: P(batch_axes, None), 1: P(batch_axes)}
+    specs = [by_rank[jnp.ndim(a)] for a in args]
+    if paged:
+        specs[1:3] = [P(None, None, head_axis, None)] * 2
+    return jax.shard_map(kernel, in_specs=tuple(specs), out_specs=act,
+                         axis_names=set(axes), check_vma=False)(*args)
 
 
 def _mask_bias(
@@ -120,32 +189,20 @@ def attention(
     if page_table is not None:
         if (kv_lengths is not None
                 and impl == "pallas" and _kernels_dispatchable()):
-            try:
-                if q.shape[1] == 1:
-                    from megatron_tpu.ops.pallas.paged_flash_decode import (
-                        paged_flash_decode,
-                    )
+            use, plan = _shard_plan("paged decode", q.shape[0], k.shape[2])
+            if use:
+                # q_len > 1 is the multi-query decode (speculative verify:
+                # k+1 query rows per slot, each one position deeper)
+                from megatron_tpu.ops.pallas import flash_template as ft
 
-                    return paged_flash_decode(
-                        q, k, v, page_table, kv_lengths,
-                        sliding_window=sliding_window)
-                # multi-query decode (speculative verify: k+1 query rows
-                # per slot, each one position deeper than the last)
-                from megatron_tpu.ops.pallas.paged_flash_decode import (
-                    paged_flash_decode_mq,
-                )
-
-                return paged_flash_decode_mq(
-                    q, k, v, page_table, kv_lengths,
-                    sliding_window=sliding_window)
-            except (ImportError, ValueError) as e:
-                warnings.warn(
-                    f"paged flash-decode kernel unavailable ({e}); falling "
-                    "back to the gathered masked-einsum decode path",
-                    stacklevel=2)
-        # masked-einsum gather fallback (exact): materialize each row's
-        # logical context from its pages, then flow into the dense paths
-        # below unchanged
+                fn = (ft.paged_flash_decode if q.shape[1] == 1
+                      else ft.paged_flash_decode_mq)
+                return _per_shard(
+                    plan,
+                    functools.partial(fn, sliding_window=sliding_window),
+                    (q, k, v, page_table, kv_lengths), paged=True)
+        # dense path (exact): materialize each row's logical context
+        # from its pages, then flow into the masked einsum below unchanged
         bq = q.shape[0]
         k = k[page_table].reshape(bq, -1, *k.shape[-2:])
         v = v[page_table].reshape(bq, -1, *v.shape[-2:])
@@ -158,26 +215,18 @@ def attention(
             raise ValueError("kv_lengths is a serving-decode path: no "
                              "dropout / padding masks")
         if impl == "pallas" and _kernels_dispatchable():
-            try:
-                if q.shape[1] == 1:
-                    from megatron_tpu.ops.pallas.flash_decode import (
-                        flash_decode,
-                    )
+            use, plan = _shard_plan("decode", q.shape[0], k.shape[2])
+            if use:
+                from megatron_tpu.ops.pallas import flash_template as ft
 
-                    return flash_decode(q, k, v, kv_lengths,
-                                        sliding_window=sliding_window)
-                from megatron_tpu.ops.pallas.flash_decode import (
-                    flash_decode_mq,
-                )
-
-                return flash_decode_mq(q, k, v, kv_lengths,
-                                       sliding_window=sliding_window)
-            except (ImportError, ValueError) as e:
-                warnings.warn(
-                    f"flash-decode kernel unavailable ({e}); falling back "
-                    "to the masked-einsum decode path", stacklevel=2)
-        # masked-einsum fallback (exact): flow into the dense path below
-        # with the per-row prefix mask applied in place of the causal bias
+                fn = (ft.flash_decode if q.shape[1] == 1
+                      else ft.flash_decode_mq)
+                return _per_shard(
+                    plan,
+                    functools.partial(fn, sliding_window=sliding_window),
+                    (q, k, v, kv_lengths))
+        # masked einsum (exact): flow into the dense path below with the
+        # per-row prefix mask applied in place of the causal bias
     if impl in ("ring", "ulysses"):
         # context-parallel exact attention; requires an ambient mesh with a
         # "context" axis (jax.sharding.set_mesh) and no dropout/padding
@@ -248,27 +297,23 @@ def attention(
                 "gradient) runs on the O(S^2) XLA path", stacklevel=2)
             can_use = False
         if can_use:
-            try:
-                from megatron_tpu.ops.pallas.flash_attention import flash_attention
-            except ImportError:
-                flash_attention = None
-                warnings.warn(
-                    "attention_impl='pallas' requested but the flash kernel "
-                    "is unavailable; falling back to the O(S^2) XLA path",
-                    stacklevel=2)
-            if flash_attention is not None:
-                try:
-                    return flash_attention(q, k, v, sliding_window=sliding_window)
-                except ValueError as e:
-                    # geometry the template can't instantiate — loud, so a
-                    # silent revert to the XLA-generated attention
-                    # gradient is impossible (tested: test_pallas_attention)
-                    warnings.warn(
-                        f"flash fwd+bwd template unavailable for this "
-                        f"config ({e}); attention AND its gradient fall "
-                        "back to the O(S^2) XLA path", stacklevel=2)
-        # fall through to the XLA path for shapes/features the kernel
-        # doesn't cover (decode steps, padding masks, dropout)
+            can_use, plan = _shard_plan("full sequence", q.shape[0],
+                                          k.shape[2])
+        if can_use:
+            from megatron_tpu.ops.pallas.flash_attention import (
+                flash_attention,
+            )
+
+            # a geometry the template cannot instantiate raises here: a
+            # step asked to train on the kernel never trains on the XLA
+            # O(S^2) attention gradient instead
+            return _per_shard(
+                plan,
+                functools.partial(flash_attention,
+                                  sliding_window=sliding_window),
+                (q, k, v))
+        # the XLA path below serves what the kernel does not cover
+        # (q_len != kv_len, padding masks, dropout, --no_flash_bwd)
 
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape

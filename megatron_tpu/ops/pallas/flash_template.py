@@ -53,7 +53,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from megatron_tpu.ops.pallas import masks
-from megatron_tpu.ops.pallas.compat import CompilerParams as _CompilerParams
 
 DEFAULT_BLOCK = 256
 _NEG_INF = masks.NEG_INF
@@ -76,6 +75,14 @@ def _pick_block(s: int, cap: int = 512) -> Optional[int]:
         if b <= s and s % b == 0:
             return b
     return s if s % 128 == 0 else None
+
+
+def _fit_block(block: int, s: int) -> int:
+    """The asked block, or the 128 tile when the block does not divide a
+    sequence that 128 does (384, 640, ...: serving prefill buckets); a
+    sequence shorter than a block is one block (interpreter-size inputs)."""
+    block = min(block, s)
+    return 128 if (s % block and s % 128 == 0) else block
 
 
 def supported(q_len: int, kv_len: int, block_q: int = DEFAULT_BLOCK,
@@ -181,7 +188,7 @@ def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
@@ -301,7 +308,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
                                lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
@@ -334,7 +341,7 @@ def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
@@ -381,13 +388,12 @@ def flash_mha(
     forward + the FA-2 recompute backward via custom_vjp — jax.grad
     through this never builds the XLA O(S^2) gradient. GQA broadcasts
     K/V per group (dk/dv group-sum falls out of the broadcast's own
-    vjp). Raises ValueError for geometries the template doesn't cover
-    (the attention() dispatcher falls back loudly)."""
+    vjp). Raises ValueError for geometries the template doesn't cover."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     groups = hq // hkv
-    block_q = min(block_q, sq)
-    block_k = min(block_k, skv)
+    block_q = _fit_block(block_q, sq)
+    block_k = _fit_block(block_k, skv)
     if not supported(sq, skv, block_q, block_k):
         raise ValueError(
             f"flash kernel needs equal seq lens divisible by the block "
@@ -525,7 +531,7 @@ def _decode_call(q, k, v, kv_lengths, *, window: Optional[int], blk: int,
                                    lambda bi, h, ki: (bi, h, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
             scratch_shapes=scratch_shapes,
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
         )(lens, qt, kt, vt)
@@ -575,8 +581,7 @@ def flash_decode_mq(
 ) -> jnp.ndarray:
     """Multi-query decode attention with per-row valid-prefix masking
     (the speculative verify pass: query j sees k_pos < kv_lengths + j).
-    Returns [B, Sq, Hq, D]. Raises ValueError for unsupported shapes
-    (the attention() dispatcher falls back to the masked einsum)."""
+    Returns [B, Sq, Hq, D]. Raises ValueError for unsupported shapes."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     _check_heads(hq, hkv)
@@ -597,8 +602,9 @@ def flash_decode(
 ) -> jnp.ndarray:
     """Single-token decode attention with per-row valid-prefix masking:
     the sq == 1 point of the decode specialization. Returns
-    [B, 1, Hq, D]. Raises ValueError for unsupported shapes (the
-    attention() dispatcher falls back to the masked-einsum path)."""
+    [B, 1, Hq, D]. Raises ValueError for unsupported shapes (the serving
+    engines size their caches so this never fires:
+    inference/engine.py _kernel_seq_multiple)."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     if sq != 1:
@@ -616,8 +622,7 @@ def _check_paged(q, k_pages, page_table, name: str) -> None:
     ps = k_pages.shape[1]
     _check_heads(q.shape[2], k_pages.shape[2])
     if ps % 8:
-        # TPU sublane alignment for the [ps, D] kv tile; the gather
-        # fallback covers exotic page sizes
+        # TPU sublane alignment for the [ps, D] kv tile
         raise ValueError(f"page_size {ps} must be a multiple of 8")
     if page_table.shape[0] != b:
         raise ValueError(
@@ -634,8 +639,7 @@ def paged_flash_decode_mq(
 ) -> jnp.ndarray:
     """Multi-query decode attention over paged KV (the speculative
     verify pass) — the paged knob of the decode specialization. Returns
-    [B, Sq, Hq, D]; ValueError for unsupported shapes (the attention()
-    dispatcher falls back to the gather + masked einsum)."""
+    [B, Sq, Hq, D]; ValueError for unsupported shapes."""
     _check_paged(q, k_pages, page_table, "paged_flash_decode_mq")
     return _decode_call(q, k_pages, v_pages, kv_lengths,
                         window=sliding_window, blk=k_pages.shape[1],
@@ -652,8 +656,7 @@ def paged_flash_decode(
 ) -> jnp.ndarray:
     """Single-token decode attention over paged KV with per-row prefix
     masking. Returns [B, 1, Hq, D]. Raises ValueError for unsupported
-    shapes (the attention() dispatcher falls back to the gather +
-    masked-einsum path)."""
+    shapes."""
     if q.shape[1] != 1:
         raise ValueError(
             f"paged_flash_decode is single-token only (q_len={q.shape[1]})")

@@ -84,13 +84,11 @@ def _bound_axis_names():
 def constrain(x: jax.Array, spec: P) -> jax.Array:
     """Apply a sharding constraint inside jit (requires mesh context).
 
-    Inside a FULL-manual shard_map body (the only mode the compat shim's
-    jax.shard_map offers on jax 0.4.37 — megatron_tpu/compat.py) a
-    constraint over manual axes is meaningless — every axis is already
-    manual, there is nothing left for GSPMD to place — and this jax
-    rejects it at lowering (too late for a try/except here). Current jax
-    keeps non-axis_names axes automatic and the constraint matters, so
-    the constraint is skipped ONLY when one of its axes is actually bound
+    Inside a shard_map body a constraint over a MANUAL axis is meaningless
+    — nothing is left for GSPMD to place on it — and jax rejects it at
+    lowering (too late for a try/except here). Axes outside the
+    shard_map's axis_names stay automatic and the constraint matters
+    there, so it is skipped ONLY when one of its axes is actually bound
     manual at this trace point."""
     spec_axes = {a for part in spec if part is not None
                  for a in ((part,) if isinstance(part, str) else part)}

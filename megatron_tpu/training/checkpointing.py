@@ -666,31 +666,11 @@ def _abstract_like(state: TrainState, shardings=None) -> TrainState:
         state, shardings)
 
 
-def _restore_pre_field_checkpoint(path: str, abstract: TrainState,
-                                  state_template: TrainState) -> TrainState:
-    """Restore a checkpoint whose TrainState predates fields the current
-    dataclass has (e.g. nonfinite_streak, added with the divergence
-    sentinel): restore exactly the fields the checkpoint recorded, fill
-    the new ones from the fresh template. A checkpoint with fields we do
-    NOT know is a different (newer) format and still fails hard."""
-    saved_keys = set(
-        ocp.PyTreeCheckpointer().metadata(os.path.join(path, "state")).keys())
-    field_names = [f.name for f in dataclasses.fields(state_template)]
-    unknown = saved_keys - set(field_names)
-    if unknown:
-        raise ValueError(
-            f"checkpoint at {path} has unknown TrainState fields "
-            f"{sorted(unknown)} — written by a NEWER version?")
-    target = {k: getattr(abstract, k) for k in field_names
-              if k in saved_keys}
-    ckptr = ocp.StandardCheckpointer()
-    restored = ckptr.restore(os.path.join(path, "state"), target)
-    missing = [k for k in field_names if k not in saved_keys]
-    warnings.warn(
-        f"checkpoint at {path} predates TrainState fields {missing}; "
-        "filling them from the fresh template")
-    return type(state_template)(
-        **restored, **{k: getattr(state_template, k) for k in missing})
+def _saved_state_tree(state_path: str) -> Dict[str, Any]:
+    """The saved state's own metadata tree, {field: subtree of array
+    metadata}. Restores decide from it what the checkpoint holds — never
+    from the text of a failed restore's error."""
+    return ocp.StandardCheckpointer().metadata(state_path).item_metadata.tree
 
 
 def load_checkpoint(
@@ -731,45 +711,62 @@ def load_checkpoint(
     if config is not None and not finetune:
         check_config_compatibility(meta.get("config", {}), config)
 
-    ckptr = ocp.StandardCheckpointer()
-    abstract = _abstract_like(state_template, shardings)
-    try:
-        restored: TrainState = ckptr.restore(os.path.join(path, "state"), abstract)
-    except ValueError as e:
-        if "Dict key mismatch" in str(e):
-            # checkpoint written before TrainState grew a field (e.g.
-            # nonfinite_streak): restore the fields it HAS, fill the rest
-            # from the fresh template
-            restored = _restore_pre_field_checkpoint(path, abstract,
-                                                     state_template)
-        elif "tree structures do not match" not in str(e) or state_template.master is not None:
-            raise
-        else:
-            # the checkpoint was written by a mixed-precision run (fp32
-            # master copies present) but this template has none (fp32
-            # params, or an inference-only load) — restore with a
-            # synthesized master tree and drop it below
-            import jax.numpy as jnp
+    state_path = os.path.join(path, "state")
+    saved = _saved_state_tree(state_path)
+    field_names = [f.name for f in dataclasses.fields(state_template)]
+    unknown = set(saved) - set(field_names)
+    if unknown:
+        raise ValueError(
+            f"checkpoint at {path} has unknown TrainState fields "
+            f"{sorted(unknown)} — written by a NEWER version?")
+    # a checkpoint written before TrainState grew a field (e.g.
+    # nonfinite_streak): restore the fields it HAS, fill the rest from the
+    # fresh template
+    missing = [k for k in field_names if k not in saved]
 
-            if shardings is not None:
-                fake_master = jax.tree.map(
-                    lambda x, s: jax.ShapeDtypeStruct(x.shape, jnp.float32,
-                                                      sharding=s),
-                    state_template.params, shardings.params)
-            else:
-                fake_master = jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(
-                        x.shape, jnp.float32, sharding=_template_sharding(x)),
-                    state_template.params)
-            abstract = dataclasses.replace(abstract, master=fake_master)
-            restored = ckptr.restore(os.path.join(path, "state"), abstract)
-            # prefer the fp32 masters as the source of truth for params
-            restored = dataclasses.replace(
-                restored,
-                params=jax.tree.map(
-                    lambda m, p: m.astype(p.dtype), restored.master,
-                    state_template.params),
-                master=None)
+    abstract = _abstract_like(state_template, shardings)
+    # the checkpoint was written by a mixed-precision run (fp32 master
+    # copies present) but this template has none (fp32 params, or an
+    # inference-only load) — restore with a synthesized master tree and
+    # drop it below. A fp32 run saves master=None, which orbax records as
+    # an EMPTY subtree: presence of the key is not enough, it needs leaves.
+    synth_master = (state_template.master is None
+                    and bool(jax.tree.leaves(saved.get("master"))))
+    if synth_master:
+        import jax.numpy as jnp
+
+        if shardings is not None:
+            fake_master = jax.tree.map(
+                lambda x, s: jax.ShapeDtypeStruct(x.shape, jnp.float32,
+                                                  sharding=s),
+                state_template.params, shardings.params)
+        else:
+            fake_master = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, jnp.float32, sharding=_template_sharding(x)),
+                state_template.params)
+        abstract = dataclasses.replace(abstract, master=fake_master)
+
+    ckptr = ocp.StandardCheckpointer()
+    if missing:
+        warnings.warn(
+            f"checkpoint at {path} predates TrainState fields {missing}; "
+            "filling them from the fresh template")
+        target = {k: getattr(abstract, k) for k in field_names
+                  if k in saved}
+        restored = type(state_template)(
+            **ckptr.restore(state_path, target),
+            **{k: getattr(state_template, k) for k in missing})
+    else:
+        restored = ckptr.restore(state_path, abstract)
+    if synth_master:
+        # prefer the fp32 masters as the source of truth for params
+        restored = dataclasses.replace(
+            restored,
+            params=jax.tree.map(
+                lambda m, p: m.astype(p.dtype), restored.master,
+                state_template.params),
+            master=None)
 
     if finetune or no_load_optim:
         restored = dataclasses.replace(
@@ -819,8 +816,7 @@ def load_params_only(
     ckptr = ocp.PyTreeCheckpointer()
     # a fp32 run saves master=None, which orbax records as an EMPTY subtree
     # under the same key — presence alone is not enough, it must have leaves
-    saved = ckptr.metadata(path)
-    use_master = bool(jax.tree.leaves(saved.get("master")))
+    use_master = bool(jax.tree.leaves(_saved_state_tree(path).get("master")))
     key = "master" if use_master else "params"
     target = {key: abstract(params_template,
                             dtype=jnp.float32 if use_master else None,

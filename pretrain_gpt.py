@@ -17,10 +17,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from megatron_tpu.platform import ensure_platform
-
-ensure_platform()
-
 from megatron_tpu.parallel.distributed import initialize_distributed
 
 initialize_distributed()
@@ -83,7 +79,7 @@ def main(argv=None):
         return build_data_loader(valid_ds, sampler, collate_fn=collate,
                                  prefetch=args.num_workers)
 
-    pretrain(cfg, train_iter_factory, valid_iter_factory)
+    return pretrain(cfg, train_iter_factory, valid_iter_factory)
 
 
 if __name__ == "__main__":

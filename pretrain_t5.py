@@ -19,10 +19,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from megatron_tpu.platform import ensure_platform
-
-ensure_platform()
-
 from megatron_tpu.parallel.distributed import initialize_distributed
 
 initialize_distributed()

@@ -232,10 +232,6 @@ def _api_generate_fn(url: str, out_seq_length: int):
 
 
 def main(argv=None):
-    from megatron_tpu.platform import ensure_platform
-
-    ensure_platform()
-
     from megatron_tpu.arguments import parse_args
 
     task = (argv or sys.argv[1:])[:1]

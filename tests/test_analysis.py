@@ -268,12 +268,16 @@ def test_auditor_flags_manual_axis_constraint():
 def test_auditor_flags_callbacks_and_promotions():
     def fn(x):
         jax.debug.print("x {x}", x=x)
+        jax.debug.callback(lambda v: None, x)
         return x.astype(jnp.float32) * 2
 
     x = jax.ShapeDtypeStruct((64, 64), jnp.bfloat16)
     rep = jaxpr_audit.audit_jaxpr(jax.make_jaxpr(fn)(x),
                                   promotion_threshold_bytes=1024)
-    assert [c.primitive for c in rep.callbacks] == ["debug_callback"]
+    # every way into the host from a traced program is seen, whatever
+    # primitive this jax gives it (debug.print: debug_print on 0.9)
+    assert [c.primitive for c in rep.callbacks] == [
+        "debug_print", "debug_callback"]
     assert len(rep.promotions) == 1
     assert rep.promotions[0].bytes_out == 64 * 64 * 4
 

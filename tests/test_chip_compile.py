@@ -1,0 +1,199 @@
+"""The main path compiled for a described TPU v5e — no chip attached.
+
+The chip's compiler is installed with jaxlib/libtpu and compiles for a
+topology that is described, not present (on-chip-measurement guide,
+section 2). Interpret mode lowers a Pallas kernel to plain XLA ops, which
+always compile and always partition; these cases compile the kernels FOR
+REAL at Mistral-7B widths, so a slice that is not tile-aligned, a kernel
+that needs too much VMEM, a step that does not fit 16 GB or a kernel that
+cannot be partitioned over a mesh fails here and not on chip time.
+
+Nothing runs: these say the compiler accepts the program, never that its
+results or times are right (chip_smoke.py checks results on the chip).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import dataclasses
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from megatron_tpu.config import OptimizerConfig, ParallelConfig
+from megatron_tpu.models import presets
+from megatron_tpu.ops.pallas import flash_template as ft
+
+# megatron_tpu.ops re-exports the attention FUNCTION under the module's name
+attention_mod = importlib.import_module("megatron_tpu.ops.attention")
+
+GIB = 1 << 30
+# Mistral-7B attention widths (models/presets.py mistral)
+HQ, HKV, D, WINDOW = 32, 8, 128, 4096
+SEQ = 4096          # training sequence
+SLOTS, CACHE = 8, 2048   # the smoke's server: --serve_num_slots 8, 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu / no such topology:
+        # the host cannot describe the chip, so there is nothing to compile
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _as_on_the_chip():
+    """The backend switches read jax.default_backend(), which is the CPU
+    under such a compile: steer them to their chip side from the test
+    (module scope, so the compiled-step fixture below sees it too)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ft, "_interpret", lambda: False)
+        mp.setattr(attention_mod, "_kernels_dispatchable", lambda: True)
+        yield
+
+
+def _abstract(shape, dtype, device):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(device))
+
+
+def _kernel_cases():
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    q_train = ((1, SEQ, HQ, D), bf16)
+    kv_train = ((1, SEQ, HKV, D), bf16)
+    kv_cache = ((SLOTS, CACHE, HKV, D), bf16)
+    lens = ((SLOTS,), i32)
+
+    def fwd(q, k, v):
+        return ft.flash_mha(q, k, v, sliding_window=WINDOW)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    def decode(q, k, v, n):
+        return ft.flash_decode(q, k, v, n, sliding_window=WINDOW)
+
+    def decode_mq(q, k, v, n):
+        return ft.flash_decode_mq(q, k, v, n, sliding_window=WINDOW)
+
+    def decode_int8(q, kq, vq, ks, vs, n):
+        # --kv_cache_int8: the cache dequantizes to bf16 in front of the
+        # same kernel (models/transformer.py attention_block)
+        from megatron_tpu.ops.kv_quant import dequantize_kv
+
+        return ft.flash_decode(q, dequantize_kv(kq, ks, bf16),
+                               dequantize_kv(vq, vs, bf16), n,
+                               sliding_window=WINDOW)
+
+    def paged(q, kp, vp, table, n):
+        return ft.paged_flash_decode(q, kp, vp, table, n,
+                                     sliding_window=WINDOW)
+
+    cases = [
+        ("forward", fwd, [q_train, kv_train, kv_train], 1),
+        ("forward_backward", fwd_bwd, [q_train, kv_train, kv_train], 3),
+        ("decode", decode,
+         [((SLOTS, 1, HQ, D), bf16), kv_cache, kv_cache, lens], 1),
+        ("decode_mq5", decode_mq,
+         [((SLOTS, 5, HQ, D), bf16), kv_cache, kv_cache, lens], 1),
+        ("decode_int8_kv", decode_int8,
+         [((SLOTS, 1, HQ, D), bf16),
+          ((SLOTS, CACHE, HKV, D), jnp.int8),
+          ((SLOTS, CACHE, HKV, D), jnp.int8),
+          ((SLOTS, CACHE, HKV, 1), jnp.float32),
+          ((SLOTS, CACHE, HKV, 1), jnp.float32), lens], 1),
+    ]
+    for ps in (8, 16, 128):
+        per_seq = CACHE // ps
+        pool = ((SLOTS * per_seq + 1, ps, HKV, D), bf16)
+        cases.append((f"paged_decode_page{ps}", paged,
+                      [((SLOTS, 1, HQ, D), bf16), pool, pool,
+                       ((SLOTS, per_seq), i32), lens], 1))
+    return cases
+
+
+_CASES = _kernel_cases()
+
+
+@pytest.mark.parametrize("name,fn,args,n_kernels", _CASES,
+                         ids=[c[0] for c in _CASES])
+def test_kernel_compiles_for_v5e(topo, name, fn, args, n_kernels):
+    dev = topo.devices[0]
+    compiled = jax.jit(fn).lower(
+        *[_abstract(s, d, dev) for s, d in args]).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+
+
+def _mistral_2l():
+    """presets.mistral at full width, depth cut to what one 16 GB chip
+    trains with Adam (chip_smoke.py's model)."""
+    return dataclasses.replace(
+        presets.mistral(seq_length=SEQ), num_layers=2,
+        params_dtype="bfloat16", ce_chunk_size=512,
+        attention_impl="pallas").validate()
+
+
+def _per_device_bytes(compiled):
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def one_chip_step(topo):
+    from megatron_tpu.training.aot import aot_compile_train_step
+
+    compiled, _ = aot_compile_train_step(
+        _mistral_2l(), ParallelConfig(),
+        OptimizerConfig(lr=1e-4), micro_batch_size=1, num_microbatches=1,
+        recompute="selective", devices=topo.devices[:1])
+    return compiled
+
+
+@pytest.mark.slow  # ~10 s of compile; tier-1 runs against its time limit
+# (ROADMAP D10) and keeps the four-chip case below, which guards the repair
+def test_train_step_fits_one_v5e(one_chip_step):
+    """The trainer's own step (training/train_step.make_train_step) at
+    the smoke's size: Pallas forward + two backward kernels per layer
+    scan, and XLA's buffer assignment under the chip's 16 GB."""
+    text = one_chip_step.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert _per_device_bytes(one_chip_step) < 16e9
+
+
+def test_train_step_tp2_dp2_partitions_the_kernel(topo):
+    """The same step over the four chips of a v5e 2x2 host at TP 2 x DP 2
+    with sequence parallelism and the sharded optimizer. GSPMD cannot
+    partition a Mosaic kernel ("wrap the call in a shard_map"): the
+    dispatcher in ops/attention.py runs it per shard, so this compiles,
+    keeps its custom calls, gains the collectives, and each device holds
+    well under the unsharded state (bf16 params + fp32 master and Adam
+    moments: 14 bytes a parameter)."""
+    from megatron_tpu.training.aot import aot_compile_train_step
+
+    compiled, meta = aot_compile_train_step(
+        _mistral_2l(),
+        ParallelConfig(tensor_parallel=2, sequence_parallel=True),
+        OptimizerConfig(lr=1e-4, use_distributed_optimizer=True),
+        micro_batch_size=1, num_microbatches=1, recompute="selective",
+        devices=topo.devices)
+    assert meta["mesh_shape"]["tensor"] == 2
+    assert meta["mesh_shape"]["data"] == 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for collective in ("all-reduce", "all-gather"):
+        assert re.search(rf"\b{collective}(-start)?\(", text), collective
+    sharded = compiled.memory_analysis().argument_size_in_bytes
+    whole = 14 * meta["n_params"]
+    assert sharded < 0.5 * whole, (sharded / GIB, whole / GIB)

@@ -637,7 +637,6 @@ def _run_two_hosts(corpus, base, coord_dir, fault_by_host=None,
     for host in range(2):
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
-        env["MEGATRON_TPU_FORCE_PLATFORM"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
         env.pop("JAX_COMPILATION_CACHE_DIR", None)
         env.pop(resilience.FAULT_ENV, None)
@@ -810,7 +809,6 @@ def test_kill_during_save_never_half_commits(tmp_path, corpus):
     # uninterrupted run is the curve both hosts must reproduce
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["MEGATRON_TPU_FORCE_PLATFORM"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop(resilience.FAULT_ENV, None)

@@ -176,7 +176,6 @@ def _run_elastic(corpus, save, tele, n_devices, extra=(), fault=None,
                  train_iters=8, micro=1, timeout=420):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["MEGATRON_TPU_FORCE_PLATFORM"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop(resilience.FAULT_ENV, None)

@@ -35,7 +35,6 @@ def test_finetune_cli_instruction_data(tmp_path):
     env = {k: v for k, v in os.environ.items()}
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["JAX_PLATFORMS"] = "cpu"
-    env["MEGATRON_TPU_FORCE_PLATFORM"] = "cpu"
     prefix = str(tmp_path / "instr")
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools/preprocess_instruct_data.py"),

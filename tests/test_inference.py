@@ -359,7 +359,6 @@ def test_zeroshot_wikitext_adjusted_ppl(tmp_path):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["JAX_PLATFORMS"] = "cpu"
-    env["MEGATRON_TPU_FORCE_PLATFORM"] = "cpu"
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools/evaluate_zeroshot.py"),
          "--task", "wikitext", "--text", str(tmp_path / "wiki.txt"),

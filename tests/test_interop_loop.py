@@ -32,7 +32,6 @@ def tiny_hf_llama(tmp_path_factory):
 def _env():
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["MEGATRON_TPU_FORCE_PLATFORM"] = "cpu"
     env["XLA_FLAGS"] = env.get("XLA_FLAGS", "") + \
         " --xla_force_host_platform_device_count=8"
     return env

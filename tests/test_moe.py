@@ -307,27 +307,10 @@ def _ep_mesh(**kw):
     return build_mesh(ParallelConfig(**kw))
 
 
-def _skip_ep_on_old_xla():
-    """The expert-parallel dispatch paths cannot compile (or, worse,
-    mis-execute) on the old toolchain the compat shard_map shim serves:
-    a shard_map output re-entering GSPMD context trips the
-    sharding-remover pass (RET_CHECK replacing the SPMDFullToShardShape
-    custom-call chain, hlo_instruction.cc:3432), and GSPMD silently
-    miscompiles lax.ragged_dot against expert-sharded weights. The ep=1
-    dropless/capacity paths cover the dispatch math on this toolchain;
-    EP runs under MEGATRON_TPU_TEST_PLATFORM=tpu captures."""
-    from megatron_tpu import compat
-
-    if compat.SHARD_MAP_SHIMMED:
-        pytest.skip("old-toolchain XLA cannot compile the expert-axis "
-                    "shard_map paths (see _skip_ep_on_old_xla)")
-
-
 def test_moe_dropless_ep_matches_single_group():
     """Dropless under expert parallelism (VERDICT r4 #3): the explicit
     expert-axis all-to-all path on ep2 x tp2 reproduces the ep=1
     sort/ragged_dot path exactly — values, aux loss, AND grads."""
-    _skip_ep_on_old_xla()
     from megatron_tpu.ops.moe import moe_block, moe_block_dropless
 
     cfg = _moe_cfg(moe_dispatch="dropless")
@@ -363,7 +346,6 @@ def test_moe_dropless_ep_exact_under_extreme_imbalance():
     """Default receive buffer (factor = ep) is mathematically dropless:
     even with the router saturated toward ONE expert (everything lands on
     one shard), ep2 matches the ep=1 dropless path exactly."""
-    _skip_ep_on_old_xla()
     from megatron_tpu.ops.moe import moe_block, moe_block_dropless
 
     cfg = _moe_cfg(moe_dispatch="dropless", moe_top_k=1,
@@ -390,7 +372,6 @@ def test_moe_dropless_ep_buffer_factor_semantics():
     one hot shard and the overflow rows (greedy source-order clamp) lose
     that expert — their tokens pass through with zero MLP output under
     top_k=1, while kept tokens still match the reference."""
-    _skip_ep_on_old_xla()
     from megatron_tpu.ops.moe import moe_block, moe_block_dropless
 
     cfg = _moe_cfg(moe_dispatch="dropless", moe_top_k=1,
@@ -451,11 +432,8 @@ def test_moe_ragged_transport_path_matches_dense():
     metadata and the mirrored-exchange custom VJP before the one-shot
     hardware window."""
     if not hasattr(jax.lax, "ragged_all_to_all"):
-        pytest.skip("this jax predates jax.lax.ragged_all_to_all entirely "
-                    "(no primitive to monkeypatch around, and nothing the "
-                    "compat shim could alias it from); the emulated-path "
-                    "parity proof needs a newer toolchain")
-    _skip_ep_on_old_xla()
+        pytest.skip("this jax has no jax.lax.ragged_all_to_all (no "
+                    "primitive to monkeypatch around)")
     import megatron_tpu.ops.moe as moe_mod
     from megatron_tpu.ops.moe import moe_block, moe_block_dropless
 
@@ -501,7 +479,6 @@ def test_moe_dropless_serves_single_row_on_ep_mesh():
     an ep mesh must not crash the dropless dispatch: the GSPMD fallback
     runs against the expert-sharded weights and matches the unsharded
     path exactly."""
-    _skip_ep_on_old_xla()
     from megatron_tpu.ops.moe import moe_block, moe_block_dropless
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -629,8 +606,7 @@ def test_moe_trains_with_dedicated_expert_axis():
 
 
 @pytest.mark.parametrize("dispatch", [
-    # each point is its own ~6-8s XLA:CPU compile (suite revived by
-    # the compat shard_map shim, PR 4); pipeline parity lives in
+    # each point is its own ~6-8s XLA:CPU compile; pipeline parity lives in
     # test_pipeline, dispatch math at ep=1 above — both stay tier-1
     pytest.param("capacity", marks=pytest.mark.slow),
     pytest.param("dropless", marks=pytest.mark.slow),

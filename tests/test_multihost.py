@@ -18,8 +18,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # holds only its slice of the global batch) routes through a multihost
 # device broadcast that the CPU backend rejects with exactly this
 # message. On a real TPU backend the same code path works; the step test
-# must skip, not fail, so the suite stays green on CPU CI while still
-# running under MEGATRON_TPU_TEST_PLATFORM=tpu captures (ROADMAP item).
+# must skip, not fail, so the suite stays green on CPU CI (a multi-host
+# TPU run of it is not available: the chip tool hands out one host).
 # The skip is NARROW now: everything that is not an XLA program — the
 # jax.distributed coordination service, its KV store, barriers, and the
 # whole training/coordination.py protocol suite — runs FOR REAL on CPU
@@ -140,8 +140,7 @@ def test_two_process_host_broadcast(jax_cluster):
 @pytest.mark.slow  # 10s measured on CPU — where it only SKIPS anyway
 # (multiprocess XLA:CPU computations unimplemented; the non-XLA half of
 # multihost — coordination service, KV store, host broadcast — runs for
-# real above); device-collective coverage runs under
-# MEGATRON_TPU_TEST_PLATFORM=tpu
+# real above); the device-collective step needs several TPU hosts
 def test_two_process_distributed_step(tmp_path):
     with socket.socket() as s:
         s.bind(("localhost", 0))
@@ -171,8 +170,8 @@ def test_two_process_distributed_step(tmp_path):
             "this jax's CPU backend cannot device_put to a non-addressable "
             f"sharding ({_CPU_MULTIHOST_UNSUPPORTED!r}: the per-host batch "
             "placement routes through a multihost broadcast XLA:CPU does "
-            "not implement); run with MEGATRON_TPU_TEST_PLATFORM=tpu for "
-            "real multi-process coverage")
+            "not implement); real multi-process coverage needs several "
+            "TPU hosts")
     for i, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {i} failed:\n{out}"
     losses = []

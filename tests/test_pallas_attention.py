@@ -259,16 +259,36 @@ def test_dispatch_no_flash_bwd_is_loud_and_dense(monkeypatch):
     assert "pallas_call" not in jaxpr
 
 
-def test_dispatch_geometry_fallback_is_loud(monkeypatch):
+def test_dispatch_kernel_error_propagates(monkeypatch):
     """A geometry the template can't instantiate (seq longer than the
-    default block but not divisible by it) falls back to XLA with a
-    warning naming the gradient."""
+    block and not a multiple of 128) RAISES under impl='pallas': a kernel
+    that was chosen never turns into a warning and a quiet O(S^2) run —
+    in a server log that warning was lost."""
     monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
     q, k, v = _qkv(s=300, hq=2, hkv=1, d=16)
-    with pytest.warns(UserWarning, match="O\\(S\\^2\\)"):
-        out = attention(q, k, v, impl="pallas")
-    want = attention(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(ValueError, match="flash kernel needs"):
+            attention(q, k, v, impl="pallas")
+        # same for the decode kernels: a cache length they cannot tile
+        lens = jnp.asarray([3], jnp.int32)
+        with pytest.raises(ValueError, match="divisible by 128"):
+            attention(q[:, :1], k, v, kv_lengths=lens, impl="pallas")
+
+
+def test_serving_bucket_lengths_tile_at_128(monkeypatch):
+    """Sequences that 128 divides but the default 256 block does not
+    (serving prefill buckets: 384, 640, ...) run the kernel at the 128
+    tile instead of being refused."""
+    from megatron_tpu.ops.pallas.flash_template import _fit_block
+
+    assert [_fit_block(256, s) for s in (64, 128, 256, 384, 512, 640)] == [
+        64, 128, 256, 128, 256, 128]
+    monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+    q, k, v = _qkv(s=384, hq=2, hkv=1, d=16)
+    out = attention(q, k, v, impl="pallas")
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(attention(q, k, v)),
                                rtol=2e-3, atol=2e-3)
 
 

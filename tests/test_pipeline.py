@@ -69,7 +69,7 @@ def test_pipeline_grads_match_unpipelined(pp):
                                    rtol=5e-4, atol=1e-5)
 
 
-@pytest.mark.slow  # newly revived by the compat jax.shard_map shim
+@pytest.mark.slow
 # (PR 4): XLA:CPU compile-heavy on the 2-core tier-1 host; the pp2
 # loss/grads parity tests keep the schedule covered in tier-1
 def test_pipeline_train_step_descends():
@@ -92,7 +92,7 @@ def test_pipeline_train_step_descends():
     assert last < first * 0.7, (first, last)
 
 
-@pytest.mark.slow  # newly revived by the compat jax.shard_map shim
+@pytest.mark.slow
 # (PR 4): XLA:CPU compile-heavy on the 2-core tier-1 host; the pp2
 # loss/grads parity tests keep the schedule covered in tier-1
 def test_pipeline_bubble_gate_saves_walltime():
@@ -161,7 +161,7 @@ def test_pipeline_gated_pure_pp_with_production_sharder():
     np.testing.assert_allclose(float(loss_pp), float(loss_ref), rtol=1e-5)
 
 
-@pytest.mark.slow  # newly revived by the compat jax.shard_map shim
+@pytest.mark.slow
 # (PR 4): XLA:CPU compile-heavy on the 2-core tier-1 host; the pp2
 # loss/grads parity tests keep the schedule covered in tier-1
 def test_pipeline_gating_on_sharded_mesh_matches_ungated():
@@ -218,7 +218,7 @@ def test_pipeline_gating_on_sharded_mesh_matches_ungated():
     # (test_parallel_matrix.py), which runs every combo through auto
 
 
-@pytest.mark.slow  # newly revived by the compat jax.shard_map shim
+@pytest.mark.slow
 # (PR 4): XLA:CPU compile-heavy on the 2-core tier-1 host; the pp2
 # loss/grads parity tests keep the schedule covered in tier-1
 def test_pipeline_block_recompute_matches_unpipelined():
@@ -266,7 +266,7 @@ def test_interleaved_vpp_loss_matches_unpipelined(pp, vpp):
     assert float(aux["ntokens"]) == batch["tokens"].size
 
 
-@pytest.mark.slow  # newly revived by the compat jax.shard_map shim
+@pytest.mark.slow
 # (PR 4): XLA:CPU compile-heavy on the 2-core tier-1 host; the pp2
 # loss/grads parity tests keep the schedule covered in tier-1
 def test_interleaved_vpp_grads_match_unpipelined():
@@ -289,7 +289,7 @@ def test_interleaved_vpp_microbatch_constraint():
                               recompute="full", num_virtual_chunks=2)
 
 
-@pytest.mark.slow  # newly revived by the compat jax.shard_map shim
+@pytest.mark.slow
 # (PR 4): XLA:CPU compile-heavy on the 2-core tier-1 host; the pp2
 # loss/grads parity tests keep the schedule covered in tier-1
 def test_pipeline_train_loop_with_data_parallel():
@@ -321,7 +321,7 @@ def test_pipeline_train_loop_with_data_parallel():
     assert float(m2["loss"]) < float(m1["loss"])
 
 
-@pytest.mark.slow  # newly revived (compat shard_map shim); two full
+@pytest.mark.slow  # two full
 # remat compiles at ~10s each on the 2-core tier-1 host
 @pytest.mark.parametrize("vpp", [1, 2])
 def test_pipeline_segment_remat_parity(vpp):
@@ -344,7 +344,7 @@ def test_pipeline_segment_remat_parity(vpp):
                                    rtol=1e-5, atol=1e-7)
 
 
-@pytest.mark.slow  # newly revived (compat shard_map shim); ~11s of
+@pytest.mark.slow  # ~11s of
 # pp2xVPP compiles + a checkpoint round-trip on the 2-core host
 def test_vpp_placed_storage_parity_and_checkpoint(tmp_path):
     """TrainLoop stores layers in placed order under VPP: first-step loss

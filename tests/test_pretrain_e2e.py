@@ -101,7 +101,6 @@ def test_pretrain_gpt_cli(tmp_path):
     prefix = str(tmp_path / "corpus")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["MEGATRON_TPU_FORCE_PLATFORM"] = "cpu"
     env["XLA_FLAGS"] = env.get("XLA_FLAGS", "") + \
         " --xla_force_host_platform_device_count=8"
     subprocess.run([

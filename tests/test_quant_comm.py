@@ -288,7 +288,18 @@ def tp_setup():
         pytest.skip("needs >= 2 (fake) devices")
     cfg = tiny_cfg()
     rt = tp2_mesh()
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    # The gates below were calibrated on ONE random model: PRNGKey(0)
+    # under the threefry stream that was jax's default then
+    # (jax_threefry_partitionable=False). jax 0.9 flipped that default,
+    # which draws a different model from the same key — and on these
+    # near-uniform logits (a fifth of the positions have a top-1/top-2
+    # gap under 0.01) argmax agreement moves by +-0.02 from draw to
+    # draw. The transport arithmetic did not change: the e4m3 cast is
+    # bit-identical to the round-to-nearest-even reference and the max
+    # logit error on the calibrated model is the recorded 0.0143. So the
+    # model is pinned, not the floor lowered.
+    with jax.threefry_partitionable(False):
+        params = init_params(cfg, jax.random.PRNGKey(0))
     sparams = shard_tree(rt, params, param_specs(cfg))
     dense = InferenceEngine(cfg, sparams, num_slots=4, max_seq_len=32,
                             mesh=rt.mesh)

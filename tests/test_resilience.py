@@ -272,7 +272,6 @@ def _run_pretrain(corpus, save, extra=(), fault=None, train_iters=8,
                   timeout=420):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["MEGATRON_TPU_FORCE_PLATFORM"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     # NB: never give these subprocesses a shared persistent XLA compile
     # cache: the fault harness SIGKILLs runs mid-flight, which can tear a

@@ -172,16 +172,6 @@ def test_ring_flash_inner_window_grads_match_dense():
 def test_contiguous_ring_flash_matches_dense(mask_type):
     """The contiguous ring's flash inner (bidirectional CP, and causal
     shapes zig-zag can't stripe) — values AND grads vs dense."""
-    from megatron_tpu import compat
-
-    if compat.SHARD_MAP_SHIMMED and mask_type == "bidirectional":
-        pytest.skip(
-            "old-toolchain XLA: the contiguous ring's bidirectional flash "
-            "inner lowers an axis_index the SPMD partitioner turns into a "
-            "PartitionId instruction it then rejects as UNIMPLEMENTED "
-            "(the causal variant and every einsum ring path compile fine; "
-            "kernel is covered on real TPU via "
-            "MEGATRON_TPU_TEST_PLATFORM=tpu captures)")
     rt = build_mesh(ParallelConfig(context_parallel=4))
     q, k, v = _qkv(b=1, s=32, hq=4, hkv=2, d=8)
     want = attention(q, k, v, mask_type=mask_type)
@@ -241,15 +231,6 @@ def test_cp_chunked_prefill_warns_decode_does_not():
 def test_model_forward_with_ring_impl():
     """Full model with attention_impl='ring' on a cp=2 mesh matches the
     xla-impl forward."""
-    from megatron_tpu import compat
-
-    if compat.SHARD_MAP_SHIMMED:
-        pytest.skip(
-            "old-toolchain XLA: embedding ring attention inside the full "
-            "lm_forward jit trips the sharding-remover pass (RET_CHECK "
-            "replacing the SPMDFullToShardShape custom-call chain, "
-            "hlo_instruction.cc) on this XLA; the ring kernel itself is "
-            "covered by the standalone parity tests above")
     from megatron_tpu.models import presets
     from megatron_tpu.models.params import init_params
     from megatron_tpu.models.language_model import lm_forward

@@ -447,7 +447,7 @@ def test_train_goodput_attributes_slow_save_stall(tmp_path, corpus):
     keeps the injected sleep inside the train-loop stall span (async
     saves overlap it with compute by design)."""
     env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu", MEGATRON_TPU_FORCE_PLATFORM="cpu",
+    env.update(JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=1",
                MEGATRON_TPU_FAULT="slow_save:400")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)

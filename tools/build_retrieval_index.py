@@ -18,9 +18,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.platform import ensure_platform
-
-ensure_platform()
 
 import numpy as np
 

@@ -30,10 +30,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.platform import ensure_platform
-
-ensure_platform()
-
 
 def verify_main(argv=None):
     """`verify` subcommand: manifest-check one or all checkpoints in a run

@@ -143,7 +143,9 @@ def main(argv=None) -> int:
         return _diff_manifests(*args.diff)
     if args.regen is not None or args.check:
         sys.path.insert(0, str(_REPO))
-        import megatron_tpu  # noqa: F401 - installs compat shims
+        from megatron_tpu.platform import force_cpu
+
+        force_cpu(8)  # the contracts are traced on the 8-device CPU mesh
         from megatron_tpu.analysis import contracts
 
         names = args.regen or args.config or sorted(contracts.CONFIGS)

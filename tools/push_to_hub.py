@@ -23,10 +23,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from megatron_tpu.platform import ensure_platform
-
-ensure_platform()
-
 
 def main(argv=None):
     p = argparse.ArgumentParser()

@@ -8,14 +8,11 @@ without the rank>0 worker loop (single-controller JAX needs none).
       --tokenizer_type null --vocab_size 128 --port 5000
 """
 
+import json
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from megatron_tpu.platform import ensure_platform
-
-ensure_platform()
 
 
 def extra_args(parser):
@@ -196,11 +193,16 @@ def main(argv=None):
     from megatron_tpu.arguments import args_to_run_config, parse_args
     from megatron_tpu.inference.server import run_server
     from megatron_tpu.models.params import init_params
+    from megatron_tpu.platform import device_summary, enable_compile_cache
     from megatron_tpu.tokenizer import build_tokenizer
     from megatron_tpu.training import checkpointing
 
     args = parse_args(argv, extra_args_provider=extra_args)
     cfg = args_to_run_config(args)
+    cache_dir = enable_compile_cache(cfg.training.compilation_cache_dir or "")
+    # what this replica runs on, said once where a log reader finds it
+    print(f"devices: {json.dumps(device_summary())} | "
+          f"compile cache: {cache_dir}", flush=True)
     tokenizer = build_tokenizer(
         args.tokenizer_type, vocab_file=args.vocab_file,
         merges_file=args.merges_file, tokenizer_model=args.tokenizer_model,

@@ -11,7 +11,7 @@ story (docs/observability.md "Runtime traces").
 Works on any ``--profile`` window, bench ``MEGATRON_TPU_PROFILE_DIR``
 re-run, serving ``/admin/profile`` capture, or SIGUSR1 window — CPU and
 TPU alike (XLA:CPU xplanes carry real op events, so the whole pipeline
-is provable before a chip window).
+is testable without a chip).
 
 Prints the per-op table, the compute / collective / infeed busy split
 with per-collective total vs. EXPOSED time (not overlapped by compute —

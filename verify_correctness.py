@@ -18,10 +18,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from megatron_tpu.platform import ensure_platform
-
-ensure_platform()
-
 
 def main(argv=None):
     p = argparse.ArgumentParser()
