@@ -1,0 +1,128 @@
+"""Find a cell's files by the names in BENCHMARK.json.
+
+A cell names a configuration and a traffic mix; per-layer metrics name a
+reader. All three are files that the harness finds by name, so a later PR
+adds a cell, a configuration or a metric as new files plus new entries
+and edits nothing that exists:
+
+    configuration  `file` of its entry in `configs`
+    architecture   <path>/reference/<name>.py, where <name> is the
+                   configuration's "reference": the plain reference of one
+                   block type, its map from the program's weights and its
+                   translation into the program's flags (the harness
+                   itself knows no architecture)
+    traffic mix    <path>/traffic/<traffic>.json for a <path> in `paths`
+    reader         <path>/layer_metrics/<reader>.py, where <reader> is the
+                   metric's name up to its first "." (so `device_idle_pct.
+                   train` and `device_idle_pct.serve` share one reader and
+                   move different end-to-end metrics)
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+from typing import Any, Callable, Dict, List, Optional
+
+HARNESS_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO = os.path.dirname(HARNESS_ROOT)
+
+
+class SpecError(ValueError):
+    pass
+
+
+def load_module(path: str):
+    """A reader or a reference, loaded from its file: it need not be
+    importable by name, so a later PR's directory works like this one."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    parent = os.path.basename(os.path.dirname(path))
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_{parent}_{stem}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+class Cell:
+    """One entry of `workloads`, with its files loaded."""
+
+    def __init__(self, spec_path: str, name: str):
+        self.spec_path = os.path.abspath(spec_path)
+        self.root = os.path.dirname(self.spec_path)
+        with open(self.spec_path) as f:
+            self.spec = json.load(f)
+        cells = {w["name"]: w for w in self.spec["workloads"]}
+        if name not in cells:
+            raise SpecError(f"no workload {name!r} in {spec_path} "
+                            f"(has {sorted(cells)})")
+        self.workload = cells[name]
+        self.name = name
+        self.chips = int(self.workload["chips"])
+        configs = {c["name"]: c for c in self.spec["configs"]}
+        entry = configs.get(self.workload["config"])
+        if entry is None:
+            raise SpecError(f"workload {name!r} names config "
+                            f"{self.workload['config']!r}, not in `configs`")
+        self.config_name = entry["name"]
+        with open(os.path.join(self.root, entry["file"])) as f:
+            self.config: Dict[str, Any] = json.load(f)
+        self.traffic_name = self.workload["traffic"]
+        self.traffic: Dict[str, Any] = self._load_traffic()
+
+    def _search(self, *parts: str) -> Optional[str]:
+        for base in self.spec["paths"]:
+            path = os.path.normpath(os.path.join(self.root, base, *parts))
+            if os.path.exists(path):
+                return path
+        # readers that ship with the harness serve a spec kept elsewhere
+        path = os.path.join(HARNESS_ROOT, *parts)
+        return path if os.path.exists(path) else None
+
+    def _load_traffic(self) -> Dict[str, Any]:
+        path = self._search("traffic", self.traffic_name + ".json")
+        if path is None:
+            raise SpecError(f"no traffic file {self.traffic_name}.json "
+                            f"under {self.spec['paths']}")
+        with open(path) as f:
+            return json.load(f)
+
+    def _reported_here(self, metric: dict) -> bool:
+        return self.name in metric.get("workloads", [self.name])
+
+    def end_to_end(self) -> List[dict]:
+        return [m for m in self.spec["end_to_end"]
+                if self._reported_here(m)]
+
+    def per_layer(self) -> List[dict]:
+        """Per-layer metrics of this cell: those listed for it whose
+        `moves` is an end-to-end metric the cell reports."""
+        here = {m["name"] for m in self.end_to_end()}
+        return [m for m in self.spec["per_layer"]
+                if self._reported_here(m) and m["moves"] in here]
+
+    def reader(self, metric_name: str) -> Callable[[Any], Optional[float]]:
+        stem = metric_name.split(".", 1)[0]
+        path = self._search("layer_metrics", stem + ".py")
+        if path is None:
+            raise SpecError(f"no reader layer_metrics/{stem}.py for "
+                            f"per-layer metric {metric_name!r}")
+        return load_module(path).read
+
+    def reference_path(self) -> str:
+        """The file that holds this configuration's architecture. The
+        children that hold the chip load it (it imports JAX; this
+        process does not)."""
+        name = self.config.get("reference")
+        if not name:
+            raise SpecError(
+                f"configuration {self.config_name!r} names no "
+                "\"reference\": the file under reference/ that holds its "
+                "architecture")
+        path = self._search("reference", name + ".py")
+        if path is None:
+            raise SpecError(f"no reference/{name}.py under "
+                            f"{self.spec['paths']} for configuration "
+                            f"{self.config_name!r}")
+        return path
