@@ -44,6 +44,7 @@ from megatron_tpu.inference.generation import GenerationOutput, _init_caches
 from megatron_tpu.inference.sampling import sample_logits_batched
 from megatron_tpu.telemetry import journal as _journal
 from megatron_tpu.telemetry.metrics import MetricsRegistry, default_registry
+from megatron_tpu.telemetry.tracing import capture
 from megatron_tpu.training import resilience
 
 #: flash_decode (ops/pallas/flash_decode.py) requires the cache length
@@ -1323,13 +1324,13 @@ class InferenceEngine:
         try:
             start_ticks = self.stats["ticks"]
             t0 = time.monotonic()
-            jax.profiler.start_trace(out_dir)
+            capture.start(out_dir)
             try:
                 while (self.stats["ticks"] - start_ticks < ticks
                        and time.monotonic() - t0 < timeout_s):
                     time.sleep(0.005)
             finally:
-                jax.profiler.stop_trace()
+                capture.stop()
         finally:
             _PROFILE_LOCK.release()
         captured = self.stats["ticks"] - start_ticks

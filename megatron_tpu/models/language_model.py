@@ -153,15 +153,16 @@ def embed_tokens(
 ) -> jnp.ndarray:
     """Token (+ absolute position, + tokentype) embedding with embedding
     dropout (ref: language_model.py:133-262 Embedding)."""
-    x = take_rows(params["embed"]["tokens"], tokens, cfg.dtype)
-    if cfg.position_embedding_type == "absolute":
-        pos = positions if positions is not None else jnp.arange(tokens.shape[1])[None, :]
-        x = x + jnp.take(params["embed"]["pos"], pos, axis=0)
-    if tokentype_ids is not None:
-        x = x + jnp.take(params["embed"]["tokentype"], tokentype_ids, axis=0)
-    if cfg.hidden_dropout > 0 and dropout_key is not None:
-        x = _dropout(x, cfg.hidden_dropout, dropout_key)
-    return x
+    with jax.named_scope("embed"):
+        x = take_rows(params["embed"]["tokens"], tokens, cfg.dtype)
+        if cfg.position_embedding_type == "absolute":
+            pos = positions if positions is not None else jnp.arange(tokens.shape[1])[None, :]
+            x = x + jnp.take(params["embed"]["pos"], pos, axis=0)
+        if tokentype_ids is not None:
+            x = x + jnp.take(params["embed"]["tokentype"], tokentype_ids, axis=0)
+        if cfg.hidden_dropout > 0 and dropout_key is not None:
+            x = _dropout(x, cfg.hidden_dropout, dropout_key)
+        return x
 
 
 def final_hidden_norm(cfg: ModelConfig, params: Dict[str, Any],
@@ -293,14 +294,18 @@ def lm_forward(
     (x, moe_aux), new_caches = scan_with_remat(
         body, (x, jnp.zeros((), jnp.float32)), xs, recompute)
 
-    x = final_hidden_norm(cfg, params, x)
+    # "head_loss" names the final norm, the head and (in lm_loss) the
+    # cross-entropy: one region of the step in a device trace
+    with jax.named_scope("head_loss"):
+        x = final_hidden_norm(cfg, params, x)
     if return_hidden:
         # MoE backbones under task heads (BERT/classification/biencoder)
         # must not silently drop the router losses
         return (x, moe_aux) if return_moe_aux else x
 
-    logits = lm_logits(cfg, params, x, tp_comm=tp_comm)
-    logits = sharder(logits, "logits")
+    with jax.named_scope("head_loss"):
+        logits = lm_logits(cfg, params, x, tp_comm=tp_comm)
+        logits = sharder(logits, "logits")
     if return_moe_aux and kv_caches is not None:
         raise ValueError("return_moe_aux with kv_caches is ambiguous — "
                          "decode paths don't train the router")
@@ -383,17 +388,19 @@ def lm_loss(
     )
     if chunked:
         hidden, moe_aux = out if moe else (out, None)
-        per_token = chunked_lm_loss_tokens(
-            cfg, params, hidden, batch["labels"], sharder=sharder)
-        if "loss_mask" in batch:
-            m = batch["loss_mask"].astype(jnp.float32)
-            mean = jnp.sum(per_token * m) / jnp.maximum(jnp.sum(m), 1.0)
-        else:
-            mean = jnp.mean(per_token)
+        with jax.named_scope("head_loss"):
+            per_token = chunked_lm_loss_tokens(
+                cfg, params, hidden, batch["labels"], sharder=sharder)
+            if "loss_mask" in batch:
+                m = batch["loss_mask"].astype(jnp.float32)
+                mean = jnp.sum(per_token * m) / jnp.maximum(jnp.sum(m), 1.0)
+            else:
+                mean = jnp.mean(per_token)
     else:
         logits, moe_aux = out if moe else (out, None)
-        mean, per_token = cross_entropy_loss(
-            logits, batch["labels"], loss_mask=batch.get("loss_mask"))
+        with jax.named_scope("head_loss"):
+            mean, per_token = cross_entropy_loss(
+                logits, batch["labels"], loss_mask=batch.get("loss_mask"))
     ntokens = (jnp.sum(batch["loss_mask"]) if "loss_mask" in batch
                else jnp.asarray(per_token.size, jnp.float32))
     aux = {"lm_loss": mean, "ntokens": ntokens}

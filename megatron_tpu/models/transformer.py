@@ -386,42 +386,48 @@ def block_forward(
         k_attn_drop = k_hidden1 = k_hidden2 = None
     rate = cfg.hidden_dropout if hidden_dropout_rate is None else hidden_dropout_rate
 
-    # post-LN (ref --use_post_ln): no pre-norm; the layer ends with its own
-    # LN, reusing the ln1 parameter slot as the output norm
-    normed = x if cfg.use_post_ln else _norm(cfg, lp["ln1"], x)
-    attn_out, kv_cache = attention_block(
-        cfg, lp["attn"], normed, rope, positions,
-        attn_dropout_key=k_attn_drop if cfg.attention_dropout > 0 else None,
-        kv_cache=kv_cache, cache_index=cache_index,
-        padding_mask=padding_mask,
-        page_table=page_table,
-        page_write_start=page_write_start,
-        page_write_end=page_write_end,
-        tp_comm=tp_comm,
-        cp_comm=cp_comm,
-    )
-    attn_out = _dropout(attn_out, rate, k_hidden1 if cfg.hidden_dropout > 0 else None)
+    # The two named scopes are the regions a device trace is read by
+    # (docs/observability.md "Runtime traces"): every operation of a layer,
+    # forward, backward or recomputed, carries "attention" or "mlp" in its
+    # name stack, whichever jaxpr wrapper XLA names it after.
+    with jax.named_scope("attention"):
+        # post-LN (ref --use_post_ln): no pre-norm; the layer ends with its
+        # own LN, reusing the ln1 parameter slot as the output norm
+        normed = x if cfg.use_post_ln else _norm(cfg, lp["ln1"], x)
+        attn_out, kv_cache = attention_block(
+            cfg, lp["attn"], normed, rope, positions,
+            attn_dropout_key=k_attn_drop if cfg.attention_dropout > 0 else None,
+            kv_cache=kv_cache, cache_index=cache_index,
+            padding_mask=padding_mask,
+            page_table=page_table,
+            page_write_start=page_write_start,
+            page_write_end=page_write_end,
+            tp_comm=tp_comm,
+            cp_comm=cp_comm,
+        )
+        attn_out = _dropout(attn_out, rate, k_hidden1 if cfg.hidden_dropout > 0 else None)
+        if not cfg.parallel_attn:
+            # residual from the LN output with --apply_residual_connection_
+            # post_layernorm (ref transformer.py:795-799)
+            res1 = normed if cfg.apply_residual_post_ln else x
+            y = sharder(res1 + attn_out, "residual")
 
-    if cfg.parallel_attn:
-        # Falcon: mlp input is ln1(x) (7B) or a dedicated ln_mlp(x) (40B);
-        # one residual add for both branches.
-        mlp_in = _norm(cfg, lp["ln_mlp"], x) if cfg.parallel_layernorm else normed
-        mlp_out, moe_aux = _ffn(cfg, lp, mlp_in, tp_comm=tp_comm)
-        mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
-        res = normed if cfg.apply_residual_post_ln else x
-        y = res + attn_out + mlp_out
-    else:
-        # residual from the LN output with --apply_residual_connection_
-        # post_layernorm (ref transformer.py:795-799)
-        res1 = normed if cfg.apply_residual_post_ln else x
-        y = res1 + attn_out
-        y = sharder(y, "residual")
-        normed2 = _norm(cfg, lp["ln2"], y)
-        mlp_out, moe_aux = _ffn(cfg, lp, normed2, tp_comm=tp_comm)
-        mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
-        res2 = normed2 if cfg.apply_residual_post_ln else y
-        y = res2 + mlp_out
-        if cfg.use_post_ln:
-            y = _norm(cfg, lp["ln1"], y)
+    with jax.named_scope("mlp"):
+        if cfg.parallel_attn:
+            # Falcon: mlp input is ln1(x) (7B) or a dedicated ln_mlp(x)
+            # (40B); one residual add for both branches.
+            mlp_in = _norm(cfg, lp["ln_mlp"], x) if cfg.parallel_layernorm else normed
+            mlp_out, moe_aux = _ffn(cfg, lp, mlp_in, tp_comm=tp_comm)
+            mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
+            res = normed if cfg.apply_residual_post_ln else x
+            y = res + attn_out + mlp_out
+        else:
+            normed2 = _norm(cfg, lp["ln2"], y)
+            mlp_out, moe_aux = _ffn(cfg, lp, normed2, tp_comm=tp_comm)
+            mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
+            res2 = normed2 if cfg.apply_residual_post_ln else y
+            y = res2 + mlp_out
+            if cfg.use_post_ln:
+                y = _norm(cfg, lp["ln1"], y)
     y = sharder(y, "residual")
     return y, kv_cache, moe_aux

@@ -148,6 +148,23 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                                          lse_ref.shape[2:])
 
 
+def _named_pallas_call(name: str, kernel, **kwargs):
+    """`pl.pallas_call(kernel, name=name, ...)`, bound under
+    `jax.named_scope(name)`. `name=` names the Mosaic module and the HLO
+    instruction (`%flash_fwd.13`); the scope puts the same word into the
+    operation's name stack (".../attention/flash_fwd/pallas_call"), which
+    is where a device trace's readers look for it and which holds
+    whatever the instruction ends up being called
+    (docs/observability.md "Runtime traces")."""
+    call = pl.pallas_call(kernel, name=name, **kwargs)
+
+    def bound(*args):
+        with jax.named_scope(name):
+            return call(*args)
+
+    return bound
+
+
 def _delta_arr(delta):
     """Scalar global-position offset -> [1] int32 SMEM operand."""
     if delta is None:
@@ -166,8 +183,8 @@ def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k)
-    o, lse = pl.pallas_call(
-        kernel,
+    o, lse = _named_pallas_call(
+        "flash_fwd", kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -292,8 +309,8 @@ def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
     dq_kernel = functools.partial(
         _dq_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k)
-    dq = pl.pallas_call(
-        dq_kernel,
+    dq = _named_pallas_call(
+        "flash_bwd_dq", dq_kernel,
         grid=(B, H, Sq // block_q, Skv // block_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -317,8 +334,8 @@ def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
     dkv_kernel = functools.partial(
         _dkv_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    dk, dv = _named_pallas_call(
+        "flash_bwd_dkv", dkv_kernel,
         grid=(B, H, Skv // block_k, Sq // block_q),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -518,8 +535,8 @@ def _decode_call(q, k, v, kv_lengths, *, window: Optional[int], blk: int,
 
     if page_table is None:
         skv = k.shape[1]
-        o = pl.pallas_call(
-            kernel,
+        o = _named_pallas_call(
+            "flash_decode", kernel,
             grid=(b, hkv, skv // blk),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -556,8 +573,8 @@ def _decode_call(q, k, v, kv_lengths, *, window: Optional[int], blk: int,
                                    lambda bi, h, ki, lens, pt: (bi, h, 0, 0)),
             scratch_shapes=scratch_shapes,
         )
-        o = pl.pallas_call(
-            _with_page_table(kernel),
+        o = _named_pallas_call(
+            "paged_flash_decode", _with_page_table(kernel),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
             interpret=_interpret(),

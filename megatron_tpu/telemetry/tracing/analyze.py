@@ -1,6 +1,6 @@
 """Trace analysis: comm/compute split, exposed time, contract check.
 
-Three results out of one pass over classified op events (stdlib only):
+Five results out of one pass over classified op events (stdlib only):
 
   * **op table** — per-op count/total time, top-K by time, plus the
     busy-time split compute / collective / infeed / host and per-step
@@ -10,6 +10,16 @@ Three results out of one pass over classified op events (stdlib only):
     plane (interval subtraction). This is the Flash Communication
     measurement (arXiv 2412.04964): only the exposed fraction is worth
     compressing/re-routing, overlapped comm is already free.
+
+  * **own time by scope** — the module's op self time under each of the
+    program's ``jax.named_scope`` regions (``events.REGION_SCOPES``, the
+    innermost wins, the rest is ``other``), and the Pallas kernels by
+    name; what they do not cover is what a refactor left unnamed.
+  * **idle gaps by host span** — each stretch in which the (first)
+    device runs nothing, under the innermost event of the loop thread
+    (the host line that holds step annotations: ``train-pass`` with the
+    loop's timers and the runtime's dispatch events inside it) at the
+    moment the device ran dry: what the host was doing instead.
 
 Events on one line NEST (XLA:CPU wraps a layer scan's body in one big
 ``while.N`` event containing the per-iteration ops; the python line
@@ -43,8 +53,9 @@ from megatron_tpu.analysis.taxonomy import (
     COLLECTIVE_PRIMITIVES, is_collective_done_half,
 )
 from megatron_tpu.telemetry.tracing.events import (
-    KIND_COLLECTIVE, KIND_COMPUTE, KIND_HOST, KIND_INFEED,
-    OpEvent, modules, step_markers,
+    DEVICE_PLANE_PREFIX, KERNEL_SCOPES, KIND_COLLECTIVE, KIND_COMPUTE,
+    KIND_HOST, KIND_INFEED, REGION_SCOPES, OpEvent, innermost_scope, modules,
+    scope_tokens, step_markers,
 )
 
 PS_PER_S = 1e12
@@ -136,6 +147,7 @@ class OpAgg:
     count: int
     total_ps: int       # summed event spans (children included)
     self_ps: int        # summed self time (what the op itself ran)
+    detail: str = ""    # TPU: result shape and opcode
 
     @property
     def total_s(self) -> float:
@@ -167,6 +179,13 @@ class TraceReport:
     collectives: List[CollectiveAgg]      # per-mnemonic comm split
     steps: Dict[str, Dict[str, float]]    # step marker -> wall stats (ms)
     all_modules: Dict[str, float]         # module -> total op seconds
+    scopes: Dict[str, float] = dataclasses.field(default_factory=dict)
+    #   region scope (or "other") -> op self seconds; empty where the
+    #   trace carries none of the program's region names
+    kernels: Dict[str, Dict[str, float]] = dataclasses.field(
+        default_factory=dict)             # kernel scope -> count, self_s
+    idle_gaps: List[Dict[str, Any]] = dataclasses.field(
+        default_factory=list)             # host span -> count, total_s, max_s
 
     @property
     def compute_s(self) -> float:
@@ -190,7 +209,8 @@ class TraceReport:
             "busy_s": {k: round(v, 6) for k, v in sorted(self.busy_s.items())},
             "exposed_collective_s": round(self.exposed_collective_s, 6),
             "top_ops": [
-                {"name": o.name, "kind": o.kind, "count": o.count,
+                {"name": o.name, "detail": o.detail, "kind": o.kind,
+                 "count": o.count,
                  "self_s": round(o.self_s, 6),
                  "total_s": round(o.total_s, 6)}
                 for o in self.ops[:top]],
@@ -203,6 +223,9 @@ class TraceReport:
             "steps": self.steps,
             "modules": {m: round(s, 6)
                         for m, s in sorted(self.all_modules.items())},
+            "scopes": {k: round(v, 6) for k, v in self.scopes.items()},
+            "kernels": self.kernels,
+            "idle_gaps": self.idle_gaps[:top],
         }
 
 
@@ -215,6 +238,50 @@ def _percentile(sorted_vals: List[float], q: float) -> float:
     idx = min(len(sorted_vals) - 1,
               max(0, round(q * (len(sorted_vals) - 1))))
     return sorted_vals[idx]
+
+
+OTHER_SCOPE = "other"
+
+
+def idle_gaps_by_host_span(events: List[OpEvent]) -> List[Dict[str, Any]]:
+    """The first TPU device's idle gaps (between the union of its op
+    intervals), summed under the innermost loop-thread event that covers
+    the moment each gap opens. The loop thread is the host line that
+    holds step annotations; the profiler puts host and device on one
+    clock. Empty without a device plane; every gap falls under
+    ``<no host span>`` where no line is annotated. Largest total first."""
+    ops = [e for e in events if e.kind != KIND_HOST
+           and e.plane.startswith(DEVICE_PLANE_PREFIX)]
+    if not ops:
+        return []
+    device = min(e.plane for e in ops)
+    busy = merge_intervals((e.start_ps, e.end_ps) for e in ops
+                           if e.plane == device)
+    loop_lines = {(e.plane, e.line) for e in events
+                  if e.step_num is not None}
+    spans = sorted((e for e in events if e.kind == KIND_HOST
+                    and (e.plane, e.line) in loop_lines
+                    and e.duration_ps > 0),
+                   key=lambda e: (e.start_ps, -e.end_ps))
+    starts = [e.start_ps for e in spans]
+    out: Dict[str, Dict[str, Any]] = {}
+    for (_, gap_start), (gap_end, _) in zip(busy, busy[1:]):
+        name = "<no host span>"
+        # the latest-started span still open at gap_start is the innermost
+        for i in range(bisect.bisect_right(starts, gap_start) - 1, -1, -1):
+            if spans[i].end_ps > gap_start:
+                name = spans[i].name
+                break
+        row = out.setdefault(name, {"span": name, "count": 0,
+                                    "total_s": 0.0, "max_s": 0.0})
+        gap_s = (gap_end - gap_start) / PS_PER_S
+        row["count"] += 1
+        row["total_s"] += gap_s
+        row["max_s"] = max(row["max_s"], gap_s)
+    rows = sorted(out.values(), key=lambda r: -r["total_s"])
+    for r in rows:
+        r["total_s"], r["max_s"] = round(r["total_s"], 9), round(r["max_s"], 9)
+    return rows
 
 
 def analyze_events(events: List[OpEvent],
@@ -241,6 +308,8 @@ def analyze_events(events: List[OpEvent],
     compute_segs: Dict[str, List[Tuple[int, int]]] = {}  # plane -> segs
     coll_events: Dict[str, List[OpEvent]] = {}           # plane -> events
     xla_span: List[int] = []  # [min_start, max_end] of the module's ops
+    scope_ps: Dict[str, int] = {}
+    kernels: Dict[str, Dict[str, float]] = {}
     for (plane, _line), line_events in by_line.items():
         for e, segs, self_ps in self_segments(line_events):
             if e.kind == KIND_HOST:
@@ -256,11 +325,19 @@ def analyze_events(events: List[OpEvent],
             agg = per_op.get((e.name, e.kind))
             if agg is None:
                 per_op[(e.name, e.kind)] = OpAgg(
-                    e.name, e.kind, 1, e.duration_ps, self_ps)
+                    e.name, e.kind, 1, e.duration_ps, self_ps, e.detail)
             else:
                 agg.count += 1
                 agg.total_ps += e.duration_ps
                 agg.self_ps += self_ps
+            parts = scope_tokens(e.tf_op)
+            region = innermost_scope(parts, REGION_SCOPES) or OTHER_SCOPE
+            scope_ps[region] = scope_ps.get(region, 0) + self_ps
+            kernel = innermost_scope(parts, KERNEL_SCOPES)
+            if kernel is not None and "custom-call" in e.detail:
+                k = kernels.setdefault(kernel, {"count": 0, "self_s": 0.0})
+                k["count"] += 1
+                k["self_s"] += self_ps / PS_PER_S
             if e.kind == KIND_COLLECTIVE and e.collective:
                 coll_events.setdefault(plane, []).append(e)
             if not xla_span:
@@ -297,6 +374,11 @@ def analyze_events(events: List[OpEvent],
             "total_ms": round(sum(ms), 3),
         }
 
+    if set(scope_ps) <= {OTHER_SCOPE}:
+        scope_ps = {}   # a trace without the program's names: no table
+    for k in kernels.values():
+        k["self_s"] = round(k["self_s"], 6)
+
     wall_s = (xla_span[1] - xla_span[0]) / PS_PER_S if xla_span else 0.0
     return TraceReport(
         module=module,
@@ -306,6 +388,11 @@ def analyze_events(events: List[OpEvent],
         collectives=sorted(collectives.values(), key=lambda c: -c.total_ps),
         steps=steps,
         all_modules=per_module,
+        scopes={k: v / PS_PER_S for k, v in sorted(
+            scope_ps.items(), key=lambda kv: -kv[1])},
+        kernels=dict(sorted(kernels.items(),
+                            key=lambda kv: -kv[1]["self_s"])),
+        idle_gaps=idle_gaps_by_host_span(events),
     )
 
 

@@ -1,28 +1,42 @@
 """Typed, classified op events out of a decoded XSpace (stdlib only).
 
-Classification keys off what XLA's runtime stamps on each event rather
-than which plane/line it sits on, so the same walk reads XLA:CPU traces
-(op events live on host thread-pool lines — what tier-1 exercises) and
-TPU traces (op events live on ``/device:TPU:N`` lines):
+Two kinds of trace, told apart by the plane an event sits on:
 
-  * an event carrying an ``hlo_op``/``hlo_module`` stat — or sitting on
-    a device plane's "XLA Ops" line — is an **XLA op**, split
-    collective / transfer / compute by HLO name against
-    ``analysis/taxonomy.py`` (the same vocabulary the golden comm
-    contracts count);
-  * everything else is **host** activity (python dispatch, runtime
-    bookkeeping, thread-pool markers). ``PjitFunction(fn)`` host events
-    are the step markers the analyzer derives per-step wall from.
+  * **XLA:CPU** (what tier-1 exercises): op events live on host
+    thread-pool lines and carry ``hlo_op``/``hlo_module`` stats; an event
+    with one of them is an **XLA op**.
+  * **TPU**: on a ``/device:TPU:<n>`` plane only the ``XLA Ops`` line
+    holds operations. ``XLA Modules`` and ``Steps`` hold whole-program
+    ENVELOPES (classified as compute they would cover the plane and zero
+    out every collective's exposed time), ``Async XLA Ops`` repeats the
+    async halves (``copy-done.14`` with an ``hlo_op`` stat: counted, they
+    read as hundreds of milliseconds of "infeed") and ``TC Overlay`` is a
+    view. An operation's event is named by its whole HLO text and carries
+    no module: it is shown by instruction name, result shape and opcode,
+    and takes the module of the ``XLA Modules`` envelope it runs inside.
+
+Ops split collective / infeed / compute by HLO instruction name against
+``analysis/taxonomy.py`` (the vocabulary the golden comm contracts count).
+Everything else is **host** activity. ``PjitFunction(fn)`` host events,
+host events with a ``step_num`` stat (``jax.profiler.StepTraceAnnotation``:
+the train loop's ``train-pass``) and the device's ``Steps`` envelopes are
+the step markers the analyzer derives per-step wall from.
+
+The program's names (docs/observability.md "Runtime traces") arrive in
+``tf_op``, the jaxpr name stack: ``scope_tokens`` takes it apart,
+``REGION_SCOPES`` and ``KERNEL_SCOPES`` are the ``jax.named_scope`` names
+the model and the flash kernels put there.
 """
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import re
 from typing import Dict, Iterable, List, Optional
 
 from megatron_tpu.analysis.taxonomy import collective_base, is_transfer
-from megatron_tpu.telemetry.tracing.xplane import XSpace, iter_events
+from megatron_tpu.telemetry.tracing.xplane import XSpace
 
 KIND_COMPUTE = "compute"
 KIND_COLLECTIVE = "collective"
@@ -32,13 +46,22 @@ KIND_HOST = "host"
 #: python dispatch events naming the jitted callable — the step markers
 PJIT_RE = re.compile(r"^PjitFunction\((.+)\)$")
 
-#: TPU device planes put op events on THIS line even when individual
-#: events lack hlo stats. "Steps" and "XLA Modules" lines deliberately
-#: stay host-kind: their events are whole-step/whole-module ENVELOPES —
-#: classified as compute they would cover the entire plane and zero out
-#: every collective's exposed time (the number this package exists for)
-_DEVICE_OP_LINES = ("XLA Ops",)
-_DEVICE_MARKER_LINES = ("Steps", "XLA Modules")
+DEVICE_PLANE_PREFIX = "/device:TPU:"
+_DEVICE_OP_LINE = "XLA Ops"
+_DEVICE_MODULE_LINE = "XLA Modules"
+DEVICE_STEP_LINE = "Steps"
+
+#: jax.named_scope names in the program, innermost wins
+#: (models/transformer.py, models/language_model.py,
+#: training/train_step.py; ops/pallas/flash_template.py)
+REGION_SCOPES = ("optimizer", "head_loss", "attention", "mlp", "embed")
+KERNEL_SCOPES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                 "flash_decode", "paged_flash_decode")
+
+_WRAPPED = re.compile(r"^[A-Za-z_]\w*\((.*)\)$")  # jvp(x), transpose(jvp(x))
+_PROGRAM_ID = re.compile(r"\(\d+\)$")             # jit_train_step(1234)
+_HLO_RESULT = re.compile(r"\(?([a-z0-9]+\[[0-9,]*\])")
+_HLO_OPCODE = re.compile(r"[}\])]\s([a-z][a-z0-9\-]*)\(")
 
 
 @dataclasses.dataclass
@@ -52,44 +75,111 @@ class OpEvent:
     module: Optional[str] = None      # hlo_module ("jit_train_step")
     program_id: Optional[int] = None
     collective: Optional[str] = None  # base mnemonic ("all-reduce")
+    detail: str = ""                  # TPU op: "bf16[8,128] fusion"
+    tf_op: Optional[str] = None       # jaxpr name stack, with the scopes
+    step_num: Optional[int] = None    # host StepTraceAnnotation
 
     @property
     def end_ps(self) -> int:
         return self.start_ps + self.duration_ps
 
 
+def scope_tokens(tf_op: Optional[str]) -> List[str]:
+    """The name stack's parts, outermost first, without the wrappers that
+    differentiation puts around a scope's name:
+    "a/transpose(jvp(attention))/mul:" -> ["a", "attention", "mul"]."""
+    out: List[str] = []
+    for part in (tf_op or "").rstrip(":").split("/"):
+        while True:
+            m = _WRAPPED.match(part)
+            if not m:
+                break
+            part = m.group(1)
+        out.append(part)
+    return out
+
+
+def split_hlo_text(text: str) -> tuple:
+    """("fusion.4", "bf16[8,128] fusion") from a TPU op event's name, its
+    whole HLO text "%fusion.4 = bf16[8,128]{1,0:T(8,128)} fusion(...)"; a
+    name that is no HLO text comes back whole."""
+    lhs, sep, rhs = text.partition(" = ")
+    if not sep:
+        return text, ""
+    shape = _HLO_RESULT.match(rhs)
+    opcode = _HLO_OPCODE.search(rhs)
+    detail = " ".join(m.group(1) for m in (shape, opcode) if m)
+    return lhs.lstrip("%"), detail
+
+
+def innermost_scope(parts: List[str], names) -> Optional[str]:
+    """The innermost of `names` among a name stack's parts, or None."""
+    return next((p for p in reversed(parts) if p in names), None)
+
+
+def _op_event(name: str, ev, plane: str, line: str, module,
+              detail: str = "") -> OpEvent:
+    """An XLA op's event, its kind from the instruction's name."""
+    base = collective_base(name)
+    pid = ev.stats.get("program_id")
+    tf_op = ev.stats.get("tf_op")
+    return OpEvent(
+        name=name,
+        kind=(KIND_COLLECTIVE if base else KIND_INFEED if is_transfer(name)
+              else KIND_COMPUTE),
+        start_ps=ev.start_ps, duration_ps=ev.duration_ps, plane=plane,
+        line=line, module=module if isinstance(module, str) else None,
+        program_id=pid if isinstance(pid, int) else None, collective=base,
+        detail=detail, tf_op=tf_op if isinstance(tf_op, str) else None)
+
+
+def _host_event(ev, plane: str, line: str) -> OpEvent:
+    step = ev.stats.get("step_num")
+    return OpEvent(name=ev.name, kind=KIND_HOST, start_ps=ev.start_ps,
+                   duration_ps=ev.duration_ps, plane=plane, line=line,
+                   step_num=step if isinstance(step, int) else None)
+
+
+def _device_plane_events(plane, out: List[OpEvent]) -> None:
+    """A /device:TPU: plane: operations from "XLA Ops" alone, each under
+    the module whose "XLA Modules" envelope holds it; every other line's
+    events are host-kind markers."""
+    envelopes = sorted((ev for line in plane.lines
+                        if line.name == _DEVICE_MODULE_LINE
+                        for ev in line.events), key=lambda ev: ev.start_ps)
+    starts = [ev.start_ps for ev in envelopes]
+    for line in plane.lines:
+        for ev in line.events:
+            if line.name != _DEVICE_OP_LINE:
+                out.append(_host_event(ev, plane.name, line.name))
+                continue
+            name, detail = split_hlo_text(ev.name)
+            module = ev.stats.get("hlo_module")
+            at = bisect.bisect_right(starts, ev.start_ps) - 1
+            if not isinstance(module, str) and at >= 0 and (
+                    ev.start_ps < envelopes[at].end_ps):
+                module = _PROGRAM_ID.sub("", envelopes[at].name)
+            out.append(_op_event(name, ev, plane.name, line.name, module,
+                                 detail))
+
+
 def classify_xspace(space: XSpace) -> List[OpEvent]:
     """Every event in the space as a classified OpEvent, time-sorted."""
     out: List[OpEvent] = []
-    for plane, line, ev in iter_events(space):
-        stats = ev.stats
-        on_device = plane.name.startswith("/device:")
-        is_xla_op = ((("hlo_module" in stats or "hlo_op" in stats)
-                      and not (on_device
-                               and line.name in _DEVICE_MARKER_LINES))
-                     or (on_device and line.name in _DEVICE_OP_LINES))
-        if is_xla_op:
-            name = stats.get("hlo_op") or ev.name
-            if not isinstance(name, str):
-                name = ev.name
-            base = collective_base(name)
-            kind = (KIND_COLLECTIVE if base
-                    else KIND_INFEED if is_transfer(name)
-                    else KIND_COMPUTE)
-            module = stats.get("hlo_module")
-            pid = stats.get("program_id")
-            out.append(OpEvent(
-                name=name, kind=kind, start_ps=ev.start_ps,
-                duration_ps=ev.duration_ps, plane=plane.name,
-                line=line.name,
-                module=module if isinstance(module, str) else None,
-                program_id=pid if isinstance(pid, int) else None,
-                collective=base))
-        else:
-            out.append(OpEvent(
-                name=ev.name, kind=KIND_HOST, start_ps=ev.start_ps,
-                duration_ps=ev.duration_ps, plane=plane.name,
-                line=line.name))
+    for plane in space.planes:
+        if plane.name.startswith(DEVICE_PLANE_PREFIX):
+            _device_plane_events(plane, out)
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                stats = ev.stats
+                if "hlo_module" in stats or "hlo_op" in stats:
+                    name = stats.get("hlo_op")
+                    out.append(_op_event(
+                        name if isinstance(name, str) and name else ev.name,
+                        ev, plane.name, line.name, stats.get("hlo_module")))
+                else:
+                    out.append(_host_event(ev, plane.name, line.name))
     out.sort(key=lambda e: (e.start_ps, e.end_ps))
     return out
 
@@ -109,8 +199,9 @@ def modules(events: Iterable[OpEvent]) -> Dict[str, int]:
 
 
 def step_markers(events: Iterable[OpEvent]) -> Dict[str, List[OpEvent]]:
-    """Host-side step markers: ``PjitFunction(fn)`` dispatch events
-    grouped by fn, plus TPU "Steps"-line events grouped by name.
+    """Step markers: ``PjitFunction(fn)`` dispatch events grouped by fn,
+    host step annotations (``train-pass``) by name, and a device's
+    "Steps"-line envelopes under the line's name.
 
     The runtime emits the python dispatch span twice (a python-level and
     a C++ TraceMe with the same name, one nested in the other), so a
@@ -120,11 +211,16 @@ def step_markers(events: Iterable[OpEvent]) -> Dict[str, List[OpEvent]]:
     for e in events:
         if e.kind == KIND_HOST:
             m = PJIT_RE.match(e.name)
-            if m and e.duration_ps > 0:
+            if e.duration_ps <= 0:
+                continue
+            if m:
                 out.setdefault(m.group(1), []).append(e)
-            elif e.line == "Steps" and e.duration_ps > 0:
-                # TPU "Steps"-line envelopes (host-kind markers)
+            elif e.step_num is not None:
                 out.setdefault(e.name, []).append(e)
+            elif (e.line == DEVICE_STEP_LINE
+                    and e.plane.startswith(DEVICE_PLANE_PREFIX)):
+                # one envelope a step, named by its number: one marker
+                out.setdefault(DEVICE_STEP_LINE, []).append(e)
     deduped: Dict[str, List[OpEvent]] = {}
     for name, marks in out.items():
         marks.sort(key=lambda e: (e.start_ps, -e.end_ps))

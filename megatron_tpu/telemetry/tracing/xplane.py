@@ -10,9 +10,18 @@ writes one ``<host>.xplane.pb`` per host under
         lines: XLine          one per thread / device stream
           events: XEvent      metadata_id -> name, offset_ps, duration_ps
             stats: XStat      hlo_op / hlo_module / program_id / ...
-        event_metadata: map<id, XEventMetadata>   (interned event names)
+        event_metadata: map<id, XEventMetadata>   (interned event names,
+                                                   and stats of their own)
         stat_metadata:  map<id, XStatMetadata>    (interned stat names
                                                    AND str ref values)
+
+On a TPU an operation's event is named by its whole HLO text, and what
+says where in the PROGRAM it came from sits on the event's metadata, not
+on the event: ``tf_op`` (the jaxpr name stack, which holds the
+``jax.named_scope`` names: ".../transpose(jvp(attention))/flash_bwd_dq/
+pallas_call:"), ``hlo_category``, ``flops``, ``source``. The walker hands
+them out as stats of every event of that name; a stat of the event itself
+wins over one of the same name on its metadata.
 
 Events carry times as ``line.timestamp_ns`` + ``offset_ps``; this
 walker resolves both the name interning and the timebase so consumers
@@ -26,7 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
 
 from megatron_tpu.telemetry.tracing import proto
 
@@ -91,7 +100,7 @@ _EVENT_MD_ID, _EVENT_OFFSET_PS, _EVENT_DUR_PS, _EVENT_STATS = 1, 2, 3, 4
 _STAT_MD_ID = 1
 _STAT_DOUBLE, _STAT_UINT64, _STAT_INT64 = 2, 3, 4
 _STAT_STR, _STAT_BYTES, _STAT_REF = 5, 6, 7
-_MD_ID, _MD_NAME = 1, 2
+_MD_ID, _MD_NAME, _MD_STATS = 1, 2, 5
 
 
 def _metadata_name(buf: bytes) -> (int, str):
@@ -138,12 +147,14 @@ def _decode_stat(buf: bytes, stat_names: Dict[int, str]) -> XStat:
 
 
 def _decode_event(buf: bytes, ts_ps: int, event_names: Dict[int, str],
-                  stat_names: Dict[int, str]) -> XEvent:
-    name, offset_ps, dur_ps = "", 0, 0
+                  stat_names: Dict[int, str],
+                  event_stats: Dict[int, Dict[str, Any]]) -> XEvent:
+    name, offset_ps, dur_ps, md_id = "", 0, 0, None
     stats: Dict[str, Any] = {}
     for fn, wt, v in proto.fields(buf):
         if fn == _EVENT_MD_ID and wt == proto.WIRE_VARINT:
-            name = event_names.get(proto.to_signed(v), str(v))
+            md_id = proto.to_signed(v)
+            name = event_names.get(md_id, str(v))
         elif fn == _EVENT_OFFSET_PS and wt == proto.WIRE_VARINT:
             offset_ps = proto.to_signed(v)
         elif fn == _EVENT_DUR_PS and wt == proto.WIRE_VARINT:
@@ -151,12 +162,15 @@ def _decode_event(buf: bytes, ts_ps: int, event_names: Dict[int, str],
         elif fn == _EVENT_STATS and wt == proto.WIRE_LEN:
             s = _decode_stat(v, stat_names)
             stats[s.name] = s.value
+    if md_id in event_stats:
+        stats = {**event_stats[md_id], **stats}
     return XEvent(name=name, start_ps=ts_ps + offset_ps,
                   duration_ps=max(dur_ps, 0), stats=stats)
 
 
 def _decode_line(buf: bytes, event_names: Dict[int, str],
-                 stat_names: Dict[int, str]) -> XLine:
+                 stat_names: Dict[int, str],
+                 event_stats: Dict[int, Dict[str, Any]]) -> XLine:
     line_id, name, display, ts_ns = 0, "", "", 0
     raw_events: List[bytes] = []
     for fn, wt, v in proto.fields(buf):
@@ -171,7 +185,7 @@ def _decode_line(buf: bytes, event_names: Dict[int, str],
         elif fn == _LINE_EVENTS and wt == proto.WIRE_LEN:
             raw_events.append(v)
     ts_ps = ts_ns * 1000
-    events = [_decode_event(e, ts_ps, event_names, stat_names)
+    events = [_decode_event(e, ts_ps, event_names, stat_names, event_stats)
               for e in raw_events]
     return XLine(id=line_id, name=display or name, timestamp_ns=ts_ns,
                  events=events)
@@ -185,6 +199,7 @@ def _decode_plane(buf: bytes) -> XPlane:
     stat_names: Dict[int, str] = {}
     raw_lines: List[bytes] = []
     raw_stats: List[bytes] = []
+    raw_event_md: Dict[int, bytes] = {}
     for fn, wt, v in proto.fields(buf):
         if fn == _PLANE_NAME and wt == proto.WIRE_LEN:
             name = proto.to_text(v)
@@ -193,12 +208,22 @@ def _decode_plane(buf: bytes) -> XPlane:
         elif fn == _PLANE_EVENT_MD and wt == proto.WIRE_LEN:
             key, md = _map_entry(v)
             event_names[key] = _metadata_name(md)[1]
+            raw_event_md[key] = md
         elif fn == _PLANE_STAT_MD and wt == proto.WIRE_LEN:
             key, md = _map_entry(v)
             stat_names[key] = _metadata_name(md)[1]
         elif fn == _PLANE_STATS and wt == proto.WIRE_LEN:
             raw_stats.append(v)
-    lines = [_decode_line(ln, event_names, stat_names) for ln in raw_lines]
+    # the stats an event NAME carries (stat names are all known by now)
+    event_stats: Dict[int, Dict[str, Any]] = {}
+    for key, md in raw_event_md.items():
+        found = [_decode_stat(v, stat_names)
+                 for fn, wt, v in proto.fields(md)
+                 if fn == _MD_STATS and wt == proto.WIRE_LEN]
+        if found:
+            event_stats[key] = {st.name: st.value for st in found}
+    lines = [_decode_line(ln, event_names, stat_names, event_stats)
+             for ln in raw_lines]
     stats = {s.name: s.value
              for s in (_decode_stat(r, stat_names) for r in raw_stats)}
     return XPlane(name=name, lines=lines, stats=stats,
@@ -243,11 +268,3 @@ def find_xplane_files(path: str, latest_session_only: bool = True
         latest = max(os.path.dirname(h) for h in hits)
         hits = [h for h in hits if os.path.dirname(h) == latest]
     return sorted(hits)
-
-
-def iter_events(space: XSpace) -> Iterator[tuple]:
-    """(plane, line, event) triples across the whole space."""
-    for plane in space.planes:
-        for line in plane.lines:
-            for event in line.events:
-                yield plane, line, event

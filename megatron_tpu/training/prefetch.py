@@ -39,7 +39,9 @@ import threading
 import time
 from typing import Any, Callable, Dict, Iterator, Optional
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class DevicePrefetcher:
@@ -100,16 +102,20 @@ class DevicePrefetcher:
                     return
                 if self._transform is not None:
                     batch = self._transform(batch, self._first_iteration + i)
-                t0 = time.perf_counter()
-                device_batch = self._put_fn(batch)
-                t1 = time.perf_counter()
-                if self._land:
-                    # land the copy in the worker so a queue pop hands the
-                    # loop a device-resident batch, not an in-flight one
-                    import jax
-
-                    jax.block_until_ready(device_batch)
-                t2 = time.perf_counter()
+                # the spans the loop credits from put_s/land_s after the
+                # fact (Timers.record), annotated here where the work runs:
+                # under a trace capture they sit on this thread's line
+                with TraceAnnotation("batch-transfer"):
+                    t0 = time.perf_counter()
+                    with TraceAnnotation("batch-transfer-dispatch"):
+                        device_batch = self._put_fn(batch)
+                    t1 = time.perf_counter()
+                    if self._land:
+                        # land the copy in the worker so a queue pop hands
+                        # the loop a device-resident batch, not an
+                        # in-flight one
+                        jax.block_until_ready(device_batch)
+                    t2 = time.perf_counter()
                 self.put_s += t1 - t0
                 self.land_s += t2 - t1
                 self.batches_put += 1

@@ -38,6 +38,7 @@ from megatron_tpu.models.language_model import (
 from megatron_tpu.models.params import init_params, param_specs
 from megatron_tpu.parallel.mesh import MeshRuntime, build_mesh
 from megatron_tpu.platform import device_summary, enable_compile_cache
+from megatron_tpu.telemetry.tracing import capture
 from megatron_tpu.parallel.sharding import (
     activation_spec, batch_spec, constrain, shard_tree, tree_shardings,
 )
@@ -182,6 +183,9 @@ class TrainLoop:
         # --profile having been set (docs/observability.md)
         self._profile_signal_pending = False
         self._profile_until: Optional[int] = None
+        # (jitted step, its microbatch count, batch avals) of the first
+        # step a trace window held
+        self._profiled_step: Optional[Tuple[Callable, int, Any]] = None
 
         model_cfg = run_cfg.model
         if model_cfg.attention_impl == "pallas":
@@ -1112,6 +1116,14 @@ class TrainLoop:
         step = self._train_step_for(max(n_micro, 1))
         with jax.sharding.set_mesh(self.rt.mesh):
             self.state, metrics = step(self.state, device_batch)
+        if self._profiling and self._profiled_step is None:
+            # the program this window traces, for _journal_step_program
+            self._profiled_step = (
+                step, self.fixed_num_microbatches or max(n_micro, 1),
+                jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=x.sharding),
+                    device_batch))
         self.iteration += 1
         self.consumed_samples += gbs
         return metrics
@@ -1249,7 +1261,7 @@ class TrainLoop:
     def _profile_start(self, start: int, until: int, source: str) -> None:
         out = self._profile_out_dir()
         try:
-            jax.profiler.start_trace(out)
+            capture.start(out)
         except Exception as e:  # noqa: BLE001 - a capture already owned
             # by /admin-style tooling (the profiler session is process-
             # global) must not kill the run; the window is just skipped
@@ -1268,7 +1280,7 @@ class TrainLoop:
         self._profiling = False
         self._profile_until = None
         try:
-            jax.profiler.stop_trace()
+            capture.stop()
         except Exception as e:  # noqa: BLE001 - an abort path on another
             # thread (peer-abort sideband) may have closed the session
             # between our flag check and here; the journal has its story
@@ -1304,7 +1316,7 @@ class TrainLoop:
 
             def _flush():
                 try:
-                    jax.profiler.stop_trace()
+                    capture.stop()
                     done.set()
                 except Exception as e:  # noqa: BLE001 - the abort
                     # proceeds regardless; an unreadable trace is
@@ -1324,6 +1336,36 @@ class TrainLoop:
             self.telemetry.emit("profile_aborted", reason=reason,
                                 flushed=flushed, iteration=self.iteration)
 
+    def _journal_step_program(self) -> None:
+        """One `step_program` record for a run that traced: what the
+        compiler says the traced step program needs on a chip, which
+        `peak_bytes_in_use` cannot (it leaves a program's temporaries
+        out). The program is lowered again from the live state and the
+        traced batch's avals and shardings, so its compile is a hit in the
+        persistent cache; still seconds for a large program, which is why
+        this runs once, after the loop has returned and its last
+        checkpoint is committed, never inside a step or a trace window,
+        and not at all in a run that opened no window."""
+        if self._profiled_step is None or self.telemetry is None:
+            return
+        step, n_micro, batch_avals = self._profiled_step
+        self._profiled_step = None
+        try:
+            with jax.sharding.set_mesh(self.rt.mesh):
+                ma = step.lower(self.state, batch_avals).compile(
+                    ).memory_analysis()
+        except Exception as e:  # noqa: BLE001 - a note for the journal
+            # must not turn a finished run into a failed one
+            self.log(f"profiler: step program not analysed ({e})")
+            return
+        self.telemetry.emit(
+            "step_program", iteration=self.iteration,
+            num_microbatches=n_micro,
+            argument_bytes=int(ma.argument_size_in_bytes),
+            temp_bytes=int(ma.temp_size_in_bytes),
+            output_bytes=int(ma.output_size_in_bytes),
+            alias_bytes=int(ma.alias_size_in_bytes))
+
     # -- loop ---------------------------------------------------------------
 
     def train(
@@ -1334,7 +1376,9 @@ class TrainLoop:
         """train_iter_factory(consumed_samples, global_batch) returns an
         iterator of global batches at that batch size (rampup-aware)."""
         try:
-            return self._train_inner(train_iter_factory, valid_iter_factory)
+            state = self._train_inner(train_iter_factory, valid_iter_factory)
+            self._journal_step_program()
+            return state
         except BaseException as e:  # noqa: BLE001 - re-raised below; the
             # catch exists ONLY to publish the cluster poison record so
             # peers stop cleanly instead of wedging in a collective
@@ -1427,6 +1471,7 @@ class TrainLoop:
                 grad_norm=float(host["grad_norm"]),
                 skipped=bool(float(host.get("skipped", 0.0))),
                 data_wait_ms=round(rec["data_wait_s"] * 1e3, 3),
+                dispatch_ms=round(rec["dispatch_s"] * 1e3, 3),
                 tokens_per_s=round(ntok / max(step_s, 1e-9), 1),
                 model_tflops_per_s=round(
                     ntok / max(step_s, 1e-9)
@@ -1586,6 +1631,10 @@ class TrainLoop:
                 _s.callback(self._stop_watchdog)
             data_iter = None
             current_gbs = None
+            # one pass of the loop below is one `train-pass` span on the
+            # profiler's clock, numbered by the iteration it dispatches;
+            # the timers' spans nest inside it (training/timers.py)
+            pass_span = _s.enter_context(contextlib.ExitStack())
 
             def drain(n_keep: int) -> bool:
                 """Process pending records down to n_keep, oldest first;
@@ -1610,6 +1659,9 @@ class TrainLoop:
                 self.timers.elapsed_ms(reset=True)
 
             while True:
+                pass_span.close()
+                pass_span.enter_context(jax.profiler.StepTraceAnnotation(
+                    "train-pass", step_num=self.iteration + 1))
                 if self.iteration >= (t.train_iters or 0):
                     # drain the metrics pipeline before declaring victory:
                     # a sentinel trip hiding in the tail rolls back and

@@ -3,15 +3,23 @@
 Equivalent of megatron/timers.py (304 LoC): hierarchical named timers with
 a log level gate and elapsed reporting. CUDA-sync start/stop becomes a host
 sync via jax.block_until_ready on demand (it waits for the device:
-chip_smoke.py's device phase checks that on every run). The deep
-profiling story is jax.profiler traces (start_trace/stop_trace), which the
-train loop exposes via TrainingConfig.tensorboard_dir.
+chip_smoke.py's device phase checks that on every run).
+
+Every start/stop pair is also a `jax.profiler.TraceAnnotation` of the
+timer's name, so while a trace is being captured (--profile, SIGUSR1:
+telemetry/tracing/capture.py) the phases the loop times appear on the
+`/host:CPU` plane of the same `.xplane.pb` as the device's operations, on
+the profiler's clock: one list of spans, read by `tools/trace_report.py`
+and the benchmark alike (docs/observability.md "Runtime traces"). With no
+capture running an annotation costs well under a microsecond.
 """
 
 from __future__ import annotations
 
 import time
 from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class _Timer:
@@ -21,30 +29,39 @@ class _Timer:
         self._elapsed = 0.0
         self._count = 0
         self._last = 0.0
+        self._span: Optional[TraceAnnotation] = None
 
     def start(self):
         if self._start is not None:
             raise RuntimeError(f"timer {self.name} already started")
+        self._span = TraceAnnotation(self.name)
+        self._span.__enter__()
         self._start = time.perf_counter()
 
     def stop(self):
         if self._start is None:
             raise RuntimeError(f"timer {self.name} not started")
+        self._lap()
+        self._start = None
+        self._span.__exit__(None, None, None)
+        self._span = None
+
+    def _lap(self):
         self._last = time.perf_counter() - self._start
         self._elapsed += self._last
         self._count += 1
-        self._start = None
 
     def elapsed(self, reset: bool = True) -> float:
         running = self._start is not None
         if running:
-            self.stop()
+            # the clock laps; the trace span stays one span
+            self._lap()
         out = self._elapsed
         if reset:
             self._elapsed = 0.0
             self._count = 0
         if running:
-            self.start()
+            self._start = time.perf_counter()
         return out
 
 

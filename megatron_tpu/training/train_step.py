@@ -79,7 +79,8 @@ def make_train_step(
 
             (_, loss), grads = jax.value_and_grad(scaled_loss, has_aux=True)(
                 state.params)
-            new_state, metrics = opt_apply(state, grads)
+            with jax.named_scope("optimizer"):
+                new_state, metrics = opt_apply(state, grads)
             metrics["loss"] = loss
             return new_state, metrics
 
@@ -113,7 +114,8 @@ def make_train_step(
         # mean over microbatches; scaled grads stay scaled for the optimizer
         grads = jax.tree.map(lambda g: g / n, acc)
 
-        new_state, metrics = opt_apply(state, grads)
+        with jax.named_scope("optimizer"):
+            new_state, metrics = opt_apply(state, grads)
         metrics["loss"] = jnp.mean(losses)
         return new_state, metrics
 

@@ -100,39 +100,88 @@ def _kernel_cases():
         return ft.paged_flash_decode(q, kp, vp, table, n,
                                      sliding_window=WINDOW)
 
+    # (case, function, arguments, the kernels it runs, by the names a
+    # device trace finds them under)
     cases = [
-        ("forward", fwd, [q_train, kv_train, kv_train], 1),
-        ("forward_backward", fwd_bwd, [q_train, kv_train, kv_train], 3),
+        ("forward", fwd, [q_train, kv_train, kv_train], ["flash_fwd"]),
+        ("forward_backward", fwd_bwd, [q_train, kv_train, kv_train],
+         ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]),
         ("decode", decode,
-         [((SLOTS, 1, HQ, D), bf16), kv_cache, kv_cache, lens], 1),
+         [((SLOTS, 1, HQ, D), bf16), kv_cache, kv_cache, lens],
+         ["flash_decode"]),
         ("decode_mq5", decode_mq,
-         [((SLOTS, 5, HQ, D), bf16), kv_cache, kv_cache, lens], 1),
+         [((SLOTS, 5, HQ, D), bf16), kv_cache, kv_cache, lens],
+         ["flash_decode"]),
         ("decode_int8_kv", decode_int8,
          [((SLOTS, 1, HQ, D), bf16),
           ((SLOTS, CACHE, HKV, D), jnp.int8),
           ((SLOTS, CACHE, HKV, D), jnp.int8),
           ((SLOTS, CACHE, HKV, 1), jnp.float32),
-          ((SLOTS, CACHE, HKV, 1), jnp.float32), lens], 1),
+          ((SLOTS, CACHE, HKV, 1), jnp.float32), lens], ["flash_decode"]),
     ]
     for ps in (8, 16, 128):
         per_seq = CACHE // ps
         pool = ((SLOTS * per_seq + 1, ps, HKV, D), bf16)
         cases.append((f"paged_decode_page{ps}", paged,
                       [((SLOTS, 1, HQ, D), bf16), pool, pool,
-                       ((SLOTS, per_seq), i32), lens], 1))
+                       ((SLOTS, per_seq), i32), lens],
+                      ["paged_flash_decode"]))
     return cases
 
 
 _CASES = _kernel_cases()
+_KERNEL_TEXTS = {}
 
 
-@pytest.mark.parametrize("name,fn,args,n_kernels", _CASES,
-                         ids=[c[0] for c in _CASES])
-def test_kernel_compiles_for_v5e(topo, name, fn, args, n_kernels):
-    dev = topo.devices[0]
-    compiled = jax.jit(fn).lower(
-        *[_abstract(s, d, dev) for s, d in args]).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+def _kernel_text(topo, name):
+    """The case's program compiled for one described chip, once."""
+    if name not in _KERNEL_TEXTS:
+        _, fn, args, _ = next(c for c in _CASES if c[0] == name)
+        dev = topo.devices[0]
+        _KERNEL_TEXTS[name] = jax.jit(fn).lower(
+            *[_abstract(s, d, dev) for s, d in args]).compile().as_text()
+    return _KERNEL_TEXTS[name]
+
+
+def _kernel_name_stacks(text):
+    """The name stack (`op_name`) of every Pallas custom call of a
+    compiled program, as the tokens a trace reader splits it into."""
+    from megatron_tpu.telemetry.tracing.events import scope_tokens
+
+    stacks = []
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and " custom-call(" in line:
+            m = re.search(r'op_name="([^"]+)"', line)
+            stacks.append(scope_tokens(m.group(1) if m else ""))
+    return stacks
+
+
+def _kernels_named(text):
+    from megatron_tpu.telemetry.tracing.events import (
+        KERNEL_SCOPES, innermost_scope,
+    )
+
+    return sorted(innermost_scope(toks, KERNEL_SCOPES) or "<unnamed>"
+                  for toks in _kernel_name_stacks(text))
+
+
+@pytest.mark.parametrize("name", [c[0] for c in _CASES])
+def test_kernel_compiles_for_v5e(topo, name):
+    kernels = next(c for c in _CASES if c[0] == name)[3]
+    assert _kernel_text(topo, name).count("tpu_custom_call") >= len(kernels)
+
+
+@pytest.mark.parametrize("name", [c[0] for c in _CASES])
+def test_kernel_carries_its_name_for_v5e(topo, name):
+    """Each custom call the case counts sits under its kernel's
+    `jax.named_scope` in the chip compiler's own metadata (what a device
+    trace hands out as `tf_op`), and the HLO instruction is named after
+    the kernel (`pl.pallas_call(name=...)`): none unnamed, none extra."""
+    kernels = next(c for c in _CASES if c[0] == name)[3]
+    text = _kernel_text(topo, name)
+    assert _kernels_named(text) == kernels
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
 
 
 def _mistral_2l():
@@ -170,9 +219,39 @@ def test_train_step_fits_one_v5e(one_chip_step):
     text = one_chip_step.as_text()
     assert text.count("tpu_custom_call") >= 3
     assert _per_device_bytes(one_chip_step) < 16e9
+    _assert_step_kernels_named(text)
 
 
-def test_train_step_tp2_dp2_partitions_the_kernel(topo):
+def _tp2_dp2_step(topo):
+    from megatron_tpu.training.aot import aot_compile_train_step
+
+    return aot_compile_train_step(
+        _mistral_2l(),
+        ParallelConfig(tensor_parallel=2, sequence_parallel=True),
+        OptimizerConfig(lr=1e-4, use_distributed_optimizer=True),
+        micro_batch_size=1, num_microbatches=1, recompute="selective",
+        devices=topo.devices)
+
+
+@pytest.fixture(scope="module")
+def tp2_dp2_step(topo):
+    return _tp2_dp2_step(topo)
+
+
+def _assert_step_kernels_named(text):
+    """The train step's Pallas calls by name: per layer scan the forward,
+    its recomputation in the backward pass, and the two backward kernels,
+    every one nested under the `attention` scope."""
+    assert _kernels_named(text) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                    "flash_fwd", "flash_fwd"]
+    for toks in _kernel_name_stacks(text):
+        kernel = next(t for t in reversed(toks) if t.startswith("flash_"))
+        assert "attention" in toks[:toks.index(kernel)], toks
+    assert any("rematted_computation" in toks
+               for toks in _kernel_name_stacks(text))
+
+
+def test_train_step_tp2_dp2_partitions_the_kernel(tp2_dp2_step):
     """The same step over the four chips of a v5e 2x2 host at TP 2 x DP 2
     with sequence parallelism and the sharded optimizer. GSPMD cannot
     partition a Mosaic kernel ("wrap the call in a shard_map"): the
@@ -180,14 +259,7 @@ def test_train_step_tp2_dp2_partitions_the_kernel(topo):
     keeps its custom calls, gains the collectives, and each device holds
     well under the unsharded state (bf16 params + fp32 master and Adam
     moments: 14 bytes a parameter)."""
-    from megatron_tpu.training.aot import aot_compile_train_step
-
-    compiled, meta = aot_compile_train_step(
-        _mistral_2l(),
-        ParallelConfig(tensor_parallel=2, sequence_parallel=True),
-        OptimizerConfig(lr=1e-4, use_distributed_optimizer=True),
-        micro_batch_size=1, num_microbatches=1, recompute="selective",
-        devices=topo.devices)
+    compiled, meta = tp2_dp2_step
     assert meta["mesh_shape"]["tensor"] == 2
     assert meta["mesh_shape"]["data"] == 2
     text = compiled.as_text()
@@ -197,3 +269,44 @@ def test_train_step_tp2_dp2_partitions_the_kernel(topo):
     sharded = compiled.memory_analysis().argument_size_in_bytes
     whole = 14 * meta["n_params"]
     assert sharded < 0.5 * whole, (sharded / GIB, whole / GIB)
+
+
+def test_train_step_tp2_dp2_names_its_kernels_and_regions(tp2_dp2_step):
+    """Under the mesh the kernels run inside `shard_map`: the names still
+    arrive, on exactly the custom calls the step holds, and every region
+    of the step is in the chip compiler's metadata."""
+    from megatron_tpu.telemetry.tracing.events import (
+        REGION_SCOPES, scope_tokens,
+    )
+
+    text = tp2_dp2_step[0].as_text()
+    _assert_step_kernels_named(text)
+    assert all("shard_map" in toks for toks in _kernel_name_stacks(text))
+    stacks = [scope_tokens(n)
+              for n in set(re.findall(r'op_name="([^"]+)"', text))]
+    for scope in REGION_SCOPES:
+        assert any(scope in toks for toks in stacks), scope
+
+
+def test_the_scopes_change_no_byte_of_the_step(topo, tp2_dp2_step):
+    """`jax.named_scope` writes metadata and nothing else: the same step
+    built with every scope a no-op needs the same bytes on a chip, to the
+    byte, and holds the same instructions. (The kernels keep their
+    `name=`: that names the HLO instruction and the Mosaic module, and is
+    part of the program with scopes and without.)"""
+    import contextlib
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        bare, _ = _tp2_dp2_step(topo)
+    assert "attention" not in " ".join(
+        re.findall(r'op_name="([^"]+)"', bare.as_text()))
+    named, _ = tp2_dp2_step
+    for field in ("argument_size_in_bytes", "output_size_in_bytes",
+                  "alias_size_in_bytes", "temp_size_in_bytes",
+                  "generated_code_size_in_bytes"):
+        assert (getattr(named.memory_analysis(), field)
+                == getattr(bare.memory_analysis(), field)), field
+    opcodes = lambda c: sorted(re.findall(  # noqa: E731
+        r"^\s*(?:ROOT )?%[\w.\-]+ = \S+ ([a-z\-]+)\(", c.as_text(), re.M))
+    assert opcodes(named) == opcodes(bare)

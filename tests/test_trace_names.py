@@ -1,0 +1,82 @@
+"""The names the train step carries for a device trace (docs/
+observability.md "Runtime traces"): `jax.named_scope` on the model's
+regions and on the flash kernels. A trace reader finds an operation's
+region and kernel as tokens of its `op_name` (on a TPU: the `tf_op` of the
+event's metadata), so what is held here is that every name arrives in the
+compiled step, wherever differentiation, recomputation and the mesh's
+`shard_map` put the operation."""
+
+import dataclasses
+import re
+
+import pytest
+
+from megatron_tpu.analysis import targets
+from megatron_tpu.telemetry.tracing.events import (
+    KERNEL_SCOPES, REGION_SCOPES, scope_tokens,
+)
+
+TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def _op_names(parallel, zero1):
+    """Every op_name of the toy train step, compiled for the CPU mesh with
+    the flash kernels dispatched (interpreted)."""
+    t = targets.train_step_target(
+        "named", parallel, zero1=zero1,
+        model_overrides={"attention_impl": "pallas"})
+    t = dataclasses.replace(t, env={"MEGATRON_TPU_FLASH_INTERPRET": "1"})
+    found = set(re.findall(r'op_name="([^"]+)"', t.compiled_text()))
+    # whole name stacks only: interpreted, a kernel's inner loops are
+    # computations of their own whose stacks start at the kernel
+    return sorted(n for n in found if n.startswith("jit("))
+
+
+@pytest.mark.parametrize("parallel, zero1", [
+    ({}, False),
+    ({"tensor_parallel": 2, "sequence_parallel": True}, True),
+], ids=["one_replica_per_device", "tp2_sp_zero1"])
+def test_the_compiled_step_holds_every_scope(parallel, zero1):
+    names = _op_names(parallel, zero1)
+    stacks = [(n, scope_tokens(n)) for n in names]
+    for scope in REGION_SCOPES:
+        assert any(scope in toks for _n, toks in stacks), scope
+    # each kernel sits under `attention`, never beside it
+    for kernel in TRAIN_KERNELS:
+        under = [toks for _n, toks in stacks if kernel in toks]
+        assert under, kernel
+        assert all("attention" in toks[:toks.index(kernel)]
+                   for toks in under), kernel
+    # forward, backward and recomputation keep the names: the forward
+    # kernel runs under jvp and again as rematted computation, the two
+    # backward kernels under the transpose
+    fwd = [n for n, toks in stacks if "flash_fwd" in toks]
+    assert any("jvp(" in n and "rematted_computation" not in n for n in fwd)
+    assert any("rematted_computation" in n for n in fwd)
+    for kernel in TRAIN_KERNELS[1:]:
+        assert all("transpose(" in n for n, toks in stacks
+                   if kernel in toks), kernel
+    # no operation is under two regions at once, nor under a kernel of
+    # the serving path
+    for n, toks in stacks:
+        assert len({t for t in toks if t in REGION_SCOPES}) <= 1, n
+        assert not {"flash_decode", "paged_flash_decode"} & set(toks), n
+    assert set(TRAIN_KERNELS) < set(KERNEL_SCOPES)
+    # the matmuls that hold the time fall under the layer that owns them
+    owners = {next((t for t in toks if t in REGION_SCOPES), None)
+              for _n, toks in stacks if "dot_general" in toks}
+    assert {"attention", "mlp", "head_loss"} <= owners
+
+
+@pytest.mark.parametrize("text, want", [
+    ("jit(train_step)/while/body/closed_call/transpose(jvp(attention))/"
+     "flash_bwd_dq/pallas_call:",
+     ["train_step", "while", "body", "closed_call", "attention",
+      "flash_bwd_dq", "pallas_call"]),
+    ("jit(f)/jvp(head_loss)/bsh,hv->bsv/dot_general",
+     ["f", "head_loss", "bsh,hv->bsv", "dot_general"]),
+    ("", [""]),
+    (None, [""]),
+])
+def test_scope_tokens_strip_the_wrappers(text, want):
+    assert scope_tokens(text) == want

@@ -221,8 +221,9 @@ def test_tpu_marker_lines_are_not_compute():
     # the envelopes span [0,1000) but must NOT hide the collective —
     # only the real fusion op (disjoint from it) counts as compute
     assert ar.exposed_ps == ar.total_ps == 300
-    # the Steps envelope still reads as a step marker
-    assert report.steps["1"]["count"] == 1
+    # the Steps envelope still reads as a step marker, under the line's
+    # name (one marker, not one a step number)
+    assert report.steps["Steps"]["count"] == 1
 
 
 def test_async_collective_pair_counts_once():
@@ -404,6 +405,112 @@ def test_live_capture_real_train_step(tmp_path):
     # 2 profiled steps); the layer scan only multiplies
     for op, row in golden.items():
         assert measured[op] >= row["count"] * 8 * 2, (op, measured)
+
+
+def test_capture_holds_the_loops_spans_and_no_python_tracer(tmp_path):
+    """A --profile window over three whole loop passes, through the one
+    capture helper: the loop thread's line holds a `train-pass` a pass,
+    numbered by the iteration it dispatches, with the timers' spans
+    nested inside under their own names; the prefetcher's transfer is
+    annotated on its worker's line; and no event of the Python tracer
+    (`$file.py:12 fn`) is in the file. The journal gains `dispatch_ms` a
+    step and, after the loop, one `step_program`."""
+    from megatron_tpu.analysis.targets import tiny_model
+    from megatron_tpu.config import (
+        OptimizerConfig, ParallelConfig, RunConfig, TrainingConfig,
+    )
+    from megatron_tpu.telemetry.tracing import (
+        analyze_events, classify_xspace, load_xspace,
+    )
+    from megatron_tpu.training.pretrain import TrainLoop
+
+    trace_dir, tele_dir = tmp_path / "trace", tmp_path / "tele"
+    cfg = RunConfig(
+        model=tiny_model(num_layers=2),
+        parallel=ParallelConfig(),
+        optimizer=OptimizerConfig(lr=1e-3, lr_decay_style="constant"),
+        training=TrainingConfig(
+            micro_batch_size=1, global_batch_size=8, train_iters=7,
+            log_interval=1, profile=True, profile_step_start=3,
+            profile_step_end=7, profile_dir=str(trace_dir),
+            telemetry_dir=str(tele_dir), timing_log_level=1))
+    rows = np.random.default_rng(0).integers(0, 128, (8, 33))
+
+    def factory(consumed, gbs):
+        while True:
+            yield {"tokens": rows[:, :-1].astype(np.int64),
+                   "labels": rows[:, 1:].astype(np.int64),
+                   "loss_mask": np.ones((gbs, 32), np.float32)}
+
+    TrainLoop(cfg, log=lambda s: None).train(factory)
+
+    [path] = _xplane_under(trace_dir)
+    space = load_xspace(path)
+    host = space.plane("/host:CPU")
+    names = {e.name for ln in host.lines for e in ln.events}
+    assert not [n for n in names if n.startswith("$")]
+    [loop_line] = [ln for ln in host.lines
+                   if any(e.name == "train-pass" for e in ln.events)]
+    passes = sorted((e for e in loop_line.events if e.name == "train-pass"),
+                    key=lambda e: e.start_ps)
+    # the window opens inside pass 3 and closes inside pass 7
+    assert [e.stats["step_num"] for e in passes] == [4, 5, 6]
+    for p in passes:
+        inside = {e.name for e in loop_line.events
+                  if p.start_ps <= e.start_ps and e.end_ps <= p.end_ps}
+        assert {"batch-generator", "forward-backward-optimizer",
+                "metrics-fetch"} <= inside
+    [worker_line] = [ln for ln in host.lines
+                     if any(e.name == "batch-transfer" for e in ln.events)]
+    assert worker_line is not loop_line
+    assert any(e.name == "batch-transfer-dispatch"
+               for e in worker_line.events)
+    # the operator's reader takes the annotation as a step marker
+    report = analyze_events(classify_xspace(space))
+    assert report.steps["train-pass"]["count"] == 3
+
+    with open(tele_dir / "events.jsonl") as f:
+        records = [json.loads(line) for line in f]
+    steps = [r for r in records if r["kind"] == "step"]
+    assert len(steps) == 7
+    assert all(0 < r["dispatch_ms"] <= r["step_ms"] for r in steps)
+    [program] = [r for r in records if r["kind"] == "step_program"]
+    # global batch 8 over the fake mesh's 8 data-parallel devices
+    assert program["num_microbatches"] == 1 and program["temp_bytes"] > 0
+    kinds = [r["kind"] for r in records]
+    assert kinds.index("profile_end") < kinds.index("step_program")
+    assert "step" not in kinds[kinds.index("step_program"):]
+
+
+def test_no_profile_window_lowers_nothing(tmp_path):
+    """Without a trace window the trainer journals no `step_program`:
+    the extra lowering is paid only by a run that traced."""
+    from megatron_tpu.analysis.targets import tiny_model
+    from megatron_tpu.config import (
+        OptimizerConfig, ParallelConfig, RunConfig, TrainingConfig,
+    )
+    from megatron_tpu.training.pretrain import TrainLoop
+
+    cfg = RunConfig(
+        model=tiny_model(num_layers=2), parallel=ParallelConfig(),
+        optimizer=OptimizerConfig(lr=1e-3, lr_decay_style="constant"),
+        training=TrainingConfig(
+            micro_batch_size=1, global_batch_size=8, train_iters=2,
+            log_interval=1, telemetry_dir=str(tmp_path / "tele")))
+    rows = np.random.default_rng(0).integers(0, 128, (8, 33))
+
+    def factory(consumed, gbs):
+        while True:
+            yield {"tokens": rows[:, :-1].astype(np.int64),
+                   "labels": rows[:, 1:].astype(np.int64),
+                   "loss_mask": np.ones((gbs, 32), np.float32)}
+
+    loop = TrainLoop(cfg, log=lambda s: None)
+    loop.train(factory)
+    assert loop._profiled_step is None
+    with open(tmp_path / "tele" / "events.jsonl") as f:
+        kinds = [json.loads(line)["kind"] for line in f]
+    assert kinds.count("step") == 2 and "step_program" not in kinds
 
 
 def test_live_contract_measured_equals_expected_ulysses(tmp_path):
@@ -590,6 +697,132 @@ def test_engine_capture_trace_busy_raises():
 
 
 # ---------------------------------------------------------------------------
+# recorded TPU traces (benchmark/fixtures): what a real device plane holds
+# ---------------------------------------------------------------------------
+
+TPU_FIXTURES = os.path.join(REPO, "benchmark", "fixtures")
+
+
+def _tpu_report(name):
+    from megatron_tpu.telemetry.tracing import (
+        analyze_events, classify_xspace, load_xspace,
+    )
+
+    events = classify_xspace(load_xspace(os.path.join(TPU_FIXTURES, name)))
+    return events, analyze_events(events)
+
+
+def test_tpu_trace_ops_come_from_the_xla_ops_line_alone():
+    """PR 22's recording of the one-chip train step, whose program has no
+    scopes. Before PR 23 this read as module <none>, infeed 228.9 ms
+    against compute 141.7 ms (the `Async XLA Ops` line's `copy-done`s
+    counted as operations), steps "0".."8", and ops named by their whole
+    HLO text."""
+    from megatron_tpu.telemetry.tracing.events import KIND_HOST
+
+    events, report = _tpu_report("train_seq4k_tpu_v5e.xplane.pb")
+    ops = [e for e in events if e.kind != KIND_HOST]
+    assert {e.line for e in ops} == {"XLA Ops"}
+    assert {e.module for e in ops} == {"jit_train_step"}
+    assert report.module == "jit_train_step"
+    assert report.compute_s == pytest.approx(0.1264, rel=0.01)
+    assert report.busy_s["infeed"] < 0.001        # real copies on XLA Ops
+    assert report.collective_s == 0
+    # one marker for the device's step envelopes, a step long
+    assert set(report.steps) == {"Steps"}
+    assert report.steps["Steps"]["count"] == 9
+    assert report.steps["Steps"]["p50_ms"] == pytest.approx(170.5, abs=0.5)
+    top = report.ops[0]
+    assert (top.name, top.detail) == (
+        "checkpoint.20", "bf16[1,32,4096,128] custom-call")
+    assert all(len(o.name) < 60 and " = " not in o.name
+               for o in report.ops)
+    # no names in this program: no scope table, gaps under no span
+    assert report.scopes == {} and report.kernels == {}
+    assert [g["span"] for g in report.idle_gaps] == ["<no host span>"]
+
+
+@pytest.mark.parametrize("name, planes, kernel_calls", [
+    ("named_seq4k_tpu_v5e.xplane.pb", 1, {"flash_fwd": 8,
+                                          "flash_bwd_dq": 4,
+                                          "flash_bwd_dkv": 4}),
+    ("named_tp2dp2_tpu_v5e.xplane.pb", 2, {"flash_fwd": 16,
+                                           "flash_bwd_dq": 8,
+                                           "flash_bwd_dkv": 8}),
+])
+def test_named_tpu_trace_reads_by_scope_kernel_and_host_span(
+        name, planes, kernel_calls):
+    """PR 23's recordings (two whole runs a device, the loop thread's
+    spans): own time by the program's scopes, kernels by name, idle gaps
+    by what the loop thread was inside."""
+    from megatron_tpu.telemetry.tracing.events import (
+        KIND_HOST, REGION_SCOPES,
+    )
+
+    events, report = _tpu_report(name)
+    ops = [e for e in events if e.kind != KIND_HOST]
+    assert len({e.plane for e in ops}) == planes
+    assert report.module == "jit_train_step"
+    assert set(report.scopes) == set(REGION_SCOPES) | {"other"}
+    whole = sum(report.scopes.values())
+    # every op's own time is under exactly one scope
+    assert whole == pytest.approx(
+        sum(v for k, v in report.busy_s.items() if k != KIND_HOST))
+    assert list(report.scopes)[:2] == ["attention", "mlp"]
+    assert (report.scopes["other"] + report.scopes["embed"]) < 0.06 * whole
+    assert {k: v["count"] for k, v in report.kernels.items()} == kernel_calls
+    assert sum(k["self_s"] for k in report.kernels.values()) == (
+        pytest.approx(sum(o.self_s for o in report.ops
+                          if o.detail.endswith("custom-call")
+                          and o.name.startswith("flash_")), rel=1e-4))
+    assert report.ops[0].name.startswith("flash_bwd_dkv")
+    # host and device share a clock: the loop's passes are a step long,
+    # and the device ran dry only while the loop waited for its metrics
+    assert report.steps["train-pass"]["count"] >= 2
+    assert report.idle_gaps[0]["span"] == "np.asarray(jax.Array)"
+    assert sum(g["total_s"] for g in report.idle_gaps) < 1e-3
+    if planes > 1:
+        assert {c.op for c in report.collectives} >= {"all-gather",
+                                                      "all-reduce"}
+    d = report.to_dict(top=5)
+    assert json.dumps(d) and d["scopes"] and d["kernels"] and d["idle_gaps"]
+
+
+def test_idle_gaps_fall_under_the_innermost_loop_thread_span():
+    from megatron_tpu.telemetry.tracing.analyze import idle_gaps_by_host_span
+    from megatron_tpu.telemetry.tracing.events import (
+        KIND_COMPUTE, KIND_HOST, OpEvent,
+    )
+
+    US = 1_000_000  # picoseconds
+
+    def op(start, dur):
+        return OpEvent("fusion.1", KIND_COMPUTE, start * US, dur * US,
+                       "/device:TPU:0", "XLA Ops")
+
+    def span(name, start, dur, line="loop", step=None):
+        return OpEvent(name, KIND_HOST, start * US, dur * US, "/host:CPU",
+                       line, step_num=step)
+
+    events = [
+        op(0, 100), op(150, 100), op(300, 100), op(1000, 50),
+        span("train-pass", 0, 600, step=1),
+        span("batch-generator", 90, 30),            # open at 100
+        span("forward-backward-optimizer", 200, 200),
+        span("PjitFunction(train_step)", 240, 40),  # open at 250
+        span("noise", 390, 100, line="another thread"),
+        # the gap that opens at 400: train-pass alone; nothing at 1050
+    ]
+    gaps = {g["span"]: g for g in idle_gaps_by_host_span(events)}
+    assert gaps["batch-generator"]["total_s"] == pytest.approx(50e-6)
+    assert gaps["PjitFunction(train_step)"]["count"] == 1
+    assert gaps["train-pass"]["total_s"] == pytest.approx(600e-6)
+    assert "noise" not in gaps and len(gaps) == 3
+    assert idle_gaps_by_host_span([e for e in events
+                                   if e.kind == KIND_HOST]) == []
+
+
+# ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------
 
@@ -607,6 +840,29 @@ def test_trace_report_cli_text_and_json(capsys):
     data = json.loads(capsys.readouterr().out)
     assert data["report"]["module"] == "jit_fixture_step"
     assert data["report"]["busy_s"]["compute"] > 0
+
+
+@pytest.mark.parametrize("name, wants, never", [
+    ("train_seq4k_tpu_v5e.xplane.pb",
+     ["module jit_train_step", "infeed 378.8us",
+      "checkpoint.20 bf16[1,32,4096,128] custom-call"],
+     ["<none>", "own time by scope", " = "]),
+    ("named_seq4k_tpu_v5e.xplane.pb",
+     ["module jit_train_step", "own time by scope", "kernel flash_fwd",
+      "kernel flash_bwd_dkv", "idle gaps of the first device",
+      "np.asarray(jax.Array)", "train-pass"],
+     ["<none>", " = "]),
+])
+def test_trace_report_cli_on_tpu_traces(capsys, name, wants, never):
+    from tools import trace_report
+
+    path = os.path.join(REPO, "benchmark", "fixtures", name)
+    assert trace_report.main([path]) == 0
+    out = capsys.readouterr().out
+    for text in wants:
+        assert text in out, (text, out)
+    for text in never:
+        assert text not in out, (text, out)
 
 
 def test_trace_report_cli_never_imports_jax(tmp_path):

@@ -15,8 +15,11 @@ is testable without a chip).
 
 Prints the per-op table, the compute / collective / infeed busy split
 with per-collective total vs. EXPOSED time (not overlapped by compute —
-the Flash Communication number), per-step wall from the jit dispatch
-markers, and with ``--contract NAME`` the measured-vs-expected
+the Flash Communication number), per-step wall from the step markers,
+own time by the program's named scopes with the kernels by name, the
+device's idle gaps by the host span under each (both where the trace
+carries the names: a TPU trace of a program with the scopes and the
+loop's annotations), and with ``--contract NAME`` the measured-vs-expected
 collective counts against the golden comm manifest
 (``megatron_tpu/analysis/golden/NAME.json``) plus effective bus
 bandwidth from the manifest's byte volumes.
@@ -126,15 +129,34 @@ def render_text(report, comparison, top: int, files) -> str:
                 f"{_fmt_s(c.exposed_ps / 1e12):>10} "
                 f"({100 * c.exposed_frac:.1f}%)")
     if report.steps:
-        lines.append("steps (jit dispatch spans):")
+        lines.append("steps (jit dispatch spans, step annotations, a "
+                     "device's Steps envelopes):")
         for name, st in sorted(report.steps.items(),
                                key=lambda kv: -kv[1]["total_ms"]):
             lines.append(f"  {name:<32} x{st['count']:<5} "
                          f"p50 {st['p50_ms']}ms  max {st['max_ms']}ms")
+    if report.scopes:
+        lines.append("own time by scope (the program's jax.named_scope "
+                     "regions; kernels by name):")
+        whole = sum(report.scopes.values()) or 1.0
+        for name, sec in report.scopes.items():
+            lines.append(f"  {name:<20} {_fmt_s(sec):>10}  "
+                         f"({100 * sec / whole:.1f}%)")
+        for name, k in report.kernels.items():
+            lines.append(f"    kernel {name:<15} {_fmt_s(k['self_s']):>10}"
+                         f"  x{k['count']}")
+    if report.idle_gaps:
+        lines.append("idle gaps of the first device, by the innermost "
+                     "loop-thread span as each opens:")
+        for g in report.idle_gaps[:top]:
+            lines.append(f"  {g['span']:<32} x{g['count']:<6} total "
+                         f"{_fmt_s(g['total_s']):>10}  max "
+                         f"{_fmt_s(g['max_s']):>10}")
     lines.append(f"top {top} ops by self time:")
     for o in report.ops[:top]:
         lines.append(f"  {o.self_s * 1e3:10.3f}ms  x{o.count:<6} "
-                     f"[{o.kind[:4]}] {o.name}")
+                     f"[{o.kind[:4]}] {o.name}"
+                     + (f" {o.detail}" if o.detail else ""))
     if comparison is not None:
         lines.append(
             f"contract {comparison.config} ({comparison.level} level, "
