@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 
 from megatron_tpu.ops.pallas.flash_template import (  # noqa: F401
-    DEFAULT_BLOCK,
     _NEG_INF,
     _bwd,
     _delta_arr,
@@ -97,8 +96,8 @@ def flash_attention(
     v: jnp.ndarray,
     sliding_window: Optional[int] = None,
     causal: bool = True,
-    block_q: int = DEFAULT_BLOCK,
-    block_k: int = DEFAULT_BLOCK,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
     """Public entry in framework layout: the template's fused fwd +
     custom-vjp bwd (flash_template.flash_mha) on every backend —

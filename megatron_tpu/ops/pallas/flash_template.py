@@ -31,10 +31,23 @@ instantiation of the one template in this module, with four knobs:
 
 Online softmax (running max m, running sum l, unnormalized acc in VMEM
 scratch persisting across the sequential kv steps) is shared by every
-instantiation, as is the block-skip: a kv tile outside the visible band of
-the tile's queries (masks.block_live) never loads or computes, so causal
-prefill pays ~half the tiles and a young decode slot in a long cache pays
-only for the context it has.
+instantiation, as is the block-skip: a grid step whose kv tile lies
+outside the visible band of the tile's queries (masks.block_live) skips
+its compute, so causal prefill computes ~half the tiles and a young decode
+slot in a long cache computes only the context it has. The step itself is
+still taken, and the BlockSpec pipeline still issues its DMA unless the
+index map names the block the step before it held: the training kernels'
+maps clamp a skipped step to the nearest live tile of its row
+(`_inner_tile_map`), so their dead steps move nothing; the
+decode kernels' maps do not, and load the tiles they skip.
+
+Precision: the training kernels (fwd, dq, dk/dv) hand the MXU their
+operands in the dtype they arrive in — q, k, v, do as given, the
+probabilities and ds cast to that dtype right before their matmuls — and
+every matmul accumulates in float32 (`_dot`). The softmax statistics (m,
+l, lse, delta), exp, the 1/sqrt(d) scale and all accumulators are
+float32. bf16 in: bf16 MXU passes. float32 in (the CPU suite, a float32
+reference): float32 operands, at the backend's default matmul precision.
 
 Layouts: public entries take the framework-native [B, S, H, D]; kernels
 run on [B, H, S, D] so the (S, D) tile is MXU-facing. Kernels run in
@@ -45,7 +58,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,8 +67,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 from megatron_tpu.ops.pallas import masks
 
-DEFAULT_BLOCK = 256
 _NEG_INF = masks.NEG_INF
+# Mosaic's scoped-VMEM default on a v5e, and what a kernel may ask for of
+# the core's 128 MiB
+_DEFAULT_SCOPED_VMEM = 16 << 20
+_MAX_SCOPED_VMEM = 96 << 20
+
+# contraction patterns of the 2-D tile matmuls: A·Bᵀ, A·B, Aᵀ·B
+_NT = (((1,), (1,)), ((), ()))
+_NN = (((1,), (0,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _dot(a, b, dims):
+    """Tile matmul of the training kernels: operands in the dtype they
+    were given, float32 result (the precision contract above)."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
 
 
 def _interpret() -> bool:
@@ -78,17 +106,58 @@ def _pick_block(s: int, cap: int = 512) -> Optional[int]:
 
 
 def _fit_block(block: int, s: int) -> int:
-    """The asked block, or the 128 tile when the block does not divide a
-    sequence that 128 does (384, 640, ...: serving prefill buckets); a
-    sequence shorter than a block is one block (interpreter-size inputs)."""
-    block = min(block, s)
-    return 128 if (s % block and s % 128 == 0) else block
+    """The asked block, halved down to the 128 tile until it divides the
+    sequence (384, 640, ...: serving prefill buckets run at 128). Where
+    none does, a sequence shorter than the block is one block
+    (interpreter-size inputs) and a longer one keeps the asked block,
+    which `supported` then refuses."""
+    b = block
+    while b >= 128:
+        if b <= s and s % b == 0:
+            return b
+        if b % 256:
+            break
+        b //= 2
+    return min(block, s)
 
 
-def supported(q_len: int, kv_len: int, block_q: int = DEFAULT_BLOCK,
-              block_k: int = DEFAULT_BLOCK) -> bool:
+def supported(q_len: int, kv_len: int, block_q: int, block_k: int) -> bool:
     return (q_len == kv_len and q_len % block_q == 0
             and kv_len % block_k == 0)
+
+
+# Tiles of the three training kernels, from a sweep on the v5e (PR 24;
+# bf16 [1,32,S,128], window 4096, ms a call at block_q x block_k with
+# dead steps re-naming the held block; PERF.md section 6 has all of it):
+#
+#   S 4096         256x256  512x512  512x1024  1024x512  1024x1024
+#   flash_fwd        5.62     2.50     2.03      2.67      1.79
+#   flash_bwd_dq     4.20     2.28     2.10      2.08      1.91
+#   flash_bwd_dkv    6.75     2.60     2.16      2.22      2.11
+#
+# 1024x1024 is also the fastest of the nine at S 2048, 8192 and 16384 for
+# every kernel (16384: 13.3 / 13.7 / 17.1 ms against 52.5 / 42.6 / 68.2 at
+# 256x256) and at [8,16,4096,128]; at S 1024 every tile of 512 or more
+# reads the same. Why: the body is straight-line code that issues one
+# bundle a cycle, and per 1024 scores it is 23 bundles at 256x256, 11 at
+# 512x512 and 8 at 1024x1024 (the per-row statistics, their lane
+# reductions and the accumulator's rescale spread over more columns),
+# which outweighs the masked share of the diagonal tiles (20 % of the live
+# area at 1024). A 2048 tile's [BQ, BK] float32 temporaries do not fit.
+_SWEPT_BLOCK = 1024
+
+
+def pick_blocks(s: int, d: int, dtype) -> Tuple[int, int]:
+    """(block_q, block_k) of the training kernels for sequences of s rows
+    of d elements of dtype: the swept tile fitted to the sequence
+    (`_fit_block`), halved while the backward kernels' VMEM footprint at
+    this row width is more than a kernel may ask for."""
+    block = _SWEPT_BLOCK
+    while (block > 128 and _bwd_vmem_bytes(
+            block, block, d, jnp.dtype(dtype).itemsize) > _MAX_SCOPED_VMEM):
+        block //= 2
+    block = _fit_block(block, s)
+    return block, block
 
 
 # ---------------------------------------------------------------------------
@@ -112,14 +181,15 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # FA-2 block-skip: tiles outside the visible band (beyond the causal
-    # frontier / before the window's lower edge) never compute
+    # frontier / before the window's lower edge) never compute, and the
+    # kv index map has re-named the held block, so they never load either
     @pl.when(masks.prefill_block_live(qi, ki, block_q, block_k,
                                       causal=causal, window=window,
                                       delta=delta))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale     # [BQ, D]
-        k = k_ref[0, 0].astype(jnp.float32)             # [BK, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [BQ, BK]
+        q = q_ref[0, 0]                                  # [BQ, D]
+        k = k_ref[0, 0]                                  # [BK, D]
+        s = _dot(q, k, _NT) * scale                      # [BQ, BK] f32
 
         q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k,
                                                delta)
@@ -131,8 +201,8 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, 0].astype(jnp.float32)              # [BK, D]
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))  # [BQ, D]
+        v = v_ref[0, 0]                                  # [BK, D]
+        pv = _dot(p.astype(v.dtype), v, _NN)             # [BQ, D] f32
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = m_new
         l_scr[:] = l_new
@@ -172,42 +242,88 @@ def _delta_arr(delta):
     return jnp.asarray(delta, jnp.int32).reshape(1)
 
 
+def _outer_tile_map(b, h, outer, inner, off_ref):
+    """Index map of the tile a grid (b, h, outer, inner) holds across its
+    inner, sequential axis (the scalar-prefetch operand rides along)."""
+    return (b, h, outer, 0)
+
+
+def _inner_tile_map(live_tiles, n: int):
+    """Index map of the tile that walks the inner axis of a grid
+    (b, h, outer, inner) whose scalar-prefetch operand is the position
+    offset. `live_tiles(outer, delta=)` is the (first, last) inner tile
+    the block-skip admits (masks.prefill_live_kv_tiles for the forward
+    and dq grids, prefill_live_q_tiles for dk/dv): a step outside it
+    names the nearest live tile instead of its own, so the pipeline sees
+    the block it already holds and issues no DMA. The second clip keeps a
+    row with no live tile at all inside the grid of n tiles."""
+    def index(b, h, outer, inner, off_ref):
+        lo, hi = live_tiles(outer, delta=off_ref[0])
+        return (b, h, jnp.clip(jnp.clip(inner, lo, hi), 0, n - 1), 0)
+    return index
+
+
+def _compiler_params(vmem_bytes: int):
+    """Three parallel axes and the sequential reduction axis; the scoped
+    VMEM limit raised only when the tiles' footprint needs it."""
+    limit = None
+    if vmem_bytes > _DEFAULT_SCOPED_VMEM:
+        limit = min(vmem_bytes, _MAX_SCOPED_VMEM)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"),
+        vmem_limit_bytes=limit)
+
+
+def _fwd_vmem_bytes(block_q, block_k, D, item):
+    """q, o and the k, v tiles double-buffered, lse lane-padded, the
+    float32 statistics and accumulator, and ~4 live [BQ, BK] float32
+    temporaries (s, mask, p and p in v's dtype)."""
+    return (2 * (2 * block_q + 2 * block_k) * D * item
+            + 2 * block_q * 128 * 4 + block_q * (D + 256) * 4
+            + 4 * block_q * block_k * 4)
+
+
 def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
     """q [B,Hq,Sq,D], k/v [B,Hq,Skv,D] (kv already group-broadcast).
     Returns (o [B,Hq,Sq,D], lse [B,Hq,Sq]). delta: traced q-vs-k global
     position offset (ring stripes); None = aligned."""
     B, H, Sq, D = q.shape
     Skv = k.shape[2]
-    grid = (B, H, Sq // block_q, Skv // block_k)
+    nk = Skv // block_k
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k)
+    q_map = _outer_tile_map
+    kv_map = _inner_tile_map(functools.partial(
+        masks.prefill_live_kv_tiles, block_q=block_q, block_k=block_k,
+        causal=causal, window=window), nk)
     o, lse = _named_pallas_call(
         "flash_fwd", kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 128), lambda b, h, qi, ki: (b, h, qi, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, Sq // block_q, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), q_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, D), q_map),
+                pl.BlockSpec((1, 1, block_q, 128), q_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, D), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Sq, 128), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_compiler_params(
+            _fwd_vmem_bytes(block_q, block_k, D, q.dtype.itemsize)),
         interpret=_interpret(),
     )(_delta_arr(delta), q, k, v)
     return o, lse
@@ -235,25 +351,25 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                       causal=causal, window=window,
                                       delta=off))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
         lse = lse_ref[0, 0][:, 0:1]                      # [BQ, 1]
         delta = delta_ref[0, 0][:, 0:1]                  # [BQ, 1]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        s = _dot(q, k, _NT) * scale
         q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k, off)
         mask = masks.visible(q_pos, k_pos, causal=causal, window=window)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)       # softmax probs
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))  # [BQ, BK]
+        dp = _dot(do, v, _NT)                            # [BQ, BK]
         ds = p * (dp - delta)
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ()))) * scale
+        dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
 
     @pl.when(ki == nk - 1)
     def _emit():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        # the 1/sqrt(d) of the scores, once on the float32 sum
+        dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -274,95 +390,133 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                       causal=causal, window=window,
                                       delta=off))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
         lse = lse_ref[0, 0][:, 0:1]
         delta = delta_ref[0, 0][:, 0:1]
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        s = _dot(q, k, _NT) * scale
         q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k, off)
         mask = masks.visible(q_pos, k_pos, causal=causal, window=window)
         p = jnp.where(mask, jnp.exp(s - lse), 0.0)       # [BQ, BK]
-        dv_scr[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
+        dv_scr[:] += _dot(p.astype(do.dtype), do, _TN)
+        dp = _dot(do, v, _NT)
         ds = p * (dp - delta)
-        # q was pre-scaled on load, so this dot already carries the 1/sqrt(d)
-        dk_scr[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
+        dk_scr[:] += _dot(ds.astype(q.dtype), q, _TN)
 
     @pl.when(qi == nq - 1)
     def _emit():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
+        # the 1/sqrt(d) of the scores, once on the float32 sum
+        dk_ref[0, 0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
-         offset=None):
-    B, H, Sq, D = q.shape
-    Skv = k.shape[2]
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)  # [B,H,Sq,1]
-    delta = jnp.broadcast_to(delta, delta.shape[:-1] + (128,))
-    off = _delta_arr(offset)
+def _bwd_vmem_bytes(block_q, block_k, D, item):
+    """q, do, k, v tiles and the output tile(s) double-buffered, lse and
+    delta lane-padded, the float32 accumulators, and ~6 live [BQ, BK]
+    float32 temporaries (s, mask, p, dp, ds and a cast of p or ds)."""
+    return (2 * (3 * block_q + 4 * block_k) * D * item
+            + 2 * 2 * block_q * 128 * 4
+            + 2 * max(block_q, block_k) * D * 4
+            + 6 * block_q * block_k * 4)
 
-    dq_kernel = functools.partial(
+
+def _bwd_delta(o, do):
+    """rowsum(do * o) in float32, lane-padded like lse: [B,H,Sq,128]."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    return jnp.broadcast_to(delta, delta.shape[:-1] + (128,))
+
+
+def _bwd_dq(q, k, v, do, lse, delta, scale, causal, window, block_q,
+            block_k, offset=None):
+    """dq [B,H,Sq,D]: grid (b, h, qi, ki), kv innermost, one float32
+    accumulator per q tile."""
+    B, H, Sq, D = q.shape
+    nk = k.shape[2] // block_k
+    kernel = functools.partial(
         _dq_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k)
-    dq = _named_pallas_call(
-        "flash_bwd_dq", dq_kernel,
-        grid=(B, H, Sq // block_q, Skv // block_k),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 128), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 128), lambda b, h, qi, ki: (b, h, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D),
-                               lambda b, h, qi, ki: (b, h, qi, 0)),
+    q_map = _outer_tile_map
+    kv_map = _inner_tile_map(functools.partial(
+        masks.prefill_live_kv_tiles, block_q=block_q, block_k=block_k,
+        causal=causal, window=window), nk)
+    return _named_pallas_call(
+        "flash_bwd_dq", kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, Sq // block_q, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), q_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_q, D), q_map),
+                pl.BlockSpec((1, 1, block_q, 128), q_map),
+                pl.BlockSpec((1, 1, block_q, 128), q_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, block_q, D), q_map),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_compiler_params(
+            _bwd_vmem_bytes(block_q, block_k, D, q.dtype.itemsize)),
         interpret=_interpret(),
-    )(off, q, k, v, do, lse, delta)
+    )(_delta_arr(offset), q, k, v, do, lse, delta)
 
-    dkv_kernel = functools.partial(
+
+def _bwd_dkv(q, k, v, do, lse, delta, scale, causal, window, block_q,
+             block_k, offset=None):
+    """(dk, dv) [B,H,Skv,D]: grid (b, h, ki, qi), q innermost, two
+    float32 accumulators per kv tile."""
+    B, H, Sq, D = q.shape
+    Skv = k.shape[2]
+    nq = Sq // block_q
+    kernel = functools.partial(
         _dkv_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k)
-    dk, dv = _named_pallas_call(
-        "flash_bwd_dkv", dkv_kernel,
-        grid=(B, H, Skv // block_k, Sq // block_q),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, ki, qi: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, qi: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, qi: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, ki, qi: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 128), lambda b, h, ki, qi: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q, 128), lambda b, h, ki, qi: (b, h, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, qi: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, qi: (b, h, ki, 0)),
-        ],
+    q_map = _inner_tile_map(functools.partial(
+        masks.prefill_live_q_tiles, block_q=block_q, block_k=block_k,
+        causal=causal, window=window), nq)
+    kv_map = _outer_tile_map
+    return _named_pallas_call(
+        "flash_bwd_dkv", kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, Skv // block_k, nq),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), q_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_q, D), q_map),
+                pl.BlockSpec((1, 1, block_q, 128), q_map),
+                pl.BlockSpec((1, 1, block_q, 128), q_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_compiler_params(
+            _bwd_vmem_bytes(block_q, block_k, D, q.dtype.itemsize)),
         interpret=_interpret(),
-    )(off, q, k, v, do, lse, delta)
+    )(_delta_arr(offset), q, k, v, do, lse, delta)
+
+
+def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
+         offset=None):
+    delta = _bwd_delta(o, do)
+    dq = _bwd_dq(q, k, v, do, lse, delta, scale, causal, window,
+                 block_q, block_k, offset)
+    dk, dv = _bwd_dkv(q, k, v, do, lse, delta, scale, causal, window,
+                      block_q, block_k, offset)
     return dq, dk, dv
 
 
@@ -398,19 +552,21 @@ def flash_mha(
     v: jnp.ndarray,
     sliding_window: Optional[int] = None,
     causal: bool = True,
-    block_q: int = DEFAULT_BLOCK,
-    block_k: int = DEFAULT_BLOCK,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jnp.ndarray:
     """The training/prefill instantiation in framework layout: fused
     forward + the FA-2 recompute backward via custom_vjp — jax.grad
     through this never builds the XLA O(S^2) gradient. GQA broadcasts
     K/V per group (dk/dv group-sum falls out of the broadcast's own
-    vjp). Raises ValueError for geometries the template doesn't cover."""
+    vjp). Tiles come from `pick_blocks` unless block_q / block_k are
+    given. Raises ValueError for geometries the template doesn't cover."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     groups = hq // hkv
-    block_q = _fit_block(block_q, sq)
-    block_k = _fit_block(block_k, skv)
+    picked_q, picked_k = pick_blocks(sq, d, q.dtype)
+    block_q = _fit_block(block_q, sq) if block_q else picked_q
+    block_k = _fit_block(block_k, skv) if block_k else picked_k
     if not supported(sq, skv, block_q, block_k):
         raise ValueError(
             f"flash kernel needs equal seq lens divisible by the block "

@@ -141,3 +141,27 @@ def prefill_block_live(qi, ki, block_q: int, block_k: int, *,
     return block_live(ki, block_k, qi * block_q + delta,
                       qi * block_q + block_q - 1 + delta,
                       causal=causal, window=window)
+
+
+def prefill_live_kv_tiles(qi, block_q: int, block_k: int, *,
+                          causal: bool = True, window: Optional[int] = None,
+                          delta=0):
+    """(first, last) kv tile that `prefill_block_live` admits for q tile
+    qi: the interval form solved for ki. Unclipped: `first` may be
+    negative and `last` beyond the grid (or below `first`: no live tile),
+    so callers clip into their grid."""
+    q_lo = qi * block_q + delta
+    first = (q_lo - window + 1) // block_k if window is not None else 0
+    last = (q_lo + block_q - 1) // block_k if causal else 2 ** 30
+    return first, last
+
+
+def prefill_live_q_tiles(ki, block_q: int, block_k: int, *,
+                         causal: bool = True, window: Optional[int] = None,
+                         delta=0):
+    """(first, last) q tile that `prefill_block_live` admits for kv tile
+    ki: the same interval solved for qi (the dk/dv kernel's inner axis)."""
+    first = (ki * block_k - delta) // block_q if causal else 0
+    last = (((ki + 1) * block_k + window - delta - 2) // block_q
+            if window is not None else 2 ** 30)
+    return first, last

@@ -106,6 +106,18 @@ def _kernel_cases():
         ("forward", fwd, [q_train, kv_train, kv_train], ["flash_fwd"]),
         ("forward_backward", fwd_bwd, [q_train, kv_train, kv_train],
          ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]),
+    ]
+    # the tiles `pick_blocks` gives other lengths (window 4096 clips the
+    # longer one): a pick that does not fit VMEM or is not tile-aligned
+    # fails here
+    cases += [
+        (f"forward_backward_s{s}", fwd_bwd,
+         [((1, s, HQ, D), bf16), ((1, s, HKV, D), bf16),
+          ((1, s, HKV, D), bf16)],
+         ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"])
+        for s in (1024, 16384)
+    ]
+    cases += [
         ("decode", decode,
          [((SLOTS, 1, HQ, D), bf16), kv_cache, kv_cache, lens],
          ["flash_decode"]),
@@ -182,6 +194,46 @@ def test_kernel_carries_its_name_for_v5e(topo, name):
     assert _kernels_named(text) == kernels
     for kernel in kernels:
         assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+
+
+@pytest.mark.parametrize("s", [128, 384, 640, 1024, 1536, 2048, 4096,
+                               16384, 32768])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_picked_blocks_tile_the_sequence(s, dtype):
+    """The tile `pick_blocks` hands the kernels divides the sequence, is
+    lane-aligned and is the swept one wherever that divides; the serving
+    buckets that 128 divides and 256 does not run at 128."""
+    bq, bk = ft.pick_blocks(s, D, dtype)
+    assert s % bq == 0 and s % bk == 0, (bq, bk)
+    assert bq % 128 == 0 and bk % 128 == 0, (bq, bk)
+    want = {128: 128, 384: 128, 640: 128, 1536: 512}.get(s, 1024)
+    assert (bq, bk) == (want, want)
+
+
+def test_picked_blocks_shrink_for_rows_that_do_not_fit_vmem():
+    """A row wide enough that the backward tiles would outgrow what a
+    kernel may ask of VMEM gets a smaller tile, never an uncompilable
+    one."""
+    wide = ft.pick_blocks(4096, 8192, jnp.float32)
+    assert wide[0] < ft.pick_blocks(4096, D, jnp.bfloat16)[0]
+    assert ft._bwd_vmem_bytes(*wide, 8192, 4) <= ft._MAX_SCOPED_VMEM
+
+
+def test_explicit_blocks_win_over_the_pick(monkeypatch):
+    """block_q= / block_k= given to flash_mha (tests, ring stripes) reach
+    the kernels; one left out keeps its picked value."""
+    seen = []
+    monkeypatch.setattr(
+        ft, "_flash_bhsd",
+        lambda q, k, v, scale, causal, window, block_q, block_k:
+        seen.append((block_q, block_k)) or q)
+    x = jnp.zeros((1, 2048, 2, D), jnp.bfloat16)
+    ft.flash_mha(x, x, x)
+    ft.flash_mha(x, x, x, block_q=128, block_k=256)
+    ft.flash_mha(x, x, x, block_k=128)
+    picked = ft.pick_blocks(2048, D, jnp.bfloat16)
+    assert seen == [picked, (128, 256), (picked[0], 128)]
 
 
 def _mistral_2l():

@@ -1,6 +1,8 @@
 """Pallas flash-attention kernel vs the XLA einsum path (interpret mode on
 the CPU suite; the same kernels compile for real on TPU — see bench.py)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -105,3 +107,101 @@ def test_splash_path_grads_finite():
     grads = jax.grad(f, argnums=(0, 1, 2))(qt, kt, vt)
     for g in grads:
         assert np.isfinite(np.asarray(g)).all()
+
+
+# ---------------------------------------------------------------------------
+# bf16 inputs: the operands reach the MXU as given, statistics and
+# accumulators stay float32 (flash_template's precision contract)
+# ---------------------------------------------------------------------------
+
+
+def _bf16_case(window, hkv):
+    """bf16 q/k/v/weights and, on the SAME bf16 values, the float32 XLA
+    reference's output and gradients."""
+    rng = np.random.default_rng(11)
+    q, k, v, w = (jnp.asarray(rng.standard_normal((1, 256, 4, 64)),
+                              jnp.bfloat16) for _ in range(4))
+    k, v = k[:, :, :hkv], v[:, :, :hkv]
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+
+    def loss(fn, q, k, v):
+        return jnp.sum(f32(fn(q, k, v)) * f32(w))
+
+    def ref(q, k, v):
+        return attention(q, k, v, sliding_window=window)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, sliding_window=window,
+                               block_q=128, block_k=128)
+
+    want_o = ref(f32(q), f32(k), f32(v))
+    want_g = jax.grad(functools.partial(loss, ref), argnums=(0, 1, 2))(
+        f32(q), f32(k), f32(v))
+    return (q, k, v), flash, functools.partial(loss, flash), want_o, want_g
+
+
+def _share_of_range(got, want):
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(np.asarray(got, np.float32) - want))
+                 / np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("hkv", [2, 4], ids=["gqa", "mha"])
+@pytest.mark.parametrize("window", [None, 64])
+def test_flash_bf16_forward_matches_float32_reference(window, hkv):
+    """Measured 2.6e-3 of range at the cell's shapes (one bf16 rounding
+    of the output is 2e-3); 1e-2 leaves headroom and still catches a
+    rounded statistic or a bf16 accumulator."""
+    args, flash, _, want_o, _ = _bf16_case(window, hkv)
+    got = flash(*args)
+    assert got.dtype == jnp.bfloat16
+    assert _share_of_range(got, want_o) <= 1e-2
+
+
+@pytest.mark.parametrize("hkv", [2, 4], ids=["gqa", "mha"])
+@pytest.mark.parametrize("window", [None, 64])
+def test_flash_bf16_grads_match_float32_reference(window, hkv):
+    """dq/dk/dv measured <= 4.0e-3 of their range; 1.5e-2 is the gate."""
+    args, _, loss, _, want_g = _bf16_case(window, hkv)
+    got_g = jax.grad(loss, argnums=(0, 1, 2))(*args)
+    for name, got, want in zip("qkv", got_g, want_g):
+        assert got.dtype == jnp.bfloat16
+        assert _share_of_range(got, want) <= 1.5e-2, f"d{name}"
+
+
+def _kernel_dots(jaxpr, kernel=None):
+    """(kernel name, operand dtypes, result dtype) of every dot_general
+    inside a pallas_call, through nested jaxprs."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield from _kernel_dots(eqn.params["jaxpr"], eqn.params["name"])
+            continue
+        if kernel and eqn.primitive.name == "dot_general":
+            yield (kernel, tuple(v.aval.dtype for v in eqn.invars),
+                   eqn.outvars[0].aval.dtype)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_dots(sub, kernel)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_kernel_matmuls_take_operands_as_given(dtype):
+    """Every matmul of the three training kernels multiplies the dtype
+    the caller passed and accumulates in float32: bf16 tensors reach the
+    MXU as bf16 (no cast to float32 comes back), and float32 tensors
+    still multiply in float32 — which is what keeps the float32 numerics
+    tests above meaning what they say."""
+    from megatron_tpu.ops.pallas.flash_template import flash_mha
+
+    q, k, v = (x.astype(dtype) for x in _qkv(s=256, hq=2, hkv=1, d=64))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_mha(q, k, v, block_q=128, block_k=128)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
+    dots = list(_kernel_dots(jaxpr.jaxpr))
+    per_kernel = {name: sum(1 for d in dots if d[0] == name)
+                  for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert per_kernel == {"flash_fwd": 2, "flash_bwd_dq": 3,
+                          "flash_bwd_dkv": 4}, dots
+    for kernel, operands, result in dots:
+        assert operands == (dtype, dtype), (kernel, operands)
+        assert result == jnp.float32, (kernel, result)

@@ -61,6 +61,34 @@ def test_prefill_block_live_matches_dense(causal, window):
                 assert bool(got) == want, (qi, ki, delta)
 
 
+@pytest.mark.parametrize("window", [None, 1, 3, 8, 64])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blk_q,blk_k", [(8, 8), (8, 16), (16, 8)])
+def test_prefill_live_tile_ranges_are_the_block_predicate(blk_q, blk_k,
+                                                          causal, window):
+    """The (first, last) live tile the index maps clamp a skipped step to
+    is `prefill_block_live` solved for the inner grid axis: inside the
+    grid a tile is in the range iff the predicate admits it — for the kv
+    axis of the forward and dq kernels and the q axis of dk/dv."""
+    n = 12
+    for delta in (0, 5, 64, -16):
+        for outer in range(n):
+            lo, hi = masks.prefill_live_kv_tiles(
+                outer, blk_q, blk_k, causal=causal, window=window,
+                delta=delta)
+            assert [lo <= ki <= hi for ki in range(n)] == [
+                bool(masks.prefill_block_live(
+                    outer, ki, blk_q, blk_k, causal=causal, window=window,
+                    delta=delta)) for ki in range(n)], (outer, delta)
+            lo, hi = masks.prefill_live_q_tiles(
+                outer, blk_q, blk_k, causal=causal, window=window,
+                delta=delta)
+            assert [lo <= qi <= hi for qi in range(n)] == [
+                bool(masks.prefill_block_live(
+                    qi, outer, blk_q, blk_k, causal=causal, window=window,
+                    delta=delta)) for qi in range(n)], (outer, delta)
+
+
 @pytest.mark.parametrize("window", [None, 1, 4, 16])
 @pytest.mark.parametrize("sq", [1, 4])
 def test_decode_block_live_matches_dense(sq, window):
@@ -265,7 +293,7 @@ def test_dispatch_kernel_error_propagates(monkeypatch):
     that was chosen never turns into a warning and a quiet O(S^2) run —
     in a server log that warning was lost."""
     monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
-    q, k, v = _qkv(s=300, hq=2, hkv=1, d=16)
+    q, k, v = _qkv(s=1100, hq=2, hkv=1, d=16)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         with pytest.raises(ValueError, match="flash kernel needs"):
