@@ -43,7 +43,6 @@ class Run:
     requests: List[dict] = dataclasses.field(default_factory=list)
     engine_requests: List[dict] = dataclasses.field(default_factory=list)
     trace: Optional[Dict[str, Any]] = None  # trace/reduce.py, traced run
-    step_memory_bytes: Optional[Dict[str, int]] = None  # traced training
     peaks: Optional[Dict[str, Any]] = None  # None only in a rehearsal
     extras: Dict[str, Any] = dataclasses.field(default_factory=dict)
 

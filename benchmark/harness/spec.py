@@ -1,9 +1,10 @@
 """Find a cell's files by the names in BENCHMARK.json.
 
 A cell names a configuration and a traffic mix; per-layer metrics name a
-reader. All three are files that the harness finds by name, so a later PR
-adds a cell, a configuration or a metric as new files plus new entries
-and edits nothing that exists:
+reader; a reader that wants a kernel's roofline names the kernel. All are
+files that the harness finds by name under the directories of `paths`, so
+a later PR adds a cell, a configuration, a metric or a kernel as new files
+plus new entries and edits nothing that exists:
 
     configuration  `file` of its entry in `configs`
     architecture   <path>/reference/<name>.py, where <name> is the
@@ -16,6 +17,37 @@ and edits nothing that exists:
                    metric's name up to its first "." (so `device_idle_pct.
                    train` and `device_idle_pct.serve` share one reader and
                    move different end-to-end metrics)
+    kernel's cost  <path>/kernel_costs/<kernel>.py, where <kernel> is the
+                   `name=` of the program's `pallas_call`: what one call
+                   needs to do, for its share of the roofline
+
+What a PR that adds a configuration brings, all of it new:
+
+    1. <path>/configs/<name>.json: the source's config.json with the cut
+       keys changed, plus "source", "reference", "reduced" {key: why},
+       "assumed" {what the source does not state: the value taken},
+       "program" {"flags": [...]};
+    2. <path>/reference/<reference>.py, unless an existing one holds the
+       block type: `program_flags(config, seq_length)`,
+       `from_program_params(params)`, `lm_loss(weights, tokens, labels,
+       mask, config)`, for a served cell `next_token_logprobs`, and
+       optionally `train_flops_per_token`;
+    3. tests/benchmark/published/<name>.json: {"source": the URL,
+       "config": the source's own value of every key the configuration
+       file takes from it}, which the contract test holds the
+       configuration to outside `reduced`;
+    4. <path>/traffic/<mix>.json for each new mix ("driver": "train",
+       "serve_open" or "serve_closed", and that driver's parameters);
+    5. in BENCHMARK.json: the `configs` entry, a `workloads` entry a
+       cell, and the cell's name appended to the `workloads` list of
+       every metric it reports.
+
+A reader of a scope the new block adds is a file of its own,
+`def read(run): return named.scope_ms(run, "router")` (harness/trace/
+named.py: any `jax.named_scope`, any `pallas_call(name=)`), with its
+`per_layer` entry; a kernel's roofline share wants the kernel's cost file
+beside it. tests/benchmark/test_benchmark_contract.py rehearses exactly
+such a PR on a copy of the tree.
 """
 
 from __future__ import annotations
@@ -109,6 +141,12 @@ class Cell:
             raise SpecError(f"no reader layer_metrics/{stem}.py for "
                             f"per-layer metric {metric_name!r}")
         return load_module(path).read
+
+    def kernel_cost(self, kernel: str) -> Optional[Callable]:
+        """`needed(dims, itemsize, config)` of kernel_costs/<kernel>.py,
+        or None where no directory of `paths` holds that file."""
+        path = self._search("kernel_costs", kernel + ".py")
+        return None if path is None else load_module(path).needed
 
     def reference_path(self) -> str:
         """The file that holds this configuration's architecture. The

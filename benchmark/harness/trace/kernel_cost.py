@@ -1,14 +1,23 @@
-"""What the flash-attention kernels NEED to do, as functions of their
-shapes: the yardstick a kernel's measured time is held against for its
-share of the roofline. Nothing here comes from the program: the shapes are
-read from the HLO text of the kernel's event in the device trace, the
-window from the cell's configuration, the peaks from benchmark/peaks.json
-(never the `flops` or `bytes_accessed` a trace event carries: those are the
-compiler's count of what the program does, recomputation included).
+"""A kernel's share of its roofline: the least time the chip could take
+for what the kernel NEEDS to do over the time the trace shows for it.
+Nothing here comes from the program: the shapes are read from the HLO
+text of the kernel's event in the device trace, the peaks from
+benchmark/peaks.json, and the needed work from a file of the kernel's own,
 
-Causal attention over S positions with a window W scores, for each query
-i, the keys j <= i with i - j < W: S(S+1)/2 pairs, less the area the
-window clips. One matmul over those pairs is 2*B*H*D FLOP a pair. The
+    <path>/kernel_costs/<kernel>.py:  needed(dims, itemsize, config)
+                                      -> (FLOP, bytes) of one call, or
+                                      None for a shape it does not know
+
+found by the name the program gives the kernel (`pallas_call(name=)`) like
+a reader is found by its metric's; `config` is the cell's whole
+configuration. Never the `flops` or `bytes_accessed` a trace event
+carries: those are the compiler's count of what the program does,
+recomputation included. A kernel without such a file has no roofline.
+
+`causal_attention` is the arithmetic the flash-attention kernels' files
+share. Causal attention over S positions with a window W scores, for each
+query i, the keys j <= i with i - j < W: S(S+1)/2 pairs, less the area
+the window clips. One matmul over those pairs is 2*B*H*D FLOP a pair. The
 forward needs two matmuls (QK^T, PV); the backward five (QK^T again, since
 the probabilities are not kept, dO V^T, P^T dO, dS^T Q, dS K): 2.5 times
 the forward. The split backward RUNS seven (`flash_bwd_dq` and
@@ -23,12 +32,12 @@ dv once by the backward (6 booked on dq, 2 on dkv).
 from __future__ import annotations
 
 import re
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s8": 1, "f8e4m3fn": 1,
             "f8e5m2": 1}
-NEEDED_MATMULS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 2}
-NEEDED_TENSORS = {"flash_fwd": 4, "flash_bwd_dq": 6, "flash_bwd_dkv": 2}
+Needed = Callable[[Sequence[int], int, dict],
+                  Optional[Tuple[float, float]]]
 
 _RESULT = re.compile(r"=\s*\(?([a-z0-9]+)\[([0-9,]+)\]")
 
@@ -36,8 +45,7 @@ _RESULT = re.compile(r"=\s*\(?([a-z0-9]+)\[([0-9,]+)\]")
 def result_shape(hlo_text: str) -> Optional[Tuple[str, Tuple[int, ...]]]:
     """(dtype, dims) of an instruction's (first) result, from its text:
     "%flash_fwd.1 = (bf16[1,32,4096,128]{...}, f32[...]) custom-call(..."
-    -> ("bf16", (1, 32, 4096, 128)). For all three kernels that is a
-    tensor of the [B, H, S, D] shape the attention runs over."""
+    -> ("bf16", (1, 32, 4096, 128))."""
     m = _RESULT.search(hlo_text)
     if not m:
         return None
@@ -52,35 +60,47 @@ def causal_pairs(s: int, window: Optional[int]) -> int:
     return window * (window + 1) // 2 + (s - window) * window
 
 
-def needed(kernel: str, dims: Sequence[int], itemsize: int,
-           window: Optional[int]) -> Tuple[float, float]:
-    """(FLOP, bytes) one call of `kernel` over [B, H, S, D] needs."""
+def causal_attention(dims: Sequence[int], itemsize: int,
+                     window: Optional[int], matmuls: int,
+                     tensors: int) -> Optional[Tuple[float, float]]:
+    """(FLOP, bytes) of `matmuls` matmuls over the causal windowed pairs
+    of [B, H, S, D] and `tensors` tensors of that shape moved once; None
+    for a result of another rank."""
+    if len(dims) != 4:
+        return None
     b, h, s, d = dims
-    flops = NEEDED_MATMULS[kernel] * 2.0 * b * h * d * causal_pairs(s, window)
-    return flops, float(NEEDED_TENSORS[kernel] * b * h * s * d * itemsize)
+    flops = matmuls * 2.0 * b * h * d * causal_pairs(s, window)
+    return flops, float(tensors * b * h * s * d * itemsize)
 
 
 def roofline(kernels: Dict[str, dict], names: Sequence[str],
-             window: Optional[int], peaks: dict) -> Optional[dict]:
+             needed_of: Callable[[str], Optional[Needed]], config: dict,
+             peaks: dict) -> Optional[dict]:
     """The share of the roofline the calls of `names` reached in one run
     of the step: the least time the chip could take for what they need
     (the larger of FLOP over peak FLOP/s and bytes over peak bytes/s)
-    over the time they took. `kernels` is named.per_run()["kernels"].
-    None where none of them ran or a call's shape cannot be read."""
+    over the time they took. `kernels` is named.per_run()["kernels"],
+    `needed_of(name)` the `needed` of that kernel's cost file (spec.Cell.
+    kernel_cost). None where none of them ran, one that ran has no cost
+    file, or a call's shape cannot be read or is not one its file knows."""
     flops = nbytes = seconds = 0.0
     for name in names:
         k = kernels.get(name)
         if k is None:
             continue
+        needed = needed_of(name)
+        if needed is None:
+            return None
         seconds += k["s"]
         for text, calls in k["calls"].items():
             shape = result_shape(text)
-            if (shape is None or len(shape[1]) != 4
-                    or shape[0] not in ITEMSIZE):
+            if shape is None or shape[0] not in ITEMSIZE:
                 return None
-            f, n = needed(name, shape[1], ITEMSIZE[shape[0]], window)
-            flops += calls * f
-            nbytes += calls * n
+            work = needed(shape[1], ITEMSIZE[shape[0]], config)
+            if work is None:
+                return None
+            flops += calls * work[0]
+            nbytes += calls * work[1]
     if not seconds:
         return None
     compute_s = flops / peaks["bf16_flops_per_s"]

@@ -1,27 +1,27 @@
-"""A traced training run read by the names the program gives its work.
+"""A traced training run read by the names the program gives its work
+(names.py says how they arrive and which partition the step).
 
-The program names its regions with `jax.named_scope` (`embed`, `attention`,
-`mlp`, `head_loss`, `optimizer`) and its Pallas kernels by `name=` and a
-scope of the same string (`flash_fwd`, `flash_bwd_dq`, ...). On a TPU the
-names arrive in each operation's event METADATA, as the stat `tf_op`: the
-jaxpr name stack, "jit(train_step)/while/body/closed_call/transpose(jvp())/
-.../attention/flash_bwd_dq/pallas_call:". The loop's host phases arrive on
-the `/host:CPU` plane as `jax.profiler` annotations: one `train-pass` span
-a pass of the train loop, the loop's timers nested inside it.
-
-    per_run(path)          own device time of each region, of recomputation
+    per_run(path)          own device time of each region, of every scope
                            and of each kernel, inside the runs of the step
                            program that the trace holds whole, per run, mean
                            of devices
     read(path)["passes"]   each whole `train-pass` with the time its
                            `metrics-fetch` and `batch-generator` spans took
     journal(file)          the records of a run's tele/events.jsonl
-    of_run, region_ms, kernel_ms, roofline_pct: what the readers of
-    benchmark/layer_metrics/ call, given the harness's `run`
+    of_run, region_ms, scope_ms, kernel_ms, roofline_pct, step_program:
+    what the readers of <path>/layer_metrics/ call, given the harness's
+    `run`. A reader of a scope or a kernel a later PR adds is a new file
+    of a few lines: `named.scope_ms(run, "router")`,
+    `named.kernel_ms(run, "grouped_matmul")`, and for a roofline share
+    `named.roofline_pct(run, "label", "grouped_matmul")` with the kernel's
+    needed work in <path>/kernel_costs/grouped_matmul.py.
 
 Every operation's own time goes to exactly one region, so the regions sum
-to the busy time of a whole run. A program without the names (a parent
-commit) gives None, never a partition with everything under `other`.
+to the busy time of a whole run; a scope's time is that of the operations
+whose name stack holds it at any depth, so scopes overlap (`mlp` holds
+`router`, `rematted_computation` cuts across regions). A program without
+the region names (a parent commit) gives None, never a partition with
+everything under `other`.
 
 Readers import this module by name, so the decode of one trace, a few
 seconds of pure-Python protobuf walking, is shared by all of them.
@@ -33,25 +33,15 @@ import bisect
 import functools
 import json
 import os
-import re
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from benchmark.harness.spec import REPO
-from benchmark.harness.trace import kernel_cost, proto, reduce, xplane
-
-REGIONS = ("optimizer", "head_loss", "attention", "mlp", "embed")
-OTHER = "other"
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
-           "paged_flash_decode")
-RECOMPUTED = "rematted_computation"
-PASS = "train-pass"
-FETCH, DATA = "metrics-fetch", "batch-generator"
-HOST_PLANE = "/host:CPU"
-
-_EVENT_MD_NAME, _EVENT_MD_STATS = 2, 5          # XEventMetadata
-_PLANE_EVENT_MD, _PLANE_STAT_MD = 4, 5          # XPlane
-_WRAPPED = re.compile(r"^[A-Za-z_]\w*\((.*)\)$")  # jvp(x), transpose(jvp(x))
+from benchmark.harness.trace import kernel_cost, reduce, xplane
+from benchmark.harness.trace.names import (
+    DATA, FETCH, HOST_PLANE, OTHER, PASS, REGIONS, kernel_of,
+    name_stacks, region_of, tokens,
+)
 
 
 def run_files(run) -> Tuple[str, str]:
@@ -59,67 +49,6 @@ def run_files(run) -> Tuple[str, str]:
     run_dir = os.path.join(REPO, "runs", "benchmark", run.cell.name)
     return (os.path.join(run_dir, "trace"),
             os.path.join(run_dir, "tele", "events.jsonl"))
-
-
-def tokens(tf_op: str) -> List[str]:
-    """The name stack's parts, outermost first, with the wrappers that
-    differentiation and transposition put around a scope's name taken
-    off: "a/transpose(jvp(attention))/mul:" -> ["a", "attention", "mul"]."""
-    out = []
-    for part in tf_op.rstrip(":").split("/"):
-        while True:
-            m = _WRAPPED.match(part)
-            if not m:
-                break
-            part = m.group(1)
-        out.append(part)
-    return out
-
-
-def region_of(parts: List[str]) -> str:
-    """The innermost region scope the operation sits under, else `other`."""
-    for part in reversed(parts):
-        if part in REGIONS:
-            return part
-    return OTHER
-
-
-def kernel_of(parts: List[str]) -> Optional[str]:
-    for part in reversed(parts):
-        if part in KERNELS:
-            return part
-    return None
-
-
-def metadata_tf_ops(plane_buf: bytes) -> Dict[str, str]:
-    """Event name -> `tf_op`, from the plane's event metadata (the stats
-    an event's NAME carries, which harness/trace/xplane.py leaves out: it
-    decodes only the stats of each event)."""
-    stat_names: Dict[int, str] = {}
-    entries: List[bytes] = []
-    for fn, wt, v in proto.fields(plane_buf):
-        if wt != proto.WIRE_LEN:
-            continue
-        if fn == _PLANE_STAT_MD:
-            key, md = xplane._map_entry(v)
-            stat_names[key] = xplane._metadata_name(md)
-        elif fn == _PLANE_EVENT_MD:
-            entries.append(xplane._map_entry(v)[1])
-    out: Dict[str, str] = {}
-    for md in entries:
-        name, tf_op = "", None
-        for fn, wt, v in proto.fields(md):
-            if wt != proto.WIRE_LEN:
-                continue
-            if fn == _EVENT_MD_NAME:
-                name = proto.to_text(v)
-            elif fn == _EVENT_MD_STATS:
-                key, value = xplane._decode_stat(v, stat_names)
-                if key == "tf_op" and isinstance(value, str):
-                    tf_op = value
-        if tf_op is not None:
-            out[name] = tf_op
-    return out
 
 
 def _inside(segs: List[reduce.Interval], runs: List[reduce.Interval],
@@ -135,13 +64,14 @@ def _inside(segs: List[reduce.Interval], runs: List[reduce.Interval],
 
 def reduce_device(plane: xplane.Plane, tf_ops: Dict[str, str]
                   ) -> Dict[str, Any]:
-    """One device: picoseconds inside its whole runs by region, in
-    recomputation, and by kernel (with each kernel call's HLO text)."""
+    """One device: picoseconds inside its whole runs by region, by scope
+    (every part of every name stack) and by kernel (with each kernel
+    call's HLO text)."""
     whole = reduce.whole_runs(reduce._line(plane, reduce.MODULE_LINE))
     runs = reduce.merge((m.start_ps, m.end_ps) for m in whole)
     starts = [s for s, _ in runs]
     regions = {name: 0 for name in REGIONS + (OTHER,)}
-    recomputed = 0
+    scopes: Dict[str, int] = {}
     kernels: Dict[str, Dict[str, Any]] = {}
     named = False
     for ev, segs in reduce.self_segments(reduce._line(plane,
@@ -153,15 +83,15 @@ def reduce_device(plane: xplane.Plane, tf_ops: Dict[str, str]
         region = region_of(parts)
         named = named or region != OTHER
         regions[region] += own
-        if RECOMPUTED in parts:
-            recomputed += own
+        for part in set(parts):
+            scopes[part] = scopes.get(part, 0) + own
         kernel = kernel_of(parts) if reduce.is_kernel(ev) else None
         if kernel is not None:
             k = kernels.setdefault(kernel, {"ps": 0, "calls": {}})
             k["ps"] += own
             k["calls"][ev.name] = k["calls"].get(ev.name, 0) + 1
     return {"runs": len(whole), "named": named, "regions": regions,
-            "recomputed_ps": recomputed, "kernels": kernels}
+            "scopes": scopes, "kernels": kernels}
 
 
 def host_passes(plane: xplane.Plane) -> List[Dict[str, Any]]:
@@ -191,16 +121,12 @@ def _read(path: str, stamp: float) -> Dict[str, Any]:
     devices: Dict[str, Dict[str, Any]] = {}
     passes: List[Dict[str, Any]] = []
     want = lambda n: n in (reduce.OP_LINE, reduce.MODULE_LINE)  # noqa: E731
-    for f in xplane.find_xplane_files(path):
-        with open(f, "rb") as fh:
-            data = fh.read()
-        for buf in xplane.raw_planes(data):
-            name = xplane.plane_name(buf)
-            if reduce._DEVICE_PLANE.match(name):
-                devices[name] = reduce_device(
-                    xplane.decode_plane(buf, want), metadata_tf_ops(buf))
-            elif name == HOST_PLANE:
-                passes += host_passes(xplane.decode_plane(buf))
+    for name, buf in xplane.capture_planes(path):
+        if reduce._DEVICE_PLANE.match(name):
+            devices[name] = reduce_device(
+                xplane.decode_plane(buf, want), name_stacks(buf))
+        elif name == HOST_PLANE:
+            passes += host_passes(xplane.decode_plane(buf))
     return {"devices": devices, "passes": passes,
             "decode_s": time.monotonic() - t0}
 
@@ -214,10 +140,10 @@ def read(path: str) -> Dict[str, Any]:
 
 def per_run(path: str) -> Optional[Dict[str, Any]]:
     """Seconds a whole run of the step program, mean over the devices
-    that hold one: `regions` (they sum to the run's busy time),
-    `recomputed`, and per kernel its seconds and its calls a run
-    ({HLO text: calls}). None where no device holds a whole run, or the
-    program carries none of the region names."""
+    that hold one: `regions` (they sum to the run's busy time), `scopes`
+    (every part of a name stack, by name), and per kernel its seconds and
+    its calls a run ({HLO text: calls}). None where no device holds a
+    whole run, or the program carries none of the region names."""
     devices = [d for d in read(path)["devices"].values() if d["runs"]]
     if not devices or not any(d["named"] for d in devices):
         return None
@@ -237,7 +163,8 @@ def per_run(path: str) -> Optional[Dict[str, Any]]:
     return {"devices": n, "runs": min(d["runs"] for d in devices),
             "regions": {r: mean(lambda d: d["regions"][r])
                         for r in REGIONS + (OTHER,)},
-            "recomputed": mean(lambda d: d["recomputed_ps"]),
+            "scopes": {name: mean(lambda d: d["scopes"].get(name, 0))
+                       for name in {s for d in devices for s in d["scopes"]}},
             "kernels": kernels}
 
 
@@ -257,9 +184,18 @@ def region_ms(run, *regions: str) -> Optional[float]:
                                               for r in regions)
 
 
+def scope_ms(run, name: str) -> Optional[float]:
+    """Milliseconds a step of the operations whose name stack holds
+    `name` at any depth (a `jax.named_scope` of the program, or one of
+    JAX's own parts); 0.0 where the program is named and no operation
+    sits under it."""
+    got = of_run(run)
+    return None if got is None else 1e3 * got["scopes"].get(name, 0.0)
+
+
 def kernel_ms(run, *kernels: str) -> Optional[float]:
-    """Milliseconds a step in the named kernels' custom calls; None where
-    none of them ran by that name."""
+    """Milliseconds a step in the custom calls of the Pallas kernels of
+    those names (`pallas_call(name=)`); None where none of them ran."""
     got = of_run(run)
     if got is None or not any(k in got["kernels"] for k in kernels):
         return None
@@ -269,16 +205,16 @@ def kernel_ms(run, *kernels: str) -> Optional[float]:
 
 def roofline_pct(run, label: str, *kernels: str) -> Optional[float]:
     """The named kernels' share of their roofline (kernel_cost.roofline:
-    needed work from the calls' own shapes, the window from the cell's
-    configuration, the run's peaks); what it was computed from, with the
-    bound that applies, goes to the line's `extras.roofline[label]`. None
-    in a rehearsal (no peaks)."""
+    needed work by each kernel's own file of <path>/kernel_costs/, from
+    the calls' shapes and the cell's configuration, over the run's
+    peaks); what it was computed from, with the bound that applies, goes
+    to the line's `extras.roofline[label]`. None in a rehearsal (no
+    peaks)."""
     got = of_run(run) if run.peaks is not None else None
     if got is None:
         return None
-    roof = kernel_cost.roofline(got["kernels"], kernels,
-                                run.cell.config.get("sliding_window"),
-                                run.peaks)
+    roof = kernel_cost.roofline(got["kernels"], kernels, run.cell.kernel_cost,
+                                run.cell.config, run.peaks)
     if roof is None:
         return None
     run.extras.setdefault("roofline", {})[label] = roof
@@ -293,3 +229,16 @@ def journal(path: str) -> List[dict]:
             return [json.loads(line) for line in f if line.strip()]
     except FileNotFoundError:
         return []
+
+
+def step_program(run) -> Optional[dict]:
+    """The last `step_program` record of a run's journal: what the
+    compiler says the traced step program needs on one chip
+    (`argument_bytes`, `temp_bytes`, `output_bytes`, `alias_bytes`; the
+    trainer writes it after a run that opened a trace window). None for
+    a run that journalled no step, before the disk is touched."""
+    if not run.steps:
+        return None
+    programs = [r for r in journal(run_files(run)[1])
+                if r.get("kind") == "step_program"]
+    return programs[-1] if programs else None

@@ -1,24 +1,27 @@
-"""From a device trace to numbers: busy time, kernel time, exposed
-collective time, the operations that took most time and the longest idle
-gaps. Picoseconds inside, seconds out.
+"""From a device trace to numbers: busy time, exposed collective time,
+the operations that took most time and the longest idle gaps.
+Picoseconds inside, seconds out.
 
 A device is a plane named "/device:TPU:<n>"; its operations are the
 events of its "XLA Ops" line (whole-program envelopes on "XLA Modules"
 and "Steps" are not operations: counted as such they would make every
 device busy all the time). Events nest (a `while` holds its body), so an
 operation's own time is its duration minus the operations inside it.
+Operations and idle gaps are labelled by the program's own names
+(names.py): the region and kernel of the operation's name stack, the
+host span that was open as the gap began.
 """
 
 from __future__ import annotations
 
 import bisect
 import re
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
-from benchmark.harness.trace import xplane
+from benchmark.harness.trace import names, xplane
 
 PS = 1e-12
-KERNEL_TARGET = "tpu_custom_call"
+KERNEL_TARGET = names.KERNEL_TARGET
 OP_LINE = "XLA Ops"
 MODULE_LINE = "XLA Modules"
 _DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -91,30 +94,13 @@ def self_segments(events: List[xplane.Event]
     return out
 
 
-def short_name(name: str) -> str:
-    """On a TPU an operation's event is named by its whole HLO text
-    ("%fusion.4 = bf16[8,128]{...} fusion(...), kind=..."): keep the
-    instruction's name, its result's type and shape, and its opcode."""
-    lhs, sep, rhs = name.partition(" = ")
-    if not sep:
-        return name[:120]
-    shape = re.match(r"\(?([a-z0-9]+\[[0-9,]*\])", rhs)
-    opcode = re.search(r"[}\])]\s([a-z][a-z0-9\-]*)\(", rhs)
-    parts = [lhs.lstrip("%"), shape.group(1) if shape else "",
-             opcode.group(1) if opcode else "",
-             "tpu_custom_call" if KERNEL_TARGET in rhs else ""]
-    return " ".join(p for p in parts if p)[:120]
-
-
 def is_collective(name: str) -> bool:
     return bool(_COLLECTIVE.match(name.lstrip("%")))
 
 
 def is_kernel(ev: xplane.Event) -> bool:
     """A Pallas (Mosaic) kernel: a custom call whose target is
-    tpu_custom_call. The five flash kernels have no names of their own
-    yet, so they are one number (kernel_ms_per_step) until the tracing
-    issue names them."""
+    tpu_custom_call."""
     return KERNEL_TARGET in ev.name
 
 
@@ -148,45 +134,50 @@ def _line(plane: xplane.Plane, name: str) -> List[xplane.Event]:
             for ev in ln.events if ev.duration_ps > 0]
 
 
-def reduce_device(plane: xplane.Plane) -> Dict[str, Any]:
+def reduce_device(plane: xplane.Plane,
+                  tf_ops: Optional[Dict[str, str]] = None,
+                  spans: Sequence[xplane.Event] = ()) -> Dict[str, Any]:
+    """One device. `tf_ops` is names.name_stacks() of its plane, `spans`
+    names.gap_spans() of the host plane; without them every operation is
+    under `other` and every gap under the program that ends it."""
+    tf_ops = tf_ops or {}
     ops = self_segments(_line(plane, OP_LINE))
     busy = merge((ev.start_ps, ev.end_ps) for ev, _ in ops)
     by_name: Dict[str, int] = {}
-    kernel = collective = 0
+    collective = 0
     compute_segs: List[Interval] = []
     collective_segs: List[Interval] = []
-    kernel_segs: List[Interval] = []
     for ev, segs in ops:
         own = total(segs)
-        key = short_name(ev.name)
+        key = names.op_label(ev.name, tf_ops.get(ev.name, ""))
         by_name[key] = by_name.get(key, 0) + own
         if is_collective(ev.name):
             collective += own
             collective_segs += segs
         else:
             compute_segs += segs
-            if is_kernel(ev):
-                kernel += own
-                kernel_segs += segs
     exposed = total(subtract(merge(collective_segs), merge(compute_segs)))
-    # an idle gap is named by the program that ends it: the host was
-    # getting that program's launch ready (the tracing issue puts host
-    # spans on this clock; until then this is all the trace knows)
     modules = sorted(_line(plane, MODULE_LINE), key=lambda ev: ev.end_ps)
     ends = [m.end_ps for m in modules]
-    runs = whole_runs(modules)
-    kernels = merge(kernel_segs)
-    outside = subtract(kernels, merge((m.start_ps, m.end_ps) for m in runs))
-    gaps: List[Tuple[str, int]] = []
-    for (_, e0), (s1, _) in zip(busy, busy[1:]):
-        at = bisect.bisect_right(ends, s1)  # first program ending after s1
-        name = ("before " + _PROGRAM_ID.sub("", modules[at].name)
+    starts = [ev.start_ps for ev in spans]
+
+    def gap_name(opens_ps: int, closes_ps: int) -> str:
+        """What the host was doing as the device fell idle: the innermost
+        span (they are sorted by start, outer first) that covers the
+        moment the gap opens; where none does, the program whose run ends
+        the gap (the host was getting its launch ready)."""
+        for i in range(bisect.bisect_right(starts, opens_ps) - 1, -1, -1):
+            if spans[i].end_ps > opens_ps:
+                return spans[i].name
+        at = bisect.bisect_right(ends, closes_ps)
+        return ("before " + _PROGRAM_ID.sub("", modules[at].name)
                 if at < len(modules) else "unattributed")
-        gaps.append((name, s1 - e0))
-    return {"busy": busy, "busy_ps": total(busy), "kernel_ps": kernel,
+
+    gaps = [(gap_name(e0, s1), s1 - e0)
+            for (_, e0), (s1, _) in zip(busy, busy[1:])]
+    return {"busy": busy, "busy_ps": total(busy),
             "collective_ps": collective, "collective_exposed_ps": exposed,
-            "runs": len(runs),
-            "kernel_in_runs_ps": total(kernels) - total(outside),
+            "runs": len(whole_runs(modules)),
             "by_name": by_name, "gaps": gaps,
             "span": (busy[0][0], busy[-1][1]) if busy else None}
 
@@ -194,12 +185,26 @@ def reduce_device(plane: xplane.Plane) -> Dict[str, Any]:
 def reduce_trace(path: str, top: int = 10) -> Optional[Dict[str, Any]]:
     """The trace under `path` as the numbers the per-layer readers take,
     or None when no device plane holds an operation."""
-    devices = {k: reduce_device(p) for k, p in device_planes(path).items()}
+    planes: Dict[int, bytes] = {}
+    spans: List[xplane.Event] = []
+    lines = lambda n: n in (OP_LINE, MODULE_LINE)  # noqa: E731
+    for name, buf in xplane.capture_planes(path):
+        if _DEVICE_PLANE.match(name):
+            planes[int(_DEVICE_PLANE.match(name).group(1))] = buf
+        elif name == names.HOST_PLANE:
+            spans = names.gap_spans(xplane.decode_plane(buf))
+    return summarize({k: reduce_device(xplane.decode_plane(buf, lines),
+                                       names.name_stacks(buf), spans)
+                      for k, buf in planes.items()}, top)
+
+
+def summarize(devices: Dict[int, Dict[str, Any]], top: int = 10
+              ) -> Optional[Dict[str, Any]]:
+    """reduce_device() of each device as one record: seconds, means over
+    the devices that held an operation; None where none did."""
     devices = {k: d for k, d in devices.items() if d["span"]}
     if not devices:
         return None
-    start = min(d["span"][0] for d in devices.values())
-    end = max(d["span"][1] for d in devices.values())
     n = len(devices)
     by_name: Dict[str, int] = {}
     gaps: Dict[str, int] = {}
@@ -208,21 +213,20 @@ def reduce_trace(path: str, top: int = 10) -> Optional[Dict[str, Any]]:
             by_name[name] = by_name.get(name, 0) + ps
     worst = max(devices.values(),
                 key=lambda d: d["collective_exposed_ps"])
-    # the first device's gaps, summed by the program that ended them
+    # the first device's gaps, summed by what the host was doing
     first = devices[min(devices)]
     for name, ps in first["gaps"]:
         gaps[name] = gaps.get(name, 0) + ps
     rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]  # noqa: E731
-    per_run = [d["kernel_in_runs_ps"] / d["runs"] for d in devices.values()
-               if d["runs"]]
     return {
         "devices": n,
-        "window_s": (end - start) * PS,
+        # each device's own window, first operation to last: devices start
+        # and stop a program some microseconds apart, and a window over
+        # all of them would book that skew as idle time
+        "window_s": sum(d["span"][1] - d["span"][0]
+                        for d in devices.values()) / n * PS,
         "busy_s": sum(d["busy_ps"] for d in devices.values()) / n * PS,
-        "kernel_s": sum(d["kernel_ps"] for d in devices.values()) / n * PS,
         "runs": min(d["runs"] for d in devices.values()),
-        "kernel_s_per_run": (sum(per_run) / len(per_run) * PS
-                             if per_run else None),
         "collective_exposed_worst_s": worst["collective_exposed_ps"] * PS,
         "device_ops": [[name, ps / n * PS] for name, ps in rank(by_name)],
         "idle_gaps": [[name, ps * PS] for name, ps in rank(gaps)],

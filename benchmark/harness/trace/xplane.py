@@ -173,6 +173,16 @@ def raw_planes(data: bytes) -> List[bytes]:
             if fn == _SPACE_PLANES and wt == proto.WIRE_LEN]
 
 
+def capture_planes(path: str):
+    """(name, undecoded plane) of every plane of the newest capture under
+    `path`: the caller decodes those it wants, as far as it wants."""
+    for f in find_xplane_files(path):
+        with open(f, "rb") as fh:
+            data = fh.read()
+        for buf in raw_planes(data):
+            yield plane_name(buf), buf
+
+
 def load_planes(path: str,
                 want_plane: Callable[[str], bool] = lambda _n: True,
                 want_line: Callable[[str], bool] = lambda _n: True

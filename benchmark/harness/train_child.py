@@ -11,11 +11,11 @@ the entry point, the step journal (`EventJournal.emit("step", ...)`: the
 benchmark stamps its own clock as each record arrives, which is when the
 step's metrics have been fetched from the device) and
 `TrainLoop.train`, wrapped once to copy the initial weights and the first
-batch for the reference check. The traced run also wraps
-`TrainLoop._train_step_for`, to lower the jitted step as it is first
-called: after the window it asks the compiler what that step needs on a
-chip. The trainer is stopped by its own exit: a SIGTERM at the end of the
-window, which its signal handler turns into drain-and-return.
+batch for the reference check; nothing else of the program is wrapped
+(what the compiled step needs on a chip is the journal's own
+`step_program` record). The trainer is stopped by its own exit: a SIGTERM
+at the end of the window, which its signal handler turns into
+drain-and-return.
 """
 
 from __future__ import annotations
@@ -119,41 +119,6 @@ def snapshot_at_train_start(kept: dict, marks: dict) -> None:
     pretrain_mod.TrainLoop.train = train_with_snapshot
 
 
-def lower_step_at_first_call(kept: dict) -> None:
-    """Wrap TrainLoop._train_step_for (traced run only): the first call
-    of the jitted step also lowers it, there and then, under whatever
-    mesh the trainer has set, and keeps the lowering."""
-    from megatron_tpu.training import pretrain as pretrain_mod
-
-    step_for = pretrain_mod.TrainLoop._train_step_for
-
-    def step_for_and_lower(loop, num_microbatches):
-        step = step_for(loop, num_microbatches)
-        if "lowered" in kept:
-            return step
-
-        def first_call(state, batch):
-            kept["lowered"] = step.lower(state, batch)
-            return step(state, batch)
-
-        return first_call
-
-    pretrain_mod.TrainLoop._train_step_for = step_for_and_lower
-
-
-def step_memory_bytes(lowered) -> dict:
-    """What the compiler says the train step needs on one chip: its
-    arguments (the state and the batch), its temporaries, and its
-    outputs where they do not reuse an argument's room. Unlike
-    `peak_bytes_in_use` this counts the temporaries. The same program as
-    the one that ran, so the compile is a hit in the cache."""
-    ma = lowered.compile().memory_analysis()
-    return {"arguments": int(ma.argument_size_in_bytes),
-            "temporaries": int(ma.temp_size_in_bytes),
-            "outputs_not_aliased": int(ma.output_size_in_bytes
-                                       - ma.alias_size_in_bytes)}
-
-
 def reference_first_loss(reference, kept: dict, config: dict) -> float:
     """The configuration's plain float32 reference's loss on the first
     global batch under the initial weights."""
@@ -192,8 +157,6 @@ def main(plan_path: str) -> int:
     clock.install()
     kept: dict = {}
     snapshot_at_train_start(kept, marks)
-    if plan["trace"]:
-        lower_step_at_first_call(kept)
 
     import pretrain_gpt
 
@@ -203,8 +166,6 @@ def main(plan_path: str) -> int:
         "device": found, "memory_peak_bytes": devices.memory_peak_bytes(),
         "marks": marks, "corpus_tokens": corpus_tokens,
         "steps": clock.steps, "window_start": clock.window_start}
-    if "lowered" in kept:
-        result["step_memory_bytes"] = step_memory_bytes(kept.pop("lowered"))
     flops = getattr(reference, "train_flops_per_token", None)
     if flops is not None:
         result["train_flops_per_token"] = flops(config, plan["seq_length"])

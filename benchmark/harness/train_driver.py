@@ -119,7 +119,6 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         end_to_end={"train_tokens_per_s":
                     lambda: tokens_per_s(inside, mono0)},
         attempted=len(inside), failed=0, problems=problems, steps=inside,
-        step_memory_bytes=result.get("step_memory_bytes"),
         compiles_in_window=compiles_inside(
             compiles, inside, (wall0, wall0 + seconds)))
     marks = result["marks"]

@@ -1,8 +1,6 @@
 """Device time a step of the two backward Pallas kernels, `flash_bwd_dq`
 and `flash_bwd_dkv` (ops/pallas/flash_template.py), found by name: inside
-the whole runs of the step program, over those runs, mean over devices.
-With flash_fwd_ms_per_step it sums to kernel_ms_per_step, which counts
-every custom call whatever its name."""
+the whole runs of the step program, over those runs, mean over devices."""
 
 from benchmark.harness.trace import named
 

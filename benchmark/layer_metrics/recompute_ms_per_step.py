@@ -9,5 +9,4 @@ from benchmark.harness.trace import named
 
 
 def read(run):
-    got = named.of_run(run)
-    return None if got is None else 1e3 * got["recomputed"]
+    return named.scope_ms(run, "rematted_computation")

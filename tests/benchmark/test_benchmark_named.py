@@ -1,9 +1,11 @@
 """The readers that find the program's own names in a traced run
-(benchmark/harness/trace/named.py, kernel_cost.py and the twelve readers
-of benchmark/layer_metrics/ that use them): on made-up events, on the
-named TPU recordings of benchmark/fixtures/ (cut from PR 23's first traced
-chip run of each cell by benchmark/tools/cut_named_trace.py), and end to
-end in a CPU rehearsal of a toy cell with a spec of its own."""
+(benchmark/harness/trace/names.py, named.py, kernel_cost.py, the files of
+benchmark/kernel_costs/ and the twelve readers of benchmark/layer_metrics/
+that use them): on made-up events, on the named TPU recordings of
+benchmark/fixtures/ (cut from PR 23's first traced chip run of each cell
+by benchmark/tools/cut_named_trace.py), where every number is held EQUAL
+to what PR 24's tree read, and end to end in a CPU rehearsal of a toy cell
+with a spec of its own."""
 
 import json
 import os
@@ -38,7 +40,11 @@ V5E = peaks.peaks_for("TPU v5 lite")
     ("jit(train_step)/while/body/closed_call/transpose(jvp(attention))/"
      "flash_bwd_dq/pallas_call:", "attention", "flash_bwd_dq"),
     ("jit(train_step)/while/body/closed_call/jvp()/while/body/closed_call/"
-     "attention/shard_map/flash_fwd:", "attention", "flash_fwd"),
+     "attention/shard_map/flash_fwd/flash_fwd/pallas_call:", "attention",
+     "flash_fwd"),
+    # any `pallas_call(name=)`, under any scope: no list of kernels
+    ("jit(train_step)/jvp(mlp)/experts/grouped_matmul/pallas_call:", "mlp",
+     "grouped_matmul"),
     ("jit(train_step)/jvp(head_loss)/bsh,hv->bsv/dot_general:",
      "head_loss", None),
     ("jit(train_step)/optimizer/convert_element_type:", "optimizer", None),
@@ -116,7 +122,13 @@ def test_the_partition_closes_exactly_and_cut_runs_are_left_out():
                               "other": 500}
     busy_inside = 2 * (500 + 250 + 150 + 50)
     assert sum(got["regions"].values()) == busy_inside
-    assert got["recomputed_ps"] == 500
+    # every part of a name stack is a scope, whoever put it there: the
+    # program (`attention`), JAX (`rematted_computation`, `while`)
+    assert got["scopes"]["rematted_computation"] == 500
+    assert got["scopes"]["attention"] == got["scopes"]["body"] == 600
+    assert got["scopes"]["flash_fwd"] == 400
+    assert got["scopes"]["while"] == 400 + 600   # the loop and its body
+    assert got["scopes"]["step"] == busy_inside - 100   # all but the copy
     assert got["kernels"] == {"flash_fwd": {"ps": 400,
                                             "calls": {KERNEL_TEXT: 2}}}
     # the same events without their names are a program without scopes
@@ -138,26 +150,40 @@ def test_an_operation_across_a_runs_edge_counts_only_its_part_inside():
 
 # --- what the kernels need --------------------------------------------------
 
+def _needed(kernel, dims, itemsize, window):
+    """One call's needed work by benchmark/kernel_costs/<kernel>.py, as a
+    cell of BENCHMARK.json finds it; the window rides in the cell's whole
+    configuration."""
+    cell = spec.Cell(BENCHMARK, "train_mistral7b_seq4k")
+    return cell.kernel_cost(kernel)(dims, itemsize,
+                                    dict(cell.config, sliding_window=window))
+
+
 def test_kernel_costs_equal_hand_numbers():
     dims = (1, 32, 4096, 128)
-    flops, nbytes = kernel_cost.needed("flash_fwd", dims, 2, window=4096)
+    flops, nbytes = _needed("flash_fwd", dims, 2, window=4096)
     # 4*B*H*D * S(S+1)/2 = 16384 * 8,390,656
     assert flops == pytest.approx(137.47e9, rel=1e-3)
     assert flops == 4 * 32 * 128 * 4096 * 4097 // 2
     assert nbytes == 4 * 32 * 4096 * 128 * 2
-    assert kernel_cost.needed("flash_fwd", dims, 2, None) == (flops, nbytes)
+    assert _needed("flash_fwd", dims, 2, None) == (flops, nbytes)
     # a window of S/4: each query past the first 1024 sees 1024 keys
-    clipped, _ = kernel_cost.needed("flash_fwd", dims, 2, window=1024)
+    clipped, _ = _needed("flash_fwd", dims, 2, window=1024)
     pairs = 1024 * 1025 // 2 + 3072 * 1024
     assert kernel_cost.causal_pairs(4096, 1024) == pairs
     assert clipped == 4 * 32 * 128 * pairs
     assert flops - clipped == 4 * 32 * 128 * (3072 * 3073 // 2)
     # the backward needs five matmuls of the seven its kernels run, and
     # moves eight tensors
-    dq, dq_bytes = kernel_cost.needed("flash_bwd_dq", dims, 2, 4096)
-    dkv, dkv_bytes = kernel_cost.needed("flash_bwd_dkv", dims, 2, 4096)
+    dq, dq_bytes = _needed("flash_bwd_dq", dims, 2, 4096)
+    dkv, dkv_bytes = _needed("flash_bwd_dkv", dims, 2, 4096)
     assert dq + dkv == pytest.approx(2.5 * flops)
     assert dq_bytes + dkv_bytes == 2 * nbytes
+    # a result of another rank is not a shape these files know
+    assert _needed("flash_fwd", (32, 4096, 128), 2, 4096) is None
+    # a kernel nobody wrote a cost for has none: no roofline, no default
+    assert spec.Cell(BENCHMARK, "train_mistral7b_seq4k").kernel_cost(
+        "paged_flash_decode") is None
 
 
 def test_shapes_are_read_from_the_events_own_text():
@@ -169,35 +195,90 @@ def test_shapes_are_read_from_the_events_own_text():
     assert kernel_cost.result_shape("no hlo text") is None
 
 
+def _costs(config_window=4096):
+    cell = spec.Cell(BENCHMARK, "train_mistral7b_seq4k")
+    return cell.kernel_cost, dict(cell.config, sliding_window=config_window)
+
+
 def test_roofline_share_names_the_bound_that_applies():
     calls = {"flash_fwd": {"s": 0.020, "calls": {KERNEL_TEXT: 4.0}}}
-    roof = kernel_cost.roofline(calls, ("flash_fwd",), 4096, V5E)
+    needed_of, config = _costs()
+    roof = kernel_cost.roofline(calls, ("flash_fwd",), needed_of, config, V5E)
     # 4 calls of 137.47 GFLOP at 197 TFLOP/s are 2.79 ms of the 20
     assert roof["bound"] == "compute"
     assert roof["needed_ms"] == pytest.approx(2.791, rel=1e-3)
     assert roof["pct"] == pytest.approx(13.96, rel=1e-3)
     # at a hundredth of the FLOP/s peak... of the bandwidth, memory binds
     slow_memory = dict(V5E, hbm_bytes_per_s=V5E["hbm_bytes_per_s"] / 100)
-    assert kernel_cost.roofline(calls, ("flash_fwd",), 4096,
+    assert kernel_cost.roofline(calls, ("flash_fwd",), needed_of, config,
                                 slow_memory)["bound"] == "memory"
-    assert kernel_cost.roofline({}, ("flash_fwd",), 4096, V5E) is None
-    unreadable = {"flash_fwd": {"s": 0.02, "calls": {"%x = token[]": 1.0}}}
-    assert kernel_cost.roofline(unreadable, ("flash_fwd",), 4096,
+    assert kernel_cost.roofline({}, ("flash_fwd",), needed_of, config,
                                 V5E) is None
+    unreadable = {"flash_fwd": {"s": 0.02, "calls": {"%x = token[]": 1.0}}}
+    assert kernel_cost.roofline(unreadable, ("flash_fwd",), needed_of,
+                                config, V5E) is None
+    # a kernel that ran and has no cost file: no share, not a guess
+    assert kernel_cost.roofline(calls, ("flash_fwd",), lambda name: None,
+                                config, V5E) is None
+
+
+def test_a_kernel_of_any_name_gets_its_roofline_from_a_file_of_its_own(
+        tmp_path, monkeypatch):
+    """What a PR that adds a kernel brings: kernel_costs/<kernel>.py under
+    a directory of `paths` and a reader of three lines. Here the kernel is
+    a grouped matmul under `mlp/experts`, the spec a copy of the named toy
+    spec with this directory first in its `paths`."""
+    (tmp_path / "kernel_costs").mkdir()
+    (tmp_path / "kernel_costs" / "grouped_matmul.py").write_text(
+        "def needed(dims, itemsize, config):\n"
+        "    tokens, width = dims\n"
+        "    k = config['num_experts_per_tok']\n"
+        "    return 2.0 * tokens * width * config['hidden_size'] * k, "
+        "float(tokens * width * itemsize)\n")
+    (tmp_path / "layer_metrics").mkdir()
+    (tmp_path / "layer_metrics" / "experts_roofline_pct.py").write_text(
+        "from benchmark.harness.trace import named\n\n\n"
+        "def read(run):\n"
+        "    return named.roofline_pct(run, 'experts', 'grouped_matmul')\n")
+    with open(NAMED_SPEC) as f:
+        s = json.load(f)
+    root = os.path.dirname(NAMED_SPEC)
+    s["paths"] = [str(tmp_path)] + [os.path.join(root, p) for p in s["paths"]]
+    s["configs"][0]["file"] = os.path.join(root, s["configs"][0]["file"])
+    s["per_layer"].append({
+        "name": "experts_roofline_pct", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels",
+        "moves": "train_tokens_per_s", "workloads": ["named_train"]})
+    (tmp_path / "spec.json").write_text(json.dumps(s))
+    cell = spec.Cell(str(tmp_path / "spec.json"), "named_train")
+    cell.config["num_experts_per_tok"] = 8
+    text = ('%grouped_matmul.3 = bf16[32768,1024]{1,0} custom-call(bf16[8] '
+            '%x), custom_call_target="tpu_custom_call"')
+    monkeypatch.setattr(named, "per_run", lambda path: {
+        "kernels": {"grouped_matmul": {"s": 0.004, "calls": {text: 2.0}}}})
+    run = _fake_run(cell, trace={"devices": 1})
+    got = cell.reader("experts_roofline_pct")(run)
+    flop = 2 * 2.0 * 32768 * 1024 * cell.config["hidden_size"] * 8
+    assert got == pytest.approx(100 * flop / 197e12 / 0.004)
+    assert run.extras["roofline"]["experts"]["needed_flop"] == flop
+    assert named.kernel_ms(run, "grouped_matmul") == 4.0
+    assert named.kernel_ms(run, "flash_fwd") is None
 
 
 # --- the recordings ---------------------------------------------------------
 
-def _busy_inside_whole_runs(path):
-    """Per device, the union of operation intervals inside the whole runs,
-    by the benchmark's own interval arithmetic and no name at all."""
+def _busy_inside_whole_runs(path, which=lambda ev: True):
+    """Per device, the union of the intervals of the operations `which`
+    picks inside the whole runs, by the benchmark's own interval
+    arithmetic and no name at all."""
     out = {}
     for index, plane in reduce.device_planes(path).items():
         runs = reduce.merge(
             (m.start_ps, m.end_ps) for m in reduce.whole_runs(
                 reduce._line(plane, reduce.MODULE_LINE)))
         busy = reduce.merge((ev.start_ps, ev.end_ps)
-                            for ev in reduce._line(plane, reduce.OP_LINE))
+                            for ev in reduce._line(plane, reduce.OP_LINE)
+                            if which(ev))
         out[index] = reduce.total(busy) - reduce.total(
             reduce.subtract(busy, runs))
     return out
@@ -226,19 +307,29 @@ def test_readers_on_a_named_recording(name, devices, other_under):
     assert all(got["regions"][r] > 0 for r in named.REGIONS)
     other = got["regions"]["other"] + got["regions"]["embed"]
     assert 0 < other < other_under * whole
-    assert 0 < got["recomputed"] < whole
+    assert 0 < got["scopes"]["rematted_computation"] < whole
+    # a scope at any depth: `attention` is never inside another region,
+    # so its scope is its region; the kernels' scopes hold the kernels
+    assert got["scopes"]["attention"] == got["regions"]["attention"]
+    assert got["scopes"]["flash_fwd"] >= got["kernels"]["flash_fwd"]["s"]
+    # (GSPMD's own collectives carry no name stack at all: 1.6 % of the
+    # four-chip step)
+    assert 0.98 * whole < got["scopes"]["train_step"] <= whole
     # kernels found by name: all of the custom calls' time, none beside
     assert set(got["kernels"]) == {"flash_fwd", "flash_bwd_dq",
                                    "flash_bwd_dkv"}
     by_name = sum(k["s"] for k in got["kernels"].values())
+    custom_calls = _busy_inside_whole_runs(path, reduce.is_kernel)
     assert by_name == pytest.approx(
-        reduce.reduce_trace(path)["kernel_s_per_run"], rel=1e-9)
+        sum(custom_calls.values()) / devices / 2 * reduce.PS, rel=1e-9)
     # two layers: the forward and its recomputation, one backward pair
     assert sum(got["kernels"]["flash_fwd"]["calls"].values()) == 4.0
     assert sum(got["kernels"]["flash_bwd_dq"]["calls"].values()) == 2.0
-    fwd = kernel_cost.roofline(got["kernels"], ("flash_fwd",), 4096, V5E)
+    fwd = kernel_cost.roofline(got["kernels"], ("flash_fwd",), *_costs(),
+                               V5E)
     bwd = kernel_cost.roofline(got["kernels"],
-                               ("flash_bwd_dq", "flash_bwd_dkv"), 4096, V5E)
+                               ("flash_bwd_dq", "flash_bwd_dkv"), *_costs(),
+                               V5E)
     assert fwd["bound"] == bwd["bound"] == "compute"
     assert 10 < fwd["pct"] < 15 < bwd["pct"] < 18
     # the loop's passes, on the same clock: nearly all of a pass is the
@@ -294,16 +385,22 @@ def _fake_run(cell, **fields):
     return common.Run(**base)
 
 
-def test_benchmark_json_gains_exactly_the_twelve_entries_at_its_end():
+def test_benchmark_json_holds_the_twelve_entries_in_their_order():
+    """PR 23's twelve, found by name (later PRs append entries after them
+    and cells' names to their `workloads`)."""
     with open(BENCHMARK) as f:
         entries = json.load(f)["per_layer"]
-    assert [m["name"] for m in entries[-12:]] == NEW
-    for m in entries[-12:]:
+    ours = [m for m in entries if m["name"] in NEW]
+    assert [m["name"] for m in ours] == NEW
+    for m in ours:
         assert m["moves"] == "train_tokens_per_s"
-        assert m["workloads"] == ["train_mistral7b_seq4k",
-                                  "train_mistral7b_tp2dp2"]
+        assert m["workloads"][:2] == ["train_mistral7b_seq4k",
+                                      "train_mistral7b_tp2dp2"]
         assert m["unit"] in ("ms", "%", "GB")
         assert m["better"] == ("higher" if m["unit"] == "%" else "lower")
+    # one number is measured once: the custom calls' time is the two named
+    # sums (flash_fwd_ms_per_step + flash_bwd_ms_per_step)
+    assert "kernel_ms_per_step" not in [m["name"] for m in entries]
 
 
 @pytest.mark.parametrize("metric", NEW)
@@ -340,9 +437,13 @@ def test_the_readers_report_a_recorded_run(cell_name, fixture, tmp_path,
     assert got["other_ms_per_step"] < 0.06 * regions
     per_run = named.per_run(os.path.join(FIXTURES, fixture))
     assert regions == pytest.approx(1e3 * sum(per_run["regions"].values()))
+    # the two named sums are all of the custom calls' time (what the
+    # retired kernel_ms_per_step read, by opcode and no name)
+    path = os.path.join(FIXTURES, fixture)
+    custom_calls = _busy_inside_whole_runs(path, reduce.is_kernel)
     assert got["flash_fwd_ms_per_step"] + got["flash_bwd_ms_per_step"] == (
-        pytest.approx(1e3 * reduce.reduce_trace(os.path.join(
-            FIXTURES, fixture))["kernel_s_per_run"]))
+        pytest.approx(1e3 * sum(custom_calls.values()) / len(custom_calls)
+                      / per_run["runs"] * reduce.PS))
     assert got["step_temp_hbm_gb"] == pytest.approx(3.5665152)
     assert 1.0 < got["train_host_ms_per_step"] < 6.0
     # the bound that applies rides on the line's extras
@@ -352,6 +453,51 @@ def test_the_readers_report_a_recorded_run(cell_name, fixture, tmp_path,
     # a rehearsal has no peaks: no roofline, and nothing raised
     run.peaks = None
     assert cell.reader("flash_fwd_roofline_pct")(run) is None
+
+
+# --- equal, not close: the numbers of PR 24's tree on the recordings --------
+
+RECORDINGS = [("train_mistral7b_seq4k", "named_seq4k_tpu_v5e.xplane.pb"),
+              ("train_mistral7b_tp2dp2", "named_tp2dp2_tpu_v5e.xplane.pb")]
+with open(os.path.join(REPO, "tests", "benchmark", "named",
+                       "recorded_at_pr24.json")) as _f:
+    AT_PR24 = json.load(_f)
+
+
+@pytest.fixture(scope="module")
+def read_now(tmp_path_factory):
+    """{fixture: (the twelve metrics, extras.roofline)} as this tree's
+    readers give them for a run whose files are the recording and a
+    journal with the record's `temp_bytes`."""
+    from unittest import mock
+
+    journal = tmp_path_factory.mktemp("journal") / "events.jsonl"
+    journal.write_text(json.dumps(
+        {"kind": "step_program", "temp_bytes": 3_566_515_200}) + "\n")
+    out = {}
+    for cell_name, fixture in RECORDINGS:
+        cell = spec.Cell(BENCHMARK, cell_name)
+        run = _fake_run(cell, trace={"devices": cell.chips},
+                        steps=[{"t": 1.0, "step_ms": 170.0}])
+        with mock.patch.object(named, "run_files", lambda run: (
+                os.path.join(FIXTURES, fixture), str(journal))):
+            out[fixture] = ({m: cell.reader(m)(run) for m in NEW},
+                            run.extras["roofline"])
+    return out
+
+
+@pytest.mark.parametrize("fixture", [f for _, f in RECORDINGS])
+@pytest.mark.parametrize("metric", NEW)
+def test_a_named_metric_equals_what_pr24_read_on_the_recording(
+        metric, fixture, read_now):
+    assert read_now[fixture][0][metric] == AT_PR24[fixture]["metrics"][metric]
+
+
+@pytest.mark.parametrize("fixture", [f for _, f in RECORDINGS])
+@pytest.mark.parametrize("label", ["flash_fwd", "flash_bwd"])
+def test_a_roofline_record_equals_what_pr24_read_on_the_recording(
+        label, fixture, read_now):
+    assert read_now[fixture][1][label] == AT_PR24[fixture]["roofline"][label]
 
 
 # --- end to end, on the CPU -------------------------------------------------

@@ -54,9 +54,10 @@ def test_four_chip_training_cell_on_four_virtual_devices_traced():
     # program spans are read; device metrics have no device to read
     assert {"train_step_ms_p50", "train_data_wait_pct"} <= set(
         line["metrics"])
-    assert not {"device_idle_pct.train", "kernel_ms_per_step",
+    assert not {"device_idle_pct.train",
                 "collective_exposed_pct"} & set(line["metrics"])
-    # what the compiler says the step needs is read on any backend
+    # what the compiler says the step needs is read on any backend, from
+    # the `step_program` record the trainer journals after a traced run
     assert line["metrics"]["step_hbm_gb"]["value"] > 0
     assert "busy_s" not in line["device"]
     log = os.path.join(REPO, "runs", "benchmark", "toy_train4", "child.log")

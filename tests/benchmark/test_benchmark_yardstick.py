@@ -1,6 +1,7 @@
 """Unit tests of the benchmark's yardstick (benchmark/harness): the
-arithmetic, the generator, the trace reduction and the contract of the
-files, none of which needs a device."""
+arithmetic, the generator, the trace reduction, the readers and the
+result line, none of which needs a device. (The files against the
+contract: test_benchmark_contract.py.)"""
 
 import asyncio
 import hashlib
@@ -20,7 +21,9 @@ sys.path.insert(0, REPO)
 from benchmark.harness import (  # noqa: E402
     common, peaks, spec, stats, traffic,
 )
-from benchmark.harness.trace import reduce, xplane  # noqa: E402
+from benchmark.harness.trace import (  # noqa: E402
+    named, names, reduce, xplane,
+)
 from benchmark.reference import mistral as flops  # noqa: E402 - its counts
 
 BENCHMARK = os.path.join(REPO, "BENCHMARK.json")
@@ -246,39 +249,95 @@ def _ev(name, start, dur, **stats_):
     return xplane.Event(name, start, dur, stats_)
 
 
-def test_self_time_busy_union_and_exposed_collectives_on_made_up_events():
+PALLAS = ('%pallas.3 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} %x), '
+          'custom_call_target="tpu_custom_call"')
+ALL_REDUCE = "%all-reduce.4 = f32[4096]{0} all-reduce(f32[4096]{0} %g)"
+
+
+def _made_up_plane():
     ops = [
         _ev("while.1", 0, 1000),
         _ev("fusion.2", 100, 300),          # inside the while
-        _ev('%pallas.3 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} %x), '
-            'custom_call_target="tpu_custom_call"', 400, 200),  # in the while
-        _ev("%all-reduce.4 = f32[4096]{0} all-reduce(f32[4096]{0} %g)",
-            1200, 300),                     # alone on the device: exposed
+        _ev(PALLAS, 400, 200),              # inside the while
+        _ev(ALL_REDUCE, 1200, 300),         # alone on the device: exposed
         _ev("fusion.5", 2000, 100),
     ]
-    plane = xplane.Plane("/device:TPU:0", [
+    return xplane.Plane("/device:TPU:0", [
         xplane.Line(reduce.OP_LINE, ops),
         xplane.Line(reduce.MODULE_LINE, [_ev("jit_step(1)", 0, 1500),
                                          _ev("jit_other(2)", 1990, 200)]),
     ], {})
-    got = reduce.reduce_device(plane)
+
+
+def test_self_time_busy_union_and_exposed_collectives_on_made_up_events():
+    got = reduce.reduce_device(_made_up_plane())
     assert got["busy_ps"] == 1000 + 300 + 100
-    assert got["by_name"]["while.1"] == 500     # 1000 - 300 - 200
-    assert got["by_name"]["pallas.3 bf16[8,128] custom-call "
-                          "tpu_custom_call"] == 200
-    assert got["by_name"]["all-reduce.4 f32[4096] all-reduce"] == 300
-    assert got["kernel_ps"] == 200
+    # a program that names nothing: everything under `other`, every gap
+    # under the program that ends it
+    assert got["by_name"]["other: while.1"] == 500     # 1000 - 300 - 200
+    assert got["by_name"]["other: tpu_custom_call bf16[8,128]"] == 200
+    assert got["by_name"]["other: all-reduce f32[4096]"] == 300
     assert got["collective_ps"] == got["collective_exposed_ps"] == 300
     assert got["gaps"] == [("before jit_step", 200),
                            ("before jit_other", 500)]
+    assert got["span"] == (0, 2100)
     # a collective under compute on another line is hidden
     assert reduce.total(reduce.subtract(
         reduce.merge([(0, 100)]), reduce.merge([(20, 60)]))) == 60
     # two runs of a program are its first and its last: none is whole
-    assert got["runs"] == 0 and got["kernel_in_runs_ps"] == 0
+    assert got["runs"] == 0
 
 
-def test_kernel_time_per_step_counts_only_the_runs_the_trace_holds_whole():
+def test_operations_are_labelled_by_region_and_kernel_and_gaps_by_host_span():
+    tf_ops = {"fusion.2": "jit(step)/while/body/jvp(mlp)/dot_general:",
+              "fusion.5": "jit(step)/transpose(jvp(mlp))/dot_general:",
+              PALLAS: "jit(step)/while/body/attention/flash_fwd/flash_fwd/"
+                      "pallas_call:",
+              ALL_REDUCE: "jit(step)/optimizer/psum:"}
+    # the loop's thread: a pass that holds the wait for data as the first
+    # gap opens (at 1000) and nothing but itself as the second does (1500)
+    spans = [_ev("train-pass", 50, 1900), _ev("batch-generator", 900, 250)]
+    got = reduce.reduce_device(_made_up_plane(), tf_ops, spans)
+    # the two mlp operations merge under one label: what they are, not
+    # which instruction number the compiler gave them
+    assert got["by_name"] == {
+        "other: while.1": 500, "mlp: fusion.2": 300, "mlp: fusion.5": 100,
+        "attention/flash_fwd: tpu_custom_call bf16[8,128]": 200,
+        "optimizer: all-reduce f32[4096]": 300}
+    assert got["gaps"] == [("batch-generator", 200), ("train-pass", 500)]
+    # a gap that opens after the loop's last span falls to the program
+    late = reduce.reduce_device(_made_up_plane(), tf_ops,
+                                [_ev("train-pass", 50, 900)])
+    assert late["gaps"] == [("before jit_step", 200),
+                            ("before jit_other", 500)]
+    text = ("%fusion.7 = (bf16[4096,28672]{1,0:T(8,128)(2,1)}, f32[8]{0}) "
+            "fusion(bf16[4096,4096]{1,0} %p), kind=kOutput")
+    assert names.op_label(text, "jit(s)/jvp(mlp)/mul:") == (
+        "mlp: fusion bf16[4096,28672]")
+    assert names.op_label(text.replace("fusion.7", "fusion.8"), "") == (
+        "other: fusion bf16[4096,28672]")
+
+
+def test_busy_time_is_each_devices_own_over_its_own_window():
+    """Two devices that run the same 1000 ps of work 400 ps apart: over a
+    window from the first operation of any to the last of any each would
+    read 29 % idle; over its own window each is busy all the time."""
+    def device(at):
+        return reduce.reduce_device(xplane.Plane("/device:TPU:0", [
+            xplane.Line(reduce.OP_LINE, [_ev("fusion.1", at, 600),
+                                         _ev("fusion.2", at + 600, 400)]),
+            xplane.Line(reduce.MODULE_LINE, [_ev("jit_step(1)", at, 1000)]),
+        ], {}))
+
+    got = reduce.summarize({0: device(0), 1: device(400)})
+    assert got["devices"] == 2
+    assert got["busy_s"] == got["window_s"] == pytest.approx(1000e-12)
+    assert got["device_ops"] == [["other: fusion.1", pytest.approx(600e-12)],
+                                 ["other: fusion.2", pytest.approx(400e-12)]]
+    assert reduce.summarize({}) is None
+
+
+def test_whole_runs_leave_out_the_runs_the_traces_edges_cut():
     kernel = ('%k = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} %x), '
               'custom_call_target="tpu_custom_call"')
     # a trace that starts inside one step and stops inside another: five
@@ -293,11 +352,14 @@ def test_kernel_time_per_step_counts_only_the_runs_the_trace_holds_whole():
     plane = xplane.Plane("/device:TPU:0", [
         xplane.Line(reduce.OP_LINE, ops),
         xplane.Line(reduce.MODULE_LINE, runs)], {})
-    got = reduce.reduce_device(plane)
-    assert got["kernel_ps"] == 2 * 15 + 3 * 40
-    assert got["runs"] == 3 and got["kernel_in_runs_ps"] == 3 * 40
+    assert reduce.reduce_device(plane)["runs"] == 3
     assert [m.start_ps for m in reduce.whole_runs(runs)] == [100, 200, 300]
     assert reduce.whole_runs([]) == []
+    # by name, only what the whole runs hold counts (named.reduce_device)
+    per_run = named.reduce_device(plane, {
+        kernel: "jit(train_step)/mlp/grouped_matmul/pallas_call:"})
+    assert per_run["kernels"]["grouped_matmul"]["ps"] == 3 * 40
+    assert per_run["scopes"]["mlp"] == 3 * 40
 
 
 def test_decoder_reads_the_recorded_cpu_trace_and_finds_no_device_in_it():
@@ -328,158 +390,44 @@ def test_reduction_of_a_recorded_tpu_trace(name):
     got = reduce.reduce_trace(os.path.join(FIXTURES, name))
     assert got is not None and got["devices"] >= 1
     assert 0 < got["busy_s"] <= got["window_s"]
-    assert 0 <= got["kernel_s"] <= got["busy_s"]
     assert got["collective_exposed_worst_s"] <= got["window_s"]
     assert 1 <= len(got["device_ops"]) <= 10
     assert len(got["idle_gaps"]) <= 10
+    labels = [n for n, _ in got["device_ops"]]
+    assert len(set(labels)) == len(labels)
     assert all(isinstance(n, str) and s >= 0 for n, s in got["device_ops"])
     seconds = [s for _, s in got["device_ops"]]
     assert seconds == sorted(seconds, reverse=True)
     # what each recording is known to hold (PERF.md section 5)
     if name.startswith("train_seq4k"):
-        assert got["devices"] == 1 and got["kernel_s"] > 0
+        assert got["devices"] == 1
         # the recording keeps every run of the step (the first and the
         # last of them cut by the trace's edges) and the operations of the
         # first alone, so the whole runs hold no kernel here
-        assert got["runs"] == 9 and got["kernel_s_per_run"] == 0
+        assert got["runs"] == 9
         assert got["collective_exposed_worst_s"] == 0
         assert "tpu_custom_call" in got["device_ops"][0][0]
+        # PR 22's program named nothing and its loop had no spans
+        assert got["device_ops"][1][0] == "other: fusion bf16[2,4096,28672]"
         assert got["idle_gaps"][0][0] == "before jit_train_step"
     elif name.startswith("train_tp2dp2"):
         assert got["devices"] == 4 and got["runs"] == 3
         assert 0 < got["collective_exposed_worst_s"] < got["window_s"]
     elif name.startswith("serve_longprompt"):
         # the prefill chunk runs no Pallas kernel and copies the page pool
-        assert got["kernel_s"] == 0
-        assert "bf16[8,8000,16,8,128]" in got["device_ops"][0][0]
+        assert not any("tpu_custom_call" in n for n, _ in got["device_ops"])
+        assert got["device_ops"][0][0] == "other: fusion bf16[8,8000,16,8,128]"
         assert got["idle_gaps"][0][0] == "before jit_chunk_step"
-
-
-# --- the files, against the contract ----------------------------------------
-
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-
-
-def test_benchmark_json_has_the_contracts_keys_and_names():
-    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
-                         "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(BENCHMARK) < 64 * 1024
-    assert 1 <= SPEC["run_seconds"] <= 51
-    metrics = SPEC["end_to_end"] + SPEC["per_layer"]
-    names = ([m["name"] for m in metrics] + CELLS
-             + [c["name"] for c in SPEC["configs"]]
-             + [w["traffic"] for w in SPEC["workloads"]])
-    assert all(NAME.match(n) for n in names), names
-    for group in (metrics, SPEC["workloads"], SPEC["configs"]):
-        assert len({x["name"] for x in group}) == len(group)
-    for m in metrics:
-        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
-        assert m["source"] in SOURCES
-        assert set(m.get("workloads", [])) <= set(CELLS)
-    for m in SPEC["end_to_end"]:
-        assert set(m) <= {"name", "unit", "better", "bound", "source",
-                          "workloads"}
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.1
-    assert any(m["name"] == "setup_s" and "workloads" not in m
-               for m in SPEC["end_to_end"])
-    e2e = {m["name"] for m in SPEC["end_to_end"]}
-    for m in SPEC["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert m["moves"] in e2e and 1 <= len(m["layer"]) <= 200
-    for w in SPEC["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
-    four = sum(w["chips"] == 4 for w in SPEC["workloads"])
-    assert four <= max(len(CELLS) // 4, 1)
-    pairs = [(w["config"], w["traffic"]) for w in SPEC["workloads"]]
-    assert len(set(pairs)) == len(pairs)
-    used = {w["config"] for w in SPEC["workloads"]}
-    assert used == {c["name"] for c in SPEC["configs"]}
-    for c in SPEC["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert any(c["file"].startswith(p + "/") for p in SPEC["paths"])
-        assert not any(k.endswith(("_dim", "_rank", "_size"))
-                       for k in c["reduced"])
-    files = [c["file"] for c in SPEC["configs"]]
-    assert len(set(files)) == len(files)
-    command = " ".join(SPEC["command"])
-    assert ".." not in command and not any(
-        word.startswith("/") for word in SPEC["command"])
-    # the full check fits its budget with all 24 cells the contract allows
-    runs = 2 + 14 * 24
-    assert runs * (SPEC["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
-
-
-def test_configs_keep_the_published_widths():
-    published = {"hidden_size": 4096, "intermediate_size": 14336,
-                 "num_attention_heads": 32, "num_key_value_heads": 8,
-                 "vocab_size": 32000, "sliding_window": 4096,
-                 "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
-                 "max_position_embeddings": 32768}
-    listed = {c["name"]: c for c in SPEC["configs"]}
-    # the served configuration is kept ready for the cells PERF.md names
-    for name in ("mistral-7b-d2", "mistral-7b-d8-serve"):
-        cfg = _config(name)
-        assert {k: cfg[k] for k in published} == published, name
-        assert list(cfg["reduced"]) == ["num_hidden_layers"]
-        if name in listed:
-            assert cfg["source"] == listed[name]["source"]
-            assert listed[name]["reduced"] == list(cfg["reduced"])
-    assert "mistral-7b-d2" in listed
-
-
-@pytest.mark.parametrize("cell_name", CELLS)
-def test_every_cell_finds_its_files_and_reports_what_the_contract_asks(cell_name):
-    cell = spec.Cell(BENCHMARK, cell_name)
-    assert cell.traffic["driver"].split("_")[0] in ("train", "serve")
-    e2e = [m["name"] for m in cell.end_to_end()]
-    assert "setup_s" in e2e and len(e2e) >= 2
-    layer = cell.per_layer()
-    assert layer
-    for m in layer:
-        assert callable(cell.reader(m["name"]))
-    # the architecture is a file found by the configuration's name for it
-    assert cell.reference_path() == os.path.join(
-        REPO, "benchmark", "reference", "mistral.py")
-    if cell.traffic["driver"] == "train":
-        flags = spec.load_module(cell.reference_path()).program_flags(
-            cell.config, cell.traffic["seq_length"])
-        assert flags[flags.index("--num_layers") + 1] == "2"
-        assert "--no_tie_embed_logits" in flags
-
-
-CANDIDATES = os.path.join(REPO, "benchmark", "candidates.json")
-
-
-@pytest.mark.parametrize("cell_name", ["serve_mistral7b_instruct",
-                                       "serve_mistral7b_longprompt"])
-def test_serving_cells_kept_ready_find_their_files(cell_name):
-    cell = spec.Cell(CANDIDATES, cell_name)
-    assert cell.traffic["driver"] in ("serve_open", "serve_closed")
-    assert cell.config["num_hidden_layers"] == 8
-    assert {"setup_s"} < {m["name"] for m in cell.end_to_end()}
-    for m in cell.per_layer():
-        assert callable(cell.reader(m["name"]))
-    flags = cell.config["program"]["serve"]["flags"]
-    assert "--serve_kv_paging" in flags
-    # the longest request of the mix fits the engine's sequence limit
-    longest = (cell.traffic["prompt_tokens"]["max"]
-               + cell.traffic["new_tokens"]["max"])
-    assert longest <= int(flags[flags.index("--serve_max_seq_len") + 1])
-
-
-def test_every_reader_file_is_named_by_some_metric():
-    with open(TOY) as f:
-        toy = json.load(f)
-    stems = {m["name"].split(".")[0]
-             for m in SPEC["per_layer"] + toy["per_layer"]}
-    on_disk = {f[:-3] for f in os.listdir(os.path.join(
-        REPO, "benchmark", "layer_metrics")) if f.endswith(".py")}
-    assert stems == on_disk
+    elif name.startswith("named_"):
+        # every operation under a region, every Pallas call by its kernel,
+        # the gaps by what the loop was doing
+        regions = named.REGIONS + (named.OTHER,)
+        for label, _ in got["device_ops"]:
+            where = label.split(":")[0].split("/")
+            assert where[0] in regions, label
+            assert ("tpu_custom_call" in label) == (len(where) == 2), label
+        assert got["device_ops"][0][0].startswith("attention/flash_fwd: ")
+        assert got["idle_gaps"][0][0] == "metrics-fetch"
 
 
 # --- readers and the result line, on a made-up run --------------------------
@@ -496,26 +444,31 @@ def _fake_run(cell_name, spec_path=BENCHMARK, **fields):
     return common.Run(**base)
 
 
-def test_readers_compute_from_the_runs_records_and_return_nothing_on_nothing():
+def test_readers_compute_from_the_runs_records_and_return_nothing_on_nothing(
+        tmp_path, monkeypatch):
     steps = [{"t": 1.0 + i, "ntokens": 4096, "step_ms": 170.0 + i,
               "data_wait_ms": 1.7, "loss": 3.0, "compiles": 0}
              for i in range(3)]
-    trace = {"devices": 1, "window_s": 2.0, "busy_s": 1.5, "kernel_s": 0.3,
-             "runs": 8, "kernel_s_per_run": 0.044,
-             "collective_exposed_worst_s": 0.2, "device_ops": [],
+    trace = {"devices": 1, "window_s": 2.0, "busy_s": 1.5,
+             "runs": 8, "collective_exposed_worst_s": 0.2, "device_ops": [],
              "idle_gaps": []}
     run = _fake_run("train_mistral7b_seq4k", steps=steps, trace=trace,
-                    step_memory_bytes={"arguments": 9_000_000_000,
-                                       "temporaries": 3_000_000_000,
-                                       "outputs_not_aliased": 500_000_000},
                     end_to_end={"train_tokens_per_s": lambda: 24000.0})
     read = lambda name: run.cell.reader(name)(run)  # noqa: E731
     assert read("train_step_ms_p50") == 171.0
     assert read("train_data_wait_pct") == pytest.approx(100 * 5.1 / 513)
+    # what the step needs on a chip is the journal's own record of it:
+    # arguments + temporaries + the outputs that reuse no argument's room
+    journal = tmp_path / "events.jsonl"
+    monkeypatch.setattr(named, "run_files",
+                        lambda run: (str(tmp_path), str(journal)))
+    assert read("step_hbm_gb") is None and read("step_temp_hbm_gb") is None
+    journal.write_text(json.dumps(
+        {"kind": "step_program", "argument_bytes": 9_000_000_000,
+         "temp_bytes": 3_000_000_000, "output_bytes": 9_400_000_000,
+         "alias_bytes": 8_900_000_000}) + "\n")
     assert read("step_hbm_gb") == 12.5
-    assert read("kernel_ms_per_step") == pytest.approx(44.0)
-    run.trace["kernel_s_per_run"] = None     # no whole run in the trace
-    assert read("kernel_ms_per_step") is None
+    assert read("step_temp_hbm_gb") == 3.0
     assert read("device_idle_pct.train") == pytest.approx(25.0)
     assert read("collective_exposed_pct") is None      # one device
     run.trace["devices"] = 4
@@ -546,11 +499,10 @@ def test_result_line_holds_the_contracts_keys_and_names_the_device():
     run = _fake_run("train_mistral7b_seq4k", steps=steps,
                     end_to_end={"train_tokens_per_s": lambda: 24000.0},
                     trace={"devices": 1, "window_s": 2.0, "busy_s": 1.9,
-                           "kernel_s": 0.2, "runs": 9,
-                           "kernel_s_per_run": 0.02,
+                           "runs": 9,
                            "collective_exposed_worst_s": 0,
-                           "device_ops": [["fusion.1", 1.0]],
-                           "idle_gaps": [["before jit_train_step", 0.1]]})
+                           "device_ops": [["mlp: fusion bf16[8]", 1.0]],
+                           "idle_gaps": [["metrics-fetch", 0.1]]})
     line = run_py.result_line(run, trace=False)
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
     assert set(line["metrics"]) == {"train_tokens_per_s", "setup_s"}
@@ -560,8 +512,8 @@ def test_result_line_holds_the_contracts_keys_and_names_the_device():
     assert line["correct"] is True
     traced = run_py.result_line(run, trace=True)
     assert "setup_s" not in traced["metrics"]
-    assert {"train_step_ms_p50", "device_idle_pct.train",
-            "kernel_ms_per_step"} <= set(traced["metrics"])
+    assert {"train_step_ms_p50", "train_data_wait_pct",
+            "device_idle_pct.train"} <= set(traced["metrics"])
     assert "step_hbm_gb" not in traced["metrics"]   # nothing to read
     assert traced["device"]["busy_s"] == 1.9
     assert traced["device"]["window_s"] == 2.0
