@@ -59,6 +59,10 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
                    help="untie the word embedding and lm head (ref default "
                         "is tied)")
     g.add_argument("--sliding_window_size", type=int, default=None)
+    g.add_argument("--qk_norm", action="store_true", default=None,
+                   help="RMSNorm with a learned scale over the whole q and "
+                        "the whole k projection, before the head split and "
+                        "the rotary (OLMoE)")
     # MoE (beyond the reference; see ops/moe.py). Defaults are None so an
     # explicitly-passed knob overrides a preset's value but an unpassed
     # knob never clobbers it (the mixtral preset carries its own values).
@@ -94,7 +98,8 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
                    help="always on here (the TPU path computes softmax in "
                         "fp32 by default); flag kept for CLI parity")
     g.add_argument("--model_name", default=None,
-                   help="preset: llama/llama2/codellama/falcon/mistral/gpt2"
+                   help="preset: llama/llama2/codellama/falcon/mistral/mixtral/"
+                        "olmoe/gpt2"
                         " (optionally 'name-SIZE', e.g. llama2-7B)")
     g.add_argument("--model_size", default=None)
 
@@ -560,6 +565,8 @@ def args_to_run_config(args) -> RunConfig:
         overrides.update(_fp8_overrides(args))
         if args.tie_embed_logits is not None:  # explicit (no_)tie flag
             overrides["tie_embed_logits"] = args.tie_embed_logits
+        if args.qk_norm is not None:
+            overrides["qk_norm"] = args.qk_norm
         overrides.update(_moe_overrides(args))
         model = ModelConfig(**{**model.__dict__, **overrides}).validate()
     else:
@@ -596,6 +603,7 @@ def args_to_run_config(args) -> RunConfig:
                               else args.tie_embed_logits),
             **_moe_overrides(args),
             sliding_window_size=args.sliding_window_size,
+            qk_norm=bool(args.qk_norm),
             use_post_ln=args.use_post_ln,
             apply_residual_post_ln=args.apply_residual_connection_post_layernorm,
             hidden_dropout=args.hidden_dropout,

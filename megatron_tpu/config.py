@@ -113,6 +113,11 @@ class ModelConfig:
     # Mistral sliding-window attention (ref: transformer.py:528-536)
     sliding_window_size: Optional[int] = None
 
+    # OLMoE QK-norm: RMSNorm with a learned scale over the WHOLE q and the
+    # whole k projection (all heads at once), before the head split and
+    # the rotary (HF modeling_olmoe.py q_norm / k_norm)
+    qk_norm: bool = False
+
     # Mixture-of-Experts (beyond the reference): GShard/Switch einsum
     # dispatch with capacity; Mixtral-style renormalized top-k gates.
     # None = dense MLP. See ops/moe.py.

@@ -17,6 +17,7 @@ Embedding, parallel_lm_logits) + megatron/model/gpt_model.py
 
 from __future__ import annotations
 
+import operator
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.transformer import Sharder, _dropout, _identity_sharder, block_forward
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
+from megatron_tpu.ops.moe import LOAD_METRIC, merge_layer_stats
 from megatron_tpu.ops.weight_quant import deq, take_rows
 from megatron_tpu.ops.normalization import norm_forward
 from megatron_tpu.ops.rotary import precompute_rope
@@ -221,6 +223,9 @@ def lm_forward(
     kv_caches: stacked per-layer caches for incremental decoding; when
     given, returns (logits, updated_caches).
 
+    return_moe_aux: also return [aux loss summed over the layers, the
+    worst layer's load statistic] (ops/moe.py layer_stats).
+
     page_table: the caches are PAGED pools [L, num_pages, page_size,
     nkv, D] (inference/paging/) shared by every slot; each row's logical
     context is page_table[b] physical pages. The table is broadcast to
@@ -266,6 +271,8 @@ def lm_forward(
                                cfg.rope_scaling_factor)
 
     rates = _layer_dropout_rates(cfg)
+    moe = cfg.num_experts is not None
+    add_aux = merge_layer_stats if moe else operator.add
 
     def body(carry, scanned):
         x, aux = carry
@@ -285,14 +292,14 @@ def lm_forward(
             tp_comm=tp_comm,
             cp_comm=cp_comm,
         )
-        return (y, aux + moe_aux), new_cache
+        return (y, add_aux(aux, moe_aux)), new_cache
 
     layer_idx = jnp.arange(cfg.num_layers)
     xs = (params["layers"], rates, layer_idx, kv_caches)
     if kv_caches is not None and parse_recompute(recompute)[1] is not None:
         recompute = "none"  # decode path: caches preclude the split scan
     (x, moe_aux), new_caches = scan_with_remat(
-        body, (x, jnp.zeros((), jnp.float32)), xs, recompute)
+        body, (x, jnp.zeros((2,) if moe else (), jnp.float32)), xs, recompute)
 
     # "head_loss" names the final norm, the head and (in lm_loss) the
     # cross-entropy: one region of the step in a device trace
@@ -405,8 +412,9 @@ def lm_loss(
                else jnp.asarray(per_token.size, jnp.float32))
     aux = {"lm_loss": mean, "ntokens": ntokens}
     if moe:
-        # router losses train alongside CE (Switch eq. 4 / ST-MoE z-loss);
+        # router losses train alongside CE (load balance / ST-MoE z-loss);
         # lm_loss in metrics stays the pure CE term
-        aux["moe_aux_loss"] = moe_aux
-        return mean + moe_aux, aux
+        aux["moe_aux_loss"] = moe_aux[0]
+        aux[LOAD_METRIC] = moe_aux[1]
+        return mean + moe_aux[0], aux
     return mean, aux

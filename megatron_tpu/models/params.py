@@ -70,6 +70,10 @@ def _defs(cfg: ModelConfig) -> Dict[str, Any]:
     d["layers/attn/wk"] = ((L, h, nkv * D), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
     d["layers/attn/wv"] = ((L, h, nkv * D), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
     d["layers/attn/wo"] = ((L, nq * D, h), P(AXIS_PIPE, AXIS_TENSOR, None), _SCALED)
+    if cfg.qk_norm:
+        # one scale over the whole projection, sharded with its output axis
+        d["layers/attn/q_norm/scale"] = ((L, nq * D), P(AXIS_PIPE, AXIS_TENSOR), _ONES)
+        d["layers/attn/k_norm/scale"] = ((L, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ONES)
     if cfg.use_bias_qkv:
         d["layers/attn/bq"] = ((L, nq * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
         d["layers/attn/bk"] = ((L, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)

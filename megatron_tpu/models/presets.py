@@ -113,6 +113,23 @@ def mixtral(size: str = "8x7B", seq_length: int = 8192) -> ModelConfig:
     )
 
 
+def olmoe(size: str = "1B-7B", seq_length: int = 4096) -> ModelConfig:
+    """OLMoE-1B-7B (arXiv:2409.02060; allenai/OLMoE-1B-7B-0125-Instruct
+    config.json): 16 layers, hidden 2048, 16 heads (MHA), 64 SwiGLU
+    experts of width 1024 with 8 a token, raw softmax gates
+    (`norm_topk_prob: false`), no shared expert, QK-norm over the whole
+    projections, untied head, vocab 50304. Dropless dispatch, as the
+    paper trains; load-balance coefficient 0.01, router z-loss 0.001."""
+    assert size == "1B-7B"
+    return _llama_base(
+        hidden_size=2048, num_layers=16, num_attention_heads=16,
+        ffn_hidden_size=1024, vocab_size=50304, seq_length=seq_length,
+        qk_norm=True, num_experts=64, moe_top_k=8, moe_renorm_gates=False,
+        moe_dispatch="dropless", moe_aux_loss_coeff=0.01,
+        moe_z_loss_coeff=0.001,
+    )
+
+
 def falcon(size: str = "7B", seq_length: int = 2048) -> ModelConfig:
     """Falcon 7B/40B: rotary, MQA/GQA, parallel attention, layernorm, gelu,
     tied embeddings, no linear biases (ref: megatron/model/falcon_model.py)."""
@@ -175,6 +192,7 @@ PRESETS = {
     "codellama": codellama,
     "mistral": mistral,
     "mixtral": mixtral,
+    "olmoe": olmoe,
     "falcon": falcon,
     "gpt2": gpt2,
     "tiny": tiny,

@@ -27,8 +27,8 @@ from megatron_tpu.config import ModelConfig
 from megatron_tpu.ops.activations import apply_activation
 from megatron_tpu.ops.attention import attention
 from megatron_tpu.ops.fp8 import maybe_fp8_matmul
-from megatron_tpu.ops.moe import moe_block
-from megatron_tpu.ops.normalization import norm_forward
+from megatron_tpu.ops.moe import layer_stats, moe_block
+from megatron_tpu.ops.normalization import norm_forward, rmsnorm
 from megatron_tpu.ops.rotary import apply_rotary_emb
 from megatron_tpu.ops.weight_quant import deq
 
@@ -105,6 +105,11 @@ def attention_block(
     v = maybe_fp8_matmul(cfg, x, deq(p["wv"], x.dtype))
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    if cfg.qk_norm:
+        # over all heads at once: under TP the mean square crosses shards
+        # (GSPMD reduces it); the cache below holds normed, rotated keys
+        q = rmsnorm(q, p["q_norm"]["scale"], cfg.layernorm_epsilon)
+        k = rmsnorm(k, p["k_norm"]["scale"], cfg.layernorm_epsilon)
     q = q.reshape(b, s, nq, D)
     k = k.reshape(b, s, nkv, D)
     v = v.reshape(b, s, nkv, D)
@@ -350,9 +355,11 @@ def mlp_block(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray,
 
 def _ffn(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
          tp_comm=None):
-    """Dense MLP or MoE, by config. Returns (out, aux_loss fp32 scalar)."""
+    """Dense MLP or MoE, by config. Returns (out, moe_aux): a zero fp32
+    scalar for a dense layer, [aux loss, load statistic] for an MoE one."""
     if cfg.num_experts is not None:
-        return moe_block(cfg, lp["moe"], x)
+        out, aux, load = moe_block(cfg, lp["moe"], x)
+        return out, layer_stats(aux, load)
     return (mlp_block(cfg, lp["mlp"], x, tp_comm=tp_comm),
             jnp.zeros((), jnp.float32))
 
@@ -375,11 +382,12 @@ def block_forward(
     tp_comm=None,
     cp_comm=None,
 ) -> Tuple[jnp.ndarray, Optional[Tuple[jnp.ndarray, jnp.ndarray]], jnp.ndarray]:
-    """One decoder layer -> (y, kv_cache, moe_aux_loss).
+    """One decoder layer -> (y, kv_cache, moe_aux).
 
     hidden_dropout_rate may be a traced scalar (LIMA per-layer ramp, ref
-    transformer.py:994-1001). moe_aux_loss is a zero scalar for dense
-    models."""
+    transformer.py:994-1001). moe_aux is a zero scalar for dense models
+    and [aux loss, load statistic] for MoE ones (ops/moe.py
+    layer_stats)."""
     if dropout_key is not None:
         k_attn_drop, k_hidden1, k_hidden2 = jax.random.split(dropout_key, 3)
     else:

@@ -859,6 +859,7 @@ SHAPE_KEYS = ("num_layers", "encoder_num_layers", "decoder_num_layers",
 #: #3: same weights, different forward function)
 DRIFT_KEYS = ("normalization", "activation", "position_embedding_type",
               "rope_theta", "rope_scaling_factor", "sliding_window_size",
+              "qk_norm",
               "tie_embed_logits", "parallel_attn", "parallel_layernorm",
               "use_post_ln", "apply_residual_post_ln", "attn_mask_type",
               "use_bias_linear", "use_bias_qkv", "layernorm_epsilon",

@@ -53,6 +53,7 @@ from megatron_tpu.models.language_model import (
 )
 from megatron_tpu.models.transformer import block_forward
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
+from megatron_tpu.ops.moe import aux_loss_of
 from megatron_tpu.ops.rotary import precompute_rope
 
 
@@ -116,7 +117,7 @@ def _stage_fn(cfg: ModelConfig, chunk_layers: Any, x: jnp.ndarray,
                                       dropout_key=key,
                                       hidden_dropout_rate=rate,
                                       **({"sharder": sharder} if sharder else {}))
-        return (y, aux + moe_aux), None
+        return (y, aux + aux_loss_of(moe_aux)), None
 
     # block:N remats only the first N of this chunk's layers (the
     # reference applies the budget per pipeline stage)

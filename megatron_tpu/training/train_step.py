@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from megatron_tpu.config import ModelConfig, OptimizerConfig, TrainingConfig
 from megatron_tpu.models.language_model import lm_loss
 from megatron_tpu.models.transformer import Sharder, _identity_sharder
+from megatron_tpu.ops.moe import LOAD_METRIC
 from megatron_tpu.parallel.random import RngStreams
 from megatron_tpu.training.optimizer import TrainState, make_optimizer_step
 
@@ -103,20 +104,26 @@ def make_train_step(
 
             def scaled_loss(p):
                 loss, aux = loss_fn(model_cfg, p, mb, key)
-                return loss * scale, loss
+                # None for a model without experts: no leaf, no output
+                return loss * scale, (loss, aux.get(LOAD_METRIC))
 
-            (_, loss), grads = jax.value_and_grad(scaled_loss, has_aux=True)(state.params)
-            acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32), acc, grads)
-            return acc, loss
+            (_, out), grads = jax.value_and_grad(scaled_loss, has_aux=True)(state.params)
+            with jax.named_scope("grad_accumulate"):
+                acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32), acc, grads)
+            return acc, out
 
         zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-        acc, losses = jax.lax.scan(one_micro, zeros, (micro, jnp.arange(n)))
+        acc, (losses, loads) = jax.lax.scan(one_micro, zeros,
+                                            (micro, jnp.arange(n)))
         # mean over microbatches; scaled grads stay scaled for the optimizer
         grads = jax.tree.map(lambda g: g / n, acc)
 
         with jax.named_scope("optimizer"):
             new_state, metrics = opt_apply(state, grads)
         metrics["loss"] = jnp.mean(losses)
+        if loads is not None:
+            # worst layer's largest expert over the mean, mean of micro-batches
+            metrics[LOAD_METRIC] = jnp.mean(loads)
         return new_state, metrics
 
     return train_step

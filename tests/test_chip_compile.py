@@ -274,6 +274,45 @@ def test_train_step_fits_one_v5e(one_chip_step):
     _assert_step_kernels_named(text)
 
 
+def test_olmoe_cell_step_fits_one_v5e(topo):
+    """The step of the benchmark's `train_olmoe1b7b_seq4k` cell: OLMoE-1B-7B
+    widths, one layer with all 64 experts, 16 micro-batches of one
+    4096-token sequence accumulated in float32. It fits the chip; each
+    `lax.ragged_dot` (forward and the two backward products of both expert
+    matrices) becomes a grouped-matmul Mosaic kernel of XLA's own and not
+    a dense product over all experts; the flash kernels keep their names
+    and the MoE block's scopes arrive."""
+    from megatron_tpu.telemetry.tracing.events import scope_tokens
+    from megatron_tpu.training.aot import aot_compile_train_step
+
+    cfg = dataclasses.replace(
+        presets.olmoe(seq_length=SEQ), num_layers=1,
+        params_dtype="bfloat16", ce_chunk_size=512,
+        attention_impl="pallas").validate()
+    compiled, _ = aot_compile_train_step(
+        cfg, ParallelConfig(), OptimizerConfig(lr=1e-4),
+        micro_batch_size=1, num_microbatches=16, recompute="selective",
+        devices=topo.devices[:1])
+    assert _per_device_bytes(compiled) < 15e9
+    text = compiled.as_text()
+    grouped = re.findall(r"%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])", text)
+    assert sorted(grouped) == sorted(
+        3 * ["bf16[32768,2048]"] + ["bf16[32768,1024]",
+                                    "bf16[64,2048,2048]",
+                                    "bf16[64,1024,2048]"]), grouped
+    # the program's own kernels by name (at one layer the compiler keeps
+    # one forward call: the recomputed one is merged with it); XLA's eight
+    # (six grouped matmuls, their two offset tables) carry the compiler's
+    # name and no scope
+    assert _kernels_named(text) == [
+        "<unnamed>"] * 8 + ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    stacks = [scope_tokens(n)
+              for n in set(re.findall(r'op_name="([^"]+)"', text))]
+    for scope in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine", "grad_accumulate"):
+        assert any(scope in toks for toks in stacks), scope
+
+
 def _tp2_dp2_step(topo):
     from megatron_tpu.training.aot import aot_compile_train_step
 

@@ -29,13 +29,13 @@ def test_topk_dispatch_slots_and_weights():
     gates = jnp.asarray([[0.7, 0.2, 0.1],
                          [0.6, 0.3, 0.1],
                          [0.1, 0.8, 0.1]], jnp.float32)
-    combine, dispatch, top1 = topk_dispatch(gates, top_k=1, capacity=2,
+    combine, dispatch, chosen = topk_dispatch(gates, top_k=1, capacity=2,
                                             renorm=True)
     # top-1 renormalized weight is 1.0; tokens 0,1 -> expert 0 slots 0,1
     assert combine[0, 0, 0] == pytest.approx(1.0)
     assert combine[1, 0, 1] == pytest.approx(1.0)
     assert combine[2, 1, 0] == pytest.approx(1.0)
-    np.testing.assert_array_equal(np.asarray(top1).argmax(1), [0, 0, 1])
+    np.testing.assert_array_equal(np.asarray(chosen).argmax(1), [0, 0, 1])
     # each (expert, slot) holds at most one token
     assert np.asarray(dispatch).sum(axis=0).max() <= 1
 
@@ -63,7 +63,7 @@ def test_single_expert_matches_dense_mlp():
     w_out = jnp.asarray(rng.normal(0, 0.02, (cfg.ffn_size, 32)), jnp.float32)
     x = jnp.asarray(rng.normal(0, 1, (2, 16, 32)), jnp.float32)
     router = jnp.zeros((32, 1), jnp.float32)
-    y_moe, aux = moe_block(cfg, {"router": router, "w_in": w_in[None],
+    y_moe, aux, _ = moe_block(cfg, {"router": router, "w_in": w_in[None],
                                  "w_out": w_out[None]}, x)
     y_dense = mlp_block(dense, {"w_in": w_in, "w_out": w_out}, x)
     np.testing.assert_allclose(np.asarray(y_moe), np.asarray(y_dense),
@@ -100,7 +100,7 @@ def test_moe_block_matches_hf_mixtral():
 
     rng = np.random.default_rng(1)
     x = np.asarray(rng.normal(0, 1, (2, 16, H)), np.float32)
-    y_ours, _ = moe_block(cfg, {"router": router, "w_in": w_in,
+    y_ours, _, _ = moe_block(cfg, {"router": router, "w_in": w_in,
                                 "w_out": w_out}, jnp.asarray(x))
     with torch.no_grad():
         y_hf, _ = hf(torch.from_numpy(x))
@@ -218,15 +218,15 @@ def test_moe_dropless_matches_capacity_at_ample_capacity():
     p = init_params(cfg_cap, jax.random.PRNGKey(5))
     lp = jax.tree.map(lambda a: a[0], p["layers"])
 
-    y_cap, aux_cap = moe_block(cfg_cap, lp["moe"], x)
-    y_drop, aux_drop = moe_block_dropless(cfg_drop, lp["moe"], x)
+    y_cap, aux_cap, _ = moe_block(cfg_cap, lp["moe"], x)
+    y_drop, aux_drop, _ = moe_block_dropless(cfg_drop, lp["moe"], x)
     np.testing.assert_allclose(np.asarray(y_drop), np.asarray(y_cap),
                                rtol=2e-5, atol=2e-6)
     np.testing.assert_allclose(float(aux_drop), float(aux_cap), rtol=1e-5)
 
     def loss(fn, cfg, lp):
         def f(lp):
-            y, aux = fn(cfg, lp["moe"], x)
+            y, aux, _ = fn(cfg, lp["moe"], x)
             return jnp.sum(jnp.square(y)) + aux
         return jax.grad(f)(lp)
 
@@ -255,8 +255,8 @@ def test_moe_dropless_keeps_overflow_tokens():
     p = init_params(cfg_cap, jax.random.PRNGKey(5))
     lp = jax.tree.map(lambda a: a[0], p["layers"])
 
-    y_cap, _ = moe_block(cfg_cap, lp["moe"], x)
-    y_drop, _ = moe_block_dropless(cfg_drop, lp["moe"], x)
+    y_cap, _, _ = moe_block(cfg_cap, lp["moe"], x)
+    y_drop, _, _ = moe_block_dropless(cfg_drop, lp["moe"], x)
     cap_zero = np.all(np.isclose(np.asarray(y_cap)[0], 0.0, atol=1e-7), -1)
     drop_zero = np.all(np.isclose(np.asarray(y_drop)[0], 0.0, atol=1e-7), -1)
     assert cap_zero.sum() > 0, "test needs actual overflow drops"
@@ -319,10 +319,10 @@ def test_moe_dropless_ep_matches_single_group():
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((4, 16, 32)).astype(np.float32))
 
-    y_ref, aux_ref = moe_block_dropless(cfg, lp["moe"], x)
+    y_ref, aux_ref, _ = moe_block_dropless(cfg, lp["moe"], x)
     rt = _ep_mesh(expert_parallel=2, tensor_parallel=2)
     with jax.sharding.set_mesh(rt.mesh):
-        y_ep, aux_ep = jax.jit(
+        y_ep, aux_ep, _ = jax.jit(
             lambda lp, x: moe_block(cfg, lp["moe"], x))(lp, x)
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref),
                                rtol=2e-5, atol=2e-6)
@@ -330,7 +330,7 @@ def test_moe_dropless_ep_matches_single_group():
 
     def loss(fn):
         def f(lp, x):
-            y, aux = fn(cfg, lp["moe"], x)
+            y, aux, _ = fn(cfg, lp["moe"], x)
             return jnp.sum(jnp.square(y)) + aux
         return f
 
@@ -358,10 +358,10 @@ def test_moe_dropless_ep_exact_under_extreme_imbalance():
     rng = np.random.default_rng(11)
     x = jnp.asarray(rng.standard_normal((2, 16, 32)).astype(np.float32))
 
-    y_ref, _ = moe_block_dropless(cfg, lp["moe"], x)
+    y_ref, _, _ = moe_block_dropless(cfg, lp["moe"], x)
     rt = _ep_mesh(expert_parallel=2)
     with jax.sharding.set_mesh(rt.mesh):
-        y_ep, _ = jax.jit(lambda lp, x: moe_block(cfg, lp["moe"], x))(lp, x)
+        y_ep, _, _ = jax.jit(lambda lp, x: moe_block(cfg, lp["moe"], x))(lp, x)
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -387,9 +387,9 @@ def test_moe_dropless_ep_buffer_factor_semantics():
     router = np.zeros((32, 4), np.float32)
     router[:, 0] = 10.0
     lp["moe"]["router"] = jnp.asarray(router)
-    y_ref2, _ = moe_block_dropless(cfg, lp["moe"], x)
+    y_ref2, _, _ = moe_block_dropless(cfg, lp["moe"], x)
     with jax.sharding.set_mesh(rt.mesh):
-        y_ep2, _ = jax.jit(lambda lp, x: moe_block(cfg, lp["moe"], x))(lp, x)
+        y_ep2, _, _ = jax.jit(lambda lp, x: moe_block(cfg, lp["moe"], x))(lp, x)
     y_ref2, y_ep2 = np.asarray(y_ref2), np.asarray(y_ep2)
     zero_rows = np.all(np.isclose(y_ep2.reshape(-1, 32), 0.0, atol=1e-7), -1)
     assert zero_rows.sum() > 0, "saturation must overflow the buffer"
@@ -442,7 +442,7 @@ def test_moe_ragged_transport_path_matches_dense():
     lp = jax.tree.map(lambda a: a[0], p["layers"])
     rng = np.random.default_rng(17)
     x = jnp.asarray(rng.standard_normal((4, 16, 32)).astype(np.float32))
-    y_ref, aux_ref = moe_block_dropless(cfg, lp["moe"], x)
+    y_ref, aux_ref, _ = moe_block_dropless(cfg, lp["moe"], x)
 
     orig_pred = moe_mod._use_ragged_transport
     orig_a2a = jax.lax.ragged_all_to_all
@@ -451,7 +451,7 @@ def test_moe_ragged_transport_path_matches_dense():
     try:
         rt = _ep_mesh(expert_parallel=2, tensor_parallel=2)
         with jax.sharding.set_mesh(rt.mesh):
-            y_ep, aux_ep = jax.jit(
+            y_ep, aux_ep, _ = jax.jit(
                 lambda lp, x: moe_block(cfg, lp["moe"], x))(lp, x)
         np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref),
                                    rtol=2e-5, atol=2e-6)
@@ -459,7 +459,7 @@ def test_moe_ragged_transport_path_matches_dense():
 
         def loss(fn):
             def f(lp, x):
-                y, aux = fn(cfg, lp["moe"], x)
+                y, aux, _ = fn(cfg, lp["moe"], x)
                 return jnp.sum(jnp.square(y)) + aux
             return f
 
@@ -488,7 +488,7 @@ def test_moe_dropless_serves_single_row_on_ep_mesh():
     lp = jax.tree.map(lambda a: a[0], p["layers"])
     rng = np.random.default_rng(23)
     x = jnp.asarray(rng.standard_normal((1, 16, 32)).astype(np.float32))
-    y_ref, _ = moe_block_dropless(cfg, lp["moe"], x)
+    y_ref, _, _ = moe_block_dropless(cfg, lp["moe"], x)
 
     rt = _ep_mesh(expert_parallel=2)
     # REALLY shard the expert weights E/ep — the property under test is
@@ -498,7 +498,7 @@ def test_moe_dropless_serves_single_row_on_ep_mesh():
     lp["moe"]["w_out"] = jax.device_put(
         lp["moe"]["w_out"], NamedSharding(rt.mesh, P("expert", None, None)))
     with jax.sharding.set_mesh(rt.mesh):
-        y_ep, _ = jax.jit(lambda lp, x: moe_block(cfg, lp["moe"], x))(lp, x)
+        y_ep, _, _ = jax.jit(lambda lp, x: moe_block(cfg, lp["moe"], x))(lp, x)
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -678,8 +678,8 @@ def test_moe_grouped_matches_whole_batch_with_ample_capacity():
     p = jax.tree.map(lambda l: l[0], params["layers"]["moe"])  # layer 0
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(0, 1, (2, 16, 32)), jnp.float32)
-    y_small, aux_small = moe_block(cfg_small, p, x)
-    y_row, aux_row = moe_block(cfg_row, p, x)
+    y_small, aux_small, _ = moe_block(cfg_small, p, x)
+    y_row, aux_row, _ = moe_block(cfg_row, p, x)
     np.testing.assert_allclose(np.asarray(y_small), np.asarray(y_row),
                                rtol=1e-5, atol=1e-6)
     # aux losses are global over tokens, so they match too
@@ -699,7 +699,7 @@ def test_moe_capacity_is_per_group():
         "w_out": jnp.ones((2, cfg.ffn_size, 4), jnp.float32) * 0.1,
     }
     x = jnp.ones((1, 8, 4), jnp.float32)
-    y, _ = moe_block(cfg, p, x)
+    y, _, _ = moe_block(cfg, p, x)
     y = np.asarray(y)[0]  # [8, 4]
     # capacity per group of 4 = ceil(0.51*1*4/2)=2: in EACH group the first
     # two tokens are kept, the last two dropped (zero output). Global
@@ -724,7 +724,7 @@ def test_moe_mixtral_geometry_compiles_within_memory():
     assert moe_group_size(cfg) == 2048
 
     def layer_loss(p, x):
-        y, aux = moe_block(cfg, p, x)
+        y, aux, _ = moe_block(cfg, p, x)
         return jnp.sum(y.astype(jnp.float32) ** 2) + aux
 
     p_shapes = {
