@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.transformer import Sharder, _dropout, _identity_sharder, block_forward
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
-from megatron_tpu.ops.moe import LOAD_METRIC, merge_layer_stats
+from megatron_tpu.ops.moe import LOAD_METRIC, SAVED_PRODUCT, merge_layer_stats
 from megatron_tpu.ops.weight_quant import deq, take_rows
 from megatron_tpu.ops.normalization import norm_forward
 from megatron_tpu.ops.rotary import precompute_rope
@@ -78,7 +78,11 @@ def _remat_policy(recompute: str):
         # save weight-matmul outputs, recompute core attention — the TPU
         # expression of the reference's selective recompute
         # (transformer.py:391-410 checkpointed core attention)
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        # (the dropless experts' grouped products are such outputs too;
+        # where they are Pallas kernels and no dot, their name saves them)
+        return jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names(SAVED_PRODUCT))
     raise ValueError(f"unknown recompute policy {recompute!r}")
 
 

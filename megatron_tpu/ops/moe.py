@@ -20,14 +20,19 @@ definition of the auxiliary losses serve both dispatch forms:
   * `moe_dispatch="dropless"` (`moe_block_dropless`; what the benchmark's
     OLMoE cell runs, on one chip): the N*k (token, choice) rows are
     argsorted by expert and gathered into expert order, the two expert
-    matmuls run as `lax.ragged_dot` grouped GEMMs over contiguous
-    per-expert row spans, and the outputs scatter-add back to their
-    tokens weighted by the gates. No token is dropped and no [.., E, C]
-    tensor exists. Its four stages carry the scopes a device trace is
-    read by: `moe_router`, `moe_dispatch`, `moe_experts`, `moe_combine`
-    (docs/observability.md "Runtime traces"). Under a mesh whose data or
-    expert axis divides the batch, `moe_block_dropless_ep` runs the same
-    per shard with an explicit expert-axis all-to-all.
+    matmuls run as grouped GEMMs over contiguous per-expert row spans
+    (`ops/pallas/grouped_matmul.py`: on one TPU the program's own Pallas
+    kernels `moe_gmm` / `moe_tgmm`, forward and both gradients, with
+    tiles picked from the shapes; `lax.ragged_dot` on every other
+    backend, under a mesh of several devices and at row counts the tiles
+    do not divide, such as decode), and the outputs scatter-add back to
+    their tokens weighted by the gates. No token is dropped and no
+    [.., E, C] tensor exists. Its four stages carry the scopes a device
+    trace is read by: `moe_router`, `moe_dispatch`, `moe_experts`,
+    `moe_combine` (docs/observability.md "Runtime traces"). Under a mesh
+    whose data or expert axis divides the batch, `moe_block_dropless_ep`
+    runs the same per shard with an explicit expert-axis all-to-all (its
+    grouped GEMMs are `lax.ragged_dot` still).
   * `moe_dispatch="capacity"` (GShard/Switch, the default): the N = B*S
     tokens are reshaped into G groups of Sg tokens (Sg divides S, so
     groups never cross batch rows and data sharding stays aligned);
@@ -47,6 +52,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.ops.activations import apply_activation
@@ -55,6 +61,11 @@ from megatron_tpu.ops.activations import apply_activation
 # the key of the load statistic in the loss's aux, the step's metrics and
 # the journal's `step` record
 LOAD_METRIC = "moe_load_max_over_mean"
+# the `checkpoint_name` of the dropless experts' two grouped products:
+# selective recomputation saves weight-matmul outputs, and knows a
+# `lax.ragged_dot` for one but not a Pallas call
+# (models/language_model.py _remat_policy)
+SAVED_PRODUCT = "moe_expert_product"
 
 
 def moe_capacity(cfg: ModelConfig, num_tokens: int) -> int:
@@ -185,8 +196,9 @@ def moe_block_dropless(
 
     No token is ever dropped and no [.., E, C] dispatch/combine tensors
     exist: the N*k (token, choice) rows are argsorted by expert, the two
-    expert matmuls run as lax.ragged_dot grouped GEMMs (contiguous
-    per-expert row spans — TPU's grouped-matmul primitive), and outputs
+    expert matmuls run as grouped GEMMs over contiguous per-expert row
+    spans (grouped_matmul: the program's Pallas kernels on one TPU,
+    lax.ragged_dot elsewhere), and outputs
     scatter back through the inverse sort weighted by the gates. FLOPs are
     exactly N*k MLP rows vs the capacity path's dense O(G*Sg*E*Cg)
     dispatch einsums.
@@ -199,6 +211,11 @@ def moe_block_dropless(
     batch-axis argsort collectives) and whose expert axis carries the
     explicit dispatch all-to-all.
     """
+    # with the other kernels: imported where it is used (ops/attention.py)
+    from megatron_tpu.ops.pallas.grouped_matmul import (
+        grouped_matmul, visits_for,
+    )
+
     b, s, h = x.shape
     N = b * s
     E = cfg.num_experts
@@ -221,12 +238,19 @@ def moe_block_dropless(
         xs = jnp.take(xf, rows, axis=0)                # [N*k, H] sorted
 
     with jax.named_scope("moe_experts"):
-        hmid = jax.lax.ragged_dot(xs, p["w_in"], group_sizes)
+        # one visit table for both products and their gradients (None
+        # where the products are lax.ragged_dot)
+        visits = visits_for(group_sizes, N * k)
+        hmid = checkpoint_name(
+            grouped_matmul(xs, p["w_in"], group_sizes, visits=visits),
+            SAVED_PRODUCT)
         if "b_in" in p:
             # per-row expert bias: gather by the row's expert id
             hmid = hmid + jnp.take(p["b_in"], jnp.take(flat_e, order), axis=0)
         hmid = apply_activation(cfg.activation, hmid.astype(x.dtype))
-        out = jax.lax.ragged_dot(hmid, p["w_out"], group_sizes)
+        out = checkpoint_name(
+            grouped_matmul(hmid, p["w_out"], group_sizes, visits=visits),
+            SAVED_PRODUCT)
         if "b_out" in p:
             out = out + jnp.take(p["b_out"], jnp.take(flat_e, order), axis=0)
 
@@ -367,6 +391,13 @@ def moe_block_dropless_ep(
     Transport is ragged_all_to_all on TPU; CPU (and therefore CI) uses an
     all_gather reconstruction with identical math — the ragged path is on
     the on-device capture list.
+
+    The grouped GEMMs here stay lax.ragged_dot, not grouped_matmul's
+    Pallas kernels: this shard_map names only the expert (and data) axes,
+    and a Mosaic kernel lowers only under ONE shard_map naming every mesh
+    axis (PR 21; ops/attention.py _shard_plan). This form has never run on
+    the chip and no benchmark cell reaches it: ROADMAP S6's four-chip
+    follow-up.
 
     include_data: also make the DATA axis manual (tokens divide data x
     expert). The sort/bincount/scatter then run per-shard with no

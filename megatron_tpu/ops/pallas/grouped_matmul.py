@@ -1,0 +1,499 @@
+"""Grouped matmul over ragged row groups: the dropless experts' product.
+
+    grouped_matmul(lhs [m, k], rhs [E, k, n], group_sizes [E]) -> [m, n]
+
+Row r of `lhs` belongs to group g when offsets[g] <= r < offsets[g + 1]
+(offsets = the running sum of `group_sizes`, which must sum to m: every
+row has an expert, the dropless dispatch's invariant) and is multiplied
+by rhs[g]. That is `jax.lax.ragged_dot`, and on every backend but a TPU,
+under a mesh of several devices, or at shapes the tiles do not divide,
+this function IS `jax.lax.ragged_dot`. On one TPU it is three Pallas
+kernels of the program's own under one `jax.custom_vjp`:
+
+  kernel      | product                          | grid
+  ------------|----------------------------------|--------------------------
+  `moe_gmm`   | out[rows of g] = lhs · rhs[g]    | (n tiles, visits, k tiles)
+  `moe_gmm`   | dlhs = dout · rhs[g]ᵀ: the same  | the same; rhs's block is
+              | kernel contracting rhs's last    | taken [tn, tk] from the
+              | axis, no transposed copy in HBM  | stored [E, k, n]
+  `moe_tgmm`  | drhs[g] = lhs[rows of g]ᵀ · dout | (n tiles, k tiles, visits)
+
+The algorithm is MegaBlocks' as jax ships it
+(jax/experimental/pallas/ops/tpu/megablox): rows are cut into tiles of tm
+aligned to the array, not to the groups, and a VISIT is one (row tile,
+group) pair whose rows intersect. A tile wholly inside one group is
+visited once; a tile that holds a group boundary once per group it
+touches, keeping only that group's rows. So there are m / tm +
+(groups - 1) visits at most. The visit table (`group_visits`) rides in as
+scalar-prefetch operands and the BlockSpec index maps read it, so
+consecutive visits of one group name the same rhs block and its [tk, tn]
+slab stays in VMEM while the group's row tiles stream past.
+
+What differs from jax's copy, and why the kernels live here:
+  * a name (`pallas_call(name=)` under `jax.named_scope`, flash_template's
+    `_named_pallas_call`), so that a device trace books them under
+    `mlp`/`moe_experts`; tiles chosen in code from the shapes
+    (`pick_gmm_tiles`, swept on the chip) and a VMEM limit to match;
+  * a boundary visit does a boundary's work, not a tile's: `moe_gmm`
+    takes it in 128-row spans and skips those the group does not reach,
+    `moe_tgmm` contracts over the smallest 128, 256, ... row window that
+    holds the group's rows. With 64 groups of 512 rows in the mean nearly
+    every tile holds a boundary, and a whole-tile product per visit is
+    what kept the compiler's kernel at a third of the MXU's peak;
+  * the store is masked only where a span holds a boundary; `moe_tgmm`
+    zeroes one operand, not both; where one k tile spans the contraction
+    there is no accumulator round trip;
+  * one row tile for the three kernels, so one visit table for a layer,
+    built once and shared by every product over the same groups
+    (`visits=`).
+
+Precision: operands reach the MXU in the dtype they arrive in, every
+product accumulates in float32, results are cast to the operands' dtype on
+the way out, as `lax.ragged_dot` and its gradients give them.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from megatron_tpu.ops.pallas import flash_template as ft
+from megatron_tpu.ops.pallas.flash_template import _NN, _NT, _TN, _dot
+
+Tiles = Tuple[int, int, int]          # (tm, tk, tn)
+
+
+class GroupVisits(NamedTuple):
+    """The visit table of one row-tile size (all int32, scalar-prefetched).
+    offsets [E + 1]: first row of each group, m last. group_ids, tile_ids
+    [m / tm + E - 1]: the group and the row tile of each visit, in row
+    order; entries past `count` repeat the last visit, so their steps move
+    nothing. count [1]: the visits there are. An empty group has one visit
+    (it does nothing in `moe_gmm`; `moe_tgmm` writes the group's zeros)."""
+    offsets: jnp.ndarray
+    group_ids: jnp.ndarray
+    tile_ids: jnp.ndarray
+    count: jnp.ndarray
+
+
+def group_visits(group_sizes: jnp.ndarray, m: int, tm: int) -> GroupVisits:
+    """The visit table for rows cut into m / tm tiles.
+
+    `lax.div`, not `//` (the rows are not negative, so it floors): with
+    jnp's floor_divide twice in a layer's body, or with two tables in it,
+    XLA inlines a layer scan of length one only after its first CSE, which
+    then no longer merges a one-layer model's recomputed forward with the
+    forward itself (in the benchmark's OLMoE cell: a second `flash_fwd` a
+    micro-batch, 12 ms a step; tests/test_chip_compile.py counts one)."""
+    E = group_sizes.shape[0]
+    n_tiles = m // tm
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = jnp.minimum(jax.lax.div(starts, tm), n_tiles - 1)
+    # tiles a group touches; an empty group is visited once
+    n_visits = jnp.where(sizes > 0,
+                         jax.lax.div(ends - 1, tm) - first + 1, 1)
+    visit_ends = jnp.cumsum(n_visits)
+    count = visit_ends[E - 1:]
+    v = jnp.minimum(jnp.arange(n_tiles + E - 1, dtype=jnp.int32), count - 1)
+    group_ids = jnp.sum(v[:, None] >= visit_ends[None, :], axis=1,
+                        dtype=jnp.int32)
+    # visit v of group g is tile first[g] + (v - the visits before g)
+    tile_ids = (jnp.take(first, group_ids)
+                + v - jnp.take(visit_ends - n_visits, group_ids))
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return GroupVisits(offsets, group_ids, tile_ids, count)
+
+
+# ---------------------------------------------------------------------------
+# tiles
+# ---------------------------------------------------------------------------
+
+_SLAB = 2048 * 2048
+
+
+def _largest_tile(dim: int, cap: int) -> Optional[int]:
+    """The largest multiple of 128 that divides dim and is at most cap."""
+    for t in range(min(dim, cap) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return None
+
+
+def pick_row_tile(m: int, num_groups: int) -> Optional[int]:
+    """tm of every kernel over m rows in num_groups groups: the largest of
+    512, 256, 128 that divides m and is no larger than the mean group.
+    None where 128 does not divide m (decode, odd batches). One row tile
+    for the three kernels is one visit table for a layer. A boundary tile
+    is worked in 128-row spans (`moe_gmm`) or in the smallest 128, 256,
+    ... row window that holds the group's rows (`moe_tgmm`), so a larger
+    tile wastes no more MXU time at boundaries than a smaller one and
+    takes fewer grid steps and accumulator passes (PERF.md, PR 27: 512
+    over 256 over 128 at a mean group of 512 rows and of 1024)."""
+    if m % 128 or num_groups < 1:
+        return None
+    for tm in (512, 256):
+        if m % tm == 0 and tm <= m // num_groups:
+            return tm
+    return 128
+
+
+def pick_gmm_tiles(m: int, k: int, n: int, num_groups: int, *,
+                   tgmm: bool = False) -> Optional[Tiles]:
+    """(tm, tk, tn) for the product of m rows in num_groups groups over a
+    contraction of k into n columns (`moe_gmm`; for the rows' gradient
+    call it with k and n exchanged), or with tgmm=True for the [k, n]
+    products of `moe_tgmm`. None where the kernels do not tile the shape:
+    rows that `pick_row_tile` declines, k or n that 128 does not divide.
+
+    The rule the sweeps on the v5e left (PERF.md, PR 27): the widest tn
+    up to 2048, then the longest tk the slab a kernel keeps in VMEM
+    allows. `moe_gmm` keeps rhs's [tk, tn], which stays while a group's
+    row tiles stream past: 2048 x 2048 where that is the whole contraction
+    (no accumulator, each product stored as it is made), half of it where
+    k tiles accumulate (at k 4096 the larger slab took 1.8 times as
+    long). `moe_tgmm` keeps the float32 [tk, tn] accumulator: 2048 x 2048
+    at row tiles up to 256, half of it above (512 rows against the larger
+    one took 2.4 times as long)."""
+    tm = pick_row_tile(m, num_groups)
+    if tm is None or k % 128 or n % 128:
+        return None
+    tn = _largest_tile(n, 2048)
+    if tgmm:
+        slab = _SLAB // 2 if tm > 256 else _SLAB
+    else:
+        slab = _SLAB if k * tn <= _SLAB else _SLAB // 2
+    tk = _largest_tile(k, min(2048, slab // tn))
+    return tm, tk, tn
+
+
+def _vmem_limit(nbytes: int) -> Optional[int]:
+    """The scoped-VMEM limit for a kernel whose blocks and temporaries
+    count nbytes: a quarter and 4 MiB more for what the compiler adds
+    (the count came 2 % short at [512, 2048] x [2048, 1024] tiles), left
+    alone where the default already holds it."""
+    nbytes = nbytes * 5 // 4 + (4 << 20)
+    if nbytes <= ft._DEFAULT_SCOPED_VMEM:
+        return None
+    return min(nbytes, ft._MAX_SCOPED_VMEM)
+
+
+# ---------------------------------------------------------------------------
+# moe_gmm: out[rows of g] = lhs[rows of g] · rhs[g]  (or · rhs[g]ᵀ)
+# ---------------------------------------------------------------------------
+
+
+# rows of a boundary tile's spans: one pass of the 128 x 128 MXU
+_SPAN = 128
+# rows a dynamic slice of a tile may start at: bf16 packs 16 to a sublane tile
+_SUBLANES = 16
+
+
+def _visit(offs_ref, gids_ref, tids_ref, v, tm: int):
+    """(first row, end row) of the visit's group, first row of its tile."""
+    g = gids_ref[v]
+    return offs_ref[g], offs_ref[g + 1], tids_ref[v] * tm
+
+
+def _row_mask(lo, hi, row0, tm: int):
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return (rows >= lo) & (rows < hi)
+
+
+def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, rhs_ref,
+                out_ref, *scratch, tm: int, dims):
+    v = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
+    lo, hi, row0 = _visit(offs_ref, gids_ref, tids_ref, v, tm)
+    live = (v < count_ref[0]) & (hi > lo)
+    whole = (lo <= row0) & (hi >= row0 + tm)
+
+    def span(start: int, size: int, inside):
+        """The product of the tile's rows [start, start + size), kept
+        where they are the group's. inside: the span holds no boundary
+        (True where the caller knows, else traced)."""
+        rows = pl.ds(start, size)
+        first_row = row0 + start
+
+        def keep(acc):
+            def all_rows():
+                out_ref[rows, :] = acc.astype(out_ref.dtype)
+
+            def the_groups_rows():
+                # the other rows belong to the visits before and after
+                # this one, which hold the same output block
+                out_ref[rows, :] = jnp.where(
+                    _row_mask(lo, hi, first_row, size), acc,
+                    out_ref[rows, :].astype(jnp.float32)
+                ).astype(out_ref.dtype)
+
+            if inside is True:
+                all_rows()
+            else:
+                pl.when(inside)(all_rows)
+                pl.when(jnp.logical_not(inside))(the_groups_rows)
+
+        prod = _dot(lhs_ref[rows, :], rhs_ref[...], dims)
+        if not scratch:      # one k tile spans the contraction
+            keep(prod)
+            return
+        acc_ref, = scratch
+
+        @pl.when(ki == 0)
+        def _first():
+            acc_ref[rows, :] = prod
+
+        @pl.when(ki > 0)
+        def _later():
+            acc_ref[rows, :] += prod
+
+        @pl.when(ki == nk - 1)
+        def _emit():
+            keep(acc_ref[rows, :])
+
+    # a tile inside one group is one product; a tile that holds a boundary
+    # is taken in spans of _SPAN rows, and only those the group reaches
+    pl.when(live & whole)(lambda: span(0, tm, True))
+    for start in range(0, tm, _SPAN):
+        first_row = row0 + start
+        reached = (hi > first_row) & (lo < first_row + _SPAN)
+        inside = (lo <= first_row) & (hi >= first_row + _SPAN)
+        pl.when(live & jnp.logical_not(whole) & reached)(
+            functools.partial(span, start, _SPAN, inside))
+
+
+def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool):
+    """lhs [m, k] · rhs[g] with rhs [E, k, n], or with transpose_rhs
+    lhs [m, k] · rhs[g]ᵀ with rhs [E, n, k]. Returns [m, n]."""
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tm, tk, tn = tiles
+    nk = k // tk
+    dtype = jnp.result_type(lhs.dtype, rhs.dtype)
+    item = jnp.dtype(dtype).itemsize
+
+    def lhs_map(j, v, ki, offs, gids, tids, count):
+        return tids[v], ki
+
+    def out_map(j, v, ki, offs, gids, tids, count):
+        return tids[v], j
+
+    if transpose_rhs:
+        rhs_spec = pl.BlockSpec(
+            (None, tn, tk),
+            lambda j, v, ki, offs, gids, tids, count: (gids[v], j, ki))
+    else:
+        rhs_spec = pl.BlockSpec(
+            (None, tk, tn),
+            lambda j, v, ki, offs, gids, tids, count: (gids[v], ki, j))
+
+    # lhs, rhs and out blocks double-buffered, the float32 product and (for
+    # several k tiles) the accumulator
+    vmem = (2 * (tm * tk + tk * tn + tm * tn) * item
+            + (1 if nk == 1 else 2) * tm * tn * 4)
+    return ft._named_pallas_call(
+        "moe_gmm",
+        functools.partial(_gmm_kernel, tm=tm,
+                          dims=_NT if transpose_rhs else _NN),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, visits.group_ids.shape[0], nk),
+            in_specs=[pl.BlockSpec((tm, tk), lhs_map), rhs_spec],
+            out_specs=pl.BlockSpec((tm, tn), out_map),
+            scratch_shapes=([] if nk == 1
+                            else [pltpu.VMEM((tm, tn), jnp.float32)])),
+        out_shape=jax.ShapeDtypeStruct((m, n), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(vmem)),
+        interpret=ft._interpret(),
+    )(*visits, lhs.astype(dtype), rhs.astype(dtype))
+
+
+# ---------------------------------------------------------------------------
+# moe_tgmm: out[g] = lhs[rows of g]ᵀ · dout[rows of g]
+# ---------------------------------------------------------------------------
+
+
+def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, dout_ref,
+                 out_ref, acc_ref, *, tm: int):
+    v = pl.program_id(2)
+    last_step = pl.num_programs(2) - 1
+    count = count_ref[0]
+    g = gids_ref[v]
+    lo, hi, row0 = _visit(offs_ref, gids_ref, tids_ref, v, tm)
+    live = v < count
+    first = (v == 0) | (gids_ref[jnp.maximum(v - 1, 0)] != g)
+    last = (v == count - 1) | (gids_ref[jnp.minimum(v + 1, last_step)] != g)
+    whole = (lo <= row0) & (hi >= row0 + tm)
+
+    def accumulate(prod):
+        @pl.when(first)
+        def _first():
+            acc_ref[...] = prod
+
+        @pl.when(jnp.logical_not(first))
+        def _later():
+            acc_ref[...] += prod
+
+    @pl.when(live & whole)
+    def _all_rows():
+        accumulate(_dot(lhs_ref[...], dout_ref[...], _TN))
+
+    # A tile that holds a boundary: the group's rows are [a, b) of it, and
+    # the product is taken over the smallest window of 128, 256, ... rows
+    # that holds them (from a sublane-aligned start), the other groups'
+    # rows in it zeroed in the narrower operand: a zero row on one side is
+    # a zero term of the sum, and the MXU's time follows the window's rows.
+    a = jnp.maximum(lo - row0, 0)
+    b = jnp.minimum(hi - row0, tm)
+    smaller_fits = jnp.bool_(False)
+    size = _SPAN
+    while size <= tm:
+        start = jnp.minimum(a // _SUBLANES * _SUBLANES, tm - size)
+        fits = b <= start + size
+
+        def window(start=start, size=size):
+            rows = pl.ds(pl.multiple_of(start, _SUBLANES), size)
+            mask = _row_mask(lo, hi, row0 + start, size)
+            lhs, dout = lhs_ref[rows, :], dout_ref[rows, :]
+            if lhs.shape[1] <= dout.shape[1]:
+                lhs = jnp.where(mask, lhs, jnp.zeros_like(lhs))
+            else:
+                dout = jnp.where(mask, dout, jnp.zeros_like(dout))
+            accumulate(_dot(lhs, dout, _TN))
+
+        pl.when(live & jnp.logical_not(whole) & (hi > lo) & fits
+                & jnp.logical_not(smaller_fits))(window)
+        smaller_fits = smaller_fits | fits
+        size *= 2
+
+    @pl.when(live & (hi == lo))
+    def _no_rows():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live & last)
+    def _emit():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles):
+    """lhs [m, k], dout [m, n] -> [E, k, n], one product per group."""
+    m, k = lhs.shape
+    n = dout.shape[1]
+    E = visits.offsets.shape[0] - 1
+    tm, tk, tn = tiles
+    dtype = jnp.result_type(lhs.dtype, dout.dtype)
+    item = jnp.dtype(dtype).itemsize
+
+    def lhs_map(j, i, v, offs, gids, tids, count):
+        return tids[v], i
+
+    def dout_map(j, i, v, offs, gids, tids, count):
+        return tids[v], j
+
+    def out_map(j, i, v, offs, gids, tids, count):
+        return gids[v], i, j
+
+    # row tiles and the output block double-buffered, the float32
+    # accumulator and one product beside it
+    vmem = (2 * (tm * tk + tm * tn + tk * tn) * item
+            + 2 * tk * tn * 4)
+    return ft._named_pallas_call(
+        "moe_tgmm", functools.partial(_tgmm_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, k // tk, visits.group_ids.shape[0]),
+            in_specs=[pl.BlockSpec((tm, tk), lhs_map),
+                      pl.BlockSpec((tm, tn), dout_map)],
+            out_specs=pl.BlockSpec((None, tk, tn), out_map),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((E, k, n), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(vmem)),
+        interpret=ft._interpret(),
+    )(*visits, lhs.astype(dtype), dout.astype(dtype))
+
+
+# ---------------------------------------------------------------------------
+# the function and its gradient
+# ---------------------------------------------------------------------------
+
+
+class _Plan(NamedTuple):
+    """Tiles of the three products of one grouped_matmul (one tm)."""
+    fwd: Tiles       # lhs · rhs[g]
+    drows: Tiles     # dout · rhs[g]ᵀ
+    tgmm: Tiles      # lhs ᵀ · dout per group
+
+
+def _plan(m: int, k: int, n: int, num_groups: int) -> Optional[_Plan]:
+    tiles = (pick_gmm_tiles(m, k, n, num_groups),
+             pick_gmm_tiles(m, n, k, num_groups),
+             pick_gmm_tiles(m, k, n, num_groups, tgmm=True))
+    return None if None in tiles else _Plan(*tiles)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul_kernels(lhs, rhs, visits: GroupVisits, plan: _Plan):
+    return _gmm(lhs, rhs, visits, plan.fwd, False)
+
+
+def _kernels_fwd(lhs, rhs, visits, plan: _Plan):
+    return _grouped_matmul_kernels(lhs, rhs, visits, plan), (lhs, rhs, visits)
+
+
+def _kernels_bwd(plan: _Plan, res, dout):
+    lhs, rhs, visits = res
+    dout = dout.astype(jnp.result_type(lhs.dtype, rhs.dtype))
+    dlhs = _gmm(dout, rhs, visits, plan.drows, True)
+    drhs = _tgmm(lhs, dout, visits, plan.tgmm)
+    return dlhs.astype(lhs.dtype), drhs.astype(rhs.dtype), None
+
+
+_grouped_matmul_kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
+def _one_tpu() -> bool:
+    """The kernels serve one TPU: GSPMD cannot partition a Mosaic call, and
+    under a mesh of several devices the products stay `lax.ragged_dot`,
+    which it can."""
+    if jax.default_backend() != "tpu":
+        return False
+    from megatron_tpu.parallel.mesh import ambient_mesh_shape
+
+    return math.prod(ambient_mesh_shape().values()) == 1
+
+
+def visits_for(group_sizes: jnp.ndarray, m: int) -> Optional[GroupVisits]:
+    """The visit table of m rows in these groups for
+    `grouped_matmul(visits=)`: built once, shared by every product over
+    the same groups. None where the kernels do not serve."""
+    tm = pick_row_tile(m, group_sizes.shape[0]) if _one_tpu() else None
+    return None if tm is None else group_visits(group_sizes, m, tm)
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
+                   group_sizes: jnp.ndarray, *,
+                   visits: Optional[GroupVisits] = None) -> jnp.ndarray:
+    """lhs [m, k] times rhs[g] [k, n] for the rows of each group g of
+    group_sizes [E] (which sum to m); differentiable in lhs and rhs.
+    `visits` is `visits_for(group_sizes, m)` from a caller that runs
+    several products over the same groups."""
+    m, k = lhs.shape
+    E, _, n = rhs.shape
+    plan = _plan(m, k, n, E) if _one_tpu() else None
+    if plan is None:
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    if visits is None:
+        visits = visits_for(group_sizes, m)
+    return _grouped_matmul_kernels(lhs, rhs, visits, plan)

@@ -53,10 +53,12 @@ DEVICE_STEP_LINE = "Steps"
 
 #: jax.named_scope names in the program, innermost wins
 #: (models/transformer.py, models/language_model.py,
-#: training/train_step.py; ops/pallas/flash_template.py)
+#: training/train_step.py; ops/pallas/flash_template.py,
+#: ops/pallas/grouped_matmul.py)
 REGION_SCOPES = ("optimizer", "head_loss", "attention", "mlp", "embed")
 KERNEL_SCOPES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                 "flash_decode", "paged_flash_decode")
+                 "flash_decode", "paged_flash_decode",
+                 "moe_gmm", "moe_tgmm")
 
 _WRAPPED = re.compile(r"^[A-Za-z_]\w*\((.*)\)$")  # jvp(x), transpose(jvp(x))
 _PROGRAM_ID = re.compile(r"\(\d+\)$")             # jit_train_step(1234)

@@ -28,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 from megatron_tpu.config import OptimizerConfig, ParallelConfig
 from megatron_tpu.models import presets
 from megatron_tpu.ops.pallas import flash_template as ft
+from megatron_tpu.ops.pallas import grouped_matmul as gm
 
 # megatron_tpu.ops re-exports the attention FUNCTION under the module's name
 attention_mod = importlib.import_module("megatron_tpu.ops.attention")
@@ -59,6 +60,7 @@ def _as_on_the_chip():
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ft, "_interpret", lambda: False)
         mp.setattr(attention_mod, "_kernels_dispatchable", lambda: True)
+        mp.setattr(gm, "_one_tpu", lambda: True)
         yield
 
 
@@ -277,11 +279,12 @@ def test_train_step_fits_one_v5e(one_chip_step):
 def test_olmoe_cell_step_fits_one_v5e(topo):
     """The step of the benchmark's `train_olmoe1b7b_seq4k` cell: OLMoE-1B-7B
     widths, one layer with all 64 experts, 16 micro-batches of one
-    4096-token sequence accumulated in float32. It fits the chip; each
-    `lax.ragged_dot` (forward and the two backward products of both expert
-    matrices) becomes a grouped-matmul Mosaic kernel of XLA's own and not
-    a dense product over all experts; the flash kernels keep their names
-    and the MoE block's scopes arrive."""
+    4096-token sequence accumulated in float32. It fits the chip; the six
+    grouped products of the experts (forward and the two backward products
+    of both expert matrices) are the program's own kernels, by name and
+    under the scopes `mlp` and `moe_experts`, and none is left to XLA's
+    `ragged-dot-none`; the flash kernels keep their names and the MoE
+    block's scopes arrive."""
     from megatron_tpu.telemetry.tracing.events import scope_tokens
     from megatron_tpu.training.aot import aot_compile_train_step
 
@@ -295,17 +298,24 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
         devices=topo.devices[:1])
     assert _per_device_bytes(compiled) < 15e9
     text = compiled.as_text()
-    grouped = re.findall(r"%ragged-dot-none[.\d]* = (\w+\[[\d,]+\])", text)
-    assert sorted(grouped) == sorted(
-        3 * ["bf16[32768,2048]"] + ["bf16[32768,1024]",
-                                    "bf16[64,2048,2048]",
-                                    "bf16[64,1024,2048]"]), grouped
-    # the program's own kernels by name (at one layer the compiler keeps
-    # one forward call: the recomputed one is merged with it); XLA's eight
-    # (six grouped matmuls, their two offset tables) carry the compiler's
-    # name and no scope
+    assert "ragged-dot" not in text
+    # the program's own kernels by name, nothing unnamed. Selective
+    # recomputation saves the grouped products like the dots they are, so
+    # `moe_gmm` runs four times (two forward products, two gradients of
+    # the rows) and not six; at one layer the compiler merges the
+    # recomputed flash forward with the forward itself
     assert _kernels_named(text) == [
-        "<unnamed>"] * 8 + ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
+        "moe_gmm", "moe_gmm", "moe_gmm", "moe_gmm", "moe_tgmm", "moe_tgmm"]
+    results = re.findall(
+        r"%moe_t?gmm[.\d]* = (\w+\[[\d,]+\])", text)
+    assert set(results) == {"bf16[32768,2048]", "bf16[32768,1024]",
+                            "bf16[64,2048,2048]", "bf16[64,1024,2048]"}
+    for toks in _kernel_name_stacks(text):
+        kernel = toks[-2]
+        if kernel.startswith("moe_"):
+            assert "mlp" in toks and "moe_experts" in toks, toks
+            assert toks.index("mlp") < toks.index("moe_experts"), toks
     stacks = [scope_tokens(n)
               for n in set(re.findall(r'op_name="([^"]+)"', text))]
     for scope in ("moe_router", "moe_dispatch", "moe_experts",

@@ -1,0 +1,235 @@
+"""The program's grouped-matmul kernels (ops/pallas/grouped_matmul.py)
+against `jax.lax.ragged_dot`, on the CPU with the kernels in interpret
+mode at small shapes: value and both gradients over every kind of group
+layout, the tile rule, and the rule that picks kernel or `ragged_dot`.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from megatron_tpu.ops.pallas import grouped_matmul as gm
+
+M, K, N = 512, 256, 384
+
+# group sizes summing to M; the row tile at these shapes is 128
+LAYOUTS = {
+    "uniform": [128, 128, 128, 128],
+    "skewed": [300, 12, 150, 50],
+    "empty_group": [200, 0, 56, 256],
+    "empty_first_and_last": [0, 256, 256, 0],
+    "boundaries_off_the_tile": [1, 130, 127, 254],
+    "one_group_holds_every_row": [0, 0, 512, 0],
+    "many_small_groups": [40] * 12 + [32],
+}
+
+
+def _operands(sizes, dtype=jnp.float32, k=K, n=N):
+    keys = jax.random.split(jax.random.PRNGKey(len(sizes)), 3)
+    lhs = jax.random.normal(keys[0], (M, k), dtype)
+    rhs = jax.random.normal(keys[1], (len(sizes), k, n), dtype)
+    weight = jax.random.normal(keys[2], (M, n), dtype)
+    return lhs, rhs, weight, jnp.asarray(sizes, jnp.int32)
+
+
+@pytest.fixture
+def as_on_one_tpu(monkeypatch):
+    """`grouped_matmul` takes its kernels (interpret mode: the backend is
+    still the CPU) as it would on one TPU."""
+    monkeypatch.setattr(gm, "_one_tpu", lambda: True)
+
+
+def _value_and_grads(fn, lhs, rhs, weight):
+    """fn's value and the gradients of a scalar of it in lhs and rhs."""
+    loss = lambda a, b: jnp.sum(fn(a, b) * weight)  # noqa: E731
+    return (fn(lhs, rhs),) + jax.grad(loss, argnums=(0, 1))(lhs, rhs)
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_kernels_equal_ragged_dot_in_value_and_gradients(as_on_one_tpu,
+                                                         layout):
+    """`moe_gmm` forward, `moe_gmm` on the weights' other axis (the
+    gradient of the rows) and `moe_tgmm` (the gradient of the weights)
+    give what `lax.ragged_dot` and its own gradients give."""
+    sizes = LAYOUTS[layout]
+    lhs, rhs, weight, gs = _operands(sizes)
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda a, b: gm.grouped_matmul(a, b, gs))(lhs, rhs))
+    got = _value_and_grads(lambda a, b: gm.grouped_matmul(a, b, gs),
+                           lhs, rhs, weight)
+    want = _value_and_grads(lambda a, b: jax.lax.ragged_dot(a, b, gs),
+                            lhs, rhs, weight)
+    for g, w, what in zip(got, want, ("value", "d lhs", "d rhs")):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-4, err_msg=what)
+    for e, size in enumerate(sizes):
+        if size == 0:   # no row, no gradient: exactly zero, not nearly
+            assert not np.any(np.asarray(got[2][e])), e
+
+
+@pytest.mark.parametrize("layout", ["skewed", "empty_group"])
+def test_bf16_operands_give_bf16_results(as_on_one_tpu, layout):
+    """bf16 in, float32 accumulation, bf16 out, for the value and both
+    gradients, as `lax.ragged_dot` gives them."""
+    lhs, rhs, weight, gs = _operands(LAYOUTS[layout], jnp.bfloat16)
+    got = _value_and_grads(lambda a, b: gm.grouped_matmul(a, b, gs),
+                           lhs, rhs, weight)
+    want = _value_and_grads(lambda a, b: jax.lax.ragged_dot(a, b, gs),
+                            lhs, rhs, weight)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == jnp.bfloat16
+        np.testing.assert_allclose(g.astype(jnp.float32),
+                                   w.astype(jnp.float32),
+                                   rtol=2e-2, atol=0.25)
+
+
+@pytest.mark.parametrize("tiles", [(128, 128, 128), (256, 128, 384),
+                                   (512, 256, 128), (128, 256, 384)])
+@pytest.mark.parametrize("kernel", ["gmm", "gmm_transposed", "tgmm"])
+def test_each_kernel_at_tiles_of_several_steps(kernel, tiles):
+    """Every kernel at tiles that cut k and n into several steps (the
+    accumulator across k tiles, the row tile's visits across n tiles) and
+    at row tiles of one to four visits a group."""
+    sizes = LAYOUTS["boundaries_off_the_tile"]
+    lhs, rhs, weight, gs = _operands(sizes)
+    visits = gm.group_visits(gs, M, tiles[0])
+    if kernel == "gmm":
+        got = gm._gmm(lhs, rhs, visits, tiles, False)
+        want = jax.lax.ragged_dot(lhs, rhs, gs)
+    elif kernel == "gmm_transposed":
+        # weight [M, N] through rhs [E, K, N] on its last axis -> [M, K]
+        tm, tk, tn = tiles
+        got = gm._gmm(weight, rhs, visits, (tm, tn, tk), True)
+        want = jax.lax.ragged_dot(weight, jnp.swapaxes(rhs, 1, 2), gs)
+    else:
+        got = gm._tgmm(lhs, weight, visits, tiles)
+        want = jax.grad(lambda r: jnp.sum(
+            jax.lax.ragged_dot(lhs, r, gs) * weight))(rhs)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-4)
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("tm", [128, 256, 512])
+def test_visit_table_covers_every_row_once(layout, tm):
+    """Each row is inside exactly one visit's (tile, group) intersection,
+    visits walk the tiles and the groups in order, every group (an empty
+    one too) has a visit, and the entries past `count` repeat the last."""
+    sizes = np.asarray(LAYOUTS[layout])
+    offsets, gids, tids, count = (np.asarray(a) for a in gm.group_visits(
+        jnp.asarray(sizes, jnp.int32), M, tm))
+    count = int(count[0])
+    assert len(gids) == len(tids) == M // tm + len(sizes) - 1
+    assert count <= len(gids)
+    np.testing.assert_array_equal(offsets, np.concatenate([[0],
+                                                           sizes.cumsum()]))
+    covered = np.zeros(M, np.int32)
+    for g, t in zip(gids[:count], tids[:count]):
+        lo = max(offsets[g], t * tm)
+        hi = min(offsets[g + 1], (t + 1) * tm)
+        assert hi > lo or sizes[g] == 0, (g, t)
+        covered[lo:max(lo, hi)] += 1
+    assert (covered == 1).all()
+    assert (np.diff(gids) >= 0).all() and (np.diff(tids) >= 0).all()
+    assert set(gids[:count]) == set(range(len(sizes)))
+    assert (gids[count:] == gids[count - 1]).all()
+    assert (tids[count:] == tids[count - 1]).all()
+
+
+@pytest.mark.parametrize("m,k,n,groups", [
+    (32768, 2048, 2048, 64), (32768, 1024, 2048, 64),   # the OLMoE cell
+    (32768, 2048, 1024, 64),
+    (8192, 4096, 28672, 8), (8192, 14336, 4096, 8),     # Mixtral-shaped
+    (512, 256, 384, 4), (128, 128, 128, 1), (4096, 640, 1152, 16),
+])
+@pytest.mark.parametrize("tgmm", [False, True], ids=["gmm", "tgmm"])
+def test_picked_tiles_divide_the_shape(m, k, n, groups, tgmm):
+    tm, tk, tn = gm.pick_gmm_tiles(m, k, n, groups, tgmm=tgmm)
+    assert m % tm == 0 and k % tk == 0 and n % tn == 0, (tm, tk, tn)
+    assert tm % 128 == 0 and tk % 128 == 0 and tn % 128 == 0, (tm, tk, tn)
+    # the row tile follows the rows and the groups alone: one visit table
+    # serves every product over the same groups
+    assert tm == gm.pick_row_tile(m, groups)
+
+
+@pytest.mark.parametrize("shape,gmm,tgmm", [
+    # the OLMoE cell's products (PERF.md, PR 27): one k tile, widest tn
+    ((32768, 2048, 2048, 64), (512, 2048, 2048), (512, 1024, 2048)),
+    ((32768, 1024, 2048, 64), (512, 1024, 2048), (512, 1024, 2048)),
+    ((32768, 2048, 1024, 64), (512, 2048, 1024), (512, 2048, 1024)),
+    # Mixtral-shaped: k tiles accumulate, so half the slab
+    ((8192, 4096, 28672, 8), (512, 1024, 2048), (512, 1024, 2048)),
+    ((8192, 14336, 4096, 8), (512, 1024, 2048), (512, 1024, 2048)),
+    # groups smaller than a tile in the mean: the smallest row tile
+    ((4096, 2048, 2048, 64), (128, 2048, 2048), (128, 2048, 2048)),
+    ((16384, 2048, 2048, 64), (256, 2048, 2048), (256, 2048, 2048)),
+])
+def test_picked_tiles_are_the_swept_rule(shape, gmm, tgmm):
+    assert gm.pick_gmm_tiles(*shape) == gmm
+    assert gm.pick_gmm_tiles(*shape, tgmm=True) == tgmm
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (1, 2048, 2048),        # single-row decode through an MoE layer
+    (8, 2048, 2048),        # a decode batch
+    (4095 * 8, 2048, 2048),  # an odd row count
+    (512, 200, 384), (512, 256, 100),   # lanes that 128 does not divide
+])
+def test_declined_shapes_are_ragged_dot(as_on_one_tpu, m, k, n):
+    """Shapes the tiles do not divide are declined, and the function is
+    then `lax.ragged_dot` itself, on a TPU too."""
+    assert gm.pick_gmm_tiles(m, k, n, 4) is None
+    assert gm.pick_gmm_tiles(m, k, n, 4, tgmm=True) is None
+    lhs = jnp.zeros((m, k), jnp.float32)
+    rhs = jnp.zeros((4, k, n), jnp.float32)
+    gs = jnp.asarray([m, 0, 0, 0], jnp.int32)
+    text = str(jax.make_jaxpr(
+        lambda a, b: gm.grouped_matmul(a, b, gs))(lhs, rhs))
+    assert "ragged_dot" in text and "pallas_call" not in text
+
+
+def test_off_the_tpu_the_function_is_ragged_dot():
+    """On this backend (the CPU) nothing of the kernels is traced: same
+    primitive, same result, no visit table."""
+    lhs, rhs, _, gs = _operands(LAYOUTS["skewed"])
+    assert gm.visits_for(gs, M) is None
+    ours = jax.make_jaxpr(lambda a, b: gm.grouped_matmul(a, b, gs))(lhs, rhs)
+    plain = jax.make_jaxpr(lambda a, b: jax.lax.ragged_dot(a, b, gs))(
+        lhs, rhs)
+    assert str(ours) == str(plain)
+    assert "ragged_dot" in str(ours)
+
+
+def test_under_a_mesh_of_several_devices_the_function_is_ragged_dot(
+        monkeypatch):
+    """GSPMD cannot partition a Mosaic call: with more than one device in
+    the ambient mesh the products stay `lax.ragged_dot` on a TPU too."""
+    from jax.sharding import Mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gm._one_tpu()
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    with jax.sharding.set_mesh(mesh):
+        assert not gm._one_tpu()
+
+
+def test_one_visit_table_serves_both_products(as_on_one_tpu, monkeypatch):
+    """`visits_for` builds the layer's one table; handed to both products,
+    neither builds its own."""
+    lhs, rhs, _, gs = _operands(LAYOUTS["skewed"])
+    back = jnp.swapaxes(rhs, 1, 2)
+    built = []
+    build = gm.group_visits
+    monkeypatch.setattr(
+        gm, "group_visits",
+        lambda sizes, m, tm: built.append(tm) or build(sizes, m, tm))
+
+    def layer(shared):
+        del built[:]
+        visits = gm.visits_for(gs, M) if shared else None
+        mid = gm.grouped_matmul(lhs, rhs, gs, visits=visits)
+        return gm.grouped_matmul(mid, back, gs, visits=visits), list(built)
+
+    shared, once = layer(True)
+    apart, twice = layer(False)
+    assert once == [gm.pick_row_tile(M, len(gs))] and twice == 2 * once
+    np.testing.assert_allclose(shared, apart)
