@@ -136,7 +136,7 @@ def flash_bwd_train_step_target(
     the env knob so the CPU host traces the REAL kernel dispatch, and the
     audited gradient path is the custom-vjp recompute backward — the
     pallas calls sit visibly in the jaxpr (asserted in
-    tests/test_analysis.py; bench.py gates on the same fact) instead of
+    tests/test_analysis.py) instead of
     an XLA-generated O(S^2) attention gradient. Not part of
     contracts.CONFIGS: pallas_call bodies hide their innards from the
     jaxpr collective walk, so the golden-manifest ledger keeps auditing

@@ -16,6 +16,7 @@ from typing import Optional, Sequence
 
 from megatron_tpu.config import (
     ModelConfig, OptimizerConfig, ParallelConfig, RunConfig, TrainingConfig,
+    model_config_from_saved,
 )
 
 
@@ -148,15 +149,6 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
                         "logits (0 = unchunked full [B,S,V] logits)")
     g.add_argument("--use_flash_attn", action="store_true",
                    help="ref alias for --attention_impl pallas")
-    g.add_argument("--flash_bwd", dest="flash_bwd", action="store_true",
-                   default=True,
-                   help="fused flash fwd+bwd kernel for full-sequence "
-                        "attention under --attention_impl pallas "
-                        "(default on)")
-    g.add_argument("--no_flash_bwd", dest="flash_bwd", action="store_false",
-                   help="escape hatch: keep the flash forward off the "
-                        "gradient path and pay the XLA O(S^2) attention "
-                        "gradient (loudly logged)")
     g.add_argument("--exit_signal_handler", action="store_true",
                    default=True,
                    help="SIGTERM checkpoint-and-exit is always enabled here")
@@ -559,7 +551,6 @@ def args_to_run_config(args) -> RunConfig:
         overrides["attention_dropout"] = args.attention_dropout
         overrides["lima_dropout"] = args.lima_dropout
         overrides["attention_impl"] = args.attention_impl
-        overrides["flash_bwd"] = args.flash_bwd
         overrides["ce_chunk_size"] = args.ce_chunk_size
         overrides["params_dtype"] = _dtype_name(args)
         overrides.update(_fp8_overrides(args))
@@ -612,7 +603,6 @@ def args_to_run_config(args) -> RunConfig:
             init_method_std=args.init_method_std,
             params_dtype=_dtype_name(args),
             attention_impl=args.attention_impl,
-            flash_bwd=args.flash_bwd,
             ce_chunk_size=args.ce_chunk_size,
             **_fp8_overrides(args),
         ).validate()
@@ -760,7 +750,7 @@ def _model_config_from_checkpoint(load: str, iteration=None):
         saved = json.load(f).get("config", {})
     if "model" not in saved:
         return None
-    return ModelConfig(**saved["model"]).validate()
+    return model_config_from_saved(saved["model"]).validate()
 
 
 def _dtype_name(args) -> str:

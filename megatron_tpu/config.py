@@ -181,12 +181,6 @@ class ModelConfig:
     # (falls back to xla for unsupported shapes), or "ring" context-parallel
     # ring attention (requires an ambient mesh with a "context" axis).
     attention_impl: str = "xla"
-    # route full-sequence attention through the flash template's
-    # custom-vjp kernel (ops/pallas/flash_template.py) so training never
-    # pays the XLA-generated O(S^2) attention gradient; --no_flash_bwd
-    # is the escape hatch (dense gradient, loudly logged). Only
-    # meaningful under attention_impl="pallas".
-    flash_bwd: bool = True
 
     # BERT-style extras (ref: megatron/model/bert_model.py,
     # language_model.py Embedding tokentype path)
@@ -305,6 +299,18 @@ class ModelConfig:
 # ---------------------------------------------------------------------------
 # parallel topology
 # ---------------------------------------------------------------------------
+
+
+# Fields a checkpoint's meta.json may still hold that ModelConfig no longer
+# has. Exactly these are dropped on load; any other unknown key still
+# raises TypeError.
+RETIRED_MODEL_FIELDS = ("flash_bwd",)
+
+
+def model_config_from_saved(saved: dict) -> ModelConfig:
+    """ModelConfig from the "model" dict of a saved run config."""
+    return ModelConfig(**{k: v for k, v in saved.items()
+                          if k not in RETIRED_MODEL_FIELDS})
 
 
 @dataclass(frozen=True)
@@ -721,7 +727,7 @@ class RunConfig:
     @staticmethod
     def from_dict(d: dict) -> "RunConfig":
         return RunConfig(
-            model=ModelConfig(**d["model"]),
+            model=model_config_from_saved(d["model"]),
             parallel=ParallelConfig(**d["parallel"]),
             optimizer=OptimizerConfig(**d["optimizer"]),
             training=TrainingConfig(**{k: (tuple(v) if k == "rampup_batch_size" and v else v)

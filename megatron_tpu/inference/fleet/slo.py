@@ -1,7 +1,7 @@
 """Traffic-replay SLO harness: offered load in, latency percentiles out.
 
-Serving claims need the same discipline training claims got from bench.py:
-measured percentiles under a FIXED OFFERED LOAD, not anecdotes. An
+Serving claims need the discipline training claims get from the
+benchmark: measured percentiles under a FIXED OFFERED LOAD, not anecdotes. An
 open-loop replay (requests fire at their scheduled times whether or not
 earlier ones returned — the "millions of users" arrival model) is the
 honest one: a closed loop would slow its own arrival rate exactly when the
@@ -15,8 +15,8 @@ scraped before and after the window and DIFFED, so warmup compiles and
 unrelated traffic fall out; client-side wall-time percentiles ride along
 as the end-to-end view (router retries included).
 
-Used by tools/slo_harness.py (CLI: attach to a live fleet or spawn one)
-and bench.py's `serve_slo_offered_load` line. Pure host code — no jax.
+Used by tools/slo_harness.py (CLI: attach to a live fleet or spawn one).
+Pure host code — no jax.
 """
 
 from __future__ import annotations

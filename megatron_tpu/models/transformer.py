@@ -317,7 +317,6 @@ def attention_block(
             softmax_fp32=cfg.softmax_fp32,
             kv_lengths=kv_lengths,
             page_table=page_table,
-            flash_bwd=cfg.flash_bwd,
         )
     if tp_comm is not None and "attn_out" in tp_comm.sites:
         # explicit row-parallel reduction (dense psum or the compressed

@@ -45,7 +45,7 @@ def _kernels_dispatchable() -> bool:
     real hardware always; CPU hosts only when interpret mode is forced
     (MEGATRON_TPU_FLASH_INTERPRET=1 — the interpreter is orders of
     magnitude slower than fused XLA, so CPU sanity runs must not pay it;
-    tests/bench set the env var to trace/verify the kernel path)."""
+    tests set the env var to trace/verify the kernel path)."""
     if jax.default_backend() != "cpu":
         return True
     from megatron_tpu.ops.pallas.flash_template import interpret_forced
@@ -154,7 +154,6 @@ def attention(
     softmax_fp32: bool = True,
     kv_lengths: Optional[jnp.ndarray] = None,  # [B] valid-prefix lengths
     page_table: Optional[jnp.ndarray] = None,  # [B, max_pages] int32
-    flash_bwd: bool = True,
 ) -> jnp.ndarray:
     """Scaled dot-product attention with GQA. Returns [B, Sq, Hq, D].
 
@@ -167,7 +166,7 @@ def attention(
     (k_pos < kv_lengths + j) and the sliding window becomes
     k_pos >= kv_lengths + j - window. Sq == 1 is plain decode; Sq > 1 is
     the speculative multi-token verify. On TPU under impl="pallas" this
-    runs the fused flash-decode kernels (ops/pallas/flash_decode.py,
+    runs the fused flash-decode kernels (flash_template.flash_decode,
     single- and multi-query variants) which skip cache blocks past each
     row's prefix; elsewhere a masked einsum computes the same values.
 
@@ -175,16 +174,10 @@ def attention(
     page pools [num_pages, page_size, Hkv, D] and each row's logical
     context is page_table[b] physical pages. With kv_lengths (single-token
     decode) the TPU path is the paged flash-decode kernel
-    (ops/pallas/paged_flash_decode.py) which resolves pages inside the
+    (flash_template.paged_flash_decode) which resolves pages inside the
     grid; everywhere else the pages are gathered into a dense [B, S, ...]
     view and the existing masked paths compute identical values (the
     gather is exact — pages hold the same bits a dense cache would).
-
-    flash_bwd: route full-sequence causal attention through the
-    template's custom-vjp kernel so jax.grad never builds the XLA
-    O(S^2) gradient (config.flash_bwd / --no_flash_bwd). False skips
-    the kernel for differentiable full-sequence passes — decode paths
-    (no gradient) still use the fused kernels.
     """
     if page_table is not None:
         if (kv_lengths is not None
@@ -288,32 +281,22 @@ def attention(
             and mask_type == "causal"
             and _kernels_dispatchable()
         )
-        if can_use and not flash_bwd:
-            # escape hatch (--no_flash_bwd): deliberate, but still loud —
-            # the step now pays the XLA-generated O(S^2) attention
-            # gradient, which is the regression flash_bwd exists to stop
-            warnings.warn(
-                "flash_bwd disabled: full-sequence attention (and its "
-                "gradient) runs on the O(S^2) XLA path", stacklevel=2)
-            can_use = False
         if can_use:
             can_use, plan = _shard_plan("full sequence", q.shape[0],
                                           k.shape[2])
         if can_use:
-            from megatron_tpu.ops.pallas.flash_attention import (
-                flash_attention,
-            )
+            from megatron_tpu.ops.pallas import flash_template as ft
 
             # a geometry the template cannot instantiate raises here: a
             # step asked to train on the kernel never trains on the XLA
             # O(S^2) attention gradient instead
             return _per_shard(
                 plan,
-                functools.partial(flash_attention,
+                functools.partial(ft.flash_mha,
                                   sliding_window=sliding_window),
                 (q, k, v))
         # the XLA path below serves what the kernel does not cover
-        # (q_len != kv_len, padding masks, dropout, --no_flash_bwd)
+        # (q_len != kv_len, padding masks, dropout)
 
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape

@@ -9,7 +9,7 @@ is the one op where a hand-written blockwise kernel beats the compiler.
 Attention is ONE kernel family (flash_template.py, mask/block-skip
 predicates in masks.py): training/prefill fwd + custom-vjp recompute bwd,
 decode as the Sq-small specialization, page-table indirection / sliding
-window / kv_lengths masking / multi-query tiling as template knobs.
-flash_attention.py, flash_decode.py and paged_flash_decode.py are the
-stable import points for the instantiations.
+window / kv_lengths masking / multi-query tiling as template knobs;
+callers import the instantiations from flash_template itself. The MoE
+experts' grouped matmuls are grouped_matmul.py.
 """

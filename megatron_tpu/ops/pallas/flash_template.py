@@ -94,7 +94,8 @@ def _interpret() -> bool:
 def interpret_forced() -> bool:
     """True when the dispatcher should use the kernels EVEN on a CPU host
     (interpreter mode — orders of magnitude slower than fused XLA, so
-    only tests/bench set this; see ops/attention.py)."""
+    only tests and the benchmark's rehearsals set this; see
+    ops/attention.py)."""
     return os.environ.get("MEGATRON_TPU_FLASH_INTERPRET", "") not in ("", "0")
 
 
@@ -544,6 +545,35 @@ def _flash_bwd_rule(scale, causal, window, block_q, block_k, res, do):
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
+# one stripe pair of a ring schedule (ops/ring_attention.py): the forward
+# and backward above without the custom_vjp, which the ring owns
+# ---------------------------------------------------------------------------
+
+
+def stripe_fwd(q, k, v, delta, window, scale, block, causal=True):
+    """(o float32, lse [B, H, c]) for one stripe pair, [B, H, c, D] layout
+    (k/v already group-broadcast). ONE kernel covers every stripe
+    relation: `delta` (traced, an SMEM scalar inside the kernel) is the
+    q-vs-k global-position offset, so the causal mask k <= q + delta
+    renders the aligned diagonal (delta 0), fully-visible past blocks
+    (delta >= c) and shifted sliding-window bands alike. causal=False =
+    fully-visible blocks (bidirectional contiguous ring). A fully-masked
+    row reports lse at masks.NEG_INF depth, finite."""
+    o, lse = _fwd(q, k, v, scale, causal, window, block, block, delta=delta)
+    return o.astype(jnp.float32), lse[..., 0]
+
+
+def stripe_bwd(q, k, v, o, lse, do, delta, window, scale, block,
+               causal=True):
+    """(dq, dk, dv) for one stripe pair given the GLOBAL lse [B, H, c]
+    (the FA-2 recompute scheme: p = exp(s - lse_global), so per-stripe
+    gradients sum to the exact dense gradient)."""
+    lse128 = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
+    return _bwd(q, k, v, o, lse128, do, scale, causal, window, block, block,
+                offset=delta)
 
 
 def flash_mha(

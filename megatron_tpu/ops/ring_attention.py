@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from megatron_tpu.ops.pallas import flash_template as ft
+from megatron_tpu.ops.pallas.masks import NEG_INF
 from megatron_tpu.parallel.mesh import AXIS_CONTEXT
 
 
@@ -230,7 +232,7 @@ def ring_attention(
 # The einsum inner step above materializes fp32 scores
 # [B, Hkv, G, Sq_local, Skv_local] every ring hop. This path replaces each
 # stripe-level einsum with the in-tree Pallas flash kernel
-# (ops/pallas/flash_attention.py), whose VMEM-blocked online softmax never
+# (ops/pallas/flash_template.py), whose VMEM-blocked online softmax never
 # materializes a score buffer. ONE kernel covers every stripe pair: the
 # q-vs-k global-position offset rides into the kernel as an SMEM scalar
 # (`delta`), so the causal mask k <= q + delta renders the aligned
@@ -250,14 +252,12 @@ def _merge_normalized(st, o_i, lse_i):
     """Merge a block's (normalized out, lse) into the running pair.
 
     The kernel reports fully-masked rows with a finite ~-1e30 lse sentinel
-    (flash_attention._NEG_INF); clamp anything at sentinel depth to -inf so
+    (masks.NEG_INF); clamp anything at sentinel depth to -inf so
     such rows carry ZERO merge weight no matter which hop merges first —
     correctness must not depend on the diagonal/past hop preceding
     fully-masked ones (ADVICE r4)."""
-    from megatron_tpu.ops.pallas.flash_attention import _NEG_INF
-
     out, lse = st
-    lse_i = jnp.where(lse_i <= _NEG_INF / 2, -jnp.inf, lse_i)
+    lse_i = jnp.where(lse_i <= NEG_INF / 2, -jnp.inf, lse_i)
     m = jnp.maximum(lse, lse_i)
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
     w_old = jnp.where(jnp.isfinite(lse), jnp.exp(lse - m_safe), 0.0)
@@ -276,37 +276,11 @@ def _rep_bhsd(x, groups):
     return jnp.repeat(xt, groups, axis=1) if groups > 1 else xt
 
 
-def _stripe_fwd(q, k, v, delta, window, scale, block, causal=True):
-    """(o, lse) for one stripe pair, [B, H, c, D] layout. ONE kernel
-    covers every stripe relation: `delta` (traced, an SMEM scalar inside
-    the kernel) is the q-vs-k global-position offset, so the causal mask
-    k <= q + delta renders the aligned diagonal (delta 0), fully-visible
-    past blocks (delta >= c) and shifted sliding-window bands alike.
-    causal=False = fully-visible blocks (bidirectional contiguous ring)."""
-    from megatron_tpu.ops.pallas import flash_attention as fa
-
-    o, lse = fa._fwd(q, k, v, scale, causal, window, block, block,
-                     delta=delta)
-    return o.astype(jnp.float32), lse[..., 0]
-
-
-def _stripe_bwd(q, k, v, o, lse, do, delta, window, scale, block,
-                causal=True):
-    """(dq, dk, dv) for one stripe pair given the GLOBAL lse."""
-    from megatron_tpu.ops.pallas import flash_attention as fa
-
-    lse128 = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
-    return fa._bwd(q, k, v, o, lse128, do, scale, causal, window,
-                   block, block, offset=delta)
-
-
 def _pick_stripe_block(c: int) -> int:
     """Largest tier the stripe length supports (same tiering as the
     kernel's own _pick_block), falling back to c itself for the tiny
     shapes CPU interpret tests force through."""
-    from megatron_tpu.ops.pallas.flash_attention import _pick_block
-
-    return _pick_block(c) or c
+    return ft._pick_block(c) or c
 
 
 def _zigzag_window_pred(w: Optional[int], c: int, k_stripe, q_stripe):
@@ -340,7 +314,7 @@ def _zigzag_flash_fwd_impl(q, k, v, axis_name, block, window):
     def guarded_merge(pred, st, qs, ks, vs, delta):
         def do(st):
             return _merge_normalized(
-                st, *_stripe_fwd(qs, ks, vs, delta, window, scale, block))
+                st, *ft.stripe_fwd(qs, ks, vs, delta, window, scale, block))
 
         if pred is True:
             return do(st)
@@ -417,7 +391,7 @@ def _make_zigzag_flash(axis_name: str, block: int,
 
         def guarded_bwd(pred, qs, ks, vs, os_, lses, dos, delta):
             def run():
-                return _stripe_bwd(qs, _rep_bhsd(ks, groups),
+                return ft.stripe_bwd(qs, _rep_bhsd(ks, groups),
                                    _rep_bhsd(vs, groups), os_, lses, dos,
                                    delta, window, scale, block)
 
@@ -500,7 +474,7 @@ def _contig_flash_fwd_impl(q, k, v, axis_name, block, causal):
         delta = (my - src) * sq  # only read when causal
 
         def run():
-            return _stripe_fwd(qt, kb, vb, delta if causal else 0,
+            return ft.stripe_fwd(qt, kb, vb, delta if causal else 0,
                                None, scale, block, causal=causal)
 
         if causal:
@@ -562,7 +536,7 @@ def _make_contig_flash(axis_name: str, block: int, causal: bool):
             delta = (my - src) * sq
 
             def run():
-                return _stripe_bwd(
+                return ft.stripe_bwd(
                     qt, _rep_bhsd(kc, groups), _rep_bhsd(vc, groups), ot,
                     lse, dt, delta if causal else 0, None, scale, block,
                     causal=causal)
@@ -644,13 +618,11 @@ def ring_attention_sharded(
     S = q.shape[1]
     if mask_type == "causal" and cp > 1 and S % (2 * cp) == 0:
         c = S // (2 * cp)
-        from megatron_tpu.ops.pallas.flash_attention import _interpret
-
         if inner_impl is None or inner_impl == "auto":
-            use_flash = c % 128 == 0 and not _interpret()
+            use_flash = c % 128 == 0 and not ft._interpret()
         else:
             use_flash = inner_impl == "flash"
-        if use_flash and c % 128 != 0 and not _interpret():
+        if use_flash and c % 128 != 0 and not ft._interpret():
             # a forced flash request must fail loudly, not with an opaque
             # Mosaic tiling error from a block == stripe fallback
             raise ValueError(
@@ -683,10 +655,8 @@ def ring_attention_sharded(
     # the einsum (zig-zag owns the windowed kernel path for even shapes).
     contig_flash_ok = cp > 1 and S % cp == 0 and sliding_window is None
     if inner_impl is None or inner_impl == "auto":
-        from megatron_tpu.ops.pallas.flash_attention import _interpret
-
         use_flash = (contig_flash_ok and (S // cp) % 128 == 0
-                     and not _interpret())
+                     and not ft._interpret())
     else:
         use_flash = inner_impl == "flash"
     if use_flash and not contig_flash_ok:

@@ -90,10 +90,11 @@ def require_tpu() -> dict:
 
 
 # Peak dense bf16 FLOP/s by jax device_kind (public spec sheets; v5e:
-# Google Cloud documentation "TPU v5e", 197 TFLOP/s). Single source for
-# the MFU denominator in bench.py / tools/bench_sweep.py. Keyed on the
-# exact string the runtime reports, lower-cased: a kind that is not here
-# is an error, never a default.
+# Google Cloud documentation "TPU v5e", 197 TFLOP/s). chip_smoke.py's
+# device gate asks it; the benchmark keeps its own table
+# (benchmark/peaks.json, ROADMAP D13). Keyed on the exact string the
+# runtime reports, lower-cased: a kind that is not here is an error, never
+# a default.
 _PEAK_BF16 = {
     "tpu v4": 275e12,
     "tpu v5 lite": 197e12,   # v5e

@@ -1,11 +1,11 @@
 """Runtime trace analysis: xplane profiler ingestion (ROADMAP item 2).
 
-``jax.profiler`` (the train loop's ``--profile`` window, bench's
-``MEGATRON_TPU_PROFILE_DIR``, the serving ``/admin/profile`` endpoint)
-writes ``*.xplane.pb`` protobufs — the XSpace/XPlane schema shared by
-XLA on every backend. This package reads them with ZERO non-stdlib
-imports and turns the op events into the runtime half of the comm
-measurement story the golden contracts (``analysis/``) pin statically:
+``jax.profiler`` (the train loop's ``--profile`` window, a SIGUSR1
+window, the serving ``/admin/profile`` endpoint) writes ``*.xplane.pb``
+protobufs — the XSpace/XPlane schema shared by XLA on every backend. This
+package reads them with ZERO non-stdlib imports and turns the op events
+into the runtime half of the comm measurement story the golden contracts
+(``analysis/``) pin statically:
 
   * ``proto``   — minimal protobuf wire-format decoder (varint/fixed/
                   length-delimited), schema-free;

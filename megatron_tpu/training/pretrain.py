@@ -191,12 +191,9 @@ class TrainLoop:
         model_cfg = run_cfg.model
         if model_cfg.attention_impl == "pallas":
             # one line at startup so the gradient path is never a mystery
-            # in the log: flash_bwd on = the template's custom-vjp
-            # kernels, off = the XLA-generated O(S^2) attention gradient
+            # in the log (chip_smoke.py checks for it)
             self.log("attention: pallas flash template, "
-                     + ("fused fwd+bwd (custom vjp)" if model_cfg.flash_bwd
-                        else "fwd only — XLA O(S^2) attention gradient "
-                        "(--no_flash_bwd)"))
+                     "fused fwd+bwd (custom vjp)")
         E = model_cfg.num_experts
         if E is not None and E % self.rt.ep:
             raise ValueError(

@@ -3,6 +3,8 @@ defaults that scripts rely on)."""
 
 import os
 
+import pytest
+
 from megatron_tpu.arguments import args_to_run_config, parse_args
 
 BASE = ["--num_layers", "2", "--hidden_size", "32",
@@ -45,3 +47,53 @@ def test_wandb_api_key_exported(monkeypatch):
     monkeypatch.setenv("WANDB_API_KEY", "preexisting")
     args_to_run_config(parse_args(BASE + ["--wandb_api_key", "k-other"]))
     assert os.environ["WANDB_API_KEY"] == "preexisting"
+
+
+def _saved_run_config(tmp_path, **extra_model_keys):
+    """(run-config dict, checkpoint root) as a run before PR 28 left them:
+    meta.json's "config" with extra keys in its "model" dict."""
+    import json
+
+    from megatron_tpu.training.checkpointing import checkpoint_dir
+
+    saved = args_to_run_config(parse_args(BASE)).to_dict()
+    saved["model"].update(extra_model_keys)
+    ckpt = checkpoint_dir(str(tmp_path), 1)
+    os.makedirs(ckpt)
+    with open(os.path.join(ckpt, "meta.json"), "w") as f:
+        json.dump({"config": saved}, f)
+    with open(os.path.join(str(tmp_path),
+                           "latest_checkpointed_iteration.txt"), "w") as f:
+        f.write("1")
+    return saved, str(tmp_path)
+
+
+def _load_run_config(saved, root):
+    from megatron_tpu.config import RunConfig
+
+    return RunConfig.from_dict(saved).model
+
+
+def _load_checkpoint_args(saved, root):
+    from megatron_tpu.arguments import _model_config_from_checkpoint
+
+    return _model_config_from_checkpoint(root)
+
+
+@pytest.mark.parametrize("load, extra, ok", [
+    (_load_run_config, {"flash_bwd": False}, True),
+    (_load_checkpoint_args, {"flash_bwd": True}, True),
+    (_load_run_config, {"flash_fwd": True}, False),
+    (_load_checkpoint_args, {"flash_fwd": True}, False),
+], ids=["from_dict-retired", "use_checkpoint_args-retired",
+        "from_dict-unknown", "use_checkpoint_args-unknown"])
+def test_saved_model_config_loads_without_retired_fields(tmp_path, load,
+                                                         extra, ok):
+    """A meta.json written while ModelConfig still had `flash_bwd` loads
+    through both loaders; a key that was never a field still raises."""
+    saved, root = _saved_run_config(tmp_path, **extra)
+    if ok:
+        assert load(saved, root) == args_to_run_config(parse_args(BASE)).model
+    else:
+        with pytest.raises(TypeError, match="flash_fwd"):
+            load(saved, root)

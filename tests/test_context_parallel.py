@@ -347,12 +347,11 @@ def _logprob_atol(cell: str) -> float:
     return 5e-3 if "int8" in cell else 1e-5
 
 
-# tier-1 keeps the two NEW geometries' default transport (the tentpole
-# gates); the other cells ride the slow suite — serial-ring parity also
-# runs inside tier-1's bench line (serve_cp_overlap A/Bs serial vs
-# overlapped with greedy-parity gates), and int8 transport keeps its
-# tier-1 roundtrip/jaxpr units above. The 870s suite budget is why.
-_TIER1_CELLS = ("2d_dense", "ring_overlap_dense")
+# tier-1 keeps the dense transport of every schedule and geometry: the
+# serial ring, the overlapped ring (the default) and the 2D geometry;
+# the int8 transports ride the slow suite and keep their tier-1
+# roundtrip/jaxpr units above.
+_TIER1_CELLS = ("2d_dense", "ring_overlap_dense", "ring_serial_dense")
 
 
 def _matrix_cells():
@@ -435,6 +434,27 @@ def test_cp_matrix_preempt_resume_parity(cp_setup, matrix_cache, cell):
     assert req.generated == want.generated
     np.testing.assert_allclose(req.logprobs, want.logprobs,
                                atol=_logprob_atol(cell), rtol=0)
+
+
+def test_cp_overlap_moves_the_serial_rings_hops_and_bytes(matrix_cache):
+    """Overlap moves exposed time, never traffic. Statically: the
+    committed decode_cp2_overlap golden carries EXACTLY the serial
+    ring's (decode_tp2_cp2) ppermute rows. At run time (order-dependent
+    on the matrix scenarios above, which gave both engines the same
+    requests): equal ring steps and equal bytes on the wire."""
+    from megatron_tpu.analysis import contracts
+
+    def ppermute_rows(name):
+        rows = contracts.load_manifest(name)["jaxpr"]["collectives"]
+        return {k: (v["count"], v["total_wire_bytes"])
+                for k, v in rows.items() if k.startswith("ppermute")}
+
+    rows = ppermute_rows("decode_cp2_overlap")
+    assert rows and rows == ppermute_rows("decode_tp2_cp2")
+    serial = matrix_cache["ring_serial_dense"].stats
+    over = matrix_cache["ring_overlap_dense"].stats
+    assert serial["cp_ring_steps"] == over["cp_ring_steps"] > 0
+    assert serial["cp_comm_dense_bytes"] == over["cp_comm_dense_bytes"] > 0
 
 
 def test_cp_matrix_zero_decode_recompiles(matrix_cache):

@@ -1,5 +1,6 @@
 """Pallas flash-attention kernel vs the XLA einsum path (interpret mode on
-the CPU suite; the same kernels compile for real on TPU — see bench.py)."""
+the CPU suite; the same kernels compile for a described v5e in
+tests/test_chip_compile.py and run on the chip in every benchmark cell)."""
 
 import functools
 
@@ -9,7 +10,7 @@ import numpy as np
 import pytest
 
 from megatron_tpu.ops.attention import attention
-from megatron_tpu.ops.pallas.flash_attention import flash_attention, supported
+from megatron_tpu.ops.pallas.flash_template import flash_mha, supported
 
 RNG = np.random.default_rng(7)
 
@@ -24,7 +25,7 @@ def _qkv(b=1, s=256, hq=4, hkv=2, d=64):
 @pytest.mark.parametrize("window", [None, 64])
 def test_flash_forward_matches_xla(window):
     q, k, v = _qkv()
-    got = flash_attention(q, k, v, sliding_window=window,
+    got = flash_mha(q, k, v, sliding_window=window,
                           block_q=128, block_k=128)
     want = attention(q, k, v, sliding_window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -33,7 +34,7 @@ def test_flash_forward_matches_xla(window):
 
 def test_flash_mha_no_gqa():
     q, k, v = _qkv(hq=4, hkv=4)
-    got = flash_attention(q, k, v, block_q=128, block_k=128)
+    got = flash_mha(q, k, v, block_q=128, block_k=128)
     want = attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
@@ -43,7 +44,7 @@ def test_flash_grads_match_xla():
     q, k, v = _qkv(s=256, hq=2, hkv=1, d=64)
 
     def f_flash(q, k, v):
-        return jnp.sum(jnp.square(flash_attention(q, k, v, block_q=128,
+        return jnp.sum(jnp.square(flash_mha(q, k, v, block_q=128,
                                                   block_k=128)))
 
     def f_ref(q, k, v):
@@ -63,7 +64,7 @@ def test_supported_predicate_and_rejection():
     assert not supported(512, 256, 128, 128)
     q, k, v = _qkv(s=200)
     with pytest.raises(ValueError, match="flash kernel"):
-        flash_attention(q[:, :200], k[:, :200], v[:, :200],
+        flash_mha(q[:, :200], k[:, :200], v[:, :200],
                         block_q=128, block_k=128)
 
 
@@ -78,35 +79,6 @@ def test_model_dispatch_falls_back_cleanly():
     # decode shape (q_len != kv_len) silently uses XLA
     out2 = attention(q[:, :1], k, v, impl="pallas", q_offset=255)
     assert out2.shape == (1, 1, 4, 64)
-
-
-@pytest.mark.parametrize("window", [None, 64])
-def test_splash_path_matches_xla_gqa(window):
-    """The TPU dispatch path (splash MQA kernel): GQA with grouped — not
-    replicated — K/V, causal and sliding-window masks."""
-    from megatron_tpu.ops.pallas.flash_attention import _splash_attention
-
-    q, k, v = _qkv(s=256, hq=4, hkv=2, d=128)
-    qt, kt, vt = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
-    got = jnp.transpose(_splash_attention(qt, kt, vt, True, window),
-                        (0, 2, 1, 3))
-    want = attention(q, k, v, sliding_window=window)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-3, atol=2e-3)
-
-
-def test_splash_path_grads_finite():
-    from megatron_tpu.ops.pallas.flash_attention import _splash_attention
-
-    q, k, v = _qkv(s=256, hq=2, hkv=1, d=128)
-    qt, kt, vt = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k, v))
-
-    def f(qt, kt, vt):
-        return jnp.sum(jnp.square(_splash_attention(qt, kt, vt, True, 64)))
-
-    grads = jax.grad(f, argnums=(0, 1, 2))(qt, kt, vt)
-    for g in grads:
-        assert np.isfinite(np.asarray(g)).all()
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +103,7 @@ def _bf16_case(window, hkv):
         return attention(q, k, v, sliding_window=window)
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, sliding_window=window,
+        return flash_mha(q, k, v, sliding_window=window,
                                block_q=128, block_k=128)
 
     want_o = ref(f32(q), f32(k), f32(v))

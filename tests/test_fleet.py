@@ -47,7 +47,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 import pytest
 
 from megatron_tpu.inference.fleet import scrape, slo
-from megatron_tpu.inference.fleet.router import ReplicaRouter
+from megatron_tpu.inference.fleet.router import ReplicaRouter, RouterServer
 from megatron_tpu.telemetry.metrics import MetricsRegistry
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -582,6 +582,27 @@ def test_drain_and_readmit_over_http(fleet_service):
     assert _get(url, "/readyz")[0] == 200
     assert _post(url, "/api", {"prompts": ["3 4"],
                                "tokens_to_generate": 2})[0] == 200
+
+
+def test_slo_replay_through_router_completes_every_request(fleet_service):
+    """The open-loop replay (fleet/slo.py) against a live front door: a
+    router over the one in-process replica. Every request completes and
+    the engine's histograms fill the percentile blocks — counts and
+    presence, never a latency."""
+    svc, url = fleet_service
+    svc.warmup()
+    router = RouterServer([url]).start()
+    try:
+        trace = slo.make_trace(8, 16.0, vocab=CFG.vocab_size, new_tokens=4)
+        report = slo.run_slo(router.url + "/api", [url + "/metrics"], trace,
+                             16.0, timeout=60.0)
+    finally:
+        router.close()
+    assert report["failed"] == 0 and report["scrape_errors"] == 0
+    assert report["completed"] == report["requests"] == 8
+    for key in ("ttft_s", "tpot_s", "client_wall_s"):
+        assert set(report[key]) >= {"p50", "p95", "p99"}
+        assert all(v == v and v >= 0 for v in report[key].values())
 
 
 def test_injected_admission_rejection_maps_503(fleet_service, monkeypatch):
@@ -1194,19 +1215,28 @@ def test_chaos_failover_speculating_replica(tmp_path):
         r1.close()
 
 
-@pytest.mark.slow  # ~60s: two in-process engine compiles + a ~6s replay;
-# the SLO math itself is tier-1 (test_slo_trace_deterministic...)
-def test_serve_slo_bench_line_reports_percentiles():
-    import bench
+@pytest.mark.slow  # ~60s: two subprocess replica warmups + a ~6s replay;
+# the SLO math and a one-replica live replay are tier-1
+# (test_slo_trace_deterministic..., test_slo_replay_through_router...)
+def test_slo_harness_spawned_fleet_reports_percentiles():
+    """tools/slo_harness.py --spawn 2: the open-loop trace through a
+    router over two replica processes; every request completes and the
+    percentile blocks are populated."""
+    import importlib.util
 
-    line = bench.serve_slo_bench(time.perf_counter() + 240)
-    assert "error" not in line, line
-    d = line["detail"]
-    assert d["failed"] == 0 and d["completed"] == d["requests"]
-    assert line["value"] > 0
+    spec = importlib.util.spec_from_file_location(
+        "slo_harness", os.path.join(REPO, "tools", "slo_harness.py"))
+    harness = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(harness)
+    report = harness.run_spawned(harness.parse_args(
+        ["--spawn", "2", "--requests", "18", "--offered_rps", "3",
+         "--new_tokens", "8"]))
+    assert report["failed"] == 0
+    assert report["completed"] == report["requests"] == 18
+    assert report["achieved_rps"] > 0
     for key in ("ttft_s", "tpot_s", "client_wall_s"):
         for q in ("p50", "p95", "p99"):
-            v = d[key][q]
+            v = report[key][q]
             assert v == v and v >= 0, (key, q, v)  # finite, not NaN
 
 

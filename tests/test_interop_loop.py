@@ -87,8 +87,8 @@ def test_full_conversion_loop(tiny_hf_llama, tmp_path):
 # test_verify_correctness_in_memory keeps torch-parity coverage in tier-1
 def test_training_parity_vs_torch_adamw(tiny_hf_llama):
     """N optimizer steps here track N steps of torch AdamW on identical
-    init/data/hyperparams (BASELINE.json loss-curve north star; VERDICT r4
-    next-round #2). Gates: per-step loss delta and final param max-abs
+    init/data/hyperparams (the loss curve matching the reference's; VERDICT
+    r4 next-round #2). Gates: per-step loss delta and final param max-abs
     delta, both at fp32."""
     out = _run([os.path.join(REPO, "verify_correctness.py"),
                 "--model", tiny_hf_llama, "--train_iters", "12",

@@ -178,7 +178,7 @@ def test_paged_flash_decode_matches_gather_reference():
     sliding window."""
     import jax.numpy as jnp
 
-    from megatron_tpu.ops.pallas.paged_flash_decode import paged_flash_decode
+    from megatron_tpu.ops.pallas.flash_template import paged_flash_decode
 
     rng = np.random.default_rng(0)
     B, P, ps, Hq, Hkv, D = 3, 9, 8, 4, 2, 16
@@ -217,7 +217,7 @@ def test_paged_flash_decode_matches_gather_reference():
 def test_paged_flash_decode_rejects_bad_shapes():
     import jax.numpy as jnp
 
-    from megatron_tpu.ops.pallas.paged_flash_decode import paged_flash_decode
+    from megatron_tpu.ops.pallas.flash_template import paged_flash_decode
 
     q = jnp.zeros((2, 1, 4, 8))
     kp = jnp.zeros((4, 8, 2, 8))

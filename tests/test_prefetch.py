@@ -10,8 +10,7 @@ watermark. Subprocess kill/resume coverage rides in test_resilience.py
 (those runs exercise the async loop by default since this PR).
 
 Also covered: the steady-state sync-freedom invariant (exactly one
-blocking host transfer per step, zero recompiles after warmup), the
-injected-data-stall recovery micro-bench (bench.async_loop_bench), and
+blocking host transfer per step, zero recompiles after warmup) and
 the warm-compilation-cache assertion (second process start pays the
 goodput `compile` bucket from the cache, asserted via the recompile
 tracker's cache-hit counters).
@@ -295,65 +294,12 @@ def test_steady_state_sync_freedom_and_zero_recompiles(tmp_path):
     for e in steps[1:]:
         assert "compiles" not in e, e
     # steady-state pops come from a full double-buffer: data_wait ~ 0
-    # (in-memory iterator here, so even the first pop is cheap; the
-    # stall-recovery numbers live in test_async_loop_recovers_data_stall)
+    # (in-memory iterator here, so even the first pop is cheap)
     for e in steps[2:]:
         assert e["data_wait_ms"] < 50.0, e
     # the host-sync counter is exported for scraping too
     reg = loop.telemetry.metrics
     assert reg.get("train_host_syncs_total").value() - before == 8
-
-
-# ---------------------------------------------------------------------------
-# injected-data-stall recovery (the ISSUE acceptance micro-bench)
-
-
-@pytest.mark.slow  # single-device subprocess bench: ~21s on the 2-core host
-def test_async_loop_recovers_injected_data_stall():
-    """Acceptance: with a 20 ms/step injected host data stall the async
-    loop recovers >= 80% of the stall — the steady-state queue-pop
-    data_wait collapses to ~0 AND the end-to-end per-step wall drops by at
-    least the stall — and the goodput data_wait share collapses vs the
-    synchronous loop. Runs bench.async_loop_bench in a SINGLE-device
-    subprocess: under conftest's 8-fake-devices-on-2-cores mesh the
-    prefetch worker competes with the 8 virtual devices for the same
-    cores, which deflates the wall-gap signal without touching the
-    critical-path one (measured: wait recovery 0.99 either way; wall-gap
-    recovery 3.4 solo vs 0.19 contended)."""
-    import json
-    import subprocess
-
-    env = dict(os.environ)
-    env.update(JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=1")
-    r = subprocess.run(
-        [sys.executable, "-c",
-         "import json, time, sys; sys.path.insert(0, '.');"
-         "import bench;"
-         "print(json.dumps(bench.async_loop_bench("
-         "time.perf_counter() + 240)))"],
-        env=env, capture_output=True, text=True, cwd=REPO, timeout=420)
-    assert r.returncode == 0, r.stderr[-3000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert "error" not in out, out
-    # critical-path recovery: the stall left on the loop is the queue-pop
-    assert out["recovered_wait_frac"] >= 0.8, out
-    assert out["async"]["steady_data_wait_ms_mean"] <= 4.0, out
-    # sync pays ~the full stall every step on the critical path
-    assert out["sync"]["steady_data_wait_ms_mean"] >= 0.6 * out["stall_ms"]
-    # The wall-gap number (recovered_stall_frac) is REPORTED evidence, not
-    # asserted: across quiet runs of this exact setup it measured 3.4,
-    # 0.77 and 0.31 — the sync-async step-time difference rides scheduler
-    # noise on this shared 2-core host, while the queue-pop wait above is
-    # sleep-based and stable. The >=0.8 criterion is carried by the
-    # critical-path metrics, which are what the journal reports in
-    # production too.
-    assert "recovered_stall_frac" in out
-    # goodput attribution: the async run's data_wait share collapses
-    sync_gp, async_gp = out["sync"]["goodput"], out["async"]["goodput"]
-    sync_share = sync_gp["data_wait_s"] / sync_gp["wall_s"]
-    async_share = async_gp["data_wait_s"] / async_gp["wall_s"]
-    assert async_share < 0.5 * sync_share, out
 
 
 # ---------------------------------------------------------------------------

@@ -263,26 +263,40 @@ def test_zigzag_fallback_when_seq_not_divisible():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_flash_attention_shims_are_flash_template():
-    """flash_attention.py is a re-export facade over the one kernel
-    family in flash_template.py — the ring stripes, the paged decode
-    specialization and direct flash_mha callers must all resolve to the
-    SAME functions, not drifting copies."""
-    from megatron_tpu.ops.pallas import flash_attention as fa
+def test_template_stripe_pair_matches_dense():
+    """flash_template.stripe_fwd / stripe_bwd — the two functions the ring
+    schedules call — against the dense path on one aligned stripe pair
+    (delta 0 is plain causal): normalized output, per-row lse [B, H, c]
+    and, given that lse, the exact dense gradients."""
     from megatron_tpu.ops.pallas import flash_template as ft
 
-    assert fa._fwd is ft._fwd
-    assert fa._bwd is ft._bwd
-    assert fa.flash_mha is ft.flash_mha
-    assert fa._NEG_INF == ft._NEG_INF
-    assert fa._pick_block is ft._pick_block
+    rng = np.random.default_rng(5)
+    q, k, v, do = (jnp.asarray(rng.standard_normal((1, 2, 16, 8)),
+                               jnp.float32) for _ in range(4))
+    scale = 8 ** -0.5
+
+    def dense(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        s = jnp.where(jnp.tril(jnp.ones((16, 16), bool)), s, -jnp.inf)
+        return (jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v),
+                jax.nn.logsumexp(s, -1))
+
+    (want_o, want_lse), vjp = jax.vjp(dense, q, k, v)
+    o, lse = ft.stripe_fwd(q, k, v, 0, None, scale, 8)
+    assert o.dtype == jnp.float32 and lse.shape == (1, 2, 16)
+    np.testing.assert_allclose(o, want_o, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-5, atol=1e-5)
+    got = ft.stripe_bwd(q, k, v, o, lse, do, 0, None, scale, 8)
+    for g, w in zip(got, vjp((do, jnp.zeros_like(want_lse)))):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
 
 
 def test_ring_flash_dispatches_into_template_kernel(monkeypatch):
     """The ring stripes' inner flash forward really lands in the
     flash_template kernel (under MEGATRON_TPU_FLASH_INTERPRET=1 on CPU)
-    — count calls through the facade the stripe resolves at call time."""
-    from megatron_tpu.ops.pallas import flash_attention as fa
+    — count calls through the module global the stripe resolves at call
+    time."""
+    from megatron_tpu.ops.pallas import flash_template as fa
 
     monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
     calls = {"n": 0}

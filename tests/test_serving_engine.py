@@ -339,6 +339,9 @@ def test_paged_prefix_cache_hit_parity():
     # boundary token + suffix recomputed (15 positions skipped)
     assert paged.stats["prefix_hits"] == 1
     assert paged.stats["prefix_tokens_saved"] == 15
+    # shared-prompt traffic computes well under its prompt tokens: the
+    # prefix cache's saving as a ratio of counts (>= 1.5x here)
+    assert (len(p1) + len(p2)) / paged.stats["prefill_tokens"] >= 1.5
     assert paged.stats["decode_recompiles"] == 0
 
 
@@ -571,7 +574,7 @@ def test_server_replies_503_with_retry_after_when_queue_full():
 def test_flash_decode_matches_masked_einsum():
     """Split-KV flash-decode kernel (interpret mode on CPU) vs the dense
     masked reference, GQA + per-row lengths + sliding window."""
-    from megatron_tpu.ops.pallas.flash_decode import flash_decode
+    from megatron_tpu.ops.pallas.flash_template import flash_decode
 
     rng = np.random.default_rng(0)
     B, S, Hq, Hkv, D = 3, 256, 4, 2, 16
@@ -728,8 +731,8 @@ def test_server_engine_concurrent_requests():
 @pytest.mark.slow
 def test_offered_load_throughput_scales_with_slots():
     """Continuous batching must beat sequential one-request-at-a-time
-    handling for >= 4 concurrent requests (the superlinear-scaling gate
-    runs in bench.py; here we only require a real speedup)."""
+    handling for >= 4 concurrent requests (a CPU wall: it says the
+    batched step is shared, nothing about the chip)."""
     import time
 
     prompt_len, new_tokens, n_req = 8, 24, 4

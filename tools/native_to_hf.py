@@ -28,7 +28,7 @@ def main(argv=None):
     import jax
     import torch
 
-    from megatron_tpu.config import ModelConfig
+    from megatron_tpu.config import model_config_from_saved
     from megatron_tpu.interop.hf import hf_config_from_native, params_to_hf_state_dict
     from megatron_tpu.models.params import init_params
     from megatron_tpu.training import checkpointing
@@ -40,7 +40,7 @@ def main(argv=None):
                            "meta.json")) as f:
         meta = json.load(f)
     model_dict = meta["config"]["model"]
-    cfg = ModelConfig(**model_dict).validate()
+    cfg = model_config_from_saved(model_dict).validate()
     model_type = args.model_type or meta["config"].get("hf_model_type")
     if not model_type:
         raise SystemExit("--model_type required (not recorded in checkpoint)")

@@ -24,8 +24,7 @@ its graceful drain MIGRATES in-flight and queued requests to the peers
 over the KV fabric, so the gate stays "failed": 0 even though a replica
 died under load. Exit code 1 if any client-visible request failed.
 
-Output is one JSON report on stdout (percentiles in seconds). The
-`serve_slo_offered_load` bench.py line is this harness inlined.
+Output is one JSON report on stdout (percentiles in seconds).
 """
 
 import argparse

@@ -26,8 +26,7 @@ Reads the append-only JSONL journal a training run writes under
     training counterpart of the serving histograms on /metrics
 
 No jax import — this runs anywhere, including laptops reading journals
-scp'd off a pod. bench.py attaches the same goodput split to its headline
-JSON line (detail["goodput"]).
+scp'd off a pod.
 """
 
 import argparse

@@ -8,8 +8,8 @@ story (docs/observability.md "Runtime traces").
     python tools/trace_report.py DIR --contract ulysses_cp2
     python tools/trace_report.py DIR --format json
 
-Works on any ``--profile`` window, bench ``MEGATRON_TPU_PROFILE_DIR``
-re-run, serving ``/admin/profile`` capture, or SIGUSR1 window — CPU and
+Works on any ``--profile`` window, serving ``/admin/profile`` capture,
+or SIGUSR1 window — CPU and
 TPU alike (XLA:CPU xplanes carry real op events, so the whole pipeline
 is testable without a chip).
 

@@ -132,8 +132,8 @@ def run_training_parity(args, cfg, params, hf_model, hf_config, data):
     """N-step optimizer parity: our fused Adam vs torch AdamW.
 
     The reference's verify_correctness.py (130-189) is forward-only; this
-    closes the other BASELINE.json north star — "loss curve matching the
-    CUDA baseline" — by running the SAME weights, data, and hyperparameters
+    closes the other half of parity with the reference — "loss curve
+    matching the CUDA baseline" — by running the SAME weights, data, and hyperparameters
     through N full optimizer steps on both stacks at fp32 and gating
       * per-step |loss_ours - loss_torch|
       * final param max-abs delta (torch state_dict converted back into our
