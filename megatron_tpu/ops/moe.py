@@ -25,8 +25,12 @@ definition of the auxiliary losses serve both dispatch forms:
     kernels `moe_gmm` / `moe_tgmm`, forward and both gradients, with
     tiles picked from the shapes; `lax.ragged_dot` on every other
     backend, under a mesh of several devices and at row counts the tiles
-    do not divide, such as decode), and the outputs scatter-add back to
-    their tokens weighted by the gates. No token is dropped and no
+    do not divide, such as decode), and the outputs are gathered back
+    into token order through the inverse of the sort, where each token's k
+    choices are weighted by the gates and summed in float32. The sort is a
+    permutation, so rows cross it by gathers in both directions, forward
+    and backward (`rows_to_expert_order`, `rows_to_token_order`): the
+    block holds no scatter. No token is dropped and no
     [.., E, C] tensor exists. Its four stages carry the scopes a device
     trace is read by: `moe_router`, `moe_dispatch`, `moe_experts`,
     `moe_combine` (docs/observability.md "Runtime traces"). Under a mesh
@@ -47,8 +51,9 @@ definition of the auxiliary losses serve both dispatch forms:
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,7 +66,8 @@ from megatron_tpu.ops.activations import apply_activation
 # the key of the load statistic in the loss's aux, the step's metrics and
 # the journal's `step` record
 LOAD_METRIC = "moe_load_max_over_mean"
-# the `checkpoint_name` of the dropless experts' two grouped products:
+# the `checkpoint_name` of the dropless experts' two grouped products (the
+# second under rows_to_token_order, which keeps it for the backward):
 # selective recomputation saves weight-matmul outputs, and knows a
 # `lax.ragged_dot` for one but not a Pallas call
 # (models/language_model.py _remat_policy)
@@ -132,7 +138,12 @@ def topk_dispatch(
 def _topk_gates(gates: jnp.ndarray, top_k: int, renorm: bool):
     """THE top-k + renorm numerics (one definition for both dispatch
     modes, so they cannot drift apart)."""
-    topw, topi = jax.lax.top_k(gates, top_k)
+    _, topi = jax.lax.top_k(gates, top_k)
+    # the chosen gates are read off by a dense compare-and-sum over E (the
+    # same values: one term a choice is not zero), so that their gradient
+    # is dense too; lax.top_k's own transposes into a scatter-add
+    chosen = topi[..., None] == jnp.arange(gates.shape[-1])
+    topw = jnp.sum(jnp.where(chosen, gates[..., None, :], 0.0), axis=-1)
     if renorm:
         topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
     return topw, topi
@@ -186,6 +197,129 @@ def aux_loss_of(moe_aux: jnp.ndarray) -> jnp.ndarray:
     return moe_aux.reshape(-1)[:1]
 
 
+def _expert_counts(flat_e: jnp.ndarray, num_experts: int) -> jnp.ndarray:
+    """[E] int32 rows of each expert among flat_e [N*k]: a dense
+    compare-and-sum (jnp.bincount is a scatter-add, which a TPU works
+    through one update at a time)."""
+    ids = jnp.arange(num_experts, dtype=flat_e.dtype)
+    return jnp.sum(flat_e[:, None] == ids, axis=0, dtype=jnp.int32)
+
+
+def sort_by_expert(topi: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(order [N*k], inv [N, k]) for the expert choices topi [N, k], whose
+    (token, choice) rows are numbered n*k + j. `order[r]` is the row that
+    stands at place r of expert order; the stable sort keeps token order
+    within an expert (GShard priority order, though without capacity it
+    only decides the order of float sums). `inv[n, j]` is the place row
+    n*k + j went to: the argsort of a permutation is its inverse, and a
+    sort of N*k keys costs the chip next to nothing where scattering an
+    iota through `order` does not."""
+    order = jnp.argsort(topi.reshape(-1), stable=True)
+    return order, jnp.argsort(order).reshape(topi.shape)
+
+
+def _permute(values: jnp.ndarray, to: jnp.ndarray) -> jnp.ndarray:
+    """result[to[i]] = values[i] for scalars values [N*k] and a
+    permutation `to`: a key-value sort by the destination. Moving N*k
+    scalars by a gather through the inverse costs the chip ten times what
+    the sort does (rows of h values are another matter: `_take_rows`)."""
+    return jax.lax.sort((to, values), num_keys=1)[1]
+
+
+def _take_rows(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """a[idx] along axis 0 for indices known to be in range (they come
+    from a permutation): no clamp and no fill select around the gather."""
+    return a.at[idx].get(mode="promise_in_bounds")
+
+
+def _sum_of_choices(rows: jnp.ndarray, inv: jnp.ndarray,
+                    topw: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """float32 [N, h]: the sum over a token's k choices j of
+    rows[inv[n, j]] (times topw[n, j], where gates are given), rows
+    [N*k, h] standing in expert order. One gather of N rows a choice,
+    added up as it arrives: the compiler fuses each gather with its
+    multiply-add, where one gather of all N*k rows stands alone in front
+    of the sum with an [N, k, h] temporary between them (on the chip 16.0
+    against 20.9 ms a step of the OLMoE cell, each way)."""
+    total = None
+    for j in range(inv.shape[1]):
+        term = _take_rows(rows, inv[:, j]).astype(jnp.float32)
+        if topw is not None:
+            term = term * topw[:, j, None]
+        total = term if total is None else total + term
+    return total
+
+
+def _token_of(order: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The token of each row of expert order. lax.div, not `//`: jnp's
+    floor division kept XLA from inlining the one-layer scan before its
+    first CSE (grouped_matmul.group_visits has the story); the rows are
+    not negative, so it floors."""
+    return jax.lax.div(order, jnp.asarray(k, order.dtype))
+
+
+@jax.custom_vjp
+def rows_to_expert_order(xf, order, inv):
+    """Token order -> expert order: xs[r] = xf[order[r] / k] for xf [N, h]
+    and the permutation (order, inv) of its N*k (token, choice) rows
+    (`sort_by_expert`). A gather forward; and since autodiff would
+    transpose a gather into a scatter-add whatever its indices, the
+    gradient is written out as what it is here: the cotangent's rows
+    gathered through `inv`, each token's k summed in float32 and rounded
+    once."""
+    return _take_rows(xf, _token_of(order, inv.shape[1]))
+
+
+def _to_expert_fwd(xf, order, inv):
+    return rows_to_expert_order(xf, order, inv), inv
+
+
+def _to_expert_bwd(inv, dxs):
+    return _sum_of_choices(dxs, inv).astype(dxs.dtype), None, None
+
+
+rows_to_expert_order.defvjp(_to_expert_fwd, _to_expert_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def rows_to_token_order(out, topw, order, inv, dtype):
+    """Expert order -> token order with the gates: y[n] = sum over j of
+    topw[n, j] * out[inv[n, j]] for out [N*k, h] in expert order and the
+    gates topw [N, k]; the k terms are weighted and summed in float32 and
+    rounded once, to `dtype`. Its gradient holds gathers only:
+    d_out[r] = w[r] * dy[order[r] / k] with w the gates in expert order,
+    and d_topw[n, j] = <out[inv[n, j]], dy[n]>, taken as the row sums of
+    out * dy[order / k] in expert order (the rows d_out reads anyway) and
+    moved to token order as N*k scalars. Residuals are out, the gates and
+    the permutation: no second copy of the rows."""
+    return _sum_of_choices(out, inv, topw).astype(dtype)
+
+
+def _to_token_fwd(out, topw, order, inv, dtype):
+    # selective recomputation keeps `out` for the gate gradient, by this
+    # name. Named here, on a value that only the backward reads, and not
+    # at the product: jax.checkpoint rounds every saved value that the
+    # forward reads too (a `reduce_precision`), and behind a Pallas call
+    # that is a pass of its own over all N*k rows
+    saved = checkpoint_name(out, SAVED_PRODUCT)
+    return (rows_to_token_order(out, topw, order, inv, dtype),
+            (saved, topw, order, inv))
+
+
+def _to_token_bwd(dtype, res, dy):
+    out, topw, order, inv = res
+    tokens = _token_of(order, inv.shape[1])
+    dy_rows = _take_rows(dy, tokens).astype(jnp.float32)
+    w = _permute(topw.reshape(-1), inv.reshape(-1))    # gates, expert order
+    d_out = (dy_rows * w[:, None]).astype(out.dtype)
+    d_w = jnp.sum(out.astype(jnp.float32) * dy_rows, axis=-1)
+    d_topw = _permute(d_w, order).reshape(topw.shape)  # back to token order
+    return d_out, d_topw.astype(topw.dtype), None, None
+
+
+rows_to_token_order.defvjp(_to_token_fwd, _to_token_bwd)
+
+
 def moe_block_dropless(
     cfg: ModelConfig,
     p: Dict[str, Any],
@@ -198,8 +332,10 @@ def moe_block_dropless(
     exist: the N*k (token, choice) rows are argsorted by expert, the two
     expert matmuls run as grouped GEMMs over contiguous per-expert row
     spans (grouped_matmul: the program's Pallas kernels on one TPU,
-    lax.ragged_dot elsewhere), and outputs
-    scatter back through the inverse sort weighted by the gates. FLOPs are
+    lax.ragged_dot elsewhere), and the outputs are gathered back through
+    the inverse of the sort, weighted by the gates and summed over each
+    token's k choices (rows_to_expert_order, rows_to_token_order: gathers
+    forward and backward, no scatter). FLOPs are
     exactly N*k MLP rows vs the capacity path's dense O(G*Sg*E*Cg)
     dispatch einsums.
 
@@ -225,17 +361,14 @@ def moe_block_dropless(
     with jax.named_scope("moe_router"):
         logits, gates, topw, topi = _route(cfg, p, xf)
         flat_e = topi.reshape(-1)                      # [N*k]
-        group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+        group_sizes = _expert_counts(flat_e, E)
         aux, load = _aux_losses(cfg, logits, gates,
                                 group_sizes.astype(jnp.float32) / N)
 
     with jax.named_scope("moe_dispatch"):
-        # sort the (token, choice) rows by expert; stable sort keeps token
-        # order within an expert (GShard priority order, though without
-        # capacity it only affects float summation order)
-        order = jnp.argsort(flat_e, stable=True)
-        rows = jnp.take(jnp.repeat(jnp.arange(N), k), order)  # row's token
-        xs = jnp.take(xf, rows, axis=0)                # [N*k, H] sorted
+        # the (token, choice) rows sorted by expert, and the way back
+        order, inv = sort_by_expert(topi)
+        xs = rows_to_expert_order(xf, order, inv)      # [N*k, H] sorted
 
     with jax.named_scope("moe_experts"):
         # one visit table for both products and their gradients (None
@@ -248,18 +381,16 @@ def moe_block_dropless(
             # per-row expert bias: gather by the row's expert id
             hmid = hmid + jnp.take(p["b_in"], jnp.take(flat_e, order), axis=0)
         hmid = apply_activation(cfg.activation, hmid.astype(x.dtype))
-        out = checkpoint_name(
-            grouped_matmul(hmid, p["w_out"], group_sizes, visits=visits),
-            SAVED_PRODUCT)
+        # (rows_to_token_order keeps this product for the backward)
+        out = grouped_matmul(hmid, p["w_out"], group_sizes, visits=visits)
         if "b_out" in p:
             out = out + jnp.take(p["b_out"], jnp.take(flat_e, order), axis=0)
 
     with jax.named_scope("moe_combine"):
-        # weight by gates and scatter-add the k choices back per token
-        w = jnp.take(topw.reshape(-1), order)          # [N*k] sorted gates
-        y = jnp.zeros((N, h), jnp.float32).at[rows].add(
-            out.astype(jnp.float32) * w[:, None])
-        y = y.astype(x.dtype).reshape(b, s, h)
+        # back to token order through the inverse sort; each token's k
+        # choices weighted by its gates and summed in float32
+        y = rows_to_token_order(out, topw, order, inv, x.dtype)
+        y = y.reshape(b, s, h)
     return y, aux, load
 
 
@@ -400,8 +531,8 @@ def moe_block_dropless_ep(
     follow-up.
 
     include_data: also make the DATA axis manual (tokens divide data x
-    expert). The sort/bincount/scatter then run per-shard with no
-    batch-axis collectives — the "local-sort form" the ep=1 docstring
+    expert). The sort, the counts and the row gathers then run per-shard
+    with no batch-axis collectives — the "local-sort form" the ep=1 docstring
     names as the known GSPMD-argsort fix — and the expert exchange stays
     within each data slice. Requires B % (data*ep) == 0 (the caller
     guards); the context/tensor axes stay auto by design (tensor carries
@@ -430,10 +561,9 @@ def moe_block_dropless_ep(
 
         # local sort by global expert id
         flat_e = topi.reshape(-1)
-        order = jnp.argsort(flat_e, stable=True)
-        rows = jnp.take(jnp.repeat(jnp.arange(n), k), order)
-        xs = jnp.take(xf, rows, axis=0)               # [nk, h]
-        my_counts = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+        order, inv = sort_by_expert(topi)
+        xs = rows_to_expert_order(xf, order, inv)     # [nk, h]
+        my_counts = _expert_counts(flat_e, E)
         counts = jax.lax.all_gather(my_counts, AXIS_EXPERT)   # [ep, E]
         md = _ep_metadata(counts, me, ep, El, R)
 
@@ -502,9 +632,7 @@ def moe_block_dropless_ep(
                                    AXIS_EXPERT)
 
         # ---- combine at home: gates weight the returned rows ---------
-        w = jnp.take(topw.reshape(-1), order)
-        y = (jnp.zeros((n, h), jnp.float32)
-             .at[rows].add(back.astype(jnp.float32) * w[:, None]))
+        y = rows_to_token_order(back, topw, order, inv, xb.dtype)
 
         stat_axes = ((AXIS_DATA, AXIS_EXPERT) if include_data
                      else AXIS_EXPERT)
@@ -513,7 +641,7 @@ def moe_block_dropless_ep(
         z_sq = jax.lax.pmean(
             jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2), stat_axes)
         aux, load = _aux_from_stats(cfg, frac, prob, z_sq)
-        return y.astype(xb.dtype).reshape(b, s, h), aux, load
+        return y.reshape(b, s, h), aux, load
 
     zeros_b = jnp.zeros((E, 0), x.dtype)
     batch_axes = (AXIS_DATA, AXIS_EXPERT) if include_data else AXIS_EXPERT

@@ -284,7 +284,11 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
     of both expert matrices) are the program's own kernels, by name and
     under the scopes `mlp` and `moe_experts`, and none is left to XLA's
     `ragged-dot-none`; the flash kernels keep their names and the MoE
-    block's scopes arrive."""
+    block's scopes arrive, on forward and transposed operations alike;
+    and nothing under `mlp` is a scatter: rows cross the sort by expert
+    through gathers in both directions (ops/moe.py rows_to_expert_order,
+    rows_to_token_order), and the counts and the chosen gates are dense
+    sums."""
     from megatron_tpu.telemetry.tracing.events import scope_tokens
     from megatron_tpu.training.aot import aot_compile_train_step
 
@@ -316,11 +320,27 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
         if kernel.startswith("moe_"):
             assert "mlp" in toks and "moe_experts" in toks, toks
             assert toks.index("mlp") < toks.index("moe_experts"), toks
-    stacks = [scope_tokens(n)
-              for n in set(re.findall(r'op_name="([^"]+)"', text))]
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    stacks = [scope_tokens(n) for n in names]
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine", "grad_accumulate"):
         assert any(scope in toks for toks in stacks), scope
+    for scope in ("moe_router", "moe_dispatch", "moe_combine"):
+        sides = {"transpose(" in n for n in names if scope in scope_tokens(n)}
+        assert sides == {False, True}, (scope, sides)
+    # the step's scatters (the embedding's backward is one): none under
+    # `mlp`, and none without a name, which could hide from the scopes
+    scatters = _scatter_op_names(text)
+    assert scatters and all(scatters), scatters
+    assert not [op for op in scatters if "mlp" in scope_tokens(op)]
+
+
+def _scatter_op_names(text):
+    """The `op_name` of every scatter instruction of a compiled program
+    ("" for one that carries none)."""
+    lines = re.findall(r"= \S+ scatter\([^\n]*", text)
+    return [(re.findall(r'op_name="([^"]+)"', line) or [""])[0]
+            for line in lines]
 
 
 def _tp2_dp2_step(topo):

@@ -268,6 +268,106 @@ def test_moe_dropless_keeps_overflow_tokens():
                                rtol=2e-5, atol=2e-6)
 
 
+def _routing_cases():
+    """name -> expert choices topi [N, k] over E = 4 experts."""
+    n = np.arange(64)
+    rng = np.random.default_rng(11)
+    return {
+        "balanced": np.stack([n % 4, (n + 1) % 4], 1),          # N*k 128
+        "one_expert_takes_every_row": np.full((16, 2), 2),
+        "an_expert_with_no_rows": rng.choice([0, 1, 3], (24, 2)),
+        "rows_not_a_multiple_of_128": rng.integers(0, 4, (37, 3)),
+        "one_token_decode": np.array([[3, 1]]),
+    }
+
+
+def _scatter_dispatch(xf, topi):
+    """The block's old dispatch, the plain reference of the helpers: a
+    gather by each sorted row's token, whose autodiff is a scatter-add."""
+    N, k = topi.shape
+    order = jnp.argsort(topi.reshape(-1), stable=True)
+    rows = jnp.take(jnp.repeat(jnp.arange(N), k), order)
+    return jnp.take(xf, rows, axis=0), rows, order
+
+
+def _scatter_combine(out, topw, rows, order):
+    """The block's old combine: gate the sorted rows and scatter-add them
+    to their tokens in float32."""
+    w = jnp.take(topw.reshape(-1), order)
+    y = jnp.zeros((topw.shape[0], out.shape[-1]), jnp.float32)
+    return y.at[rows].add(out.astype(jnp.float32) * w[:, None])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_routing_cases()))
+def test_rows_cross_the_sort_by_gathers_and_equal_the_scatter_form(
+        case, dtype):
+    """`rows_to_expert_order` and `rows_to_token_order` against the
+    take / `.at[rows].add` formulation they replaced, kept here in
+    float32: values and the gradients with respect to the tokens, the
+    expert outputs and the gates. In float32 to 1e-6; in bf16 every
+    result the helpers round (values, d xf, d out) is within one bf16
+    rounding of the float32 answer. Neither helper, forward or backward,
+    holds a scatter, and the expert counts are bincount's integers."""
+    from megatron_tpu.ops import moe
+
+    topi = jnp.asarray(_routing_cases()[case], jnp.int32)
+    N, k = topi.shape
+    h, E = 32, 4
+    rng = np.random.default_rng(N * k)
+
+    def draw(*shape):
+        """float32 values that bf16 holds exactly, so both dtypes and the
+        reference start from the same numbers"""
+        a = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+    xf, out = draw(N, h), draw(N * k, h)
+    c_xs, c_y = draw(N * k, h), draw(N, h)        # the two cotangents
+    topw = jnp.asarray(rng.random((N, k)), jnp.float32)
+    order, inv = moe.sort_by_expert(topi)
+    np.testing.assert_array_equal(np.asarray(inv).reshape(-1)[order],
+                                  np.arange(N * k))
+    np.testing.assert_array_equal(
+        moe._expert_counts(topi.reshape(-1), E),
+        np.bincount(np.asarray(topi).reshape(-1), minlength=E))
+
+    def helpers(xf, out, topw):
+        xs = moe.rows_to_expert_order(xf.astype(dtype), order, inv)
+        y = moe.rows_to_token_order(out.astype(dtype), topw, order, inv,
+                                    jnp.dtype(dtype))
+        assert xs.dtype == y.dtype == jnp.dtype(dtype)
+        loss = (jnp.sum(xs.astype(jnp.float32) * c_xs)
+                + jnp.sum(y.astype(jnp.float32) * c_y))
+        return loss, (xs, y)
+
+    def reference(xf, out, topw):
+        xs, rows, order = _scatter_dispatch(xf, topi)
+        y = _scatter_combine(out, topw, rows, order)
+        return jnp.sum(xs * c_xs) + jnp.sum(y * c_y), (xs, y)
+
+    grad = jax.value_and_grad(helpers, argnums=(0, 1, 2), has_aux=True)
+    (_, got), got_g = grad(xf, out, topw)
+    (_, want), want_g = jax.value_and_grad(
+        reference, argnums=(0, 1, 2), has_aux=True)(xf, out, topw)
+    assert "scatter" not in str(jax.make_jaxpr(grad)(xf, out, topw))
+    assert "scatter" in str(jax.make_jaxpr(jax.grad(
+        lambda *a: reference(*a)[0], argnums=(0, 1, 2)))(xf, out, topw))
+
+    named = dict(xs=(got[0], want[0]), y=(got[1], want[1]),
+                 d_xf=(got_g[0], want_g[0]), d_out=(got_g[1], want_g[1]))
+    for name, (a, b) in named.items():
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6,
+                                       err_msg=name)
+        else:   # one rounding to bf16's 8 bits: half a unit in the last
+            assert np.all(np.abs(a - b) <= 2.0 ** -8 * np.abs(b) + 1e-30), \
+                name
+    # the gates stay float32 in both: a sum over h in another order
+    np.testing.assert_allclose(got_g[2], want_g[2], rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.slow  # 13s measured cacheless (PR 4 tier-1 re-budget);
 # the overflow/EP dropless cases keep dispatch coverage in tier-1
 def test_moe_dropless_exact_under_data_sharding():

@@ -322,6 +322,13 @@ def test_the_lowered_step_names_the_five_scopes():
     accumulate = [toks for _n, toks in stacks if "grad_accumulate" in toks]
     assert accumulate and not any(
         {"mlp", "attention", "optimizer"} & set(toks) for toks in accumulate)
+    # no scatter under `mlp`: rows cross the sort by expert through
+    # gathers in both directions, the counts and the chosen gates are
+    # dense sums (tests/test_chip_compile.py asserts the same of the
+    # cell's own step compiled for the chip). The embedding's backward is
+    # the scatter that shows the search finds one
+    scatters = [toks for n, toks in stacks if "scatter" in n.lower()]
+    assert scatters and not [t for t in scatters if "mlp" in t], scatters
 
 
 # --- (c) QK-norm under tensor parallelism ------------------------------------
