@@ -12,7 +12,9 @@ Embedding, parallel_lm_logits) + megatron/model/gpt_model.py
     transformer.py:1110-1176).
   * Vocab-parallel logits + cross-entropy are plain expressions; sharding
     specs make them "parallel" (ref: language_model.py:24-53
-    parallel_lm_logits, cross_entropy.py).
+    parallel_lm_logits, cross_entropy.py). The chunked training loss
+    under a mesh with "tensor" > 1 writes its collectives itself
+    (ops/cross_entropy.py vocab_parallel_chunked_loss).
 """
 
 from __future__ import annotations
@@ -26,7 +28,9 @@ import jax.numpy as jnp
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.transformer import Sharder, _dropout, _identity_sharder, block_forward
-from megatron_tpu.ops.cross_entropy import cross_entropy_loss
+from megatron_tpu.ops.cross_entropy import (
+    cross_entropy_loss, head_loss_plan, vocab_parallel_chunked_loss,
+)
 from megatron_tpu.ops.moe import LOAD_METRIC, SAVED_PRODUCT, merge_layer_stats
 from megatron_tpu.ops.weight_quant import deq, take_rows
 from megatron_tpu.ops.normalization import norm_forward
@@ -343,10 +347,24 @@ def chunked_lm_loss_tokens(
     Beyond the reference (which materializes full logits,
     gpt_model.py:18-42); exact same numbers as the unchunked path — the
     softmax is complete within a chunk because CE is independent per
-    token, only the sequence axis is split."""
+    token, only the sequence axis is split.
+
+    Under a mesh with "tensor" > 1 the same loss runs with its
+    communication written out (ops/cross_entropy.py
+    vocab_parallel_chunked_loss): which tokens share a chunk changes no
+    number. A sharder that carries `sequence_parallel` (the trainer's
+    ActivationSharder) says whether a rank owns S / tp rows of `hidden`
+    or all of them."""
     B, S, H = hidden.shape
     C = cfg.ce_chunk_size
     n = S // C
+    tied = cfg.tie_embed_logits
+    plan = head_loss_plan(B, S, cfg.vocab_size, C,
+                          getattr(sharder, "sequence_parallel", False))
+    if plan is not None:
+        w = deq(params["embed"]["tokens"] if tied else params["lm_head"]["w"],
+                hidden.dtype)
+        return vocab_parallel_chunked_loss(hidden, w, labels, tied, plan)
 
     def chunk_loss(h_c, y_c):
         # h_c [B, C, H], y_c [B, C] -> per-token loss [B, C]

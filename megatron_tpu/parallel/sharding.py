@@ -21,6 +21,7 @@ Conventions:
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Optional
 
 import jax
@@ -59,14 +60,6 @@ def activation_spec(sequence_parallel: bool) -> P:
     return P(BATCH_AXES, AXIS_CONTEXT, None)
 
 
-def logits_spec() -> P:
-    """[batch, seq, vocab] — vocab sharded over tensor (vocab-parallel CE
-    then runs on sharded logits; the reference's 3-allreduce
-    vocab_parallel_cross_entropy (cross_entropy.py:14-127) becomes XLA-fused
-    sharded reductions)."""
-    return P(BATCH_AXES, AXIS_CONTEXT, AXIS_TENSOR)
-
-
 def _bound_axis_names():
     """Axis names currently bound by an enclosing shard_map/*map body —
     i.e. the MANUAL axes at this trace point. Private-API probe (no public
@@ -95,6 +88,22 @@ def constrain(x: jax.Array, spec: P) -> jax.Array:
     if spec_axes & _bound_axis_names():
         return x
     return jax.lax.with_sharding_constraint(x, spec)
+
+
+@dataclasses.dataclass(frozen=True)
+class ActivationSharder:
+    """The trainer's `sharder(x, role)` (models/transformer.py Sharder):
+    the residual stream is held to activation_spec at every block edge;
+    every other role passes through. The head and loss read
+    `sequence_parallel` off it to know which rows of the hidden state a
+    rank of "tensor" owns (ops/cross_entropy.py head_loss_plan)."""
+
+    sequence_parallel: bool = False
+
+    def __call__(self, x: jax.Array, role: str) -> jax.Array:
+        if role == "residual":
+            return constrain(x, activation_spec(self.sequence_parallel))
+        return x
 
 
 def tree_shardings(runtime: MeshRuntime, spec_tree: Any) -> Any:

@@ -148,7 +148,7 @@ def aot_compile_train_step(
     from megatron_tpu.config import OptimizerConfig, TrainingConfig
     from megatron_tpu.models.params import num_params
     from megatron_tpu.parallel.mesh import build_mesh
-    from megatron_tpu.parallel.sharding import activation_spec, constrain
+    from megatron_tpu.parallel.sharding import ActivationSharder
     from megatron_tpu.training.pipeline import make_pipeline_loss_fn
     from megatron_tpu.training.train_step import make_train_step
 
@@ -161,13 +161,7 @@ def aot_compile_train_step(
                           global_batch_size=global_batch,
                           recompute_granularity=recompute, seed=0)
 
-    sp = parallel_cfg.sequence_parallel
-
-    def sharder(x, role):
-        if role == "residual":
-            return constrain(x, activation_spec(sp))
-        return x
-
+    sharder = ActivationSharder(parallel_cfg.sequence_parallel)
     pp_loss_fn = None
     if rt.pp > 1:
         pp_loss_fn = make_pipeline_loss_fn(

@@ -41,7 +41,7 @@ from megatron_tpu.parallel.mesh import MeshRuntime, build_mesh
 from megatron_tpu.platform import device_summary, enable_compile_cache
 from megatron_tpu.telemetry.tracing import capture
 from megatron_tpu.parallel.sharding import (
-    activation_spec, batch_spec, constrain, shard_tree, tree_shardings,
+    ActivationSharder, batch_spec, shard_tree, tree_shardings,
 )
 from megatron_tpu.training import (
     checkpointing, coordination, prefetch, resilience,
@@ -328,14 +328,7 @@ class TrainLoop:
                         self.log(f"save cadence: seeded from {n} journaled "
                                  "commit-latency samples")
 
-        sp = run_cfg.parallel.sequence_parallel
-
-        def sharder(x, role):
-            if role == "residual":
-                return constrain(x, activation_spec(sp))
-            return x
-
-        self._sharder = sharder
+        self._sharder = ActivationSharder(run_cfg.parallel.sequence_parallel)
         self._step_cache: Dict[int, Callable] = {}
         self.loss_fn = loss_fn
         self.fixed_num_microbatches = fixed_num_microbatches

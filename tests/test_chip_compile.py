@@ -16,8 +16,10 @@ import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
+import collections
 import dataclasses
 import importlib
+import math
 import re
 
 import jax
@@ -431,3 +433,159 @@ def test_the_scopes_change_no_byte_of_the_step(topo, tp2_dp2_step):
     opcodes = lambda c: sorted(re.findall(  # noqa: E731
         r"^\s*(?:ROOT )?%[\w.\-]+ = \S+ ([a-z\-]+)\(", c.as_text(), re.M))
     assert opcodes(named) == opcodes(bare)
+
+
+# ---------------------------------------------------------------------------
+# Where the step's collectives stand: in which loop, how often, over which axis
+# ---------------------------------------------------------------------------
+
+_COLLECTIVE = re.compile(r" (all-gather|all-reduce|reduce-scatter|all-to-all|"
+                         r"collective-permute)(?:-start)?\(")
+_RESULT = re.compile(r"\b(pred|[subf]\d+|bf16)\[([\d,]*)\]")
+_CALLED = re.compile(r"\b(calls|to_apply|body|condition|branch_computations)"
+                     r"=\{?((?:%[\w.\-]+(?:, )?)+)\}?")
+_ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4}
+
+
+def _elements(dims):
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+def _replica_groups(text):
+    """`{{0,2},{1,3}}` or the iota form `[2,2]<=[2,2]T(1,0)` as a set of
+    frozensets of device ids."""
+    if text.startswith("{"):
+        return {frozenset(int(i) for i in g.split(","))
+                for g in re.findall(r"\{([\d,]+)\}", text)}
+    import numpy as np
+
+    m = re.match(r"\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?", text)
+    shape, reshape, perm = (tuple(int(i) for i in g.split(",")) if g else None
+                            for g in m.groups())
+    ids = np.arange(math.prod(reshape)).reshape(reshape)
+    if perm:
+        ids = ids.transpose(perm)
+    return {frozenset(int(i) for i in row) for row in ids.reshape(shape)}
+
+
+def _collectives(text):
+    """Every collective instruction of a compiled program that the step
+    reaches: kind, dtype, elements of its largest result, how often a
+    step runs it (the product of the trip counts of the loops around it),
+    whether a loop is around it, its replica groups, and its `op_name`
+    (its own, else that of the instruction calling the computation it
+    stands in: the chip compiler fuses an all-reduce with the slice that
+    follows it and leaves the name on the fusion)."""
+    comps, cur, entry = {}, None, None
+    for line in text.splitlines():
+        if cur is None:
+            m = re.match(r"^(ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+            if m:
+                cur = m.group(2)
+                comps[cur] = []
+                entry = cur if m.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        else:
+            comps[cur].append(line)
+    trips = {}  # a scan's condition compares its counter with a constant
+    for name, lines in comps.items():
+        limits = [int(c) for line in lines for c in
+                  re.findall(r"s32\[\][^ ]* constant\((\d+)\)", line)]
+        if limits and any("direction=LT" in line for line in lines):
+            trips[name] = max(limits)
+    edges = collections.defaultdict(list)
+    waiting = collections.Counter()
+    for name, lines in comps.items():
+        for line in lines:
+            called = {k: re.findall(r"%([\w.\-]+)", v)
+                      for k, v in _CALLED.findall(line)}
+            n = trips.get((called.get("condition") or [None])[0], 1)
+            op = re.search(r'op_name="([^"]+)"', line)
+            for key, callees in called.items():
+                for callee in callees:
+                    edges[name].append((callee, n if key == "body" else 1,
+                                        key == "body", op and op.group(1)))
+                    waiting[callee] += 1
+    times = collections.defaultdict(int, {entry: 1})
+    looped, caller_op = collections.defaultdict(bool), {}
+    ready = [entry]
+    while ready:
+        name = ready.pop()
+        for callee, n, loop, op in edges[name]:
+            times[callee] += times[name] * n
+            looped[callee] |= looped[name] or loop
+            caller_op.setdefault(callee, op or caller_op.get(name))
+            waiting[callee] -= 1
+            if not waiting[callee]:
+                ready.append(callee)
+    found = []
+    for name, lines in comps.items():
+        for line in lines:
+            m = _COLLECTIVE.search(line)
+            if not m or not times[name]:
+                continue
+            dtype, dims = max(_RESULT.findall(line[:m.start()]),
+                              key=lambda r: _elements(r[1]))
+            op = re.search(r'op_name="([^"]+)"', line)
+            groups = re.search(r"replica_groups=(\S+?),? ", line)
+            found.append(dict(
+                kind=m.group(1), dtype=dtype, dims=dims,
+                elements=_elements(dims), times=times[name],
+                in_loop=looped[name],
+                groups=_replica_groups(groups.group(1)) if groups else set(),
+                op_name=op.group(1) if op else caller_op.get(name) or ""))
+    return found
+
+
+def test_head_and_loss_state_their_collectives_tp2_dp2(tp2_dp2_step):
+    """Under TP x SP the head and the chunked cross-entropy run as one
+    `shard_map` that writes its collectives where it wants them
+    (ops/cross_entropy.py vocab_parallel_chunked_loss). In the described
+    v5e 2x2 step program, under `head_loss`:
+
+    * no collective inside a loop has a result larger than one chunk of
+      the hidden state, B x C x H;
+    * none inside a loop crosses `data`;
+    * the head's weight gradient, `[H, V / tp]`, crosses `data` once a
+      step;
+    * what the all-gathers over `tensor` deliver a step is at most twice
+      the hidden state (once a pass) and one float32 a token.
+
+    Fails on the parent of PR 30 (78a1ffa), where every sharding in the
+    region was the partitioner's and a pass of the chip compiler sank the
+    gather of the whole hidden state into both chunk loops: there the
+    program holds `all-gather bf16[8,B,512,4096]` 16 times a step (sixteen
+    times the hidden state), `all-reduce bf16[4096,16000]` over `data` 8
+    times and `all-reduce bf16[1,B,512,4096]` over `tensor` 8 times, all
+    inside the loops. A later change of compiler or code cannot bring
+    that back unseen."""
+    from megatron_tpu.telemetry.tracing.events import scope_tokens
+
+    text = tp2_dp2_step[0].as_text()
+    cfg = _mistral_2l()
+    b, chunk, h = 1, cfg.ce_chunk_size, cfg.hidden_size
+    mine = [c for c in _collectives(text)
+            if "head_loss" in scope_tokens(c["op_name"])]
+    # device ids are laid out data-major, tensor-minor (parallel/mesh.py)
+    tensor = {frozenset({0, 1}), frozenset({2, 3})}
+    data = {frozenset({0, 2}), frozenset({1, 3})}
+    in_loops = [c for c in mine if c["in_loop"]]
+    assert in_loops and {c["times"] for c in in_loops} == {SEQ // chunk}
+    for c in in_loops:
+        assert c["elements"] <= b * chunk * h, c
+        assert c["groups"] == tensor, c
+    head_grads = [c for c in mine
+                  if c["dims"] == f"{h},{cfg.vocab_size // 2}"]
+    assert [(c["times"], c["groups"]) for c in head_grads] == [(1, data)]
+    gathered = sum(c["elements"] * _ITEMSIZE[c["dtype"]] * c["times"]
+                   for c in mine
+                   if c["kind"] == "all-gather" and c["groups"] == tensor)
+    hidden = b * SEQ * h * 2
+    assert hidden <= gathered <= 2 * hidden + b * SEQ * 4, gathered / hidden
+
+
+def test_one_chip_step_holds_no_collective(one_chip_step):
+    """With no mesh the head and loss are the plain expressions they
+    were, and the whole step communicates with nobody."""
+    assert _collectives(one_chip_step.as_text()) == []

@@ -116,12 +116,11 @@ def test_chunked_ce_in_pipeline_last_stage():
 
 def test_chunked_ce_under_tensor_parallel():
     """tp=2 sharded run with chunking matches the unsharded unchunked loss
-    (the per-chunk logits keep the vocab-sharded 'logits' spec)."""
+    (each rank computes the chunk's logits over its half of the vocabulary:
+    ops/cross_entropy.py vocab_parallel_chunked_loss)."""
     from megatron_tpu.config import ParallelConfig
     from megatron_tpu.parallel.mesh import build_mesh
-    from megatron_tpu.parallel.sharding import (
-        activation_spec, constrain, logits_spec, shard_tree,
-    )
+    from megatron_tpu.parallel.sharding import ActivationSharder, shard_tree
     from megatron_tpu.models.params import param_specs
 
     cfg = presets.tiny(seq_length=32, vocab_size=64)
@@ -130,13 +129,7 @@ def test_chunked_ce_under_tensor_parallel():
     batch = _batch(cfg)
     loss0, _ = lm_loss(cfg, params, batch)
 
-    def sharder(x, role):
-        if role == "residual":
-            return constrain(x, activation_spec(False))
-        if role == "logits":
-            return constrain(x, logits_spec())
-        return x
-
+    sharder = ActivationSharder(sequence_parallel=False)
     rt = build_mesh(ParallelConfig(tensor_parallel=2))
     with jax.sharding.set_mesh(rt.mesh):
         sp = shard_tree(rt, params, param_specs(cfg))
