@@ -145,8 +145,9 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
                    choices=["xla", "pallas", "ring", "ulysses"])
     g.add_argument("--ce_chunk_size", type=int, default=0,
                    help="compute LM head + cross-entropy over sequence "
-                        "chunks of this many tokens with rematerialized "
-                        "logits (0 = unchunked full [B,S,V] logits)")
+                        "chunks of this many tokens, a chunk's gradient "
+                        "formed beside its logits "
+                        "(0 = unchunked full [B,S,V] logits)")
     g.add_argument("--use_flash_attn", action="store_true",
                    help="ref alias for --attention_impl pallas")
     g.add_argument("--exit_signal_handler", action="store_true",

@@ -171,10 +171,10 @@ class ModelConfig:
     attn_mask_type: str = "causal"
 
     # chunked fused logits+cross-entropy (beyond the reference): compute
-    # the LM head and CE over sequence chunks of this many tokens, with
-    # per-chunk logits rematerialized in the backward — the full [B,S,V]
-    # logits buffer (plus its fp32 CE intermediates and gradient) never
-    # lives in HBM. 0 = unchunked. Must divide seq_length.
+    # the LM head and CE over sequence chunks of this many tokens, each
+    # chunk's gradient formed while its logits are there — the full
+    # [B,S,V] logits buffer (plus its fp32 CE intermediates and gradient)
+    # never lives in HBM. 0 = unchunked. Must divide seq_length.
     ce_chunk_size: int = 0
 
     # attention implementation: "xla" einsum path, "pallas" flash kernel

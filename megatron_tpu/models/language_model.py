@@ -12,9 +12,9 @@ Embedding, parallel_lm_logits) + megatron/model/gpt_model.py
     transformer.py:1110-1176).
   * Vocab-parallel logits + cross-entropy are plain expressions; sharding
     specs make them "parallel" (ref: language_model.py:24-53
-    parallel_lm_logits, cross_entropy.py). The chunked training loss
-    under a mesh with "tensor" > 1 writes its collectives itself
-    (ops/cross_entropy.py vocab_parallel_chunked_loss).
+    parallel_lm_logits, cross_entropy.py). The chunked training loss is
+    one function with its own gradient rule and, under a mesh, its own
+    collectives (ops/cross_entropy.py chunked_head_loss).
 """
 
 from __future__ import annotations
@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.transformer import Sharder, _dropout, _identity_sharder, block_forward
 from megatron_tpu.ops.cross_entropy import (
-    cross_entropy_loss, head_loss_plan, vocab_parallel_chunked_loss,
+    chunked_head_loss, cross_entropy_loss,
 )
 from megatron_tpu.ops.moe import LOAD_METRIC, SAVED_PRODUCT, merge_layer_stats
 from megatron_tpu.ops.weight_quant import deq, take_rows
@@ -331,58 +331,38 @@ def lm_forward(
     return logits
 
 
-def chunked_lm_loss_tokens(
+def chunked_lm_loss(
     cfg: ModelConfig,
     params: Dict[str, Any],
     hidden: jnp.ndarray,           # [B, S, H] final-norm'd hidden states
     labels: jnp.ndarray,           # [B, S]
+    weights: Optional[jnp.ndarray] = None,   # [B, S]; None: every token 1
     sharder: Sharder = _identity_sharder,
-) -> jnp.ndarray:
-    """Per-token CE [B, S] computed over sequence chunks of
-    cfg.ce_chunk_size tokens, LM head included, with per-chunk logits
-    REMATERIALIZED in the backward — the [B, S, V] logits buffer (bf16
-    forward copy, fp32 CE intermediates, and its gradient) never resides
-    in HBM; peak extra memory is one [B, C, V] chunk.
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(sum of weight x CE over the tokens, per-token CE [B, S]) computed
+    over sequence chunks of cfg.ce_chunk_size tokens, LM head included:
+    the [B, S, V] logits buffer (bf16 forward copy, fp32 CE intermediates,
+    and its gradient) never resides in HBM; peak extra memory is one
+    [B, C, V] chunk. Each chunk's logits are computed once a step: the
+    gradient of the chunk is formed while the forward holds them
+    (ops/cross_entropy.py chunked_head_loss), so only the weighted sum
+    carries a gradient; the per-token losses are for reporting.
 
     Beyond the reference (which materializes full logits,
-    gpt_model.py:18-42); exact same numbers as the unchunked path — the
+    gpt_model.py:18-42); exact same numbers as the unchunked path: the
     softmax is complete within a chunk because CE is independent per
     token, only the sequence axis is split.
 
-    Under a mesh with "tensor" > 1 the same loss runs with its
-    communication written out (ops/cross_entropy.py
-    vocab_parallel_chunked_loss): which tokens share a chunk changes no
-    number. A sharder that carries `sequence_parallel` (the trainer's
-    ActivationSharder) says whether a rank owns S / tp rows of `hidden`
-    or all of them."""
-    B, S, H = hidden.shape
-    C = cfg.ce_chunk_size
-    n = S // C
+    The one function serves every mesh, and the mesh alone says how. A
+    sharder that carries `sequence_parallel` (the trainer's
+    ActivationSharder) says whether a rank of "tensor" owns S / tp rows of
+    `hidden` or all of them."""
     tied = cfg.tie_embed_logits
-    plan = head_loss_plan(B, S, cfg.vocab_size, C,
-                          getattr(sharder, "sequence_parallel", False))
-    if plan is not None:
-        w = deq(params["embed"]["tokens"] if tied else params["lm_head"]["w"],
-                hidden.dtype)
-        return vocab_parallel_chunked_loss(hidden, w, labels, tied, plan)
-
-    def chunk_loss(h_c, y_c):
-        # h_c [B, C, H], y_c [B, C] -> per-token loss [B, C]
-        logits = sharder(lm_logits(cfg, params, h_c), "logits")
-        return cross_entropy_loss(logits, y_c)[1]
-
-    # remat: backward recomputes the chunk's logits from h_c instead of
-    # storing them (the whole point of chunking)
-    chunk_loss = jax.checkpoint(chunk_loss, prevent_cse=False)
-
-    def body(_, xs):
-        h_c, y_c = xs
-        return None, chunk_loss(h_c, y_c)
-
-    h_chunks = hidden.reshape(B, n, C, H).transpose(1, 0, 2, 3)
-    y_chunks = labels.reshape(B, n, C).transpose(1, 0, 2)
-    _, per_chunk = jax.lax.scan(body, None, (h_chunks, y_chunks))
-    return per_chunk.transpose(1, 0, 2).reshape(B, S)
+    w = deq(params["embed"]["tokens"] if tied else params["lm_head"]["w"],
+            hidden.dtype)
+    return chunked_head_loss(
+        hidden, w, labels, weights, tied, cfg.ce_chunk_size,
+        getattr(sharder, "sequence_parallel", False))
 
 
 def lm_loss(
@@ -403,8 +383,8 @@ def lm_loss(
     S = batch["tokens"].shape[1]
     # fall back to unchunked when the chunk doesn't tile this batch's
     # sequence (variable_seq_lengths batches may be shorter than
-    # seq_length). C == S still chunks: the single remat'd chunk drops the
-    # forward logits copy.
+    # seq_length). C == S still chunks: the single chunk forms its
+    # gradient beside its logits and keeps neither.
     chunked = bool(cfg.ce_chunk_size) and S % cfg.ce_chunk_size == 0
     out = lm_forward(
         cfg, params, batch["tokens"],
@@ -418,13 +398,12 @@ def lm_loss(
     if chunked:
         hidden, moe_aux = out if moe else (out, None)
         with jax.named_scope("head_loss"):
-            per_token = chunked_lm_loss_tokens(
-                cfg, params, hidden, batch["labels"], sharder=sharder)
-            if "loss_mask" in batch:
-                m = batch["loss_mask"].astype(jnp.float32)
-                mean = jnp.sum(per_token * m) / jnp.maximum(jnp.sum(m), 1.0)
-            else:
-                mean = jnp.mean(per_token)
+            # the mask goes in, its normaliser stays out here on the scalar
+            mask = batch.get("loss_mask")
+            total, per_token = chunked_lm_loss(
+                cfg, params, hidden, batch["labels"], mask, sharder=sharder)
+            mean = total / (per_token.size if mask is None else jnp.maximum(
+                jnp.sum(mask.astype(jnp.float32)), 1.0))
     else:
         logits, moe_aux = out if moe else (out, None)
         with jax.named_scope("head_loss"):

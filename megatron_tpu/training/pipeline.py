@@ -48,7 +48,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.language_model import (
-    _dropout, _layer_dropout_rates, chunked_lm_loss_tokens,
+    _dropout, _layer_dropout_rates, chunked_lm_loss,
     final_hidden_norm, lm_logits, scan_with_remat,
 )
 from megatron_tpu.models.transformer import block_forward
@@ -359,13 +359,16 @@ def make_pipeline_loss_fn(
                                                       keepdims=False)
                     C = model_cfg.ce_chunk_size
                     if C and S % C == 0:
-                        per_tok = chunked_lm_loss_tokens(
-                            model_cfg, params_local, h, lab)
+                        # a tick's residuals are stacked over the ticks:
+                        # under jax.checkpoint the loss keeps `h` alone
+                        # and forms its gradients in the backward pass
+                        lsum = jax.checkpoint(lambda h: chunked_lm_loss(
+                            model_cfg, params_local, h, lab, lm)[0])(h)
                     else:
                         logits = lm_logits(model_cfg, params_local, h)
                         _, per_tok = cross_entropy_loss(logits, lab)
-                    return (jnp.sum(per_tok * lm).reshape(1),
-                            jnp.sum(lm).reshape(1))
+                        lsum = jnp.sum(per_tok * lm)
+                    return lsum.reshape(1), jnp.sum(lm).reshape(1)
 
                 def without_loss(_):
                     return (jnp.zeros((1,), jnp.float32),

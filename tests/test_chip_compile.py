@@ -276,6 +276,10 @@ def test_train_step_fits_one_v5e(one_chip_step):
     assert text.count("tpu_custom_call") >= 3
     assert _per_device_bytes(one_chip_step) < 16e9
     _assert_step_kernels_named(text)
+    cfg = _mistral_2l()
+    _assert_head_loss_forms_its_logits_once(
+        text, f"{cfg.ce_chunk_size},{cfg.vocab_size}",
+        SEQ // cfg.ce_chunk_size)
 
 
 def test_olmoe_cell_step_fits_one_v5e(topo):
@@ -290,7 +294,7 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
     and nothing under `mlp` is a scatter: rows cross the sort by expert
     through gathers in both directions (ops/moe.py rows_to_expert_order,
     rows_to_token_order), and the counts and the chosen gates are dense
-    sums."""
+    sums; and the head and loss form each chunk's logits once."""
     from megatron_tpu.telemetry.tracing.events import scope_tokens
     from megatron_tpu.training.aot import aot_compile_train_step
 
@@ -335,6 +339,10 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
     scatters = _scatter_op_names(text)
     assert scatters and all(scatters), scatters
     assert not [op for op in scatters if "mlp" in scope_tokens(op)]
+    # the head and loss: one chunk loop a micro-batch, the logits once
+    _assert_head_loss_forms_its_logits_once(
+        text, f"{cfg.ce_chunk_size},{cfg.vocab_size}",
+        16 * SEQ // cfg.ce_chunk_size)
 
 
 def _scatter_op_names(text):
@@ -468,14 +476,24 @@ def _replica_groups(text):
     return {frozenset(int(i) for i in row) for row in ids.reshape(shape)}
 
 
+# a product as the step program holds it: a fusion around a convolution
+_PRODUCT = re.compile(r" (fusion|convolution)\(")
+
+
 def _collectives(text):
-    """Every collective instruction of a compiled program that the step
-    reaches: kind, dtype, elements of its largest result, how often a
-    step runs it (the product of the trip counts of the loops around it),
-    whether a loop is around it, its replica groups, and its `op_name`
-    (its own, else that of the instruction calling the computation it
-    stands in: the chip compiler fuses an all-reduce with the slice that
-    follows it and leaves the name on the fusion)."""
+    return _instructions(text, _COLLECTIVE)
+
+
+def _instructions(text, opcode):
+    """Every instruction of a compiled program that matches `opcode` (a
+    pattern with the kind as its one group) and that the step reaches:
+    kind, dtype, elements of its largest result, how often a step runs it
+    (the product of the trip counts of the loops around it), whether a
+    loop is around it, whether it stands inside a fusion, its replica
+    groups, and its `op_name` (its own, else that of the instruction
+    calling the computation it stands in: the chip compiler fuses an
+    all-reduce with the slice that follows it and leaves the name on the
+    fusion)."""
     comps, cur, entry = {}, None, None
     for line in text.splitlines():
         if cur is None:
@@ -505,16 +523,19 @@ def _collectives(text):
             for key, callees in called.items():
                 for callee in callees:
                     edges[name].append((callee, n if key == "body" else 1,
-                                        key == "body", op and op.group(1)))
+                                        key == "body", op and op.group(1),
+                                        " fusion(" in line))
                     waiting[callee] += 1
     times = collections.defaultdict(int, {entry: 1})
     looped, caller_op = collections.defaultdict(bool), {}
+    fused = collections.defaultdict(bool)
     ready = [entry]
     while ready:
         name = ready.pop()
-        for callee, n, loop, op in edges[name]:
+        for callee, n, loop, op, fusion in edges[name]:
             times[callee] += times[name] * n
             looped[callee] |= looped[name] or loop
+            fused[callee] |= fused[name] or fusion
             caller_op.setdefault(callee, op or caller_op.get(name))
             waiting[callee] -= 1
             if not waiting[callee]:
@@ -522,7 +543,7 @@ def _collectives(text):
     found = []
     for name, lines in comps.items():
         for line in lines:
-            m = _COLLECTIVE.search(line)
+            m = opcode.search(line)
             if not m or not times[name]:
                 continue
             dtype, dims = max(_RESULT.findall(line[:m.start()]),
@@ -532,25 +553,48 @@ def _collectives(text):
             found.append(dict(
                 kind=m.group(1), dtype=dtype, dims=dims,
                 elements=_elements(dims), times=times[name],
-                in_loop=looped[name],
+                in_loop=looped[name], fused=fused[name],
                 groups=_replica_groups(groups.group(1)) if groups else set(),
                 op_name=op.group(1) if op else caller_op.get(name) or ""))
     return found
 
 
+def _assert_head_loss_forms_its_logits_once(text, chunk_dims, times):
+    """Under `head_loss` nothing is computed again, and the product that
+    gives a chunk's logits, bf16[.., C, V / tp], stands in the step once,
+    inside the region's one chunk loop, `times` times a step
+    (ops/cross_entropy.py _chunk_loop forms the chunk's gradient in the
+    iteration that holds its logits). The parent of PR 31 (94cc775) held
+    it twice, in two loops, the second under `rematted_computation`."""
+    from megatron_tpu.telemetry.tracing.events import scope_tokens
+
+    again = [n for n in set(re.findall(r'op_name="([^"]+)"', text))
+             if {"head_loss", "rematted_computation"} <= set(scope_tokens(n))]
+    assert not again, again[:3]
+    logits = [i for i in _instructions(text, _PRODUCT)
+              if not i["fused"] and i["dtype"] == "bf16"
+              and i["dims"].endswith(chunk_dims)
+              and "head_loss" in scope_tokens(i["op_name"])
+              and "dot_general" in i["op_name"]]
+    assert [(i["times"], i["in_loop"]) for i in logits] == [(times, True)], \
+        logits
+
+
 def test_head_and_loss_state_their_collectives_tp2_dp2(tp2_dp2_step):
     """Under TP x SP the head and the chunked cross-entropy run as one
     `shard_map` that writes its collectives where it wants them
-    (ops/cross_entropy.py vocab_parallel_chunked_loss). In the described
-    v5e 2x2 step program, under `head_loss`:
+    (ops/cross_entropy.py chunked_head_loss). In the described v5e 2x2
+    step program, under `head_loss`:
 
     * no collective inside a loop has a result larger than one chunk of
       the hidden state, B x C x H;
     * none inside a loop crosses `data`;
     * the head's weight gradient, `[H, V / tp]`, crosses `data` once a
       step;
-    * what the all-gathers over `tensor` deliver a step is at most twice
-      the hidden state (once a pass) and one float32 a token.
+    * what the all-gathers over `tensor` deliver a step is the hidden
+      state once (the one pass over the head that PR 31 left) and at most
+      one float32 a token;
+    * nothing is computed again, and the chunk's logits are formed once.
 
     Fails on the parent of PR 30 (78a1ffa), where every sharding in the
     region was the partitioner's and a pass of the chip compiler sank the
@@ -582,10 +626,13 @@ def test_head_and_loss_state_their_collectives_tp2_dp2(tp2_dp2_step):
                    for c in mine
                    if c["kind"] == "all-gather" and c["groups"] == tensor)
     hidden = b * SEQ * h * 2
-    assert hidden <= gathered <= 2 * hidden + b * SEQ * 4, gathered / hidden
+    assert hidden <= gathered <= hidden + b * SEQ * 4, gathered / hidden
+    _assert_head_loss_forms_its_logits_once(
+        text, f"{chunk},{cfg.vocab_size // 2}", SEQ // chunk)
 
 
 def test_one_chip_step_holds_no_collective(one_chip_step):
-    """With no mesh the head and loss are the plain expressions they
-    were, and the whole step communicates with nobody."""
+    """On a mesh of one device the head and loss name no collective (a
+    reduction over an axis of one device is not written), and the whole
+    step communicates with nobody."""
     assert _collectives(one_chip_step.as_text()) == []

@@ -2,7 +2,9 @@
 materializes the full [B,S,V] logits — gpt_model.py:18-42). The chunked
 path must be numerically identical to the unchunked one: the softmax is
 complete within a chunk because CE is per-token; only the sequence axis
-is split."""
+is split. Its gradient is formed chunk by chunk beside the logits
+(ops/cross_entropy.py chunked_head_loss; the head alone against autodiff
+on every mesh: tests/test_vocab_parallel_loss.py)."""
 
 import dataclasses
 
@@ -12,7 +14,9 @@ import numpy as np
 import pytest
 
 from megatron_tpu.models import presets
-from megatron_tpu.models.language_model import lm_loss
+from megatron_tpu.models.language_model import (
+    chunked_lm_loss, final_hidden_norm, lm_forward, lm_loss,
+)
 from megatron_tpu.models.params import init_params
 
 
@@ -50,8 +54,8 @@ def test_chunked_ce_matches_unchunked(tie, masked):
 
 
 def test_chunked_ce_full_size_chunk():
-    """C == S is a single remat'd chunk (drops the forward logits copy),
-    not a silent no-op; numbers still match."""
+    """C == S is a single chunk whose gradient is formed beside its
+    logits (neither is kept), not a silent no-op; numbers still match."""
     cfg = presets.tiny(seq_length=32)
     chunked = dataclasses.replace(cfg, ce_chunk_size=32).validate()
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -64,6 +68,60 @@ def test_chunked_ce_full_size_chunk():
     for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("tie", [False, True])
+def test_chunked_ce_under_checkpoint_equals_the_bare_call(tie):
+    """The pipeline schedule's form: the chunked loss wrapped in
+    jax.checkpoint keeps the hidden state alone and forms its gradients
+    in the backward pass. Same loss, same gradients, to the bit; and no
+    gradient work where nothing is differentiated."""
+    cfg = presets.tiny(seq_length=32, tie_embed_logits=tie, ce_chunk_size=8)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = _batch(cfg, masked=True)
+    hidden = lm_forward(cfg, params, batch["tokens"], return_hidden=True)
+
+    def bare(p, h):
+        return chunked_lm_loss(cfg, p, h, batch["labels"],
+                               batch["loss_mask"])[0]
+
+    want, want_grads = jax.value_and_grad(bare, argnums=(0, 1))(
+        params, hidden)
+    got, got_grads = jax.value_and_grad(jax.checkpoint(bare), argnums=(0, 1))(
+        params, hidden)
+    assert float(got) == float(want)
+    for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # evaluated, the loop forms no gradient: the one product of the head
+    # gradient's shape is absent from the program
+    evaluated = str(jax.make_jaxpr(bare)(params, hidden))
+    differentiated = str(jax.make_jaxpr(jax.grad(bare))(params, hidden))
+    assert "optimization_barrier" not in evaluated
+    assert "optimization_barrier" in differentiated
+
+
+def test_per_token_losses_carry_no_gradient():
+    """The per-token losses are for reporting: the gradient is that of the
+    weighted sum, formed before any cotangent is known. Differentiating
+    the per-token output raises; it is never a silent zero. The weights'
+    own gradient is exact (the per-token losses)."""
+    cfg = presets.tiny(seq_length=32, ce_chunk_size=8)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = _batch(cfg, masked=True)
+    hidden = lm_forward(cfg, params, batch["tokens"], return_hidden=True)
+    with pytest.raises(TypeError, match="for reporting"):
+        jax.grad(lambda h: jnp.sum(chunked_lm_loss(
+            cfg, params, h, batch["labels"])[1]))(hidden)
+    total, per_token = chunked_lm_loss(cfg, params, hidden, batch["labels"],
+                                       batch["loss_mask"])
+    d_weights = jax.grad(lambda m: chunked_lm_loss(
+        cfg, params, hidden, batch["labels"], m)[0])(batch["loss_mask"])
+    np.testing.assert_allclose(np.asarray(d_weights), np.asarray(per_token),
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        float(total), float(jnp.sum(per_token * batch["loss_mask"])),
+        rtol=1e-6)
 
 
 def test_chunked_ce_falls_back_on_non_tiling_seq():
@@ -86,7 +144,10 @@ def test_chunked_ce_validate_rejects_non_divisor():
 
 def test_chunked_ce_in_pipeline_last_stage():
     """pp=2 with chunked CE on the last stage matches the unpipelined
-    unchunked loss."""
+    unchunked loss, value and every gradient: the last stage wraps the
+    chunked loss in jax.checkpoint, inside the cond inside the scan over
+    the ticks, so that a tick keeps its hidden state and not the head's
+    gradient."""
     from megatron_tpu.config import ParallelConfig
     from megatron_tpu.parallel.mesh import build_mesh
     from megatron_tpu.parallel.sharding import shard_tree
@@ -109,15 +170,21 @@ def test_chunked_ce_in_pipeline_last_stage():
     pp_loss_fn = make_pipeline_loss_fn(chunked, rt.mesh, num_stages=2,
                                        num_microbatches=4, recompute="full")
     with jax.sharding.set_mesh(rt.mesh):
-        loss_pp, _ = jax.jit(lambda p, b: pp_loss_fn(p, b, None))(sp, batch)
-    loss_ref = lm_loss(cfg, params, batch)[0]
+        loss_pp, grads_pp = jax.jit(jax.value_and_grad(
+            lambda p, b: pp_loss_fn(p, b, None)[0]))(sp, batch)
+    loss_ref, grads_ref = jax.value_and_grad(
+        lambda p: lm_loss(cfg, p, batch)[0])(params)
     np.testing.assert_allclose(float(loss_pp), float(loss_ref), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads_pp), jax.tree.leaves(grads_ref),
+                    strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-6)
 
 
 def test_chunked_ce_under_tensor_parallel():
     """tp=2 sharded run with chunking matches the unsharded unchunked loss
     (each rank computes the chunk's logits over its half of the vocabulary:
-    ops/cross_entropy.py vocab_parallel_chunked_loss)."""
+    ops/cross_entropy.py chunked_head_loss)."""
     from megatron_tpu.config import ParallelConfig
     from megatron_tpu.parallel.mesh import build_mesh
     from megatron_tpu.parallel.sharding import ActivationSharder, shard_tree
