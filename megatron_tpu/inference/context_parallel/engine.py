@@ -173,10 +173,12 @@ class ContextParallelEngine(PagedInferenceEngine):
         that jax 0.4.37 offered), where a tensor-sharded heads dim would
         just be force-gathered at the island boundary each step — not
         re-tried on jax 0.9 (ROADMAP D9)."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding
+
+        from megatron_tpu.ops import kv_store
 
         return NamedSharding(self.mesh,
-                             P(None, AXIS_CONTEXT, None, None, None))
+                             kv_store.partition_spec(rows=AXIS_CONTEXT))
 
     # ----- page accounting -------------------------------------------------
 

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.ring_attention import _merge_normalized
 from megatron_tpu.quant.collectives import (
     grouped_all_gather, grouped_all_to_all, ring_permute,
@@ -131,18 +132,19 @@ def _merge_2d(cpc, o, lse):
                               chunk=cpc.chunk)
 
 
-def paged_ring_attention(cpc, q, k_new, v_new, kv_cache, loc_tables,
+def paged_ring_attention(cpc, q, k_new, v_new, kv_cache, layer, loc_tables,
                          cache_index, per_slot, page_write_start=None,
                          page_write_end=None, sliding_window=None):
     """Cross-shard paged attention for one layer.
 
     q [B, S, Hq, D]; k_new/v_new [B, S, Hkv, D] (post-rope);
-    kv_cache = (k_pool, v_pool) each [num_pages, page_size, Hkv, D]
-    sharded over "context" on the pages dim; loc_tables [cp, B_t, mpl]
-    sharded over "context" on dim 0. per_slot decode: cache_index =
-    lengths [B] and S must be 1. Chunk prefill: cache_index = scalar
-    chunk offset, B == 1, and the write fences bound the page writes.
-    Returns (ctx [B, S, Hq, D] in q.dtype, (k_pool, v_pool) updated).
+    kv_cache = (k_pool, v_pool): the stacked bf16 store (ops/kv_store.py)
+    with its pages sharded over "context"; `layer` this layer's index
+    into it; loc_tables [cp, B_t, mpl] sharded over "context" on dim 0.
+    per_slot decode: cache_index = lengths [B] and S must be 1. Chunk
+    prefill: cache_index = scalar chunk offset, B == 1, and the write
+    fences bound the page writes. Returns (ctx [B, S, Hq, D] in q.dtype,
+    the store with this layer's rows written in place).
     """
     k_pool, v_pool = kv_cache
     cp, axis = cpc.cp, cpc.axis
@@ -159,9 +161,9 @@ def paged_ring_attention(cpc, q, k_new, v_new, kv_cache, loc_tables,
         page_write_end = jnp.int32(2 ** 30)
     window = sliding_window
 
-    def inner(qx, kn, vn, kp, vp, loc, idx, ws, we):
+    def inner(qx, kn, vn, kp, vp, loc, idx, ws, we, layer):
         r = jax.lax.axis_index(axis)
-        npl, ps = kp.shape[0], kp.shape[1]
+        npl, ps = kv_store.rows_and_row_len(kp)    # this rank's pages
         loc = loc[0]                               # [B_t, mpl] local view
         mpl = loc.shape[1]
         B, S, Hq, D = qx.shape
@@ -169,32 +171,25 @@ def paged_ring_attention(cpc, q, k_new, v_new, kv_cache, loc_tables,
 
         # -- scatter-write this step's K/V into the local stripe -------
         if per_slot:
-            pos = idx                              # [B] write positions
+            pos = idx[:, None]                     # [B, 1] positions
             lpage = pos // ps
             j = jnp.minimum(lpage // cp, mpl - 1)
-            phys = jnp.take_along_axis(loc, j[:, None], axis=1)[:, 0]
+            phys = jnp.take_along_axis(loc, j, axis=1)
             owned = (lpage % cp) == r
-            tgt = jnp.where(owned, phys, npl)
-            kp = kp.at[tgt, pos % ps].set(kn[:, 0].astype(kp.dtype),
-                                          mode="drop")
-            vp = vp.at[tgt, pos % ps].set(vn[:, 0].astype(vp.dtype),
-                                          mode="drop")
         else:
-            pos = idx + jnp.arange(S, dtype=jnp.int32)   # [S]
+            pos = (idx + jnp.arange(S, dtype=jnp.int32))[None, :]  # [1, S]
             lpage = pos // ps
             j = jnp.minimum(lpage // cp, mpl - 1)
-            phys = jnp.take(loc[0], j, mode="clip")
+            phys = jnp.take_along_axis(loc[:1], j, axis=1, mode="clip")
             owned = ((lpage % cp) == r) & (pos >= ws) & (pos < we)
-            tgt = jnp.where(owned, phys, npl)
-            kp = kp.at[tgt, pos % ps].set(kn[0].astype(kp.dtype),
-                                          mode="drop")
-            vp = vp.at[tgt, pos % ps].set(vn[0].astype(vp.dtype),
-                                          mode="drop")
+        tgt = jnp.where(owned, phys, npl)          # npl: the write drops
+        kp = kv_store.scatter_rows(kp, layer, tgt, pos % ps, kn, drop=True)
+        vp = kv_store.scatter_rows(vp, layer, tgt, pos % ps, vn, drop=True)
 
         # -- gather the local stripe + its global token positions ------
         safe = jnp.minimum(loc, npl - 1)           # [B_t, mpl]
-        kf = jnp.take(kp, safe, axis=0, mode="clip")
-        vf = jnp.take(vp, safe, axis=0, mode="clip")
+        kf = kv_store.gather_rows(kp, layer, safe)
+        vf = kv_store.gather_rows(vp, layer, safe)
         s_loc = mpl * ps
         kf = kf.reshape(loc.shape[0], s_loc, Hkv, D)
         vf = vf.reshape(loc.shape[0], s_loc, Hkv, D)
@@ -245,11 +240,12 @@ def paged_ring_attention(cpc, q, k_new, v_new, kv_cache, loc_tables,
         return acc_o.astype(qx.dtype), kp, vp
 
     shard = P(axis)
+    pools = kv_store.partition_spec(rows=axis)
     ctx, k_pool, v_pool = jax.shard_map(
         inner, mesh=cpc.mesh,
-        in_specs=(P(), P(), P(), shard, shard, shard, P(), P(), P()),
-        out_specs=(P(), shard, shard),
+        in_specs=(P(), P(), P(), pools, pools, shard, P(), P(), P(), P()),
+        out_specs=(P(), pools, pools),
         axis_names={axis}, check_vma=False)(
             q, k_new, v_new, k_pool, v_pool, loc_tables,
-            cache_index, page_write_start, page_write_end)
+            cache_index, page_write_start, page_write_end, layer)
     return ctx, (k_pool, v_pool)

@@ -2,8 +2,8 @@
 
 The one-shot path (generation.py) allocates a dense [B, L, S, H] cache per
 call and serves one request at a time — decode utilization collapses to a
-single sequence's matmul. This engine owns ONE long-lived cache shaped
-[L, num_slots, S, H, D] (optionally int8, ops/kv_quant.py) and runs a step
+single sequence's matmul. This engine owns ONE long-lived cache of
+num_slots rows (ops/kv_store.py; optionally int8) and runs a step
 loop: every tick it admits queued requests into free slots (a bucketed
 prefill writes the slot's rows) and then executes ONE batched single-token
 decode for all slots — one jit-compiled step reused across traffic, no
@@ -40,8 +40,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from megatron_tpu.config import ModelConfig
-from megatron_tpu.inference.generation import GenerationOutput, _init_caches
+from megatron_tpu.inference.generation import GenerationOutput
 from megatron_tpu.inference.sampling import sample_logits_batched
+from megatron_tpu.ops import kv_store
 from megatron_tpu.telemetry import journal as _journal
 from megatron_tpu.telemetry.metrics import MetricsRegistry, default_registry
 from megatron_tpu.telemetry.tracing import capture
@@ -397,8 +398,8 @@ class InferenceEngine:
     def _fresh_caches(self):
         """Host-built zeroed KV storage (overridden by the paged engine
         to build page pools instead of per-slot rows)."""
-        return _init_caches(self.cfg, self.num_slots, self.max_seq_len,
-                            int8=self.kv_cache_int8)
+        return kv_store.create(self.cfg, self.num_slots, self.max_seq_len,
+                               int8=self.kv_cache_int8)
 
     def _fresh_draft_caches(self):
         """The draft model's second cache tree (speculative decoding,
@@ -406,8 +407,8 @@ class InferenceEngine:
         the draft config's own layer/head geometry, always bf16/f32 —
         the draft is small, quantizing it would buy little and cost a
         second quantization seam. Paged engine overrides with pools."""
-        return _init_caches(self.spec.draft_cfg, self.num_slots,
-                            self.max_seq_len, int8=False)
+        return kv_store.create(self.spec.draft_cfg, self.num_slots,
+                               self.max_seq_len)
 
     def _rebuild_caches(self):
         """Replace every donated cache tree after a failed device call
@@ -451,19 +452,19 @@ class InferenceEngine:
         return jax.tree.map(lambda a: jax.device_put(a, sharding), tree)
 
     def _kv_sharding(self):
-        """Cache-leaf placement on a mesh engine: every cache leaf is
-        5-D with kv_heads at axis 3 (dense rows, paged pools, and their
-        int8 scale companions alike), sharded over "tensor" when it
-        divides — matching the column-parallel wk/wv head sharding so
-        cache writes stay local. None on mesh-less engines."""
+        """Cache-leaf placement on a mesh engine: every leaf (dense
+        rows, paged pools, and their int8 scale companions alike) has its
+        kv heads sharded over "tensor" when it divides — matching the
+        column-parallel wk/wv head sharding so cache writes stay local.
+        None on mesh-less engines."""
         if self.mesh is None:
             return None
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         tp = dict(self.mesh.shape).get("tensor", 1)
         if tp > 1 and self.cfg.n_kv_heads % tp == 0:
-            return NamedSharding(self.mesh, P(None, None, None, "tensor",
-                                              None))
+            return NamedSharding(self.mesh,
+                                 kv_store.partition_spec(heads="tensor"))
         return NamedSharding(self.mesh, P())
 
     def _commit_caches(self, tree):
@@ -583,17 +584,11 @@ class InferenceEngine:
 
         @partial(jax.jit, donate_argnums=self._donate())
         def draft_prefill(dparams, dcaches, tokens, slot):
-            small = _init_caches(dcfg, 1, P, int8=False)
+            small = kv_store.create(dcfg, 1, P)
             _, small = lm_forward(dcfg, dparams, tokens,
                                   positions=jnp.arange(P)[None, :],
                                   kv_caches=small, cache_index=0)
-
-            def paste(big, sm):
-                idx = (0, slot) + (0,) * (big.ndim - 2)
-                return jax.lax.dynamic_update_slice(
-                    big, sm.astype(big.dtype), idx)
-
-            return jax.tree.map(paste, dcaches, small)
+            return kv_store.install(dcaches, kv_store.row(small, 0), slot)
 
         self._draft_prefill_steps[P] = draft_prefill
         return draft_prefill
@@ -616,18 +611,12 @@ class InferenceEngine:
                      ("rep", "rep", "rep", "kv", "rep")))
         def prefill(params, caches, tokens, length, slot, key, temp,
                     top_k, top_p):
-            small = _init_caches(cfg, 1, P, int8=int8)
+            small = kv_store.create(cfg, 1, P, int8=int8)
             logits, small = lm_forward(cfg, params, tokens,
                                        positions=jnp.arange(P)[None, :],
                                        kv_caches=small, cache_index=0,
                                        tp_comm=tp_comm)
-
-            def paste(big, sm):
-                idx = (0, slot) + (0,) * (big.ndim - 2)
-                return jax.lax.dynamic_update_slice(
-                    big, sm.astype(big.dtype), idx)
-
-            caches = jax.tree.map(paste, caches, small)
+            caches = kv_store.install(caches, kv_store.row(small, 0), slot)
             last = jnp.take_along_axis(
                 logits, jnp.full((1, 1, 1), length - 1), axis=1)[:, 0]
             key, sub = jax.random.split(key)
@@ -1467,8 +1456,7 @@ class InferenceEngine:
         length = int(self.lengths[i])
         if length <= 0:
             return None
-        host = [np.asarray(leaf)[:, i, :length]
-                for leaf in jax.device_get(self.caches)]
+        host = kv_store.export_span(jax.device_get(self.caches), [i], length)
         return self._pack_kv_sections(host, length)
 
     def export_request_state(self, req: Request, include_kv: bool = True
@@ -1590,24 +1578,15 @@ class InferenceEngine:
         return None
 
     def _kv_install_writer(self):
-        """Once-jitted axis-1 paste: a [L, T, ...] block into the
-        [L, N, T, ...] cache tree at a TRACED index (slot for the dense
-        engine, page for the paged pool). Static shapes, its own jit —
-        repeated imports never grow the decode step's cache (the
+        """Once-jitted kv_store.install: a canonical [L, T, ...] block
+        into the cache at a TRACED row (slot for the dense engine, page
+        for the paged pool). Static shapes, its own jit — repeated
+        imports never grow the decode step's cache (the
         zero-decode-recompiles invariant holds through migration)."""
         if self._kv_writer is None:
-            from functools import partial
-
-            @partial(jax.jit, donate_argnums=(0,) if self._donate() else ())
-            def write(caches, blocks, at):
-                def paste(big, sm):
-                    idx = (0, at) + (0,) * (big.ndim - 2)
-                    return jax.lax.dynamic_update_slice(
-                        big, sm[:, None].astype(big.dtype), idx)
-
-                return jax.tree.map(paste, caches, blocks)
-
-            self._kv_writer = write
+            self._kv_writer = jax.jit(
+                kv_store.install,
+                donate_argnums=(0,) if self._donate() else ())
         return self._kv_writer
 
     def _install_request_kv(self, req: Request, kv: dict,
@@ -1620,15 +1599,10 @@ class InferenceEngine:
             return False
         length = int(kv["length"])
         leaves = self._decode_kv_sections(kv, sections)
-        blocks = []
-        for leaf in leaves:
-            row = np.zeros((leaf.shape[0], self.max_seq_len)
-                           + leaf.shape[2:], leaf.dtype)
-            row[:, :length] = leaf
-            blocks.append(jnp.asarray(row))
         self._sync_carry()
         self.caches = self._kv_install_writer()(
-            self.caches, tuple(blocks), jnp.int32(i))
+            self.caches, kv_store.span_block(leaves, 0, self.max_seq_len),
+            jnp.int32(i))
         self._arm_imported_slot(i, req, length)
         return True
 

@@ -25,6 +25,7 @@ import numpy as np
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.sampling import sample_logits
 from megatron_tpu.models.language_model import lm_forward
+from megatron_tpu.ops import kv_store
 
 
 @dataclasses.dataclass
@@ -32,18 +33,6 @@ class GenerationOutput:
     tokens: np.ndarray       # [B, total_len] int32 (prompt + generated)
     lengths: np.ndarray      # [B] generated sequence end (index past last)
     logprobs: np.ndarray     # [B, total_len-1] logprob of each emitted token
-
-
-def _init_caches(cfg: ModelConfig, batch: int, total_len: int,
-                 int8: bool = False):
-    shape = (cfg.num_layers, batch, total_len, cfg.n_kv_heads, cfg.head_dim)
-    if int8:
-        # (k_q, v_q, k_scale, v_scale) — half the bytes of a bf16 cache;
-        # format is detected by tuple arity in attention_block
-        sshape = shape[:-1] + (1,)
-        return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                jnp.zeros(sshape, jnp.float32), jnp.zeros(sshape, jnp.float32))
-    return (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
 def _default_fwd(cfg):
@@ -82,7 +71,7 @@ def _generate_jit(
     fwd = forward_fn or _default_fwd(cfg)
     B = tokens.shape[0]
     min_len = jnp.min(lengths)
-    caches = _init_caches(cfg, B, total_len, int8=kv_cache_int8)
+    caches = kv_store.create(cfg, B, total_len, int8=kv_cache_int8)
 
     # Prefill the prompt region in one pass — the reference likewise batches
     # the common prompt prefix. min_len is dynamic, so the prefill runs a
@@ -222,8 +211,8 @@ def beam_search_tokens(
     """Beam search for one prompt (the reference's beam path also requires
     batch 1, text_generation/api.py:147). Host-side loop over a jitted
     scoring step; returns (beams [beam_size, total], scores [beam_size]).
-    The per-beam cache gathers are tree-mapped, so the int8 cache tuple
-    flows through unchanged."""
+    The per-beam cache gathers are kv_store's, so the int8 store flows
+    through unchanged."""
     prompt = np.asarray(prompt, np.int32)
     plen = len(prompt)
     total = plen + max_new_tokens
@@ -233,16 +222,16 @@ def beam_search_tokens(
     # prefill the prompt once at batch 1, tile the caches across beams, then
     # one single-token forward per emitted token with per-beam cache
     # reordering (gather over the batch axis) at each step.
-    caches = _init_caches(cfg, 1, total, int8=kv_cache_int8)
+    caches = kv_store.create(cfg, 1, total, int8=kv_cache_int8)
     prefill_logits, caches = lm_forward(
         cfg, params, jnp.asarray(prompt)[None, :],
         positions=jnp.arange(plen)[None, :], kv_caches=caches, cache_index=0)
-    caches = jax.tree.map(lambda c: jnp.repeat(c, beam_size, axis=1), caches)
+    caches = kv_store.repeat_rows(caches, beam_size)
     step_logits_dev = jnp.repeat(prefill_logits[:, -1], beam_size, axis=0)
 
     @jax.jit
     def decode_step(caches, parents, toks, t):
-        caches = jax.tree.map(lambda c: jnp.take(c, parents, axis=1), caches)
+        caches = kv_store.take_rows(caches, parents)
         pos = jnp.full((beam_size, 1), t, jnp.int32)
         logits, caches = lm_forward(cfg, params, toks[:, None], positions=pos,
                                     kv_caches=caches, cache_index=t)

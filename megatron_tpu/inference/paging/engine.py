@@ -43,6 +43,7 @@ from megatron_tpu.inference.paging.scheduler import (
     ChunkedPrefillQueue, PrefillTask,
 )
 from megatron_tpu.inference.sampling import sample_logits_batched
+from megatron_tpu.ops import kv_store
 
 
 class PagedInferenceEngine(InferenceEngine):
@@ -161,8 +162,8 @@ class PagedInferenceEngine(InferenceEngine):
         return self.page_size
 
     def _fresh_caches(self):
-        """Paged pools [L, num_pages, page_size, kv_heads, head_dim]
-        (int8: the 4-tuple with per-position scales). On the
+        """Paged pools: num_pages rows of page_size positions
+        (ops/kv_store.py; int8 with per-position scales). On the
         failed-step rebuild path every cached prefix dies with the pool
         bytes, and mid-prefill slots lose their computed chunks — fail
         them like the active ones the caller already failed."""
@@ -181,15 +182,8 @@ class PagedInferenceEngine(InferenceEngine):
             self.num_pages = self.num_slots * self.max_pages + 1
         else:
             self.max_pages = -(-self.max_seq_len // self.page_size)
-        cfg = self.cfg
-        shape = (cfg.num_layers, self.num_pages, self.page_size,
-                 cfg.n_kv_heads, cfg.head_dim)
-        if self.kv_cache_int8:
-            sshape = shape[:-1] + (1,)
-            return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                    jnp.zeros(sshape, jnp.float32),
-                    jnp.zeros(sshape, jnp.float32))
-        return (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
+        return kv_store.create(self.cfg, self.num_pages, self.page_size,
+                               int8=self.kv_cache_int8)
 
     def _fresh_draft_caches(self):
         """Draft-model page pools (speculative decoding): the draft
@@ -199,10 +193,8 @@ class PagedInferenceEngine(InferenceEngine):
         story covers both trees (a page shared via the radix cache is
         shared in both pools, since both were written through the same
         table by the original prefill). Always bf16/f32."""
-        dcfg = self.spec.draft_cfg
-        shape = (dcfg.num_layers, self.num_pages, self.page_size,
-                 dcfg.n_kv_heads, dcfg.head_dim)
-        return (jnp.zeros(shape, dcfg.dtype), jnp.zeros(shape, dcfg.dtype))
+        return kv_store.create(self.spec.draft_cfg, self.num_pages,
+                               self.page_size)
 
     def _spec_paged(self) -> bool:
         return True
@@ -731,25 +723,9 @@ class PagedInferenceEngine(InferenceEngine):
         pages = [int(p) for p in row[:n_pages]]
         if any(p == SCRATCH_PAGE for p in pages):
             return None
-        host = []
-        for leaf in jax.device_get(self.caches):
-            g = np.asarray(leaf)[:, pages]          # [L, n, ps, H, D]
-            host.append(g.reshape(g.shape[0], n_pages * ps,
-                                  *g.shape[3:])[:, :length])
+        host = kv_store.export_span(jax.device_get(self.caches), pages,
+                                    length)
         return self._pack_kv_sections(host, length)
-
-    def _page_blocks(self, leaves: List[np.ndarray], j: int):
-        """Page j's [L, page_size, ...] block of each canonical leaf
-        (zero-padded past the committed length)."""
-        ps = self.page_size
-        blocks = []
-        for leaf in leaves:
-            block = np.zeros((leaf.shape[0], ps) + leaf.shape[2:],
-                             leaf.dtype)
-            end = min(leaf.shape[1] - j * ps, ps)
-            block[:, :end] = leaf[:, j * ps:j * ps + end]
-            blocks.append(jnp.asarray(block))
-        return tuple(blocks)
 
     def _install_request_kv(self, req: Request, kv: dict,
                             sections) -> bool:
@@ -771,7 +747,8 @@ class PagedInferenceEngine(InferenceEngine):
         writer = self._kv_install_writer()
         self._sync_carry()
         for j, pg in enumerate(pages):
-            self.caches = writer(self.caches, self._page_blocks(leaves, j),
+            self.caches = writer(self.caches,
+                                 kv_store.span_block(leaves, j, ps),
                                  jnp.int32(pg))
         row = np.zeros(self.max_pages, np.int32)
         row[:n_pages] = pages
@@ -803,10 +780,8 @@ class PagedInferenceEngine(InferenceEngine):
                 return None
             ps = self.page_size
             span = len(pages) * ps
-            host = []
-            for leaf in jax.device_get(self.caches):
-                g = np.asarray(leaf)[:, [int(p) for p in pages]]
-                host.append(g.reshape(g.shape[0], span, *g.shape[3:]))
+            host = kv_store.export_span(jax.device_get(self.caches),
+                                        [int(p) for p in pages], span)
             kv_meta, sections = self._pack_kv_sections(host, span)
         meta = {"kind": "prefix", "tokens": toks[:span], "kv": kv_meta}
         # per-node logprob slices concatenate back into the engine's
@@ -842,7 +817,7 @@ class PagedInferenceEngine(InferenceEngine):
             writer = self._kv_install_writer()
             for j, pg in enumerate(pages):
                 self.caches = writer(self.caches,
-                                     self._page_blocks(leaves, j),
+                                     kv_store.span_block(leaves, j, ps),
                                      jnp.int32(pg))
             lp = np.asarray(sections.get("prefix_logprobs",
                                          np.zeros(0)), np.float32)

@@ -4,8 +4,8 @@ Equivalent of the reference's pipelined ForwardStep
 (megatron/text_generation/forward_step.py:45-204): there, each decode step
 streams (micro)batches through pipeline stages with NCCL p2p and the last
 stage broadcasts logits back. Here the layer stack runs under shard_map
-manual over the "pipe" axis — the stacked layer params and KV caches are
-sharded over their leading (layer) axis, the hidden state rotates
+manual over the "pipe" axis — the stacked layer params and the KV store
+(ops/kv_store.py) are sharded over their layers, the hidden state rotates
 stage-to-stage with lax.ppermute, and a final psum broadcasts the
 last stage's logits to every stage (the reference's
 broadcast_from_last_pipeline_stage, text_generation/communication.py).
@@ -27,6 +27,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.language_model import final_hidden_norm, lm_logits
 from megatron_tpu.models.transformer import block_forward
+from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.rotary import precompute_rope
 from megatron_tpu.training.pipeline import _embed_onehot
 
@@ -42,11 +43,11 @@ def make_pipelined_lm_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int):
     Lp = L // Pn
     perm = [(i, (i + 1) % Pn) for i in range(Pn)]
 
-    def pipelined(layers, other, tokens, positions, ck, cv, cache_index):
+    def pipelined(layers, other, tokens, positions, caches, cache_index):
         params_local = dict(other, layers=layers)
         stage = jax.lax.axis_index("pipe")
         B, S = tokens.shape
-        total = ck.shape[2]
+        total = kv_store.logical_length(caches)
 
         rope = None
         if cfg.position_embedding_type == "rotary":
@@ -57,25 +58,29 @@ def make_pipelined_lm_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int):
                            positions=positions).astype(cfg.dtype)
 
         def tick(carry, t):
-            state, ck, cv, logits = carry
+            state, caches, logits = carry
             active = t == stage
 
             def compute(args):
-                state, ck, cv = args
+                state, caches = args
                 x = jnp.where(stage == 0, x0, state)
 
-                def lbody(x, sc):
-                    lp, k1, v1 = sc
-                    y, new_kv, _ = block_forward(
-                        cfg, lp, x, rope, positions,
-                        kv_cache=(k1, v1), cache_index=cache_index)
-                    return y, new_kv
+                # this stage's layers write their rows into its shard of
+                # the store in place, as lm_forward's scan does
+                def lbody(c, sc):
+                    x, caches = c
+                    lp, idx = sc
+                    y, caches, _ = block_forward(
+                        cfg, lp, x, rope, positions, kv_cache=caches,
+                        layer=idx, cache_index=cache_index)
+                    return (y, caches), None
 
-                y, (nk, nv) = jax.lax.scan(lbody, x, (layers, ck, cv))
-                return y, nk, nv
+                (y, caches), _ = jax.lax.scan(
+                    lbody, (x, caches), (layers, jnp.arange(Lp)))
+                return y, caches
 
-            state2, ck2, cv2 = jax.lax.cond(
-                active, compute, lambda a: a, (state, ck, cv))
+            state2, caches2 = jax.lax.cond(
+                active, compute, lambda a: a, (state, caches))
 
             def mk_logits(_):
                 h = final_hidden_norm(cfg, params_local, state2)
@@ -84,32 +89,34 @@ def make_pipelined_lm_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int):
             logits = jax.lax.cond(active & (stage == Pn - 1), mk_logits,
                                   lambda _: logits, None)
             state3 = jax.lax.ppermute(state2, "pipe", perm)
-            return (state3, ck2, cv2, logits), None
+            return (state3, caches2, logits), None
 
         V = (cfg.vocab_size if not cfg.tie_embed_logits
              else params_local["embed"]["tokens"].shape[0])
-        init = (jnp.zeros((B, S, cfg.hidden_size), cfg.dtype), ck, cv,
+        init = (jnp.zeros((B, S, cfg.hidden_size), cfg.dtype), caches,
                 jnp.zeros((B, S, V), jnp.float32))
-        (state, ck, cv, logits), _ = jax.lax.scan(tick, init, jnp.arange(Pn))
+        (state, caches, logits), _ = jax.lax.scan(tick, init,
+                                                  jnp.arange(Pn))
         # zeros everywhere but the last stage: psum = broadcast
         logits = jax.lax.psum(logits, "pipe")
-        return logits, ck, cv
+        return logits, caches
 
     def fwd(params, tokens, positions, caches, cache_index):
         layers = params["layers"]
         other = {k: v for k, v in params.items() if k != "layers"}
+        by_stage = tuple(kv_store.partition_spec(layers="pipe")
+                         for _ in caches)
         fn = jax.shard_map(
             pipelined,
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P("pipe"), layers),
                       jax.tree.map(lambda _: P(), other),
-                      P(), P(), P("pipe"), P("pipe"), P()),
-            out_specs=(P(), P("pipe"), P("pipe")),
+                      P(), P(), by_stage, P()),
+            out_specs=(P(), by_stage),
             axis_names={"pipe"},
             check_vma=False,
         )
-        logits, ck, cv = fn(layers, other, tokens, positions,
-                            caches[0], caches[1], cache_index)
-        return logits, (ck, cv)
+        return fn(layers, other, tokens, positions, tuple(caches),
+                  cache_index)
 
     return fwd

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.transformer import Sharder, _dropout, _identity_sharder, block_forward
+from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.cross_entropy import (
     chunked_head_loss, cross_entropy_loss,
 )
@@ -214,7 +215,7 @@ def lm_forward(
     dropout_key: Optional[jax.Array] = None,
     recompute: str = "none",
     sharder: Sharder = _identity_sharder,
-    kv_caches: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # [L,B,Smax,nkv,D] x2
+    kv_caches=None,   # an ops/kv_store.py store: slots, or pages
     cache_index=None,
     return_hidden: bool = False,
     return_moe_aux: bool = False,
@@ -228,15 +229,17 @@ def lm_forward(
 ):
     """Forward pass to logits.
 
-    kv_caches: stacked per-layer caches for incremental decoding; when
-    given, returns (logits, updated_caches).
+    kv_caches: the stacked KV store for incremental decoding
+    (ops/kv_store.py: `create` makes one); when given, returns
+    (logits, updated_caches). Donate it: the layers write their new rows
+    into it in place.
 
     return_moe_aux: also return [aux loss summed over the layers, the
     worst layer's load statistic] (ops/moe.py layer_stats).
 
-    page_table: the caches are PAGED pools [L, num_pages, page_size,
-    nkv, D] (inference/paging/) shared by every slot; each row's logical
-    context is page_table[b] physical pages. The table is broadcast to
+    page_table: the store is a pool of pages (inference/paging/) shared
+    by every slot; each row's logical context is page_table[b] physical
+    pages. The table is broadcast to
     all layers (the paging engine allocates one table per slot, not per
     layer).
     """
@@ -261,18 +264,8 @@ def lm_forward(
 
     rope = None
     if cfg.position_embedding_type == "rotary":
-        if kv_caches is not None and page_table is not None:
-            # paged pools are [L, num_pages, page_size, ...]: the logical
-            # max length is the table width x page size, not shape[2].
-            # A context-parallel table ([cp, rows, pages_per_rank]) covers
-            # cp x pages_per_rank logical pages per row.
-            if getattr(page_table, "ndim", 2) == 3:
-                rope_len = (page_table.shape[0] * page_table.shape[2]
-                            * kv_caches[0].shape[2])
-            else:
-                rope_len = page_table.shape[1] * kv_caches[0].shape[2]
-        elif kv_caches is not None:
-            rope_len = kv_caches[0].shape[2]  # cache max length
+        if kv_caches is not None:
+            rope_len = kv_store.logical_length(kv_caches, page_table)
         else:
             rope_len = max(cfg.seq_length, tokens.shape[1])
         rope = precompute_rope(cfg.head_dim, rope_len, cfg.rope_theta,
@@ -282,15 +275,20 @@ def lm_forward(
     moe = cfg.num_experts is not None
     add_aux = merge_layer_stats if moe else operator.add
 
+    # the caches ride in the carry: each layer writes its rows into the
+    # stacked store in place (ops/kv_store.py) and the store the caller
+    # donated is the one handed back. As a scanned input and output the
+    # scan would build a second store, layer by layer, every call.
     def body(carry, scanned):
-        x, aux = carry
-        lp, rate, idx, caches = scanned
+        x, aux, caches = carry
+        lp, rate, idx = scanned
         key = jax.random.fold_in(dropout_key, idx) if train else None
-        y, new_cache, moe_aux = block_forward(
+        y, caches, moe_aux = block_forward(
             cfg, lp, x, rope, positions,
             dropout_key=key,
             hidden_dropout_rate=rate,
             kv_cache=caches,
+            layer=idx,
             cache_index=cache_index,
             sharder=sharder,
             padding_mask=attention_mask,
@@ -300,14 +298,15 @@ def lm_forward(
             tp_comm=tp_comm,
             cp_comm=cp_comm,
         )
-        return (y, add_aux(aux, moe_aux)), new_cache
+        return (y, add_aux(aux, moe_aux), caches), None
 
     layer_idx = jnp.arange(cfg.num_layers)
-    xs = (params["layers"], rates, layer_idx, kv_caches)
+    xs = (params["layers"], rates, layer_idx)
     if kv_caches is not None and parse_recompute(recompute)[1] is not None:
         recompute = "none"  # decode path: caches preclude the split scan
-    (x, moe_aux), new_caches = scan_with_remat(
-        body, (x, jnp.zeros((2,) if moe else (), jnp.float32)), xs, recompute)
+    (x, moe_aux, new_caches), _ = scan_with_remat(
+        body, (x, jnp.zeros((2,) if moe else (), jnp.float32), kv_caches),
+        xs, recompute)
 
     # "head_loss" names the final norm, the head and (in lm_loss) the
     # cross-entropy: one region of the step in a device trace

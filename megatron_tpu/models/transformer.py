@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from megatron_tpu.config import ModelConfig
+from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.activations import apply_activation
 from megatron_tpu.ops.attention import attention
 from megatron_tpu.ops.fp8 import maybe_fp8_matmul
@@ -61,7 +62,8 @@ def attention_block(
     rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]],
     positions: Optional[jnp.ndarray],
     attn_dropout_key: Optional[jax.Array] = None,
-    kv_cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+    kv_cache=None,      # ops/kv_store.py store, all layers stacked
+    layer=None,         # this layer's index into the store
     cache_index=None,
     padding_mask: Optional[jnp.ndarray] = None,  # [B, S] True = attend
     page_table: Optional[jnp.ndarray] = None,    # [B, max_pages] int32
@@ -69,33 +71,25 @@ def attention_block(
     page_write_end: Optional[jnp.ndarray] = None,    # scalar int32
     tp_comm=None,  # quant.TpComm: explicit/compressed TP collectives
     cp_comm=None,  # quant.CpComm: context-parallel ring transport
-) -> Tuple[jnp.ndarray, Optional[Tuple[jnp.ndarray, jnp.ndarray]]]:
-    """Returns (out [B,S,h], updated kv_cache).
+):
+    """Returns (out [B,S,h], kv_cache with this layer's rows written).
 
     tp_comm (serving, quant/collectives.py): route the row-parallel
     output projection through an explicit shard_map collective — dense
     psum or the compressed (int8/fp8) two-step — instead of GSPMD's
     inserted all-reduce. None = the GSPMD path, unchanged.
 
-    page_table: the cache tuple holds PAGED pools [num_pages, page_size,
-    nkv, D] (inference/paging/) instead of dense [B, S, nkv, D] buffers;
-    new K/V scatters through the table to each position's physical page
-    and attention reads back through it (ops/attention.py). Two shapes:
-    single-token decode (vector cache_index — every slot at its own
-    depth) and single-row chunked prefill (traced scalar cache_index,
-    s > 1, batch 1 — one chunk of one prompt lands at positions
-    cache_index..cache_index+s-1).
+    kv_cache is the whole stacked store (ops/kv_store.py owns its
+    format): this layer writes its new K/V rows into it in place at
+    cache_index and attends what the store then holds. A vector
+    cache_index is the continuous-batching cache (every row at its own
+    depth: s == 1 plain decode, s > 1 the speculative verify pass, row
+    b's queries at cache_index[b]..cache_index[b]+s-1); a scalar is a
+    prefill, one chunk of one prompt, or one-shot generation's step.
 
-    page_write_start / page_write_end (chunked prefill only): positions
-    outside [start, end) redirect their K/V write to the reserved
-    scratch page. The first chunk after a prefix-cache hit starts ONE
-    position inside the shared span (so the boundary token's
-    teacher-forced logprob is recomputed exactly), and the start fence
-    keeps that overlap query from rewriting a refcount-shared page —
-    shared pages are copy-on-write: never written through a sharer's
-    table. The end fence (the prompt length) parks the final chunk's
-    padded-tail writes on scratch, where an index-clipped write could
-    otherwise scribble a live page."""
+    page_table: the store is a pool of pages shared by every row
+    (inference/paging/). page_write_start / page_write_end (chunked
+    prefill only) fence the chunk's writes: kv_store.write says why."""
     b, s, _ = x.shape
     D = cfg.head_dim
     nq, nkv = cfg.num_attention_heads, cfg.n_kv_heads
@@ -127,175 +121,55 @@ def attention_block(
     # against the full cache on the dense path, where GSPMD shards the
     # [.., 1, S] score row over a context-sharded cache (flash-decoding
     # by partitioner).
+    # Not over pages (paged serving replaces it with the ring path below)
+    # and not with an int8 store: attending the fresh bf16 k/v would
+    # diverge from the dequantized-cache numerics the int8 tests pin down.
     cp_prefill = (type(cache_index) is int and cache_index == 0 and s > 1
-                  and cfg.attention_impl in ("ring", "ulysses"))
+                  and cfg.attention_impl in ("ring", "ulysses")
+                  and page_table is None
+                  and not (kv_cache is not None
+                           and kv_store.is_int8(kv_cache)))
 
-    # A vector cache_index is the continuous-batching slot cache
-    # (inference/engine.py): every row decodes at its OWN depth, so each
-    # row's new K/V scatters to its own position and attention masks each
-    # row to its own valid prefix (kv_lengths). s == 1 is plain decode;
-    # s > 1 is the speculative verify pass (inference/speculative.py) —
-    # row b's queries land at positions cache_index[b]..cache_index[b]+s-1
-    # and each sees one position more than the last (kv_lengths + j).
     per_slot = getattr(cache_index, "ndim", 0) == 1
-
-    paged = page_table is not None
     # a 3-D page table ([cp, rows, pages_per_rank], sharded over the
     # "context" mesh axis) selects the context-parallel paged path: the
     # KV pools are sequence-striped and attention runs as a ring over
     # per-rank partials (inference/context_parallel/ring_kv.py)
-    cp_paged = paged and getattr(page_table, "ndim", 2) == 3
-    if paged:
-        if kv_cache is None:
-            raise ValueError("page_table requires a (paged) kv_cache")
-        cp_prefill = False  # paged serving replaces it with the ring path
-        if not per_slot and b != 1:
-            raise ValueError(
-                f"paged chunked prefill is single-row (batch {b})")
+    cp_paged = page_table is not None and page_table.ndim == 3
+    if page_table is not None and kv_cache is None:
+        raise ValueError("page_table requires a (paged) kv_cache")
+
+    q_offset = 0
+    kv_lengths = None
+    table = None
+    ctx = None
     if cp_paged:
         if cp_comm is None:
             raise ValueError(
                 "a [cp, rows, pages] page table requires cp_comm "
                 "(quant/collectives.make_cp_comm)")
-        if len(kv_cache) == 4:
+        if kv_store.is_int8(kv_cache):
             raise ValueError(
                 "context-parallel paged serving does not support int8 "
                 "KV pools (stripe the bf16 pools instead)")
-
-    def _paged_write(store, new):
-        """Scatter new rows through the page table. Decode: new [B,1,...]
-        lands at each row's own depth; speculative verify: new [B,s,...]
-        lands at positions cache_index[b]..cache_index[b]+s-1 per row.
-        Chunk: new [1,C,...] lands at positions
-        cache_index..cache_index+C-1 of row 0."""
-        ps = store.shape[1]
-        if per_slot:
-            if s == 1:
-                pos = cache_index                          # [B]
-                phys = jnp.take_along_axis(
-                    page_table, (pos // ps)[:, None], axis=1,
-                    mode="clip")[:, 0]
-                return store.at[phys, pos % ps].set(
-                    new[:, 0].astype(store.dtype))
-            pos = cache_index[:, None] + jnp.arange(s)     # [B, s]
-            phys = jnp.take_along_axis(page_table, pos // ps, axis=1,
-                                       mode="clip")
-            return store.at[phys, pos % ps].set(new.astype(store.dtype))
-        pos = cache_index + jnp.arange(s)                  # [C]
-        phys = jnp.take(page_table[0], pos // ps, mode="clip")
-        if page_write_start is not None:
-            # overlap queries below the write fence read the shared pages
-            # but park their (identical-valued) K/V on scratch
-            phys = jnp.where(pos >= page_write_start, phys, 0)
-        if page_write_end is not None:
-            # padded-tail queries past the prompt park on scratch too
-            phys = jnp.where(pos < page_write_end, phys, 0)
-        return store.at[phys, pos % ps].set(new[0].astype(store.dtype))
-
-    q_offset = 0
-    kv_lengths = None
-    ctx = None
-    if cp_paged:
         from megatron_tpu.inference.context_parallel.ring_kv import (
             paged_ring_attention,
         )
 
         ctx, kv_cache = paged_ring_attention(
-            cp_comm, q, k, v, kv_cache, page_table, cache_index,
+            cp_comm, q, k, v, kv_cache, layer, page_table, cache_index,
             per_slot, page_write_start, page_write_end,
             sliding_window=cfg.sliding_window_size)
-    elif paged and len(kv_cache) == 4:
-        # int8 paged pools: quantize the new rows on write, dequantize the
-        # whole pool for attention — the same numerics as the dense int8
-        # slot cache (quantize-once, dequantize-everything), so the paged
-        # engine stays token-identical to the slot engine in int8 mode
-        from megatron_tpu.ops.kv_quant import dequantize_kv, quantize_kv
-
-        kq, vq, ks, vs = kv_cache
-        knew, ksnew = quantize_kv(k)
-        vnew, vsnew = quantize_kv(v)
-        kq, vq = _paged_write(kq, knew), _paged_write(vq, vnew)
-        ks, vs = _paged_write(ks, ksnew), _paged_write(vs, vsnew)
-        kv_cache = (kq, vq, ks, vs)
-        k = dequantize_kv(kq, ks, cfg.dtype)
-        v = dequantize_kv(vq, vs, cfg.dtype)
-        if per_slot:
-            kv_lengths = cache_index + 1
-        else:
-            q_offset = cache_index
-    elif paged:
-        kc, vc = kv_cache
-        kc, vc = _paged_write(kc, k), _paged_write(vc, v)
-        kv_cache = (kc, vc)
-        k, v = kc, vc
-        if per_slot:
-            kv_lengths = cache_index + 1
-        else:
-            q_offset = cache_index
-    elif kv_cache is not None and len(kv_cache) == 4:
-        # int8 KV cache (serving option): quantize the new K/V slice on
-        # write, dequantize the whole cache for attention — cache bytes
-        # halve vs bf16 (ops/kv_quant.py)
-        from megatron_tpu.ops.kv_quant import dequantize_kv, quantize_kv
-
-        kq, vq, ks, vs = kv_cache
-        knew, ksnew = quantize_kv(k)
-        vnew, vsnew = quantize_kv(v)
-        if per_slot and s == 1:
-            rows = jnp.arange(b)
-            kq = kq.at[rows, cache_index].set(knew[:, 0])
-            vq = vq.at[rows, cache_index].set(vnew[:, 0])
-            ks = ks.at[rows, cache_index].set(ksnew[:, 0].astype(ks.dtype))
-            vs = vs.at[rows, cache_index].set(vsnew[:, 0].astype(vs.dtype))
-            kv_lengths = cache_index + 1
-        elif per_slot:
-            # speculative verify: s tokens per row at each row's depth
-            rows = jnp.arange(b)[:, None]
-            pos = cache_index[:, None] + jnp.arange(s)     # [B, s]
-            kq = kq.at[rows, pos].set(knew)
-            vq = vq.at[rows, pos].set(vnew)
-            ks = ks.at[rows, pos].set(ksnew.astype(ks.dtype))
-            vs = vs.at[rows, pos].set(vsnew.astype(vs.dtype))
-            kv_lengths = cache_index + 1
-        else:
-            at = (0, cache_index, 0, 0)
-            kq = jax.lax.dynamic_update_slice(kq, knew, at)
-            vq = jax.lax.dynamic_update_slice(vq, vnew, at)
-            ks = jax.lax.dynamic_update_slice(ks, ksnew.astype(ks.dtype), at)
-            vs = jax.lax.dynamic_update_slice(vs, vsnew.astype(vs.dtype), at)
-            q_offset = cache_index
-        k = dequantize_kv(kq, ks, cfg.dtype)
-        v = dequantize_kv(vq, vs, cfg.dtype)
-        kv_cache = (kq, vq, ks, vs)
-        cp_prefill = False  # int8 serving is single-chip scope (STATUS
-        # #30); attending the fresh bf16 k/v here would silently diverge
-        # from the dequantized-cache numerics the int8 tests pin down
     elif kv_cache is not None:
-        # functional KV cache: fixed-size [B, max_seq, nkv, D] buffers,
-        # in-place slice update at cache_index (donated under jit).
-        kc, vc = kv_cache
-        if per_slot and s == 1:
-            rows = jnp.arange(b)
-            kc = kc.at[rows, cache_index].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[rows, cache_index].set(v[:, 0].astype(vc.dtype))
-            kv_cache = (kc, vc)
-            k, v = kc, vc
-            kv_lengths = cache_index + 1
-        elif per_slot:
-            # speculative verify: s tokens per row at each row's depth
-            rows = jnp.arange(b)[:, None]
-            pos = cache_index[:, None] + jnp.arange(s)     # [B, s]
-            kc = kc.at[rows, pos].set(k.astype(kc.dtype))
-            vc = vc.at[rows, pos].set(v.astype(vc.dtype))
-            kv_cache = (kc, vc)
-            k, v = kc, vc
-            kv_lengths = cache_index + 1
-        else:
-            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), (0, cache_index, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), (0, cache_index, 0, 0))
-            kv_cache = (kc, vc)
-            if not cp_prefill:
-                k, v = kc, vc
+        kv_cache = kv_store.write(kv_cache, layer, k, v, cache_index,
+                                  page_table, page_write_start,
+                                  page_write_end)
+        if not cp_prefill:
+            k, v, table = kv_store.read(kv_cache, layer, page_table,
+                                        cfg.dtype)
+            if per_slot:
+                kv_lengths = cache_index + 1
+            else:
                 q_offset = cache_index
 
     if cfg.attn_mask_type == "padding" and padding_mask is None:
@@ -316,7 +190,7 @@ def attention_block(
             impl=cfg.attention_impl,
             softmax_fp32=cfg.softmax_fp32,
             kv_lengths=kv_lengths,
-            page_table=page_table,
+            page_table=table,
         )
     if tp_comm is not None and "attn_out" in tp_comm.sites:
         # explicit row-parallel reduction (dense psum or the compressed
@@ -371,7 +245,8 @@ def block_forward(
     positions: Optional[jnp.ndarray] = None,
     dropout_key: Optional[jax.Array] = None,
     hidden_dropout_rate=None,
-    kv_cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+    kv_cache=None,      # ops/kv_store.py store, all layers stacked
+    layer=None,         # this layer's index into the store
     cache_index=None,
     sharder: Sharder = _identity_sharder,
     padding_mask: Optional[jnp.ndarray] = None,
@@ -380,8 +255,9 @@ def block_forward(
     page_write_end: Optional[jnp.ndarray] = None,
     tp_comm=None,
     cp_comm=None,
-) -> Tuple[jnp.ndarray, Optional[Tuple[jnp.ndarray, jnp.ndarray]], jnp.ndarray]:
-    """One decoder layer -> (y, kv_cache, moe_aux).
+):
+    """One decoder layer -> (y, kv_cache, moe_aux): kv_cache is the whole
+    store with this layer's rows written (attention_block).
 
     hidden_dropout_rate may be a traced scalar (LIMA per-layer ramp, ref
     transformer.py:994-1001). moe_aux is a zero scalar for dense models
@@ -404,7 +280,7 @@ def block_forward(
         attn_out, kv_cache = attention_block(
             cfg, lp["attn"], normed, rope, positions,
             attn_dropout_key=k_attn_drop if cfg.attention_dropout > 0 else None,
-            kv_cache=kv_cache, cache_index=cache_index,
+            kv_cache=kv_cache, layer=layer, cache_index=cache_index,
             padding_mask=padding_mask,
             page_table=page_table,
             page_write_start=page_write_start,

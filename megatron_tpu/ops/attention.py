@@ -170,19 +170,23 @@ def attention(
     single- and multi-query variants) which skip cache blocks past each
     row's prefix; elsewhere a masked einsum computes the same values.
 
-    page_table: paged KV cache (inference/paging/): k/v are the shared
-    page pools [num_pages, page_size, Hkv, D] and each row's logical
-    context is page_table[b] physical pages. With kv_lengths (single-token
-    decode) the TPU path is the paged flash-decode kernel
+    page_table: a KV cache as ops/kv_store.py presents one (`read`):
+    k/v are page pools [num_pages, page_size, Hkv, D] and each row's
+    logical context is page_table[b] physical pages (a slot cache comes
+    as a pool whose pages are whole rows). With kv_lengths (decode) the
+    TPU path is the paged flash-decode kernel
     (flash_template.paged_flash_decode) which resolves pages inside the
     grid; everywhere else the pages are gathered into a dense [B, S, ...]
     view and the existing masked paths compute identical values (the
     gather is exact — pages hold the same bits a dense cache would).
     """
     if page_table is not None:
+        from megatron_tpu.ops import kv_store
+
         if (kv_lengths is not None
                 and impl == "pallas" and _kernels_dispatchable()):
-            use, plan = _shard_plan("paged decode", q.shape[0], k.shape[2])
+            use, plan = _shard_plan("paged decode", q.shape[0],
+                                    kv_store.pool_dims(k)[2])
             if use:
                 # q_len > 1 is the multi-query decode (speculative verify:
                 # k+1 query rows per slot, each one position deeper)
@@ -196,9 +200,8 @@ def attention(
                     (q, k, v, page_table, kv_lengths), paged=True)
         # dense path (exact): materialize each row's logical context
         # from its pages, then flow into the masked einsum below unchanged
-        bq = q.shape[0]
-        k = k[page_table].reshape(bq, -1, *k.shape[-2:])
-        v = v[page_table].reshape(bq, -1, *v.shape[-2:])
+        k = kv_store.gather_pages(k, page_table)
+        v = kv_store.gather_pages(v, page_table)
     if kv_lengths is not None:
         # q_len == 1 is plain continuous-batching decode; q_len > 1 is
         # the speculative verify pass — query j of a row sits at

@@ -10,9 +10,11 @@ instantiation of the one template in this module, with four knobs:
                 |                         | q tile [BQ, D] (FA-2 partitioning:
                 |                         | parallel over Sq blocks and heads, kv
                 |                         | axis innermost+sequential); decode:
-                |                         | grid (B, Hkv, Skv/BK), q tile
-                |                         | [Sq*G, D] (the Sq-small
-                |                         | specialization — all of one kv
+                |                         | grid (B, Skv/BK), kv tile
+                |                         | [BK*Hkv, D] (BK cache positions with
+                |                         | every kv head, as the cache lies in
+                |                         | memory), q tile [Hkv*Sq*G, D] (the
+                |                         | Sq-small specialization — every kv
                 |                         | head's grouped queries ride in one
                 |                         | MXU tile, K/V never replicated)
   mask          | causal / bidirectional, | ops/pallas/masks.py: ONE position
@@ -22,7 +24,9 @@ instantiation of the one template in this module, with four knobs:
   paging        | dense / page table      | the page table rides in as a
                 |                         | scalar-prefetch operand; BlockSpec
                 |                         | index maps dereference it at
-                |                         | DMA-issue time (no dense gather)
+                |                         | DMA-issue time (no dense gather); a
+                |                         | dense cache is a pool whose pages
+                |                         | are whole rows
   gradient      | fwd-only / custom_vjp   | the FA-2 recompute backward: fwd
                 |                         | saves lse, bwd recomputes p from
                 |                         | (q, k, lse), one kernel accumulates
@@ -49,9 +53,12 @@ l, lse, delta), exp, the 1/sqrt(d) scale and all accumulators are
 float32. bf16 in: bf16 MXU passes. float32 in (the CPU suite, a float32
 reference): float32 operands, at the backend's default matmul precision.
 
-Layouts: public entries take the framework-native [B, S, H, D]; kernels
-run on [B, H, S, D] so the (S, D) tile is MXU-facing. Kernels run in
-interpreter mode on CPU hosts (tests/CI) and compile for real on TPU.
+Layouts: public entries take the framework-native [B, S, H, D]; the
+training kernels run on [B, H, S, D] so the (S, D) tile is MXU-facing
+(their transposes are of activations). The decode kernels read a KV cache
+where it lies, [rows, positions, Hkv, D] (ops/kv_store.py): no cache is
+transposed or copied for them. Kernels run in interpreter mode on CPU
+hosts (tests/CI) and compile for real on TPU.
 """
 
 from __future__ import annotations
@@ -65,6 +72,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.pallas import masks
 
 _NEG_INF = masks.NEG_INF
@@ -625,22 +633,24 @@ def flash_mha(
 # ---------------------------------------------------------------------------
 
 
-def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr,
                    *, scale: float, window: Optional[int], block_k: int,
-                   groups: int, sq: int):
+                   kv_heads: int, groups: int, sq: int):
     """ONE body for all four decode instantiations (single/multi-query x
-    dense/paged). The q tile is the Sq speculative query rows x G
-    grouped heads of one kv head, flattened to [Sq*G, D] (sq == 1 is
-    plain decode: the tile is just the G grouped heads). Row r is
-    speculative query r // G at global position kv_len - 1 + r // G;
-    masks.py turns those positions into the element mask and the
-    block-skip predicate. The paged variant reuses this body unchanged —
-    page resolution happens in the BlockSpec index maps, queries never
-    see it."""
+    dense/paged). The kv tile is block_k cache positions with EVERY kv
+    head, [block_k * Hkv, D], exactly as a cache row lies in memory
+    (ops/kv_store.py), so no caller transposes a cache for this kernel.
+    The q tile stacks, per kv head, the Sq speculative query rows x G
+    grouped heads: [Hkv * Sq * G, D] (sq == 1 is plain decode). One
+    matmul forms the scores of every query against every key of the
+    tile; masks.py says which pairs share a kv head and a visible
+    position, and the block-skip predicate. The page table rides as the
+    second scalar-prefetch operand for the BlockSpec index maps: page
+    resolution happens there, the body never reads it."""
     b = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
+    nk = pl.num_programs(1)
     kv_len = lens_ref[b]
     rows = sq * groups
 
@@ -652,26 +662,27 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     # blocks past the deepest query's frontier (kv_len + sq - 2) — or,
     # windowed, entirely before the shallowest query's window — never
-    # load/compute: a young slot in a long cache is cheap, and
-    # scratch-mapped unallocated page-table entries are skipped the same
-    # way
+    # compute: a young slot in a long cache is cheap, and scratch-mapped
+    # unallocated page-table entries are skipped the same way
     @pl.when(masks.decode_block_live(ki, block_k, kv_len, sq,
                                      window=window))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # [rows, D]
-        k = k_ref[0, 0].astype(jnp.float32)              # [BK, D]
+        q = q_ref[0].astype(jnp.float32) * scale         # [Hkv*rows, D]
+        k = k_ref[0].astype(jnp.float32)                 # [BK*Hkv, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
 
         q_pos, k_pos = masks.decode_positions(ki, block_k, kv_len,
-                                              groups, rows)
-        allowed = masks.visible(q_pos, k_pos, causal=True, window=window)
+                                              groups, rows, kv_heads)
+        allowed = (masks.decode_same_head(block_k, rows, kv_heads)
+                   & masks.visible(q_pos, k_pos, causal=True,
+                                   window=window))
         s = jnp.where(allowed, s, _NEG_INF)
 
-        m_prev = m_scr[:]                                # [rows, 1]
+        m_prev = m_scr[:]                                # [Hkv*rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.where(allowed, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        v = v_ref[0, 0].astype(jnp.float32)              # [BK, D]
+        v = v_ref[0].astype(jnp.float32)                 # [BK*Hkv, D]
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
         acc_scr[:] = acc_scr[:] * alpha + pv
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
@@ -680,91 +691,83 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ki == nk - 1)
     def _emit():
         l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
-def _with_page_table(kernel):
-    """Adapt the decode body to the scalar-prefetch calling convention:
-    the page table rides as the second prefetch operand for the
-    BlockSpec index maps, but the body itself never reads it."""
-    def paged_kernel(lens_ref, pt_ref, *rest):
-        kernel(lens_ref, *rest)
-    return paged_kernel
+# the most rows of a kv tile ([block_k * Hkv, D]): 2048 rows of 128 bf16
+# are 0.5 MB, so k and v double-buffered, their float32 copies and the
+# [q rows, 2048] scores stay well inside the default scoped VMEM
+_DECODE_TILE_ROWS = 2048
 
 
-def _decode_call(q, k, v, kv_lengths, *, window: Optional[int], blk: int,
-                 page_table=None):
-    """Shared launch for the decode specialization. Dense: k/v
-    [B, Skv, Hkv, D], blk = kv block. Paged: k/v are the page pools
-    [P, ps, Hkv, D], blk = page size, one page per grid step."""
+def _decode_block(page_size: int, kv_heads: int, cap: int) -> int:
+    """Cache positions a grid step takes: the whole page, or for a page
+    longer than `cap` positions or _DECODE_TILE_ROWS tile rows (a slot
+    cache's row is one page of the whole sequence) the largest power of
+    two under both that divides it."""
+    cap = min(cap, max(8, _DECODE_TILE_ROWS // kv_heads))
+    if page_size <= cap:
+        return page_size
+    for blk in (256, 128, 64, 32, 16, 8):
+        if blk <= cap and page_size % blk == 0:
+            return blk
+    return page_size
+
+
+def _decode_call(q, k_pages, v_pages, page_table, kv_lengths, *,
+                 window: Optional[int], name: str, block_k: int = 256):
+    """Shared launch for the decode specialization: k/v are page pools
+    [P, ps, Hkv, D] in the cache's own layout and a grid step takes one
+    page of one row through its table [B, n] (a page longer than the
+    block is addressed as several). Nothing here copies a pool: the
+    tile views are reshapes of contiguous memory."""
     b, sq, hq, d = q.shape
-    hkv = k.shape[2]
+    _, ps, hkv, _ = kv_store.pool_dims(k_pages)
     groups = hq // hkv
     rows = sq * groups
+    blk = _decode_block(ps, hkv, block_k)
 
-    # [B, Sq, Hkv, G, D] -> [B, Hkv, Sq*G, D]: the q tile is all Sq
-    # queries' grouped heads of one kv head
+    # [B, Sq, Hkv, G, D] -> [B, Hkv*Sq*G, D]: per kv head, all Sq
+    # queries' grouped heads
     qt = q.reshape(b, sq, hkv, groups, d).transpose(0, 2, 1, 3, 4)
-    qt = qt.reshape(b, hkv, rows, d)
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
+    qt = qt.reshape(b, hkv * rows, d)
+    kt = k_pages.reshape(-1, blk * hkv, d)
+    vt = v_pages.reshape(-1, blk * hkv, d)
     lens = jnp.asarray(kv_lengths, jnp.int32)
+    table = jnp.asarray(page_table, jnp.int32)
+    if blk != ps:
+        table = (table[:, :, None] * (ps // blk)
+                 + jnp.arange(ps // blk, dtype=jnp.int32)).reshape(b, -1)
 
     kernel = functools.partial(
-        _decode_kernel, scale=float(1.0 / (d ** 0.5)),
-        window=window, block_k=blk, groups=groups, sq=sq)
-    scratch_shapes = [
-        pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((rows, 1), jnp.float32),
-        pltpu.VMEM((rows, d), jnp.float32),
-    ]
-
-    if page_table is None:
-        skv = k.shape[1]
-        o = _named_pallas_call(
-            "flash_decode", kernel,
-            grid=(b, hkv, skv // blk),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1, rows, d), lambda bi, h, ki: (bi, h, 0, 0)),
-                pl.BlockSpec((1, 1, blk, d), lambda bi, h, ki: (bi, h, ki, 0)),
-                pl.BlockSpec((1, 1, blk, d), lambda bi, h, ki: (bi, h, ki, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, rows, d),
-                                   lambda bi, h, ki: (bi, h, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-            scratch_shapes=scratch_shapes,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=_interpret(),
-        )(lens, qt, kt, vt)
-    else:
-        table = jnp.asarray(page_table, jnp.int32)
-        max_pages = table.shape[1]
-        # scalar-prefetch index maps: (grid indices..., lens_ref, pt_ref)
-        # -> block indices; the kv maps dereference the page table so the
-        # DMA fetches the slot's physical page for this logical block
-        grid_spec = pltpu.PrefetchScalarGridSpec(
+        _decode_kernel, scale=float(1.0 / (d ** 0.5)), window=window,
+        block_k=blk, kv_heads=hkv, groups=groups, sq=sq)
+    # scalar-prefetch index maps: (grid indices..., lens_ref, table_ref)
+    # -> block indices; the kv maps dereference the page table so the
+    # DMA fetches the row's physical page for this logical block
+    q_map = lambda bi, ki, lens, pt: (bi, 0, 0)  # noqa: E731
+    kv_map = lambda bi, ki, lens, pt: (pt[bi, ki], 0, 0)  # noqa: E731
+    o = _named_pallas_call(
+        name, kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, hkv, max_pages),
+            grid=(b, table.shape[1]),
             in_specs=[
-                pl.BlockSpec((1, 1, rows, d),
-                             lambda bi, h, ki, lens, pt: (bi, h, 0, 0)),
-                pl.BlockSpec((1, 1, blk, d),
-                             lambda bi, h, ki, lens, pt: (pt[bi, ki], h, 0, 0)),
-                pl.BlockSpec((1, 1, blk, d),
-                             lambda bi, h, ki, lens, pt: (pt[bi, ki], h, 0, 0)),
+                pl.BlockSpec((1, hkv * rows, d), q_map),
+                pl.BlockSpec((1, blk * hkv, d), kv_map),
+                pl.BlockSpec((1, blk * hkv, d), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, rows, d),
-                                   lambda bi, h, ki, lens, pt: (bi, h, 0, 0)),
-            scratch_shapes=scratch_shapes,
-        )
-        o = _named_pallas_call(
-            "paged_flash_decode", _with_page_table(kernel),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
-            interpret=_interpret(),
-        )(lens, table, qt, kt, vt)
+            out_specs=pl.BlockSpec((1, hkv * rows, d), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((hkv * rows, 1), jnp.float32),
+                pltpu.VMEM((hkv * rows, 1), jnp.float32),
+                pltpu.VMEM((hkv * rows, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv * rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(),
+    )(lens, table, qt, kt, vt)
     return o.reshape(b, hkv, sq, groups, d).transpose(0, 2, 1, 3, 4
                                                       ).reshape(b, sq, hq, d)
 
@@ -772,6 +775,19 @@ def _decode_call(q, k, v, kv_lengths, *, window: Optional[int], blk: int,
 def _check_heads(hq: int, hkv: int) -> None:
     if hq % hkv:
         raise ValueError(f"query heads {hq} not a multiple of kv heads {hkv}")
+
+
+def _dense_decode(q, k, v, kv_lengths, window, block_k: int):
+    """A dense cache [B, S, Hkv, D] through the one launch: row b is
+    page b of a pool whose pages are whole rows."""
+    b, skv, hkv, _ = kv_store.pool_dims(k)
+    _check_heads(q.shape[2], hkv)
+    if skv % 128:
+        raise ValueError(
+            f"flash_decode needs cache length divisible by 128 ({skv=})")
+    return _decode_call(q, k, v, jnp.arange(b, dtype=jnp.int32)[:, None],
+                        kv_lengths, window=window, name="flash_decode",
+                        block_k=block_k)
 
 
 def flash_decode_mq(
@@ -785,14 +801,7 @@ def flash_decode_mq(
     """Multi-query decode attention with per-row valid-prefix masking
     (the speculative verify pass: query j sees k_pos < kv_lengths + j).
     Returns [B, Sq, Hq, D]. Raises ValueError for unsupported shapes."""
-    b, sq, hq, d = q.shape
-    _, skv, hkv, _ = k.shape
-    _check_heads(hq, hkv)
-    blk = min(block_k, _pick_block(skv) or 0)
-    if not blk or skv % blk:
-        raise ValueError(
-            f"flash_decode_mq needs cache length divisible by 128 ({skv=})")
-    return _decode_call(q, k, v, kv_lengths, window=sliding_window, blk=blk)
+    return _dense_decode(q, k, v, kv_lengths, sliding_window, block_k)
 
 
 def flash_decode(
@@ -808,24 +817,18 @@ def flash_decode(
     [B, 1, Hq, D]. Raises ValueError for unsupported shapes (the serving
     engines size their caches so this never fires:
     inference/engine.py _kernel_seq_multiple)."""
-    b, sq, hq, d = q.shape
-    _, skv, hkv, _ = k.shape
-    if sq != 1:
-        raise ValueError(f"flash_decode is single-token only (q_len={sq})")
-    _check_heads(hq, hkv)
-    blk = min(block_k, _pick_block(skv) or 0)
-    if not blk or skv % blk:
+    if q.shape[1] != 1:
         raise ValueError(
-            f"flash_decode needs cache length divisible by 128 ({skv=})")
-    return _decode_call(q, k, v, kv_lengths, window=sliding_window, blk=blk)
+            f"flash_decode is single-token only (q_len={q.shape[1]})")
+    return _dense_decode(q, k, v, kv_lengths, sliding_window, block_k)
 
 
-def _check_paged(q, k_pages, page_table, name: str) -> None:
+def _check_paged(q, k_pages, page_table) -> None:
     b = q.shape[0]
-    ps = k_pages.shape[1]
-    _check_heads(q.shape[2], k_pages.shape[2])
+    _, ps, hkv, _ = kv_store.pool_dims(k_pages)
+    _check_heads(q.shape[2], hkv)
     if ps % 8:
-        # TPU sublane alignment for the [ps, D] kv tile
+        # TPU sublane alignment for the [ps * Hkv, D] kv tile
         raise ValueError(f"page_size {ps} must be a multiple of 8")
     if page_table.shape[0] != b:
         raise ValueError(
@@ -843,10 +846,9 @@ def paged_flash_decode_mq(
     """Multi-query decode attention over paged KV (the speculative
     verify pass) — the paged knob of the decode specialization. Returns
     [B, Sq, Hq, D]; ValueError for unsupported shapes."""
-    _check_paged(q, k_pages, page_table, "paged_flash_decode_mq")
-    return _decode_call(q, k_pages, v_pages, kv_lengths,
-                        window=sliding_window, blk=k_pages.shape[1],
-                        page_table=page_table)
+    _check_paged(q, k_pages, page_table)
+    return _decode_call(q, k_pages, v_pages, page_table, kv_lengths,
+                        window=sliding_window, name="paged_flash_decode")
 
 
 def paged_flash_decode(
@@ -863,7 +865,6 @@ def paged_flash_decode(
     if q.shape[1] != 1:
         raise ValueError(
             f"paged_flash_decode is single-token only (q_len={q.shape[1]})")
-    _check_paged(q, k_pages, page_table, "paged_flash_decode")
-    return _decode_call(q, k_pages, v_pages, kv_lengths,
-                        window=sliding_window, blk=k_pages.shape[1],
-                        page_table=page_table)
+    _check_paged(q, k_pages, page_table)
+    return _decode_call(q, k_pages, v_pages, page_table, kv_lengths,
+                        window=sliding_window, name="paged_flash_decode")

@@ -82,17 +82,32 @@ def prefill_positions(qi, ki, block_q: int, block_k: int, delta=0):
     return q_pos, k_pos
 
 
-def decode_positions(ki, block_k: int, kv_len, groups: int, rows: int):
-    """(q_pos, k_pos) [rows, BK] grids for a decode tile.
+def decode_positions(ki, block_k: int, kv_len, groups: int, rows: int,
+                     kv_heads: int = 1):
+    """(q_pos, k_pos) [kv_heads * rows, BK * kv_heads] grids for a decode
+    tile that holds every kv head of BK cache positions.
 
-    rows = Sq * groups: row r is speculative query j = r // groups of
-    this slot, at global position kv_len - 1 + j (the verify pass —
-    each query one position deeper than the last; Sq == 1 is plain
-    single-token decode). kv_len may be a traced SMEM scalar."""
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0) // groups
+    rows = Sq * groups: row r of a kv head is speculative query
+    j = r // groups of this slot, at global position kv_len - 1 + j (the
+    verify pass — each query one position deeper than the last; Sq == 1
+    is plain single-token decode). The q tile stacks the heads' rows
+    (head-major); the kv tile is [BK, kv_heads] flattened, position-major,
+    the order a cache row lies in memory. kv_len may be a traced SMEM
+    scalar."""
+    shape = (kv_heads * rows, block_k * kv_heads)
+    q_idx = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) % rows) // groups
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, block_k), 1)
+        jnp.int32, shape, 1) // kv_heads
     return kv_len - 1 + q_idx, k_pos
+
+
+def decode_same_head(block_k: int, rows: int, kv_heads: int):
+    """[kv_heads * rows, BK * kv_heads] grid: the key of that column
+    belongs to the kv head of that row's queries (the layout of
+    `decode_positions`)."""
+    shape = (kv_heads * rows, block_k * kv_heads)
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0) // rows
+            == jax.lax.broadcasted_iota(jnp.int32, shape, 1) % kv_heads)
 
 
 # ---------------------------------------------------------------------------

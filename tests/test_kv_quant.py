@@ -31,7 +31,7 @@ def test_quantize_roundtrip_error_bound():
 
 
 def _caches(int8):
-    from megatron_tpu.inference.generation import _init_caches
+    from megatron_tpu.ops.kv_store import create as _init_caches
 
     return _init_caches(CFG, 2, 48, int8=int8)
 
@@ -125,3 +125,104 @@ def test_int8_cache_rejects_pipelined_forward():
         generate_tokens(CFG, PARAMS, np.zeros((1, 4), np.int32),
                         np.array([4]), max_new_tokens=2,
                         forward_fn=lambda *a: None, kv_cache_int8=True)
+
+
+# ---------------------------------------------------------------------------
+# every cache kind against the cache-free forward (ops/kv_store.py: the
+# store rides in lm_forward's scan carry and is written in place)
+
+_KINDS = [(paged, int8, spec) for paged in (False, True)
+          for int8 in (False, True) for spec in (False, True)]
+
+
+@pytest.mark.parametrize(
+    "paged,int8,spec", _KINDS,
+    ids=[f"{'paged' if p else 'slots'}-{'int8' if i else 'f32'}-"
+         f"{'verify3' if s else 'decode1'}" for p, i, s in _KINDS])
+def test_prefill_then_decode_matches_the_cache_free_forward(paged, int8,
+                                                            spec):
+    """Prefill 8 positions, then decode the next 6 with every row at its
+    own depth (one token a row, or the 3 tokens of a speculative verify),
+    through a slot store and through a page pool (chunks of 4 through the
+    table, scattered pages), float and int8: the logits are the cache-free
+    forward's on the same seeded random weights — to float32 rounding for
+    a float store, to the quantization's drift for an int8 one."""
+    from megatron_tpu.ops import kv_store
+
+    rng = np.random.default_rng(3)
+    toks = jnp.asarray(rng.integers(0, 128, (2, 14)), jnp.int32)
+    ref = np.asarray(lm_forward(CFG, PARAMS, toks), np.float32)
+    if paged:
+        page, table = 4, jnp.asarray([[7, 2, 9, 4], [1, 8, 3, 6]], jnp.int32)
+        store = kv_store.create(CFG, 10, page, int8=int8)
+        outs = []
+        for r in range(2):          # chunked prefill: one row a call
+            row = []
+            for off in (0, 4):
+                lg, store = lm_forward(
+                    CFG, PARAMS, toks[r:r + 1, off:off + 4], kv_caches=store,
+                    cache_index=jnp.int32(off), page_table=table[r:r + 1],
+                    page_write_start=jnp.int32(0), page_write_end=jnp.int32(8))
+                row.append(lg)
+            outs.append(jnp.concatenate(row, axis=1))
+        got = [jnp.concatenate(outs, axis=0)]
+        kw = {"page_table": table}
+    else:
+        store = kv_store.create(CFG, 2, 16, int8=int8)
+        lg, store = lm_forward(CFG, PARAMS, toks[:, :8], kv_caches=store,
+                               cache_index=0)
+        got, kw = [lg], {}
+    step = 3 if spec else 1
+    for t in range(8, 14, step):
+        lg, store = lm_forward(CFG, PARAMS, toks[:, t:t + step],
+                               kv_caches=store,
+                               cache_index=jnp.full((2,), t, jnp.int32), **kw)
+        got.append(lg)
+    got = np.asarray(jnp.concatenate(got, axis=1), np.float32)
+    if int8:
+        assert np.abs(got - ref).max() < 0.05
+        assert (got.argmax(-1) == ref.argmax(-1)).mean() >= 0.95
+    else:
+        np.testing.assert_allclose(got, ref, atol=2e-5)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slots", "paged"])
+@pytest.mark.parametrize("step", [1, 3], ids=["decode1", "verify3"])
+def test_decode_kernel_reads_the_store_where_it_lies(monkeypatch, paged,
+                                                     step):
+    """The same decode through the Pallas decode kernel (interpret mode):
+    the kernel tiles the stacked store as it lies in memory, a layer's
+    share addressed through the table kv_store.read builds (a slot row is
+    one page of 128 positions, split into the kernel's blocks), and gives
+    the logits of the XLA path over the same store."""
+    import dataclasses
+
+    from megatron_tpu.ops import kv_store
+
+    monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+    kernel_cfg = dataclasses.replace(CFG, attention_impl="pallas")
+    rng = np.random.default_rng(4)
+    toks = jnp.asarray(rng.integers(0, 128, (2, 14)), jnp.int32)
+    if paged:
+        table = jnp.asarray([[5, 2], [1, 4]], jnp.int32)
+        store, kw = kv_store.create(CFG, 6, 8), {"page_table": table}
+        for r in range(2):
+            _, store = lm_forward(
+                CFG, PARAMS, toks[r:r + 1, :8], kv_caches=store,
+                cache_index=jnp.int32(0), page_table=table[r:r + 1],
+                page_write_start=jnp.int32(0), page_write_end=jnp.int32(8))
+    else:
+        store, kw = kv_store.create(CFG, 2, 128), {}
+        _, store = lm_forward(CFG, PARAMS, toks[:, :8], kv_caches=store,
+                              cache_index=0)
+    at = jnp.full((2,), 8, jnp.int32)
+    want, _ = lm_forward(CFG, PARAMS, toks[:, 8:8 + step], kv_caches=store,
+                         cache_index=at, **kw)
+
+    def through_the_kernel(s):
+        return lm_forward(kernel_cfg, PARAMS, toks[:, 8:8 + step],
+                          kv_caches=s, cache_index=at, **kw)[0]
+
+    assert "pallas_call" in str(jax.make_jaxpr(through_the_kernel)(store))
+    got = jax.jit(through_the_kernel)(store)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)

@@ -390,7 +390,7 @@ def test_dropless_equals_capacity_dispatch_with_ample_capacity():
 # --- (e) serving: the cache holds normed, rotated keys -----------------------
 
 def test_prefill_then_decode_through_the_slot_cache_equals_the_forward():
-    from megatron_tpu.inference.generation import _init_caches
+    from megatron_tpu.ops.kv_store import create as _init_caches
 
     cfg = program_config()
     params = seeded_params(cfg)

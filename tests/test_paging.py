@@ -358,3 +358,70 @@ def test_paged_engine_rejects_undersized_pool():
     with pytest.raises(ValueError, match="page_size"):
         PagedInferenceEngine(cfg, params, num_slots=1, max_seq_len=64,
                              page_size=0)
+
+
+# ---------------------------------------------------------------------------
+# the pool's format on the wire (ops/kv_store.py owns it; the wire layout
+# of exported pages did not move with PR 32)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_pages_in_the_parents_layout_export_and_install(int8):
+    """A fixture of a few pages written the way the parent of PR 32 laid a
+    pool out — [layers, pages, page, kv_heads, head_dim], int8 scales
+    [..., 1] — is what the engine holds today: its prefix pages export to
+    the canonical [layers, positions, kv_heads, head_dim] sections, page
+    after page, and install bit for bit into another replica's pool."""
+    import jax
+    import jax.numpy as jnp
+
+    from megatron_tpu.inference.fleet.migration import (
+        pack_state, unpack_state,
+    )
+    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.models import presets
+    from megatron_tpu.models.params import init_params
+
+    cfg = presets.tiny(vocab_size=64, seq_length=32, num_layers=2)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+
+    def mk():
+        return PagedInferenceEngine(cfg, params, num_slots=2, max_seq_len=32,
+                                    page_size=4, prefill_chunk=4,
+                                    num_pages=9, kv_cache_int8=int8)
+
+    src = mk()
+    L, P, ps, H, D = 2, 9, 4, cfg.n_kv_heads, cfg.head_dim
+    rng = np.random.default_rng(5)
+    if int8:
+        fixture = [rng.integers(-127, 128, (L, P, ps, H, D)).astype(np.int8)
+                   for _ in range(2)]
+        fixture += [rng.random((L, P, ps, H, 1)).astype(np.float32)
+                    for _ in range(2)]
+    else:
+        dtype = np.asarray(src.caches[0]).dtype
+        fixture = [rng.standard_normal((L, P, ps, H, D)).astype(dtype)
+                   for _ in range(2)]
+    assert [(c.shape, c.dtype) for c in src.caches] == [
+        (f.shape, f.dtype) for f in fixture]
+    src.caches = tuple(jnp.asarray(f) for f in fixture)
+    tokens = list(range(1, 13))                       # three full pages
+    pages = src.pool.alloc(3)
+    src.prefix_cache.insert(tokens, pages, np.zeros(11, np.float32))
+
+    meta, sections = src.export_prefix_state(tokens)
+    names = (["kv_k", "kv_v", "kv_k_scale", "kv_v_scale"] if int8
+             else ["kv_k", "kv_v"])
+    for name, f in zip(names, fixture):
+        want = np.concatenate([f[:, p] for p in pages], axis=1)
+        assert want.shape[:2] == (L, 12)
+        np.testing.assert_array_equal(sections[name], want)
+
+    meta, sections = unpack_state(pack_state(meta, sections))
+    dst = mk()
+    assert dst.import_prefix_state(meta, sections) == 3
+    landed, _ = dst.prefix_cache.lookup(tokens)
+    assert len(landed) == 3
+    for got, f in zip(dst.caches, fixture):
+        np.testing.assert_array_equal(np.asarray(got)[:, landed],
+                                      f[:, pages])
