@@ -70,7 +70,7 @@ def make_pipelined_lm_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int):
                 def lbody(c, sc):
                     x, caches = c
                     lp, idx = sc
-                    y, caches, _ = block_forward(
+                    y, caches, _, _ = block_forward(
                         cfg, lp, x, rope, positions, kv_cache=caches,
                         layer=idx, cache_index=cache_index)
                     return (y, caches), None

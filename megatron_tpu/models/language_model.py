@@ -19,6 +19,7 @@ Embedding, parallel_lm_logits) + megatron/model/gpt_model.py
 
 from __future__ import annotations
 
+import math
 import operator
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -32,7 +33,9 @@ from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.cross_entropy import (
     chunked_head_loss, cross_entropy_loss,
 )
-from megatron_tpu.ops.moe import LOAD_METRIC, SAVED_PRODUCT, merge_layer_stats
+from megatron_tpu.ops.moe import (
+    LOAD_METRIC, SAVED_PRODUCT, expert_grad_sinks, merge_layer_stats,
+)
 from megatron_tpu.ops.weight_quant import deq, take_rows
 from megatron_tpu.ops.normalization import norm_forward
 from megatron_tpu.ops.rotary import precompute_rope
@@ -142,6 +145,15 @@ def scan_with_remat(body, carry, xs, recompute: str):
     policy = _remat_policy(gran)
     if policy is not None:
         body = jax.checkpoint(body, policy=policy, prevent_cse=False)
+    if jax.tree.leaves(xs)[0].shape[0] == 1:
+        # A stack of one layer is a call, not a loop of one trip. XLA
+        # inlines such a loop sooner or later, and how soon decides what
+        # its first CSE still sees: as a loop whose state held the
+        # gradient sinks it was inlined too late for a one-layer model's
+        # recomputed flash forward to merge with the forward itself (28 ms
+        # a step in the benchmark's OLMoE cell; PERF.md, PR 33).
+        carry, ys = body(carry, jax.tree.map(lambda a: a[0], xs))
+        return carry, jax.tree.map(lambda a: a[None], ys)
     return jax.lax.scan(body, carry, xs)
 
 
@@ -226,8 +238,18 @@ def lm_forward(
     page_write_end: Optional[jnp.ndarray] = None,
     tp_comm=None,  # quant.TpComm: explicit/compressed TP collectives
     cp_comm=None,  # quant.CpComm: context-parallel ring transport
+    grad_sink=None,
 ):
     """Forward pass to logits.
+
+    grad_sink: float32 accumulators for the gradients of some leaves of
+    `params`, in a tree shaped like `params` that holds None at every
+    other leaf (`grad_sink_leaves` says which a call can take). They are
+    handed through the layers unread and returned behind the result,
+    (result, grad_sink), so that their cotangents ride the backward pass:
+    the vector-Jacobian product answers a running sum of gradients with
+    that sum plus this call's, added inside the kernels that make them
+    (ops/moe.py moe_block), and such a leaf's own cotangent is zero.
 
     kv_caches: the stacked KV store for incremental decoding
     (ops/kv_store.py: `create` makes one); when given, returns
@@ -278,12 +300,15 @@ def lm_forward(
     # the caches ride in the carry: each layer writes its rows into the
     # stacked store in place (ops/kv_store.py) and the store the caller
     # donated is the one handed back. As a scanned input and output the
-    # scan would build a second store, layer by layer, every call.
+    # scan would build a second store, layer by layer, every call. So do
+    # the gradient sinks, whose cotangents the backward scan then carries
+    # and the kernels update in place: a scanned slice of a stacked
+    # accumulator would be copied in and out, layer by layer.
     def body(carry, scanned):
-        x, aux, caches = carry
+        x, aux, caches, sinks = carry
         lp, rate, idx = scanned
         key = jax.random.fold_in(dropout_key, idx) if train else None
-        y, caches, moe_aux = block_forward(
+        y, caches, moe_aux, sinks = block_forward(
             cfg, lp, x, rope, positions,
             dropout_key=key,
             hidden_dropout_rate=rate,
@@ -297,16 +322,23 @@ def lm_forward(
             page_write_end=page_write_end,
             tp_comm=tp_comm,
             cp_comm=cp_comm,
+            grad_sink=sinks,
         )
-        return (y, add_aux(aux, moe_aux), caches), None
+        return (y, add_aux(aux, moe_aux), caches, sinks), None
 
     layer_idx = jnp.arange(cfg.num_layers)
     xs = (params["layers"], rates, layer_idx)
     if kv_caches is not None and parse_recompute(recompute)[1] is not None:
         recompute = "none"  # decode path: caches preclude the split scan
-    (x, moe_aux, new_caches), _ = scan_with_remat(
-        body, (x, jnp.zeros((2,) if moe else (), jnp.float32), kv_caches),
+    (x, moe_aux, new_caches, layer_sinks), _ = scan_with_remat(
+        body, (x, jnp.zeros((2,) if moe else (), jnp.float32), kv_caches,
+               None if grad_sink is None else grad_sink["layers"]),
         xs, recompute)
+
+    def with_sinks(result):
+        if grad_sink is None:
+            return result
+        return result, {**grad_sink, "layers": layer_sinks}
 
     # "head_loss" names the final norm, the head and (in lm_loss) the
     # cross-entropy: one region of the step in a device trace
@@ -315,7 +347,7 @@ def lm_forward(
     if return_hidden:
         # MoE backbones under task heads (BERT/classification/biencoder)
         # must not silently drop the router losses
-        return (x, moe_aux) if return_moe_aux else x
+        return with_sinks((x, moe_aux) if return_moe_aux else x)
 
     with jax.named_scope("head_loss"):
         logits = lm_logits(cfg, params, x, tp_comm=tp_comm)
@@ -324,10 +356,10 @@ def lm_forward(
         raise ValueError("return_moe_aux with kv_caches is ambiguous — "
                          "decode paths don't train the router")
     if return_moe_aux:
-        return logits, moe_aux
+        return with_sinks((logits, moe_aux))
     if kv_caches is not None:
-        return logits, new_caches
-    return logits
+        return with_sinks((logits, new_caches))
+    return with_sinks(logits)
 
 
 def chunked_lm_loss(
@@ -364,6 +396,25 @@ def chunked_lm_loss(
         getattr(sharder, "sequence_parallel", False))
 
 
+# the key of the gradient sinks in lm_loss's aux
+GRAD_SINK = "grad_sink"
+
+
+def grad_sink_leaves(cfg: ModelConfig, params: Dict[str, Any],
+                     tokens_shape: Tuple[int, ...]) -> Dict[str, Any]:
+    """A tree shaped like `params` with True at the leaves whose gradient
+    `lm_loss`, traced here over tokens [B, S] of tokens_shape, can sum
+    into an accumulator handed to it as `grad_sink`, and False at every
+    other: the stacked expert matrices where a Pallas kernel makes their
+    gradient (ops/moe.py expert_grad_sinks)."""
+    takes = jax.tree.map(lambda _: False, params)
+    moe = params["layers"].get("moe")
+    if moe is not None:
+        for name in expert_grad_sinks(cfg, moe, math.prod(tokens_shape)):
+            takes["layers"]["moe"][name] = True
+    return takes
+
+
 def lm_loss(
     cfg: ModelConfig,
     params: Dict[str, Any],
@@ -371,12 +422,15 @@ def lm_loss(
     dropout_key: Optional[jax.Array] = None,
     recompute: str = "none",
     sharder: Sharder = _identity_sharder,
+    grad_sink=None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Training loss on a batch dict with keys:
     tokens [B,S], labels [B,S], loss_mask [B,S], optional position_ids.
 
     Matches the reference contract: per-token CE weighted by loss_mask
     (gpt_model.py post_language_model_processing + finetune.py loss_func).
+
+    grad_sink: as lm_forward's; it comes back in aux[GRAD_SINK].
     """
     moe = cfg.num_experts is not None
     S = batch["tokens"].shape[1]
@@ -393,7 +447,10 @@ def lm_loss(
         sharder=sharder,
         return_moe_aux=moe,
         return_hidden=chunked,
+        grad_sink=grad_sink,
     )
+    if grad_sink is not None:
+        out, grad_sink = out
     if chunked:
         hidden, moe_aux = out if moe else (out, None)
         with jax.named_scope("head_loss"):
@@ -411,6 +468,8 @@ def lm_loss(
     ntokens = (jnp.sum(batch["loss_mask"]) if "loss_mask" in batch
                else jnp.asarray(per_token.size, jnp.float32))
     aux = {"lm_loss": mean, "ntokens": ntokens}
+    if grad_sink is not None:
+        aux[GRAD_SINK] = grad_sink
     if moe:
         # router losses train alongside CE (load balance / ST-MoE z-loss);
         # lm_loss in metrics stays the pure CE term

@@ -227,14 +227,19 @@ def mlp_block(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray,
 
 
 def _ffn(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
-         tp_comm=None):
-    """Dense MLP or MoE, by config. Returns (out, moe_aux): a zero fp32
-    scalar for a dense layer, [aux loss, load statistic] for an MoE one."""
+         tp_comm=None, grad_sink=None, layer=None):
+    """Dense MLP or MoE, by config. Returns (out, moe_aux, grad_sink):
+    moe_aux a zero fp32 scalar for a dense layer, [aux loss, load
+    statistic] for an MoE one; grad_sink as block_forward has it."""
+    if grad_sink is not None:
+        out, aux, load, stacks = moe_block(cfg, lp["moe"], x,
+                                           (grad_sink["moe"], layer))
+        return out, layer_stats(aux, load), {**grad_sink, "moe": stacks}
     if cfg.num_experts is not None:
         out, aux, load = moe_block(cfg, lp["moe"], x)
-        return out, layer_stats(aux, load)
+        return out, layer_stats(aux, load), None
     return (mlp_block(cfg, lp["mlp"], x, tp_comm=tp_comm),
-            jnp.zeros((), jnp.float32))
+            jnp.zeros((), jnp.float32), None)
 
 
 def block_forward(
@@ -255,9 +260,16 @@ def block_forward(
     page_write_end: Optional[jnp.ndarray] = None,
     tp_comm=None,
     cp_comm=None,
+    grad_sink=None,
 ):
-    """One decoder layer -> (y, kv_cache, moe_aux): kv_cache is the whole
-    store with this layer's rows written (attention_block).
+    """One decoder layer -> (y, kv_cache, moe_aux, grad_sink): kv_cache is
+    the whole store with this layer's rows written (attention_block).
+
+    grad_sink: float32 accumulators of the gradients of some of the
+    stacked layers' leaves, in a tree shaped like the layers' params
+    (today {"moe": {"w_in", "w_out"}}: ops/moe.py moe_block), handed
+    through for the cotangents to ride the backward pass; `layer` is this
+    layer's index into them as into the store.
 
     hidden_dropout_rate may be a traced scalar (LIMA per-layer ramp, ref
     transformer.py:994-1001). moe_aux is a zero scalar for dense models
@@ -300,17 +312,19 @@ def block_forward(
             # Falcon: mlp input is ln1(x) (7B) or a dedicated ln_mlp(x)
             # (40B); one residual add for both branches.
             mlp_in = _norm(cfg, lp["ln_mlp"], x) if cfg.parallel_layernorm else normed
-            mlp_out, moe_aux = _ffn(cfg, lp, mlp_in, tp_comm=tp_comm)
+            mlp_out, moe_aux, grad_sink = _ffn(
+                cfg, lp, mlp_in, tp_comm, grad_sink, layer)
             mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
             res = normed if cfg.apply_residual_post_ln else x
             y = res + attn_out + mlp_out
         else:
             normed2 = _norm(cfg, lp["ln2"], y)
-            mlp_out, moe_aux = _ffn(cfg, lp, normed2, tp_comm=tp_comm)
+            mlp_out, moe_aux, grad_sink = _ffn(
+                cfg, lp, normed2, tp_comm, grad_sink, layer)
             mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
             res2 = normed2 if cfg.apply_residual_post_ln else y
             y = res2 + mlp_out
             if cfg.use_post_ln:
                 y = _norm(cfg, lp["ln1"], y)
     y = sharder(y, "residual")
-    return y, kv_cache, moe_aux
+    return y, kv_cache, moe_aux, grad_sink

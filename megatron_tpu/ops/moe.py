@@ -320,13 +320,40 @@ def _to_token_bwd(dtype, res, dy):
 rows_to_token_order.defvjp(_to_token_fwd, _to_token_bwd)
 
 
+# the expert matrices of a layer's moe subtree: the leaves whose gradient a
+# grouped product makes, and so the keys a `grad_sink` may hold
+EXPERT_MATRICES = ("w_in", "w_out")
+
+
+def expert_grad_sinks(cfg: ModelConfig, p: Dict[str, Any],
+                      num_tokens: int) -> Tuple[str, ...]:
+    """The leaves of the moe subtree `p` (one layer's or the stacked
+    layers': the last three axes are read) whose gradient `moe_block` can
+    sum into a float32 accumulator it is handed as `grad_sink`, in a call
+    over num_tokens tokens traced where this is asked: the expert matrices,
+    where the dropless block's products are the program's kernels (one
+    TPU, rows the tiles divide: `grouped_matmul.takes_sink`, the predicate
+    the products themselves go by). Elsewhere none."""
+    from megatron_tpu.ops.pallas.grouped_matmul import takes_sink
+
+    if cfg.num_experts is None or cfg.moe_dispatch != "dropless":
+        return ()
+    rows = num_tokens * cfg.moe_top_k
+    if all(takes_sink(rows, *p[name].shape[-2:], cfg.num_experts)
+           for name in EXPERT_MATRICES):
+        return EXPERT_MATRICES
+    return ()
+
+
 def moe_block_dropless(
     cfg: ModelConfig,
     p: Dict[str, Any],
     x: jnp.ndarray,      # [B, S, H]
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    grad_sink=None,      # ({"w_in", "w_out": f32 [L, E, k, n]}, layer)
+):
     """Sort-based dropless dispatch (MegaBlocks-style, TPU form).
-    Returns (y [B,S,H], aux loss, load statistic).
+    Returns (y [B,S,H], aux loss, load statistic), and with `grad_sink`
+    its stacks behind them, handed through (`moe_block` says what for).
 
     No token is ever dropped and no [.., E, C] dispatch/combine tensors
     exist: the N*k (token, choice) rows are argsorted by expert, the two
@@ -374,15 +401,27 @@ def moe_block_dropless(
         # one visit table for both products and their gradients (None
         # where the products are lax.ragged_dot)
         visits = visits_for(group_sizes, N * k)
-        hmid = checkpoint_name(
-            grouped_matmul(xs, p["w_in"], group_sizes, visits=visits),
-            SAVED_PRODUCT)
+        stacks, layer = ({}, None) if grad_sink is None else grad_sink
+        stacks = dict(stacks)
+
+        def product(rows, name):
+            """rows · p[name] by group; the matrix's gradient goes into
+            its stack where it has one."""
+            if stacks.get(name) is None:
+                return grouped_matmul(rows, p[name], group_sizes,
+                                      visits=visits)
+            out, stacks[name] = grouped_matmul(
+                rows, p[name], group_sizes, visits=visits,
+                sink=(stacks[name], layer))
+            return out
+
+        hmid = checkpoint_name(product(xs, "w_in"), SAVED_PRODUCT)
         if "b_in" in p:
             # per-row expert bias: gather by the row's expert id
             hmid = hmid + jnp.take(p["b_in"], jnp.take(flat_e, order), axis=0)
         hmid = apply_activation(cfg.activation, hmid.astype(x.dtype))
         # (rows_to_token_order keeps this product for the backward)
-        out = grouped_matmul(hmid, p["w_out"], group_sizes, visits=visits)
+        out = product(hmid, "w_out")
         if "b_out" in p:
             out = out + jnp.take(p["b_out"], jnp.take(flat_e, order), axis=0)
 
@@ -391,7 +430,7 @@ def moe_block_dropless(
         # choices weighted by its gates and summed in float32
         y = rows_to_token_order(out, topw, order, inv, x.dtype)
         y = y.reshape(b, s, h)
-    return y, aux, load
+    return (y, aux, load) if grad_sink is None else (y, aux, load, stacks)
 
 
 def _excl_cumsum(x, axis=0):
@@ -678,8 +717,22 @@ def moe_block(
     cfg: ModelConfig,
     p: Dict[str, Any],   # one layer's moe subtree: router, w_in, w_out (+biases)
     x: jnp.ndarray,      # [B, S, H]
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Returns (y [B,S,H], aux loss, load statistic), both fp32 scalars."""
+    grad_sink=None,
+):
+    """Returns (y [B,S,H], aux loss, load statistic), both fp32 scalars.
+
+    grad_sink = (stacks, layer): where the gradients of this layer's
+    expert matrices are to be summed, for a step that accumulates them
+    over micro-batches. stacks holds, for names `expert_grad_sinks` gave,
+    the float32 accumulator of every layer's matrix, [L, E, k, n]. They
+    come back as a fourth result, as they went in: they are there for
+    their cotangents, which answer a running sum with the sum plus this
+    call's gradient at [layer], added by the kernel that makes it
+    (`grouped_matmul`'s `sink`); the matrices' own cotangents are then
+    zero."""
+    if grad_sink is not None:
+        # expert_grad_sinks names a leaf only where this form runs
+        return moe_block_dropless(cfg, p, x, grad_sink)
     if cfg.moe_dispatch == "dropless":
         dsz, ep, named_axes = _ambient_batch_axes()
         # manual data axis (per-shard local sort, no batch-axis argsort

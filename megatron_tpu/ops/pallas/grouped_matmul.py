@@ -7,8 +7,8 @@ Row r of `lhs` belongs to group g when offsets[g] <= r < offsets[g + 1]
 row has an expert, the dropless dispatch's invariant) and is multiplied
 by rhs[g]. That is `jax.lax.ragged_dot`, and on every backend but a TPU,
 under a mesh of several devices, or at shapes the tiles do not divide,
-this function IS `jax.lax.ragged_dot`. On one TPU it is three Pallas
-kernels of the program's own under one `jax.custom_vjp`:
+this function IS `jax.lax.ragged_dot`. On one TPU it is Pallas kernels of
+the program's own under one `jax.custom_vjp`:
 
   kernel      | product                          | grid
   ------------|----------------------------------|--------------------------
@@ -17,6 +17,20 @@ kernels of the program's own under one `jax.custom_vjp`:
               | kernel contracting rhs's last    | taken [tn, tk] from the
               | axis, no transposed copy in HBM  | stored [E, k, n]
   `moe_tgmm`  | drhs[g] = lhs[rows of g]ᵀ · dout | (n tiles, k tiles, visits)
+  `moe_tgmm`  | acc[layer, g] += the same, in    | the same; the block of the
+  (`sink=`)   | float32, unrounded, in place     | stacked [L, E, k, n] acc is
+              |                                  | read, added to and written
+
+The last row is for a step that accumulates rhs's gradient over several
+calls (micro-batches): handed the float32 accumulator of every layer's
+rhs as `sink=(stack, layer)`, the function sums the gradient where it is
+made. A backward rule is handed nothing but cotangents, so the stack goes
+through the function untouched and its COTANGENT is the accumulator: the
+rule answers it with `moe_tgmm(..., into=that cotangent)`, whose result is
+the operand's own buffer (`input_output_aliases`) with the layer's blocks
+updated. No [E, k, n] gradient exists then, in any dtype, and no pass of
+its own adds it (that pass took 2.2 times the kernel's time; PERF.md,
+PR 33).
 
 The algorithm is MegaBlocks' as jax ships it
 (jax/experimental/pallas/ops/tpu/megablox): rows are cut into tiles of tm
@@ -49,7 +63,8 @@ What differs from jax's copy, and why the kernels live here:
 
 Precision: operands reach the MXU in the dtype they arrive in, every
 product accumulates in float32, results are cast to the operands' dtype on
-the way out, as `lax.ragged_dot` and its gradients give them.
+the way out, as `lax.ragged_dot` and its gradients give them; the sum into
+a sink stays float32 all the way.
 """
 
 from __future__ import annotations
@@ -90,7 +105,9 @@ def group_visits(group_sizes: jnp.ndarray, m: int, tm: int) -> GroupVisits:
     XLA inlines a layer scan of length one only after its first CSE, which
     then no longer merges a one-layer model's recomputed forward with the
     forward itself (in the benchmark's OLMoE cell: a second `flash_fwd` a
-    micro-batch, 12 ms a step; tests/test_chip_compile.py counts one)."""
+    micro-batch, 12 ms a step; tests/test_chip_compile.py counts one).
+    Since PR 33 a stack of one layer is a call and no loop
+    (language_model.scan_with_remat), so nothing hangs on that order."""
     E = group_sizes.shape[0]
     n_tiles = m // tm
     sizes = group_sizes.astype(jnp.int32)
@@ -323,8 +340,18 @@ def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool):
 # ---------------------------------------------------------------------------
 
 
-def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, dout_ref,
-                 out_ref, acc_ref, *, tm: int):
+def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
+                 into: bool):
+    """refs: lhs, dout, out and the float32 scratch a group's product is
+    summed in; with `into`, the layer's index (read by the index maps
+    alone) in front, the accumulator's block after dout, and no scratch:
+    the float32 output block is where the product is summed, on top of
+    the accumulator's block."""
+    if into:
+        _, lhs_ref, dout_ref, into_ref, out_ref = refs
+        acc_ref = out_ref
+    else:
+        lhs_ref, dout_ref, out_ref, acc_ref = refs
     v = pl.program_id(2)
     last_step = pl.num_programs(2) - 1
     count = count_ref[0]
@@ -338,7 +365,7 @@ def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, dout_ref,
     def accumulate(prod):
         @pl.when(first)
         def _first():
-            acc_ref[...] = prod
+            acc_ref[...] = into_ref[...] + prod if into else prod
 
         @pl.when(jnp.logical_not(first))
         def _later():
@@ -378,15 +405,21 @@ def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, dout_ref,
 
     @pl.when(live & (hi == lo))
     def _no_rows():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc_ref[...] = into_ref[...] if into else jnp.zeros_like(acc_ref)
 
-    @pl.when(live & last)
-    def _emit():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+    if not into:
+        @pl.when(live & last)
+        def _emit():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles):
-    """lhs [m, k], dout [m, n] -> [E, k, n], one product per group."""
+def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles, into=None):
+    """lhs [m, k], dout [m, n] -> [E, k, n], one product per group, in
+    the operands' dtype. With into = (stack float32 [L, E, k, n], layer
+    int32 scalar): the stack with stack[layer] + the products in place of
+    stack[layer], in float32 and unrounded. The stack is aliased to the
+    result, so only the layer's blocks are read and written and the rest
+    of the buffer is never touched."""
     m, k = lhs.shape
     n = dout.shape[1]
     E = visits.offsets.shape[0] - 1
@@ -394,34 +427,59 @@ def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles):
     dtype = jnp.result_type(lhs.dtype, dout.dtype)
     item = jnp.dtype(dtype).itemsize
 
-    def lhs_map(j, i, v, offs, gids, tids, count):
+    # the index maps' trailing arguments are the scalar-prefetch operands:
+    # the visit table, and behind it the layer where there is a stack
+    def lhs_map(j, i, v, offs, gids, tids, *_):
         return tids[v], i
 
-    def dout_map(j, i, v, offs, gids, tids, count):
+    def dout_map(j, i, v, offs, gids, tids, *_):
         return tids[v], j
 
     def out_map(j, i, v, offs, gids, tids, count):
         return gids[v], i, j
 
-    # row tiles and the output block double-buffered, the float32
-    # accumulator and one product beside it
-    vmem = (2 * (tm * tk + tm * tn + tk * tn) * item
-            + 2 * tk * tn * 4)
+    def stack_map(j, i, v, offs, gids, tids, count, layer):
+        return layer[0], gids[v], i, j
+
+    in_specs = [pl.BlockSpec((tm, tk), lhs_map),
+                pl.BlockSpec((tm, tn), dout_map)]
+    operands = [*visits, lhs.astype(dtype), dout.astype(dtype)]
+    if into is None:
+        out_shape = jax.ShapeDtypeStruct((E, k, n), dtype)
+        out_spec = pl.BlockSpec((None, tk, tn), out_map)
+        scratch, aliases = [pltpu.VMEM((tk, tn), jnp.float32)], {}
+        # row tiles and the output block double-buffered, the float32
+        # accumulator and one product beside it
+        vmem = 2 * (tm * tk + tm * tn + tk * tn) * item + 2 * tk * tn * 4
+    else:
+        stack, layer = into
+        if stack.dtype != jnp.float32 or stack.shape[1:] != (E, k, n):
+            raise ValueError(
+                f"moe_tgmm sums into a float32 [layers, {E}, {k}, {n}] "
+                f"stack, not {stack.dtype}{list(stack.shape)}")
+        out_shape = jax.ShapeDtypeStruct(stack.shape, jnp.float32)
+        out_spec = pl.BlockSpec((None, None, tk, tn), stack_map)
+        in_specs.append(out_spec)
+        operands.insert(4, jnp.asarray(layer, jnp.int32).reshape(1))
+        operands.append(stack)
+        scratch, aliases = [], {len(operands) - 1: 0}
+        # row tiles double-buffered; the accumulator's block in and the
+        # block out, both float32 and double-buffered; one product
+        vmem = 2 * (tm * tk + tm * tn) * item + 5 * tk * tn * 4
     return ft._named_pallas_call(
-        "moe_tgmm", functools.partial(_tgmm_kernel, tm=tm),
+        "moe_tgmm",
+        functools.partial(_tgmm_kernel, tm=tm, into=into is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=4 if into is None else 5,
             grid=(n // tn, k // tk, visits.group_ids.shape[0]),
-            in_specs=[pl.BlockSpec((tm, tk), lhs_map),
-                      pl.BlockSpec((tm, tn), dout_map)],
-            out_specs=pl.BlockSpec((None, tk, tn), out_map),
-            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((E, k, n), dtype),
+            in_specs=in_specs, out_specs=out_spec, scratch_shapes=scratch),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=ft._interpret(),
-    )(*visits, lhs.astype(dtype), dout.astype(dtype))
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -443,21 +501,33 @@ def _plan(m: int, k: int, n: int, num_groups: int) -> Optional[_Plan]:
     return None if None in tiles else _Plan(*tiles)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _grouped_matmul_kernels(lhs, rhs, visits: GroupVisits, plan: _Plan):
-    return _gmm(lhs, rhs, visits, plan.fwd, False)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _grouped_matmul_kernels(lhs, rhs, visits: GroupVisits, sink, plan: _Plan):
+    out = _gmm(lhs, rhs, visits, plan.fwd, False)
+    # the sink's stack is handed through unread: it is there for its
+    # cotangent, which the backward rule answers
+    return out if sink is None else (out, sink[0])
 
 
-def _kernels_fwd(lhs, rhs, visits, plan: _Plan):
-    return _grouped_matmul_kernels(lhs, rhs, visits, plan), (lhs, rhs, visits)
+def _kernels_fwd(lhs, rhs, visits, sink, plan: _Plan):
+    layer = None if sink is None else sink[1]
+    return (_grouped_matmul_kernels(lhs, rhs, visits, sink, plan),
+            (lhs, rhs, visits, layer))
 
 
-def _kernels_bwd(plan: _Plan, res, dout):
-    lhs, rhs, visits = res
+def _kernels_bwd(plan: _Plan, res, ct):
+    lhs, rhs, visits, layer = res
+    dout, dstack = (ct, None) if layer is None else ct
     dout = dout.astype(jnp.result_type(lhs.dtype, rhs.dtype))
-    dlhs = _gmm(dout, rhs, visits, plan.drows, True)
-    drhs = _tgmm(lhs, dout, visits, plan.tgmm)
-    return dlhs.astype(lhs.dtype), drhs.astype(rhs.dtype), None
+    dlhs = _gmm(dout, rhs, visits, plan.drows, True).astype(lhs.dtype)
+    if layer is None:
+        drhs = _tgmm(lhs, dout, visits, plan.tgmm).astype(rhs.dtype)
+        return dlhs, drhs, None, None
+    # rhs's gradient goes into the stack's cotangent and nowhere else:
+    # rhs's own cotangent is zero (dead code for a caller that does not
+    # differentiate in rhs, as the train step does not)
+    dstack = _tgmm(lhs, dout, visits, plan.tgmm, into=(dstack, layer))
+    return dlhs, jnp.zeros_like(rhs), None, (dstack, None)
 
 
 _grouped_matmul_kernels.defvjp(_kernels_fwd, _kernels_bwd)
@@ -482,18 +552,39 @@ def visits_for(group_sizes: jnp.ndarray, m: int) -> Optional[GroupVisits]:
     return None if tm is None else group_visits(group_sizes, m, tm)
 
 
+def takes_sink(m: int, k: int, n: int, num_groups: int) -> bool:
+    """Whether `grouped_matmul` of these shapes, traced here, can sum rhs's
+    gradient into a `sink`: where its products are the kernels."""
+    return _one_tpu() and _plan(m, k, n, num_groups) is not None
+
+
 def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
                    group_sizes: jnp.ndarray, *,
-                   visits: Optional[GroupVisits] = None) -> jnp.ndarray:
+                   visits: Optional[GroupVisits] = None,
+                   sink: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None):
     """lhs [m, k] times rhs[g] [k, n] for the rows of each group g of
     group_sizes [E] (which sum to m); differentiable in lhs and rhs.
     `visits` is `visits_for(group_sizes, m)` from a caller that runs
-    several products over the same groups."""
+    several products over the same groups.
+
+    `sink` = (stack float32 [L, E, k, n], layer) is where rhs's gradient
+    is to be summed, for a caller that accumulates it over several calls
+    (training/train_step.py; only where `takes_sink` holds). The result is
+    then (product, stack): the stack comes back as it went in, and the
+    gradient rule answers ITS cotangent c with c, c[layer] replaced by
+    c[layer] + lhs[rows of g]ᵀ · dout[rows of g] in float32, unrounded,
+    written in place by `moe_tgmm`; rhs's own cotangent is zero. So the
+    vector-Jacobian product with the running sum as the stack's cotangent
+    gives, for the stack, the new running sum: the gradient is added where
+    it is made and exists nowhere in rhs's dtype or shape."""
     m, k = lhs.shape
     E, _, n = rhs.shape
     plan = _plan(m, k, n, E) if _one_tpu() else None
     if plan is None:
+        if sink is not None:
+            raise ValueError("grouped_matmul: a gradient sink where the "
+                             "products are not the kernels (takes_sink)")
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
     if visits is None:
         visits = visits_for(group_sizes, m)
-    return _grouped_matmul_kernels(lhs, rhs, visits, plan)
+    return _grouped_matmul_kernels(lhs, rhs, visits, sink, plan)

@@ -113,10 +113,10 @@ def _stage_fn(cfg: ModelConfig, chunk_layers: Any, x: jnp.ndarray,
         rate = rates_all[global_idx]
         key = (jax.random.fold_in(dropout_key, global_idx)
                if dropout_key is not None else None)
-        y, _, moe_aux = block_forward(cfg, lp, x, rope, positions,
-                                      dropout_key=key,
-                                      hidden_dropout_rate=rate,
-                                      **({"sharder": sharder} if sharder else {}))
+        y, _, moe_aux, _ = block_forward(
+            cfg, lp, x, rope, positions, dropout_key=key,
+            hidden_dropout_rate=rate,
+            **({"sharder": sharder} if sharder else {}))
         return (y, aux + aux_loss_of(moe_aux)), None
 
     # block:N remats only the first N of this chunk's layers (the

@@ -55,7 +55,9 @@ from megatron_tpu.training.pipeline import (
 )
 from megatron_tpu.training.signal_handler import DistributedSignalHandler
 from megatron_tpu.training.timers import Timers
-from megatron_tpu.training.train_step import make_eval_step, make_train_step
+from megatron_tpu.training.train_step import (
+    kernel_summed, make_eval_step, make_train_step,
+)
 
 
 def get_ltor_masks_and_position_ids(
@@ -1336,26 +1338,39 @@ class TrainLoop:
         persistent cache; still seconds for a large program, which is why
         this runs once, after the loop has returned and its last
         checkpoint is committed, never inside a step or a trace window,
-        and not at all in a run that opened no window."""
+        and not at all in a run that opened no window. The record also
+        counts the leaves whose gradient the step sums inside the kernel
+        that makes it (`train_step.kernel_summed`), and their share of
+        the parameters' elements."""
         if self._profiled_step is None or self.telemetry is None:
             return
         step, n_micro, batch_avals = self._profiled_step
         self._profiled_step = None
+        params = self.state.params
         try:
             with jax.sharding.set_mesh(self.rt.mesh):
                 ma = step.lower(self.state, batch_avals).compile(
                     ).memory_analysis()
+                # traced under the mesh, as the step was: the same answer
+                summed = kernel_summed(
+                    self.cfg.model, params, batch_avals, n_micro,
+                    own_loss=self.loss_fn is None and self.rt.pp == 1)
         except Exception as e:  # noqa: BLE001 - a note for the journal
             # must not turn a finished run into a failed one
             self.log(f"profiler: step program not analysed ({e})")
             return
+        sizes = [(p.size, s) for p, s in zip(jax.tree.leaves(params),
+                                             jax.tree.leaves(summed))]
         self.telemetry.emit(
             "step_program", iteration=self.iteration,
             num_microbatches=n_micro,
             argument_bytes=int(ma.argument_size_in_bytes),
             temp_bytes=int(ma.temp_size_in_bytes),
             output_bytes=int(ma.output_size_in_bytes),
-            alias_bytes=int(ma.alias_size_in_bytes))
+            alias_bytes=int(ma.alias_size_in_bytes),
+            kernel_summed_leaves=sum(s for _, s in sizes),
+            kernel_summed_share=(sum(n for n, s in sizes if s)
+                                 / sum(n for n, _ in sizes)))
 
     # -- loop ---------------------------------------------------------------
 

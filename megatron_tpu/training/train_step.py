@@ -9,7 +9,16 @@ data-sharded batches are partial sums that XLA reduces when they meet the
 (replicated or ZeRO-sharded) optimizer state.
 
 Gradients accumulate in fp32 regardless of compute dtype
-(ref: accumulate_allreduce_grads_in_fp32 / MemoryBuffer main_grad).
+(ref: accumulate_allreduce_grads_in_fp32 / MemoryBuffer main_grad). One
+accumulation, two places where the add is made: for a leaf whose gradient
+a Pallas kernel of the program makes (the stacked expert matrices of a
+dropless MoE on one TPU: `kernel_summed`), the kernel adds its float32
+product into the accumulator, which reaches it as a cotangent and rides
+the backward pass in the layer scan's carry (models/language_model.py
+`grad_sink`; the reference's `wgrad_gemm_accum_fp32`, main_grad += dYᵀ·X
+inside the GEMM); for every other leaf XLA adds the micro-batch's
+gradient under the scope `grad_accumulate`. Which leaf goes where follows
+from what the trace can see (backend, mesh, shapes), never from a flag.
 
 Pipeline-parallel schedules live in megatron_tpu/training/pipeline.py.
 """
@@ -22,11 +31,43 @@ import jax
 import jax.numpy as jnp
 
 from megatron_tpu.config import ModelConfig, OptimizerConfig, TrainingConfig
-from megatron_tpu.models.language_model import lm_loss
+from megatron_tpu.models.language_model import (
+    GRAD_SINK, grad_sink_leaves, lm_loss,
+)
 from megatron_tpu.models.transformer import Sharder, _identity_sharder
 from megatron_tpu.ops.moe import LOAD_METRIC
 from megatron_tpu.parallel.random import RngStreams
 from megatron_tpu.training.optimizer import TrainState, make_optimizer_step
+
+
+def kernel_summed(model_cfg: ModelConfig, params: Any,
+                  batch: Dict[str, Any], num_microbatches: int,
+                  own_loss: bool = True) -> Any:
+    """A tree shaped like `params`: True at the leaves whose gradient
+    `make_train_step`'s step, traced here over `batch` (arrays or shapes,
+    [global_batch_per_step, ...]), sums over its micro-batches inside the
+    kernel that makes it; False where XLA adds (`grad_accumulate`).
+    own_loss: the step runs the language model's own loss (no `loss_fn`,
+    no pipeline), the one that knows what to do with a sink. A single
+    micro-batch has no sum to make. For the rest the model says which
+    leaves a kernel of its own can take (language_model.grad_sink_leaves:
+    by the predicate its products themselves go by, never by a flag)."""
+    if num_microbatches == 1 or not own_loss:
+        return jax.tree.map(lambda _: False, params)
+    rows, *rest = batch["tokens"].shape
+    return grad_sink_leaves(model_cfg, params,
+                            (rows // num_microbatches, *rest))
+
+
+def _where(mask: Any, keep: bool, tree: Any) -> Any:
+    """`tree` with None at the leaves where `mask` is not `keep`."""
+    return jax.tree.map(lambda m, x: x if m is keep else None, mask, tree)
+
+
+def _either(a: Any, b: Any) -> Any:
+    """Two trees of one shape with None where the other has a leaf."""
+    return jax.tree.map(lambda x, y: y if x is None else x, a, b,
+                        is_leaf=lambda x: x is None)
 
 
 def make_train_step(
@@ -61,9 +102,10 @@ def make_train_step(
             user_fn = loss_fn
             loss_fn = (lambda cfg, p, b, key:
                        user_fn(cfg, p, b, key, sharder=sharder))
-    loss_fn = loss_fn or (lambda cfg, p, b, key: lm_loss(
+    own_loss = loss_fn is None
+    loss_fn = loss_fn or (lambda cfg, p, b, key, **sink: lm_loss(
         cfg, p, b, dropout_key=key, recompute=train_cfg.recompute_granularity,
-        sharder=sharder))
+        sharder=sharder, **sink))
     opt_apply = make_optimizer_step(opt_cfg, train_iters or train_cfg.train_iters or 1)
     dropout_on = model_cfg.hidden_dropout > 0 or model_cfg.attention_dropout > 0
     streams = RngStreams(train_cfg.seed)
@@ -93,6 +135,11 @@ def make_train_step(
             lambda x: x.reshape((n, x.shape[0] // n) + x.shape[1:]), batch)
 
         scale = state.scaler.scale if state.scaler is not None else jnp.float32(1.0)
+        # the leaves whose gradient the kernel that makes it also sums
+        summed = kernel_summed(model_cfg, state.params, batch, n, own_loss)
+        any_summed = any(jax.tree.leaves(summed))
+        params = _where(summed, False, state.params)
+        held = _where(summed, True, state.params)
 
         def one_micro(acc, scanned):
             mb, idx = scanned
@@ -102,15 +149,26 @@ def make_train_step(
             else:
                 key = None
 
-            def scaled_loss(p):
-                loss, aux = loss_fn(model_cfg, p, mb, key)
+            def scaled_loss(p, sink):
+                loss, aux = loss_fn(model_cfg, _either(p, held), mb, key,
+                                    **({"grad_sink": sink} if any_summed
+                                       else {}))
                 # None for a model without experts: no leaf, no output
-                return loss * scale, (loss, aux.get(LOAD_METRIC))
+                return ((loss * scale, aux.get(GRAD_SINK, sink)),
+                        (loss, aux.get(LOAD_METRIC)))
 
-            (_, out), grads = jax.value_and_grad(scaled_loss, has_aux=True)(state.params)
+            # A summed leaf is no argument of the differentiated function,
+            # so its own gradient is never formed; its accumulator is one,
+            # and goes in again as the cotangent of the sink the loss hands
+            # through: what comes back for it is the accumulator plus this
+            # micro-batch's gradient, added by the kernel (lm_forward).
+            sink = _where(summed, True, acc)
+            _, vjp, out = jax.vjp(scaled_loss, params, sink, has_aux=True)
+            grads, sink = vjp((jnp.ones((), jnp.float32), sink))
             with jax.named_scope("grad_accumulate"):
-                acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32), acc, grads)
-            return acc, out
+                acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32),
+                                   _where(summed, False, acc), grads)
+            return _either(acc, sink), out
 
         zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
         acc, (losses, loads) = jax.lax.scan(one_micro, zeros,

@@ -294,7 +294,12 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
     and nothing under `mlp` is a scatter: rows cross the sort by expert
     through gathers in both directions (ops/moe.py rows_to_expert_order,
     rows_to_token_order), and the counts and the chosen gates are dense
-    sums; and the head and loss form each chunk's logits once."""
+    sums; and the head and loss form each chunk's logits once. The expert
+    matrices' gradient is summed where it is made: `moe_tgmm`'s results
+    are the step's float32 accumulators themselves, each aliased to the
+    operand it came in as, nothing else in the micro-batch loop has a
+    result of their shape, and `grad_accumulate` names the add of the
+    other leaves."""
     from megatron_tpu.telemetry.tracing.events import scope_tokens
     from megatron_tpu.training.aot import aot_compile_train_step
 
@@ -320,7 +325,14 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
     results = re.findall(
         r"%moe_t?gmm[.\d]* = (\w+\[[\d,]+\])", text)
     assert set(results) == {"bf16[32768,2048]", "bf16[32768,1024]",
-                            "bf16[64,2048,2048]", "bf16[64,1024,2048]"}
+                            "f32[1,64,2048,2048]", "f32[1,64,1024,2048]"}
+    _assert_only_the_kernel_touches_the_accumulators(
+        text, r"f32\[1,64,(2048|1024),2048\]")
+    # what the rest of the loop adds is the other leaves': the parent's
+    # two `grad_accumulate/add` fusions of the experts' shapes are gone
+    assert not re.findall(
+        r"= (?:bf16|f32)\[(?:1,)?64,(?:2048|1024),2048\][^\n]*"
+        r"grad_accumulate", text)
     for toks in _kernel_name_stacks(text):
         kernel = toks[-2]
         if kernel.startswith("moe_"):
@@ -343,6 +355,77 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
     _assert_head_loss_forms_its_logits_once(
         text, f"{cfg.ce_chunk_size},{cfg.vocab_size}",
         16 * SEQ // cfg.ce_chunk_size)
+
+
+def _computations(text):
+    """{name: [instruction lines]} of a compiled program's text, and the
+    names of the computations that are some fusion's body."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line.strip())
+    return comps, set(re.findall(r"calls=%?([\w.\-]+)", text))
+
+
+def _assert_only_the_kernel_touches_the_accumulators(text, shape):
+    """In the step program `text`, inside the micro-batch loop (every
+    computation but the entry, which zeroes the accumulators and runs the
+    optimizer over them), the only instructions with an array result of
+    an expert accumulator's `shape` (a regex) are `moe_tgmm` calls whose
+    result aliases an operand: no fusion, no copy, no (dynamic-)slice or
+    update of that size; loops and tuples only pass them on."""
+    comps, fused = _computations(text)
+    entry = re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)
+    kernels = 0
+    for name, lines in comps.items():
+        if name == entry or name in fused:
+            continue
+        for line in lines:
+            m = re.match(r"(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+            if (not m or not re.fullmatch(shape + r"(\{[^ ]*)?", m.group(2))
+                    or m.group(3) in ("get-tuple-element", "parameter",
+                                      "bitcast")):
+                continue
+            assert m.group(1).startswith("moe_tgmm"), (name, line[:200])
+            assert "output_to_operand_aliasing={{}: (7, {})}" in line, line
+            kernels += 1
+    assert kernels == 2, kernels
+
+
+def test_olmoe_depth_2_carries_the_accumulators_through_the_layer_loop(topo):
+    """Where the layer scan is a loop (two layers; 16 experts at the
+    published widths, a size the described chip compiles), the stacked
+    float32 accumulators of the expert matrices ride the BACKWARD loop's
+    carry and `moe_tgmm` updates the layer's blocks in place: no
+    `dynamic-slice`, `dynamic-update-slice`, `copy` or fusion of an
+    accumulator's size anywhere inside the micro-batch loop, as a scanned
+    slice of the stack would need. The forward loop does not carry them
+    at all: the hand-through folds away."""
+    from megatron_tpu.training.aot import aot_compile_train_step
+
+    cfg = dataclasses.replace(
+        presets.olmoe(seq_length=SEQ), num_layers=2, num_experts=16,
+        params_dtype="bfloat16", ce_chunk_size=512,
+        attention_impl="pallas").validate()
+    compiled, _ = aot_compile_train_step(
+        cfg, ParallelConfig(), OptimizerConfig(lr=1e-4),
+        micro_batch_size=1, num_microbatches=4, recompute="selective",
+        devices=topo.devices[:1])
+    text = compiled.as_text()
+    stack = r"f32\[2,16,(2048|1024),2048\]"
+    _assert_only_the_kernel_touches_the_accumulators(text, stack)
+    # the loops that hold a stack in their state: the micro-batch loop and
+    # in it the backward layer loop, and no third (the forward one)
+    comps, _ = _computations(text)
+    loops = [line for lines in comps.values() for line in lines
+             if " while(" in line
+             and re.search(stack, line.split(" while(")[0])]
+    assert len(loops) == 2, [line[:120] for line in loops]
 
 
 def _scatter_op_names(text):

@@ -85,11 +85,13 @@ def test_bf16_operands_give_bf16_results(as_on_one_tpu, layout):
 
 @pytest.mark.parametrize("tiles", [(128, 128, 128), (256, 128, 384),
                                    (512, 256, 128), (128, 256, 384)])
-@pytest.mark.parametrize("kernel", ["gmm", "gmm_transposed", "tgmm"])
+@pytest.mark.parametrize("kernel", ["gmm", "gmm_transposed", "tgmm",
+                                    "tgmm_into"])
 def test_each_kernel_at_tiles_of_several_steps(kernel, tiles):
     """Every kernel at tiles that cut k and n into several steps (the
     accumulator across k tiles, the row tile's visits across n tiles) and
-    at row tiles of one to four visits a group."""
+    at row tiles of one to four visits a group; `moe_tgmm` also in the
+    form that sums into an accumulator's blocks."""
     sizes = LAYOUTS["boundaries_off_the_tile"]
     lhs, rhs, weight, gs = _operands(sizes)
     visits = gm.group_visits(gs, M, tiles[0])
@@ -102,10 +104,136 @@ def test_each_kernel_at_tiles_of_several_steps(kernel, tiles):
         got = gm._gmm(weight, rhs, visits, (tm, tn, tk), True)
         want = jax.lax.ragged_dot(weight, jnp.swapaxes(rhs, 1, 2), gs)
     else:
-        got = gm._tgmm(lhs, weight, visits, tiles)
         want = jax.grad(lambda r: jnp.sum(
             jax.lax.ragged_dot(lhs, r, gs) * weight))(rhs)
+        if kernel == "tgmm":
+            got = gm._tgmm(lhs, weight, visits, tiles)
+        else:
+            got, = gm._tgmm(lhs, weight, visits, tiles,
+                            into=(rhs[None], jnp.int32(0)))
+            want = rhs + want
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-4)
+
+
+def _sunk(lhs, rhs, weight, gs, stack, layer):
+    """Value, d lhs, rhs's own cotangent and the stack's, of
+    grouped_matmul with `stack` as the sink and as its cotangent."""
+    def fn(a, b, c):
+        out, c = gm.grouped_matmul(a, b, gs, sink=(c, layer))
+        return jnp.sum(out * weight, dtype=jnp.float32), (c, out)
+
+    (_, (through, out)), vjp = jax.vjp(fn, lhs, rhs, stack)
+    dlhs, drhs, summed = vjp((jnp.ones((), jnp.float32),
+                              (stack, jnp.zeros_like(out))))
+    return out, dlhs, drhs, summed, through
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_a_sink_receives_its_cotangent_plus_the_weight_gradient(
+        as_on_one_tpu, layout):
+    """With a sink, the stack goes through the function untouched and the
+    gradient rule answers its cotangent (a running sum) with that sum plus
+    the float32 `lax.ragged_dot` weight gradient at the named layer; the
+    value and the rows' gradient are those without a sink, and rhs's own
+    cotangent is zero: the gradient exists once."""
+    sizes = LAYOUTS[layout]
+    lhs, rhs, weight, gs = _operands(sizes)
+    stack = jax.random.normal(jax.random.PRNGKey(7), (1,) + rhs.shape)
+    out, dlhs, drhs, summed, through = _sunk(lhs, rhs, weight, gs, stack,
+                                             jnp.int32(0))
+    want = _value_and_grads(lambda a, b: jax.lax.ragged_dot(a, b, gs),
+                            lhs, rhs, weight)
+    np.testing.assert_array_equal(through, stack)
+    np.testing.assert_allclose(out, want[0], rtol=2e-5, atol=2e-4)
+    np.testing.assert_allclose(dlhs, want[1], rtol=2e-5, atol=2e-4)
+    np.testing.assert_allclose(summed[0], stack[0] + want[2],
+                               rtol=2e-5, atol=2e-4)
+    assert not np.any(np.asarray(drhs))
+    for e, size in enumerate(sizes):
+        if size == 0:   # an empty group adds zero, exactly
+            np.testing.assert_array_equal(summed[0, e], stack[0, e])
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_only_the_named_layer_of_a_stack_changes(as_on_one_tpu, layer):
+    """In a stack of three layers' accumulators the kernel reads and
+    writes the named layer's blocks; the other two come back bit-equal."""
+    lhs, rhs, weight, gs = _operands(LAYOUTS["empty_group"])
+    stack = jax.random.normal(jax.random.PRNGKey(8), (3,) + rhs.shape)
+    summed = _sunk(lhs, rhs, weight, gs, stack, jnp.int32(layer))[3]
+    want = jax.grad(lambda r: jnp.sum(
+        jax.lax.ragged_dot(lhs, r, gs) * weight))(rhs)
+    for at in range(3):
+        if at == layer:
+            np.testing.assert_allclose(summed[at], stack[at] + want,
+                                       rtol=2e-5, atol=2e-4)
+        else:
+            np.testing.assert_array_equal(summed[at], stack[at])
+
+
+@pytest.mark.parametrize("layout", ["skewed", "empty_group"])
+def test_bf16_operands_sum_in_float32(as_on_one_tpu, layout):
+    """bf16 operands, a float32 sum: the product reaches the accumulator
+    unrounded, so the sum is nearer the float32 gradient than the sum of
+    the rounded gradient that the form without a sink gives."""
+    lhs, rhs, weight, gs = _operands(LAYOUTS[layout], jnp.bfloat16)
+    stack = jax.random.normal(jax.random.PRNGKey(9), (1,) + rhs.shape)
+    summed = _sunk(lhs, rhs, weight, gs, stack, jnp.int32(0))[3]
+    assert summed.dtype == jnp.float32
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    exact = stack[0] + jax.grad(lambda r: jnp.sum(
+        jax.lax.ragged_dot(f32(lhs), r, gs) * f32(weight)))(f32(rhs))
+    rounded = stack[0] + f32(_value_and_grads(
+        lambda a, b: gm.grouped_matmul(a, b, gs), lhs, rhs, weight)[2])
+    np.testing.assert_allclose(summed[0], exact, rtol=1e-5, atol=1e-3)
+    assert (np.abs(summed[0] - exact).max()
+            < 0.1 * np.abs(rounded - exact).max())
+
+
+def test_without_a_sink_the_kernel_is_the_one_it_was(as_on_one_tpu):
+    """No sink: `moe_tgmm` takes the visit table's four scalar operands,
+    aliases nothing and gives the operands' dtype; with one it takes the
+    layer as a fifth and its float32 result is its last operand's
+    buffer."""
+    lhs, rhs, weight, gs = _operands(LAYOUTS["skewed"], jnp.bfloat16)
+    stack = jnp.zeros((2,) + rhs.shape, jnp.float32)
+
+    def tgmm_call(fn, *args):
+        eqns = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+                if e.primitive.name == "pallas_call"
+                and "moe_tgmm" in str(e.params["name"])]
+        assert len(eqns) == 1
+        return eqns[0]
+
+    plain = tgmm_call(jax.grad(lambda b: jnp.sum(
+        gm.grouped_matmul(lhs, b, gs) * weight, dtype=jnp.float32)), rhs)
+    assert plain.params["grid_mapping"].num_index_operands == 4
+    assert not plain.params["input_output_aliases"]
+    assert [v.aval.dtype for v in plain.outvars] == [jnp.bfloat16]
+
+    into = tgmm_call(lambda c: _sunk(lhs, rhs, weight, gs, c,
+                                     jnp.int32(1))[3], stack)
+    assert into.params["grid_mapping"].num_index_operands == 5
+    assert tuple(into.params["input_output_aliases"]) == ((7, 0),)
+    assert [v.aval.dtype for v in into.outvars] == [jnp.float32]
+    assert into.outvars[0].aval.shape == stack.shape
+
+
+def test_a_sink_where_the_products_are_not_the_kernels_is_refused():
+    """`takes_sink` is the predicate the products go by: off the TPU (this
+    backend) it is false, and a sink handed in all the same raises."""
+    lhs, rhs, _, gs = _operands(LAYOUTS["skewed"])
+    assert not gm.takes_sink(M, K, N, len(gs))
+    with pytest.raises(ValueError, match="takes_sink"):
+        gm.grouped_matmul(lhs, rhs, gs, sink=(
+            jnp.zeros((1,) + rhs.shape), jnp.int32(0)))
+
+
+def test_takes_sink_follows_the_tiles(as_on_one_tpu):
+    assert gm.takes_sink(M, K, N, 4)
+    assert gm.takes_sink(32768, 2048, 2048, 64)
+    assert not gm.takes_sink(8, 2048, 2048, 4)       # a decode batch
+    assert not gm.takes_sink(512, 200, 384, 4)       # lanes off 128
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))

@@ -5,7 +5,8 @@
 
 Times each of the six products of one dropless MoE layer's experts
 (megatron_tpu/ops/pallas/grouped_matmul.py: forward and the two backward
-products of the gate-up and of the down matrix) at every tile of the grid
+products of the gate-up and of the down matrix; `moe_tgmm` also in the
+form that sums into a float32 accumulator, `ms_into`) at every tile of the grid
 below, against `lax.ragged_dot` on the same rows, and prints one JSON line
 a measurement (also written under --out). `pick_gmm_tiles` keeps the rule
 this table teaches; PERF.md keeps the table.
@@ -82,15 +83,25 @@ def mixtral_group_sizes(m: int) -> np.ndarray:
     return sizes
 
 
-def time_ms(fn, args, reps: int = 3, calls: int = 10) -> float:
-    fn = jax.jit(fn)
-    jax.block_until_ready(fn(*args))
+def time_ms(fn, args, carried=None, reps: int = 3, calls: int = 10) -> float:
+    """Best mean of `calls` calls of fn(*args), or with `carried` of
+    carried = fn(*args, carried): the last argument donated and the result
+    fed back, as a step's accumulator is."""
+    if carried is None:
+        fn = jax.jit(fn)
+        jax.block_until_ready(fn(*args))
+    else:
+        fn = jax.jit(fn, donate_argnums=len(args))
+        carried = jax.block_until_ready(fn(*args, carried))
     best = float("inf")
     for _ in range(reps):
         t0 = time.perf_counter()
         out = None
         for _ in range(calls):
-            out = fn(*args)
+            if carried is None:
+                out = fn(*args)
+            else:
+                out = carried = fn(*args, carried)
         jax.block_until_ready(out)
         best = min(best, (time.perf_counter() - t0) / calls)
     return 1e3 * best
@@ -104,6 +115,8 @@ def main() -> int:
     ap.add_argument("--tk", type=int, nargs="+", default=[512, 1024, 0],
                     help="0 = the whole contraction")
     ap.add_argument("--tn", type=int, nargs="+", default=[512, 1024, 2048])
+    ap.add_argument("--products", nargs="+", default=None,
+                    help="only these (fwd_in, ..., tgmm_out)")
     ap.add_argument("--out", default="chiprun_out/gmm_sweep")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
@@ -143,6 +156,8 @@ def main() -> int:
                 ("drows_in", "nt", f2, h), ("drows_out", "nt", h, f),
                 ("tgmm_in", "t", h, f2), ("tgmm_out", "t", f, h)]
     for name, kind, k, n in products:
+        if args.products and name not in args.products:
+            continue
         lhs = rand(1, (m, k))
         if kind == "t":
             other = rand(2, (m, n))
@@ -156,7 +171,7 @@ def main() -> int:
             table = gm.group_visits(gs, m, plan.fwd[0])
             ways = {"ragged_dot": lambda a, b: jax.lax.ragged_dot(a, b, gs),
                     "picked": lambda a, b: gm._grouped_matmul_kernels(
-                        a, b, table, plan)}
+                        a, b, table, None, plan)}
             for way, fn in ways.items():
                 both = jax.grad(lambda a, b, fn=fn: jnp.sum(
                     fn(a, b).astype(jnp.float32)), argnums=(0, 1))
@@ -181,6 +196,14 @@ def main() -> int:
                     fn = lambda a, b: gm._gmm(a, b, visits, (tm, tk, tn),
                                               kind == "nt")
                 rec["ms"] = time_ms(fn, (lhs, other))
+                if kind == "t":
+                    # the form that sums into a float32 accumulator of the
+                    # result's shape, in place (a stack of one layer)
+                    rec["ms_into"] = time_ms(
+                        lambda a, b, c: gm._tgmm(
+                            a, b, visits, (tm, tk, tn),
+                            into=(c, jnp.int32(0))),
+                        (lhs, other), jnp.zeros((1, E, k, n), jnp.float32))
             except Exception as e:  # noqa: BLE001 - a tile the compiler
                 # refuses is a row of the table, not the end of the sweep
                 rec["error"] = str(e).splitlines()[0][:160]
