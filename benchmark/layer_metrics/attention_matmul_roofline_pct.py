@@ -1,0 +1,15 @@
+"""Share of the MXU's peak the attention projections reach: the least
+time one device could take for the FLOP that the Q, K, V and output
+projections NEED a step (kernel_costs/attention_matmul.py: forward and
+both backward products of every layer, nothing computed again; from the
+cell's configuration, the mix's tokens a step and its TP x DP; peaks from
+benchmark/peaks.json) over `attention_matmul_ms_per_step`. The record,
+with the compiler's own FLOP count of those operations beside the needed,
+goes to the line's `extras.roofline.attention_matmul`. None in a
+rehearsal (no peaks) or on an untraced run."""
+
+from benchmark.harness.trace import classes
+
+
+def read(run):
+    return classes.matmul_roofline_pct(run, "attention_matmul", "attention")

@@ -100,7 +100,17 @@ def scan_with_remat(body, carry, xs, recompute: str):
     T5 enc/dec slices). "block:N" splits the scan: iterations [0, N)
     under full remat, [N, len) saved (ref --recompute_method block,
     transformer.py:1148-1172). The block path discards scan outputs
-    (callers using ys — decode caches — never run block)."""
+    (callers using ys — decode caches — never run block).
+
+    Every form of it runs under the scope `layer_stack`: the loop's own
+    work (slicing the stacked weights, stacking what the backward pass
+    saved) sits under no region of a layer, and a device trace finds it
+    by this name (docs/observability.md "Runtime traces")."""
+    with jax.named_scope("layer_stack"):
+        return _scan_layers(body, carry, xs, recompute)
+
+
+def _scan_layers(body, carry, xs, recompute: str):
     gran, block_n = parse_recompute(recompute)
     if gran == "block":
         length = jax.tree.leaves(xs)[0].shape[0]

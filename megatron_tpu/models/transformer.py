@@ -94,22 +94,28 @@ def attention_block(
     D = cfg.head_dim
     nq, nkv = cfg.num_attention_heads, cfg.n_kv_heads
 
-    q = maybe_fp8_matmul(cfg, x, deq(p["wq"], x.dtype))
-    k = maybe_fp8_matmul(cfg, x, deq(p["wk"], x.dtype))
-    v = maybe_fp8_matmul(cfg, x, deq(p["wv"], x.dtype))
-    if "bq" in p:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    if cfg.qk_norm:
-        # over all heads at once: under TP the mean square crosses shards
-        # (GSPMD reduces it); the cache below holds normed, rotated keys
-        q = rmsnorm(q, p["q_norm"]["scale"], cfg.layernorm_epsilon)
-        k = rmsnorm(k, p["k_norm"]["scale"], cfg.layernorm_epsilon)
-    q = q.reshape(b, s, nq, D)
-    k = k.reshape(b, s, nkv, D)
-    v = v.reshape(b, s, nkv, D)
+    # The scopes inside a region name its parts for a device trace (docs/
+    # observability.md "Runtime traces"): projections, rotary, everything
+    # around the kernel, the out projection with what GSPMD hangs on it.
+    with jax.named_scope("attn_qkv"):
+        q = maybe_fp8_matmul(cfg, x, deq(p["wq"], x.dtype))
+        k = maybe_fp8_matmul(cfg, x, deq(p["wk"], x.dtype))
+        v = maybe_fp8_matmul(cfg, x, deq(p["wv"], x.dtype))
+        if "bq" in p:
+            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+        if cfg.qk_norm:
+            # over all heads at once: under TP the mean square crosses
+            # shards (GSPMD reduces it); the cache below holds normed,
+            # rotated keys
+            q = rmsnorm(q, p["q_norm"]["scale"], cfg.layernorm_epsilon)
+            k = rmsnorm(k, p["k_norm"]["scale"], cfg.layernorm_epsilon)
+        q = q.reshape(b, s, nq, D)
+        k = k.reshape(b, s, nkv, D)
+        v = v.reshape(b, s, nkv, D)
 
     if rope is not None:
-        q, k = apply_rotary_emb(q, k, rope[0], rope[1], positions)
+        with jax.named_scope("attn_rope"):
+            q, k = apply_rotary_emb(q, k, rope[0], rope[1], positions)
 
     # CP prefill (VERDICT r4 #6): when the whole prompt enters at once
     # (cache_index is a STATIC 0 — the prefill call site passes a Python
@@ -139,90 +145,95 @@ def attention_block(
     if page_table is not None and kv_cache is None:
         raise ValueError("page_table requires a (paged) kv_cache")
 
-    q_offset = 0
-    kv_lengths = None
-    table = None
-    ctx = None
-    if cp_paged:
-        if cp_comm is None:
+    with jax.named_scope("attn_core"):
+        q_offset = 0
+        kv_lengths = None
+        table = None
+        ctx = None
+        if cp_paged:
+            if cp_comm is None:
+                raise ValueError(
+                    "a [cp, rows, pages] page table requires cp_comm "
+                    "(quant/collectives.make_cp_comm)")
+            if kv_store.is_int8(kv_cache):
+                raise ValueError(
+                    "context-parallel paged serving does not support int8 "
+                    "KV pools (stripe the bf16 pools instead)")
+            from megatron_tpu.inference.context_parallel.ring_kv import (
+                paged_ring_attention,
+            )
+
+            ctx, kv_cache = paged_ring_attention(
+                cp_comm, q, k, v, kv_cache, layer, page_table, cache_index,
+                per_slot, page_write_start, page_write_end,
+                sliding_window=cfg.sliding_window_size)
+        elif kv_cache is not None:
+            kv_cache = kv_store.write(kv_cache, layer, k, v, cache_index,
+                                      page_table, page_write_start,
+                                      page_write_end)
+            if not cp_prefill:
+                k, v, table = kv_store.read(kv_cache, layer, page_table,
+                                            cfg.dtype)
+                if per_slot:
+                    kv_lengths = cache_index + 1
+                else:
+                    q_offset = cache_index
+
+        if cfg.attn_mask_type == "padding" and padding_mask is None:
             raise ValueError(
-                "a [cp, rows, pages] page table requires cp_comm "
-                "(quant/collectives.make_cp_comm)")
-        if kv_store.is_int8(kv_cache):
-            raise ValueError(
-                "context-parallel paged serving does not support int8 "
-                "KV pools (stripe the bf16 pools instead)")
-        from megatron_tpu.inference.context_parallel.ring_kv import (
-            paged_ring_attention,
-        )
+                "attn_mask_type='padding' requires an attention_mask input — "
+                "running without one would silently attend to pad tokens")
+        if ctx is None:
+            ctx = attention(
+                q, k, v,
+                mask_type=("bidirectional" if cfg.attn_mask_type == "padding"
+                           else cfg.attn_mask_type),
+                padding_mask=padding_mask,
+                sliding_window=cfg.sliding_window_size,
+                dropout=(cfg.attention_dropout
+                         if attn_dropout_key is not None else 0.0),
+                dropout_rng=attn_dropout_key,
+                q_offset=q_offset,
+                impl=cfg.attention_impl,
+                softmax_fp32=cfg.softmax_fp32,
+                kv_lengths=kv_lengths,
+                page_table=table,
+            )
+    with jax.named_scope("attn_out"):
+        if tp_comm is not None and "attn_out" in tp_comm.sites:
+            # explicit row-parallel reduction (dense psum or the compressed
+            # quantize->all_to_all->reduce->all_gather; quant/collectives.py)
+            from megatron_tpu.quant.collectives import row_parallel_matmul
 
-        ctx, kv_cache = paged_ring_attention(
-            cp_comm, q, k, v, kv_cache, layer, page_table, cache_index,
-            per_slot, page_write_start, page_write_end,
-            sliding_window=cfg.sliding_window_size)
-    elif kv_cache is not None:
-        kv_cache = kv_store.write(kv_cache, layer, k, v, cache_index,
-                                  page_table, page_write_start,
-                                  page_write_end)
-        if not cp_prefill:
-            k, v, table = kv_store.read(kv_cache, layer, page_table,
-                                        cfg.dtype)
-            if per_slot:
-                kv_lengths = cache_index + 1
-            else:
-                q_offset = cache_index
-
-    if cfg.attn_mask_type == "padding" and padding_mask is None:
-        raise ValueError(
-            "attn_mask_type='padding' requires an attention_mask input — "
-            "running without one would silently attend to pad tokens")
-    if ctx is None:
-        ctx = attention(
-            q, k, v,
-            mask_type=("bidirectional" if cfg.attn_mask_type == "padding"
-                       else cfg.attn_mask_type),
-            padding_mask=padding_mask,
-            sliding_window=cfg.sliding_window_size,
-            dropout=(cfg.attention_dropout
-                     if attn_dropout_key is not None else 0.0),
-            dropout_rng=attn_dropout_key,
-            q_offset=q_offset,
-            impl=cfg.attention_impl,
-            softmax_fp32=cfg.softmax_fp32,
-            kv_lengths=kv_lengths,
-            page_table=table,
-        )
-    if tp_comm is not None and "attn_out" in tp_comm.sites:
-        # explicit row-parallel reduction (dense psum or the compressed
-        # quantize->all_to_all->reduce->all_gather; quant/collectives.py)
-        from megatron_tpu.quant.collectives import row_parallel_matmul
-
-        out = row_parallel_matmul(ctx.reshape(b, s, nq * D),
-                                  deq(p["wo"], ctx.dtype), tp_comm,
-                                  "attn_out")
-    else:
-        out = maybe_fp8_matmul(cfg, ctx.reshape(b, s, nq * D),
-                               deq(p["wo"], ctx.dtype))
-    if "bo" in p:
-        out = out + p["bo"]
+            out = row_parallel_matmul(ctx.reshape(b, s, nq * D),
+                                      deq(p["wo"], ctx.dtype), tp_comm,
+                                      "attn_out")
+        else:
+            out = maybe_fp8_matmul(cfg, ctx.reshape(b, s, nq * D),
+                                   deq(p["wo"], ctx.dtype))
+        if "bo" in p:
+            out = out + p["bo"]
     return out, kv_cache
 
 
 def mlp_block(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray,
               tp_comm=None) -> jnp.ndarray:
-    h = maybe_fp8_matmul(cfg, x, deq(p["w_in"], x.dtype))
-    if "b_in" in p:
-        h = h + p["b_in"]
-    h = apply_activation(cfg.activation, h)
-    if tp_comm is not None and "mlp_out" in tp_comm.sites:
-        from megatron_tpu.quant.collectives import row_parallel_matmul
+    with jax.named_scope("mlp_in"):
+        h = maybe_fp8_matmul(cfg, x, deq(p["w_in"], x.dtype))
+        if "b_in" in p:
+            h = h + p["b_in"]
+    with jax.named_scope("mlp_act"):
+        h = apply_activation(cfg.activation, h)
+    with jax.named_scope("mlp_out"):
+        if tp_comm is not None and "mlp_out" in tp_comm.sites:
+            from megatron_tpu.quant.collectives import row_parallel_matmul
 
-        out = row_parallel_matmul(h, deq(p["w_out"], h.dtype), tp_comm,
-                                  "mlp_out")
-    else:
-        out = maybe_fp8_matmul(cfg, h, deq(p["w_out"], h.dtype))
-    if "b_out" in p:
-        out = out + p["b_out"]
+            out = row_parallel_matmul(h, deq(p["w_out"], h.dtype), tp_comm,
+                                      "mlp_out")
+        else:
+            out = maybe_fp8_matmul(cfg, h, deq(p["w_out"], h.dtype))
+        if "b_out" in p:
+            out = out + p["b_out"]
     return out
 
 
@@ -284,11 +295,16 @@ def block_forward(
     # The two named scopes are the regions a device trace is read by
     # (docs/observability.md "Runtime traces"): every operation of a layer,
     # forward, backward or recomputed, carries "attention" or "mlp" in its
-    # name stack, whichever jaxpr wrapper XLA names it after.
+    # name stack, whichever jaxpr wrapper XLA names it after. The scopes
+    # inside them (`attn_norm` ... `attn_out`, `mlp_norm` ... `mlp_out`;
+    # attention_block and mlp_block hold the middle ones) say which part
+    # of its region an operation belongs to, and move no operation from
+    # one region to another.
     with jax.named_scope("attention"):
         # post-LN (ref --use_post_ln): no pre-norm; the layer ends with its
         # own LN, reusing the ln1 parameter slot as the output norm
-        normed = x if cfg.use_post_ln else _norm(cfg, lp["ln1"], x)
+        with jax.named_scope("attn_norm"):
+            normed = x if cfg.use_post_ln else _norm(cfg, lp["ln1"], x)
         attn_out, kv_cache = attention_block(
             cfg, lp["attn"], normed, rope, positions,
             attn_dropout_key=k_attn_drop if cfg.attention_dropout > 0 else None,
@@ -300,31 +316,36 @@ def block_forward(
             tp_comm=tp_comm,
             cp_comm=cp_comm,
         )
-        attn_out = _dropout(attn_out, rate, k_hidden1 if cfg.hidden_dropout > 0 else None)
-        if not cfg.parallel_attn:
-            # residual from the LN output with --apply_residual_connection_
-            # post_layernorm (ref transformer.py:795-799)
-            res1 = normed if cfg.apply_residual_post_ln else x
-            y = sharder(res1 + attn_out, "residual")
+        with jax.named_scope("attn_out"):
+            attn_out = _dropout(attn_out, rate, k_hidden1 if cfg.hidden_dropout > 0 else None)
+            if not cfg.parallel_attn:
+                # residual from the LN output with --apply_residual_
+                # connection_post_layernorm (ref transformer.py:795-799)
+                res1 = normed if cfg.apply_residual_post_ln else x
+                y = sharder(res1 + attn_out, "residual")
 
     with jax.named_scope("mlp"):
         if cfg.parallel_attn:
             # Falcon: mlp input is ln1(x) (7B) or a dedicated ln_mlp(x)
             # (40B); one residual add for both branches.
-            mlp_in = _norm(cfg, lp["ln_mlp"], x) if cfg.parallel_layernorm else normed
+            with jax.named_scope("mlp_norm"):
+                mlp_in = _norm(cfg, lp["ln_mlp"], x) if cfg.parallel_layernorm else normed
             mlp_out, moe_aux, grad_sink = _ffn(
                 cfg, lp, mlp_in, tp_comm, grad_sink, layer)
-            mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
-            res = normed if cfg.apply_residual_post_ln else x
-            y = res + attn_out + mlp_out
+            with jax.named_scope("mlp_out"):
+                mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
+                res = normed if cfg.apply_residual_post_ln else x
+                y = res + attn_out + mlp_out
         else:
-            normed2 = _norm(cfg, lp["ln2"], y)
+            with jax.named_scope("mlp_norm"):
+                normed2 = _norm(cfg, lp["ln2"], y)
             mlp_out, moe_aux, grad_sink = _ffn(
                 cfg, lp, normed2, tp_comm, grad_sink, layer)
-            mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
-            res2 = normed2 if cfg.apply_residual_post_ln else y
-            y = res2 + mlp_out
-            if cfg.use_post_ln:
-                y = _norm(cfg, lp["ln1"], y)
+            with jax.named_scope("mlp_out"):
+                mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
+                res2 = normed2 if cfg.apply_residual_post_ln else y
+                y = res2 + mlp_out
+                if cfg.use_post_ln:
+                    y = _norm(cfg, lp["ln1"], y)
     y = sharder(y, "residual")
     return y, kv_cache, moe_aux, grad_sink

@@ -53,9 +53,9 @@ from megatron_tpu.analysis.taxonomy import (
     COLLECTIVE_PRIMITIVES, is_collective_done_half,
 )
 from megatron_tpu.telemetry.tracing.events import (
-    DEVICE_PLANE_PREFIX, KERNEL_SCOPES, KIND_COLLECTIVE, KIND_COMPUTE,
-    KIND_HOST, KIND_INFEED, REGION_SCOPES, OpEvent, innermost_scope, modules,
-    scope_tokens, step_markers,
+    DEVICE_PLANE_PREFIX, KIND_COLLECTIVE, KIND_COMPUTE, KIND_HOST,
+    KIND_INFEED, OP_CLASSES, REGION_SCOPES, OpEvent, innermost_scope,
+    kernel_of, modules, op_class, scope_tokens, step_markers,
 )
 
 PS_PER_S = 1e12
@@ -182,8 +182,13 @@ class TraceReport:
     scopes: Dict[str, float] = dataclasses.field(default_factory=dict)
     #   region scope (or "other") -> op self seconds; empty where the
     #   trace carries none of the program's region names
+    scope_classes: Dict[str, Dict[str, float]] = dataclasses.field(
+        default_factory=dict)
+    #   the same seconds by class of work (events.op_class, from the
+    #   profiler's hlo_category): region -> class -> seconds, and under
+    #   UNNAMED_SCOPE the part of "other" that has no name stack at all
     kernels: Dict[str, Dict[str, float]] = dataclasses.field(
-        default_factory=dict)             # kernel scope -> count, self_s
+        default_factory=dict)             # kernel name -> count, self_s
     idle_gaps: List[Dict[str, Any]] = dataclasses.field(
         default_factory=list)             # host span -> count, total_s, max_s
 
@@ -224,6 +229,9 @@ class TraceReport:
             "modules": {m: round(s, 6)
                         for m, s in sorted(self.all_modules.items())},
             "scopes": {k: round(v, 6) for k, v in self.scopes.items()},
+            "scope_classes": {
+                scope: {c: round(v, 6) for c, v in by_class.items()}
+                for scope, by_class in self.scope_classes.items()},
             "kernels": self.kernels,
             "idle_gaps": self.idle_gaps[:top],
         }
@@ -241,6 +249,7 @@ def _percentile(sorted_vals: List[float], q: float) -> float:
 
 
 OTHER_SCOPE = "other"
+UNNAMED_SCOPE = "(unnamed)"   # operations with no name stack: part of "other"
 
 
 def idle_gaps_by_host_span(events: List[OpEvent]) -> List[Dict[str, Any]]:
@@ -309,6 +318,7 @@ def analyze_events(events: List[OpEvent],
     coll_events: Dict[str, List[OpEvent]] = {}           # plane -> events
     xla_span: List[int] = []  # [min_start, max_end] of the module's ops
     scope_ps: Dict[str, int] = {}
+    class_ps: Dict[str, Dict[str, int]] = {}
     kernels: Dict[str, Dict[str, float]] = {}
     for (plane, _line), line_events in by_line.items():
         for e, segs, self_ps in self_segments(line_events):
@@ -333,11 +343,17 @@ def analyze_events(events: List[OpEvent],
             parts = scope_tokens(e.tf_op)
             region = innermost_scope(parts, REGION_SCOPES) or OTHER_SCOPE
             scope_ps[region] = scope_ps.get(region, 0) + self_ps
-            kernel = innermost_scope(parts, KERNEL_SCOPES)
-            if kernel is not None and "custom-call" in e.detail:
+            kernel = (kernel_of(parts) if "custom-call" in e.detail
+                      else None)
+            if kernel is not None:
                 k = kernels.setdefault(kernel, {"count": 0, "self_s": 0.0})
                 k["count"] += 1
                 k["self_s"] += self_ps / PS_PER_S
+            cls = op_class(e.name, e.category, kernel is not None)
+            for scope in ((region,) if e.tf_op
+                          else (region, UNNAMED_SCOPE)):
+                class_ps.setdefault(
+                    scope, dict.fromkeys(OP_CLASSES, 0))[cls] += self_ps
             if e.kind == KIND_COLLECTIVE and e.collective:
                 coll_events.setdefault(plane, []).append(e)
             if not xla_span:
@@ -375,7 +391,9 @@ def analyze_events(events: List[OpEvent],
         }
 
     if set(scope_ps) <= {OTHER_SCOPE}:
-        scope_ps = {}   # a trace without the program's names: no table
+        # without the program's names: no tables (an unnamed kernel's
+        # stack closes in `pallas_call` behind one of JAX's own parts)
+        scope_ps = class_ps = kernels = {}
     for k in kernels.values():
         k["self_s"] = round(k["self_s"], 6)
 
@@ -390,6 +408,9 @@ def analyze_events(events: List[OpEvent],
         all_modules=per_module,
         scopes={k: v / PS_PER_S for k, v in sorted(
             scope_ps.items(), key=lambda kv: -kv[1])},
+        scope_classes={
+            scope: {c: ps / PS_PER_S for c, ps in by_class.items()}
+            for scope, by_class in class_ps.items()},
         kernels=dict(sorted(kernels.items(),
                             key=lambda kv: -kv[1]["self_s"])),
         idle_gaps=idle_gaps_by_host_span(events),

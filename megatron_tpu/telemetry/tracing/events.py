@@ -24,8 +24,13 @@ the step markers the analyzer derives per-step wall from.
 
 The program's names (docs/observability.md "Runtime traces") arrive in
 ``tf_op``, the jaxpr name stack: ``scope_tokens`` takes it apart,
-``REGION_SCOPES`` and ``KERNEL_SCOPES`` are the ``jax.named_scope`` names
-the model and the flash kernels put there.
+``REGION_SCOPES`` are the ``jax.named_scope`` names the model puts there,
+and ``kernel_of`` finds a Pallas kernel by the part in front of the stack's
+closing ``pallas_call`` (``pallas_call(name=)`` puts it there, whatever the
+kernel is called: there is no list of kernels to keep). ``hlo_category``,
+the profiler's own word for what an operation is, gives its class of work
+(``op_class``): the column ``tools/trace_report.py`` prints beside each
+scope, the same classes the benchmark reads a traced run by.
 """
 
 from __future__ import annotations
@@ -35,7 +40,9 @@ import dataclasses
 import re
 from typing import Dict, Iterable, List, Optional
 
-from megatron_tpu.analysis.taxonomy import collective_base, is_transfer
+from megatron_tpu.analysis.taxonomy import (
+    HLO_COLLECTIVE_OPS, collective_base, is_transfer,
+)
 from megatron_tpu.telemetry.tracing.xplane import XSpace
 
 KIND_COMPUTE = "compute"
@@ -53,12 +60,26 @@ DEVICE_STEP_LINE = "Steps"
 
 #: jax.named_scope names in the program, innermost wins
 #: (models/transformer.py, models/language_model.py,
-#: training/train_step.py; ops/pallas/flash_template.py,
-#: ops/pallas/grouped_matmul.py)
+#: training/train_step.py)
 REGION_SCOPES = ("optimizer", "head_loss", "attention", "mlp", "embed")
-KERNEL_SCOPES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-                 "flash_decode", "paged_flash_decode",
-                 "moe_gmm", "moe_tgmm")
+PALLAS = "pallas_call"
+
+#: classes of work, by the profiler's `hlo_category` (`op_class`)
+CLASS_KERNEL, CLASS_MATMUL = "kernel", "matmul"
+CLASS_COLLECTIVE, CLASS_COLLECTIVE_FUSED = "collective", "collective_fused"
+CLASS_ELEMENTWISE, CLASS_DATA_MOVEMENT = "elementwise", "data_movement"
+CLASS_REST = "rest"
+OP_CLASSES = (CLASS_KERNEL, CLASS_MATMUL, CLASS_COLLECTIVE,
+              CLASS_COLLECTIVE_FUSED, CLASS_ELEMENTWISE,
+              CLASS_DATA_MOVEMENT, CLASS_REST)
+_ELEMENTWISE = frozenset({"loop fusion", "custom fusion", "input fusion",
+                          "non-fusion elementwise", "reduce",
+                          "reduce-window"})
+_DATA_MOVEMENT = frozenset({
+    "data formatting", "broadcast", "copy", "copy-start", "copy-done",
+    "async-start", "async-done", "slice", "dynamic-slice",
+    "dynamic-update-slice", "concatenate", "pad", "gather", "scatter",
+    "transpose", "reshape", "iota"})
 
 _WRAPPED = re.compile(r"^[A-Za-z_]\w*\((.*)\)$")  # jvp(x), transpose(jvp(x))
 _PROGRAM_ID = re.compile(r"\(\d+\)$")             # jit_train_step(1234)
@@ -79,6 +100,7 @@ class OpEvent:
     collective: Optional[str] = None  # base mnemonic ("all-reduce")
     detail: str = ""                  # TPU op: "bf16[8,128] fusion"
     tf_op: Optional[str] = None       # jaxpr name stack, with the scopes
+    category: Optional[str] = None    # the profiler's hlo_category
     step_num: Optional[int] = None    # host StepTraceAnnotation
 
     @property
@@ -119,12 +141,51 @@ def innermost_scope(parts: List[str], names) -> Optional[str]:
     return next((p for p in reversed(parts) if p in names), None)
 
 
+def kernel_of(parts: List[str]) -> Optional[str]:
+    """The `name=` of the `pallas_call` a name stack ends in, else None:
+    [..., "attention", "flash_fwd", "pallas_call"] -> "flash_fwd"."""
+    if len(parts) >= 2 and parts[-1] == PALLAS:
+        return parts[-2]
+    return None
+
+
+def op_class(name: str, category: Optional[str], kernel: bool = False) -> str:
+    """An operation's class of work from its instruction's `name`
+    ("all-gather.3", "fusion.12") and the profiler's `hlo_category` of
+    it: a Pallas kernel (`kernel`: the caller found it by `kernel_of` and
+    its custom call); a collective, by name or by category; a fusion
+    whose category names a collective, or one the compiler runs beside
+    other work ("async-collective-start"), which communicates though its
+    name says `fusion`; a matmul (a convolution, alone or as a fusion's
+    root); elementwise; data movement; the rest (loops' own time, sorts,
+    a category this table does not know)."""
+    category = category or ""
+    if kernel:
+        return CLASS_KERNEL
+    if collective_base(name) or collective_base(category):
+        # by category too: a collective the program wrote itself is named
+        # after its primitive (`reduce_scatter.13`)
+        return CLASS_COLLECTIVE
+    if ((category.endswith(" fusion")
+         and category.startswith(HLO_COLLECTIVE_OPS))
+            or name.startswith("async-collective-")):
+        return CLASS_COLLECTIVE_FUSED
+    if category.startswith("convolution") or category == "dot":
+        return CLASS_MATMUL
+    if category in _ELEMENTWISE:
+        return CLASS_ELEMENTWISE
+    if category in _DATA_MOVEMENT:
+        return CLASS_DATA_MOVEMENT
+    return CLASS_REST
+
+
 def _op_event(name: str, ev, plane: str, line: str, module,
               detail: str = "") -> OpEvent:
     """An XLA op's event, its kind from the instruction's name."""
     base = collective_base(name)
     pid = ev.stats.get("program_id")
     tf_op = ev.stats.get("tf_op")
+    category = ev.stats.get("hlo_category")
     return OpEvent(
         name=name,
         kind=(KIND_COLLECTIVE if base else KIND_INFEED if is_transfer(name)
@@ -132,7 +193,8 @@ def _op_event(name: str, ev, plane: str, line: str, module,
         start_ps=ev.start_ps, duration_ps=ev.duration_ps, plane=plane,
         line=line, module=module if isinstance(module, str) else None,
         program_id=pid if isinstance(pid, int) else None, collective=base,
-        detail=detail, tf_op=tf_op if isinstance(tf_op, str) else None)
+        detail=detail, tf_op=tf_op if isinstance(tf_op, str) else None,
+        category=category if isinstance(category, str) else None)
 
 
 def _host_event(ev, plane: str, line: str) -> OpEvent:

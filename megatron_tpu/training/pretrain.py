@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from megatron_tpu.analysis import step_program
 from megatron_tpu.config import RunConfig
 from megatron_tpu.models.language_model import (
     is_full_remat_family, lm_loss,
@@ -1341,7 +1342,14 @@ class TrainLoop:
         and not at all in a run that opened no window. The record also
         counts the leaves whose gradient the step sums inside the kernel
         that makes it (`train_step.kernel_summed`), and their share of
-        the parameters' elements."""
+        the parameters' elements; and, read from the same compiled
+        program's text (analysis/step_program.py), where each of its
+        collectives stands (`collectives`: region and scope of its name
+        stack, kind, whether the chip compiler fused it into an operation
+        a trace shows as a `fusion`, result bytes, group size, times a
+        step) and which instructions carry no name stack at all
+        (`unnamed_instructions`): what a trace's classes of work are
+        checked against (docs/observability.md "Runtime traces")."""
         if self._profiled_step is None or self.telemetry is None:
             return
         step, n_micro, batch_avals = self._profiled_step
@@ -1349,8 +1357,11 @@ class TrainLoop:
         params = self.state.params
         try:
             with jax.sharding.set_mesh(self.rt.mesh):
-                ma = step.lower(self.state, batch_avals).compile(
-                    ).memory_analysis()
+                compiled = step.lower(self.state, batch_avals).compile()
+                ma = compiled.memory_analysis()
+                text = compiled.as_text()
+                where = step_program.collectives(text)
+                unnamed = step_program.unnamed_instructions(text)
                 # traced under the mesh, as the step was: the same answer
                 summed = kernel_summed(
                     self.cfg.model, params, batch_avals, n_micro,
@@ -1370,7 +1381,8 @@ class TrainLoop:
             alias_bytes=int(ma.alias_size_in_bytes),
             kernel_summed_leaves=sum(s for _, s in sizes),
             kernel_summed_share=(sum(n for n, s in sizes if s)
-                                 / sum(n for n, _ in sizes)))
+                                 / sum(n for n, _ in sizes)),
+            collectives=where, unnamed_instructions=unnamed)
 
     # -- loop ---------------------------------------------------------------
 

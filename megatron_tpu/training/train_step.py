@@ -171,8 +171,11 @@ def make_train_step(
             return _either(acc, sink), out
 
         zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-        acc, (losses, loads) = jax.lax.scan(one_micro, zeros,
-                                            (micro, jnp.arange(n)))
+        # the loop's own slicing of the batch and carrying of the
+        # accumulators sit under no region: this names them for a trace
+        with jax.named_scope("micro_batches"):
+            acc, (losses, loads) = jax.lax.scan(one_micro, zeros,
+                                                (micro, jnp.arange(n)))
         # mean over microbatches; scaled grads stay scaled for the optimizer
         grads = jax.tree.map(lambda g: g / n, acc)
 

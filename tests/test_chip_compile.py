@@ -173,11 +173,11 @@ def _kernel_name_stacks(text):
 
 
 def _kernels_named(text):
-    from megatron_tpu.telemetry.tracing.events import (
-        KERNEL_SCOPES, innermost_scope,
-    )
+    """Each Pallas custom call by the rule a trace reader finds it by:
+    the part in front of its name stack's closing `pallas_call`."""
+    from megatron_tpu.telemetry.tracing.events import kernel_of
 
-    return sorted(innermost_scope(toks, KERNEL_SCOPES) or "<unnamed>"
+    return sorted(kernel_of(toks) or "<unnamed>"
                   for toks in _kernel_name_stacks(text))
 
 
@@ -533,8 +533,6 @@ def test_the_scopes_change_no_byte_of_the_step(topo, tp2_dp2_step):
 _COLLECTIVE = re.compile(r" (all-gather|all-reduce|reduce-scatter|all-to-all|"
                          r"collective-permute)(?:-start)?\(")
 _RESULT = re.compile(r"\b(pred|[subf]\d+|bf16)\[([\d,]*)\]")
-_CALLED = re.compile(r"\b(calls|to_apply|body|condition|branch_computations)"
-                     r"=\{?((?:%[\w.\-]+(?:, )?)+)\}?")
 _ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4}
 
 
@@ -576,69 +574,26 @@ def _instructions(text, opcode):
     groups, and its `op_name` (its own, else that of the instruction
     calling the computation it stands in: the chip compiler fuses an
     all-reduce with the slice that follows it and leaves the name on the
-    fusion)."""
-    comps, cur, entry = {}, None, None
-    for line in text.splitlines():
-        if cur is None:
-            m = re.match(r"^(ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
-            if m:
-                cur = m.group(2)
-                comps[cur] = []
-                entry = cur if m.group(1) else entry
-        elif line.startswith("}"):
-            cur = None
-        else:
-            comps[cur].append(line)
-    trips = {}  # a scan's condition compares its counter with a constant
-    for name, lines in comps.items():
-        limits = [int(c) for line in lines for c in
-                  re.findall(r"s32\[\][^ ]* constant\((\d+)\)", line)]
-        if limits and any("direction=LT" in line for line in lines):
-            trips[name] = max(limits)
-    edges = collections.defaultdict(list)
-    waiting = collections.Counter()
-    for name, lines in comps.items():
-        for line in lines:
-            called = {k: re.findall(r"%([\w.\-]+)", v)
-                      for k, v in _CALLED.findall(line)}
-            n = trips.get((called.get("condition") or [None])[0], 1)
-            op = re.search(r'op_name="([^"]+)"', line)
-            for key, callees in called.items():
-                for callee in callees:
-                    edges[name].append((callee, n if key == "body" else 1,
-                                        key == "body", op and op.group(1),
-                                        " fusion(" in line))
-                    waiting[callee] += 1
-    times = collections.defaultdict(int, {entry: 1})
-    looped, caller_op = collections.defaultdict(bool), {}
-    fused = collections.defaultdict(bool)
-    ready = [entry]
-    while ready:
-        name = ready.pop()
-        for callee, n, loop, op, fusion in edges[name]:
-            times[callee] += times[name] * n
-            looped[callee] |= looped[name] or loop
-            fused[callee] |= fused[name] or fusion
-            caller_op.setdefault(callee, op or caller_op.get(name))
-            waiting[callee] -= 1
-            if not waiting[callee]:
-                ready.append(callee)
+    fusion). The walk over the computations is the program's own
+    (analysis/step_program.py: what the trainer journals of a traced
+    step)."""
+    from megatron_tpu.analysis.step_program import Program
+
+    program = Program(text)
     found = []
-    for name, lines in comps.items():
-        for line in lines:
-            m = opcode.search(line)
-            if not m or not times[name]:
-                continue
-            dtype, dims = max(_RESULT.findall(line[:m.start()]),
-                              key=lambda r: _elements(r[1]))
-            op = re.search(r'op_name="([^"]+)"', line)
-            groups = re.search(r"replica_groups=(\S+?),? ", line)
-            found.append(dict(
-                kind=m.group(1), dtype=dtype, dims=dims,
-                elements=_elements(dims), times=times[name],
-                in_loop=looped[name], fused=fused[name],
-                groups=_replica_groups(groups.group(1)) if groups else set(),
-                op_name=op.group(1) if op else caller_op.get(name) or ""))
+    for comp, line, _name, _results, _opcode in program.instructions():
+        m = opcode.search(line)
+        if not m:
+            continue
+        dtype, dims = max(_RESULT.findall(line[:m.start()]),
+                          key=lambda r: _elements(r[1]))
+        groups = re.search(r"replica_groups=(\S+?),? ", line)
+        found.append(dict(
+            kind=m.group(1), dtype=dtype, dims=dims,
+            elements=_elements(dims), times=program.times[comp],
+            in_loop=program.looped[comp], fused=program.fused[comp],
+            groups=_replica_groups(groups.group(1)) if groups else set(),
+            op_name=program.op_name(comp, line)))
     return found
 
 
@@ -717,8 +672,88 @@ def test_head_and_loss_state_their_collectives_tp2_dp2(tp2_dp2_step):
 def test_one_chip_step_holds_no_collective(one_chip_step):
     """On a mesh of one device the head and loss name no collective (a
     reduction over an axis of one device is not written), and the whole
-    step communicates with nobody."""
+    step communicates with nobody; the journal's record of it
+    (analysis/step_program.py) is empty too."""
+    from megatron_tpu.analysis import step_program
+
     assert _collectives(one_chip_step.as_text()) == []
+    assert step_program.collectives(one_chip_step.as_text()) == []
+
+
+def test_step_program_record_says_where_the_collectives_stand(tp2_dp2_step):
+    """What the trainer journals of a traced step (`step_program`'s
+    `collectives` and `unnamed_instructions`, analysis/step_program.py),
+    on the described v5e 2x2 step at TP 2 x DP 2 with SP and ZeRO-1, one
+    sequence a replica. The record names every collective by region and
+    by the scope one level down, and says which the chip compiler fused
+    into an operation a trace shows as a `fusion`:
+
+    * every collective of a layer carries a part of its region in its
+      name stack, and none stands under `attn_core` (the kernels'
+      `shard_map` and the layout changes around it) or `attn_rope`;
+    * behind the output projection stands an all-reduce of the whole
+      [B, S, h] over `tensor`, under `attention/attn_out` (with the slice
+      behind it the sequence-parallel reduce-scatter; at the benchmark
+      cell's eight sequences a replica the chip compiler fuses the two,
+      "all-reduce-scatter fusion", and the record says `fused`: PERF.md
+      section 5), and the weight gradients' reductions are fused;
+    * the gathers the backward pass needs ride through chains of fusions
+      beside the matmuls (`async`, `fused`, `links` > 1);
+    * at this size GSPMD also moves the FFN's activation between its two
+      shardings around the activation function (all-to-all under
+      `mlp_act`): the record is where that is seen;
+    * it agrees with this file's own walk on how many collective
+      instructions the step holds, fused and not, the copies of one
+      chained collective counted as its links;
+    * the instructions without a name stack are mostly slices, copies
+      and the buffers they fill: no matmul and no reduction or gather
+      across devices among them."""
+    from megatron_tpu.analysis import step_program
+    from megatron_tpu.telemetry.tracing.events import scope_tokens
+
+    text = tp2_dp2_step[0].as_text()
+    found = step_program.collectives(text)
+    mine = _collectives(text)
+    parts = {"attention": {"attn_norm", "attn_qkv", "attn_out"},
+             "mlp": {"mlp_norm", "mlp_in", "mlp_act", "mlp_out"}}
+    for c in mine:
+        toks = scope_tokens(c["op_name"])
+        region = next((t for t in toks if t in parts), None)
+        if region:
+            assert parts[region] & set(toks), c["op_name"]
+    by_scope = collections.defaultdict(list)
+    for c in found:
+        by_scope[(c["region"], c["scope"])].append(c)
+    assert {"attn_qkv", "attn_out", "mlp_in", "mlp_out"} <= {
+        scope for _region, scope in by_scope}
+    cfg = _mistral_2l()
+    whole = f"bf16[1,{SEQ},{cfg.hidden_size}]"
+    behind_out = [c for c in by_scope[("attention", "attn_out")]
+                  if c["kind"] == "all-reduce" and c["result"] == whole]
+    assert [(c["group_size"], c["times"]) for c in behind_out] == [
+        (2, cfg.num_layers)], by_scope[("attention", "attn_out")]
+    weight_grads = [c for c in found if c["region"] in parts
+                    and c["kind"] == "all-reduce" and c["fused"]]
+    assert len(weight_grads) >= 4 and not any(c["async"]
+                                              for c in weight_grads)
+    chained = [c for c in found if c["links"] > 1]
+    assert chained and all(c["async"] and c["fused"]
+                           and c["kind"] == "all-gather" for c in chained)
+    assert any(c["kind"] == "all-to-all"
+               for c in by_scope[("mlp", "mlp_act")] + by_scope[
+                   ("mlp", "silu")])
+    assert len(mine) == sum(c["links"] for c in found)
+    assert (sum(c["fused"] for c in mine)
+            == sum(c["links"] for c in found if c["fused"]))
+    for c in found:
+        assert c["result_bytes"] > 0 and c["times"] >= 1, c
+        assert c["group_size"] in (0, 2, 4), c
+    unnamed = step_program.unnamed_instructions(text)
+    assert unnamed and all(set(u) == {"opcode", "result", "count", "times"}
+                           for u in unnamed)
+    opcodes = {u["opcode"] for u in unnamed}
+    assert {"slice-start", "copy-start"} <= opcodes
+    assert not {"convolution", "dot", "all-reduce", "all-gather"} & opcodes
 
 
 # ---------------------------------------------------------------------------

@@ -13,10 +13,16 @@ import pytest
 
 from megatron_tpu.analysis import targets
 from megatron_tpu.telemetry.tracing.events import (
-    KERNEL_SCOPES, REGION_SCOPES, scope_tokens,
+    REGION_SCOPES, kernel_of, scope_tokens,
 )
 
 TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+# the parts of the two layer regions (models/transformer.py), the layers'
+# scan (models/language_model.py) and the micro-batch loop (train_step.py)
+SUB_SCOPES = {"attention": ("attn_norm", "attn_qkv", "attn_rope",
+                            "attn_core", "attn_out"),
+              "mlp": ("mlp_norm", "mlp_in", "mlp_act", "mlp_out")}
+LOOP_SCOPES = ("micro_batches", "layer_stack")
 
 
 def _op_names(parallel, zero1):
@@ -61,7 +67,42 @@ def test_the_compiled_step_holds_every_scope(parallel, zero1):
     for n, toks in stacks:
         assert len({t for t in toks if t in REGION_SCOPES}) <= 1, n
         assert not {"flash_decode", "paged_flash_decode"} & set(toks), n
-    assert set(TRAIN_KERNELS) < set(KERNEL_SCOPES)
+    # a kernel is found by a rule, not by a list: the part in front of a
+    # stack's closing `pallas_call` (tests/test_chip_compile.py holds it
+    # on the chip compiler's own stacks; interpreted, here, the kernel's
+    # body stands where the call would)
+    for kernel in TRAIN_KERNELS:
+        assert kernel_of(next(toks[:toks.index(kernel) + 1] for _n, toks
+                              in stacks if kernel in toks)
+                         + ["pallas_call"]) == kernel
+    # the parts of a region sit inside it and nowhere else, on forward,
+    # transposed and recomputed operations alike; the kernels are part of
+    # `attn_core`; a matmul of a region is in one of its parts
+    for region, parts in SUB_SCOPES.items():
+        for part in parts:
+            under = [(n, toks) for n, toks in stacks if part in toks]
+            assert under, part
+            assert all(region in toks[:toks.index(part)]
+                       for _n, toks in under), part
+            assert any("transpose(" in n for n, _toks in under), part
+        for n, toks in stacks:
+            if region in toks and "dot_general" in toks:
+                assert set(parts) & set(toks), n
+    for kernel in TRAIN_KERNELS:
+        assert all("attn_core" in toks[:toks.index(kernel)]
+                   for _n, toks in stacks if kernel in toks), kernel
+    # the loops carry their names, and every layer region is inside both
+    for scope in LOOP_SCOPES:
+        assert any(scope in toks for _n, toks in stacks), scope
+    for _n, toks in stacks:
+        if {"attention", "mlp"} & set(toks):
+            region = next(t for t in toks if t in ("attention", "mlp"))
+            assert toks.index("micro_batches") < toks.index(
+                "layer_stack") < toks.index(region), toks
+    # the scan's own work (slicing the stacked weights, stacking what the
+    # backward pass saved) is under `layer_stack` and under no region
+    assert any("layer_stack" in toks and not set(REGION_SCOPES) & set(toks)
+               for _n, toks in stacks)
     # the matmuls that hold the time fall under the layer that owns them
     owners = {next((t for t in toks if t in REGION_SCOPES), None)
               for _n, toks in stacks if "dot_general" in toks}
@@ -80,3 +121,6 @@ def test_the_compiled_step_holds_every_scope(parallel, zero1):
 ])
 def test_scope_tokens_strip_the_wrappers(text, want):
     assert scope_tokens(text) == want
+    # the rule that finds a kernel: only a stack that closes in the call
+    assert kernel_of(want) == ("flash_bwd_dq" if want[-1] == "pallas_call"
+                               else None)

@@ -786,6 +786,57 @@ def test_named_tpu_trace_reads_by_scope_kernel_and_host_span(
                                                       "all-reduce"}
     d = report.to_dict(top=5)
     assert json.dumps(d) and d["scopes"] and d["kernels"] and d["idle_gaps"]
+    # the same time by class of work (the profiler's hlo_category): each
+    # region's classes sum to the region; the kernels are `attention`'s
+    # class `kernel`; what has no name stack is a part of `other`
+    from megatron_tpu.telemetry.tracing.analyze import UNNAMED_SCOPE
+    from megatron_tpu.telemetry.tracing.events import OP_CLASSES
+
+    table = report.scope_classes
+    assert set(table) == set(report.scopes) | {UNNAMED_SCOPE}
+    for region, seconds in report.scopes.items():
+        assert list(table[region]) == list(OP_CLASSES)
+        assert sum(table[region].values()) == pytest.approx(seconds)
+    assert table["attention"]["kernel"] == pytest.approx(
+        sum(k["self_s"] for k in report.kernels.values()), rel=1e-4)
+    assert table["mlp"]["matmul"] > 0.9 * report.scopes["mlp"]
+    assert all(table[UNNAMED_SCOPE][c] <= table["other"][c]
+               for c in OP_CLASSES)
+    fused = sum(row["collective_fused"] for row in table.values())
+    if planes > 1:
+        # the reduce-scatter behind a projection is a fusion by name and
+        # a collective by category: ~15.5 ms a run on each device
+        assert table["attention"]["collective_fused"] == pytest.approx(
+            4 * 15.52e-3, rel=0.01)
+        assert table[UNNAMED_SCOPE]["collective"] > 0
+    else:
+        assert fused == 0 and not any(row["collective"]
+                                      for row in table.values())
+    assert d["scope_classes"]["mlp"]["matmul"] > 0
+
+
+@pytest.mark.parametrize("name, category, kernel, want", [
+    ("flash_fwd.13", "custom-call", True, "kernel"),
+    ("custom-call.5", "custom-call", False, "rest"),
+    ("fusion.472", "convolution fusion", False, "matmul"),
+    ("all-gather-start.3", "all-gather-start", False, "collective"),
+    ("all-reduce.93", "all-reduce", False, "collective"),
+    ("reduce_scatter.13", "reduce-scatter", False, "collective"),
+    ("fusion.396", "all-reduce-scatter fusion", False, "collective_fused"),
+    ("async-collective-start", "custom fusion", False, "collective_fused"),
+    ("fusion.389", "loop fusion", False, "elementwise"),
+    ("copy.182", "data formatting", False, "data_movement"),
+    ("copy-done.3", "copy-done", False, "data_movement"),
+    ("while.143", "while", False, "rest"),
+    ("fusion.1", None, False, "rest"),
+    ("fft.1", "a category of tomorrow", False, "rest"),
+])
+def test_an_operation_gets_its_class_from_name_and_category(
+        name, category, kernel, want):
+    from megatron_tpu.telemetry.tracing.events import OP_CLASSES, op_class
+
+    assert op_class(name, category, kernel) == want
+    assert want in OP_CLASSES
 
 
 def test_idle_gaps_fall_under_the_innermost_loop_thread_span():
@@ -850,8 +901,13 @@ def test_trace_report_cli_text_and_json(capsys):
     ("named_seq4k_tpu_v5e.xplane.pb",
      ["module jit_train_step", "own time by scope", "kernel flash_fwd",
       "kernel flash_bwd_dkv", "idle gaps of the first device",
-      "np.asarray(jax.Array)", "train-pass"],
-     ["<none>", " = "]),
+      "np.asarray(jax.Array)", "train-pass",
+      # the class columns, and the row of what carries no name at all
+      "      kernel       matmul  elementwise data_movemen", "(unnamed)"],
+     ["<none>", " = ", "collective_f"]),
+    ("named_tp2dp2_tpu_v5e.xplane.pb",
+     ["   collective collective_f  elementwise", "(unnamed)"],
+     ["<none>", " = bf16", "%fusion"]),
 ])
 def test_trace_report_cli_on_tpu_traces(capsys, name, wants, never):
     from tools import trace_report

@@ -16,7 +16,10 @@ is testable without a chip).
 Prints the per-op table, the compute / collective / infeed busy split
 with per-collective total vs. EXPOSED time (not overlapped by compute —
 the Flash Communication number), per-step wall from the step markers,
-own time by the program's named scopes with the kernels by name, the
+own time by the program's named scopes, split by class of work (matmul,
+kernel, collective plain and fused, elementwise, data movement, rest: from
+the profiler's ``hlo_category``, the table the benchmark reads) with a row
+for what carries no name at all and the kernels by name, the
 device's idle gaps by the host span under each (both where the trace
 carries the names: a TPU trace of a program with the scopes and the
 loop's annotations), and with ``--contract NAME`` the measured-vs-expected
@@ -139,9 +142,22 @@ def render_text(report, comparison, top: int, files) -> str:
         lines.append("own time by scope (the program's jax.named_scope "
                      "regions; kernels by name):")
         whole = sum(report.scopes.values()) or 1.0
-        for name, sec in report.scopes.items():
+        # the same time by class of work (the profiler's hlo_category:
+        # events.op_class), the table the benchmark's readers take
+        table = report.scope_classes
+        classes = [c for c in next(iter(table.values()), {})
+                   if any(row[c] for row in table.values())]
+        lines.append(f"  {'':<20} {'':>10}  {'':>8}"
+                     + "".join(f" {c[:12]:>12}" for c in classes))
+        rows = list(report.scopes.items()) + [
+            (name, sum(row.values())) for name, row in table.items()
+            if name not in report.scopes]
+        for name, sec in rows:
+            row = table.get(name, {})
             lines.append(f"  {name:<20} {_fmt_s(sec):>10}  "
-                         f"({100 * sec / whole:.1f}%)")
+                         f"({100 * sec / whole:5.1f}%)" + "".join(
+                             f" {_fmt_s(row.get(c, 0.0)):>12}"
+                             for c in classes))
         for name, k in report.kernels.items():
             lines.append(f"    kernel {name:<15} {_fmt_s(k['self_s']):>10}"
                          f"  x{k['count']}")
