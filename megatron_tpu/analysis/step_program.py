@@ -7,6 +7,8 @@ its collectives stand, and which of its instructions lost their name.
                                  inside a fused computation
     unnamed_instructions(text)   opcode and result of every instruction
                                  that does work and carries no `op_name`
+    kernel_calls(text)           for each named Pallas kernel, its calls
+                                 and how many of them are recomputation
 
 `text` is `compiled.as_text()`: the program after GSPMD and the chip
 compiler's fusion, which is what a device trace times. The chip compiler
@@ -32,11 +34,13 @@ from megatron_tpu.analysis.taxonomy import (
     HLO_COLLECTIVE_OPS, HLO_DTYPE_BITS,
 )
 from megatron_tpu.telemetry.tracing.events import (
-    REGION_SCOPES, innermost_scope, scope_tokens,
+    REGION_SCOPES, innermost_scope, kernel_of, scope_tokens,
 )
 
 OTHER = "other"
 KERNEL_TARGET = "tpu_custom_call"
+# the scope `jax.checkpoint` puts what the backward pass computes again under
+REMATTED = "rematted_computation"
 
 _COMPUTATION = re.compile(r"^(ENTRY )?%([\w.\-]+) \(.*\{\s*$")
 _INSTRUCTION = re.compile(
@@ -247,3 +251,26 @@ def unnamed_instructions(text: str, top: int = 64) -> List[Dict[str, Any]]:
         rec["_bytes"] += nbytes * program.times[comp]
     ranked = sorted(merged.values(), key=lambda r: -r["_bytes"])[:top]
     return [{k: v for k, v in r.items() if k != "_bytes"} for r in ranked]
+
+
+def kernel_calls(text: str) -> Dict[str, Dict[str, int]]:
+    """The Pallas kernels of a compiled program by the name a trace finds
+    them under (the part in front of the name stack's closing
+    `pallas_call`): `calls`, the custom calls the step reaches as the
+    text holds them (a scanned layer's once), `rematted`, how many of
+    them stand under `rematted_computation` (a forward kernel that the
+    backward pass runs a second time because the layer's checkpoint kept
+    none of its results), and `times` a step. Empty where the kernels
+    are interpreted: there is no custom call to find."""
+    program = Program(text)
+    out: Dict[str, Dict[str, int]] = {}
+    for comp, line, name, results, opcode in program.instructions():
+        if opcode != "custom-call" or KERNEL_TARGET not in line:
+            continue
+        parts = scope_tokens(program.op_name(comp, line))
+        rec = out.setdefault(kernel_of(parts) or "", {
+            "calls": 0, "rematted": 0, "times": 0})
+        rec["calls"] += 1
+        rec["rematted"] += REMATTED in parts
+        rec["times"] += program.times[comp]
+    return dict(sorted(out.items()))

@@ -96,7 +96,8 @@ def _sds(tree):
 def train_step_target(name: str, parallel_kwargs: Dict[str, Any],
                       zero1: bool = False,
                       model_overrides: Optional[Dict[str, Any]] = None,
-                      global_batch: int = 8) -> AuditTarget:
+                      global_batch: int = 8,
+                      recompute: str = "full") -> AuditTarget:
     """The production train step: a real TrainLoop's jitted step lowered
     on ShapeDtypeStructs (state donated, batch sharded like _put_batch)."""
     from megatron_tpu.training.pretrain import TrainLoop
@@ -109,7 +110,7 @@ def train_step_target(name: str, parallel_kwargs: Dict[str, Any],
         training=TrainingConfig(micro_batch_size=1,
                                 global_batch_size=global_batch,
                                 train_iters=2, log_interval=1,
-                                recompute_granularity="full"))
+                                recompute_granularity=recompute))
     loop = TrainLoop(cfg, log=lambda s: None)
     n_micro = max(global_batch // (1 * loop.rt.dp), 1)
     step = loop._train_step_for(n_micro)

@@ -127,7 +127,17 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     g.add_argument("--seed", type=int, default=1234)
     g.add_argument("--init_method_std", type=float, default=0.02)
     g.add_argument("--recompute_granularity", default="none",
-                   choices=["none", "selective", "full"])
+                   choices=["none", "selective", "full"],
+                   help="what a layer keeps for its backward pass. "
+                        "selective keeps the weight matmuls' outputs and "
+                        "computes norms, rotary, activations and the dense "
+                        "core attention again; with --attention_impl "
+                        "pallas it also keeps the flash kernel's output "
+                        "and log-sum-exp (one hidden-state-sized tensor "
+                        "and seq_length floats a head, a layer, a "
+                        "sequence), so the forward kernel runs once. full "
+                        "keeps the layer's input only and runs the whole "
+                        "forward, the kernel included, a second time")
     g.add_argument("--recompute_activations", action="store_true",
                    help="ref alias for --recompute_granularity selective")
     g.add_argument("--recompute_method", default="uniform",
@@ -310,7 +320,7 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     g.add_argument("--pipeline_model_parallel_size", type=int, default=1)
     g.add_argument("--expert_model_parallel_size", type=int, default=1,
                    help="MoE expert-parallel degree (dedicated mesh axis; "
-                        "E % ep == 0, dp unconstrained)")
+                        "E %% ep == 0, dp unconstrained)")
     g.add_argument("--context_parallel_size", type=int, default=1)
     g.add_argument("--num_layers_per_virtual_pipeline_stage", type=int,
                    default=None,

@@ -36,6 +36,7 @@ from megatron_tpu.ops.cross_entropy import (
 from megatron_tpu.ops.moe import (
     LOAD_METRIC, SAVED_PRODUCT, expert_grad_sinks, merge_layer_stats,
 )
+from megatron_tpu.ops.pallas.flash_template import SAVED_RESIDUAL
 from megatron_tpu.ops.weight_quant import deq, take_rows
 from megatron_tpu.ops.normalization import norm_forward
 from megatron_tpu.ops.rotary import precompute_rope
@@ -83,14 +84,19 @@ def _remat_policy(recompute: str):
         # block applies full remat to its rematted slice
         return jax.checkpoint_policies.nothing_saveable
     if recompute == "selective":
-        # save weight-matmul outputs, recompute core attention — the TPU
-        # expression of the reference's selective recompute
-        # (transformer.py:391-410 checkpointed core attention)
-        # (the dropless experts' grouped products are such outputs too;
-        # where they are Pallas kernels and no dot, their name saves them)
+        # save weight-matmul outputs and recompute what is cheap beside
+        # them: norms, rotary, activations, the layout changes, and the
+        # dense core attention, whose S x S scores are a layer's largest
+        # activation (the reference's selective recompute,
+        # transformer.py:391-410). The flash kernel keeps no scores, so
+        # its forward is not run again: its output and its log-sum-exp
+        # (one hidden-state-sized tensor and S floats a head) are saved
+        # by name, as are the dropless experts' grouped products — Pallas
+        # calls' results both, which the policy does not know for dots
         return jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            jax.checkpoint_policies.save_only_these_names(SAVED_PRODUCT))
+            jax.checkpoint_policies.save_only_these_names(
+                SAVED_PRODUCT, SAVED_RESIDUAL))
     raise ValueError(f"unknown recompute policy {recompute!r}")
 
 

@@ -28,10 +28,13 @@ instantiation of the one template in this module, with four knobs:
                 |                         | dense cache is a pool whose pages
                 |                         | are whole rows
   gradient      | fwd-only / custom_vjp   | the FA-2 recompute backward: fwd
-                |                         | saves lse, bwd recomputes p from
-                |                         | (q, k, lse), one kernel accumulates
-                |                         | dq over kv blocks, one dk/dv over q
-                |                         | blocks
+                |                         | saves o and lse (compact, one float
+                |                         | a row; named SAVED_RESIDUAL so a
+                |                         | layer's checkpoint keeps them and
+                |                         | the forward runs once), bwd
+                |                         | recomputes p from (q, k, lse), one
+                |                         | kernel accumulates dq over kv
+                |                         | blocks, one dk/dv over q blocks
 
 Online softmax (running max m, running sum l, unnormalized acc in VMEM
 scratch persisting across the sequential kv steps) is shared by every
@@ -69,6 +72,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -76,6 +80,12 @@ from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.pallas import masks
 
 _NEG_INF = masks.NEG_INF
+# the `checkpoint_name` of what the training forward hands its backward
+# beside q, k, v: the output and the log-sum-exp. Selective recomputation
+# saves them by this name (models/language_model.py _remat_policy): a
+# Pallas call's result is no dot, and unnamed it is thrown away at the
+# layer's checkpoint and the forward kernel runs a second time
+SAVED_RESIDUAL = "flash_fwd_residual"
 # Mosaic's scoped-VMEM default on a v5e, and what a kernel may ask for of
 # the core's 128 MiB
 _DEFAULT_SCOPED_VMEM = 16 << 20
@@ -335,7 +345,8 @@ def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
             _fwd_vmem_bytes(block_q, block_k, D, q.dtype.itemsize)),
         interpret=_interpret(),
     )(_delta_arr(delta), q, k, v)
-    return o, lse
+    # every lane of the kernel's lane-padded result holds the row's number
+    return o, lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -343,7 +354,7 @@ def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                dq_ref, dq_scr,
                *, scale: float, causal: bool, window: Optional[int],
                block_q: int, block_k: int):
@@ -364,8 +375,7 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, 0:1]                      # [BQ, 1]
-        delta = delta_ref[0, 0][:, 0:1]                  # [BQ, 1]
+        lse, delta = _row_stats(stats_ref)               # [BQ, 1] each
 
         s = _dot(q, k, _NT) * scale
         q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k, off)
@@ -381,7 +391,7 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0, 0] = (dq_scr[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, scale: float, causal: bool, window: Optional[int],
                 block_q: int, block_k: int):
@@ -403,8 +413,7 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, 0:1]
-        delta = delta_ref[0, 0][:, 0:1]
+        lse, delta = _row_stats(stats_ref)
 
         s = _dot(q, k, _NT) * scale
         q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k, off)
@@ -423,23 +432,42 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_vmem_bytes(block_q, block_k, D, item):
-    """q, do, k, v tiles and the output tile(s) double-buffered, lse and
-    delta lane-padded, the float32 accumulators, and ~6 live [BQ, BK]
-    float32 temporaries (s, mask, p, dp, ds and a cast of p or ds)."""
+    """q, do, k, v tiles and the output tile(s) double-buffered, the row
+    statistics lane-padded, the float32 accumulators, and ~6 live
+    [BQ, BK] float32 temporaries (s, mask, p, dp, ds and a cast of p or
+    ds)."""
     return (2 * (3 * block_q + 4 * block_k) * D * item
-            + 2 * 2 * block_q * 128 * 4
+            + 2 * block_q * 128 * 4
             + 2 * max(block_q, block_k) * D * 4
             + 6 * block_q * block_k * 4)
 
 
-def _bwd_delta(o, do):
-    """rowsum(do * o) in float32, lane-padded like lse: [B,H,Sq,128]."""
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
-    return jnp.broadcast_to(delta, delta.shape[:-1] + (128,))
+# The two per-row statistics of the backward kernels, lse and
+# delta = rowsum(do * o), ride in ONE float32 operand [B,H,Sq,128]: a
+# [..., 1]-shaped operand is tiled to 128 lanes anyway, so lanes
+# [0, _DELTA_LANE) hold lse and the rest hold delta. Spreading a row's
+# number over the lanes is the slowest pass around the kernels (0.36 ms
+# for [1,32,4096,128] on a v5e, a quarter of the HBM's rate: PERF.md
+# section 6, PR 36); packed, the two statistics cost one such pass.
+_DELTA_LANE = 64
 
 
-def _bwd_dq(q, k, v, do, lse, delta, scale, causal, window, block_q,
+def _bwd_stats(lse, o, do):
+    """lse [B,H,Sq] and rowsum(do * o), float32, as the backward kernels
+    read them (`_row_stats`): [B,H,Sq,128]."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    shape = lse.shape + (128,)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return jnp.where(lane < _DELTA_LANE, lse[..., None], delta[..., None])
+
+
+def _row_stats(stats_ref):
+    """(lse, delta), [BQ, 1] each, of a backward kernel's q tile."""
+    stats = stats_ref[0, 0]
+    return stats[:, 0:1], stats[:, _DELTA_LANE:_DELTA_LANE + 1]
+
+
+def _bwd_dq(q, k, v, do, stats, scale, causal, window, block_q,
             block_k, offset=None):
     """dq [B,H,Sq,D]: grid (b, h, qi, ki), kv innermost, one float32
     accumulator per q tile."""
@@ -463,7 +491,6 @@ def _bwd_dq(q, k, v, do, lse, delta, scale, causal, window, block_q,
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
                 pl.BlockSpec((1, 1, block_q, D), q_map),
                 pl.BlockSpec((1, 1, block_q, 128), q_map),
-                pl.BlockSpec((1, 1, block_q, 128), q_map),
             ],
             out_specs=pl.BlockSpec((1, 1, block_q, D), q_map),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
@@ -471,10 +498,10 @@ def _bwd_dq(q, k, v, do, lse, delta, scale, causal, window, block_q,
         compiler_params=_compiler_params(
             _bwd_vmem_bytes(block_q, block_k, D, q.dtype.itemsize)),
         interpret=_interpret(),
-    )(_delta_arr(offset), q, k, v, do, lse, delta)
+    )(_delta_arr(offset), q, k, v, do, stats)
 
 
-def _bwd_dkv(q, k, v, do, lse, delta, scale, causal, window, block_q,
+def _bwd_dkv(q, k, v, do, stats, scale, causal, window, block_q,
              block_k, offset=None):
     """(dk, dv) [B,H,Skv,D]: grid (b, h, ki, qi), q innermost, two
     float32 accumulators per kv tile."""
@@ -499,7 +526,6 @@ def _bwd_dkv(q, k, v, do, lse, delta, scale, causal, window, block_q,
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
                 pl.BlockSpec((1, 1, block_q, D), q_map),
                 pl.BlockSpec((1, 1, block_q, 128), q_map),
-                pl.BlockSpec((1, 1, block_q, 128), q_map),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
@@ -516,15 +542,17 @@ def _bwd_dkv(q, k, v, do, lse, delta, scale, causal, window, block_q,
         compiler_params=_compiler_params(
             _bwd_vmem_bytes(block_q, block_k, D, q.dtype.itemsize)),
         interpret=_interpret(),
-    )(_delta_arr(offset), q, k, v, do, lse, delta)
+    )(_delta_arr(offset), q, k, v, do, stats)
 
 
 def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
          offset=None):
-    delta = _bwd_delta(o, do)
-    dq = _bwd_dq(q, k, v, do, lse, delta, scale, causal, window,
+    """(dq, dk, dv) given the forward's output and its log-sum-exp
+    [B,H,Sq] (compact: one float a row)."""
+    stats = _bwd_stats(lse, o, do)
+    dq = _bwd_dq(q, k, v, do, stats, scale, causal, window,
                  block_q, block_k, offset)
-    dk, dv = _bwd_dkv(q, k, v, do, lse, delta, scale, causal, window,
+    dk, dv = _bwd_dkv(q, k, v, do, stats, scale, causal, window,
                       block_q, block_k, offset)
     return dq, dk, dv
 
@@ -542,14 +570,21 @@ def _flash_bhsd(q, k, v, scale, causal, window, block_q, block_k):
 
 def _flash_fwd_rule(q, k, v, scale, causal, window, block_q, block_k):
     o, lse = _fwd(q, k, v, scale, causal, window, block_q, block_k)
+    # The residuals a layer's checkpoint may keep (SAVED_RESIDUAL). The
+    # primal output is the named `o` too: what reads it downstream (the
+    # out projection's weight gradient) then reads the kept one. The
+    # log-sum-exp is kept compact, float32 [B, H, S]: the kernel's
+    # lane-padded [B, H, S, 128] is twice `o`, and every lane holds the
+    # same number
+    o = checkpoint_name(o, SAVED_RESIDUAL)
+    lse = checkpoint_name(lse, SAVED_RESIDUAL)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd_rule(scale, causal, window, block_q, block_k, res, do):
     q, k, v, o, lse = res
-    dq, dk, dv = _bwd(q, k, v, o, lse, do, scale, causal, window,
-                      block_q, block_k)
-    return dq, dk, dv
+    return _bwd(q, k, v, o, lse, do, scale, causal, window, block_q,
+                block_k)
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -571,7 +606,7 @@ def stripe_fwd(q, k, v, delta, window, scale, block, causal=True):
     fully-visible blocks (bidirectional contiguous ring). A fully-masked
     row reports lse at masks.NEG_INF depth, finite."""
     o, lse = _fwd(q, k, v, scale, causal, window, block, block, delta=delta)
-    return o.astype(jnp.float32), lse[..., 0]
+    return o.astype(jnp.float32), lse
 
 
 def stripe_bwd(q, k, v, o, lse, do, delta, window, scale, block,
@@ -579,8 +614,7 @@ def stripe_bwd(q, k, v, o, lse, do, delta, window, scale, block,
     """(dq, dk, dv) for one stripe pair given the GLOBAL lse [B, H, c]
     (the FA-2 recompute scheme: p = exp(s - lse_global), so per-stripe
     gradients sum to the exact dense gradient)."""
-    lse128 = jnp.broadcast_to(lse[..., None], lse.shape + (128,))
-    return _bwd(q, k, v, o, lse128, do, scale, causal, window, block, block,
+    return _bwd(q, k, v, o, lse, do, scale, causal, window, block, block,
                 offset=delta)
 
 

@@ -1349,7 +1349,10 @@ class TrainLoop:
         a trace shows as a `fusion`, result bytes, group size, times a
         step) and which instructions carry no name stack at all
         (`unnamed_instructions`): what a trace's classes of work are
-        checked against (docs/observability.md "Runtime traces")."""
+        checked against (docs/observability.md "Runtime traces"); and
+        each Pallas kernel's calls with how many of them are a
+        recomputation (`kernel_calls`: under `selective` the flash
+        forward has none)."""
         if self._profiled_step is None or self.telemetry is None:
             return
         step, n_micro, batch_avals = self._profiled_step
@@ -1362,6 +1365,7 @@ class TrainLoop:
                 text = compiled.as_text()
                 where = step_program.collectives(text)
                 unnamed = step_program.unnamed_instructions(text)
+                kernels = step_program.kernel_calls(text)
                 # traced under the mesh, as the step was: the same answer
                 summed = kernel_summed(
                     self.cfg.model, params, batch_avals, n_micro,
@@ -1382,7 +1386,8 @@ class TrainLoop:
             kernel_summed_leaves=sum(s for _, s in sizes),
             kernel_summed_share=(sum(n for n, s in sizes if s)
                                  / sum(n for n, _ in sizes)),
-            collectives=where, unnamed_instructions=unnamed)
+            collectives=where, unnamed_instructions=unnamed,
+            kernel_calls=kernels)
 
     # -- loop ---------------------------------------------------------------
 

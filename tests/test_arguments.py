@@ -97,3 +97,17 @@ def test_saved_model_config_loads_without_retired_fields(tmp_path, load,
     else:
         with pytest.raises(TypeError, match="flash_fwd"):
             load(saved, root)
+
+
+def test_help_renders_and_says_what_selective_keeps():
+    """`--help` formats (argparse takes a bare `%` in a help string for a
+    format: one stood in --expert_model_parallel_size's and broke the
+    whole page), and --recompute_granularity says what each policy keeps
+    of the flash kernel."""
+    from megatron_tpu.arguments import build_parser
+
+    page = " ".join(build_parser().format_help().split())
+    at = page.index("--recompute_granularity {none,selective,full} ")
+    told = page[at:at + 900]
+    assert "keeps the flash kernel's output and log-sum-exp" in told
+    assert "full keeps the layer's input only" in told

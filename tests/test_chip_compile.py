@@ -436,14 +436,14 @@ def _scatter_op_names(text):
             for line in lines]
 
 
-def _tp2_dp2_step(topo):
+def _tp2_dp2_step(topo, recompute="selective"):
     from megatron_tpu.training.aot import aot_compile_train_step
 
     return aot_compile_train_step(
         _mistral_2l(),
         ParallelConfig(tensor_parallel=2, sequence_parallel=True),
         OptimizerConfig(lr=1e-4, use_distributed_optimizer=True),
-        micro_batch_size=1, num_microbatches=1, recompute="selective",
+        micro_batch_size=1, num_microbatches=1, recompute=recompute,
         devices=topo.devices)
 
 
@@ -452,17 +452,33 @@ def tp2_dp2_step(topo):
     return _tp2_dp2_step(topo)
 
 
-def _assert_step_kernels_named(text):
-    """The train step's Pallas calls by name: per layer scan the forward,
-    its recomputation in the backward pass, and the two backward kernels,
-    every one nested under the `attention` scope."""
+def _assert_step_kernels_named(text, recompute="selective"):
+    """The train step's Pallas calls by name: per layer scan the forward
+    and the two backward kernels, every one nested under the `attention`
+    scope. Under `selective` the layer's checkpoint keeps the forward's
+    output and log-sum-exp, so no forward stands under
+    `rematted_computation`; under `full` it keeps nothing and the
+    backward pass runs the forward a second time. The journal's
+    `step_program.kernel_calls` (analysis/step_program.py) says the same
+    of the same text."""
+    from megatron_tpu.analysis import step_program
+
+    again = ["flash_fwd"] if recompute == "full" else []
     assert _kernels_named(text) == ["flash_bwd_dkv", "flash_bwd_dq",
-                                    "flash_fwd", "flash_fwd"]
+                                    "flash_fwd"] + again
     for toks in _kernel_name_stacks(text):
         kernel = next(t for t in reversed(toks) if t.startswith("flash_"))
         assert "attention" in toks[:toks.index(kernel)], toks
-    assert any("rematted_computation" in toks
-               for toks in _kernel_name_stacks(text))
+    rematted = [toks for toks in _kernel_name_stacks(text)
+                if "rematted_computation" in toks]
+    assert len(rematted) == len(again)
+    assert all("flash_fwd" in toks for toks in rematted)
+    layers = _mistral_2l().num_layers
+    backward = {"calls": 1, "rematted": 0, "times": layers}
+    assert step_program.kernel_calls(text) == {
+        "flash_bwd_dkv": backward, "flash_bwd_dq": backward,
+        "flash_fwd": {"calls": 1 + len(again), "rematted": len(again),
+                      "times": layers * (1 + len(again))}}
 
 
 def test_train_step_tp2_dp2_partitions_the_kernel(tp2_dp2_step):
@@ -500,6 +516,22 @@ def test_train_step_tp2_dp2_names_its_kernels_and_regions(tp2_dp2_step):
               for n in set(re.findall(r'op_name="([^"]+)"', text))]
     for scope in REGION_SCOPES:
         assert any(scope in toks for toks in stacks), scope
+
+
+@pytest.mark.parametrize("recompute", ["selective", "full"])
+def test_the_policy_decides_how_often_the_flash_forward_runs(
+        topo, tp2_dp2_step, recompute):
+    """`selective` keeps what the flash forward hands its backward and
+    runs it once a layer; `full` keeps nothing and runs it twice. The
+    price of keeping: `selective` holds more at the step's peak than
+    `full`, and both fit the chip."""
+    compiled = (tp2_dp2_step[0] if recompute == "selective"
+                else _tp2_dp2_step(topo, recompute)[0])
+    _assert_step_kernels_named(compiled.as_text(), recompute)
+    assert _per_device_bytes(compiled) < 15.75 * GIB
+    if recompute == "full":
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < tp2_dp2_step[0].memory_analysis().temp_size_in_bytes)
 
 
 def test_the_scopes_change_no_byte_of_the_step(topo, tp2_dp2_step):

@@ -16,10 +16,15 @@ Three layers of proof:
      through the template (jaxpr contains the pallas calls), stays dense
      on a CPU host that does not force interpret mode, and RAISES for a
      geometry the chosen kernel cannot tile.
+  4. what a layer's checkpoint keeps of the forward kernel under
+     `selective` recomputation: its output and its compact log-sum-exp,
+     so the backward pass runs no second forward and computes the same
+     bits as a step that recomputes nothing.
 
 The same kernels compile for a described v5e in tests/test_chip_compile.py
 and run on the chip in every benchmark cell."""
 
+import dataclasses
 import warnings
 
 import jax
@@ -310,3 +315,112 @@ def test_dispatch_stays_dense_on_cpu_without_forcing(monkeypatch):
 
     jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
     assert "pallas_call" not in jaxpr
+
+
+# ---------------------------------------------------------------------------
+# 4. selective recomputation keeps the forward kernel's residuals
+# ---------------------------------------------------------------------------
+
+_B, _S, _V = 4, 32, 64
+
+
+def _two_layer_case():
+    from megatron_tpu.models import presets
+    from megatron_tpu.models.params import init_params
+
+    cfg = presets.tiny(vocab_size=_V, seq_length=_S, attention_impl="pallas")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(1)
+    batch = {k: jnp.asarray(rng.integers(0, _V, (_B, _S)), jnp.int32)
+             for k in ("tokens", "labels")}
+    batch["loss_mask"] = jnp.asarray(rng.integers(0, 2, (_B, _S)),
+                                     jnp.float32)
+    return cfg, params, batch
+
+
+@pytest.mark.parametrize("tp", [None, 2], ids=["nomesh", "tp2dp2"])
+def test_selective_equals_no_recompute_bit_for_bit(monkeypatch, tp):
+    """Loss and every gradient of a two-layer model under `selective`
+    are those under `none`, bit for bit: the layer's checkpoint keeps the
+    forward kernel's output and log-sum-exp (flash_template
+    SAVED_RESIDUAL), the backward kernels read the very values the
+    forward made, and nothing of the attention core is computed a second
+    time. Under TP 2 x DP 2 the kept values cross the kernels'
+    `shard_map` as each device's own shard. Both sides run operation by
+    operation (`jax.disable_jit`): compiled as two whole programs, the
+    CPU compiler fuses their elementwise work differently and the last
+    bit of every leaf, the head's included, follows the fusion and not
+    the policy."""
+    from jax.sharding import NamedSharding
+
+    from megatron_tpu.config import ParallelConfig
+    from megatron_tpu.models.language_model import lm_loss
+    from megatron_tpu.models.params import param_specs
+    from megatron_tpu.parallel.mesh import build_mesh
+    from megatron_tpu.parallel.sharding import (
+        ActivationSharder, batch_spec, shard_tree,
+    )
+
+    monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+    cfg, params, batch = _two_layer_case()
+
+    def run(recompute):
+        if tp is None:
+            with jax.disable_jit():
+                return jax.value_and_grad(
+                    lambda p: lm_loss(cfg, p, batch,
+                                      recompute=recompute)[0])(params)
+        rt = build_mesh(ParallelConfig(tensor_parallel=tp,
+                                       sequence_parallel=True),
+                        devices=jax.devices()[:4])
+        sharder = ActivationSharder(True)
+        with jax.sharding.set_mesh(rt.mesh):
+            sharded = shard_tree(rt, params, param_specs(cfg))
+            placed = {k: jax.device_put(
+                v, NamedSharding(rt.mesh, batch_spec()))
+                for k, v in batch.items()}
+            with jax.disable_jit():
+                return jax.value_and_grad(
+                    lambda p, b: lm_loss(cfg, p, b, recompute=recompute,
+                                         sharder=sharder)[0])(sharded, placed)
+
+    want, want_grads = run("none")
+    got, got_grads = run("selective")
+    assert float(got) == float(want)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_grads),
+                            jax.tree.leaves(want_grads), strict=True):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), path
+
+
+@pytest.mark.parametrize("recompute, kept", [("selective", True),
+                                             ("full", False)])
+def test_what_a_layer_keeps_of_the_flash_forward(monkeypatch, recompute,
+                                                 kept):
+    """The residuals a `selective` layer saves (what
+    `jax.ad_checkpoint.print_saved_residuals` lists) hold, from the
+    kernel, one [B, H, S, D] (its output) and one float32 [B, H, S] (the
+    log-sum-exp, compact) and nothing in the kernel's lane-padded
+    [B, H, S, 128]: that layout is twice the output. `full` saves neither
+    (there the forward runs twice, which is what it is for)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from megatron_tpu.models.language_model import lm_loss
+
+    monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+    cfg, params, batch = _two_layer_case()
+    # a stack of one layer is a call, so the layer's residuals are the
+    # function's own and not a scan's stacked outputs
+    params["layers"] = jax.tree.map(lambda a: a[:1], params["layers"])
+    cfg = dataclasses.replace(cfg, num_layers=1).validate()
+    h, d = cfg.num_attention_heads, cfg.head_dim
+    saved = saved_residuals(
+        lambda p: lm_loss(cfg, p, batch, recompute=recompute)[0], params)
+    from_kernel = [(aval.shape, str(aval.dtype)) for aval, why in saved
+                   if "flash_template.py" in why]
+    shapes = [aval.shape for aval, _why in saved]
+    assert (_B, h, _S, 128) not in shapes
+    if kept:
+        assert sorted(from_kernel) == [((_B, h, _S), "float32"),
+                                       ((_B, h, _S, d), "float32")]
+    else:
+        assert from_kernel == []

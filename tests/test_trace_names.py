@@ -25,11 +25,11 @@ SUB_SCOPES = {"attention": ("attn_norm", "attn_qkv", "attn_rope",
 LOOP_SCOPES = ("micro_batches", "layer_stack")
 
 
-def _op_names(parallel, zero1):
+def _op_names(parallel, zero1, recompute):
     """Every op_name of the toy train step, compiled for the CPU mesh with
     the flash kernels dispatched (interpreted)."""
     t = targets.train_step_target(
-        "named", parallel, zero1=zero1,
+        "named", parallel, zero1=zero1, recompute=recompute,
         model_overrides={"attention_impl": "pallas"})
     t = dataclasses.replace(t, env={"MEGATRON_TPU_FLASH_INTERPRET": "1"})
     found = set(re.findall(r'op_name="([^"]+)"', t.compiled_text()))
@@ -38,12 +38,13 @@ def _op_names(parallel, zero1):
     return sorted(n for n in found if n.startswith("jit("))
 
 
+@pytest.mark.parametrize("recompute", ["full", "selective"])
 @pytest.mark.parametrize("parallel, zero1", [
     ({}, False),
     ({"tensor_parallel": 2, "sequence_parallel": True}, True),
 ], ids=["one_replica_per_device", "tp2_sp_zero1"])
-def test_the_compiled_step_holds_every_scope(parallel, zero1):
-    names = _op_names(parallel, zero1)
+def test_the_compiled_step_holds_every_scope(parallel, zero1, recompute):
+    names = _op_names(parallel, zero1, recompute)
     stacks = [(n, scope_tokens(n)) for n in names]
     for scope in REGION_SCOPES:
         assert any(scope in toks for _n, toks in stacks), scope
@@ -54,11 +55,14 @@ def test_the_compiled_step_holds_every_scope(parallel, zero1):
         assert all("attention" in toks[:toks.index(kernel)]
                    for toks in under), kernel
     # forward, backward and recomputation keep the names: the forward
-    # kernel runs under jvp and again as rematted computation, the two
-    # backward kernels under the transpose
+    # kernel runs under jvp, the two backward kernels under the
+    # transpose. Under `full` the forward runs again as rematted
+    # computation; under `selective` the layer's checkpoint keeps its
+    # output and log-sum-exp, and no flash forward is computed twice
     fwd = [n for n, toks in stacks if "flash_fwd" in toks]
     assert any("jvp(" in n and "rematted_computation" not in n for n in fwd)
-    assert any("rematted_computation" in n for n in fwd)
+    assert any("rematted_computation" in n for n in fwd) == (
+        recompute == "full")
     for kernel in TRAIN_KERNELS[1:]:
         assert all("transpose(" in n for n, toks in stacks
                    if kernel in toks), kernel
