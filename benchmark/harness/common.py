@@ -38,6 +38,10 @@ class Run:
     attempted: int
     failed: int
     problems: List[str]                    # why `correct` is false
+    # each number `correct` compared, beside its limit: {name: {"value",
+    # and "at_most" or "at_least"}}; the line's last key
+    compared: Dict[str, Dict[str, float]] = dataclasses.field(
+        default_factory=dict)
     compiles_in_window: int = 0            # any at all voids the run
     steps: List[dict] = dataclasses.field(default_factory=list)
     requests: List[dict] = dataclasses.field(default_factory=list)

@@ -253,6 +253,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         attempted=len(records), failed=len(bad), problems=problems,
         requests=records, compiles_in_window=n_compiles)
     run_.extras["logprob_worst_diff"] = worst_logprob
+    run_.compared["logprob_gap"] = {
+        "value": worst_logprob,
+        "at_most": cell.traffic["check"]["logprob_tolerance"]}
     boot = result["boot"]
     # where the server's boot goes (wall clock, same host): process start
     # and imports, JAX finding the device, init_params, then engine build,

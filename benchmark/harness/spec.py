@@ -26,7 +26,7 @@ What a PR that adds a configuration brings, all of it new:
     1. <path>/configs/<name>.json: the source's config.json with the cut
        keys changed, plus "source", "reference", "reduced" {key: why},
        "assumed" {what the source does not state: the value taken},
-       "program" {"flags": [...]};
+       "deployment" (what the cut stands for), "program" {"flags": [...]};
     2. <path>/reference/<reference>.py, unless an existing one holds the
        block type: `program_flags(config, seq_length)`,
        `from_program_params(params)`, `lm_loss(weights, tokens, labels,
@@ -42,12 +42,41 @@ What a PR that adds a configuration brings, all of it new:
        cell, and the cell's name appended to the `workloads` list of
        every metric it reports.
 
+What `reduced` may cut (the `model-configs` guide, section 4; the
+contract test holds a configuration to it from its own published/
+<name>.json): the depth, and one chip's share of a layer in a stated
+deployment: the routed experts held (whichever of `num_experts`,
+`n_routed_experts`, `num_local_experts` the source has) and `vocab_size`,
+the slice of the vocabulary. Never a width: no other key ending in
+`_dim`, `_rank` or `_size`, and not the experts a token. The floors of a
+share: at least 8 experts held and a number that divides the published
+count; at least an eighth of the published vocabulary, rounded up; and,
+where either is cut, at least four layers behind the leading dense ones
+and a "deployment" that says over how many chips a layer is divided ("8
+chips share each layer: ..."). A cut of depth alone is bound by none of
+them. A sliced vocabulary is a smaller vocabulary: the corpus draws every
+id under the file's `vocab_size` (its last id ends a document), and a mix
+whose "corpus" gives "reserved_ids": n keeps the n ids under that one out
+of the corpus, for the configuration's own tokens (a mask token). The file
+gives the experts HELD and the program is run with that; the expert layer
+that is told which experts of a wider router it holds belongs to the PR
+that adds the configuration, with its test that the shares add up to the
+uncut reference.
+
 A reader of a scope the new block adds is a file of its own,
 `def read(run): return named.scope_ms(run, "router")` (harness/trace/
 named.py: any `jax.named_scope`, any `pallas_call(name=)`), with its
 `per_layer` entry; a kernel's roofline share wants the kernel's cost file
-beside it. tests/benchmark/test_benchmark_contract.py rehearses exactly
-such a PR on a copy of the tree.
+beside it. A cell lists only the rooflines whose cost files count what
+ITS kernels do: `flash_*_roofline_pct` count one causal band over S
+positions and `moe_experts_roofline_pct` micro-batch x sequence x k
+rows, so a step under another mask (block-causal, block-diagonal, a
+doubled sequence) or another grouping would read too high there. Such a
+kernel comes under a `pallas_call(name=)` of its own, with
+kernel_costs/<that name>.py and readers that name it, and the cell leaves
+the others out of its `workloads` lists.
+tests/benchmark/test_benchmark_contract.py rehearses exactly such PRs on
+a copy of the tree: another block type, and one chip's share.
 """
 
 from __future__ import annotations

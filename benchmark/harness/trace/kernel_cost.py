@@ -26,7 +26,8 @@ needed work, so the five are booked three on dq and two on dkv, and only
 the two kernels' sum means anything. Bytes: each of q, k, v, o read or
 written once by the forward (4 tensors of the q shape: the kernels take K
 and V already broadcast to the query heads), and q, k, v, o, do, dq, dk,
-dv once by the backward (6 booked on dq, 2 on dkv).
+dv once by the backward (6 booked on dq, 2 on dkv). One fused `flash_bwd`
+call is booked the pair's sum: five matmuls, eight tensors.
 """
 
 from __future__ import annotations

@@ -32,7 +32,10 @@ def build_corpus(prefix: str, spec: dict, vocab_size: int, seed: int) -> int:
     writer: log-normal lengths, each document walking one seeded cycle of
     token ids from a random start (so the next token is a function of the
     current one and the loss falls within the warm-up), end-of-document id
-    appended. Returns the number of tokens written."""
+    appended. The mix's optional "reserved_ids" (default 0) keeps that
+    many ids just under the end-of-document id out of the cycle, free for
+    a configuration's own tokens (a mask token): the corpus never emits
+    them. Returns the number of tokens written."""
     import numpy as np
 
     from megatron_tpu.data.indexed_dataset import (
@@ -41,7 +44,8 @@ def build_corpus(prefix: str, spec: dict, vocab_size: int, seed: int) -> int:
 
     rng = np.random.default_rng(seed)
     eod = vocab_size - 1
-    cycle = rng.choice(eod, size=spec["cycle"], replace=False)
+    cycle = rng.choice(eod - spec.get("reserved_ids", 0),
+                       size=spec["cycle"], replace=False)
     lengths = np.exp(rng.normal(np.log(spec["doc_tokens_median"]),
                                 spec["doc_tokens_sigma"],
                                 size=4 * spec["tokens"]
@@ -162,10 +166,15 @@ def main(plan_path: str) -> int:
 
     state = pretrain_gpt.main(argv)
     del state  # the reference below needs the room
+    ids = [kept["batch"][k] for k in ("tokens", "labels")]
     result = {
         "device": found, "memory_peak_bytes": devices.memory_peak_bytes(),
         "marks": marks, "corpus_tokens": corpus_tokens,
-        "steps": clock.steps, "window_start": clock.window_start}
+        "steps": clock.steps, "window_start": clock.window_start,
+        # smallest and largest id the first global batch holds (a sliced
+        # vocabulary is a smaller vocabulary: none may reach vocab_size)
+        "first_batch_ids": [int(min(a.min() for a in ids)),
+                            int(max(a.max() for a in ids))]}
     flops = getattr(reference, "train_flops_per_token", None)
     if flops is not None:
         result["train_flops_per_token"] = flops(config, plan["seq_length"])

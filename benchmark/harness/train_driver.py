@@ -61,7 +61,8 @@ def tokens_per_s(steps: List[dict], window_start: float) -> float:
 
 
 def check(result: dict, plan: dict, inside: List[dict],
-          mix: dict) -> List[str]:
+          mix: dict) -> tuple:
+    """(problems, the numbers compared, each beside its limit)."""
     problems = []
     steps = result["steps"]
     first = steps[0]["loss"] if steps else float("nan")
@@ -70,9 +71,15 @@ def check(result: dict, plan: dict, inside: List[dict],
     # float32 reference, averaged over a batch of thousands of tokens:
     # the two agree to the mix's tolerance (set from chip runs, PERF.md);
     # dropping a term of the loss or a norm moves it by far more
+    compared = {"first_loss_gap": {
+        "value": abs(first - ref), "at_most": mix["first_loss_tolerance"]}}
     if not abs(first - ref) <= mix["first_loss_tolerance"]:
         problems.append(f"first loss {first} vs reference {ref}")
     losses = [s["loss"] for s in inside]
+    if losses:
+        compared["loss_fall_in_window"] = {
+            "value": first - max(losses),
+            "at_least": mix["loss_must_fall_by"]}
     if not all(math.isfinite(x) for x in losses):
         problems.append("a loss inside the window is not finite")
     elif losses and max(losses) > first - mix["loss_must_fall_by"]:
@@ -82,7 +89,7 @@ def check(result: dict, plan: dict, inside: List[dict],
     if len(steps) >= plan["train_iters"]:
         problems.append("the trainer ran out of steps before the window "
                         "closed (raise max_steps_per_s in a new mix)")
-    return problems
+    return problems, compared
 
 
 def compiles_inside(compiles: list, inside: List[dict], window: tuple) -> int:
@@ -111,14 +118,15 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     inside = [s for s in result["steps"]
               if mono0 < s["t"] <= mono0 + seconds]
     compiles = compile_watch.read(os.path.join(run_dir, "compiles.jsonl"))
-    problems = check(result, plan, inside, cell.traffic)
+    problems, compared = check(result, plan, inside, cell.traffic)
     out = common.Run(
         cell=cell, seconds=seconds, device=result["device"],
         memory_peak_bytes=result["memory_peak_bytes"],
         setup_s=wall0 - started,
         end_to_end={"train_tokens_per_s":
                     lambda: tokens_per_s(inside, mono0)},
-        attempted=len(inside), failed=0, problems=problems, steps=inside,
+        attempted=len(inside), failed=0, problems=problems,
+        compared=compared, steps=inside,
         compiles_in_window=compiles_inside(
             compiles, inside, (wall0, wall0 + seconds)))
     marks = result["marks"]

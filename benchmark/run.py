@@ -13,7 +13,9 @@ file of benchmark/reference/: the plain reference `correct` is held to
 and the translation of the sizes into the program's flags. With --trace 0 the line holds the cell's
 end-to-end metrics, with --trace 1 its per-layer metrics (each read by
 benchmark/layer_metrics/<reader>.py), `device.busy_s`/`window_s` and a
-`breakdown`. Weights, data and requests are made from --seed. The program
+`breakdown`. Each number `correct` compared stands beside its limit in the
+line's last key, `compared`, and in the last lines of standard error.
+Weights, data and requests are made from --seed. The program
 under test runs in a child that alone holds the chip(s); this process
 never initialises a JAX backend.
 
@@ -136,6 +138,13 @@ def main(argv=None) -> int:
         return 2
     if args.rehearse:
         line["rehearsal"] = True
+    # each number `correct` compared beside its limit: the last lines on
+    # standard error, and the line's last key
+    for name, got in run.compared.items():
+        print(f"benchmark: compared {name} "
+              + " ".join(f"{k} {v}" for k, v in got.items()),
+              file=sys.stderr)
+    line["compared"] = run.compared
     print(json.dumps(line), flush=True)
     return 0
 

@@ -4,15 +4,16 @@ files that spec names (a configuration's own keys, its published values
 under published/, its reference's functions), so a PR that adds a
 configuration of another architecture, or a cell on it, adds files and
 entries and edits no test. The rehearsal at the end is that PR: the real
-BENCHMARK.json plus the `toy-falcon` configuration of tests/benchmark/
-added/ and one training cell on it, in a temporary tree, under the same
-checks and through the harness on the CPU."""
+BENCHMARK.json plus the two configurations of tests/benchmark/added/
+(`toy-falcon`, another block type, cut in nothing; `toy-moe-share8`, one
+chip's share of a deployment: depth, experts held and vocabulary cut to
+the floors) and one training cell on each, in a temporary tree, under the
+same checks and through the harness on the CPU."""
 
 import json
 import os
 import re
 import shutil
-import subprocess
 import sys
 
 import pytest
@@ -20,8 +21,10 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark.harness import spec  # noqa: E402
+from test_benchmark_rehearse_train import rehearse  # noqa: E402
 
 BENCHMARK = os.path.join(REPO, "BENCHMARK.json")
 # the serving cells kept ready for the `benchmark` PR that admits them
@@ -35,6 +38,17 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 # what a configuration file holds besides the source's own keys
 OURS = {"source", "reference", "reduced", "assumed", "deployment", "program"}
+# `reduced` lists cuts of depth and of the chip's share of a layer (the
+# `model-configs` guide, section 4), never a width: no key with a width's
+# ending, but for the vocabulary held, and not the experts a token
+WIDTH_ENDINGS = ("_dim", "_rank", "_size")
+NO_WIDTH = {"vocab_size"}
+WIDTHS_BY_NAME = {"num_experts_per_tok"}
+# the keys under which sources count a layer's routed experts, and its
+# leading dense layers; the floors of a share
+EXPERT_COUNTS = ("num_experts", "n_routed_experts", "num_local_experts")
+LEADING_DENSE = "first_k_dense_replace"
+LEAST_EXPERTS, LEAST_VOCABULARY_SHARE, LEAST_LAYERS = 8, 8, 4
 
 
 def _load(path):
@@ -92,8 +106,13 @@ def file_contract(spec_path):
     for c in s["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert any(c["file"].startswith(p + "/") for p in s["paths"])
-        assert not any(k.endswith(("_dim", "_rank", "_size"))
-                       for k in c["reduced"])
+        for key in c["reduced"]:
+            assert key in NO_WIDTH or not (
+                key.endswith(WIDTH_ENDINGS) or key in WIDTHS_BY_NAME), (
+                f"configuration {c['name']!r} lists {key!r} in `reduced`: "
+                "a width is never cut; the vocabulary held is no width "
+                "(`reduced` takes the depth, the experts held and "
+                "`vocab_size`)")
     files = [c["file"] for c in s["configs"]]
     assert len(set(files)) == len(files)
     command = " ".join(s["command"])
@@ -115,10 +134,44 @@ def _published_file(spec_path, name):
     return os.path.join(PUBLISHED, name + ".json")
 
 
+def share_contract(name, config, published, reduced):
+    """One chip's share of a deployment keeps the guide's floors, read
+    off the configuration's own published values: an eighth of the
+    vocabulary, 8 routed experts a layer and a whole number of such
+    shares, four layers behind the leading dense ones, and the deployment
+    said in words. A configuration that cuts depth alone cuts no share,
+    and none of this binds it."""
+    cut = [k for k in reduced if k in NO_WIDTH or k in EXPERT_COUNTS]
+    if "vocab_size" in reduced:
+        whole = published["vocab_size"]
+        least = -(-whole // LEAST_VOCABULARY_SHARE)
+        assert least <= config["vocab_size"] < whole, (
+            f"{name}: a vocabulary of {config['vocab_size']} held of "
+            f"{whole}: a slice is at least an eighth, {least}")
+    for key in set(reduced) & set(EXPERT_COUNTS):
+        held, whole = config[key], published[key]
+        assert LEAST_EXPERTS <= held < whole and whole % held == 0, (
+            f"{name}: {held} experts held of {whole} ({key!r}): a chip "
+            f"holds at least {LEAST_EXPERTS}, and a whole number of such "
+            "shares makes the layer")
+    if not cut:
+        return
+    deployment = config.get("deployment", "")
+    assert re.search(r"\b\d+ chips\b", deployment), (
+        f"{name}: {cut} cut to one chip's share and no \"deployment\" that "
+        "says how many chips share a layer (\"8 chips share each layer: "
+        "...\")")
+    layers = config["num_hidden_layers"] - config.get(LEADING_DENSE, 0)
+    assert layers >= LEAST_LAYERS, (
+        f"{name}: {layers} layers behind the leading dense ones: where a "
+        f"share is cut, at least {LEAST_LAYERS} stay")
+
+
 def configuration_contract(spec_path, name):
     """A configuration keeps what its source published: outside `reduced`
     every key it takes from the source has the source's own value, which
-    published/<name>.json holds with the source's URL."""
+    published/<name>.json holds with the source's URL; what `reduced`
+    cuts keeps to the floors of share_contract()."""
     entry = {c["name"]: c for c in _load(spec_path)["configs"]}[name]
     config = _load(os.path.join(os.path.dirname(spec_path), entry["file"]))
     path = _published_file(spec_path, name)
@@ -147,6 +200,7 @@ def configuration_contract(spec_path, name):
                 f"{name}: {key!r} is {config[key]!r}, the source has "
                 f"{published['config'][key]!r}, and `reduced` does not "
                 "list it")
+    share_contract(name, config, published["config"], reduced)
 
 
 def cell_contract(spec_path, name):
@@ -220,6 +274,9 @@ def test_serving_cells_kept_ready_find_their_files(cell_name):
 
 
 def test_every_reader_file_is_named_by_some_metric():
+    # readers only: a file of benchmark/kernel_costs/ is no reader (a
+    # reader names the kernels whose costs it wants, and a cost file no
+    # kernel carries the name of yet, as flash_bwd.py, is read by none)
     stems = {m["name"].split(".")[0]
              for path in (BENCHMARK, CANDIDATES, TOY)
              for m in _load(path)["per_layer"]}
@@ -232,14 +289,25 @@ def test_every_reader_file_is_named_by_some_metric():
 
 ADDED_CONFIG = "toy-falcon"
 ADDED_CELL = "train_toyfalcon_rehearsed"
+# one chip's share of a deployment: a toy of the MoE block the benchmark
+# has, its depth, experts held and vocabulary each cut to the floor
+SHARE_CONFIG = "toy-moe-share8"
+SHARE_CELL = "train_toymoe_share8_rehearsed"
+# (configuration, cell, traffic mix, the configuration's why)
+ADDED = [
+    (ADDED_CONFIG, ADDED_CELL, "added_train", "another block type, added"),
+    (SHARE_CONFIG, SHARE_CELL, "added_share_train",
+     "one chip's share of 8 that hold each layer, added"),
+]
 
 
 def added_tree(root, published=True):
-    """A copy of what the benchmark is made of with what such a PR
-    brings: files (tests/benchmark/added: the configuration, the
-    reference it names, its published values, a traffic mix) and entries
-    (a configuration, a cell, the cell's name on each metric it
-    reports). Nothing that exists is edited. Returns the spec's path."""
+    """A copy of what the benchmark is made of with what such PRs bring:
+    files (tests/benchmark/added: each configuration, the reference it
+    names unless the benchmark has it, its published values, a traffic
+    mix) and entries (a configuration with the `reduced` its file gives,
+    a cell, the cell's name on each metric it reports). Nothing that
+    exists is edited. Returns the spec's path."""
     os.makedirs(root / "tests")
     os.symlink(os.path.join(REPO, "benchmark"), root / "benchmark")
     shutil.copytree(os.path.join(REPO, "tests", "benchmark"),
@@ -251,20 +319,22 @@ def added_tree(root, published=True):
         os.remove(root / "tests" / "benchmark" / "published"
                   / (ADDED_CONFIG + ".json"))
     s = _load(BENCHMARK)
-    file = "tests/benchmark/" + ADDED_CONFIG + ".json"
-    s["configs"].append({
-        "name": ADDED_CONFIG, "source": _load(root / file)["source"],
-        "file": file, "reduced": [], "why": "another block type, added"})
-    s["workloads"].append({
-        "name": ADDED_CELL, "config": ADDED_CONFIG, "traffic": "added_train",
-        "chips": 1, "why": "rehearsal of a cell added by files and entries"})
-    # it reports what the one-chip training cells report
-    alike = {w["name"] for w in _load(BENCHMARK)["workloads"]
+    # they report what the one-chip training cells report
+    alike = {w["name"] for w in s["workloads"]
              if w["chips"] == 1 and spec.Cell(
                  BENCHMARK, w["name"]).traffic["driver"] == "train"}
-    for m in s["end_to_end"] + s["per_layer"]:
-        if alike & set(m.get("workloads", [])):
-            m["workloads"].append(ADDED_CELL)
+    for config, cell, traffic, why in ADDED:
+        file = "tests/benchmark/" + config + ".json"
+        held = _load(root / file)
+        s["configs"].append({
+            "name": config, "source": held["source"], "file": file,
+            "reduced": list(held.get("reduced", {})), "why": why})
+        s["workloads"].append({
+            "name": cell, "config": config, "traffic": traffic, "chips": 1,
+            "why": "rehearsal of a cell added by files and entries"})
+        for m in s["end_to_end"] + s["per_layer"]:
+            if alike & set(m.get("workloads", [])):
+                m["workloads"].append(cell)
     path = root / "BENCHMARK.json"
     with open(path, "w") as f:
         json.dump(s, f)
@@ -278,19 +348,139 @@ def added(tmp_path_factory):
 
 def test_rehearsed_pr_keeps_the_files_contract(added):
     file_contract(added)
-    assert ADDED_CELL in _names(added, "workloads")
+    assert {ADDED_CELL, SHARE_CELL} <= set(_names(added, "workloads"))
+    reduced = {c["name"]: c["reduced"] for c in _load(added)["configs"]}
+    assert reduced[ADDED_CONFIG] == []
+    assert reduced[SHARE_CONFIG] == ["num_hidden_layers", "num_experts",
+                                     "vocab_size"]
 
 
 @pytest.mark.parametrize("name", _names(BENCHMARK, "configs")
-                         + [ADDED_CONFIG])
+                         + [ADDED_CONFIG, SHARE_CONFIG])
 def test_rehearsed_pr_keeps_every_configuration_to_its_source(added, name):
     configuration_contract(added, name)
 
 
 @pytest.mark.parametrize("cell_name", _names(BENCHMARK, "workloads")
-                         + [ADDED_CELL])
+                         + [ADDED_CELL, SHARE_CELL])
 def test_rehearsed_pr_has_every_cell_find_its_files(added, cell_name):
     cell_contract(added, cell_name)
+
+
+# --- what `reduced` may cut, and how far ------------------------------------
+
+def _width(key):
+    """The share-cut toy with `key`, a width, listed as reduced too (and
+    its value halved, as such a cut would)."""
+    def edit(entry, config, published):
+        config[key] = published[key] = published.get(key, 32)
+        config[key] //= 2
+        config["reduced"][key] = "cut"
+        entry["reduced"].append(key)
+    return edit
+
+
+def _set(config=(), published=()):
+    def edit(entry, held, source):
+        held.update(config)
+        source.update(published)
+    return edit
+
+
+def _no_deployment(entry, config, published):
+    del config["deployment"]
+
+
+def _vocabulary_alone(entry, config, published):
+    """Only the vocabulary is sliced, at depth 3: the share's floors bind
+    whichever part of the share is cut."""
+    config.update(num_hidden_layers=3, num_experts=64)
+    published["num_hidden_layers"] = 3
+    for key in ("num_hidden_layers", "num_experts"):
+        del config["reduced"][key]
+        entry["reduced"].remove(key)
+
+
+NEVER_A_WIDTH = "a width is never cut; the vocabulary held is no width"
+SHARE_CASES = {
+    # the toy as it stands: every floor met exactly
+    "as_added": (None, None),
+    "hidden_size": (_width("hidden_size"), NEVER_A_WIDTH),
+    "moe_intermediate_size": (_width("moe_intermediate_size"),
+                              NEVER_A_WIDTH),
+    "head_dim": (_width("head_dim"), NEVER_A_WIDTH),
+    "kv_lora_rank": (_width("kv_lora_rank"), NEVER_A_WIDTH),
+    "num_experts_per_tok": (_width("num_experts_per_tok"), NEVER_A_WIDTH),
+    # an eighth, rounded up: 512 of 4096 and of 4095, not of 4097
+    "vocabulary_an_eighth_rounded_up": (
+        _set(published={"vocab_size": 4095}), None),
+    "vocabulary_under_an_eighth": (
+        _set(published={"vocab_size": 4097}), "at least an eighth, 513"),
+    "vocabulary_not_cut_at_all": (
+        _set({"vocab_size": 4096}), "'vocab_size' is listed as reduced"),
+    "16_experts_held_of_64": (_set({"num_experts": 16}), None),
+    "4_experts_held": (_set({"num_experts": 4}),
+                       "4 experts held of 64"),
+    "12_experts_held_of_128": (
+        _set({"num_experts": 12}, {"num_experts": 128}),
+        "12 experts held of 128"),
+    "share_without_deployment": (_no_deployment, "no \"deployment\""),
+    "deployment_without_a_count": (
+        _set({"deployment": "an expert-parallel job"}),
+        "how many chips share a layer"),
+    "share_at_depth_3": (_set({"num_hidden_layers": 3}),
+                         "3 layers behind the leading dense ones"),
+    "share_at_depth_5_behind_2_dense": (
+        _set({"num_hidden_layers": 5, "first_k_dense_replace": 2},
+             {"first_k_dense_replace": 2}),
+        "3 layers behind the leading dense ones"),
+    "share_at_depth_6_behind_2_dense": (
+        _set({"num_hidden_layers": 6, "first_k_dense_replace": 2},
+             {"first_k_dense_replace": 2}), None),
+    "vocabulary_alone_at_depth_3": (
+        _vocabulary_alone, "3 layers behind the leading dense ones"),
+}
+
+
+@pytest.mark.parametrize("case", SHARE_CASES)
+def test_a_share_keeps_the_floors_and_a_width_is_never_cut(case, tmp_path):
+    """Each rule on `reduced` with a configuration it refuses and one it
+    takes, on the rehearsed tree with the share-cut toy edited; what is
+    expected comes from the toy's own published/<name>.json."""
+    edit, refusal = SHARE_CASES[case]
+    path = added_tree(tmp_path)
+    s = _load(path)
+    entry = {c["name"]: c for c in s["configs"]}[SHARE_CONFIG]
+    files = [tmp_path / entry["file"],
+             tmp_path / "tests" / "benchmark" / "published"
+             / (SHARE_CONFIG + ".json")]
+    config, published = (_load(f) for f in files)
+    if edit is not None:
+        edit(entry, config, published["config"])
+    for file, value in zip(files + [path], (config, published, s)):
+        with open(file, "w") as f:
+            json.dump(value, f)
+    if refusal is None:
+        file_contract(path)
+        configuration_contract(path, SHARE_CONFIG)
+        return
+    with pytest.raises(AssertionError, match=refusal):
+        file_contract(path)
+        configuration_contract(path, SHARE_CONFIG)
+
+
+def test_a_cut_of_depth_alone_is_bound_by_no_floor_of_the_share():
+    """The accepted configurations and the candidate cut depth only: one
+    layer, two layers, no `deployment` that counts chips, and they pass
+    as they are."""
+    depths = []
+    for path in (BENCHMARK, CANDIDATES):
+        for entry in _load(path)["configs"]:
+            assert entry["reduced"] == ["num_hidden_layers"]
+            configuration_contract(path, entry["name"])
+            depths.append(_load(os.path.join(os.path.dirname(
+                path), entry["file"]))["num_hidden_layers"])
+    assert min(depths) < LEAST_LAYERS
 
 
 def test_a_configuration_without_published_values_is_told_which_file_to_add(
@@ -317,14 +507,7 @@ def test_rehearsed_cell_runs_traced_through_the_unchanged_harness(added):
     metric the training cells report is asked of a block type no reader
     has seen; those with something to read report it, the device readers
     say nothing, none raises."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
-         "--spec", added, "--workload", ADDED_CELL, "--seed", "2500000001",
-         "--seconds", "3", "--trace", "1", "--rehearse"],
-        cwd=REPO, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert line["rehearsal"] is True and line["correct"] is True
+    line = rehearse(ADDED_CELL, 1, 3, spec=added, seed=2500000001)
     asked = {m["name"] for m in spec.Cell(added, ADDED_CELL).per_layer()}
     assert {"mlp_ms_per_step", "flash_fwd_roofline_pct"} <= asked
     assert set(line["metrics"]) == {
@@ -338,3 +521,80 @@ def test_rehearsed_cell_runs_traced_through_the_unchanged_harness(added):
     assert plan["reference"] == os.path.join(
         os.path.dirname(added), "tests", "benchmark", "reference",
         "toyfalcon.py")
+
+
+# --- the share-cut configuration through the unchanged harness --------------
+
+@pytest.fixture(scope="module")
+def share_rehearsed(added):
+    """The share-cut toy's cell through run.py on the CPU, untraced and
+    then traced (each run empties the cell's directory, so what is read
+    from it below is the traced run's): (untraced line, traced line, the
+    child's result, the ids of the corpus on disk).
+
+    To the program as it stands a toy with 8 experts is only a smaller
+    model: 8 experts behind a router 8 wide, a vocabulary of 512. The
+    layer that holds 8 experts of a router 64 wide, and computes its own
+    experts' part of the result, is the configuration PR's, with its own
+    test that the shares add up to the uncut reference. What is rehearsed
+    here is that the harness carries such a file (`reduced` depth,
+    experts held and vocabulary, a `deployment`, a mix that reserves ids)
+    from BENCHMARK.json to a result line unedited."""
+    from megatron_tpu.data.indexed_dataset import make_dataset
+
+    untraced, traced = (rehearse(SHARE_CELL, trace, 2, spec=added,
+                                 seed=2500000003) for trace in (0, 1))
+    run_dir = os.path.join(REPO, "runs", "benchmark", SHARE_CELL)
+    corpus = make_dataset(os.path.join(run_dir, "corpus"))
+    ids = set()
+    for i in range(len(corpus)):
+        ids.update(corpus[i].tolist())
+    return untraced, traced, _load(os.path.join(run_dir, "result.json")), ids
+
+
+def test_rehearsed_share_is_correct_and_reports_every_metric(
+        added, share_rehearsed):
+    untraced, traced, result, _ = share_rehearsed  # correct, none failed
+    cell = spec.Cell(added, SHARE_CELL)
+    assert set(untraced["metrics"]) == {
+        m["name"] for m in cell.end_to_end()} == {"train_tokens_per_s",
+                                                  "setup_s"}
+    # asked of it: every per-layer metric a one-chip training cell
+    # reports, the expert block's among them
+    asked = {m["name"] for m in cell.per_layer()}
+    assert asked == {
+        m["name"] for name in _names(BENCHMARK, "workloads")
+        if spec.Cell(BENCHMARK, name).chips == 1
+        for m in spec.Cell(BENCHMARK, name).per_layer()}
+    assert {"moe_experts_roofline_pct", "flash_bwd_roofline_pct",
+            "grad_accumulate_ms_per_step"} <= asked
+    # in the line: what the program's spans, counters and journal give
+    # on any backend; the device readers have no device plane to read on
+    # a CPU, say nothing, and none raises
+    assert set(traced["metrics"]) == {
+        "train_step_ms_p50", "train_data_wait_pct", "train_host_ms_per_step",
+        "step_hbm_gb", "step_temp_hbm_gb", "moe_load_max_over_mean"}
+    assert 1.0 <= traced["metrics"]["moe_load_max_over_mean"]["value"] <= 8
+    # the reference is the existing MoE block's, given the sliced sizes
+    assert result["steps"][0]["ntokens"] == 2 * 128
+    assert abs(result["steps"][0]["loss"]
+               - result["reference_first_loss"]) < 0.02
+
+
+def test_rehearsed_share_draws_every_id_from_the_slice(
+        added, share_rehearsed):
+    _, _, result, corpus_ids = share_rehearsed
+    cell = spec.Cell(added, SHARE_CELL)
+    vocabulary = cell.config["vocab_size"]
+    assert vocabulary == 512 == _load(_published_file(
+        added, SHARE_CONFIG))["config"]["vocab_size"] // 8
+    # the corpus: ids of the cycle and the end-of-document id, all under
+    # the sliced vocabulary; the ids the mix reserves never occur
+    eod, reserved = vocabulary - 1, cell.traffic["corpus"]["reserved_ids"]
+    assert eod in corpus_ids and max(corpus_ids) == eod
+    assert min(corpus_ids) >= 0
+    assert reserved == 2 and not corpus_ids & {eod - 1, eod - 2}
+    assert len(corpus_ids) == cell.traffic["corpus"]["cycle"] + 1
+    # the first global batch, tokens and labels, as the trainer drew it
+    low, high = result["first_batch_ids"]
+    assert 0 <= low <= high < vocabulary

@@ -76,6 +76,19 @@ KERNEL_TEXT = ('%flash_fwd.1 = (bf16[1,32,4096,128]{3,2,1,0}, '
                'custom_call_target="tpu_custom_call"')
 
 
+BWD_TEXT = {
+    "flash_bwd_dq": '%flash_bwd_dq.1 = bf16[1,32,4096,128]{3,2,1,0} '
+                    'custom-call(bf16[1] %a), '
+                    'custom_call_target="tpu_custom_call"',
+    "flash_bwd_dkv": '%flash_bwd_dkv.1 = (bf16[1,32,4096,128]{3,2,1,0}, '
+                     'bf16[1,32,4096,128]{3,2,1,0}) custom-call(bf16[1] %a),'
+                     ' custom_call_target="tpu_custom_call"'}
+FUSED_BWD_TEXT = ('%flash_bwd.1 = (bf16[1,32,4096,128]{3,2,1,0}, '
+                  'bf16[1,32,4096,128]{3,2,1,0}, bf16[1,32,4096,128]'
+                  '{3,2,1,0:T(8,128)(2,1)}) custom-call(bf16[1] %a), '
+                  'custom_call_target="tpu_custom_call"')
+
+
 def _made_up_plane():
     """Four runs of one program, 1000 ps each with a gap between: the
     first and the last are cut by the trace's edges and left out. Each
@@ -179,6 +192,12 @@ def test_kernel_costs_equal_hand_numbers():
     dkv, dkv_bytes = _needed("flash_bwd_dkv", dims, 2, 4096)
     assert dq + dkv == pytest.approx(2.5 * flops)
     assert dq_bytes + dkv_bytes == 2 * nbytes
+    # one fused call is booked exactly what the pair is: five, eight
+    assert _needed("flash_bwd", dims, 2, 4096) == (dq + dkv,
+                                                   dq_bytes + dkv_bytes)
+    assert _needed("flash_bwd", dims, 2, 1024) == tuple(
+        a + b for a, b in zip(_needed("flash_bwd_dq", dims, 2, 1024),
+                              _needed("flash_bwd_dkv", dims, 2, 1024)))
     # a result of another rank is not a shape these files know
     assert _needed("flash_fwd", (32, 4096, 128), 2, 4096) is None
     # a kernel nobody wrote a cost for has none: no roofline, no default
@@ -192,6 +211,9 @@ def test_shapes_are_read_from_the_events_own_text():
     assert kernel_cost.result_shape(
         "%flash_bwd_dq.1 = bf16[8,16,4096,128]{3,2,1,0:T(8,128)(2,1)} "
         "custom-call(s32[1]{0} %c)") == ("bf16", (8, 16, 4096, 128))
+    # of several results the first: a fused backward's (dq, dk, dv)
+    assert kernel_cost.result_shape(FUSED_BWD_TEXT) == (
+        "bf16", (1, 32, 4096, 128))
     assert kernel_cost.result_shape("no hlo text") is None
 
 
@@ -263,6 +285,54 @@ def test_a_kernel_of_any_name_gets_its_roofline_from_a_file_of_its_own(
     assert run.extras["roofline"]["experts"]["needed_flop"] == flop
     assert named.kernel_ms(run, "grouped_matmul") == 4.0
     assert named.kernel_ms(run, "flash_fwd") is None
+
+
+@pytest.mark.parametrize("ran", [
+    ("flash_bwd",), ("flash_bwd_dq", "flash_bwd_dkv"),
+    ("flash_bwd_dq", "flash_bwd_dkv", "flash_bwd")],
+    ids=lambda ran: "+".join(ran))
+def test_the_backward_readers_take_the_fused_kernels_name(ran, monkeypatch):
+    """What refused PR 35: a program whose backward is ONE kernel,
+    `flash_bwd`, gave the two backward readers nothing to read. A record
+    holding it alone reads a time and a share, booked what the split pair
+    is booked over the same result; one holding all three sums them."""
+    cell = spec.Cell(BENCHMARK, "train_mistral7b_seq4k")
+    texts = dict(BWD_TEXT, flash_bwd=FUSED_BWD_TEXT)
+    seconds = {"flash_bwd_dq": 0.0035, "flash_bwd_dkv": 0.0041,
+               "flash_bwd": 0.0060}
+    # two layers, and beside them a kernel the readers do not name
+    kernels = {k: {"s": seconds[k], "calls": {texts[k]: 2.0}} for k in ran}
+    kernels["flash_fwd"] = {"s": 0.0036, "calls": {KERNEL_TEXT: 2.0}}
+    monkeypatch.setattr(named, "per_run", lambda path: {"kernels": kernels})
+    run = _fake_run(cell, trace={"devices": 1})
+    ms = cell.reader("flash_bwd_ms_per_step")(run)
+    assert ms == pytest.approx(1e3 * sum(seconds[k] for k in ran))
+    pct = cell.reader("flash_bwd_roofline_pct")(run)
+    roof = run.extras["roofline"]["flash_bwd"]
+    assert roof["pct"] == pct and roof["bound"] == "compute"
+    assert roof["measured_ms"] == pytest.approx(ms)
+    # the pair's booking over [1, 32, 4096, 128], a layer: 5 matmuls, 8
+    # tensors; all three ran = the backward ran twice over
+    pair = [_needed(k, (1, 32, 4096, 128), 2, 4096)
+            for k in ("flash_bwd_dq", "flash_bwd_dkv")]
+    times = 2.0 * (2 if len(ran) == 3 else 1)
+    assert roof["needed_flop"] == times * sum(w[0] for w in pair)
+    assert roof["needed_bytes"] == times * sum(w[1] for w in pair)
+    assert pct == pytest.approx(
+        100 * roof["needed_flop"] / V5E["bf16_flops_per_s"] / (ms / 1e3))
+    # the forward's readers see their own kernel and no other
+    assert cell.reader("flash_fwd_ms_per_step")(run) == pytest.approx(3.6)
+
+
+def test_a_program_without_a_backward_kernel_gives_the_readers_nothing(
+        monkeypatch):
+    cell = spec.Cell(BENCHMARK, "train_mistral7b_seq4k")
+    monkeypatch.setattr(named, "per_run", lambda path: {"kernels": {
+        "flash_fwd": {"s": 0.0036, "calls": {KERNEL_TEXT: 2.0}}}})
+    run = _fake_run(cell, trace={"devices": 1})
+    assert cell.reader("flash_bwd_ms_per_step")(run) is None
+    assert cell.reader("flash_bwd_roofline_pct")(run) is None
+    assert "flash_bwd" not in run.extras.get("roofline", {})
 
 
 # --- the recordings ---------------------------------------------------------

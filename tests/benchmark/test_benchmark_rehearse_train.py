@@ -27,6 +27,18 @@ def rehearse(workload, trace, seconds, spec=TOY, seed=3):
     assert line["device"]["platform"] == "cpu"
     assert line["correct"] is True, line.get("problems")
     assert line["failed"] == 0 and line["attempted"] > 0
+    # each number `correct` compared, beside its limit: the line's last
+    # key and the last lines of standard error
+    assert list(line)[-1] == "compared" and line["compared"]
+    said = proc.stderr.strip().splitlines()[-len(line["compared"]):]
+    for text, (name, got) in zip(said, line["compared"].items()):
+        limit = got.get("at_most", got.get("at_least"))
+        assert set(got) - {"value"} in ({"at_most"}, {"at_least"})
+        assert text.split() == ["benchmark:", "compared", name, "value",
+                                str(got["value"]), *(set(got) - {"value"}),
+                                str(limit)]
+        assert (got["value"] <= limit if "at_most" in got
+                else got["value"] >= limit)
     return line
 
 

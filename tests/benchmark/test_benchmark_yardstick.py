@@ -243,6 +243,69 @@ def test_unreachable_server_is_a_failed_request_not_an_exception():
     assert status == 0 and body
 
 
+# --- the training corpus ----------------------------------------------------
+
+CORPUS = {"tokens": 20000, "cycle": 64, "doc_tokens_median": 100,
+          "doc_tokens_sigma": 1.0, "doc_tokens_min": 8,
+          "doc_tokens_max": 1024}
+# sha256 of the .bin that build_corpus(CORPUS, vocabulary 512, seed
+# 2500000037) wrote on PR 37's parent tree (bd0215a), before the mix's
+# "reserved_ids" existed: 20,301 uint16 tokens
+CORPUS_AT_PARENT = ("91fe36c32cfd7290eb3f8890096482cd"
+                    "2a7ca7083530a92849cf98fda638d67a")
+
+
+@pytest.mark.parametrize("reserved", [None, 0, 2])
+def test_corpus_is_the_parents_bit_for_bit_unless_ids_are_reserved(
+        reserved, tmp_path):
+    """A mix that reserves no ids (the key absent, or 0) draws its cycle
+    from every id under the end-of-document id by the call the parent
+    made: the accepted cells' corpora do not move. One that reserves 2
+    never emits the two ids just under the end-of-document id, which are
+    then a configuration's own (a mask token)."""
+    import numpy as np
+
+    from benchmark.harness.train_child import build_corpus
+
+    mix = dict(CORPUS) if reserved is None else dict(
+        CORPUS, reserved_ids=reserved)
+    prefix = str(tmp_path / "corpus")
+    written = build_corpus(prefix, mix, 512, 2500000037)
+    with open(prefix + ".bin", "rb") as f:
+        data = f.read()
+    ids = np.frombuffer(data, np.uint16)
+    assert written == len(ids) and ids.max() == 511
+    assert len(set(ids.tolist())) == 64 + 1
+    same = hashlib.sha256(data).hexdigest() == CORPUS_AT_PARENT
+    if not reserved:
+        assert same and written == 20301
+    else:
+        assert not same and not {509, 510} & set(ids.tolist())
+
+
+def test_reserved_ids_occur_in_no_seeds_corpus_and_unreserved_they_do(
+        tmp_path):
+    """Over 24 seeds: with two ids reserved neither ever occurs; with
+    none reserved some seed's cycle holds one (so the case above can
+    fail)."""
+    import numpy as np
+
+    from benchmark.harness.train_child import build_corpus
+
+    small = dict(CORPUS, tokens=2000)
+    seen = {0: set(), 2: set()}
+    for seed in range(2500000100, 2500000124):
+        for reserved in seen:
+            prefix = str(tmp_path / f"c{seed}_{reserved}")
+            build_corpus(prefix, dict(small, reserved_ids=reserved), 512,
+                         seed)
+            seen[reserved] |= set(np.fromfile(prefix + ".bin",
+                                              np.uint16).tolist())
+    assert not seen[2] & {509, 510} and 511 in seen[2]
+    assert seen[0] & {509, 510}
+    assert max(seen[2] - {511}) == 508
+
+
 # --- the trace reduction ----------------------------------------------------
 
 def _ev(name, start, dur, **stats_):
@@ -522,6 +585,28 @@ def test_result_line_holds_the_contracts_keys_and_names_the_device():
     # an end-to-end metric without a value makes the run incorrect
     run.end_to_end = {"train_tokens_per_s": lambda: stats.percentile([1], 95)}
     assert run_py.result_line(run, trace=False)["correct"] is False
+
+
+def test_a_training_runs_check_gives_each_number_beside_its_limit():
+    from benchmark.harness import train_driver
+
+    mix = {"first_loss_tolerance": 0.005, "loss_must_fall_by": 1.0}
+    result = {"steps": [{"loss": 11.25}] * 3, "reference_first_loss": 11.251}
+    inside = [{"loss": 9.5}, {"loss": 9.0}]
+    problems, compared = train_driver.check(
+        result, {"train_iters": 99}, inside, mix)
+    assert problems == []
+    assert compared == {
+        "first_loss_gap": {"value": pytest.approx(0.001), "at_most": 0.005},
+        "loss_fall_in_window": {"value": 1.75, "at_least": 1.0}}
+    # a reference a bf16 step away, a loss that does not fall: both said,
+    # with the numbers that failed
+    result["reference_first_loss"] = 11.35
+    problems, compared = train_driver.check(
+        result, {"train_iters": 99}, [{"loss": 10.5}], mix)
+    assert len(problems) == 2
+    assert compared["first_loss_gap"]["value"] == pytest.approx(0.1)
+    assert compared["loss_fall_in_window"]["value"] == 0.75
 
 
 # --- what the command refuses -----------------------------------------------
