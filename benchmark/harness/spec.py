@@ -24,9 +24,10 @@ plus new entries and edits nothing that exists:
 What a PR that adds a configuration brings, all of it new:
 
     1. <path>/configs/<name>.json: the source's config.json with the cut
-       keys changed, plus "source", "reference", "reduced" {key: why},
-       "assumed" {what the source does not state: the value taken},
-       "deployment" (what the cut stands for), "program" {"flags": [...]};
+       keys changed, plus the harness's own keys: "source", "reference",
+       "reduced" {key: why}, "assumed" {what the source does not state:
+       the value taken}, "deployment" (what the cut stands for), "whole"
+       (only where a share is cut, see below), "program" {"flags": [...]};
     2. <path>/reference/<reference>.py, unless an existing one holds the
        block type: `program_flags(config, seq_length)`,
        `from_program_params(params)`, `lm_loss(weights, tokens, labels,
@@ -39,8 +40,12 @@ What a PR that adds a configuration brings, all of it new:
     4. <path>/traffic/<mix>.json for each new mix ("driver": "train",
        "serve_open" or "serve_closed", and that driver's parameters);
     5. in BENCHMARK.json: the `configs` entry, a `workloads` entry a
-       cell, and the cell's name appended to the `workloads` list of
-       every metric it reports.
+       cell, the cell's name APPENDED to the `workloads` list of every
+       metric it reports (any metric's: the tests that hold PR 23's
+       twelve and PR 34's nine hold the cells they listed in front, in
+       their order, and take a name behind them), and its own per-layer
+       entries after those that are there, each with its reader
+       <path>/layer_metrics/<reader>.py.
 
 What `reduced` may cut (the `model-configs` guide, section 4; the
 contract test holds a configuration to it from its own published/
@@ -48,35 +53,73 @@ contract test holds a configuration to it from its own published/
 deployment: the routed experts held (whichever of `num_experts`,
 `n_routed_experts`, `num_local_experts` the source has) and `vocab_size`,
 the slice of the vocabulary. Never a width: no other key ending in
-`_dim`, `_rank` or `_size`, and not the experts a token. The floors of a
-share: at least 8 experts held and a number that divides the published
-count; at least an eighth of the published vocabulary, rounded up; and,
-where either is cut, at least four layers behind the leading dense ones
-and a "deployment" that says over how many chips a layer is divided ("8
-chips share each layer: ..."). A cut of depth alone is bound by none of
-them. A sliced vocabulary is a smaller vocabulary: the corpus draws every
-id under the file's `vocab_size` (its last id ends a document), and a mix
+`_dim`, `_rank` or `_size`, and not the experts a token.
+
+With the depth go the source's other counts of layers, which are depth
+and no width. The leading dense layers are read from the first the file
+has of `first_k_dense_replace`, `num_dense_layers`, else the leading run
+of "dense" in `mlp_layer_types`, else none; a count of them may be cut
+and listed (2 -> 1). A list with one entry a layer (a value of the source
+that is a list as long as its `num_hidden_layers`: `layer_types`,
+`mlp_layer_types`, `indexer_types`) is cut with the depth: it is listed
+in `reduced`, is as long as the depth held, and is a contiguous run of
+the published list (entries i to i + depth - 1), so the kinds of layer
+keep their published order and, over whole periods, their ratio; left
+whole at a cut depth, of another length or reordered it is refused.
+
+The floors of a share: at least 8 experts held and a number that divides
+the published count; at least an eighth of the published vocabulary,
+rounded up; and, where either is cut, at least four layers behind the
+leading dense ones, a "deployment" that says over how many chips a layer
+is divided ("8 chips share each layer: ...") and "whole": {key: the
+source's value} for exactly the keys of `reduced` that cut a share (the
+experts' count, `vocab_size`), each equal to published/<name>.json's. A
+cut of depth alone is bound by none of them and states no "whole".
+"whole" is where the configuration's `program_flags(config, seq)` reads
+the router's published width and the whole vocabulary's size, beside the
+counts held under the source's own keys: the file is all it is given.
+(The rehearsed toy's reference, `olmoe`, does not read it: to the program
+as it stands that toy is a model with 8 experts behind a router 8 wide.)
+A sliced vocabulary is a smaller vocabulary: the corpus draws every id
+under the file's `vocab_size` (its last id ends a document), and a mix
 whose "corpus" gives "reserved_ids": n keeps the n ids under that one out
-of the corpus, for the configuration's own tokens (a mask token). The file
-gives the experts HELD and the program is run with that; the expert layer
-that is told which experts of a wider router it holds belongs to the PR
-that adds the configuration, with its test that the shares add up to the
-uncut reference.
+of the corpus, for the configuration's own tokens (a mask token). The
+expert layer that is told which experts of a wider router it holds
+belongs to the PR that adds the configuration, with its test that the
+shares add up to the uncut reference.
 
 A reader of a scope the new block adds is a file of its own,
 `def read(run): return named.scope_ms(run, "router")` (harness/trace/
 named.py: any `jax.named_scope`, any `pallas_call(name=)`), with its
 `per_layer` entry; a kernel's roofline share wants the kernel's cost file
-beside it. A cell lists only the rooflines whose cost files count what
-ITS kernels do: `flash_*_roofline_pct` count one causal band over S
-positions and `moe_experts_roofline_pct` micro-batch x sequence x k
-rows, so a step under another mask (block-causal, block-diagonal, a
-doubled sequence) or another grouping would read too high there. Such a
-kernel comes under a `pallas_call(name=)` of its own, with
-kernel_costs/<that name>.py and readers that name it, and the cell leaves
-the others out of its `workloads` lists.
+beside it. What the rehearsal demands of a new reader: tests/benchmark/
+test_benchmark_contract.py appends its two toys' cells to every metric a
+one-chip training cell lists, and holds their CPU lines to exact sets
+(`test_rehearsed_cell_runs_traced_through_the_unchanged_harness`,
+`test_rehearsed_share_is_correct_and_reports_every_metric`). So a reader
+gives None wherever its scope, kernel or journal field does not occur (a
+dense toy, a CPU, a parent commit), and a counter the trainer journals is
+journalled only by a model that has the mechanism (as
+`moe_load_max_over_mean` is: a step record of a model without experts
+lacks the field).
+
+A cell lists only the rooflines whose cost files count what ITS kernels
+do: `flash_*_roofline_pct` count one causal band over S positions, so a
+step under another mask (block-causal, block-diagonal, a doubled
+sequence) would read too high there. kernel_costs/moe_experts.py reads
+`intermediate_size` as ONE expert's width and `num_experts` as the
+experts whose weights move, and its reader counts micro-batch x sequence
+x k rows. So a source that gives the expert's width under
+`moe_intermediate_size`, or a share (whose rows are about held / whole of
+that, by the routing), brings a cost file and a reader of its own and
+leaves `moe_experts_roofline_pct` out of its cell's lists: it would read
+several times too high, and the driver refuses a share of a roofline
+over 105 %. Such a kernel comes under a `pallas_call(name=)` of its own,
+with kernel_costs/<that name>.py and readers that name it.
 tests/benchmark/test_benchmark_contract.py rehearses exactly such PRs on
-a copy of the tree: another block type, and one chip's share.
+a copy of the tree (another block type; one chip's share with a metric of
+its own), and states every fact it states of every configuration, cell
+or metric of BENCHMARK.json of that copy too.
 """
 
 from __future__ import annotations

@@ -14,9 +14,13 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark.harness import common, peaks, spec  # noqa: E402
 from benchmark.harness.trace import classes, named, reduce, xplane  # noqa: E402
+from test_benchmark_contract import (  # noqa: E402
+    ADDED_CELL, SHARE_CELL, added_tree,
+)
 
 BENCHMARK = os.path.join(REPO, "BENCHMARK.json")
 FIXTURES = os.path.join(REPO, "benchmark", "fixtures")
@@ -430,8 +434,11 @@ def _fake_run(cell, **fields):
     return common.Run(**base)
 
 
-def test_benchmark_json_holds_the_nine_entries_in_their_order():
-    with open(BENCHMARK) as f:
+def nine_entries_contract(spec_path):
+    """PR 34's nine, found by name in a spec, in their order, each with
+    the cells it listed in front (later PRs append entries after them
+    and cells' names to their `workloads`)."""
+    with open(spec_path) as f:
         entries = json.load(f)["per_layer"]
     ours = [m for m in entries if m["name"] in NEW]
     assert [m["name"] for m in ours] == list(NEW)
@@ -439,7 +446,7 @@ def test_benchmark_json_holds_the_nine_entries_in_their_order():
     assert entries.index(ours[0]) > [m["name"] for m in entries].index(
         "moe_load_max_over_mean")
     for m in ours:
-        assert m["workloads"] == NEW[m["name"]]
+        assert m["workloads"][:len(NEW[m["name"]])] == NEW[m["name"]]
         assert m["source"] == "device_trace"
         assert m["moves"] == "train_tokens_per_s"
         assert (m["unit"], m["better"]) in (("ms", "lower"), ("%", "higher"))
@@ -448,6 +455,46 @@ def test_benchmark_json_holds_the_nine_entries_in_their_order():
     # what they stand beside stays: only a benchmark PR retires a metric
     names = [m["name"] for m in entries]
     assert "collective_exposed_pct" in names and "other_ms_per_step" in names
+
+
+@pytest.mark.parametrize("tree", ["BENCHMARK.json", "rehearsed"])
+def test_benchmark_json_holds_the_nine_entries_in_their_order(
+        tree, tmp_path):
+    """In the real file, and as a PR that adds two configurations and
+    their cells leaves it (test_benchmark_contract.py)."""
+    if tree == "BENCHMARK.json":
+        return nine_entries_contract(BENCHMARK)
+    rehearsed = added_tree(tmp_path)
+    nine_entries_contract(rehearsed)
+    # its cells stand behind, wherever a one-chip cell reads
+    with open(rehearsed) as f:
+        grown = [m["name"] for m in json.load(f)["per_layer"]
+                 if m["name"] in NEW
+                 and m["workloads"][-2:] == [ADDED_CELL, SHARE_CELL]]
+    assert grown == [m for m in NEW if ALL[0] in NEW[m]]
+
+
+@pytest.mark.parametrize("edit, taken", [
+    (lambda cells: cells + ["a_later_cell"], True),
+    (lambda cells: ["a_later_cell"] + cells, False),
+    (lambda cells: cells[1::-1] + cells[2:], False),
+    (lambda cells: cells[1:], False),
+    (lambda cells: cells[:2] + cells[3:], False)],
+    ids=["appended", "put_in_front", "reordered", "first_taken_out",
+         "third_taken_out"])
+def test_a_later_cell_is_appended_to_the_nine_and_none_is_moved_or_taken(
+        edit, taken, tmp_path):
+    with open(BENCHMARK) as f:
+        s = json.load(f)
+    [m] = [m for m in s["per_layer"] if m["name"] == "layer_scan_ms_per_step"]
+    m["workloads"] = edit(m["workloads"])
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(s))
+    if taken:
+        nine_entries_contract(path)
+        return
+    with pytest.raises(AssertionError):
+        nine_entries_contract(path)
 
 
 @pytest.mark.parametrize("metric", list(NEW))

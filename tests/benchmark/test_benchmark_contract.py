@@ -8,7 +8,10 @@ BENCHMARK.json plus the two configurations of tests/benchmark/added/
 (`toy-falcon`, another block type, cut in nothing; `toy-moe-share8`, one
 chip's share of a deployment: depth, experts held and vocabulary cut to
 the floors) and one training cell on each, in a temporary tree, under the
-same checks and through the harness on the CPU."""
+same checks and through the harness on the CPU; every fact stated of
+every configuration, cell or metric of BENCHMARK.json is stated of that
+tree too, so an entry a later PR may write never meets a test for the
+first time in that PR."""
 
 import json
 import os
@@ -37,17 +40,20 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 # what a configuration file holds besides the source's own keys
-OURS = {"source", "reference", "reduced", "assumed", "deployment", "program"}
+OURS = {"source", "reference", "reduced", "assumed", "deployment", "program",
+        "whole"}
 # `reduced` lists cuts of depth and of the chip's share of a layer (the
 # `model-configs` guide, section 4), never a width: no key with a width's
 # ending, but for the vocabulary held, and not the experts a token
 WIDTH_ENDINGS = ("_dim", "_rank", "_size")
 NO_WIDTH = {"vocab_size"}
 WIDTHS_BY_NAME = {"num_experts_per_tok"}
-# the keys under which sources count a layer's routed experts, and its
-# leading dense layers; the floors of a share
+# the keys under which sources count a layer's routed experts and the
+# dense layers in front of the expert layers (a count, or the leading run
+# of "dense" in a list with one entry a layer); the floors of a share
 EXPERT_COUNTS = ("num_experts", "n_routed_experts", "num_local_experts")
-LEADING_DENSE = "first_k_dense_replace"
+LEADING_DENSE_COUNTS = ("first_k_dense_replace", "num_dense_layers")
+LEADING_DENSE_KINDS = "mlp_layer_types"
 LEAST_EXPERTS, LEAST_VOCABULARY_SHARE, LEAST_LAYERS = 8, 8, 4
 
 
@@ -134,13 +140,84 @@ def _published_file(spec_path, name):
     return os.path.join(PUBLISHED, name + ".json")
 
 
+def leading_dense(config):
+    """The dense layers in front of the expert layers, as the source
+    counts them: a count under one of LEADING_DENSE_COUNTS, else the
+    leading run of "dense" in a list of the layers' FFN kinds, else none."""
+    for key in LEADING_DENSE_COUNTS:
+        if key in config:
+            return config[key]
+    kinds = config.get(LEADING_DENSE_KINDS)
+    if not isinstance(kinds, list):
+        return 0
+    return next((i for i, kind in enumerate(kinds) if kind != "dense"),
+                len(kinds))
+
+
+def per_layer_lists_contract(name, config, published, reduced):
+    """A list of the source with one entry a layer (as long as the
+    source's depth: the kinds of layer, of FFN, of indexer) is cut with
+    the depth: it stands in `reduced`, is as long as the depth held, and
+    is a contiguous run of the published list, so the kinds of layer keep
+    their published order and, over whole periods, their ratio."""
+    depth, whole = config["num_hidden_layers"], published["num_hidden_layers"]
+    for key, theirs in published.items():
+        if not (isinstance(theirs, list) and len(theirs) == whole
+                and key in config):
+            continue
+        ours = config[key]
+        if key not in reduced:      # the source's own list, checked before
+            assert depth == whole, (
+                f"{name}: {key!r} is left whole, {whole} entries, at a "
+                f"depth of {depth}: a list with one entry a layer is cut "
+                f"with the depth: list it in `reduced` and keep {depth} "
+                "entries in a row of the published list")
+            continue
+        assert isinstance(ours, list) and len(ours) == depth, (
+            f"{name}: {key!r} has {len(ours)} entries at a depth of "
+            f"{depth}: a list with one entry a layer is as long as the "
+            "configuration's depth")
+        assert any(theirs[i:i + depth] == ours
+                   for i in range(whole - depth + 1)), (
+            f"{name}: {key!r} is no contiguous run of the published list: "
+            "the kinds of layer keep their published order (entries i to "
+            f"i + {depth - 1} of the source's {whole}, for one i)")
+
+
+def whole_contract(name, config, published, cut):
+    """A share's file states the published counts beside the ones held,
+    under "whole", for exactly the keys of `reduced` that cut a share
+    (`cut`): there a configuration's `program_flags` reads the router's
+    width and the whole vocabulary. A file that cuts no share has none."""
+    stated = config.get("whole")
+    if not cut:
+        assert stated is None, (
+            f"{name}: \"whole\" {stated!r} and no share cut: it states the "
+            "published counts of the keys of `reduced` that cut a share "
+            f"({list(EXPERT_COUNTS)}, 'vocab_size'), and none is listed")
+        return
+    wanted = {key: published[key] for key in cut}
+    assert stated is not None, (
+        f"{name}: {cut} cut to one chip's share and no \"whole\": the file "
+        "states the published counts beside the ones held, \"whole\": "
+        f"{json.dumps(wanted)}")
+    assert set(stated) == set(cut), (
+        f"{name}: \"whole\" names {sorted(stated)}: it names exactly the "
+        f"keys of `reduced` that cut a share, {sorted(cut)}")
+    for key in cut:
+        assert stated[key] == published[key], (
+            f"{name}: \"whole\" gives {key!r} as {stated[key]!r}, the "
+            f"source has {published[key]!r}")
+
+
 def share_contract(name, config, published, reduced):
     """One chip's share of a deployment keeps the guide's floors, read
     off the configuration's own published values: an eighth of the
     vocabulary, 8 routed experts a layer and a whole number of such
-    shares, four layers behind the leading dense ones, and the deployment
-    said in words. A configuration that cuts depth alone cuts no share,
-    and none of this binds it."""
+    shares, four layers behind the leading dense ones, the published
+    counts under "whole" and the deployment said in words. A
+    configuration that cuts depth alone cuts no share, and none of this
+    binds it."""
     cut = [k for k in reduced if k in NO_WIDTH or k in EXPERT_COUNTS]
     if "vocab_size" in reduced:
         whole = published["vocab_size"]
@@ -154,6 +231,7 @@ def share_contract(name, config, published, reduced):
             f"{name}: {held} experts held of {whole} ({key!r}): a chip "
             f"holds at least {LEAST_EXPERTS}, and a whole number of such "
             "shares makes the layer")
+    whole_contract(name, config, published, cut)
     if not cut:
         return
     deployment = config.get("deployment", "")
@@ -161,7 +239,7 @@ def share_contract(name, config, published, reduced):
         f"{name}: {cut} cut to one chip's share and no \"deployment\" that "
         "says how many chips share a layer (\"8 chips share each layer: "
         "...\")")
-    layers = config["num_hidden_layers"] - config.get(LEADING_DENSE, 0)
+    layers = config["num_hidden_layers"] - leading_dense(config)
     assert layers >= LEAST_LAYERS, (
         f"{name}: {layers} layers behind the leading dense ones: where a "
         f"share is cut, at least {LEAST_LAYERS} stay")
@@ -171,7 +249,8 @@ def configuration_contract(spec_path, name):
     """A configuration keeps what its source published: outside `reduced`
     every key it takes from the source has the source's own value, which
     published/<name>.json holds with the source's URL; what `reduced`
-    cuts keeps to the floors of share_contract()."""
+    cuts keeps to per_layer_lists_contract() and the floors of
+    share_contract()."""
     entry = {c["name"]: c for c in _load(spec_path)["configs"]}[name]
     config = _load(os.path.join(os.path.dirname(spec_path), entry["file"]))
     path = _published_file(spec_path, name)
@@ -200,6 +279,7 @@ def configuration_contract(spec_path, name):
                 f"{name}: {key!r} is {config[key]!r}, the source has "
                 f"{published['config'][key]!r}, and `reduced` does not "
                 "list it")
+    per_layer_lists_contract(name, config, published["config"], reduced)
     share_contract(name, config, published["config"], reduced)
 
 
@@ -245,6 +325,46 @@ def cell_contract(spec_path, name):
                 flags[flags.index("--serve_max_seq_len") + 1])
 
 
+def depth_alone_contract(spec_path):
+    """The configurations of a spec whose `reduced` is the depth alone
+    pass as they are and are bound by no floor of the share, at any
+    depth: each is held to its source, and would be at one layer. (One
+    that cuts more is held to share_contract() by configuration_contract()
+    like every other.) Returns {name: depth} of those it looked at."""
+    depths = {}
+    for entry in _load(spec_path)["configs"]:
+        if entry["reduced"] != ["num_hidden_layers"]:
+            continue
+        name = entry["name"]
+        configuration_contract(spec_path, name)
+        config = _load(os.path.join(os.path.dirname(spec_path),
+                                    entry["file"]))
+        published = _load(_published_file(spec_path, name))["config"]
+        share_contract(name, dict(config, num_hidden_layers=1), published,
+                       config["reduced"])
+        depths[name] = config["num_hidden_layers"]
+    return depths
+
+
+def reader_files_contract(spec_path, *others):
+    """Every reader under a layer_metrics/ of the spec's `paths` is named
+    by some metric of the spec or of `others` (specs that keep a reader
+    under test while no cell of this one reports it), and every metric
+    names one. Readers only: a file of kernel_costs/ is no reader (a
+    reader names the kernels whose costs it wants, and a cost file no
+    kernel carries the name of yet, as flash_bwd.py, is read by none)."""
+    stems = {m["name"].split(".")[0] for path in (spec_path, *others)
+             for m in _load(path)["per_layer"]}
+    on_disk = set()
+    for base in _load(spec_path)["paths"]:
+        folder = os.path.join(os.path.dirname(spec_path), base,
+                              "layer_metrics")
+        if os.path.isdir(folder):
+            on_disk |= {f[:-3] for f in os.listdir(folder)
+                        if f.endswith(".py")}
+    assert stems == on_disk
+
+
 # --- BENCHMARK.json and the candidates under them ---------------------------
 
 def test_benchmark_json_has_the_contracts_keys_and_names():
@@ -274,15 +394,7 @@ def test_serving_cells_kept_ready_find_their_files(cell_name):
 
 
 def test_every_reader_file_is_named_by_some_metric():
-    # readers only: a file of benchmark/kernel_costs/ is no reader (a
-    # reader names the kernels whose costs it wants, and a cost file no
-    # kernel carries the name of yet, as flash_bwd.py, is read by none)
-    stems = {m["name"].split(".")[0]
-             for path in (BENCHMARK, CANDIDATES, TOY)
-             for m in _load(path)["per_layer"]}
-    on_disk = {f[:-3] for f in os.listdir(os.path.join(
-        REPO, "benchmark", "layer_metrics")) if f.endswith(".py")}
-    assert stems == on_disk
+    reader_files_contract(BENCHMARK, CANDIDATES, TOY)
 
 
 # --- the rehearsal: a PR that adds a configuration and a cell ---------------
@@ -293,11 +405,18 @@ ADDED_CELL = "train_toyfalcon_rehearsed"
 # has, its depth, experts held and vocabulary each cut to the floor
 SHARE_CONFIG = "toy-moe-share8"
 SHARE_CELL = "train_toymoe_share8_rehearsed"
-# (configuration, cell, traffic mix, the configuration's why)
+# what a configuration PR brings besides: a per-layer metric of its own
+# that lists its cell alone, its reader under added/layer_metrics/
+SHARE_METRIC = {
+    "name": "moe_load_worst_step", "unit": "x", "better": "lower",
+    "source": "program_counter", "layer": "mlp",
+    "moves": "train_tokens_per_s", "workloads": [SHARE_CELL]}
+# (configuration, cell, traffic mix, the configuration's why, its metrics)
 ADDED = [
-    (ADDED_CONFIG, ADDED_CELL, "added_train", "another block type, added"),
+    (ADDED_CONFIG, ADDED_CELL, "added_train", "another block type, added",
+     []),
     (SHARE_CONFIG, SHARE_CELL, "added_share_train",
-     "one chip's share of 8 that hold each layer, added"),
+     "one chip's share of 8 that hold each layer, added", [SHARE_METRIC]),
 ]
 
 
@@ -305,9 +424,10 @@ def added_tree(root, published=True):
     """A copy of what the benchmark is made of with what such PRs bring:
     files (tests/benchmark/added: each configuration, the reference it
     names unless the benchmark has it, its published values, a traffic
-    mix) and entries (a configuration with the `reduced` its file gives,
-    a cell, the cell's name on each metric it reports). Nothing that
-    exists is edited. Returns the spec's path."""
+    mix, a reader) and entries (a configuration with the `reduced` its
+    file gives, a cell, the cell's name on each metric it reports, the
+    per-layer metrics of its own after those that are there). Nothing
+    that exists is edited. Returns the spec's path."""
     os.makedirs(root / "tests")
     os.symlink(os.path.join(REPO, "benchmark"), root / "benchmark")
     shutil.copytree(os.path.join(REPO, "tests", "benchmark"),
@@ -323,7 +443,7 @@ def added_tree(root, published=True):
     alike = {w["name"] for w in s["workloads"]
              if w["chips"] == 1 and spec.Cell(
                  BENCHMARK, w["name"]).traffic["driver"] == "train"}
-    for config, cell, traffic, why in ADDED:
+    for config, cell, traffic, why, own in ADDED:
         file = "tests/benchmark/" + config + ".json"
         held = _load(root / file)
         s["configs"].append({
@@ -335,6 +455,8 @@ def added_tree(root, published=True):
         for m in s["end_to_end"] + s["per_layer"]:
             if alike & set(m.get("workloads", [])):
                 m["workloads"].append(cell)
+        s["per_layer"] += [dict(m, workloads=list(m["workloads"]))
+                           for m in own]
     path = root / "BENCHMARK.json"
     with open(path, "w") as f:
         json.dump(s, f)
@@ -353,6 +475,9 @@ def test_rehearsed_pr_keeps_the_files_contract(added):
     assert reduced[ADDED_CONFIG] == []
     assert reduced[SHARE_CONFIG] == ["num_hidden_layers", "num_experts",
                                      "vocab_size"]
+    # the share's own metric stands last and lists its cell alone
+    assert _load(added)["per_layer"][-1] == SHARE_METRIC
+    assert SHARE_METRIC["name"] not in _names(BENCHMARK, "per_layer")
 
 
 @pytest.mark.parametrize("name", _names(BENCHMARK, "configs")
@@ -367,41 +492,93 @@ def test_rehearsed_pr_has_every_cell_find_its_files(added, cell_name):
     cell_contract(added, cell_name)
 
 
+def test_rehearsed_pr_has_every_reader_file_named_by_some_metric(
+        added, tmp_path):
+    """The rehearsed tree keeps two readers of its own beside the
+    benchmark's (tests/benchmark/added/layer_metrics): the share's, and
+    the one the serving rehearsal's spec names."""
+    from test_benchmark_rehearse_serve import _added_spec
+
+    own = set(os.listdir(os.path.join(ADDED_DIR, "layer_metrics")))
+    assert own == {SHARE_METRIC["name"] + ".py", "requests_done_count.py"}
+    assert own <= set(os.listdir(os.path.join(
+        os.path.dirname(added), "tests", "benchmark", "layer_metrics")))
+    reader_files_contract(added, CANDIDATES, _added_spec(tmp_path))
+
+
 # --- what `reduced` may cut, and how far ------------------------------------
 
-def _width(key):
-    """The share-cut toy with `key`, a width, listed as reduced too (and
-    its value halved, as such a cut would)."""
-    def edit(entry, config, published):
-        config[key] = published[key] = published.get(key, 32)
-        config[key] //= 2
+def _cut(key, published, held, depth=None):
+    """The share-cut toy with `key` listed as reduced too: the source
+    has `published`, the file holds `held` (and `depth` layers)."""
+    def edit(entry, config, source):
+        source[key], config[key] = published, held
         config["reduced"][key] = "cut"
         entry["reduced"].append(key)
+        if depth is not None:
+            config["num_hidden_layers"] = depth
+    return edit
+
+
+def _width(key):
+    """... with `key`, a width, listed (its value halved, as such a cut
+    would)."""
+    def edit(entry, config, source):
+        value = source.get(key, 32)
+        _cut(key, value, value // 2)(entry, config, source)
     return edit
 
 
 def _set(config=(), published=()):
+    """... with these values, "whole" kept in step with a published count
+    it states."""
     def edit(entry, held, source):
         held.update(config)
         source.update(published)
+        whole = held.get("whole", {})
+        whole.update({k: v for k, v in dict(published).items() if k in whole})
     return edit
 
 
-def _no_deployment(entry, config, published):
-    del config["deployment"]
+def _without(key):
+    def edit(entry, config, source):
+        del config[key]
+    return edit
 
 
-def _vocabulary_alone(entry, config, published):
-    """Only the vocabulary is sliced, at depth 3: the share's floors bind
-    whichever part of the share is cut."""
-    config.update(num_hidden_layers=3, num_experts=64)
-    published["num_hidden_layers"] = 3
-    for key in ("num_hidden_layers", "num_experts"):
-        del config["reduced"][key]
-        entry["reduced"].remove(key)
+def _uncut(keys, depth):
+    """... at the source's own depth, `depth`, and with `keys` of its
+    share held whole again, all of them unlisted."""
+    def edit(entry, config, source):
+        source["num_hidden_layers"] = depth
+        for key in ("num_hidden_layers", *keys):
+            config[key] = source[key]
+            del config["reduced"][key]
+            entry["reduced"].remove(key)
+            config["whole"].pop(key, None)
+    return edit
+
+
+def _then(*edits):
+    def edit(entry, config, source):
+        for one in edits:
+            one(entry, config, source)
+    return edit
+
+
+def _depth_alone(depth):
+    """... as a cut of depth alone: 16 layers -> `depth`, every expert
+    and the whole vocabulary held, no "whole"."""
+    return _then(_uncut(["num_experts", "vocab_size"], depth),
+                 _without("whole"), _cut("num_hidden_layers", 16, depth))
 
 
 NEVER_A_WIDTH = "a width is never cut; the vocabulary held is no width"
+BEHIND_3 = "3 layers behind the leading dense ones"
+# lists with one entry a layer of the toy's 16: the kinds of layer in
+# periods of four, and two dense FFNs in front of the expert FFNs
+KINDS = ["conv", "conv", "full_attention", "conv"] * 4
+FFNS = ["dense"] * 2 + ["sparse"] * 14
 SHARE_CASES = {
     # the toy as it stands: every floor met exactly
     "as_added": (None, None),
@@ -424,22 +601,79 @@ SHARE_CASES = {
     "12_experts_held_of_128": (
         _set({"num_experts": 12}, {"num_experts": 128}),
         "12 experts held of 128"),
-    "share_without_deployment": (_no_deployment, "no \"deployment\""),
+    "share_without_deployment": (_without("deployment"),
+                                 "no \"deployment\""),
     "deployment_without_a_count": (
         _set({"deployment": "an expert-parallel job"}),
         "how many chips share a layer"),
-    "share_at_depth_3": (_set({"num_hidden_layers": 3}),
-                         "3 layers behind the leading dense ones"),
+    "share_at_depth_3": (_set({"num_hidden_layers": 3}), BEHIND_3),
     "share_at_depth_5_behind_2_dense": (
         _set({"num_hidden_layers": 5, "first_k_dense_replace": 2},
-             {"first_k_dense_replace": 2}),
-        "3 layers behind the leading dense ones"),
+             {"first_k_dense_replace": 2}), BEHIND_3),
     "share_at_depth_6_behind_2_dense": (
         _set({"num_hidden_layers": 6, "first_k_dense_replace": 2},
              {"first_k_dense_replace": 2}), None),
-    "vocabulary_alone_at_depth_3": (
-        _vocabulary_alone, "3 layers behind the leading dense ones"),
+    # only the vocabulary is sliced, at depth 3: the share's floors bind
+    # whichever part of the share is cut
+    "vocabulary_alone_at_depth_3": (_uncut(["num_experts"], 3), BEHIND_3),
+    # the leading dense layers under the other names sources give them
+    "share_at_depth_5_behind_num_dense_layers_1": (
+        _set({"num_hidden_layers": 5, "num_dense_layers": 1},
+             {"num_dense_layers": 1}), None),
+    "share_at_depth_4_behind_num_dense_layers_1": (
+        _set({"num_dense_layers": 1}, {"num_dense_layers": 1}), BEHIND_3),
+    # a count of layers is depth, no width: `reduced` takes it
+    "num_dense_layers_cut_with_the_depth": (
+        _cut("num_dense_layers", 2, 1, depth=5), None),
+    "two_dense_ffns_then_four_sparse": (
+        _cut("mlp_layer_types", FFNS, FFNS[:6], depth=6), None),
+    "two_dense_ffns_then_three_sparse": (
+        _cut("mlp_layer_types", FFNS, FFNS[:5], depth=5), BEHIND_3),
+    # a list with one entry a layer is cut with the depth, to a
+    # contiguous run of the published list
+    "layer_types_entries_1_to_5": (
+        _cut("layer_types", KINDS, KINDS[1:6], depth=5), None),
+    "layer_types_reordered": (
+        _cut("layer_types", KINDS, sorted(KINDS[1:6]), depth=5),
+        "'layer_types' is no contiguous run of the published list"),
+    "layer_types_of_the_wrong_length": (
+        _cut("layer_types", KINDS, KINDS[1:6]),
+        "'layer_types' has 5 entries at a depth of 4"),
+    "layer_types_left_whole_at_a_cut_depth": (
+        _set({"layer_types": KINDS}, {"layer_types": KINDS}),
+        "'layer_types' is left whole, 16 entries, at a depth of 4"),
+    # the published counts of the share, and only where a share is cut
+    "whole_missing": (_without("whole"), "no \"whole\": the file states"),
+    "whole_not_the_sources": (
+        _set({"whole": {"num_experts": 32, "vocab_size": 4096}}),
+        "\"whole\" gives 'num_experts' as 32, the source has 64"),
+    "whole_with_a_key_too_many": (
+        _set({"whole": {"num_experts": 64, "vocab_size": 4096,
+                        "num_hidden_layers": 16}}),
+        "names exactly the keys of `reduced` that cut a share"),
+    "depth_alone_at_depth_3": (_depth_alone(3), None),
+    "depth_alone_with_whole": (
+        _then(_depth_alone(3), _set({"whole": {}})),
+        "\"whole\" {} and no share cut"),
 }
+
+
+def _edited_share_tree(root, edit):
+    """The rehearsed tree under `root` with the share-cut toy's entry,
+    file and published values edited; returns the spec's path."""
+    path = added_tree(root)
+    s = _load(path)
+    entry = {c["name"]: c for c in s["configs"]}[SHARE_CONFIG]
+    files = [root / entry["file"],
+             root / "tests" / "benchmark" / "published"
+             / (SHARE_CONFIG + ".json")]
+    config, published = (_load(f) for f in files)
+    if edit is not None:
+        edit(entry, config, published["config"])
+    for file, value in zip(files + [path], (config, published, s)):
+        with open(file, "w") as f:
+            json.dump(value, f)
+    return path
 
 
 @pytest.mark.parametrize("case", SHARE_CASES)
@@ -448,18 +682,7 @@ def test_a_share_keeps_the_floors_and_a_width_is_never_cut(case, tmp_path):
     takes, on the rehearsed tree with the share-cut toy edited; what is
     expected comes from the toy's own published/<name>.json."""
     edit, refusal = SHARE_CASES[case]
-    path = added_tree(tmp_path)
-    s = _load(path)
-    entry = {c["name"]: c for c in s["configs"]}[SHARE_CONFIG]
-    files = [tmp_path / entry["file"],
-             tmp_path / "tests" / "benchmark" / "published"
-             / (SHARE_CONFIG + ".json")]
-    config, published = (_load(f) for f in files)
-    if edit is not None:
-        edit(entry, config, published["config"])
-    for file, value in zip(files + [path], (config, published, s)):
-        with open(file, "w") as f:
-            json.dump(value, f)
+    path = _edited_share_tree(tmp_path, edit)
     if refusal is None:
         file_contract(path)
         configuration_contract(path, SHARE_CONFIG)
@@ -469,18 +692,33 @@ def test_a_share_keeps_the_floors_and_a_width_is_never_cut(case, tmp_path):
         configuration_contract(path, SHARE_CONFIG)
 
 
-def test_a_cut_of_depth_alone_is_bound_by_no_floor_of_the_share():
-    """The accepted configurations and the candidate cut depth only: one
-    layer, two layers, no `deployment` that counts chips, and they pass
-    as they are."""
-    depths = []
-    for path in (BENCHMARK, CANDIDATES):
-        for entry in _load(path)["configs"]:
-            assert entry["reduced"] == ["num_hidden_layers"]
-            configuration_contract(path, entry["name"])
-            depths.append(_load(os.path.join(os.path.dirname(
-                path), entry["file"]))["num_hidden_layers"])
-    assert min(depths) < LEAST_LAYERS
+@pytest.mark.parametrize("tree", ["BENCHMARK.json", "candidates.json",
+                                  "rehearsed"])
+def test_a_cut_of_depth_alone_is_bound_by_no_floor_of_the_share(tree, added):
+    """One layer, two layers, eight: the configurations that cut depth
+    alone pass as they are, in the real file, the candidates and the
+    rehearsed tree (whose other two cut nothing, and a share)."""
+    depths = depth_alone_contract({"BENCHMARK.json": BENCHMARK,
+                                   "candidates.json": CANDIDATES,
+                                   "rehearsed": added}[tree])
+    assert depths, "no configuration here cuts depth alone"
+    assert not {ADDED_CONFIG, SHARE_CONFIG} & set(depths)
+
+
+@pytest.mark.parametrize("broken, refusal", [
+    (None, None), (_set({"hidden_size": 96}), "'hidden_size' is 96")],
+    ids=["kept", "a_width_drifts"])
+def test_a_cut_of_depth_alone_is_still_held_to_its_source(
+        broken, refusal, tmp_path):
+    """The toy as a cut of depth alone, under the share's floor of four:
+    taken as it is, refused where it breaks configuration_contract."""
+    path = _edited_share_tree(tmp_path, _then(
+        _depth_alone(3), *([] if broken is None else [broken])))
+    if refusal is None:
+        assert depth_alone_contract(path)[SHARE_CONFIG] == 3 < LEAST_LAYERS
+        return
+    with pytest.raises(AssertionError, match=refusal):
+        depth_alone_contract(path)
 
 
 def test_a_configuration_without_published_values_is_told_which_file_to_add(
@@ -560,21 +798,31 @@ def test_rehearsed_share_is_correct_and_reports_every_metric(
         m["name"] for m in cell.end_to_end()} == {"train_tokens_per_s",
                                                   "setup_s"}
     # asked of it: every per-layer metric a one-chip training cell
-    # reports, the expert block's among them
+    # reports, the expert block's among them, and the one of its own
     asked = {m["name"] for m in cell.per_layer()}
-    assert asked == {
+    own = SHARE_METRIC["name"]
+    accepted = {
         m["name"] for name in _names(BENCHMARK, "workloads")
         if spec.Cell(BENCHMARK, name).chips == 1
         for m in spec.Cell(BENCHMARK, name).per_layer()}
+    assert asked == accepted | {own} and own not in accepted
     assert {"moe_experts_roofline_pct", "flash_bwd_roofline_pct",
             "grad_accumulate_ms_per_step"} <= asked
+    # no other cell is asked for it, the other rehearsed one neither
+    assert [name for name in _names(added, "workloads") if own in {
+        m["name"] for m in spec.Cell(added, name).per_layer()}] == [
+            SHARE_CELL]
     # in the line: what the program's spans, counters and journal give
-    # on any backend; the device readers have no device plane to read on
-    # a CPU, say nothing, and none raises
+    # on any backend (the field of the `step` records that a model with
+    # experts journals, read by the accepted reader and by the cell's
+    # own); the device readers have no device plane to read on a CPU,
+    # say nothing, and none raises
     assert set(traced["metrics"]) == {
         "train_step_ms_p50", "train_data_wait_pct", "train_host_ms_per_step",
-        "step_hbm_gb", "step_temp_hbm_gb", "moe_load_max_over_mean"}
-    assert 1.0 <= traced["metrics"]["moe_load_max_over_mean"]["value"] <= 8
+        "step_hbm_gb", "step_temp_hbm_gb", "moe_load_max_over_mean", own}
+    assert traced["metrics"][own]["unit"] == SHARE_METRIC["unit"]
+    assert 1.0 <= traced["metrics"]["moe_load_max_over_mean"]["value"] <= (
+        traced["metrics"][own]["value"]) <= 8
     # the reference is the existing MoE block's, given the sliced sizes
     assert result["steps"][0]["ntokens"] == 2 * 128
     assert abs(result["steps"][0]["loss"]

@@ -17,11 +17,13 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark.harness import common, peaks, spec  # noqa: E402
 from benchmark.harness.trace import (  # noqa: E402
     kernel_cost, named, reduce, xplane,
 )
+from test_benchmark_contract import added_tree  # noqa: E402
 
 BENCHMARK = os.path.join(REPO, "BENCHMARK.json")
 FIXTURES = os.path.join(REPO, "benchmark", "fixtures")
@@ -455,10 +457,10 @@ def _fake_run(cell, **fields):
     return common.Run(**base)
 
 
-def test_benchmark_json_holds_the_twelve_entries_in_their_order():
-    """PR 23's twelve, found by name (later PRs append entries after them
-    and cells' names to their `workloads`)."""
-    with open(BENCHMARK) as f:
+def twelve_entries_contract(spec_path):
+    """PR 23's twelve, found by name in a spec (later PRs append entries
+    after them and cells' names to their `workloads`)."""
+    with open(spec_path) as f:
         entries = json.load(f)["per_layer"]
     ours = [m for m in entries if m["name"] in NEW]
     assert [m["name"] for m in ours] == NEW
@@ -471,6 +473,15 @@ def test_benchmark_json_holds_the_twelve_entries_in_their_order():
     # one number is measured once: the custom calls' time is the two named
     # sums (flash_fwd_ms_per_step + flash_bwd_ms_per_step)
     assert "kernel_ms_per_step" not in [m["name"] for m in entries]
+
+
+@pytest.mark.parametrize("tree", ["BENCHMARK.json", "rehearsed"])
+def test_benchmark_json_holds_the_twelve_entries_in_their_order(
+        tree, tmp_path):
+    """In the real file, and as a PR that adds two configurations and
+    their cells leaves it (test_benchmark_contract.py)."""
+    twelve_entries_contract(BENCHMARK if tree == "BENCHMARK.json"
+                            else added_tree(tmp_path))
 
 
 @pytest.mark.parametrize("metric", NEW)
