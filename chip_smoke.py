@@ -547,7 +547,8 @@ def phase_train(size: dict, work: str, seed: int, rehearse: bool) -> dict:
     _check(recompiles == 0, f"{recompiles} compiles after the first step: "
            + json.dumps([s.get("compiles", 0) for s in steps]))
     kernels = _dumped(ir, "train_step")
-    # forward + the two backward kernels, in the one scanned layer body
+    # forward, the backward's row statistics and the fused backward
+    # kernel, in the one scanned layer body
     _check_kernels_in_step(kernels, dev, "the train step", 3)
     _check("fused fwd+bwd (custom vjp)" in log,
            "trainer did not announce the flash gradient path")

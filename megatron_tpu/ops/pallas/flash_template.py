@@ -32,9 +32,21 @@ instantiation of the one template in this module, with four knobs:
                 |                         | a row; named SAVED_RESIDUAL so a
                 |                         | layer's checkpoint keeps them and
                 |                         | the forward runs once), bwd
-                |                         | recomputes p from (q, k, lse), one
-                |                         | kernel accumulates dq over kv
-                |                         | blocks, one dk/dv over q blocks
+                |                         | recomputes p from (q, k, lse): ONE
+                |                         | kernel visits each live tile pair
+                |                         | once and sums dk/dv over q blocks
+                |                         | in a tile's scratch and dq over kv
+                |                         | blocks in a scratch of the head's
+                |                         | whole sequence (five matmuls a
+                |                         | pair). A sequence whose dq does
+                |                         | not fit VMEM (`fused_bwd_fits`:
+                |                         | beyond 64k rows of 128 bf16) runs
+                |                         | the split pair instead: one kernel
+                |                         | for dq, one for dk/dv (seven).
+                |                         | Before either, `flash_bwd_stats`
+                |                         | spreads lse and delta = rowsum(do
+                |                         | * o) over the lanes of the one
+                |                         | operand the kernels read both from
 
 Online softmax (running max m, running sum l, unnormalized acc in VMEM
 scratch persisting across the sequential kv steps) is shared by every
@@ -48,7 +60,8 @@ maps clamp a skipped step to the nearest live tile of its row
 (`_inner_tile_map`), so their dead steps move nothing; the
 decode kernels' maps do not, and load the tiles they skip.
 
-Precision: the training kernels (fwd, dq, dk/dv) hand the MXU their
+Precision: the training kernels (fwd; the fused bwd and the split dq,
+dk/dv, which share one tile function) hand the MXU their
 operands in the dtype they arrive in — q, k, v, do as given, the
 probabilities and ds cast to that dtype right before their matmuls — and
 every matmul accumulates in float32 (`_dot`). The softmax statistics (m,
@@ -145,15 +158,25 @@ def supported(q_len: int, kv_len: int, block_q: int, block_k: int) -> bool:
             and kv_len % block_k == 0)
 
 
-# Tiles of the three training kernels, from a sweep on the v5e (PR 24;
-# bf16 [1,32,S,128], window 4096, ms a call at block_q x block_k with
-# dead steps re-naming the held block; PERF.md section 6 has all of it):
+# Tiles of the training kernels, from sweeps on the v5e (PR 24, and PR 40
+# for the fused backward; bf16 [1,32,S,128], window 4096, ms a call at
+# block_q x block_k with dead steps re-naming the held block; PERF.md
+# section 6 has all of it):
 #
 #   S 4096         256x256  512x512  512x1024  1024x512  1024x1024
 #   flash_fwd        5.62     2.50     2.03      2.67      1.79
 #   flash_bwd_dq     4.20     2.28     2.10      2.08      1.91
 #   flash_bwd_dkv    6.75     2.60     2.16      2.22      2.11
+#   flash_bwd                 2.82     2.69      2.69      2.56
+#   (dq + dkv, PR 40)         4.47     4.20      4.21      3.97
 #
+# The fused `flash_bwd` does the work of the two rows above it in one
+# visit of each tile pair: five matmuls and one vector pass where the pair
+# run seven and two (the smaller tiles were read with dv's product first,
+# which reads 2.62 at 1024x1024). At 1024x1024 a causal sequence of 4096
+# computes 10 whole tiles a head where 8.2 tiles' worth of pairs are
+# visible, so the MXU alone needs 2.18 ms a call: the fused kernel is at
+# 85 % of that.
 # 1024x1024 is also the fastest of the nine at S 2048, 8192 and 16384 for
 # every kernel (16384: 13.3 / 13.7 / 17.1 ms against 52.5 / 42.6 / 68.2 at
 # 256x256) and at [8,16,4096,128]; at S 1024 every tile of 512 or more
@@ -272,7 +295,8 @@ def _inner_tile_map(live_tiles, n: int):
     (b, h, outer, inner) whose scalar-prefetch operand is the position
     offset. `live_tiles(outer, delta=)` is the (first, last) inner tile
     the block-skip admits (masks.prefill_live_kv_tiles for the forward
-    and dq grids, prefill_live_q_tiles for dk/dv): a step outside it
+    and dq grids, prefill_live_q_tiles for the fused backward's and
+    dk/dv's): a step outside it
     names the nearest live tile instead of its own, so the pipeline sees
     the block it already holds and issues no DMA. The second clip keeps a
     row with no live tile at all inside the grid of n tiles."""
@@ -282,15 +306,18 @@ def _inner_tile_map(live_tiles, n: int):
     return index
 
 
-def _compiler_params(vmem_bytes: int):
-    """Three parallel axes and the sequential reduction axis; the scoped
-    VMEM limit raised only when the tiles' footprint needs it."""
+def _compiler_params(vmem_bytes: int, outer: str = "parallel"):
+    """Batch and heads parallel, the inner sequence axis the sequential
+    reduction; the outer sequence axis parallel too unless a kernel sums
+    over it as well (`outer="arbitrary"`: the fused backward's dq; on a
+    megacore part only B x H is then left to split across the two cores,
+    which the single-core v5e this was measured on cannot show). The
+    scoped VMEM limit is raised only when the footprint needs it."""
     limit = None
     if vmem_bytes > _DEFAULT_SCOPED_VMEM:
         limit = min(vmem_bytes, _MAX_SCOPED_VMEM)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"),
+        dimension_semantics=("parallel", "parallel", outer, "arbitrary"),
         vmem_limit_bytes=limit)
 
 
@@ -354,6 +381,81 @@ def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
 # ---------------------------------------------------------------------------
 
 
+def _bwd_tile(off, qi, ki, q, k, v, do, stats_ref,
+              *, scale: float, causal: bool, window: Optional[int],
+              block_q: int, block_k: int):
+    """(p, ds), [BQ, BK] float32 each, of one live tile pair: the
+    probabilities recomputed from the log-sum-exp and the gradient of
+    the scores (before the 1/sqrt(d), which the kernels put once on
+    their float32 sums). Two matmuls and the whole of the tile's vector
+    work; every backward kernel forms them by this one function."""
+    lse, delta = _row_stats(stats_ref)                   # [BQ, 1] each
+    s = _dot(q, k, _NT) * scale
+    q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k, off)
+    mask = masks.visible(q_pos, k_pos, causal=causal, window=window)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)           # softmax probs
+    dp = _dot(do, v, _NT)                                # [BQ, BK]
+    return p, p * (dp - delta)
+
+
+def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                *, scale: float, causal: bool, window: Optional[int],
+                block_q: int, block_k: int):
+    """The fused backward: grid (b, h, ki, qi), q innermost. Every live
+    tile pair is visited once and gives all three gradients from one p
+    and one ds (five matmuls). dk and dv of the kv tile sum over the
+    inner axis in [BK, D] scratch; dq sums over the OUTER axis, so its
+    float32 accumulator holds the head's whole sequence, [Sq/BQ, BQ, D],
+    and the dq output block is the head's whole [Sq, D]: q tile qi's rows
+    are zeroed on the first kv tile's pass and scaled, cast and written
+    on the last one's. Each sum takes its terms in the order the split
+    pair takes them (ascending tiles)."""
+    ki = pl.program_id(2)
+    qi = pl.program_id(3)
+    nk = pl.num_programs(2)
+    nq = pl.num_programs(3)
+    off = off_ref[0]
+
+    @pl.when(qi == 0)
+    def _init_kv():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(ki == 0)
+    def _init_q():
+        dq_scr[qi] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
+
+    @pl.when(masks.prefill_block_live(qi, ki, block_q, block_k,
+                                      causal=causal, window=window,
+                                      delta=off))
+    def _compute():
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        do = do_ref[0, 0]
+        p, ds = _bwd_tile(off, qi, ki, q, k, v_ref[0, 0], do, stats_ref,
+                          scale=scale, causal=causal, window=window,
+                          block_q=block_q, block_k=block_k)
+        # dq's product first: it takes ds as it lies, while the other two
+        # wait for a transpose of ds and of p (2.56 ms a call against 2.62
+        # with dv's first and 2.65 with dq's last, at the swept shape)
+        ds = ds.astype(q.dtype)
+        dq_scr[qi] += _dot(ds, k, _NN)
+        dk_scr[:] += _dot(ds, q, _TN)
+        dv_scr[:] += _dot(p.astype(do.dtype), do, _TN)
+
+    @pl.when(qi == nq - 1)
+    def _emit_kv():
+        # the 1/sqrt(d) of the scores, once on the float32 sum
+        dk_ref[0, 0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(ki == nk - 1)
+    def _emit_q():
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_ref[0, 0, rows, :] = (dq_scr[qi] * scale).astype(dq_ref.dtype)
+
+
 def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                dq_ref, dq_scr,
                *, scale: float, causal: bool, window: Optional[int],
@@ -371,18 +473,11 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                                       causal=causal, window=window,
                                       delta=off))
     def _compute():
-        q = q_ref[0, 0]
         k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse, delta = _row_stats(stats_ref)               # [BQ, 1] each
-
-        s = _dot(q, k, _NT) * scale
-        q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k, off)
-        mask = masks.visible(q_pos, k_pos, causal=causal, window=window)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)       # softmax probs
-        dp = _dot(do, v, _NT)                            # [BQ, BK]
-        ds = p * (dp - delta)
+        _, ds = _bwd_tile(off, qi, ki, q_ref[0, 0], k, v_ref[0, 0],
+                          do_ref[0, 0], stats_ref, scale=scale,
+                          causal=causal, window=window, block_q=block_q,
+                          block_k=block_k)
         dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
 
     @pl.when(ki == nk - 1)
@@ -410,18 +505,11 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                                       delta=off))
     def _compute():
         q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse, delta = _row_stats(stats_ref)
-
-        s = _dot(q, k, _NT) * scale
-        q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k, off)
-        mask = masks.visible(q_pos, k_pos, causal=causal, window=window)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)       # [BQ, BK]
+        p, ds = _bwd_tile(off, qi, ki, q, k_ref[0, 0], v_ref[0, 0], do,
+                          stats_ref, scale=scale, causal=causal,
+                          window=window, block_q=block_q, block_k=block_k)
         dv_scr[:] += _dot(p.astype(do.dtype), do, _TN)
-        dp = _dot(do, v, _NT)
-        ds = p * (dp - delta)
         dk_scr[:] += _dot(ds.astype(q.dtype), q, _TN)
 
     @pl.when(qi == nq - 1)
@@ -442,29 +530,88 @@ def _bwd_vmem_bytes(block_q, block_k, D, item):
             + 6 * block_q * block_k * 4)
 
 
+def _fused_bwd_vmem_bytes(sq, block_q, block_k, D, item):
+    """The tiles above (dk's and dv's output tiles and accumulators
+    among them) and dq of a head's whole sequence: the float32
+    accumulator and the double-buffered output block."""
+    return (_bwd_vmem_bytes(block_q, block_k, D, item)
+            + sq * D * 4 + 2 * sq * D * item)
+
+
+def fused_bwd_fits(sq: int, d: int, dtype, block_q: int,
+                   block_k: int) -> bool:
+    """Which backward a shape takes. One algorithm, two footprints: the
+    fused kernel keeps dq of a head's whole sequence in VMEM, which fits
+    beside the tiles up to 64k rows of 128 bf16; a longer sequence runs
+    the split pair, whose footprint does not grow with the sequence."""
+    return _fused_bwd_vmem_bytes(
+        sq, block_q, block_k, d,
+        jnp.dtype(dtype).itemsize) <= _MAX_SCOPED_VMEM
+
+
 # The two per-row statistics of the backward kernels, lse and
 # delta = rowsum(do * o), ride in ONE float32 operand [B,H,Sq,128]: a
 # [..., 1]-shaped operand is tiled to 128 lanes anyway, so lanes
 # [0, _DELTA_LANE) hold lse and the rest hold delta. Spreading a row's
-# number over the lanes is the slowest pass around the kernels (0.36 ms
-# for [1,32,4096,128] on a v5e, a quarter of the HBM's rate: PERF.md
-# section 6, PR 36); packed, the two statistics cost one such pass.
+# number over the lanes is a pass of its own around the kernels (PERF.md
+# section 6, PR 36); packed, the two statistics cost one such pass: the
+# Pallas call `flash_bwd_stats` (0.43 ms for [8,16,4096,128] on a v5e,
+# three quarters of the HBM's rate and what XLA's broadcast-and-select
+# took for the same bits: PERF.md section 6, PR 40). delta itself stays
+# with XLA, which sums it in the epilogue of the matmul that makes `do`.
 _DELTA_LANE = 64
 
 
-def _bwd_stats(lse, o, do):
+def _stats_kernel(lse_ref, delta_ref, stats_ref):
+    """Grid (b, q tile, h): the q tile's two statistics of every head,
+    [H, BQ] each with the rows in lanes (fetched once a q tile), spread
+    into head h's [BQ, 128]: the two rows are laid over the sublanes,
+    lse on the first _DELTA_LANE and delta on the rest, and transposed."""
+    h = pl.program_id(2)
+    block_q = stats_ref.shape[2]
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (128, block_q), 0)
+    rows = jnp.where(
+        sublane < _DELTA_LANE,
+        jnp.broadcast_to(lse_ref[0, pl.ds(h, 1), :], (128, block_q)),
+        jnp.broadcast_to(delta_ref[0, pl.ds(h, 1), :], (128, block_q)))
+    stats_ref[0, 0] = rows.T
+
+
+def _bwd_stats(lse, o, do, block_q):
     """lse [B,H,Sq] and rowsum(do * o), float32, as the backward kernels
     read them (`_row_stats`): [B,H,Sq,128]."""
+    B, H, Sq = lse.shape
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    shape = lse.shape + (128,)
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
-    return jnp.where(lane < _DELTA_LANE, lse[..., None], delta[..., None])
+    rows = pl.BlockSpec((1, H, block_q), lambda b, qi, h: (b, 0, qi))
+    return _named_pallas_call(
+        "flash_bwd_stats", _stats_kernel,
+        grid=(B, Sq // block_q, H),
+        in_specs=[rows, rows],
+        out_specs=pl.BlockSpec((1, 1, block_q, 128),
+                               lambda b, qi, h: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, 128), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3),
+        interpret=_interpret(),
+    )(lse, delta)
 
 
 def _row_stats(stats_ref):
     """(lse, delta), [BQ, 1] each, of a backward kernel's q tile."""
     stats = stats_ref[0, 0]
     return stats[:, 0:1], stats[:, _DELTA_LANE:_DELTA_LANE + 1]
+
+
+def _bwd_in_specs(block_q, block_k, D, q_map, kv_map):
+    """q, k, v, do and the row statistics, as every backward kernel
+    takes them behind the scalar-prefetch offset."""
+    return [
+        pl.BlockSpec((1, 1, block_q, D), q_map),
+        pl.BlockSpec((1, 1, block_k, D), kv_map),
+        pl.BlockSpec((1, 1, block_k, D), kv_map),
+        pl.BlockSpec((1, 1, block_q, D), q_map),
+        pl.BlockSpec((1, 1, block_q, 128), q_map),
+    ]
 
 
 def _bwd_dq(q, k, v, do, stats, scale, causal, window, block_q,
@@ -485,13 +632,7 @@ def _bwd_dq(q, k, v, do, stats, scale, causal, window, block_q,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, Sq // block_q, nk),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_q, 128), q_map),
-            ],
+            in_specs=_bwd_in_specs(block_q, block_k, D, q_map, kv_map),
             out_specs=pl.BlockSpec((1, 1, block_q, D), q_map),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
@@ -520,13 +661,7 @@ def _bwd_dkv(q, k, v, do, stats, scale, causal, window, block_q,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, Skv // block_k, nq),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_q, D), q_map),
-                pl.BlockSpec((1, 1, block_q, 128), q_map),
-            ],
+            in_specs=_bwd_in_specs(block_q, block_k, D, q_map, kv_map),
             out_specs=[
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
@@ -545,16 +680,69 @@ def _bwd_dkv(q, k, v, do, stats, scale, causal, window, block_q,
     )(_delta_arr(offset), q, k, v, do, stats)
 
 
+def _bwd_fused(q, k, v, do, stats, scale, causal, window, block_q,
+               block_k, offset=None):
+    """(dq [B,H,Sq,D], dk, dv [B,H,Skv,D]) in one call: the dk/dv grid
+    (b, h, ki, qi) with dq summed across its outer axis in a float32
+    scratch of the head's whole sequence. dq is the FIRST result: the
+    benchmark's cost file counts over it."""
+    B, H, Sq, D = q.shape
+    Skv = k.shape[2]
+    nq = Sq // block_q
+    kernel = functools.partial(
+        _bwd_kernel, scale=scale, causal=causal, window=window,
+        block_q=block_q, block_k=block_k)
+    q_map = _inner_tile_map(functools.partial(
+        masks.prefill_live_q_tiles, block_q=block_q, block_k=block_k,
+        causal=causal, window=window), nq)
+    kv_map = _outer_tile_map
+    return _named_pallas_call(
+        "flash_bwd", kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, Skv // block_k, nq),
+            in_specs=_bwd_in_specs(block_q, block_k, D, q_map, kv_map),
+            out_specs=[
+                # held for all of a head's steps, written back once
+                pl.BlockSpec((1, 1, Sq, D),
+                             lambda b, h, ki, qi, off_ref: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                pl.BlockSpec((1, 1, block_k, D), kv_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((nq, block_q, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
+        ],
+        compiler_params=_compiler_params(
+            _fused_bwd_vmem_bytes(Sq, block_q, block_k, D,
+                                  q.dtype.itemsize), outer="arbitrary"),
+        interpret=_interpret(),
+    )(_delta_arr(offset), q, k, v, do, stats)
+
+
+def _bwd_split(q, k, v, do, stats, *tile_args):
+    """The same gradients by two calls, each with one tile's accumulators
+    (seven matmuls and the vector work twice a tile pair)."""
+    dq = _bwd_dq(q, k, v, do, stats, *tile_args)
+    dk, dv = _bwd_dkv(q, k, v, do, stats, *tile_args)
+    return dq, dk, dv
+
+
 def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
          offset=None):
     """(dq, dk, dv) given the forward's output and its log-sum-exp
-    [B,H,Sq] (compact: one float a row)."""
-    stats = _bwd_stats(lse, o, do)
-    dq = _bwd_dq(q, k, v, do, stats, scale, causal, window,
-                 block_q, block_k, offset)
-    dk, dv = _bwd_dkv(q, k, v, do, stats, scale, causal, window,
-                      block_q, block_k, offset)
-    return dq, dk, dv
+    [B,H,Sq] (compact: one float a row): the fused kernel wherever its
+    footprint fits (`fused_bwd_fits`), else the split pair."""
+    stats = _bwd_stats(lse, o, do, block_q)
+    fused = fused_bwd_fits(q.shape[2], q.shape[3], q.dtype, block_q, block_k)
+    return (_bwd_fused if fused else _bwd_split)(
+        q, k, v, do, stats, scale, causal, window, block_q, block_k, offset)
 
 
 # ---------------------------------------------------------------------------

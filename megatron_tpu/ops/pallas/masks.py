@@ -175,7 +175,8 @@ def prefill_live_q_tiles(ki, block_q: int, block_k: int, *,
                          causal: bool = True, window: Optional[int] = None,
                          delta=0):
     """(first, last) q tile that `prefill_block_live` admits for kv tile
-    ki: the same interval solved for qi (the dk/dv kernel's inner axis)."""
+    ki: the same interval solved for qi (the inner axis of the fused
+    backward kernel and of the split dk/dv kernel)."""
     first = (ki * block_k - delta) // block_q if causal else 0
     last = (((ki + 1) * block_k + window - delta - 2) // block_q
             if window is not None else 2 ** 30)

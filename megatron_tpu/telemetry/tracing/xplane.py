@@ -18,7 +18,7 @@ writes one ``<host>.xplane.pb`` per host under
 On a TPU an operation's event is named by its whole HLO text, and what
 says where in the PROGRAM it came from sits on the event's metadata, not
 on the event: ``tf_op`` (the jaxpr name stack, which holds the
-``jax.named_scope`` names: ".../transpose(jvp(attention))/flash_bwd_dq/
+``jax.named_scope`` names: ".../transpose(jvp(attention))/flash_bwd/
 pallas_call:"), ``hlo_category``, ``flops``, ``source``. The walker hands
 them out as stats of every event of that name; a stat of the event itself
 wins over one of the same name on its metadata.

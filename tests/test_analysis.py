@@ -521,3 +521,74 @@ def test_comm_report_prints_table(capsys):
     assert "train_pp2" in out
     assert "ppermute[pipe]" in out
     assert "host_callbacks=0" in out
+
+
+# ---------------------------------------------------------------------------
+# the journal's step_program.kernel_calls (analysis/step_program.py)
+# ---------------------------------------------------------------------------
+
+_STEP_TEXT = """HloModule jit_train_step
+
+%cond (arg: (s32[], bf16[8])) -> pred[] {{
+  %arg = (s32[], bf16[8]) parameter(0)
+  %i = s32[] get-tuple-element(%arg), index=0
+  %n = s32[] constant({layers})
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}}
+
+%forward_body (arg.1: (s32[], bf16[8])) -> (s32[], bf16[8]) {{
+  %arg.1 = (s32[], bf16[8]) parameter(0)
+  %flash_fwd.1 = (bf16[1,32,4096,128]{{3,2,1,0}}, f32[1,32,4096,128]{{3,2,1,0}}) custom-call(%q, %k, %v), custom_call_target="tpu_custom_call", metadata={{op_name="jit(train_step)/layer_stack/while/body/checkpoint/attention/attn_core/flash_fwd/pallas_call"}}
+  ROOT %t = (s32[], bf16[8]) tuple(%i.1, %x)
+}}
+
+%backward_body (arg.2: (s32[], bf16[8])) -> (s32[], bf16[8]) {{
+  %arg.2 = (s32[], bf16[8]) parameter(0)
+{backward}
+  ROOT %t.1 = (s32[], bf16[8]) tuple(%i.2, %x.1)
+}}
+
+ENTRY %main (p: bf16[8]) -> bf16[8] {{
+  %p = bf16[8] parameter(0)
+  %while.1 = (s32[], bf16[8]) while(%init), condition=%cond, body=%forward_body
+  %while.2 = (s32[], bf16[8]) while(%init.1), condition=%cond, body=%backward_body
+  ROOT %out = bf16[8] get-tuple-element(%while.2), index=1
+}}
+"""
+_KERNEL_LINE = (
+    '  %{name}.{n} = {results} custom-call(%q.{n}, %k.{n}), '
+    'custom_call_target="tpu_custom_call", metadata={{op_name="jit(train_'
+    'step)/layer_stack/transpose(jvp(while))/body/{remat}transpose(jvp('
+    'attention))/attn_core/{name}/pallas_call"}}')
+_DQ = "bf16[1,32,4096,128]{3,2,1,0}"
+
+
+@pytest.mark.parametrize("backward, want", [
+    # the fused backward: one call a layer whose first result is dq
+    ([("flash_bwd", f"({_DQ}, {_DQ}, {_DQ})", "")],
+     {"flash_bwd": {"calls": 1, "rematted": 0, "times": 2}}),
+    # the split pair, which a sequence too long for the fused kernel runs
+    ([("flash_bwd_dq", _DQ, ""), ("flash_bwd_dkv", f"({_DQ}, {_DQ})", "")],
+     {"flash_bwd_dkv": {"calls": 1, "rematted": 0, "times": 2},
+      "flash_bwd_dq": {"calls": 1, "rematted": 0, "times": 2}}),
+    # `full`: the backward pass runs the forward kernel a second time
+    ([("flash_fwd", f"({_DQ}, f32[1,32,4096,128]{{3,2,1,0}})",
+       "rematted_computation/"),
+      ("flash_bwd", f"({_DQ}, {_DQ}, {_DQ})", "")],
+     {"flash_bwd": {"calls": 1, "rematted": 0, "times": 2}}),
+], ids=["fused", "split_pair", "full_recompute"])
+def test_kernel_calls_count_the_backward_a_step_runs(backward, want):
+    """What a traced run journals of its kernels: each by the name in
+    front of its stack's closing `pallas_call`, a scanned layer's call
+    once in the text and `layers` times a step, and under
+    `rematted_computation` only a forward that is run again."""
+    from megatron_tpu.analysis import step_program
+
+    lines = [_KERNEL_LINE.format(name=name, n=n, results=results,
+                                 remat=remat)
+             for n, (name, results, remat) in enumerate(backward, 2)]
+    text = _STEP_TEXT.format(layers=2, backward="\n".join(lines))
+    again = sum(1 for name, _, _ in backward if name == "flash_fwd")
+    want = dict(want, flash_fwd={"calls": 1 + again, "rematted": again,
+                                 "times": 2 * (1 + again)})
+    assert step_program.kernel_calls(text) == dict(sorted(want.items()))

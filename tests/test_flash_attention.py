@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from megatron_tpu.ops.attention import attention
+from megatron_tpu.ops.pallas import flash_template as ft
 from megatron_tpu.ops.pallas.flash_template import flash_mha, supported
 
 RNG = np.random.default_rng(7)
@@ -155,25 +156,166 @@ def _kernel_dots(jaxpr, kernel=None):
             yield from _kernel_dots(sub, kernel)
 
 
+@pytest.mark.parametrize("backward,matmuls", [
+    ("fused", {"flash_bwd": 5}),
+    ("split", {"flash_bwd_dq": 3, "flash_bwd_dkv": 4})])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-def test_kernel_matmuls_take_operands_as_given(dtype):
-    """Every matmul of the three training kernels multiplies the dtype
-    the caller passed and accumulates in float32: bf16 tensors reach the
-    MXU as bf16 (no cast to float32 comes back), and float32 tensors
-    still multiply in float32 — which is what keeps the float32 numerics
-    tests above meaning what they say."""
-    from megatron_tpu.ops.pallas.flash_template import flash_mha
-
+def test_kernel_matmuls_take_operands_as_given(monkeypatch, dtype, backward,
+                                               matmuls):
+    """Every matmul of the training kernels multiplies the dtype the
+    caller passed and accumulates in float32: bf16 tensors reach the MXU
+    as bf16 (no cast to float32 comes back), and float32 tensors still
+    multiply in float32 — which is what keeps the float32 numerics tests
+    above meaning what they say. The backward is ONE kernel of five
+    matmuls: each tile pair's p and ds are formed once; the split pair
+    that a sequence too long for it runs forms them twice (seven). The
+    statistics' kernel multiplies nothing."""
+    if backward == "split":
+        monkeypatch.setattr(ft, "fused_bwd_fits", lambda *a: False)
     q, k, v = (x.astype(dtype) for x in _qkv(s=256, hq=2, hkv=1, d=64))
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: flash_mha(q, k, v, block_q=128, block_k=128)
         .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
     dots = list(_kernel_dots(jaxpr.jaxpr))
-    per_kernel = {name: sum(1 for d in dots if d[0] == name)
-                  for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
-    assert per_kernel == {"flash_fwd": 2, "flash_bwd_dq": 3,
-                          "flash_bwd_dkv": 4}, dots
+    per_kernel = {}
+    for name, _, _ in dots:
+        per_kernel[name] = per_kernel.get(name, 0) + 1
+    assert per_kernel == {"flash_fwd": 2, **matmuls}, dots
     for kernel, operands, result in dots:
         assert operands == (dtype, dtype), (kernel, operands)
         assert result == jnp.float32, (kernel, result)
+
+
+@pytest.mark.parametrize("block", [64, 128, 256])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_row_statistics_kernel_packs_lse_and_delta(dtype, block):
+    """`flash_bwd_stats` spreads the two compact statistics over the
+    lanes and changes no bit: lanes [0, 64) of a row hold its
+    log-sum-exp as given, the rest hold rowsum(do * o), summed in
+    float32 from the tensors' own dtype by XLA as before."""
+    _, _, o, do = _bhsd_case(dtype, heads=3)
+    lse = jnp.asarray(np.random.default_rng(5).standard_normal((1, 3, 256)),
+                      jnp.float32) * 3 + 7
+    stats = np.asarray(ft._bwd_stats(lse, o, do, block))
+    assert stats.shape == (1, 3, 256, 128) and stats.dtype == np.float32
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    for lanes, want in ((slice(0, ft._DELTA_LANE), lse),
+                        (slice(ft._DELTA_LANE, 128), delta)):
+        np.testing.assert_array_equal(
+            stats[..., lanes],
+            np.broadcast_to(np.asarray(want)[..., None], (1, 3, 256, 64)))
+
+
+# ---------------------------------------------------------------------------
+# the fused backward against the split pair it replaced (which sequences
+# too long for the fused kernel's VMEM footprint still run) and against
+# the XLA gradient
+# ---------------------------------------------------------------------------
+
+# (mask, (causal, window)) over S = 256: the window smaller than the
+# sequence leaves dead tiles on both sides of the band at either tile
+_MASKS = {"causal": (True, None), "window64": (True, 64),
+          "window_s": (True, 256), "bidirectional": (False, None)}
+# offset of the q rows' global positions against the keys' (a ring
+# stripe): aligned, a stripe wholly in the keys' future (every pair
+# visible under `causal`), one in their past (no q row sees a kv tile
+# under `causal`: dq must come out zero), and a band cut askew
+_OFFSETS = {"aligned": None, "future": 256, "past": -256, "askew": 96}
+
+
+def _bhsd_case(dtype, heads, seed=3, s=256, d=64):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((1, heads, s, d)), dtype)
+                 for _ in range(4))
+
+
+def _backwards(q, k, v, do, causal, window, block, offset):
+    """(fused, split): (dq, dk, dv) of the two backwards from the same
+    forward results."""
+    scale = float(1.0 / q.shape[-1] ** 0.5)
+    o, lse = ft._fwd(q, k, v, scale, causal, window, block, block,
+                     delta=offset)
+    stats = ft._bwd_stats(lse, o, do, block)
+    args = (q, k, v, do, stats, scale, causal, window, block, block, offset)
+    return ft._bwd_fused(*args), ft._bwd_split(*args)
+
+
+@pytest.mark.parametrize("block", [64, 128])
+@pytest.mark.parametrize("offset", list(_OFFSETS))
+@pytest.mark.parametrize("mask", list(_MASKS))
+def test_fused_backward_equals_the_split_pair_bit_for_bit(mask, offset,
+                                                          block):
+    """float32 under the interpreter: every sum of the fused kernel
+    takes the pair's terms in the pair's order, so no bit differs."""
+    causal, window = _MASKS[mask]
+    fused, split = _backwards(*_bhsd_case(jnp.float32, 2), causal, window,
+                              block, _OFFSETS[offset])
+    for name, got, want in zip(("dq", "dk", "dv"), fused, split):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
+    if causal and offset == "past":
+        assert not np.asarray(fused[0]).any()    # no visible pair at all
+
+
+@pytest.mark.parametrize("offset", ["aligned", "askew"])
+@pytest.mark.parametrize("mask", list(_MASKS))
+def test_fused_backward_equals_the_split_pair_in_bf16(mask, offset):
+    causal, window = _MASKS[mask]
+    fused, split = _backwards(*_bhsd_case(jnp.bfloat16, 2), causal, window,
+                              128, _OFFSETS[offset])
+    for name, got, want in zip(("dq", "dk", "dv"), fused, split):
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("block", [64, 128])
+@pytest.mark.parametrize("hkv", [1, 2], ids=["gqa", "mha"])
+@pytest.mark.parametrize("mask", list(_MASKS))
+def test_fused_backward_matches_xla_gradient(mask, hkv, block):
+    """Through `flash_mha` (transposes, the GQA repeat and its vjp's sum
+    over the group) against the gradient of the XLA attention."""
+    causal, window = _MASKS[mask]
+    q, k, v = _qkv(s=256, hq=2, hkv=hkv, d=64)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.square(fn(q, k, v)))
+
+    got = jax.grad(loss(functools.partial(
+        flash_mha, sliding_window=window, causal=causal, block_q=block,
+        block_k=block)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(functools.partial(
+        attention, sliding_window=window,
+        mask_type="causal" if causal else "bidirectional")),
+        argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert _share_of_range(a, b) <= 2e-3, f"d{name}"
+
+
+@pytest.mark.parametrize("sq,d,dtype,fused", [
+    (1024, 128, jnp.bfloat16, True), (4096, 128, jnp.bfloat16, True),
+    (16384, 128, jnp.bfloat16, True), (65536, 128, jnp.bfloat16, True),
+    (131072, 128, jnp.bfloat16, False), (32768, 128, jnp.float32, True),
+    (65536, 128, jnp.float32, False), (16384, 256, jnp.bfloat16, True),
+    (65536, 256, jnp.bfloat16, False)])
+def test_which_backward_a_shape_takes(monkeypatch, sq, d, dtype, fused):
+    """A function of the sequence, the row and the dtype alone: the
+    fused kernel while dq of a whole sequence fits in VMEM beside the
+    tiles `pick_blocks` gives, the split pair beyond. `_bwd` asks it and
+    nothing else."""
+    blocks = ft.pick_blocks(sq, d, dtype)
+    assert ft.fused_bwd_fits(sq, d, dtype, *blocks) is fused
+    item = jnp.dtype(dtype).itemsize
+    assert (ft._fused_bwd_vmem_bytes(sq, *blocks, d, item)
+            - ft._bwd_vmem_bytes(*blocks, d, item)) == sq * d * (4 + 2 * item)
+    taken = []
+    for name in ("_bwd_fused", "_bwd_split"):
+        monkeypatch.setattr(
+            ft, name, lambda *a, name=name: taken.append(name) or (None,) * 3)
+    monkeypatch.setattr(ft, "_bwd_stats", lambda lse, o, do, block_q: None)
+    x = jax.ShapeDtypeStruct((1, 1, sq, d), dtype)
+    ft._bwd(x, x, x, x, None, x, 1.0, True, None, *blocks)
+    assert taken == ["_bwd_fused" if fused else "_bwd_split"]

@@ -16,7 +16,7 @@ from megatron_tpu.telemetry.tracing.events import (
     REGION_SCOPES, kernel_of, scope_tokens,
 )
 
-TRAIN_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+TRAIN_KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_stats")
 # the parts of the two layer regions (models/transformer.py), the layers'
 # scan (models/language_model.py) and the micro-batch loop (train_step.py)
 SUB_SCOPES = {"attention": ("attn_norm", "attn_qkv", "attn_rope",
@@ -55,8 +55,8 @@ def test_the_compiled_step_holds_every_scope(parallel, zero1, recompute):
         assert all("attention" in toks[:toks.index(kernel)]
                    for toks in under), kernel
     # forward, backward and recomputation keep the names: the forward
-    # kernel runs under jvp, the two backward kernels under the
-    # transpose. Under `full` the forward runs again as rematted
+    # kernel runs under jvp, the fused backward kernel and the kernel
+    # of its row statistics under the transpose. Under `full` the forward runs again as rematted
     # computation; under `selective` the layer's checkpoint keeps its
     # output and log-sum-exp, and no flash forward is computed twice
     fwd = [n for n, toks in stacks if "flash_fwd" in toks]
@@ -115,9 +115,9 @@ def test_the_compiled_step_holds_every_scope(parallel, zero1, recompute):
 
 @pytest.mark.parametrize("text, want", [
     ("jit(train_step)/while/body/closed_call/transpose(jvp(attention))/"
-     "flash_bwd_dq/pallas_call:",
+     "flash_bwd/pallas_call:",
      ["train_step", "while", "body", "closed_call", "attention",
-      "flash_bwd_dq", "pallas_call"]),
+      "flash_bwd", "pallas_call"]),
     ("jit(f)/jvp(head_loss)/bsh,hv->bsv/dot_general",
      ["f", "head_loss", "bsh,hv->bsv", "dot_general"]),
     ("", [""]),
@@ -126,5 +126,5 @@ def test_the_compiled_step_holds_every_scope(parallel, zero1, recompute):
 def test_scope_tokens_strip_the_wrappers(text, want):
     assert scope_tokens(text) == want
     # the rule that finds a kernel: only a stack that closes in the call
-    assert kernel_of(want) == ("flash_bwd_dq" if want[-1] == "pallas_call"
+    assert kernel_of(want) == ("flash_bwd" if want[-1] == "pallas_call"
                                else None)

@@ -815,8 +815,45 @@ def test_named_tpu_trace_reads_by_scope_kernel_and_host_span(
     assert d["scope_classes"]["mlp"]["matmul"] > 0
 
 
+@pytest.mark.parametrize("backward", [
+    ("flash_bwd",), ("flash_bwd_dq", "flash_bwd_dkv"),
+], ids=["fused", "split_pair"])
+def test_report_books_the_backward_kernels_a_step_runs(backward):
+    """The kernel table is keyed by what stands in front of the name
+    stack's closing `pallas_call`: a step that runs the fused backward
+    reads one `flash_bwd` row (own time under `attention`, class
+    `kernel`), one that still runs the split pair reads its two names
+    (PR 23's recordings above: made before PR 40 fused them)."""
+    from megatron_tpu.telemetry.tracing.analyze import analyze_events
+    from megatron_tpu.telemetry.tracing.events import KIND_COMPUTE, OpEvent
+
+    US = 1_000_000  # picoseconds
+    stack = ("jit(train_step)/while/body/closed_call/transpose(jvp("
+             "attention))/attn_core/{}/pallas_call:")
+
+    def call(kernel, start, dur):
+        return OpEvent(f"{kernel}.3", KIND_COMPUTE, start * US, dur * US,
+                       "/device:TPU:0", "XLA Ops", module="jit_train_step",
+                       detail="bf16[1,32,4096,128] custom-call",
+                       tf_op=stack.format(kernel), category="custom-call")
+
+    events = [call("flash_fwd", 0, 1800)]
+    for layer in range(2):
+        for i, kernel in enumerate(backward):
+            events.append(call(kernel, 2000 * (1 + 2 * layer + i), 1500))
+    report = analyze_events(events)
+    assert {k: v["count"] for k, v in report.kernels.items()} == dict(
+        {"flash_fwd": 1}, **{k: 2 for k in backward})
+    for kernel in backward:
+        assert report.kernels[kernel]["self_s"] == pytest.approx(3000e-6)
+    assert set(report.scopes) == {"attention"}
+    assert report.to_dict()["scope_classes"]["attention"]["kernel"] == (
+        pytest.approx(sum(k["self_s"] for k in report.kernels.values())))
+
+
 @pytest.mark.parametrize("name, category, kernel, want", [
     ("flash_fwd.13", "custom-call", True, "kernel"),
+    ("flash_bwd.7", "custom-call", True, "kernel"),
     ("custom-call.5", "custom-call", False, "rest"),
     ("fusion.472", "convolution fusion", False, "matmul"),
     ("all-gather-start.3", "all-gather-start", False, "collective"),
