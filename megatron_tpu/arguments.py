@@ -11,13 +11,24 @@ MicroBatchCalculator at use sites.
 from __future__ import annotations
 
 import argparse
+import json
 import os
 from typing import Optional, Sequence
 
 from megatron_tpu.config import (
-    ModelConfig, OptimizerConfig, ParallelConfig, RunConfig, TrainingConfig,
+    AttentionKind, ModelConfig, OptimizerConfig, ParallelConfig, RunConfig,
+    TrainingConfig,
     model_config_from_saved,
 )
+
+
+def _attention_pattern(text: str):
+    """--attention_pattern's JSON list as a tuple of AttentionKind."""
+    try:
+        return tuple(AttentionKind(**kind) for kind in json.loads(text))
+    except (TypeError, ValueError) as e:
+        raise argparse.ArgumentTypeError(
+            f"not a JSON list of attention kinds: {e}") from e
 
 
 def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
@@ -60,6 +71,17 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
                    help="untie the word embedding and lm head (ref default "
                         "is tied)")
     g.add_argument("--sliding_window_size", type=int, default=None)
+    g.add_argument("--attention_pattern", type=_attention_pattern,
+                   default=None,
+                   help="layers of several attention kinds in one stack: "
+                        "a JSON list with one object a layer of one period "
+                        "of the pattern, its keys config.AttentionKind's "
+                        "(name, sliding_window_size, rope_theta, "
+                        "rope_scaling_factor, rope_type linear|yarn, "
+                        "yarn_*); the stack repeats it over --num_layers. "
+                        "In place of --sliding_window_size, --rope_theta "
+                        "and --rope_scaling_factor, which state one kind "
+                        "(layers that are all alike are said with those)")
     g.add_argument("--qk_norm", action="store_true", default=None,
                    help="RMSNorm with a learned scale over the whole q and "
                         "the whole k projection, before the head split and "
@@ -68,6 +90,14 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     # explicitly-passed knob overrides a preset's value but an unpassed
     # knob never clobbers it (the mixtral preset carries its own values).
     g.add_argument("--num_experts", type=int, default=None)
+    g.add_argument("--moe_experts_held", type=int, default=None,
+                   help="one chip's share of an expert-parallel layer, run "
+                        "alone: the weights of this many of the router's "
+                        "--num_experts experts exist here, and the layer "
+                        "returns the part of the result they give")
+    g.add_argument("--moe_expert_share", type=int, default=None,
+                   help="which share: experts share * held up to the next "
+                        "share's first (default 0)")
     g.add_argument("--moe_top_k", type=int, default=None)
     g.add_argument("--moe_capacity_factor", type=float, default=None)
     g.add_argument("--moe_aux_loss_coeff", type=float, default=None)
@@ -470,7 +500,8 @@ def _moe_overrides(args) -> dict:
     """MoE knobs that were explicitly passed (None = flag absent, keep the
     preset's or ModelConfig's value)."""
     out = {}
-    for name in ("num_experts", "moe_top_k", "moe_capacity_factor",
+    for name in ("num_experts", "moe_experts_held", "moe_expert_share",
+                 "moe_top_k", "moe_capacity_factor",
                  "moe_aux_loss_coeff", "moe_z_loss_coeff",
                  "moe_renorm_gates", "moe_group_size", "moe_dispatch",
                  "moe_ep_buffer_factor"):
@@ -605,6 +636,7 @@ def args_to_run_config(args) -> RunConfig:
                               else args.tie_embed_logits),
             **_moe_overrides(args),
             sliding_window_size=args.sliding_window_size,
+            attention_pattern=args.attention_pattern,
             qk_norm=bool(args.qk_norm),
             use_post_ln=args.use_post_ln,
             apply_residual_post_ln=args.apply_residual_connection_post_layernorm,

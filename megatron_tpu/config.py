@@ -46,6 +46,48 @@ def _resolve_dtype(name: str):
 # model architecture
 # ---------------------------------------------------------------------------
 
+ROPE_TYPES = ("linear", "yarn")
+
+
+@dataclass(frozen=True)
+class AttentionKind:
+    """What may differ between the attention layers of one stack and is
+    static where a layer calls its kernels: the window and the rotary
+    table. Layers of one kind compare equal; a model's layers are of the
+    kinds of `ModelConfig.attention_period`, in that order, over and over.
+
+    rope_type "linear": positions divided by rope_scaling_factor (1.0: the
+    plain table). "yarn" (arXiv:2309.00071, as Hugging Face's
+    `rope_parameters` state it): each frequency blended between itself and
+    itself / rope_scaling_factor by a linear ramp over the dimensions,
+    from the one that turns yarn_beta_fast times over
+    yarn_original_max_positions (and faster: left alone) to the one that
+    turns yarn_beta_slow times (and slower: interpolated); cos and sin
+    scaled by yarn_attention_factor (None: 0.1 ln(factor) + 1)."""
+
+    name: str = "full"     # the layer's scope in a trace: attn_<name>
+    sliding_window_size: Optional[int] = None
+    rope_theta: float = 10000.0
+    rope_scaling_factor: float = 1.0
+    rope_type: str = "linear"
+    yarn_original_max_positions: Optional[int] = None
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: Optional[float] = None
+
+    def validate(self) -> "AttentionKind":
+        if self.rope_type not in ROPE_TYPES:
+            raise ValueError(f"bad rope_type {self.rope_type!r}; one of "
+                             f"{ROPE_TYPES}")
+        if self.rope_type == "yarn" and not self.yarn_original_max_positions:
+            raise ValueError("rope_type 'yarn' needs "
+                             "yarn_original_max_positions")
+        if (self.sliding_window_size is not None
+                and self.sliding_window_size < 1):
+            raise ValueError("sliding_window_size must be >= 1")
+        return self
+
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -112,6 +154,16 @@ class ModelConfig:
 
     # Mistral sliding-window attention (ref: transformer.py:528-536)
     sliding_window_size: Optional[int] = None
+    # Layers of several attention kinds in one stack (window and full
+    # layers mixed, a rotary table a kind): one period of the layers'
+    # pattern, which the stack repeats over its depth (num_layers is a
+    # multiple of its length). None: every layer is of the one kind that
+    # rope_theta, rope_scaling_factor and sliding_window_size above state;
+    # given, those three stay at their defaults (a kind states its own),
+    # and its layers are not all alike: a model has one spelling (a
+    # pattern of one layer only for a table the scalars cannot state).
+    # Read through `attention_period`, never directly.
+    attention_pattern: Optional[Tuple[AttentionKind, ...]] = None
 
     # OLMoE QK-norm: RMSNorm with a learned scale over the WHOLE q and the
     # whole k projection (all heads at once), before the head split and
@@ -122,6 +174,13 @@ class ModelConfig:
     # dispatch with capacity; Mixtral-style renormalized top-k gates.
     # None = dense MLP. See ops/moe.py.
     num_experts: Optional[int] = None
+    # One chip's share of an expert-parallel layer, run alone: the router
+    # stays num_experts wide, the weights of moe_experts_held experts
+    # exist here (experts moe_expert_share * held up to the next share's
+    # first), and the layer returns the part of the result they give
+    # (ops/moe.py moe_block_dropless). None: every expert is held.
+    moe_experts_held: Optional[int] = None
+    moe_expert_share: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_loss_coeff: float = 1e-2
@@ -216,6 +275,40 @@ class ModelConfig:
     def dtype(self):
         return _resolve_dtype(self.params_dtype)
 
+    @property
+    def attention_period(self) -> Tuple[AttentionKind, ...]:
+        """The kinds of the layers of one period of the stack, in order;
+        one entry for a model whose layers are all alike."""
+        if self.attention_pattern is not None:
+            return self.attention_pattern
+        return (AttentionKind(
+            name="sliding" if self.sliding_window_size else "full",
+            sliding_window_size=self.sliding_window_size,
+            rope_theta=self.rope_theta,
+            rope_scaling_factor=self.rope_scaling_factor),)
+
+    @property
+    def attention_kind(self) -> AttentionKind:
+        """The one kind of a model whose layers are all alike, for the
+        paths that run no other (pipeline stages, the serving engines)."""
+        kinds = set(self.attention_period)
+        if len(kinds) > 1:
+            raise NotImplementedError(
+                "this path runs one kind of attention layer; the model "
+                f"has {sorted(k.name for k in kinds)}")
+        return self.attention_period[0]
+
+    @property
+    def experts_held(self) -> Optional[int]:
+        """The experts whose weights exist here; None for a dense model."""
+        return self.moe_experts_held or self.num_experts
+
+    @property
+    def holds_expert_share(self) -> bool:
+        """The router is wider than the experts held."""
+        return (self.moe_experts_held is not None
+                and self.moe_experts_held < (self.num_experts or 0))
+
     def validate(self) -> "ModelConfig":
         if self.position_embedding_type not in POSITION_EMBEDDING_TYPES:
             raise ValueError(f"bad position_embedding_type {self.position_embedding_type}")
@@ -241,6 +334,47 @@ class ModelConfig:
             raise ValueError("absolute position embeddings need max_position_embeddings")
         if self.parallel_layernorm and not self.parallel_attn:
             raise ValueError("parallel_layernorm requires parallel_attn")
+        if self.attention_pattern is not None:
+            if not self.attention_pattern or (
+                    self.num_layers % len(self.attention_pattern)):
+                raise ValueError(
+                    f"attention_pattern of {len(self.attention_pattern)} "
+                    f"layers does not divide num_layers={self.num_layers}")
+            if (self.sliding_window_size is not None
+                    or self.rope_theta != 10000.0
+                    or self.rope_scaling_factor != 1.0):
+                raise ValueError(
+                    "attention_pattern states each kind's window and "
+                    "rotary table: leave sliding_window_size, rope_theta "
+                    "and rope_scaling_factor at their defaults")
+            for kind in self.attention_pattern:
+                kind.validate()
+            # one spelling a model: layers that are all alike are said by
+            # the three scalars (names apart), as far as those can say them
+            alike = {dataclasses.replace(k, name="")
+                     for k in self.attention_pattern}
+            if len(alike) == 1 and (
+                    len(self.attention_pattern) > 1
+                    or dataclasses.replace(
+                        alike.pop(), sliding_window_size=None,
+                        rope_theta=10000.0, rope_scaling_factor=1.0)
+                    == AttentionKind(name="")):
+                raise ValueError(
+                    "attention_pattern's layers are all alike: say a "
+                    "one-kind model with sliding_window_size, rope_theta "
+                    "and rope_scaling_factor (or, where those cannot say "
+                    "its rotary table, with a pattern of one layer)")
+        if self.moe_experts_held is not None:
+            if self.num_experts is None or self.moe_dispatch != "dropless":
+                raise ValueError(
+                    "moe_experts_held needs num_experts (the router's "
+                    "width) and moe_dispatch='dropless'")
+            shares, rest = divmod(self.num_experts, self.moe_experts_held)
+            if rest or not 0 <= self.moe_expert_share < shares:
+                raise ValueError(
+                    f"moe_experts_held={self.moe_experts_held} must divide "
+                    f"num_experts={self.num_experts}, and moe_expert_share="
+                    f"{self.moe_expert_share} be one of its {shares} shares")
         if self.num_experts is not None:
             if self.num_experts < 1:
                 raise ValueError("num_experts must be >= 1")
@@ -283,14 +417,21 @@ class ModelConfig:
         f = self.ffn_size
         per_layer = 0.0
         per_layer += 2 * h * (nq + 2 * nkv) * hd        # qkv proj
-        per_layer += 2 * 2 * s * nq * hd                # qk^T and av (causal ~ /2 but count full)
         per_layer += 2 * nq * hd * h                    # out proj
         mlp_in_width = f * (2 if self.is_glu else 1)
         mlp = 2 * h * mlp_in_width + 2 * f * h
         if self.num_experts is not None:
-            # each token visits top_k experts; router matmul is extra
-            mlp = mlp * self.moe_top_k + 2 * h * self.num_experts
+            # each token visits top_k experts, of which the share held
+            # here is computed here (all of them where every expert is
+            # held); the router matmul is extra, over its whole width
+            mlp = (mlp * self.moe_top_k * self.experts_held
+                   / self.num_experts + 2 * h * self.num_experts)
         per_layer += mlp
+        # qk^T and av over the keys a layer's kind lets a query see (causal
+        # ~ /2 but count full): s, or the window where it is shorter
+        period = self.attention_period
+        keys = sum(min(s, k.sliding_window_size or s) for k in period)
+        per_layer += 2 * 2 * nq * hd * keys / len(period)
         total = self.num_layers * per_layer
         total += 2 * h * self.vocab_size                # logits
         return float(total)
@@ -309,8 +450,13 @@ RETIRED_MODEL_FIELDS = ("flash_bwd",)
 
 def model_config_from_saved(saved: dict) -> ModelConfig:
     """ModelConfig from the "model" dict of a saved run config."""
-    return ModelConfig(**{k: v for k, v in saved.items()
-                          if k not in RETIRED_MODEL_FIELDS})
+    kept = {k: v for k, v in saved.items() if k not in RETIRED_MODEL_FIELDS}
+    if kept.get("attention_pattern") is not None:
+        # JSON knows no dataclass and no tuple
+        kept["attention_pattern"] = tuple(
+            k if isinstance(k, AttentionKind) else AttentionKind(**k)
+            for k in kept["attention_pattern"])
+    return ModelConfig(**kept)
 
 
 @dataclass(frozen=True)

@@ -1384,8 +1384,7 @@ class InferenceEngine:
             "head_dim": int(cfg.head_dim),
             "dtype": jnp.empty((0,), cfg.dtype).dtype.name,
             "int8": bool(self.kv_cache_int8),
-            "sliding_window": (None if cfg.sliding_window_size is None
-                               else int(cfg.sliding_window_size)),
+            "sliding_window": cfg.attention_kind.sliding_window_size,
         }
 
     def _pack_kv_sections(self, leaves: List[np.ndarray], length: int

@@ -659,7 +659,7 @@ class PagedInferenceEngine(InferenceEngine):
         the masked scores stay well-defined). Pages the radix prefix
         cache also holds keep their cache reference: a later request
         sharing the prompt still hits them."""
-        window = self.cfg.sliding_window_size
+        window = self.cfg.attention_kind.sliding_window_size
         if window is None:
             return
         ps = self.page_size

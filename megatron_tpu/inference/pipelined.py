@@ -28,7 +28,7 @@ from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.language_model import final_hidden_norm, lm_logits
 from megatron_tpu.models.transformer import block_forward
 from megatron_tpu.ops import kv_store
-from megatron_tpu.ops.rotary import precompute_rope
+from megatron_tpu.ops.rotary import rope_table
 from megatron_tpu.training.pipeline import _embed_onehot
 
 
@@ -51,8 +51,8 @@ def make_pipelined_lm_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int):
 
         rope = None
         if cfg.position_embedding_type == "rotary":
-            rope = precompute_rope(cfg.head_dim, max(cfg.seq_length, total),
-                                   cfg.rope_theta, cfg.rope_scaling_factor)
+            rope = rope_table(cfg.attention_kind, cfg.head_dim,
+                              max(cfg.seq_length, total))
 
         x0 = _embed_onehot(cfg, params_local, tokens, None,
                            positions=positions).astype(cfg.dtype)

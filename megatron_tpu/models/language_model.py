@@ -34,12 +34,13 @@ from megatron_tpu.ops.cross_entropy import (
     chunked_head_loss, cross_entropy_loss,
 )
 from megatron_tpu.ops.moe import (
-    LOAD_METRIC, SAVED_PRODUCT, expert_grad_sinks, merge_layer_stats,
+    HELD_METRIC, LOAD_METRIC, SAVED_PRODUCT, expert_grad_sinks,
+    merge_layer_stats, moe_stats_zero,
 )
 from megatron_tpu.ops.pallas.flash_template import SAVED_RESIDUAL
 from megatron_tpu.ops.weight_quant import deq, take_rows
 from megatron_tpu.ops.normalization import norm_forward
-from megatron_tpu.ops.rotary import precompute_rope
+from megatron_tpu.ops.rotary import rope_table
 
 
 def parse_recompute(recompute: str):
@@ -173,6 +174,43 @@ def _scan_layers(body, carry, xs, recompute: str):
     return jax.lax.scan(body, carry, xs)
 
 
+def scan_periods_with_remat(bodies, carry, xs, recompute: str):
+    """scan_with_remat for a stack whose layers are of several kinds: the
+    stack is len(bodies) layers a period, over and over, and `bodies[i]`
+    runs the i-th layer of a period, what is static in its kind (the
+    window at the kernel calls, the rotary table) closed over. The scan
+    goes over the periods, its body runs a period's layers one after the
+    other, each under the remat policy on its own, and `xs` keeps its
+    stacked [L, ...] layout: a period's slices are taken from a
+    [L / period, period, ...] view of it. The policies that cut the stack
+    itself (block:N, uniform:N) are not served."""
+    gran, n = parse_recompute(recompute)
+    if n is not None:
+        raise NotImplementedError(
+            f"recompute {recompute!r} cuts the layer stack by count; a "
+            "stack of several attention kinds takes none, selective or full")
+    policy = _remat_policy(gran)
+    period = len(bodies)
+    if policy is not None:
+        # a stack of one period is a call, not a loop (_scan_layers): its
+        # layers' recomputation then stands in one computation with their
+        # forward pass, where common-subexpression elimination would merge
+        # the two and keep every activation after all
+        once = jax.tree.leaves(xs)[0].shape[0] == period
+        bodies = [jax.checkpoint(body, policy=policy, prevent_cse=once)
+                  for body in bodies]
+
+    def period_body(carry, scanned):
+        for i, body in enumerate(bodies):
+            carry, _ = body(carry, jax.tree.map(lambda a: a[i], scanned))
+        return carry, None
+
+    xs = jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // period, period) + a.shape[1:]), xs)
+    with jax.named_scope("layer_stack"):
+        return _scan_layers(period_body, carry, xs, "none")
+
+
 def _layer_dropout_rates(cfg: ModelConfig) -> jnp.ndarray:
     """Per-layer hidden-dropout rates; LIMA ramps linearly from 0 at the
     first layer to hidden_dropout at the last (ref transformer.py:994-1001)."""
@@ -273,7 +311,9 @@ def lm_forward(
     into it in place.
 
     return_moe_aux: also return [aux loss summed over the layers, the
-    worst layer's load statistic] (ops/moe.py layer_stats).
+    worst layer's load statistic] (ops/moe.py layer_stats; behind them,
+    where the layers hold a share of their experts, the held rows' shares
+    summed over the layers).
 
     page_table: the store is a pool of pages (inference/paging/) shared
     by every slot; each row's logical context is page_table[b] physical
@@ -300,14 +340,16 @@ def lm_forward(
     )
     x = sharder(x, "residual")
 
-    rope = None
+    # one rotary table a kind of attention layer
+    period = cfg.attention_period
+    ropes = dict.fromkeys(period)
     if cfg.position_embedding_type == "rotary":
         if kv_caches is not None:
             rope_len = kv_store.logical_length(kv_caches, page_table)
         else:
             rope_len = max(cfg.seq_length, tokens.shape[1])
-        rope = precompute_rope(cfg.head_dim, rope_len, cfg.rope_theta,
-                               cfg.rope_scaling_factor)
+        ropes = {kind: rope_table(kind, cfg.head_dim, rope_len)
+                 for kind in ropes}
 
     rates = _layer_dropout_rates(cfg)
     moe = cfg.num_experts is not None
@@ -320,12 +362,12 @@ def lm_forward(
     # the gradient sinks, whose cotangents the backward scan then carries
     # and the kernels update in place: a scanned slice of a stacked
     # accumulator would be copied in and out, layer by layer.
-    def body(carry, scanned):
+    def body(carry, scanned, kind=period[0]):
         x, aux, caches, sinks = carry
         lp, rate, idx = scanned
         key = jax.random.fold_in(dropout_key, idx) if train else None
         y, caches, moe_aux, sinks = block_forward(
-            cfg, lp, x, rope, positions,
+            cfg, lp, x, ropes[kind], positions,
             dropout_key=key,
             hidden_dropout_rate=rate,
             kv_cache=caches,
@@ -339,6 +381,7 @@ def lm_forward(
             tp_comm=tp_comm,
             cp_comm=cp_comm,
             grad_sink=sinks,
+            kind=kind,
         )
         return (y, add_aux(aux, moe_aux), caches, sinks), None
 
@@ -346,10 +389,16 @@ def lm_forward(
     xs = (params["layers"], rates, layer_idx)
     if kv_caches is not None and parse_recompute(recompute)[1] is not None:
         recompute = "none"  # decode path: caches preclude the split scan
-    (x, moe_aux, new_caches, layer_sinks), _ = scan_with_remat(
-        body, (x, jnp.zeros((2,) if moe else (), jnp.float32), kv_caches,
-               None if grad_sink is None else grad_sink["layers"]),
-        xs, recompute)
+    carry = (x, moe_stats_zero(cfg) if moe else jnp.zeros((), jnp.float32),
+             kv_caches, None if grad_sink is None else grad_sink["layers"])
+    if len(period) == 1:
+        carry, _ = scan_with_remat(body, carry, xs, recompute)
+    else:
+        # the kinds in their published order, each layer's window static
+        carry, _ = scan_periods_with_remat(
+            [partial(body, kind=kind) for kind in period], carry, xs,
+            recompute)
+    x, moe_aux, new_caches, layer_sinks = carry
 
     def with_sinks(result):
         if grad_sink is None:
@@ -491,5 +540,7 @@ def lm_loss(
         # lm_loss in metrics stays the pure CE term
         aux["moe_aux_loss"] = moe_aux[0]
         aux[LOAD_METRIC] = moe_aux[1]
+        if cfg.holds_expert_share:
+            aux[HELD_METRIC] = moe_aux[2] / cfg.num_layers
         return mean + moe_aux[0], aux
     return mean, aux

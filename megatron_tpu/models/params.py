@@ -94,8 +94,11 @@ def _defs(cfg: ModelConfig) -> Dict[str, Any]:
         # and tensor-parallel inside each expert, composing EP x TP; the
         # expert axis is independent of dp, so E never constrains the
         # data-parallel degree (VERDICT r3 next-round #6)
-        E = cfg.num_experts
-        d["layers/moe/router"] = ((L, h, E), P(AXIS_PIPE, None, None), _NORMAL)
+        # the router keeps its width where only a share of its experts'
+        # weights exist here (ModelConfig.moe_experts_held)
+        d["layers/moe/router"] = ((L, h, cfg.num_experts),
+                                  P(AXIS_PIPE, None, None), _NORMAL)
+        E = cfg.experts_held
         d["layers/moe/w_in"] = ((L, E, h, Fin),
                                 P(AXIS_PIPE, AXIS_EXPERT, None, AXIS_TENSOR),
                                 _NORMAL)

@@ -18,12 +18,13 @@ megatron/text_generation/forward_step.py:17-43) as functional state.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from megatron_tpu.config import ModelConfig
+from megatron_tpu.config import AttentionKind, ModelConfig
 from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.activations import apply_activation
 from megatron_tpu.ops.attention import attention
@@ -71,8 +72,12 @@ def attention_block(
     page_write_end: Optional[jnp.ndarray] = None,    # scalar int32
     tp_comm=None,  # quant.TpComm: explicit/compressed TP collectives
     cp_comm=None,  # quant.CpComm: context-parallel ring transport
+    kind: Optional[AttentionKind] = None,
 ):
     """Returns (out [B,S,h], kv_cache with this layer's rows written).
+
+    kind: this layer's attention kind, whose window is static at every
+    kernel call (`rope` is that kind's table); None: the model's one kind.
 
     tp_comm (serving, quant/collectives.py): route the row-parallel
     output projection through an explicit shard_map collective — dense
@@ -93,6 +98,7 @@ def attention_block(
     b, s, _ = x.shape
     D = cfg.head_dim
     nq, nkv = cfg.num_attention_heads, cfg.n_kv_heads
+    window = (kind or cfg.attention_kind).sliding_window_size
 
     # The scopes inside a region name its parts for a device trace (docs/
     # observability.md "Runtime traces"): projections, rotary, everything
@@ -166,7 +172,7 @@ def attention_block(
             ctx, kv_cache = paged_ring_attention(
                 cp_comm, q, k, v, kv_cache, layer, page_table, cache_index,
                 per_slot, page_write_start, page_write_end,
-                sliding_window=cfg.sliding_window_size)
+                sliding_window=window)
         elif kv_cache is not None:
             kv_cache = kv_store.write(kv_cache, layer, k, v, cache_index,
                                       page_table, page_write_start,
@@ -189,7 +195,7 @@ def attention_block(
                 mask_type=("bidirectional" if cfg.attn_mask_type == "padding"
                            else cfg.attn_mask_type),
                 padding_mask=padding_mask,
-                sliding_window=cfg.sliding_window_size,
+                sliding_window=window,
                 dropout=(cfg.attention_dropout
                          if attn_dropout_key is not None else 0.0),
                 dropout_rng=attn_dropout_key,
@@ -272,9 +278,14 @@ def block_forward(
     tp_comm=None,
     cp_comm=None,
     grad_sink=None,
+    kind: Optional[AttentionKind] = None,
 ):
     """One decoder layer -> (y, kv_cache, moe_aux, grad_sink): kv_cache is
     the whole store with this layer's rows written (attention_block).
+
+    kind: this layer's attention kind (attention_block), with `rope` that
+    kind's table. In a stack of several kinds the region `attention`
+    holds the layer under the scope `attn_<kind's name>`.
 
     grad_sink: float32 accumulators of the gradients of some of the
     stacked layers' leaves, in a tree shaped like the layers' params
@@ -300,7 +311,10 @@ def block_forward(
     # attention_block and mlp_block hold the middle ones) say which part
     # of its region an operation belongs to, and move no operation from
     # one region to another.
-    with jax.named_scope("attention"):
+    mixed = kind is not None and len(set(cfg.attention_period)) > 1
+    with jax.named_scope("attention"), (
+            jax.named_scope(f"attn_{kind.name}") if mixed
+            else contextlib.nullcontext()):
         # post-LN (ref --use_post_ln): no pre-norm; the layer ends with its
         # own LN, reusing the ln1 parameter slot as the output norm
         with jax.named_scope("attn_norm"):
@@ -315,6 +329,7 @@ def block_forward(
             page_write_end=page_write_end,
             tp_comm=tp_comm,
             cp_comm=cp_comm,
+            kind=kind,
         )
         with jax.named_scope("attn_out"):
             attn_out = _dropout(attn_out, rate, k_hidden1 if cfg.hidden_dropout > 0 else None)

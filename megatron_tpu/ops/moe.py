@@ -66,6 +66,14 @@ from megatron_tpu.ops.activations import apply_activation
 # the key of the load statistic in the loss's aux, the step's metrics and
 # the journal's `step` record
 LOAD_METRIC = "moe_load_max_over_mean"
+# the key, in the same places, of the share of a call's N * k (token,
+# choice) rows that the router sends to experts held here, mean of the
+# layers: only of a model that holds a share of a wider router's experts
+# (ModelConfig.moe_experts_held), where the even split is held / E
+HELD_METRIC = "moe_held_rows_share"
+# what of a loss's aux the step's metrics carry, each the mean of the
+# step's micro-batches
+STEP_METRICS = (LOAD_METRIC, HELD_METRIC)
 # the `checkpoint_name` of the dropless experts' two grouped products (the
 # second under rows_to_token_order, which keeps it for the backward):
 # selective recomputation saves weight-matmul outputs, and knows a
@@ -181,14 +189,25 @@ def _aux_losses(cfg: ModelConfig, logits, gates, frac):
 
 def layer_stats(aux, load) -> jnp.ndarray:
     """One MoE layer's [aux loss, load statistic] as block_forward hands
-    it up the layer scan."""
+    it up the layer scan; `load` of a layer that holds a share of its
+    router's experts is [load statistic, share of the rows routed to held
+    experts] (moe_block_dropless), and both go up."""
+    if load.ndim:
+        return jnp.concatenate([aux[None], load])
     return jnp.stack([aux, load])
 
 
+def moe_stats_zero(cfg: ModelConfig) -> jnp.ndarray:
+    """What merge_layer_stats starts from."""
+    return jnp.zeros((3,) if cfg.holds_expert_share else (2,), jnp.float32)
+
+
 def merge_layer_stats(acc: jnp.ndarray, new: jnp.ndarray) -> jnp.ndarray:
-    """Across layers the aux losses add and the worst layer's load
-    statistic stands."""
-    return jnp.stack([acc[0] + new[0], jnp.maximum(acc[1], new[1])])
+    """Across layers the aux losses add, the worst layer's load statistic
+    stands, and the held rows' shares add (language_model.lm_loss takes
+    their mean)."""
+    return jnp.stack([acc[0] + new[0], jnp.maximum(acc[1], new[1])]
+                     + [acc[i] + new[i] for i in range(2, acc.shape[0])])
 
 
 def aux_loss_of(moe_aux: jnp.ndarray) -> jnp.ndarray:
@@ -233,10 +252,12 @@ def _take_rows(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
 
 
 def _sum_of_choices(rows: jnp.ndarray, inv: jnp.ndarray,
-                    topw: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                    topw: Optional[jnp.ndarray] = None,
+                    kept: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """float32 [N, h]: the sum over a token's k choices j of
     rows[inv[n, j]] (times topw[n, j], where gates are given), rows
-    [N*k, h] standing in expert order. One gather of N rows a choice,
+    [N*k, h] standing in expert order; of a share of the experts, the
+    sum over the choices kept [N, k] alone. One gather of N rows a choice,
     added up as it arrives: the compiler fuses each gather with its
     multiply-add, where one gather of all N*k rows stands alone in front
     of the sum with an [N, k, h] temporary between them (on the chip 16.0
@@ -246,6 +267,8 @@ def _sum_of_choices(rows: jnp.ndarray, inv: jnp.ndarray,
         term = _take_rows(rows, inv[:, j]).astype(jnp.float32)
         if topw is not None:
             term = term * topw[:, j, None]
+        if kept is not None:
+            term = jnp.where(kept[:, j, None], term, 0.0)
         total = term if total is None else total + term
     return total
 
@@ -259,30 +282,33 @@ def _token_of(order: jnp.ndarray, k: int) -> jnp.ndarray:
 
 
 @jax.custom_vjp
-def rows_to_expert_order(xf, order, inv):
+def rows_to_expert_order(xf, order, inv, kept=None):
     """Token order -> expert order: xs[r] = xf[order[r] / k] for xf [N, h]
     and the permutation (order, inv) of its N*k (token, choice) rows
     (`sort_by_expert`). A gather forward; and since autodiff would
     transpose a gather into a scatter-add whatever its indices, the
     gradient is written out as what it is here: the cotangent's rows
     gathered through `inv`, each token's k summed in float32 and rounded
-    once."""
+    once. A share of the experts hands `kept` [N, k], the choices whose
+    expert it holds (else None): the others' rows give no gradient."""
     return _take_rows(xf, _token_of(order, inv.shape[1]))
 
 
-def _to_expert_fwd(xf, order, inv):
-    return rows_to_expert_order(xf, order, inv), inv
+def _to_expert_fwd(xf, order, inv, kept):
+    return rows_to_expert_order(xf, order, inv, kept), (inv, kept)
 
 
-def _to_expert_bwd(inv, dxs):
-    return _sum_of_choices(dxs, inv).astype(dxs.dtype), None, None
+def _to_expert_bwd(res, dxs):
+    inv, kept = res
+    return (_sum_of_choices(dxs, inv, kept=kept).astype(dxs.dtype),
+            None, None, None)
 
 
 rows_to_expert_order.defvjp(_to_expert_fwd, _to_expert_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def rows_to_token_order(out, topw, order, inv, dtype):
+def rows_to_token_order(out, topw, order, inv, dtype, kept=None):
     """Expert order -> token order with the gates: y[n] = sum over j of
     topw[n, j] * out[inv[n, j]] for out [N*k, h] in expert order and the
     gates topw [N, k]; the k terms are weighted and summed in float32 and
@@ -291,30 +317,41 @@ def rows_to_token_order(out, topw, order, inv, dtype):
     and d_topw[n, j] = <out[inv[n, j]], dy[n]>, taken as the row sums of
     out * dy[order / k] in expert order (the rows d_out reads anyway) and
     moved to token order as N*k scalars. Residuals are out, the gates and
-    the permutation: no second copy of the rows."""
-    return _sum_of_choices(out, inv, topw).astype(dtype)
+    the permutation: no second copy of the rows. A share of the experts
+    hands `kept` [N, k], the choices whose expert it holds (else None):
+    the sum is over those, and the others' gates get no gradient."""
+    return _sum_of_choices(out, inv, topw, kept).astype(dtype)
 
 
-def _to_token_fwd(out, topw, order, inv, dtype):
+def _to_token_fwd(out, topw, order, inv, dtype, kept):
     # selective recomputation keeps `out` for the gate gradient, by this
     # name. Named here, on a value that only the backward reads, and not
     # at the product: jax.checkpoint rounds every saved value that the
     # forward reads too (a `reduce_precision`), and behind a Pallas call
     # that is a pass of its own over all N*k rows
-    saved = checkpoint_name(out, SAVED_PRODUCT)
-    return (rows_to_token_order(out, topw, order, inv, dtype),
-            (saved, topw, order, inv))
+    # (not of a share of the experts: its buffer takes every row of the
+    # call, several times what the router sends here in the mean, and the
+    # backward pass makes the product again: one more `moe_gmm` a layer)
+    saved = out if kept is not None else checkpoint_name(out, SAVED_PRODUCT)
+    return (rows_to_token_order(out, topw, order, inv, dtype, kept),
+            (saved, topw, order, inv, kept))
 
 
 def _to_token_bwd(dtype, res, dy):
-    out, topw, order, inv = res
+    out, topw, order, inv, kept = res
+    if kept is not None:
+        # the gates of the choices held elsewhere are zero here, and so is
+        # what comes back for them
+        topw = jnp.where(kept, topw, 0.0)
     tokens = _token_of(order, inv.shape[1])
     dy_rows = _take_rows(dy, tokens).astype(jnp.float32)
     w = _permute(topw.reshape(-1), inv.reshape(-1))    # gates, expert order
     d_out = (dy_rows * w[:, None]).astype(out.dtype)
     d_w = jnp.sum(out.astype(jnp.float32) * dy_rows, axis=-1)
     d_topw = _permute(d_w, order).reshape(topw.shape)  # back to token order
-    return d_out, d_topw.astype(topw.dtype), None, None
+    if kept is not None:
+        d_topw = jnp.where(kept, d_topw, 0.0)
+    return d_out, d_topw.astype(topw.dtype), None, None, None
 
 
 rows_to_token_order.defvjp(_to_token_fwd, _to_token_bwd)
@@ -339,10 +376,70 @@ def expert_grad_sinks(cfg: ModelConfig, p: Dict[str, Any],
     if cfg.num_experts is None or cfg.moe_dispatch != "dropless":
         return ()
     rows = num_tokens * cfg.moe_top_k
-    if all(takes_sink(rows, *p[name].shape[-2:], cfg.num_experts)
+    if all(takes_sink(rows, *p[name].shape[-2:], cfg.experts_held)
            for name in EXPERT_MATRICES):
         return EXPERT_MATRICES
     return ()
+
+
+def experts_mlp(cfg: ModelConfig, p: Dict[str, Any], xs: jnp.ndarray,
+                group_sizes: jnp.ndarray, expert_of_row, dtype,
+                ragged: bool = False, grad_sink=None):
+    """The held experts' MLP over rows xs [R, h] that stand sorted by
+    expert: the next group_sizes[g] rows are expert g's, g counting the
+    experts whose matrices `p` holds. Returns ([R, h], the sink's stacks).
+    The two products are grouped GEMMs over those groups (grouped_matmul:
+    the program's Pallas kernels on one TPU, lax.ragged_dot elsewhere),
+    the activation between them in `dtype`; `expert_of_row()` gives each
+    row's expert for the biases, where the experts have them.
+
+    ragged: the groups may end before the rows do (a share of the experts:
+    the rows behind belong to experts held elsewhere, or to none). No
+    kernel visits those rows' tiles, so in every product's result (and in
+    the gradient of its rows) they hold whatever the buffer held: callers
+    read the result's rows through their own `kept` (`rows_to_token_order`,
+    `rows_to_expert_order`), and the first product's are set to zero on the
+    way into the activation (and so their gradient on the way back), which
+    keeps what the kernels contract over rows (`moe_tgmm`: a zeroed row on
+    one side still meets the other side's value) finite.
+
+    grad_sink = (stacks, layer), as moe_block has it: the matrix's
+    gradient goes into its stack where it has one."""
+    # with the other kernels: imported where it is used (ops/attention.py)
+    from megatron_tpu.ops.pallas.grouped_matmul import (
+        grouped_matmul, visits_for,
+    )
+
+    # one visit table for both products and their gradients (None where
+    # the products are lax.ragged_dot)
+    visits = visits_for(group_sizes, xs.shape[0])
+    stacks, layer = ({}, None) if grad_sink is None else grad_sink
+    stacks = dict(stacks)
+
+    def product(rows, name):
+        """rows · p[name] by group; the matrix's gradient goes into
+        its stack where it has one."""
+        if stacks.get(name) is None:
+            return grouped_matmul(rows, p[name], group_sizes,
+                                  visits=visits)
+        out, stacks[name] = grouped_matmul(
+            rows, p[name], group_sizes, visits=visits,
+            sink=(stacks[name], layer))
+        return out
+
+    hmid = checkpoint_name(product(xs, "w_in"), SAVED_PRODUCT)
+    if "b_in" in p:
+        # per-row expert bias: gather by the row's expert id
+        hmid = hmid + jnp.take(p["b_in"], expert_of_row(), axis=0)
+    if ragged:
+        grouped = jnp.arange(xs.shape[0]) < jnp.sum(group_sizes)
+        hmid = jnp.where(grouped[:, None], hmid, jnp.zeros_like(hmid))
+    hmid = apply_activation(cfg.activation, hmid.astype(dtype))
+    # (rows_to_token_order keeps this product for the backward)
+    out = product(hmid, "w_out")
+    if "b_out" in p:
+        out = out + jnp.take(p["b_out"], expert_of_row(), axis=0)
+    return out, stacks
 
 
 def moe_block_dropless(
@@ -366,6 +463,19 @@ def moe_block_dropless(
     exactly N*k MLP rows vs the capacity path's dense O(G*Sg*E*Cg)
     dispatch einsums.
 
+    One chip's share of an expert-parallel layer, run alone
+    (cfg.moe_experts_held of a router cfg.num_experts wide; `p` holds that
+    many experts' matrices): the router, its k a token, the gates (over
+    the token's k choices, as everywhere) and the load-balance statistics
+    are over all num_experts; the rows whose expert is held elsewhere sort
+    behind the held ones, where no group of the grouped products reaches
+    them (the buffer is all N*k rows still: no routing leaves a row out),
+    and y is the part of the layer's result that the held experts give:
+    the parts of all the shares add up to the whole layer's. Nothing
+    stands in for the other chips or their rows.
+    The load statistic is then a [2]-vector: behind it the share of the
+    N*k rows that went to held experts (HELD_METRIC).
+
     This function is the unsharded form: experts replicated, tokens
     unsharded (or sharded in ways the manual path can't host — batch not
     divisible by the batch axes, mesh missing the named axes). Whenever
@@ -374,16 +484,12 @@ def moe_block_dropless(
     batch-axis argsort collectives) and whose expert axis carries the
     explicit dispatch all-to-all.
     """
-    # with the other kernels: imported where it is used (ops/attention.py)
-    from megatron_tpu.ops.pallas.grouped_matmul import (
-        grouped_matmul, visits_for,
-    )
-
     b, s, h = x.shape
     N = b * s
     E = cfg.num_experts
     k = cfg.moe_top_k
     xf = x.reshape(N, h)
+    share = cfg.holds_expert_share
 
     with jax.named_scope("moe_router"):
         logits, gates, topw, topi = _route(cfg, p, xf)
@@ -391,44 +497,33 @@ def moe_block_dropless(
         group_sizes = _expert_counts(flat_e, E)
         aux, load = _aux_losses(cfg, logits, gates,
                                 group_sizes.astype(jnp.float32) / N)
+        mine = None
+        if share:
+            held = cfg.moe_experts_held
+            first = cfg.moe_expert_share * held
+            mine = (topi >= first) & (topi < first + held)
+            # held experts by their place here; the others behind them all
+            topi = jnp.where(mine, topi - first, held)
+            group_sizes = group_sizes[first:first + held]
+            load = jnp.stack([load, jnp.sum(group_sizes).astype(jnp.float32)
+                              / (N * k)])
 
     with jax.named_scope("moe_dispatch"):
         # the (token, choice) rows sorted by expert, and the way back
         order, inv = sort_by_expert(topi)
-        xs = rows_to_expert_order(xf, order, inv)      # [N*k, H] sorted
+        xs = rows_to_expert_order(xf, order, inv, mine)  # [N*k, H] sorted
 
     with jax.named_scope("moe_experts"):
-        # one visit table for both products and their gradients (None
-        # where the products are lax.ragged_dot)
-        visits = visits_for(group_sizes, N * k)
-        stacks, layer = ({}, None) if grad_sink is None else grad_sink
-        stacks = dict(stacks)
-
-        def product(rows, name):
-            """rows · p[name] by group; the matrix's gradient goes into
-            its stack where it has one."""
-            if stacks.get(name) is None:
-                return grouped_matmul(rows, p[name], group_sizes,
-                                      visits=visits)
-            out, stacks[name] = grouped_matmul(
-                rows, p[name], group_sizes, visits=visits,
-                sink=(stacks[name], layer))
-            return out
-
-        hmid = checkpoint_name(product(xs, "w_in"), SAVED_PRODUCT)
-        if "b_in" in p:
-            # per-row expert bias: gather by the row's expert id
-            hmid = hmid + jnp.take(p["b_in"], jnp.take(flat_e, order), axis=0)
-        hmid = apply_activation(cfg.activation, hmid.astype(x.dtype))
-        # (rows_to_token_order keeps this product for the backward)
-        out = product(hmid, "w_out")
-        if "b_out" in p:
-            out = out + jnp.take(p["b_out"], jnp.take(flat_e, order), axis=0)
+        out, stacks = experts_mlp(
+            cfg, p, xs, group_sizes,
+            lambda: jnp.take(jnp.minimum(topi.reshape(-1), held - 1)
+                             if share else flat_e, order),
+            x.dtype, ragged=share, grad_sink=grad_sink)
 
     with jax.named_scope("moe_combine"):
         # back to token order through the inverse sort; each token's k
         # choices weighted by its gates and summed in float32
-        y = rows_to_token_order(out, topw, order, inv, x.dtype)
+        y = rows_to_token_order(out, topw, order, inv, x.dtype, mine)
         y = y.reshape(b, s, h)
     return (y, aux, load) if grad_sink is None else (y, aux, load, stacks)
 
@@ -554,9 +649,9 @@ def moe_block_dropless_ep(
     WEIGHTS sharded E/ep). Smaller f scales FLOPs/memory by f/ep at the
     cost of greedy source-order drops when one shard's experts attract
     more than f x fair-share rows — the same failure semantics as
-    capacity dispatch, at shard granularity. ragged_dot cost is
-    proportional to R either way (rows in the slack tail multiply a
-    zero-weight trash expert; XLA's grouped GEMM cannot skip them).
+    capacity dispatch, at shard granularity. The rows of the slack tail
+    belong to no group of the grouped GEMMs and come back zero
+    (experts_mlp).
 
     Transport is ragged_all_to_all on TPU; CPU (and therefore CI) uses an
     all_gather reconstruction with identical math — the ragged path is on
@@ -638,20 +733,19 @@ def moe_block_dropless_ep(
         run = jnp.cumsum(delta[:-1])
         ids = jnp.where(run > 0, run - 1, El)
 
-        # ---- grouped GEMMs over local experts (+ zero trash expert) --
+        # ---- the local experts' MLP over the received rows sorted by
+        # local expert, the slack tail behind them (experts_mlp: what a
+        # share of the experts runs on one chip without the exchange) ---
         order2 = jnp.argsort(ids, stable=True)
         xs2 = jnp.take(recv_buf, order2, axis=0)
         ids2 = jnp.take(ids, order2)
-        gsz = jnp.bincount(ids2, length=El + 1).astype(jnp.int32)
-        pad = lambda w: jnp.concatenate(
-            [w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
-        hmid = jax.lax.ragged_dot(xs2, pad(w_in), gsz)
+        gsz = jnp.bincount(ids2, length=El + 1).astype(jnp.int32)[:El]
+        local = {"w_in": w_in, "w_out": w_out}
         if has_b:
-            hmid = hmid + jnp.take(pad(b_in), ids2, axis=0)
-        hmid = apply_activation(cfg.activation, hmid.astype(xb.dtype))
-        out2 = jax.lax.ragged_dot(hmid, pad(w_out), gsz)
-        if has_b:
-            out2 = out2 + jnp.take(pad(b_out), ids2, axis=0)
+            local.update(b_in=b_in, b_out=b_out)
+        out2, _ = experts_mlp(cfg, local, xs2, gsz,
+                              lambda: jnp.minimum(ids2, El - 1), xb.dtype,
+                              ragged=True)
         out_rows = (jnp.zeros((R, h), out2.dtype).at[order2].set(out2))
 
         # ---- return trip along the mirrored route --------------------
@@ -733,6 +827,9 @@ def moe_block(
     if grad_sink is not None:
         # expert_grad_sinks names a leaf only where this form runs
         return moe_block_dropless(cfg, p, x, grad_sink)
+    if cfg.holds_expert_share:
+        # one chip's share runs without the exchange, whatever the mesh
+        return moe_block_dropless(cfg, p, x)
     if cfg.moe_dispatch == "dropless":
         dsz, ep, named_axes = _ambient_batch_axes()
         # manual data axis (per-shard local sort, no batch-axis argsort

@@ -852,19 +852,26 @@ def load_params_only(
 #: with an opaque shape error instead of this check's clear message
 SHAPE_KEYS = ("num_layers", "encoder_num_layers", "decoder_num_layers",
               "hidden_size", "num_attention_heads", "num_kv_heads",
-              "ffn_hidden_size", "vocab_size")
+              "ffn_hidden_size", "vocab_size", "moe_experts_held")
 
 #: same-shape drift keys — a mismatch restores CLEANLY and then silently
 #: trains a different model (the silent-killer class from VERDICT r3 weak
 #: #3: same weights, different forward function)
 DRIFT_KEYS = ("normalization", "activation", "position_embedding_type",
               "rope_theta", "rope_scaling_factor", "sliding_window_size",
-              "qk_norm",
+              "attention_pattern", "moe_expert_share", "qk_norm",
               "tie_embed_logits", "parallel_attn", "parallel_layernorm",
               "use_post_ln", "apply_residual_post_ln", "attn_mask_type",
               "use_bias_linear", "use_bias_qkv", "layernorm_epsilon",
               "num_experts", "moe_top_k", "moe_renorm_gates",
               "moe_dispatch", "moe_capacity_factor", "moe_group_size")
+
+
+def _plain(value):
+    """A config value as JSON hands it back (a tuple comes back a list)."""
+    if isinstance(value, (list, tuple)):
+        return [_plain(v) for v in value]
+    return value
 
 
 def check_config_compatibility(saved: Dict[str, Any], current: Dict[str, Any]):
@@ -880,7 +887,7 @@ def check_config_compatibility(saved: Dict[str, Any], current: Dict[str, Any]):
            f"current={current_model.get(k)!r}"
            for k in SHAPE_KEYS + DRIFT_KEYS
            if k in saved_model and k in current_model
-           and saved_model.get(k) != current_model.get(k)]
+           and _plain(saved_model.get(k)) != _plain(current_model.get(k))]
     if bad:
         raise ValueError(
             "checkpoint/config architecture mismatch — resuming would "

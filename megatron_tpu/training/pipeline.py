@@ -54,7 +54,7 @@ from megatron_tpu.models.language_model import (
 from megatron_tpu.models.transformer import block_forward
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
 from megatron_tpu.ops.moe import aux_loss_of
-from megatron_tpu.ops.rotary import precompute_rope
+from megatron_tpu.ops.rotary import rope_table
 
 
 def _embed_onehot(cfg: ModelConfig, params: Dict[str, Any],
@@ -273,10 +273,8 @@ def make_pipeline_loss_fn(
 
         rope = None
         if model_cfg.position_embedding_type == "rotary":
-            rope = precompute_rope(model_cfg.head_dim,
-                                   max(model_cfg.seq_length, S),
-                                   model_cfg.rope_theta,
-                                   model_cfg.rope_scaling_factor)
+            rope = rope_table(model_cfg.attention_kind, model_cfg.head_dim,
+                              max(model_cfg.seq_length, S))
 
         T = M * V + Pn - 1  # pipeline ticks
 

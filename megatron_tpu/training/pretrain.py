@@ -37,7 +37,7 @@ from megatron_tpu.models.language_model import (
     is_full_remat_family, lm_loss,
 )
 from megatron_tpu.models.params import init_params, param_specs
-from megatron_tpu.ops.moe import LOAD_METRIC
+from megatron_tpu.ops.moe import STEP_METRICS
 from megatron_tpu.parallel.mesh import MeshRuntime, build_mesh
 from megatron_tpu.platform import device_summary, enable_compile_cache
 from megatron_tpu.telemetry.tracing import capture
@@ -1487,8 +1487,8 @@ class TrainLoop:
         data_crc = self._batch_fps.pop(it, None)
         if self.telemetry is not None:
             extra = {"data_crc": data_crc} if data_crc else {}
-            if LOAD_METRIC in host:
-                extra[LOAD_METRIC] = round(float(host[LOAD_METRIC]), 4)
+            extra.update({k: round(float(host[k]), 4)
+                          for k in STEP_METRICS if k in host})
             self.telemetry.step(
                 it, step_s, ntok, rec["compile_delta"],
                 loss=loss_host,

@@ -35,7 +35,7 @@ from megatron_tpu.models.language_model import (
     GRAD_SINK, grad_sink_leaves, lm_loss,
 )
 from megatron_tpu.models.transformer import Sharder, _identity_sharder
-from megatron_tpu.ops.moe import LOAD_METRIC
+from megatron_tpu.ops.moe import STEP_METRICS
 from megatron_tpu.parallel.random import RngStreams
 from megatron_tpu.training.optimizer import TrainState, make_optimizer_step
 
@@ -153,9 +153,9 @@ def make_train_step(
                 loss, aux = loss_fn(model_cfg, _either(p, held), mb, key,
                                     **({"grad_sink": sink} if any_summed
                                        else {}))
-                # None for a model without experts: no leaf, no output
+                # empty for a model without experts: no leaf, no output
                 return ((loss * scale, aux.get(GRAD_SINK, sink)),
-                        (loss, aux.get(LOAD_METRIC)))
+                        (loss, {k: aux[k] for k in STEP_METRICS if k in aux}))
 
             # A summed leaf is no argument of the differentiated function,
             # so its own gradient is never formed; its accumulator is one,
@@ -174,17 +174,18 @@ def make_train_step(
         # the loop's own slicing of the batch and carrying of the
         # accumulators sit under no region: this names them for a trace
         with jax.named_scope("micro_batches"):
-            acc, (losses, loads) = jax.lax.scan(one_micro, zeros,
-                                                (micro, jnp.arange(n)))
+            acc, (losses, moe) = jax.lax.scan(one_micro, zeros,
+                                              (micro, jnp.arange(n)))
         # mean over microbatches; scaled grads stay scaled for the optimizer
         grads = jax.tree.map(lambda g: g / n, acc)
 
         with jax.named_scope("optimizer"):
             new_state, metrics = opt_apply(state, grads)
         metrics["loss"] = jnp.mean(losses)
-        if loads is not None:
-            # worst layer's largest expert over the mean, mean of micro-batches
-            metrics[LOAD_METRIC] = jnp.mean(loads)
+        # a model with experts: the worst layer's largest expert over the
+        # mean and, of a share, the rows routed to held experts; each the
+        # mean of the micro-batches
+        metrics.update({k: jnp.mean(v) for k, v in moe.items()})
         return new_state, metrics
 
     return train_step

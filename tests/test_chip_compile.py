@@ -373,6 +373,79 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
         16 * SEQ // cfg.ce_chunk_size)
 
 
+def test_mellum_cell_step_fits_one_v5e(topo):
+    """The step of the benchmark's `train_mellum2_share4_seq8k` cell, built
+    from the cell's own files as the harness builds it: Mellum 2 widths,
+    one period of four layers (three window-1024, one full under YaRN), 16
+    of the router's 64 experts held, sequence 8192 at the micro-batch the
+    mix states. It fits the 15.75 GiB a program gets at that micro-batch
+    (ISSUE 42's fallback, micro-batch 1 with 2 accumulated, is not taken);
+    every layer's window is static at its kernel calls: four `flash_fwd`,
+    four fused `flash_bwd` (at 8192 rows dq of a head's sequence fits in
+    VMEM) and their statistics, each under its kind's scope in region
+    `attention`, three sliding for one full; the held experts' six
+    products a layer are the program's own kernels over 16 groups of a
+    buffer that takes every one of the call's 131,072 (token, choice)
+    rows (the share path has no shorter one: no routing leaves a row
+    out), and a seventh is the second product computed again for the gate's gradient
+    (a share does not keep it: ops/moe.py rows_to_token_order); none is
+    left to XLA's `ragged-dot`."""
+    from benchmark.harness import spec
+    from megatron_tpu.arguments import args_to_run_config, parse_args
+    from megatron_tpu.telemetry.tracing.events import scope_tokens
+    from megatron_tpu.training.aot import aot_compile_train_step
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = spec.Cell(os.path.join(repo, "BENCHMARK.json"),
+                     "train_mellum2_share4_seq8k")
+    mix = cell.traffic
+    flags = spec.load_module(cell.reference_path()).program_flags(
+        cell.config, mix["seq_length"])
+    run = args_to_run_config(parse_args(
+        flags + cell.config["program"]["flags"] + mix["flags"]
+        + ["--micro_batch_size", str(mix["micro_batch_size"]),
+           "--global_batch_size", str(mix["global_batch_size"])]))
+    cfg = run.model
+    assert (mix["micro_batch_size"], mix["global_batch_size"]) == (2, 2)
+    compiled, _ = aot_compile_train_step(
+        cfg, ParallelConfig(), OptimizerConfig(lr=1e-4),
+        micro_batch_size=2, num_microbatches=1,
+        recompute=run.training.recompute_granularity,
+        devices=topo.devices[:1])
+    assert 0.25 * 16e9 < _per_device_bytes(compiled) < 15.75 * GIB
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert collections.Counter(_kernels_named(text)) == {
+        "flash_fwd": 4, "flash_bwd": 4, "flash_bwd_stats": 4,
+        "moe_gmm": 20, "moe_tgmm": 8}
+    kinds = collections.Counter()
+    for toks in _kernel_name_stacks(text):
+        kernel = toks[-2]
+        if kernel.startswith("flash_"):
+            at = toks.index("attention")
+            assert toks[at + 1] in ("attn_sliding", "attn_full"), toks
+            kinds[kernel, toks[at + 1]] += 1
+        else:
+            assert toks.index("mlp") < toks.index("moe_experts"), toks
+    assert kinds == {(k, "attn_sliding"): 3 for k in (
+        "flash_fwd", "flash_bwd", "flash_bwd_stats")} | {
+        (k, "attn_full"): 1 for k in (
+            "flash_fwd", "flash_bwd", "flash_bwd_stats")}
+    # the grouped products run over the buffer's rows and the 16 held
+    # experts' matrices
+    results = set(re.findall(r"%moe_t?gmm[.\d]* = (\w+\[[\d,]+\])", text))
+    assert results == {"bf16[131072,1792]", "bf16[131072,2304]",
+                       "bf16[131072,896]", "bf16[16,2304,1792]",
+                       "bf16[16,896,2304]"}, results
+    names = set(re.findall(r'op_name="([^"]+)"', text))
+    stacks = [scope_tokens(n) for n in names]
+    for scope in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine", "attn_sliding", "attn_full"):
+        assert any(scope in toks for toks in stacks), scope
+    assert not [op for op in _scatter_op_names(text)
+                if "mlp" in scope_tokens(op)]
+
+
 def _computations(text):
     """{name: [instruction lines]} of a compiled program's text, and the
     names of the computations that are some fusion's body."""
