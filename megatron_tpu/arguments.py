@@ -159,9 +159,10 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     g.add_argument("--recompute_granularity", default="none",
                    choices=["none", "selective", "full"],
                    help="what a layer keeps for its backward pass. "
-                        "selective keeps the weight matmuls' outputs and "
-                        "computes norms, rotary, activations and the dense "
-                        "core attention again; with --attention_impl "
+                        "selective keeps the weight matmuls' outputs "
+                        "(the rotated q and k in place of the projected "
+                        "ones) and computes norms, activations and the "
+                        "dense core attention again; with --attention_impl "
                         "pallas it also keeps the flash kernel's output "
                         "and log-sum-exp (one hidden-state-sized tensor "
                         "and seq_length floats a head, a layer, a "

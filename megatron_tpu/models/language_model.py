@@ -40,7 +40,7 @@ from megatron_tpu.ops.moe import (
 from megatron_tpu.ops.pallas.flash_template import SAVED_RESIDUAL
 from megatron_tpu.ops.weight_quant import deq, take_rows
 from megatron_tpu.ops.normalization import norm_forward
-from megatron_tpu.ops.rotary import rope_table
+from megatron_tpu.ops.rotary import SAVED_ROTATED, rope_table
 
 
 def parse_recompute(recompute: str):
@@ -86,18 +86,22 @@ def _remat_policy(recompute: str):
         return jax.checkpoint_policies.nothing_saveable
     if recompute == "selective":
         # save weight-matmul outputs and recompute what is cheap beside
-        # them: norms, rotary, activations, the layout changes, and the
+        # them: norms, activations, the layout changes, and the
         # dense core attention, whose S x S scores are a layer's largest
         # activation (the reference's selective recompute,
         # transformer.py:391-410). The flash kernel keeps no scores, so
         # its forward is not run again: its output and its log-sum-exp
         # (one hidden-state-sized tensor and S floats a head) are saved
         # by name, as are the dropless experts' grouped products — Pallas
-        # calls' results both, which the policy does not know for dots
+        # calls' results both, which the policy does not know for dots —
+        # and the rotated q and k: the backward needs them and not the
+        # projections' own results, which then are not kept (without a
+        # QK-norm, whose backward reads them), so rotary is not applied
+        # a third time and its half turn, a dot, is not kept either
         return jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(
-                SAVED_PRODUCT, SAVED_RESIDUAL))
+                SAVED_PRODUCT, SAVED_RESIDUAL, SAVED_ROTATED))
     raise ValueError(f"unknown recompute policy {recompute!r}")
 
 

@@ -18,7 +18,13 @@ from __future__ import annotations
 import math
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+SAVED_ROTATED = "rotary_rotated"
 
 
 def precompute_rope(
@@ -91,10 +97,53 @@ def rope_table(kind, head_dim: int, max_positions: int,
             (jnp.sin(emb) * scale).astype(dtype))
 
 
-def _rotate_half(x: jnp.ndarray) -> jnp.ndarray:
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([-x2, x1], axis=-1)
+def _half_turn(x: jnp.ndarray, signed: bool = True) -> jnp.ndarray:
+    """rotate_half(x) == concatenate([-x[..., D/2:], x[..., :D/2]]) as the
+    product x @ R, R the [D, D] signed permutation (unsigned: the halves
+    swapped). Every element of the product is one element of x times +-1
+    plus zeros, so it is exact in x's own dtype, and the lane axis is never
+    split: a slice and concatenate of a head's halves is not fused on the
+    TPU (the compiler writes both halves out, lane-padded)."""
+    d = x.shape[-1]
+    j = np.arange(d)
+    r = np.zeros((d, d), np.float32)
+    r[(j + d // 2) % d, j] = np.where(j < d // 2, -1, 1) if signed else 1
+    one_pass = x.dtype == jnp.bfloat16  # bf16 products are exact on the MXU
+    return lax.dot_general(
+        x, jnp.asarray(r, x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+        precision=None if one_pass else lax.Precision.HIGHEST,
+        preferred_element_type=x.dtype)
+
+
+def _turn(x, cos, sin):
+    """x * cos + rotate_half(x) * sin in float32, rounded once to x's
+    dtype: one pass that reads x once and writes it once."""
+    return (x.astype(jnp.float32) * cos
+            + _half_turn(x).astype(jnp.float32) * sin).astype(x.dtype)
+
+
+@jax.custom_vjp
+def _rotate(x, cos, sin):
+    return _turn(x, cos, sin)
+
+
+def _rotate_fwd(x, cos, sin):
+    return _turn(x, cos, sin), (cos, sin)
+
+
+def _rotate_bwd(tables, dy):
+    # Linear in x, so the cotangent takes the transposed pass and needs no
+    # activation: dx = dy * cos + (dy * sin) @ R^T. With R^T = -R the
+    # permutation moves to the cotangent itself, which is exact where
+    # autodiff turns the float32 dy * sin: dx = dy * cos - (dy @ R) * sin',
+    # sin' the sine with its halves swapped (a table, not an activation).
+    # The tables are constants of the model (stop_gradient below).
+    cos, sin = tables
+    return (_turn(dy, cos, -_half_turn(sin, signed=False)),
+            jnp.zeros_like(cos), jnp.zeros_like(sin))
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
 
 
 def apply_rotary_emb(
@@ -108,18 +157,18 @@ def apply_rotary_emb(
 
     positions: [batch, seq] int ids; None => 0..seq-1. Non-monotonic ids
     (packed sequences) are supported via gather, as in the reference.
+
+    The results carry the name SAVED_ROTATED: a layer's checkpoint that
+    keeps them (models/language_model.py, `selective`) applies rotary
+    twice a layer, forward and backward, and not again in between.
     """
     if positions is None:
         seq = q.shape[1]
-        cos_g, sin_g = cos[None, :seq], sin[None, :seq]
+        tables = (cos[None, :seq], sin[None, :seq])
     else:
-        cos_g, sin_g = cos[positions], sin[positions]
+        tables = (cos[positions], sin[positions])
     # [B, S, D] -> [B, S, 1, D] to broadcast over heads
-    cos_g = cos_g[:, :, None, :].astype(jnp.float32)
-    sin_g = sin_g[:, :, None, :].astype(jnp.float32)
-
-    def rot(x):
-        xf = x.astype(jnp.float32)
-        return (xf * cos_g + _rotate_half(xf) * sin_g).astype(x.dtype)
-
-    return rot(q), rot(k)
+    tables = [lax.stop_gradient(t[:, :, None, :].astype(jnp.float32))
+              for t in tables]
+    return (checkpoint_name(_rotate(q, *tables), SAVED_ROTATED),
+            checkpoint_name(_rotate(k, *tables), SAVED_ROTATED))

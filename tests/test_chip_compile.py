@@ -373,7 +373,34 @@ def test_olmoe_cell_step_fits_one_v5e(topo):
         16 * SEQ // cfg.ce_chunk_size)
 
 
-def test_mellum_cell_step_fits_one_v5e(topo):
+@pytest.fixture(scope="module")
+def mellum_step(topo):
+    """The compiled step of the benchmark's `train_mellum2_share4_seq8k`
+    cell, built from the cell's own files as the harness builds it."""
+    from benchmark.harness import spec
+    from megatron_tpu.arguments import args_to_run_config, parse_args
+    from megatron_tpu.training.aot import aot_compile_train_step
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = spec.Cell(os.path.join(repo, "BENCHMARK.json"),
+                     "train_mellum2_share4_seq8k")
+    mix = cell.traffic
+    flags = spec.load_module(cell.reference_path()).program_flags(
+        cell.config, mix["seq_length"])
+    run = args_to_run_config(parse_args(
+        flags + cell.config["program"]["flags"] + mix["flags"]
+        + ["--micro_batch_size", str(mix["micro_batch_size"]),
+           "--global_batch_size", str(mix["global_batch_size"])]))
+    assert (mix["micro_batch_size"], mix["global_batch_size"]) == (2, 2)
+    compiled, _ = aot_compile_train_step(
+        run.model, ParallelConfig(), OptimizerConfig(lr=1e-4),
+        micro_batch_size=2, num_microbatches=1,
+        recompute=run.training.recompute_granularity,
+        devices=topo.devices[:1])
+    return compiled
+
+
+def test_mellum_cell_step_fits_one_v5e(mellum_step):
     """The step of the benchmark's `train_mellum2_share4_seq8k` cell, built
     from the cell's own files as the harness builds it: Mellum 2 widths,
     one period of four layers (three window-1024, one full under YaRN), 16
@@ -390,28 +417,9 @@ def test_mellum_cell_step_fits_one_v5e(topo):
     out), and a seventh is the second product computed again for the gate's gradient
     (a share does not keep it: ops/moe.py rows_to_token_order); none is
     left to XLA's `ragged-dot`."""
-    from benchmark.harness import spec
-    from megatron_tpu.arguments import args_to_run_config, parse_args
     from megatron_tpu.telemetry.tracing.events import scope_tokens
-    from megatron_tpu.training.aot import aot_compile_train_step
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cell = spec.Cell(os.path.join(repo, "BENCHMARK.json"),
-                     "train_mellum2_share4_seq8k")
-    mix = cell.traffic
-    flags = spec.load_module(cell.reference_path()).program_flags(
-        cell.config, mix["seq_length"])
-    run = args_to_run_config(parse_args(
-        flags + cell.config["program"]["flags"] + mix["flags"]
-        + ["--micro_batch_size", str(mix["micro_batch_size"]),
-           "--global_batch_size", str(mix["global_batch_size"])]))
-    cfg = run.model
-    assert (mix["micro_batch_size"], mix["global_batch_size"]) == (2, 2)
-    compiled, _ = aot_compile_train_step(
-        cfg, ParallelConfig(), OptimizerConfig(lr=1e-4),
-        micro_batch_size=2, num_microbatches=1,
-        recompute=run.training.recompute_granularity,
-        devices=topo.devices[:1])
+    compiled = mellum_step
     assert 0.25 * 16e9 < _per_device_bytes(compiled) < 15.75 * GIB
     text = compiled.as_text()
     assert "ragged-dot" not in text
@@ -444,6 +452,66 @@ def test_mellum_cell_step_fits_one_v5e(topo):
         assert any(scope in toks for toks in stacks), scope
     assert not [op for op in _scatter_op_names(text)
                 if "mlp" in scope_tokens(op)]
+
+
+def _rotary_results(text, seq, heads, d):
+    """(dtype, dims, times a step, bytes) of every array that an
+    instruction under the scope `attn_rope` writes, outside fusions'
+    bodies. Bytes where it is q-, k- or table-shaped: a last dimension of
+    d or d / 2 (lane-padded to 128) behind the sequence, the dims in front
+    taken as the device's rows (of a loop's stacked buffer one layer's
+    slice is written and counts), or (seq, heads * d) flat. What a
+    collective fusion carries beside the pass (the next layer's gathered
+    weights) is nobody's q and counts 0."""
+    from megatron_tpu.analysis.step_program import _NO_WORK, Program
+    from megatron_tpu.telemetry.tracing.events import scope_tokens
+
+    program = Program(text)
+    found = []
+    for comp, line, _name, results, opcode in program.instructions():
+        if (program.fused[comp] or opcode in _NO_WORK
+                or "attn_rope" not in scope_tokens(
+                    program.op_name(comp, line))):
+            continue
+        for dtype, dims in _RESULT.findall(results):
+            dims = tuple(int(i) for i in dims.split(",") if i)
+            nbytes = 0
+            if dims[-1:] in ((d,), (d // 2,)) and seq in dims[-3:-1]:
+                nbytes = (_ITEMSIZE[dtype] * math.prod(dims[-4:-1])
+                          * max(dims[-1], 128))
+            elif dims[-2:] in {(seq, h * d) for h in heads}:
+                nbytes = _ITEMSIZE[dtype] * math.prod(dims[-3:])
+            found.append((dtype, dims, program.times[comp], nbytes))
+    return found
+
+
+# fixture, rows of the batch a device holds, sequence, (q heads, kv heads)
+# a device holds, layers
+_ROTARY_STEPS = {
+    "one_chip": ("one_chip_step", 1, SEQ, (HQ, HKV), 2),
+    "tp2_dp2": ("tp2_dp2_step", 1, SEQ, (HQ // 2, HKV // 2), 2),
+    "mellum": ("mellum_step", 2, 8192, (32, 4), 4),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROTARY_STEPS))
+def test_rotary_is_one_pass_in_the_compiled_step(request, case):
+    """In the compiled steps nothing under `attn_rope` has a result half a
+    head wide (ops/rotary.py: the half turn is a product, and the chip's
+    compiler does not fuse a slice and concatenate of the lane axis: it
+    wrote both halves of q out in float32, lane-padded, three times a
+    layer), and what is written under `attn_rope` is at most three times
+    what the applications write of q and k: two a layer, forward and
+    backward, since `selective` keeps the rotated q and k (1.5 to 2.0
+    times as this was written; the slices read 10.8)."""
+    fixture, rows, seq, heads, layers = _ROTARY_STEPS[case]
+    step = request.getfixturevalue(fixture)
+    compiled = step[0] if isinstance(step, tuple) else step  # (step, meta)
+    found = _rotary_results(compiled.as_text(), seq, heads, D)
+    assert not [f for f in found if f[1][-1:] == (D // 2,)], found
+    written = sum(times * nbytes for _, _, times, nbytes in found)
+    needed = layers * 2 * rows * seq * sum(heads) * D * 2
+    assert needed <= written <= 3 * needed, written / needed
 
 
 def _computations(text):

@@ -2,6 +2,8 @@
 (counterpart of reference tests/test_activations.py and
 megatron/mpu/tests/test_cross_entropy.py)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +13,10 @@ from megatron_tpu.ops.activations import apply_activation
 from megatron_tpu.ops.attention import attention
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
 from megatron_tpu.ops.normalization import layernorm, rmsnorm
-from megatron_tpu.ops.rotary import apply_rotary_emb, precompute_rope
+from megatron_tpu.config import AttentionKind
+from megatron_tpu.ops.rotary import (
+    apply_rotary_emb, precompute_rope, rope_table,
+)
 
 RNG = np.random.default_rng(0)
 
@@ -92,6 +97,104 @@ def test_rope_scaling_interpolates():
     cos2, _ = precompute_rope(8, 64, scaling_factor=2.0)
     # position 2p at scale 2 == position p at scale 1
     np.testing.assert_allclose(np.asarray(cos2)[10], np.asarray(cos1)[5], atol=1e-6)
+
+
+def _plain_rotary(q, k, cos, sin, positions=None):
+    """The formula as it is written down: the head's halves sliced,
+    one negated, concatenated, in float32. The reference that
+    apply_rotary_emb, which never splits the head, is held to."""
+    if positions is None:
+        cos_g, sin_g = cos[None, :q.shape[1]], sin[None, :q.shape[1]]
+    else:
+        cos_g, sin_g = cos[positions], sin[positions]
+    cos_g = cos_g[:, :, None, :].astype(jnp.float32)
+    sin_g = sin_g[:, :, None, :].astype(jnp.float32)
+
+    def rot(x):
+        xf = x.astype(jnp.float32)
+        half = x.shape[-1] // 2
+        turned = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
+        return (xf * cos_g + turned * sin_g).astype(x.dtype)
+
+    return rot(q), rot(k)
+
+
+def _rope_tables(kind_name, head_dim, max_positions):
+    if kind_name == "plain":
+        return precompute_rope(head_dim, max_positions)
+    if kind_name == "yarn":
+        return rope_table(
+            AttentionKind(rope_type="yarn", rope_theta=500000.0,
+                          rope_scaling_factor=16.0,
+                          yarn_original_max_positions=16).validate(),
+            head_dim, max_positions)
+    # no table of the program's: halves that differ, so a backward that
+    # forgot to swap the sine's halves is seen
+    cos, sin = precompute_rope(head_dim, max_positions)
+    return cos, sin * jnp.linspace(0.5, 1.5, head_dim)
+
+
+@pytest.mark.parametrize("table", ["plain", "yarn", "unequal_halves"])
+@pytest.mark.parametrize("with_positions", [False, True],
+                         ids=["in_order", "positions"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float16, jnp.float32],
+                         ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+def test_rotary_equals_the_plain_formula(table, with_positions, dtype, jit):
+    """apply_rotary_emb (the half turn as a product with a signed
+    permutation, a hand-written backward) against slice-negate-concatenate
+    and its autodiff: the same bits for bf16 and float16 operands, forward
+    and both cotangents, GQA shapes, positions in order and not (op by op;
+    as one compiled function each, to a unit of the dtype's last place);
+    float32 operands to 1e-6."""
+    b, s, hq, hkv, d, pmax = 2, 12, 4, 2, 16, 64
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    q = jax.random.normal(keys[0], (b, s, hq, d), dtype)
+    k = jax.random.normal(keys[1], (b, s, hkv, d), dtype)
+    cos, sin = _rope_tables(table, d, pmax)
+    positions = (jax.random.randint(keys[2], (b, s), 0, pmax)
+                 if with_positions else None)
+    if with_positions:
+        assert not bool(jnp.all(jnp.diff(positions, axis=1) > 0))
+    ours = lambda q, k: apply_rotary_emb(q, k, cos, sin, positions)
+    plain = lambda q, k: _plain_rotary(q, k, cos, sin, positions)
+    if jit:
+        ours, plain = jax.jit(ours), jax.jit(plain)
+    got, got_vjp = jax.vjp(ours, q, k)
+    want, want_vjp = jax.vjp(plain, q, k)
+    cts = (jax.random.normal(keys[3], q.shape, dtype),
+           jax.random.normal(keys[4], k.shape, dtype))
+    for a, w in zip(got + got_vjp(cts), want + want_vjp(cts)):
+        assert a.dtype == w.dtype and a.shape == w.shape
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, w, rtol=0, atol=1e-6)
+        elif not jit:
+            assert jnp.array_equal(a, w)
+        else:
+            # one compiled function each: the CPU's compiler may contract
+            # the multiply-add of one form and not of the other, which
+            # moves the float32 sum by a unit and so, rarely, the rounding
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(w, np.float32),
+                rtol=float(jnp.finfo(dtype).eps), atol=0)
+
+
+def test_rotary_never_splits_the_head():
+    """No concatenate in the traced function or its backward, and no
+    slice that cuts the head dimension (the tables' rows are sliced)."""
+    d = 16
+    q = jnp.zeros((2, 8, 4, d), jnp.bfloat16)
+    k = jnp.zeros((2, 8, 2, d), jnp.bfloat16)
+    cos, sin = precompute_rope(d, 32)
+
+    def loss(q, k):
+        qr, kr = apply_rotary_emb(q, k, cos, sin)
+        return (qr.astype(jnp.float32).sum() + kr.astype(jnp.float32).sum())
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(q, k))
+    assert "dot_general" in text and "concatenate" not in text
+    sliced = re.findall(r":\w+\[([\d,]+)\] = (?:dynamic_)?slice\[", text)
+    assert all(dims.endswith(f",{d}") for dims in sliced), sliced
 
 
 def _ref_attention(q, k, v, causal=True, window=None):
