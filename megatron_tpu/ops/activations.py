@@ -21,7 +21,24 @@ def _split_glu(x: jnp.ndarray):
     return gate, up
 
 
+def glu_activation(name: str, gate: jnp.ndarray,
+                   up: jnp.ndarray) -> jnp.ndarray:
+    """act(gate) * up of the GLU `name`, the halves already apart (a
+    kernel that holds them as two tiles: ops/pallas/grouped_matmul.py)."""
+    if name == "swiglu":
+        return jax.nn.silu(gate) * up
+    if name == "geglu":
+        return jax.nn.gelu(gate, approximate=False) * up
+    if name == "reglu":
+        return jax.nn.relu(gate) * up
+    if name == "liglu":
+        return gate * up
+    raise ValueError(f"unknown GLU activation {name!r}")
+
+
 def apply_activation(name: str, x: jnp.ndarray) -> jnp.ndarray:
+    from megatron_tpu.config import GLU_ACTIVATIONS
+
     if name == "gelu":
         return jax.nn.gelu(x, approximate=False)
     if name == "gelu_tanh":  # HF "gelu_new" (tanh approximation)
@@ -31,18 +48,8 @@ def apply_activation(name: str, x: jnp.ndarray) -> jnp.ndarray:
     if name == "squared_relu":
         r = jax.nn.relu(x)
         return r * r
-    if name == "swiglu":
-        gate, up = _split_glu(x)
-        return jax.nn.silu(gate) * up
-    if name == "geglu":
-        gate, up = _split_glu(x)
-        return jax.nn.gelu(gate, approximate=False) * up
-    if name == "reglu":
-        gate, up = _split_glu(x)
-        return jax.nn.relu(gate) * up
-    if name == "liglu":
-        gate, up = _split_glu(x)
-        return gate * up
+    if name in GLU_ACTIVATIONS:
+        return glu_activation(name, *_split_glu(x))
     raise ValueError(f"unknown activation {name!r}")
 
 

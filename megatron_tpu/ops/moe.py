@@ -388,26 +388,32 @@ def experts_mlp(cfg: ModelConfig, p: Dict[str, Any], xs: jnp.ndarray,
     """The held experts' MLP over rows xs [R, h] that stand sorted by
     expert: the next group_sizes[g] rows are expert g's, g counting the
     experts whose matrices `p` holds. Returns ([R, h], the sink's stacks).
-    The two products are grouped GEMMs over those groups (grouped_matmul:
-    the program's Pallas kernels on one TPU, lax.ragged_dot elsewhere),
-    the activation between them in `dtype`; `expert_of_row()` gives each
-    row's expert for the biases, where the experts have them.
+    The two products are grouped GEMMs over those groups, the activation
+    between them in `dtype`. Where they are the program's Pallas kernels
+    (one TPU, shapes the tiles divide) the activation and its backward are
+    made of the tiles inside the kernels (`grouped_mlp`) and nothing but
+    the kernels touches the first product; elsewhere (`lax.ragged_dot`;
+    experts with biases, for which `expert_of_row()` gives each row's
+    expert; an activation the kernels do not hold) it stands between the
+    products as XLA's (`grouped_matmul`, `apply_activation`).
 
     ragged: the groups may end before the rows do (a share of the experts:
     the rows behind belong to experts held elsewhere, or to none). No
     kernel visits those rows' tiles, so in every product's result (and in
     the gradient of its rows) they hold whatever the buffer held: callers
     read the result's rows through their own `kept` (`rows_to_token_order`,
-    `rows_to_expert_order`), and the first product's are set to zero on the
-    way into the activation (and so their gradient on the way back), which
-    keeps what the kernels contract over rows (`moe_tgmm`: a zeroed row on
-    one side still meets the other side's value) finite.
+    `rows_to_expert_order`). What the kernels contract over rows
+    (`moe_tgmm`, the last group's boundary window) has to stay clear of
+    them: the kernels that hold the activation zero both operands' rows
+    there; the other form sets the first product's to zero on the way
+    into the activation (and so their gradient on the way back), a pass
+    over the buffer each.
 
     grad_sink = (stacks, layer), as moe_block has it: the matrix's
     gradient goes into its stack where it has one."""
     # with the other kernels: imported where it is used (ops/attention.py)
     from megatron_tpu.ops.pallas.grouped_matmul import (
-        grouped_matmul, visits_for,
+        grouped_matmul, grouped_mlp, visits_for,
     )
 
     # one visit table for both products and their gradients (None where
@@ -415,6 +421,21 @@ def experts_mlp(cfg: ModelConfig, p: Dict[str, Any], xs: jnp.ndarray,
     visits = visits_for(group_sizes, xs.shape[0])
     stacks, layer = ({}, None) if grad_sink is None else grad_sink
     stacks = dict(stacks)
+
+    # where the products are the kernels, the activation (and, of a share,
+    # the rows behind the last group) is theirs too: nothing stands between
+    # them but the first product. Experts with biases keep the form below.
+    if visits is not None and "b_in" not in p and "b_out" not in p:
+        fused = grouped_mlp(
+            xs, p["w_in"], p["w_out"], group_sizes, cfg.activation,
+            visits=visits, ragged=ragged, save_as=SAVED_PRODUCT,
+            sinks=(*(stacks.get(name) for name in EXPERT_MATRICES), layer))
+        if fused is not None:
+            out, through = fused
+            stacks.update((name, stack) for name, stack
+                          in zip(EXPERT_MATRICES, through)
+                          if stack is not None)
+            return out, stacks
 
     def product(rows, name):
         """rows · p[name] by group; the matrix's gradient goes into

@@ -75,9 +75,13 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from megatron_tpu.ops.activations import (
+    apply_activation, glu_activation, mlp_input_width_factor,
+)
 from megatron_tpu.ops.pallas import flash_template as ft
 from megatron_tpu.ops.pallas.flash_template import _NN, _NT, _TN, _dot
 
@@ -224,8 +228,51 @@ def _row_mask(lo, hi, row0, tm: int):
     return (rows >= lo) & (rows < hi)
 
 
-def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, rhs_ref,
-                out_ref, *scratch, tm: int, dims):
+def _act_parts(name: Optional[str]) -> int:
+    """The blocks of its argument that one block of the activation's result
+    reads: a GLU's gate and up, F columns apart; else (and of no
+    activation) the block itself."""
+    return mlp_input_width_factor(name)
+
+
+def _activation(name: str, *parts):
+    """`apply_activation`'s arithmetic on the blocks of `_act_parts`."""
+    if len(parts) == 2:
+        return glu_activation(name, *parts)
+    return apply_activation(name, *parts)
+
+
+def _act_tile(name: str, parts, dtype):
+    """The activation of a tile inside a kernel: of the first product's
+    values as they are stored, in float32, rounded once to the operands'
+    dtype, which is what XLA's fusion of the same arithmetic gives."""
+    return _activation(
+        name, *(p.astype(jnp.float32) for p in parts)).astype(dtype)
+
+
+def _act_vjp_tile(name: str, parts, dact, dtype):
+    """The cotangents of the activation's argument blocks for the float32
+    product `dact`, the cotangent of its result, which is rounded to the
+    operands' dtype first, as it was when it was an array of its own. The
+    formula is autodiff's of `_activation`, traced in the kernel's body."""
+    _, vjp = jax.vjp(functools.partial(_activation, name),
+                     *(p.astype(jnp.float32) for p in parts))
+    return [d.astype(dtype)
+            for d in vjp(dact.astype(dtype).astype(jnp.float32))]
+
+
+def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
+                dims, act: Optional[str], act_vjp: Optional[str]):
+    """refs: lhs, rhs, out and, where k tiles accumulate, the float32
+    scratch. With `act`, lhs is the `_act_parts` blocks of the activation's
+    argument and the rows that meet rhs are the activation of them. With
+    `act_vjp`, the block(s) of the activation's argument stand behind rhs,
+    side by side in one, and out holds, side by side too, their cotangents
+    for the product as the cotangent of the activation's result."""
+    n_lhs = _act_parts(act)
+    lhs_refs, (rhs_ref, *refs) = refs[:n_lhs], refs[n_lhs:]
+    arg_ref = refs.pop(0) if act_vjp else None
+    out_ref, *scratch = refs
     v = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -240,17 +287,31 @@ def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, rhs_ref,
         rows = pl.ds(start, size)
         first_row = row0 + start
 
+        def results(acc):
+            """(columns of out, their values) for the finished product."""
+            if act_vjp is None:
+                return [(slice(None), acc)]
+            tn = acc.shape[1]
+            blocks = [slice(i * tn, (i + 1) * tn)
+                      for i in range(_act_parts(act_vjp))]
+            return list(zip(blocks, _act_vjp_tile(
+                act_vjp, [arg_ref[rows, b] for b in blocks], acc,
+                out_ref.dtype)))
+
         def keep(acc):
             def all_rows():
-                out_ref[rows, :] = acc.astype(out_ref.dtype)
+                for cols, val in results(acc):
+                    out_ref[rows, cols] = val.astype(out_ref.dtype)
 
             def the_groups_rows():
                 # the other rows belong to the visits before and after
                 # this one, which hold the same output block
-                out_ref[rows, :] = jnp.where(
-                    _row_mask(lo, hi, first_row, size), acc,
-                    out_ref[rows, :].astype(jnp.float32)
-                ).astype(out_ref.dtype)
+                mask = _row_mask(lo, hi, first_row, size)
+                for cols, val in results(acc):
+                    out_ref[rows, cols] = jnp.where(
+                        mask, val.astype(jnp.float32),
+                        out_ref[rows, cols].astype(jnp.float32)
+                    ).astype(out_ref.dtype)
 
             if inside is True:
                 all_rows()
@@ -258,7 +319,12 @@ def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, rhs_ref,
                 pl.when(inside)(all_rows)
                 pl.when(jnp.logical_not(inside))(the_groups_rows)
 
-        prod = _dot(lhs_ref[rows, :], rhs_ref[...], dims)
+        if act is None:
+            lhs = lhs_refs[0][rows, :]
+        else:
+            lhs = _act_tile(act, [r[rows, :] for r in lhs_refs],
+                            rhs_ref.dtype)
+        prod = _dot(lhs, rhs_ref[...], dims)
         if not scratch:      # one k tile spans the contraction
             keep(prod)
             return
@@ -287,18 +353,28 @@ def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, lhs_ref, rhs_ref,
             functools.partial(span, start, _SPAN, inside))
 
 
-def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool):
+def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool,
+         act: Optional[str] = None, act_vjp=None):
     """lhs [m, k] · rhs[g] with rhs [E, k, n], or with transpose_rhs
-    lhs [m, k] · rhs[g]ᵀ with rhs [E, n, k]. Returns [m, n]."""
-    m, k = lhs.shape
-    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    lhs [m, k] · rhs[g]ᵀ with rhs [E, n, k]. Returns [m, n].
+
+    act: lhs is the argument [m, k or 2k] of the activation of that name,
+    and the rows that meet rhs are the activation of it, made of the tile
+    in front of each product (a GLU's gate and up blocks are two windows
+    onto the one array). act_vjp = (name, arg [m, n or 2n]): the result is
+    the cotangent of `arg` for the product as the cotangent of the
+    activation of `arg`, [m, n or 2n], made of the finished tile: no
+    [m, n] array is written. A GLU's two halves are written side by side
+    by the one n tile that spans n."""
+    m = lhs.shape[0]
+    k, n = rhs.shape[:0:-1] if transpose_rhs else rhs.shape[1:]
     tm, tk, tn = tiles
     nk = k // tk
     dtype = jnp.result_type(lhs.dtype, rhs.dtype)
     item = jnp.dtype(dtype).itemsize
 
-    def lhs_map(j, v, ki, offs, gids, tids, count):
-        return tids[v], ki
+    def lhs_map(j, v, ki, offs, gids, tids, count, part=0):
+        return tids[v], ki + part * nk
 
     def out_map(j, v, ki, offs, gids, tids, count):
         return tids[v], j
@@ -312,27 +388,47 @@ def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool):
             (None, tk, tn),
             lambda j, v, ki, offs, gids, tids, count: (gids[v], ki, j))
 
+    n_lhs = _act_parts(act)
+    in_specs = [pl.BlockSpec((tm, tk), functools.partial(lhs_map, part=i))
+                for i in range(n_lhs)] + [rhs_spec]
+    operands = [lhs.astype(dtype)] * n_lhs + [rhs.astype(dtype)]
+    out_parts = 1
+    if act_vjp is not None:
+        act_vjp, arg = act_vjp
+        out_parts = _act_parts(act_vjp)
+        if out_parts > 1 and tn != n:
+            raise ValueError(f"moe_gmm writes a GLU's two cotangents from "
+                             f"one n tile: {tn} of {n} columns")
+        in_specs.append(pl.BlockSpec((tm, out_parts * tn), out_map))
+        operands.append(arg.astype(dtype))
+
     # lhs, rhs and out blocks double-buffered, the float32 product and (for
-    # several k tiles) the accumulator
-    vmem = (2 * (tm * tk + tk * tn + tm * tn) * item
+    # several k tiles) the accumulator; an activation's float32 values
+    # (argument, result and what stands between) beside them
+    vmem = (2 * (n_lhs * tm * tk + tk * tn + tm * out_parts * tn) * item
             + (1 if nk == 1 else 2) * tm * tn * 4)
+    if act is not None:
+        vmem += (n_lhs + 2) * tm * tk * 4
+    if act_vjp is not None:
+        vmem += 2 * tm * out_parts * tn * item + 3 * out_parts * tm * tn * 4
     return ft._named_pallas_call(
         "moe_gmm",
         functools.partial(_gmm_kernel, tm=tm,
-                          dims=_NT if transpose_rhs else _NN),
+                          dims=_NT if transpose_rhs else _NN,
+                          act=act, act_vjp=act_vjp),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n // tn, visits.group_ids.shape[0], nk),
-            in_specs=[pl.BlockSpec((tm, tk), lhs_map), rhs_spec],
-            out_specs=pl.BlockSpec((tm, tn), out_map),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((tm, out_parts * tn), out_map),
             scratch_shapes=([] if nk == 1
                             else [pltpu.VMEM((tm, tn), jnp.float32)])),
-        out_shape=jax.ShapeDtypeStruct((m, n), dtype),
+        out_shape=jax.ShapeDtypeStruct((m, out_parts * n), dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=ft._interpret(),
-    )(*visits, lhs.astype(dtype), rhs.astype(dtype))
+    )(*visits, *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -341,17 +437,24 @@ def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool):
 
 
 def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
-                 into: bool):
+                 into: bool, act: Optional[str], ragged: bool):
     """refs: lhs, dout, out and the float32 scratch a group's product is
     summed in; with `into`, the layer's index (read by the index maps
     alone) in front, the accumulator's block after dout, and no scratch:
     the float32 output block is where the product is summed, on top of
-    the accumulator's block."""
+    the accumulator's block. With `act`, lhs is the `_act_parts` blocks of
+    the activation's argument, and the rows contracted are the activation
+    of them. ragged: rows may stand behind the last group, where either
+    operand may hold anything."""
     if into:
-        _, lhs_ref, dout_ref, into_ref, out_ref = refs
+        refs = refs[1:]
+    n_lhs = _act_parts(act)
+    lhs_refs, (dout_ref, *refs) = refs[:n_lhs], refs[n_lhs:]
+    if into:
+        into_ref, out_ref = refs
         acc_ref = out_ref
     else:
-        lhs_ref, dout_ref, out_ref, acc_ref = refs
+        out_ref, acc_ref = refs
     v = pl.program_id(2)
     last_step = pl.num_programs(2) - 1
     count = count_ref[0]
@@ -361,6 +464,12 @@ def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
     first = (v == 0) | (gids_ref[jnp.maximum(v - 1, 0)] != g)
     last = (v == count - 1) | (gids_ref[jnp.minimum(v + 1, last_step)] != g)
     whole = (lo <= row0) & (hi >= row0 + tm)
+
+    def lhs_rows(rows):
+        if act is None:
+            return lhs_refs[0][rows, :]
+        return _act_tile(act, [r[rows, :] for r in lhs_refs],
+                         dout_ref.dtype)
 
     def accumulate(prod):
         @pl.when(first)
@@ -373,13 +482,16 @@ def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
 
     @pl.when(live & whole)
     def _all_rows():
-        accumulate(_dot(lhs_ref[...], dout_ref[...], _TN))
+        accumulate(_dot(lhs_rows(slice(None)), dout_ref[...], _TN))
 
     # A tile that holds a boundary: the group's rows are [a, b) of it, and
     # the product is taken over the smallest window of 128, 256, ... rows
     # that holds them (from a sublane-aligned start), the other groups'
     # rows in it zeroed in the narrower operand: a zero row on one side is
     # a zero term of the sum, and the MXU's time follows the window's rows.
+    # Not where rows stand behind the last group: those a kernel never
+    # wrote, a zero times what they hold is not a zero, and both operands'
+    # are zeroed.
     a = jnp.maximum(lo - row0, 0)
     b = jnp.minimum(hi - row0, tm)
     smaller_fits = jnp.bool_(False)
@@ -391,10 +503,10 @@ def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
         def window(start=start, size=size):
             rows = pl.ds(pl.multiple_of(start, _SUBLANES), size)
             mask = _row_mask(lo, hi, row0 + start, size)
-            lhs, dout = lhs_ref[rows, :], dout_ref[rows, :]
-            if lhs.shape[1] <= dout.shape[1]:
+            lhs, dout = lhs_rows(rows), dout_ref[rows, :]
+            if ragged or lhs.shape[1] <= dout.shape[1]:
                 lhs = jnp.where(mask, lhs, jnp.zeros_like(lhs))
-            else:
+            if ragged or lhs.shape[1] > dout.shape[1]:
                 dout = jnp.where(mask, dout, jnp.zeros_like(dout))
             accumulate(_dot(lhs, dout, _TN))
 
@@ -413,14 +525,23 @@ def _tgmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
             out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles, into=None):
+def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles, into=None,
+          act: Optional[str] = None, ragged: bool = False):
     """lhs [m, k], dout [m, n] -> [E, k, n], one product per group, in
     the operands' dtype. With into = (stack float32 [L, E, k, n], layer
     int32 scalar): the stack with stack[layer] + the products in place of
     stack[layer], in float32 and unrounded. The stack is aliased to the
     result, so only the layer's blocks are read and written and the rest
-    of the buffer is never touched."""
-    m, k = lhs.shape
+    of the buffer is never touched.
+
+    act: lhs is the argument [m, k or 2k] of the activation of that name,
+    and what is contracted is the activation of it, made of each row
+    window in front of its product. ragged: the groups may end before the
+    rows, and what the rows behind them hold (in either operand) is kept
+    out of the last group's boundary window."""
+    m = lhs.shape[0]
+    n_lhs = _act_parts(act)
+    k = lhs.shape[1] // n_lhs
     n = dout.shape[1]
     E = visits.offsets.shape[0] - 1
     tm, tk, tn = tiles
@@ -429,8 +550,8 @@ def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles, into=None):
 
     # the index maps' trailing arguments are the scalar-prefetch operands:
     # the visit table, and behind it the layer where there is a stack
-    def lhs_map(j, i, v, offs, gids, tids, *_):
-        return tids[v], i
+    def lhs_map(j, i, v, offs, gids, tids, *_, part=0):
+        return tids[v], i + part * (k // tk)
 
     def dout_map(j, i, v, offs, gids, tids, *_):
         return tids[v], j
@@ -441,16 +562,19 @@ def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles, into=None):
     def stack_map(j, i, v, offs, gids, tids, count, layer):
         return layer[0], gids[v], i, j
 
-    in_specs = [pl.BlockSpec((tm, tk), lhs_map),
-                pl.BlockSpec((tm, tn), dout_map)]
-    operands = [*visits, lhs.astype(dtype), dout.astype(dtype)]
+    in_specs = [pl.BlockSpec((tm, tk), functools.partial(lhs_map, part=p))
+                for p in range(n_lhs)] + [pl.BlockSpec((tm, tn), dout_map)]
+    operands = [*visits, *[lhs.astype(dtype)] * n_lhs, dout.astype(dtype)]
+    # an activation's float32 values beside the blocks
+    act_vmem = (n_lhs + 2) * tm * tk * 4 if act else 0
     if into is None:
         out_shape = jax.ShapeDtypeStruct((E, k, n), dtype)
         out_spec = pl.BlockSpec((None, tk, tn), out_map)
         scratch, aliases = [pltpu.VMEM((tk, tn), jnp.float32)], {}
         # row tiles and the output block double-buffered, the float32
         # accumulator and one product beside it
-        vmem = 2 * (tm * tk + tm * tn + tk * tn) * item + 2 * tk * tn * 4
+        vmem = (2 * (n_lhs * tm * tk + tm * tn + tk * tn) * item
+                + 2 * tk * tn * 4)
     else:
         stack, layer = into
         if stack.dtype != jnp.float32 or stack.shape[1:] != (E, k, n):
@@ -465,10 +589,11 @@ def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles, into=None):
         scratch, aliases = [], {len(operands) - 1: 0}
         # row tiles double-buffered; the accumulator's block in and the
         # block out, both float32 and double-buffered; one product
-        vmem = 2 * (tm * tk + tm * tn) * item + 5 * tk * tn * 4
+        vmem = 2 * (n_lhs * tm * tk + tm * tn) * item + 5 * tk * tn * 4
     return ft._named_pallas_call(
         "moe_tgmm",
-        functools.partial(_tgmm_kernel, tm=tm, into=into is not None),
+        functools.partial(_tgmm_kernel, tm=tm, into=into is not None,
+                          act=act, ragged=ragged),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4 if into is None else 5,
             grid=(n // tn, k // tk, visits.group_ids.shape[0]),
@@ -477,7 +602,7 @@ def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles, into=None):
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(vmem)),
+            vmem_limit_bytes=_vmem_limit(vmem + act_vmem)),
         interpret=ft._interpret(),
     )(*operands)
 
@@ -531,6 +656,87 @@ def _kernels_bwd(plan: _Plan, res, ct):
 
 
 _grouped_matmul_kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
+class _MlpPlan(NamedTuple):
+    """The experts' two products with the activation between them."""
+    first: _Plan             # rows · w_in
+    second: _Plan            # activation(that) · w_out
+    activation: str
+    ragged: bool             # rows may stand behind the last group
+    save_as: Optional[str]   # the first product's `checkpoint_name`
+
+
+@functools.partial(jax.jit, static_argnames=("plan",))
+def _mlp_products(xs, w_in, w_out, visits: GroupVisits, plan: _MlpPlan):
+    """(activation(xs · w_in[g]) · w_out[g], xs · w_in[g]), the first
+    product under its name for a `jax.checkpoint` policy.
+
+    A function of its own (an inner `jit`) for that name's sake: the
+    second product has to read the NAMED value, so that a backward pass
+    that makes the second product again (a share of the experts, for the
+    gates' gradient: ops/moe.py `_to_token_fwd`) reads the saved first
+    product and does not make that again too. But `jax.checkpoint` rounds
+    every saved value that the forward pass reads as well (a
+    `reduce_precision`, which this chip's compiler keeps as a pass over
+    the array where nothing but kernels stands around it), unless the
+    reader stands inside a call, as here."""
+    hmid = _gmm(xs, w_in, visits, plan.first.fwd, False)
+    if plan.save_as is not None:
+        hmid = checkpoint_name(hmid, plan.save_as)
+    return _gmm(hmid, w_out, visits, plan.second.fwd, False,
+                act=plan.activation), hmid
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _grouped_mlp_kernels(xs, w_in, w_out, visits: GroupVisits, sinks,
+                         plan: _MlpPlan):
+    """activation(xs · w_in[g]) · w_out[g], the activation made of the
+    first product's tiles inside the second's kernel. sinks = (w_in's
+    stack, w_out's stack, layer), a matrix without one None: the stacks are
+    handed through unread behind the result, for their cotangents."""
+    return _mlp_products(xs, w_in, w_out, visits, plan=plan)[0], sinks[:2]
+
+
+def _mlp_fwd(xs, w_in, w_out, visits, sinks, plan: _MlpPlan):
+    out, hmid = _mlp_products(xs, w_in, w_out, visits, plan=plan)
+    return (out, sinks[:2]), (xs, hmid, w_in, w_out, visits, sinks[2])
+
+
+def _mlp_bwd(plan: _MlpPlan, res, ct):
+    xs, hmid, w_in, w_out, visits, layer = res
+    dout, dstacks = ct
+    dout = dout.astype(hmid.dtype)
+    # the rows' gradient through w_out, turned into the first product's
+    # cotangent by the activation's vjp as each tile is finished
+    dhmid = _gmm(dout, w_out, visits, plan.second.drows, True,
+                 act_vjp=(plan.activation, hmid))
+    dxs = _gmm(dhmid, w_in, visits, plan.first.drows, True).astype(xs.dtype)
+
+    def matrix_grad(w, dstack, lhs, d, tiles, act=None):
+        """(w's cotangent, its stack's): the gradient goes into the stack
+        where there is one, and w's own is then zero (`_kernels_bwd`)."""
+        if dstack is None:
+            return _tgmm(lhs, d, visits, tiles, act=act,
+                         ragged=plan.ragged).astype(w.dtype), None
+        return jnp.zeros_like(w), _tgmm(lhs, d, visits, tiles, act=act,
+                                        ragged=plan.ragged,
+                                        into=(dstack, layer))
+
+    dw_in, dstack_in = matrix_grad(w_in, dstacks[0], xs, dhmid,
+                                   plan.first.tgmm)
+    dw_out, dstack_out = matrix_grad(w_out, dstacks[1], hmid, dout,
+                                     plan.second.tgmm, plan.activation)
+    return dxs, dw_in, dw_out, None, (dstack_in, dstack_out, None)
+
+
+_grouped_mlp_kernels.defvjp(_mlp_fwd, _mlp_bwd)
+
+
+# activations whose arithmetic the chip's kernel compiler does not lower
+# (the exact gelu's `erfc`): their experts keep the activation between the
+# kernels
+_NOT_IN_KERNEL = ("gelu", "geglu")
 
 
 def _one_tpu() -> bool:
@@ -588,3 +794,47 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
     if visits is None:
         visits = visits_for(group_sizes, m)
     return _grouped_matmul_kernels(lhs, rhs, visits, sink, plan)
+
+
+def grouped_mlp(xs: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray,
+                group_sizes: jnp.ndarray, activation: str, *,
+                visits: Optional[GroupVisits] = None,
+                sinks=(None, None, None), ragged: bool = False,
+                save_as: Optional[str] = None):
+    """activation(xs [m, h] · w_in[g] [h, f or 2f]) · w_out[g] [f, h] for
+    the rows of each group, as one function with one gradient rule, the
+    activation and its backward inside the kernels: `moe_gmm` over w_out
+    and `moe_tgmm` for w_out's gradient make it of the first product's
+    tiles in front of their products, and the `moe_gmm` that takes the
+    result's cotangent back through w_out turns each finished tile into
+    the first product's cotangent. No array of the activation's shape
+    exists and nothing but the kernels touches the first product.
+    Returns (result [m, h], (w_in's stack, w_out's stack)), or None where
+    this form does not serve and the caller runs the products apart
+    (`grouped_matmul`): where the products are not the kernels, operands
+    of several dtypes, an activation the kernels' compiler does not
+    lower, a GLU wider than the one n tile that writes its two cotangents
+    side by side.
+
+    sinks = (w_in's stack, w_out's stack, layer) as `grouped_matmul(sink=)`
+    has one. ragged: the groups may end before the rows; a result row
+    behind them, and its gradient, holds whatever the buffer held.
+    save_as: the `checkpoint_name` of the first product, the one value
+    between the kernels, for a caller's `jax.checkpoint` policy."""
+    m, h = xs.shape
+    E, f, _ = w_out.shape
+    # (the first product, the activation and the result all in the rows'
+    # dtype, as the products apart have them from such operands)
+    if (not _one_tpu() or activation in _NOT_IN_KERNEL
+            or w_in.shape[2] != _act_parts(activation) * f
+            or not xs.dtype == w_in.dtype == w_out.dtype):
+        return None
+    first, second = _plan(m, h, w_in.shape[2], E), _plan(m, f, h, E)
+    if first is None or second is None or (
+            _act_parts(activation) > 1 and second.drows[2] != f):
+        return None
+    if visits is None:
+        visits = visits_for(group_sizes, m)
+    return _grouped_mlp_kernels(
+        xs, w_in, w_out, visits, tuple(sinks),
+        _MlpPlan(first, second, activation, ragged, save_as))

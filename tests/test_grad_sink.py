@@ -246,3 +246,81 @@ def test_the_journal_counts_the_leaves_the_kernels_sum(
                     if "w_in" in k or "w_out" in k) / sum(sizes.values())
         assert 0.1 < share < 1.0
     assert program["kernel_summed_share"] == pytest.approx(share)
+
+
+# ---------------------------------------------------------------------------
+# the dropless block with the activation inside its kernels
+# (ops/pallas/grouped_matmul.py `grouped_mlp`) against its `lax.ragged_dot`
+# form, whole and as a share of the experts, sinks included
+# ---------------------------------------------------------------------------
+
+BLOCK_CASES = {
+    "whole": {},
+    # experts 2 and 3 of the router's four: the rows of experts 0 and 1
+    # stand behind the two groups
+    "share": dict(moe_experts_held=2, moe_expert_share=1),
+}
+
+
+def _block_parts(cfg, sunk, kernels):
+    """(loss, d x, d router, d w_in, d w_out or what its stack got) of a
+    scalar of `moe_block_dropless`'s result, float32, the products the
+    kernels (interpreted) or `lax.ragged_dot`."""
+    from megatron_tpu.ops import moe
+
+    p = jax.tree.map(
+        lambda a: a[0], init_params(cfg, jax.random.PRNGKey(1),
+                                    dtype=jnp.float32)["layers"]["moe"])
+    # wider than the init, so that the experts weigh on the scalar
+    p = {name: 4.0 * a for name, a in p.items()}
+    keys = jax.random.split(jax.random.PRNGKey(2), 4)
+    x = jax.random.normal(keys[0], (1, SEQ, cfg.hidden_size))
+    weight = jax.random.normal(keys[1], x.shape)
+    stacks = {name: jax.random.normal(key, (2,) + p[name].shape)
+              for name, key in zip(moe.EXPERT_MATRICES, keys[2:])}
+
+    def scalar(x, p, stacks):
+        if not sunk:
+            y, aux, _ = moe.moe_block_dropless(cfg, p, x)
+            return jnp.sum(y * weight) + aux, {}
+        y, aux, _, through = moe.moe_block_dropless(
+            cfg, p, x, grad_sink=(stacks, jnp.int32(1)))
+        return jnp.sum(y * weight) + aux, through
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(gm, "_one_tpu", lambda: kernels)
+        text = str(jax.make_jaxpr(scalar)(x, p, stacks))
+        (loss, through), vjp = jax.vjp(scalar, x, p, stacks)
+        dx, dp, dstacks = vjp((jnp.ones(()), through))
+    return text, {"loss": loss, "d x": dx, "d router": dp["router"],
+                  **{f"d {name}": (dstacks[name][1] - stacks[name][1]
+                                   if sunk else dp[name])
+                     for name in moe.EXPERT_MATRICES}}, (dp, dstacks, stacks)
+
+
+@pytest.mark.parametrize("sunk", [False, True], ids=["plain", "sinks"])
+@pytest.mark.parametrize("case", list(BLOCK_CASES))
+def test_the_block_with_the_activation_in_its_kernels_is_the_ragged_dot_form(
+        case, sunk):
+    """`moe_block_dropless`, whole and as a share (the rows of the experts
+    held elsewhere behind the last group, where no kernel writes the first
+    product): loss and every gradient with the kernels, which hold the
+    activation, are those of the `lax.ragged_dot` form, which zeroes those
+    rows and applies the activation between its products. With sinks each
+    matrix's gradient is in its stack's layer, the other layer untouched,
+    and the matrix's own cotangent is zero."""
+    cfg = toy_moe("float32", **BLOCK_CASES[case])
+    text, got, (dp, dstacks, stacks) = _block_parts(cfg, sunk, kernels=True)
+    # (off the kernels nobody gets a sink: the matrices' own gradients)
+    ragged_text, want, _ = _block_parts(cfg, False, kernels=False)
+    assert text.count("name=moe_gmm") == 2 and "ragged_dot" not in text
+    assert "ragged_dot" in ragged_text and "pallas_call" not in ragged_text
+    for what in want:
+        assert np.isfinite(np.asarray(got[what])).all(), what
+        np.testing.assert_allclose(got[what], want[what], rtol=2e-5,
+                                   atol=2e-4, err_msg=what)
+    if sunk:
+        for name, stack in stacks.items():
+            assert not np.any(np.asarray(dp[name])), name
+            np.testing.assert_array_equal(dstacks[name][0], stack[0])
+            assert np.abs(np.asarray(got[f"d {name}"])).max() > 0

@@ -1,7 +1,9 @@
 """The program's grouped-matmul kernels (ops/pallas/grouped_matmul.py)
 against `jax.lax.ragged_dot`, on the CPU with the kernels in interpret
 mode at small shapes: value and both gradients over every kind of group
-layout, the tile rule, and the rule that picks kernel or `ragged_dot`.
+layout, the tile rule, and the rule that picks kernel or `ragged_dot`;
+and the experts' two products with the activation inside the kernels
+(`grouped_mlp`) against `ragged_dot`, `apply_activation`, `ragged_dot`.
 """
 
 import jax
@@ -9,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from megatron_tpu.ops.activations import apply_activation
 from megatron_tpu.ops.pallas import grouped_matmul as gm
 
 M, K, N = 512, 256, 384
@@ -234,6 +237,257 @@ def test_takes_sink_follows_the_tiles(as_on_one_tpu):
     assert gm.takes_sink(32768, 2048, 2048, 64)
     assert not gm.takes_sink(8, 2048, 2048, 4)       # a decode batch
     assert not gm.takes_sink(512, 200, 384, 4)       # lanes off 128
+
+
+# ---------------------------------------------------------------------------
+# the pair of products with the activation inside the kernels
+# ---------------------------------------------------------------------------
+
+H, F = 256, 128     # the experts' hidden width and inner width
+ACTIVATIONS = ("swiglu", "gelu_tanh", "squared_relu")
+PAIR_LAYOUTS = ("skewed", "empty_group", "boundaries_off_the_tile")
+
+
+def _pair_operands(sizes, activation, dtype=jnp.float32, h=H, f=F):
+    """Rows, the experts' two matrices (the first twice as wide for a
+    GLU), the weight of the scalar and the group sizes."""
+    keys = jax.random.split(jax.random.PRNGKey(len(sizes)), 4)
+    fin = gm._act_parts(activation) * f
+    xs = jax.random.normal(keys[0], (M, h), dtype)
+    w_in = (jax.random.normal(keys[1], (len(sizes), h, fin)) / 8).astype(dtype)
+    w_out = (jax.random.normal(keys[2], (len(sizes), f, h)) / 8).astype(dtype)
+    weight = jax.random.normal(keys[3], (M, h), dtype)
+    return xs, w_in, w_out, weight, jnp.asarray(sizes, jnp.int32)
+
+
+def _dense_pair(xs, w_in, w_out, weight, gs, activation):
+    """The scalar's value parts (result, d rows, d w_in, d w_out) by
+    `lax.ragged_dot`, `apply_activation` and `lax.ragged_dot`, over the
+    rows the groups hold (the operands' first sum(gs))."""
+    rows = int(gs.sum())
+
+    def fn(a, b, c):
+        mid = apply_activation(activation, jax.lax.ragged_dot(a, b, gs))
+        return jax.lax.ragged_dot(mid, c, gs)
+
+    loss = lambda a, b, c: jnp.sum(  # noqa: E731
+        fn(a, b, c) * weight[:rows], dtype=jnp.float32)
+    return (fn(xs[:rows], w_in, w_out),) + jax.grad(loss, argnums=(0, 1, 2))(
+        xs[:rows], w_in, w_out)
+
+
+def _fused_pair(xs, w_in, w_out, weight, gs, activation, stacks=(None, None),
+                ragged=False):
+    """The same parts by `grouped_mlp` (over the groups' rows, the others
+    kept out of the scalar as a caller's `kept` keeps them out), and what
+    the stacks' cotangents come back as, each handed in as its stack's."""
+    held = (jnp.arange(xs.shape[0]) < gs.sum())[:, None]
+
+    def fn(a, b, c, sunk):
+        out, through = gm.grouped_mlp(a, b, c, gs, activation, ragged=ragged,
+                                      sinks=(*sunk, jnp.int32(0)))
+        loss = jnp.sum(jnp.where(held, out * weight, 0.0), dtype=jnp.float32)
+        return loss, through, out
+
+    (_, through, out), vjp = jax.vjp(fn, xs, w_in, w_out, stacks)
+    dxs, dw_in, dw_out, summed = vjp((jnp.ones((), jnp.float32), stacks,
+                                      jnp.zeros_like(out)))
+    for stack, same in zip(stacks, through):
+        assert (stack is None) == (same is None)
+        if stack is not None:
+            np.testing.assert_array_equal(same, stack)
+    rows = int(gs.sum())
+    return (out[:rows], dxs[:rows], dw_in, dw_out), summed
+
+
+def _made_outside_kernels(jaxpr):
+    """The primitives, outside the Pallas calls' bodies, whose result has
+    the rows' leading dimension: the arithmetic that stands between the
+    kernels."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        inner = [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                 if hasattr(getattr(v, "jaxpr", v), "eqns")]
+        for sub in inner:
+            found += _made_outside_kernels(sub)
+        if not inner and any(getattr(v.aval, "shape", ())[:1] == (M,)
+                             for v in eqn.outvars):
+            found.append(eqn.primitive.name)
+    return found
+
+
+def _assert_pair(got, want, rtol, atol, dtype=None):
+    for g, w, what in zip(got, want, ("value", "d rows", "d w_in",
+                                      "d w_out")):
+        if dtype is not None:
+            assert g.dtype == w.dtype == dtype, what
+        np.testing.assert_allclose(g.astype(jnp.float32),
+                                   w.astype(jnp.float32),
+                                   rtol=rtol, atol=atol, err_msg=what)
+
+
+@pytest.mark.parametrize("sunk", [False, True], ids=["plain", "sinks"])
+@pytest.mark.parametrize("activation", ACTIVATIONS)
+@pytest.mark.parametrize("layout", PAIR_LAYOUTS)
+def test_fused_pair_equals_ragged_dot_and_activation(as_on_one_tpu, layout,
+                                                     activation, sunk):
+    """`grouped_mlp`: the activation made inside `moe_gmm` (second
+    product) and `moe_tgmm` (its matrix's gradient), its backward inside
+    the `moe_gmm` that takes the cotangent back: value and every gradient
+    are those of `ragged_dot`, `apply_activation`, `ragged_dot`; with
+    sinks each matrix's gradient is added to its stack's cotangent in
+    float32 and the matrix's own cotangent is zero."""
+    sizes = LAYOUTS[layout]
+    xs, w_in, w_out, weight, gs = _pair_operands(sizes, activation)
+    assert _made_outside_kernels(jax.make_jaxpr(jax.grad(
+        lambda a, b, c: jnp.sum(gm.grouped_mlp(a, b, c, gs, activation)[0]),
+        argnums=(0, 1, 2)))(xs, w_in, w_out).jaxpr) == ["broadcast_in_dim"]
+    stacks = (None, None)
+    if sunk:
+        stacks = tuple(jax.random.normal(jax.random.PRNGKey(7 + i),
+                                         (1,) + w.shape)
+                       for i, w in enumerate((w_in, w_out)))
+    got, summed = _fused_pair(xs, w_in, w_out, weight, gs, activation,
+                              stacks)
+    want = _dense_pair(xs, w_in, w_out, weight, gs, activation)
+    if sunk:
+        for own, total, stack, grad in zip(got[2:], summed[:2], stacks,
+                                           want[2:]):
+            assert not np.any(np.asarray(own))
+            np.testing.assert_allclose(total[0], stack[0] + grad,
+                                       rtol=2e-5, atol=2e-4)
+        got, want = got[:2], want[:2]
+    else:
+        assert summed[:2] == (None, None)
+    _assert_pair(got, want, rtol=2e-5, atol=2e-4)
+    for e, size in enumerate(sizes):
+        if size == 0 and not sunk:   # no row, no gradient: exactly zero
+            assert not np.any(np.asarray(got[2][e])), e
+            assert not np.any(np.asarray(got[3][e])), e
+
+
+@pytest.mark.parametrize("activation", ACTIVATIONS)
+@pytest.mark.parametrize("layout", ["skewed", "empty_group"])
+def test_fused_pair_bf16_operands_give_bf16_results(as_on_one_tpu, layout,
+                                                    activation):
+    """bf16 in, the first product kept in bf16, the activation rounded to
+    bf16 in front of the MXU, float32 accumulation, bf16 out: the dense
+    form's rounding points (the activation itself in float32 and rounded
+    once, where the dense form rounds after each of its operations), so
+    each part is as near the float32 result as the dense bf16 form's."""
+    operands = _pair_operands(LAYOUTS[layout], activation, jnp.bfloat16)
+    got, _ = _fused_pair(*operands, activation)
+    want = _dense_pair(*operands, activation)
+    exact = _dense_pair(*(a.astype(jnp.float32) for a in operands[:4]),
+                        operands[4], activation)
+    for g, w, e, what in zip(got, want, exact, ("value", "d rows", "d w_in",
+                                                "d w_out")):
+        assert g.dtype == w.dtype == jnp.bfloat16, what
+        off = lambda a: np.abs(np.asarray(a, np.float32) - e)  # noqa: E731
+        assert off(g).max() <= 2 * off(w).max(), what
+        assert off(g).mean() <= 1.25 * off(w).mean(), what
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "gelu_tanh"])
+@pytest.mark.parametrize("kernel", ["gmm_act", "gmm_act_vjp", "tgmm_act",
+                                    "tgmm_act_into"])
+def test_each_activation_kernel_at_tiles_of_several_steps(kernel,
+                                                          activation):
+    """The kernels that hold the activation at tiles that cut the experts'
+    inner width into several steps, so that a GLU's gate and up blocks are
+    two windows F columns apart that move together: the contraction of
+    `moe_gmm` over F, the k tiles of `moe_tgmm`; the backward's n tiles
+    (one for a GLU, which writes both cotangents side by side; several for
+    a plain activation)."""
+    f = 256
+    sizes = LAYOUTS["boundaries_off_the_tile"]
+    xs, w_in, w_out, weight, gs = _pair_operands(sizes, activation, f=f)
+    visits = gm.group_visits(gs, M, 128)
+    hmid = jax.lax.ragged_dot(xs, w_in, gs)
+    act = lambda a: apply_activation(activation, a)  # noqa: E731
+    if kernel == "gmm_act":
+        got = gm._gmm(hmid, w_out, visits, (128, 128, 128), False,
+                      act=activation)
+        want = jax.lax.ragged_dot(act(hmid), w_out, gs)
+    elif kernel == "gmm_act_vjp":
+        tn = f if gm._act_parts(activation) > 1 else 128
+        got = gm._gmm(weight, w_out, visits, (128, 128, tn), True,
+                      act_vjp=(activation, hmid))
+        want = jax.grad(lambda a: jnp.sum(
+            jax.lax.ragged_dot(act(a), w_out, gs) * weight))(hmid)
+    else:
+        want = jax.grad(lambda w: jnp.sum(
+            jax.lax.ragged_dot(act(hmid), w, gs) * weight))(w_out)
+        if kernel == "tgmm_act":
+            got = gm._tgmm(hmid, weight, visits, (128, 128, 128),
+                           act=activation)
+        else:
+            got, = gm._tgmm(hmid, weight, visits, (128, 128, 128),
+                            act=activation, into=(w_out[None], jnp.int32(0)))
+            want = w_out + want
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-4)
+
+
+def test_a_glu_wider_than_its_n_tile_is_refused_by_the_kernel():
+    lhs, rhs, _, gs = _operands(LAYOUTS["skewed"])
+    with pytest.raises(ValueError, match="one n tile"):
+        gm._gmm(lhs, rhs, gm.group_visits(gs, M, 128), (128, 128, 128),
+                False, act_vjp=("swiglu", jnp.zeros((M, 2 * N))))
+
+
+@pytest.mark.parametrize("activation", ACTIVATIONS)
+def test_rows_behind_the_last_group_reach_nothing(as_on_one_tpu, activation):
+    """A share of the experts: the groups end before the rows. The rows
+    behind them hold NaN on the way in, and no kernel writes the first
+    product's (the interpreter leaves NaN there, the chip whatever the
+    buffer held): every result row of a group, both matrices' gradients
+    and the groups' rows of `d xs` are finite and equal the dense form's
+    over the groups' rows, the last group's boundary window of `moe_tgmm`
+    included."""
+    sizes = [200, 0, 56, 100]                  # 356 of the 512 rows
+    xs, w_in, w_out, weight, gs = _pair_operands(sizes, activation)
+    behind = (jnp.arange(M) >= sum(sizes))[:, None]
+    xs = jnp.where(behind, jnp.nan, xs)
+    got, _ = _fused_pair(xs, w_in, w_out, weight, gs, activation,
+                         ragged=True)
+    for part in got:
+        assert np.isfinite(np.asarray(part)).all()
+    _assert_pair(got, _dense_pair(xs, w_in, w_out, weight, gs, activation),
+                 rtol=2e-5, atol=2e-4)
+    # without the call's word that rows stand behind the groups, one
+    # operand of `moe_tgmm`'s window keeps what those rows hold
+    unmasked, _ = _fused_pair(xs, w_in, w_out, weight, gs, activation)
+    assert not np.isfinite(np.asarray(unmasked[2])).all()
+
+
+@pytest.mark.parametrize("why,kwargs", [
+    ("exact gelu: erfc does not lower", dict(activation="gelu")),
+    ("exact gelu: erfc does not lower", dict(activation="geglu")),
+    ("a GLU wider than one n tile", dict(activation="swiglu", f=4096)),
+    ("rows the tiles do not divide", dict(activation="swiglu", m=8)),
+    ("widths that do not fit the activation", dict(activation="relu",
+                                                   fin=2 * F)),
+])
+def test_where_the_fused_pair_does_not_serve(as_on_one_tpu, why, kwargs):
+    """`grouped_mlp` answers None, and the caller runs the products apart
+    with the activation between them."""
+    activation = kwargs["activation"]
+    m, f = kwargs.get("m", M), kwargs.get("f", F)
+    fin = kwargs.get("fin", gm._act_parts(activation) * f)
+    shape = jax.ShapeDtypeStruct
+    served = jax.eval_shape(
+        lambda a, b, c: gm.grouped_mlp(a, b, c, jnp.zeros((4,), jnp.int32),
+                                       activation),
+        shape((m, H), jnp.float32), shape((4, H, fin), jnp.float32),
+        shape((4, f, H), jnp.float32))
+    assert served is None, why
+
+
+def test_off_the_tpu_there_is_no_fused_pair():
+    xs, w_in, w_out, _, gs = _pair_operands(LAYOUTS["skewed"], "swiglu")
+    assert gm.grouped_mlp(xs, w_in, w_out, gs, "swiglu") is None
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
