@@ -17,18 +17,16 @@ batch — and each stage's KV caches stay resident on its devices.
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Any, Dict, Optional, Tuple
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from megatron_tpu.config import ModelConfig
-from megatron_tpu.models.language_model import final_hidden_norm, lm_logits
-from megatron_tpu.models.transformer import block_forward
+from megatron_tpu.models.language_model import (
+    final_hidden_norm, lm_logits, rope_tables, run_layers,
+)
 from megatron_tpu.ops import kv_store
-from megatron_tpu.ops.rotary import rope_table
+from megatron_tpu.ops.moe import moe_stats_zero
 from megatron_tpu.training.pipeline import _embed_onehot
 
 
@@ -49,10 +47,8 @@ def make_pipelined_lm_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int):
         B, S = tokens.shape
         total = kv_store.logical_length(caches)
 
-        rope = None
-        if cfg.position_embedding_type == "rotary":
-            rope = rope_table(cfg.attention_kind, cfg.head_dim,
-                              max(cfg.seq_length, total))
+        ropes = rope_tables(cfg, [cfg.attention_kind],
+                            max(cfg.seq_length, total))
 
         x0 = _embed_onehot(cfg, params_local, tokens, None,
                            positions=positions).astype(cfg.dtype)
@@ -66,17 +62,11 @@ def make_pipelined_lm_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int):
                 x = jnp.where(stage == 0, x0, state)
 
                 # this stage's layers write their rows into its shard of
-                # the store in place, as lm_forward's scan does
-                def lbody(c, sc):
-                    x, caches = c
-                    lp, idx = sc
-                    y, caches, _, _ = block_forward(
-                        cfg, lp, x, rope, positions, kv_cache=caches,
-                        layer=idx, cache_index=cache_index)
-                    return (y, caches), None
-
-                (y, caches), _ = jax.lax.scan(
-                    lbody, (x, caches), (layers, jnp.arange(Lp)))
+                # the store in place
+                y, _, caches, _ = run_layers(
+                    cfg, layers, (x, moe_stats_zero(cfg), caches, None),
+                    ropes, positions, first_layer=stage * Lp,
+                    cache_index=cache_index)
                 return y, caches
 
             state2, caches2 = jax.lax.cond(

@@ -22,7 +22,7 @@ from __future__ import annotations
 import math
 import operator
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,38 +105,74 @@ def _remat_policy(recompute: str):
     raise ValueError(f"unknown recompute policy {recompute!r}")
 
 
-def scan_with_remat(body, carry, xs, recompute: str):
-    """lax.scan over a layer stack with the configured remat policy — THE
-    single implementation for every stack (flat LM, GPT pipeline chunks,
-    T5 enc/dec slices). "block:N" splits the scan: iterations [0, N)
-    under full remat, [N, len) saved (ref --recompute_method block,
-    transformer.py:1148-1172). The block path discards scan outputs
-    (callers using ys — decode caches — never run block).
+def scan_with_remat(bodies, carry, xs, recompute: str):
+    """The loop of every layer stack (the LM's, a pipeline chunk's, T5's
+    encoder and decoder slices) under the remat policy; returns the carry.
 
-    Every form of it runs under the scope `layer_stack`: the loop's own
-    work (slicing the stacked weights, stacking what the backward pass
-    saved) sits under no region of a layer, and a device trace finds it
-    by this name (docs/observability.md "Runtime traces")."""
+    The stack is len(bodies) layers a period, over and over (layers all
+    alike: a period of one). `bodies[i]`, a scan body, runs the i-th layer
+    of a period, what is static in its kind (the window at the kernel
+    calls, the rotary table) closed over. A trip runs one period's layers,
+    each under the policy on its own, on slices of a [L / period, period,
+    ...] view of the stacked `xs` (no view, no index where the period is
+    one). "block:N" / "uniform:N" count layers, N a multiple of the period.
+
+    A stack of one trip is a call, not a loop: XLA inlines such a loop
+    sooner or later, and how soon decides what its first CSE still sees.
+    A call of ONE layer is checkpointed with prevent_cse=False, so that XLA
+    merges the recomputation with the forward beside it (inlined late, a
+    one-layer model ran its flash forward twice: 28 ms a step in the
+    benchmark's OLMoE cell, PERF.md PR 33; with True 1.4 % of its rate,
+    PR 45). A call of SEVERAL layers sets it: merged, every activation of
+    the period is kept after all (the Mellum cell does not fit: PR 42).
+
+    Every form runs under the scope `layer_stack`: the loop's own work
+    (slicing the stacked weights, stacking what the backward pass saved)
+    is under no region of a layer, and a device trace finds it by this
+    name (docs/observability.md "Runtime traces")."""
+    period = len(bodies)
+    gran, n = parse_recompute(recompute)
+    if n is not None and n % period:
+        raise ValueError(
+            f"recompute {recompute!r} counts layers of a stack of {period} "
+            "layers a period: the count must be a multiple of the period")
+    length = jax.tree.leaves(xs)[0].shape[0]
+
+    def loop(bodies, carry, xs, policy):
+        layers = len(bodies)  # a trip
+        trips = jax.tree.leaves(xs)[0].shape[0] // layers
+        if policy is not None:
+            bodies = [jax.checkpoint(body, policy=policy,
+                                     prevent_cse=trips == 1 and layers > 1)
+                      for body in bodies]
+        if layers == 1:
+            trip = bodies[0]
+        else:
+            def trip(carry, scanned):
+                for i, body in enumerate(bodies):
+                    carry, _ = body(carry,
+                                    jax.tree.map(lambda a: a[i], scanned))
+                return carry, None
+
+            xs = _chunked(xs, layers)
+        if trips == 1:
+            return trip(carry, jax.tree.map(lambda a: a[0], xs))[0]
+        return jax.lax.scan(trip, carry, xs)[0]
+
     with jax.named_scope("layer_stack"):
-        return _scan_layers(body, carry, xs, recompute)
-
-
-def _scan_layers(body, carry, xs, recompute: str):
-    gran, block_n = parse_recompute(recompute)
-    if gran == "block":
-        length = jax.tree.leaves(xs)[0].shape[0]
-        n = min(block_n, length)
-        sl = lambda lo, hi: jax.tree.map(lambda a: a[lo:hi], xs)
-        if n > 0:
-            ck = jax.checkpoint(body, policy=_remat_policy("block"),
-                                prevent_cse=False)
-            carry, _ = jax.lax.scan(ck, carry, sl(0, n))
-        if n < length:
-            carry, _ = jax.lax.scan(body, carry, sl(n, length))
-        return carry, None
-    if gran == "uniform" and block_n > 1:
-        length = jax.tree.leaves(xs)[0].shape[0]
-        n = block_n
+        if gran == "block":
+            n = min(n, length)
+            sl = lambda lo, hi: jax.tree.map(lambda a: a[lo:hi], xs)
+            if n > 0:
+                carry = loop(bodies, carry, sl(0, n), _remat_policy("block"))
+            if n < length:
+                carry = loop(bodies, carry, sl(n, length), None)
+            return carry
+        if gran == "uniform":
+            gran = "full"  # uniform:1 == per-layer full remat
+        policy = _remat_policy(gran)
+        if n is None or n == 1:
+            return loop(bodies, carry, xs, policy)
         if length % n:
             raise ValueError(
                 f"uniform:{n} needs the layer count ({length}) divisible "
@@ -144,75 +180,20 @@ def _scan_layers(body, carry, xs, recompute: str):
 
         # BOTH levels rematted (classic sqrt-remat): the outer backward
         # stores L/N chunk carries; replaying a chunk stores N per-layer
-        # carries because the inner body is itself rematted — without the
-        # inner remat each replayed chunk would save N full layers'
+        # carries because the layers inside are themselves rematted:
+        # without that each replayed chunk would save N full layers'
         # internals and chunking would COST memory (measured 254 MB at
-        # uniform:2 vs 101 MB plain full before this line existed)
-        inner = jax.checkpoint(body, policy=_remat_policy("full"),
-                               prevent_cse=False)
+        # uniform:2 vs 101 MB plain full)
+        def chunk(carry, chunk_xs):
+            return loop(bodies, carry, chunk_xs, policy), None
 
-        def chunk_body(c, chunk_xs):
-            c, _ = jax.lax.scan(inner, c, chunk_xs)
-            return c, None
-
-        ck = jax.checkpoint(chunk_body, policy=_remat_policy("full"),
-                            prevent_cse=False)
-        xs2 = jax.tree.map(
-            lambda a: a.reshape((length // n, n) + a.shape[1:]), xs)
-        carry, _ = jax.lax.scan(ck, carry, xs2)
-        return carry, None
-    if gran == "uniform":
-        gran = "full"  # uniform:1 == per-layer full remat
-    policy = _remat_policy(gran)
-    if policy is not None:
-        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-    if jax.tree.leaves(xs)[0].shape[0] == 1:
-        # A stack of one layer is a call, not a loop of one trip. XLA
-        # inlines such a loop sooner or later, and how soon decides what
-        # its first CSE still sees: as a loop whose state held the
-        # gradient sinks it was inlined too late for a one-layer model's
-        # recomputed flash forward to merge with the forward itself (28 ms
-        # a step in the benchmark's OLMoE cell; PERF.md, PR 33).
-        carry, ys = body(carry, jax.tree.map(lambda a: a[0], xs))
-        return carry, jax.tree.map(lambda a: a[None], ys)
-    return jax.lax.scan(body, carry, xs)
+        return loop([chunk], carry, _chunked(xs, n), policy)
 
 
-def scan_periods_with_remat(bodies, carry, xs, recompute: str):
-    """scan_with_remat for a stack whose layers are of several kinds: the
-    stack is len(bodies) layers a period, over and over, and `bodies[i]`
-    runs the i-th layer of a period, what is static in its kind (the
-    window at the kernel calls, the rotary table) closed over. The scan
-    goes over the periods, its body runs a period's layers one after the
-    other, each under the remat policy on its own, and `xs` keeps its
-    stacked [L, ...] layout: a period's slices are taken from a
-    [L / period, period, ...] view of it. The policies that cut the stack
-    itself (block:N, uniform:N) are not served."""
-    gran, n = parse_recompute(recompute)
-    if n is not None:
-        raise NotImplementedError(
-            f"recompute {recompute!r} cuts the layer stack by count; a "
-            "stack of several attention kinds takes none, selective or full")
-    policy = _remat_policy(gran)
-    period = len(bodies)
-    if policy is not None:
-        # a stack of one period is a call, not a loop (_scan_layers): its
-        # layers' recomputation then stands in one computation with their
-        # forward pass, where common-subexpression elimination would merge
-        # the two and keep every activation after all
-        once = jax.tree.leaves(xs)[0].shape[0] == period
-        bodies = [jax.checkpoint(body, policy=policy, prevent_cse=once)
-                  for body in bodies]
-
-    def period_body(carry, scanned):
-        for i, body in enumerate(bodies):
-            carry, _ = body(carry, jax.tree.map(lambda a: a[i], scanned))
-        return carry, None
-
-    xs = jax.tree.map(
-        lambda a: a.reshape((a.shape[0] // period, period) + a.shape[1:]), xs)
-    with jax.named_scope("layer_stack"):
-        return _scan_layers(period_body, carry, xs, "none")
+def _chunked(xs, n: int):
+    """The stacked xs [L, ...] seen as [L / n, n, ...]."""
+    return jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // n, n) + a.shape[1:]), xs)
 
 
 def _layer_dropout_rates(cfg: ModelConfig) -> jnp.ndarray:
@@ -222,6 +203,72 @@ def _layer_dropout_rates(cfg: ModelConfig) -> jnp.ndarray:
     if cfg.lima_dropout and L > 1:
         return cfg.hidden_dropout * jnp.arange(L, dtype=jnp.float32) / (L - 1)
     return jnp.full((L,), cfg.hidden_dropout, dtype=jnp.float32)
+
+
+def rope_tables(cfg: ModelConfig, kinds, length: int) -> Dict[Any, Any]:
+    """One rotary table a kind of attention layer, by kind (None for a
+    model without rotary embeddings)."""
+    rotary = cfg.position_embedding_type == "rotary"
+    return {kind: rope_table(kind, cfg.head_dim, length) if rotary else None
+            for kind in dict.fromkeys(kinds)}
+
+
+def run_layers(
+    cfg: ModelConfig,
+    layers: Dict[str, Any],   # stacked [n, ...]: the whole stack, or a slice
+    carry,                    # (x, moe_aux, kv store, gradient sinks)
+    ropes: Dict[Any, Any],    # rope_tables
+    positions: Optional[jnp.ndarray],
+    first_layer=0,            # the slice's first layer in the whole network
+    dropout_key: Optional[jax.Array] = None,
+    recompute: str = "none",
+    **layer_args,
+):
+    """What a layer of the stack is, for the whole model (lm_forward), a
+    pipeline chunk (training/pipeline.py) and a decoding stage
+    (inference/pipelined.py): `layers` through scan_with_remat, their
+    kinds those of `cfg.attention_period` in order; returns the carry.
+
+    carry: x [B, S, h]; the layers' router statistics merged so far (from
+    ops/moe.py moe_stats_zero on; a dense layer adds its zero scalar to
+    whatever zero the caller starts from); the KV store and the gradient
+    sinks (lm_forward's kv_caches and grad_sink), or None. Those two ride
+    in the carry so that the layers write the donated store, and the
+    kernels the sinks' cotangents, in place: as a scanned input and
+    output either would be a second copy, built layer by layer every call.
+
+    LIMA's dropout rate and the dropout key go by a layer's index in the
+    whole network, first_layer (traced or not) + its index in `layers`;
+    the store and the sinks, stacked like `layers`, by the latter.
+    layer_args go to every layer alike (block_forward's)."""
+    n = jax.tree.leaves(layers)[0].shape[0]
+    rates = _layer_dropout_rates(cfg)
+    if n != cfg.num_layers:
+        rates = jax.lax.dynamic_slice_in_dim(rates, first_layer, n)
+    add_aux = (operator.add if cfg.num_experts is None
+               else merge_layer_stats)
+
+    def body(carry, scanned, kind):
+        x, aux, caches, sinks = carry
+        lp, rate, idx = scanned
+        key = (None if dropout_key is None
+               else jax.random.fold_in(dropout_key, first_layer + idx))
+        y, caches, moe_aux, sinks = block_forward(
+            cfg, lp, x, ropes[kind], positions,
+            dropout_key=key,
+            hidden_dropout_rate=rate,
+            kv_cache=caches,
+            layer=idx,
+            grad_sink=sinks,
+            kind=kind,
+            **layer_args,
+        )
+        return (y, add_aux(aux, moe_aux), caches, sinks), None
+
+    # the kinds in their published order, each layer's window static
+    return scan_with_remat(
+        [partial(body, kind=kind) for kind in cfg.attention_period], carry,
+        (layers, rates, jnp.arange(n)), recompute)
 
 
 def embed_tokens(
@@ -344,65 +391,27 @@ def lm_forward(
     )
     x = sharder(x, "residual")
 
-    # one rotary table a kind of attention layer
-    period = cfg.attention_period
-    ropes = dict.fromkeys(period)
-    if cfg.position_embedding_type == "rotary":
-        if kv_caches is not None:
-            rope_len = kv_store.logical_length(kv_caches, page_table)
-        else:
-            rope_len = max(cfg.seq_length, tokens.shape[1])
-        ropes = {kind: rope_table(kind, cfg.head_dim, rope_len)
-                 for kind in ropes}
-
-    rates = _layer_dropout_rates(cfg)
+    if kv_caches is not None:
+        rope_len = kv_store.logical_length(kv_caches, page_table)
+    else:
+        rope_len = max(cfg.seq_length, tokens.shape[1])
+    ropes = rope_tables(cfg, cfg.attention_period, rope_len)
     moe = cfg.num_experts is not None
-    add_aux = merge_layer_stats if moe else operator.add
-
-    # the caches ride in the carry: each layer writes its rows into the
-    # stacked store in place (ops/kv_store.py) and the store the caller
-    # donated is the one handed back. As a scanned input and output the
-    # scan would build a second store, layer by layer, every call. So do
-    # the gradient sinks, whose cotangents the backward scan then carries
-    # and the kernels update in place: a scanned slice of a stacked
-    # accumulator would be copied in and out, layer by layer.
-    def body(carry, scanned, kind=period[0]):
-        x, aux, caches, sinks = carry
-        lp, rate, idx = scanned
-        key = jax.random.fold_in(dropout_key, idx) if train else None
-        y, caches, moe_aux, sinks = block_forward(
-            cfg, lp, x, ropes[kind], positions,
-            dropout_key=key,
-            hidden_dropout_rate=rate,
-            kv_cache=caches,
-            layer=idx,
-            cache_index=cache_index,
-            sharder=sharder,
-            padding_mask=attention_mask,
-            page_table=page_table,
-            page_write_start=page_write_start,
-            page_write_end=page_write_end,
-            tp_comm=tp_comm,
-            cp_comm=cp_comm,
-            grad_sink=sinks,
-            kind=kind,
-        )
-        return (y, add_aux(aux, moe_aux), caches, sinks), None
-
-    layer_idx = jnp.arange(cfg.num_layers)
-    xs = (params["layers"], rates, layer_idx)
-    if kv_caches is not None and parse_recompute(recompute)[1] is not None:
-        recompute = "none"  # decode path: caches preclude the split scan
     carry = (x, moe_stats_zero(cfg) if moe else jnp.zeros((), jnp.float32),
              kv_caches, None if grad_sink is None else grad_sink["layers"])
-    if len(period) == 1:
-        carry, _ = scan_with_remat(body, carry, xs, recompute)
-    else:
-        # the kinds in their published order, each layer's window static
-        carry, _ = scan_periods_with_remat(
-            [partial(body, kind=kind) for kind in period], carry, xs,
-            recompute)
-    x, moe_aux, new_caches, layer_sinks = carry
+    x, moe_aux, new_caches, layer_sinks = run_layers(
+        cfg, params["layers"], carry, ropes, positions,
+        dropout_key=dropout_key if train else None,
+        recompute=recompute,
+        cache_index=cache_index,
+        sharder=sharder,
+        padding_mask=attention_mask,
+        page_table=page_table,
+        page_write_start=page_write_start,
+        page_write_end=page_write_end,
+        tp_comm=tp_comm,
+        cp_comm=cp_comm,
+    )
 
     def with_sinks(result):
         if grad_sink is None:

@@ -198,7 +198,9 @@ def layer_stats(aux, load) -> jnp.ndarray:
 
 
 def moe_stats_zero(cfg: ModelConfig) -> jnp.ndarray:
-    """What merge_layer_stats starts from."""
+    """What merge_layer_stats starts from (a dense stack under a
+    shard_map may start from it too, where a scan's carry must not be of
+    rank 0: its layers add their zero scalar to it)."""
     return jnp.zeros((3,) if cfg.holds_expert_share else (2,), jnp.float32)
 
 

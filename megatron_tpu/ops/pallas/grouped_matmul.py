@@ -104,14 +104,8 @@ class GroupVisits(NamedTuple):
 def group_visits(group_sizes: jnp.ndarray, m: int, tm: int) -> GroupVisits:
     """The visit table for rows cut into m / tm tiles.
 
-    `lax.div`, not `//` (the rows are not negative, so it floors): with
-    jnp's floor_divide twice in a layer's body, or with two tables in it,
-    XLA inlines a layer scan of length one only after its first CSE, which
-    then no longer merges a one-layer model's recomputed forward with the
-    forward itself (in the benchmark's OLMoE cell: a second `flash_fwd` a
-    micro-batch, 12 ms a step; tests/test_chip_compile.py counts one).
-    Since PR 33 a stack of one layer is a call and no loop
-    (language_model.scan_with_remat), so nothing hangs on that order."""
+    `lax.div`, not `//`: the rows are not negative, so truncating floors,
+    and jnp's floor_divide would put its sign correction on every index."""
     E = group_sizes.shape[0]
     n_tiles = m // tm
     sizes = group_sizes.astype(jnp.int32)

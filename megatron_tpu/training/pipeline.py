@@ -48,13 +48,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.models.language_model import (
-    _dropout, _layer_dropout_rates, chunked_lm_loss,
-    final_hidden_norm, lm_logits, scan_with_remat,
+    _dropout, chunked_lm_loss, final_hidden_norm, lm_logits, rope_tables,
+    run_layers,
 )
-from megatron_tpu.models.transformer import block_forward
 from megatron_tpu.ops.cross_entropy import cross_entropy_loss
-from megatron_tpu.ops.moe import aux_loss_of
-from megatron_tpu.ops.rotary import rope_table
+from megatron_tpu.ops.moe import aux_loss_of, moe_stats_zero
 
 
 def _embed_onehot(cfg: ModelConfig, params: Dict[str, Any],
@@ -95,42 +93,21 @@ def _embed_onehot(cfg: ModelConfig, params: Dict[str, Any],
 
 
 def _stage_fn(cfg: ModelConfig, chunk_layers: Any, x: jnp.ndarray,
-              rope, positions, dropout_key, global_offset: jnp.ndarray,
-              layers_per_chunk: int, recompute: str,
-              sharder=None):
-    """Run one chunk's contiguous slice of layers (lax.scan over Lv).
-    global_offset = index of the chunk's first layer in the full network
-    (for per-layer LIMA dropout rates and dropout key folding).
-    Returns (x, moe_aux_sum) — aux is a zero [1]-vector for dense models
-    (shape [1], not scalar: rank-0 accumulators crossing a differentiated
-    shard_map scan trip jax 0.4.37's residual naming, see pipelined())."""
-    rates_all = _layer_dropout_rates(cfg)  # [L] per-global-layer rates
-
-    def body(carry, scanned):
-        x, aux = carry
-        lp, local_idx = scanned
-        global_idx = global_offset + local_idx
-        rate = rates_all[global_idx]
-        key = (jax.random.fold_in(dropout_key, global_idx)
-               if dropout_key is not None else None)
-        y, _, moe_aux, _ = block_forward(
-            cfg, lp, x, rope, positions, dropout_key=key,
-            hidden_dropout_rate=rate,
-            **({"sharder": sharder} if sharder else {}))
-        return (y, aux + aux_loss_of(moe_aux)), None
-
-    # block:N remats only the first N of this chunk's layers (the
-    # reference applies the budget per pipeline stage)
-    (x, aux), _ = scan_with_remat(
-        body, (x, jnp.zeros((1,), jnp.float32)),
-        (chunk_layers, jnp.arange(layers_per_chunk)), recompute)
-    return x, aux
-
-
-def _reshape1(out):
-    """(x, aux) with aux coerced to shape [1] (see _stage_fn docstring)."""
-    x, aux = out
-    return x, aux.reshape(1)
+              ropes, positions, dropout_key, global_offset: jnp.ndarray,
+              recompute: str, sharder=None):
+    """Run one chunk's contiguous slice of layers (block:N remats the
+    first N of them: the reference applies the budget per pipeline stage).
+    global_offset = index of the chunk's first layer in the full network.
+    Returns (x, moe_aux_sum): the aux-loss term of the chunk's merged
+    statistics, zero for dense models, a [1]-vector like the statistics
+    (never a scalar: rank-0 accumulators crossing a differentiated
+    shard_map scan trip jax 0.4.37's residual naming, see pipelined();
+    analysis/jaxpr_audit.py holds the convention)."""
+    x, moe_aux, _, _ = run_layers(
+        cfg, chunk_layers, (x, moe_stats_zero(cfg), None, None), ropes,
+        positions, first_layer=global_offset, dropout_key=dropout_key,
+        recompute=recompute, **({"sharder": sharder} if sharder else {}))
+    return x, aux_loss_of(moe_aux)
 
 
 def vpp_place_indices(L: int, Pn: int, V: int):
@@ -271,10 +248,8 @@ def make_pipeline_loss_fn(
         dropout_on = dropout_key is not None and (
             model_cfg.hidden_dropout > 0 or model_cfg.attention_dropout > 0)
 
-        rope = None
-        if model_cfg.position_embedding_type == "rotary":
-            rope = rope_table(model_cfg.attention_kind, model_cfg.head_dim,
-                              max(model_cfg.seq_length, S))
+        ropes = rope_tables(model_cfg, [model_cfg.attention_kind],
+                            max(model_cfg.seq_length, S))
 
         T = M * V + Pn - 1  # pipeline ticks
 
@@ -332,8 +307,8 @@ def make_pipeline_loss_fn(
                 # below stays unconditional either way — the known deadlock
                 # class is collectives whose participants diverge.
                 def run_stage(x):
-                    return _stage_fn(model_cfg, chunk_layers, x, rope,
-                                     pos_m, key_t, global_offset, Lv,
+                    return _stage_fn(model_cfg, chunk_layers, x, ropes,
+                                     pos_m, key_t, global_offset,
                                      recompute, sharder=sharder)
 
                 # NB: every cross-tick accumulator below is kept [1]-shaped,
@@ -343,10 +318,10 @@ def make_pipeline_loss_fn(
                 # only appear after the final psum, outside the scan
                 if gate_bubbles:
                     out, stage_aux = jax.lax.cond(
-                        valid, lambda x: _reshape1(run_stage(x)),
+                        valid, run_stage,
                         lambda x: (x, jnp.zeros((1,), jnp.float32)), x)
                 else:
-                    out, stage_aux = _reshape1(run_stage(x))
+                    out, stage_aux = run_stage(x)
                     stage_aux = jnp.where(valid, stage_aux, 0.0)
 
                 def with_loss(_):

@@ -59,8 +59,7 @@ def _enc_stack(cfg, layers, x, padding_mask, recompute):
         h = h + _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], h))
         return h, None
 
-    x, _ = scan_with_remat(body, x, layers, recompute)
-    return x
+    return scan_with_remat([body], x, layers, recompute)
 
 
 def _dec_stack(cfg, layers, y, enc_out, enc_padding_mask, recompute):
@@ -74,8 +73,7 @@ def _dec_stack(cfg, layers, y, enc_out, enc_padding_mask, recompute):
         h = h + _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], h))
         return h, None
 
-    y, _ = scan_with_remat(body, y, layers, recompute)
-    return y
+    return scan_with_remat([body], y, layers, recompute)
 
 
 def make_t5_pipeline_loss_fn(

@@ -439,21 +439,21 @@ def test_interpreted_flash_kernels_match_the_reference_attention(
         assert float(jnp.max(jnp.abs(full - out))) > 1e-2
 
 
-# --- a one-kind model through the new stack ---------------------------------
+# --- a one-kind model said as a period of two --------------------------------
 
-def test_a_one_kind_model_is_bit_equal_through_the_period_stack():
-    """A model whose layers are all alike, said as a pattern of one kind
-    repeated over a period of two, runs through scan_periods_with_remat:
-    the same parameters (the stacked [L, ...] layout is the same tree) and
-    bit-equal loss and gradients to the scan over single layers."""
+def one_kind_pair(num_layers=4):
+    """(a model whose layers are all alike, the same model said as a
+    pattern of one kind repeated over a period of two, the parameters of
+    both: the stacked [L, ...] layout is the same tree)."""
     from megatron_tpu.models import presets
 
     one = dataclasses.replace(
-        presets.tiny(seq_length=SEQ), num_layers=4, sliding_window_size=8,
-        rope_theta=5e5, params_dtype="float32").validate()
+        presets.tiny(seq_length=SEQ), num_layers=num_layers,
+        sliding_window_size=8, rope_theta=5e5,
+        params_dtype="float32").validate()
     kind = one.attention_kind
     # (not validated: a model has one spelling, and validate() refuses
-    # this one: below)
+    # this one: test_a_model_has_one_spelling)
     as_pattern = dataclasses.replace(
         one, sliding_window_size=None, rope_theta=10000.0,
         attention_pattern=(kind, dataclasses.replace(kind, name="again")))
@@ -463,17 +463,70 @@ def test_a_one_kind_model_is_bit_equal_through_the_period_stack():
     assert jax.tree.all(jax.tree.map(
         lambda a, b: a.shape == b.shape and bool(jnp.all(a == b)),
         params, again))
+    return one, as_pattern, params
+
+
+@pytest.mark.parametrize(
+    "recompute", ["none", "selective", "full", "block:2", "uniform:2"])
+def test_a_one_kind_model_is_bit_equal_through_the_period_stack(recompute):
+    """The stack of periods of two gives bit-equal loss and gradients to
+    the stack of single layers under every recomputation, those that count
+    layers among them; a count that cuts a period is refused."""
+    one, as_pattern, params = one_kind_pair()
     batch = sequences()
-    for recompute in ("none", "selective", "full"):
-        want, want_grads = jax.value_and_grad(lambda p: lm_loss(
-            one, p, batch, recompute=recompute)[0])(params)
-        got, grads = jax.value_and_grad(lambda p: lm_loss(
-            as_pattern, p, batch, recompute=recompute)[0])(params)
-        assert float(got) == float(want), recompute
-        assert jax.tree.all(jax.tree.map(
-            lambda a, b: bool(jnp.all(a == b)), grads, want_grads))
-    with pytest.raises(NotImplementedError, match="several attention kinds"):
-        lm_loss(as_pattern, params, batch, recompute="block:1")
+    want, want_grads = jax.jit(jax.value_and_grad(lambda p: lm_loss(
+        one, p, batch, recompute=recompute)[0]))(params)
+    got, grads = jax.jit(jax.value_and_grad(lambda p: lm_loss(
+        as_pattern, p, batch, recompute=recompute)[0]))(params)
+    assert float(got) == float(want)
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: bool(jnp.all(a == b)), grads, want_grads))
+    n = recompute.partition(":")[2]
+    if n:
+        with pytest.raises(ValueError, match="multiple of the period"):
+            lm_loss(as_pattern, params, batch,
+                    recompute=recompute.replace(n, "1"))
+
+
+def _checkpoints(jaxpr, in_loop=False):
+    """(under a loop?, prevent_cse) of every `jax.checkpoint` equation of
+    a traced function, the ones inside other equations' bodies too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("remat"):
+            found.append((in_loop, eqn.params["prevent_cse"]))
+        loop = in_loop or eqn.primitive.name in ("scan", "while")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _checkpoints(sub, loop)
+    return found
+
+
+@pytest.mark.parametrize("case,layers,in_loop,prevent_cse", [
+    ("one", 1, False, False),         # XLA may merge it with the forward
+    ("as_pattern", 2, False, True),   # merged, the period would be kept
+    ("one", 4, True, False),
+    ("as_pattern", 4, True, False),
+])
+def test_a_stack_of_one_trip_is_a_call(case, layers, in_loop, prevent_cse):
+    """scan_with_remat's one-trip rule, read off the traced loss: one
+    layer is a checkpointed call that XLA may merge with its forward, one
+    period of several layers a call each that it may not, and more trips
+    are a loop with the layers' checkpoints inside."""
+    one, as_pattern, params = one_kind_pair(layers)
+    cfg = {"one": one, "as_pattern": as_pattern}[case]
+    traced = jax.make_jaxpr(lambda p: lm_loss(
+        cfg, p, sequences(), recompute="selective")[0])(params)
+    per_trip = len(cfg.attention_period)
+    assert _checkpoints(traced.jaxpr) == [(in_loop, prevent_cse)] * per_trip
+
+
+def test_a_model_has_one_spelling():
+    """A pattern whose layers are all alike is refused (the scalars say
+    it), as are the scalars beside a pattern and the one-kind paths for a
+    model of several kinds; what the scalars cannot say keeps the pattern
+    of one layer."""
+    one, as_pattern, _ = one_kind_pair()
+    kind = one.attention_kind
     with pytest.raises(NotImplementedError, match="one kind"):
         as_pattern.attention_kind
     with pytest.raises(ValueError, match="leave sliding_window_size"):
@@ -482,7 +535,6 @@ def test_a_one_kind_model_is_bit_equal_through_the_period_stack():
         with pytest.raises(ValueError, match="all alike: say a one-kind"):
             dataclasses.replace(as_pattern,
                                 attention_pattern=pattern).validate()
-    # what the scalars cannot say keeps the pattern of one layer
     yarn = dataclasses.replace(kind, rope_type="yarn",
                                yarn_original_max_positions=SEQ)
     dataclasses.replace(as_pattern, attention_pattern=(yarn,)).validate()
