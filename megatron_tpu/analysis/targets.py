@@ -200,6 +200,7 @@ def paged_decode_step_target(name: str = "decode_paged",
     args = (
         _sds(params),
         _sds(eng.caches),
+        eng.state,                                  # no state-space layers
         jax.ShapeDtypeStruct((N, eng.max_pages), jnp.int32),  # page table
         jax.ShapeDtypeStruct((N,), jnp.int32),      # last_tok
         jax.ShapeDtypeStruct((N,), jnp.int32),      # lengths
@@ -336,6 +337,7 @@ def cp_paged_decode_step_target(name: str = "decode_tp2_cp2",
     args = (
         _sds(sparams),
         _sds(eng.caches),
+        eng.state,                                  # no state-space layers
         jax.ShapeDtypeStruct((cp, N, eng._mpl), jnp.int32),  # local tables
         jax.ShapeDtypeStruct((N,), jnp.int32),      # last_tok
         jax.ShapeDtypeStruct((N,), jnp.int32),      # lengths
@@ -373,6 +375,7 @@ def cp_chunk_step_target(name: str = "prefill_cp2",
     args = (
         _sds(sparams),
         _sds(eng.caches),
+        eng.state,                                  # no state-space layers
         jax.ShapeDtypeStruct((cp, 1, eng._mpl), jnp.int32),  # local table
         jax.ShapeDtypeStruct((1, C + 1), jnp.int32),  # tokens_ext
         jax.ShapeDtypeStruct((), jnp.int32),          # off

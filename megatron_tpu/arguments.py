@@ -31,6 +31,19 @@ def _attention_pattern(text: str):
             f"not a JSON list of attention kinds: {e}") from e
 
 
+def _layer_pattern(text: str):
+    """--layer_pattern's JSON list of layer types as a tuple."""
+    try:
+        types = json.loads(text)
+        if not isinstance(types, list) or not all(
+                isinstance(t, str) for t in types):
+            raise ValueError("expected a list of strings")
+        return tuple(types)
+    except ValueError as e:
+        raise argparse.ArgumentTypeError(
+            f"not a JSON list of layer types: {e}") from e
+
+
 def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description="megatron_tpu",
                                 allow_abbrev=False)
@@ -47,7 +60,7 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     g.add_argument("--vocab_size", type=int, default=32000)
     g.add_argument("--make_vocab_size_divisible_by", type=int, default=128)
     g.add_argument("--position_embedding_type", default="rotary",
-                   choices=["rotary", "absolute"])
+                   choices=["rotary", "absolute", "none"])
     g.add_argument("--rope_theta", type=float, default=10000.0)
     g.add_argument("--rope_scaling_factor", type=float, default=1.0)
     g.add_argument("--layernorm_epsilon", type=float, default=1e-5)
@@ -82,6 +95,24 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
                         "In place of --sliding_window_size, --rope_theta "
                         "and --rope_scaling_factor, which state one kind "
                         "(layers that are all alike are said with those)")
+    g.add_argument("--layer_pattern", type=_layer_pattern, default=None,
+                   help="layers of several TYPES in one stack: a JSON list "
+                        "with one type a layer of one period of the "
+                        "pattern, \"attention\" or \"mamba\" (a Mamba-1 "
+                        "state-space mixer, sized by --ssm_*); the stack "
+                        "repeats it over --num_layers")
+    g.add_argument("--ssm_d_state", type=int, default=16,
+                   help="the state a channel of a state-space layer")
+    g.add_argument("--ssm_d_conv", type=int, default=4,
+                   help="the width of its causal convolution")
+    g.add_argument("--ssm_expand", type=int, default=2,
+                   help="its inner width over the hidden size")
+    g.add_argument("--ssm_dt_rank", type=int, default=None,
+                   help="the rank of its step size's projection (default: "
+                        "ceil(hidden_size / 16))")
+    g.add_argument("--ssm_inner_norms", action="store_true",
+                   help="an RMSNorm with a learned scale on dt, B and C "
+                        "(Jamba)")
     g.add_argument("--qk_norm", action="store_true", default=None,
                    help="RMSNorm with a learned scale over the whole q and "
                         "the whole k projection, before the head split and "
@@ -638,6 +669,12 @@ def args_to_run_config(args) -> RunConfig:
             **_moe_overrides(args),
             sliding_window_size=args.sliding_window_size,
             attention_pattern=args.attention_pattern,
+            layer_pattern=args.layer_pattern,
+            ssm_d_state=args.ssm_d_state,
+            ssm_d_conv=args.ssm_d_conv,
+            ssm_expand=args.ssm_expand,
+            ssm_dt_rank=args.ssm_dt_rank,
+            ssm_inner_norms=args.ssm_inner_norms,
             qk_norm=bool(args.qk_norm),
             use_post_ln=args.use_post_ln,
             apply_residual_post_ln=args.apply_residual_connection_post_layernorm,

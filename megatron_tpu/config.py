@@ -23,7 +23,13 @@ import jax.numpy as jnp
 # enums (ref: megatron/model/enums.py)
 # ---------------------------------------------------------------------------
 
-POSITION_EMBEDDING_TYPES = ("rotary", "absolute")
+# "none": no positional encoding at all (a stack whose state-space layers
+# carry the order of the sequence)
+POSITION_EMBEDDING_TYPES = ("rotary", "absolute", "none")
+# What a layer's sequence mixer can be (`ModelConfig.layer_pattern`):
+# softmax attention, or a Mamba-1 selective state-space mixer (ops/ssm.py).
+# Types differ in their parameters' shapes; attention KINDS share them.
+LAYER_TYPES = ("attention", "mamba")
 NORMALIZATION_TYPES = ("layernorm", "rmsnorm")
 # GLU family per ref megatron/model/glu_activations.py plus plain variants.
 ACTIVATION_TYPES = ("gelu", "gelu_tanh", "geglu", "swiglu", "reglu", "liglu", "relu", "squared_relu")
@@ -165,6 +171,25 @@ class ModelConfig:
     # Read through `attention_period`, never directly.
     attention_pattern: Optional[Tuple[AttentionKind, ...]] = None
 
+    # Layers of several TYPES in one stack (LAYER_TYPES): one period of the
+    # layers' types, which the stack repeats over its depth. A type has its
+    # own parameter leaves, stacked over THAT type's layers alone
+    # (models/params.py: layers/ssm/* over the "mamba" layers, layers/attn/*
+    # over the "attention" ones; norms and the FFN over all). None: every
+    # layer is an attention layer. Its attention layers are of one kind
+    # (no attention_pattern beside it). Read through `layer_period`.
+    layer_pattern: Optional[Tuple[str, ...]] = None
+    # The state-space mixer's sizes (Mamba-1, arXiv:2312.00752): the state
+    # a channel N, the causal convolution's width K, the inner width over
+    # the hidden size, the rank R of the step size's projection (None:
+    # ceil(hidden_size / 16)); ssm_inner_norms: an RMSNorm with a learned
+    # scale on each of dt, B and C before they are used (Jamba's own).
+    ssm_d_state: int = 16
+    ssm_d_conv: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: Optional[int] = None
+    ssm_inner_norms: bool = False
+
     # OLMoE QK-norm: RMSNorm with a learned scale over the WHOLE q and the
     # whole k projection (all heads at once), before the head split and
     # the rotary (HF modeling_olmoe.py q_norm / k_norm)
@@ -299,6 +324,29 @@ class ModelConfig:
         return self.attention_period[0]
 
     @property
+    def layer_period(self) -> Tuple[str, ...]:
+        """The types of the layers of one period of the stack, in order."""
+        return self.layer_pattern or ("attention",)
+
+    @property
+    def has_ssm(self) -> bool:
+        """Some layers carry a recurrent state and no keys."""
+        return "mamba" in self.layer_period
+
+    def layers_of(self, layer_type: str) -> int:
+        """How many of the stack's layers are of `layer_type`."""
+        period = self.layer_period
+        return self.num_layers // len(period) * period.count(layer_type)
+
+    @property
+    def ssm_d_inner(self) -> int:
+        return self.ssm_expand * self.hidden_size
+
+    @property
+    def ssm_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.hidden_size // 16)
+
+    @property
     def experts_held(self) -> Optional[int]:
         """The experts whose weights exist here; None for a dense model."""
         return self.moe_experts_held or self.num_experts
@@ -364,6 +412,33 @@ class ModelConfig:
                     "one-kind model with sliding_window_size, rope_theta "
                     "and rope_scaling_factor (or, where those cannot say "
                     "its rotary table, with a pattern of one layer)")
+        if self.layer_pattern is not None:
+            bad = set(self.layer_pattern) - set(LAYER_TYPES)
+            if bad or not self.layer_pattern:
+                raise ValueError(
+                    f"layer_pattern holds {sorted(bad)}; a layer is one of "
+                    f"{LAYER_TYPES}")
+            if self.num_layers % len(self.layer_pattern):
+                raise ValueError(
+                    f"layer_pattern of {len(self.layer_pattern)} layers "
+                    f"does not divide num_layers={self.num_layers}")
+            if set(self.layer_pattern) == {"attention"}:
+                raise ValueError(
+                    "layer_pattern's layers are all attention layers: "
+                    "leave it out")
+            if self.attention_pattern is not None:
+                raise NotImplementedError(
+                    "layer_pattern with attention_pattern: a typed stack's "
+                    "attention layers are of one kind")
+            if (self.parallel_attn or self.use_post_ln
+                    or self.num_experts is not None or self.fp8_format):
+                raise NotImplementedError(
+                    "a stack with state-space layers is pre-norm and "
+                    "sequential with a dense FFN in bf16/f32 (no "
+                    "parallel_attn, use_post_ln, num_experts, fp8_format)")
+            if min(self.ssm_d_state, self.ssm_d_conv, self.ssm_expand,
+                   self.ssm_rank) < 1:
+                raise ValueError("the state-space sizes must be >= 1")
         if self.moe_experts_held is not None:
             if self.num_experts is None or self.moe_dispatch != "dropless":
                 raise ValueError(
@@ -433,6 +508,14 @@ class ModelConfig:
         keys = sum(min(s, k.sliding_window_size or s) for k in period)
         per_layer += 2 * 2 * nq * hd * keys / len(period)
         total = self.num_layers * per_layer
+        if self.has_ssm:
+            # a state-space layer has its mixer's products in place of the
+            # projections and the scores: in, x, dt and out projections,
+            # the convolution, and ~9 operations a state element a token
+            di, n, r = self.ssm_d_inner, self.ssm_d_state, self.ssm_rank
+            mixer = (2 * h * 2 * di + 2 * di * (r + 2 * n) + 2 * r * di
+                     + 2 * di * h + 2 * self.ssm_d_conv * di + 9 * di * n)
+            total += self.layers_of("mamba") * (mixer - (per_layer - mlp))
         total += 2 * h * self.vocab_size                # logits
         return float(total)
 
@@ -456,6 +539,8 @@ def model_config_from_saved(saved: dict) -> ModelConfig:
         kept["attention_pattern"] = tuple(
             k if isinstance(k, AttentionKind) else AttentionKind(**k)
             for k in kept["attention_pattern"])
+    if kept.get("layer_pattern") is not None:
+        kept["layer_pattern"] = tuple(kept["layer_pattern"])
     return ModelConfig(**kept)
 
 

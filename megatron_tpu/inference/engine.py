@@ -168,6 +168,8 @@ class InferenceEngine:
                 f"max_seq_len {self.max_seq_len} exceeds "
                 f"max_position_embeddings {cfg.max_position_embeddings}")
         self.kv_cache_int8 = kv_cache_int8
+        if cfg.has_ssm:
+            self._refuse_unless_it_carries_state(mesh, speculative)
         # migration wire codec for FLOAT caches (fleet/migration.py):
         # "raw" ships native bytes (exact); "int8"/"fp8" quantize via
         # quant/primitives.py (smaller, NOT bit-exact — importers that
@@ -366,6 +368,24 @@ class InferenceEngine:
                                "comm_compressed_bytes": 0})
             self._journal_comm_policy()
         self._m_slots.set(num_slots)
+
+    # ----- models with state-space layers ---------------------------------
+
+    def _refuse_unless_it_carries_state(self, mesh, speculative) -> None:
+        """A model with state-space layers (cfg.layer_pattern) carries a
+        recurrent state a sequence beside its keys and values. The paged
+        engine holds it (a row a slot: paging/engine.py); every path that
+        cannot raises here, by name."""
+        raise NotImplementedError(
+            "the slot engine does not hold a recurrent state: serve a "
+            "model with state-space layers with --serve_kv_paging")
+
+    def _refuse_state_transfer(self, what: str) -> None:
+        if self.cfg.has_ssm:
+            raise NotImplementedError(
+                f"{what} moves keys and values only: a model with "
+                "state-space layers also needs its recurrent state at the "
+                "span's end, which no snapshot holds yet")
 
     # ----- cache + shape policy -------------------------------------------
 
@@ -1100,6 +1120,13 @@ class InferenceEngine:
         table here)."""
         return ()
 
+    def _call_decode_step(self, *carry):
+        """The decode step over what the engine holds for its sequences
+        (the paged engine: its state store too); the rest of its results."""
+        toks, lps, self.caches, keys, lens = self._decode_step(
+            self.params, self.caches, *self._decode_extra_args(), *carry)
+        return toks, lps, keys, lens
+
     def _decode_write_span(self) -> int:
         """Cache positions one decode tick writes per slot: 1 plain,
         k+1 speculative (the paged engine sizes page allocation off
@@ -1245,14 +1272,12 @@ class InferenceEngine:
         last, lens, keys, temps, top_ks, top_ps = self._init_carry()
         t_tick = time.monotonic()
         try:
-            toks, lps, caches, keys, lens = self._decode_step(
-                self.params, self.caches, *self._decode_extra_args(),
+            toks, lps, keys, lens = self._call_decode_step(
                 last, lens, keys, temps, top_ks, top_ps)
         except Exception as e:  # noqa: BLE001 - shared recovery, then
             # surface the error to the driver
             self._fail_decode(active, e)
             raise
-        self.caches = caches
         # toks/lens/keys chain into the next tick on device; only the
         # sampled tokens (and logprobs) cross to the host each tick
         self._carry = (toks, lens, keys, temps, top_ks, top_ps)
@@ -1467,6 +1492,8 @@ class InferenceEngine:
         emits exactly the tokens this engine would have — greedy AND
         sampled, because the chain keys migrate. Call with the step loop
         paused (self.paused()) or from the driver thread."""
+        if include_kv:
+            self._refuse_state_transfer("KV export (migration)")
         meta: Dict[str, Any] = {
             "kind": "request",
             "prompt": [int(t) for t in np.asarray(req.prompt).tolist()],
@@ -1660,6 +1687,8 @@ class InferenceEngine:
         if "resume_key" in sections:
             req.resume_key = np.asarray(sections["resume_key"], np.uint32)
         kv = meta.get("kv")
+        if kv is not None:
+            self._refuse_state_transfer("KV import (migration)")
         path, reason = "recompute", ""
         if kv is None:
             reason = "no KV in transfer"

@@ -43,7 +43,7 @@ from megatron_tpu.inference.paging.scheduler import (
     ChunkedPrefillQueue, PrefillTask,
 )
 from megatron_tpu.inference.sampling import sample_logits_batched
-from megatron_tpu.ops import kv_store
+from megatron_tpu.ops import kv_store, ssm
 
 
 class PagedInferenceEngine(InferenceEngine):
@@ -153,6 +153,23 @@ class PagedInferenceEngine(InferenceEngine):
                                     "one prefill chunk's wall time")
         self._m_pages_total.set(self.num_pages - 1)
         self._m_pages_free.set(self.pool.free_pages)
+        # a model with state-space layers: self.state (_fresh_caches) is
+        # its state store, a row a slot, beside the KV pool of its
+        # attention layers; None for every other model. The row is zeroed
+        # at admission, carried from chunk to chunk of its slot's prompt,
+        # advanced by the decode ticks the slot takes part in (those whose
+        # row of the decode table holds a page), and dropped with the slot.
+        self._m_state_bytes = m.gauge(
+            "engine_state_bytes",
+            "recurrent state held beside the KV pages (state-space layers)")
+        self._m_state_resets = m.counter(
+            "engine_state_resets_total",
+            "slot states zeroed at admission (state-space layers)")
+        if self.state is not None:
+            self.stats["state_resets"] = 0
+            self._m_state_bytes.set(ssm.state_bytes(self.state))
+            self._zero_state_row = jax.jit(
+                ssm.zero_row, donate_argnums=(0,) if self._donate() else ())
 
     # ----- cache + shape policy -------------------------------------------
 
@@ -161,9 +178,23 @@ class PagedInferenceEngine(InferenceEngine):
         # per-page, so the dense kernel's 128 constraint doesn't apply
         return self.page_size
 
+    def _refuse_unless_it_carries_state(self, mesh, speculative) -> None:
+        if speculative is not None:
+            raise NotImplementedError(
+                "speculative decoding over a model with state-space layers: "
+                "a rejected draft rolls the length back, and the recurrent "
+                "state has no rollback")
+        if mesh is not None or getattr(self, "cp_comm", None) is not None:
+            raise NotImplementedError(
+                "sharded serving (a tensor- or context-parallel mesh) of a "
+                "model with state-space layers: the state store and the "
+                "mixer are not sharded; serve it on one chip")
+
     def _fresh_caches(self):
         """Paged pools: num_pages rows of page_size positions
-        (ops/kv_store.py; int8 with per-position scales). On the
+        (ops/kv_store.py; int8 with per-position scales), of the attention
+        layers; with them self.state, the state-space layers' state store
+        (ops/ssm.py: a zeroed row a slot; None for a model without). On the
         failed-step rebuild path every cached prefix dies with the pool
         bytes, and mid-prefill slots lose their computed chunks — fail
         them like the active ones the caller already failed."""
@@ -182,6 +213,8 @@ class PagedInferenceEngine(InferenceEngine):
             self.num_pages = self.num_slots * self.max_pages + 1
         else:
             self.max_pages = -(-self.max_seq_len // self.page_size)
+        self.state = (self._commit(ssm.create_state(self.cfg, self.num_slots))
+                      if self.cfg.has_ssm else None)
         return kv_store.create(self.cfg, self.num_pages, self.page_size,
                                int8=self.kv_cache_int8)
 
@@ -201,31 +234,51 @@ class PagedInferenceEngine(InferenceEngine):
 
     # ----- jitted device steps --------------------------------------------
 
-    def _build_decode_step(self):
-        cfg, vocab, wlp = self.cfg, self.vocab_size, self.want_logprobs
-        tp_comm = self.tp_comm
+    def _donate_with_state(self):
+        """Both steps write the pool and the state store in place."""
+        return (1, 2) if self._donate() else ()
+
+    def _forward(self):
+        """lm_forward over the page pool and the state store beside it
+        (None without state-space layers) -> (logits, pool, state): what
+        both jitted steps run."""
+        cfg, tp_comm = self.cfg, self.tp_comm
         # the CP engine sets cp_comm before super().__init__ so the same
         # builders serve it — a 3-D device table then routes the forward
         # through the ring-attention island (models/transformer.py)
         cp_comm = getattr(self, "cp_comm", None)
-        from functools import partial
-
         from megatron_tpu.models.language_model import lm_forward
 
-        @partial(jax.jit, donate_argnums=self._donate(),
+        def forward(params, caches, state, tokens, **where):
+            out = lm_forward(cfg, params, tokens, kv_caches=caches,
+                             ssm_state=state, tp_comm=tp_comm,
+                             cp_comm=cp_comm, **where)
+            return out if state is not None else (*out, None)
+
+        return forward
+
+    def _build_decode_step(self):
+        vocab, wlp = self.vocab_size, self.want_logprobs
+        forward = self._forward()
+        from functools import partial
+
+        @partial(jax.jit, donate_argnums=self._donate_with_state(),
                  **self._jit_sharding_kwargs(
-                     ("rep", "rep", "kv", "rep", "rep")))
-        def decode_step(params, caches, table, last_tok, lengths, keys,
-                        temps, top_ks, top_ps):
+                     ("rep", "rep", "kv", "rep", "rep", "rep")))
+        def decode_step(params, caches, state, table, last_tok, lengths,
+                        keys, temps, top_ks, top_ps):
             # identical to the slot decode step except K/V writes and
             # reads route through the page table (ops/attention.py picks
-            # the paged flash-decode kernel on TPU, the gather elsewhere)
-            logits, caches = lm_forward(cfg, params, last_tok[:, None],
-                                        kv_caches=caches,
-                                        cache_index=lengths,
-                                        page_table=table,
-                                        tp_comm=tp_comm,
-                                        cp_comm=cp_comm)
+            # the paged flash-decode kernel on TPU, the gather elsewhere).
+            # state (None without state-space layers): this tick advances
+            # the rows of the slots that decode, those whose row of the
+            # table holds a page (an idle slot's and a prefilling slot's
+            # are scratch: their state stays).
+            decoding = (None if state is None else
+                        (table[:, 0] != SCRATCH_PAGE).astype(jnp.int32))
+            logits, caches, state = forward(
+                params, caches, state, last_tok[:, None],
+                cache_index=lengths, page_table=table, state_valid=decoding)
             logits = logits[:, 0]
             split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
             new_keys, subs = split[:, 0], split[:, 1]
@@ -237,25 +290,22 @@ class PagedInferenceEngine(InferenceEngine):
                     toks[:, None], axis=-1)[:, 0]
             else:
                 lp = jnp.zeros(toks.shape, jnp.float32)
-            return toks, lp, caches, new_keys, lengths + 1
+            return toks, lp, caches, state, new_keys, lengths + 1
 
         return decode_step
 
     def _build_chunk_step(self):
-        cfg, vocab, wlp = self.cfg, self.vocab_size, self.want_logprobs
+        vocab, wlp = self.vocab_size, self.want_logprobs
         C = self.prefill_chunk
-        tp_comm = self.tp_comm
-        cp_comm = getattr(self, "cp_comm", None)
+        forward = self._forward()
         from functools import partial
 
-        from megatron_tpu.models.language_model import lm_forward
-
-        @partial(jax.jit, donate_argnums=self._donate(),
+        @partial(jax.jit, donate_argnums=self._donate_with_state(),
                  **self._jit_sharding_kwargs(
-                     ("rep", "rep", "rep", "kv", "rep")))
-        def chunk_step(params, caches, table_row, tokens_ext, off,
+                     ("rep", "rep", "rep", "kv", "rep", "rep")))
+        def chunk_step(params, caches, state, table_row, tokens_ext, off,
                        write_start, write_end, sample_pos, key, temp,
-                       top_k, top_p):
+                       top_k, top_p, slot=None):
             """One prefill chunk of one prompt.
 
             tokens_ext [1, C+1]: the chunk's tokens at absolute positions
@@ -266,14 +316,20 @@ class PagedInferenceEngine(InferenceEngine):
             tail). Every call also samples from the logits at absolute
             position sample_pos (= prompt_len - 1); the host uses that
             token and the advanced key only on the final chunk, so
-            non-final chunks never consume the request's PRNG chain."""
-            logits, caches = lm_forward(cfg, params, tokens_ext[:, :C],
-                                        kv_caches=caches, cache_index=off,
-                                        page_table=table_row,
-                                        page_write_start=write_start,
-                                        page_write_end=write_end,
-                                        tp_comm=tp_comm,
-                                        cp_comm=cp_comm)
+            non-final chunks never consume the request's PRNG chain.
+
+            state, slot (None without state-space layers): the state
+            store and the row of it the prompt belongs to. The chunk takes
+            the state up where the prompt's last chunk left it and leaves
+            it after the last real position (write_end - off of C: the
+            padded tail moves neither the state nor the convolution's
+            tail)."""
+            logits, caches, state = forward(
+                params, caches, state, tokens_ext[:, :C],
+                cache_index=off, page_table=table_row,
+                page_write_start=write_start, page_write_end=write_end,
+                state_row=slot,
+                state_valid=jnp.clip(write_end - off, 0, C)[None])
             if wlp:
                 lsm = jax.nn.log_softmax(logits[0].astype(jnp.float32),
                                          axis=-1)
@@ -295,7 +351,7 @@ class PagedInferenceEngine(InferenceEngine):
                     tok[None, None], axis=-1)[0, 0]
             else:
                 lp = jnp.zeros((), jnp.float32)
-            return tok, lp, plp, caches, key
+            return tok, lp, plp, caches, state, key
 
         return chunk_step
 
@@ -398,7 +454,12 @@ class PagedInferenceEngine(InferenceEngine):
                 if resumed else np.asarray(req.prompt, np.int32))
         p_ext = len(toks)
         ps = self.page_size
-        hit_pages, hit_lps = self.prefix_cache.lookup(toks)
+        # the prefix cache gives a model with state-space layers no hit:
+        # a hit needs the recurrent state at the prefix's end beside its
+        # pages, and no snapshot holds it yet (the tree is never asked,
+        # and _finish_prefill enters nothing into it)
+        hit_pages, hit_lps = (([], []) if self.cfg.has_ssm
+                              else self.prefix_cache.lookup(toks))
         span = len(hit_pages) * ps
         n_prompt_pages = -(-p_ext // ps)
         # retain the hits BEFORE allocating: _alloc_pages may evict
@@ -425,6 +486,11 @@ class PagedInferenceEngine(InferenceEngine):
         row[:len(hit_pages)] = hit_pages
         row[len(hit_pages):n_prompt_pages] = fresh
         self._pending_rows[i] = row
+        if self.state is not None:
+            # a sequence starts (a preempted one again, from position 0)
+            self.state = self._zero_state_row(self.state, jnp.int32(i))
+            self.stats["state_resets"] += 1
+            self._m_state_resets.inc()
         self.slots[i] = req
         self._admit_counter += 1
         self._admit_seq[i] = self._admit_counter
@@ -476,14 +542,15 @@ class PagedInferenceEngine(InferenceEngine):
         row = self._pending_rows[i]
         t0 = time.monotonic()
         try:
-            tok, lp, plp, caches, key = self._chunk_step(
-                self.params, self.caches, self._chunk_table_arg(row),
+            tok, lp, plp, self.caches, self.state, key = self._chunk_step(
+                self.params, self.caches, self.state,
+                self._chunk_table_arg(row),
                 jnp.asarray(toks_ext), jnp.int32(off),
                 jnp.int32(task.write_start), jnp.int32(task.total),
                 jnp.int32(task.total - 1), jnp.asarray(task.key),
                 jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jnp.float32(req.top_p))
-            self.caches = caches
+                jnp.float32(req.top_p),
+                None if self.state is None else jnp.int32(i))
             if self._has_draft_model():
                 # mirror the chunk into the draft pools through the same
                 # table row and write fences
@@ -551,7 +618,7 @@ class PagedInferenceEngine(InferenceEngine):
                 float(x) for x in np.concatenate(task.plp_parts)[:p_ext - 1]
             ] if task.plp_parts else []
         p0 = len(req.prompt)
-        if p0 >= self.page_size:
+        if p0 >= self.page_size and not self.cfg.has_ssm:
             # only FULL pages of the ORIGINAL prompt enter the tree (the
             # partially-filled tail page stays private — decode writes
             # into it); resumes re-register recomputed pages, and insert
@@ -640,6 +707,12 @@ class PagedInferenceEngine(InferenceEngine):
             self._device_table = self._commit_small(jnp.asarray(self.tables))
             self._table_dirty = False
         return (self._device_table,)
+
+    def _call_decode_step(self, *carry):
+        toks, lps, self.caches, self.state, keys, lens = self._decode_step(
+            self.params, self.caches, self.state,
+            *self._decode_extra_args(), *carry)
+        return toks, lps, keys, lens
 
     def _chunk_table_arg(self, row):
         """Device form of one pending table row for the chunk step
@@ -773,6 +846,7 @@ class PagedInferenceEngine(InferenceEngine):
         """Package the radix-cached whole-page prefix of `tokens` for
         replication to a peer: (meta, sections) in the migration wire
         vocabulary (kind="prefix"), or None when nothing is cached."""
+        self._refuse_state_transfer("the fleet's prefix directory")
         toks = [int(t) for t in tokens]
         with self.paused():
             pages, lps = self.prefix_cache.lookup(toks)
@@ -796,6 +870,7 @@ class PagedInferenceEngine(InferenceEngine):
         Returns pages added (0 = incompatible, lossy, or already
         cached). Only EXACT codecs enter the tree — a lossy prefix would
         silently poison every future request that hits it."""
+        self._refuse_state_transfer("the fleet's prefix directory")
         kv = meta.get("kv") or {}
         ok, _ = self._kv_import_compatible(kv)
         if not ok or not kv.get("exact"):

@@ -63,8 +63,8 @@ def make_pipelined_lm_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int):
 
                 # this stage's layers write their rows into its shard of
                 # the store in place
-                y, _, caches, _ = run_layers(
-                    cfg, layers, (x, moe_stats_zero(cfg), caches, None),
+                y, _, caches, _, _ = run_layers(
+                    cfg, layers, (x, moe_stats_zero(cfg), caches, None, None),
                     ropes, positions, first_layer=stage * Lp,
                     cache_index=cache_index)
                 return y, caches

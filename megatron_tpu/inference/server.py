@@ -95,7 +95,11 @@ from megatron_tpu.inference.engine import (
 from megatron_tpu.telemetry.http import PROMETHEUS_CONTENT_TYPE
 from megatron_tpu.telemetry.metrics import MetricsRegistry, default_registry
 
-MAX_TOKENS_TO_GENERATE = 1024  # ref caps requests similarly
+# A bound on one request's size (the reference caps requests similarly, at
+# 1024: an answer that reasons before it replies is longer). What a
+# sequence may hold is the engine's to say: admission rejects prompt +
+# answer past its sequence length.
+MAX_TOKENS_TO_GENERATE = 4096
 MAX_PROMPTS = 128
 #: Retry-After hint on 503 queue-full rejections: one decode tick's
 #: worth of backoff is enough for a slot to free in steady traffic

@@ -105,7 +105,7 @@ def _remat_policy(recompute: str):
     raise ValueError(f"unknown recompute policy {recompute!r}")
 
 
-def scan_with_remat(bodies, carry, xs, recompute: str):
+def scan_with_remat(bodies, carry, xs, recompute: str, types=None):
     """The loop of every layer stack (the LM's, a pipeline chunk's, T5's
     encoder and decoder slices) under the remat policy; returns the carry.
 
@@ -116,6 +116,12 @@ def scan_with_remat(bodies, carry, xs, recompute: str):
     each under the policy on its own, on slices of a [L / period, period,
     ...] view of the stacked `xs` (no view, no index where the period is
     one). "block:N" / "uniform:N" count layers, N a multiple of the period.
+
+    types: the layer TYPE of each layer of the period, where types differ
+    (ModelConfig.layer_pattern). `xs` is then (common, {type: tree}): a
+    type's tree is stacked over THAT type's layers alone, and bodies[i]
+    is handed (common's slice for its layer, its type's tree's slice,
+    the layer's index among ALL the stack's layers of its type).
 
     A stack of one trip is a call, not a loop: XLA inlines such a loop
     sooner or later, and how soon decides what its first CSE still sees.
@@ -136,6 +142,10 @@ def scan_with_remat(bodies, carry, xs, recompute: str):
         raise ValueError(
             f"recompute {recompute!r} counts layers of a stack of {period} "
             "layers a period: the count must be a multiple of the period")
+    if n is not None and types is not None:
+        raise NotImplementedError(
+            f"recompute {recompute!r} slices the stack by layers; a stack "
+            "of several layer types takes none, selective or full")
     length = jax.tree.leaves(xs)[0].shape[0]
 
     def loop(bodies, carry, xs, policy):
@@ -146,18 +156,40 @@ def scan_with_remat(bodies, carry, xs, recompute: str):
                                      prevent_cse=trips == 1 and layers > 1)
                       for body in bodies]
         if layers == 1:
-            trip = bodies[0]
+            trip, scanned = bodies[0], xs
         else:
-            def trip(carry, scanned):
+            # where a trip finds its layers' slices. Layers of one type:
+            # in its own slice of the [trips, period, ...] view, which the
+            # scan hands it (and stacks the gradients of). Several types,
+            # each type's tree stacked over ITS layers alone: in the whole
+            # stacks, by the layer's index among the layers of its type; a
+            # trip's slice [period, ...] of those, scanned, is a copy of
+            # the period's weights every trip (1.2 GB of one leaf in a
+            # decode step of the benchmark's typed configuration)
+            if types is None:
+                scanned = _chunked(xs, layers)
+
+                def slices(view, i):
+                    return jax.tree.map(lambda a: a[i], view)
+            else:
+                scanned = jnp.arange(trips)
+                ordinal = [types[:i].count(t) for i, t in enumerate(types)]
+                common, typed = xs
+
+                def slices(t, i):
+                    of_type = t * types.count(types[i]) + ordinal[i]
+                    return (jax.tree.map(lambda a: a[t * layers + i], common),
+                            jax.tree.map(lambda a: a[of_type],
+                                         typed[types[i]]), of_type)
+
+            def trip(carry, at):
                 for i, body in enumerate(bodies):
-                    carry, _ = body(carry,
-                                    jax.tree.map(lambda a: a[i], scanned))
+                    carry, _ = body(carry, slices(at, i))
                 return carry, None
 
-            xs = _chunked(xs, layers)
         if trips == 1:
-            return trip(carry, jax.tree.map(lambda a: a[0], xs))[0]
-        return jax.lax.scan(trip, carry, xs)[0]
+            return trip(carry, jax.tree.map(lambda a: a[0], scanned))[0]
+        return jax.lax.scan(trip, carry, scanned)[0]
 
     with jax.named_scope("layer_stack"):
         if gran == "block":
@@ -216,7 +248,8 @@ def rope_tables(cfg: ModelConfig, kinds, length: int) -> Dict[Any, Any]:
 def run_layers(
     cfg: ModelConfig,
     layers: Dict[str, Any],   # stacked [n, ...]: the whole stack, or a slice
-    carry,                    # (x, moe_aux, kv store, gradient sinks)
+    carry,                    # (x, moe_aux, kv store, gradient sinks,
+                              #  state-space store)
     ropes: Dict[Any, Any],    # rope_tables
     positions: Optional[jnp.ndarray],
     first_layer=0,            # the slice's first layer in the whole network
@@ -232,28 +265,43 @@ def run_layers(
     carry: x [B, S, h]; the layers' router statistics merged so far (from
     ops/moe.py moe_stats_zero on; a dense layer adds its zero scalar to
     whatever zero the caller starts from); the KV store and the gradient
-    sinks (lm_forward's kv_caches and grad_sink), or None. Those two ride
-    in the carry so that the layers write the donated store, and the
-    kernels the sinks' cotangents, in place: as a scanned input and
-    output either would be a second copy, built layer by layer every call.
+    sinks (lm_forward's kv_caches and grad_sink), or None; the
+    state-space layers' state store (lm_forward's ssm_state), or None.
+    Those ride in the carry so that the layers write the donated stores,
+    and the kernels the sinks' cotangents, in place: as a scanned input
+    and output each would be a second copy, built layer by layer every
+    call.
+
+    A stack of several layer TYPES (cfg.layer_pattern) holds each type's
+    leaves (`layers["ssm"]`, `layers["attn"]`) stacked over that type's
+    layers, and the stores likewise: a layer indexes them by its ordinal
+    among the layers of its type. `layers` is then the whole stack.
 
     LIMA's dropout rate and the dropout key go by a layer's index in the
     whole network, first_layer (traced or not) + its index in `layers`;
     the store and the sinks, stacked like `layers`, by the latter.
     layer_args go to every layer alike (block_forward's)."""
-    n = jax.tree.leaves(layers)[0].shape[0]
+    # (a typed stack's first leaf may be of a type with fewer layers)
+    n = jax.tree.leaves(layers["ln1"] if cfg.layer_pattern
+                        else layers)[0].shape[0]
     rates = _layer_dropout_rates(cfg)
     if n != cfg.num_layers:
         rates = jax.lax.dynamic_slice_in_dim(rates, first_layer, n)
     add_aux = (operator.add if cfg.num_experts is None
                else merge_layer_stats)
 
-    def body(carry, scanned, kind):
-        x, aux, caches, sinks = carry
-        lp, rate, idx = scanned
+    def body(carry, scanned, kind, layer_type="attention", leaves=None):
+        x, aux, caches, sinks, state = carry
+        if leaves is None:
+            (lp, rate, idx), type_layer = scanned, None
+        else:
+            # a typed stack: this type's own leaves, under their name in
+            # `layers`, and the layer's index among the layers of its type
+            (lp, rate, idx), typed, type_layer = scanned
+            lp = {**lp, leaves: typed}
         key = (None if dropout_key is None
                else jax.random.fold_in(dropout_key, first_layer + idx))
-        y, caches, moe_aux, sinks = block_forward(
+        y, caches, moe_aux, sinks, state = block_forward(
             cfg, lp, x, ropes[kind], positions,
             dropout_key=key,
             hidden_dropout_rate=rate,
@@ -261,14 +309,31 @@ def run_layers(
             layer=idx,
             grad_sink=sinks,
             kind=kind,
+            layer_type=layer_type,
+            type_layer=type_layer,
+            ssm_state=state,
             **layer_args,
         )
-        return (y, add_aux(aux, moe_aux), caches, sinks), None
+        return (y, add_aux(aux, moe_aux), caches, sinks, state), None
 
-    # the kinds in their published order, each layer's window static
+    if cfg.layer_pattern is None:
+        # the kinds in their published order, each layer's window static
+        return scan_with_remat(
+            [partial(body, kind=kind) for kind in cfg.attention_period],
+            carry, (layers, rates, jnp.arange(n)), recompute)
+    if n != cfg.num_layers:
+        raise NotImplementedError(
+            "a slice of a stack of several layer types (a pipeline stage): "
+            "a type's leaves are stacked over that type's layers alone")
+    types = cfg.layer_period
+    names = {"attention": "attn", "mamba": "ssm"}
+    common = {k: v for k, v in layers.items() if k not in names.values()}
     return scan_with_remat(
-        [partial(body, kind=kind) for kind in cfg.attention_period], carry,
-        (layers, rates, jnp.arange(n)), recompute)
+        [partial(body, kind=cfg.attention_kind, layer_type=t, leaves=names[t])
+         for t in types],
+        carry, ((common, rates, jnp.arange(n)),
+                {t: layers[names[t]] for t in dict.fromkeys(types)}),
+        recompute, types=types)
 
 
 def embed_tokens(
@@ -344,8 +409,21 @@ def lm_forward(
     tp_comm=None,  # quant.TpComm: explicit/compressed TP collectives
     cp_comm=None,  # quant.CpComm: context-parallel ring transport
     grad_sink=None,
+    ssm_state=None,    # an ops/ssm.py state store (state-space layers)
+    state_row=None,
+    state_valid: Optional[jnp.ndarray] = None,   # [B] int32
 ):
     """Forward pass to logits.
+
+    ssm_state: the state store of a model with state-space layers
+    (ops/ssm.py `create_state`), beside kv_caches, which then holds the
+    attention layers alone; returns (logits, updated_caches,
+    updated_state). Donate it: the layers write it in place. The batch is
+    the store's rows in order, or (state_row, a traced scalar) the one row
+    a prefill chunk belongs to; state_valid [B]: how many of each row's
+    positions are real (a chunk's padded tail, a slot that does not
+    decode this tick: neither moves the state). None with such a model:
+    every sequence starts here and its state is dropped (training).
 
     grad_sink: float32 accumulators for the gradients of some leaves of
     `params`, in a tree shaped like `params` that holds None at every
@@ -398,8 +476,9 @@ def lm_forward(
     ropes = rope_tables(cfg, cfg.attention_period, rope_len)
     moe = cfg.num_experts is not None
     carry = (x, moe_stats_zero(cfg) if moe else jnp.zeros((), jnp.float32),
-             kv_caches, None if grad_sink is None else grad_sink["layers"])
-    x, moe_aux, new_caches, layer_sinks = run_layers(
+             kv_caches, None if grad_sink is None else grad_sink["layers"],
+             ssm_state)
+    x, moe_aux, new_caches, layer_sinks, new_state = run_layers(
         cfg, params["layers"], carry, ropes, positions,
         dropout_key=dropout_key if train else None,
         recompute=recompute,
@@ -411,6 +490,8 @@ def lm_forward(
         page_write_end=page_write_end,
         tp_comm=tp_comm,
         cp_comm=cp_comm,
+        **({} if ssm_state is None else
+           {"state_row": state_row, "state_valid": state_valid}),
     )
 
     def with_sinks(result):
@@ -435,6 +516,8 @@ def lm_forward(
                          "decode paths don't train the router")
     if return_moe_aux:
         return with_sinks((logits, moe_aux))
+    if ssm_state is not None:
+        return with_sinks((logits, new_caches, new_state))
     if kv_caches is not None:
         return with_sinks((logits, new_caches))
     return with_sinks(logits)

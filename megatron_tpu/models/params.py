@@ -34,6 +34,9 @@ _NORMAL = "normal"          # N(0, init_method_std)
 _SCALED = "scaled_normal"   # N(0, std / sqrt(2 * num_layers))  (output-facing)
 _ONES = "ones"
 _ZEROS = "zeros"
+# the state-space mixer's own (ops/ssm.py, Mamba's init)
+_A_LOG = "a_log"            # log(1..N) down the state axis
+_DT_BIAS = "dt_bias"        # softplus(bias) log-uniform in [1e-3, 1e-1]
 
 
 def _defs(cfg: ModelConfig) -> Dict[str, Any]:
@@ -66,20 +69,51 @@ def _defs(cfg: ModelConfig) -> Dict[str, Any]:
     if cfg.parallel_layernorm:
         norm("layers/ln_mlp")
 
-    d["layers/attn/wq"] = ((L, h, nq * D), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
-    d["layers/attn/wk"] = ((L, h, nkv * D), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
-    d["layers/attn/wv"] = ((L, h, nkv * D), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
-    d["layers/attn/wo"] = ((L, nq * D, h), P(AXIS_PIPE, AXIS_TENSOR, None), _SCALED)
+    # A layer type's leaves are stacked over THAT type's layers, in their
+    # order in the network (ModelConfig.layer_pattern; every layer an
+    # attention layer without one): norms and the FFN over all L.
+    La = cfg.layers_of("attention")
+    d["layers/attn/wq"] = ((La, h, nq * D), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
+    d["layers/attn/wk"] = ((La, h, nkv * D), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
+    d["layers/attn/wv"] = ((La, h, nkv * D), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
+    d["layers/attn/wo"] = ((La, nq * D, h), P(AXIS_PIPE, AXIS_TENSOR, None), _SCALED)
     if cfg.qk_norm:
         # one scale over the whole projection, sharded with its output axis
-        d["layers/attn/q_norm/scale"] = ((L, nq * D), P(AXIS_PIPE, AXIS_TENSOR), _ONES)
-        d["layers/attn/k_norm/scale"] = ((L, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ONES)
+        d["layers/attn/q_norm/scale"] = ((La, nq * D), P(AXIS_PIPE, AXIS_TENSOR), _ONES)
+        d["layers/attn/k_norm/scale"] = ((La, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ONES)
     if cfg.use_bias_qkv:
-        d["layers/attn/bq"] = ((L, nq * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
-        d["layers/attn/bk"] = ((L, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
-        d["layers/attn/bv"] = ((L, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
+        d["layers/attn/bq"] = ((La, nq * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
+        d["layers/attn/bk"] = ((La, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
+        d["layers/attn/bv"] = ((La, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
     if cfg.use_bias_linear:
-        d["layers/attn/bo"] = ((L, h), P(AXIS_PIPE, None), _ZEROS)
+        d["layers/attn/bo"] = ((La, h), P(AXIS_PIPE, None), _ZEROS)
+
+    if cfg.has_ssm:
+        # the Mamba-1 mixer (ops/ssm.py has the equations). The inner
+        # width is the LAST axis of every leaf that has it but the
+        # projections out of it: it is the one a vector lane runs along.
+        # Replicated over "tensor": the paths that shard refuse the type.
+        Ls = cfg.layers_of("mamba")
+        di, N = cfg.ssm_d_inner, cfg.ssm_d_state
+        K, R = cfg.ssm_d_conv, cfg.ssm_rank
+
+        def ssm(name, shape, kind):
+            d[f"layers/ssm/{name}"] = (
+                (Ls,) + shape, P(AXIS_PIPE, *(None,) * len(shape)), kind)
+
+        ssm("w_in", (h, 2 * di), _NORMAL)          # x and the gate z
+        ssm("conv_w", (K, di), _NORMAL)
+        ssm("conv_b", (di,), _ZEROS)
+        ssm("w_x", (di, R + 2 * N), _NORMAL)       # dt, B, C
+        if cfg.ssm_inner_norms:
+            ssm("dt_norm/scale", (R,), _ONES)
+            ssm("b_norm/scale", (N,), _ONES)
+            ssm("c_norm/scale", (N,), _ONES)
+        ssm("w_dt", (R, di), _NORMAL)
+        ssm("b_dt", (di,), _DT_BIAS)
+        ssm("a_log", (N, di), _A_LOG)
+        ssm("d_skip", (di,), _ONES)
+        ssm("w_out", (di, h), _SCALED)
 
     if cfg.num_experts is None:
         d["layers/mlp/w_in"] = ((L, h, Fin), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
@@ -172,6 +206,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Dict[str, Any]:
             flat[path] = jnp.ones(shape, dtype)
         elif kind == _ZEROS:
             flat[path] = jnp.zeros(shape, dtype)
+        elif kind == _A_LOG:
+            rows = jnp.log(jnp.arange(1, shape[-2] + 1, dtype=jnp.float32))
+            flat[path] = jnp.broadcast_to(rows[:, None], shape).astype(dtype)
+        elif kind == _DT_BIAS:
+            k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
+            dt = jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+            # the inverse of softplus
+            flat[path] = (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
         else:
             std = scaled_std if kind == _SCALED else cfg.init_method_std
             k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)

@@ -173,6 +173,27 @@ def gpt2(size: str = "124M", seq_length: int = 1024) -> ModelConfig:
     ).validate()
 
 
+def jamba(size: str = "2-3B", seq_length: int = 4096) -> ModelConfig:
+    """AI21 Jamba with a dense FFN in every layer (`num_experts` 1): in
+    each period of 14 layers the one at offset 7 is an attention layer
+    (20 heads over one KV head), the others Mamba-1 layers with Jamba's
+    norms on dt, B and C; no positional encoding; tied head
+    (https://huggingface.co/ai21labs/AI21-Jamba2-3B config.json)."""
+    if size != "2-3B":
+        raise ValueError(f"unknown jamba size {size}")
+    return ModelConfig(
+        hidden_size=2560, num_layers=28, num_attention_heads=20,
+        num_kv_heads=1, kv_channels=128, ffn_hidden_size=8192,
+        vocab_size=65536, seq_length=seq_length,
+        normalization="rmsnorm", activation="swiglu",
+        position_embedding_type="none", tie_embed_logits=True,
+        layernorm_epsilon=1e-6, init_method_std=0.02,
+        layer_pattern=("mamba",) * 7 + ("attention",) + ("mamba",) * 6,
+        ssm_d_state=16, ssm_d_conv=4, ssm_expand=2, ssm_dt_rank=160,
+        ssm_inner_norms=True, attention_impl="pallas",
+    ).validate()
+
+
 def tiny(vocab_size: int = 256, seq_length: int = 128, **kw) -> ModelConfig:
     """Small config for tests/CI."""
     base = dict(
@@ -195,5 +216,6 @@ PRESETS = {
     "olmoe": olmoe,
     "falcon": falcon,
     "gpt2": gpt2,
+    "jamba": jamba,
     "tiny": tiny,
 }

@@ -32,6 +32,7 @@ from megatron_tpu.ops.fp8 import maybe_fp8_matmul
 from megatron_tpu.ops.moe import layer_stats, moe_block
 from megatron_tpu.ops.normalization import norm_forward, rmsnorm
 from megatron_tpu.ops.rotary import apply_rotary_emb
+from megatron_tpu.ops.ssm import read_state, ssm_mixer, write_state
 from megatron_tpu.ops.weight_quant import deq
 
 Sharder = Callable[[jnp.ndarray, str], jnp.ndarray]
@@ -279,9 +280,26 @@ def block_forward(
     cp_comm=None,
     grad_sink=None,
     kind: Optional[AttentionKind] = None,
+    layer_type: str = "attention",
+    type_layer=None,    # this layer's ordinal among the layers of its type
+    ssm_state=None,     # ops/ssm.py state store, all state-space layers
+    state_row=None,
+    state_valid: Optional[jnp.ndarray] = None,
 ):
-    """One decoder layer -> (y, kv_cache, moe_aux, grad_sink): kv_cache is
-    the whole store with this layer's rows written (attention_block).
+    """One decoder layer -> (y, kv_cache, moe_aux, grad_sink, ssm_state):
+    kv_cache is the whole store with this layer's rows written
+    (attention_block).
+
+    layer_type (config.LAYER_TYPES): the layer's sequence mixer. In a
+    stack of several types a type's stores hold ITS layers, and
+    `type_layer` indexes them (None: `layer`, every layer of one type). A
+    "mamba" layer runs ops/ssm.py's mixer under the region `attention`
+    (the layer's sequence mixer: a trace's regions are a fixed set) and
+    leaves kv_cache alone; it reads and writes its state in `ssm_state`
+    (None: the sequence starts here and its state is dropped, as in
+    training): of every row, the batch the store's rows in order, or of
+    `state_row` alone (one row's prefill chunk); state_valid [B]: the
+    positions that are real (ops/ssm.py).
 
     kind: this layer's attention kind (attention_block), with `rope` that
     kind's table. In a stack of several kinds the region `attention`
@@ -319,18 +337,30 @@ def block_forward(
         # own LN, reusing the ln1 parameter slot as the output norm
         with jax.named_scope("attn_norm"):
             normed = x if cfg.use_post_ln else _norm(cfg, lp["ln1"], x)
-        attn_out, kv_cache = attention_block(
-            cfg, lp["attn"], normed, rope, positions,
-            attn_dropout_key=k_attn_drop if cfg.attention_dropout > 0 else None,
-            kv_cache=kv_cache, layer=layer, cache_index=cache_index,
-            padding_mask=padding_mask,
-            page_table=page_table,
-            page_write_start=page_write_start,
-            page_write_end=page_write_end,
-            tp_comm=tp_comm,
-            cp_comm=cp_comm,
-            kind=kind,
-        )
+        if type_layer is None:
+            type_layer = layer
+        if layer_type == "mamba":
+            state = (None if ssm_state is None
+                     else read_state(ssm_state, type_layer, state_row))
+            attn_out, state = ssm_mixer(cfg, lp["ssm"], normed, state,
+                                        state_valid)
+            if ssm_state is not None:
+                ssm_state = write_state(ssm_state, type_layer, state,
+                                        state_row)
+        else:
+            attn_out, kv_cache = attention_block(
+                cfg, lp["attn"], normed, rope, positions,
+                attn_dropout_key=(k_attn_drop if cfg.attention_dropout > 0
+                                  else None),
+                kv_cache=kv_cache, layer=type_layer, cache_index=cache_index,
+                padding_mask=padding_mask,
+                page_table=page_table,
+                page_write_start=page_write_start,
+                page_write_end=page_write_end,
+                tp_comm=tp_comm,
+                cp_comm=cp_comm,
+                kind=kind,
+            )
         with jax.named_scope("attn_out"):
             attn_out = _dropout(attn_out, rate, k_hidden1 if cfg.hidden_dropout > 0 else None)
             if not cfg.parallel_attn:
@@ -363,4 +393,4 @@ def block_forward(
                 if cfg.use_post_ln:
                     y = _norm(cfg, lp["ln1"], y)
     y = sharder(y, "residual")
-    return y, kv_cache, moe_aux, grad_sink
+    return y, kv_cache, moe_aux, grad_sink, ssm_state

@@ -49,9 +49,13 @@ Store = Tuple[jnp.ndarray, ...]
 
 
 def create(cfg, rows: int, row_len: int, int8: bool = False) -> Store:
-    """A zeroed store for `cfg`'s layers: `rows` slots of `row_len`
-    positions, or a pool of `rows` pages of `row_len` positions."""
-    shape = (cfg.num_layers, rows, row_len, cfg.n_kv_heads, cfg.head_dim)
+    """A zeroed store for `cfg`'s attention layers (every layer, but in a
+    stack with state-space layers, whose state is ops/ssm.py's store; a
+    layer indexes the store by its ordinal among the attention layers):
+    `rows` slots of `row_len` positions, or a pool of `rows` pages of
+    `row_len` positions."""
+    shape = (cfg.layers_of("attention"), rows, row_len, cfg.n_kv_heads,
+             cfg.head_dim)
     if int8:
         scales = shape[:-1] + (1,)
         return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),

@@ -103,8 +103,8 @@ def _stage_fn(cfg: ModelConfig, chunk_layers: Any, x: jnp.ndarray,
     (never a scalar: rank-0 accumulators crossing a differentiated
     shard_map scan trip jax 0.4.37's residual naming, see pipelined();
     analysis/jaxpr_audit.py holds the convention)."""
-    x, moe_aux, _, _ = run_layers(
-        cfg, chunk_layers, (x, moe_stats_zero(cfg), None, None), ropes,
+    x, moe_aux, _, _, _ = run_layers(
+        cfg, chunk_layers, (x, moe_stats_zero(cfg), None, None, None), ropes,
         positions, first_layer=global_offset, dropout_key=dropout_key,
         recompute=recompute, **({"sharder": sharder} if sharder else {}))
     return x, aux_loss_of(moe_aux)

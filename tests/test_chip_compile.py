@@ -1119,3 +1119,105 @@ def test_serving_step_writes_the_cache_in_place(topo, serve_cfg, case):
     kernels = {"decode": ["paged_flash_decode"], "chunk": [],
                "slots": ["paged_flash_decode"]}[case.split("-")[0]]
     assert _kernels_named(text) == kernels
+
+
+# ---------------------------------------------------------------------------
+# The accepted cells' programs are what they were before layer TYPES (PR 47)
+# ---------------------------------------------------------------------------
+
+def _olmoe_1l():
+    return dataclasses.replace(
+        presets.olmoe(seq_length=SEQ), num_layers=1,
+        params_dtype="bfloat16", ce_chunk_size=512,
+        attention_impl="pallas").validate()
+
+
+def _mellum_cell_cfg():
+    from benchmark.harness import spec
+    from megatron_tpu.arguments import args_to_run_config, parse_args
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = spec.Cell(os.path.join(repo, "BENCHMARK.json"),
+                     "train_mellum2_share4_seq8k")
+    flags = spec.load_module(cell.reference_path()).program_flags(
+        cell.config, cell.traffic["seq_length"])
+    return args_to_run_config(parse_args(
+        flags + cell.config["program"]["flags"] + cell.traffic["flags"]
+        + ["--micro_batch_size", "2", "--global_batch_size", "2"])).model
+
+
+# (configuration, leaves by path, loops of the layer stack in the forward
+# pass: a stack of one trip is a call)
+_ACCEPTED = {
+    "mistral": (_mistral_2l, {
+        "embed/tokens": (32000, 4096), "final_ln/scale": (4096,),
+        "lm_head/w": (4096, 32000),
+        "layers/ln1/scale": (2, 4096), "layers/ln2/scale": (2, 4096),
+        "layers/attn/wq": (2, 4096, 4096), "layers/attn/wk": (2, 4096, 1024),
+        "layers/attn/wv": (2, 4096, 1024), "layers/attn/wo": (2, 4096, 4096),
+        "layers/mlp/w_in": (2, 4096, 28672),
+        "layers/mlp/w_out": (2, 14336, 4096)}, 1),
+    "olmoe": (_olmoe_1l, {
+        "embed/tokens": (50304, 2048), "final_ln/scale": (2048,),
+        "lm_head/w": (2048, 50304),
+        "layers/ln1/scale": (1, 2048), "layers/ln2/scale": (1, 2048),
+        "layers/attn/wq": (1, 2048, 2048), "layers/attn/wk": (1, 2048, 2048),
+        "layers/attn/wv": (1, 2048, 2048), "layers/attn/wo": (1, 2048, 2048),
+        "layers/attn/q_norm/scale": (1, 2048),
+        "layers/attn/k_norm/scale": (1, 2048),
+        "layers/moe/router": (1, 2048, 64),
+        "layers/moe/w_in": (1, 64, 2048, 2048),
+        "layers/moe/w_out": (1, 64, 1024, 2048)}, 0),
+    "mellum": (_mellum_cell_cfg, {
+        "embed/tokens": (24576, 2304), "final_ln/scale": (2304,),
+        "lm_head/w": (2304, 24576),
+        "layers/ln1/scale": (4, 2304), "layers/ln2/scale": (4, 2304),
+        "layers/attn/wq": (4, 2304, 4096), "layers/attn/wk": (4, 2304, 512),
+        "layers/attn/wv": (4, 2304, 512), "layers/attn/wo": (4, 4096, 2304),
+        "layers/moe/router": (4, 2304, 64),
+        "layers/moe/w_in": (4, 16, 2304, 1792),
+        "layers/moe/w_out": (4, 16, 896, 2304)}, 0),
+}
+
+
+@pytest.mark.parametrize("name", list(_ACCEPTED))
+def test_the_accepted_cells_trees_and_loops_are_what_they_were(name):
+    """Layer types (a stack with state-space layers stacks each type's
+    leaves over that type's layers and indexes them inside its loop)
+    moved nothing of a stack of one type: the same leaves under the same
+    names with the same shapes, every one stacked over all the layers,
+    and the layer stack one `scan` over them (none where the stack is one
+    trip: one layer, or one period of kinds)."""
+    from megatron_tpu.models.language_model import lm_forward
+    from megatron_tpu.models.params import param_shapes
+
+    make, leaves, loops = _ACCEPTED[name]
+    cfg = make()
+    assert cfg.layer_pattern is None and not cfg.has_ssm
+    shapes = param_shapes(cfg)
+    flat = {"/".join(k.key for k in path): leaf.shape for path, leaf
+            in jax.tree_util.tree_flatten_with_path(shapes)[0]}
+    assert flat == leaves
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, t: lm_forward(cfg, p, t, return_hidden=True))(shapes,
+                                                                tokens)
+
+    def scans(jaxpr):
+        found = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += scans(sub)
+        return found
+
+    stack = [e for e in scans(jaxpr.jaxpr)
+             if e.params["length"] == cfg.num_layers // len(
+                 cfg.attention_period)]
+    assert len(stack) == loops
+    for eqn in stack:
+        # the stacked leaves are the loop's scanned inputs, none closed over
+        n_xs = len(eqn.invars) - eqn.params["num_consts"] \
+            - eqn.params["num_carry"]
+        assert n_xs >= len([k for k in leaves if k.startswith("layers/")])

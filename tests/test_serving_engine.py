@@ -670,6 +670,23 @@ def test_sample_logits_batched_matches_scalar_semantics():
     np.testing.assert_array_equal(np.asarray(scalar), np.asarray(batched))
 
 
+def test_a_row_with_one_candidate_is_a_greedy_row():
+    """top_k 1 leaves the argmax, whatever the temperature: such a row is
+    greedy to the batched sampler (it keeps neither the draw nor the
+    filter's sort live), beside rows that do draw."""
+    logits = jnp.asarray([[0.0, 9.0, 1.0, 2.0], [4.0, 0.0, 1.0, 3.0],
+                          [0.0, 1.0, 2.0, 3.0]])
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(3, dtype=jnp.uint32))
+    drawn = set()
+    for seed in range(16):
+        out = np.asarray(sample_logits_batched(
+            logits, keys + seed, temperature=jnp.asarray([1.0, 50.0, 50.0]),
+            top_k=jnp.asarray([1, 1, 2], jnp.int32), top_p=jnp.zeros(3)))
+        assert out[:2].tolist() == [1, 0]
+        drawn.add(int(out[2]))
+    assert drawn == {2, 3}
+
+
 # ---------------------------------------------------------------------------
 # HTTP serving through the engine
 

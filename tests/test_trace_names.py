@@ -128,3 +128,75 @@ def test_scope_tokens_strip_the_wrappers(text, want):
     # the rule that finds a kernel: only a stack that closes in the call
     assert kernel_of(want) == ("flash_bwd" if want[-1] == "pallas_call"
                                else None)
+
+
+# --- a stack with state-space layers (ops/ssm.py) ---------------------------------
+
+SSM_SCOPES = ("ssm_in", "ssm_conv", "ssm_proj", "ssm_scan", "ssm_out")
+
+
+def _served_op_names(monkeypatch, positions):
+    """Every op_name of a toy typed stack's served forward pass (one
+    prefill chunk of `positions`, or one decode step over 2 slots where
+    it is 1), compiled for the CPU with the kernels dispatched
+    (interpreted)."""
+    import jax
+    import jax.numpy as jnp
+
+    from megatron_tpu.config import ModelConfig
+    from megatron_tpu.models.language_model import lm_forward
+    from megatron_tpu.models.params import param_shapes
+    from megatron_tpu.ops import kv_store, ssm
+
+    monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+    cfg = ModelConfig(
+        num_layers=4, hidden_size=32, num_attention_heads=4, num_kv_heads=1,
+        vocab_size=64, seq_length=32, ffn_hidden_size=48,
+        position_embedding_type="none", tie_embed_logits=True,
+        params_dtype="float32", attention_impl="pallas",
+        layer_pattern=("mamba", "attention"), ssm_d_state=4,
+        ssm_inner_norms=True).validate()
+    rows = 2 if positions == 1 else 1
+
+    def step(params, kv, state, table, tokens, lengths):
+        return lm_forward(
+            cfg, params, tokens, kv_caches=kv, page_table=table,
+            cache_index=lengths if positions == 1 else lengths[0],
+            ssm_state=state, state_row=None if positions == 1 else 0,
+            state_valid=jnp.ones((rows,), jnp.int32))
+
+    args = (param_shapes(cfg),
+            jax.eval_shape(lambda: kv_store.create(cfg, 9, 8)),
+            jax.eval_shape(lambda: ssm.create_state(cfg, 2)),
+            jax.ShapeDtypeStruct((rows, 4), jnp.int32),
+            jax.ShapeDtypeStruct((rows, positions), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32))
+    text = jax.jit(step).lower(*args).compile().as_text()
+    found = set(re.findall(r'op_name="([^"]+)"', text))
+    return [scope_tokens(n) for n in sorted(found) if n.startswith("jit(")]
+
+
+@pytest.mark.parametrize("positions", [16, 1], ids=["chunk", "decode"])
+def test_a_state_space_layer_runs_named_under_attention(monkeypatch,
+                                                        positions):
+    """The mixer is its layer's sequence mixer: every operation of it is
+    under the region `attention` and the scope `ssm_mixer`, in one of its
+    five parts; the prefill chunk's scan is the kernel `ssm_scan`, the
+    decode step's XLA's own fusion; the attention layers of the same
+    stack keep their `attn_*` parts; the loop is `layer_stack`."""
+    stacks = _served_op_names(monkeypatch, positions)
+    mixer = [t for t in stacks if "ssm_mixer" in t]
+    assert mixer
+    for toks in mixer:
+        at = toks.index("ssm_mixer")
+        assert "attention" in toks[:at] and "layer_stack" in toks[:at]
+        assert set(toks[at + 1:]) & set(SSM_SCOPES), toks
+    for scope in SSM_SCOPES:
+        assert any(scope in toks for toks in mixer), scope
+    # the kernel's own scope stands inside the part of that name
+    # (interpreted, its operations carry the scope and no `pallas_call`)
+    assert any(t.count("ssm_scan") > 1 for t in mixer) == (positions > 1)
+    for scope in ("attn_qkv", "attn_core", "attn_out"):
+        under = [t for t in stacks if scope in t]
+        assert under and not any("ssm_mixer" in t for t in under), scope
+    assert not any("attn_rope" in t for t in stacks)   # no positions
