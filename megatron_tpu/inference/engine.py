@@ -286,7 +286,8 @@ class InferenceEngine:
         self.stats = {"admitted": 0, "retired": 0, "ticks": 0,
                       "rejected": 0, "decode_recompiles": 0,
                       "timeouts": 0, "weight_reloads": 0,
-                      "kv_exports": 0, "kv_imports": 0}
+                      "kv_exports": 0, "kv_imports": 0,
+                      "decode_live_block_share": 0.0}
         if self.spec is not None:
             # spec_emitted counts every token the spec path emitted
             # (accepted drafts + the guaranteed token per row per tick);
@@ -334,6 +335,10 @@ class InferenceEngine:
             "per-request decode latency per generated token")
         self._m_prefill = m.histogram("engine_prefill_seconds",
                                       "admission prefill wall time")
+        self._m_live_blocks = m.gauge(
+            "engine_decode_live_block_share",
+            "KV blocks the decode kernel visits this tick over the blocks "
+            "its page table holds")
         self._m_tick = m.histogram("engine_decode_tick_seconds",
                                    "batched decode tick wall time")
         self._m_spec_proposed = m.counter(
@@ -1127,6 +1132,36 @@ class InferenceEngine:
             self.params, self.caches, *self._decode_extra_args(), *carry)
         return toks, lps, keys, lens
 
+    def _decode_table_geometry(self) -> Tuple[int, int]:
+        """(entries of a row's page table, positions an entry names) as
+        the decode kernel is handed the cache: a slot is one page of the
+        whole row (kv_store.read)."""
+        return 1, self.max_seq_len
+
+    def _note_live_blocks(self, active) -> None:
+        """Set `engine_decode_live_block_share` for the tick about to
+        run: the trips the decode kernel's loops take over the blocks
+        the table holds, from the host's lengths. A decoding row reaches
+        the kernel with its new token written (length + 1); every other
+        slot with whatever the device's carry holds for it, 0 and a tick's
+        drift, + 1: one block, not none. Near slots / blocks under light
+        load, 1 with every slot at its full length or window."""
+        from megatron_tpu.ops.pallas.flash_template import (
+            decode_blocks_visited)
+
+        tp = (dict(self.mesh.shape).get("tensor", 1)
+              if self.mesh is not None else 1)
+        kv_heads = self.cfg.n_kv_heads
+        lens = np.ones_like(self.lengths)
+        lens[active] = self.lengths[active] + 1
+        visited, held = decode_blocks_visited(
+            lens, *self._decode_table_geometry(),
+            kv_heads // tp if kv_heads % tp == 0 else kv_heads,
+            sq=self._decode_write_span(),
+            window=self.cfg.attention_kind.sliding_window_size)
+        self.stats["decode_live_block_share"] = visited / held
+        self._m_live_blocks.set(visited / held)
+
     def _decode_write_span(self) -> int:
         """Cache positions one decode tick writes per slot: 1 plain,
         k+1 speculative (the paged engine sizes page allocation off
@@ -1267,6 +1302,7 @@ class InferenceEngine:
         active = self._decode_rows()
         if not active:
             return 0
+        self._note_live_blocks(active)
         if self.spec is not None:
             return self._decode_tick_spec(active)
         last, lens, keys, temps, top_ks, top_ps = self._init_carry()

@@ -702,6 +702,9 @@ class PagedInferenceEngine(InferenceEngine):
         return [i for i, s in enumerate(self.slots)
                 if s is not None and i not in busy]
 
+    def _decode_table_geometry(self):
+        return self.max_pages, self.page_size
+
     def _decode_extra_args(self):
         if self._table_dirty or self._device_table is None:
             self._device_table = self._commit_small(jnp.asarray(self.tables))

@@ -175,8 +175,8 @@ def attention(
     logical context is page_table[b] physical pages (a slot cache comes
     as a pool whose pages are whole rows). With kv_lengths (decode) the
     TPU path is the paged flash-decode kernel
-    (flash_template.paged_flash_decode) which resolves pages inside the
-    grid; everywhere else the pages are gathered into a dense [B, S, ...]
+    (flash_template.paged_flash_decode) which resolves pages inside its
+    own loop over a row's live blocks; everywhere else the pages are gathered into a dense [B, S, ...]
     view and the existing masked paths compute identical values (the
     gather is exact — pages hold the same bits a dense cache would).
     """

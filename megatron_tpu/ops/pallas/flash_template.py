@@ -10,10 +10,14 @@ instantiation of the one template in this module, with four knobs:
                 |                         | q tile [BQ, D] (FA-2 partitioning:
                 |                         | parallel over Sq blocks and heads, kv
                 |                         | axis innermost+sequential); decode:
-                |                         | grid (B, Skv/BK), kv tile
+                |                         | grid (B,), the kv loop INSIDE the
+                |                         | kernel, from the row's first live
+                |                         | block to its last; kv block
                 |                         | [BK*Hkv, D] (BK cache positions with
                 |                         | every kv head, as the cache lies in
-                |                         | memory), q tile [Hkv*Sq*G, D] (the
+                |                         | memory: several pages, copied by
+                |                         | the kernel itself into two
+                |                         | buffers), q tile [Hkv*Sq*G, D] (the
                 |                         | Sq-small specialization — every kv
                 |                         | head's grouped queries ride in one
                 |                         | MXU tile, K/V never replicated)
@@ -22,10 +26,11 @@ instantiation of the one template in this module, with four knobs:
                 | kv_lengths (decode)     | the block-skip predicate for every
                 |                         | instantiation
   paging        | dense / page table      | the page table rides in as a
-                |                         | scalar-prefetch operand; BlockSpec
-                |                         | index maps dereference it at
-                |                         | DMA-issue time (no dense gather); a
-                |                         | dense cache is a pool whose pages
+                |                         | scalar-prefetch operand; the pools
+                |                         | stay in HBM and the kernel's loop
+                |                         | dereferences the table as it starts
+                |                         | each page's copy (no dense gather);
+                |                         | a dense cache is a pool whose pages
                 |                         | are whole rows
   gradient      | fwd-only / custom_vjp   | the FA-2 recompute backward: fwd
                 |                         | saves o and lse (compact, one float
@@ -50,15 +55,21 @@ instantiation of the one template in this module, with four knobs:
 
 Online softmax (running max m, running sum l, unnormalized acc in VMEM
 scratch persisting across the sequential kv steps) is shared by every
-instantiation, as is the block-skip: a grid step whose kv tile lies
-outside the visible band of the tile's queries (masks.block_live) skips
-its compute, so causal prefill computes ~half the tiles and a young decode
-slot in a long cache computes only the context it has. The step itself is
-still taken, and the BlockSpec pipeline still issues its DMA unless the
-index map names the block the step before it held: the training kernels'
-maps clamp a skipped step to the nearest live tile of its row
-(`_inner_tile_map`), so their dead steps move nothing; the
-decode kernels' maps do not, and load the tiles they skip.
+instantiation, as is the block-skip, in two forms. The training kernels
+walk a grid: a step whose kv tile lies outside the visible band of the
+tile's queries (masks.block_live) skips its compute, so causal prefill
+computes ~half the tiles. The step itself is still taken, and the
+BlockSpec pipeline would still issue its DMA, so their index maps clamp
+a skipped step to the nearest live tile of its row (`_inner_tile_map`):
+the pipeline sees the block it holds and a dead step moves nothing. It
+still costs its ~0.1 us, which is nothing beside a 1024 x 1024 tile and
+was everything in decode: a grid step for every entry of every row's
+page table (64 rows x 528 entries) took 3.4 ms a call where 2 % of the
+entries were live, at the same time a step whether it held 32 KB or
+4 KB (ledger, PR 47). So the decode kernel takes no dead step at all:
+its grid is the rows, and each row's loop runs over the interval form
+of the same predicate (masks.decode_live_blocks), a young slot in a
+long cache over the context it has and an idle slot over nothing.
 
 Precision: the training kernels (fwd; the fused bwd and the split dq,
 dk/dv, which share one tile function) hand the MXU their
@@ -85,6 +96,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -855,47 +867,80 @@ def flash_mha(
 # ---------------------------------------------------------------------------
 
 
-def _decode_kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr,
-                   *, scale: float, window: Optional[int], block_k: int,
-                   kv_heads: int, groups: int, sq: int):
+def _decode_kernel(lens_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
+                   *, scale: float, window: Optional[int], unit: int,
+                   units: int, parts: int, n_blocks: int, kv_heads: int,
+                   groups: int, sq: int):
     """ONE body for all four decode instantiations (single/multi-query x
-    dense/paged). The kv tile is block_k cache positions with EVERY kv
-    head, [block_k * Hkv, D], exactly as a cache row lies in memory
-    (ops/kv_store.py), so no caller transposes a cache for this kernel.
+    dense/paged), one grid step a row. The pools stay in HBM; the row's
+    kv loop runs here, from its first live block to its last
+    (`decode_trips`), so a row costs what its live context costs and an
+    idle one (kv_len 0) nothing. A block is `units` copies of `unit`
+    cache positions with EVERY kv head, [unit * Hkv, D] each, exactly as
+    a page lies in memory (ops/kv_store.py: no caller transposes a cache
+    for this kernel): several whole pages, or, of a page longer than a
+    block (a slot cache's row), one of its `parts` parts. The loop copies
+    them itself through the page table, into two buffers: block j + 1 is
+    on its way while block j computes.
+
+    Only the units that hold a position some query sees are copied, so
+    what a block's other units hold in VMEM is arbitrary. The scores
+    there are selected away; the values are zeroed before p.v, because a
+    probability of 0 times a NaN is a NaN. So no byte outside the row's
+    live pages reaches the result, the scratch page's included.
+
     The q tile stacks, per kv head, the Sq speculative query rows x G
     grouped heads: [Hkv * Sq * G, D] (sq == 1 is plain decode). One
     matmul forms the scores of every query against every key of the
-    tile; masks.py says which pairs share a kv head and a visible
-    position, and the block-skip predicate. The page table rides as the
-    second scalar-prefetch operand for the BlockSpec index maps: page
-    resolution happens there, the body never reads it."""
+    block; masks.py says which pairs share a kv head and a visible
+    position."""
     b = pl.program_id(0)
-    ki = pl.program_id(1)
-    nk = pl.num_programs(1)
     kv_len = lens_ref[b]
     rows = sq * groups
+    blk = unit * units
+    first, end = decode_trips(kv_len, sq, window, blk, n_blocks)
+    # the same interval a unit at a time: the copies a block makes
+    first_unit, end_unit = decode_trips(kv_len, sq, window, unit,
+                                        n_blocks * units)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def copies(j, slot, act):
+        """"start" or "wait" (act) the copies of block j's live units
+        into buffer `slot`; a wait names what its start named."""
+        def one(e, _):
+            page = table_ref[b, e // parts] * parts + e % parts
+            at = e - j * units
+            for c, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                getattr(pltpu.make_async_copy(
+                    hbm.at[page], buf.at[slot, at], sems.at[c, slot]), act)()
+            return _
+        jax.lax.fori_loop(jnp.maximum(j * units, first_unit),
+                          jnp.minimum((j + 1) * units, end_unit), one, None)
 
-    # blocks past the deepest query's frontier (kv_len + sq - 2) — or,
-    # windowed, entirely before the shallowest query's window — never
-    # compute: a young slot in a long cache is cheap, and scratch-mapped
-    # unallocated page-table entries are skipped the same way
-    @pl.when(masks.decode_block_live(ki, block_k, kv_len, sq,
-                                     window=window))
-    def _compute():
+    m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(first < end)
+    def _first():
+        copies(first, 0, "start")
+
+    def block(j, _):
+        slot = (j - first) % 2
+
+        @pl.when(j + 1 < end)
+        def _next():
+            copies(j + 1, 1 - slot, "start")
+
+        copies(j, slot, "wait")
+
         q = q_ref[0].astype(jnp.float32) * scale         # [Hkv*rows, D]
-        k = k_ref[0].astype(jnp.float32)                 # [BK*Hkv, D]
+        k = k_buf[slot].reshape(blk * kv_heads, -1).astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
 
-        q_pos, k_pos = masks.decode_positions(ki, block_k, kv_len,
-                                              groups, rows, kv_heads)
-        allowed = (masks.decode_same_head(block_k, rows, kv_heads)
+        q_pos, k_pos = masks.decode_positions(j, blk, kv_len, groups, rows,
+                                              kv_heads)
+        allowed = (masks.decode_same_head(blk, rows, kv_heads)
                    & masks.visible(q_pos, k_pos, causal=True,
                                    window=window))
         s = jnp.where(allowed, s, _NEG_INF)
@@ -904,90 +949,148 @@ def _decode_kernel(lens_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.where(allowed, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        v = v_ref[0].astype(jnp.float32)                 # [BK*Hkv, D]
+        v = v_buf[slot].reshape(blk * kv_heads, -1).astype(jnp.float32)
+        v_pos = j * blk + jax.lax.broadcasted_iota(
+            jnp.int32, v.shape, 0) // kv_heads
+        v = jnp.where(masks.decode_position_live(v_pos, kv_len, sq,
+                                                 window=window), v, 0.0)
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
         acc_scr[:] = acc_scr[:] * alpha + pv
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_scr[:] = m_new
+        return _
 
-    @pl.when(ki == nk - 1)
-    def _emit():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+    jax.lax.fori_loop(first, end, block, None)
+    l = jnp.maximum(l_scr[:], 1e-30)
+    o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
-# the most rows of a kv tile ([block_k * Hkv, D]): 2048 rows of 128 bf16
-# are 0.5 MB, so k and v double-buffered, their float32 copies and the
-# [q rows, 2048] scores stay well inside the default scoped VMEM
+# the most rows of a kv block ([positions * Hkv, D]): 2048 rows of 128
+# bf16 are 0.5 MB, so the two buffers each of k and v, their float32
+# copies and the [q rows, 2048] scores stay inside the default scoped
+# VMEM (`_decode_vmem_bytes`)
 _DECODE_TILE_ROWS = 2048
 
 
-def _decode_block(page_size: int, kv_heads: int, cap: int) -> int:
-    """Cache positions a grid step takes: the whole page, or for a page
-    longer than `cap` positions or _DECODE_TILE_ROWS tile rows (a slot
-    cache's row is one page of the whole sequence) the largest power of
-    two under both that divides it."""
-    cap = min(cap, max(8, _DECODE_TILE_ROWS // kv_heads))
-    if page_size <= cap:
-        return page_size
-    for blk in (256, 128, 64, 32, 16, 8):
-        if blk <= cap and page_size % blk == 0:
-            return blk
-    return page_size
+def _decode_block(page_size: int, kv_heads: int,
+                  cap: Optional[int] = None) -> Tuple[int, int]:
+    """(unit, units): a block of the decode kernel is `units` contiguous
+    copies of `unit` cache positions each. As many whole pages as reach
+    _DECODE_TILE_ROWS rows (and `cap` positions, where a caller gives
+    one); of a page longer than that (a slot cache's row is one page of
+    the whole sequence), the largest power of two under both that
+    divides it."""
+    most = max(8, _DECODE_TILE_ROWS // kv_heads)
+    if cap is not None:
+        most = min(most, cap)
+    if page_size <= most:
+        return page_size, most // page_size
+    unit = 1 << (most.bit_length() - 1)
+    while unit > 8 and page_size % unit:
+        unit //= 2
+    return (unit, 1) if page_size % unit == 0 else (page_size, 1)
+
+
+def _decode_geometry(table_width: int, page_size: int, kv_heads: int,
+                     cap: Optional[int] = None) -> Tuple[int, int, int, int]:
+    """(unit, units, parts, n_blocks) of a decode call over tables of
+    `table_width` entries: `_decode_block`'s block, the parts a page is
+    addressed in (1 unless it is longer than a block), and the blocks a
+    row's table holds, the last one cut short where they do not divide
+    it."""
+    unit, units = _decode_block(page_size, kv_heads, cap)
+    parts = page_size // unit
+    return unit, units, parts, -(-table_width * parts // units)
+
+
+def decode_trips(kv_len, sq: int, window: Optional[int], block: int,
+                 n_blocks: int, xp=jnp):
+    """(first, end) of a row's kv loop over blocks of `block` positions
+    in a table of n_blocks: `masks.decode_live_blocks` clipped into the
+    table, `end` one past the last. kv_len is the kernel's SMEM scalar,
+    or an array of rows on the host (xp=numpy)."""
+    first, last = masks.decode_live_blocks(block, kv_len, sq, window=window)
+    return xp.maximum(first, 0), xp.minimum(last + 1, n_blocks)
+
+
+def decode_blocks_visited(kv_lengths, table_width: int, page_size: int,
+                          kv_heads: int, sq: int = 1,
+                          window: Optional[int] = None) -> Tuple[int, int]:
+    """(blocks a decode call visits, blocks its table holds) for rows of
+    these lengths, on the host: the sum of the kernel's own loop bounds.
+    The engines' `engine_decode_live_block_share` is the first over the
+    second."""
+    unit, units, _, n_blocks = _decode_geometry(table_width, page_size,
+                                                kv_heads)
+    lens = np.asarray(kv_lengths, np.int64)
+    first, end = decode_trips(lens, sq, window, unit * units, n_blocks,
+                              xp=np)
+    return int(np.maximum(end - first, 0).sum()), n_blocks * lens.size
+
+
+def _decode_vmem_bytes(blk_rows: int, q_rows: int, D: int, item: int) -> int:
+    """The two buffers each of k and v, the block's float32 copies of
+    both, q and o double-buffered, and ~5 live [q rows, block rows]
+    float32 temporaries (scores, the two masks, p, the positions)."""
+    return (4 * blk_rows * D * item + 2 * blk_rows * D * 4
+            + 4 * q_rows * D * item + 5 * q_rows * blk_rows * 4)
 
 
 def _decode_call(q, k_pages, v_pages, page_table, kv_lengths, *,
-                 window: Optional[int], name: str, block_k: int = 256):
+                 window: Optional[int], name: str,
+                 block_k: Optional[int] = None):
     """Shared launch for the decode specialization: k/v are page pools
-    [P, ps, Hkv, D] in the cache's own layout and a grid step takes one
-    page of one row through its table [B, n] (a page longer than the
-    block is addressed as several). Nothing here copies a pool: the
-    tile views are reshapes of contiguous memory."""
+    [P, ps, Hkv, D] in the cache's own layout, left in HBM, and the grid
+    is the rows; each row's loop over its table [B, n] is the kernel's.
+    Nothing here copies a pool: the views are reshapes of contiguous
+    memory."""
     b, sq, hq, d = q.shape
     _, ps, hkv, _ = kv_store.pool_dims(k_pages)
     groups = hq // hkv
     rows = sq * groups
-    blk = _decode_block(ps, hkv, block_k)
+    unit, units, parts, n_blocks = _decode_geometry(page_table.shape[1],
+                                                    ps, hkv, block_k)
 
     # [B, Sq, Hkv, G, D] -> [B, Hkv*Sq*G, D]: per kv head, all Sq
     # queries' grouped heads
     qt = q.reshape(b, sq, hkv, groups, d).transpose(0, 2, 1, 3, 4)
     qt = qt.reshape(b, hkv * rows, d)
-    kt = k_pages.reshape(-1, blk * hkv, d)
-    vt = v_pages.reshape(-1, blk * hkv, d)
+    kt = k_pages.reshape(-1, unit * hkv, d)
+    vt = v_pages.reshape(-1, unit * hkv, d)
     lens = jnp.asarray(kv_lengths, jnp.int32)
     table = jnp.asarray(page_table, jnp.int32)
-    if blk != ps:
-        table = (table[:, :, None] * (ps // blk)
-                 + jnp.arange(ps // blk, dtype=jnp.int32)).reshape(b, -1)
 
     kernel = functools.partial(
         _decode_kernel, scale=float(1.0 / (d ** 0.5)), window=window,
-        block_k=blk, kv_heads=hkv, groups=groups, sq=sq)
-    # scalar-prefetch index maps: (grid indices..., lens_ref, table_ref)
-    # -> block indices; the kv maps dereference the page table so the
-    # DMA fetches the row's physical page for this logical block
-    q_map = lambda bi, ki, lens, pt: (bi, 0, 0)  # noqa: E731
-    kv_map = lambda bi, ki, lens, pt: (pt[bi, ki], 0, 0)  # noqa: E731
+        unit=unit, units=units, parts=parts, n_blocks=n_blocks,
+        kv_heads=hkv, groups=groups, sq=sq)
+    vmem = _decode_vmem_bytes(unit * units * hkv, hkv * rows, d,
+                              k_pages.dtype.itemsize)
+    q_map = lambda bi, lens, pt: (bi, 0, 0)  # noqa: E731
     o = _named_pallas_call(
         name, kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, table.shape[1]),
+            grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, hkv * rows, d), q_map),
-                pl.BlockSpec((1, blk * hkv, d), kv_map),
-                pl.BlockSpec((1, blk * hkv, d), kv_map),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, hkv * rows, d), q_map),
             scratch_shapes=[
+                pltpu.VMEM((2, units, unit * hkv, d), k_pages.dtype),
+                pltpu.VMEM((2, units, unit * hkv, d), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((hkv * rows, 1), jnp.float32),
                 pltpu.VMEM((hkv * rows, 1), jnp.float32),
                 pltpu.VMEM((hkv * rows, d), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct((b, hkv * rows, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=(min(vmem, _MAX_SCOPED_VMEM)
+                              if vmem > _DEFAULT_SCOPED_VMEM else None)),
         interpret=_interpret(),
     )(lens, table, qt, kt, vt)
     return o.reshape(b, hkv, sq, groups, d).transpose(0, 2, 1, 3, 4

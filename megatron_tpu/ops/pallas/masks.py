@@ -148,6 +148,31 @@ def decode_block_live(ki, block_k: int, kv_len, sq: int, *,
                       causal=True, window=window)
 
 
+def decode_position_live(k_pos, kv_len, sq: int, *,
+                         window: Optional[int] = None):
+    """True where SOME query of a decode row sees cache position k_pos:
+    the union (kv_len - 1 - W, kv_len + sq - 2] of the queries' visible
+    bands, element by element. `decode_block_live` is its interval form;
+    the decode kernel zeroes the value rows outside it, which its copies
+    may not have filled."""
+    live = k_pos <= kv_len + sq - 2
+    if window is not None:
+        live = live & (k_pos > kv_len - 1 - window)
+    return live
+
+
+def decode_live_blocks(block_k: int, kv_len, sq: int, *,
+                       window: Optional[int] = None):
+    """(first, last) kv block that `decode_block_live` admits for a row:
+    the interval form solved for ki, the bounds of the decode kernel's
+    loop. Unclipped, as `prefill_live_kv_tiles`: `first` may be negative
+    and `last` beyond the table, or below `first` (kv_len 0 at sq 1: no
+    live block), so callers clip into their table."""
+    first = (kv_len - window) // block_k if window is not None else 0
+    last = (kv_len + sq - 2) // block_k
+    return first, last
+
+
 def prefill_block_live(qi, ki, block_q: int, block_k: int, *,
                        causal: bool = True, window: Optional[int] = None,
                        delta=0):

@@ -18,6 +18,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
 import collections
 import dataclasses
+import functools
 import importlib
 import math
 import re
@@ -151,9 +152,32 @@ def _kernel_cases():
                       [((SLOTS, 1, HQ, D), bf16), pool, pool,
                        ((SLOTS, per_seq), i32), lens],
                       ["paged_flash_decode"]))
+    # the paged decode at the two served cells' shapes (benchmark/configs/
+    # mistral-7b-d8-serve.json, jamba2-3b-serve.json: slots x table
+    # entries, query heads over kv heads, the window, the layers' pools
+    # seen flat as kv_store.read hands them over), single-token and as
+    # the speculative verify's five query rows
+    for cell, (entries, hq, hkv, window, pages) in _SERVED_DECODE.items():
+        pool = ((pages, 16, hkv, D), bf16)
+        for sq, entry in ((1, ft.paged_flash_decode),
+                          (5, ft.paged_flash_decode_mq)):
+            cases.append((
+                f"paged_decode_{cell}" + ("" if sq == 1 else f"_mq{sq}"),
+                functools.partial(_paged_cell, entry, window),
+                [((_DECODE_SLOTS, sq, hq, D), bf16), pool, pool,
+                 ((_DECODE_SLOTS, entries), i32), ((_DECODE_SLOTS,), i32)],
+                ["paged_flash_decode"]))
     return cases
 
 
+def _paged_cell(entry, window, q, kp, vp, table, n):
+    return entry(q, kp, vp, table, n, sliding_window=window)
+
+
+# cell: (table entries, query heads, kv heads, window, pages of all layers)
+_SERVED_DECODE = {"instruct": (528, HQ, HKV, WINDOW, 8 * 17000),
+                  "reasoning": (256, 20, 1, None, 2 * 16640)}
+_DECODE_SLOTS = 64
 _CASES = _kernel_cases()
 _KERNEL_TEXTS = {}
 
@@ -214,6 +238,67 @@ def test_kernel_carries_its_name_for_v5e(topo, name):
         b, s, h, d = args[0][0]
         first = re.search(r"%flash_bwd(?:\.\d+)? = \((\w+\[[\d,]+\])", text)
         assert first.group(1) == f"bf16[{b},{h},{s},{d}]"
+
+
+@pytest.mark.parametrize("name", [c[0] for c in _CASES if "decode" in c[0]])
+def test_decode_block_fits_the_default_scoped_vmem(name):
+    """What a decode call keeps in VMEM (two buffers each of K and V, the
+    block's float32 copies, the scores: `_decode_vmem_bytes`) stays
+    inside Mosaic's default scoped limit at every compiled shape, so the
+    launch asks for none of its own; the compile above is the compiler's
+    own word on it. A block is as many pages as reach _DECODE_TILE_ROWS
+    rows: 16 of Mistral's (8 kv heads), 128 of Jamba's (one)."""
+    _, _, args, _ = next(c for c in _CASES if c[0] == name)
+    (_, sq, hq, d), (_, ps, hkv, _) = args[0][0], args[1][0]
+    # the dense entries (a cache row is one page; an int8 cache reaches
+    # them dequantized to bf16) take flash_decode's block_k of 256
+    dense = name.startswith("decode")
+    unit, units = ft._decode_block(ps, hkv, 256 if dense else None)
+    rows = unit * units * hkv
+    assert rows <= ft._DECODE_TILE_ROWS
+    if not dense:
+        assert units == ft._DECODE_TILE_ROWS // (ps * hkv)
+    assert (ft._decode_vmem_bytes(rows, sq * hq, d, 2)
+            <= ft._DEFAULT_SCOPED_VMEM)
+
+
+def test_paged_decode_partitions_over_tp2_dp2(topo):
+    """The paged decode under a mesh, as the TP-sharded engines run it:
+    `ops/attention.py` wraps the kernel in one `shard_map` over every
+    axis (slots over `data`, kv heads over `tensor`, the pools' pages
+    whole on every chip), so each chip's kernel loops over its own
+    slots' tables with 4 of the 8 kv heads, 32 pages a block; the pools
+    stay where they lie (no all-gather, no copy of a pool's shape)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from megatron_tpu.parallel.mesh import build_mesh
+
+    rt = build_mesh(ParallelConfig(tensor_parallel=2), devices=topo.devices)
+    entries, hq, hkv, window, _ = _SERVED_DECODE["instruct"]
+    pages = 17000
+    rows, heads = P(("data", "expert")), P(None, None, "tensor", None)
+    shapes = [((_DECODE_SLOTS, 1, hq, D), jnp.bfloat16,
+               P(("data", "expert"), None, "tensor", None)),
+              ((pages, 16, hkv, D), jnp.bfloat16, heads),
+              ((pages, 16, hkv, D), jnp.bfloat16, heads),
+              ((_DECODE_SLOTS, entries), jnp.int32, rows),
+              ((_DECODE_SLOTS,), jnp.int32, rows)]
+
+    def decode(q, kp, vp, table, n):
+        return attention_mod.attention(q, kp, vp, sliding_window=window,
+                                       impl="pallas", kv_lengths=n,
+                                       page_table=table)
+
+    with jax.sharding.set_mesh(rt.mesh):
+        text = jax.jit(decode).lower(*[
+            jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(rt.mesh, spec))
+            for shape, dtype, spec in shapes]).compile().as_text()
+    assert _kernels_named(text) == ["paged_flash_decode"]
+    assert not re.search(r"\ball-gather(-start)?\(", text)
+    assert f"bf16[{pages},16,{hkv // 2},{D}]" in text
+    assert not re.search(rf"= bf16\[{pages},16,\d+,{D}\]\S* (copy|fusion)\(",
+                         text)
 
 
 @pytest.mark.parametrize("s", [128, 384, 640, 1024, 1536, 2048, 4096,

@@ -214,6 +214,127 @@ def test_paged_flash_decode_matches_gather_reference():
     np.testing.assert_allclose(np.asarray(out_w), ref(window=8), atol=2e-6)
 
 
+# the two served cells' decode shapes (benchmark/configs/*-serve.json and
+# their mixes' lengths: prompt + answer so far), and the queued long-prompt
+# mix, whose rows cross the window's lower edge:
+# (slots, table entries, page, kv heads, window, shortest, longest row)
+_SERVED_SHAPES = {
+    "instruct": (64, 528, 16, 8, 4096, 17, 1280),
+    "reasoning": (64, 256, 16, 1, None, 17, 3584),
+    "longprompt": (64, 528, 16, 8, 4096, 2048, 8447),
+}
+
+
+@pytest.mark.parametrize("sq", [1, 5])
+@pytest.mark.parametrize("in_use", [0, 8, 38, 64])
+@pytest.mark.parametrize("shape", sorted(_SERVED_SHAPES))
+def test_decode_work_is_the_live_context(shape, in_use, sq):
+    """The blocks a decode call visits (the sum of the kernel's own loop
+    bounds, `decode_trips`) are the blocks `decode_block_live` admits,
+    row by row, for lengths drawn as the mixes draw them: nothing for an
+    idle slot, so nothing at all for a table of idle slots, and never a
+    block outside the table."""
+    from megatron_tpu.ops.pallas import flash_template as ft
+    from megatron_tpu.ops.pallas import masks
+
+    slots, entries, ps, hkv, window, lo, hi = _SERVED_SHAPES[shape]
+    rng = np.random.default_rng(in_use + sq)
+    lens = np.zeros(slots, np.int64)
+    rows = rng.permutation(slots)[:in_use]
+    lens[rows] = rng.integers(lo, hi - sq + 2, in_use)
+    unit, units, _, n_blocks = ft._decode_geometry(entries, ps, hkv)
+    blk = unit * units
+    assert blk * hkv <= ft._DECODE_TILE_ROWS and blk % ps == 0
+    assert (n_blocks - 1) * blk < entries * ps <= n_blocks * blk
+    want = sum(bool(masks.decode_block_live(ki, blk, int(n), sq,
+                                            window=window))
+               for n in lens[rows] for ki in range(n_blocks))
+    visited, held = ft.decode_blocks_visited(lens, entries, ps, hkv, sq,
+                                             window)
+    assert held == slots * n_blocks
+    if sq == 1:
+        assert visited == want
+        live = -(-lens[rows] // blk) - (
+            0 if window is None
+            else np.maximum(lens[rows] - window, 0) // blk)
+        assert visited == live.sum()
+    else:
+        # an idle slot's later queries see the drafts before them: one
+        # block (masks.decode_live_blocks)
+        assert visited == want + (slots - in_use)
+    if in_use == 0 and sq == 1:
+        assert visited == 0
+
+
+@pytest.mark.parametrize("engine", ["paged", "slot", "paged-window",
+                                    "paged-spec"])
+def test_engine_reports_the_live_block_share(engine):
+    """`engine_decode_live_block_share`, set before every decode tick
+    from the host's lengths, is what the kernel's loop bounds give over
+    the blocks the table holds: checked tick by tick against the block
+    predicate, on both engines. A decoding row reaches the kernel with
+    its new token written (length + 1), an idle one with length 1 (the
+    layer hands the kernel `cache_index + 1`): one block."""
+    import jax
+
+    from megatron_tpu.inference.engine import InferenceEngine
+    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.ops.pallas import flash_template as ft
+    from megatron_tpu.ops.pallas import masks
+    from megatron_tpu.models import presets
+    from megatron_tpu.models.params import init_params
+    from megatron_tpu.telemetry.metrics import MetricsRegistry
+
+    window = 16 if engine == "paged-window" else None
+    cfg = presets.tiny(vocab_size=64, seq_length=128, num_layers=2,
+                       sliding_window_size=window)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    reg = MetricsRegistry()
+    spec = None
+    if engine == "paged-spec":
+        from megatron_tpu.inference.speculative import SpecConfig
+
+        spec = SpecConfig(drafter="ngram", k=2)
+    if engine == "slot":
+        eng = InferenceEngine(cfg, params, num_slots=3, max_seq_len=128,
+                              metrics=reg)
+        entries, ps = 1, 128
+    else:
+        eng = PagedInferenceEngine(cfg, params, num_slots=3,
+                                   max_seq_len=128, page_size=8,
+                                   prefill_chunk=16, metrics=reg,
+                                   speculative=spec)
+        entries, ps = 16, 8
+    sq = 1 if spec is None else spec.k + 1
+    unit, units, _, n_blocks = ft._decode_geometry(entries, ps,
+                                                   cfg.n_kv_heads)
+    blk = unit * units
+    seen = []
+    note = eng._note_live_blocks
+
+    def spy(active):
+        note(active)
+        want = sum(bool(masks.decode_block_live(ki, blk,
+                                                int(eng.lengths[i]) + 1,
+                                                sq, window=window))
+                   for i in active for ki in range(n_blocks))
+        want += 3 - len(active)
+        seen.append((want / (3 * n_blocks),
+                     eng.stats["decode_live_block_share"],
+                     reg.get("engine_decode_live_block_share").value()))
+
+    eng._note_live_blocks = spy
+    assert eng.stats["decode_live_block_share"] == 0.0
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(1, 64, (2, 12)).astype(np.int32)
+    eng.generate(prompts, np.asarray([12, 5], np.int32), max_new_tokens=40)
+    assert len(seen) >= 10
+    for want, stat, gauge in seen:
+        assert want == stat == gauge
+    assert 0 < min(s for _, s, _ in seen) <= max(s for _, s, _ in seen) <= 1
+    assert "engine_decode_live_block_share " in reg.render()
+
+
 def test_paged_flash_decode_rejects_bad_shapes():
     import jax.numpy as jnp
 

@@ -112,6 +112,39 @@ def test_decode_block_live_matches_dense(sq, window):
             assert bool(got) == want, (kv_len, ki)
 
 
+@pytest.mark.parametrize("window", [None, 1, 4, 8, 16, 17])
+@pytest.mark.parametrize("sq", [1, 4, 5])
+def test_decode_live_blocks_are_the_block_predicate(sq, window):
+    """The decode kernel's loop bounds: `decode_live_blocks` names
+    exactly the blocks `decode_block_live` admits, for every kv_len
+    around every block edge (0 too: an idle slot), and
+    `decode_position_live` exactly the positions some query sees. The
+    host's form (arrays of rows, `flash_template.decode_trips`) is the
+    same interval clipped into the table."""
+    from megatron_tpu.ops.pallas.flash_template import decode_trips
+
+    blk, nk = 8, 6
+    lens = np.arange(0, blk * nk + 2)
+    firsts, ends = np.broadcast_arrays(
+        *decode_trips(lens, sq, window, blk, nk, xp=np))
+    for kv_len in lens:
+        first, last = masks.decode_live_blocks(blk, int(kv_len), sq,
+                                               window=window)
+        live = [bool(masks.decode_block_live(ki, blk, int(kv_len), sq,
+                                             window=window))
+                for ki in range(nk)]
+        assert live == [first <= ki <= last for ki in range(nk)], kv_len
+        assert live == [firsts[kv_len] <= ki < ends[kv_len]
+                        for ki in range(nk)], kv_len
+        q_pos = kv_len - 1 + np.arange(sq)
+        k_pos = np.arange(blk * nk)
+        seen = masks.visible(q_pos[:, None], k_pos[None, :], causal=True,
+                             window=window).any(axis=0)
+        np.testing.assert_array_equal(
+            masks.decode_position_live(k_pos, int(kv_len), sq,
+                                       window=window), seen)
+
+
 def test_window_lower_edge_is_tight():
     """The windowed skip keeps exactly the tiles intersecting
     (q_lo - W, q_hi]: the tile just below the window's lower edge is
@@ -247,6 +280,105 @@ def test_paged_decode_window_parity(sq, window):
                      impl="xla")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
+
+
+# (layout: a page size, or "row" for a dense cache; kv heads; groups; sq)
+_POISON_CASES = [(8, 8, 4, 1), (16, 8, 4, 5), (64, 8, 4, 1),
+                 ("row", 8, 4, 5), (8, 1, 20, 5), (16, 1, 20, 1),
+                 (64, 1, 20, 5), ("row", 1, 20, 1), (16, 8, 20, 1),
+                 (16, 1, 4, 5)]
+_POISON_FNS = {}
+
+
+def _poison_case(layout, hkv, groups, sq, edge):
+    """One decode call over rows at every edge, and what it must give.
+
+    The rows' lengths: 0, 1, a block's edge - 1 / edge / edge + 1 for the
+    deepest query (kv_len + sq - 1) and for the shallowest, and the
+    table's full width. `edge` places the window's lower edge for the
+    full row: None (no window), "inside" a block, "at" a block's first
+    position, "tight" (one position before it) or "before" the cache's
+    start. Returns (fn, clean k, v, q, lens, window, block)."""
+    from megatron_tpu.ops.pallas import flash_template as ft
+
+    d, dense = 16, layout == "row"
+    if dense:
+        blk = ft._decode_block(3 * 256, hkv, 256)[0]
+        seq = 3 * 256
+    else:
+        unit, units = ft._decode_block(layout, hkv)
+        blk = unit * units
+        seq = 2 * blk + blk // 2       # a last block the table cuts short
+    full = seq - sq + 1
+    lens = sorted({0, 1, blk - 1, blk, blk + 1, blk - sq, blk - sq + 1,
+                   blk - sq + 2, 2 * blk + 1, full})
+    window = {None: None, "inside": full - 3 * blk // 2, "at": full - blk,
+              "tight": full - blk + 1, "before": seq + 7}[edge]
+    b = len(lens)
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((b, sq, hkv * groups, d)),
+                    jnp.float32)
+    k = rng.standard_normal((b, seq, hkv, d)).astype(np.float32)
+    v = rng.standard_normal((b, seq, hkv, d)).astype(np.float32)
+    key = (layout, hkv, groups, sq, window)
+    if key not in _POISON_FNS:
+        if dense:
+            fn = ft.flash_decode if sq == 1 else ft.flash_decode_mq
+            _POISON_FNS[key] = jax.jit(
+                lambda q, k, v, t, n: fn(q, k, v, n, sliding_window=window))
+        else:
+            fn = (ft.paged_flash_decode if sq == 1
+                  else ft.paged_flash_decode_mq)
+            _POISON_FNS[key] = jax.jit(
+                lambda q, k, v, t, n: fn(q, k, v, t, n,
+                                         sliding_window=window))
+    return _POISON_FNS[key], k, v, q, np.asarray(lens, np.int32), window, blk
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf], ids=["nan", "inf"])
+@pytest.mark.parametrize("edge", [None, "inside", "at", "tight", "before"])
+@pytest.mark.parametrize("layout,hkv,groups,sq", _POISON_CASES)
+def test_decode_reads_nothing_but_the_rows_live_positions(
+        layout, hkv, groups, sq, edge, poison):
+    """The four decode entries against the masked einsum over a CLEAN
+    cache, with every position no query of the row sees poisoned: the
+    tail of the row's own last page, the pages behind its window (whose
+    table entries park on scratch, as the engine's do), every page it
+    does not own and the scratch page. The kernel copies only the units
+    that hold a visible position and zeroes the value rows outside the
+    band, so no such byte may reach the result. The table is scrambled."""
+    fn, k, v, q, lens, window, blk = _poison_case(layout, hkv, groups, sq,
+                                                  edge)
+    b, seq = k.shape[:2]
+    pos = np.arange(seq)[None, :]
+    seen = np.asarray(masks.decode_position_live(
+        pos, lens[:, None], sq, window=window))
+    kp = np.where(seen[:, :, None, None], k, poison).astype(np.float32)
+    vp = np.where(seen[:, :, None, None], v, poison).astype(np.float32)
+    if layout == "row":
+        pool_k, pool_v, table = kp, vp, np.zeros((b, 1), np.int32)
+    else:
+        n = seq // layout
+        rng = np.random.default_rng(6)
+        ids = rng.permutation(np.arange(1, 2 * b * n))[:b * n].reshape(b, n)
+        owned = seen.reshape(b, n, layout).any(axis=2)
+        table = np.where(owned, ids, 0).astype(np.int32)
+        pool_k = np.full((2 * b * n, layout) + k.shape[2:], poison,
+                         np.float32)
+        pool_v = pool_k.copy()
+        pool_k[ids[owned]] = kp.reshape(b, n, layout, *k.shape[2:])[owned]
+        pool_v[ids[owned]] = vp.reshape(b, n, layout, *k.shape[2:])[owned]
+    got = np.asarray(fn(q, jnp.asarray(pool_k), jnp.asarray(pool_v),
+                        jnp.asarray(table), jnp.asarray(lens)))
+    want = np.asarray(attention(q, jnp.asarray(k), jnp.asarray(v),
+                                kv_lengths=jnp.asarray(lens),
+                                sliding_window=window, impl="xla"))
+    assert np.isfinite(got).all()
+    # a query that sees nothing (query 0 of a row of length 0) reads 0;
+    # the reference's softmax over no position is not a number to match
+    sees = (lens[:, None] + np.arange(sq)[None, :]) > 0
+    np.testing.assert_array_equal(got[~sees], 0.0)
+    np.testing.assert_allclose(got[sees], want[sees], rtol=2e-3, atol=2e-3)
 
 
 # ---------------------------------------------------------------------------
