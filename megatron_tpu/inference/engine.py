@@ -127,6 +127,22 @@ class Request:
         self.done.set()
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """What one dispatched program still owes the host: the device arrays
+    its tokens come back in, and the (slot, request) pairs they are for. A
+    row is applied at the read only if the slot still holds that request."""
+    rows: List[Tuple[int, Request]]
+    out: Any                      # fetched whole, in one device_get
+    step: int                     # the engine step that dispatched it
+    t0: float
+    ahead: bool = False           # in the queue before the last tick was read
+    # paged engine, a prompt's last chunk: its PrefillTask, and the pages
+    # held for the radix tree until the prompt's log-probabilities are read
+    task: Any = None
+    pinned: Tuple[int, ...] = ()
+
+
 class InferenceEngine:
     """Slot scheduler + jitted prefill/decode steps over one shared cache.
 
@@ -251,6 +267,21 @@ class InferenceEngine:
         # of re-uploading 6 host arrays per token; admission events
         # invalidate it (None -> re-upload from the host mirrors)
         self._carry = None
+        # the loop runs one tick ahead of the device (docs/serving.md "Step
+        # loop"): a plain decode tick is dispatched, and read only after
+        # the next one is in the queue. _inflight holds what is dispatched
+        # and unread, oldest first; _owed[i] counts slot i's tokens among
+        # it, so a finish by max_new_tokens is known at dispatch; lengths /
+        # temps / top_ks / top_ps are true as of the last DISPATCH and go
+        # up again when an event edits them (_carry_dirty), while the last
+        # tokens and the PRNG chains live on the device alone between
+        # drains. The speculative tick is synchronous: nothing of it is
+        # ever in flight.
+        self._inflight: deque[_InFlight] = deque()
+        self._owed = np.zeros(N, np.int32)
+        self._carry_dirty = False
+        self._step_no = 0
+        self._last_read_time = 0.0
         # hot weight reload: (params, version, applied_event) staged by
         # update_params(), swapped in BETWEEN decode ticks by the step
         # loop so in-flight slots never see a mid-tick change
@@ -287,7 +318,9 @@ class InferenceEngine:
                       "rejected": 0, "decode_recompiles": 0,
                       "timeouts": 0, "weight_reloads": 0,
                       "kv_exports": 0, "kv_imports": 0,
-                      "decode_live_block_share": 0.0}
+                      "decode_live_block_share": 0.0,
+                      "ticks_dispatched_ahead": 0, "tick_drains": {},
+                      "tokens_dropped_after_eod": 0}
         if self.spec is not None:
             # spec_emitted counts every token the spec path emitted
             # (accepted drafts + the guaranteed token per row per tick);
@@ -339,6 +372,18 @@ class InferenceEngine:
             "engine_decode_live_block_share",
             "KV blocks the decode kernel visits this tick over the blocks "
             "its page table holds")
+        self._m_ahead = m.counter(
+            "engine_ticks_dispatched_ahead_total",
+            "decode ticks dispatched before the previous tick's tokens "
+            "were read")
+        self._m_drains = m.counter(
+            "engine_tick_drains_total",
+            "times the loop read every tick in flight before going on, "
+            "by cause", label_names=("cause",))
+        self._m_dropped = m.counter(
+            "engine_tokens_dropped_after_eod_total",
+            "tokens of the one tick a row runs past its end-of-document "
+            "token, dropped at the read")
         self._m_tick = m.histogram("engine_decode_tick_seconds",
                                    "batched decode tick wall time")
         self._m_spec_proposed = m.counter(
@@ -473,8 +518,8 @@ class InferenceEngine:
         placement to GSPMD, as before."""
         if self.mesh is not None:
             return tree
-        sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
-        return jax.tree.map(lambda a: jax.device_put(a, sharding), tree)
+        return jax.device_put(
+            tree, jax.sharding.SingleDeviceSharding(jax.devices()[0]))
 
     def _kv_sharding(self):
         """Cache-leaf placement on a mesh engine: every leaf (dense
@@ -512,8 +557,7 @@ class InferenceEngine:
             return self._commit(tree)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        rep = NamedSharding(self.mesh, P())
-        return jax.tree.map(lambda a: jax.device_put(a, rep), tree)
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
 
     def _jit_sharding_kwargs(self, out_template):
         """out_shardings kwargs for the decode/prefill jits on a mesh
@@ -751,9 +795,10 @@ class InferenceEngine:
         leave sampling knobs behind, or the next carry upload would keep
         the batched sampler's filter branch live for stale rows. (This
         is the whole of the retire-path knob hygiene: every retire /
-        timeout / preempt / stop path funnels through here, and the
-        paired _sync_carry at each call site drops the device carry
-        that still holds the old knobs — audited again for the
+        timeout / preempt / stop path funnels through here, and
+        _carry_dirty sends the cleared knobs and length up before the
+        next dispatch (_init_carry), over the device carry that still
+        holds the old ones — audited again for the
         speculative rollback path, whose accept/reject cond reads the
         same temps/top_ks/top_ps rows; regression-pinned by
         test_speculative.py's all-greedy filter-dead test.)"""
@@ -763,6 +808,8 @@ class InferenceEngine:
         self.temps[i] = 0.0
         self.top_ks[i] = 0
         self.top_ps[i] = 0.0
+        self._owed[i] = 0
+        self._carry_dirty = True
         if not self.spec_on[i]:
             self.spec_on[i] = True
             self._spec_rows_dev = None
@@ -779,22 +826,62 @@ class InferenceEngine:
             self._m_per_token.observe(
                 (time.monotonic() - req.first_token_time)
                 / (len(req.generated) - 1))
-        # drop the device carry: it still holds this slot's sampling
-        # knobs, and a stale temperature/top_k>0 row would keep the
-        # batched sampler's lax.cond filter branch (the [N, V] sort) live
-        # for every remaining tick
-        self._sync_carry()
+        # _clear_slot marked the carry dirty: the device still holds this
+        # slot's sampling knobs, and a stale temperature/top_k>0 row would
+        # keep the batched sampler's lax.cond filter branch (the [N, V]
+        # sort) live for every remaining tick. The zeroed row goes up
+        # before the next dispatch, with no drain: a retirement happens at
+        # a read, often with the next tick already in flight
         self._journal_request(req, "ok")
         req._finish()
 
-    def _sync_carry(self):
-        """Pull the device-authoritative decode carry back into the host
-        mirrors and invalidate it (an admission is about to edit rows).
-        last_tok/lengths host mirrors are updated every tick; only the
-        per-slot PRNG chains live solely on device between events."""
+    def _sync_carry(self, cause: str):
+        """Make every host mirror true and drop the device carry: read
+        what is in flight (_drain, counted under `cause`), then pull the
+        per-slot PRNG chains, which live on the device alone between such
+        events. For the rare events that read or edit the chains or the
+        last tokens on the host (the slot engine's admission, preemption,
+        migration); the next dispatch uploads the carry from the mirrors.
+        Events that edit lengths and knobs alone mark _carry_dirty."""
+        self._drain(cause)
         if self._carry is not None:
             self.keys = np.array(self._carry[2])
             self._carry = None
+
+    def _drain(self, cause: str) -> int:
+        """Read every tick in flight, oldest first, so that the host
+        mirrors and the requests hold what the device has computed.
+        Allowed at events that edit rows, never on a tick that only
+        decodes; `engine_tick_drains_total{cause}` counts those that found
+        something to read. Returns how many programs were read."""
+        if not self._inflight:
+            return 0
+        by = self.stats["tick_drains"]
+        by[cause] = by.get(cause, 0) + 1
+        self._m_drains.inc(cause=cause)
+        n = 0
+        while self._inflight:
+            self._read(self._inflight.popleft())
+            n += 1
+        return n
+
+    def _read_behind(self, dispatched: int) -> int:
+        """The end of a step: read what earlier steps dispatched and leave
+        this step's own in flight, so that the device has the next tick
+        queued while the host walks this one's tokens; a step that
+        dispatched nothing reads everything. Returns `dispatched`, or, for
+        such a step, how many programs it read (0 = idle)."""
+        n = 0
+        while self._inflight and not (
+                dispatched and self._inflight[0].step == self._step_no):
+            self._read(self._inflight.popleft())
+            n += 1
+        return dispatched or n
+
+    def _drop_inflight(self) -> None:
+        """Forget what is in flight without reading it (a failed device
+        step, stop()): the caller fails or has failed its requests."""
+        self._inflight.clear()
 
     def _admit(self) -> int:
         """Move queued requests into free slots; prefill each. Returns the
@@ -827,7 +914,9 @@ class InferenceEngine:
         token at the final position with the preserved chain: the exact
         token the interrupted decode tick would have sampled, greedy or
         not (the paged engine's _try_assign is the same contract)."""
-        self._sync_carry()
+        # a whole-prompt prefill reads its first token at once and writes
+        # the mirrors: an admission drains (the paged engine's does not)
+        self._sync_carry("admission")
         resumed = req.resume_key is not None or bool(req.generated)
         full = (np.concatenate([np.asarray(req.prompt, np.int32),
                                 np.asarray(req.generated, np.int32)])
@@ -910,12 +999,14 @@ class InferenceEngine:
                     and req.generated[-1] == req.eod))
 
     def step(self) -> int:
-        """One engine tick: admit into free slots, then one batched decode
-        for every active slot. Returns the number of active slots served
-        (0 = idle)."""
+        """One engine tick: admit into free slots, dispatch one batched
+        decode for every active slot, then read the tick before it (the
+        loop runs one tick ahead of the device). Returns the number of
+        active slots served, or what a step with nothing to dispatch read
+        (0 = idle, and nothing in flight)."""
         self._pre_tick()
         self._admit()
-        return self._decode_tick()
+        return self._read_behind(self._decode_tick())
 
     def _pre_tick(self) -> None:
         """Per-tick control-plane work shared by every engine subclass
@@ -924,7 +1015,11 @@ class InferenceEngine:
         SIGKILLed/hung/slowed replica at a deterministic decode tick, so
         the router's failover paths are testable on CPU), staged weight
         swaps, and deadline expiry."""
-        tick = self.stats["ticks"]
+        self._step_no += 1
+        # ticks are counted at their read: the tick about to be
+        # dispatched is the one after those read and those in flight
+        tick = self.stats["ticks"] + sum(
+            1 for rec in self._inflight if rec.task is None)
         resilience.maybe_kill("kill_replica", tick)
         if (not self._preempt_signalled
                 and resilience.fault_active("preempt_replica", tick)):
@@ -978,6 +1073,9 @@ class InferenceEngine:
             self._pending_params = None
         if pending is None:
             return
+        # a swap is announced (applied.set()) only once every token of
+        # the old weights has reached its request
+        self._drain("weights")
         new, version, applied = pending
         self.params = new
         self.params_version = version
@@ -1006,15 +1104,20 @@ class InferenceEngine:
                 self._m_queue.set(len(self._queue))
         for req in expired:
             self._fail_timeout(req, "queued")
+
+        def late(req):
+            return (req is not None and req._deadline is not None
+                    and now > req._deadline)
+
+        if any(late(req) for req in self.slots):
+            # the tick in flight may be the one that ends the request:
+            # read it, then fail whoever is still there
+            self._drain("deadline")
         for i in range(self.num_slots):
             req = self.slots[i]
-            if (req is not None and req._deadline is not None
-                    and now > req._deadline):
+            if late(req):
+                # same carry hygiene as _retire (_clear_slot marks it)
                 self._clear_slot(i)
-                # same carry hygiene as _retire: the cleared row's sampling
-                # knobs must not keep the batched sampler's filter branch
-                # live for the remaining ticks
-                self._sync_carry()
                 self._m_active.set(self.num_active)
                 self._fail_timeout(req, "mid-decode")
 
@@ -1037,7 +1140,7 @@ class InferenceEngine:
         while True:
             with self._cv:
                 if (not self._queue and self._admitting == 0
-                        and self.num_active == 0
+                        and self.num_active == 0 and not self._inflight
                         and self._pending_params is None):
                     return True
             if deadline is not None and time.monotonic() > deadline:
@@ -1064,6 +1167,14 @@ class InferenceEngine:
                         (now - req.first_token_time)
                         / (len(req.generated) - 1), 6)
         j.emit("serve_request", **fields)
+        # cumulative counters of the loop's one tick of lookahead, one
+        # snapshot per retired request like serve_spec below: a reader
+        # takes the LAST one (ahead / ticks is the share of decode ticks
+        # that were in the queue before the one before them was read)
+        j.emit("serve_ticks", ticks=self.stats["ticks"],
+               ahead=self.stats["ticks_dispatched_ahead"],
+               drains=dict(self.stats["tick_drains"]),
+               dropped_after_eod=self.stats["tokens_dropped_after_eod"])
         if self.spec is not None:
             # cumulative speculative counters, one snapshot per retired
             # request (like goodput's cumulative records): the report
@@ -1116,8 +1227,12 @@ class InferenceEngine:
 
     def _decode_rows(self):
         """Slot indices the batched decode serves this tick (the paged
-        engine excludes slots still mid-chunked-prefill)."""
-        return [i for i, s in enumerate(self.slots) if s is not None]
+        engine excludes slots still mid-chunked-prefill). A request whose
+        tokens in flight fill its max_new_tokens is served no more: that
+        finish is a count, known before its last token is read."""
+        return [i for i, s in enumerate(self.slots)
+                if s is not None
+                and len(s.generated) + self._owed[i] < s.max_new_tokens]
 
     def _decode_extra_args(self):
         """Extra positional args spliced between caches and the carry in
@@ -1200,22 +1315,39 @@ class InferenceEngine:
         mirrors after an admission/retire invalidated it — shared by
         the plain and speculative ticks (ONE layout; a carry change
         must hit both paths by construction)."""
+        # copies go up, never the mirrors themselves: the host edits them
+        # while the tick they went into may still be in flight
         if self._carry is None:
             self._carry = self._commit_small(
-                (jnp.asarray(self.last_tok),
-                 jnp.asarray(self.lengths),
-                 jnp.asarray(self.keys),
-                 jnp.asarray(self.temps),
-                 jnp.asarray(self.top_ks),
-                 jnp.asarray(self.top_ps)))
+                (self.last_tok.copy(), self.lengths.copy(),
+                 self.keys.copy(), self.temps.copy(), self.top_ks.copy(),
+                 self.top_ps.copy()))
+        elif self._carry_dirty:
+            # an event edited lengths or knobs (a retirement, a prompt's
+            # end): they go up from the mirrors, which hold every live
+            # row's length as of the last dispatch and park the idle rows
+            # at 0; last tokens and chains stay the device's
+            last, _, keys, _, _, _ = self._carry
+            lens, temps, top_ks, top_ps = self._commit_small(
+                (self.lengths.copy(), self.temps.copy(),
+                 self.top_ks.copy(), self.top_ps.copy()))
+            self._carry = (last, lens, keys, temps, top_ks, top_ps)
+        self._carry_dirty = False
         return self._carry
 
     def _fail_decode(self, active, e) -> None:
         """Decode-step failure recovery shared by the plain and
         speculative ticks: fail the in-flight requests (their waiters
         must unblock), drop the carry, and restore usable caches —
-        donation may have consumed every cache tree."""
-        for i in active:
+        donation may have consumed every cache tree. Under asynchronous
+        dispatch a device failure surfaces at a dispatch or at the read
+        of a later tick: the requests of every tick in flight fail with
+        those of `active`, once, and nothing of those ticks is read."""
+        rows = set(active)
+        for rec in self._inflight:
+            rows.update(i for i, req in rec.rows if self.slots[i] is req)
+        self._drop_inflight()
+        for i in sorted(rows):
             req = self.slots[i]
             self._clear_slot(i)
             req._finish(f"decode step failed: {e}")
@@ -1298,48 +1430,102 @@ class InferenceEngine:
 
     def _decode_tick(self) -> int:
         """One batched decode for every decodable slot; returns how many
-        were served (0 = nothing to decode)."""
+        were served (0 = nothing to decode). The plain tick is dispatched
+        here and read later (_read); the speculative tick is whole here:
+        the host needs its accepts to know the lengths, the pages and,
+        for the n-gram drafter, the tokens of the next one."""
         active = self._decode_rows()
         if not active:
             return 0
         self._note_live_blocks(active)
         if self.spec is not None:
             return self._decode_tick_spec(active)
-        last, lens, keys, temps, top_ks, top_ps = self._init_carry()
+        carry = self._init_carry()
+        ahead = any(rec.task is None for rec in self._inflight)
         t_tick = time.monotonic()
         try:
-            toks, lps, keys, lens = self._call_decode_step(
-                last, lens, keys, temps, top_ks, top_ps)
+            toks, lps, keys, lens = self._call_decode_step(*carry)
         except Exception as e:  # noqa: BLE001 - shared recovery, then
             # surface the error to the driver
             self._fail_decode(active, e)
             raise
-        # toks/lens/keys chain into the next tick on device; only the
-        # sampled tokens (and logprobs) cross to the host each tick
-        self._carry = (toks, lens, keys, temps, top_ks, top_ps)
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
+        # toks/lens/keys chain into the next tick on device; the sampled
+        # tokens and logprobs cross to the host when the tick is read, in
+        # one fetch whose copy starts behind the step
+        self._carry = (toks, lens, keys, *carry[3:])
+        out = self._start_fetch((toks, lps if self.want_logprobs else None))
+        # every decoding row's length grows by exactly 1 a tick, so the
+        # next tick's pages, window and live blocks need none of this
+        # one's tokens: the fed token is in the cache once the step runs
+        self.lengths[active] += 1
+        self._owed[active] += 1
+        self._inflight.append(_InFlight(
+            rows=[(i, self.slots[i]) for i in active], out=out,
+            step=self._step_no, t0=t_tick, ahead=ahead))
+        return len(active)
+
+    @staticmethod
+    def _start_fetch(out):
+        """Start the copy to the host of a program's results, behind the
+        program in the device's queue: the read finds them there."""
+        for a in jax.tree.leaves(out):
+            if isinstance(a, jax.Array):
+                a.copy_to_host_async()
+        return out
+
+    def _fetch(self, rec: _InFlight):
+        """A program's results on the host, in one fetch. This is where
+        the loop waits for the device, and where a failed step surfaces."""
+        try:
+            return jax.device_get(rec.out)
+        except Exception as e:  # noqa: BLE001 - shared recovery, then
+            # surface the error to the driver
+            self._inflight.appendleft(rec)  # its requests fail with the rest
+            self._fail_decode((), e)
+            raise
+
+    def _read(self, rec: _InFlight) -> None:
+        """Read one dispatched decode tick: its tokens and logprobs reach
+        their requests, and those that end retire. A row applies only if
+        its slot still holds the request it was dispatched for: a row that
+        ended by eod ran one tick more than it should (only a finish by
+        eod needs the token), and that tick's result for it is dropped
+        here; its one extra KV position lies in a page the row owned."""
+        toks, lps = self._fetch(rec)
+        now = time.monotonic()
         self.stats["ticks"] += 1
         self._m_ticks.inc()
-        self._m_tick.observe(time.monotonic() - t_tick)
-        self._m_tokens.inc(len(active))
+        if rec.ahead:
+            self.stats["ticks_dispatched_ahead"] += 1
+            self._m_ahead.inc()
+        # one tick's wall time: from its dispatch, or, for a tick that
+        # queued behind another, from that one's read
+        self._m_tick.observe(now - max(rec.t0, self._last_read_time))
+        self._last_read_time = now
         self._count_comm(self._comm_tick_bytes)
         self._track_decode_recompiles()
         if self.flight_recorder is not None:
             self.flight_recorder.heartbeat(
-                f"tick {self.stats['ticks']} ({len(active)} active)")
-        for i in active:
-            req = self.slots[i]
-            # the fed token is now in the cache; the sampled one is next up
-            self.lengths[i] += 1
-            tok = int(toks[i])
-            self.last_tok[i] = tok
-            req.generated.append(tok)
-            req.logprobs.append(float(lps[i]))
+                f"tick {self.stats['ticks']} ({len(rec.rows)} active)")
+        toks = toks.tolist()
+        lps = lps.tolist() if lps is not None else None
+        applied = 0
+        for i, req in rec.rows:
+            if self.slots[i] is not req:
+                continue
+            applied += 1
+            self._owed[i] -= 1
+            self.last_tok[i] = toks[i]   # the sampled one is next up
+            req.generated.append(toks[i])
+            req.logprobs.append(lps[i] if lps is not None else 0.0)
             if self._req_finished(req):
                 self._retire(i)
+        self._m_tokens.inc(applied)
+        dropped = len(rec.rows) - applied
+        if dropped:
+            self.stats["tokens_dropped_after_eod"] += dropped
+            self._m_dropped.inc(dropped)
         self.last_progress_time = time.monotonic()
-        return len(active)
 
     def stalled(self, threshold_s: float) -> bool:
         """True when the engine has pending work (active slots or queued
@@ -1348,7 +1534,7 @@ class InferenceEngine:
         stalled, however long it sits."""
         with self._cv:
             busy = (self.num_active > 0 or bool(self._queue)
-                    or self._admitting > 0)
+                    or self._admitting > 0 or bool(self._inflight))
         return (busy and
                 time.monotonic() - self.last_progress_time > threshold_s)
 
@@ -1411,10 +1597,12 @@ class InferenceEngine:
 
     @contextlib.contextmanager
     def paused(self, timeout: float = 60.0):
-        """Park the step loop BETWEEN ticks so the caller may touch slot
-        state (request export/import). Counting, so nested pauses
-        compose; a no-op when no loop thread is running (tests and batch
-        drivers call step() themselves). Raises if the loop does not
+        """Park the step loop BETWEEN ticks, with nothing in flight (the
+        loop reads what it has dispatched before it parks), so the caller
+        may touch slot state (request export/import). Counting, so nested
+        pauses compose; a no-op when no loop thread is running (tests and
+        batch drivers call step() themselves, and the export/import entry
+        points drain). Raises if the loop does not
         reach a tick boundary within `timeout` — a wedged device step,
         which the caller must not race."""
         with self._cv:
@@ -1527,9 +1715,11 @@ class InferenceEngine:
         Token-identity contract: an importer resuming from this snapshot
         emits exactly the tokens this engine would have — greedy AND
         sampled, because the chain keys migrate. Call with the step loop
-        paused (self.paused()) or from the driver thread."""
+        paused (self.paused()) or from the driver thread: what is in
+        flight is read first (the parked loop has read it already)."""
         if include_kv:
             self._refuse_state_transfer("KV export (migration)")
+        self._drain("migration")
         meta: Dict[str, Any] = {
             "kind": "request",
             "prompt": [int(t) for t in np.asarray(req.prompt).tolist()],
@@ -1552,7 +1742,7 @@ class InferenceEngine:
         mid_prefill = (slot is not None and hasattr(self, "prefill_queue")
                        and slot in self.prefill_queue.slots)
         if slot is not None and not mid_prefill:
-            self._sync_carry()
+            self._sync_carry("migration")
             sections["resume_key"] = np.asarray(self.keys[slot],
                                                 np.uint32).copy()
             meta["position"] = int(self.lengths[slot])
@@ -1581,7 +1771,7 @@ class InferenceEngine:
         still blocked on req.done."""
         out: List[Tuple[Request, dict, Dict[str, np.ndarray]]] = []
         with self.paused():
-            self._sync_carry()
+            self._sync_carry("migration")
             for i in range(self.num_slots):
                 req = self.slots[i]
                 if req is None or req.done.is_set():
@@ -1590,7 +1780,6 @@ class InferenceEngine:
                     req, include_kv=include_kv)
                 self._clear_slot(i)
                 out.append((req, meta, sections))
-            self._sync_carry()
             self._m_active.set(self.num_active)
             with self._cv:
                 queued = list(self._queue)
@@ -1661,7 +1850,7 @@ class InferenceEngine:
             return False
         length = int(kv["length"])
         leaves = self._decode_kv_sections(kv, sections)
-        self._sync_carry()
+        self._sync_carry("migration")
         self.caches = self._kv_install_writer()(
             self.caches, kv_store.span_block(leaves, 0, self.max_seq_len),
             jnp.int32(i))
@@ -1738,6 +1927,7 @@ class InferenceEngine:
             ok, reason = self._kv_import_compatible(kv)
             if ok:
                 with self.paused():
+                    self._drain("migration")
                     if self._install_request_kv(req, kv, sections):
                         path = "kv_import"
                     else:
@@ -1772,7 +1962,9 @@ class InferenceEngine:
 
     def run_until_idle(self) -> None:
         """Step until the queue and every slot drain (single-thread use:
-        tests, benches, batch jobs)."""
+        tests, benches, batch jobs). Returns with nothing in flight: a
+        step that finds nothing to dispatch reads what is, and is not 0
+        until that is nothing."""
         with self._mesh_scope():
             while True:
                 served = self.step()
@@ -1864,7 +2056,10 @@ class InferenceEngine:
             with self._mesh_scope():
                 while True:
                     with self._cv:
-                        while (not self._stop
+                        # nothing parks with a tick unread: with one in
+                        # flight the loop goes on to read it (below, or
+                        # in a step that finds nothing to dispatch)
+                        while (not self._stop and not self._inflight
                                and (self._pause_count > 0
                                     or (self.num_active == 0
                                         and not self._queue
@@ -1887,10 +2082,13 @@ class InferenceEngine:
                             else:
                                 self._cv.wait()
                         self._paused_evt.clear()
-                        if self._stop:
-                            return
+                        stop = self._stop
+                        park = self._pause_count > 0
                     try:
-                        self.step()
+                        if stop or park:
+                            self._drain("stop" if stop else "pause")
+                        else:
+                            self.step()
                     except Exception as e:  # noqa: BLE001 - step() has
                         # already failed the affected requests; the loop
                         # must survive to serve the next ones (a dead
@@ -1900,6 +2098,8 @@ class InferenceEngine:
                         print(f"inference-engine step error: {e}",
                               file=sys.stderr)
                         traceback.print_exc()
+                    if stop:
+                        return
 
         self._thread = threading.Thread(target=loop, daemon=True,
                                         name="inference-engine")
@@ -1923,6 +2123,8 @@ class InferenceEngine:
             raise RuntimeError(
                 "inference-engine step loop did not stop within 30s")
         self._thread = None
+        self._drop_inflight()  # read by the loop on its way out; a failure
+        # there leaves nothing either
         with self._cv:
             leftovers = list(self._queue)
             self._queue.clear()

@@ -36,7 +36,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from megatron_tpu.config import ModelConfig
-from megatron_tpu.inference.engine import InferenceEngine, Request
+from megatron_tpu.inference.engine import (
+    InferenceEngine, Request, _InFlight,
+)
 from megatron_tpu.inference.paging.pool import SCRATCH_PAGE, PagePool
 from megatron_tpu.inference.paging.radix import RadixPrefixCache
 from megatron_tpu.inference.paging.scheduler import (
@@ -104,6 +106,7 @@ class PagedInferenceEngine(InferenceEngine):
         self._table_dirty = True
         self.prefill_queue = ChunkedPrefillQueue(self.prefill_chunk)
         self._chunk_step = self._build_chunk_step()
+        self._carry_row_writer = None  # once-jitted (_write_carry_row)
         # static per-chunk wire price for the compressed-collective
         # counters (one [1, C] forward; quant/collectives.py)
         from megatron_tpu.quant.collectives import forward_comm_bytes
@@ -487,8 +490,12 @@ class PagedInferenceEngine(InferenceEngine):
         row[len(hit_pages):n_prompt_pages] = fresh
         self._pending_rows[i] = row
         if self.state is not None:
-            # a sequence starts (a preempted one again, from position 0)
-            self.state = self._zero_state_row(self.state, jnp.int32(i))
+            # a sequence starts (a preempted one again, from position 0).
+            # The tick in flight may still advance the old occupant's row
+            # (one that ended by eod runs one tick more): this write is
+            # dispatched on the same chain of donated `state` buffers, so
+            # it orders after that tick by data dependence
+            self.state = self._zero_state_row(self.state, np.int32(i))
             self.stats["state_resets"] += 1
             self._m_state_resets.inc()
         self.slots[i] = req
@@ -502,8 +509,10 @@ class PagedInferenceEngine(InferenceEngine):
         task = PrefillTask(
             slot=i, tokens=toks, start=start, off=start,
             write_start=span,
+            # a fresh chain stays a device array: reading it back would
+            # wait for the tick in flight
             key=(np.asarray(req.resume_key) if req.resume_key is not None
-                 else np.asarray(jax.random.PRNGKey(req.seed))),
+                 else jax.random.PRNGKey(req.seed)),
             resumed=resumed, t_start=time.monotonic())
         if not resumed and span > 0:
             # cached teacher-forced logprobs for tokens 1..span-1; the
@@ -527,8 +536,13 @@ class PagedInferenceEngine(InferenceEngine):
     # ----- chunked prefill -------------------------------------------------
 
     def _prefill_tick(self) -> int:
-        """Run at most ONE chunk of the oldest incomplete prefill.
-        Returns 1 when a chunk ran (progress signal for run_until_idle)."""
+        """Dispatch at most ONE chunk of the oldest incomplete prefill.
+        Returns 1 when a chunk ran (progress signal for run_until_idle).
+        Nothing of it is read here: the chunk queues behind the tick in
+        flight, its scalars go up with the call (numpy values, no device
+        array made one by one), its prompt logprobs stay device arrays
+        until the prompt's last chunk, and that chunk's first token is
+        read with the ticks (_finish_prefill)."""
         task = self.prefill_queue.peek()
         if task is None:
             return 0
@@ -545,20 +559,20 @@ class PagedInferenceEngine(InferenceEngine):
             tok, lp, plp, self.caches, self.state, key = self._chunk_step(
                 self.params, self.caches, self.state,
                 self._chunk_table_arg(row),
-                jnp.asarray(toks_ext), jnp.int32(off),
-                jnp.int32(task.write_start), jnp.int32(task.total),
-                jnp.int32(task.total - 1), jnp.asarray(task.key),
-                jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jnp.float32(req.top_p),
-                None if self.state is None else jnp.int32(i))
+                toks_ext, np.int32(off),
+                np.int32(task.write_start), np.int32(task.total),
+                np.int32(task.total - 1), task.key,
+                np.float32(req.temperature), np.int32(req.top_k),
+                np.float32(req.top_p),
+                None if self.state is None else np.int32(i))
             if self._has_draft_model():
                 # mirror the chunk into the draft pools through the same
                 # table row and write fences
                 self.draft_caches = self._draft_chunk_step(
                     self.draft_params, self.draft_caches,
                     self._chunk_table_arg(row),
-                    jnp.asarray(toks_ext[:, :C]), jnp.int32(off),
-                    jnp.int32(task.write_start), jnp.int32(task.total))
+                    toks_ext[:, :C], np.int32(off),
+                    np.int32(task.write_start), np.int32(task.total))
         except Exception as e:  # noqa: BLE001 - a failing chunk must fail
             # THIS request, not strand it un-signalled and kill the loop
             # (same contract as the slot engine's prefill failure)
@@ -568,7 +582,8 @@ class PagedInferenceEngine(InferenceEngine):
             self._m_rejected.inc()
             if self._donate():
                 # the failed call may have consumed the donated pools
-                # (target AND draft trees)
+                # (target AND draft trees), and what is in flight with them
+                self._drop_inflight()
                 for j, other in enumerate(self.slots):
                     if other is not None:
                         self._clear_slot(j)
@@ -578,7 +593,7 @@ class PagedInferenceEngine(InferenceEngine):
             return 1
         n = min(C, task.total - off)
         if self.want_logprobs:
-            task.plp_parts.append(np.asarray(plp))
+            task.plp_parts.append(plp)  # the device's, until the last chunk
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_tokens"] += n
         self._count_comm(self._comm_chunk_bytes)
@@ -592,41 +607,101 @@ class PagedInferenceEngine(InferenceEngine):
         return 1
 
     def _finish_prefill(self, i: int, task: PrefillTask, tok, lp, key):
-        """The prompt is fully in the cache: publish the slot's table row
-        to the shared decode table, arm the decode mirrors, record the
-        first sampled token, and register the prompt's full pages in the
-        radix tree."""
-        self._sync_carry()
+        """The prompt's last chunk is dispatched: publish the slot's table
+        row to the shared decode table, arm the decode mirrors, and write
+        the first sampled token and the chain into the slot's row of the
+        device carry, so the slot decodes in this step's tick. `tok`, `lp`
+        and `key` are device values nobody has read: what the host owes
+        the request for them (the token, the logprobs, the radix tree's
+        entry) waits in flight and is paid at its read (_read_first)."""
         req = self.slots[i]
         row = self._pending_rows.pop(i)
         self.tables[i] = row
         self._table_dirty = True
-        p_ext = task.total
-        self.lengths[i] = p_ext
-        self.last_tok[i] = int(tok)
+        self.lengths[i] = task.total
         self.temps[i] = req.temperature
         self.top_ks[i] = req.top_k
         self.top_ps[i] = req.top_p
-        self.keys[i] = np.asarray(key)
+        self._carry_dirty = True
+        self._write_carry_row(i, tok, key)
         if self.spec is not None:
             self.spec_on[i] = bool(req.spec)
             self._spec_rows_dev = None
+        self._owed[i] += 1
+        pinned: tuple = ()
+        p0 = len(req.prompt)
+        if p0 >= self.page_size and not self.cfg.has_ssm:
+            # the FULL pages of the ORIGINAL prompt, for the radix tree.
+            # They enter it at the read, with their logprobs; held until
+            # then, so that neither the window's release nor a retirement
+            # hands one back to the pool in between
+            pinned = tuple(int(p) for p in row[:p0 // self.page_size])
+            self.pool.retain(pinned)
+        plps = (list(task.plp_parts)
+                if self.want_logprobs and not task.resumed else [])
+        rec = _InFlight(rows=[(i, req)],
+                        out=self._start_fetch((tok, lp, plps)),
+                        step=self._step_no, t0=time.monotonic(),
+                        task=task, pinned=pinned)
+        if self.spec is not None:
+            # the speculative tick is synchronous: it proposes from the
+            # tokens, so the mirrors must be true before it runs
+            self._read(rec)
+        else:
+            self._inflight.append(rec)
+
+    def _write_carry_row(self, i: int, tok, key) -> None:
+        """One row of the device carry takes a finished prompt's first
+        token and PRNG chain, both device values: a device-side write
+        (as `zero_row` is for the state), so that one prompt's end stalls
+        no other row. Lengths and knobs go up from the mirrors
+        (_init_carry)."""
+        if self._carry_row_writer is None:
+            def write_carry_row(last, keys, row, tok, key):
+                return last.at[row].set(tok), keys.at[row].set(key)
+
+            # nothing donated: `last` is also the tick in flight's tokens
+            self._carry_row_writer = jax.jit(
+                write_carry_row,
+                **self._jit_sharding_kwargs(("rep", "rep")))
+        last, lens, keys, temps, top_ks, top_ps = self._init_carry()
+        last, keys = self._carry_row_writer(last, keys, np.int32(i), tok,
+                                            key)
+        self._carry = (last, lens, keys, temps, top_ks, top_ps)
+
+    def _read(self, rec: _InFlight) -> None:
+        if rec.task is None:
+            return super()._read(rec)
+        self._read_first(rec)
+
+    def _read_first(self, rec: _InFlight) -> None:
+        """Read a finished prompt's first token: record it and the
+        prompt's logprobs, and register the prompt's full pages in the
+        radix tree."""
+        (i, req), = rec.rows
+        task = rec.task
+        tok, lp, plps = self._fetch(rec)
+        if self.slots[i] is not req:   # the rule of every row in flight
+            self.pool.release(rec.pinned)
+            return
+        self._owed[i] -= 1
+        self.last_tok[i] = int(tok)
         req.generated.append(int(tok))
         req.logprobs.append(float(lp))
         if not task.resumed and self.want_logprobs:
             req.prompt_logprobs = [
-                float(x) for x in np.concatenate(task.plp_parts)[:p_ext - 1]
-            ] if task.plp_parts else []
-        p0 = len(req.prompt)
-        if p0 >= self.page_size and not self.cfg.has_ssm:
+                float(x) for x in np.concatenate(plps)[:task.total - 1]
+            ] if plps else []
+        if rec.pinned:
             # only FULL pages of the ORIGINAL prompt enter the tree (the
             # partially-filled tail page stays private — decode writes
             # into it); resumes re-register recomputed pages, and insert
-            # skips paths already cached
-            self.prefix_cache.insert(req.prompt,
-                                     [int(p) for p in
-                                      row[:p0 // self.page_size]],
+            # skips paths already cached. The tree holds its own
+            # references now: the pin goes
+            self.prefix_cache.insert(req.prompt, rec.pinned,
                                      req.prompt_logprobs)
+            self.pool.release(rec.pinned)
+            self._m_pages_free.set(self.pool.free_pages)
         now = time.monotonic()
         self._m_prefill.observe(now - task.t_start)
         if not task.resumed:
@@ -634,8 +709,15 @@ class PagedInferenceEngine(InferenceEngine):
             if req.submit_time is not None:
                 self._m_ttft.observe(now - req.submit_time)
         self._m_tokens.inc()
+        self.last_progress_time = now
         if self._req_finished(req):
             self._retire(i)
+
+    def _drop_inflight(self) -> None:
+        for rec in self._inflight:
+            if rec.pinned:
+                self.pool.release(rec.pinned)
+        super()._drop_inflight()
 
     # ----- preemption ------------------------------------------------------
 
@@ -643,11 +725,13 @@ class PagedInferenceEngine(InferenceEngine):
         """Preempt the youngest active slot (LIFO — later arrivals yield
         pages to earlier ones). Its request re-enters the queue FRONT and
         resumes by exact teacher-forced recompute."""
+        # the chain to keep is the device's, and what is in flight may
+        # end a request: read it before choosing
+        self._sync_carry("pages")
         cands = [i for i in range(self.num_slots) if self.slots[i] is not None]
         if not cands:
             return False
         i = max(cands, key=lambda j: self._admit_seq[j])
-        self._sync_carry()
         req = self.slots[i]
         if i not in self.prefill_queue.slots:
             # mid-decode: preserve the PRNG chain so the resumed request
@@ -669,7 +753,11 @@ class PagedInferenceEngine(InferenceEngine):
         length but the pages stay mapped for future growth, and shared
         prefix pages are never in the span). Allocate across page
         boundaries, preempting the youngest slot when the pool is dry.
-        Each preemption frees that slot's pages, so this terminates."""
+        Each preemption frees that slot's pages, so this terminates.
+        Lengths are those of the last dispatch (a decoding row grows by
+        exactly 1 a tick), so this needs no token of the tick in flight;
+        only a dry pool reads it, since it may end a request and hand
+        its pages back."""
         span = self._decode_write_span()
         ps = self.page_size
         while True:
@@ -683,7 +771,8 @@ class PagedInferenceEngine(InferenceEngine):
                         continue
                     pages = self._alloc_pages(1, logical_start=pg)
                     if pages is None:
-                        if not self._preempt_one():
+                        if (not self._drain("pages")
+                                and not self._preempt_one()):
                             # unreachable: slot i itself is preemptible
                             return
                         dry = True
@@ -699,15 +788,16 @@ class PagedInferenceEngine(InferenceEngine):
 
     def _decode_rows(self):
         busy = self.prefill_queue.slots
-        return [i for i, s in enumerate(self.slots)
-                if s is not None and i not in busy]
+        return [i for i in super()._decode_rows() if i not in busy]
 
     def _decode_table_geometry(self):
         return self.max_pages, self.page_size
 
     def _decode_extra_args(self):
         if self._table_dirty or self._device_table is None:
-            self._device_table = self._commit_small(jnp.asarray(self.tables))
+            # a copy goes up: the host edits the table while the tick it
+            # went into may still be in flight
+            self._device_table = self._commit_small(self.tables.copy())
             self._table_dirty = False
         return (self._device_table,)
 
@@ -721,7 +811,7 @@ class PagedInferenceEngine(InferenceEngine):
         """Device form of one pending table row for the chunk step
         ([1, max_pages] here; the CP engine rebuilds it as per-rank
         local tables sharded over the context axis)."""
-        return jnp.asarray(row[None, :])
+        return row[None, :]
 
     def _release_window_pages(self) -> None:
         """Sliding-window page release (Mistral; ROADMAP item 1): pages
@@ -761,9 +851,13 @@ class PagedInferenceEngine(InferenceEngine):
             self._m_pages_free.set(self.pool.free_pages)
 
     def step(self) -> int:
-        """One engine tick: admit, run one prefill chunk, then one
-        batched decode for every slot whose prompt is fully cached.
-        Returns slots served + chunks run (0 = idle)."""
+        """One engine tick: admit, dispatch one prefill chunk and one
+        batched decode for every slot whose prompt is fully cached, then
+        read the tick before (the loop runs one tick ahead of the device:
+        everything before the read works from lengths the host has, and
+        happens while the device runs the last tick). Returns slots
+        served + chunks run, or what a step with nothing to dispatch
+        read (0 = idle, and nothing in flight)."""
         self._pre_tick()  # faults, staged weight swaps, deadline expiry
         self._admit()
         chunked = self._prefill_tick()
@@ -774,7 +868,7 @@ class PagedInferenceEngine(InferenceEngine):
             self.last_progress_time = time.monotonic()
         self._release_window_pages()
         self._ensure_decode_pages()
-        return self._decode_tick() + chunked
+        return self._read_behind(self._decode_tick() + chunked)
 
     def _retire(self, i: int):
         # base _retire -> _clear_slot releases this slot's page refs;
@@ -821,7 +915,7 @@ class PagedInferenceEngine(InferenceEngine):
             return False
         leaves = self._decode_kv_sections(kv, sections)
         writer = self._kv_install_writer()
-        self._sync_carry()
+        self._sync_carry("migration")
         for j, pg in enumerate(pages):
             self.caches = writer(self.caches,
                                  kv_store.span_block(leaves, j, ps),
@@ -852,6 +946,7 @@ class PagedInferenceEngine(InferenceEngine):
         self._refuse_state_transfer("the fleet's prefix directory")
         toks = [int(t) for t in tokens]
         with self.paused():
+            self._drain("migration")
             pages, lps = self.prefix_cache.lookup(toks)
             if not pages:
                 return None
@@ -885,6 +980,7 @@ class PagedInferenceEngine(InferenceEngine):
             return 0
         n_pages = span // ps
         with self.paused():
+            self._drain("migration")
             have, _ = self.prefix_cache.lookup(toks)
             if len(have) >= n_pages:
                 return 0  # the local copy stays authoritative

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import deque
-from typing import List, Optional
+from typing import Any, List, Optional
 
 import numpy as np
 
@@ -30,16 +30,19 @@ class PrefillTask:
     tokens: np.ndarray        # [p] int32 — the full logical prompt
     start: int                # first position to compute (prefix-cache skip)
     off: int                  # next chunk offset (start <= off <= p)
-    # teacher-forced logprob pieces accumulated chunk by chunk
-    # (host-side; assembled into Request.prompt_logprobs at completion)
+    # teacher-forced logprob pieces accumulated chunk by chunk (device
+    # arrays, and the radix tree's host arrays for a shared prefix;
+    # fetched and assembled into Request.prompt_logprobs when the first
+    # token is read)
     plp_parts: List[np.ndarray] = dataclasses.field(default_factory=list)
     # first position whose K/V write lands in a real page — positions
     # below it sit in prefix-cache-shared pages, so the overlap query's
     # write is fenced onto the scratch page (copy-on-write)
     write_start: int = 0
     # PRNG chain the final chunk samples with: PRNGKey(seed) for a fresh
-    # request, the preserved decode chain for a preemption resume
-    key: Optional[np.ndarray] = None
+    # request (a device array nobody reads back), the preserved decode
+    # chain for a preemption resume (the host's copy)
+    key: Optional[Any] = None
     # resume of a preempted request: `tokens` is prompt + generated, the
     # recompute is teacher-forced, and prompt_logprobs/radix bookkeeping
     # for the original prompt already happened on the first admission

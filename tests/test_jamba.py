@@ -357,15 +357,40 @@ def test_a_preempted_request_resumes_from_a_fresh_state(toy):
     assert eng.pool.used_pages == 0     # nothing is kept for a prefix hit
 
 
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "seeded"])
+def test_a_tick_in_flight_changes_no_token_of_a_typed_stack(toy, sampled):
+    """The loop runs one tick ahead of the device (docs/serving.md "Step
+    loop") over state rows too: staggered admissions, ends by eod in
+    mid-stream (such a row's state advances one tick more, and is zeroed
+    before the slot's next prompt) and by count. Each request's tokens,
+    logprobs and prompt logprobs are those the same engine gives it alone
+    with every tick read before the next is dispatched (`generate_tokens`
+    carries no recurrent state)."""
+    import _engine_lookahead_cases as cases
+
+    cfg, params = toy
+
+    def make():
+        return make_engine(cfg, params, num_slots=3)
+
+    cases.staggered_parity(make(), cases.served_alone_tick_by_tick(make),
+                           TOY["vocab_size"], sampled)
+
+
 def test_one_prompt_twice_is_no_prefix_hit(toy):
     """(e) A hit would need the state at the prefix's end: the tree is
     not asked, the finished prompt's pages are released, and the second
     request prefills whole, to the same answer."""
     from megatron_tpu.inference.engine import Request
 
+    from megatron_tpu.telemetry.metrics import MetricsRegistry
+
     cfg, params = toy
     prompt = prompts(2)[1]
-    eng = make_engine(cfg, params)
+    # a registry of its own: the process's default one counts every
+    # engine of this file
+    eng = make_engine(cfg, params, metrics=MetricsRegistry())
     first = eng.submit(Request(prompt=prompt, max_new_tokens=6))
     eng.run_until_idle()
     second = eng.submit(Request(prompt=prompt, max_new_tokens=6))
@@ -375,7 +400,7 @@ def test_one_prompt_twice_is_no_prefix_hit(toy):
     assert eng.stats["prefill_tokens"] == 2 * len(prompt)
     assert len(eng.prefix_cache) == 0 and eng.pool.used_pages == 0
     text = eng.metrics.render()
-    assert "engine_state_resets_total 2" in text
+    assert "engine_state_resets_total 2\n" in text
     state_bytes = 6 * 2 * 64 * (4 * 4 + 3 * 4)   # layers x slots x d_i x ...
     assert f"engine_state_bytes {state_bytes}" in text
 

@@ -546,3 +546,94 @@ def test_pages_in_the_parents_layout_export_and_install(int8):
     for got, f in zip(dst.caches, fixture):
         np.testing.assert_array_equal(np.asarray(got)[:, landed],
                                       f[:, pages])
+
+
+# ---------------------------------------------------------------------------
+# the loop runs one tick ahead of the device (docs/serving.md "Step loop"):
+# the events that read what is in flight
+
+
+def _tiny():
+    import jax
+
+    from megatron_tpu.models import presets
+    from megatron_tpu.models.params import init_params
+
+    cfg = presets.tiny(vocab_size=64, seq_length=64)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
+def test_a_dry_pool_reads_the_tick_in_flight_then_preempts():
+    """A pool too small for three sequences at once: the dry pool first
+    reads what is in flight (it may end a request and hand its pages
+    back), then `_preempt_one` takes the youngest, whose chain is the
+    device's. Every request still ends with the tokens, logprobs and
+    prompt logprobs `generate_tokens` gives it alone, seeded sampling
+    included, and every page comes back."""
+    import _engine_lookahead_cases as cases
+
+    from megatron_tpu.inference.paging import PagedInferenceEngine
+
+    cfg, params = _tiny()
+    # 24 positions a sequence = 6 pages of 4; 11 hold fewer than two whole
+    eng = PagedInferenceEngine(cfg, params, num_slots=3, max_seq_len=32,
+                               page_size=4, prefill_chunk=8, num_pages=12)
+    wants = cases.expected(cases.one_shot(cfg, params), cfg.vocab_size,
+                           sampled=True)
+    reqs = cases.submit_staggered(eng, wants, cfg.vocab_size, sampled=True)
+    cases.assert_served(reqs, wants, eng, drained=True)
+    assert eng.stats["preemptions"] >= 1
+    assert eng.stats["tick_drains"].get("pages", 0) >= 1
+    assert eng.pool.used_pages == len(eng.prefix_cache)
+
+
+def test_paused_exports_a_request_the_loop_had_a_tick_in_flight_for():
+    """`paused()` parks the loop with nothing in flight: the export reads
+    true mirrors in mid-decode, and the import resumes to the tokens of an
+    uninterrupted run, seeded sampling included."""
+    import time
+
+    from _engine_lookahead_cases import one_shot
+
+    from megatron_tpu.inference.engine import Request
+    from megatron_tpu.inference.fleet.migration import (
+        pack_state, unpack_state,
+    )
+    from megatron_tpu.inference.paging import PagedInferenceEngine
+
+    cfg, params = _tiny()
+    prompt = np.asarray([3, 7, 11, 2, 9], np.int32)
+    knobs = dict(temperature=0.8, top_k=8, top_p=0.9, seed=5)
+    want = one_shot(cfg, params)(prompt, 40, knobs)
+
+    def make():
+        return PagedInferenceEngine(cfg, params, num_slots=2,
+                                    max_seq_len=64, page_size=8,
+                                    prefill_chunk=8)
+
+    src = make()
+    src.generate(np.array([[1]], np.int32), np.array([1], np.int32),
+                 max_new_tokens=2)          # compiled before the clock runs
+    src.start()
+    try:
+        r = src.submit(Request(prompt=prompt, max_new_tokens=40, **knobs))
+        t0 = time.monotonic()
+        while len(r.generated) < 3 and time.monotonic() - t0 < 60:
+            time.sleep(0.001)
+        with src.paused():
+            assert not src._inflight
+            assert not r.done.is_set(), "the request ended before the pause"
+            meta, sections = src.export_request_state(r)
+            shipped = len(r.generated)
+            assert meta["position"] == len(prompt) + shipped - 1
+    finally:
+        src.stop()
+    assert 3 <= shipped < 40
+    meta, sections = unpack_state(pack_state(meta, sections))
+    dst = make()
+    req2, path = dst.import_request_state(meta, sections)
+    dst.run_until_idle()
+    assert path == "kv_import" and req2.error is None
+    assert req2.generated == want.generated
+    np.testing.assert_allclose(req2.logprobs, want.logprobs,
+                               rtol=1e-5, atol=1e-5)

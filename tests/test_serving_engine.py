@@ -774,3 +774,210 @@ def test_offered_load_throughput_scales_with_slots():
     t_eng = time.perf_counter() - t0
 
     assert t_eng < t_seq, (t_eng, t_seq)
+
+
+# ---------------------------------------------------------------------------
+# the loop runs one tick ahead of the device (docs/serving.md "Step loop")
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "seeded"])
+@pytest.mark.parametrize("make", [make_engine, make_paged],
+                         ids=["slot", "paged"])
+def test_a_tick_in_flight_changes_no_token(make, sampled):
+    """Staggered admissions, ends by eod in mid-stream and by count: each
+    request's tokens, logprobs and prompt logprobs are what
+    `generate_tokens` gives it alone."""
+    import _engine_lookahead_cases as cases
+
+    cases.staggered_parity(make(num_slots=3), cases.one_shot(CFG, PARAMS),
+                           CFG.vocab_size, sampled)
+
+
+def _fake_paged_steps(eng, V=64):
+    """The fake model of `_fake_steps` behind the paged engine's two
+    programs: a prompt's first token is its last token + 1."""
+    C = eng.prefill_chunk
+
+    def fake_chunk(params, caches, state, table_row, tokens_ext, off,
+                   write_start, write_end, sample_pos, key, temp, top_k,
+                   top_p, slot=None):
+        at = int(np.clip(int(sample_pos) - int(off), 0, C - 1))
+        tok = (jnp.asarray(tokens_ext)[0, at] + 1) % V
+        return (tok, jnp.float32(-1.0), jnp.zeros((C,), jnp.float32),
+                caches, state, jnp.asarray(key))
+
+    def fake_decode(params, caches, state, table, last, lengths, keys,
+                    temps, tks, tps):
+        return ((last + 1) % V, jnp.full(last.shape, -1.0, jnp.float32),
+                caches, state, keys, lengths + 1)
+
+    eng._chunk_step = fake_chunk
+    eng._decode_step = fake_decode
+    return eng
+
+
+def _record_order(eng):
+    """Every dispatch of the decode step and every fetch of one, in the
+    order the host made them: ("dispatch" | "read", tick number)."""
+    events, step, fetch = [], eng._decode_step, eng._fetch
+    dispatched, read = [0], [0]
+
+    def decode_step(*args):
+        dispatched[0] += 1
+        events.append(("dispatch", dispatched[0]))
+        return step(*args)
+
+    def fetch_one(rec):
+        if rec.task is None:
+            read[0] += 1
+            events.append(("read", read[0]))
+        return fetch(rec)
+
+    eng._decode_step, eng._fetch = decode_step, fetch_one
+    return events
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
+def test_dispatch_of_the_next_tick_precedes_the_read_of_this_one(paged):
+    """Host only. On a run of ticks that only decode, tick k + 1 is in the
+    queue before tick k is read, every such tick counts as dispatched
+    ahead, and the run ends with nothing in flight."""
+    eng = (_fake_paged_steps(make_paged(num_slots=2)) if paged
+           else _fake_steps(make_engine(num_slots=2)))
+    reqs = [eng.submit(Request(prompt=np.asarray([i + 1], np.int32),
+                               max_new_tokens=12)) for i in range(2)]
+    eng.step()           # both admitted, their prompts' ends dispatched
+    eng.step()
+    events = _record_order(eng)
+    ticks, ahead = eng.stats["ticks"], eng.stats["ticks_dispatched_ahead"]
+    for _ in range(6):   # ticks that only decode
+        eng.step()
+    assert eng.stats["ticks"] - ticks == 6 and len(eng._inflight) == 1
+    assert eng.stats["ticks_dispatched_ahead"] - ahead == 6
+    assert eng.stats["tick_drains"] == {}
+    # the window found one tick in flight, so its k-th read is of the
+    # tick before its k-th dispatch: d1 r1 d2 r2 ...
+    for k in range(1, 7):
+        assert (events.index(("dispatch", k))
+                < events.index(("read", k))), (k, events)
+    eng.run_until_idle()
+    assert not eng._inflight and eng.num_active == 0
+    for i, r in enumerate(reqs):
+        assert r.generated == [(i + 2 + j) % 64 for j in range(12)]
+
+
+def test_an_eod_rows_extra_tick_leaks_no_page():
+    """Host only. A row that ends by eod runs one tick more than it
+    should: the pool ends with the pages the tick-by-tick run ends with,
+    the token reaches nobody, and the request's reply is the same."""
+    from _engine_lookahead_cases import drive_tick_by_tick
+
+    def run(drive):
+        eng = _fake_paged_steps(make_paged(num_slots=2, page_size=4,
+                                           prefill_chunk=4))
+        # fake model counts up from the prompt's last token: 7 tokens to
+        # 30, crossing a page; the other request ends by count
+        a = eng.submit(Request(prompt=np.asarray([20, 21, 22, 23], np.int32),
+                               max_new_tokens=20, eod=30))
+        b = eng.submit(Request(prompt=np.asarray([40], np.int32),
+                               max_new_tokens=4))
+        drive(eng)
+        assert a.generated == list(range(24, 31))
+        assert b.generated == list(range(41, 45))
+        assert not eng._inflight and eng.num_active == 0
+        return eng
+
+    ahead = run(lambda eng: eng.run_until_idle())
+    plain = run(drive_tick_by_tick)
+    assert ahead.stats["tokens_dropped_after_eod"] == 1
+    assert plain.stats["tokens_dropped_after_eod"] == 0
+    assert ahead.pool.free_pages == plain.pool.free_pages
+    assert ahead.pool.used_pages == len(ahead.prefix_cache)
+    assert ahead.stats["ticks"] == plain.stats["ticks"] + 1
+
+
+def test_wait_idle_returns_with_nothing_in_flight():
+    """The loop thread reads what it dispatched before it parks: once
+    wait_idle() says idle no tick is unread, though the last request
+    ended by eod with the next tick already in the queue."""
+    eng = _fake_steps(make_engine(num_slots=2))
+    eng.start()
+    try:
+        r = eng.submit(Request(prompt=np.asarray([10], np.int32),
+                               max_new_tokens=30, eod=20))
+        assert r.done.wait(timeout=30)
+        assert eng.wait_idle(timeout=30)
+        assert not eng._inflight
+        assert r.generated == list(range(11, 21))
+        assert eng.stats["tokens_dropped_after_eod"] == 1
+        assert not eng.stalled(0.0)
+    finally:
+        eng.stop()
+
+
+def test_a_deadline_expires_with_a_tick_in_flight():
+    """The expiry reads the tick in flight first (it may be the one that
+    ends the request), then fails who is still there; the other request's
+    tokens are those it gets alone."""
+    eng = make_paged(num_slots=2)
+    prompt = np.asarray([3, 7, 11, 2], np.int32)
+    late = eng.submit(Request(prompt=np.asarray([5, 9], np.int32),
+                              max_new_tokens=40, deadline_s=600.0))
+    other = eng.submit(Request(prompt=prompt, max_new_tokens=14))
+    for _ in range(4):
+        eng.step()
+    assert eng._inflight and not late.done.is_set()
+    seen = len(late.generated)
+    late._deadline = time.monotonic()   # (the steps above compiled)
+    eng.step()
+    assert late.timed_out and "deadline exceeded" in late.error
+    # the tick that was in flight reached the request before it failed
+    assert len(late.generated) == seen + 1
+    assert eng.stats["tick_drains"] == {"deadline": 1}
+    eng.run_until_idle()
+    from _engine_lookahead_cases import one_shot
+
+    assert other.generated == one_shot(CFG, PARAMS)(prompt, 14, {}).generated
+    assert eng.pool.used_pages == len(eng.prefix_cache)
+    assert 'engine_tick_drains_total{cause="deadline"} 1' in (
+        eng.metrics.render())
+
+
+@pytest.mark.parametrize("where", ["dispatch", "read"])
+def test_a_failed_step_fails_the_requests_of_every_tick_in_flight(where):
+    """Host only. Under asynchronous dispatch a device failure surfaces at
+    a dispatch or at the read of a later tick: the requests of every tick
+    in flight fail, once, nothing of those ticks is read, and the engine
+    serves the next request."""
+    eng = _fake_steps(make_engine(num_slots=2))
+    reqs = [eng.submit(Request(prompt=np.asarray([i + 1], np.int32),
+                               max_new_tokens=12)) for i in range(2)]
+    for _ in range(3):
+        eng.step()
+    assert len(eng._inflight) == 1
+    seen = [len(r.generated) for r in reqs]
+    step = eng._decode_step
+
+    class Lost:
+        def __array__(self, *args, **kwargs):
+            raise RuntimeError("device lost")
+
+    def boom(*args):
+        raise RuntimeError("device lost")
+
+    if where == "dispatch":
+        eng._decode_step = boom
+    else:
+        eng._inflight[0].out = (Lost(), None)
+    with pytest.raises(RuntimeError, match="device lost"):
+        eng.step()
+    eng._decode_step = step
+    assert not eng._inflight and eng.num_active == 0 and eng._carry is None
+    for r, n in zip(reqs, seen):
+        assert r.done.is_set() and "decode step failed" in r.error
+        assert len(r.generated) == n   # the tick in flight reached nobody
+    ok = eng.submit(Request(prompt=np.asarray([9], np.int32),
+                            max_new_tokens=3))
+    eng.run_until_idle()
+    assert ok.error is None and ok.generated == [10, 11, 12]
