@@ -71,6 +71,19 @@ its grid is the rows, and each row's loop runs over the interval form
 of the same predicate (masks.decode_live_blocks), a young slot in a
 long cache over the context it has and an idle slot over nothing.
 
+A live tile of the training kernels does what its mask leaves and no
+more (`_visit_tile`): its place in the band is static wherever the
+blocks are square, the call carries no traced offset and the window is
+a multiple of the half tile (`_tile_classes`), so each kernel holds a
+body a class. An interior tile, every pair visible, runs without
+positions, mask or selects. A tile an edge of the band crosses (the
+diagonal's, the window's lower edge's) runs as the two pieces that cover
+its three live quarters, a row half at a time under a mask of static
+positions, and never computes the dead quarter
+(masks.prefill_tile_pieces). Any other shape, the ring's stripes among
+them, runs the one masked body over whole tiles. `tile_counts` says what
+a shape computes over what it needs.
+
 Precision: the training kernels (fwd; the fused bwd and the split dq,
 dk/dv, which share one tile function) hand the MXU their
 operands in the dtype they arrive in — q, k, v, do as given, the
@@ -172,8 +185,9 @@ def supported(q_len: int, kv_len: int, block_q: int, block_k: int) -> bool:
 
 # Tiles of the training kernels, from sweeps on the v5e (PR 24, and PR 40
 # for the fused backward; bf16 [1,32,S,128], window 4096, ms a call at
-# block_q x block_k with dead steps re-naming the held block; PERF.md
-# section 6 has all of it):
+# block_q x block_k with dead steps re-naming the held block and every
+# live tile run whole under one masked body; PERF.md section 6 has all of
+# it):
 #
 #   S 4096         256x256  512x512  512x1024  1024x512  1024x1024
 #   flash_fwd        5.62     2.50     2.03      2.67      1.79
@@ -185,19 +199,39 @@ def supported(q_len: int, kv_len: int, block_q: int, block_k: int) -> bool:
 # The fused `flash_bwd` does the work of the two rows above it in one
 # visit of each tile pair: five matmuls and one vector pass where the pair
 # run seven and two (the smaller tiles were read with dv's product first,
-# which reads 2.62 at 1024x1024). At 1024x1024 a causal sequence of 4096
-# computes 10 whole tiles a head where 8.2 tiles' worth of pairs are
-# visible, so the MXU alone needs 2.18 ms a call: the fused kernel is at
-# 85 % of that.
+# which reads 2.62 at 1024x1024).
 # 1024x1024 is also the fastest of the nine at S 2048, 8192 and 16384 for
 # every kernel (16384: 13.3 / 13.7 / 17.1 ms against 52.5 / 42.6 / 68.2 at
 # 256x256) and at [8,16,4096,128]; at S 1024 every tile of 512 or more
 # reads the same. Why: the body is straight-line code that issues one
 # bundle a cycle, and per 1024 scores it is 23 bundles at 256x256, 11 at
 # 512x512 and 8 at 1024x1024 (the per-row statistics, their lane
-# reductions and the accumulator's rescale spread over more columns),
-# which outweighs the masked share of the diagonal tiles (20 % of the live
-# area at 1024). A 2048 tile's [BQ, BK] float32 temporaries do not fit.
+# reductions and the accumulator's rescale spread over more columns). A
+# 2048 tile's [BQ, BK] float32 temporaries do not fit.
+#
+# What a 1024 tile wastes on the band's edges it no longer computes (PR
+# 53: each live tile runs the body of its class, `_visit_tile`). ms a call
+# at 1024x1024 on the v5e, `tools/flash_kernel_bench.py`, every live tile
+# whole under the one masked body -> a body a class, by class of layer:
+#
+#   layer, [B,H,S,128], window        tiles a head   flash_fwd       flash_bwd
+#   window [2,32,8192] 1024      15 edge, 0 interior  6.29 -> 4.52    9.02 -> 7.15
+#   full   [2,32,8192] none       8 edge, 28 interior 11.15 -> 9.83  17.67 -> 16.57
+#   causal [1,32,4096] 4096       4 edge, 6 interior  1.85 -> 1.41    2.58 -> 2.28
+#          [8,16,4096] 4096       (the four-chip shard) 7.54 -> 5.77 10.18 -> 8.99
+#          [1,16,4096] none                           0.83 -> 0.71    1.29 -> 1.16
+#
+# By tile (us; read off the same calls and their variants): a forward tile
+# whole under the mask of its traced positions 6.5, under a mask of static
+# positions 5.3, interior 4.0 (its two matmuls need 2.7), an edge tile's
+# two pieces 4.7; a backward tile 9.4 masked, 7.1 interior (its five
+# matmuls need 6.8), 7.4 as two pieces. The forward's pieces save less
+# than their area because a row's statistics cost the same whatever it
+# sees (an edge tile cut by column halves, a second pass over 512 of its
+# rows, reads 1.65 us slower), and the smaller piece runs first because
+# the other order reads 0.7 to 1.1 us a tile slower; masking only the
+# quarter an edge cuts, or dropping the second select where every row
+# sees a key, read the same to 0.1 %.
 _SWEPT_BLOCK = 1024
 
 
@@ -219,14 +253,134 @@ def pick_blocks(s: int, d: int, dtype) -> Tuple[int, int]:
 # ---------------------------------------------------------------------------
 
 
+def _tile_classes(nq: int, nk: int, block_q: int, block_k: int,
+                  causal: bool, window: Optional[int], offset):
+    """{dist: pieces}: the tile pairs of a grid of nq x nk tiles that are
+    NOT whole and wholly visible, by their distance under the diagonal
+    (dist = qi - ki), each with the pieces of it that its mask leaves
+    (`masks.prefill_tile_pieces`). A live tile at any other distance is
+    an interior tile. A causal sequence has one such distance, the
+    diagonal's; a window adds the tile its lower edge crosses (two, where
+    the window is an odd number of half tiles). None where a tile's place
+    in the band is not static or not on the half-tile grid, and every
+    live tile runs the one masked body: a traced position offset (ring
+    and Ulysses stripes), unequal blocks, a window that is no multiple of
+    the half tile, a half tile that hardware cannot slice (no multiple of
+    128; the interpreter takes any even block)."""
+    half = block_q // 2
+    if (offset is not None or block_q != block_k or block_q % 2
+            or (half % 128 and not _interpret())
+            or (window is not None and window % half)):
+        return None
+    whole = ((0, block_q, 0, block_k, False),)
+    classes = {}
+    for dist in range(1 - nk, nq):
+        pieces = masks.prefill_tile_pieces(dist, block_q, causal=causal,
+                                           window=window)
+        if pieces and pieces != whole:
+            classes[dist] = pieces
+    return classes
+
+
+def tile_counts(s: int, block: int, causal: bool,
+                window: Optional[int]) -> dict:
+    """How far the tile classes engage for a head of s rows at square
+    tiles of `block`, aligned: the live tile pairs (`tiles`) by class —
+    `interior` (every pair visible), `causal_edge` (the causal frontier
+    crosses it), `window_edge` (the window's lower edge does), `both` —
+    whether each runs the body of its class, an edge tile its live
+    pieces and no more (`by_class`;
+    false where `_tile_classes` gives none and every live tile is
+    computed whole), and the score elements the kernels compute, as
+    tiles (`tiles_computed`) and over the visible pairs
+    (`computed_over_visible`; 1.0 would be a kernel that computes no
+    masked pair). A count from the shape alone: the trainer journals it
+    for each kind of attention layer (`step_program.attention_tiles`)."""
+    n = s // block
+    classes = _tile_classes(n, n, block, block, causal, window, None)
+    counts = {"interior": 0, "causal_edge": 0, "window_edge": 0, "both": 0}
+    computed = 0
+    # aligned square tiles: a pair's class follows from its distance
+    # under the diagonal alone, and n - |dist| pairs lie at each
+    for dist in range(1 - n, n):
+        qi, ki = max(dist, 0), max(-dist, 0)
+        if not masks.prefill_block_live(qi, ki, block, block, causal=causal,
+                                        window=window):
+            continue
+        lo, hi = qi * block, qi * block + block - 1
+        on_causal = causal and (ki + 1) * block - 1 > lo
+        on_window = window is not None and ki * block <= hi - window
+        pieces = (classes or {}).get(dist, ((0, block, 0, block, None),))
+        counts[("interior", "causal_edge", "window_edge", "both")[
+            on_causal + 2 * on_window]] += n - abs(dist)
+        computed += (n - abs(dist)) * sum(nr * nc
+                                          for _, nr, _, nc, _ in pieces)
+    q_pos = np.arange(s, dtype=np.int64)
+    last = q_pos if causal else np.full_like(q_pos, s - 1)
+    first = (np.zeros_like(q_pos) if window is None
+             else np.maximum(q_pos - window + 1, 0))
+    visible = int((last - first + 1).sum())
+    return {"tiles": sum(counts.values()), **counts,
+            "by_class": classes is not None,
+            "tiles_computed": computed / block ** 2,
+            "computed_over_visible": computed / visible}
+
+
+def _visit_tile(qi, ki, off, classes, piece, *, causal: bool,
+                window: Optional[int], block_q: int, block_k: int):
+    """Runs `piece(rows, cols, mask)` over what its mask leaves of tile
+    pair (qi, ki): the slices of the tile's q rows and kv columns a piece
+    holds, and its mask, None where every pair of the piece is visible.
+    The FA-2 block-skip: a tile outside the
+    visible band (beyond the causal frontier / before the window's lower
+    edge) runs nothing, and its index map has re-named the held block, so
+    it loads nothing either. A live tile runs the body of its class
+    (`_tile_classes`): an interior tile the whole tile with no mask
+    arithmetic at all, an edge tile its live pieces under a mask of
+    static positions. Without classes, one body: the whole tile under
+    the mask of its traced positions."""
+    live = masks.prefill_block_live(qi, ki, block_q, block_k, causal=causal,
+                                    window=window, delta=off)
+    whole_q, whole_k = pl.ds(0, block_q), pl.ds(0, block_k)
+    if classes is None:
+        @pl.when(live)
+        def _masked():
+            q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k,
+                                                   off)
+            piece(whole_q, whole_k,
+                  masks.visible(q_pos, k_pos, causal=causal, window=window))
+        return
+
+    dist = qi - ki
+    interior = live
+    for at, pieces in classes.items():
+        interior = interior & (dist != at)
+
+        @pl.when(dist == at)
+        def _edge(at=at, pieces=pieces):
+            for r0, nr, c0, nc, masked in pieces:
+                mask = None
+                if masked:
+                    # the tile's first query sits at * block_q positions
+                    # past its first key, wherever the tile is
+                    q_pos, k_pos = masks.tile_positions(at * block_q + r0,
+                                                        c0, nr, nc)
+                    mask = masks.visible(q_pos, k_pos, causal=causal,
+                                         window=window)
+                piece(pl.ds(r0, nr), pl.ds(c0, nc), mask)
+
+    @pl.when(interior)
+    def _interior():
+        piece(whole_q, whole_k, None)
+
+
 def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
                 *, scale: float, causal: bool, window: Optional[int],
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, classes):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
-    delta = delta_ref[0]
 
     @pl.when(ki == 0)
     def _init():
@@ -234,32 +388,32 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # FA-2 block-skip: tiles outside the visible band (beyond the causal
-    # frontier / before the window's lower edge) never compute, and the
-    # kv index map has re-named the held block, so they never load either
-    @pl.when(masks.prefill_block_live(qi, ki, block_q, block_k,
-                                      causal=causal, window=window,
-                                      delta=delta))
-    def _compute():
-        q = q_ref[0, 0]                                  # [BQ, D]
-        k = k_ref[0, 0]                                  # [BK, D]
-        s = _dot(q, k, _NT) * scale                      # [BQ, BK] f32
+    def piece(rows, cols, mask):
+        """The online-softmax step of those q rows over those kv columns
+        of the tile: rows are independent in every statistic, so a piece
+        updates its rows' and no others."""
+        q = q_ref[0, 0, rows, :]                         # [nr, D]
+        k = k_ref[0, 0, cols, :]                         # [nc, D]
+        s = _dot(q, k, _NT) * scale                      # [nr, nc] f32
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
 
-        q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k,
-                                               delta)
-        mask = masks.visible(q_pos, k_pos, causal=causal, window=window)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_scr[:]                                # [BQ, 1]
+        m_prev = m_scr[rows, :]                          # [nr, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if mask is not None:
+            # a row the mask leaves nothing of has m_new at _NEG_INF
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, 0]                                  # [BK, D]
-        pv = _dot(p.astype(v.dtype), v, _NN)             # [BQ, D] f32
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        l_new = l_scr[rows, :] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, 0, cols, :]                         # [nc, D]
+        pv = _dot(p.astype(v.dtype), v, _NN)             # [nr, D] f32
+        acc_scr[rows, :] = acc_scr[rows, :] * alpha + pv
+        m_scr[rows, :] = m_new
+        l_scr[rows, :] = l_new
+
+    _visit_tile(qi, ki, delta_ref[0], classes, piece, causal=causal,
+                window=window, block_q=block_q, block_k=block_k)
 
     @pl.when(ki == nk - 1)
     def _emit():
@@ -352,7 +506,9 @@ def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k,
+        classes=_tile_classes(Sq // block_q, nk, block_q, block_k, causal,
+                              window, delta))
     q_map = _outer_tile_map
     kv_map = _inner_tile_map(functools.partial(
         masks.prefill_live_kv_tiles, block_q=block_q, block_k=block_k,
@@ -393,41 +549,40 @@ def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_tile(off, qi, ki, q, k, v, do, stats_ref,
-              *, scale: float, causal: bool, window: Optional[int],
-              block_q: int, block_k: int):
-    """(p, ds), [BQ, BK] float32 each, of one live tile pair: the
-    probabilities recomputed from the log-sum-exp and the gradient of
-    the scores (before the 1/sqrt(d), which the kernels put once on
-    their float32 sums). Two matmuls and the whole of the tile's vector
-    work; every backward kernel forms them by this one function."""
-    lse, delta = _row_stats(stats_ref)                   # [BQ, 1] each
+def _bwd_tile(q, k, v, do, stats, mask, scale: float):
+    """(p, ds), [rows, columns] float32 each, of one piece of a live
+    tile pair: the probabilities recomputed from the log-sum-exp and the
+    gradient of the scores (before the 1/sqrt(d), which the kernels put
+    once on their float32 sums). Two matmuls and the whole of the piece's
+    vector work; every backward kernel forms them by this one function.
+    mask None: every pair of the piece is visible."""
+    lse, delta = stats                                   # [rows, 1] each
     s = _dot(q, k, _NT) * scale
-    q_pos, k_pos = masks.prefill_positions(qi, ki, block_q, block_k, off)
-    mask = masks.visible(q_pos, k_pos, causal=causal, window=window)
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)           # softmax probs
-    dp = _dot(do, v, _NT)                                # [BQ, BK]
+    p = jnp.exp(s - lse)                                 # softmax probs
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    dp = _dot(do, v, _NT)                                # [rows, columns]
     return p, p * (dp - delta)
 
 
 def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
                 *, scale: float, causal: bool, window: Optional[int],
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, classes):
     """The fused backward: grid (b, h, ki, qi), q innermost. Every live
     tile pair is visited once and gives all three gradients from one p
-    and one ds (five matmuls). dk and dv of the kv tile sum over the
-    inner axis in [BK, D] scratch; dq sums over the OUTER axis, so its
-    float32 accumulator holds the head's whole sequence, [Sq/BQ, BQ, D],
-    and the dq output block is the head's whole [Sq, D]: q tile qi's rows
-    are zeroed on the first kv tile's pass and scaled, cast and written
-    on the last one's. Each sum takes its terms in the order the split
-    pair takes them (ascending tiles)."""
+    and one ds (five matmuls a piece of it: `_visit_tile`). dk and dv of
+    the kv tile sum over the inner axis in [BK, D] scratch; dq sums over
+    the OUTER axis, so its float32 accumulator holds the head's whole
+    sequence, [Sq/BQ, BQ, D], and the dq output block is the head's whole
+    [Sq, D]: q tile qi's rows are zeroed on the first kv tile's pass and
+    scaled, cast and written on the last one's. Each sum takes its terms
+    in the order the split pair takes them (ascending tiles, and a
+    tile's pieces in theirs)."""
     ki = pl.program_id(2)
     qi = pl.program_id(3)
     nk = pl.num_programs(2)
     nq = pl.num_programs(3)
-    off = off_ref[0]
 
     @pl.when(qi == 0)
     def _init_kv():
@@ -438,23 +593,22 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
     def _init_q():
         dq_scr[qi] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
 
-    @pl.when(masks.prefill_block_live(qi, ki, block_q, block_k,
-                                      causal=causal, window=window,
-                                      delta=off))
-    def _compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        do = do_ref[0, 0]
-        p, ds = _bwd_tile(off, qi, ki, q, k, v_ref[0, 0], do, stats_ref,
-                          scale=scale, causal=causal, window=window,
-                          block_q=block_q, block_k=block_k)
+    def piece(rows, cols, mask):
+        q = q_ref[0, 0, rows, :]
+        k = k_ref[0, 0, cols, :]
+        do = do_ref[0, 0, rows, :]
+        p, ds = _bwd_tile(q, k, v_ref[0, 0, cols, :], do,
+                          _row_stats(stats_ref, rows), mask, scale)
         # dq's product first: it takes ds as it lies, while the other two
         # wait for a transpose of ds and of p (2.56 ms a call against 2.62
         # with dv's first and 2.65 with dq's last, at the swept shape)
         ds = ds.astype(q.dtype)
-        dq_scr[qi] += _dot(ds, k, _NN)
-        dk_scr[:] += _dot(ds, q, _TN)
-        dv_scr[:] += _dot(p.astype(do.dtype), do, _TN)
+        dq_scr[qi, rows, :] += _dot(ds, k, _NN)
+        dk_scr[cols, :] += _dot(ds, q, _TN)
+        dv_scr[cols, :] += _dot(p.astype(do.dtype), do, _TN)
+
+    _visit_tile(qi, ki, off_ref[0], classes, piece, causal=causal,
+                window=window, block_q=block_q, block_k=block_k)
 
     @pl.when(qi == nq - 1)
     def _emit_kv():
@@ -471,26 +625,24 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
 def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                dq_ref, dq_scr,
                *, scale: float, causal: bool, window: Optional[int],
-               block_q: int, block_k: int):
+               block_q: int, block_k: int, classes):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
-    off = off_ref[0]
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(masks.prefill_block_live(qi, ki, block_q, block_k,
-                                      causal=causal, window=window,
-                                      delta=off))
-    def _compute():
-        k = k_ref[0, 0]
-        _, ds = _bwd_tile(off, qi, ki, q_ref[0, 0], k, v_ref[0, 0],
-                          do_ref[0, 0], stats_ref, scale=scale,
-                          causal=causal, window=window, block_q=block_q,
-                          block_k=block_k)
-        dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
+    def piece(rows, cols, mask):
+        k = k_ref[0, 0, cols, :]
+        _, ds = _bwd_tile(q_ref[0, 0, rows, :], k, v_ref[0, 0, cols, :],
+                          do_ref[0, 0, rows, :],
+                          _row_stats(stats_ref, rows), mask, scale)
+        dq_scr[rows, :] += _dot(ds.astype(k.dtype), k, _NN)
+
+    _visit_tile(qi, ki, off_ref[0], classes, piece, causal=causal,
+                window=window, block_q=block_q, block_k=block_k)
 
     @pl.when(ki == nk - 1)
     def _emit():
@@ -501,28 +653,26 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
 def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, scale: float, causal: bool, window: Optional[int],
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, classes):
     ki = pl.program_id(2)
     qi = pl.program_id(3)
     nq = pl.num_programs(3)
-    off = off_ref[0]
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(masks.prefill_block_live(qi, ki, block_q, block_k,
-                                      causal=causal, window=window,
-                                      delta=off))
-    def _compute():
-        q = q_ref[0, 0]
-        do = do_ref[0, 0]
-        p, ds = _bwd_tile(off, qi, ki, q, k_ref[0, 0], v_ref[0, 0], do,
-                          stats_ref, scale=scale, causal=causal,
-                          window=window, block_q=block_q, block_k=block_k)
-        dv_scr[:] += _dot(p.astype(do.dtype), do, _TN)
-        dk_scr[:] += _dot(ds.astype(q.dtype), q, _TN)
+    def piece(rows, cols, mask):
+        q = q_ref[0, 0, rows, :]
+        do = do_ref[0, 0, rows, :]
+        p, ds = _bwd_tile(q, k_ref[0, 0, cols, :], v_ref[0, 0, cols, :], do,
+                          _row_stats(stats_ref, rows), mask, scale)
+        dv_scr[cols, :] += _dot(p.astype(do.dtype), do, _TN)
+        dk_scr[cols, :] += _dot(ds.astype(q.dtype), q, _TN)
+
+    _visit_tile(qi, ki, off_ref[0], classes, piece, causal=causal,
+                window=window, block_q=block_q, block_k=block_k)
 
     @pl.when(qi == nq - 1)
     def _emit():
@@ -608,9 +758,10 @@ def _bwd_stats(lse, o, do, block_q):
     )(lse, delta)
 
 
-def _row_stats(stats_ref):
-    """(lse, delta), [BQ, 1] each, of a backward kernel's q tile."""
-    stats = stats_ref[0, 0]
+def _row_stats(stats_ref, rows):
+    """(lse, delta), [rows, 1] each, of those rows of a backward
+    kernel's q tile."""
+    stats = stats_ref[0, 0, rows, :]
     return stats[:, 0:1], stats[:, _DELTA_LANE:_DELTA_LANE + 1]
 
 
@@ -634,7 +785,9 @@ def _bwd_dq(q, k, v, do, stats, scale, causal, window, block_q,
     nk = k.shape[2] // block_k
     kernel = functools.partial(
         _dq_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k,
+        classes=_tile_classes(Sq // block_q, nk, block_q, block_k, causal, window,
+                              offset))
     q_map = _outer_tile_map
     kv_map = _inner_tile_map(functools.partial(
         masks.prefill_live_kv_tiles, block_q=block_q, block_k=block_k,
@@ -663,7 +816,9 @@ def _bwd_dkv(q, k, v, do, stats, scale, causal, window, block_q,
     nq = Sq // block_q
     kernel = functools.partial(
         _dkv_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k,
+        classes=_tile_classes(nq, Skv // block_k, block_q, block_k, causal, window,
+                              offset))
     q_map = _inner_tile_map(functools.partial(
         masks.prefill_live_q_tiles, block_q=block_q, block_k=block_k,
         causal=causal, window=window), nq)
@@ -703,7 +858,9 @@ def _bwd_fused(q, k, v, do, stats, scale, causal, window, block_q,
     nq = Sq // block_q
     kernel = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k,
+        classes=_tile_classes(nq, Skv // block_k, block_q, block_k, causal, window,
+                              offset))
     q_map = _inner_tile_map(functools.partial(
         masks.prefill_live_q_tiles, block_q=block_q, block_k=block_k,
         causal=causal, window=window), nq)

@@ -101,6 +101,27 @@ def gpt_collate(items, eod_token=None, eod_mask_loss=False,
     return batch
 
 
+def _attention_tiles(model_cfg, batch) -> Dict[str, Dict[str, Any]]:
+    """`flash_template.tile_counts` by the name of each kind of attention
+    layer, for a step whose attention is the flash kernels over the whole
+    sequences of the batch's `tokens` (`--attention_impl pallas` on a
+    TPU, or interpreted on request) at the tiles `pick_blocks` gives
+    them: how far the kernels' tile classes engage at this shape. Empty
+    where the step runs other attention."""
+    from megatron_tpu.ops.attention import _kernels_dispatchable
+    from megatron_tpu.ops.pallas import flash_template
+
+    if (model_cfg.attention_impl != "pallas" or "tokens" not in batch
+            or not _kernels_dispatchable()):
+        return {}
+    seq_len = batch["tokens"].shape[-1]
+    block, _ = flash_template.pick_blocks(seq_len, model_cfg.head_dim,
+                                          model_cfg.dtype)
+    return {kind.name: flash_template.tile_counts(
+        seq_len, block, True, kind.sliding_window_size)
+        for kind in model_cfg.attention_period}
+
+
 class TrainLoop:
     """Owns mesh, state, jitted steps, and the iteration loop."""
 
@@ -1352,7 +1373,10 @@ class TrainLoop:
         checked against (docs/observability.md "Runtime traces"); and
         each Pallas kernel's calls with how many of them are a
         recomputation (`kernel_calls`: under `selective` the flash
-        forward has none)."""
+        forward has none); and, for each kind of attention layer, the
+        tiles a head of the flash kernels visits by class and the score
+        elements they compute over the visible pairs (`attention_tiles`:
+        `_attention_tiles`)."""
         if self._profiled_step is None or self.telemetry is None:
             return
         step, n_micro, batch_avals = self._profiled_step
@@ -1387,7 +1411,8 @@ class TrainLoop:
             kernel_summed_share=(sum(n for n, s in sizes if s)
                                  / sum(n for n, _ in sizes)),
             collectives=where, unnamed_instructions=unnamed,
-            kernel_calls=kernels)
+            kernel_calls=kernels,
+            attention_tiles=_attention_tiles(self.cfg.model, batch_avals))
 
     # -- loop ---------------------------------------------------------------
 

@@ -131,6 +131,20 @@ def _kernel_cases():
             (131072, 4, ["flash_bwd_dkv", "flash_bwd_dq", "flash_bwd_stats",
                       "flash_fwd"]))
     ]
+    # the training cells' calls (benchmark/configs/: Mellum's window-1024
+    # and full layers at 8192 x micro-batch 2, the TP 2 x DP 2 cell's
+    # shard, OLMoE's causal layer; "forward_backward" above is the one-chip
+    # Mistral cell's): each kernel holds a body a class of tile
+    # (`ft._tile_classes`), whose half-tile slices the chip's compiler
+    # has to take
+    cases += [
+        (f"forward_backward_{cell}",
+         functools.partial(_fwd_bwd_cell, window),
+         [((b, s, hq, D), bf16), ((b, s, hkv, D), bf16),
+          ((b, s, hkv, D), bf16)],
+         ["flash_bwd", "flash_bwd_stats", "flash_fwd"])
+        for cell, (b, s, hq, hkv, window) in _TRAIN_CELLS.items()
+    ]
     cases += [
         ("decode", decode,
          [((SLOTS, 1, HQ, D), bf16), kv_cache, kv_cache, lens],
@@ -168,6 +182,20 @@ def _kernel_cases():
                  ((_DECODE_SLOTS, entries), i32), ((_DECODE_SLOTS,), i32)],
                 ["paged_flash_decode"]))
     return cases
+
+
+def _fwd_bwd_cell(window, q, k, v):
+    return jax.grad(
+        lambda *a: ft.flash_mha(*a, sliding_window=window)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+# cell's layer: (micro-batch, sequence, query heads, kv heads, window) of
+# one chip's flash calls
+_TRAIN_CELLS = {"mellum_sliding": (2, 8192, 32, 8, 1024),
+                "mellum_full": (2, 8192, 32, 8, None),
+                "mistral_tp2dp2": (8, 4096, 16, 4, 4096),
+                "olmoe": (1, 4096, 16, 16, None)}
 
 
 def _paged_cell(entry, window, q, kp, vp, table, n):
@@ -238,6 +266,42 @@ def test_kernel_carries_its_name_for_v5e(topo, name):
         b, s, h, d = args[0][0]
         first = re.search(r"%flash_bwd(?:\.\d+)? = \((\w+\[[\d,]+\])", text)
         assert first.group(1) == f"bf16[{b},{h},{s},{d}]"
+
+
+@pytest.mark.parametrize("name", ["forward_backward"] + [
+    f"forward_backward_{cell}" for cell in _TRAIN_CELLS])
+def test_training_kernels_fit_the_vmem_their_formulas_ask_for(topo, name):
+    """Each training kernel is compiled under the scoped-VMEM limit its
+    own formula gives (`_fwd_vmem_bytes`, `_fused_bwd_vmem_bytes`; the
+    default where that is more), and Mosaic refuses a kernel that needs
+    more than its limit: the compile above, with a body a class of tile
+    in each kernel, is the proof that the formulas still bound them. The
+    classes engage at every cell's shape: a kernel that fell back to the
+    one masked body would compile too."""
+    _, _, args, _ = next(c for c in _CASES if c[0] == name)
+    (b, s, hq, d), item = args[0][0], 2
+    window = WINDOW if name == "forward_backward" else _TRAIN_CELLS[
+        name[len("forward_backward_"):]][4]
+    block_q, block_k = ft.pick_blocks(s, d, jnp.bfloat16)
+    asked = {}
+    for line in _kernel_text(topo, name).splitlines():
+        m = re.search(r"%(flash_\w+?)(?:\.\d+)? = .*tpu_custom_call.*"
+                      r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                      line)
+        if m:
+            asked[m.group(1)] = int(m.group(2))
+    want = {"flash_fwd": ft._fwd_vmem_bytes(block_q, block_k, d, item),
+            "flash_bwd": ft._fused_bwd_vmem_bytes(s, block_q, block_k, d,
+                                                  item)}
+    assert ft._bwd_vmem_bytes(block_q, block_k, d, item) < want["flash_bwd"]
+    for kernel, formula in want.items():
+        assert asked[kernel] == max(formula, ft._DEFAULT_SCOPED_VMEM)
+        assert asked[kernel] <= ft._MAX_SCOPED_VMEM
+    n = s // block_q
+    classes = ft._tile_classes(n, n, block_q, block_k, True, window, None)
+    assert sorted(classes) == ([0] if window is None or window >= s
+                               else [0, window // block_q])
+    assert all(len(pieces) == 2 for pieces in classes.values())
 
 
 @pytest.mark.parametrize("name", [c[0] for c in _CASES if "decode" in c[0]])

@@ -170,7 +170,9 @@ def test_kernel_matmuls_take_operands_as_given(monkeypatch, dtype, backward,
     above meaning what they say. The backward is ONE kernel of five
     matmuls: each tile pair's p and ds are formed once; the split pair
     that a sequence too long for it runs forms them twice (seven). The
-    statistics' kernel multiplies nothing."""
+    statistics' kernel multiplies nothing. Each kernel holds those
+    matmuls once a body, and a causal sequence has three: the interior
+    tiles' and the two pieces of the diagonal tile (`ft._tile_classes`)."""
     if backward == "split":
         monkeypatch.setattr(ft, "fused_bwd_fits", lambda *a: False)
     q, k, v = (x.astype(dtype) for x in _qkv(s=256, hq=2, hkv=1, d=64))
@@ -181,7 +183,11 @@ def test_kernel_matmuls_take_operands_as_given(monkeypatch, dtype, backward,
     per_kernel = {}
     for name, _, _ in dots:
         per_kernel[name] = per_kernel.get(name, 0) + 1
-    assert per_kernel == {"flash_fwd": 2, **matmuls}, dots
+    (pieces,) = ft._tile_classes(2, 2, 128, 128, True, None, None).values()
+    bodies = 1 + len(pieces)
+    assert bodies == 3
+    assert per_kernel == {name: n * bodies for name, n in
+                          {"flash_fwd": 2, **matmuls}.items()}, dots
     for kernel, operands, result in dots:
         assert operands == (dtype, dtype), (kernel, operands)
         assert result == jnp.float32, (kernel, result)
@@ -319,3 +325,133 @@ def test_which_backward_a_shape_takes(monkeypatch, sq, d, dtype, fused):
     x = jax.ShapeDtypeStruct((1, 1, sq, d), dtype)
     ft._bwd(x, x, x, x, None, x, 1.0, True, None, *blocks)
     assert taken == ["_bwd_fused" if fused else "_bwd_split"]
+
+
+# ---------------------------------------------------------------------------
+# tile classes: each live tile runs the body of its class (interior: no
+# mask arithmetic; band edge: its live half-tile pieces), chosen from the
+# call's static shape, window and offset (`ft._tile_classes`)
+# ---------------------------------------------------------------------------
+
+# case: (window, kv heads of 2 query heads, traced offset, the distances
+# under the diagonal at which S 256 in tiles of 64 has edge tiles, each
+# with its number of pieces; None: the shape falls back to the one masked
+# body)
+_CLASS_CASES = {
+    "causal": (None, 2, False, {0: 2}),
+    "window_is_the_tile": (64, 2, False, {0: 2, 1: 2}),
+    "window_is_two_tiles": (128, 2, False, {0: 2, 2: 2}),
+    # an odd number of half tiles: the edge crosses a whole tile's lower
+    # left quarter (one masked piece) and the next tile's upper right
+    "window_is_three_halves": (96, 2, False, {0: 2, 1: 1, 2: 1}),
+    # shorter than the tile: the diagonal tile holds both edges
+    "window_is_the_half": (32, 2, False, {0: 2, 1: 1}),
+    "window_off_the_half": (40, 2, False, None),
+    "gqa": (64, 1, False, {0: 2, 1: 2}),
+    "traced_offset": (64, 2, True, None),
+}
+
+
+def _reference_bhsd(q, k, v, window):
+    """The XLA attention on [B, H, S, D] tensors."""
+    t = lambda x: jnp.transpose(x, (0, 2, 1, 3))  # noqa: E731
+    return t(attention(t(q), t(k), t(v), sliding_window=window))
+
+
+@pytest.mark.parametrize("case", list(_CLASS_CASES))
+def test_every_tile_class_matches_the_plain_reference(case):
+    """Forward and backward at S 256 in tiles of 64 (halves of 32)
+    against the XLA attention and its gradient, at this file's
+    tolerances, for each class of tile and its neighbours; and the
+    classes are the ones the shape should have. The traced offset goes
+    through the ring's entries (`stripe_fwd` / `stripe_bwd`), offset 0."""
+    window, hkv, traced, want = _CLASS_CASES[case]
+    got = ft._tile_classes(4, 4, 64, 64, True, window, 0 if traced else None)
+    assert (got and {d: len(p) for d, p in got.items()}) == want
+    if traced:
+        q, k, v, do = _bhsd_case(jnp.float32, 2)
+        scale = float(1.0 / q.shape[-1] ** 0.5)
+
+        @jax.jit
+        def stripes(delta):
+            o, lse = ft.stripe_fwd(q, k, v, delta, window, scale, 64)
+            return o, ft.stripe_bwd(q, k, v, o, lse, do, delta, window,
+                                    scale, 64)
+
+        o, grads = stripes(jnp.int32(0))
+        want_o, vjp = jax.vjp(
+            lambda *a: _reference_bhsd(*a, window), q, k, v)
+        want_grads = vjp(do)
+    else:
+        q, k, v = _qkv(s=256, hq=2, hkv=hkv, d=64)
+        flash = functools.partial(flash_mha, sliding_window=window,
+                                  block_q=64, block_k=64)
+        ref = functools.partial(attention, sliding_window=window)
+        loss = lambda fn: (lambda *a: jnp.sum(jnp.square(fn(*a))))  # noqa: E731
+        o, want_o = flash(q, k, v), ref(q, k, v)
+        grads = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        want_grads = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o),
+                               rtol=2e-3, atol=2e-3)
+    for name, a, b in zip("qkv", grads, want_grads):
+        assert _share_of_range(a, b) <= 2e-3, f"d{name}"
+
+
+def test_interior_body_equals_the_masked_body_bit_for_bit():
+    """A bidirectional sequence has interior tiles only: the body without
+    positions, mask and selects gives the bits of the masked body (which
+    a traced offset of 0 still runs on every tile), forward and backward."""
+    q, k, v, do = _bhsd_case(jnp.float32, 2)
+    scale = float(1.0 / q.shape[-1] ** 0.5)
+    assert ft._tile_classes(4, 4, 64, 64, False, None, None) == {}
+
+    def both(offset):
+        o, lse = ft._fwd(q, k, v, scale, False, None, 64, 64, delta=offset)
+        return (o, lse) + tuple(ft._bwd(q, k, v, o, lse, do, scale, False,
+                                        None, 64, 64, offset=offset))
+
+    for name, got, want in zip(("o", "lse", "dq", "dk", "dv"), both(None),
+                               jax.jit(both)(jnp.int32(0))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("s,block,window,tiles,computed,ratio,by_class", [
+    # a window-1024 layer at 8192: edge tiles only, 8 diagonal + 7 window
+    (8192, 1024, 1024, (0, 8, 7, 0), 11.25, 1.50, True),
+    (8192, 1024, None, (28, 8, 0, 0), 34.0, 1.06, True),
+    (4096, 1024, None, (6, 4, 0, 0), 9.0, 1.12, True),
+    (4096, 1024, 4096, (6, 4, 0, 0), 9.0, 1.12, True),
+    # shapes that fall back compute every live tile whole, as before
+    (8192, 1024, 1000, (0, 0, 7, 8), 15.0, 2.04, False),
+    (4096, 1024, 640, (0, 0, 3, 4), 7.0, 3.04, False),
+    # one tile a sequence; a window shorter than the tile
+    (1024, 1024, None, (0, 1, 0, 0), 0.75, 1.50, True),
+    (4096, 1024, 512, (0, 0, 3, 4), 3.75, 2.00, True),
+])
+def test_tile_counts_arithmetic(monkeypatch, s, block, window, tiles,
+                                computed, ratio, by_class):
+    """`tile_counts`: a head's live tiles by class, the tiles' worth of
+    score elements the kernels compute and that over the visible pairs,
+    on hardware's rule for the half tile (a multiple of 128)."""
+    monkeypatch.setattr(ft, "_interpret", lambda: False)
+    got = ft.tile_counts(s, block, True, window)
+    assert (got["interior"], got["causal_edge"], got["window_edge"],
+            got["both"]) == tiles
+    assert got["tiles"] == sum(tiles) and got["by_class"] is by_class
+    assert got["tiles_computed"] == computed
+    assert round(got["computed_over_visible"], 2) == ratio
+    rows = np.arange(s)
+    visible = np.minimum(rows + 1, window or s).sum()
+    assert got["computed_over_visible"] == pytest.approx(
+        computed * block * block / visible)
+
+
+def test_a_half_tile_hardware_cannot_slice_falls_back(monkeypatch):
+    """Tiles of 128 (serving prefill buckets) have halves of 64: the
+    interpreter slices them, hardware runs the one masked body."""
+    assert ft._tile_classes(2, 2, 128, 128, True, None, None)
+    monkeypatch.setattr(ft, "_interpret", lambda: False)
+    assert ft._tile_classes(2, 2, 128, 128, True, None, None) is None
+    assert ft._tile_classes(2, 2, 256, 256, True, None, None)
+    assert ft._tile_classes(2, 2, 256, 512, True, None, None) is None

@@ -642,3 +642,25 @@ def test_the_step_record_carries_the_held_rows_share(rehearsed):
                 "flash_fwd_by_kind_roofline_pct",
                 "flash_bwd_by_kind_roofline_pct",
                 "moe_held_experts_roofline_pct"} & set(line["metrics"])
+
+
+def test_the_step_program_counts_each_kinds_tiles(rehearsed):
+    """The traced run's `step_program` record says, for each kind of
+    attention layer, how far the flash kernels' tile classes engage at
+    the step's shape (`flash_template.tile_counts`): the toy's sequence
+    of 128 is one tile, whose dead upper right quarter the full layer's
+    kernels skip (the interpreter slices any even tile); the window of 8
+    is no multiple of the half tile, so the sliding layers' run the one
+    masked body over the whole tile."""
+    _, _, journal = rehearsed
+    [program] = [r for r in journal if r.get("kind") == "step_program"]
+    tiles = program["attention_tiles"]
+    assert sorted(tiles) == ["full", "sliding"]
+    assert tiles["full"] == ft.tile_counts(128, 128, True, None)
+    assert tiles["full"]["by_class"] and tiles["full"]["causal_edge"] == 1
+    assert tiles["full"]["tiles_computed"] == 0.75
+    assert tiles["sliding"] == ft.tile_counts(128, 128, True, WINDOW)
+    assert not tiles["sliding"]["by_class"] and tiles["sliding"]["both"] == 1
+    assert tiles["sliding"]["tiles_computed"] == 1.0
+    assert (tiles["sliding"]["computed_over_visible"]
+            > 10 * tiles["full"]["computed_over_visible"])

@@ -558,3 +558,36 @@ def test_what_a_layer_keeps_of_the_flash_forward(monkeypatch, recompute,
                                        ((_B, h, _S, d), "float32")]
     else:
         assert from_kernel == []
+
+
+@pytest.mark.parametrize("window", [None, 8, 16, 24, 32, 48])
+@pytest.mark.parametrize("causal", [True, False])
+def test_tile_pieces_cover_what_the_mask_leaves(causal, window):
+    """`prefill_tile_pieces` against the dense mask, tiles of 16 (halves
+    of 8) at every distance under and over the diagonal: the pieces are
+    disjoint, hold every visible pair, hold no quarter without one, say
+    `masked` wherever they hold a hidden pair, exist exactly where
+    `prefill_block_live` admits the tile, and come smaller first."""
+    block, half = 16, 8
+    for dist in range(-4, 5):
+        qi, ki = max(dist, 0), max(-dist, 0)
+        q_pos = qi * block + np.arange(block)[:, None]
+        k_pos = ki * block + np.arange(block)[None, :]
+        dense = np.broadcast_to(np.asarray(masks.visible(
+            q_pos, k_pos, causal=causal, window=window)), (block, block))
+        pieces = masks.prefill_tile_pieces(dist, block, causal=causal,
+                                           window=window)
+        assert bool(pieces) == bool(masks.prefill_block_live(
+            qi, ki, block, block, causal=causal, window=window)), dist
+        covered = np.zeros_like(dense, dtype=np.int32)
+        for r0, nr, c0, nc, masked in pieces:
+            covered[r0:r0 + nr, c0:c0 + nc] += 1
+            part = dense[r0:r0 + nr, c0:c0 + nc]
+            assert masked == (not part.all()), (dist, r0, c0)
+            for r in range(0, nr, half):
+                for c in range(0, nc, half):
+                    assert part[r:r + half, c:c + half].any(), (dist, r, c)
+        assert covered.max(initial=0) <= 1
+        assert not (dense & (covered == 0)).any(), dist
+        areas = [nr * nc for _, nr, _, nc, _ in pieces]
+        assert areas == sorted(areas)
