@@ -59,6 +59,8 @@ def generate_and_post_process(
     engine=None,
     deadline_s=None,
     spec: bool = True,
+    request_id=None,
+    timing=None,
 ):
     """(texts, segments, logprobs, tokens) like the reference's
     generate_and_post_process (api.py:19-90). forward_fn plugs in the
@@ -70,7 +72,11 @@ def generate_and_post_process(
     past it the engine fails the request with RequestTimeoutError
     (HTTP 504) instead of leaving the caller waiting. spec=False pins
     the request to plain one-token-per-tick decode on a speculating
-    engine (no-op otherwise); greedy output is identical either way."""
+    engine (no-op otherwise); greedy output is identical either way.
+    request_id (engine path) names the prompts' requests in the journal;
+    timing, a dict, takes the engine's `engine_s` for them (engine.py
+    `generate`): what the server's `serve_reply` record sets against its
+    own time."""
     if tokens_to_generate < 0:
         raise ValueError("tokens_to_generate must be >= 0")
     prompt_tokens, lengths = tokenize_prompts(tokenizer, prompts,
@@ -97,7 +103,9 @@ def generate_and_post_process(
             prompt_tokens, lengths, max_new_tokens=tokens_to_generate,
             temperature=temperature, top_k=top_k_sampling,
             top_p=top_p_sampling, eod=tokenizer.eod, seed=random_seed,
-            deadline_s=deadline_s, spec=spec)
+            deadline_s=deadline_s, spec=spec, request_id=request_id)
+        if timing is not None:
+            timing["engine_s"] = out.engine_s
     else:
         out = generate_tokens(
             cfg, params, prompt_tokens, lengths,

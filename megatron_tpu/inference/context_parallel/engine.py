@@ -39,6 +39,7 @@ import numpy as np
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.context_parallel.pool import StripedPagePool
+from megatron_tpu.inference.engine import EVICT
 from megatron_tpu.inference.paging.engine import PagedInferenceEngine
 from megatron_tpu.inference.paging.pool import SCRATCH_PAGE
 from megatron_tpu.inference.paging.radix import RadixPrefixCache
@@ -110,7 +111,8 @@ class ContextParallelEngine(PagedInferenceEngine):
         # SAME refcount/scratch contract (nothing is allocated yet — the
         # base constructor only sized the pool)
         self.pool = StripedPagePool(self.num_pages, cp)
-        self.prefix_cache = RadixPrefixCache(self.pool, self.page_size)
+        self.prefix_cache = RadixPrefixCache(
+            self.pool, self.page_size, evict_span=self.timers(EVICT))
         self._m_pages_free.set(self.pool.free_pages)
 
         self._cp_bytes_for = {
@@ -192,7 +194,8 @@ class ContextParallelEngine(PagedInferenceEngine):
         detail (_overload_detail), so operators can tell striped-pool
         pressure from ordinary queue depth."""
         pages = self.pool.alloc(n, logical_start)
-        while pages is None and self.prefix_cache.evict(max(n, 1)) > 0:
+        while pages is None and self._note_evicted(
+                self.prefix_cache.evict(max(n, 1))) > 0:
             pages = self.pool.alloc(n, logical_start)
         if pages is not None:
             self._m_pages_free.set(self.pool.free_pages)
@@ -291,7 +294,7 @@ class ContextParallelEngine(PagedInferenceEngine):
         for r, free in enumerate(self.pool.free_pages_by_rank()):
             self._m_cp_shard_free.set(free, shard=str(r))
 
-    def step(self) -> int:
-        served = super().step()
+    def _tick(self) -> int:
+        served = super()._tick()
         self._set_shard_gauges()
         return served

@@ -29,9 +29,12 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
+import statistics
 import sys
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -47,6 +50,7 @@ from megatron_tpu.telemetry import journal as _journal
 from megatron_tpu.telemetry.metrics import MetricsRegistry, default_registry
 from megatron_tpu.telemetry.tracing import capture
 from megatron_tpu.training import resilience
+from megatron_tpu.training.timers import Timers
 
 #: flash_decode (ops/pallas/flash_decode.py) requires the cache length
 #: divisible by this; engines round max_seq_len UP to it on the TPU
@@ -58,6 +62,67 @@ KERNEL_SEQ_MULTIPLE = 128
 #: on-demand captures serialize here — a second /admin/profile while one
 #: is running answers 409 instead of corrupting the live session
 _PROFILE_LOCK = threading.Lock()
+
+
+#: The loop's spans, each a pair of the engine's `Timers`
+#: (training/timers.py): a `jax.profiler` annotation on the host plane of
+#: whatever capture is open, and the accumulator behind
+#: `engine_tick_phase_seconds_total{phase}`, `stats["tick_phase_s"]` and the
+#: journal's `phase_s` (docs/observability.md "The names in a trace"). A
+#: phase's seconds are its spans' OWN time, less the spans inside them, so
+#: the phases of a tick sum to it: a read inside a drain inside `tick-pages`
+#: is `read`'s. `tick-read` (_fetch) is the only one in which the loop waits
+#: for the device; a span around a dispatch measures the dispatch.
+TICK = "serve-tick"       # one step(); a step marker, step_num = its number
+PRE = "tick-pre"          # _pre_tick: faults, staged weights, deadlines
+ADMIT = "tick-admit"      # _admit: slots, page allocation, the radix match
+PREFILL = "tick-prefill"  # a chunk's (slot engine: a prompt's) preparation
+                          # and its dispatch
+PAGES = "tick-pages"      # window release, pages under the decode span
+PROPOSE = "tick-propose"  # the n-gram drafter's proposals
+DECODE = "tick-decode"    # carry, live-block gauge, dispatch, _start_fetch
+READ = "tick-read"        # _fetch: the wait for the device
+APPLY = "tick-apply"      # tokens to requests, _retire, the journal
+DRAIN = "tick-drain"      # _drain, in whichever phase needs true mirrors
+EVICT = "page-evict"      # RadixPrefixCache.evict
+PREEMPT = "page-preempt"  # _preempt_one
+LOOP = "tick-loop"        # between two steps of a running loop (credited,
+                          # no annotation: it is the gap between the ticks)
+_PHASE_OF = {TICK: "other", PRE: "pre", ADMIT: "admit", PREFILL: "prefill",
+             PAGES: "pages", PROPOSE: "propose", DECODE: "decode",
+             READ: "read", APPLY: "apply", DRAIN: "drain", EVICT: "evict",
+             PREEMPT: "preempt", LOOP: "loop"}
+
+#: a tick is slow when it took longer than both of these: seconds, and a
+#: multiple of the median of the last SLOW_TICK_HISTORY ticks
+SLOW_TICK_S = 0.25
+SLOW_TICK_OVER_MEDIAN = 8.0
+SLOW_TICK_HISTORY = 256
+
+
+class _GcWatch:
+    """Seconds the collector paused the process, by `gc.callbacks` (a
+    pause holds the interpreter lock, so it stops the loop whichever
+    thread set it off). Registered only while a journal is set: the
+    journal's `serve_slow_tick` is its one reader."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.watching = False
+        self._t0 = 0.0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        else:
+            self.seconds += time.perf_counter() - self._t0
+
+    def watch(self, on: bool) -> None:
+        if on and not self.watching:
+            gc.callbacks.append(self)
+        elif not on and self.watching:
+            gc.callbacks.remove(self)
+        self.watching = on
 
 
 class EngineOverloadedError(RuntimeError):
@@ -111,9 +176,21 @@ class Request:
     prompt_logprobs: List[float] = dataclasses.field(default_factory=list)
     done: threading.Event = dataclasses.field(default_factory=threading.Event)
     error: Optional[str] = None
-    # latency telemetry (monotonic clock): stamped by submit()/admission
+    # the request's name in the journal (`serve_request`, the server's
+    # `serve_reply`): the client's X-Request-Id, else the server's own;
+    # `<id>/<k>` for the k-th prompt of a request that carries several
+    id: Optional[str] = None
+    # latency telemetry (monotonic clock), where the request changes
+    # hands: submit(); the slot (the last assignment before the first
+    # token: a request preempted mid-prefill waits again, one preempted
+    # later keeps its stamps); its first token READ; _finish()
     submit_time: Optional[float] = None
+    slot_time: Optional[float] = None
     first_token_time: Optional[float] = None
+    finish_time: Optional[float] = None
+    chunks: int = 0          # prefill programs run for it, every life
+    prefix_tokens: int = 0   # positions the radix cache saved it
+    preemptions: int = 0
 
     @property
     def tokens(self) -> np.ndarray:
@@ -124,6 +201,8 @@ class Request:
 
     def _finish(self, error: Optional[str] = None):
         self.error = error
+        if self.finish_time is None:
+            self.finish_time = time.monotonic()
         self.done.set()
 
 
@@ -282,6 +361,19 @@ class InferenceEngine:
         self._carry_dirty = False
         self._step_no = 0
         self._last_read_time = 0.0
+        # the loop's spans and their own seconds (the names above); what a
+        # tick's end has booked of them so far; when the last tick ended
+        # (None after a park: an idle loop's wait is no tick's time); the
+        # last ticks' durations, for the median a slow tick is held to;
+        # the causes of this tick's drains; the collector's pauses
+        self.timers = Timers()
+        self._phase_seen: Dict[str, float] = {}
+        self._tick_end: Optional[float] = None
+        self._tick_walls: deque = deque(maxlen=SLOW_TICK_HISTORY)
+        self._tick_drains: List[str] = []
+        self._gc = _GcWatch()
+        self._gc_seen = 0.0
+        weakref.finalize(self, self._gc.watch, False)
         # hot weight reload: (params, version, applied_event) staged by
         # update_params(), swapped in BETWEEN decode ticks by the step
         # loop so in-flight slots never see a mid-tick change
@@ -320,7 +412,9 @@ class InferenceEngine:
                       "kv_exports": 0, "kv_imports": 0,
                       "decode_live_block_share": 0.0,
                       "ticks_dispatched_ahead": 0, "tick_drains": {},
-                      "tokens_dropped_after_eod": 0}
+                      "tokens_dropped_after_eod": 0,
+                      "tick_phase_s": {}, "decode_rows": 0,
+                      "slow_ticks": 0}
         if self.spec is not None:
             # spec_emitted counts every token the spec path emitted
             # (accepted drafts + the guaranteed token per row per tick);
@@ -386,6 +480,15 @@ class InferenceEngine:
             "token, dropped at the read")
         self._m_tick = m.histogram("engine_decode_tick_seconds",
                                    "batched decode tick wall time")
+        self._m_phase = m.counter(
+            "engine_tick_phase_seconds_total",
+            "the loop thread's time by phase of the tick (own time: a "
+            "phase's spans less the spans inside them); `read` is the wait "
+            "for the device", label_names=("phase",))
+        self._m_rows = m.counter(
+            "engine_decode_rows_total",
+            "decoding rows summed over the ticks read (over "
+            "engine_ticks_total: the mean decoding batch)")
         self._m_spec_proposed = m.counter(
             "engine_spec_proposed_total",
             "draft tokens proposed to the speculative verify step")
@@ -832,6 +935,7 @@ class InferenceEngine:
         # sort) live for every remaining tick. The zeroed row goes up
         # before the next dispatch, with no drain: a retirement happens at
         # a read, often with the next tick already in flight
+        req.finish_time = time.monotonic()
         self._journal_request(req, "ok")
         req._finish()
 
@@ -845,7 +949,8 @@ class InferenceEngine:
         Events that edit lengths and knobs alone mark _carry_dirty."""
         self._drain(cause)
         if self._carry is not None:
-            self.keys = np.array(self._carry[2])
+            with self.timers(READ):
+                self.keys = np.array(self._carry[2])
             self._carry = None
 
     def _drain(self, cause: str) -> int:
@@ -859,10 +964,16 @@ class InferenceEngine:
         by = self.stats["tick_drains"]
         by[cause] = by.get(cause, 0) + 1
         self._m_drains.inc(cause=cause)
+        self._tick_drains.append(cause)
         n = 0
-        while self._inflight:
-            self._read(self._inflight.popleft())
-            n += 1
+        span = self.timers(DRAIN)
+        span.start(cause=cause)
+        try:
+            while self._inflight:
+                self._read(self._inflight.popleft())
+                n += 1
+        finally:
+            span.stop()
         return n
 
     def _read_behind(self, dispatched: int) -> int:
@@ -917,6 +1028,8 @@ class InferenceEngine:
         # a whole-prompt prefill reads its first token at once and writes
         # the mirrors: an admission drains (the paged engine's does not)
         self._sync_carry("admission")
+        if req.first_token_time is None:
+            req.slot_time = time.monotonic()
         resumed = req.resume_key is not None or bool(req.generated)
         full = (np.concatenate([np.asarray(req.prompt, np.int32),
                                 np.asarray(req.generated, np.int32)])
@@ -930,18 +1043,23 @@ class InferenceEngine:
                 else jax.random.PRNGKey(req.seed))
         t_prefill = time.monotonic()
         try:
-            tok, lp, plp, caches, key = self._prefill_step(P)(
-                self.params, self.caches, jnp.asarray(toks),
-                jnp.int32(p), jnp.int32(i), key0,
-                jnp.float32(req.temperature), jnp.int32(req.top_k),
-                jnp.float32(req.top_p))
-            self.caches = caches
-            if self._has_draft_model():
-                # mirror the prompt into the draft model's cache tree so
-                # the first speculative tick proposes with full context
-                self.draft_caches = self._draft_prefill_step(P)(
-                    self.draft_params, self.draft_caches,
-                    jnp.asarray(toks), jnp.int32(i))
+            with self.timers(PREFILL):
+                tok, lp, plp, caches, key = self._prefill_step(P)(
+                    self.params, self.caches, jnp.asarray(toks),
+                    jnp.int32(p), jnp.int32(i), key0,
+                    jnp.float32(req.temperature), jnp.int32(req.top_k),
+                    jnp.float32(req.top_p))
+                self.caches = caches
+                if self._has_draft_model():
+                    # mirror the prompt into the draft model's cache tree
+                    # so the first speculative tick proposes with full
+                    # context
+                    self.draft_caches = self._draft_prefill_step(P)(
+                        self.draft_params, self.draft_caches,
+                        jnp.asarray(toks), jnp.int32(i))
+            with self.timers(READ):
+                # a whole-prompt prefill is read at once
+                tok, lp, plp, key = jax.device_get((tok, lp, plp, key))
         except Exception as e:  # noqa: BLE001 - a failing prefill
             # (fresh-bucket compile OOM etc.) must fail THIS request,
             # not strand it un-signalled and kill the step loop
@@ -961,6 +1079,7 @@ class InferenceEngine:
                 self._rebuild_caches()
                 self._m_active.set(self.num_active)
             return 0
+        req.chunks += 1
         self.slots[i] = req
         self.lengths[i] = p
         self.last_tok[i] = int(tok)
@@ -999,23 +1118,100 @@ class InferenceEngine:
                     and req.generated[-1] == req.eod))
 
     def step(self) -> int:
-        """One engine tick: admit into free slots, dispatch one batched
-        decode for every active slot, then read the tick before it (the
-        loop runs one tick ahead of the device). Returns the number of
-        active slots served, or what a step with nothing to dispatch read
-        (0 = idle, and nothing in flight)."""
-        self._pre_tick()
-        self._admit()
-        return self._read_behind(self._decode_tick())
+        """One engine tick, a `serve-tick` span around the phases of
+        `_tick` (the names at the top of this file). Returns the number
+        of active slots served (the paged engine: + chunks run), or what
+        a step with nothing to dispatch read (0 = idle, and nothing in
+        flight)."""
+        self._step_no += 1
+        tick = self.timers(TICK)
+        tick.start(step_num=self._step_no)
+        try:
+            return self._tick()
+        finally:
+            tick.stop()
+            self._end_tick()
+
+    def _tick(self) -> int:
+        """Admit into free slots, dispatch one batched decode for every
+        active slot, then read the tick before it (the loop runs one tick
+        ahead of the device)."""
+        with self.timers(PRE):
+            self._pre_tick()
+        with self.timers(ADMIT):
+            self._admit()
+        return self._read_behind(self._decode_phase())
+
+    def _decode_phase(self) -> int:
+        """`tick-decode` around _decode_tick. A speculating engine's tick
+        is read where it is dispatched (its `tick-read` and `tick-apply`
+        lie inside this span), and the span says so."""
+        span = self.timers(DECODE)
+        if self.spec is None:
+            span.start()
+        else:
+            span.start(synchronous=1)
+        try:
+            return self._decode_tick()
+        finally:
+            span.stop()
+
+    def _loop_gap(self) -> None:
+        """Called by whoever steps in a loop, before a step: the time
+        since the last step's end was the loop's own (its lock, its
+        checks, a wait for the interpreter lock), and is booked as the
+        phase `loop`. A park sets `_tick_end` None: idle is no phase."""
+        if self._tick_end is not None:
+            self.timers.record(LOOP, time.perf_counter() - self._tick_end)
+
+    def _end_tick(self) -> None:
+        """Book the tick that just ended: its phases' own seconds into the
+        counters, and a `serve_slow_tick` into the journal if it took both
+        SLOW_TICK_S and SLOW_TICK_OVER_MEDIAN times the median of the last
+        ticks (its time runs from the last tick's end in a running loop:
+        read to read)."""
+        self._tick_end = time.perf_counter()
+        seen, total = self._phase_seen, self.stats["tick_phase_s"]
+        phases = {}
+        for name, own in self.timers.own_s().items():
+            took = own - seen.get(name, 0.0)
+            if took > 0.0:
+                seen[name] = own
+                phase = _PHASE_OF[name]
+                phases[phase] = took
+                total[phase] = total.get(phase, 0.0) + took
+                self._m_phase.inc(took, phase=phase)
+        drains, self._tick_drains = self._tick_drains, []
+        j = _journal.get_global_journal()
+        self._gc.watch(j is not None)
+        wall = sum(phases.values())
+        walls = self._tick_walls
+        if (wall > SLOW_TICK_S and len(walls) >= 16
+                and wall > SLOW_TICK_OVER_MEDIAN * statistics.median(walls)):
+            self.stats["slow_ticks"] += 1
+            if j is not None:
+                with self._cv:
+                    queue = len(self._queue)
+                j.emit("serve_slow_tick", tick=self._step_no,
+                       wall_s=round(wall, 6),
+                       phase_s={k: round(v, 6) for k, v in phases.items()},
+                       active=self.num_active, queue=queue, drains=drains,
+                       gc_s=round(self._gc.seconds - self._gc_seen, 6),
+                       **self._slow_tick_fields())
+        self._gc_seen = self._gc.seconds
+        walls.append(wall)
+
+    def _slow_tick_fields(self) -> dict:
+        """What else a `serve_slow_tick` holds (the paged engine: its
+        pool's free pages)."""
+        return {}
 
     def _pre_tick(self) -> None:
-        """Per-tick control-plane work shared by every engine subclass
-        (the paged engine overrides step() and MUST call this first):
+        """Per-tick control-plane work shared by every engine subclass:
         serving fault injection (MEGATRON_TPU_FAULT, tick-indexed — a
         SIGKILLed/hung/slowed replica at a deterministic decode tick, so
         the router's failover paths are testable on CPU), staged weight
         swaps, and deadline expiry."""
-        self._step_no += 1
         # ticks are counted at their read: the tick about to be
         # dispatched is the one after those read and those in flight
         tick = self.stats["ticks"] + sum(
@@ -1154,27 +1350,44 @@ class InferenceEngine:
         j = _journal.get_global_journal()
         if j is None:
             return
-        now = time.monotonic()
-        fields = {"status": status, "prompt_len": len(req.prompt),
-                  "new_tokens": len(req.generated)}
+        now = req.finish_time or time.monotonic()
+        fields = {"id": req.id, "status": status,
+                  "prompt_len": len(req.prompt),
+                  "new_tokens": len(req.generated), "chunks": req.chunks,
+                  "prefix_tokens": req.prefix_tokens,
+                  "preemptions": req.preemptions}
         if req.submit_time is not None:
+            # where the request's time went, stamp to stamp: queue_s +
+            # prefill_s = ttft_s, and ttft_s + (new_tokens - 1) * tpot_s
+            # = wall_s (tpot_s keeps the digits that product needs)
             fields["wall_s"] = round(now - req.submit_time, 6)
+            if req.slot_time is not None:
+                fields["queue_s"] = round(req.slot_time - req.submit_time, 6)
             if req.first_token_time is not None:
                 fields["ttft_s"] = round(
                     req.first_token_time - req.submit_time, 6)
+                if req.slot_time is not None:
+                    fields["prefill_s"] = round(
+                        req.first_token_time - req.slot_time, 6)
                 if len(req.generated) > 1:
                     fields["tpot_s"] = round(
                         (now - req.first_token_time)
-                        / (len(req.generated) - 1), 6)
+                        / (len(req.generated) - 1), 9)
         j.emit("serve_request", **fields)
-        # cumulative counters of the loop's one tick of lookahead, one
-        # snapshot per retired request like serve_spec below: a reader
-        # takes the LAST one (ahead / ticks is the share of decode ticks
-        # that were in the queue before the one before them was read)
+        # cumulative counters of the loop, one snapshot per retired request
+        # like serve_spec below: a reader takes the LAST one, or the
+        # difference of two (ahead / ticks is the share of decode ticks
+        # that were in the queue before the one before them was read;
+        # rows / ticks the mean decoding batch; phase_s where the loop
+        # thread's time went, as of the last tick that ended)
         j.emit("serve_ticks", ticks=self.stats["ticks"],
                ahead=self.stats["ticks_dispatched_ahead"],
                drains=dict(self.stats["tick_drains"]),
-               dropped_after_eod=self.stats["tokens_dropped_after_eod"])
+               dropped_after_eod=self.stats["tokens_dropped_after_eod"],
+               rows=self.stats["decode_rows"],
+               phase_s={k: round(v, 6)
+                        for k, v in self.stats["tick_phase_s"].items()},
+               **self._serve_ticks_fields())
         if self.spec is not None:
             # cumulative speculative counters, one snapshot per retired
             # request (like goodput's cumulative records): the report
@@ -1185,6 +1398,11 @@ class InferenceEngine:
                    emitted=self.stats["spec_emitted"],
                    ticks=self.stats["ticks"], k=self.spec.k,
                    drafter=self.spec.drafter)
+
+    def _serve_ticks_fields(self) -> dict:
+        """What else a `serve_ticks` snapshot holds (the paged engine: the
+        pages its prefix cache gave back)."""
+        return {}
 
     def _journal_comm_policy(self) -> None:
         """One `comm_policy` record per engine build: which collectives
@@ -1371,26 +1589,37 @@ class InferenceEngine:
         tail = (last, lens, keys, temps, top_ks, top_ps,
                 self._spec_rows_arg())
         if spec.drafter == "ngram":
-            tail += (self._commit(jnp.asarray(self._propose_ngram())),)
+            with self.timers(PROPOSE):
+                drafts = self._propose_ngram()
+            tail += (self._commit(jnp.asarray(drafts)),)
         t_tick = time.monotonic()
         try:
             out = self._spec_step(*pre, *tail)
+            if self._has_draft_model():
+                (toks, lps, accepts, caches, dcaches, keys, lens,
+                 last) = out
+                self.draft_caches = dcaches
+            else:
+                toks, lps, accepts, caches, keys, lens, last = out
+            self.caches = caches
+            self._carry = (last, lens, keys, temps, top_ks, top_ps)
+            with self.timers(READ):
+                # the speculative tick is read where it is dispatched
+                toks, lps, accepts = jax.device_get((toks, lps, accepts))
         except Exception as e:  # noqa: BLE001 - shared recovery, then
             # surface the error to the driver
             self._fail_decode(active, e)
             raise
-        if self._has_draft_model():
-            (toks, lps, accepts, caches, dcaches, keys, lens, last) = out
-            self.draft_caches = dcaches
-        else:
-            toks, lps, accepts, caches, keys, lens, last = out
-        self.caches = caches
-        self._carry = (last, lens, keys, temps, top_ks, top_ps)
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
-        accepts = np.asarray(accepts)
+        with self.timers(APPLY):
+            return self._apply_spec(active, toks, lps, accepts, t_tick)
+
+    def _apply_spec(self, active, toks, lps, accepts, t_tick) -> int:
+        """A speculative tick's tokens to their requests."""
+        spec = self.spec
         self.stats["ticks"] += 1
+        self.stats["decode_rows"] += len(active)
         self._m_ticks.inc()
+        self._m_rows.inc(len(active))
         self._m_tick.observe(time.monotonic() - t_tick)
         self._track_decode_recompiles()
         if self.flight_recorder is not None:
@@ -1477,7 +1706,8 @@ class InferenceEngine:
         """A program's results on the host, in one fetch. This is where
         the loop waits for the device, and where a failed step surfaces."""
         try:
-            return jax.device_get(rec.out)
+            with self.timers(READ):
+                return jax.device_get(rec.out)
         except Exception as e:  # noqa: BLE001 - shared recovery, then
             # surface the error to the driver
             self._inflight.appendleft(rec)  # its requests fail with the rest
@@ -1492,9 +1722,17 @@ class InferenceEngine:
         eod needs the token), and that tick's result for it is dropped
         here; its one extra KV position lies in a page the row owned."""
         toks, lps = self._fetch(rec)
+        with self.timers(APPLY):
+            self._apply(rec, toks, lps)
+
+    def _apply(self, rec: _InFlight, toks, lps) -> None:
+        """A read tick's tokens to their requests."""
+        rec.out = None  # the device's copies go here, not between phases
         now = time.monotonic()
         self.stats["ticks"] += 1
+        self.stats["decode_rows"] += len(rec.rows)
         self._m_ticks.inc()
+        self._m_rows.inc(len(rec.rows))
         if rec.ahead:
             self.stats["ticks_dispatched_ahead"] += 1
             self._m_ahead.inc()
@@ -1965,8 +2203,10 @@ class InferenceEngine:
         tests, benches, batch jobs). Returns with nothing in flight: a
         step that finds nothing to dispatch reads what is, and is not 0
         until that is nothing."""
+        self._tick_end = None
         with self._mesh_scope():
             while True:
+                self._loop_gap()
                 served = self.step()
                 with self._cv:
                     if served == 0 and not self._queue:
@@ -1977,7 +2217,8 @@ class InferenceEngine:
                  top_k: int = 0, top_p: float = 0.0,
                  eod: Optional[int] = None, seed: int = 0,
                  deadline_s: Optional[float] = None,
-                 spec: bool = True
+                 spec: bool = True,
+                 request_id: Optional[str] = None,
                  ) -> GenerationOutput:
         """Batch convenience with generate_tokens' semantics: submit one
         request per row, drain, and repack [B, maxp+max_new] (rows padded
@@ -1985,7 +2226,10 @@ class InferenceEngine:
         row of a ragged batch to maxp + max_new_tokens, so shorter
         prompts get the difference as extra generated tokens — matched
         here so flipping a server between engine and one-shot mode never
-        changes a response."""
+        changes a response. `request_id` names the rows' requests in the
+        journal (`<id>/<k>` where there are several); the output's
+        `engine_s` runs from the first row's submit to the last one's
+        end."""
         B, maxp = prompts.shape
         reqs = []
         # the queue-capacity check and the B submits happen under ONE
@@ -2013,7 +2257,9 @@ class InferenceEngine:
                     max_new_tokens=maxp - p + max_new_tokens,
                     temperature=temperature, deadline_s=deadline_s,
                     top_k=top_k, top_p=top_p, eod=eod, seed=seed + b,
-                    spec=spec)))
+                    spec=spec,
+                    id=(request_id if B == 1 or request_id is None
+                        else f"{request_id}/{b}"))))
         if self._thread is None:
             self.run_until_idle()
         for r in reqs:
@@ -2041,7 +2287,10 @@ class InferenceEngine:
             lp[b, :len(r.prompt_logprobs)] = r.prompt_logprobs
             gen0 = int(lengths[b]) - 1  # logprob row index of first token
             lp[b, gen0:gen0 + len(r.logprobs)] = r.logprobs
-        return GenerationOutput(tokens=tokens, lengths=ends, logprobs=lp)
+        return GenerationOutput(
+            tokens=tokens, lengths=ends, logprobs=lp,
+            engine_s=(max(r.finish_time for r in reqs)
+                      - min(r.submit_time for r in reqs)))
 
     # ----- background thread (HTTP serving) --------------------------------
 
@@ -2064,6 +2313,7 @@ class InferenceEngine:
                                     or (self.num_active == 0
                                         and not self._queue
                                         and self._pending_params is None))):
+                            self._tick_end = None  # a park is no tick's
                             if self._pause_count > 0:
                                 # state-migration pause: park between
                                 # ticks and tell the pauser slot state is
@@ -2088,6 +2338,7 @@ class InferenceEngine:
                         if stop or park:
                             self._drain("stop" if stop else "pause")
                         else:
+                            self._loop_gap()
                             self.step()
                     except Exception as e:  # noqa: BLE001 - step() has
                         # already failed the affected requests; the loop
@@ -2123,6 +2374,7 @@ class InferenceEngine:
             raise RuntimeError(
                 "inference-engine step loop did not stop within 30s")
         self._thread = None
+        self._gc.watch(False)
         self._drop_inflight()  # read by the loop on its way out; a failure
         # there leaves nothing either
         with self._cv:

@@ -33,6 +33,9 @@ class GenerationOutput:
     tokens: np.ndarray       # [B, total_len] int32 (prompt + generated)
     lengths: np.ndarray      # [B] generated sequence end (index past last)
     logprobs: np.ndarray     # [B, total_len-1] logprob of each emitted token
+    # the serving engine's alone: seconds from the first row's submit to
+    # the last row's retirement, on the engine's clock
+    engine_s: Optional[float] = None
 
 
 def _default_fwd(cfg):

@@ -37,7 +37,8 @@ import numpy as np
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.engine import (
-    InferenceEngine, Request, _InFlight,
+    ADMIT, APPLY, EVICT, PAGES, PRE, PREEMPT, PREFILL, InferenceEngine,
+    Request, _InFlight,
 )
 from megatron_tpu.inference.paging.pool import SCRATCH_PAGE, PagePool
 from megatron_tpu.inference.paging.radix import RadixPrefixCache
@@ -95,7 +96,8 @@ class PagedInferenceEngine(InferenceEngine):
 
         N = num_slots
         self.pool = PagePool(self.num_pages)
-        self.prefix_cache = RadixPrefixCache(self.pool, self.page_size)
+        self.prefix_cache = RadixPrefixCache(
+            self.pool, self.page_size, evict_span=self.timers(EVICT))
         # host page tables: tables[i] is slot i's logical->physical map.
         # Mid-prefill slots keep their REAL row in _pending_rows and a
         # scratch row here, so the shared decode table can never route an
@@ -128,7 +130,7 @@ class PagedInferenceEngine(InferenceEngine):
             "prefix_hits": 0, "prefix_misses": 0,
             "prefix_tokens_saved": 0, "prefill_tokens": 0,
             "prefill_chunks": 0, "preemptions": 0,
-            "window_pages_released": 0,
+            "window_pages_released": 0, "pages_evicted": 0,
         })
         m = self.metrics
         self._m_pages_total = m.gauge("engine_pages_total",
@@ -152,8 +154,9 @@ class PagedInferenceEngine(InferenceEngine):
         self._m_window_released = m.counter(
             "engine_window_pages_released_total",
             "pages freed from behind the sliding attention window")
-        self._m_chunk = m.histogram("engine_prefill_chunk_seconds",
-                                    "one prefill chunk's wall time")
+        self._m_evicted = m.counter(
+            "engine_pages_evicted_total",
+            "cache-only prefix pages the radix tree gave back to the pool")
         self._m_pages_total.set(self.num_pages - 1)
         self._m_pages_free.set(self.pool.free_pages)
         # a model with state-space layers: self.state (_fresh_caches) is
@@ -393,11 +396,24 @@ class PagedInferenceEngine(InferenceEngine):
         slot (inference/context_parallel/pool.py)."""
         pages = self.pool.alloc(n)
         if pages is None:
-            self.prefix_cache.evict(n - self.pool.free_pages)
+            self._note_evicted(
+                self.prefix_cache.evict(n - self.pool.free_pages))
             pages = self.pool.alloc(n)
         if pages is not None:
             self._m_pages_free.set(self.pool.free_pages)
         return pages
+
+    def _note_evicted(self, freed: int) -> int:
+        if freed:
+            self.stats["pages_evicted"] += freed
+            self._m_evicted.inc(freed)
+        return freed
+
+    def _serve_ticks_fields(self) -> dict:
+        return {"evicted": self.stats["pages_evicted"]}
+
+    def _slow_tick_fields(self) -> dict:
+        return {"pages_free": self.pool.free_pages}
 
     def _release_slot_pages(self, i: int) -> None:
         row = self._pending_rows.pop(i, self.tables[i])
@@ -499,6 +515,8 @@ class PagedInferenceEngine(InferenceEngine):
             self.stats["state_resets"] += 1
             self._m_state_resets.inc()
         self.slots[i] = req
+        if req.first_token_time is None:
+            req.slot_time = time.monotonic()
         self._admit_counter += 1
         self._admit_seq[i] = self._admit_counter
 
@@ -521,6 +539,7 @@ class PagedInferenceEngine(InferenceEngine):
         self.prefill_queue.add(task)
 
         if span > 0:
+            req.prefix_tokens += start
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_saved"] += start
             self._m_prefix_hits.inc()
@@ -554,7 +573,6 @@ class PagedInferenceEngine(InferenceEngine):
         avail = task.tokens[off:off + C + 1]
         toks_ext[0, :len(avail)] = avail
         row = self._pending_rows[i]
-        t0 = time.monotonic()
         try:
             tok, lp, plp, self.caches, self.state, key = self._chunk_step(
                 self.params, self.caches, self.state,
@@ -594,11 +612,11 @@ class PagedInferenceEngine(InferenceEngine):
         n = min(C, task.total - off)
         if self.want_logprobs:
             task.plp_parts.append(plp)  # the device's, until the last chunk
+        req.chunks += 1
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_tokens"] += n
         self._count_comm(self._comm_chunk_bytes)
         self._m_chunks.inc()
-        self._m_chunk.observe(time.monotonic() - t0)
         if self.flight_recorder is not None:
             self.flight_recorder.heartbeat(
                 f"prefill chunk slot {i} ({off}+{n}/{task.total})")
@@ -678,9 +696,14 @@ class PagedInferenceEngine(InferenceEngine):
         """Read a finished prompt's first token: record it and the
         prompt's logprobs, and register the prompt's full pages in the
         radix tree."""
+        tok, lp, plps = self._fetch(rec)
+        with self.timers(APPLY):
+            self._apply_first(rec, tok, lp, plps)
+
+    def _apply_first(self, rec: _InFlight, tok, lp, plps) -> None:
         (i, req), = rec.rows
         task = rec.task
-        tok, lp, plps = self._fetch(rec)
+        rec.out = None  # the device's copies go here, not between phases
         if self.slots[i] is not req:   # the rule of every row in flight
             self.pool.release(rec.pinned)
             return
@@ -725,6 +748,10 @@ class PagedInferenceEngine(InferenceEngine):
         """Preempt the youngest active slot (LIFO — later arrivals yield
         pages to earlier ones). Its request re-enters the queue FRONT and
         resumes by exact teacher-forced recompute."""
+        with self.timers(PREEMPT):
+            return self._preempt_youngest()
+
+    def _preempt_youngest(self) -> bool:
         # the chain to keep is the device's, and what is in flight may
         # end a request: read it before choosing
         self._sync_carry("pages")
@@ -733,6 +760,7 @@ class PagedInferenceEngine(InferenceEngine):
             return False
         i = max(cands, key=lambda j: self._admit_seq[j])
         req = self.slots[i]
+        req.preemptions += 1
         if i not in self.prefill_queue.slots:
             # mid-decode: preserve the PRNG chain so the resumed request
             # samples exactly the tokens it would have sampled
@@ -850,7 +878,7 @@ class PagedInferenceEngine(InferenceEngine):
             self._m_window_released.inc(freed)
             self._m_pages_free.set(self.pool.free_pages)
 
-    def step(self) -> int:
+    def _tick(self) -> int:
         """One engine tick: admit, dispatch one prefill chunk and one
         batched decode for every slot whose prompt is fully cached, then
         read the tick before (the loop runs one tick ahead of the device:
@@ -858,17 +886,21 @@ class PagedInferenceEngine(InferenceEngine):
         happens while the device runs the last tick). Returns slots
         served + chunks run, or what a step with nothing to dispatch
         read (0 = idle, and nothing in flight)."""
-        self._pre_tick()  # faults, staged weight swaps, deadline expiry
-        self._admit()
-        chunked = self._prefill_tick()
-        if chunked:
-            # chunked prefill with no decodable slots is still progress —
-            # without this a long multi-chunk prompt would trip the
-            # stalled() readiness check while prefilling normally
-            self.last_progress_time = time.monotonic()
-        self._release_window_pages()
-        self._ensure_decode_pages()
-        return self._read_behind(self._decode_tick() + chunked)
+        with self.timers(PRE):
+            self._pre_tick()  # faults, staged weight swaps, deadlines
+        with self.timers(ADMIT):
+            self._admit()
+        with self.timers(PREFILL):
+            chunked = self._prefill_tick()
+            if chunked:
+                # chunked prefill with no decodable slots is still progress
+                # — without this a long multi-chunk prompt would trip the
+                # stalled() readiness check while prefilling normally
+                self.last_progress_time = time.monotonic()
+        with self.timers(PAGES):
+            self._release_window_pages()
+            self._ensure_decode_pages()
+        return self._read_behind(self._decode_phase() + chunked)
 
     def _retire(self, i: int):
         # base _retire -> _clear_slot releases this slot's page refs;

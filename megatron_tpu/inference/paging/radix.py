@@ -55,9 +55,13 @@ class _Node:
 
 
 class RadixPrefixCache:
-    def __init__(self, pool: PagePool, page_size: int):
+    def __init__(self, pool: PagePool, page_size: int, evict_span=None):
+        """evict_span: the engine's `page-evict` timer (training/timers.py:
+        a span of the trace and the accumulator of the loop's `evict`
+        phase) around every evict(); None for a tree nobody times."""
         self.pool = pool
         self.page_size = int(page_size)
+        self._evict_span = evict_span
         self._children: Dict[Tuple[int, ...], _Node] = {}  # root level
         self._clock = 0
         self._nodes = 0
@@ -145,6 +149,17 @@ class RadixPrefixCache:
         because freeing a leaf can expose its parent as an OLDER
         candidate than the next stale leaf. Returns how many pages were
         actually freed."""
+        if self._evict_span is None:
+            return self._evict(n_pages)
+        freed = 0
+        self._evict_span.start(asked=n_pages)
+        try:
+            freed = self._evict(n_pages)
+            return freed
+        finally:
+            self._evict_span.stop(freed=freed)
+
+    def _evict(self, n_pages: int) -> int:
         freed = 0
         while freed < n_pages:
             cands = self._evictable()

@@ -80,6 +80,7 @@ import signal
 import sys
 import threading
 import time
+import uuid
 
 import jax
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -104,6 +105,9 @@ MAX_PROMPTS = 128
 #: Retry-After hint on 503 queue-full rejections: one decode tick's
 #: worth of backoff is enough for a slot to free in steady traffic
 RETRY_AFTER_SECONDS = 1
+#: a request's name: taken from the client where it sends one, made here
+#: otherwise, echoed on the reply (docs/serving.md "A request's time")
+REQUEST_ID_HEADER = "X-Request-Id"
 #: engine progress-stall window before readiness flips (a hung device
 #: step keeps the thread "alive" — only lack of progress reveals it)
 STALL_THRESHOLD_SECONDS = 10.0
@@ -834,7 +838,12 @@ class GenerationService:
         return min(self.engines,
                    key=lambda e: e.num_active + len(e._queue))
 
-    def handle(self, req: dict) -> dict:
+    def handle(self, req: dict, request_id: Optional[str] = None,
+               timing: Optional[dict] = None) -> dict:
+        """One generation request. `request_id` names its prompts in the
+        engine's journal records; `timing`, a dict, takes what the
+        handler's `serve_reply` record wants of it (`prompts`, and
+        `engine_s` where the engine served it)."""
         if self.draining:
             raise ServiceDrainingError(
                 "server is draining; retry (the fleet router re-routes "
@@ -846,6 +855,8 @@ class GenerationService:
             raise ValueError(f"at most {MAX_PROMPTS} prompts per request")
         if not all(isinstance(p, str) and p for p in prompts):
             raise ValueError("prompts must be non-empty strings")
+        if timing is not None:
+            timing["prompts"] = len(prompts)
         n = int(req.get("tokens_to_generate", 64))
         if not 0 <= n <= MAX_TOKENS_TO_GENERATE:
             raise ValueError(f"tokens_to_generate in [0, {MAX_TOKENS_TO_GENERATE}]")
@@ -913,7 +924,7 @@ class GenerationService:
                 kv_cache_int8=self.kv_cache_int8,
                 engine=engine,
                 deadline_s=deadline_s if use_engine else None,
-                spec=spec)
+                spec=spec, request_id=request_id, timing=timing)
             out = {"text": texts, "segments": segments}
             if logprobs is not None:
                 out["logprobs"] = [list(map(float, row)) for row in logprobs]
@@ -933,11 +944,21 @@ class GenerationService:
 
 def make_handler(service: GenerationService):
     class Handler(BaseHTTPRequestHandler):
+        _request_id: Optional[str] = None  # set for the generation API
+        _t_first = 0.0
+
+        def parse_request(self) -> bool:
+            # the request line has been read: the request's first bytes
+            self._t_first = time.monotonic()
+            return super().parse_request()
+
         def _reply(self, code: int, payload: dict, headers=()):
             body = json.dumps(payload).encode()
             self.send_response(code)
             self.send_header("Content-Type", "application/json")
             self.send_header("Content-Length", str(len(body)))
+            if self._request_id is not None:
+                self.send_header(REQUEST_ID_HEADER, self._request_id)
             for name, value in headers:
                 self.send_header(name, value)
             self.end_headers()
@@ -967,9 +988,16 @@ def make_handler(service: GenerationService):
             # pre-fleet server accepted any path, kept for compatibility)
             t0 = time.monotonic()
             status = "500"
+            # the request's name, from socket to socket: the client's own
+            # where it sent one, echoed on the reply, on the engine's
+            # `serve_request` records and on this handler's `serve_reply`
+            self._request_id = (self.headers.get(REQUEST_ID_HEADER)
+                                or uuid.uuid4().hex)
+            timing: dict = {}
             try:
                 req = self._read_json()
-                payload = service.handle(req)
+                payload = service.handle(req, request_id=self._request_id,
+                                         timing=timing)
                 status = "200"
                 self._reply(200, payload)
             except ServiceDrainingError as e:
@@ -998,8 +1026,23 @@ def make_handler(service: GenerationService):
             except Exception as e:  # noqa: BLE001 — server must not die
                 self._reply(500, {"message": f"internal error: {e}"})
             finally:
+                now = time.monotonic()
                 service._m_requests.inc(status=status)
-                service._m_latency.observe(time.monotonic() - t0)
+                service._m_latency.observe(now - t0)
+                # after the reply's last byte: handler_s from the request's
+                # first bytes to here, engine_s from the first of its
+                # prompts' submits to the last of their retirements (the
+                # engine's clock; absent where no engine served it). The
+                # difference is the server's own: parse, tokenise, the wait
+                # for the interpreter lock behind the loop, detokenise,
+                # JSON, the socket
+                fields = {"id": self._request_id, "status": status,
+                          "handler_s": round(now - self._t_first, 6),
+                          "prompts": timing.get("prompts", 0)}
+                if timing.get("engine_s") is not None:
+                    fields["engine_s"] = round(timing["engine_s"], 6)
+                service._journal("serve_reply", **fields)
+                self._request_id = None
 
         def _handle_admin(self, path: str):
             from megatron_tpu.inference.fleet.migration import (

@@ -191,6 +191,10 @@ class TraceReport:
         default_factory=dict)             # kernel name -> count, self_s
     idle_gaps: List[Dict[str, Any]] = dataclasses.field(
         default_factory=list)             # host span -> count, total_s, max_s
+    loop_thread: List[Dict[str, Any]] = dataclasses.field(
+        default_factory=list)             # loop-thread event -> count,
+    #   total_s, self_s: where the thread that dispatches the steps spent
+    #   its own time, the program's spans and the runtime's events alike
 
     @property
     def compute_s(self) -> float:
@@ -234,6 +238,7 @@ class TraceReport:
                 for scope, by_class in self.scope_classes.items()},
             "kernels": self.kernels,
             "idle_gaps": self.idle_gaps[:top],
+            "loop_thread": self.loop_thread[:top],
         }
 
 
@@ -290,6 +295,36 @@ def idle_gaps_by_host_span(events: List[OpEvent]) -> List[Dict[str, Any]]:
     rows = sorted(out.values(), key=lambda r: -r["total_s"])
     for r in rows:
         r["total_s"], r["max_s"] = round(r["total_s"], 9), round(r["max_s"], 9)
+    return rows
+
+
+def loop_thread_self_time(events: List[OpEvent]) -> List[Dict[str, Any]]:
+    """The loop thread's events by name, largest self time first: the
+    host line that holds step annotations (`train-pass` with the loop's
+    timers; `serve-tick` with the engine's `tick-*` phases) and the
+    runtime's dispatch and wait events nested inside them. An instant
+    belongs to the innermost event covering it, so the self times sum to
+    the line's covered time: under `serve-tick`, `tick-read`'s own is the
+    wait for the device and what is left of the tick is the host's work.
+    Empty where no host line is annotated."""
+    lines: Dict[Tuple[str, str], List[OpEvent]] = {}
+    for e in events:
+        if e.kind == KIND_HOST and not e.plane.startswith(
+                DEVICE_PLANE_PREFIX):
+            lines.setdefault((e.plane, e.line), []).append(e)
+    out: Dict[str, Dict[str, Any]] = {}
+    for line_events in lines.values():
+        if not any(e.step_num is not None for e in line_events):
+            continue
+        for e, _segs, self_ps in self_segments(line_events):
+            row = out.setdefault(e.name, {"span": e.name, "count": 0,
+                                          "total_s": 0.0, "self_s": 0.0})
+            row["count"] += 1
+            row["total_s"] += e.duration_ps / PS_PER_S
+            row["self_s"] += self_ps / PS_PER_S
+    rows = sorted(out.values(), key=lambda r: -r["self_s"])
+    for r in rows:
+        r["total_s"], r["self_s"] = round(r["total_s"], 9), round(r["self_s"], 9)
     return rows
 
 
@@ -414,6 +449,7 @@ def analyze_events(events: List[OpEvent],
         kernels=dict(sorted(kernels.items(),
                             key=lambda kv: -kv[1]["self_s"])),
         idle_gaps=idle_gaps_by_host_span(events),
+        loop_thread=loop_thread_self_time(events),
     )
 
 

@@ -220,13 +220,35 @@ def test_telemetry_report_serving_section():
             "emitted": 6, "ticks": 4, "k": 4, "drafter": "ngram"},
            {"kind": "serve_spec", "proposed": 40, "accepted": 30,
             "emitted": 50, "ticks": 10, "k": 4, "drafter": "ngram"}])
+    # cumulative snapshots of the loop, and two ticks that stood
+    events += [
+        {"kind": "serve_request", "status": "ok", "ttft_s": 0.05,
+         "queue_s": 0.01, "prefill_s": 0.04, "tpot_s": 0.01,
+         "wall_s": 0.3},
+        {"kind": "serve_ticks", "ticks": 10, "rows": 30,
+         "phase_s": {"read": 0.5, "decode": 0.5}},
+        {"kind": "serve_ticks", "ticks": 100, "rows": 850,
+         "phase_s": {"read": 6.0, "decode": 1.0, "evict": 3.0}},
+        {"kind": "serve_slow_tick", "tick": 40, "wall_s": 0.5,
+         "phase_s": {"read": 0.45, "pre": 0.05}},
+        {"kind": "serve_slow_tick", "tick": 70, "wall_s": 2.5,
+         "phase_s": {"evict": 2.4, "read": 0.1}}]
     summary = telemetry_report.summarize(events)
     sv = summary["serving"]
+    assert sv["queue_s"]["p50"] == 0.01 and sv["prefill_s"]["p50"] == 0.04
+    assert sv["loop"] == {
+        "ticks": 100, "rows_per_tick": 8.5,
+        "phase_share": {"read": 0.6, "evict": 0.3, "decode": 0.1},
+        "slow_ticks": 2, "slow_tick_s": 3.0,
+        "worst_slow_tick": {"wall_s": 2.5, "phase": "evict"}}
+    text = telemetry_report.render(summary)
+    assert "8.5 rows a tick; time by phase read 60.0%, evict 30.0%" in text
+    assert "slow ticks: 2 (3.0 s); the worst 2.5 s in `evict`" in text
     assert sv["speculative"]["accept_rate"] == 0.75
     assert sv["speculative"]["tokens_per_forward"] == 5.0
     assert sv["speculative"]["drafter"] == "ngram"
-    assert sv["requests"]["total"] == 10
-    assert sv["requests"]["by_status"] == {"ok": 9, "timeout": 1}
+    assert sv["requests"]["total"] == 11
+    assert sv["requests"]["by_status"] == {"ok": 10, "timeout": 1}
     assert sv["ttft_s"]["p50"] == 0.05
     assert sv["router"] == {"routed": 10, "retries": 3, "failovers": 1,
                             "exhausted": 1}

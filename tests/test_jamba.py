@@ -549,7 +549,10 @@ def test_the_served_cell_runs_through_the_unchanged_harness(tmp_path):
                    "ssm_prefill_ms_per_chunk", "ssm_scan_roofline_pct"}
     assert set(listed) >= from_device
     end_to_end = {"request_ms_p50", "request_ms_p95"}  # no traced run's
-    assert set(line["metrics"]) == set(listed) - from_device - end_to_end
+    # a 95th percentile wants more than this mix's 32 requests
+    too_few = {"engine_queue_ms_p95"}
+    assert set(line["metrics"]) == (set(listed) - from_device - end_to_end
+                                    - too_few)
     with open(os.path.join(REPO, "runs", "benchmark", cell,
                            "child.log")) as f:
         assert "paged KV" in f.read()

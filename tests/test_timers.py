@@ -81,3 +81,50 @@ def test_a_pair_with_no_capture_running_is_cheap_and_silent():
         t.stop()
     assert time.perf_counter() - t0 < 0.5
     assert t._span is None and t._count == 1000
+
+
+def test_own_time_is_a_span_less_the_spans_inside_it():
+    """What the serving engine's phases are booked by: nested pairs of one
+    Timers sum to the pair that holds them."""
+    timers = Timers()
+    with timers("tick"):
+        with timers("admit"):
+            time.sleep(0.004)
+            with timers("evict"):
+                time.sleep(0.006)
+        with timers("read"):
+            time.sleep(0.003)
+        with timers("read"):        # a second span of the same timer
+            time.sleep(0.002)
+    own = timers.own_s()
+    assert set(own) == {"tick", "admit", "evict", "read"}
+    assert own["evict"] >= 0.006 and own["read"] >= 0.005
+    assert 0.004 <= own["admit"] < own["admit"] + own["evict"]
+    assert own["admit"] == pytest.approx(
+        timers("admit").elapsed(reset=False) - own["evict"])
+    assert sum(own.values()) == pytest.approx(
+        timers("tick").elapsed(reset=False))
+    # a credited span is its own; a gated timer has none; nothing resets it
+    timers.record("gap", 0.5)
+    assert timers.own_s()["gap"] == 0.5
+    assert Timers(log_level=0)("quiet", 1).own() == 0.0
+    timers.elapsed_ms(reset=True)
+    assert timers.own_s()["evict"] == own["evict"]
+
+
+def test_a_span_takes_arguments_at_both_ends_and_may_be_a_step_marker():
+    timers = Timers()
+    t = timers("page-evict")
+    t.start(asked=3)
+    t.stop(freed=2)
+    tick = timers("serve-tick")
+    tick.start(step_num=7)
+    assert type(tick._span).__name__ == "StepTraceAnnotation"
+    tick.stop()
+    with timers("quiet", 1) as quiet:      # gated: the same calls, no span
+        quiet.start(asked=1)
+        quiet.stop(freed=1)
+    with pytest.raises(RuntimeError, match="already started"):
+        with timers("x"):
+            timers("x").start()
+    assert timers("x")._start is None      # the context closed it

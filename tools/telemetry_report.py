@@ -311,13 +311,43 @@ def _summarize_serving(events: List[Dict[str, Any]]
             s = str(e.get("status", "?"))
             by_status[s] = by_status.get(s, 0) + 1
         out["requests"] = {"total": len(reqs), "by_status": by_status}
-        for field, label in (("ttft_s", "ttft_s"), ("tpot_s", "tpot_s"),
+        # queue_s + prefill_s = ttft_s: the wait for a slot, and the
+        # prompt's chunks up to the first token's read
+        for field, label in (("ttft_s", "ttft_s"), ("queue_s", "queue_s"),
+                             ("prefill_s", "prefill_s"),
+                             ("tpot_s", "tpot_s"),
                              ("wall_s", "request_wall_s")):
             vals = sorted(float(e[field]) for e in reqs if field in e)
             if vals:
                 out[label] = {"p50": round(percentile(vals, 0.50), 4),
                               "p95": round(percentile(vals, 0.95), 4),
                               "p99": round(percentile(vals, 0.99), 4)}
+    ticks = [e for e in events if e.get("kind") == "serve_ticks"
+             and e.get("phase_s")]
+    if ticks:
+        # cumulative like serve_spec: the LAST one is the totals. Where
+        # the loop thread's time went by phase of the tick (own time, so
+        # the shares sum to 1; `read` is its wait for the device), the
+        # mean decoding batch, and the ticks that stood
+        t = ticks[-1]
+        whole = sum(t["phase_s"].values()) or 1.0
+        slow = [e for e in events if e.get("kind") == "serve_slow_tick"]
+        out["loop"] = {
+            "ticks": int(t.get("ticks", 0)),
+            "rows_per_tick": round(
+                t.get("rows", 0) / max(t.get("ticks", 0), 1), 3),
+            "phase_share": {k: round(v / whole, 4) for k, v in sorted(
+                t["phase_s"].items(), key=lambda kv: -kv[1])},
+            "slow_ticks": len(slow),
+            "slow_tick_s": round(sum(float(e.get("wall_s", 0.0))
+                                     for e in slow), 3),
+        }
+        if slow:
+            worst = max(slow, key=lambda e: e.get("wall_s", 0.0))
+            by_phase = worst.get("phase_s") or {"?": 0.0}
+            out["loop"]["worst_slow_tick"] = {
+                "wall_s": worst.get("wall_s"),
+                "phase": max(by_phase, key=by_phase.get)}
     if routes:
         retries = sum(max(0, int(e.get("attempts", 1)) - 1) for e in routes)
         failovers = sum(1 for e in routes
@@ -433,12 +463,27 @@ def render(summary: Dict[str, Any]) -> str:
             r = sv["requests"]
             lines.append(f"serving: {r['total']} requests "
                          f"{r['by_status']}")
-        for key, label in (("ttft_s", "ttft s"), ("tpot_s", "tpot s"),
+        for key, label in (("ttft_s", "ttft s"), ("queue_s", "  queue s"),
+                           ("prefill_s", "  prefill s"),
+                           ("tpot_s", "tpot s"),
                            ("request_wall_s", "request wall s")):
             if key in sv:
                 p = sv[key]
                 lines.append(f"  {label}: p50 {p['p50']} | "
                              f"p95 {p['p95']} | p99 {p['p99']}")
+        if "loop" in sv:
+            lp = sv["loop"]
+            lines.append(
+                f"  loop: {lp['ticks']} ticks, {lp['rows_per_tick']} rows "
+                "a tick; time by phase "
+                + ", ".join(f"{k} {100 * v:.1f}%"
+                            for k, v in lp["phase_share"].items()))
+            if lp["slow_ticks"]:
+                w = lp["worst_slow_tick"]
+                lines.append(
+                    f"  slow ticks: {lp['slow_ticks']} "
+                    f"({lp['slow_tick_s']} s); the worst {w['wall_s']} s "
+                    f"in `{w['phase']}`")
         if "speculative" in sv:
             s = sv["speculative"]
             lines.append(

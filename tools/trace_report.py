@@ -168,6 +168,14 @@ def render_text(report, comparison, top: int, files) -> str:
             lines.append(f"  {g['span']:<32} x{g['count']:<6} total "
                          f"{_fmt_s(g['total_s']):>10}  max "
                          f"{_fmt_s(g['max_s']):>10}")
+    if report.loop_thread:
+        lines.append("loop thread by own time (the host line that holds "
+                     "the step annotations; the program's spans and the "
+                     "runtime's events):")
+        for g in report.loop_thread[:top]:
+            lines.append(f"  {g['span']:<40} x{g['count']:<6} own "
+                         f"{_fmt_s(g['self_s']):>10}  total "
+                         f"{_fmt_s(g['total_s']):>10}")
     lines.append(f"top {top} ops by self time:")
     for o in report.ops[:top]:
         lines.append(f"  {o.self_s * 1e3:10.3f}ms  x{o.count:<6} "
