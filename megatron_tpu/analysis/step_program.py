@@ -9,6 +9,10 @@ its collectives stand, and which of its instructions lost their name.
                                  that does work and carries no `op_name`
     kernel_calls(text)           for each named Pallas kernel, its calls
                                  and how many of them are recomputation
+    relaid_arrays(text, n)       every instruction that writes an array of
+                                 n bytes or more a second time and computes
+                                 nothing: a `copy` into another layout, a
+                                 slice taken out for its consumer to read
 
 `text` is `compiled.as_text()`: the program after GSPMD and the chip
 compiler's fusion, which is what a device trace times. The chip compiler
@@ -19,7 +23,8 @@ that it communicates. An instruction's name stack (`op_name`) is its own,
 else that of the instruction that calls the computation it stands in: the
 compiler leaves the name on the fusion. Read by the trainer's
 `step_program` journal record (training/pretrain.py) and by
-tests/test_chip_compile.py; the record is the cross-check for the classes
+tests/test_chip_compile.py (`relaid_arrays` by its guard on the served
+steps' weights alone); the record is the cross-check for the classes
 a trace is read by (docs/observability.md "Runtime traces").
 """
 
@@ -56,6 +61,11 @@ _GROUPS = re.compile(r"replica_groups=(\{\{[\d,]*\}|\[[\d,]+\]<=)")
 _CHANNEL = re.compile(r"channel_id=(\d+)")
 _ASYNC_FUSION = re.compile(r"async[-_]collective")
 _IDENTIFIER = re.compile(r"^[A-Za-z_]\w*$")
+_LAYOUT = re.compile(r"\]\{([\d,]*)")
+_OPERAND = re.compile(r"\(%([\w.\-]+)")
+_FUSED = re.compile(r"\bcalls=%([\w.\-]+)")
+# what takes a part of an array out and does nothing else to it
+_SLICES = frozenset({"slice", "dynamic-slice"})
 # instructions that move or compute nothing of their own
 _NO_WORK = frozenset({
     "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
@@ -274,3 +284,62 @@ def kernel_calls(text: str) -> Dict[str, Dict[str, int]]:
         rec["rematted"] += REMATTED in parts
         rec["times"] += program.times[comp]
     return dict(sorted(out.items()))
+
+
+def _layout(results: str) -> str:
+    """`{2,1,0}` of `bf16[1,4096,4096]{2,1,0:T(8,128)(2,1)S(1)}`: the
+    dimensions from the fastest-varying to the slowest; "" where the text
+    states none."""
+    m = _LAYOUT.search(results)
+    return "{" + m.group(1) + "}" if m else ""
+
+
+def relaid_arrays(text: str, min_bytes: int) -> List[Dict[str, Any]]:
+    """The instructions a step reaches that write `min_bytes` or more and
+    compute nothing: a `copy` (the same elements in another layout), and a
+    slice that is a result of its own (`slice` / `dynamic-slice`, bare or
+    the only work of a fusion: a layer's weight taken out of its stack
+    into memory of its own, where the product that needs it could have
+    taken the stack and the index). Not counted: what stands inside a
+    fusion (a slice fused into its consumer is read in place and written
+    nowhere), and the asynchronous pairs (`copy-start`, `slice-start`: the
+    compiler's prefetch into fast memory beside other work, same layout).
+    Each record: `name`, `kind` ("copy" or "slice"), `result`, `layout`
+    and, of a copy, the operand's `from_layout`, `bytes`, `times` a step,
+    `region` and `scope` of its name stack; most bytes a step first. With
+    `min_bytes` the smallest of a layer's matrices, an empty list says no
+    weight is moved but into the product that reads it."""
+    program = Program(text)
+    parsed = {comp: [m for m in map(_INSTRUCTION.match, lines) if m]
+              for comp, lines in program.lines.items()}
+    slicing = set()   # the computations that slice and do nothing else
+    for comp, found in parsed.items():
+        ops = {m.group("opcode") for m in found}
+        if ops & _SLICES and ops <= _SLICES | _NO_WORK:
+            slicing.add(comp)
+    out: List[Dict[str, Any]] = []
+    for comp, line, name, results, opcode in program.instructions():
+        if program.fused[comp]:
+            continue
+        called = _FUSED.search(line) if opcode == "fusion" else None
+        if opcode == "copy":
+            kind = "copy"
+        elif opcode in _SLICES or (called and called.group(1) in slicing):
+            kind = "slice"
+        else:
+            continue
+        dtype, dims, nbytes = _largest_result(results)
+        if nbytes < min_bytes:
+            continue
+        region, scope = scope_of(program.op_name(comp, line))
+        rec = {"name": name, "kind": kind, "result": f"{dtype}[{dims}]",
+               "layout": _layout(results), "bytes": nbytes,
+               "times": program.times[comp], "region": region,
+               "scope": scope}
+        if kind == "copy":
+            operand = _OPERAND.search(line[line.index(" copy("):]).group(1)
+            rec["from_layout"] = next(
+                (_layout(m.group("results")) for m in parsed[comp]
+                 if m.group("name") == operand), "")
+        out.append(rec)
+    return sorted(out, key=lambda r: -r["bytes"] * r["times"])

@@ -116,6 +116,17 @@ def attention_block(
             # rotated keys
             q = rmsnorm(q, p["q_norm"]["scale"], cfg.layernorm_epsilon)
             k = rmsnorm(k, p["k_norm"]["scale"], cfg.layernorm_epsilon)
+        if b * s < x.shape[-1]:
+            # Fewer rows than the weights have (a decode tick, a prefill
+            # chunk): keep the products apart from the split into heads.
+            # Folded into the product, the split yields q head-major and
+            # the chip's compiler pays for that with the WEIGHT, sliced
+            # out of its stack and copied transposed every layer of every
+            # call (h * n elements against the result's rows * n). Behind
+            # the barrier each product reads the stack in place, as `wo`'s
+            # does, and what is re-laid is the result. With h rows or more
+            # (training) the fold is the right trade and stays.
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
         q = q.reshape(b, s, nq, D)
         k = k.reshape(b, s, nkv, D)
         v = v.reshape(b, s, nkv, D)

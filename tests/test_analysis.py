@@ -592,3 +592,100 @@ def test_kernel_calls_count_the_backward_a_step_runs(backward, want):
     want = dict(want, flash_fwd={"calls": 1 + again, "rematted": again,
                                  "times": 2 * (1 + again)})
     assert step_program.kernel_calls(text) == dict(sorted(want.items()))
+
+
+# ---------------------------------------------------------------------------
+# step_program.relaid_arrays: what a program writes a second time unchanged
+# ---------------------------------------------------------------------------
+
+_T = "{2,1,0:T(8,128)(2,1)}"
+_SCRATCH = "{2,1,0:T(8,128)(2,1)S(1)}"
+_DS = 'metadata={op_name="jit(decode)/layer_stack/while/body/dynamic_slice"}'
+_SERVE_TEXT = """HloModule jit_decode, is_scheduled=true
+
+%fused_computation.70 (param_0.398: bf16[8,4096,4096], param_1.417: s32[]) -> bf16[1,4096,4096] {{
+  %param_0.398 = bf16[8,4096,4096]{t} parameter(0)
+  %param_1.417 = s32[]{{:T(128)}} parameter(1)
+  %constant.514 = s32[]{{:T(128)}} constant(0)
+  ROOT %dynamic_slice.109 = bf16[1,4096,4096]{s} dynamic-slice(%param_0.398, %param_1.417, %constant.514, %constant.514), dynamic_slice_sizes={{1,4096,4096}}, {ds}
+}}
+
+%fused_computation.13 (param_0.406: bf16[8,4096,4096], param_1.422: s32[]) -> bf16[4096,4096] {{
+  %param_0.406 = bf16[8,4096,4096]{t} parameter(0)
+  %param_1.422 = s32[]{{:T(128)}} parameter(1)
+  %constant.516 = s32[]{{:T(128)}} constant(0)
+  %dynamic_slice.110 = bf16[1,4096,4096]{t} dynamic-slice(%param_0.406, %param_1.422, %constant.516, %constant.516), dynamic_slice_sizes={{1,4096,4096}}, {ds}
+  ROOT %bitcast.191 = bf16[4096,4096]{{1,0:T(8,128)(2,1)}} bitcast(%dynamic_slice.110)
+}}
+
+%fused_computation.31 (param_0.408: bf16[64,4096], param_1.423: bf16[8,4096,4096], param_2.334: s32[]) -> bf16[64,4096] {{
+  %param_0.408 = bf16[64,4096]{{1,0:T(8,128)(2,1)}} parameter(0)
+  %param_1.423 = bf16[8,4096,4096]{t} parameter(1)
+  %param_2.334 = s32[]{{:T(128)}} parameter(2)
+  %fusion.43 = bf16[4096,4096]{{1,0:T(8,128)(2,1)}} fusion(%param_1.423, %param_2.334), kind=kLoop, calls=%fused_computation.13
+  ROOT %convolution.16 = bf16[64,4096]{{1,0:T(8,128)(2,1)}} convolution(%param_0.408, %fusion.43), dim_labels=bf_io->bf, metadata={{op_name="jit(decode)/layer_stack/while/body/closed_call/attention/attn_out/...k,kn->...n/dot_general"}}
+}}
+
+%cond (arg: (s32[], bf16[64,4096], bf16[8,4096,4096])) -> pred[] {{
+  %arg = (s32[], bf16[64,4096], bf16[8,4096,4096]) parameter(0)
+  %i = s32[] get-tuple-element(%arg), index=0
+  %n = s32[] constant(8)
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}}
+
+%body (arg.1: (s32[], bf16[64,4096], bf16[8,4096,4096])) -> (s32[], bf16[64,4096], bf16[8,4096,4096]) {{
+  %arg.1 = (s32[], bf16[64,4096], bf16[8,4096,4096]) parameter(0)
+  %get-tuple-element.705 = s32[]{{:T(128)}} get-tuple-element(%arg.1), index=0
+  %get-tuple-element.706 = bf16[64,4096]{{1,0:T(8,128)(2,1)}} get-tuple-element(%arg.1), index=1
+  %get-tuple-element.735 = bf16[8,4096,4096]{t} get-tuple-element(%arg.1), index=2
+{layer}
+  ROOT %t = (s32[], bf16[64,4096], bf16[8,4096,4096]) tuple(%get-tuple-element.705, %x, %get-tuple-element.735)
+}}
+
+ENTRY %main (p: bf16[8,4096,4096]) -> bf16[64,4096] {{
+  %p = bf16[8,4096,4096]{t} parameter(0)
+  %while.1 = (s32[], bf16[64,4096], bf16[8,4096,4096]) while(%init), condition=%cond, body=%body
+  ROOT %out = bf16[64,4096] get-tuple-element(%while.1), index=1
+}}
+"""
+# the parent of PR 55, the served Mistral decode step compiled for the
+# described v5e: `wq` sliced out of its stack, copied transposed, and the
+# product reads the copy
+_WQ_RELAID = """\
+  %constant_dynamic-slice_fusion.6 = bf16[1,4096,4096]{s} fusion(%get-tuple-element.735, %get-tuple-element.705), kind=kLoop, calls=%fused_computation.70, {ds}
+  %copy.50 = bf16[1,4096,4096]{{1,2,0:T(8,128)(2,1)S(1)}} copy(%constant_dynamic-slice_fusion.6), {ds}
+  %bitcast.211 = bf16[32,128,4096]{s} bitcast(%copy.50)
+  %x = bf16[64,32,128]{{2,0,1:T(8,128)(2,1)S(1)}} fusion(%bitcast.211, %get-tuple-element.706), kind=kOutput, calls=%fused_computation.47, metadata={{op_name="jit(decode)/layer_stack/while/body/closed_call/attention/attn_qkv/...k,kn->...n/dot_general"}}"""
+# `wo` as it has always been: the product takes the stack and the layer,
+# the slice stands inside its fusion
+_WO_IN_PLACE = """\
+  %x = bf16[64,4096]{{1,0:T(8,128)(2,1)}} fusion(%get-tuple-element.706, %get-tuple-element.735, %get-tuple-element.705), kind=kOutput, calls=%fused_computation.31, metadata={{op_name="jit(decode)/layer_stack/while/body/closed_call/attention/attn_out/...k,kn->...n/dot_general"}}"""
+_WQ_BYTES = 4096 * 4096 * 2
+
+
+@pytest.mark.parametrize("layer, min_bytes, want", [
+    (_WQ_RELAID, _WQ_BYTES, [
+        ("constant_dynamic-slice_fusion.6", "slice", "{2,1,0}", None),
+        ("copy.50", "copy", "{1,2,0}", "{2,1,0}")]),
+    (_WQ_RELAID, _WQ_BYTES + 1, []),
+    (_WO_IN_PLACE, 1 << 20, []),
+], ids=["wq_sliced_and_copied", "under_the_size_asked_for", "wo_in_place"])
+def test_relaid_arrays_finds_a_weight_moved_without_being_used(
+        layer, min_bytes, want):
+    """The copy of a layer's weight into another layout and the slice
+    that took it out of its stack are found, each with its bytes, its
+    layouts and how often a step runs it (the loop's eight trips); a
+    slice fused into the product that reads it is nothing written, and
+    nothing is found."""
+    from megatron_tpu.analysis import step_program
+
+    text = _SERVE_TEXT.format(
+        t=_T, s=_SCRATCH, ds=_DS,
+        layer=layer.format(s=_SCRATCH, ds=_DS))
+    found = step_program.relaid_arrays(text, min_bytes)
+    assert [(r["name"], r["kind"], r["layout"], r.get("from_layout"))
+            for r in found] == want
+    for r in found:
+        assert r["result"] == "bf16[1,4096,4096]"
+        assert (r["bytes"], r["times"]) == (33_554_432, 8)
+        assert (r["region"], r["scope"]) == ("other", "body")

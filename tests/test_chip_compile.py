@@ -32,6 +32,7 @@ from megatron_tpu.config import OptimizerConfig, ParallelConfig
 from megatron_tpu.models import presets
 from megatron_tpu.ops.pallas import flash_template as ft
 from megatron_tpu.ops.pallas import grouped_matmul as gm
+from megatron_tpu.ops.pallas import ssm_scan as ssm_scan_mod
 
 # megatron_tpu.ops re-exports the attention FUNCTION under the module's name
 attention_mod = importlib.import_module("megatron_tpu.ops.attention")
@@ -62,6 +63,7 @@ def _as_on_the_chip():
     (module scope, so the compiled-step fixture below sees it too)."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ft, "_interpret", lambda: False)
+        mp.setattr(ssm_scan_mod, "_interpret", lambda: False)
         mp.setattr(attention_mod, "_kernels_dispatchable", lambda: True)
         mp.setattr(gm, "_one_tpu", lambda: True)
         yield
@@ -1170,6 +1172,52 @@ def serve_cfg():
         params_dtype="bfloat16", attention_impl="pallas").validate()
 
 
+def _paged_step(topo, cfg, step, pages, page, slots, max_len, chunk):
+    """The model call of PagedInferenceEngine's decode step or prefill
+    chunk (inference/paging/engine.py `_forward`; the state store beside
+    the pool where the model has state-space layers) compiled for one
+    described chip with pool and state donated."""
+    from megatron_tpu.models.language_model import lm_forward
+    from megatron_tpu.models.params import param_shapes
+    from megatron_tpu.ops import kv_store, ssm
+
+    dev, i32 = topo.devices[0], jnp.int32
+
+    def abstract(tree):
+        return jax.tree.map(lambda s: _abstract(s.shape, s.dtype, dev), tree)
+
+    params = abstract(param_shapes(cfg))
+    pool = abstract(jax.eval_shape(lambda: kv_store.create(cfg, pages, page)))
+    state = abstract(jax.eval_shape(
+        lambda: ssm.create_state(cfg, slots))) if cfg.has_ssm else None
+
+    def decode(params, caches, state, table, tok, lengths):
+        decoding = (None if state is None
+                    else (table[:, 0] != 0).astype(i32))
+        return lm_forward(cfg, params, tok[:, None], kv_caches=caches,
+                          ssm_state=state, cache_index=lengths,
+                          page_table=table, state_valid=decoding)
+
+    def prefill_chunk(params, caches, state, row, toks, off, start, end,
+                      slot):
+        of_state = {} if state is None else dict(
+            state_row=slot, state_valid=jnp.clip(end - off, 0, chunk)[None])
+        return lm_forward(cfg, params, toks, kv_caches=caches,
+                          ssm_state=state, cache_index=off, page_table=row,
+                          page_write_start=start, page_write_end=end,
+                          **of_state)
+
+    per_seq = max_len // page
+    fn, args = {
+        "decode": (decode, [((slots, per_seq), i32), ((slots,), i32),
+                            ((slots,), i32)]),
+        "chunk": (prefill_chunk, [((1, per_seq), i32), ((1, chunk), i32)]
+                  + [((), i32)] * 4)}[step]
+    return jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, pool, state,
+        *[_abstract(shape, dtype, dev) for shape, dtype in args]).compile()
+
+
 def _serve_program(topo, cfg, case):
     """The model call of the engine's step (PagedInferenceEngine's decode
     and chunk steps, InferenceEngine's decode step) compiled for one
@@ -1178,39 +1226,25 @@ def _serve_program(topo, cfg, case):
     from megatron_tpu.models.params import param_shapes
     from megatron_tpu.ops import kv_store
 
-    dev, i32 = topo.devices[0], jnp.int32
     rows = _SERVE_CASES[case]
-    paged = case != "slots-decode"
-    store = jax.eval_shape(
-        lambda: kv_store.create(cfg, rows, PAGE if paged else SERVE_LEN))
+    if case != "slots-decode":
+        compiled = _paged_step(topo, cfg, case.split("-")[0], rows, PAGE,
+                               SERVE_SLOTS, SERVE_LEN, CHUNK)
+        return compiled, (cfg.num_layers, rows, PAGE, cfg.n_kv_heads,
+                          cfg.head_dim)
+    dev, i32 = topo.devices[0], jnp.int32
+    store = jax.eval_shape(lambda: kv_store.create(cfg, rows, SERVE_LEN))
     store = tuple(_abstract(leaf.shape, leaf.dtype, dev) for leaf in store)
     params = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, dev),
                           param_shapes(cfg))
-    per_seq = SERVE_LEN // PAGE
-
-    def decode(params, caches, table, tok, lengths):
-        return lm_forward(cfg, params, tok[:, None], kv_caches=caches,
-                          cache_index=lengths, page_table=table)
-
-    def chunk(params, caches, row, toks, off, start, end):
-        return lm_forward(cfg, params, toks, kv_caches=caches,
-                          cache_index=off, page_table=row,
-                          page_write_start=start, page_write_end=end)
 
     def slots_decode(params, caches, tok, lengths):
         return lm_forward(cfg, params, tok[:, None], kv_caches=caches,
                           cache_index=lengths)
 
-    if case.startswith("decode"):
-        fn, args = decode, [((SERVE_SLOTS, per_seq), i32),
-                            ((SERVE_SLOTS,), i32), ((SERVE_SLOTS,), i32)]
-    elif case.startswith("chunk"):
-        fn, args = chunk, [((1, per_seq), i32), ((1, CHUNK), i32),
-                           ((), i32), ((), i32), ((), i32)]
-    else:
-        fn, args = slots_decode, [((rows,), i32), ((rows,), i32)]
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, store, *[_abstract(s, d, dev) for s, d in args]).compile()
+    compiled = jax.jit(slots_decode, donate_argnums=(1,)).lower(
+        params, store, _abstract((rows,), i32, dev),
+        _abstract((rows,), i32, dev)).compile()
     return compiled, store[0].shape
 
 
@@ -1268,6 +1302,100 @@ def test_serving_step_writes_the_cache_in_place(topo, serve_cfg, case):
     kernels = {"decode": ["paged_flash_decode"], "chunk": [],
                "slots": ["paged_flash_decode"]}[case.split("-")[0]]
     assert _kernels_named(text) == kernels
+
+
+# ---------------------------------------------------------------------------
+# Serving: a layer's weights are read where they lie (PR 55)
+# ---------------------------------------------------------------------------
+
+_SERVED_STEPS = [(cell, step)
+                 for cell in ("serve_mistral7b_instruct",
+                              "serve_jamba2_3b_reasoning")
+                 for step in ("decode", "chunk")]
+
+
+def _served_step_text(topo, cell_name, step):
+    """A served cell's decode step or prefill chunk (`_paged_step`) at the
+    sizes of the cell's own configuration file: the compiled text, and the
+    model configuration."""
+    from benchmark.harness import spec
+    from megatron_tpu.arguments import args_to_run_config, parse_args
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = spec.Cell(os.path.join(repo, "BENCHMARK.json"), cell_name)
+    serve = cell.config["program"]["serve"]
+    cfg = args_to_run_config(parse_args(
+        spec.load_module(cell.reference_path()).program_flags(
+            cell.config, serve["seq_length"])
+        + cell.config["program"]["flags"]
+        + ["--micro_batch_size", "1", "--global_batch_size", "1"])).model
+    sizes = [int(serve["flags"][serve["flags"].index(f"--serve_{name}") + 1])
+             for name in ("num_pages", "page_size", "num_slots",
+                          "max_seq_len", "prefill_chunk")]
+    return _paged_step(topo, cfg, step, *sizes).as_text(), cfg
+
+
+def _dims(result):
+    """(1, 4096, 4096) of `bf16[1,4096,4096]{2,1,0:...}`."""
+    return tuple(int(d) for d in
+                 re.findall(r"\d+", result.split("[")[1].split("]")[0]))
+
+
+@pytest.mark.parametrize("cell, step", _SERVED_STEPS,
+                         ids=[f"{c}-{s}" for c, s in _SERVED_STEPS])
+def test_served_step_reads_a_layers_weights_where_they_lie(topo, cell, step):
+    """A decode tick has 64 rows and a prefill chunk 512, the weights 2560
+    or 4096: in such a call `attention_block` keeps the q/k/v products
+    apart from the split into heads (models/transformer.py), so the chip's
+    compiler
+
+    (a) neither copies nor slices out an array of the shape of one of an
+        attention layer's matrices (`step_program.relaid_arrays` over the
+        smallest of them: no record of such a shape);
+    (b) gives every product under `attn_qkv` the STACK of its weight as an
+        operand, the layer's slice fused into the product, as `attn_out`
+        and the FFN have always had theirs.
+
+    On the parent of PR 55 (cada2b2) the compiler folds the split into
+    the product, which then yields q head-major, and pays with the
+    weight: in each Mistral program `wq`, `wk` and `wv` are sliced out of
+    their stacks and copied into the layout `{1,2,0}`, 50.3 MB a layer and
+    0.40 GB a call (3 copies and 3 slices found), and the three products
+    read the copies; in each Jamba program `wq` is (13.1 MB a layer)."""
+    from megatron_tpu.analysis import step_program
+    from megatron_tpu.models.params import param_shapes
+
+    text, cfg = _served_step_text(topo, cell, step)
+    layers = param_shapes(cfg)["layers"]
+    stacks = {k: v.shape for part in ("attn", "mlp")
+              for k, v in layers[part].items() if len(v.shape) == 3}
+    assert {"wq", "wk", "wv", "wo"} <= set(stacks)
+    matrices = {shape[1:] for shape in stacks.values()}
+    smallest = min(math.prod(m) for m in matrices) * 2       # bf16
+    moved = [r for r in step_program.relaid_arrays(text, smallest)
+             if _dims(r["result"])[-2:] in matrices
+             and math.prod(_dims(r["result"])[:-2]) == 1]
+    assert moved == []                                               # (a)
+
+    program = step_program.Program(text)
+    instructions = list(program.instructions())
+    dims_of = {(comp, name): _dims(results)
+               for comp, _, name, results, _ in instructions}
+    products = []
+    for comp, line, name, results, opcode in instructions:
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        if (program.fused[comp] or opcode != "fusion" or not called
+                or "/attn_qkv/" not in program.op_name(comp, line)
+                or not any(" convolution(" in ln
+                           for ln in program.lines[called.group(1)])):
+            continue
+        operands = re.findall(
+            r"%([\w.\-]+)", line[line.index(" fusion(") + 8:].split(")")[0])
+        products.append((name, {dims_of.get((comp, o)) for o in operands}))
+    assert len(products) == 3, products
+    weights = {stacks[k] for k in ("wq", "wk", "wv")}
+    for name, shapes in products:                                    # (b)
+        assert shapes & weights, (name, shapes)
 
 
 # ---------------------------------------------------------------------------

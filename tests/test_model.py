@@ -208,3 +208,119 @@ def test_post_ln_convention():
         g = jax.grad(lambda p: lm_loss(c, p, batch)[0])(params)
         assert all(np.isfinite(np.asarray(x)).all()
                    for x in jax.tree.leaves(jax.device_get(g)))
+
+
+# ---------------------------------------------------------------------------
+# The q/k/v products of a call with few rows (PR 55)
+# ---------------------------------------------------------------------------
+
+def _attention_block_as_it_was(cfg, p, x, rope, positions, **rest):
+    """`transformer.attention_block` without a cache as the parent of PR
+    55 had it, the plain reference: the three products, bias and qk norm,
+    and the split into heads straight behind them, whatever the rows."""
+    from megatron_tpu.ops.attention import attention
+    from megatron_tpu.ops.normalization import rmsnorm
+    from megatron_tpu.ops.rotary import apply_rotary_emb
+
+    assert rest.get("kv_cache") is None
+    b, s, _ = x.shape
+    nq, nkv, D = cfg.num_attention_heads, cfg.n_kv_heads, cfg.head_dim
+    q, k, v = (jnp.einsum("...k,kn->...n", x, p[w])
+               for w in ("wq", "wk", "wv"))
+    if "bq" in p:
+        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    if cfg.qk_norm:
+        q = rmsnorm(q, p["q_norm"]["scale"], cfg.layernorm_epsilon)
+        k = rmsnorm(k, p["k_norm"]["scale"], cfg.layernorm_epsilon)
+    q, k, v = (t.reshape(b, s, n, D) for t, n in ((q, nq), (k, nkv), (v, nkv)))
+    if rope is not None:
+        q, k = apply_rotary_emb(q, k, rope[0], rope[1], positions)
+    ctx = attention(q, k, v, mask_type=cfg.attn_mask_type,
+                    sliding_window=cfg.attention_kind.sliding_window_size,
+                    impl=cfg.attention_impl, softmax_fp32=cfg.softmax_fp32)
+    out = jnp.einsum("...k,kn->...n", ctx.reshape(b, s, nq * D), p["wo"])
+    return (out + p["bo"] if "bo" in p else out), None
+
+
+def _barriers(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count("optimization_barrier")
+
+
+def _close_to_bf16_rounding(got, want):
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    scale = max(float(np.abs(want).max()), 1e-6)
+    np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -8 * scale)
+
+
+_QKV_VARIANTS = {"plain": {}, "bias": dict(use_bias_qkv=True),
+                 "qk_norm": dict(qk_norm=True),
+                 "bias_qk_norm": dict(use_bias_qkv=True, qk_norm=True)}
+
+
+@pytest.mark.parametrize("variant", list(_QKV_VARIANTS))
+@pytest.mark.parametrize("batch, seq, apart", [(1, 32, True), (2, 16, True),
+                                               (1, 64, False), (2, 64, False)],
+                         ids=["32_rows", "2x16_rows", "64_rows", "128_rows"])
+def test_attention_block_is_the_parents_on_either_side_of_its_rows(
+        variant, batch, seq, apart):
+    """With fewer rows than the weights have (64 here) the block keeps its
+    three products apart from the split into heads, with as many or more
+    it is the parent's to the letter; either way it computes what the
+    parent's did, bias and qk norm between the product and the split
+    included: forward and gradient equal to bf16 rounding."""
+    from megatron_tpu.models import transformer
+    from megatron_tpu.ops.rotary import rope_table
+
+    cfg = presets.tiny(params_dtype="bfloat16", **_QKV_VARIANTS[variant])
+    p = jax.tree.map(lambda leaf: leaf[0], init_params(
+        cfg, jax.random.PRNGKey(1))["layers"]["attn"])
+    if "bq" in p:   # biases start at zero: give them something to add
+        p = {**p, **{b: jax.random.normal(jax.random.PRNGKey(i), p[b].shape,
+                                          p[b].dtype)
+                     for i, b in enumerate(("bq", "bk", "bv"))}}
+    x = jax.random.normal(jax.random.PRNGKey(2),
+                          (batch, seq, cfg.hidden_size), jnp.bfloat16)
+    rope = rope_table(cfg.attention_kind, cfg.head_dim, cfg.seq_length)
+
+    def loss(block, p, x):
+        out, _ = block(cfg, p, x, rope, None)
+        return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+    assert _barriers(lambda p, x: transformer.attention_block(
+        cfg, p, x, rope, None)[0], p, x) == (1 if apart else 0)
+    (_, out), grads = jax.value_and_grad(
+        lambda p, x: loss(transformer.attention_block, p, x),
+        argnums=(0, 1), has_aux=True)(p, x)
+    (_, want), want_grads = jax.value_and_grad(
+        lambda p, x: loss(_attention_block_as_it_was, p, x),
+        argnums=(0, 1), has_aux=True)(p, x)
+    _close_to_bf16_rounding(out, want)
+    jax.tree.map(_close_to_bf16_rounding, grads, want_grads)
+
+
+@pytest.mark.parametrize("batch, seq, apart", [(1, 32, True), (2, 64, False)],
+                         ids=["32_rows", "128_rows"])
+def test_lm_forward_is_the_parents_on_either_side_of_its_rows(
+        monkeypatch, batch, seq, apart):
+    """The whole toy model, qk norm and bias on: logits and the loss's
+    gradient with the block as it is against the block as it was."""
+    from megatron_tpu.models import transformer
+
+    cfg = presets.tiny(params_dtype="bfloat16", use_bias_qkv=True,
+                       qk_norm=True)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    data = _batch(cfg, batch=batch, seq=seq)
+
+    def run():
+        logits = lm_forward(cfg, params, data["tokens"])
+        grads = jax.grad(lambda p: lm_loss(cfg, p, data)[0])(params)
+        return logits, grads
+
+    assert _barriers(lambda p: lm_forward(cfg, p, data["tokens"]),
+                     params) == (1 if apart else 0)
+    logits, grads = run()
+    monkeypatch.setattr(transformer, "attention_block",
+                        _attention_block_as_it_was)
+    want_logits, want_grads = run()
+    _close_to_bf16_rounding(logits, want_logits)
+    jax.tree.map(_close_to_bf16_rounding, grads, want_grads)
