@@ -1482,18 +1482,23 @@ class InferenceEngine:
         from megatron_tpu.ops.pallas.flash_template import (
             decode_blocks_visited)
 
-        tp = (dict(self.mesh.shape).get("tensor", 1)
-              if self.mesh is not None else 1)
-        kv_heads = self.cfg.n_kv_heads
         lens = np.ones_like(self.lengths)
         lens[active] = self.lengths[active] + 1
         visited, held = decode_blocks_visited(
-            lens, *self._decode_table_geometry(),
-            kv_heads // tp if kv_heads % tp == 0 else kv_heads,
+            lens, *self._decode_table_geometry(), self._kernel_kv_heads(),
             sq=self._decode_write_span(),
             window=self.cfg.attention_kind.sliding_window_size)
         self.stats["decode_live_block_share"] = visited / held
         self._m_live_blocks.set(visited / held)
+
+    def _kernel_kv_heads(self) -> int:
+        """KV heads an attention kernel sees a shard: the model's over the
+        mesh's `tensor` axis where they divide (ops/attention.py
+        `_shard_plan`), else all of them."""
+        tp = (dict(self.mesh.shape).get("tensor", 1)
+              if self.mesh is not None else 1)
+        kv_heads = self.cfg.n_kv_heads
+        return kv_heads // tp if kv_heads % tp == 0 else kv_heads
 
     def _decode_write_span(self) -> int:
         """Cache positions one decode tick writes per slot: 1 plain,

@@ -131,6 +131,8 @@ class PagedInferenceEngine(InferenceEngine):
             "prefix_tokens_saved": 0, "prefill_tokens": 0,
             "prefill_chunks": 0, "preemptions": 0,
             "window_pages_released": 0, "pages_evicted": 0,
+            # prefill_live_block_share joins them at the first chunk
+            "prefill_blocks_visited": 0, "prefill_blocks_held": 0,
         })
         m = self.metrics
         self._m_pages_total = m.gauge("engine_pages_total",
@@ -151,6 +153,10 @@ class PagedInferenceEngine(InferenceEngine):
             "slots preempted under page-pool pressure")
         self._m_chunks = m.counter("engine_prefill_chunks_total",
                                    "chunked-prefill steps executed")
+        self._m_prefill_blocks = m.gauge(
+            "engine_prefill_live_block_share",
+            "KV blocks the last prefill chunk's attention kernel visited "
+            "over the blocks its page table holds a query tile")
         self._m_window_released = m.counter(
             "engine_window_pages_released_total",
             "pages freed from behind the sliding attention window")
@@ -410,7 +416,9 @@ class PagedInferenceEngine(InferenceEngine):
         return freed
 
     def _serve_ticks_fields(self) -> dict:
-        return {"evicted": self.stats["pages_evicted"]}
+        return {"evicted": self.stats["pages_evicted"],
+                "prefill_blocks": [self.stats["prefill_blocks_visited"],
+                                   self.stats["prefill_blocks_held"]]}
 
     def _slow_tick_fields(self) -> dict:
         return {"pages_free": self.pool.free_pages}
@@ -573,6 +581,7 @@ class PagedInferenceEngine(InferenceEngine):
         avail = task.tokens[off:off + C + 1]
         toks_ext[0, :len(avail)] = avail
         row = self._pending_rows[i]
+        self._note_prefill_blocks(off, task.total)
         try:
             tok, lp, plp, self.caches, self.state, key = self._chunk_step(
                 self.params, self.caches, self.state,
@@ -623,6 +632,28 @@ class PagedInferenceEngine(InferenceEngine):
         if self.prefill_queue.advance(task, n):
             self._finish_prefill(i, task, tok, lp, key)
         return 1
+
+    def _note_prefill_blocks(self, off: int, total: int) -> None:
+        """Set `engine_prefill_live_block_share` for the chunk about to
+        run: the trips the chunk kernel's loops take (a query tile's, over
+        the blocks its queries see below the prompt's end) over the blocks
+        the row's table holds a query tile, from the host's offset and
+        length through the kernel's own loop bounds. The twin of
+        `engine_decode_live_block_share`; the journal's `serve_ticks`
+        carries both counts summed over every chunk."""
+        from megatron_tpu.ops.pallas.flash_template import (
+            chunk_blocks_visited)
+
+        cfg = self.cfg
+        visited, held = chunk_blocks_visited(
+            off, self.prefill_chunk, total,
+            cfg.num_attention_heads // cfg.n_kv_heads, self.max_pages,
+            self.page_size, self._kernel_kv_heads(),
+            window=cfg.attention_kind.sliding_window_size)
+        self.stats["prefill_blocks_visited"] += visited
+        self.stats["prefill_blocks_held"] += held
+        self.stats["prefill_live_block_share"] = visited / held
+        self._m_prefill_blocks.set(visited / held)
 
     def _finish_prefill(self, i: int, task: PrefillTask, tok, lp, key):
         """The prompt's last chunk is dispatched: publish the slot's table

@@ -216,6 +216,7 @@ def attention_block(
                 softmax_fp32=cfg.softmax_fp32,
                 kv_lengths=kv_lengths,
                 page_table=table,
+                kv_end=page_write_end,
             )
     with jax.named_scope("attn_out"):
         if tp_comm is not None and "attn_out" in tp_comm.sites:

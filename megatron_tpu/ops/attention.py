@@ -97,6 +97,16 @@ def _shard_plan(what: str, batch: int, kv_heads: int):
     return True, (mesh.axis_names, batch_axes or None, head_axis)
 
 
+def _head_shards(plan) -> int:
+    """How many shards a plan splits the heads into (1: no wrapper, or a
+    mesh without a "tensor" axis)."""
+    from jax.sharding import get_abstract_mesh
+
+    if plan is None or plan[2] is None:
+        return 1
+    return dict(get_abstract_mesh().shape)[plan[2]]
+
+
 def _per_shard(plan, kernel, args, paged: bool = False):
     """kernel(*args) once per shard of the plan's mesh. Each argument's
     rank says what it is: 4 = [B, S, H, D] activations (batch and heads
@@ -154,6 +164,7 @@ def attention(
     softmax_fp32: bool = True,
     kv_lengths: Optional[jnp.ndarray] = None,  # [B] valid-prefix lengths
     page_table: Optional[jnp.ndarray] = None,  # [B, max_pages] int32
+    kv_end=None,  # scalar: positions a paged row holds (a prefill chunk)
 ) -> jnp.ndarray:
     """Scaled dot-product attention with GQA. Returns [B, Sq, Hq, D].
 
@@ -176,17 +187,38 @@ def attention(
     as a pool whose pages are whole rows). With kv_lengths (decode) the
     TPU path is the paged flash-decode kernel
     (flash_template.paged_flash_decode) which resolves pages inside its
-    own loop over a row's live blocks; everywhere else the pages are gathered into a dense [B, S, ...]
-    view and the existing masked paths compute identical values (the
-    gather is exact — pages hold the same bits a dense cache would).
+    own loop over a row's live blocks. Without kv_lengths and with more
+    than one query a row (a prefill chunk, a whole prompt into a slot
+    cache, one-shot generation's first pass: every row's queries at the
+    scalar q_offset ..) the TPU path is that loop at a chunk's query
+    count (flash_template.paged_flash_chunk): it walks the blocks the
+    queries can see and no others, where the dense path gathers the
+    table's every page. It takes a causal call without dropout or padding
+    mask whose heads divide over the mesh and whose pages it can address
+    and read a head out of (`chunk_supported`: a page size that is a
+    multiple of 8, which the engines' are and a one-shot generation's row,
+    sized to its request, may not be; float32, or bfloat16 at one kv head
+    or an even number a shard); one query a row at a scalar offset
+    (one-shot generation's steps) keeps the dense path. Everywhere else
+    the pages are gathered into a dense [B, S, ...] view and the existing
+    masked paths compute identical values (the gather is exact: pages
+    hold the same bits a dense cache would).
+
+    kv_end: with a page table and a scalar q_offset, the positions a row
+    holds once this call's keys are written (a chunk's page_write_end:
+    behind it lie a short prompt's padded tail, parked on the scratch
+    page). The chunk kernel reads no key at or past it, and query tiles
+    wholly at or past it come back zero: their rows are padding, which no
+    caller reads (the dense path gives them what the scratch page holds).
+    None: q_offset + Sq.
     """
     if page_table is not None:
         from megatron_tpu.ops import kv_store
 
+        _, page_size, kv_heads, _ = kv_store.pool_dims(k)
         if (kv_lengths is not None
                 and impl == "pallas" and _kernels_dispatchable()):
-            use, plan = _shard_plan("paged decode", q.shape[0],
-                                    kv_store.pool_dims(k)[2])
+            use, plan = _shard_plan("paged decode", q.shape[0], kv_heads)
             if use:
                 # q_len > 1 is the multi-query decode (speculative verify:
                 # k+1 query rows per slot, each one position deeper)
@@ -198,6 +230,28 @@ def attention(
                     plan,
                     functools.partial(fn, sliding_window=sliding_window),
                     (q, k, v, page_table, kv_lengths), paged=True)
+        if (kv_lengths is None and q.shape[1] > 1 and mask_type == "causal"
+                and dropout == 0.0 and padding_mask is None
+                and impl == "pallas" and _kernels_dispatchable()):
+            # a prefill chunk (or a whole prompt, or one-shot generation's
+            # first pass): every row's queries at q_offset .., over what
+            # its pages hold below kv_end
+            from megatron_tpu.ops.pallas import flash_template as ft
+
+            use, plan = _shard_plan("paged chunk", q.shape[0], kv_heads)
+            if use and ft.chunk_supported(
+                    k.dtype, kv_heads // _head_shards(plan), page_size):
+                off = jnp.asarray(q_offset, jnp.int32)
+                end = (off + q.shape[1] if kv_end is None
+                       else jnp.minimum(jnp.asarray(kv_end, jnp.int32),
+                                        off + q.shape[1]))
+                rows = (q.shape[0],)
+                return _per_shard(
+                    plan,
+                    functools.partial(ft.paged_flash_chunk,
+                                      sliding_window=sliding_window),
+                    (q, k, v, page_table, jnp.broadcast_to(off, rows),
+                     jnp.broadcast_to(end, rows)), paged=True)
         # dense path (exact): materialize each row's logical context
         # from its pages, then flow into the masked einsum below unchanged
         k = kv_store.gather_pages(k, page_table)

@@ -1350,3 +1350,287 @@ def paged_flash_decode(
     _check_paged(q, k_pages, page_table)
     return _decode_call(q, k_pages, v_pages, page_table, kv_lengths,
                         window=sliding_window, name="paged_flash_decode")
+
+
+# ---------------------------------------------------------------------------
+# chunk: the decode loop at a prefill chunk's query count
+# ---------------------------------------------------------------------------
+
+
+def _head_rows(buf, slot, h: int, blk: int, kv_heads: int):
+    """Head h's keys (or values) [blk, D] out of buffer `slot` of
+    [2, blk * kv_heads, D], where a block lies as its pages do: position-
+    major, row p * kv_heads + h. A strided read of the buffer, so nothing
+    re-lays a block for the heads' sake. Mosaic's strided load moves
+    32-bit rows: a 16-bit block is read as the words that pair its rows
+    (2i low, 2i + 1 high: heads h and h + 1 of one position, kv_heads
+    even) and the head's half is widened in place, which for bfloat16 is
+    its float32 value's upper half and exact both ways (`chunk_supported`
+    keeps every other 16-bit type off the kernel)."""
+    if kv_heads == 1:
+        return buf[slot]
+    if buf.dtype.itemsize == 4:
+        return buf[slot, pl.ds(h, blk, stride=kv_heads), :]
+    words = buf.bitcast(jnp.uint32)[
+        slot, pl.ds(h // 2, blk, stride=kv_heads // 2), :]
+    bits = (words & jnp.uint32(0xFFFF0000)) if h % 2 else (words << 16)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32).astype(buf.dtype)
+
+
+def chunk_supported(dtype, kv_heads: int, page_size: int) -> bool:
+    """Whether the chunk kernel takes pools of this dtype, this many
+    (local) kv heads and this page size: pages the copies can address
+    (`_check_paged`: a slot cache's row is a page, and one-shot generation
+    sizes its rows to the request) that `_head_rows` can read a head out
+    of, float32 always, bfloat16 at one head or an even number."""
+    dtype = jnp.dtype(dtype)
+    return page_size % 8 == 0 and (dtype.itemsize == 4 or (
+        dtype == jnp.bfloat16 and (kv_heads == 1 or kv_heads % 2 == 0)))
+
+
+def _chunk_kernel(offs_ref, ends_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, m_scr, l_scr, acc_scr,
+                  *, scale: float, window: Optional[int], unit: int,
+                  units: int, parts: int, n_blocks: int, kv_heads: int,
+                  groups: int, tq: int):
+    """The decode kernel's loop at a prefill chunk's query count: grid
+    (rows, query tiles), one tile of `tq` query positions x every head a
+    step. The tile's queries sit at offs[b] + qi * tq ..; they see keys
+    up to themselves, from the window's lower edge, and none at or past
+    ends[b] (what the row holds once this chunk is written: behind it lie
+    a short prompt's padded tail and the scratch page). A tile wholly
+    behind ends[b] makes no trip and writes zeros.
+
+    The loop walks the tile's live blocks through the page table as
+    `_decode_kernel` does, a page copied once a tile into one of two
+    buffers, only the units that hold a visible position. What does not
+    carry over is the body. Here each kv head's queries, [groups * tq, D]
+    (head-major: row g * tq + i is head g's query i), meet that head's
+    keys alone, read out of the block where its pages put them
+    (`_head_rows`); operands in the dtype they arrive in, products, the
+    softmax's statistics and the accumulator in float32 (`_dot`: the
+    training kernels' contract). A block every pair of which is visible
+    runs without positions, mask or selects; any other under the mask,
+    its values zeroed where no copy filled them (a probability of 0 times
+    a NaN is a NaN), so no byte outside the row's live pages reaches the
+    result."""
+    b, qi = pl.program_id(0), pl.program_id(1)
+    blk = unit * units
+    rows = groups * tq
+    q_lo = offs_ref[b] + qi * tq
+    row_end = ends_ref[b]
+    first, end = chunk_trips(q_lo, tq, row_end, window, blk, n_blocks)
+    first_unit, end_unit = chunk_trips(q_lo, tq, row_end, window, unit,
+                                       n_blocks * units)
+
+    def copies(j, slot, act):
+        def one(e, _):
+            page = table_ref[b, e // parts] * parts + e % parts
+            at = (e - j * units) * unit * kv_heads
+            for c, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                getattr(pltpu.make_async_copy(
+                    hbm.at[page],
+                    buf.at[slot, pl.ds(at, unit * kv_heads)],
+                    sems.at[c, slot]), act)()
+            return _
+        jax.lax.fori_loop(jnp.maximum(j * units, first_unit),
+                          jnp.minimum((j + 1) * units, end_unit), one, None)
+
+    m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(first < end)
+    def _first():
+        copies(first, 0, "start")
+
+    def visit(slot, allowed, v_live):
+        """The online-softmax step of every head over the block in
+        buffer `slot`; allowed None: every pair visible."""
+        for h in range(kv_heads):
+            q = q_ref[0, h * groups:(h + 1) * groups].reshape(rows, -1)
+            k = _head_rows(k_buf, slot, h, blk, kv_heads)
+            s = _dot(q, k, _NT) * scale                  # [rows, blk] f32
+            if allowed is not None:
+                s = jnp.where(allowed, s, _NEG_INF)
+            m_prev = m_scr[h]                            # [rows, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            v = _head_rows(v_buf, slot, h, blk, kv_heads)
+            if allowed is not None:
+                # a row the mask leaves nothing of has m_new at _NEG_INF
+                p = jnp.where(allowed, p, 0.0)
+                v = jnp.where(v_live, v, jnp.zeros_like(v))
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + _dot(p.astype(v.dtype), v, _NN)
+            m_scr[h] = m_new
+
+    def block(j, _):
+        slot = (j - first) % 2
+
+        @pl.when(j + 1 < end)
+        def _next():
+            copies(j + 1, 1 - slot, "start")
+
+        copies(j, slot, "wait")
+        k_lo = j * blk
+        interior = masks.chunk_block_interior(q_lo, tq, k_lo, blk, row_end,
+                                              window=window)
+
+        @pl.when(interior)
+        def _interior():
+            visit(slot, None, None)
+
+        @pl.when(jnp.logical_not(interior))
+        def _edge():
+            q_pos, k_pos = masks.chunk_positions(q_lo, k_lo, tq, groups, blk)
+            allowed = (masks.visible(q_pos, k_pos, causal=True, window=window)
+                       & (k_pos < row_end))
+            v_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
+            v_live = (masks.decode_position_live(v_pos, q_lo + 1, tq,
+                                                 window=window)
+                      & (v_pos < row_end))
+            visit(slot, allowed, v_live)
+        return _
+
+    jax.lax.fori_loop(first, end, block, None)
+    for h in range(kv_heads):
+        l = jnp.maximum(l_scr[h], 1e-30)
+        o_ref[0, h * groups:(h + 1) * groups] = (acc_scr[h] / l).reshape(
+            groups, tq, -1).astype(o_ref.dtype)
+
+
+# a chunk's query tile against one head's block: about this many query
+# rows ([groups * tq, D], what a head's matmuls see), and at most this
+# many scores ([groups * tq, block] float32: 2 MB a temporary). On the
+# v5e (tools/chunk_kernel_bench.py): Mistral's 4 groups run tiles of 128
+# positions against blocks of 256, Jamba2's 20 heads over one tiles of 32
+# against blocks of 512: 0.08 / 0.07 ms a call for a whole first chunk of
+# 512, 0.61 / 0.69 at the window's depth and at offset 3584 of 4000.
+_CHUNK_TILE_ROWS = 512
+_CHUNK_TILE_SCORES = 1 << 19
+
+
+def _chunk_geometry(s: int, groups: int, table_width: int, page_size: int,
+                    kv_heads: int) -> Tuple[int, int, int, int, int]:
+    """(tq, unit, units, parts, n_blocks) of a chunk call: query tiles of
+    the power of two of positions, 16 to 128, that reaches
+    _CHUNK_TILE_ROWS rows over a head's groups (a short prompt in a long
+    chunk then leaves whole tiles behind its end; one tile where that
+    does not divide the chunk, which the interpreter takes), and
+    `_decode_geometry`'s blocks, of the power of two of positions that
+    keeps a tile's scores against one head's block under
+    _CHUNK_TILE_SCORES."""
+    tq = -(-_CHUNK_TILE_ROWS // groups)
+    tq = min(max(1 << (tq - 1).bit_length(), 16), 128)
+    if s % tq:
+        tq = s
+    cap = max(128, _CHUNK_TILE_SCORES // (groups * tq))
+    cap = 1 << (cap.bit_length() - 1)
+    return (tq,) + _decode_geometry(table_width, page_size, kv_heads, cap)
+
+
+def chunk_trips(q_lo, tq: int, row_end, window: Optional[int], block: int,
+                n_blocks: int, xp=jnp):
+    """(first, end) of the kv loop of a query tile whose tq queries sit
+    at q_lo .. q_lo + tq - 1 in a row that holds row_end positions:
+    `decode_trips` for those queries, ended where the row ends; no trip
+    for a tile wholly behind it."""
+    first, end = decode_trips(q_lo + 1, tq, window, block, n_blocks, xp)
+    end = xp.minimum(end, (row_end + block - 1) // block)
+    return first, xp.where(q_lo < row_end, end, first)
+
+
+def chunk_blocks_visited(off: int, s: int, row_end: int, groups: int,
+                         table_width: int, page_size: int, kv_heads: int,
+                         window: Optional[int] = None) -> Tuple[int, int]:
+    """(blocks a chunk call visits, blocks its table holds a query tile
+    times its tiles) for one chunk of s queries at offset `off` in a row
+    that holds row_end positions, on the host: the sum of the kernel's own
+    loop bounds. The paged engine's `engine_prefill_live_block_share` is
+    the first over the second."""
+    tq, unit, units, _, n_blocks = _chunk_geometry(s, groups, table_width,
+                                                   page_size, kv_heads)
+    q_lo = off + np.arange(s // tq, dtype=np.int64) * tq
+    first, end = chunk_trips(q_lo, tq, row_end, window, unit * units,
+                             n_blocks, xp=np)
+    return int(np.maximum(end - first, 0).sum()), n_blocks * q_lo.size
+
+
+def _chunk_vmem_bytes(blk_rows: int, heads: int, rows: int, blk: int,
+                      tq: int, D: int, item: int) -> int:
+    """The two buffers each of k and v, q and o double-buffered, the
+    float32 statistics (lane-padded) and accumulator of every head, and
+    ~6 live [rows, block] float32 temporaries of the head in hand
+    (scores, the mask, p twice, the positions)."""
+    return (4 * blk_rows * D * item + 4 * heads * tq * D * item
+            + heads * tq * (D + 256) * 4 + 6 * rows * blk * 4)
+
+
+def paged_flash_chunk(
+    q: jnp.ndarray,            # [B, S, Hq, D]: one chunk a row
+    k_pages: jnp.ndarray,      # [P, ps, Hkv, D] shared page pool
+    v_pages: jnp.ndarray,      # [P, ps, Hkv, D]
+    page_table: jnp.ndarray,   # [B, max_pages] int32
+    q_offsets: jnp.ndarray,    # [B] int32, position of each row's q[0]
+    kv_ends: jnp.ndarray,      # [B] int32, positions each row holds
+    sliding_window: Optional[int] = None,
+) -> jnp.ndarray:
+    """Causal attention of a prefill chunk over paged KV: the chunk
+    instantiation of the decode specialization. Row b's S queries sit at
+    q_offsets[b] .. and see the keys its table holds up to themselves,
+    inside the window, below kv_ends[b]. Returns [B, S, Hq, D]; a query
+    tile wholly at or past kv_ends[b] comes back zero. Raises ValueError
+    for unsupported shapes."""
+    _check_paged(q, k_pages, page_table)
+    b, s, hq, d = q.shape
+    _, ps, hkv, _ = kv_store.pool_dims(k_pages)
+    groups = hq // hkv
+    if not chunk_supported(k_pages.dtype, hkv, ps):
+        raise ValueError(
+            f"paged_flash_chunk cannot read a head out of {k_pages.dtype} "
+            f"pages of {ps} positions x {hkv} kv heads")
+    tq, unit, units, parts, n_blocks = _chunk_geometry(
+        s, groups, page_table.shape[1], ps, hkv)
+    blk = unit * units
+    rows = groups * tq
+
+    qt = jnp.transpose(q, (0, 2, 1, 3))              # [B, Hq, S, D]
+    kt = k_pages.reshape(-1, unit * hkv, d)
+    vt = v_pages.reshape(-1, unit * hkv, d)
+    kernel = functools.partial(
+        _chunk_kernel, scale=float(1.0 / (d ** 0.5)), window=sliding_window,
+        unit=unit, units=units, parts=parts, n_blocks=n_blocks,
+        kv_heads=hkv, groups=groups, tq=tq)
+    vmem = _chunk_vmem_bytes(blk * hkv, hq, rows, blk, tq, d,
+                             k_pages.dtype.itemsize)
+    q_map = lambda bi, qi, offs, ends, pt: (bi, 0, qi, 0)  # noqa: E731
+    o = _named_pallas_call(
+        "paged_flash_chunk", kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, s // tq),
+            in_specs=[
+                pl.BlockSpec((1, hq, tq, d), q_map),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, hq, tq, d), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((2, blk * hkv, d), k_pages.dtype),
+                pltpu.VMEM((2, blk * hkv, d), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hkv, rows, 1), jnp.float32),
+                pltpu.VMEM((hkv, rows, 1), jnp.float32),
+                pltpu.VMEM((hkv, rows, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, hq, s, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=(min(vmem, _MAX_SCOPED_VMEM)
+                              if vmem > _DEFAULT_SCOPED_VMEM else None)),
+        interpret=_interpret(),
+    )(jnp.asarray(q_offsets, jnp.int32), jnp.asarray(kv_ends, jnp.int32),
+      jnp.asarray(page_table, jnp.int32), qt, kt, vt)
+    return jnp.transpose(o, (0, 2, 1, 3))

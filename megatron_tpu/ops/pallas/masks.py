@@ -117,6 +117,31 @@ def decode_same_head(block_k: int, rows: int, kv_heads: int):
             == jax.lax.broadcasted_iota(jnp.int32, shape, 1) % kv_heads)
 
 
+def chunk_positions(q_lo, k_lo, tq: int, groups: int, block_k: int):
+    """(q_pos, k_pos) [groups * tq, BK] grids for a prefill chunk's query
+    tile against ONE kv head's keys: the tile stacks the head's grouped
+    query heads (head-major: row g * tq + i is query i, at q_lo + i), the
+    columns are the block's positions from k_lo. Traced scalars or ints."""
+    shape = (groups * tq, block_k)
+    q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 0) % tq
+    k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return q_pos, k_pos
+
+
+def chunk_block_interior(q_lo, tq: int, k_lo, block_k: int, row_end, *,
+                         window: Optional[int] = None):
+    """True iff EVERY key of the block [k_lo, k_lo + BK) is visible to
+    every query of the tile [q_lo, q_lo + tq) of a row that holds row_end
+    positions: at or before the shallowest query, inside the deepest
+    one's window, below the row's end. The chunk kernel runs such a block
+    without mask arithmetic."""
+    k_hi = k_lo + block_k - 1
+    interior = (k_hi <= q_lo) & (k_hi < row_end)
+    if window is not None:
+        interior = interior & (k_lo > q_lo + tq - 1 - window)
+    return interior
+
+
 # ---------------------------------------------------------------------------
 # block-level skip predicates (the interval form)
 # ---------------------------------------------------------------------------

@@ -183,7 +183,26 @@ def _kernel_cases():
                 [((_DECODE_SLOTS, sq, hq, D), bf16), pool, pool,
                  ((_DECODE_SLOTS, entries), i32), ((_DECODE_SLOTS,), i32)],
                 ["paged_flash_decode"]))
+    # the prefill chunk at both served cells' shapes (one chunk of 512 of
+    # one prompt, the same tables and pools), and a slot cache's whole
+    # prompt (pages that are whole rows: the kernel's `parts` form)
+    for cell, (entries, hq, hkv, window, pages) in _SERVED_DECODE.items():
+        pool = ((pages, 16, hkv, D), bf16)
+        cases.append((
+            f"paged_chunk_{cell}", functools.partial(_chunk_cell, window),
+            [((1, _CHUNK, hq, D), bf16), pool, pool, ((1, entries), i32),
+             ((1,), i32), ((1,), i32)], ["paged_flash_chunk"]))
+    cases.append((
+        "paged_chunk_slot_rows", functools.partial(_chunk_cell, WINDOW),
+        [((SLOTS, _CHUNK, HQ, D), bf16), kv_cache, kv_cache,
+         ((SLOTS, 1), i32), ((SLOTS,), i32), ((SLOTS,), i32)],
+        ["paged_flash_chunk"]))
     return cases
+
+
+def _chunk_cell(window, q, kp, vp, table, offs, ends):
+    return ft.paged_flash_chunk(q, kp, vp, table, offs, ends,
+                                sliding_window=window)
 
 
 def _fwd_bwd_cell(window, q, k, v):
@@ -208,6 +227,7 @@ def _paged_cell(entry, window, q, kp, vp, table, n):
 _SERVED_DECODE = {"instruct": (528, HQ, HKV, WINDOW, 8 * 17000),
                   "reasoning": (256, 20, 1, None, 2 * 16640)}
 _DECODE_SLOTS = 64
+_CHUNK = 512      # --serve_prefill_chunk of both served cells
 _CASES = _kernel_cases()
 _KERNEL_TEXTS = {}
 
@@ -328,39 +348,56 @@ def test_decode_block_fits_the_default_scoped_vmem(name):
             <= ft._DEFAULT_SCOPED_VMEM)
 
 
-def test_paged_decode_partitions_over_tp2_dp2(topo):
-    """The paged decode under a mesh, as the TP-sharded engines run it:
-    `ops/attention.py` wraps the kernel in one `shard_map` over every
-    axis (slots over `data`, kv heads over `tensor`, the pools' pages
-    whole on every chip), so each chip's kernel loops over its own
-    slots' tables with 4 of the 8 kv heads, 32 pages a block; the pools
-    stay where they lie (no all-gather, no copy of a pool's shape)."""
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_paged_decode_partitions_over_tp2_dp2(topo, step):
+    """The paged kernels under a mesh, as the TP-sharded engines run
+    them: `ops/attention.py` wraps the kernel in one `shard_map` over
+    every axis (slots over `data`, kv heads over `tensor`, the pools'
+    pages whole on every chip), so each chip's kernel loops over its own
+    rows' tables with 4 of the 8 kv heads (the decode step's 64 slots,
+    32 pages a block; a prefill chunk's one row at its scalar offset and
+    end, on a TP 2 replica); the pools stay where they lie (no
+    all-gather, no copy of a pool's shape)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from megatron_tpu.parallel.mesh import build_mesh
 
-    rt = build_mesh(ParallelConfig(tensor_parallel=2), devices=topo.devices)
+    # a chunk is ONE row: it takes the kernel where the batch axes hold
+    # one device (a TP-only replica), and the dense path, said out loud,
+    # where they hold more (`_shard_plan`: the one mesh condition)
+    devices = topo.devices if step == "decode" else topo.devices[:2]
+    rt = build_mesh(ParallelConfig(tensor_parallel=2), devices=devices)
     entries, hq, hkv, window, _ = _SERVED_DECODE["instruct"]
     pages = 17000
-    rows, heads = P(("data", "expert")), P(None, None, "tensor", None)
-    shapes = [((_DECODE_SLOTS, 1, hq, D), jnp.bfloat16,
-               P(("data", "expert"), None, "tensor", None)),
-              ((pages, 16, hkv, D), jnp.bfloat16, heads),
-              ((pages, 16, hkv, D), jnp.bfloat16, heads),
-              ((_DECODE_SLOTS, entries), jnp.int32, rows),
-              ((_DECODE_SLOTS,), jnp.int32, rows)]
+    heads = P(None, None, "tensor", None)
+    pool = ((pages, 16, hkv, D), jnp.bfloat16, heads)
+    if step == "decode":
+        rows = P(("data", "expert"))
+        shapes = [((_DECODE_SLOTS, 1, hq, D), jnp.bfloat16,
+                   P(("data", "expert"), None, "tensor", None)), pool, pool,
+                  ((_DECODE_SLOTS, entries), jnp.int32, rows),
+                  ((_DECODE_SLOTS,), jnp.int32, rows)]
 
-    def decode(q, kp, vp, table, n):
-        return attention_mod.attention(q, kp, vp, sliding_window=window,
-                                       impl="pallas", kv_lengths=n,
-                                       page_table=table)
+        def call(q, kp, vp, table, n):
+            return attention_mod.attention(q, kp, vp, sliding_window=window,
+                                           impl="pallas", kv_lengths=n,
+                                           page_table=table)
+    else:
+        shapes = [((1, _CHUNK, hq, D), jnp.bfloat16, heads), pool, pool,
+                  ((1, entries), jnp.int32, P()), ((), jnp.int32, P()),
+                  ((), jnp.int32, P())]
+
+        def call(q, kp, vp, table, off, end):
+            return attention_mod.attention(q, kp, vp, sliding_window=window,
+                                           impl="pallas", q_offset=off,
+                                           page_table=table, kv_end=end)
 
     with jax.sharding.set_mesh(rt.mesh):
-        text = jax.jit(decode).lower(*[
+        text = jax.jit(call).lower(*[
             jax.ShapeDtypeStruct(shape, dtype,
                                  sharding=NamedSharding(rt.mesh, spec))
             for shape, dtype, spec in shapes]).compile().as_text()
-    assert _kernels_named(text) == ["paged_flash_decode"]
+    assert _kernels_named(text) == [f"paged_flash_{step}"]
     assert not re.search(r"\ball-gather(-start)?\(", text)
     assert f"bf16[{pages},16,{hkv // 2},{D}]" in text
     assert not re.search(rf"= bf16\[{pages},16,\d+,{D}\]\S* (copy|fusion)\(",
@@ -1299,7 +1336,8 @@ def test_serving_step_writes_the_cache_in_place(topo, serve_cfg, case):
              in line.split(" fusion(")[0]]
     assert all(re.search(r'op_name="[^"]*/scatter"', line)
                for line in fused), fused[:1]
-    kernels = {"decode": ["paged_flash_decode"], "chunk": [],
+    kernels = {"decode": ["paged_flash_decode"],
+               "chunk": ["paged_flash_chunk"],
                "slots": ["paged_flash_decode"]}[case.split("-")[0]]
     assert _kernels_named(text) == kernels
 
@@ -1396,6 +1434,65 @@ def test_served_step_reads_a_layers_weights_where_they_lie(topo, cell, step):
     weights = {stacks[k] for k in ("wq", "wk", "wv")}
     for name, shapes in products:                                    # (b)
         assert shapes & weights, (name, shapes)
+
+
+@pytest.mark.parametrize("cell", ["serve_mistral7b_instruct",
+                                  "serve_jamba2_3b_reasoning"])
+def test_served_chunk_attends_the_pages_where_they_lie(topo, cell):
+    """A prefill chunk's attention is the chunk kernel over the pool
+    (`flash_template.paged_flash_chunk`, under `attention/attn_core`), so
+    the compiled chunk step of each served cell
+
+    (a) neither copies nor slices out the row's context
+        (`step_program.relaid_arrays` finds nothing of the size of the
+        table's pages of one layer's keys: the gather is gone);
+    (b) holds no instruction whose result runs the sequence limit's
+        length (the dense scores and their mask were [.., 512, limit]
+        float32, the gathered keys [limit, kv heads, 128]);
+    (c) asks for the scoped VMEM the kernel's formula gives (none of its
+        own inside Mosaic's default, where both cells' are).
+
+    On the parent of PR 58 (6f6f86b) the Mistral program gathers
+    `bf16[528,16,8,128]` twice a layer (17.3 MB each) and forms
+    `f32[1,8,4,512,8448]` scores; the Jamba program `bf16[256,16,1,128]`
+    and `f32[1,1,20,512,4096]`."""
+    from megatron_tpu.analysis import step_program
+
+    text, cfg = _served_step_text(topo, cell, "chunk")
+    from megatron_tpu.telemetry.tracing.events import kernel_of
+
+    stacks = [toks for toks in _kernel_name_stacks(text)
+              if kernel_of(toks) == "paged_flash_chunk"]
+    assert stacks and all("attention" in toks and "attn_core" in toks
+                          for toks in stacks)
+    entries, page = {"serve_mistral7b_instruct": (528, 16),
+                     "serve_jamba2_3b_reasoning": (256, 16)}[cell]
+    limit = entries * page
+    gathered = entries * page * cfg.n_kv_heads * cfg.head_dim * 2
+    moved = [r for r in step_program.relaid_arrays(text, gathered)
+             if cfg.head_dim in _dims(r["result"])[-1:]
+             and math.prod(_dims(r["result"])) == gathered // 2]
+    assert moved == []                                               # (a)
+    for dtype, dims, opcode in _RESULT_LINE.findall(text):           # (b)
+        if opcode in ("parameter", "get-tuple-element"):
+            continue    # the table's row (s32[1, entries]) and the rotary
+        shape = [int(d) for d in dims.split(",") if d]
+        assert not (limit in shape and math.prod(shape) >= limit * _CHUNK
+                    ), (opcode, dtype, dims)
+    asked = re.search(r"%paged_flash_chunk(?:\.\d+)? = .*tpu_custom_call.*"
+                      r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                      text)
+    groups = cfg.num_attention_heads // cfg.n_kv_heads
+    tq, unit, units, _, _ = ft._chunk_geometry(_CHUNK, groups, entries, page,
+                                               cfg.n_kv_heads)
+    want = ft._chunk_vmem_bytes(unit * units * cfg.n_kv_heads,
+                                cfg.num_attention_heads, groups * tq,
+                                unit * units, tq, cfg.head_dim, 2)
+    # a launch inside Mosaic's default asks for no limit of its own, and
+    # the compile is the compiler's word that it fits
+    assert want <= ft._MAX_SCOPED_VMEM
+    assert (int(asked.group(1)) if asked else ft._DEFAULT_SCOPED_VMEM
+            ) == max(want, ft._DEFAULT_SCOPED_VMEM)                  # (c)
 
 
 # ---------------------------------------------------------------------------

@@ -335,6 +335,77 @@ def test_engine_reports_the_live_block_share(engine):
     assert "engine_decode_live_block_share " in reg.render()
 
 
+@pytest.mark.parametrize("window", [None, 128], ids=["full", "window"])
+def test_engine_reports_the_prefill_live_block_share(window):
+    """`engine_prefill_live_block_share`, set as each chunk is dispatched
+    from the host's offset and the prompt's length, is what the chunk
+    kernel's loop bounds give (a query tile's trips, over the blocks its
+    queries see below the prompt's end) over the blocks the table holds a
+    query tile: checked chunk by chunk against the block predicate. Unset
+    until a chunk has run; the counts add up in `stats` and in the
+    journal's `serve_ticks`."""
+    import jax
+
+    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.models import presets
+    from megatron_tpu.models.params import init_params
+    from megatron_tpu.ops.pallas import flash_template as ft
+    from megatron_tpu.ops.pallas import masks
+    from megatron_tpu.telemetry.metrics import MetricsRegistry
+
+    # 16 kv heads: a block of 128 positions, four to the table
+    cfg = presets.tiny(vocab_size=64, seq_length=512, num_layers=1,
+                       num_attention_heads=16, num_kv_heads=16,
+                       sliding_window_size=window)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    reg = MetricsRegistry()
+    chunk, ps, entries = 64, 8, 64
+    eng = PagedInferenceEngine(cfg, params, num_slots=2, max_seq_len=512,
+                               page_size=ps, prefill_chunk=chunk,
+                               metrics=reg)
+    groups = cfg.num_attention_heads // cfg.n_kv_heads
+    tq, unit, units, _, n_blocks = ft._chunk_geometry(
+        chunk, groups, entries, ps, cfg.n_kv_heads)
+    blk = unit * units
+    assert (blk, n_blocks) == (128, 4)
+    assert "prefill_live_block_share" not in eng.stats
+    assert reg.get("engine_prefill_live_block_share").value() == 0
+    assert eng._serve_ticks_fields()["prefill_blocks"] == [0, 0]
+    seen = []
+    note = eng._note_prefill_blocks
+
+    def spy(off, total):
+        note(off, total)
+        want = 0
+        for q_lo in range(off, off + chunk, tq):
+            last = min(q_lo + tq, total) - 1     # the deepest live query
+            want += sum(
+                bool(masks.block_live(ki, blk, q_lo, last, window=window))
+                for ki in range(n_blocks)) if q_lo < total else 0
+        seen.append((off, total, want / (n_blocks * (chunk // tq)),
+                     eng.stats["prefill_live_block_share"],
+                     reg.get("engine_prefill_live_block_share").value()))
+
+    eng._note_prefill_blocks = spy
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(1, 64, (2, 300)).astype(np.int32)
+    eng.generate(prompts, np.asarray([300, 5], np.int32), max_new_tokens=3)
+    # 300 tokens in chunks of 64: offsets 0 .. 256; 5 tokens: one chunk
+    assert [(off, total) for off, total, *_ in seen] == [
+        (0, 300), (64, 300), (128, 300), (192, 300), (256, 300), (0, 5)]
+    for _, _, want, stat, gauge in seen:
+        assert want == stat == gauge
+    # one block of four, then two, then three: all of them without a
+    # window, the newest two behind one
+    assert [s[2] for s in seen] == (
+        [.25, .25, .5, .5, .75, .25] if window is None
+        else [.25, .25, .5, .5, .5, .25])
+    visited, held = eng._serve_ticks_fields()["prefill_blocks"]
+    assert held == len(seen) * n_blocks * (chunk // tq)
+    assert visited == round(sum(s[2] for s in seen) * held / len(seen))
+    assert "engine_prefill_live_block_share " in reg.render()
+
+
 def test_paged_flash_decode_rejects_bad_shapes():
     import jax.numpy as jnp
 

@@ -381,6 +381,109 @@ def test_decode_reads_nothing_but_the_rows_live_positions(
     np.testing.assert_allclose(got[sees], want[sees], rtol=2e-3, atol=2e-3)
 
 
+# name: (kv heads, groups, page size or "row", table pages, chunk, offset,
+#        row end or None, window, write start, dtype)
+_CHUNK_CASES = {
+    "first_chunk": (2, 2, 16, 48, 256, 0, 256, None, 0, jnp.float32),
+    "second_chunk": (2, 2, 16, 48, 256, 256, 512, None, 0, jnp.float32),
+    "later_chunk_ends_inside": (2, 2, 16, 48, 256, 512, 700, None, 0,
+                                jnp.float32),
+    "window_crossed": (2, 2, 16, 64, 256, 640, 896, 300, 0, jnp.float32),
+    "window_at_a_block_edge": (8, 4, 16, 64, 128, 640, 768, 256, 0,
+                               jnp.float32),
+    "short_prompt_in_a_long_chunk": (2, 2, 16, 48, 256, 0, 70, None, 0,
+                                     jnp.float32),
+    "shared_prefix": (2, 2, 16, 48, 256, 63, 200, None, 64, jnp.float32),
+    "groups_4_bf16": (8, 4, 16, 40, 128, 256, 384, 200, 0, jnp.bfloat16),
+    "groups_20_over_1": (1, 20, 16, 24, 128, 128, 250, None, 0,
+                         jnp.float32),
+    "groups_20_over_1_bf16": (1, 20, 16, 24, 128, 0, 128, None, 0,
+                              jnp.bfloat16),
+    "chunk_narrower_than_a_block": (8, 4, 8, 96, 32, 600, 632, None, 0,
+                                    jnp.float32),
+    "slot_rows_from_zero": (2, 2, "row", 1, 128, 0, None, None, 0,
+                            jnp.float32),
+    "slot_rows_later": (8, 4, "row", 1, 128, 256, None, 300, 0,
+                        jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("name", list(_CHUNK_CASES))
+def test_chunk_kernel_matches_the_dense_path_it_replaces(monkeypatch, name):
+    """A prefill chunk through the store and the dispatch, as a layer
+    makes the call (`kv_store.write` with the chunk's fences, `read`,
+    `attention(..., page_table=, kv_end=)` under impl "pallas" with the
+    interpreter forced: `paged_flash_chunk`), against the masked einsum
+    over a clean dense cache. The pool holds NaN at every position no live
+    query sees: the scratch page, pages the row does not own, the pages
+    behind the window (whose table entries park on scratch, as the
+    engine's do), the tail of the row's last page and everything behind
+    the row's end; the row's pages lie in shuffled physical order, and the
+    call reads layer 1 of a store of two. A slot cache comes as whole-row
+    pages (`read`'s table; two rows). Rows at or past the row's end are
+    padding: finite, and zero where their whole query tile is."""
+    from megatron_tpu.ops import kv_store
+    from megatron_tpu.ops.pallas import flash_template as ft
+
+    (hkv, groups, page, n_pages, s, off, row_end, window, write_start,
+     dtype) = _CHUNK_CASES[name]
+    monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+    d, slots = 16, page == "row"
+    b = 2 if slots else 1
+    seq = 512 if slots else n_pages * page
+    end = off + s if row_end is None else row_end
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.standard_normal((b, s, hkv * groups, d)), dtype)
+    k = rng.standard_normal((b, seq, hkv, d)).astype(np.float32)
+    v = rng.standard_normal((b, seq, hkv, d)).astype(np.float32)
+    pos = np.arange(seq)
+    seen = pos < end
+    if window is not None:
+        seen &= pos > off - window
+    # what the store holds before the chunk: the live context below the
+    # write fence; the chunk brings the rest
+    before = np.where((seen & (pos < max(write_start, off)))[None, :, None,
+                                                             None],
+                      np.stack([k, v]).reshape(2 * b, seq, hkv, d), np.nan
+                      ).reshape(2, b, seq, hkv, d)
+    if slots:
+        table = None
+        store = tuple(jnp.asarray(np.stack([np.full_like(x, np.nan), x]),
+                                  dtype) for x in before)
+    else:
+        n = seq // page
+        ids = rng.permutation(np.arange(1, 3 * n))[:n]
+        owned = seen.reshape(n, page).any(axis=1)
+        table = jnp.asarray(np.where(owned, ids, 0)[None], jnp.int32)
+        pools = np.full((2, 2, 3 * n, page, hkv, d), np.nan, np.float32)
+        pools[:, 1, ids[owned]] = before.reshape(2, n, page, hkv, d)[:, owned]
+        store = tuple(jnp.asarray(x, dtype) for x in pools)
+    store = kv_store.write(
+        store, 1, jnp.asarray(k[:, off:off + s], dtype),
+        jnp.asarray(v[:, off:off + s], dtype), jnp.int32(off), table,
+        None if slots else jnp.int32(write_start),
+        None if slots else jnp.int32(end))
+    kp, vp, tbl = kv_store.read(store, 1, table, dtype)
+    got = np.asarray(attention(
+        q, kp, vp, sliding_window=window, q_offset=jnp.int32(off),
+        impl="pallas", page_table=tbl,
+        kv_end=None if row_end is None else jnp.int32(row_end)
+    ).astype(jnp.float32))
+    want = np.asarray(attention(
+        q, jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+        sliding_window=window, q_offset=off, impl="xla"
+    ).astype(jnp.float32))
+    assert np.isfinite(got).all()
+    live = off + np.arange(s) < end
+    tol = 2e-3 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[:, live], want[:, live], rtol=tol,
+                               atol=tol)
+    tq = ft._chunk_geometry(s, groups, 1 if slots else n_pages,
+                            seq if slots else page, hkv)[0]
+    dead_tiles = off + (np.arange(s) // tq) * tq >= end
+    np.testing.assert_array_equal(got[:, dead_tiles], 0.0)
+
+
 # ---------------------------------------------------------------------------
 # 3. dispatch gates
 # ---------------------------------------------------------------------------

@@ -18,6 +18,7 @@ The offered-load throughput check is `slow` (it times real compiled
 steps); everything else is tier-1.
 """
 
+import dataclasses
 import json
 import time
 import threading
@@ -43,18 +44,18 @@ CFG = presets.tiny(vocab_size=64, seq_length=64)
 PARAMS = init_params(CFG, jax.random.PRNGKey(0))
 
 
-def make_engine(**kw):
+def make_engine(cfg=CFG, **kw):
     kw.setdefault("num_slots", 4)
     kw.setdefault("max_seq_len", 64)
-    return InferenceEngine(CFG, PARAMS, **kw)
+    return InferenceEngine(cfg, PARAMS, **kw)
 
 
-def make_paged(**kw):
+def make_paged(cfg=CFG, **kw):
     kw.setdefault("num_slots", 4)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
-    return PagedInferenceEngine(CFG, PARAMS, **kw)
+    return PagedInferenceEngine(cfg, PARAMS, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -270,16 +271,31 @@ def test_slot_reuse_does_not_leak_stale_cache():
 # slot engine on the same traffic, zero decode recompiles after warmup
 
 
-def test_paged_engine_greedy_parity_multi_chunk():
+@pytest.fixture(params=["dense", "interpreted"])
+def attention_path(request, monkeypatch):
+    """The paged engine's parity tests on both of attention's paths: the
+    dense one a CPU host runs, and the kernels forced through the
+    interpreter (`interpret_forced`), where a prefill chunk runs
+    `paged_flash_chunk` and a decode tick `paged_flash_decode`, as on the
+    chip. The one-shot reference then takes the kernels too (its whole
+    prompt is a chunk over a slot cache's rows). Returns the model's
+    configuration for that path."""
+    if request.param == "dense":
+        return CFG
+    monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+    return dataclasses.replace(CFG, attention_impl="pallas")
+
+
+def test_paged_engine_greedy_parity_multi_chunk(attention_path):
     """Greedy decode through the paged engine (chunked prefill crossing
     page boundaries) is token-identical to the one-shot path, full
     logprob rows included."""
     prompts = np.asarray([[3, 7, 11, 2, 9, 4, 1, 8, 5, 2]], np.int32)
     lengths = np.asarray([10], np.int32)
-    want = generate_tokens(CFG, PARAMS, prompts, lengths, max_new_tokens=8,
-                           temperature=0.0)
+    want = generate_tokens(attention_path, PARAMS, prompts, lengths,
+                           max_new_tokens=8, temperature=0.0)
     # chunk 4 < prompt 10 < 2 pages: 3 chunks, page-spanning writes
-    eng = make_paged(prefill_chunk=4)
+    eng = make_paged(attention_path, prefill_chunk=4)
     got = eng.generate(prompts, lengths, max_new_tokens=8, temperature=0.0)
     np.testing.assert_array_equal(got.tokens, want.tokens)
     np.testing.assert_allclose(got.logprobs, want.logprobs,
@@ -288,32 +304,33 @@ def test_paged_engine_greedy_parity_multi_chunk():
     assert eng.stats["decode_recompiles"] == 0
 
 
-def test_paged_engine_ragged_batch_parity():
+def test_paged_engine_ragged_batch_parity(attention_path):
     prompts = np.asarray([[3, 7, 11, 2], [5, 0, 0, 0]], np.int32)
     lengths = np.asarray([4, 1], np.int32)
-    want = generate_tokens(CFG, PARAMS, prompts, lengths, max_new_tokens=6,
-                           temperature=0.0)
-    got = make_paged().generate(prompts, lengths, max_new_tokens=6,
-                                temperature=0.0)
+    want = generate_tokens(attention_path, PARAMS, prompts, lengths,
+                           max_new_tokens=6, temperature=0.0)
+    got = make_paged(attention_path).generate(
+        prompts, lengths, max_new_tokens=6, temperature=0.0)
     np.testing.assert_array_equal(got.tokens, want.tokens)
     np.testing.assert_array_equal(got.lengths, want.lengths)
     np.testing.assert_allclose(got.logprobs, want.logprobs,
                                rtol=1e-5, atol=1e-5)
 
 
-def test_paged_engine_int8_cache_parity():
+def test_paged_engine_int8_cache_parity(attention_path):
     """int8 paged pools (quantize-on-write through the page table) match
     the one-shot int8 path."""
     prompts = np.asarray([[3, 7, 11, 2]], np.int32)
     lengths = np.asarray([4], np.int32)
-    want = generate_tokens(CFG, PARAMS, prompts, lengths, max_new_tokens=6,
-                           temperature=0.0, kv_cache_int8=True)
-    got = make_paged(kv_cache_int8=True).generate(
+    want = generate_tokens(attention_path, PARAMS, prompts, lengths,
+                           max_new_tokens=6, temperature=0.0,
+                           kv_cache_int8=True)
+    got = make_paged(attention_path, kv_cache_int8=True).generate(
         prompts, lengths, max_new_tokens=6, temperature=0.0)
     np.testing.assert_array_equal(got.tokens, want.tokens)
 
 
-def test_paged_prefix_cache_hit_parity():
+def test_paged_prefix_cache_hit_parity(attention_path):
     """A request sharing another's prompt prefix aliases its pages, skips
     the shared prefill span, and still produces identical tokens AND
     teacher-forced prompt logprobs."""
@@ -328,8 +345,8 @@ def test_paged_prefix_cache_hit_parity():
         assert r.error is None, r.error
         return r
 
-    slot = make_engine()
-    paged = make_paged()
+    slot = make_engine(attention_path)
+    paged = make_paged(attention_path)
     for prompt in (p1, p2):
         a, b = run(slot, prompt), run(paged, prompt)
         assert a.generated == b.generated
@@ -345,18 +362,20 @@ def test_paged_prefix_cache_hit_parity():
     assert paged.stats["decode_recompiles"] == 0
 
 
-def test_paged_preemption_midstream_parity():
+def test_paged_preemption_midstream_parity(attention_path):
     """Under page-pool pressure the youngest request is preempted
     mid-stream and later resumed by teacher-forced recompute — both
     requests still finish token-identical to uncontended runs (greedy
     AND sampled: the preserved PRNG chain must resume exactly)."""
     pa = np.asarray([3, 7, 11, 2, 9, 4], np.int32)
     pb = np.asarray([5, 8, 1, 6, 2, 7], np.int32)
-    kw = dict(num_slots=2, max_seq_len=32, page_size=4, prefill_chunk=8)
+    # the kernels take pages of 8 and more
+    page = 4 if attention_path is CFG else 8
+    kw = dict(num_slots=2, max_seq_len=32, page_size=page, prefill_chunk=8)
     sampled = dict(temperature=0.7, top_k=8, seed=5)
 
     def solo(prompt, **skw):
-        eng = make_paged(**kw)
+        eng = make_paged(attention_path, **kw)
         r = eng.submit(Request(prompt=prompt, max_new_tokens=16, **skw))
         eng.run_until_idle()
         assert r.error is None, r.error
@@ -364,9 +383,10 @@ def test_paged_preemption_midstream_parity():
 
     a_solo, b_solo = solo(pa), solo(pb, **sampled)
 
-    # 9 usable pages can't hold both sequences at full length (6 pages
-    # each): B (younger) gets preempted, A finishes, B resumes
-    eng = make_paged(num_pages=10, **kw)
+    # 9 usable pages of 4 (4 of 8) can't hold both sequences at full
+    # length (6 pages each; 3): B (younger) gets preempted, A finishes, B
+    # resumes
+    eng = make_paged(attention_path, num_pages=40 // page, **kw)
     ra = eng.submit(Request(prompt=pa, max_new_tokens=16))
     rb = eng.submit(Request(prompt=pb, max_new_tokens=16, **sampled))
     eng.run_until_idle()

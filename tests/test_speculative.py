@@ -29,6 +29,8 @@ code paths, one compile set; the zero model's constant greedy
 continuation also makes it the high-acceptance bench-claim fixture).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,18 +51,18 @@ DCFG = presets.tiny(vocab_size=64, seq_length=64, num_layers=2)
 DPARAMS = init_params(DCFG, jax.random.PRNGKey(7))
 
 
-def make_engine(**kw):
+def make_engine(cfg=CFG, **kw):
     kw.setdefault("num_slots", 4)
     kw.setdefault("max_seq_len", 64)
-    return InferenceEngine(CFG, PARAMS, **kw)
+    return InferenceEngine(cfg, PARAMS, **kw)
 
 
-def make_paged(**kw):
+def make_paged(cfg=CFG, **kw):
     kw.setdefault("num_slots", 4)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
-    return PagedInferenceEngine(CFG, PARAMS, **kw)
+    return PagedInferenceEngine(cfg, PARAMS, **kw)
 
 
 def run_one(eng, prompt, n=10, **kw):
@@ -513,18 +515,26 @@ def test_paged_spec_ngram_greedy_parity_multi_chunk():
 @pytest.mark.slow  # ~25s measured cacheless (3 engine compile sets:
 # paged spec model-drafter steps are the big traces); the ngram paged
 # gate + slot model-drafter gates keep the coverage in tier-1
-def test_paged_spec_model_drafter_parity_and_prefix_hit():
+@pytest.mark.parametrize("path", ["dense", "interpreted"])
+def test_paged_spec_model_drafter_parity_and_prefix_hit(monkeypatch, path):
     """Paged engine + draft model: the draft pools ride the SAME page
     tables (prefix-cache hits alias pages in both trees) — greedy
     token-identical at full acceptance, prompt logprobs exact on the
-    aliased request."""
-    base = make_engine()
+    aliased request. On the dense path a CPU host runs, and with the
+    kernels forced through the interpreter, where the drafter's chunk
+    (`draft_chunk`) and the target's run `paged_flash_chunk` over the one
+    table and the verify step `paged_flash_decode`'s multi-query form."""
+    cfg = CFG
+    if path == "interpreted":
+        monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+        cfg = dataclasses.replace(CFG, attention_impl="pallas")
+    base = make_engine(cfg)
     p1 = np.asarray([3, 7, 11, 2, 9, 4, 1, 8, 5, 2], np.int32)
     shared = p1[:8]
     p2 = np.concatenate([shared, [9, 5]]).astype(np.int32)
     a1, a2 = run_one(base, p1), run_one(base, p2, n=8)
-    eng = make_paged(speculative=SpecConfig(
-        k=3, drafter="model", draft_cfg=CFG, draft_params=PARAMS))
+    eng = make_paged(cfg, speculative=SpecConfig(
+        k=3, drafter="model", draft_cfg=cfg, draft_params=PARAMS))
     b1 = run_one(eng, p1)
     b2 = run_one(eng, p2, n=8)
     assert a1.generated == b1.generated

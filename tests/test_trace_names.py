@@ -13,7 +13,7 @@ import pytest
 
 from megatron_tpu.analysis import targets
 from megatron_tpu.telemetry.tracing.events import (
-    REGION_SCOPES, kernel_of, scope_tokens,
+    REGION_SCOPES, kernel_of, op_class, scope_tokens,
 )
 
 TRAIN_KERNELS = ("flash_fwd", "flash_bwd", "flash_bwd_stats")
@@ -200,3 +200,18 @@ def test_a_state_space_layer_runs_named_under_attention(monkeypatch,
         under = [t for t in stacks if scope in t]
         assert under and not any("ssm_mixer" in t for t in under), scope
     assert not any("attn_rope" in t for t in stacks)   # no positions
+    # the attention layers' kernel over the pages (the chunk's
+    # instantiation of the decode loop for a chunk, the decode kernel for
+    # a step) stands under `attention/attn_core`; a reader finds it by
+    # the rule and books its time as class `kernel`
+    kernel = "paged_flash_chunk" if positions > 1 else "paged_flash_decode"
+    under = [t for t in stacks if kernel in t]
+    assert under
+    for toks in under:
+        at = toks.index(kernel)
+        assert "attention" in toks[:at] and "attn_core" in toks[:at], toks
+        assert toks.index("attention") < toks.index("attn_core")
+        assert "ssm_mixer" not in toks
+        assert kernel_of(toks[:at + 1] + ["pallas_call"]) == kernel
+    assert op_class("paged_flash_chunk.3", "custom-call",
+                    kernel=True) == "kernel"
