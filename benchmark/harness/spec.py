@@ -50,10 +50,20 @@ What a PR that adds a configuration brings, all of it new:
     5. in BENCHMARK.json: the `configs` entry, a `workloads` entry a
        cell, the cell's name APPENDED to the `workloads` list of every
        metric it reports (any metric's: the tests that hold PR 23's
-       twelve and PR 34's nine hold the cells they listed in front, in
-       their order, and take a name behind them), and its own per-layer
-       entries after those that are there, each with its reader
-       <path>/layer_metrics/<reader>.py.
+       twelve, PR 34's nine and PR 54's five hold the cells they listed
+       in front, in their order, and take a name behind them), and its
+       own per-layer entries after those that are there, each with its
+       reader <path>/layer_metrics/<reader>.py. A served open-loop cell
+       appends its name to the metrics every served open-loop cell
+       lists (`request_ms_p50`, `request_ms_p95`, `engine_tpot_ms_p50`,
+       `gen_lateness_ms_max` and PR 54's five: `engine_host_ms_per_tick`,
+       `engine_rows_per_tick`, `engine_queue_ms_p95`,
+       `server_overhead_ms_p50`, `engine_stall_ms_total`), and
+       `engine_ttft_ms_p50.<mix>` and `device_idle_pct.<mix>` are entries
+       of its own (each says which request metric it moves in that mix;
+       `<mix>` is the cell's `traffic` name, and the contract test's
+       `serving_cells_contract` refuses a cell of another mix on them),
+       as are the readers of what only its architecture has.
 
 What `reduced` may cut (the `model-configs` guide, section 4; the
 contract test holds a configuration to it from its own published/
@@ -125,9 +135,14 @@ several times too high, and the driver refuses a share of a roofline
 over 105 %. Such a kernel comes under a `pallas_call(name=)` of its own,
 with kernel_costs/<that name>.py and readers that name it.
 tests/benchmark/test_benchmark_contract.py rehearses exactly such PRs on
-a copy of the tree (another block type; one chip's share with a metric of
-its own), and states every fact it states of every configuration, cell
-or metric of BENCHMARK.json of that copy too.
+a copy of the tree (`added_tree()`: another block type; one chip's share
+with a metric of its own; and, since PR 60, a served open-loop cell,
+`serve_toyfalcon_rehearsed` under the mix `added_serve_open`, its name
+behind the accepted served cells' and its two entries behind every
+other), and states every fact it states of every configuration, cell or
+metric of BENCHMARK.json of that copy too: the served cells' load,
+deployment and plan among them. A served cell added behind another RUNS,
+untraced and traced, in tests/benchmark/test_benchmark_engine_spans.py.
 """
 
 from __future__ import annotations

@@ -7,11 +7,12 @@ entries and edits no test. The rehearsal at the end is that PR: the real
 BENCHMARK.json plus the two configurations of tests/benchmark/added/
 (`toy-falcon`, another block type, cut in nothing; `toy-moe-share8`, one
 chip's share of a deployment: depth, experts held and vocabulary cut to
-the floors) and one training cell on each, in a temporary tree, under the
-same checks and through the harness on the CPU; every fact stated of
-every configuration, cell or metric of BENCHMARK.json is stated of that
-tree too, so an entry a later PR may write never meets a test for the
-first time in that PR."""
+the floors), one training cell on each and one served open-loop cell on
+the first (ISSUE 60), in a temporary tree, under the same checks and
+through the harness on the CPU; every fact stated of every
+configuration, cell or metric of BENCHMARK.json is stated of that tree
+too, so an entry a later PR may write never meets a test for the first
+time in that PR."""
 
 import json
 import os
@@ -367,6 +368,39 @@ def reader_files_contract(spec_path, *others):
     assert stems == on_disk
 
 
+def serving_cells_contract(spec_path, cell_name):
+    """A served cell offers a load fixed in its mix and searches for
+    none: an open loop a rate that is a number (four fifths of the knee,
+    whose sweep the mix names), a closed loop its callers; the engine is
+    the paged one; the check that decides `correct` samples the window's
+    own requests against a limit the mix gives with its reason."""
+    cell = spec.Cell(spec_path, cell_name)
+    mix = cell.traffic
+    assert mix["driver"] in ("serve_open", "serve_closed")
+    if mix["driver"] == "serve_open":
+        assert isinstance(mix["rate_rps"], (int, float)) and mix["rate_rps"] > 0
+        knee = mix["knee"]
+        assert abs(mix["rate_rps"] - 0.8 * knee["sustained_rps"]) < 1e-9
+        assert os.path.exists(os.path.join(REPO, knee["sweep"]))
+        # its own two entries carry the mix's name (spec.py, item 5) and
+        # list no cell of another mix
+        mixes = {w["name"]: w["traffic"] for w in cell.spec["workloads"]}
+        own = {m["name"]: m["workloads"] for m in cell.per_layer()}
+        for reader in ("engine_ttft_ms_p50", "device_idle_pct"):
+            listed = own[reader + "." + cell.traffic_name]
+            assert {mixes[c] for c in listed} == {cell.traffic_name}
+    else:
+        assert mix["clients"] >= 1 and "rate_rps" not in mix
+    assert "--serve_kv_paging" in cell.config["program"]["serve"]["flags"]
+    check = mix["check"]
+    assert set(check) == {"requests", "logit_gap_tolerance", "why"}
+    assert check["requests"] >= 4 and 0 < check["logit_gap_tolerance"] < 1
+    e2e = {m["name"] for m in cell.end_to_end()} - {"setup_s"}
+    assert e2e == ({"request_ms_p50", "request_ms_p95"}
+                   if mix["driver"] == "serve_open"
+                   else {"serve_tokens_per_s"})
+
+
 # --- BENCHMARK.json and the candidates under them ---------------------------
 
 def test_benchmark_json_has_the_contracts_keys_and_names():
@@ -402,38 +436,6 @@ def test_serving_cells_kept_ready_find_their_files(cell_name):
     assert kept & ours == {"setup_s"}     # nothing the benchmark has
 
 
-@pytest.mark.parametrize("spec_path, cell_name", [
-    (path, name) for path in (BENCHMARK, CANDIDATES)
-    for name in _names(path, "workloads") if spec.Cell(
-        path, name).traffic["driver"] != "train"],
-    ids=lambda v: os.path.basename(v))
-def test_serving_cells_fix_their_load_and_their_deployment(spec_path,
-                                                           cell_name):
-    """A served cell offers a load fixed in its mix and searches for
-    none: an open loop a rate that is a number (four fifths of the knee,
-    whose sweep the mix names), a closed loop its callers; the engine is
-    the paged one; the check that decides `correct` samples the window's
-    own requests against a limit the mix gives with its reason."""
-    cell = spec.Cell(spec_path, cell_name)
-    mix = cell.traffic
-    assert mix["driver"] in ("serve_open", "serve_closed")
-    if mix["driver"] == "serve_open":
-        assert isinstance(mix["rate_rps"], (int, float)) and mix["rate_rps"] > 0
-        knee = mix["knee"]
-        assert abs(mix["rate_rps"] - 0.8 * knee["sustained_rps"]) < 1e-9
-        assert os.path.exists(os.path.join(REPO, knee["sweep"]))
-    else:
-        assert mix["clients"] >= 1 and "rate_rps" not in mix
-    assert "--serve_kv_paging" in cell.config["program"]["serve"]["flags"]
-    check = mix["check"]
-    assert set(check) == {"requests", "logit_gap_tolerance", "why"}
-    assert check["requests"] >= 4 and 0 < check["logit_gap_tolerance"] < 1
-    e2e = {m["name"] for m in cell.end_to_end()} - {"setup_s"}
-    assert e2e == ({"request_ms_p50", "request_ms_p95"}
-                   if mix["driver"] == "serve_open"
-                   else {"serve_tokens_per_s"})
-
-
 def test_the_benchmark_serves_as_well_as_trains():
     drivers = {spec.Cell(BENCHMARK, n).traffic["driver"]
                for n in _names(BENCHMARK, "workloads")}
@@ -458,23 +460,69 @@ SHARE_METRIC = {
     "name": "moe_load_worst_step", "unit": "x", "better": "lower",
     "source": "program_counter", "layer": "mlp",
     "moves": "train_tokens_per_s", "workloads": [SHARE_CELL]}
+
+
+def own_served_entries(cell, mix):
+    """The two entries a served open-loop cell brings for its mix, by
+    readers the benchmark has: each lists that cell alone."""
+    return [
+        {"name": "engine_ttft_ms_p50." + mix, "unit": "ms",
+         "better": "lower", "source": "program_span", "layer": "engine",
+         "moves": "request_ms_p95", "workloads": [cell]},
+        {"name": "device_idle_pct." + mix, "unit": "%", "better": "lower",
+         "source": "device_trace", "layer": "device",
+         "moves": "request_ms_p50", "workloads": [cell]}]
+
+
+# a served open-loop cell on the first toy (its file has `program.serve`,
+# its reference `make_weights`, `to_program_params` and `logits`)
+SERVED_CELL = "serve_toyfalcon_rehearsed"
+SERVED_MIX = "added_serve_open"
+SERVED_METRICS = own_served_entries(SERVED_CELL, SERVED_MIX)
 # (configuration, cell, traffic mix, the configuration's why, its metrics)
 ADDED = [
     (ADDED_CONFIG, ADDED_CELL, "added_train", "another block type, added",
      []),
     (SHARE_CONFIG, SHARE_CELL, "added_share_train",
      "one chip's share of 8 that hold each layer, added", [SHARE_METRIC]),
+    (ADDED_CONFIG, SERVED_CELL, SERVED_MIX,
+     "another block type, added", SERVED_METRICS),
 ]
+
+
+def reported_by_every(s, cells):
+    """The metrics of a spec, end to end and per layer, whose `workloads`
+    hold every one of `cells`."""
+    return [m for m in s["end_to_end"] + s["per_layer"]
+            if set(cells) <= set(m.get("workloads", ()))]
+
+
+def appended_to(s, driver):
+    """The metrics of BENCHMARK.json (`s`) to whose `workloads` an added
+    one-chip cell under `driver` appends its name (spec.py, item 5): a
+    training cell to every metric SOME one-chip training cell lists (a
+    reader says nothing where its scope or kernel does not occur); a
+    served open-loop cell to every metric ALL the served open-loop cells
+    list (the two request metrics, `engine_tpot_ms_p50`,
+    `gen_lateness_ms_max`, PR 54's five), the entries of one mix or of
+    one architecture staying that cell's own."""
+    alike = [w["name"] for w in s["workloads"] if w["chips"] == 1
+             and spec.Cell(BENCHMARK, w["name"]).traffic["driver"] == driver]
+    if driver == "train":
+        return [m for m in s["end_to_end"] + s["per_layer"]
+                if set(alike) & set(m.get("workloads", ()))]
+    return reported_by_every(s, alike)
 
 
 def added_tree(root, published=True):
     """A copy of what the benchmark is made of with what such PRs bring:
     files (tests/benchmark/added: each configuration, the reference it
     names unless the benchmark has it, its published values, a traffic
-    mix, a reader) and entries (a configuration with the `reduced` its
-    file gives, a cell, the cell's name on each metric it reports, the
-    per-layer metrics of its own after those that are there). Nothing
-    that exists is edited. Returns the spec's path."""
+    mix, a served open-loop mix's sweep, a reader) and entries (a
+    configuration with the `reduced` its file gives, a cell, the cell's
+    name BEHIND those on each metric it reports, the per-layer metrics
+    of its own after those that are there). Nothing that exists is
+    edited. Returns the spec's path."""
     os.makedirs(root / "tests")
     os.symlink(os.path.join(REPO, "benchmark"), root / "benchmark")
     shutil.copytree(os.path.join(REPO, "tests", "benchmark"),
@@ -486,22 +534,23 @@ def added_tree(root, published=True):
         os.remove(root / "tests" / "benchmark" / "published"
                   / (ADDED_CONFIG + ".json"))
     s = _load(BENCHMARK)
-    # they report what the one-chip training cells report
-    alike = {w["name"] for w in s["workloads"]
-             if w["chips"] == 1 and spec.Cell(
-                 BENCHMARK, w["name"]).traffic["driver"] == "train"}
+    # what a cell reports is read off the accepted cells of its driver
+    reports = {driver: appended_to(s, driver)
+               for driver in ("train", "serve_open")}
     for config, cell, traffic, why, own in ADDED:
         file = "tests/benchmark/" + config + ".json"
         held = _load(root / file)
-        s["configs"].append({
-            "name": config, "source": held["source"], "file": file,
-            "reduced": list(held.get("reduced", {})), "why": why})
+        if config not in [c["name"] for c in s["configs"]]:
+            s["configs"].append({
+                "name": config, "source": held["source"], "file": file,
+                "reduced": list(held.get("reduced", {})), "why": why})
         s["workloads"].append({
             "name": cell, "config": config, "traffic": traffic, "chips": 1,
             "why": "rehearsal of a cell added by files and entries"})
-        for m in s["end_to_end"] + s["per_layer"]:
-            if alike & set(m.get("workloads", [])):
-                m["workloads"].append(cell)
+        mix = _load(root / "tests" / "benchmark" / "traffic"
+                    / (traffic + ".json"))
+        for m in reports[mix["driver"]]:
+            m["workloads"].append(cell)
         s["per_layer"] += [dict(m, workloads=list(m["workloads"]))
                            for m in own]
     path = root / "BENCHMARK.json"
@@ -515,16 +564,49 @@ def added(tmp_path_factory):
     return added_tree(tmp_path_factory.mktemp("added_pr"))
 
 
+@pytest.mark.parametrize("spec_path, cell_name", [
+    (path, name) for path in (BENCHMARK, CANDIDATES)
+    for name in _names(path, "workloads") if spec.Cell(
+        path, name).traffic["driver"] != "train"]
+    + [("rehearsed", SERVED_CELL)],
+    ids=lambda v: os.path.basename(v))
+def test_serving_cells_fix_their_load_and_their_deployment(spec_path,
+                                                           cell_name, added):
+    """Of the real files' served cells, and of the one the rehearsed PR
+    adds."""
+    serving_cells_contract(added if spec_path == "rehearsed" else spec_path,
+                           cell_name)
+
+
 def test_rehearsed_pr_keeps_the_files_contract(added):
     file_contract(added)
-    assert {ADDED_CELL, SHARE_CELL} <= set(_names(added, "workloads"))
+    assert _names(added, "workloads") == _names(BENCHMARK, "workloads") + [
+        ADDED_CELL, SHARE_CELL, SERVED_CELL]
     reduced = {c["name"]: c["reduced"] for c in _load(added)["configs"]}
     assert reduced[ADDED_CONFIG] == []
     assert reduced[SHARE_CONFIG] == ["num_hidden_layers", "num_experts",
                                      "vocab_size"]
-    # the share's own metric stands last and lists its cell alone
-    assert _load(added)["per_layer"][-1] == SHARE_METRIC
-    assert SHARE_METRIC["name"] not in _names(BENCHMARK, "per_layer")
+    # the cells' own metrics stand behind all of BENCHMARK.json's own,
+    # which keep their places, and each lists its cell alone
+    theirs = _names(BENCHMARK, "per_layer")
+    entries = _load(added)["per_layer"]
+    assert [m["name"] for m in entries[:len(theirs)]] == theirs
+    assert entries[len(theirs):] == [SHARE_METRIC] + SERVED_METRICS
+    # the served cell's name went behind the accepted cells' wherever
+    # every served open-loop cell reports, end to end and per layer
+    accepted = [n for n in _names(BENCHMARK, "workloads")
+                if spec.Cell(BENCHMARK, n).traffic["driver"] == "serve_open"]
+    grown = reported_by_every(_load(added), accepted)
+    for m in grown:
+        assert m["workloads"] == accepted + [SERVED_CELL]
+    # today's nine, and whatever a later PR has every served cell report
+    assert {"request_ms_p50", "request_ms_p95", "engine_tpot_ms_p50",
+            "gen_lateness_ms_max", "engine_host_ms_per_tick",
+            "engine_rows_per_tick", "engine_queue_ms_p95",
+            "server_overhead_ms_p50", "engine_stall_ms_total"} <= {
+                m["name"] for m in grown}
+    assert reported_by_every(_load(added), [SERVED_CELL]) == (
+        grown + SERVED_METRICS)
 
 
 @pytest.mark.parametrize("name", _names(BENCHMARK, "configs")
@@ -534,7 +616,7 @@ def test_rehearsed_pr_keeps_every_configuration_to_its_source(added, name):
 
 
 @pytest.mark.parametrize("cell_name", _names(BENCHMARK, "workloads")
-                         + [ADDED_CELL, SHARE_CELL])
+                         + [ADDED_CELL, SHARE_CELL, SERVED_CELL])
 def test_rehearsed_pr_has_every_cell_find_its_files(added, cell_name):
     cell_contract(added, cell_name)
 

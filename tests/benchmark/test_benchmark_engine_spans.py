@@ -3,13 +3,16 @@
 `engine_rows_per_tick`, `engine_queue_ms_p95`, `server_overhead_ms_p50`
 and `engine_stall_ms_total` over the journal. Each over a small fixture
 with known answers; nothing on a journal or a trace of the parent commit
-(no `id`, no `phase_s`, no tick marked); and one rehearsal through
-`benchmark/run.py --rehearse --trace 1` whose line holds all five."""
+(no `id`, no `phase_s`, no tick marked); and rehearsals through
+`benchmark/run.py --rehearse` of two toy cells, the second added behind
+the first, whose traced lines hold all five. The five entries are held
+in a spec by name and by prefix (ISSUE 60), as PR 23's twelve and PR 34's
+nine are: a later served cell appends its name to them and its own
+entries behind them."""
 
 import json
 import os
 import shutil
-import subprocess
 import sys
 
 import pytest
@@ -17,12 +20,18 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark.harness import common, serve_journal, spec  # noqa: E402
 from benchmark.harness.trace import named, serve_ticks, xplane  # noqa: E402
+from test_benchmark_contract import (  # noqa: E402
+    SERVED_CELL, added_tree, own_served_entries, reported_by_every,
+)
+from test_benchmark_rehearse_train import rehearse  # noqa: E402
 
 BENCHMARK = os.path.join(REPO, "BENCHMARK.json")
 TOY_DIR = os.path.join(REPO, "tests", "benchmark", "toy")
+ADDED_DIR = os.path.join(REPO, "tests", "benchmark", "added")
 CELLS = ["serve_mistral7b_instruct", "serve_jamba2_3b_reasoning"]
 FIVE = {"engine_host_ms_per_tick": ("ms", "program_span", "request_ms_p50"),
         "engine_rows_per_tick": ("rows", "program_counter", "request_ms_p50"),
@@ -31,15 +40,77 @@ FIVE = {"engine_host_ms_per_tick": ("ms", "program_span", "request_ms_p50"),
         "engine_stall_ms_total": ("ms", "program_span", "request_ms_p95")}
 
 
-def test_the_five_entries_are_the_last_of_per_layer():
-    with open(BENCHMARK) as f:
+def five_entries_contract(spec_path):
+    """PR 54's five, found by name in a spec, in their order among
+    themselves, each as it was accepted and with the cells it listed in
+    front, in their order. Where in `per_layer` they stand, what stands
+    behind them and which cells' names stand behind those two is a later
+    PR's to write: it appends."""
+    with open(spec_path) as f:
         entries = json.load(f)["per_layer"]
-    assert [m["name"] for m in entries[-5:]] == list(FIVE)
-    for m in entries[-5:]:
+    ours = [m for m in entries if m["name"] in FIVE]
+    assert [m["name"] for m in ours] == list(FIVE)
+    for m in ours:
         unit, source, moves = FIVE[m["name"]]
-        assert m == {"name": m["name"], "unit": unit, "better": "lower",
-                     "source": source, "layer": "engine", "moves": moves,
-                     "workloads": CELLS}
+        assert dict(m, workloads=None) == {
+            "name": m["name"], "unit": unit, "better": "lower",
+            "source": source, "layer": "engine", "moves": moves,
+            "workloads": None}
+        assert m["workloads"][:len(CELLS)] == CELLS
+        assert len(set(m["workloads"])) == len(m["workloads"])
+
+
+@pytest.mark.parametrize("tree", ["BENCHMARK.json", "rehearsed"])
+def test_benchmark_json_holds_the_five_entries_in_their_order(tree,
+                                                              tmp_path):
+    """In the real file, and as a PR that adds a served open-loop cell
+    with two entries of its own leaves it (test_benchmark_contract.py)."""
+    if tree == "BENCHMARK.json":
+        return five_entries_contract(BENCHMARK)
+    rehearsed = added_tree(tmp_path)
+    five_entries_contract(rehearsed)
+    # its cell stands behind the two in each of the five, and entries
+    # stand behind the five
+    with open(rehearsed) as f:
+        entries = json.load(f)["per_layer"]
+    assert all(m["workloads"][-1] == SERVED_CELL
+               for m in entries if m["name"] in FIVE)
+    assert entries[-1]["name"] not in FIVE
+
+
+def _cells_of(name, edit):
+    def edited(s):
+        [m] = [m for m in s["per_layer"] if m["name"] == name]
+        m["workloads"] = edit(m["workloads"])
+    return edited
+
+
+def _an_entry_behind(s):
+    s["workloads"].append(dict(s["workloads"][-1], name="a_later_cell",
+                               traffic="a_later_mix"))
+    s["per_layer"] += own_served_entries("a_later_cell", "a_later_mix")
+
+
+@pytest.mark.parametrize("edit, taken", [
+    (_cells_of("engine_queue_ms_p95", lambda c: c + ["a_later_cell"]), True),
+    (_an_entry_behind, True),
+    (_cells_of("engine_queue_ms_p95", lambda c: ["a_later_cell"] + c), False),
+    (_cells_of("engine_queue_ms_p95", lambda c: c[::-1]), False),
+    (_cells_of("engine_host_ms_per_tick", lambda c: c[1:]), False),
+    (_cells_of("engine_stall_ms_total", lambda c: c[:1]), False)],
+    ids=["appended", "entry_behind_the_five", "put_in_front", "reordered",
+         "first_taken_out", "second_taken_out"])
+def test_a_later_cell_is_appended_to_the_five_and_none_is_moved_or_taken(
+        edit, taken, tmp_path):
+    with open(BENCHMARK) as f:
+        s = json.load(f)
+    edit(s)
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(s))
+    if taken:
+        return five_entries_contract(path)
+    with pytest.raises(AssertionError):
+        five_entries_contract(path)
 
 
 def fake_run(**fields):
@@ -244,40 +315,61 @@ def test_a_run_that_kept_no_records_is_not_looked_up(monkeypatch):
 # --- the whole path, rehearsed ----------------------------------------------
 
 CELL = "toy_instruct_spans"
+# a second served open-loop cell, of another block type, ADDED behind the
+# first by files and entries as spec.py's item 5 has it
+ADDED_OPEN, ADDED_OPEN_MIX = "toy_falcon_added_open", "added_open"
+ADDED_OPEN_METRICS = own_served_entries(ADDED_OPEN, ADDED_OPEN_MIX)
 
 
 @pytest.fixture(scope="module")
-def rehearsed(tmp_path_factory):
-    """The real BENCHMARK.json's `per_layer` entries of
-    `serve_mistral7b_instruct` over the toy configuration and
-    `toy_instruct`'s mix, run traced through benchmark/run.py on the CPU:
-    the server's own entry point, its engine's loop, its journal."""
+def toy_spec(tmp_path_factory):
+    """The real BENCHMARK.json's own entries over two toy cells: the
+    first stands where `serve_mistral7b_instruct` stands (the toy
+    configuration under `toy_instruct`'s mix, 60 req/s); the second, on
+    `toy-falcon` with the reference it names (tests/benchmark/added) under
+    a mix of its own name, is what a later PR adds: its name BEHIND the
+    first's on every metric that every accepted served cell reports, its
+    two entries behind every other. Returns the spec's path."""
     root = tmp_path_factory.mktemp("toy_spans")
     with open(BENCHMARK) as f:
         bench = json.load(f)
+    shared = reported_by_every(bench, CELLS)
     bench["paths"] = ["."]
-    bench["configs"] = [{"name": "toy-d2", "source": "none",
-                         "file": "toy-d2.json", "reduced": [],
-                         "why": "CPU rehearsal"}]
-    bench["workloads"] = [{"name": CELL, "config": "toy-d2", "traffic": CELL,
-                           "chips": 1,
-                           "why": "CPU rehearsal of " + CELLS[0]}]
+    bench["configs"] = [
+        {"name": name, "source": "none", "file": name + ".json",
+         "reduced": [], "why": "CPU rehearsal"}
+        for name in ("toy-d2", "toy-falcon")]
+    bench["workloads"] = [
+        {"name": CELL, "config": "toy-d2", "traffic": CELL, "chips": 1,
+         "why": "CPU rehearsal of " + CELLS[0]},
+        {"name": ADDED_OPEN, "config": "toy-falcon",
+         "traffic": ADDED_OPEN_MIX, "chips": 1,
+         "why": "CPU rehearsal of a served cell added behind another"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
             m["workloads"] = [CELL] if CELLS[0] in m["workloads"] else []
+    for m in shared:
+        m["workloads"].append(ADDED_OPEN)
+    bench["per_layer"] += ADDED_OPEN_METRICS
     os.makedirs(root / "traffic")
-    shutil.copy(os.path.join(TOY_DIR, "toy-d2.json"), root / "toy-d2.json")
-    shutil.copy(os.path.join(TOY_DIR, "traffic", "toy_instruct.json"),
-                root / "traffic" / (CELL + ".json"))
+    os.makedirs(root / "reference")
+    shutil.copy(os.path.join(TOY_DIR, "toy-d2.json"), root)
+    shutil.copy(os.path.join(ADDED_DIR, "toy-falcon.json"), root)
+    shutil.copy(os.path.join(ADDED_DIR, "reference", "toyfalcon.py"),
+                root / "reference")
+    for mix in (CELL, ADDED_OPEN_MIX):
+        shutil.copy(os.path.join(TOY_DIR, "traffic", "toy_instruct.json"),
+                    root / "traffic" / (mix + ".json"))
     with open(root / "spec.json", "w") as f:
         json.dump(bench, f)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
-         "--spec", str(root / "spec.json"), "--workload", CELL, "--seed",
-         "2147480054", "--seconds", "4", "--trace", "1", "--rehearse"],
-        cwd=REPO, capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    return str(root / "spec.json")
+
+
+@pytest.fixture(scope="module")
+def rehearsed(toy_spec):
+    """The first cell run traced through benchmark/run.py on the CPU: the
+    server's own entry point, its engine's loop, its journal."""
+    line = rehearse(CELL, 1, 4, spec=toy_spec, seed=2147480054)
     journal = named.journal(os.path.join(REPO, "runs", "benchmark", CELL,
                                          "tele", "events.jsonl"))
     return line, journal
@@ -329,3 +421,36 @@ def test_the_rehearsed_journal_keeps_its_promises(rehearsed):
     # the phases kept are the loop thread's time: all of it but its parks
     busy = sum(last["phase_s"].values()) - sum(first["phase_s"].values())
     assert 0 < busy <= span * 1.001
+
+
+# --- a served cell added behind another, through the unchanged harness ------
+
+@pytest.mark.parametrize("trace", [0, 1], ids=["untraced", "traced"])
+def test_a_served_cell_added_behind_another_runs(trace, toy_spec):
+    """What the PR after this one does, run: the second cell through
+    benchmark/run.py on the CPU (`rehearse` holds the line to `correct`
+    true and no failed request). Untraced it reports the two request
+    metrics and the set-up; traced the five, the two every served cell
+    shares and, of its own two, the one a CPU run can read: no device
+    plane, so `device_idle_pct.added_open` says nothing, none raises."""
+    line = rehearse(ADDED_OPEN, trace, 4, spec=toy_spec,
+                    seed=2147480060 + trace)
+    assert line["attempted"] >= 200          # a p95 needs them
+    cell = spec.Cell(toy_spec, ADDED_OPEN)
+    if not trace:
+        assert set(line["metrics"]) == {
+            m["name"] for m in cell.end_to_end()} == {
+                "request_ms_p50", "request_ms_p95", "setup_s"}
+        return
+    asked = [m["name"] for m in cell.per_layer()]
+    own = [m["name"] for m in ADDED_OPEN_METRICS]
+    assert set(asked) == set(FIVE) | {"engine_tpot_ms_p50",
+                                      "gen_lateness_ms_max", *own}
+    assert asked[-2:] == own
+    assert all(m["workloads"] == [CELL, ADDED_OPEN]
+               for m in cell.per_layer()[:-2])
+    assert set(line["metrics"]) == set(asked) - {own[1]}
+    with open(os.path.join(REPO, "runs", "benchmark", ADDED_OPEN,
+                           "plan.json")) as f:
+        assert json.load(f)["reference"] == os.path.join(
+            os.path.dirname(toy_spec), "reference", "toyfalcon.py")

@@ -16,6 +16,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+from test_benchmark_contract import SERVED_CELL, added_tree  # noqa: E402
 from test_benchmark_rehearse_train import REPO, TOY, rehearse  # noqa: E402
 
 sys.path.insert(0, REPO)
@@ -32,8 +33,10 @@ def _served(path):
                 if w["name"].startswith("serve_")]
 
 
-# the served cells of the benchmark, and the one kept ready beside it
-SERVED = _served(BENCHMARK) + _served(CANDIDATES)
+# the served cells of the benchmark, the one kept ready beside it, and
+# the one the rehearsed PR adds (test_benchmark_contract.py `added_tree`)
+SERVED = (_served(BENCHMARK) + _served(CANDIDATES)
+          + [("rehearsed", SERVED_CELL)])
 
 TOY_DIR = os.path.join(REPO, "tests", "benchmark", "toy")
 ADDED_DIR = os.path.join(REPO, "tests", "benchmark", "added")
@@ -181,9 +184,13 @@ def test_the_plan_of_a_served_cell_of_the_benchmark(spec_path, cell_name,
     requests and due times come from the seed alone (a seed over 2**31
     too), the open loop offers enough requests for its 95th percentile,
     every request fits the engine, and the sample `correct` scores holds
-    the longest request."""
+    the longest request. The rehearsed PR's served cell is held to all
+    of it, the two facts of an accepted mix's size too: its mix carries
+    them inside the 256 positions the toy serves."""
     from benchmark.harness import serve_driver, stats
 
+    if spec_path == "rehearsed":
+        spec_path = added_tree(tmp_path / "added_pr")
     cell = spec.Cell(spec_path, cell_name)
     seconds = cell.spec["run_seconds"]
     plan = serve_driver.plan_server(cell, 2**31 + 7, False, False,
