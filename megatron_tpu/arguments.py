@@ -73,6 +73,10 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
                    help="take residuals from the LN output (ref semantics)")
     g.add_argument("--glu_activation", default=None,
                    choices=["swiglu", "geglu", "reglu", "liglu"])
+    g.add_argument("--activation", default=None,
+                   choices=["gelu", "gelu_tanh", "relu", "squared_relu"],
+                   help="the FFN's activation where it is no GLU (default "
+                        "gelu; --glu_activation states a GLU)")
     g.add_argument("--parallel_attn", action="store_true")
     g.add_argument("--parallel_layernorm", action="store_true")
     g.add_argument("--use_bias", action="store_true")
@@ -98,9 +102,14 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     g.add_argument("--layer_pattern", type=_layer_pattern, default=None,
                    help="layers of several TYPES in one stack: a JSON list "
                         "with one type a layer of one period of the "
-                        "pattern, \"attention\" or \"mamba\" (a Mamba-1 "
-                        "state-space mixer, sized by --ssm_*); the stack "
-                        "repeats it over --num_layers")
+                        "pattern; the stack repeats it over --num_layers. "
+                        "Mixers: \"attention\", \"mamba\" (Mamba-1) and "
+                        "\"mamba2\" (Mamba-2 / SSD), both sized by "
+                        "--ssm_*; each layer then holds an FFN too. With a "
+                        "feed-forward type in it, \"mlp\" (dense) or "
+                        "\"moe\" (experts, --num_experts ...), every layer "
+                        "is ONE block alone: a mixer or an FFN, behind one "
+                        "norm and one residual add")
     g.add_argument("--ssm_d_state", type=int, default=16,
                    help="the state a channel of a state-space layer")
     g.add_argument("--ssm_d_conv", type=int, default=4,
@@ -113,6 +122,14 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     g.add_argument("--ssm_inner_norms", action="store_true",
                    help="an RMSNorm with a learned scale on dt, B and C "
                         "(Jamba)")
+    g.add_argument("--ssm_num_heads", type=int, default=None,
+                   help="Mamba-2: the heads of the inner width (one step "
+                        "size and one scalar decay a head)")
+    g.add_argument("--ssm_n_groups", type=int, default=1,
+                   help="Mamba-2: the groups of heads that share B and C "
+                        "(and of the gated norm)")
+    g.add_argument("--ssm_chunk_size", type=int, default=128,
+                   help="Mamba-2: the positions a chunk of the chunked scan")
     g.add_argument("--qk_norm", action="store_true", default=None,
                    help="RMSNorm with a learned scale over the whole q and "
                         "the whole k projection, before the head split and "
@@ -152,6 +169,21 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
                    dest="moe_renorm_gates",
                    help="use raw softmax gate values (GShard) instead of "
                         "renormalized top-k weights (Mixtral)")
+    g.add_argument("--moe_router_score", choices=["softmax", "sigmoid"],
+                   default=None,
+                   help="sigmoid: a score an expert on its own, the choice "
+                        "by score + a learned selection bias, gates the "
+                        "chosen scores over their sum, no load-balance "
+                        "loss (ops/moe.py)")
+    g.add_argument("--moe_route_scale", type=float, default=None,
+                   help="the sigmoid form's gates times this")
+    g.add_argument("--moe_latent_size", type=int, default=None,
+                   help="the routed experts work in this narrower width, "
+                        "between a projection down in front of the "
+                        "dispatch and one up behind the weighted sum")
+    g.add_argument("--moe_shared_ffn_size", type=int, default=None,
+                   help="a shared expert of this width beside the routed "
+                        "ones: every token's, added to their result")
     g.add_argument("--lima_dropout", action="store_true")
     g.add_argument("--encoder_seq_length", type=int, default=None,
                    help="alias of --seq_length (ref derives one from the other)")
@@ -536,7 +568,9 @@ def _moe_overrides(args) -> dict:
                  "moe_top_k", "moe_capacity_factor",
                  "moe_aux_loss_coeff", "moe_z_loss_coeff",
                  "moe_renorm_gates", "moe_group_size", "moe_dispatch",
-                 "moe_ep_buffer_factor"):
+                 "moe_ep_buffer_factor", "moe_router_score",
+                 "moe_route_scale", "moe_latent_size",
+                 "moe_shared_ffn_size"):
         v = getattr(args, name, None)
         if v is not None:
             out[name] = v
@@ -658,7 +692,7 @@ def args_to_run_config(args) -> RunConfig:
             rope_scaling_factor=args.rope_scaling_factor,
             normalization="rmsnorm" if args.use_rms_norm else "layernorm",
             layernorm_epsilon=args.layernorm_epsilon,
-            activation=args.glu_activation or "gelu",
+            activation=args.glu_activation or args.activation or "gelu",
             parallel_attn=args.parallel_attn,
             parallel_layernorm=args.parallel_layernorm,
             use_bias_linear=args.use_bias,
@@ -675,6 +709,9 @@ def args_to_run_config(args) -> RunConfig:
             ssm_expand=args.ssm_expand,
             ssm_dt_rank=args.ssm_dt_rank,
             ssm_inner_norms=args.ssm_inner_norms,
+            ssm_num_heads=args.ssm_num_heads,
+            ssm_n_groups=args.ssm_n_groups,
+            ssm_chunk_size=args.ssm_chunk_size,
             qk_norm=bool(args.qk_norm),
             use_post_ln=args.use_post_ln,
             apply_residual_post_ln=args.apply_residual_connection_post_layernorm,

@@ -26,10 +26,16 @@ import jax.numpy as jnp
 # "none": no positional encoding at all (a stack whose state-space layers
 # carry the order of the sequence)
 POSITION_EMBEDDING_TYPES = ("rotary", "absolute", "none")
-# What a layer's sequence mixer can be (`ModelConfig.layer_pattern`):
-# softmax attention, or a Mamba-1 selective state-space mixer (ops/ssm.py).
-# Types differ in their parameters' shapes; attention KINDS share them.
-LAYER_TYPES = ("attention", "mamba")
+# What a layer can be (`ModelConfig.layer_pattern`). A sequence mixer:
+# softmax attention, a Mamba-1 selective state-space mixer or a Mamba-2
+# (SSD) one (ops/ssm.py has both forms). Or a feed-forward block ALONE: a
+# dense MLP, or an expert layer (ops/moe.py). Types differ in their
+# parameters' shapes; attention KINDS share them.
+MIXER_TYPES = ("attention", "mamba", "mamba2")
+SSM_TYPES = ("mamba", "mamba2")
+FFN_TYPES = ("mlp", "moe")
+LAYER_TYPES = MIXER_TYPES + FFN_TYPES
+MOE_ROUTER_SCORES = ("softmax", "sigmoid")
 NORMALIZATION_TYPES = ("layernorm", "rmsnorm")
 # GLU family per ref megatron/model/glu_activations.py plus plain variants.
 ACTIVATION_TYPES = ("gelu", "gelu_tanh", "geglu", "swiglu", "reglu", "liglu", "relu", "squared_relu")
@@ -174,21 +180,38 @@ class ModelConfig:
     # Layers of several TYPES in one stack (LAYER_TYPES): one period of the
     # layers' types, which the stack repeats over its depth. A type has its
     # own parameter leaves, stacked over THAT type's layers alone
-    # (models/params.py: layers/ssm/* over the "mamba" layers, layers/attn/*
-    # over the "attention" ones; norms and the FFN over all). None: every
-    # layer is an attention layer. Its attention layers are of one kind
-    # (no attention_pattern beside it). Read through `layer_period`.
+    # (models/params.py: layers/ssm/* over the state-space layers,
+    # layers/attn/* over the "attention" ones, layers/mlp/* and
+    # layers/moe/* over the layers of those types). What a layer is
+    # follows from the pattern. Mixer types alone (MIXER_TYPES): every
+    # layer is a mixer AND a feed-forward block, two norms and two
+    # residual adds (norms and the FFN stacked over all layers). Where the
+    # pattern names a feed-forward type (FFN_TYPES), every layer is ONE
+    # block, a mixer alone or a feed-forward block alone, behind one norm
+    # (`ln1`, stacked over all layers) and one residual add: no `ln2`, and
+    # no FFN in a mixer's layer (`single_block_layers`). None: every layer
+    # is an attention layer and an FFN. Its attention layers are of one
+    # kind (no attention_pattern beside it), its state-space layers of one
+    # form. Read through `layer_period`.
     layer_pattern: Optional[Tuple[str, ...]] = None
-    # The state-space mixer's sizes (Mamba-1, arXiv:2312.00752): the state
-    # a channel N, the causal convolution's width K, the inner width over
-    # the hidden size, the rank R of the step size's projection (None:
+    # The state-space mixers' sizes: the state N (a channel of Mamba-1,
+    # arXiv:2312.00752; a head's [P, N] of Mamba-2, arXiv:2405.21060), the
+    # causal convolution's width K, the inner width over the hidden size.
+    # Mamba-1's own: the rank R of the step size's projection (None:
     # ceil(hidden_size / 16)); ssm_inner_norms: an RMSNorm with a learned
     # scale on each of dt, B and C before they are used (Jamba's own).
+    # Mamba-2's own: the heads H (of inner width / H channels each, one
+    # step size and one scalar decay a head), the groups G that share B
+    # and C (H / G heads a group; also the groups of the gated norm), and
+    # the positions a chunk of the chunked scan.
     ssm_d_state: int = 16
     ssm_d_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: Optional[int] = None
     ssm_inner_norms: bool = False
+    ssm_num_heads: Optional[int] = None
+    ssm_n_groups: int = 1
+    ssm_chunk_size: int = 128
 
     # OLMoE QK-norm: RMSNorm with a learned scale over the WHOLE q and the
     # whole k projection (all heads at once), before the head split and
@@ -211,6 +234,21 @@ class ModelConfig:
     moe_aux_loss_coeff: float = 1e-2
     moe_z_loss_coeff: float = 0.0
     moe_renorm_gates: bool = True
+    # How the router scores its experts (ops/moe.py `_route`). "softmax":
+    # probabilities over all experts, the k largest chosen. "sigmoid": a
+    # score an expert on its own; the k chosen are the largest of score +
+    # a learned selection bias (layers/moe/router_bias, read for the
+    # choice alone), the gates the chosen scores over their sum
+    # (moe_renorm_gates) times moe_route_scale, and no load-balance loss.
+    moe_router_score: str = "softmax"
+    moe_route_scale: float = 1.0
+    # The routed experts work in a narrower width: a linear projection of
+    # the layer's input down to it in front of the dispatch, and one back
+    # up behind the weighted sum (None: the experts read the hidden size).
+    moe_latent_size: Optional[int] = None
+    # A shared expert of this width beside the routed ones: an MLP every
+    # token goes through, its result added to theirs (None: none).
+    moe_shared_ffn_size: Optional[int] = None
     # "capacity": GShard grouped capacity dispatch (einsum, EP-shardable);
     # "dropless": sort-based dispatch over lax.ragged_dot — NO token ever
     # dropped and no dense [.., E, C] dispatch FLOPs; under ep > 1 rows
@@ -329,14 +367,49 @@ class ModelConfig:
         return self.layer_pattern or ("attention",)
 
     @property
+    def ssm_type(self) -> Optional[str]:
+        """The form of the stack's state-space layers (SSM_TYPES), None
+        for a stack without."""
+        return next((t for t in SSM_TYPES if t in self.layer_period), None)
+
+    @property
     def has_ssm(self) -> bool:
         """Some layers carry a recurrent state and no keys."""
-        return "mamba" in self.layer_period
+        return self.ssm_type is not None
+
+    @property
+    def single_block_layers(self) -> bool:
+        """Every layer is one block behind one norm and one residual add,
+        a mixer alone or a feed-forward block alone: the pattern names a
+        feed-forward type (`layer_pattern`'s comment)."""
+        return bool(set(self.layer_period) & set(FFN_TYPES))
 
     def layers_of(self, layer_type: str) -> int:
         """How many of the stack's layers are of `layer_type`."""
         period = self.layer_period
         return self.num_layers // len(period) * period.count(layer_type)
+
+    @property
+    def expert_layers(self) -> int:
+        """The layers that hold experts (0 for a dense model)."""
+        if self.num_experts is None:
+            return 0
+        return (self.layers_of("moe") if self.single_block_layers
+                else self.num_layers)
+
+    @property
+    def ssm_head_dim(self) -> int:
+        """P: the channels of a Mamba-2 head."""
+        return self.ssm_d_inner // self.ssm_num_heads
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """The channels the causal convolution runs over: the inner width
+        (Mamba-1), with B and C of every group behind it (Mamba-2)."""
+        if self.ssm_type == "mamba2":
+            return (self.ssm_d_inner
+                    + 2 * self.ssm_n_groups * self.ssm_d_state)
+        return self.ssm_d_inner
 
     @property
     def ssm_d_inner(self) -> int:
@@ -430,15 +503,40 @@ class ModelConfig:
                 raise NotImplementedError(
                     "layer_pattern with attention_pattern: a typed stack's "
                     "attention layers are of one kind")
-            if (self.parallel_attn or self.use_post_ln
-                    or self.num_experts is not None or self.fp8_format):
+            if self.parallel_attn or self.use_post_ln or self.fp8_format:
                 raise NotImplementedError(
-                    "a stack with state-space layers is pre-norm and "
-                    "sequential with a dense FFN in bf16/f32 (no "
-                    "parallel_attn, use_post_ln, num_experts, fp8_format)")
+                    "a stack of several layer types is pre-norm and "
+                    "sequential in bf16/f32: no parallel_attn, "
+                    "use_post_ln or fp8_format")
+            if ("moe" in self.layer_pattern) != (self.num_experts is not None):
+                raise NotImplementedError(
+                    "in a stack of several layer types the experts are "
+                    "the \"moe\" layers' (a feed-forward block alone, "
+                    "which makes every layer one block): num_experts "
+                    "comes with that type in the pattern, and not beside "
+                    "a pattern of mixers whose layers each hold a dense "
+                    "FFN")
+            if not set(self.layer_pattern) & set(MIXER_TYPES):
+                raise ValueError(
+                    "layer_pattern names no sequence mixer "
+                    f"(one of {MIXER_TYPES})")
+            if len(set(self.layer_pattern) & set(SSM_TYPES)) > 1:
+                raise NotImplementedError(
+                    "layer_pattern with both state-space forms: the state "
+                    "store holds rows of one shape")
             if min(self.ssm_d_state, self.ssm_d_conv, self.ssm_expand,
                    self.ssm_rank) < 1:
                 raise ValueError("the state-space sizes must be >= 1")
+            if "mamba2" in self.layer_pattern:
+                heads, groups = self.ssm_num_heads, self.ssm_n_groups
+                if (not heads or groups < 1 or self.ssm_d_inner % heads
+                        or heads % groups or self.ssm_chunk_size < 1):
+                    raise ValueError(
+                        "a \"mamba2\" layer needs ssm_num_heads that "
+                        f"divide the inner width {self.ssm_d_inner}, "
+                        "ssm_n_groups that divide the heads and "
+                        f"ssm_chunk_size >= 1 (heads {heads}, groups "
+                        f"{groups}, chunk {self.ssm_chunk_size})")
         if self.moe_experts_held is not None:
             if self.num_experts is None or self.moe_dispatch != "dropless":
                 raise ValueError(
@@ -457,6 +555,18 @@ class ModelConfig:
                 raise ValueError(
                     f"moe_top_k={self.moe_top_k} must be in "
                     f"[1, num_experts={self.num_experts}]")
+            if self.moe_router_score not in MOE_ROUTER_SCORES:
+                raise ValueError(
+                    f"moe_router_score={self.moe_router_score!r} must be "
+                    f"one of {MOE_ROUTER_SCORES}")
+            if ((self.moe_router_score == "sigmoid"
+                 or self.moe_latent_size is not None
+                 or self.moe_shared_ffn_size is not None)
+                    and self.moe_dispatch != "dropless"):
+                raise NotImplementedError(
+                    "sigmoid router scores, moe_latent_size and "
+                    "moe_shared_ffn_size are the dropless block's "
+                    "(moe_dispatch='dropless')")
             if self.moe_dispatch not in ("capacity", "dropless"):
                 raise ValueError(
                     f"moe_dispatch={self.moe_dispatch!r} must be "
@@ -508,16 +618,51 @@ class ModelConfig:
         keys = sum(min(s, k.sliding_window_size or s) for k in period)
         per_layer += 2 * 2 * nq * hd * keys / len(period)
         total = self.num_layers * per_layer
+        if self.single_block_layers:
+            return self._flops_per_token_single_blocks(
+                per_layer - mlp, 2 * h * mlp_in_width + 2 * f * h)
         if self.has_ssm:
             # a state-space layer has its mixer's products in place of the
-            # projections and the scores: in, x, dt and out projections,
-            # the convolution, and ~9 operations a state element a token
-            di, n, r = self.ssm_d_inner, self.ssm_d_state, self.ssm_rank
-            mixer = (2 * h * 2 * di + 2 * di * (r + 2 * n) + 2 * r * di
-                     + 2 * di * h + 2 * self.ssm_d_conv * di + 9 * di * n)
-            total += self.layers_of("mamba") * (mixer - (per_layer - mlp))
+            # projections and the scores
+            total += self.layers_of(self.ssm_type) * (
+                self._ssm_mixer_flops() - (per_layer - mlp))
         total += 2 * h * self.vocab_size                # logits
         return float(total)
+
+    def _ssm_mixer_flops(self) -> float:
+        """A state-space mixer's operations a token: its projections, the
+        convolution, and ~9 operations a state element."""
+        h, di, n = self.hidden_size, self.ssm_d_inner, self.ssm_d_state
+        conv = 2 * self.ssm_d_conv * self.ssm_conv_width
+        if self.ssm_type == "mamba2":
+            into = 2 * h * (di + self.ssm_conv_width + self.ssm_num_heads)
+            return into + conv + 9 * di * n + 2 * di * h
+        r = self.ssm_rank
+        return (2 * h * 2 * di + 2 * di * (r + 2 * n) + 2 * r * di
+                + 2 * di * h + conv + 9 * di * n)
+
+    def _flops_per_token_single_blocks(self, attention: float,
+                                       dense: float) -> float:
+        """flops_per_token_fwd of a stack whose layers are one block each
+        (`single_block_layers`): each type's layers counted as what they
+        are. attention: an attention layer's mixer; dense: one MLP of
+        ffn_size between hidden-size rows."""
+        h = self.hidden_size
+        total = (self.layers_of("attention") * attention
+                 + self.layers_of("mlp") * dense)
+        if self.has_ssm:
+            total += self.layers_of(self.ssm_type) * self._ssm_mixer_flops()
+        if self.num_experts is not None:
+            width = self.moe_latent_size or h
+            expert = dense * width / h
+            layer = (2 * h * self.num_experts + expert * self.moe_top_k
+                     * self.experts_held / self.num_experts)
+            if self.moe_latent_size is not None:
+                layer += 2 * 2 * h * width
+            if self.moe_shared_ffn_size is not None:
+                layer += dense * self.moe_shared_ffn_size / self.ffn_size
+            total += self.layers_of("moe") * layer
+        return float(total + 2 * h * self.vocab_size)
 
 
 # ---------------------------------------------------------------------------

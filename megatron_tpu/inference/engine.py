@@ -970,7 +970,7 @@ class InferenceEngine:
         span.start(cause=cause)
         try:
             while self._inflight:
-                self._read(self._inflight.popleft())
+                self._read_next()
                 n += 1
         finally:
             span.stop()
@@ -985,9 +985,22 @@ class InferenceEngine:
         n = 0
         while self._inflight and not (
                 dispatched and self._inflight[0].step == self._step_no):
-            self._read(self._inflight.popleft())
+            self._read_next()
             n += 1
         return dispatched or n
+
+    # a record has left `_inflight` and its read is not over yet: to
+    # wait_idle() the engine is not idle until what the read applies (the
+    # last tokens, the counters) is applied
+    _reading = False
+
+    def _read_next(self) -> None:
+        """Read the oldest program in flight."""
+        self._reading = True
+        try:
+            self._read(self._inflight.popleft())
+        finally:
+            self._reading = False
 
     def _drop_inflight(self) -> None:
         """Forget what is in flight without reading it (a failed device
@@ -1337,6 +1350,7 @@ class InferenceEngine:
             with self._cv:
                 if (not self._queue and self._admitting == 0
                         and self.num_active == 0 and not self._inflight
+                        and not self._reading
                         and self._pending_params is None):
                     return True
             if deadline is not None and time.monotonic() > deadline:
@@ -1687,7 +1701,8 @@ class InferenceEngine:
         # tokens and logprobs cross to the host when the tick is read, in
         # one fetch whose copy starts behind the step
         self._carry = (toks, lens, keys, *carry[3:])
-        out = self._start_fetch((toks, lps if self.want_logprobs else None))
+        out = self._start_fetch((toks, lps if self.want_logprobs else None,
+                                 self._step_counts))
         # every decoding row's length grows by exactly 1 a tick, so the
         # next tick's pages, window and live blocks need none of this
         # one's tokens: the fed token is in the cache once the step runs
@@ -1697,6 +1712,14 @@ class InferenceEngine:
             rows=[(i, self.slots[i]) for i in active], out=out,
             step=self._step_no, t0=t_tick, ahead=ahead))
         return len(active)
+
+    # What a step counted on the device beside its tokens, as the last
+    # step left it (None: this engine's steps count nothing). It rides to
+    # the host in the fetch of the step's tokens (_apply_counts).
+    _step_counts = None
+
+    def _apply_counts(self, counts) -> None:
+        """A read step's device-side counts to the host's counters."""
 
     @staticmethod
     def _start_fetch(out):
@@ -1726,9 +1749,10 @@ class InferenceEngine:
         ended by eod ran one tick more than it should (only a finish by
         eod needs the token), and that tick's result for it is dropped
         here; its one extra KV position lies in a page the row owned."""
-        toks, lps = self._fetch(rec)
+        toks, lps, counts = self._fetch(rec)
         with self.timers(APPLY):
             self._apply(rec, toks, lps)
+            self._apply_counts(counts)
 
     def _apply(self, rec: _InFlight, toks, lps) -> None:
         """A read tick's tokens to their requests."""

@@ -182,6 +182,23 @@ class PagedInferenceEngine(InferenceEngine):
             self._m_state_bytes.set(ssm.state_bytes(self.state))
             self._zero_state_row = jax.jit(
                 ssm.zero_row, donate_argnums=(0,) if self._donate() else ())
+        # a model that holds a share of its router's experts: how many of
+        # the (row, choice) pairs its steps computed went to experts held
+        # here, summed over the expert layers. Both steps add to one pair
+        # of counters on the device (_step_counts: [held, all], uint32,
+        # which wraps), read with the steps' tokens (_apply_counts).
+        self._m_moe_rows = m.counter(
+            "engine_moe_rows_total",
+            "(row, choice) pairs the steps routed, over the expert layers "
+            "(rows x experts a token x expert layers; a model that holds a "
+            "share of its router's experts)")
+        self._m_moe_held = m.counter(
+            "engine_moe_held_rows_total",
+            "those of them sent to experts held on this chip")
+        if self.cfg.holds_expert_share:
+            self.stats["moe_rows"] = self.stats["moe_held_rows"] = 0
+            self._step_counts = self._commit_small(np.zeros(2, np.uint32))
+            self._counts_seen = np.zeros(2, np.uint32)
 
     # ----- cache + shape policy -------------------------------------------
 
@@ -261,13 +278,30 @@ class PagedInferenceEngine(InferenceEngine):
         cp_comm = getattr(self, "cp_comm", None)
         from megatron_tpu.models.language_model import lm_forward
 
-        def forward(params, caches, state, tokens, **where):
+        def forward(params, caches, state, tokens, *counts, **where):
             out = lm_forward(cfg, params, tokens, kv_caches=caches,
                              ssm_state=state, tp_comm=tp_comm,
-                             cp_comm=cp_comm, **where)
-            return out if state is not None else (*out, None)
+                             cp_comm=cp_comm, return_moe_aux=bool(counts),
+                             **where)
+            if counts:
+                # the layers' shares of held rows, summed, times the pairs
+                # a layer routes: whole numbers, exact in float32
+                *out, moe_aux = out
+                pairs = tokens.size * cfg.moe_top_k
+                counts = (counts[0] + jnp.stack([
+                    jnp.round(moe_aux[2] * pairs),
+                    jnp.float32(pairs * cfg.expert_layers)]
+                ).astype(jnp.uint32),)
+            return (*out, *((None,) if state is None else ()), *counts)
 
         return forward
+
+    def _counts_template(self):
+        """The steps' last result where they count (`_step_counts`)."""
+        return ("rep",) if self.cfg.holds_expert_share else ()
+
+    def _counts_arg(self):
+        return () if self._step_counts is None else (self._step_counts,)
 
     def _build_decode_step(self):
         vocab, wlp = self.vocab_size, self.want_logprobs
@@ -276,9 +310,10 @@ class PagedInferenceEngine(InferenceEngine):
 
         @partial(jax.jit, donate_argnums=self._donate_with_state(),
                  **self._jit_sharding_kwargs(
-                     ("rep", "rep", "kv", "rep", "rep", "rep")))
+                     ("rep", "rep", "kv", "rep", "rep", "rep")
+                     + self._counts_template()))
         def decode_step(params, caches, state, table, last_tok, lengths,
-                        keys, temps, top_ks, top_ps):
+                        keys, temps, top_ks, top_ps, *counts):
             # identical to the slot decode step except K/V writes and
             # reads route through the page table (ops/attention.py picks
             # the paged flash-decode kernel on TPU, the gather elsewhere).
@@ -288,8 +323,11 @@ class PagedInferenceEngine(InferenceEngine):
             # are scratch: their state stays).
             decoding = (None if state is None else
                         (table[:, 0] != SCRATCH_PAGE).astype(jnp.int32))
-            logits, caches, state = forward(
-                params, caches, state, last_tok[:, None],
+            # counts (of a model that holds a share of its experts, else
+            # absent): _step_counts, which this step adds its rows to and
+            # returns behind everything else
+            logits, caches, state, *counts = forward(
+                params, caches, state, last_tok[:, None], *counts,
                 cache_index=lengths, page_table=table, state_valid=decoding)
             logits = logits[:, 0]
             split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
@@ -302,7 +340,7 @@ class PagedInferenceEngine(InferenceEngine):
                     toks[:, None], axis=-1)[:, 0]
             else:
                 lp = jnp.zeros(toks.shape, jnp.float32)
-            return toks, lp, caches, state, new_keys, lengths + 1
+            return toks, lp, caches, state, new_keys, lengths + 1, *counts
 
         return decode_step
 
@@ -314,10 +352,11 @@ class PagedInferenceEngine(InferenceEngine):
 
         @partial(jax.jit, donate_argnums=self._donate_with_state(),
                  **self._jit_sharding_kwargs(
-                     ("rep", "rep", "rep", "kv", "rep", "rep")))
+                     ("rep", "rep", "rep", "kv", "rep", "rep")
+                     + self._counts_template()))
         def chunk_step(params, caches, state, table_row, tokens_ext, off,
                        write_start, write_end, sample_pos, key, temp,
-                       top_k, top_p, slot=None):
+                       top_k, top_p, slot=None, *counts):
             """One prefill chunk of one prompt.
 
             tokens_ext [1, C+1]: the chunk's tokens at absolute positions
@@ -335,9 +374,9 @@ class PagedInferenceEngine(InferenceEngine):
             the state up where the prompt's last chunk left it and leaves
             it after the last real position (write_end - off of C: the
             padded tail moves neither the state nor the convolution's
-            tail)."""
-            logits, caches, state = forward(
-                params, caches, state, tokens_ext[:, :C],
+            tail). counts: as the decode step's."""
+            logits, caches, state, *counts = forward(
+                params, caches, state, tokens_ext[:, :C], *counts,
                 cache_index=off, page_table=table_row,
                 page_write_start=write_start, page_write_end=write_end,
                 state_row=slot,
@@ -363,7 +402,7 @@ class PagedInferenceEngine(InferenceEngine):
                     tok[None, None], axis=-1)[0, 0]
             else:
                 lp = jnp.zeros((), jnp.float32)
-            return tok, lp, plp, caches, state, key
+            return tok, lp, plp, caches, state, key, *counts
 
         return chunk_step
 
@@ -416,9 +455,13 @@ class PagedInferenceEngine(InferenceEngine):
         return freed
 
     def _serve_ticks_fields(self) -> dict:
-        return {"evicted": self.stats["pages_evicted"],
-                "prefill_blocks": [self.stats["prefill_blocks_visited"],
-                                   self.stats["prefill_blocks_held"]]}
+        fields = {"evicted": self.stats["pages_evicted"],
+                  "prefill_blocks": [self.stats["prefill_blocks_visited"],
+                                     self.stats["prefill_blocks_held"]]}
+        if self.cfg.holds_expert_share:
+            fields["moe_rows"] = [self.stats["moe_held_rows"],
+                                  self.stats["moe_rows"]]
+        return fields
 
     def _slow_tick_fields(self) -> dict:
         return {"pages_free": self.pool.free_pages}
@@ -583,15 +626,18 @@ class PagedInferenceEngine(InferenceEngine):
         row = self._pending_rows[i]
         self._note_prefill_blocks(off, task.total)
         try:
-            tok, lp, plp, self.caches, self.state, key = self._chunk_step(
-                self.params, self.caches, self.state,
-                self._chunk_table_arg(row),
-                toks_ext, np.int32(off),
-                np.int32(task.write_start), np.int32(task.total),
-                np.int32(task.total - 1), task.key,
-                np.float32(req.temperature), np.int32(req.top_k),
-                np.float32(req.top_p),
-                None if self.state is None else np.int32(i))
+            tok, lp, plp, self.caches, self.state, key, *counts = (
+                self._chunk_step(
+                    self.params, self.caches, self.state,
+                    self._chunk_table_arg(row),
+                    toks_ext, np.int32(off),
+                    np.int32(task.write_start), np.int32(task.total),
+                    np.int32(task.total - 1), task.key,
+                    np.float32(req.temperature), np.int32(req.top_k),
+                    np.float32(req.top_p),
+                    None if self.state is None else np.int32(i),
+                    *self._counts_arg()))
+            self._step_counts, = counts or (None,)
             if self._has_draft_model():
                 # mirror the chunk into the draft pools through the same
                 # table row and write fences
@@ -689,7 +735,8 @@ class PagedInferenceEngine(InferenceEngine):
         plps = (list(task.plp_parts)
                 if self.want_logprobs and not task.resumed else [])
         rec = _InFlight(rows=[(i, req)],
-                        out=self._start_fetch((tok, lp, plps)),
+                        out=self._start_fetch((tok, lp, plps,
+                                               self._step_counts)),
                         step=self._step_no, t0=time.monotonic(),
                         task=task, pinned=pinned)
         if self.spec is not None:
@@ -727,9 +774,10 @@ class PagedInferenceEngine(InferenceEngine):
         """Read a finished prompt's first token: record it and the
         prompt's logprobs, and register the prompt's full pages in the
         radix tree."""
-        tok, lp, plps = self._fetch(rec)
+        tok, lp, plps, counts = self._fetch(rec)
         with self.timers(APPLY):
             self._apply_first(rec, tok, lp, plps)
+            self._apply_counts(counts)
 
     def _apply_first(self, rec: _InFlight, tok, lp, plps) -> None:
         (i, req), = rec.rows
@@ -861,10 +909,26 @@ class PagedInferenceEngine(InferenceEngine):
         return (self._device_table,)
 
     def _call_decode_step(self, *carry):
-        toks, lps, self.caches, self.state, keys, lens = self._decode_step(
-            self.params, self.caches, self.state,
-            *self._decode_extra_args(), *carry)
+        toks, lps, self.caches, self.state, keys, lens, *counts = (
+            self._decode_step(
+                self.params, self.caches, self.state,
+                *self._decode_extra_args(), *carry, *self._counts_arg()))
+        self._step_counts, = counts or (None,)
         return toks, lps, keys, lens
+
+    def _apply_counts(self, counts) -> None:
+        """The device's [held, all] pairs so far, as a read step's fetch
+        brought them: the counters move by what is new since the last
+        read (the device's pair wraps at 2**32; the difference does not
+        care)."""
+        if counts is None:
+            return
+        new = counts - self._counts_seen
+        self._counts_seen = counts
+        self._m_moe_held.inc(int(new[0]))
+        self._m_moe_rows.inc(int(new[1]))
+        self.stats["moe_held_rows"] += int(new[0])
+        self.stats["moe_rows"] += int(new[1])
 
     def _chunk_table_arg(self, row):
         """Device form of one pending table row for the chunk step

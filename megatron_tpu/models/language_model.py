@@ -245,6 +245,12 @@ def rope_tables(cfg: ModelConfig, kinds, length: int) -> Dict[Any, Any]:
             for kind in dict.fromkeys(kinds)}
 
 
+# where a layer type's own leaves stand in the stacked layers' tree
+# (models/params.py), stacked over THAT type's layers alone
+TYPE_LEAVES = {"attention": "attn", "mamba": "ssm", "mamba2": "ssm",
+               "mlp": "mlp", "moe": "moe"}
+
+
 def run_layers(
     cfg: ModelConfig,
     layers: Dict[str, Any],   # stacked [n, ...]: the whole stack, or a slice
@@ -273,9 +279,11 @@ def run_layers(
     call.
 
     A stack of several layer TYPES (cfg.layer_pattern) holds each type's
-    leaves (`layers["ssm"]`, `layers["attn"]`) stacked over that type's
-    layers, and the stores likewise: a layer indexes them by its ordinal
-    among the layers of its type. `layers` is then the whole stack.
+    leaves (TYPE_LEAVES: `layers["ssm"]`, `layers["attn"]`, and where a
+    layer is one block alone `layers["moe"]`, `layers["mlp"]`) stacked
+    over that type's layers, and the stores likewise: a layer indexes
+    them by its ordinal among the layers of its type. `layers` is then
+    the whole stack.
 
     LIMA's dropout rate and the dropout key go by a layer's index in the
     whole network, first_layer (traced or not) + its index in `layers`;
@@ -292,6 +300,7 @@ def run_layers(
 
     def body(carry, scanned, kind, layer_type="attention", leaves=None):
         x, aux, caches, sinks, state = carry
+        of_type = {}
         if leaves is None:
             (lp, rate, idx), type_layer = scanned, None
         else:
@@ -299,6 +308,12 @@ def run_layers(
             # `layers`, and the layer's index among the layers of its type
             (lp, rate, idx), typed, type_layer = scanned
             lp = {**lp, leaves: typed}
+            if leaves == "moe" and caches is not None:
+                # a serving step (nothing differentiates through a store):
+                # the experts' kernels read the layer's matrices where
+                # they lie in the stacks (ops/moe.py moe_block `of_layer`)
+                of_type = {"expert_stacks": (layers["moe"]["w_in"],
+                                             layers["moe"]["w_out"])}
         key = (None if dropout_key is None
                else jax.random.fold_in(dropout_key, first_layer + idx))
         y, caches, moe_aux, sinks, state = block_forward(
@@ -312,6 +327,7 @@ def run_layers(
             layer_type=layer_type,
             type_layer=type_layer,
             ssm_state=state,
+            **of_type,
             **layer_args,
         )
         return (y, add_aux(aux, moe_aux), caches, sinks, state), None
@@ -326,13 +342,16 @@ def run_layers(
             "a slice of a stack of several layer types (a pipeline stage): "
             "a type's leaves are stacked over that type's layers alone")
     types = cfg.layer_period
-    names = {"attention": "attn", "mamba": "ssm"}
+    # the name of each type's own leaves in `layers`; what is left is
+    # stacked over all the layers (the norms; where every layer holds an
+    # FFN beside its mixer, that FFN)
+    names = {t: TYPE_LEAVES[t] for t in dict.fromkeys(types)}
     common = {k: v for k, v in layers.items() if k not in names.values()}
     return scan_with_remat(
         [partial(body, kind=cfg.attention_kind, layer_type=t, leaves=names[t])
          for t in types],
         carry, ((common, rates, jnp.arange(n)),
-                {t: layers[names[t]] for t in dict.fromkeys(types)}),
+                {t: layers[name] for t, name in names.items()}),
         recompute, types=types)
 
 
@@ -442,7 +461,9 @@ def lm_forward(
     return_moe_aux: also return [aux loss summed over the layers, the
     worst layer's load statistic] (ops/moe.py layer_stats; behind them,
     where the layers hold a share of their experts, the held rows' shares
-    summed over the layers).
+    summed over the layers), as the last result: behind the logits, or
+    behind the stores of a serving step (the paged engine counts the rows
+    its held experts took from it).
 
     page_table: the store is a pool of pages (inference/paging/) shared
     by every slot; each row's logical context is page_table[b] physical
@@ -511,15 +532,15 @@ def lm_forward(
     with jax.named_scope("head_loss"):
         logits = lm_logits(cfg, params, x, tp_comm=tp_comm)
         logits = sharder(logits, "logits")
-    if return_moe_aux and kv_caches is not None:
-        raise ValueError("return_moe_aux with kv_caches is ambiguous — "
-                         "decode paths don't train the router")
+    # behind the stores, for a serving step that counts the rows its
+    # held experts took (inference/paging/engine.py)
+    aux = (moe_aux,) if return_moe_aux else ()
+    if ssm_state is not None:
+        return with_sinks((logits, new_caches, new_state, *aux))
+    if kv_caches is not None:
+        return with_sinks((logits, new_caches, *aux))
     if return_moe_aux:
         return with_sinks((logits, moe_aux))
-    if ssm_state is not None:
-        return with_sinks((logits, new_caches, new_state))
-    if kv_caches is not None:
-        return with_sinks((logits, new_caches))
     return with_sinks(logits)
 
 
@@ -637,6 +658,6 @@ def lm_loss(
         aux["moe_aux_loss"] = moe_aux[0]
         aux[LOAD_METRIC] = moe_aux[1]
         if cfg.holds_expert_share:
-            aux[HELD_METRIC] = moe_aux[2] / cfg.num_layers
+            aux[HELD_METRIC] = moe_aux[2] / cfg.expert_layers
         return mean + moe_aux[0], aux
     return mean, aux

@@ -35,7 +35,8 @@ _SCALED = "scaled_normal"   # N(0, std / sqrt(2 * num_layers))  (output-facing)
 _ONES = "ones"
 _ZEROS = "zeros"
 # the state-space mixer's own (ops/ssm.py, Mamba's init)
-_A_LOG = "a_log"            # log(1..N) down the state axis
+_A_LOG = "a_log"            # log(1..N) down the state axis (Mamba-1)
+_A_LOG_HEADS = "a_log_heads"  # log of uniform [1, 16) a head (Mamba-2)
 _DT_BIAS = "dt_bias"        # softplus(bias) log-uniform in [1e-3, 1e-1]
 
 
@@ -63,8 +64,12 @@ def _defs(cfg: ModelConfig) -> Dict[str, Any]:
         if ln_bias:
             d[f"{prefix}/bias"] = ((L, h), P(AXIS_PIPE, None), _ZEROS)
 
+    # a stack whose layers are one block each (cfg.single_block_layers)
+    # has the one norm a layer, and a feed-forward type's leaves stacked
+    # over that type's layers like a mixer's
+    single = cfg.single_block_layers
     norm("layers/ln1")
-    if not cfg.parallel_attn:
+    if not cfg.parallel_attn and not single:
         norm("layers/ln2")
     if cfg.parallel_layernorm:
         norm("layers/ln_mlp")
@@ -89,39 +94,56 @@ def _defs(cfg: ModelConfig) -> Dict[str, Any]:
         d["layers/attn/bo"] = ((La, h), P(AXIS_PIPE, None), _ZEROS)
 
     if cfg.has_ssm:
-        # the Mamba-1 mixer (ops/ssm.py has the equations). The inner
+        # the state-space mixers (ops/ssm.py has the equations). The inner
         # width is the LAST axis of every leaf that has it but the
         # projections out of it: it is the one a vector lane runs along.
         # Replicated over "tensor": the paths that shard refuse the type.
-        Ls = cfg.layers_of("mamba")
-        di, N = cfg.ssm_d_inner, cfg.ssm_d_state
-        K, R = cfg.ssm_d_conv, cfg.ssm_rank
+        Ls = cfg.layers_of(cfg.ssm_type)
+        di, N, K = cfg.ssm_d_inner, cfg.ssm_d_state, cfg.ssm_d_conv
 
         def ssm(name, shape, kind):
             d[f"layers/ssm/{name}"] = (
                 (Ls,) + shape, P(AXIS_PIPE, *(None,) * len(shape)), kind)
 
-        ssm("w_in", (h, 2 * di), _NORMAL)          # x and the gate z
-        ssm("conv_w", (K, di), _NORMAL)
-        ssm("conv_b", (di,), _ZEROS)
-        ssm("w_x", (di, R + 2 * N), _NORMAL)       # dt, B, C
-        if cfg.ssm_inner_norms:
-            ssm("dt_norm/scale", (R,), _ONES)
-            ssm("b_norm/scale", (N,), _ONES)
-            ssm("c_norm/scale", (N,), _ONES)
-        ssm("w_dt", (R, di), _NORMAL)
-        ssm("b_dt", (di,), _DT_BIAS)
-        ssm("a_log", (N, di), _A_LOG)
-        ssm("d_skip", (di,), _ONES)
+        if cfg.ssm_type == "mamba":
+            R = cfg.ssm_rank
+            ssm("w_in", (h, 2 * di), _NORMAL)          # x and the gate z
+            ssm("conv_w", (K, di), _NORMAL)
+            ssm("conv_b", (di,), _ZEROS)
+            ssm("w_x", (di, R + 2 * N), _NORMAL)       # dt, B, C
+            if cfg.ssm_inner_norms:
+                ssm("dt_norm/scale", (R,), _ONES)
+                ssm("b_norm/scale", (N,), _ONES)
+                ssm("c_norm/scale", (N,), _ONES)
+            ssm("w_dt", (R, di), _NORMAL)
+            ssm("b_dt", (di,), _DT_BIAS)
+            ssm("a_log", (N, di), _A_LOG)
+            ssm("d_skip", (di,), _ONES)
+        else:
+            # Mamba-2: one projection gives the gate z, x with every
+            # group's B and C behind it (the convolution runs over all of
+            # those) and a step size a head; a decay and a skip a head
+            H, W = cfg.ssm_num_heads, cfg.ssm_conv_width
+            ssm("w_in", (h, di + W + H), _NORMAL)
+            ssm("conv_w", (K, W), _NORMAL)
+            ssm("conv_b", (W,), _ZEROS)
+            ssm("b_dt", (H,), _DT_BIAS)
+            ssm("a_log", (H,), _A_LOG_HEADS)
+            ssm("d_skip", (H,), _ONES)
+            ssm("norm/scale", (di,), _ONES)            # the gated norm
         ssm("w_out", (di, h), _SCALED)
 
-    if cfg.num_experts is None:
-        d["layers/mlp/w_in"] = ((L, h, Fin), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
-        d["layers/mlp/w_out"] = ((L, F, h), P(AXIS_PIPE, AXIS_TENSOR, None), _SCALED)
+    # the dense FFN: of every layer, or of the "mlp" layers where a layer
+    # is one block (none of them: no such leaves)
+    Lf = cfg.layers_of("mlp") if single else (
+        L if cfg.num_experts is None else 0)
+    if Lf:
+        d["layers/mlp/w_in"] = ((Lf, h, Fin), P(AXIS_PIPE, None, AXIS_TENSOR), _NORMAL)
+        d["layers/mlp/w_out"] = ((Lf, F, h), P(AXIS_PIPE, AXIS_TENSOR, None), _SCALED)
         if cfg.use_bias_linear:
-            d["layers/mlp/b_in"] = ((L, Fin), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
-            d["layers/mlp/b_out"] = ((L, h), P(AXIS_PIPE, None), _ZEROS)
-    else:
+            d["layers/mlp/b_in"] = ((Lf, Fin), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
+            d["layers/mlp/b_out"] = ((Lf, h), P(AXIS_PIPE, None), _ZEROS)
+    if cfg.num_experts is not None:
         # experts sharded over the dedicated "expert" mesh axis (each ep
         # group holds E/ep experts; GSPMD inserts the dispatch all-to-all
         # between (data, expert)-sharded tokens and expert-sharded weights)
@@ -130,20 +152,40 @@ def _defs(cfg: ModelConfig) -> Dict[str, Any]:
         # data-parallel degree (VERDICT r3 next-round #6)
         # the router keeps its width where only a share of its experts'
         # weights exist here (ModelConfig.moe_experts_held)
-        d["layers/moe/router"] = ((L, h, cfg.num_experts),
+        Le = cfg.expert_layers
+        d["layers/moe/router"] = ((Le, h, cfg.num_experts),
                                   P(AXIS_PIPE, None, None), _NORMAL)
+        if cfg.moe_router_score == "sigmoid":
+            # the selection bias: added to the scores for the choice alone
+            d["layers/moe/router_bias"] = ((Le, cfg.num_experts),
+                                           P(AXIS_PIPE, None), _ZEROS)
         E = cfg.experts_held
-        d["layers/moe/w_in"] = ((L, E, h, Fin),
+        # the width the routed experts read and write: the hidden size, or
+        # the latent one between its two projections
+        w = cfg.moe_latent_size or h
+        if cfg.moe_latent_size is not None:
+            d["layers/moe/latent_in"] = ((Le, h, w), P(AXIS_PIPE, None, None),
+                                         _NORMAL)
+            d["layers/moe/latent_out"] = ((Le, w, h), P(AXIS_PIPE, None, None),
+                                          _SCALED)
+        if cfg.moe_shared_ffn_size is not None:
+            Fs = cfg.moe_shared_ffn_size
+            d["layers/moe/shared_in"] = (
+                (Le, h, Fs * (Fin // F)), P(AXIS_PIPE, None, AXIS_TENSOR),
+                _NORMAL)
+            d["layers/moe/shared_out"] = (
+                (Le, Fs, h), P(AXIS_PIPE, AXIS_TENSOR, None), _SCALED)
+        d["layers/moe/w_in"] = ((Le, E, w, Fin),
                                 P(AXIS_PIPE, AXIS_EXPERT, None, AXIS_TENSOR),
                                 _NORMAL)
-        d["layers/moe/w_out"] = ((L, E, F, h),
+        d["layers/moe/w_out"] = ((Le, E, F, w),
                                  P(AXIS_PIPE, AXIS_EXPERT, AXIS_TENSOR, None),
                                  _SCALED)
         if cfg.use_bias_linear:
-            d["layers/moe/b_in"] = ((L, E, Fin),
+            d["layers/moe/b_in"] = ((Le, E, Fin),
                                     P(AXIS_PIPE, AXIS_EXPERT, AXIS_TENSOR),
                                     _ZEROS)
-            d["layers/moe/b_out"] = ((L, E, h),
+            d["layers/moe/b_out"] = ((Le, E, w),
                                      P(AXIS_PIPE, AXIS_EXPERT, None), _ZEROS)
 
     if not cfg.use_post_ln:  # post-LN layers carry their own output norm
@@ -209,6 +251,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Dict[str, Any]:
         elif kind == _A_LOG:
             rows = jnp.log(jnp.arange(1, shape[-2] + 1, dtype=jnp.float32))
             flat[path] = jnp.broadcast_to(rows[:, None], shape).astype(dtype)
+        elif kind == _A_LOG_HEADS:
+            k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
+            flat[path] = jnp.log(jax.random.uniform(
+                k, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
         elif kind == _DT_BIAS:
             k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
             dt = jnp.exp(jax.random.uniform(

@@ -24,15 +24,15 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from megatron_tpu.config import AttentionKind, ModelConfig
+from megatron_tpu.config import FFN_TYPES, SSM_TYPES, AttentionKind, ModelConfig
 from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.activations import apply_activation
 from megatron_tpu.ops.attention import attention
 from megatron_tpu.ops.fp8 import maybe_fp8_matmul
-from megatron_tpu.ops.moe import layer_stats, moe_block
+from megatron_tpu.ops.moe import layer_stats, moe_block, moe_stats_zero
 from megatron_tpu.ops.normalization import norm_forward, rmsnorm
 from megatron_tpu.ops.rotary import apply_rotary_emb
-from megatron_tpu.ops.ssm import read_state, ssm_mixer, write_state
+from megatron_tpu.ops.ssm import mixer_through_store, ssm_mixer
 from megatron_tpu.ops.weight_quant import deq
 
 Sharder = Callable[[jnp.ndarray, str], jnp.ndarray]
@@ -256,20 +256,55 @@ def mlp_block(cfg: ModelConfig, p: Dict[str, Any], x: jnp.ndarray,
     return out
 
 
+def _no_moe_aux(cfg: ModelConfig) -> jnp.ndarray:
+    """What a layer without experts hands up as moe_aux: a zero scalar,
+    or, in a stack that has expert layers too, their statistics' zero."""
+    if cfg.num_experts is None:
+        return jnp.zeros((), jnp.float32)
+    return moe_stats_zero(cfg)
+
+
 def _ffn(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
-         tp_comm=None, grad_sink=None, layer=None):
-    """Dense MLP or MoE, by config. Returns (out, moe_aux, grad_sink):
-    moe_aux a zero fp32 scalar for a dense layer, [aux loss, load
-    statistic] for an MoE one; grad_sink as block_forward has it."""
+         tp_comm=None, grad_sink=None, layer=None, expert_stacks=None):
+    """Dense MLP or MoE, by what the layer holds (`lp`; by config where a
+    layer holds an FFN whatever its type). Returns (out, moe_aux,
+    grad_sink): moe_aux a zero fp32 scalar for a dense layer, [aux loss,
+    load statistic] for an MoE one; grad_sink as block_forward has it
+    (`layer` the layer's index into its stacks, and into expert_stacks:
+    block_forward's)."""
+    if "moe" not in lp:
+        return (mlp_block(cfg, lp["mlp"], x, tp_comm=tp_comm),
+                _no_moe_aux(cfg), grad_sink)
     if grad_sink is not None:
         out, aux, load, stacks = moe_block(cfg, lp["moe"], x,
                                            (grad_sink["moe"], layer))
         return out, layer_stats(aux, load), {**grad_sink, "moe": stacks}
-    if cfg.num_experts is not None:
-        out, aux, load = moe_block(cfg, lp["moe"], x)
-        return out, layer_stats(aux, load), None
-    return (mlp_block(cfg, lp["mlp"], x, tp_comm=tp_comm),
-            jnp.zeros((), jnp.float32), None)
+    out, aux, load = moe_block(
+        cfg, lp["moe"], x,
+        of_layer=None if expert_stacks is None else (*expert_stacks, layer))
+    return out, layer_stats(aux, load), None
+
+
+def _mixer(cfg: ModelConfig, lp: Dict[str, Any], normed: jnp.ndarray,
+           layer_type: str, type_layer, rope, positions, attn_dropout_key,
+           kv_cache, ssm_state, state_row, state_valid, kind, **attn_args):
+    """A layer's sequence mixer over its normed input -> (out, kv_cache,
+    ssm_state): a state-space mixer reads and writes its row of
+    `ssm_state` and leaves kv_cache alone, attention the other way round
+    (block_forward's docstring)."""
+    if layer_type in SSM_TYPES:
+        if ssm_state is None:
+            out, _ = ssm_mixer(cfg, lp["ssm"], normed, None, state_valid)
+        else:
+            out, ssm_state = mixer_through_store(
+                cfg, lp["ssm"], normed, ssm_state, type_layer, state_row,
+                state_valid)
+        return out, kv_cache, ssm_state
+    out, kv_cache = attention_block(
+        cfg, lp["attn"], normed, rope, positions,
+        attn_dropout_key=attn_dropout_key,
+        kv_cache=kv_cache, layer=type_layer, kind=kind, **attn_args)
+    return out, kv_cache, ssm_state
 
 
 def block_forward(
@@ -297,16 +332,28 @@ def block_forward(
     ssm_state=None,     # ops/ssm.py state store, all state-space layers
     state_row=None,
     state_valid: Optional[jnp.ndarray] = None,
+    expert_stacks=None,  # (w_in, w_out) of all the expert layers, stacked
 ):
     """One decoder layer -> (y, kv_cache, moe_aux, grad_sink, ssm_state):
     kv_cache is the whole store with this layer's rows written
     (attention_block).
 
-    layer_type (config.LAYER_TYPES): the layer's sequence mixer. In a
-    stack of several types a type's stores hold ITS layers, and
-    `type_layer` indexes them (None: `layer`, every layer of one type). A
-    "mamba" layer runs ops/ssm.py's mixer under the region `attention`
-    (the layer's sequence mixer: a trace's regions are a fixed set) and
+    What a layer is follows from the stack (ModelConfig.layer_pattern). A
+    sequence mixer AND a feed-forward block, each behind its norm and
+    with its residual add: `x += mixer(ln1(x)); x += ffn(ln2(x))` (every
+    stack whose pattern names mixers alone, or none). Or ONE block alone
+    behind one norm and one add, `x += block(ln1(x))`: a mixer under the
+    region `attention`, or a feed-forward block (layer_type "mlp", "moe")
+    under the region `mlp`, where the pattern names a feed-forward type
+    (cfg.single_block_layers: `lp` then holds `ln1` and the block's own
+    leaves, no `ln2`).
+
+    layer_type (config.LAYER_TYPES): what the layer's block, or its
+    mixer, is. In a stack of several types a type's stores hold ITS
+    layers, and `type_layer` indexes them (None: `layer`, every layer of
+    one type). A state-space layer ("mamba", "mamba2") runs ops/ssm.py's
+    mixer under the region `attention` (the layer's sequence mixer: a
+    trace's regions are a fixed set) and
     leaves kv_cache alone; it reads and writes its state in `ssm_state`
     (None: the sequence starts here and its state is dropped, as in
     training): of every row, the batch the store's rows in order, or of
@@ -323,6 +370,11 @@ def block_forward(
     through for the cotangents to ride the backward pass; `layer` is this
     layer's index into them as into the store.
 
+    expert_stacks: the expert layers' two stacked matrices, of which
+    `type_layer` is this layer's, from a serving step of a stack of one
+    block a layer (ops/moe.py moe_block's `of_layer`: the kernels read
+    the layer's matrices in place).
+
     hidden_dropout_rate may be a traced scalar (LIMA per-layer ramp, ref
     transformer.py:994-1001). moe_aux is a zero scalar for dense models
     and [aux loss, load statistic] for MoE ones (ops/moe.py
@@ -332,6 +384,40 @@ def block_forward(
     else:
         k_attn_drop = k_hidden1 = k_hidden2 = None
     rate = cfg.hidden_dropout if hidden_dropout_rate is None else hidden_dropout_rate
+
+    if type_layer is None:
+        type_layer = layer
+
+    def mixer(normed):
+        return _mixer(
+            cfg, lp, normed, layer_type, type_layer, rope, positions,
+            k_attn_drop if cfg.attention_dropout > 0 else None,
+            kv_cache, ssm_state, state_row, state_valid, kind,
+            cache_index=cache_index, padding_mask=padding_mask,
+            page_table=page_table, page_write_start=page_write_start,
+            page_write_end=page_write_end, tp_comm=tp_comm, cp_comm=cp_comm)
+
+    if cfg.single_block_layers:
+        # one norm, one block, one residual add, under the region of what
+        # the block is: `mlp` for a feed-forward block alone, `attention`
+        # for a mixer alone
+        ffn = layer_type in FFN_TYPES
+        part = "mlp" if ffn else "attn"
+        with jax.named_scope("mlp" if ffn else "attention"):
+            with jax.named_scope(f"{part}_norm"):
+                normed = _norm(cfg, lp["ln1"], x)
+            if ffn:
+                out, moe_aux, grad_sink = _ffn(
+                    cfg, lp, normed, tp_comm, grad_sink, type_layer,
+                    expert_stacks)
+            else:
+                out, kv_cache, ssm_state = mixer(normed)
+                moe_aux = _no_moe_aux(cfg)
+            with jax.named_scope(f"{part}_out"):
+                out = _dropout(out, rate,
+                               k_hidden1 if cfg.hidden_dropout > 0 else None)
+                y = sharder(x + out, "residual")
+        return y, kv_cache, moe_aux, grad_sink, ssm_state
 
     # The two named scopes are the regions a device trace is read by
     # (docs/observability.md "Runtime traces"): every operation of a layer,
@@ -349,30 +435,7 @@ def block_forward(
         # own LN, reusing the ln1 parameter slot as the output norm
         with jax.named_scope("attn_norm"):
             normed = x if cfg.use_post_ln else _norm(cfg, lp["ln1"], x)
-        if type_layer is None:
-            type_layer = layer
-        if layer_type == "mamba":
-            state = (None if ssm_state is None
-                     else read_state(ssm_state, type_layer, state_row))
-            attn_out, state = ssm_mixer(cfg, lp["ssm"], normed, state,
-                                        state_valid)
-            if ssm_state is not None:
-                ssm_state = write_state(ssm_state, type_layer, state,
-                                        state_row)
-        else:
-            attn_out, kv_cache = attention_block(
-                cfg, lp["attn"], normed, rope, positions,
-                attn_dropout_key=(k_attn_drop if cfg.attention_dropout > 0
-                                  else None),
-                kv_cache=kv_cache, layer=type_layer, cache_index=cache_index,
-                padding_mask=padding_mask,
-                page_table=page_table,
-                page_write_start=page_write_start,
-                page_write_end=page_write_end,
-                tp_comm=tp_comm,
-                cp_comm=cp_comm,
-                kind=kind,
-            )
+        attn_out, kv_cache, ssm_state = mixer(normed)
         with jax.named_scope("attn_out"):
             attn_out = _dropout(attn_out, rate, k_hidden1 if cfg.hidden_dropout > 0 else None)
             if not cfg.parallel_attn:

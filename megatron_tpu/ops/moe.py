@@ -3,10 +3,27 @@
 Beyond the reference (epfLLM/Megatron-LLM has no MoE). One router and one
 definition of the auxiliary losses serve both dispatch forms:
 
-  * router: softmax over E experts in fp32, top-k selection per token
-    (k=1 Switch, k=2 GShard/Mixtral, k=8 OLMoE); optional renormalization
-    of the selected gate weights to sum 1 (Mixtral; OLMoE's
-    `norm_topk_prob: false` is `moe_renorm_gates=False`).
+  * router, softmax form (`moe_router_score="softmax"`): softmax over E
+    experts in fp32, top-k selection per token (k=1 Switch, k=2
+    GShard/Mixtral, k=8 OLMoE); optional renormalization of the selected
+    gate weights to sum 1 (Mixtral; OLMoE's `norm_topk_prob: false` is
+    `moe_renorm_gates=False`).
+  * router, sigmoid form (`"sigmoid"`; DeepSeek-V3's, and Nemotron-H's
+    `NemotronHTopkRouter`), fp32: s = sigmoid(u W_r), a score an expert on
+    its own; the k chosen are the k largest of s + b, b the learned
+    selection bias `router_bias` [E], read for the choice alone; the gates
+    are w_e = s_e / sum over the chosen of s (`moe_renorm_gates`), times
+    `moe_route_scale`. No load-balance loss goes with it (the bias is
+    what balances): the auxiliary loss is zero, the load statistic stays.
+  * around the routed experts (the dropless block alone): with
+    `moe_latent_size` they work in a narrower width, l = u W_dn in front
+    of the dispatch and (sum over the chosen of w_e o_e) W_up behind the
+    combine, both linear, no bias, no norm (scopes `moe_latent_in`,
+    `moe_latent_out`); the router reads the full-width u. With
+    `moe_shared_ffn_size` a shared expert, an MLP of that width that every
+    token goes through, is added to their result (scope `moe_shared`).
+    With `activation="squared_relu"` an expert is relu(l W1)^2 W2: two
+    matrices, no gate matrix.
   * auxiliary losses: the load-balance loss E * sum_e f_e * P_e with f_e
     the fraction of (token, choice) assignments over ALL k choices that
     went to expert e (sum_e f_e = k) and P_e the mean router probability
@@ -143,10 +160,13 @@ def topk_dispatch(
     return combine, combine > 0, chosen
 
 
-def _topk_gates(gates: jnp.ndarray, top_k: int, renorm: bool):
+def _topk_gates(gates: jnp.ndarray, top_k: int, renorm: bool,
+                select: Optional[jnp.ndarray] = None):
     """THE top-k + renorm numerics (one definition for both dispatch
-    modes, so they cannot drift apart)."""
-    _, topi = jax.lax.top_k(gates, top_k)
+    modes and both forms of router, so they cannot drift apart). select:
+    what the choice is made by where that is not the gates themselves
+    (the sigmoid form's scores plus their selection bias)."""
+    _, topi = jax.lax.top_k(gates if select is None else select, top_k)
     # the chosen gates are read off by a dense compare-and-sum over E (the
     # same values: one term a choice is not zero), so that their gradient
     # is dense too; lax.top_k's own transposes into a scatter-add
@@ -158,9 +178,16 @@ def _topk_gates(gates: jnp.ndarray, top_k: int, renorm: bool):
 
 
 def _route(cfg: ModelConfig, p: Dict[str, Any], x2d: jnp.ndarray):
-    """Shared router: (logits, gates, topw, topi) for [N, H] tokens."""
+    """Shared router: (logits, gates, topw, topi) for [N, H] tokens, in
+    either form (module docstring)."""
     logits = jnp.einsum("nh,he->ne", x2d.astype(jnp.float32),
                         p["router"].astype(jnp.float32))
+    if cfg.moe_router_score == "sigmoid":
+        gates = jax.nn.sigmoid(logits)
+        topw, topi = _topk_gates(
+            gates, cfg.moe_top_k, cfg.moe_renorm_gates,
+            select=gates + p["router_bias"].astype(jnp.float32))
+        return logits, gates, topw * cfg.moe_route_scale, topi
     gates = jax.nn.softmax(logits, axis=-1)
     topw, topi = _topk_gates(gates, cfg.moe_top_k, cfg.moe_renorm_gates)
     return logits, gates, topw, topi
@@ -176,12 +203,21 @@ def _aux_from_stats(cfg: ModelConfig, frac, prob, z_sq_mean):
     lb_loss = cfg.num_experts * jnp.sum(frac * prob)
     aux = (cfg.moe_aux_loss_coeff * lb_loss
            + cfg.moe_z_loss_coeff * z_sq_mean).astype(jnp.float32)
-    return aux, (jnp.max(frac) / jnp.mean(frac)).astype(jnp.float32)
+    return aux, _load_statistic(frac)
+
+
+def _load_statistic(frac) -> jnp.ndarray:
+    """The largest expert's share of the assignments over the mean."""
+    return (jnp.max(frac) / jnp.mean(frac)).astype(jnp.float32)
 
 
 def _aux_losses(cfg: ModelConfig, logits, gates, frac):
     """Load-balance loss over all k choices + ST-MoE router z-loss, and
-    the load statistic (shared between dispatch modes)."""
+    the load statistic (shared between dispatch modes). The sigmoid form
+    has no such loss (its scores are no distribution over the experts):
+    zero, and the load statistic."""
+    if cfg.moe_router_score == "sigmoid":
+        return jnp.zeros((), jnp.float32), _load_statistic(frac)
     prob = jnp.mean(gates.reshape(-1, cfg.num_experts), axis=0)
     z_sq = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     return _aux_from_stats(cfg, frac, prob, z_sq)
@@ -386,7 +422,7 @@ def expert_grad_sinks(cfg: ModelConfig, p: Dict[str, Any],
 
 def experts_mlp(cfg: ModelConfig, p: Dict[str, Any], xs: jnp.ndarray,
                 group_sizes: jnp.ndarray, expert_of_row, dtype,
-                ragged: bool = False, grad_sink=None):
+                ragged: bool = False, grad_sink=None, of_layer=None):
     """The held experts' MLP over rows xs [R, h] that stand sorted by
     expert: the next group_sizes[g] rows are expert g's, g counting the
     experts whose matrices `p` holds. Returns ([R, h], the sink's stacks).
@@ -412,10 +448,14 @@ def experts_mlp(cfg: ModelConfig, p: Dict[str, Any], xs: jnp.ndarray,
     over the buffer each.
 
     grad_sink = (stacks, layer), as moe_block has it: the matrix's
-    gradient goes into its stack where it has one."""
+    gradient goes into its stack where it has one.
+
+    of_layer = (w_in's stack, w_out's stack, layer), as moe_block has it:
+    where the products are the kernels they read the layer's matrices in
+    the stacks (`grouped_mlp_of_layer`), and `p`'s own are not read."""
     # with the other kernels: imported where it is used (ops/attention.py)
     from megatron_tpu.ops.pallas.grouped_matmul import (
-        grouped_matmul, grouped_mlp, visits_for,
+        grouped_matmul, grouped_mlp, grouped_mlp_of_layer, visits_for,
     )
 
     # one visit table for both products and their gradients (None where
@@ -427,6 +467,12 @@ def experts_mlp(cfg: ModelConfig, p: Dict[str, Any], xs: jnp.ndarray,
     # where the products are the kernels, the activation (and, of a share,
     # the rows behind the last group) is theirs too: nothing stands between
     # them but the first product. Experts with biases keep the form below.
+    if (of_layer is not None and visits is not None
+            and "b_in" not in p and "b_out" not in p):
+        out = grouped_mlp_of_layer(xs, *of_layer, group_sizes,
+                                   cfg.activation, visits=visits)
+        if out is not None:
+            return out, stacks
     if visits is not None and "b_in" not in p and "b_out" not in p:
         fused = grouped_mlp(
             xs, p["w_in"], p["w_out"], group_sizes, cfg.activation,
@@ -470,6 +516,7 @@ def moe_block_dropless(
     p: Dict[str, Any],
     x: jnp.ndarray,      # [B, S, H]
     grad_sink=None,      # ({"w_in", "w_out": f32 [L, E, k, n]}, layer)
+    of_layer=None,       # (w_in [L, E, k, n], w_out [L, E, n, k], layer)
 ):
     """Sort-based dropless dispatch (MegaBlocks-style, TPU form).
     Returns (y [B,S,H], aux loss, load statistic), and with `grad_sink`
@@ -531,23 +578,39 @@ def moe_block_dropless(
             load = jnp.stack([load, jnp.sum(group_sizes).astype(jnp.float32)
                               / (N * k)])
 
+    xe = xf
+    if cfg.moe_latent_size is not None:
+        with jax.named_scope("moe_latent_in"):
+            # the routed experts' width (the router has read the whole u)
+            xe = xf @ p["latent_in"]
+
     with jax.named_scope("moe_dispatch"):
         # the (token, choice) rows sorted by expert, and the way back
         order, inv = sort_by_expert(topi)
-        xs = rows_to_expert_order(xf, order, inv, mine)  # [N*k, H] sorted
+        xs = rows_to_expert_order(xe, order, inv, mine)  # [N*k, w] sorted
 
     with jax.named_scope("moe_experts"):
         out, stacks = experts_mlp(
             cfg, p, xs, group_sizes,
             lambda: jnp.take(jnp.minimum(topi.reshape(-1), held - 1)
                              if share else flat_e, order),
-            x.dtype, ragged=share, grad_sink=grad_sink)
+            x.dtype, ragged=share, grad_sink=grad_sink, of_layer=of_layer)
 
     with jax.named_scope("moe_combine"):
         # back to token order through the inverse sort; each token's k
         # choices weighted by its gates and summed in float32
         y = rows_to_token_order(out, topw, order, inv, x.dtype, mine)
-        y = y.reshape(b, s, h)
+
+    if cfg.moe_latent_size is not None:
+        with jax.named_scope("moe_latent_out"):
+            y = y @ p["latent_out"]
+    if cfg.moe_shared_ffn_size is not None:
+        with jax.named_scope("moe_shared"):
+            # every token's, whole on every chip of a share: a chip
+            # computes it for its own tokens
+            y = y + apply_activation(
+                cfg.activation, xf @ p["shared_in"]) @ p["shared_out"]
+    y = y.reshape(b, s, h)
     return (y, aux, load) if grad_sink is None else (y, aux, load, stacks)
 
 
@@ -835,8 +898,16 @@ def moe_block(
     p: Dict[str, Any],   # one layer's moe subtree: router, w_in, w_out (+biases)
     x: jnp.ndarray,      # [B, S, H]
     grad_sink=None,
+    of_layer=None,
 ):
     """Returns (y [B,S,H], aux loss, load statistic), both fp32 scalars.
+
+    of_layer = (w_in's stack, w_out's stack, layer): the stacked layers'
+    expert matrices this layer's are part of, [L, E, ...], and its index
+    in them, from a caller that makes no gradient (a serving step). The
+    dropless block's kernels then read the layer's matrices where they
+    lie in the stacks, which `p`'s own, sliced out of them in front of a
+    kernel's call, would be copied for. The result is the same.
 
     grad_sink = (stacks, layer): where the gradients of this layer's
     expert matrices are to be summed, for a step that accumulates them
@@ -850,9 +921,13 @@ def moe_block(
     if grad_sink is not None:
         # expert_grad_sinks names a leaf only where this form runs
         return moe_block_dropless(cfg, p, x, grad_sink)
-    if cfg.holds_expert_share:
-        # one chip's share runs without the exchange, whatever the mesh
-        return moe_block_dropless(cfg, p, x)
+    if (cfg.holds_expert_share or cfg.moe_latent_size is not None
+            or cfg.moe_shared_ffn_size is not None
+            or cfg.moe_router_score != "softmax"):
+        # one chip's share runs without the exchange, whatever the mesh;
+        # the sigmoid router, the latent projections and the shared expert
+        # are this form's alone
+        return moe_block_dropless(cfg, p, x, of_layer=of_layer)
     if cfg.moe_dispatch == "dropless":
         dsz, ep, named_axes = _ambient_batch_axes()
         # manual data axis (per-shard local sort, no batch-axis argsort
@@ -868,7 +943,7 @@ def moe_block(
         if named_axes and (ep_ok or include_data):
             return moe_block_dropless_ep(cfg, p, x, None, ep,
                                          include_data=include_data)
-        return moe_block_dropless(cfg, p, x)
+        return moe_block_dropless(cfg, p, x, of_layer=of_layer)
     b, s, h = x.shape
     N = b * s
     # group tokens GShard-style; Sg must divide the *runtime* S (decode

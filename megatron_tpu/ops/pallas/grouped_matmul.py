@@ -733,6 +733,49 @@ _grouped_mlp_kernels.defvjp(_mlp_fwd, _mlp_bwd)
 _NOT_IN_KERNEL = ("gelu", "geglu")
 
 
+def _visits_of_layer(visits: GroupVisits, layer, layers: int) -> GroupVisits:
+    """The visit table of E groups as one over layers * E groups, of which
+    layer `layer`'s are these: group g becomes group layer * E + g, and
+    the offsets stand where the kernels look them up (`_visit`: entries
+    g and g + 1 of the visit's group)."""
+    E = visits.offsets.shape[0] - 1
+    first = jnp.asarray(layer, jnp.int32) * E
+    at = jnp.clip(jnp.arange(layers * E + 1, dtype=jnp.int32) - first, 0, E)
+    return GroupVisits(jnp.take(visits.offsets, at),
+                       visits.group_ids + first, visits.tile_ids,
+                       visits.count)
+
+
+def grouped_mlp_of_layer(xs: jnp.ndarray, w_in: jnp.ndarray,
+                         w_out: jnp.ndarray, layer,
+                         group_sizes: jnp.ndarray, activation: str, *,
+                         visits: Optional[GroupVisits] = None):
+    """`grouped_mlp`'s result for layer `layer` (static or traced) of the
+    STACKED matrices w_in [L, E, h, f or 2f] and w_out [L, E, f, h], read
+    where they lie. A kernel's operand is a whole array: a layer's matrices
+    sliced out of their stacks in front of the call are a copy of every
+    expert's matrices, every call (two of 0.7 GB a layer in a decode tick
+    of the benchmark's Nemotron share, as much again as the tick needs to
+    read). The stacks go in as [L * E, ...], which is no copy, and the
+    visit table names the layer's group g as group layer * E + g
+    (`_visits_of_layer`); the kernels are `moe_gmm` as they stand.
+
+    The forward products alone, with no gradient rule: for the serving
+    steps, which nothing differentiates. None where `grouped_mlp` gives
+    None."""
+    L, E = w_out.shape[:2]
+    plans = _mlp_plans(xs, w_in, w_out, activation)
+    if plans is None:
+        return None
+    if visits is None:
+        visits = visits_for(group_sizes, xs.shape[0])
+    return _mlp_products(
+        xs, w_in.reshape((L * E,) + w_in.shape[2:]),
+        w_out.reshape((L * E,) + w_out.shape[2:]),
+        _visits_of_layer(visits, layer, L),
+        plan=_MlpPlan(*plans, activation, False, None))[0]
+
+
 def _one_tpu() -> bool:
     """The kernels serve one TPU: GSPMD cannot partition a Mosaic call, and
     under a mesh of several devices the products stay `lax.ragged_dot`,
@@ -742,6 +785,23 @@ def _one_tpu() -> bool:
     from megatron_tpu.parallel.mesh import ambient_mesh_shape
 
     return math.prod(ambient_mesh_shape().values()) == 1
+
+
+def _mlp_plans(xs, w_in, w_out, activation: str):
+    """The plans of the experts' two products, (rows · w_in, activation ·
+    w_out), where the kernels hold both and the activation between them;
+    else None. w_in [.., E, h, f or 2f], w_out [.., E, f, h]: the last
+    three axes are read (a layer's matrices, or the stacked layers')."""
+    m, h = xs.shape
+    E, f, _ = w_out.shape[-3:]
+    # (the first product, the activation and the result all in the rows'
+    # dtype, as the products apart have them from such operands)
+    if (not _one_tpu() or activation in _NOT_IN_KERNEL
+            or w_in.shape[-1] != _act_parts(activation) * f
+            or not xs.dtype == w_in.dtype == w_out.dtype):
+        return None
+    plans = _plan(m, h, w_in.shape[-1], E), _plan(m, f, h, E)
+    return None if None in plans else plans
 
 
 def visits_for(group_sizes: jnp.ndarray, m: int) -> Optional[GroupVisits]:
@@ -815,20 +875,12 @@ def grouped_mlp(xs: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray,
     behind them, and its gradient, holds whatever the buffer held.
     save_as: the `checkpoint_name` of the first product, the one value
     between the kernels, for a caller's `jax.checkpoint` policy."""
-    m, h = xs.shape
-    E, f, _ = w_out.shape
-    # (the first product, the activation and the result all in the rows'
-    # dtype, as the products apart have them from such operands)
-    if (not _one_tpu() or activation in _NOT_IN_KERNEL
-            or w_in.shape[2] != _act_parts(activation) * f
-            or not xs.dtype == w_in.dtype == w_out.dtype):
-        return None
-    first, second = _plan(m, h, w_in.shape[2], E), _plan(m, f, h, E)
-    if first is None or second is None or (
-            _act_parts(activation) > 1 and second.drows[2] != f):
+    plans = _mlp_plans(xs, w_in, w_out, activation)
+    if plans is None or (_act_parts(activation) > 1
+                         and plans[1].drows[2] != w_out.shape[1]):
         return None
     if visits is None:
-        visits = visits_for(group_sizes, m)
+        visits = visits_for(group_sizes, xs.shape[0])
     return _grouped_mlp_kernels(
         xs, w_in, w_out, visits, tuple(sinks),
-        _MlpPlan(first, second, activation, ragged, save_as))
+        _MlpPlan(*plans, activation, ragged, save_as))

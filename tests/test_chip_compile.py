@@ -192,12 +192,28 @@ def _kernel_cases():
             f"paged_chunk_{cell}", functools.partial(_chunk_cell, window),
             [((1, _CHUNK, hq, D), bf16), pool, pool, ((1, entries), i32),
              ((1,), i32), ((1,), i32)], ["paged_flash_chunk"]))
+    # the Mamba-2 decode step at the Nemotron share's shapes (benchmark/
+    # configs/nemotron3-super-120b-a12b-s4-d11-serve.json: 5 layers' rows
+    # of 64 slots, a state of 128 over 8192 channels in 8 groups), the
+    # store in place
+    f32 = jnp.float32
+    cases.append((
+        "ssd_step_nemotron", _ssd_step_cell,
+        [((5, 64, 128, 8192), f32), ((), i32), ((64, 8192), f32),
+         ((64, 8192), f32), ((64, 8, 128), f32), ((64, 8, 128), f32)],
+        ["ssd_step"]))
     cases.append((
         "paged_chunk_slot_rows", functools.partial(_chunk_cell, WINDOW),
         [((SLOTS, _CHUNK, HQ, D), bf16), kv_cache, kv_cache,
          ((SLOTS, 1), i32), ((SLOTS,), i32), ((SLOTS,), i32)],
         ["paged_flash_chunk"]))
     return cases
+
+
+def _ssd_step_cell(store, layer, decay, dtx, b, c):
+    from megatron_tpu.ops.pallas.ssd_step import ssd_step
+
+    return ssd_step(store, layer, decay, dtx, b, c)
 
 
 def _chunk_cell(window, q, kp, vp, table, offs, ends):
