@@ -252,6 +252,20 @@ class ContextParallelEngine(PagedInferenceEngine):
                 cols == SCRATCH_PAGE, 0 if r == 0 else npl, cols - r * npl)
         return loc
 
+    def _rows_decoding(self):
+        """As the flat engine's, over the ranks' local tables [cp, M, mpl]:
+        an entry without a page is rank 0's scratch there and the
+        sentinel `npl` on the other ranks (_loc_tables; the pool is sized
+        when the steps are built)."""
+        cp, npl = self.cp, self.num_pages // self.cp
+
+        def rows_decoding(table):
+            empty = jnp.where(jnp.arange(cp) == 0, SCRATCH_PAGE, npl)
+            return jnp.any(table != empty[:, None, None],
+                           axis=(0, 2)).astype(jnp.int32)
+
+        return rows_decoding
+
     def _cp_table_device(self, loc: np.ndarray):
         from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -48,6 +48,24 @@ from megatron_tpu.inference.paging.scheduler import (
 from megatron_tpu.inference.sampling import sample_logits_batched
 from megatron_tpu.ops import kv_store, ssm
 
+# what the steps of a model that holds a share of its router's experts
+# count on the device, in `_step_counts`' order: the key in `stats`, the
+# counter on /metrics and its help
+_MOE_COUNTS = (
+    ("moe_held_rows", "engine_moe_held_rows_total",
+     "those of engine_moe_rows_total sent to experts held on this chip"),
+    ("moe_rows", "engine_moe_rows_total",
+     "(row, choice) pairs the steps routed, over the expert layers (rows "
+     "somebody reads x experts a token x expert layers; a model that holds "
+     "a share of its router's experts)"),
+    ("moe_experts_read", "engine_moe_experts_read_total",
+     "held experts a decoding row reached, over the decode ticks and the "
+     "expert layers (the router's count: what the experts' kernels may "
+     "leave unread)"),
+    ("moe_experts_offered", "engine_moe_experts_offered_total",
+     "held experts there were for them (held x expert layers a tick)"),
+)
+
 
 class PagedInferenceEngine(InferenceEngine):
     """Slot scheduler + paged KV pool + radix prefix cache.
@@ -183,22 +201,23 @@ class PagedInferenceEngine(InferenceEngine):
             self._zero_state_row = jax.jit(
                 ssm.zero_row, donate_argnums=(0,) if self._donate() else ())
         # a model that holds a share of its router's experts: how many of
-        # the (row, choice) pairs its steps computed went to experts held
-        # here, summed over the expert layers. Both steps add to one pair
-        # of counters on the device (_step_counts: [held, all], uint32,
-        # which wraps), read with the steps' tokens (_apply_counts).
-        self._m_moe_rows = m.counter(
-            "engine_moe_rows_total",
-            "(row, choice) pairs the steps routed, over the expert layers "
-            "(rows x experts a token x expert layers; a model that holds a "
-            "share of its router's experts)")
-        self._m_moe_held = m.counter(
-            "engine_moe_held_rows_total",
-            "those of them sent to experts held on this chip")
+        # the (row, choice) pairs its steps computed FOR A ROW SOMEBODY
+        # READS (a decoding slot's, a chunk's real positions: the others
+        # are not routed, ops/moe.py moe_block `rows_read`) went to experts
+        # held here, summed over the expert layers; and, of the decode
+        # ticks alone, how many of the held experts such a row reached
+        # (the matrices the tick's expert kernels had to move) of those
+        # there are. Both steps add to one vector of counters on the device
+        # (_step_counts: [held, all, experts read, experts offered],
+        # uint32, which wraps), read with the steps' tokens
+        # (_apply_counts).
+        self._m_moe = [m.counter(name, text) for _, name, text in _MOE_COUNTS]
         if self.cfg.holds_expert_share:
-            self.stats["moe_rows"] = self.stats["moe_held_rows"] = 0
-            self._step_counts = self._commit_small(np.zeros(2, np.uint32))
-            self._counts_seen = np.zeros(2, np.uint32)
+            for key, _, _ in _MOE_COUNTS:
+                self.stats[key] = 0
+            self._step_counts = self._commit_small(
+                np.zeros(len(_MOE_COUNTS), np.uint32))
+            self._counts_seen = np.zeros(len(_MOE_COUNTS), np.uint32)
 
     # ----- cache + shape policy -------------------------------------------
 
@@ -278,20 +297,27 @@ class PagedInferenceEngine(InferenceEngine):
         cp_comm = getattr(self, "cp_comm", None)
         from megatron_tpu.models.language_model import lm_forward
 
-        def forward(params, caches, state, tokens, *counts, **where):
+        def forward(params, caches, state, tokens, *counts, tick=False,
+                    **where):
             out = lm_forward(cfg, params, tokens, kv_caches=caches,
                              ssm_state=state, tp_comm=tp_comm,
                              cp_comm=cp_comm, return_moe_aux=bool(counts),
                              **where)
             if counts:
                 # the layers' shares of held rows, summed, times the pairs
-                # a layer routes: whole numbers, exact in float32
+                # a layer is handed: whole numbers, exact in float32;
+                # behind them the held experts a read row reached, which
+                # only a decode tick counts
                 *out, moe_aux = out
-                pairs = tokens.size * cfg.moe_top_k
-                counts = (counts[0] + jnp.stack([
-                    jnp.round(moe_aux[2] * pairs),
-                    jnp.float32(pairs * cfg.expert_layers)]
-                ).astype(jnp.uint32),)
+                layers, k = cfg.expert_layers, cfg.moe_top_k
+                read = jnp.sum(jnp.minimum(where["state_valid"],
+                                           tokens.shape[1]))
+                new = [jnp.round(moe_aux[2] * (tokens.size * k)),
+                       read * (k * layers),
+                       jnp.round(moe_aux[3]) if tick else 0,
+                       cfg.moe_experts_held * layers if tick else 0]
+                counts = (counts[0] + jnp.stack(
+                    [jnp.asarray(n).astype(jnp.uint32) for n in new]),)
             return (*out, *((None,) if state is None else ()), *counts)
 
         return forward
@@ -303,9 +329,22 @@ class PagedInferenceEngine(InferenceEngine):
     def _counts_arg(self):
         return () if self._step_counts is None else (self._step_counts,)
 
+    def _rows_decoding(self):
+        """The function the decode step reads its table with: [slots]
+        int32, 1 where the slot decodes. A decoding slot's row of the
+        table holds a page, the one it writes at the least; an idle
+        slot's and a prefilling slot's (its pages wait in `_pending_rows`)
+        are all scratch. Not the row's first entry: the window's release
+        parks that one on scratch while the slot decodes on
+        (_release_window_pages). It holds no reference to the engine, as
+        nothing the steps close over does."""
+        return lambda table: jnp.any(table != SCRATCH_PAGE,
+                                     axis=1).astype(jnp.int32)
+
     def _build_decode_step(self):
         vocab, wlp = self.vocab_size, self.want_logprobs
-        forward = self._forward()
+        experts = self.cfg.num_experts is not None
+        forward, rows_decoding = self._forward(), self._rows_decoding()
         from functools import partial
 
         @partial(jax.jit, donate_argnums=self._donate_with_state(),
@@ -318,16 +357,17 @@ class PagedInferenceEngine(InferenceEngine):
             # reads route through the page table (ops/attention.py picks
             # the paged flash-decode kernel on TPU, the gather elsewhere).
             # state (None without state-space layers): this tick advances
-            # the rows of the slots that decode, those whose row of the
-            # table holds a page (an idle slot's and a prefilling slot's
-            # are scratch: their state stays).
-            decoding = (None if state is None else
-                        (table[:, 0] != SCRATCH_PAGE).astype(jnp.int32))
+            # the rows of the slots that decode (_rows_decoding: an idle
+            # slot's and a prefilling slot's state stays). The expert
+            # layers route those rows alone. A model with neither is not
+            # told.
+            decoding = (None if state is None and not experts else
+                        rows_decoding(table))
             # counts (of a model that holds a share of its experts, else
             # absent): _step_counts, which this step adds its rows to and
             # returns behind everything else
             logits, caches, state, *counts = forward(
-                params, caches, state, last_tok[:, None], *counts,
+                params, caches, state, last_tok[:, None], *counts, tick=True,
                 cache_index=lengths, page_table=table, state_valid=decoding)
             logits = logits[:, 0]
             split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
@@ -347,6 +387,7 @@ class PagedInferenceEngine(InferenceEngine):
     def _build_chunk_step(self):
         vocab, wlp = self.vocab_size, self.want_logprobs
         C = self.prefill_chunk
+        experts = self.cfg.num_experts is not None
         forward = self._forward()
         from functools import partial
 
@@ -374,13 +415,14 @@ class PagedInferenceEngine(InferenceEngine):
             the state up where the prompt's last chunk left it and leaves
             it after the last real position (write_end - off of C: the
             padded tail moves neither the state nor the convolution's
-            tail). counts: as the decode step's."""
+            tail, and reaches no expert). counts: as the decode step's."""
+            real = (None if state is None and not experts else
+                    jnp.clip(write_end - off, 0, C)[None])
             logits, caches, state, *counts = forward(
                 params, caches, state, tokens_ext[:, :C], *counts,
                 cache_index=off, page_table=table_row,
                 page_write_start=write_start, page_write_end=write_end,
-                state_row=slot,
-                state_valid=jnp.clip(write_end - off, 0, C)[None])
+                state_row=slot, state_valid=real)
             if wlp:
                 lsm = jax.nn.log_softmax(logits[0].astype(jnp.float32),
                                          axis=-1)
@@ -461,6 +503,8 @@ class PagedInferenceEngine(InferenceEngine):
         if self.cfg.holds_expert_share:
             fields["moe_rows"] = [self.stats["moe_held_rows"],
                                   self.stats["moe_rows"]]
+            fields["moe_experts"] = [self.stats["moe_experts_read"],
+                                     self.stats["moe_experts_offered"]]
         return fields
 
     def _slow_tick_fields(self) -> dict:
@@ -917,18 +961,17 @@ class PagedInferenceEngine(InferenceEngine):
         return toks, lps, keys, lens
 
     def _apply_counts(self, counts) -> None:
-        """The device's [held, all] pairs so far, as a read step's fetch
-        brought them: the counters move by what is new since the last
-        read (the device's pair wraps at 2**32; the difference does not
-        care)."""
+        """The device's counts so far (_MOE_COUNTS), as a read step's
+        fetch brought them: the counters move by what is new since the
+        last read (the device's numbers wrap at 2**32; the difference
+        does not care)."""
         if counts is None:
             return
         new = counts - self._counts_seen
         self._counts_seen = counts
-        self._m_moe_held.inc(int(new[0]))
-        self._m_moe_rows.inc(int(new[1]))
-        self.stats["moe_held_rows"] += int(new[0])
-        self.stats["moe_rows"] += int(new[1])
+        for (key, _, _), metric, n in zip(_MOE_COUNTS, self._m_moe, new):
+            metric.inc(int(n))
+            self.stats[key] += int(n)
 
     def _chunk_table_arg(self, row):
         """Device form of one pending table row for the chunk step

@@ -443,6 +443,10 @@ def lm_forward(
     positions are real (a chunk's padded tail, a slot that does not
     decode this tick: neither moves the state). None with such a model:
     every sequence starts here and its state is dropped (training).
+    state_valid alone, of a serving step of any model: the expert layers
+    route the real positions only (ops/moe.py moe_block `rows_read`;
+    return_moe_aux then carries, of a share of the experts, the held
+    experts a real position reached, summed over the layers, last).
 
     grad_sink: float32 accumulators for the gradients of some leaves of
     `params`, in a tree shaped like `params` that holds None at every
@@ -496,7 +500,8 @@ def lm_forward(
         rope_len = max(cfg.seq_length, tokens.shape[1])
     ropes = rope_tables(cfg, cfg.attention_period, rope_len)
     moe = cfg.num_experts is not None
-    carry = (x, moe_stats_zero(cfg) if moe else jnp.zeros((), jnp.float32),
+    carry = (x, (moe_stats_zero(cfg, state_valid is not None) if moe
+                 else jnp.zeros((), jnp.float32)),
              kv_caches, None if grad_sink is None else grad_sink["layers"],
              ssm_state)
     x, moe_aux, new_caches, layer_sinks, new_state = run_layers(
@@ -511,8 +516,8 @@ def lm_forward(
         page_write_end=page_write_end,
         tp_comm=tp_comm,
         cp_comm=cp_comm,
-        **({} if ssm_state is None else
-           {"state_row": state_row, "state_valid": state_valid}),
+        state_row=state_row,
+        state_valid=state_valid,
     )
 
     def with_sinks(result):

@@ -265,13 +265,15 @@ def _no_moe_aux(cfg: ModelConfig) -> jnp.ndarray:
 
 
 def _ffn(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
-         tp_comm=None, grad_sink=None, layer=None, expert_stacks=None):
+         tp_comm=None, grad_sink=None, layer=None, expert_stacks=None,
+         rows_read=None):
     """Dense MLP or MoE, by what the layer holds (`lp`; by config where a
     layer holds an FFN whatever its type). Returns (out, moe_aux,
     grad_sink): moe_aux a zero fp32 scalar for a dense layer, [aux loss,
     load statistic] for an MoE one; grad_sink as block_forward has it
     (`layer` the layer's index into its stacks, and into expert_stacks:
-    block_forward's)."""
+    block_forward's). rows_read: block_forward's state_valid, for the
+    experts (ops/moe.py moe_block); a dense MLP computes every row."""
     if "moe" not in lp:
         return (mlp_block(cfg, lp["mlp"], x, tp_comm=tp_comm),
                 _no_moe_aux(cfg), grad_sink)
@@ -281,7 +283,8 @@ def _ffn(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
         return out, layer_stats(aux, load), {**grad_sink, "moe": stacks}
     out, aux, load = moe_block(
         cfg, lp["moe"], x,
-        of_layer=None if expert_stacks is None else (*expert_stacks, layer))
+        of_layer=None if expert_stacks is None else (*expert_stacks, layer),
+        rows_read=rows_read)
     return out, layer_stats(aux, load), None
 
 
@@ -358,7 +361,9 @@ def block_forward(
     (None: the sequence starts here and its state is dropped, as in
     training): of every row, the batch the store's rows in order, or of
     `state_row` alone (one row's prefill chunk); state_valid [B]: the
-    positions that are real (ops/ssm.py).
+    positions that are real (ops/ssm.py). An expert layer takes
+    state_valid too: the positions that are not real reach no expert
+    (ops/moe.py moe_block `rows_read`).
 
     kind: this layer's attention kind (attention_block), with `rope` that
     kind's table. In a stack of several kinds the region `attention`
@@ -409,7 +414,7 @@ def block_forward(
             if ffn:
                 out, moe_aux, grad_sink = _ffn(
                     cfg, lp, normed, tp_comm, grad_sink, type_layer,
-                    expert_stacks)
+                    expert_stacks, state_valid)
             else:
                 out, kv_cache, ssm_state = mixer(normed)
                 moe_aux = _no_moe_aux(cfg)
@@ -451,7 +456,8 @@ def block_forward(
             with jax.named_scope("mlp_norm"):
                 mlp_in = _norm(cfg, lp["ln_mlp"], x) if cfg.parallel_layernorm else normed
             mlp_out, moe_aux, grad_sink = _ffn(
-                cfg, lp, mlp_in, tp_comm, grad_sink, layer)
+                cfg, lp, mlp_in, tp_comm, grad_sink, layer,
+                rows_read=state_valid)
             with jax.named_scope("mlp_out"):
                 mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
                 res = normed if cfg.apply_residual_post_ln else x
@@ -460,7 +466,8 @@ def block_forward(
             with jax.named_scope("mlp_norm"):
                 normed2 = _norm(cfg, lp["ln2"], y)
             mlp_out, moe_aux, grad_sink = _ffn(
-                cfg, lp, normed2, tp_comm, grad_sink, layer)
+                cfg, lp, normed2, tp_comm, grad_sink, layer,
+                rows_read=state_valid)
             with jax.named_scope("mlp_out"):
                 mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
                 res2 = normed2 if cfg.apply_residual_post_ln else y

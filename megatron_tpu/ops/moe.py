@@ -70,6 +70,7 @@ from __future__ import annotations
 
 import functools
 import math
+import warnings
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -227,25 +228,31 @@ def layer_stats(aux, load) -> jnp.ndarray:
     """One MoE layer's [aux loss, load statistic] as block_forward hands
     it up the layer scan; `load` of a layer that holds a share of its
     router's experts is [load statistic, share of the rows routed to held
-    experts] (moe_block_dropless), and both go up."""
+    experts] and, of a serving step that says which rows are read, the
+    held experts such a row reached (moe_block_dropless): all go up."""
     if load.ndim:
         return jnp.concatenate([aux[None], load])
     return jnp.stack([aux, load])
 
 
-def moe_stats_zero(cfg: ModelConfig) -> jnp.ndarray:
+def moe_stats_zero(cfg: ModelConfig, rows_read: bool = False) -> jnp.ndarray:
     """What merge_layer_stats starts from (a dense stack under a
     shard_map may start from it too, where a scan's carry must not be of
-    rank 0: its layers add their zero scalar to it)."""
-    return jnp.zeros((3,) if cfg.holds_expert_share else (2,), jnp.float32)
+    rank 0: its layers add their zero scalar to it). rows_read: the call
+    says which rows are read (moe_block_dropless), and a share of the
+    experts counts the experts they reached."""
+    share = cfg.holds_expert_share
+    return jnp.zeros((2 + share * (1 + rows_read),), jnp.float32)
 
 
 def merge_layer_stats(acc: jnp.ndarray, new: jnp.ndarray) -> jnp.ndarray:
     """Across layers the aux losses add, the worst layer's load statistic
-    stands, and the held rows' shares add (language_model.lm_loss takes
-    their mean)."""
+    stands, and what stands behind them adds: the held rows' shares
+    (language_model.lm_loss takes their mean), the experts read. A layer
+    without experts hands up a zero that may be the shorter."""
     return jnp.stack([acc[0] + new[0], jnp.maximum(acc[1], new[1])]
-                     + [acc[i] + new[i] for i in range(2, acc.shape[0])])
+                     + [acc[i] + new[i] if i < new.shape[0] else acc[i]
+                        for i in range(2, acc.shape[0])])
 
 
 def aux_loss_of(moe_aux: jnp.ndarray) -> jnp.ndarray:
@@ -517,6 +524,7 @@ def moe_block_dropless(
     x: jnp.ndarray,      # [B, S, H]
     grad_sink=None,      # ({"w_in", "w_out": f32 [L, E, k, n]}, layer)
     of_layer=None,       # (w_in [L, E, k, n], w_out [L, E, n, k], layer)
+    rows_read=None,      # [B] int32: the positions of each row that count
 ):
     """Sort-based dropless dispatch (MegaBlocks-style, TPU form).
     Returns (y [B,S,H], aux loss, load statistic), and with `grad_sink`
@@ -546,6 +554,20 @@ def moe_block_dropless(
     The load statistic is then a [2]-vector: behind it the share of the
     N*k rows that went to held experts (HELD_METRIC).
 
+    rows_read (a serving step's; None: every position counts): row b's
+    first rows_read[b] positions are ones whose result somebody reads.
+    The others (an idle slot's row of a decode tick, a chunk's padded
+    tail) reach no expert: their choices are treated as held elsewhere,
+    so they sort behind the groups, no group counts them and their part
+    of y is what the dense parts of the layer give (the shared expert).
+    The experts' kernels then move the matrices of the experts a counted
+    row reached, and no others (grouped_matmul.GroupVisits). The
+    positions that count get the bits they get without the word. Of a
+    share of the experts the held rows' share is then of the call's N*k
+    rows still, those that count and went to held experts over all, and
+    a third number stands behind it: how many of the experts held here a
+    counted row reached (the serving engine's counters read both).
+
     This function is the unsharded form: experts replicated, tokens
     unsharded (or sharded in ways the manual path can't host — batch not
     divisible by the batch axes, mesh missing the named axes). Whenever
@@ -560,6 +582,7 @@ def moe_block_dropless(
     k = cfg.moe_top_k
     xf = x.reshape(N, h)
     share = cfg.holds_expert_share
+    held = cfg.experts_held
 
     with jax.named_scope("moe_router"):
         logits, gates, topw, topi = _route(cfg, p, xf)
@@ -569,14 +592,22 @@ def moe_block_dropless(
                                 group_sizes.astype(jnp.float32) / N)
         mine = None
         if share:
-            held = cfg.moe_experts_held
             first = cfg.moe_expert_share * held
             mine = (topi >= first) & (topi < first + held)
             # held experts by their place here; the others behind them all
             topi = jnp.where(mine, topi - first, held)
             group_sizes = group_sizes[first:first + held]
-            load = jnp.stack([load, jnp.sum(group_sizes).astype(jnp.float32)
-                              / (N * k)])
+        if rows_read is not None:
+            read = (jnp.arange(s) < rows_read[:, None]).reshape(N, 1)
+            mine = jnp.broadcast_to(read if mine is None else mine & read,
+                                    topi.shape)
+            topi = jnp.where(mine, topi, held)
+            group_sizes = _expert_counts(topi.reshape(-1), held)
+        if share:
+            load = jnp.stack(
+                [load, jnp.sum(group_sizes).astype(jnp.float32) / (N * k)]
+                + ([] if rows_read is None else
+                   [jnp.sum(group_sizes > 0, dtype=jnp.float32)]))
 
     xe = xf
     if cfg.moe_latent_size is not None:
@@ -593,8 +624,9 @@ def moe_block_dropless(
         out, stacks = experts_mlp(
             cfg, p, xs, group_sizes,
             lambda: jnp.take(jnp.minimum(topi.reshape(-1), held - 1)
-                             if share else flat_e, order),
-            x.dtype, ragged=share, grad_sink=grad_sink, of_layer=of_layer)
+                             if mine is not None else flat_e, order),
+            x.dtype, ragged=mine is not None, grad_sink=grad_sink,
+            of_layer=of_layer)
 
     with jax.named_scope("moe_combine"):
         # back to token order through the inverse sort; each token's k
@@ -893,14 +925,30 @@ def _ambient_batch_axes() -> Tuple[int, int, bool]:
     return shape.get(AXIS_DATA, 1), shape.get(AXIS_EXPERT, 1), both
 
 
+def _rows_read_dropped(rows_read, form: str) -> None:
+    """Said once a trace: this form of the block computes every row."""
+    if rows_read is not None:
+        warnings.warn(
+            f"moe_block ({form}): the serving step's rows that nobody "
+            "reads (idle slots, a chunk's padding) are routed like the "
+            "others; only the unsharded dropless block leaves them out",
+            stacklevel=3)
+
+
 def moe_block(
     cfg: ModelConfig,
     p: Dict[str, Any],   # one layer's moe subtree: router, w_in, w_out (+biases)
     x: jnp.ndarray,      # [B, S, H]
     grad_sink=None,
     of_layer=None,
+    rows_read=None,
 ):
     """Returns (y [B,S,H], aux loss, load statistic), both fp32 scalars.
+
+    rows_read [B] (a serving step's, else None): how many of each row's
+    positions somebody reads. The unsharded dropless block routes those
+    alone (moe_block_dropless); the capacity form and the form under an
+    expert-parallel mesh compute every row, as without the word.
 
     of_layer = (w_in's stack, w_out's stack, layer): the stacked layers'
     expert matrices this layer's are part of, [L, E, ...], and its index
@@ -927,7 +975,8 @@ def moe_block(
         # one chip's share runs without the exchange, whatever the mesh;
         # the sigmoid router, the latent projections and the shared expert
         # are this form's alone
-        return moe_block_dropless(cfg, p, x, of_layer=of_layer)
+        return moe_block_dropless(cfg, p, x, of_layer=of_layer,
+                                  rows_read=rows_read)
     if cfg.moe_dispatch == "dropless":
         dsz, ep, named_axes = _ambient_batch_axes()
         # manual data axis (per-shard local sort, no batch-axis argsort
@@ -941,9 +990,12 @@ def moe_block(
         include_data = dsz > 1 and x.shape[0] % (dsz * ep) == 0
         ep_ok = ep > 1 and x.shape[0] % ep == 0
         if named_axes and (ep_ok or include_data):
+            _rows_read_dropped(rows_read, "under an expert-parallel mesh")
             return moe_block_dropless_ep(cfg, p, x, None, ep,
                                          include_data=include_data)
-        return moe_block_dropless(cfg, p, x, of_layer=of_layer)
+        return moe_block_dropless(cfg, p, x, of_layer=of_layer,
+                                  rows_read=rows_read)
+    _rows_read_dropped(rows_read, "moe_dispatch='capacity'")
     b, s, h = x.shape
     N = b * s
     # group tokens GShard-style; Sg must divide the *runtime* S (decode

@@ -43,6 +43,19 @@ scalar-prefetch operands and the BlockSpec index maps read it, so
 consecutive visits of one group name the same rhs block and its [tk, tn]
 slab stays in VMEM while the group's row tiles stream past.
 
+The grid is the table's full length whatever the rows do, and a step
+whose visit holds no row (an empty group's, or one past the visits there
+are) is DEAD: in `moe_gmm` it computes nothing and, since a step's copies
+are decided by its block indices alone, it also names, for every operand
+and at every k tile, the blocks the step in front of it named
+(`GroupVisits.fetch`, `_gmm_index_maps`), so nothing is copied for it. A
+call's HBM traffic is the matrices of the groups that hold a row, each
+once an n tile, and no others. (Before PR 62 an empty group cost its
+whole matrix, and with several k tiles every visit past the table's end
+cost the last group's once more: a third of the bytes of a served chunk's
+second product.) `moe_tgmm` keeps the plain table: it writes an empty
+group's zeros, and its visits are the innermost axis.
+
 What differs from jax's copy, and why the kernels live here:
   * a name (`pallas_call(name=)` under `jax.named_scope`, flash_template's
     `_named_pallas_call`), so that a device trace books them under
@@ -92,13 +105,27 @@ class GroupVisits(NamedTuple):
     """The visit table of one row-tile size (all int32, scalar-prefetched).
     offsets [E + 1]: first row of each group, m last. group_ids, tile_ids
     [m / tm + E - 1]: the group and the row tile of each visit, in row
-    order; entries past `count` repeat the last visit, so their steps move
-    nothing. count [1]: the visits there are. An empty group has one visit
-    (it does nothing in `moe_gmm`; `moe_tgmm` writes the group's zeros)."""
+    order; entries past `count` repeat the last visit. count [1]: the
+    visits there are. An empty group has one visit (`moe_tgmm` writes the
+    group's zeros there, and its repeated entries, on its innermost axis,
+    name the blocks the step before them named).
+
+    A visit is DEAD in `moe_gmm` where it stands past `count` or its
+    group is empty: its steps compute nothing, and they copy nothing
+    either, because `moe_gmm`'s index maps do not read a dead visit's own
+    entries. fetch [as group_ids]: the visit whose blocks step v names. A
+    live visit's is v; a dead one's is the last live visit in front of it,
+    at that visit's LAST k tile, which is the block every operand's window
+    already holds (`_gmm_index_maps`); the dead visits in front of the first
+    live one name that one's first blocks, which it then finds fetched.
+    (All 0 where no group holds a row.) So neither an empty group's
+    matrix nor, past the table's end, the last group's once more per
+    visit, crosses from HBM."""
     offsets: jnp.ndarray
     group_ids: jnp.ndarray
     tile_ids: jnp.ndarray
     count: jnp.ndarray
+    fetch: jnp.ndarray
 
 
 def group_visits(group_sizes: jnp.ndarray, m: int, tm: int) -> GroupVisits:
@@ -117,14 +144,22 @@ def group_visits(group_sizes: jnp.ndarray, m: int, tm: int) -> GroupVisits:
                          jax.lax.div(ends - 1, tm) - first + 1, 1)
     visit_ends = jnp.cumsum(n_visits)
     count = visit_ends[E - 1:]
-    v = jnp.minimum(jnp.arange(n_tiles + E - 1, dtype=jnp.int32), count - 1)
+    steps = jnp.arange(n_tiles + E - 1, dtype=jnp.int32)
+    v = jnp.minimum(steps, count - 1)
     group_ids = jnp.sum(v[:, None] >= visit_ends[None, :], axis=1,
                         dtype=jnp.int32)
     # visit v of group g is tile first[g] + (v - the visits before g)
     tile_ids = (jnp.take(first, group_ids)
                 + v - jnp.take(visit_ends - n_visits, group_ids))
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
-    return GroupVisits(offsets, group_ids, tile_ids, count)
+    # the visit a step's blocks are named after: the last live one at or
+    # in front of it, else the first live one there is
+    live = (steps < count) & (jnp.take(sizes, group_ids) > 0)
+    behind = jax.lax.cummax(jnp.where(live, steps, -1))
+    ahead = jnp.min(jnp.where(live, steps, steps.shape[0]))
+    fetch = jnp.where(behind >= 0, behind,
+                      jnp.where(ahead < steps.shape[0], ahead, 0))
+    return GroupVisits(offsets, group_ids, tile_ids, count, fetch)
 
 
 # ---------------------------------------------------------------------------
@@ -255,8 +290,8 @@ def _act_vjp_tile(name: str, parts, dact, dtype):
             for d in vjp(dact.astype(dtype).astype(jnp.float32))]
 
 
-def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
-                dims, act: Optional[str], act_vjp: Optional[str]):
+def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, fetch_ref, *refs,
+                tm: int, dims, act: Optional[str], act_vjp: Optional[str]):
     """refs: lhs, rhs, out and, where k tiles accumulate, the float32
     scratch. With `act`, lhs is the `_act_parts` blocks of the activation's
     argument and the rows that meet rhs are the activation of them. With
@@ -347,6 +382,33 @@ def _gmm_kernel(offs_ref, gids_ref, tids_ref, count_ref, *refs, tm: int,
             functools.partial(span, start, _SPAN, inside))
 
 
+def _gmm_index_maps(nk: int, transpose_rhs: bool):
+    """`moe_gmm`'s index maps over its grid (n tile j, visit v, k tile ki)
+    and the visit table: (of an lhs part, of rhs, of out and of what
+    stands beside it). A dead visit's steps name what the step in front
+    of them named (GroupVisits), so the pipeline copies nothing for
+    them."""
+
+    def named(v, ki, fetch):
+        """(visit, k tile) whose blocks step (v, ki) names: its own where
+        visit v is live."""
+        u = fetch[v]
+        return u, jnp.where(u == v, ki, jnp.where(u < v, nk - 1, 0))
+
+    def lhs_map(j, v, ki, offs, gids, tids, count, fetch, part=0):
+        u, ki = named(v, ki, fetch)
+        return tids[u], ki + part * nk
+
+    def rhs_map(j, v, ki, offs, gids, tids, count, fetch):
+        u, ki = named(v, ki, fetch)
+        return (gids[u], j, ki) if transpose_rhs else (gids[u], ki, j)
+
+    def out_map(j, v, ki, offs, gids, tids, count, fetch):
+        return tids[fetch[v]], j
+
+    return lhs_map, rhs_map, out_map
+
+
 def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool,
          act: Optional[str] = None, act_vjp=None):
     """lhs [m, k] · rhs[g] with rhs [E, k, n], or with transpose_rhs
@@ -367,20 +429,9 @@ def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool,
     dtype = jnp.result_type(lhs.dtype, rhs.dtype)
     item = jnp.dtype(dtype).itemsize
 
-    def lhs_map(j, v, ki, offs, gids, tids, count, part=0):
-        return tids[v], ki + part * nk
-
-    def out_map(j, v, ki, offs, gids, tids, count):
-        return tids[v], j
-
-    if transpose_rhs:
-        rhs_spec = pl.BlockSpec(
-            (None, tn, tk),
-            lambda j, v, ki, offs, gids, tids, count: (gids[v], j, ki))
-    else:
-        rhs_spec = pl.BlockSpec(
-            (None, tk, tn),
-            lambda j, v, ki, offs, gids, tids, count: (gids[v], ki, j))
+    lhs_map, rhs_map, out_map = _gmm_index_maps(nk, transpose_rhs)
+    rhs_spec = pl.BlockSpec((None, tn, tk) if transpose_rhs
+                            else (None, tk, tn), rhs_map)
 
     n_lhs = _act_parts(act)
     in_specs = [pl.BlockSpec((tm, tk), functools.partial(lhs_map, part=i))
@@ -411,7 +462,7 @@ def _gmm(lhs, rhs, visits: GroupVisits, tiles: Tiles, transpose_rhs: bool,
                           dims=_NT if transpose_rhs else _NN,
                           act=act, act_vjp=act_vjp),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(visits),
             grid=(n // tn, visits.group_ids.shape[0], nk),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((tm, out_parts * tn), out_map),
@@ -558,7 +609,9 @@ def _tgmm(lhs, dout, visits: GroupVisits, tiles: Tiles, into=None,
 
     in_specs = [pl.BlockSpec((tm, tk), functools.partial(lhs_map, part=p))
                 for p in range(n_lhs)] + [pl.BlockSpec((tm, tn), dout_map)]
-    operands = [*visits, *[lhs.astype(dtype)] * n_lhs, dout.astype(dtype)]
+    # (its own four entries of the table: `fetch` is `moe_gmm`'s)
+    operands = [*visits[:4], *[lhs.astype(dtype)] * n_lhs,
+                dout.astype(dtype)]
     # an activation's float32 values beside the blocks
     act_vmem = (n_lhs + 2) * tm * tk * 4 if act else 0
     if into is None:
@@ -741,9 +794,8 @@ def _visits_of_layer(visits: GroupVisits, layer, layers: int) -> GroupVisits:
     E = visits.offsets.shape[0] - 1
     first = jnp.asarray(layer, jnp.int32) * E
     at = jnp.clip(jnp.arange(layers * E + 1, dtype=jnp.int32) - first, 0, E)
-    return GroupVisits(jnp.take(visits.offsets, at),
-                       visits.group_ids + first, visits.tile_ids,
-                       visits.count)
+    return visits._replace(offsets=jnp.take(visits.offsets, at),
+                           group_ids=visits.group_ids + first)
 
 
 def grouped_mlp_of_layer(xs: jnp.ndarray, w_in: jnp.ndarray,

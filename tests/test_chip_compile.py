@@ -1244,21 +1244,25 @@ def _paged_step(topo, cfg, step, pages, page, slots, max_len, chunk):
     state = abstract(jax.eval_shape(
         lambda: ssm.create_state(cfg, slots))) if cfg.has_ssm else None
 
+    # as the engine's steps: a model with a state store or expert layers
+    # is told which rows count
+    told = state is not None or cfg.num_experts is not None
+
     def decode(params, caches, state, table, tok, lengths):
-        decoding = (None if state is None
-                    else (table[:, 0] != 0).astype(i32))
+        decoding = (jnp.any(table != 0, axis=1).astype(i32) if told
+                    else None)
         return lm_forward(cfg, params, tok[:, None], kv_caches=caches,
                           ssm_state=state, cache_index=lengths,
                           page_table=table, state_valid=decoding)
 
     def prefill_chunk(params, caches, state, row, toks, off, start, end,
                       slot):
-        of_state = {} if state is None else dict(
-            state_row=slot, state_valid=jnp.clip(end - off, 0, chunk)[None])
+        real = jnp.clip(end - off, 0, chunk)[None] if told else None
         return lm_forward(cfg, params, toks, kv_caches=caches,
                           ssm_state=state, cache_index=off, page_table=row,
                           page_write_start=start, page_write_end=end,
-                          **of_state)
+                          state_row=None if state is None else slot,
+                          state_valid=real)
 
     per_seq = max_len // page
     fn, args = {

@@ -20,6 +20,7 @@ All CPU: a toy paged engine, captured through `capture_trace`.
 import gc
 import json
 import threading
+import time
 import urllib.request
 from http.server import ThreadingHTTPServer
 
@@ -410,7 +411,14 @@ def test_reply_joins_request_by_id_and_the_clients_id_comes_back(
                              {"X-Request-Id": "client-8"})
     assert status == 400 and headers["X-Request-Id"] == "client-8"
 
+    # the handler writes a reply's record after the reply's last byte, so
+    # the last one may still be on its way when the client has its answer
+    deadline = time.monotonic() + 5.0
     records = journal()
+    while (len(of_kind(records, "serve_reply")) < 3
+           and time.monotonic() < deadline):
+        time.sleep(0.01)
+        records = journal()
     replies = {r["id"]: r for r in of_kind(records, "serve_reply")}
     served = {r["id"]: r for r in of_kind(records, "serve_request")}
     assert set(replies) == {"client-7", made, "client-8"}

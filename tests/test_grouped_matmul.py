@@ -498,7 +498,7 @@ def test_visit_table_covers_every_row_once(layout, tm):
     one too) has a visit, and the entries past `count` repeat the last."""
     sizes = np.asarray(LAYOUTS[layout])
     offsets, gids, tids, count = (np.asarray(a) for a in gm.group_visits(
-        jnp.asarray(sizes, jnp.int32), M, tm))
+        jnp.asarray(sizes, jnp.int32), M, tm)[:4])
     count = int(count[0])
     assert len(gids) == len(tids) == M // tm + len(sizes) - 1
     assert count <= len(gids)
@@ -615,3 +615,166 @@ def test_one_visit_table_serves_both_products(as_on_one_tpu, monkeypatch):
     apart, twice = layer(False)
     assert once == [gm.pick_row_tile(M, len(gs))] and twice == 2 * once
     np.testing.assert_allclose(shared, apart)
+
+
+# ---------------------------------------------------------------------------
+# dead steps: a visit without rows names the blocks already in VMEM
+# ---------------------------------------------------------------------------
+
+# group sizes over the 512 rows that leave empty groups in front, in the
+# middle and behind, and (a share of the experts, a serving step's rows
+# nobody reads) rows behind the last group
+DEAD_LAYOUTS = {
+    "empty_in_front": [0, 0, 200, 312],
+    "empty_in_the_middle": [130, 0, 0, 0, 254, 128],
+    "empty_behind": [256, 256, 0, 0, 0],
+    "empty_everywhere": [0, 100, 0, 0, 28, 0, 384, 0],
+    "rows_behind_the_groups": [100, 0, 56, 0, 100, 0],
+    "one_row_groups_a_few": [0, 1, 0, 0, 1, 1, 0, 0, 0, 1, 0, 0],
+}
+
+
+def _grid_blocks(sizes, tm, nk, nj=2, transpose=False):
+    """Each `moe_gmm` index map's block indices over the grid (n tile,
+    visit, k tile), k innermost, with which steps are live."""
+    visits = gm.group_visits(jnp.asarray(sizes, jnp.int32), M, tm)
+    table = [np.asarray(a) for a in visits]
+    count = int(visits.count[0])
+    lhs_map, rhs_map, out_map = gm._gmm_index_maps(nk, transpose)
+    steps = []
+    for j in range(nj):
+        for v in range(len(visits.group_ids)):
+            live = v < count and sizes[int(visits.group_ids[v])] > 0
+            for ki in range(nk):
+                steps.append((live, (j, v, ki), tuple(
+                    tuple(int(i) for i in m(j, v, ki, *table))
+                    for m in (lhs_map, rhs_map, out_map))))
+    return steps, table
+
+
+@pytest.mark.parametrize("nk", [1, 3])
+@pytest.mark.parametrize("tm", [128, 256])
+@pytest.mark.parametrize("layout", sorted(DEAD_LAYOUTS))
+def test_a_dead_step_names_the_blocks_of_the_step_before(layout, tm, nk):
+    """Over the grid no index map names an empty group's rhs block, a
+    live step names its own visit's blocks at its own k tile, and no
+    block index changes across a dead step (a visit past `count`, or of
+    an empty group), whichever k tile it stands at: the pipeline copies
+    nothing for it. Dead steps in front of a pass's first live one name
+    what that one will."""
+    sizes = DEAD_LAYOUTS[layout]
+    steps, (_, gids, tids, _, _) = _grid_blocks(sizes, tm, nk)
+    assert any(live for live, _, _ in steps)
+    assert not all(live for live, _, _ in steps)
+    for at, (live, (j, v, ki), (lhs, rhs, out)) in enumerate(steps):
+        assert sizes[rhs[0]] > 0, (v, ki, rhs)
+        if live:
+            assert lhs == (tids[v], ki) and out == (tids[v], j)
+            assert rhs == (gids[v], ki, j)
+            continue
+        before = steps[at - 1] if at else None
+        if before is not None and before[1][0] == j and (
+                before[0] or before[2] == (lhs, rhs, out)):
+            assert before[2] == (lhs, rhs, out), (v, ki)
+        else:
+            # in front of the pass's first live step: that step's blocks
+            ahead = next(s for s in steps[at:] if s[0])
+            assert ahead[1][0] == j and ahead[1][2] == 0
+            assert ahead[2] == (lhs, rhs, out), (v, ki)
+
+
+def test_the_transposed_product_names_the_same_steps():
+    """rhs on its last axis (the rows' gradient): the same visits and k
+    tiles, the block's two last indices exchanged."""
+    sizes = DEAD_LAYOUTS["empty_everywhere"]
+    plain, _ = _grid_blocks(sizes, 128, 3)
+    back, _ = _grid_blocks(sizes, 128, 3, transpose=True)
+    for (_, _, (lhs, rhs, out)), (_, _, (lhs_t, rhs_t, out_t)) in zip(
+            plain, back):
+        assert (lhs, out) == (lhs_t, out_t)
+        assert rhs == (rhs_t[0], rhs_t[2], rhs_t[1])
+
+
+def test_where_no_group_holds_a_row_every_step_names_one_block():
+    steps, _ = _grid_blocks([0, 0, 0, 0], 128, 3, nj=1)
+    assert len({blocks for _, _, blocks in steps[3:]}) == 1
+
+
+@pytest.mark.parametrize("nk", [1, 3])
+@pytest.mark.parametrize("kernel", ["gmm", "gmm_transposed"])
+@pytest.mark.parametrize("layout", sorted(DEAD_LAYOUTS))
+def test_gmm_over_dead_visits_equals_ragged_dot(kernel, layout, nk):
+    """`moe_gmm` both ways round over tables with dead visits in every
+    place, at one k tile and at three: the groups' rows are
+    `lax.ragged_dot`'s (rows behind the last group are nobody's)."""
+    sizes = DEAD_LAYOUTS[layout]
+    rows = sum(sizes)
+    lhs, rhs, weight, gs = _operands(sizes, k=128 * nk, n=256)
+    visits = gm.group_visits(gs, M, 128)
+    if kernel == "gmm":
+        got = gm._gmm(lhs, rhs, visits, (128, 128, 128), False)
+        want = jax.lax.ragged_dot(lhs[:rows], rhs, gs)
+    else:
+        back = jnp.swapaxes(rhs, 1, 2)           # [E, n, k]: contract k
+        got = gm._gmm(lhs, back, visits, (128, 128, 128), True)
+        want = jax.lax.ragged_dot(lhs[:rows], rhs, gs)
+    np.testing.assert_allclose(got[:rows], want, rtol=2e-5, atol=2e-4)
+
+
+@pytest.mark.parametrize("layout", sorted(
+    name for name, sizes in DEAD_LAYOUTS.items() if sum(sizes) == M))
+def test_grouped_matmul_over_dead_visits_in_value_and_gradients(
+        as_on_one_tpu, layout):
+    """`grouped_matmul` (forward, the rows' gradient, the weights') over
+    those of the tables whose groups hold every row, against
+    `lax.ragged_dot` and its gradients; an empty group's gradient is
+    exactly zero."""
+    sizes = DEAD_LAYOUTS[layout]
+    lhs, rhs, weight, gs = _operands(sizes)
+    got = _value_and_grads(lambda a, b: gm.grouped_matmul(a, b, gs),
+                           lhs, rhs, weight)
+    want = _value_and_grads(lambda a, b: jax.lax.ragged_dot(a, b, gs),
+                            lhs, rhs, weight)
+    for g, w, what in zip(got, want, ("value", "d lhs", "d rhs")):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-4, err_msg=what)
+    for e, size in enumerate(sizes):
+        if size == 0:
+            assert not np.any(np.asarray(got[2][e])), e
+
+
+@pytest.mark.parametrize("activation", ["swiglu", "squared_relu"])
+@pytest.mark.parametrize("layout", sorted(DEAD_LAYOUTS))
+def test_fused_pair_over_dead_visits_in_value_and_gradients(
+        as_on_one_tpu, layout, activation):
+    """`grouped_mlp` over the same tables (rows behind the last group
+    where the layout leaves some), forward and the three gradients,
+    against `ragged_dot`, the activation, `ragged_dot`."""
+    sizes = DEAD_LAYOUTS[layout]
+    xs, w_in, w_out, weight, gs = _pair_operands(sizes, activation)
+    got, _ = _fused_pair(xs, w_in, w_out, weight, gs, activation,
+                         ragged=sum(sizes) != M)
+    _assert_pair(got, _dense_pair(xs, w_in, w_out, weight, gs, activation),
+                 rtol=2e-5, atol=5e-4)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("layout", ["empty_everywhere",
+                                    "rows_behind_the_groups"])
+def test_a_layer_of_the_stacks_over_dead_visits(as_on_one_tpu, layout,
+                                                layer):
+    """`grouped_mlp_of_layer`: the layer's matrices read in the stacks
+    through the shifted table give, bit for bit, what the same kernels
+    give over the layer's own matrices, and `ragged_dot`'s values."""
+    sizes = DEAD_LAYOUTS[layout]
+    rows = sum(sizes)
+    xs, w_in, w_out, weight, gs = _pair_operands(sizes, "swiglu")
+    stack_in = jnp.stack([w_in * (i + 1) for i in range(3)])
+    stack_out = jnp.stack([w_out / (i + 1) for i in range(3)])
+    got = gm.grouped_mlp_of_layer(xs, stack_in, stack_out,
+                                  jnp.int32(layer), gs, "swiglu")
+    alone, _ = gm.grouped_mlp(xs, stack_in[layer], stack_out[layer], gs,
+                              "swiglu", ragged=True)
+    np.testing.assert_array_equal(got[:rows], alone[:rows])
+    want = _dense_pair(xs, stack_in[layer], stack_out[layer], weight, gs,
+                       "swiglu")[0]
+    np.testing.assert_allclose(got[:rows], want, rtol=2e-5, atol=2e-4)

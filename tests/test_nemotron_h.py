@@ -477,10 +477,12 @@ def test_the_engine_serves_what_the_reference_computes(toy):
 
 
 def test_the_engine_counts_the_rows_its_held_experts_took(toy):
-    """Both steps add to the two counters with their tokens: every (row,
-    choice) pair the steps routed over the 5 expert layers (a tick: every
-    slot's row; a chunk: all its positions), and those of them sent to
-    the 4 held experts."""
+    """Both steps add to the counters with their tokens: every (row,
+    choice) pair of a row somebody reads over the 5 expert layers (a
+    tick: the decoding slots' rows; a chunk: its real positions, not its
+    padded tail), and those of them sent to the 4 held experts; the
+    ticks alone, the held experts such a row reached of the 4 x 5 there
+    are a tick."""
     from megatron_tpu.inference.engine import Request
     from megatron_tpu.telemetry.metrics import MetricsRegistry
 
@@ -493,14 +495,25 @@ def test_the_engine_counts_the_rows_its_held_experts_took(toy):
     assert [r.error for r in reqs] == [None, None]
     chunks, ticks = eng.stats["prefill_chunks"], eng.stats["ticks"]
     assert chunks == 3
-    pairs = (chunks * CHUNK + ticks * 2) * 3 * 5
-    assert eng.stats["moe_rows"] == pairs
+    # (the device takes a slot for decoding while its table row holds a
+    # page: a request's last tick in flight may be followed by one more)
+    pairs = eng.stats["moe_rows"]
+    rows = 13 + 5 + eng.stats["decode_rows"]
+    assert rows * 15 <= pairs <= (rows + len(reqs)) * 15
+    assert pairs % 15 == 0 and pairs < (chunks * CHUNK + ticks * 2) * 15
     assert 0 < eng.stats["moe_held_rows"] < pairs
+    assert eng.stats["moe_experts_offered"] == ticks * 4 * 5
+    assert 0 < eng.stats["moe_experts_read"] < ticks * 4 * 5
     text = eng.metrics.render()
     assert f"engine_moe_rows_total {pairs}" in text
     assert f"engine_moe_held_rows_total {eng.stats['moe_held_rows']}" in text
-    assert eng._serve_ticks_fields()["moe_rows"] == [
-        eng.stats["moe_held_rows"], pairs]
+    assert (f"engine_moe_experts_read_total "
+            f"{eng.stats['moe_experts_read']}") in text
+    assert f"engine_moe_experts_offered_total {ticks * 20}" in text
+    fields = eng._serve_ticks_fields()
+    assert fields["moe_rows"] == [eng.stats["moe_held_rows"], pairs]
+    assert fields["moe_experts"] == [eng.stats["moe_experts_read"],
+                                     ticks * 20]
 
 
 def test_continuous_batching_serves_each_request_as_if_alone(toy):
