@@ -484,8 +484,7 @@ def phase_data(size: dict, work: str, seed: int, rehearse: bool,
     # Every document walks one fixed cycle over CYCLE token ids (drawn from
     # --seed), so the next token is a function of the current one: a few
     # optimizer steps learn it, "the loss falls" is a real check, and the
-    # served model's greedy choice has a wide margin — the slot and paged
-    # engines round differently, and near-ties would flip. The null
+    # served model's greedy choice has a wide margin. The null
     # tokenizer takes id vocab-1 as end-of-document.
     rnd = random.Random(seed)
     vocab = size["vocab"] - 1
@@ -594,16 +593,14 @@ def _free_port() -> int:
 
 
 def phase_serve(size: dict, work: str, seed: int, rehearse: bool,
-                paged: bool, iteration: int) -> dict:
+                iteration: int) -> dict:
     """The real server on the trainer's checkpoint: boot, warm up, answer
     concurrent greedy requests, report zero decode recompiles, drain on
-    SIGTERM. Returns the phase line plus the generated tokens (the paged
-    run is compared with the slot run's)."""
+    SIGTERM. Returns the phase line."""
     import random
 
     t0 = time.time()
-    name = "serve_paged" if paged else "serve_slot"
-    ir = os.path.join(work, f"ir_{name}")
+    ir = os.path.join(work, "ir_serve")
     port = _free_port()
     cmd = [sys.executable, SERVER] + model_flags(size) + [
         "--tokenizer_type", "null", "--load", os.path.join(work, "ckpt"),
@@ -611,8 +608,8 @@ def phase_serve(size: dict, work: str, seed: int, rehearse: bool,
         "--serve_num_slots", str(size["slots"]),
         "--serve_max_seq_len", str(size["serve_len"]), "--serve_warmup",
         "--serve_drain_timeout", "60", "--seed", str(seed),
-    ] + (["--serve_kv_paging"] if paged else [])
-    log_path = os.path.join(work, f"{name}.log")
+    ]
+    log_path = os.path.join(work, "serve.log")
     base = f"http://127.0.0.1:{port}"
     rnd = random.Random(seed + 1)
     cycle = corpus_cycle(size, seed)
@@ -707,7 +704,7 @@ def phase_serve(size: dict, work: str, seed: int, rehearse: bool,
     _check(rehearse or dev["platform"] == "tpu", f"server ran on {dev}")
     kernels = _dumped(ir, "decode_step")
     _check_kernels_in_step(kernels, dev, "the decode step", 1)
-    return {"phase": name, "ok": True, "device": dev,
+    return {"phase": "serve", "ok": True, "device": dev,
             "seconds": round(time.time() - t0, 2),
             "requests": len(prompts), "concurrent": len(prompts) - 1,
             "new_tokens_each": size["new_tokens"],
@@ -717,8 +714,7 @@ def phase_serve(size: dict, work: str, seed: int, rehearse: bool,
             "kernels_in_step": kernels, "drained": True,
             "metrics": served,
             "setup": {"boot_to_ready_s": round(t_ready, 2),
-                      "request_s": [round(r[2], 3) for r in replies]},
-            "_generated": generated}
+                      "request_s": [round(r[2], 3) for r in replies]}}
 
 
 def phase_train4(size: dict, work: str, seed: int, rehearse: bool,
@@ -852,17 +848,8 @@ def run_one_chip(size: dict, work: str, seed: int, rehearse: bool,
     emit(phase_data(size, work, seed, rehearse, gbs=1))
     train = phase_train(size, work, seed, rehearse)
     emit(train)
-    outs = {}
-    for paged in (False, True):
-        line = phase_serve(size, work, seed, rehearse, paged,
-                           train["checkpoint_iteration"])
-        outs[paged] = line.pop("_generated")
-        if paged:
-            same = outs[True] == outs[False]
-            line["greedy_identical_to_slot_engine"] = same
-            _check(same, f"paged greedy output {outs[True]} differs from "
-                   f"the slot engine's {outs[False]}")
-        emit(line)
+    emit(phase_serve(size, work, seed, rehearse,
+                     train["checkpoint_iteration"]))
     _check(train["device"] == dev, "phases ran on different devices: "
            f"{train['device']} vs {dev}")
     return dev

@@ -72,23 +72,15 @@ CONFIGS: Dict[str, Callable[[], Any]] = {
     # dropless expert-parallel MoE dispatch at ep=2 (CPU transport);
     # jaxpr-only — compiling trips the old-XLA sharding remover
     "moe_ep2": lambda: _targets().moe_block_target("moe_ep2"),
-    # engine decode step: the contract IS "no collectives, no
-    # callbacks" — a hidden all_gather in serving fails here
-    "decode_single": lambda: _targets().decode_step_target(
-        "decode_single"),
-    # paged engine decode step (page-table KV gather): same zero-
-    # collective / zero-callback / full-donation contract as
-    # decode_single, pinned separately because the gather + scatter
-    # indexing is a whole new code path (inference/paging/)
+    # engine decode step (page-table KV gather): the contract IS "no
+    # collectives, no callbacks", and full donation — a hidden
+    # all_gather in serving fails here
     "decode_paged": lambda: _targets().paged_decode_step_target(
         "decode_paged"),
     # speculative decode step (model drafter): draft-proposal scan +
-    # multi-token verify + in-step accept/reject. Zero collectives,
+    # multi-token verify + in-step accept/reject through the page-table
+    # indirection (one table addresses both pools). Zero collectives,
     # zero callbacks, BOTH cache trees (target + draft) donated
-    "decode_spec": lambda: _targets().spec_decode_step_target(
-        "decode_spec"),
-    # paged speculative decode step: same contract through the page-
-    # table indirection (one table addresses both pools)
     "decode_spec_paged": lambda: _targets().spec_paged_decode_step_target(
         "decode_spec_paged"),
     # serving decode step on a tp=2 mesh with EXPLICIT collectives

@@ -154,48 +154,23 @@ def flash_bwd_train_step_target(
 # ---------------------------------------------------------------------------
 
 
-def decode_step_target(name: str = "decode_step",
-                       dtype: str = "bfloat16",
-                       num_slots: int = 4) -> AuditTarget:
-    """The serving engine's batched decode step. Donation is forced on
-    (the TPU configuration) so the audit checks the shipped intent even
-    though XLA:CPU would ignore it at execution time."""
+def paged_decode_step_target(name: str = "decode_paged",
+                             dtype: str = "bfloat16",
+                             num_slots: int = 4) -> AuditTarget:
+    """The serving engine's batched decode step (page-table KV gather +
+    per-slot lengths). The contract: ZERO collectives, zero host
+    callbacks, full cache donation — a hidden all_gather or callback in
+    serving fails here. Donation is forced on (the TPU configuration) so
+    the audit checks the shipped intent even though XLA:CPU would ignore
+    it at execution time."""
     from megatron_tpu.inference.engine import InferenceEngine
     from megatron_tpu.models.params import init_params
 
     cfg = tiny_model(params_dtype=dtype)
     params = init_params(cfg, jax.random.PRNGKey(0))
     eng = InferenceEngine(cfg, params, num_slots=num_slots,
-                          max_seq_len=cfg.seq_length, force_donate=True)
-    N = num_slots
-    args = (
-        _sds(params),
-        _sds(eng.caches),
-        jax.ShapeDtypeStruct((N,), jnp.int32),      # last_tok
-        jax.ShapeDtypeStruct((N,), jnp.int32),      # lengths
-        jax.ShapeDtypeStruct((N, 2), jnp.uint32),   # keys
-        jax.ShapeDtypeStruct((N,), jnp.float32),    # temps
-        jax.ShapeDtypeStruct((N,), jnp.int32),      # top_ks
-        jax.ShapeDtypeStruct((N,), jnp.float32),    # top_ps
-    )
-    return AuditTarget(name=name, fn=eng._decode_step, args=args)
-
-
-def paged_decode_step_target(name: str = "decode_paged",
-                             dtype: str = "bfloat16",
-                             num_slots: int = 4) -> AuditTarget:
-    """The paged serving engine's batched decode step (page-table KV
-    gather + per-slot lengths). Same contract as decode_single: ZERO
-    collectives, zero host callbacks, full cache donation — a hidden
-    all_gather or callback in the paged path fails here."""
-    from megatron_tpu.inference.paging import PagedInferenceEngine
-    from megatron_tpu.models.params import init_params
-
-    cfg = tiny_model(params_dtype=dtype)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    eng = PagedInferenceEngine(cfg, params, num_slots=num_slots,
-                               max_seq_len=cfg.seq_length, page_size=8,
-                               prefill_chunk=16, force_donate=True)
+                          max_seq_len=cfg.seq_length, page_size=8,
+                          prefill_chunk=16, force_donate=True)
     N = num_slots
     args = (
         _sds(params),
@@ -210,44 +185,6 @@ def paged_decode_step_target(name: str = "decode_paged",
         jax.ShapeDtypeStruct((N,), jnp.float32),    # top_ps
     )
     return AuditTarget(name=name, fn=eng._decode_step, args=args)
-
-
-def spec_decode_step_target(name: str = "decode_spec",
-                            dtype: str = "bfloat16",
-                            num_slots: int = 4, k: int = 3) -> AuditTarget:
-    """The speculative decode step (inference/speculative.py), model
-    drafter: k-step draft-proposal scan + one [N, k+1] target verify +
-    in-step accept/reject. Contract: ZERO collectives, ZERO host
-    callbacks (the accept math must stay on device), and FULL donation
-    of BOTH cache trees (target and draft)."""
-    from megatron_tpu.inference.engine import InferenceEngine
-    from megatron_tpu.inference.speculative import SpecConfig
-    from megatron_tpu.models.params import init_params
-
-    cfg = tiny_model(params_dtype=dtype)
-    dcfg = tiny_model(params_dtype=dtype, num_layers=2)
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    dparams = init_params(dcfg, jax.random.PRNGKey(1))
-    eng = InferenceEngine(
-        cfg, params, num_slots=num_slots, max_seq_len=cfg.seq_length,
-        force_donate=True,
-        speculative=SpecConfig(k=k, drafter="model", draft_cfg=dcfg,
-                               draft_params=dparams))
-    N = num_slots
-    args = (
-        _sds(params),
-        _sds(eng.caches),
-        _sds(dparams),
-        _sds(eng.draft_caches),
-        jax.ShapeDtypeStruct((N,), jnp.int32),      # last_tok
-        jax.ShapeDtypeStruct((N,), jnp.int32),      # lengths
-        jax.ShapeDtypeStruct((N, 2), jnp.uint32),   # keys
-        jax.ShapeDtypeStruct((N,), jnp.float32),    # temps
-        jax.ShapeDtypeStruct((N,), jnp.int32),      # top_ks
-        jax.ShapeDtypeStruct((N,), jnp.float32),    # top_ps
-        jax.ShapeDtypeStruct((N,), jnp.bool_),      # spec_rows
-    )
-    return AuditTarget(name=name, fn=eng._spec_step, args=args)
 
 
 def tp_decode_step_target(name: str = "decode_tp2_dense",
@@ -283,6 +220,8 @@ def tp_decode_step_target(name: str = "decode_tp2_dense",
     args = (
         _sds(sparams),
         _sds(eng.caches),
+        eng.state,                                  # no state-space layers
+        jax.ShapeDtypeStruct((N, eng.max_pages), jnp.int32),  # page table
         jax.ShapeDtypeStruct((N,), jnp.int32),      # last_tok
         jax.ShapeDtypeStruct((N,), jnp.int32),      # lengths
         jax.ShapeDtypeStruct((N, 2), jnp.uint32),   # keys
@@ -395,10 +334,13 @@ def spec_paged_decode_step_target(name: str = "decode_spec_paged",
                                   dtype: str = "bfloat16",
                                   num_slots: int = 4,
                                   k: int = 3) -> AuditTarget:
-    """The paged speculative decode step: the same contract as
-    decode_spec with the page-table indirection on BOTH cache trees
-    (target pools and draft pools share one table)."""
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    """The speculative decode step (inference/speculative.py), model
+    drafter: k-step draft-proposal scan + one [N, k+1] target verify +
+    in-step accept/reject, with the page-table indirection on BOTH cache
+    trees (target pools and draft pools share one table). Contract: ZERO
+    collectives, ZERO host callbacks (the accept math must stay on
+    device), and FULL donation of BOTH cache trees."""
+    from megatron_tpu.inference.engine import InferenceEngine
     from megatron_tpu.inference.speculative import SpecConfig
     from megatron_tpu.models.params import init_params
 
@@ -406,7 +348,7 @@ def spec_paged_decode_step_target(name: str = "decode_spec_paged",
     dcfg = tiny_model(params_dtype=dtype, num_layers=2)
     params = init_params(cfg, jax.random.PRNGKey(0))
     dparams = init_params(dcfg, jax.random.PRNGKey(1))
-    eng = PagedInferenceEngine(
+    eng = InferenceEngine(
         cfg, params, num_slots=num_slots, max_seq_len=cfg.seq_length,
         page_size=8, prefill_chunk=16, force_donate=True,
         speculative=SpecConfig(k=k, drafter="model", draft_cfg=dcfg,

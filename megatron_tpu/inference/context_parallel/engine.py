@@ -1,6 +1,6 @@
 """ContextParallelEngine: paged serving with sequence-sharded KV.
 
-Subclass of PagedInferenceEngine that keeps EVERY host-side policy
+Subclass of InferenceEngine that keeps EVERY host-side policy
 unchanged — one global radix prefix tree, one chunked-prefill queue,
 LIFO preemption, sliding-window release, the global [N, max_pages]
 table rows — and restructures only the device side:
@@ -23,9 +23,9 @@ table rows — and restructures only the device side:
 
 Because the host bookkeeping is inherited verbatim, radix hits,
 mid-prefill preempt/resume and ragged prompt tails are exact by the
-same arguments as the single-host paged engine; the parity gates in
-tests/test_context_parallel.py pin greedy token identity against the
-dense engine. int8 KV pools and speculative decoding are out of scope
+same arguments as the single-host engine; the parity gates in
+tests/test_context_parallel.py pin greedy token identity against
+one-shot generation. int8 KV pools and speculative decoding are out of scope
 (both rejected at build).
 """
 
@@ -39,15 +39,14 @@ import numpy as np
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.context_parallel.pool import StripedPagePool
-from megatron_tpu.inference.engine import EVICT
-from megatron_tpu.inference.paging.engine import PagedInferenceEngine
+from megatron_tpu.inference.engine import EVICT, InferenceEngine
 from megatron_tpu.inference.paging.pool import SCRATCH_PAGE
 from megatron_tpu.inference.paging.radix import RadixPrefixCache
 from megatron_tpu.parallel.mesh import AXIS_CONTEXT
 from megatron_tpu.quant.collectives import cp_ring_comm_bytes, make_cp_comm
 
 
-class ContextParallelEngine(PagedInferenceEngine):
+class ContextParallelEngine(InferenceEngine):
     """Paged serving engine over a TP x CP mesh (tp >= 1, cp >= 2)."""
 
     def __init__(self, cfg: ModelConfig, params: Any, num_slots: int = 8,
@@ -75,7 +74,7 @@ class ContextParallelEngine(PagedInferenceEngine):
         if cp <= 1:
             raise ValueError(
                 f"ContextParallelEngine needs {AXIS_CONTEXT} >= 2 on the "
-                f"mesh (got {cp}); use PagedInferenceEngine for cp == 1")
+                f"mesh (got {cp}); use InferenceEngine for cp == 1")
         self.cp = cp
         # set BEFORE super().__init__: the inherited step builders close
         # over cp_comm, and _fresh_caches rounds the pool to cp shards
@@ -158,13 +157,12 @@ class ContextParallelEngine(PagedInferenceEngine):
     # ----- cache + shape policy -------------------------------------------
 
     def _fresh_caches(self):
-        """Same pools as the paged engine, with the page count rounded up
+        """Same pools as the base engine, with the page count rounded up
         to a multiple of cp so every rank holds an equal shard (the
         striping arithmetic and the P(None, context, ...) placement both
         need exact divisibility)."""
         if self.num_pages is None:
-            max_pages = -(-self.max_seq_len // self.page_size)
-            self.num_pages = self.num_slots * max_pages + 1
+            self.num_pages = self.num_slots * self.max_pages + 1
         self.num_pages += (-self.num_pages) % self.cp
         return super()._fresh_caches()
 

@@ -36,7 +36,7 @@ token-identical to the dense one:
 
   * decode (per_slot): key position g attends iff ``g < lengths[i] + 1``
     (+ the sliding-window floor), lengths being the pre-increment slot
-    length — same as the dense engine's ``kv_lengths = cache_index + 1``.
+    length — same as one-device serving's ``kv_lengths = cache_index + 1``.
   * chunk prefill: ``g <= off + q_idx`` causal, window ``g > q_pos - w``.
 
 The local tables arriving here are PER-RANK views ([cp, rows, mpl],

@@ -1,15 +1,32 @@
-"""Continuous-batching decode engine with a persistent slot-based KV cache.
+"""The serving engine: continuous batching over one shared page pool.
 
 The one-shot path (generation.py) allocates a dense [B, L, S, H] cache per
 call and serves one request at a time — decode utilization collapses to a
-single sequence's matmul. This engine owns ONE long-lived cache of
-num_slots rows (ops/kv_store.py; optionally int8) and runs a step
-loop: every tick it admits queued requests into free slots (a bucketed
-prefill writes the slot's rows) and then executes ONE batched single-token
-decode for all slots — one jit-compiled step reused across traffic, no
-recompiles after warmup. Sequences of different ages coexist because the
-attention path masks each slot to its own valid prefix (per-slot lengths;
-ops/attention.py kv_lengths, Pallas flash-decode on TPU).
+single sequence's matmul. This engine owns ONE long-lived pool of
+fixed-size KV pages (ops/kv_store.py; optionally int8) shared by
+`num_slots` sequences, and runs a step loop. Every tick it
+
+  * admits queued requests into free slots: the prompt's span of pages is
+    allocated (a young sequence holds the pages it has, not its worst
+    case), and requests sharing a prompt prefix alias the same refcounted
+    pages through the radix tree (paging/radix.py) and skip prefill for
+    the shared span;
+  * runs AT MOST ONE chunk of `prefill_chunk` prompt tokens
+    (paging/scheduler.py), so one long prompt can never stall the batch;
+  * grows each decoding slot one page at a time as its length crosses a
+    page boundary. Under memory pressure it first evicts cache-only prefix
+    pages (LRU), then preempts the youngest slot (LIFO, so later arrivals
+    yield to earlier ones); a preempted request keeps its sampled tokens
+    and PRNG chain (Request.resume_key) and resumes by teacher-forced
+    recompute of prompt + generated, which is exact;
+  * executes ONE batched single-token decode for all slots — one
+    jit-compiled step (paging/engine.py builds it) reused across traffic,
+    no recompiles after warmup: its shapes, the `[N, max_pages]` device
+    page table included, never change.
+
+Sequences of different ages coexist because the attention path masks each
+slot to its own valid prefix (per-slot lengths; the paged flash-decode
+kernel on TPU, ops/pallas/paged_flash_decode.py).
 
 Per-request sampling params (temperature/top_k/top_p) are traced [N]
 arrays, not static — heterogeneous traffic shares the same compiled step
@@ -18,11 +35,10 @@ keyed off its seed, so a request's tokens never depend on which other
 slots happen to be active (the interleaved-traffic parity invariant;
 tests/test_serving_engine.py).
 
-Greedy parity gate: a single request decoded through the engine is
-token-identical to generation.generate_tokens — prefill logits come from
-the same bucketed causal pass, and masking a decode step to the valid
-prefix contributes exact zeros to the softmax, so the math matches
-bit-for-bit.
+Parity gate: a request decoded through the engine is token-identical to
+generation.generate_tokens — greedy, sampled, int8, ragged, preempted
+(tests/test_paging.py) — since masking a step to the valid prefix
+contributes exact zeros to the softmax.
 """
 
 from __future__ import annotations
@@ -44,19 +60,18 @@ import numpy as np
 
 from megatron_tpu.config import ModelConfig
 from megatron_tpu.inference.generation import GenerationOutput
-from megatron_tpu.inference.sampling import sample_logits_batched
-from megatron_tpu.ops import kv_store
+from megatron_tpu.inference.paging import engine as steps
+from megatron_tpu.inference.paging.pool import SCRATCH_PAGE, PagePool
+from megatron_tpu.inference.paging.radix import RadixPrefixCache
+from megatron_tpu.inference.paging.scheduler import (
+    ChunkedPrefillQueue, PrefillTask,
+)
+from megatron_tpu.ops import kv_store, ssm
 from megatron_tpu.telemetry import journal as _journal
 from megatron_tpu.telemetry.metrics import MetricsRegistry, default_registry
 from megatron_tpu.telemetry.tracing import capture
 from megatron_tpu.training import resilience
 from megatron_tpu.training.timers import Timers
-
-#: flash_decode (ops/pallas/flash_decode.py) requires the cache length
-#: divisible by this; engines round max_seq_len UP to it on the TPU
-#: kernel path so the fused kernel is never silently lost to the dense
-#: fallback (the _pick_block -> ValueError -> dispatcher chain).
-KERNEL_SEQ_MULTIPLE = 128
 
 #: jax's profiler session is process-global (one trace at a time), so
 #: on-demand captures serialize here — a second /admin/profile while one
@@ -76,8 +91,7 @@ _PROFILE_LOCK = threading.Lock()
 TICK = "serve-tick"       # one step(); a step marker, step_num = its number
 PRE = "tick-pre"          # _pre_tick: faults, staged weights, deadlines
 ADMIT = "tick-admit"      # _admit: slots, page allocation, the radix match
-PREFILL = "tick-prefill"  # a chunk's (slot engine: a prompt's) preparation
-                          # and its dispatch
+PREFILL = "tick-prefill"  # a chunk's preparation and its dispatch
 PAGES = "tick-pages"      # window release, pages under the decode span
 PROPOSE = "tick-propose"  # the n-gram drafter's proposals
 DECODE = "tick-decode"    # carry, live-block gauge, dispatch, _start_fetch
@@ -161,7 +175,7 @@ class Request:
     # that want the lowest per-token latency variance). Ignored when the
     # engine was built without `speculative=`.
     spec: bool = True
-    # preemption/resume (paged engine): the PRNG chain state at
+    # preemption/resume: the PRNG chain state at
     # preemption, so a recompute-resumed request samples the exact
     # tokens it would have sampled without the preemption
     resume_key: Optional[np.ndarray] = None
@@ -216,23 +230,49 @@ class _InFlight:
     step: int                     # the engine step that dispatched it
     t0: float
     ahead: bool = False           # in the queue before the last tick was read
-    # paged engine, a prompt's last chunk: its PrefillTask, and the pages
-    # held for the radix tree until the prompt's log-probabilities are read
+    # a prompt's last chunk: its PrefillTask, and the pages held for the
+    # radix tree until the prompt's log-probabilities are read
     task: Any = None
     pinned: Tuple[int, ...] = ()
 
 
+# what the steps of a model that holds a share of its router's experts
+# count on the device, in `_step_counts`' order: the key in `stats`, the
+# counter on /metrics and its help
+_MOE_COUNTS = (
+    ("moe_held_rows", "engine_moe_held_rows_total",
+     "those of engine_moe_rows_total sent to experts held on this chip"),
+    ("moe_rows", "engine_moe_rows_total",
+     "(row, choice) pairs the steps routed, over the expert layers (rows "
+     "somebody reads x experts a token x expert layers; a model that holds "
+     "a share of its router's experts)"),
+    ("moe_experts_read", "engine_moe_experts_read_total",
+     "held experts a decoding row reached, over the decode ticks and the "
+     "expert layers (the router's count: what the experts' kernels may "
+     "leave unread)"),
+    ("moe_experts_offered", "engine_moe_experts_offered_total",
+     "held experts there were for them (held x expert layers a tick)"),
+)
+
+
 class InferenceEngine:
-    """Slot scheduler + jitted prefill/decode steps over one shared cache.
+    """Slot scheduler + paged KV pool + radix prefix cache, and the jitted
+    chunk/decode steps over them.
 
     Not thread-safe for concurrent step() calls; submit() may be called
     from any thread (the HTTP handlers), step()/run_until_idle() from one
     driver thread (start() spawns it).
     """
 
+    # the context-parallel ring's transport: the CP engine sets it before
+    # this constructor runs, and the step builders route attention by it
+    cp_comm = None
+
     def __init__(self, cfg: ModelConfig, params: Any, num_slots: int = 8,
                  max_seq_len: Optional[int] = None,
-                 kv_cache_int8: bool = False, prefill_bucket: int = 64,
+                 kv_cache_int8: bool = False,
+                 page_size: int = 16, prefill_chunk: int = 32,
+                 num_pages: Optional[int] = None,
                  vocab_size: Optional[int] = None, mesh=None,
                  want_logprobs: bool = True,
                  metrics: Optional[MetricsRegistry] = None,
@@ -247,16 +287,24 @@ class InferenceEngine:
             raise ValueError("num_slots must be >= 1")
         if max_queue is not None and max_queue < 1:
             raise ValueError("max_queue must be >= 1 (or None: unbounded)")
+        if page_size < 1:
+            raise ValueError(f"page_size must be >= 1, got {page_size}")
+        if num_pages is not None and num_pages < 2:
+            raise ValueError(
+                f"num_pages must be >= 2 (page 0 is scratch), got {num_pages}")
         # force_donate: override the backend-derived donation choice
         # (None = donate except on XLA:CPU). The jaxpr/donation auditor
         # sets True so CPU-traced audits check the TPU-shipped intent.
         self.force_donate = force_donate
         self.cfg = cfg
-        self.params = params
         self.num_slots = num_slots
         self.max_queue = max_queue
+        self.page_size = int(page_size)
+        self.prefill_chunk = int(prefill_chunk)  # validated by the queue
+        self.num_pages = num_pages   # None: sized by _fresh_caches
         self.max_seq_len = self._round_seq_len(
             int(max_seq_len or cfg.seq_length))
+        self.max_pages = self.max_seq_len // self.page_size
         if (cfg.position_embedding_type == "absolute"
                 and self.max_seq_len > (cfg.max_position_embeddings or 0)):
             raise ValueError(
@@ -264,7 +312,19 @@ class InferenceEngine:
                 f"max_position_embeddings {cfg.max_position_embeddings}")
         self.kv_cache_int8 = kv_cache_int8
         if cfg.has_ssm:
-            self._refuse_unless_it_carries_state(mesh, speculative)
+            # a model with state-space layers (cfg.layer_pattern) carries
+            # a recurrent state a sequence beside its keys and values
+            # (self.state); every path that cannot hold it raises, by name
+            if speculative is not None:
+                raise NotImplementedError(
+                    "speculative decoding over a model with state-space "
+                    "layers: a rejected draft rolls the length back, and "
+                    "the recurrent state has no rollback")
+            if mesh is not None or self.cp_comm is not None:
+                raise NotImplementedError(
+                    "sharded serving (a tensor- or context-parallel mesh) "
+                    "of a model with state-space layers: the state store "
+                    "and the mixer are not sharded; serve it on one chip")
         # migration wire codec for FLOAT caches (fleet/migration.py):
         # "raw" ships native bytes (exact); "int8"/"fp8" quantize via
         # quant/primitives.py (smaller, NOT bit-exact — importers that
@@ -273,17 +333,16 @@ class InferenceEngine:
         # ("int8-native", exact). Operators set this attribute directly.
         self.kv_wire = "raw"
         self.kv_wire_chunk = 32
-        self.prefill_bucket = prefill_bucket
         self.vocab_size = vocab_size
         self.mesh = mesh
         self.want_logprobs = want_logprobs
         # compressed TP collectives (quant/collectives.py,
-        # --serve_compress_collectives): replace the decode forward's
+        # --serve_compress_collectives): replace the forward's
         # tensor-parallel output reductions + logits gather with explicit
         # low-bit (int8/fp8) collectives. None when the flag is off or
         # the mesh's tensor axis is trivial (dense path unchanged). The
-        # plan is STATIC at engine build — compiled into the decode
-        # step, zero traced args, zero recompiles.
+        # plan is STATIC at engine build — compiled into the steps,
+        # zero traced args, zero recompiles.
         from megatron_tpu.quant.collectives import (
             forward_comm_bytes, make_tp_comm,
         )
@@ -296,11 +355,13 @@ class InferenceEngine:
                 "supported (the spec step is not threaded through the "
                 "explicit TP collectives) — drop one of the two")
         # static wire-byte prices for the telemetry counters: what one
-        # decode tick moves in this mode, and what the dense path would
-        # have moved (their ratio IS the live compression ratio)
+        # decode tick (one [N, 1] forward) and one chunk (one [1, C]
+        # forward) move in this mode, and what the dense path would have
+        # moved (their ratio IS the live compression ratio)
         self._comm_tick_bytes = forward_comm_bytes(
             cfg, self.tp_comm, num_slots, 1)
-        self._comm_prefill_bytes = {}  # bucket P -> forward bytes
+        self._comm_chunk_bytes = forward_comm_bytes(
+            cfg, self.tp_comm, 1, self.prefill_chunk)
 
         N = num_slots
         # committed placement for params as well as caches: random-init
@@ -309,17 +370,27 @@ class InferenceEngine:
         # committed device_puts — without this, the first weight swap on a
         # random-init engine would split the decode step's jit cache key
         # and pay one recompile (the smoke test caught exactly that)
-        self.params = self._commit(self.params)
+        self.params = self._commit(params)
+        # self.state: a model with state-space layers' state store, a row
+        # a slot, beside the KV pool of its attention layers; None for
+        # every other model. The row is zeroed at admission, carried from
+        # chunk to chunk of its slot's prompt, advanced by the decode
+        # ticks the slot takes part in (those whose row of the decode
+        # table holds a page), and dropped with the slot.
         self.caches = self._commit_caches(self._fresh_caches())
+        if self.num_pages - 1 < self.max_pages:
+            raise ValueError(
+                f"num_pages={self.num_pages} cannot hold even one full "
+                f"sequence ({self.max_pages} pages of {self.page_size} for "
+                f"max_seq_len {self.max_seq_len}, + the scratch page)")
         # speculative decoding (inference/speculative.py): k drafted
         # tokens per slot verified by ONE [N, k+1] target forward per
         # tick, exact accept/reject inside the jitted step. The draft-
-        # model drafter keeps a SECOND cache tree threaded through the
-        # same slot/page machinery as the target's.
+        # model drafter keeps a SECOND tree of pools addressed through
+        # the same page tables as the target's.
         self.spec = speculative
         self.draft_params = None
         self.draft_caches = None
-        self._spec_step = None
         self.spec_on = np.ones(N, bool)   # per-request knob mirror
         self._spec_rows_dev = None        # committed device copy
         if speculative is not None:
@@ -336,6 +407,23 @@ class InferenceEngine:
         self.top_ks = np.zeros(N, np.int32)
         self.top_ps = np.zeros(N, np.float32)
         self.keys = np.zeros((N, 2), np.uint32)
+        # host page tables: tables[i] is slot i's logical->physical map.
+        # Mid-prefill slots keep their REAL row in _pending_rows and a
+        # scratch row here, so the shared decode table can never route an
+        # idle-drift write into a half-filled (possibly shared) page.
+        self.tables = np.zeros((N, self.max_pages), np.int32)
+        self._pending_rows = {}
+        self._device_table = None
+        self._table_dirty = True
+        self.prefill_queue = ChunkedPrefillQueue(self.prefill_chunk)
+        # admission order for the preemption policy (higher = younger)
+        self._admit_seq = [0] * N
+        self._admit_counter = 0
+        # sliding-window release cursor: first page index of each slot
+        # NOT yet released (lengths never shrink below the committed
+        # value, so release progress is monotone — the per-tick scan
+        # starts here instead of at page 0)
+        self._window_cursor = [0] * N
 
         self._queue: deque[Request] = deque()
         self._cv = threading.Condition()
@@ -343,8 +431,9 @@ class InferenceEngine:
         self._stop = False
         # device-resident decode carry (last_tok, lengths, keys, temps,
         # top_ks, top_ps): steady-state ticks chain device arrays instead
-        # of re-uploading 6 host arrays per token; admission events
-        # invalidate it (None -> re-upload from the host mirrors)
+        # of re-uploading 6 host arrays per token; the events that read or
+        # edit the chains on the host drop it (_sync_carry: None ->
+        # re-upload from the host mirrors)
         self._carry = None
         # the loop runs one tick ahead of the device (docs/serving.md "Step
         # loop"): a plain decode tick is dispatched, and read only after
@@ -374,22 +463,27 @@ class InferenceEngine:
         self._gc = _GcWatch()
         self._gc_seen = 0.0
         weakref.finalize(self, self._gc.watch, False)
+        self.pool = PagePool(self.num_pages)
+        self.prefix_cache = RadixPrefixCache(
+            self.pool, self.page_size, evict_span=self.timers(EVICT))
         # hot weight reload: (params, version, applied_event) staged by
         # update_params(), swapped in BETWEEN decode ticks by the step
         # loop so in-flight slots never see a mid-tick change
         self._pending_params: Optional[tuple] = None
         self.params_version: Optional[Any] = None
         # admissions popped from the queue but not yet landed in a slot —
-        # wait_idle() must not report idle while one is mid-prefill
+        # wait_idle() must not report idle while one is mid-assignment
         self._admitting = 0
         # state-migration pause (paused()): while _pause_count > 0 the
         # step loop parks BETWEEN ticks and raises _paused_evt, so an
         # exporter/importer can touch slot state without racing a tick
         self._pause_count = 0
         self._paused_evt = threading.Event()
-        # once-jitted KV install writer (migration import) — separate jit
-        # from the decode step, so imports cost zero decode recompiles
+        # once-jitted page writers (migration import; a finished prompt's
+        # row of the carry) — separate jits from the decode step, so
+        # imports cost zero decode recompiles
         self._kv_writer = None
+        self._carry_row_writer = None
         self._preempt_signalled = False  # preempt_replica fires once
         # last time the engine demonstrably made progress (an admission
         # or decode tick COMPLETED) — readiness uses stalled() to catch a
@@ -397,11 +491,31 @@ class InferenceEngine:
         # alive, just hung inside a device call)
         self.last_progress_time = time.monotonic()
 
-        self._decode_step = self._build_decode_step()
+        # the device programs (paging/engine.py). Both steps write the pool
+        # and the state store in place.
+        forward = steps.make_forward(cfg, self.tp_comm, self.cp_comm)
+        how = dict(vocab_size=vocab_size, want_logprobs=want_logprobs,
+                   donate_argnums=(1, 2) if self._donate() else (),
+                   shard_outputs=self._jit_sharding_kwargs)
+        self._decode_step = steps.build_decode_step(
+            cfg, forward, self._rows_decoding(), **how)
+        self._chunk_step = steps.build_chunk_step(
+            cfg, forward, self.prefill_chunk, **how)
+        self._spec_step = None
+        self._draft_chunk_step = None
         if self.spec is not None:
-            self._spec_step = self._build_spec_step()
-        self._prefill_steps = {}  # bucketed prompt length -> jitted fn
-        self._draft_prefill_steps = {}  # same buckets, draft cache writes
+            from megatron_tpu.inference.speculative import (
+                build_spec_decode_step)
+
+            # donated: the target pools, plus the draft pools for the
+            # model drafter (both are updated in place every tick)
+            self._spec_step = build_spec_decode_step(
+                cfg, self.spec, vocab_size, want_logprobs,
+                () if not self._donate()
+                else (1, 3) if self._has_draft_model() else (1,))
+            if self._has_draft_model():
+                self._draft_chunk_step = steps.build_draft_chunk_step(
+                    self.spec.draft_cfg, self._donate())
         # observability for tests/metrics: monotonically-growing counters.
         # decode_recompiles counts decode-step compiles BEYOND the warmup
         # one — the "zero recompiles after warmup" invariant (PR 1) as a
@@ -414,7 +528,14 @@ class InferenceEngine:
                       "ticks_dispatched_ahead": 0, "tick_drains": {},
                       "tokens_dropped_after_eod": 0,
                       "tick_phase_s": {}, "decode_rows": 0,
-                      "slow_ticks": 0}
+                      "slow_ticks": 0,
+                      "prefix_hits": 0, "prefix_misses": 0,
+                      "prefix_tokens_saved": 0, "prefill_tokens": 0,
+                      "prefill_chunks": 0, "preemptions": 0,
+                      "window_pages_released": 0, "pages_evicted": 0,
+                      # prefill_live_block_share joins them at the first
+                      # chunk
+                      "prefill_blocks_visited": 0, "prefill_blocks_held": 0}
         if self.spec is not None:
             # spec_emitted counts every token the spec path emitted
             # (accepted drafts + the guaranteed token per row per tick);
@@ -521,17 +642,69 @@ class InferenceEngine:
                                "comm_compressed_bytes": 0})
             self._journal_comm_policy()
         self._m_slots.set(num_slots)
+        self._m_pages_total = m.gauge("engine_pages_total",
+                                      "KV pool pages (minus scratch)")
+        self._m_pages_free = m.gauge("engine_pages_free",
+                                     "KV pool pages on the free list")
+        self._m_prefix_hits = m.counter(
+            "engine_prefix_cache_hits_total",
+            "admissions that aliased cached prefix pages")
+        self._m_prefix_misses = m.counter(
+            "engine_prefix_cache_misses_total",
+            "admissions with no cached prefix")
+        self._m_prefix_saved = m.counter(
+            "engine_prefix_tokens_saved_total",
+            "prefill positions skipped via the prefix cache")
+        self._m_preempted = m.counter(
+            "engine_preemptions_total",
+            "slots preempted under page-pool pressure")
+        self._m_chunks = m.counter("engine_prefill_chunks_total",
+                                   "chunked-prefill steps executed")
+        self._m_prefill_blocks = m.gauge(
+            "engine_prefill_live_block_share",
+            "KV blocks the last prefill chunk's attention kernel visited "
+            "over the blocks its page table holds a query tile")
+        self._m_window_released = m.counter(
+            "engine_window_pages_released_total",
+            "pages freed from behind the sliding attention window")
+        self._m_evicted = m.counter(
+            "engine_pages_evicted_total",
+            "cache-only prefix pages the radix tree gave back to the pool")
+        self._m_pages_total.set(self.num_pages - 1)
+        self._m_pages_free.set(self.pool.free_pages)
+        self._m_state_bytes = m.gauge(
+            "engine_state_bytes",
+            "recurrent state held beside the KV pages (state-space layers)")
+        self._m_state_resets = m.counter(
+            "engine_state_resets_total",
+            "slot states zeroed at admission (state-space layers)")
+        if self.state is not None:
+            self.stats["state_resets"] = 0
+            self._m_state_bytes.set(ssm.state_bytes(self.state))
+            self._zero_state_row = jax.jit(
+                ssm.zero_row, donate_argnums=(0,) if self._donate() else ())
+        # a model that holds a share of its router's experts: how many of
+        # the (row, choice) pairs its steps computed FOR A ROW SOMEBODY
+        # READS (a decoding slot's, a chunk's real positions: the others
+        # are not routed, ops/moe.py moe_block `rows_read`) went to experts
+        # held here, summed over the expert layers; and, of the decode
+        # ticks alone, how many of the held experts such a row reached
+        # (the matrices the tick's expert kernels had to move) of those
+        # there are. Both steps add to one vector of counters on the device
+        # (_step_counts: [held, all, experts read, experts offered],
+        # uint32, which wraps; None where the steps count nothing), which
+        # rides to the host in the fetch of a step's tokens
+        # (_apply_counts).
+        self._m_moe = [m.counter(name, text) for _, name, text in _MOE_COUNTS]
+        self._step_counts = None
+        if self.cfg.holds_expert_share:
+            for key, _, _ in _MOE_COUNTS:
+                self.stats[key] = 0
+            self._step_counts = self._commit_small(
+                np.zeros(len(_MOE_COUNTS), np.uint32))
+            self._counts_seen = np.zeros(len(_MOE_COUNTS), np.uint32)
 
     # ----- models with state-space layers ---------------------------------
-
-    def _refuse_unless_it_carries_state(self, mesh, speculative) -> None:
-        """A model with state-space layers (cfg.layer_pattern) carries a
-        recurrent state a sequence beside its keys and values. The paged
-        engine holds it (a row a slot: paging/engine.py); every path that
-        cannot raises here, by name."""
-        raise NotImplementedError(
-            "the slot engine does not hold a recurrent state: serve a "
-            "model with state-space layers with --serve_kv_paging")
 
     def _refuse_state_transfer(self, what: str) -> None:
         if self.cfg.has_ssm:
@@ -542,51 +715,60 @@ class InferenceEngine:
 
     # ----- cache + shape policy -------------------------------------------
 
-    def _kernel_seq_multiple(self) -> int:
-        """Cache-length divisibility the dense flash-decode kernel needs
-        wherever attention() dispatches it (hardware, or a CPU host with
-        the interpreter forced): a cache not divisible by 128 makes the
-        kernel raise (_pick_block), so engines round up. 1 = no
-        constraint (the XLA path; the paged engine's grid is per-page
-        and overrides this)."""
-        from megatron_tpu.ops.attention import _kernels_dispatchable
-
-        if self.cfg.attention_impl == "pallas" and _kernels_dispatchable():
-            return KERNEL_SEQ_MULTIPLE
-        return 1
-
     def _round_seq_len(self, n: int) -> int:
-        m = self._kernel_seq_multiple()
-        if m <= 1 or n % m == 0:
+        """Logical capacity is whole pages (the paged kernels' grid is per
+        page)."""
+        m = self.page_size
+        if n % m == 0:
             return n
         rounded = -(-n // m) * m
         import warnings
 
         warnings.warn(
-            f"engine max_seq_len {n} is not a multiple of {m}; rounding "
-            f"up to {rounded}, the next length the fused flash-decode "
-            "kernel accepts", stacklevel=3)
+            f"engine max_seq_len {n} is not a multiple of the page size "
+            f"{m}; rounding up to {rounded}", stacklevel=3)
         return rounded
 
     def _fresh_caches(self):
-        """Host-built zeroed KV storage (overridden by the paged engine
-        to build page pools instead of per-slot rows)."""
-        return kv_store.create(self.cfg, self.num_slots, self.max_seq_len,
+        """Paged pools: num_pages rows of page_size positions
+        (ops/kv_store.py; int8 with per-position scales), of the attention
+        layers; with them self.state, the state-space layers' state store
+        (ops/ssm.py: a zeroed row a slot; None for a model without)."""
+        if self.num_pages is None:
+            # default pool = every slot can grow to max_seq_len (+ the
+            # scratch page); shrink it to oversubscribe
+            self.num_pages = self.num_slots * self.max_pages + 1
+        self.state = (self._commit(ssm.create_state(self.cfg, self.num_slots))
+                      if self.cfg.has_ssm else None)
+        return kv_store.create(self.cfg, self.num_pages, self.page_size,
                                int8=self.kv_cache_int8)
 
     def _fresh_draft_caches(self):
-        """The draft model's second cache tree (speculative decoding,
-        drafter='model'): same slots and length as the target cache,
-        the draft config's own layer/head geometry, always bf16/f32 —
-        the draft is small, quantizing it would buy little and cost a
-        second quantization seam. Paged engine overrides with pools."""
-        return kv_store.create(self.spec.draft_cfg, self.num_slots,
-                               self.max_seq_len)
+        """Draft-model page pools (speculative decoding): the draft
+        config's own layer/head geometry over the SAME page count and
+        page size as the target pools, addressed through the SAME per-
+        slot page tables — one allocation/refcount/prefix-aliasing
+        story covers both trees (a page shared via the radix cache is
+        shared in both pools, since both were written through the same
+        table by the original prefill). Always bf16/f32 — the draft is
+        small, quantizing it would buy little and cost a second
+        quantization seam."""
+        return kv_store.create(self.spec.draft_cfg, self.num_pages,
+                               self.page_size)
 
     def _rebuild_caches(self):
         """Replace every donated cache tree after a failed device call
-        may have consumed the old buffers (prefill/decode failure
-        recovery). Cached prefixes and draft state die with them."""
+        may have consumed the old buffers (chunk/decode failure
+        recovery). Every cached prefix dies with the pool bytes, draft
+        state too, and mid-prefill slots lose their computed chunks —
+        fail them like the active ones the caller already failed."""
+        for i in sorted(self.prefill_queue.slots):
+            req = self.slots[i]
+            if req is not None:
+                self._clear_slot(i)
+                req._finish("engine cache rebuilt after a failed step")
+        self.prefix_cache.clear()
+        self._m_pages_free.set(self.pool.free_pages)
         self.caches = self._commit_caches(self._fresh_caches())
         if self.draft_caches is not None:
             self.draft_caches = self._commit(self._fresh_draft_caches())
@@ -599,12 +781,11 @@ class InferenceEngine:
         max_seq_len - k. 0 when speculation is off."""
         return self.spec.k if self.spec is not None else 0
 
-    # ----- jitted device steps --------------------------------------------
+    # ----- device placement of the steps' arguments -----------------------
 
     def _donate(self):
-        # donate the persistent cache so each step updates it in place
-        # (the whole point of a slot cache); XLA:CPU can't donate and
-        # would warn every compile
+        # donate the persistent pool so each step updates it in place;
+        # XLA:CPU can't donate and would warn every compile
         if self.force_donate is not None:
             return (1,) if self.force_donate else ()
         return (1,) if jax.default_backend() != "cpu" else ()
@@ -625,11 +806,11 @@ class InferenceEngine:
             tree, jax.sharding.SingleDeviceSharding(jax.devices()[0]))
 
     def _kv_sharding(self):
-        """Cache-leaf placement on a mesh engine: every leaf (dense
-        rows, paged pools, and their int8 scale companions alike) has its
-        kv heads sharded over "tensor" when it divides — matching the
-        column-parallel wk/wv head sharding so cache writes stay local.
-        None on mesh-less engines."""
+        """Cache-leaf placement on a mesh engine: every leaf (the pools
+        and their int8 scale companions alike) has its kv heads sharded
+        over "tensor" when it divides — matching the column-parallel
+        wk/wv head sharding so cache writes stay local. None on mesh-less
+        engines."""
         if self.mesh is None:
             return None
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -642,7 +823,7 @@ class InferenceEngine:
 
     def _commit_caches(self, tree):
         """Mesh engines pin the cache layout explicitly (and the decode/
-        prefill jits pin it back via out_shardings): without this the
+        chunk jits pin it back via out_shardings): without this the
         first tick's host-uploaded caches and the steady state's jit
         outputs split the decode step's cache key — the same wasted
         compile _commit fixes for single-device engines, which mesh
@@ -663,7 +844,7 @@ class InferenceEngine:
         return jax.device_put(tree, NamedSharding(self.mesh, P()))
 
     def _jit_sharding_kwargs(self, out_template):
-        """out_shardings kwargs for the decode/prefill jits on a mesh
+        """out_shardings kwargs for the decode/chunk jits on a mesh
         engine: "kv" entries take the pinned cache sharding, everything
         else replicated — so outputs re-enter the next call with
         byte-identical signatures (zero steady-state recompiles). {} on
@@ -682,135 +863,14 @@ class InferenceEngine:
 
         return {"out_shardings": tuple(resolve(t) for t in out_template)}
 
-    def _build_decode_step(self):
-        cfg, vocab, wlp = self.cfg, self.vocab_size, self.want_logprobs
-        tp_comm = self.tp_comm
-        from functools import partial
-
-        from megatron_tpu.models.language_model import lm_forward
-
-        @partial(jax.jit, donate_argnums=self._donate(),
-                 **self._jit_sharding_kwargs(
-                     ("rep", "rep", "kv", "rep", "rep")))
-        def decode_step(params, caches, last_tok, lengths, keys, temps,
-                        top_ks, top_ps):
-            # one batched token for every slot: write K/V at each slot's
-            # own position, attend each slot's own valid prefix
-            logits, caches = lm_forward(cfg, params, last_tok[:, None],
-                                        kv_caches=caches,
-                                        cache_index=lengths,
-                                        tp_comm=tp_comm)
-            logits = logits[:, 0]
-            split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-            new_keys, subs = split[:, 0], split[:, 1]
-            toks = sample_logits_batched(logits, subs, temps, top_ks,
-                                         top_ps, vocab)
-            if wlp:
-                lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1),
-                    toks[:, None], axis=-1)[:, 0]
-            else:
-                lp = jnp.zeros(toks.shape, jnp.float32)
-            # toks/lengths+1 re-enter the next tick as the carry
-            return toks, lp, caches, new_keys, lengths + 1
-
-        return decode_step
-
-    # ----- speculative decoding (inference/speculative.py) ----------------
+    def _rows_decoding(self):
+        """The function the decode step reads its table with (the CP
+        engine's reads its ranks' local tables). It holds no reference to
+        the engine, as nothing the steps close over does."""
+        return steps.rows_decoding
 
     def _has_draft_model(self) -> bool:
         return self.spec is not None and self.spec.drafter == "model"
-
-    def _spec_donate(self):
-        """Donated argnums for the speculative step: the target cache
-        tree, plus the draft cache tree for the model drafter (both are
-        persistent engine state updated in place every tick)."""
-        if not self._donate():
-            return ()
-        return (1, 3) if self._has_draft_model() else (1,)
-
-    def _spec_paged(self) -> bool:
-        """Whether the spec step threads a page table (overridden by the
-        paged engine)."""
-        return False
-
-    def _build_spec_step(self):
-        from megatron_tpu.inference.speculative import build_spec_decode_step
-
-        return build_spec_decode_step(
-            self.cfg, self.spec, self.vocab_size, self.want_logprobs,
-            self._spec_donate(), paged=self._spec_paged())
-
-    def _draft_prefill_step(self, P: int):
-        """Jitted draft-cache prefill at bucket length P (model drafter
-        only): write the prompt's K/V into the draft tree so the first
-        spec tick's proposal scan sees the full context. No sampling —
-        the draft never emits tokens directly."""
-        fn = self._draft_prefill_steps.get(P)
-        if fn is not None:
-            return fn
-        dcfg = self.spec.draft_cfg
-        from functools import partial
-
-        from megatron_tpu.models.language_model import lm_forward
-
-        @partial(jax.jit, donate_argnums=self._donate())
-        def draft_prefill(dparams, dcaches, tokens, slot):
-            small = kv_store.create(dcfg, 1, P)
-            _, small = lm_forward(dcfg, dparams, tokens,
-                                  positions=jnp.arange(P)[None, :],
-                                  kv_caches=small, cache_index=0)
-            return kv_store.install(dcaches, kv_store.row(small, 0), slot)
-
-        self._draft_prefill_steps[P] = draft_prefill
-        return draft_prefill
-
-    def _prefill_step(self, P: int):
-        """Jitted prefill at static bucket length P (compiled once per
-        bucket; nearby prompt lengths share a compile)."""
-        fn = self._prefill_steps.get(P)
-        if fn is not None:
-            return fn
-        cfg, int8, vocab = self.cfg, self.kv_cache_int8, self.vocab_size
-        wlp = self.want_logprobs
-        tp_comm = self.tp_comm
-        from functools import partial
-
-        from megatron_tpu.models.language_model import lm_forward
-
-        @partial(jax.jit, donate_argnums=self._donate(),
-                 **self._jit_sharding_kwargs(
-                     ("rep", "rep", "rep", "kv", "rep")))
-        def prefill(params, caches, tokens, length, slot, key, temp,
-                    top_k, top_p):
-            small = kv_store.create(cfg, 1, P, int8=int8)
-            logits, small = lm_forward(cfg, params, tokens,
-                                       positions=jnp.arange(P)[None, :],
-                                       kv_caches=small, cache_index=0,
-                                       tp_comm=tp_comm)
-            caches = kv_store.install(caches, kv_store.row(small, 0), slot)
-            last = jnp.take_along_axis(
-                logits, jnp.full((1, 1, 1), length - 1), axis=1)[:, 0]
-            key, sub = jax.random.split(key)
-            tok = sample_logits_batched(last, sub[None], temp[None],
-                                        top_k[None], top_p[None], vocab)[0]
-            if wlp:
-                lp = jnp.take_along_axis(
-                    jax.nn.log_softmax(last.astype(jnp.float32), axis=-1),
-                    tok[None, None], axis=-1)[0, 0]
-                # teacher-forced prompt logprobs (positions 1..P-1), like
-                # the one-shot path; the caller slices to the real length
-                plp = jnp.take_along_axis(
-                    jax.nn.log_softmax(
-                        logits[0, :P - 1].astype(jnp.float32), axis=-1),
-                    tokens[0, 1:, None], axis=-1)[:, 0]
-            else:
-                lp = jnp.zeros((), jnp.float32)
-                plp = jnp.zeros((P - 1,), jnp.float32)
-            return tok, lp, plp, caches, key
-
-        self._prefill_steps[P] = prefill
-        return prefill
 
     # ----- scheduling ------------------------------------------------------
 
@@ -882,16 +942,39 @@ class InferenceEngine:
     def num_active(self) -> int:
         return sum(1 for s in self.slots if s is not None)
 
-    def _bucket(self, p: int) -> int:
-        b = self.prefill_bucket
-        m = self._kernel_seq_multiple()
-        if m > 1:
-            # whole-prompt prefill attends its own P-long K/V (q_len ==
-            # kv_len), which is the flash kernel's shape: keep P a length
-            # the kernel tiles (max_seq_len is a multiple of m already)
-            b = -(-b // m) * m
-            return min(self.max_seq_len, -(-p // b) * b)
-        return min(self.max_seq_len - 1, max(1, -(-p // b) * b))
+    # ----- page accounting -------------------------------------------------
+
+    def _alloc_pages(self, n: int,
+                     logical_start: int = 0) -> Optional[List[int]]:
+        """n fresh pages, evicting LRU cache-only prefix pages if the
+        free list can't cover it. None = still dry (caller defers or
+        preempts). logical_start is the logical page index the run
+        starts at within its row — ignored here, but the CP engine's
+        striped pool draws each page from the rank owning that logical
+        slot (inference/context_parallel/pool.py)."""
+        pages = self.pool.alloc(n)
+        if pages is None:
+            self._note_evicted(
+                self.prefix_cache.evict(n - self.pool.free_pages))
+            pages = self.pool.alloc(n)
+        if pages is not None:
+            self._m_pages_free.set(self.pool.free_pages)
+        return pages
+
+    def _note_evicted(self, freed: int) -> int:
+        if freed:
+            self.stats["pages_evicted"] += freed
+            self._m_evicted.inc(freed)
+        return freed
+
+    def _release_slot_pages(self, i: int) -> None:
+        row = self._pending_rows.pop(i, self.tables[i])
+        live = [int(p) for p in row if p != SCRATCH_PAGE]
+        if live:
+            self.pool.release(live)
+        self.tables[i] = SCRATCH_PAGE
+        self._table_dirty = True
+        self._m_pages_free.set(self.pool.free_pages)
 
     def _clear_slot(self, i: int):
         """Reset EVERY per-slot host mirror — a cleared slot must not
@@ -904,7 +987,12 @@ class InferenceEngine:
         holds the old ones — audited again for the
         speculative rollback path, whose accept/reject cond reads the
         same temps/top_ks/top_ps rows; regression-pinned by
-        test_speculative.py's all-greedy filter-dead test.)"""
+        test_speculative.py's all-greedy filter-dead test.) The slot's
+        page references go back to the pool; pages the radix tree also
+        holds stay cached for future hits."""
+        self._release_slot_pages(i)
+        self.prefill_queue.drop_slot(i)
+        self._window_cursor[i] = 0
         self.slots[i] = None
         self.lengths[i] = 0
         self.last_tok[i] = 0
@@ -938,14 +1026,15 @@ class InferenceEngine:
         req.finish_time = time.monotonic()
         self._journal_request(req, "ok")
         req._finish()
+        self._m_pages_free.set(self.pool.free_pages)
 
     def _sync_carry(self, cause: str):
         """Make every host mirror true and drop the device carry: read
         what is in flight (_drain, counted under `cause`), then pull the
         per-slot PRNG chains, which live on the device alone between such
         events. For the rare events that read or edit the chains or the
-        last tokens on the host (the slot engine's admission, preemption,
-        migration); the next dispatch uploads the carry from the mirrors.
+        last tokens on the host (preemption, migration); the next dispatch
+        uploads the carry from the mirrors.
         Events that edit lengths and knobs alone mark _carry_dirty."""
         self._drain(cause)
         if self._carry is not None:
@@ -1005,11 +1094,16 @@ class InferenceEngine:
     def _drop_inflight(self) -> None:
         """Forget what is in flight without reading it (a failed device
         step, stop()): the caller fails or has failed its requests."""
+        for rec in self._inflight:
+            if rec.pinned:
+                self.pool.release(rec.pinned)
         self._inflight.clear()
 
     def _admit(self) -> int:
-        """Move queued requests into free slots; prefill each. Returns the
-        number admitted this tick."""
+        """Move queued requests into free slots (_try_assign), in arrival
+        order, as far as the pool covers their prompts. Returns the number
+        admitted this tick. Nothing is read: a prompt's first token comes
+        with its last chunk."""
         n = 0
         for i in range(self.num_slots):
             if self.slots[i] is not None:
@@ -1022,108 +1116,382 @@ class InferenceEngine:
             if req is None:
                 break
             try:
-                n += self._admit_one(i, req)
+                if not self._try_assign(i, req):
+                    # pool can't cover the prompt right now: keep arrival
+                    # order (front of the queue) and stop admitting —
+                    # active slots retiring will free pages
+                    with self._cv:
+                        self._queue.appendleft(req)
+                        self._m_queue.set(len(self._queue))
+                    break
+                n += 1
+                with self._cv:
+                    self._m_queue.set(len(self._queue))
             finally:
                 with self._cv:
                     self._admitting -= 1
                 self.last_progress_time = time.monotonic()
         return n
 
-    def _admit_one(self, i: int, req: Request) -> int:
-        """Prefill `req` into free slot `i`; returns 1 if admitted.
-
-        A resumed request (a preserved PRNG chain and/or already-generated
-        tokens — recompute-resume after preemption or migration) teacher-
-        forces prompt + generated in one prefill and samples the NEXT
-        token at the final position with the preserved chain: the exact
-        token the interrupted decode tick would have sampled, greedy or
-        not (the paged engine's _try_assign is the same contract)."""
-        # a whole-prompt prefill reads its first token at once and writes
-        # the mirrors: an admission drains (the paged engine's does not)
-        self._sync_carry("admission")
-        if req.first_token_time is None:
-            req.slot_time = time.monotonic()
+    def _try_assign(self, i: int, req: Request) -> bool:
+        """Give req slot i: alias cached prefix pages, allocate the rest
+        of the prompt span, queue the chunked prefill. False = defer
+        (req untouched); a request no idle engine could EVER fit is
+        failed loudly instead (returns True: req was consumed)."""
         resumed = req.resume_key is not None or bool(req.generated)
-        full = (np.concatenate([np.asarray(req.prompt, np.int32),
+        toks = (np.concatenate([np.asarray(req.prompt, np.int32),
                                 np.asarray(req.generated, np.int32)])
                 if resumed else np.asarray(req.prompt, np.int32))
-        p = len(full)
-        P = self._bucket(p)
-        toks = np.zeros((1, P), np.int32)
-        toks[0, :p] = full
-        key0 = (jnp.asarray(np.asarray(req.resume_key, np.uint32))
-                if req.resume_key is not None
-                else jax.random.PRNGKey(req.seed))
-        t_prefill = time.monotonic()
+        p_ext = len(toks)
+        ps = self.page_size
+        # the prefix cache gives a model with state-space layers no hit:
+        # a hit needs the recurrent state at the prefix's end beside its
+        # pages, and no snapshot holds it yet (the tree is never asked,
+        # and _finish_prefill enters nothing into it)
+        hit_pages, hit_lps = (([], []) if self.cfg.has_ssm
+                              else self.prefix_cache.lookup(toks))
+        span = len(hit_pages) * ps
+        n_prompt_pages = -(-p_ext // ps)
+        # retain the hits BEFORE allocating: _alloc_pages may evict
+        # cache-only pages, and un-pinned hit pages are exactly that —
+        # an eviction here would free a hit page and hand it back as
+        # "fresh", mapping one physical page at two logical blocks
+        self.pool.retain(hit_pages)
+        fresh = self._alloc_pages(n_prompt_pages - len(hit_pages),
+                                  logical_start=len(hit_pages))
+        if fresh is None:
+            self.pool.release(hit_pages)
+            if self.num_active == 0:
+                req._finish(
+                    f"prompt needs {n_prompt_pages} pages but the pool has "
+                    f"{self.pool.free_pages} free with no active slots to "
+                    f"wait for (num_pages={self.num_pages})")
+                self.stats["rejected"] += 1
+                self._m_rejected.inc()
+                return True
+            return False
+        self._m_pages_free.set(self.pool.free_pages)
+
+        row = np.zeros(self.max_pages, np.int32)
+        row[:len(hit_pages)] = hit_pages
+        row[len(hit_pages):n_prompt_pages] = fresh
+        self._pending_rows[i] = row
+        if self.state is not None:
+            # a sequence starts (a preempted one again, from position 0).
+            # The tick in flight may still advance the old occupant's row
+            # (one that ended by eod runs one tick more): this write is
+            # dispatched on the same chain of donated `state` buffers, so
+            # it orders after that tick by data dependence
+            self.state = self._zero_state_row(self.state, np.int32(i))
+            self.stats["state_resets"] += 1
+            self._m_state_resets.inc()
+        self.slots[i] = req
+        if req.first_token_time is None:
+            req.slot_time = time.monotonic()
+        self._admit_counter += 1
+        self._admit_seq[i] = self._admit_counter
+
+        # recompute starts one position INSIDE the shared span so the
+        # boundary token's teacher-forced logprob comes from real logits;
+        # its K/V write is fenced onto scratch (write_start = span)
+        start = max(span - 1, 0)
+        task = PrefillTask(
+            slot=i, tokens=toks, start=start, off=start,
+            write_start=span,
+            # a fresh chain stays a device array: reading it back would
+            # wait for the tick in flight
+            key=(np.asarray(req.resume_key) if req.resume_key is not None
+                 else jax.random.PRNGKey(req.seed)),
+            resumed=resumed, t_start=time.monotonic())
+        if not resumed and span > 0:
+            # cached teacher-forced logprobs for tokens 1..span-1; the
+            # recomputed chunks continue seamlessly from token `span`
+            task.plp_parts.extend(hit_lps)
+        self.prefill_queue.add(task)
+
+        if span > 0:
+            req.prefix_tokens += start
+            self.stats["prefix_hits"] += 1
+            self.stats["prefix_tokens_saved"] += start
+            self._m_prefix_hits.inc()
+            self._m_prefix_saved.inc(start)
+        else:
+            self.stats["prefix_misses"] += 1
+            self._m_prefix_misses.inc()
+        self.stats["admitted"] += 1
+        self._m_admitted.inc()
+        self._m_active.set(self.num_active)
+        return True
+
+    # ----- chunked prefill -------------------------------------------------
+
+    def _prefill_tick(self) -> int:
+        """Dispatch at most ONE chunk of the oldest incomplete prefill.
+        Returns 1 when a chunk ran (progress signal for run_until_idle).
+        Nothing of it is read here: the chunk queues behind the tick in
+        flight, its scalars go up with the call (numpy values, no device
+        array made one by one), its prompt logprobs stay device arrays
+        until the prompt's last chunk, and that chunk's first token is
+        read with the ticks (_finish_prefill)."""
+        task = self.prefill_queue.peek()
+        if task is None:
+            return 0
+        i = task.slot
+        req = self.slots[i]
+        C = self.prefill_chunk
+        off = task.off
+        toks_ext = np.zeros((1, C + 1), np.int32)
+        avail = task.tokens[off:off + C + 1]
+        toks_ext[0, :len(avail)] = avail
+        row = self._pending_rows[i]
+        self._note_prefill_blocks(off, task.total)
         try:
-            with self.timers(PREFILL):
-                tok, lp, plp, caches, key = self._prefill_step(P)(
-                    self.params, self.caches, jnp.asarray(toks),
-                    jnp.int32(p), jnp.int32(i), key0,
-                    jnp.float32(req.temperature), jnp.int32(req.top_k),
-                    jnp.float32(req.top_p))
-                self.caches = caches
-                if self._has_draft_model():
-                    # mirror the prompt into the draft model's cache tree
-                    # so the first speculative tick proposes with full
-                    # context
-                    self.draft_caches = self._draft_prefill_step(P)(
-                        self.draft_params, self.draft_caches,
-                        jnp.asarray(toks), jnp.int32(i))
-            with self.timers(READ):
-                # a whole-prompt prefill is read at once
-                tok, lp, plp, key = jax.device_get((tok, lp, plp, key))
-        except Exception as e:  # noqa: BLE001 - a failing prefill
-            # (fresh-bucket compile OOM etc.) must fail THIS request,
-            # not strand it un-signalled and kill the step loop
+            tok, lp, plp, self.caches, self.state, key, *counts = (
+                self._chunk_step(
+                    self.params, self.caches, self.state,
+                    self._chunk_table_arg(row),
+                    toks_ext, np.int32(off),
+                    np.int32(task.write_start), np.int32(task.total),
+                    np.int32(task.total - 1), task.key,
+                    np.float32(req.temperature), np.int32(req.top_k),
+                    np.float32(req.top_p),
+                    None if self.state is None else np.int32(i),
+                    *self._counts_arg()))
+            self._step_counts, = counts or (None,)
+            if self._has_draft_model():
+                # mirror the chunk into the draft pools through the same
+                # table row and write fences
+                self.draft_caches = self._draft_chunk_step(
+                    self.draft_params, self.draft_caches,
+                    self._chunk_table_arg(row),
+                    toks_ext[:, :C], np.int32(off),
+                    np.int32(task.write_start), np.int32(task.total))
+        except Exception as e:  # noqa: BLE001 - a failing chunk must fail
+            # THIS request, not strand it un-signalled and kill the loop
+            self._clear_slot(i)
             req._finish(f"prefill failed: {e}")
             self.stats["rejected"] += 1
             self._m_rejected.inc()
             if self._donate():
-                # the failed call may have consumed the donated cache
-                # buffers — continuing would poison every active slot
-                # at the next decode tick (step() has the matching
-                # recovery); fail the in-flight requests and restart
-                # from fresh caches (target AND draft trees)
+                # the failed call may have consumed the donated pools
+                # (target AND draft trees), and what is in flight with them
+                self._drop_inflight()
                 for j, other in enumerate(self.slots):
                     if other is not None:
                         self._clear_slot(j)
                         other._finish(f"prefill failed: {e}")
                 self._rebuild_caches()
-                self._m_active.set(self.num_active)
-            return 0
+            self._m_active.set(self.num_active)
+            return 1
+        n = min(C, task.total - off)
+        if self.want_logprobs:
+            task.plp_parts.append(plp)  # the device's, until the last chunk
         req.chunks += 1
-        self.slots[i] = req
-        self.lengths[i] = p
-        self.last_tok[i] = int(tok)
+        self.stats["prefill_chunks"] += 1
+        self.stats["prefill_tokens"] += n
+        self._count_comm(self._comm_chunk_bytes)
+        self._m_chunks.inc()
+        if self.flight_recorder is not None:
+            self.flight_recorder.heartbeat(
+                f"prefill chunk slot {i} ({off}+{n}/{task.total})")
+        if self.prefill_queue.advance(task, n):
+            self._finish_prefill(i, task, tok, lp, key)
+        return 1
+
+    def _note_prefill_blocks(self, off: int, total: int) -> None:
+        """Set `engine_prefill_live_block_share` for the chunk about to
+        run: the trips the chunk kernel's loops take (a query tile's, over
+        the blocks its queries see below the prompt's end) over the blocks
+        the row's table holds a query tile, from the host's offset and
+        length through the kernel's own loop bounds. The twin of
+        `engine_decode_live_block_share`; the journal's `serve_ticks`
+        carries both counts summed over every chunk."""
+        from megatron_tpu.ops.pallas.flash_template import (
+            chunk_blocks_visited)
+
+        cfg = self.cfg
+        visited, held = chunk_blocks_visited(
+            off, self.prefill_chunk, total,
+            cfg.num_attention_heads // cfg.n_kv_heads, self.max_pages,
+            self.page_size, self._kernel_kv_heads(),
+            window=cfg.attention_kind.sliding_window_size)
+        self.stats["prefill_blocks_visited"] += visited
+        self.stats["prefill_blocks_held"] += held
+        self.stats["prefill_live_block_share"] = visited / held
+        self._m_prefill_blocks.set(visited / held)
+
+    def _finish_prefill(self, i: int, task: PrefillTask, tok, lp, key):
+        """The prompt's last chunk is dispatched: publish the slot's table
+        row to the shared decode table, arm the decode mirrors, and write
+        the first sampled token and the chain into the slot's row of the
+        device carry, so the slot decodes in this step's tick. `tok`, `lp`
+        and `key` are device values nobody has read: what the host owes
+        the request for them (the token, the logprobs, the radix tree's
+        entry) waits in flight and is paid at its read (_read_first)."""
+        req = self.slots[i]
+        row = self._pending_rows.pop(i)
+        self.tables[i] = row
+        self._table_dirty = True
+        self.lengths[i] = task.total
         self.temps[i] = req.temperature
         self.top_ks[i] = req.top_k
         self.top_ps[i] = req.top_p
-        self.keys[i] = np.asarray(key)
+        self._carry_dirty = True
+        self._write_carry_row(i, tok, key)
         if self.spec is not None:
             self.spec_on[i] = bool(req.spec)
             self._spec_rows_dev = None
-        req.generated.append(int(tok))
-        req.logprobs.append(float(lp))
-        if not resumed:
-            req.prompt_logprobs = [float(x) for x in plp[:p - 1]]
-        self.stats["admitted"] += 1
-        self._count_comm_prefill(P)
-        now = time.monotonic()
-        self._m_prefill.observe(now - t_prefill)
-        if not resumed:
-            # TTFT is first-admission only: a resume's clock restarted
-            req.first_token_time = now
-            if req.submit_time is not None:
-                self._m_ttft.observe(now - req.submit_time)
-        self._m_admitted.inc()
-        self._m_tokens.inc()
-        self._m_active.set(self.num_active)
+        self._owed[i] += 1
+        pinned: tuple = ()
+        p0 = len(req.prompt)
+        if p0 >= self.page_size and not self.cfg.has_ssm:
+            # the FULL pages of the ORIGINAL prompt, for the radix tree.
+            # They enter it at the read, with their logprobs; held until
+            # then, so that neither the window's release nor a retirement
+            # hands one back to the pool in between
+            pinned = tuple(int(p) for p in row[:p0 // self.page_size])
+            self.pool.retain(pinned)
+        plps = (list(task.plp_parts)
+                if self.want_logprobs and not task.resumed else [])
+        rec = _InFlight(rows=[(i, req)],
+                        out=self._start_fetch((tok, lp, plps,
+                                               self._step_counts)),
+                        step=self._step_no, t0=time.monotonic(),
+                        task=task, pinned=pinned)
+        if self.spec is not None:
+            # the speculative tick is synchronous: it proposes from the
+            # tokens, so the mirrors must be true before it runs
+            self._read(rec)
+        else:
+            self._inflight.append(rec)
+
+    def _write_carry_row(self, i: int, tok, key) -> None:
+        """One row of the device carry takes a finished prompt's first
+        token and PRNG chain, both device values: a device-side write
+        (as `zero_row` is for the state), so that one prompt's end stalls
+        no other row. Lengths and knobs go up from the mirrors
+        (_init_carry)."""
+        if self._carry_row_writer is None:
+            def write_carry_row(last, keys, row, tok, key):
+                return last.at[row].set(tok), keys.at[row].set(key)
+
+            # nothing donated: `last` is also the tick in flight's tokens
+            self._carry_row_writer = jax.jit(
+                write_carry_row,
+                **self._jit_sharding_kwargs(("rep", "rep")))
+        last, lens, keys, temps, top_ks, top_ps = self._init_carry()
+        last, keys = self._carry_row_writer(last, keys, np.int32(i), tok,
+                                            key)
+        self._carry = (last, lens, keys, temps, top_ks, top_ps)
+
+    # ----- preemption ------------------------------------------------------
+
+    def _preempt_one(self) -> bool:
+        """Preempt the youngest active slot (LIFO — later arrivals yield
+        pages to earlier ones). Its request re-enters the queue FRONT and
+        resumes by exact teacher-forced recompute."""
+        with self.timers(PREEMPT):
+            return self._preempt_youngest()
+
+    def _preempt_youngest(self) -> bool:
+        # the chain to keep is the device's, and what is in flight may
+        # end a request: read it before choosing
+        self._sync_carry("pages")
+        cands = [i for i in range(self.num_slots) if self.slots[i] is not None]
+        if not cands:
+            return False
+        i = max(cands, key=lambda j: self._admit_seq[j])
+        req = self.slots[i]
+        req.preemptions += 1
+        if i not in self.prefill_queue.slots:
+            # mid-decode: preserve the PRNG chain so the resumed request
+            # samples exactly the tokens it would have sampled
+            req.resume_key = self.keys[i].copy()
+        self._clear_slot(i)
         with self._cv:
+            self._queue.appendleft(req)
             self._m_queue.set(len(self._queue))
-        if self._req_finished(req):
-            self._retire(i)
-        return 1
+        self.stats["preemptions"] += 1
+        self._m_preempted.inc()
+        self._m_active.set(self.num_active)
+        return True
+
+    def _ensure_decode_pages(self) -> None:
+        """Before a decode tick, every decodable slot needs real pages
+        under its write span (lengths[i] .. lengths[i] + span - 1; span
+        is 1 plain, k+1 speculative — rejected drafts roll back the
+        length but the pages stay mapped for future growth, and shared
+        prefix pages are never in the span). Allocate across page
+        boundaries, preempting the youngest slot when the pool is dry.
+        Each preemption frees that slot's pages, so this terminates.
+        Lengths are those of the last dispatch (a decoding row grows by
+        exactly 1 a tick), so this needs no token of the tick in flight;
+        only a dry pool reads it, since it may end a request and hand
+        its pages back."""
+        span = self._decode_write_span()
+        ps = self.page_size
+        while True:
+            rows = self._decode_rows()
+            dry = False
+            for i in rows:
+                first = int(self.lengths[i]) // ps
+                last_pg = (int(self.lengths[i]) + span - 1) // ps
+                for pg in range(first, last_pg + 1):
+                    if self.tables[i, pg] != SCRATCH_PAGE:
+                        continue
+                    pages = self._alloc_pages(1, logical_start=pg)
+                    if pages is None:
+                        if (not self._drain("pages")
+                                and not self._preempt_one()):
+                            # unreachable: slot i itself is preemptible
+                            return
+                        dry = True
+                        break  # re-derive rows (the victim may be gone)
+                    self.tables[i, pg] = pages[0]
+                    self._table_dirty = True
+                if dry:
+                    break
+            if not dry:
+                return
+
+    def _release_window_pages(self) -> None:
+        """Sliding-window page release (Mistral; ROADMAP item 1): pages
+        every position of which sits fully behind a slot's attention
+        window can never be attended again — the decode mask only allows
+        k_pos >= length + 1 - window and lengths never shrink below the
+        committed value (speculative rollback rolls back only
+        UNcommitted draft positions) — so the slot's reference goes back
+        to the pool and the table entry parks on scratch (reads of it
+        are exactly masked; scratch contents are finite activations, so
+        the masked scores stay well-defined). Pages the radix prefix
+        cache also holds keep their cache reference: a later request
+        sharing the prompt still hits them."""
+        window = self.cfg.attention_kind.sliding_window_size
+        if window is None:
+            return
+        ps = self.page_size
+        freed = 0
+        for i in self._decode_rows():
+            limit = int(self.lengths[i]) - int(window)
+            if limit < ps:
+                continue
+            # O(1) amortized: at most one page per slot newly crosses
+            # the window per tick, and the cursor never rewinds (a
+            # cleared/preempted slot resets it in _clear_slot)
+            for pg in range(self._window_cursor[i], limit // ps):
+                if self.tables[i, pg] != SCRATCH_PAGE:
+                    self.pool.release([int(self.tables[i, pg])])
+                    self.tables[i, pg] = SCRATCH_PAGE
+                    self._table_dirty = True
+                    freed += 1
+            self._window_cursor[i] = max(self._window_cursor[i],
+                                         limit // ps)
+        if freed:
+            self.stats["window_pages_released"] += freed
+            self._m_window_released.inc(freed)
+            self._m_pages_free.set(self.pool.free_pages)
+
+    # ----- stepping --------------------------------------------------------
 
     def _req_finished(self, req: Request) -> bool:
         return (len(req.generated) >= req.max_new_tokens
@@ -1133,9 +1501,8 @@ class InferenceEngine:
     def step(self) -> int:
         """One engine tick, a `serve-tick` span around the phases of
         `_tick` (the names at the top of this file). Returns the number
-        of active slots served (the paged engine: + chunks run), or what
-        a step with nothing to dispatch read (0 = idle, and nothing in
-        flight)."""
+        of active slots served + chunks run, or what a step with nothing
+        to dispatch read (0 = idle, and nothing in flight)."""
         self._step_no += 1
         tick = self.timers(TICK)
         tick.start(step_num=self._step_no)
@@ -1146,14 +1513,28 @@ class InferenceEngine:
             self._end_tick()
 
     def _tick(self) -> int:
-        """Admit into free slots, dispatch one batched decode for every
-        active slot, then read the tick before it (the loop runs one tick
-        ahead of the device)."""
+        """One engine tick: admit, dispatch one prefill chunk and one
+        batched decode for every slot whose prompt is fully cached, then
+        read the tick before (the loop runs one tick ahead of the device:
+        everything before the read works from lengths the host has, and
+        happens while the device runs the last tick). Returns slots
+        served + chunks run, or what a step with nothing to dispatch
+        read (0 = idle, and nothing in flight)."""
         with self.timers(PRE):
-            self._pre_tick()
+            self._pre_tick()  # faults, staged weight swaps, deadlines
         with self.timers(ADMIT):
             self._admit()
-        return self._read_behind(self._decode_phase())
+        with self.timers(PREFILL):
+            chunked = self._prefill_tick()
+            if chunked:
+                # chunked prefill with no decodable slots is still progress
+                # — without this a long multi-chunk prompt would trip the
+                # stalled() readiness check while prefilling normally
+                self.last_progress_time = time.monotonic()
+        with self.timers(PAGES):
+            self._release_window_pages()
+            self._ensure_decode_pages()
+        return self._read_behind(self._decode_phase() + chunked)
 
     def _decode_phase(self) -> int:
         """`tick-decode` around _decode_tick. A speculating engine's tick
@@ -1210,14 +1591,9 @@ class InferenceEngine:
                        phase_s={k: round(v, 6) for k, v in phases.items()},
                        active=self.num_active, queue=queue, drains=drains,
                        gc_s=round(self._gc.seconds - self._gc_seen, 6),
-                       **self._slow_tick_fields())
+                       pages_free=self.pool.free_pages)
         self._gc_seen = self._gc.seconds
         walls.append(wall)
-
-    def _slow_tick_fields(self) -> dict:
-        """What else a `serve_slow_tick` holds (the paged engine: its
-        pool's free pages)."""
-        return {}
 
     def _pre_tick(self) -> None:
         """Per-tick control-plane work shared by every engine subclass:
@@ -1388,20 +1764,7 @@ class InferenceEngine:
                         (now - req.first_token_time)
                         / (len(req.generated) - 1), 9)
         j.emit("serve_request", **fields)
-        # cumulative counters of the loop, one snapshot per retired request
-        # like serve_spec below: a reader takes the LAST one, or the
-        # difference of two (ahead / ticks is the share of decode ticks
-        # that were in the queue before the one before them was read;
-        # rows / ticks the mean decoding batch; phase_s where the loop
-        # thread's time went, as of the last tick that ended)
-        j.emit("serve_ticks", ticks=self.stats["ticks"],
-               ahead=self.stats["ticks_dispatched_ahead"],
-               drains=dict(self.stats["tick_drains"]),
-               dropped_after_eod=self.stats["tokens_dropped_after_eod"],
-               rows=self.stats["decode_rows"],
-               phase_s={k: round(v, 6)
-                        for k, v in self.stats["tick_phase_s"].items()},
-               **self._serve_ticks_fields())
+        j.emit("serve_ticks", **self._serve_ticks_fields())
         if self.spec is not None:
             # cumulative speculative counters, one snapshot per retired
             # request (like goodput's cumulative records): the report
@@ -1414,9 +1777,30 @@ class InferenceEngine:
                    drafter=self.spec.drafter)
 
     def _serve_ticks_fields(self) -> dict:
-        """What else a `serve_ticks` snapshot holds (the paged engine: the
-        pages its prefix cache gave back)."""
-        return {}
+        """The loop's cumulative counters, one `serve_ticks` snapshot per
+        retired request like `serve_spec`: a reader takes the LAST one, or
+        the difference of two (ahead / ticks is the share of decode ticks
+        that were in the queue before the one before them was read; rows /
+        ticks the mean decoding batch; phase_s where the loop thread's
+        time went, as of the last tick that ended; evicted the pages the
+        prefix cache gave back)."""
+        stats = self.stats
+        fields = {
+            "ticks": stats["ticks"],
+            "ahead": stats["ticks_dispatched_ahead"],
+            "drains": dict(stats["tick_drains"]),
+            "dropped_after_eod": stats["tokens_dropped_after_eod"],
+            "rows": stats["decode_rows"],
+            "phase_s": {k: round(v, 6)
+                        for k, v in stats["tick_phase_s"].items()},
+            "evicted": stats["pages_evicted"],
+            "prefill_blocks": [stats["prefill_blocks_visited"],
+                               stats["prefill_blocks_held"]]}
+        if self.cfg.holds_expert_share:
+            fields["moe_rows"] = [stats["moe_held_rows"], stats["moe_rows"]]
+            fields["moe_experts"] = [stats["moe_experts_read"],
+                                     stats["moe_experts_offered"]]
+        return fields
 
     def _journal_comm_policy(self) -> None:
         """One `comm_policy` record per engine build: which collectives
@@ -1444,46 +1828,31 @@ class InferenceEngine:
         self._m_comm_dense.inc(bytes_pair["dense"])
         self._m_comm_compressed.inc(bytes_pair["compressed"])
 
-    def _count_comm_prefill(self, P: int) -> None:
-        """Prefill-pass comm accounting at bucket length P (computed
-        once per bucket, like the jitted step itself)."""
-        if self.tp_comm is None:
-            return
-        pair = self._comm_prefill_bytes.get(P)
-        if pair is None:
-            from megatron_tpu.quant.collectives import forward_comm_bytes
-
-            pair = forward_comm_bytes(self.cfg, self.tp_comm, 1, P)
-            self._comm_prefill_bytes[P] = pair
-        self._count_comm(pair)
-
     def _decode_rows(self):
-        """Slot indices the batched decode serves this tick (the paged
-        engine excludes slots still mid-chunked-prefill). A request whose
-        tokens in flight fill its max_new_tokens is served no more: that
-        finish is a count, known before its last token is read."""
+        """Slot indices the batched decode serves this tick: not those
+        still mid-chunked-prefill, and not a request whose tokens in
+        flight fill its max_new_tokens: that finish is a count, known
+        before its last token is read."""
+        busy = self.prefill_queue.slots
         return [i for i, s in enumerate(self.slots)
-                if s is not None
+                if s is not None and i not in busy
                 and len(s.generated) + self._owed[i] < s.max_new_tokens]
 
     def _decode_extra_args(self):
-        """Extra positional args spliced between caches and the carry in
-        the decode-step call (the paged engine passes its device page
-        table here)."""
-        return ()
+        """The device page table, between the caches and the carry in the
+        decode step's (and the speculative step's) arguments."""
+        if self._table_dirty or self._device_table is None:
+            # a copy goes up: the host edits the table while the tick it
+            # went into may still be in flight
+            self._device_table = self._commit_small(self.tables.copy())
+            self._table_dirty = False
+        return (self._device_table,)
 
-    def _call_decode_step(self, *carry):
-        """The decode step over what the engine holds for its sequences
-        (the paged engine: its state store too); the rest of its results."""
-        toks, lps, self.caches, keys, lens = self._decode_step(
-            self.params, self.caches, *self._decode_extra_args(), *carry)
-        return toks, lps, keys, lens
-
-    def _decode_table_geometry(self) -> Tuple[int, int]:
-        """(entries of a row's page table, positions an entry names) as
-        the decode kernel is handed the cache: a slot is one page of the
-        whole row (kv_store.read)."""
-        return 1, self.max_seq_len
+    def _chunk_table_arg(self, row):
+        """Device form of one pending table row for the chunk step
+        ([1, max_pages] here; the CP engine rebuilds it as per-rank
+        local tables sharded over the context axis)."""
+        return row[None, :]
 
     def _note_live_blocks(self, active) -> None:
         """Set `engine_decode_live_block_share` for the tick about to
@@ -1499,7 +1868,7 @@ class InferenceEngine:
         lens = np.ones_like(self.lengths)
         lens[active] = self.lengths[active] + 1
         visited, held = decode_blocks_visited(
-            lens, *self._decode_table_geometry(), self._kernel_kv_heads(),
+            lens, self.max_pages, self.page_size, self._kernel_kv_heads(),
             sq=self._decode_write_span(),
             window=self.cfg.attention_kind.sliding_window_size)
         self.stats["decode_live_block_share"] = visited / held
@@ -1516,13 +1885,12 @@ class InferenceEngine:
 
     def _decode_write_span(self) -> int:
         """Cache positions one decode tick writes per slot: 1 plain,
-        k+1 speculative (the paged engine sizes page allocation off
-        this)."""
+        k+1 speculative (page allocation is sized off this)."""
         return 1 + self._capacity_margin()
 
     def _spec_rows_arg(self):
         """Committed device copy of the per-request spec knob mask
-        (same caching pattern as the paged engine's device table — a
+        (same caching pattern as the device page table — a
         fresh host upload every tick would flip the arg's committedness
         and split the jit cache key)."""
         if self._spec_rows_dev is None:
@@ -1549,7 +1917,7 @@ class InferenceEngine:
 
     def _init_carry(self):
         """The device-resident decode carry, (re)built from the host
-        mirrors after an admission/retire invalidated it — shared by
+        mirrors after a preemption or a migration dropped it — shared by
         the plain and speculative ticks (ONE layout; a carry change
         must hit both paths by construction)."""
         # copies go up, never the mirrors themselves: the host edits them
@@ -1692,7 +2060,12 @@ class InferenceEngine:
         ahead = any(rec.task is None for rec in self._inflight)
         t_tick = time.monotonic()
         try:
-            toks, lps, keys, lens = self._call_decode_step(*carry)
+            toks, lps, self.caches, self.state, keys, lens, *counts = (
+                self._decode_step(
+                    self.params, self.caches, self.state,
+                    *self._decode_extra_args(), *carry,
+                    *self._counts_arg()))
+            self._step_counts, = counts or (None,)
         except Exception as e:  # noqa: BLE001 - shared recovery, then
             # surface the error to the driver
             self._fail_decode(active, e)
@@ -1713,13 +2086,21 @@ class InferenceEngine:
             step=self._step_no, t0=t_tick, ahead=ahead))
         return len(active)
 
-    # What a step counted on the device beside its tokens, as the last
-    # step left it (None: this engine's steps count nothing). It rides to
-    # the host in the fetch of the step's tokens (_apply_counts).
-    _step_counts = None
+    def _counts_arg(self):
+        return () if self._step_counts is None else (self._step_counts,)
 
     def _apply_counts(self, counts) -> None:
-        """A read step's device-side counts to the host's counters."""
+        """The device's counts so far (_MOE_COUNTS), as a read step's
+        fetch brought them: the counters move by what is new since the
+        last read (the device's numbers wrap at 2**32; the difference
+        does not care)."""
+        if counts is None:
+            return
+        new = counts - self._counts_seen
+        self._counts_seen = counts
+        for (key, _, _), metric, n in zip(_MOE_COUNTS, self._m_moe, new):
+            metric.inc(int(n))
+            self.stats[key] += int(n)
 
     @staticmethod
     def _start_fetch(out):
@@ -1748,11 +2129,59 @@ class InferenceEngine:
         its slot still holds the request it was dispatched for: a row that
         ended by eod ran one tick more than it should (only a finish by
         eod needs the token), and that tick's result for it is dropped
-        here; its one extra KV position lies in a page the row owned."""
+        here; its one extra KV position lies in a page the row owned. A
+        prompt's last chunk (rec.task) is read by _read_first."""
+        if rec.task is not None:
+            return self._read_first(rec)
         toks, lps, counts = self._fetch(rec)
         with self.timers(APPLY):
             self._apply(rec, toks, lps)
             self._apply_counts(counts)
+
+    def _read_first(self, rec: _InFlight) -> None:
+        """Read a finished prompt's first token: record it and the
+        prompt's logprobs, and register the prompt's full pages in the
+        radix tree."""
+        tok, lp, plps, counts = self._fetch(rec)
+        with self.timers(APPLY):
+            self._apply_first(rec, tok, lp, plps)
+            self._apply_counts(counts)
+
+    def _apply_first(self, rec: _InFlight, tok, lp, plps) -> None:
+        (i, req), = rec.rows
+        task = rec.task
+        rec.out = None  # the device's copies go here, not between phases
+        if self.slots[i] is not req:   # the rule of every row in flight
+            self.pool.release(rec.pinned)
+            return
+        self._owed[i] -= 1
+        self.last_tok[i] = int(tok)
+        req.generated.append(int(tok))
+        req.logprobs.append(float(lp))
+        if not task.resumed and self.want_logprobs:
+            req.prompt_logprobs = [
+                float(x) for x in np.concatenate(plps)[:task.total - 1]
+            ] if plps else []
+        if rec.pinned:
+            # only FULL pages of the ORIGINAL prompt enter the tree (the
+            # partially-filled tail page stays private — decode writes
+            # into it); resumes re-register recomputed pages, and insert
+            # skips paths already cached. The tree holds its own
+            # references now: the pin goes
+            self.prefix_cache.insert(req.prompt, rec.pinned,
+                                     req.prompt_logprobs)
+            self.pool.release(rec.pinned)
+            self._m_pages_free.set(self.pool.free_pages)
+        now = time.monotonic()
+        self._m_prefill.observe(now - task.t_start)
+        if not task.resumed:
+            req.first_token_time = now
+            if req.submit_time is not None:
+                self._m_ttft.observe(now - req.submit_time)
+        self._m_tokens.inc()
+        self.last_progress_time = now
+        if self._req_finished(req):
+            self._retire(i)
 
     def _apply(self, rec: _InFlight, toks, lps) -> None:
         """A read tick's tokens to their requests."""
@@ -1962,16 +2391,23 @@ class InferenceEngine:
                     jnp.asarray(sections[n + "_scale"]), dt))
                 for n in ("kv_k", "kv_v")]
 
-    def _export_slot_kv(self, i: int
-                        ) -> Optional[Tuple[dict, Dict[str, np.ndarray]]]:
-        """Host snapshot of slot i's committed KV (positions 0..length-1)
-        in the canonical geometry-independent [L, T, H, D] layout, or
-        None when no exact export exists (the importer recompute-resumes
-        instead). The paged engine overrides this with a page gather."""
+    def _export_slot_kv(self, i: int):
+        """Gather slot i's pages into the canonical [L, T, H, D] wire
+        layout. None when any page of the span is gone (sliding-window
+        release parked it on scratch) — there is no exact KV to ship, so
+        the importer recompute-resumes from the migrated tokens (exact
+        under the deterministic position-based window mask)."""
         length = int(self.lengths[i])
+        ps = self.page_size
         if length <= 0:
             return None
-        host = kv_store.export_span(jax.device_get(self.caches), [i], length)
+        n_pages = -(-length // ps)
+        row = self._pending_rows.get(i, self.tables[i])
+        pages = [int(p) for p in row[:n_pages]]
+        if any(p == SCRATCH_PAGE for p in pages):
+            return None
+        host = kv_store.export_span(jax.device_get(self.caches), pages,
+                                    length)
         return self._pack_kv_sections(host, length)
 
     def export_request_state(self, req: Request, include_kv: bool = True
@@ -2006,7 +2442,7 @@ class InferenceEngine:
                 max(req._deadline - time.monotonic(), 0.001), 6)
         sections: Dict[str, np.ndarray] = {}
         slot = next((i for i, s in enumerate(self.slots) if s is req), None)
-        mid_prefill = (slot is not None and hasattr(self, "prefill_queue")
+        mid_prefill = (slot is not None
                        and slot in self.prefill_queue.slots)
         if slot is not None and not mid_prefill:
             self._sync_carry("migration")
@@ -2097,9 +2533,8 @@ class InferenceEngine:
 
     def _kv_install_writer(self):
         """Once-jitted kv_store.install: a canonical [L, T, ...] block
-        into the cache at a TRACED row (slot for the dense engine, page
-        for the paged pool). Static shapes, its own jit — repeated
-        imports never grow the decode step's cache (the
+        into the pool at a TRACED page. Static shapes, its own jit —
+        repeated imports never grow the decode step's cache (the
         zero-decode-recompiles invariant holds through migration)."""
         if self._kv_writer is None:
             self._kv_writer = jax.jit(
@@ -2108,24 +2543,47 @@ class InferenceEngine:
         return self._kv_writer
 
     def _install_request_kv(self, req: Request, kv: dict,
-                            sections: Dict[str, np.ndarray]) -> bool:
-        """Write the transferred KV into a free slot's cache rows (dense
-        layout; the paged engine overrides with page allocation). False =
-        no capacity, caller falls back to recompute-resume."""
+                            sections) -> bool:
+        """Paged install: allocate the span's pages, write each through
+        the once-jitted page writer, publish the table row, and re-enter
+        the prompt's full pages into the radix tree — the migrated
+        request's prefix lineage survives the hop, so followers sharing
+        its prompt hit on THIS replica too."""
         i = self._free_slot_for_import()
         if i is None:
             return False
         length = int(kv["length"])
+        ps = self.page_size
+        n_pages = -(-length // ps)
+        pages = self._alloc_pages(n_pages)
+        if pages is None:
+            return False
         leaves = self._decode_kv_sections(kv, sections)
+        writer = self._kv_install_writer()
         self._sync_carry("migration")
-        self.caches = self._kv_install_writer()(
-            self.caches, kv_store.span_block(leaves, 0, self.max_seq_len),
-            jnp.int32(i))
+        for j, pg in enumerate(pages):
+            self.caches = writer(self.caches,
+                                 kv_store.span_block(leaves, j, ps),
+                                 jnp.int32(pg))
+        row = np.zeros(self.max_pages, np.int32)
+        row[:n_pages] = pages
+        self.tables[i] = row
+        self._table_dirty = True
+        self._admit_counter += 1
+        self._admit_seq[i] = self._admit_counter
         self._arm_imported_slot(i, req, length)
+        p0 = len(req.prompt)
+        if p0 >= ps and req.prompt_logprobs:
+            # radix-prefix lineage: same full-pages-only rule as
+            # _finish_prefill (the tail page is private — decode writes it)
+            self.prefix_cache.insert(
+                req.prompt, [int(p) for p in row[:p0 // ps]],
+                req.prompt_logprobs)
+        self._m_pages_free.set(self.pool.free_pages)
         return True
 
     def _arm_imported_slot(self, i: int, req: Request, length: int) -> None:
-        """Slot bookkeeping shared by the dense and paged installs: the
+        """An installed slot's bookkeeping: the
         migrated request continues decoding at its absolute position with
         its migrated PRNG chain — no prefill, no re-sample."""
         req.submit_time = time.monotonic()
@@ -2218,6 +2676,73 @@ class InferenceEngine:
                 fields["fallback_reason"] = reason
             j.emit("serve_migrate", **fields)
         return req, path
+
+    # ----- fleet prefix directory (cross-replica radix sharing) ------------
+
+    def export_prefix_state(self, tokens):
+        """Package the radix-cached whole-page prefix of `tokens` for
+        replication to a peer: (meta, sections) in the migration wire
+        vocabulary (kind="prefix"), or None when nothing is cached."""
+        self._refuse_state_transfer("the fleet's prefix directory")
+        toks = [int(t) for t in tokens]
+        with self.paused():
+            self._drain("migration")
+            pages, lps = self.prefix_cache.lookup(toks)
+            if not pages:
+                return None
+            ps = self.page_size
+            span = len(pages) * ps
+            host = kv_store.export_span(jax.device_get(self.caches),
+                                        [int(p) for p in pages], span)
+            kv_meta, sections = self._pack_kv_sections(host, span)
+        meta = {"kind": "prefix", "tokens": toks[:span], "kv": kv_meta}
+        # per-node logprob slices concatenate back into the engine's
+        # (position-1)-indexed prompt_logprobs layout for tokens[1:span]
+        sections["prefix_logprobs"] = (
+            np.concatenate([np.asarray(x, np.float32) for x in lps])
+            if lps else np.zeros(0, np.float32))
+        return meta, sections
+
+    def import_prefix_state(self, meta: dict, sections) -> int:
+        """Install replicated prefix pages into this pool + radix tree.
+        Returns pages added (0 = incompatible, lossy, or already
+        cached). Only EXACT codecs enter the tree — a lossy prefix would
+        silently poison every future request that hits it."""
+        self._refuse_state_transfer("the fleet's prefix directory")
+        kv = meta.get("kv") or {}
+        ok, _ = self._kv_import_compatible(kv)
+        if not ok or not kv.get("exact"):
+            return 0
+        toks = [int(t) for t in meta.get("tokens", [])]
+        span = int(kv.get("length", 0))
+        ps = self.page_size
+        if span <= 0 or span % ps != 0 or span > len(toks):
+            return 0
+        n_pages = span // ps
+        with self.paused():
+            self._drain("migration")
+            have, _ = self.prefix_cache.lookup(toks)
+            if len(have) >= n_pages:
+                return 0  # the local copy stays authoritative
+            pages = self._alloc_pages(n_pages)
+            if pages is None:
+                return 0
+            leaves = self._decode_kv_sections(kv, sections)
+            writer = self._kv_install_writer()
+            for j, pg in enumerate(pages):
+                self.caches = writer(self.caches,
+                                     kv_store.span_block(leaves, j, ps),
+                                     jnp.int32(pg))
+            lp = np.asarray(sections.get("prefix_logprobs",
+                                         np.zeros(0)), np.float32)
+            added = self.prefix_cache.insert(toks[:span], pages, lp)
+            # insert() retained the refs the tree owns; drop the
+            # allocation refs so the pages become cache-only (evictable
+            # under pressure), and so pages skipped as already-cached
+            # free immediately
+            self.pool.release(pages)
+            self._m_pages_free.set(self.pool.free_pages)
+        return added
 
     # ----- driving ---------------------------------------------------------
 

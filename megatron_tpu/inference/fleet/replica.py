@@ -214,7 +214,6 @@ def _build_and_serve(spec: Dict[str, Any]) -> None:
         engine_max_seq_len=spec.get("max_seq_len"),
         engine_max_queue=spec.get("max_queue"),
         kv_cache_int8=bool(spec.get("kv_cache_int8", False)),
-        kv_paging=bool(spec.get("kv_paging", False)),
         page_size=int(spec.get("page_size", 16)),
         prefill_chunk=int(spec.get("prefill_chunk", 32)),
         num_pages=spec.get("num_pages"),

@@ -38,6 +38,16 @@ class GenerationOutput:
     engine_s: Optional[float] = None
 
 
+def _refuse_state_space(cfg: ModelConfig, what: str) -> None:
+    """The one-shot loops carry keys and values from step to step and
+    nothing else: a model with state-space layers would decode every token
+    after the first from a zeroed recurrent state."""
+    if cfg.has_ssm:
+        raise NotImplementedError(
+            f"{what} carries no recurrent state: serve a model with "
+            "state-space layers through the engine (engine_slots > 0)")
+
+
 def _default_fwd(cfg):
     """forward_fn contract: (params, tokens, positions, caches,
     cache_index) -> (logits, caches). Default = the single-stage cached
@@ -165,6 +175,7 @@ def generate_tokens(
     forward_fn=None,
     kv_cache_int8: bool = False,
 ) -> GenerationOutput:
+    _refuse_state_space(cfg, "one-shot generation")
     if kv_cache_int8 and forward_fn is not None:
         raise ValueError(
             "kv_cache_int8 is supported on the single-stage forward only "
@@ -216,6 +227,7 @@ def beam_search_tokens(
     scoring step; returns (beams [beam_size, total], scores [beam_size]).
     The per-beam cache gathers are kv_store's, so the int8 store flows
     through unchanged."""
+    _refuse_state_space(cfg, "beam search")
     prompt = np.asarray(prompt, np.int32)
     plen = len(prompt)
     total = plen + max_new_tokens

@@ -1,11 +1,11 @@
 """Paged KV-cache serving: block pool + radix prefix cache + chunked
 prefill.
 
-The slot engine (inference/engine.py) reserves `max_seq_len` cache rows
-per slot up front — a young sequence in a long cache wastes almost all
-of them, and two requests sharing a system prompt each recompute and
-store it. This package replaces the per-slot reservation with a shared
-pool of fixed-size pages:
+A cache that reserves `max_seq_len` rows per slot up front wastes almost
+all of them on a young sequence, and two requests sharing a system prompt
+each recompute and store it. The serving engine (inference/engine.py)
+keeps its KV in a shared pool of fixed-size pages instead; this package
+holds the parts:
 
   * :mod:`pool` — the free-list page allocator with refcounts. KV
     storage becomes ``[layers, num_pages, page_size, kv_heads, head_dim]``
@@ -18,10 +18,9 @@ pool of fixed-size pages:
   * :mod:`scheduler` — the chunked-prefill queue: long prompts enter the
     cache `prefill_chunk` tokens per engine tick, interleaved with the
     batched decode, so one long prompt can never stall the batch.
-  * :mod:`engine` — :class:`PagedInferenceEngine`, the drop-in paged
-    mode of the serving engine (``--serve_kv_paging``). Token-identical
-    to the slot engine on the serving test matrix
-    (tests/test_serving_engine.py), zero decode recompiles after warmup.
+  * :mod:`engine` — the builders of the engine's jitted device programs
+    (the decode step, the chunk step, the draft model's chunk step): zero
+    decode recompiles after warmup.
 
 The decode attention path reads through the table: the paged
 flash-decode kernel (ops/pallas/paged_flash_decode.py) resolves pages
@@ -30,13 +29,11 @@ the pages into a dense view and the masked einsum computes identical
 values.
 """
 
-from megatron_tpu.inference.paging.engine import PagedInferenceEngine
 from megatron_tpu.inference.paging.pool import PagePool
 from megatron_tpu.inference.paging.radix import RadixPrefixCache
 from megatron_tpu.inference.paging.scheduler import ChunkedPrefillQueue
 
 __all__ = [
-    "PagedInferenceEngine",
     "PagePool",
     "RadixPrefixCache",
     "ChunkedPrefillQueue",

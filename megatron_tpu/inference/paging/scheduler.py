@@ -1,9 +1,9 @@
 """Chunked-prefill queue: long prompts enter the cache chunk by chunk.
 
-The slot engine prefills a whole prompt in one bucketed pass — a 2k-token
-prompt stalls every active decode for the full prefill. The paged engine
-instead admits the request immediately (slot + pages assigned) and
-queues its prefill here; every engine tick runs AT MOST ONE chunk of
+A whole prompt prefilled in one pass stalls every active decode for the
+full prefill (a 2k-token prompt: 2k positions). The engine instead admits
+the request immediately (slot + pages assigned) and queues its prefill
+here; every engine tick runs AT MOST ONE chunk of
 `chunk` tokens before the batched decode, so prefill work interleaves
 with decode ticks and one long prompt can never stall the batch.
 

@@ -160,8 +160,7 @@ class GenerationService:
                  engine_slots: int = 0, engine_max_seq_len=None,
                  metrics: Optional[MetricsRegistry] = None,
                  engine_max_queue: Optional[int] = None,
-                 kv_paging: bool = False, page_size: int = 16,
-                 prefill_chunk: int = 32,
+                 page_size: int = 16, prefill_chunk: int = 32,
                  num_pages: Optional[int] = None,
                  request_timeout: Optional[float] = None,
                  reload_dir: Optional[str] = None,
@@ -187,10 +186,10 @@ class GenerationService:
         pp>1 pipelined forward (ref ForwardStep, forward_step.py:45-204).
 
         engine_slots > 0 builds a continuous-batching InferenceEngine with
-        that many KV-cache slots plus its background step-loop thread;
-        concurrent sampling requests then share each decode tick.
-        kv_paging swaps in the PagedInferenceEngine (shared page pool +
-        radix prefix cache + chunked prefill, docs/serving.md);
+        that many sequences over a shared page pool (page_size,
+        prefill_chunk, num_pages: radix prefix cache + chunked prefill,
+        docs/serving.md) plus its background step-loop thread; concurrent
+        sampling requests then share each decode tick.
         engine_max_queue bounds admission — overload answers 503 with
         Retry-After instead of growing queue latency without bound.
 
@@ -223,9 +222,9 @@ class GenerationService:
         "Context-parallel long-context serving"): shard every sequence's
         paged KV over the mesh's "context" axis and run decode/prefill
         attention as a ring over the shards — million-token prompts
-        whose KV exceeds one device's HBM. Needs kv_paging and a mesh
+        whose KV exceeds one device's HBM. Needs a mesh
         with context >= 2; greedy output stays token-identical to the
-        single-host paged engine. cp_collectives ("dense"|"int8"|"fp8")
+        single-host engine. cp_collectives ("dense"|"int8"|"fp8")
         picks the ring-hop transport; cp_comm_policy is a site-policy
         JSON gating the "cp_ring" and "cp_a2a" sites.
 
@@ -337,10 +336,6 @@ class GenerationService:
                     ContextParallelEngine,
                 )
 
-                if not kv_paging:
-                    raise ValueError(
-                        "context-parallel serving runs over the paged "
-                        "engine — enable kv_paging")
                 if kv_cache_int8 or spec_cfg is not None:
                     raise ValueError(
                         "context-parallel serving supports neither int8 "
@@ -384,20 +379,6 @@ class GenerationService:
                     self.engine = self.engines[0]
                 else:
                     self.engine = _cp_engine(mesh, params, self.metrics)
-            elif kv_paging:
-                from megatron_tpu.inference.paging import PagedInferenceEngine
-
-                self.engine = PagedInferenceEngine(
-                    cfg, params, num_slots=engine_slots,
-                    max_seq_len=engine_max_seq_len,
-                    kv_cache_int8=kv_cache_int8,
-                    page_size=page_size, prefill_chunk=prefill_chunk,
-                    num_pages=num_pages,
-                    vocab_size=tokenizer.vocab_size, mesh=mesh,
-                    metrics=self.metrics, max_queue=engine_max_queue,
-                    speculative=spec_cfg,
-                    compress_collectives=compress_collectives,
-                    comm_policy=comm_policy)
             else:
                 from megatron_tpu.inference.engine import InferenceEngine
 
@@ -405,6 +386,8 @@ class GenerationService:
                     cfg, params, num_slots=engine_slots,
                     max_seq_len=engine_max_seq_len,
                     kv_cache_int8=kv_cache_int8,
+                    page_size=page_size, prefill_chunk=prefill_chunk,
+                    num_pages=num_pages,
                     vocab_size=tokenizer.vocab_size, mesh=mesh,
                     metrics=self.metrics, max_queue=engine_max_queue,
                     speculative=spec_cfg,
@@ -672,11 +655,10 @@ class GenerationService:
 
     # ----- fleet prefix directory (page export) ----------------------------
 
-    def _paged_engine(self):
-        if self.engine is None or not hasattr(self.engine,
-                                              "export_prefix_state"):
+    def _need_engine(self):
+        if self.engine is None:
             raise ValueError(
-                "prefix export/import needs the paged engine (kv_paging)")
+                "prefix export/import needs the engine (engine_slots > 0)")
         return self.engine
 
     def export_prefix_blob(self, tokens: list) -> Optional[bytes]:
@@ -684,7 +666,7 @@ class GenerationService:
         when the radix cache holds nothing for it (HTTP 404)."""
         from megatron_tpu.inference.fleet import migration
 
-        out = self._paged_engine().export_prefix_state(
+        out = self._need_engine().export_prefix_state(
             [int(t) for t in tokens])
         if out is None:
             return None
@@ -698,7 +680,7 @@ class GenerationService:
         hits here without this replica ever having prefilled it."""
         from megatron_tpu.inference.fleet import migration
 
-        eng = self._paged_engine()
+        eng = self._need_engine()
         meta, sections = migration.unpack_state(blob)
         if meta.get("kind") != "prefix":
             raise ValueError(
@@ -717,7 +699,7 @@ class GenerationService:
         via replicate_prefix (page export, no re-prefill)."""
         import numpy as np
 
-        eng = self._paged_engine()
+        eng = self._need_engine()
         toks = [int(t) for t in tokens]
         if not toks:
             raise ValueError("tokens: non-empty int list required")
@@ -1182,8 +1164,7 @@ def run_server(cfg: ModelConfig, params: Any, tokenizer,
                mesh=None, forward_fn=None, kv_cache_int8=False,
                engine_slots: int = 0, engine_max_seq_len=None,
                engine_max_queue: Optional[int] = None,
-               kv_paging: bool = False, page_size: int = 16,
-               prefill_chunk: int = 32,
+               page_size: int = 16, prefill_chunk: int = 32,
                num_pages: Optional[int] = None,
                request_timeout: Optional[float] = None,
                drain_timeout: float = 30.0,
@@ -1223,7 +1204,7 @@ def run_server(cfg: ModelConfig, params: Any, tokenizer,
                                 engine_slots=engine_slots,
                                 engine_max_seq_len=engine_max_seq_len,
                                 engine_max_queue=engine_max_queue,
-                                kv_paging=kv_paging, page_size=page_size,
+                                page_size=page_size,
                                 prefill_chunk=prefill_chunk,
                                 num_pages=num_pages,
                                 request_timeout=request_timeout,
@@ -1299,8 +1280,8 @@ def run_server(cfg: ModelConfig, params: Any, tokenizer,
         threading.Thread(target=_warmup, daemon=True,
                          name="serve-warmup").start()
 
-    mode = (f"continuous batching, {engine_slots} slots"
-            + (", paged KV + prefix cache" if kv_paging else "")
+    mode = (f"continuous batching, {engine_slots} slots, paged KV + "
+            "prefix cache"
             + (f", context-parallel KV (cp="
                f"{getattr(service.engine, 'cp', 0)}, "
                f"{cp_geometry}"

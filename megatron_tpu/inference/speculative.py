@@ -21,11 +21,11 @@ Two pluggable drafters:
     machinery through a SECOND cache tree: the draft proposes greedily
     via a ``lax.scan`` of k single-token forwards inside the same
     jitted step (plus one extra write-only forward so the draft cache
-    covers the all-accepted case), then the target verifies. Admission
-    prefill and the paged engine's chunked prefill write the draft
-    cache through the same page tables and write fences as the target
-    cache, so prefix-cache aliasing and preempt-resume recompute work
-    identically for both trees.
+    covers the all-accepted case), then the target verifies. The
+    engine's chunked prefill writes the draft cache through the same
+    page tables and write fences as the target cache, so prefix-cache
+    aliasing and preempt-resume recompute work identically for both
+    trees.
 
 Exactness contract (pinned by tests/test_speculative.py):
 
@@ -51,7 +51,7 @@ Exactness contract (pinned by tests/test_speculative.py):
 Rollback: rejected drafts' K/V entries (written at positions past the
 accepted length by the same multi-token forward) are invalidated purely
 by the per-slot length roll-back — attention masks every row to its own
-valid prefix, and the next tick overwrites those positions. The paged
+valid prefix, and the next tick overwrites those positions. The
 engine's page table is untouched: speculative writes only ever land in
 the slot's private tail pages (shared prefix pages hold only FULL pages
 of the original prompt, strictly below the decode positions), so no
@@ -295,7 +295,7 @@ def speculative_accept(
 
 
 # ---------------------------------------------------------------------------
-# jitted step builders (slot + paged, ngram + model drafter)
+# jitted step builders (ngram + model drafter)
 # ---------------------------------------------------------------------------
 
 
@@ -305,16 +305,15 @@ def build_spec_decode_step(
     vocab_size: Optional[int],
     want_logprobs: bool,
     donate_argnums: tuple,
-    paged: bool,
 ):
     """One jitted speculative decode step for the engine.
 
-    Signature (positional, matching the engines' splice convention —
-    extra args between the cache trees and the carry):
+    Signature (positional, matching the engine's splice convention —
+    the page table between the cache trees and the carry):
 
-      ngram:  (params, caches, [table], last_tok, lengths, keys, temps,
+      ngram:  (params, caches, table, last_tok, lengths, keys, temps,
                top_ks, top_ps, spec_rows, drafts)
-      model:  (params, caches, dparams, dcaches, [table], last_tok,
+      model:  (params, caches, dparams, dcaches, table, last_tok,
                lengths, keys, temps, top_ks, top_ps, spec_rows)
 
     Returns (toks [N, k+1], lps, accepts, caches, [dcaches], new_keys,
@@ -330,10 +329,9 @@ def build_spec_decode_step(
 
     def _verify(params, caches, table, last, lens, keys, temps, tks, tps,
                 spec_rows, drafts):
-        kw = {"page_table": table} if paged else {}
         toks_in = jnp.concatenate([last[:, None], drafts], axis=1)
         logits, caches = lm_forward(cfg, params, toks_in, kv_caches=caches,
-                                    cache_index=lens, **kw)
+                                    cache_index=lens, page_table=table)
         toks, lps, accepts = speculative_accept(
             logits, drafts, lens, keys, temps, tks, tps,
             vocab_size=vocab_size, spec_rows=spec_rows,
@@ -342,30 +340,22 @@ def build_spec_decode_step(
         return toks, lps, accepts, caches, keys, lens + accepts + 1, last_new
 
     if spec.drafter == "ngram":
-        if paged:
-            @partial(jax.jit, donate_argnums=donate_argnums)
-            def spec_step(params, caches, table, last, lens, keys, temps,
-                          tks, tps, spec_rows, drafts):
-                return _verify(params, caches, table, last, lens, keys,
-                               temps, tks, tps, spec_rows, drafts)
-        else:
-            @partial(jax.jit, donate_argnums=donate_argnums)
-            def spec_step(params, caches, last, lens, keys, temps, tks,
-                          tps, spec_rows, drafts):
-                return _verify(params, caches, None, last, lens, keys,
-                               temps, tks, tps, spec_rows, drafts)
+        @partial(jax.jit, donate_argnums=donate_argnums)
+        def spec_step(params, caches, table, last, lens, keys, temps,
+                      tks, tps, spec_rows, drafts):
+            return _verify(params, caches, table, last, lens, keys,
+                           temps, tks, tps, spec_rows, drafts)
         return spec_step
 
     V = cfg.vocab_size
 
-    def _propose_and_verify(params, caches, dparams, dcaches, table, last,
-                            lens, keys, temps, tks, tps, spec_rows):
-        kw = {"page_table": table} if paged else {}
-
+    @partial(jax.jit, donate_argnums=donate_argnums)
+    def spec_step(params, caches, dparams, dcaches, table, last, lens,
+                  keys, temps, tks, tps, spec_rows):
         def body(carry, _):
             dc, tok, ln = carry
             lg, dc = lm_forward(dcfg, dparams, tok[:, None], kv_caches=dc,
-                                cache_index=ln, **kw)
+                                cache_index=ln, page_table=table)
             lg = lg[:, 0].astype(jnp.float32)
             if vocab_size is not None and vocab_size < V:
                 lg = jnp.where(jnp.arange(V) < vocab_size, lg, neg)
@@ -379,24 +369,11 @@ def build_spec_decode_step(
         # draft k's K/V so a fully-accepted tick leaves the draft cache
         # complete for the next tick's proposal
         _, dcaches = lm_forward(dcfg, dparams, d_last[:, None],
-                                kv_caches=dcaches, cache_index=d_len, **kw)
+                                kv_caches=dcaches, cache_index=d_len,
+                                page_table=table)
         toks, lps, accepts, caches, keys, lens_new, last_new = _verify(
             params, caches, table, last, lens, keys, temps, tks, tps,
             spec_rows, drafts)
         return toks, lps, accepts, caches, dcaches, keys, lens_new, last_new
 
-    if paged:
-        @partial(jax.jit, donate_argnums=donate_argnums)
-        def spec_step(params, caches, dparams, dcaches, table, last, lens,
-                      keys, temps, tks, tps, spec_rows):
-            return _propose_and_verify(params, caches, dparams, dcaches,
-                                       table, last, lens, keys, temps,
-                                       tks, tps, spec_rows)
-    else:
-        @partial(jax.jit, donate_argnums=donate_argnums)
-        def spec_step(params, caches, dparams, dcaches, last, lens, keys,
-                      temps, tks, tps, spec_rows):
-            return _propose_and_verify(params, caches, dparams, dcaches,
-                                       None, last, lens, keys, temps,
-                                       tks, tps, spec_rows)
     return spec_step

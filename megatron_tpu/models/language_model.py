@@ -466,8 +466,8 @@ def lm_forward(
     worst layer's load statistic] (ops/moe.py layer_stats; behind them,
     where the layers hold a share of their experts, the held rows' shares
     summed over the layers), as the last result: behind the logits, or
-    behind the stores of a serving step (the paged engine counts the rows
-    its held experts took from it).
+    behind the stores of a serving step (the serving engine counts the
+    rows its held experts took from it).
 
     page_table: the store is a pool of pages (inference/paging/) shared
     by every slot; each row's logical context is page_table[b] physical

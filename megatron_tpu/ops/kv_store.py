@@ -6,10 +6,10 @@ of stacked leaves, every leaf
     [layers, rows, row_len, kv_heads, head_dim]
 
   * slots — `rows` is the batch (one row a sequence), `row_len` the
-    longest sequence a row holds (inference/engine.py, generation.py);
+    longest sequence a row holds (the one-shot loops of generation.py);
   * pages — `rows` is the pool of pages every sequence shares, `row_len`
     the page size; a `page_table` [B, n] names each sequence's pages in
-    order (inference/paging/), page 0 is scratch.
+    order (the serving engine, inference/engine.py), page 0 is scratch.
 
 bf16/f32: `(k, v)`. int8 (ops/kv_quant.py): `(k_q, v_q, k_scale,
 v_scale)`, the scales one float32 a vector (`head_dim` 1).
@@ -19,9 +19,9 @@ The layer stack carries the store through its scan (`lm_forward`), each
 layer writes its new rows in place at `[layer, ...]` (`write`) and hands
 attention a view of the store (`read`): nothing the size of a layer's
 share is copied on the way (int8 stores dequantize a layer for
-attention: that is arithmetic, kept token-identical between engines).
-The engines create, paste, export and install through the functions at
-the end. The wire format of exported spans is the canonical
+attention: that is arithmetic, kept token-identical between the loops).
+The engine and the one-shot loops create, export and install through the
+functions at the end. The wire format of exported spans is the canonical
 [layers, positions, kv_heads, head_dim] of fleet/migration.py.
 
 To attention a cache is always paged: `read` presents slots as a pool
@@ -201,25 +201,18 @@ def gather_rows(leaf, layer, table):
 
 
 # ---------------------------------------------------------------------------
-# whole rows: what the engines do between steps
+# whole rows: what the engine and the one-shot loops do between steps
 # ---------------------------------------------------------------------------
 
 
 def install(store: Store, blocks: Sequence[jnp.ndarray], at) -> Store:
-    """Row `at` (a slot, a page; traced) of every layer overwritten from
+    """Row `at` (a page; traced) of every layer overwritten from
     position 0 by `blocks`, one [layers, positions, ...] array a leaf:
-    a prefilled prompt pasted into its slot, a migrated span into its
-    slot or page."""
+    a migrated span into its page."""
     return tuple(
         jax.lax.dynamic_update_slice(
             leaf, block[:, None].astype(leaf.dtype), (0, at, 0, 0, 0))
         for leaf, block in zip(store, blocks))
-
-
-def row(store: Store, index: int) -> Tuple[jnp.ndarray, ...]:
-    """Row `index` of every layer, [layers, row_len, ...] a leaf: what
-    `install` takes."""
-    return tuple(leaf[:, index] for leaf in store)
 
 
 def repeat_rows(store: Store, n: int) -> Store:
@@ -234,8 +227,8 @@ def take_rows(store: Store, rows) -> Store:
 
 def export_span(host_store: Sequence[np.ndarray], rows: Sequence[int],
                 length: int) -> List[np.ndarray]:
-    """The first `length` positions of the sequence stored in `rows` (one
-    slot, or a sequence's pages in order) of a store fetched to the
+    """The first `length` positions of the sequence stored in `rows` (a
+    sequence's pages in order) of a store fetched to the
     host, in the canonical wire layout [layers, positions, Hkv, D]."""
     out = []
     for leaf in host_store:
@@ -249,8 +242,7 @@ def export_span(host_store: Sequence[np.ndarray], rows: Sequence[int],
 def span_block(leaves: Sequence[np.ndarray], j: int, row_len: int
                ) -> Tuple[jnp.ndarray, ...]:
     """Block j of `row_len` positions of canonical leaves [layers,
-    positions, ...], zero-padded past their end: what `install` takes
-    (a whole slot row: j 0 and the row's length)."""
+    positions, ...], zero-padded past their end: what `install` takes."""
     blocks = []
     for leaf in leaves:
         block = np.zeros((leaf.shape[0], row_len) + leaf.shape[2:],

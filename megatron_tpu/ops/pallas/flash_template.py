@@ -1296,9 +1296,9 @@ def flash_decode(
 ) -> jnp.ndarray:
     """Single-token decode attention with per-row valid-prefix masking:
     the sq == 1 point of the decode specialization. Returns
-    [B, 1, Hq, D]. Raises ValueError for unsupported shapes (the serving
-    engines size their caches so this never fires:
-    inference/engine.py _kernel_seq_multiple)."""
+    [B, 1, Hq, D]. Raises ValueError for unsupported shapes (a dense
+    cache whose length no block divides; the serving engine's pool goes
+    through the paged kernels below)."""
     if q.shape[1] != 1:
         raise ValueError(
             f"flash_decode is single-token only (q_len={q.shape[1]})")
@@ -1548,7 +1548,7 @@ def chunk_blocks_visited(off: int, s: int, row_end: int, groups: int,
     """(blocks a chunk call visits, blocks its table holds a query tile
     times its tiles) for one chunk of s queries at offset `off` in a row
     that holds row_end positions, on the host: the sum of the kernel's own
-    loop bounds. The paged engine's `engine_prefill_live_block_share` is
+    loop bounds. The serving engine's `engine_prefill_live_block_share` is
     the first over the second."""
     tq, unit, units, _, n_blocks = _chunk_geometry(s, groups, table_width,
                                                    page_size, kv_heads)

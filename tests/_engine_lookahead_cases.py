@@ -2,10 +2,10 @@
 "Step loop"): the served tokens are the same tokens whichever order the
 host reads them in.
 
-Used by tests/test_serving_engine.py (slot and paged engine, against
-`generate_tokens`), tests/test_paging.py and tests/test_jamba.py (the paged
-engine on a typed stack with state rows, against the same engine driven
-tick by tick). No test here: pytest collects `test_*.py` alone.
+Used by tests/test_serving_engine.py (against `generate_tokens`),
+tests/test_paging.py and tests/test_jamba.py (the engine on a typed stack
+with state rows, against the same engine driven tick by tick). No test
+here: pytest collects `test_*.py` alone.
 """
 
 import dataclasses
@@ -99,15 +99,12 @@ def assert_served(reqs: List[Request], wants: List[Want], eng,
     assert eng.stats["decode_recompiles"] == 0
     # a row that ended by eod in mid-stream ran one tick more, and that
     # tick's token reached nobody. Where the eod is a prompt's first token
-    # the paged engine reads it a step after the tick that first served the
-    # row, so two ticks ran; the slot engine reads it at the admission, and
-    # none did
-    paged = hasattr(eng, "prefill_queue")
+    # the engine reads it a step after the tick that first served the row,
+    # so two ticks ran
     dropped = 0
     for req, want in zip(reqs, wants):
         if want.eod is not None and len(req.generated) < req.max_new_tokens:
-            first = len(req.generated) == 1
-            dropped += (2 if paged else 0) if first else 1
+            dropped += 2 if len(req.generated) == 1 else 1
     if drained:
         # an eod read at a drain has no later tick to drop from
         assert eng.stats["tokens_dropped_after_eod"] <= dropped

@@ -345,34 +345,14 @@ def test_train_step_flash_bwd_audit_clean():
     assert len(don.donated) > 10
 
 
-def test_decode_step_audit_clean():
-    """Engine decode step: zero collectives (single-device contract),
-    zero host callbacks, the KV cache donated. The only tolerated
-    bf16->f32 promotions are the softmax_fp32 numerics (K upcast per
-    layer) — anything else is a new silent upcast."""
-    t = targets.decode_step_target()
-    rep = jaxpr_audit.audit_jaxpr(t.jaxpr(), t.name)
-    assert rep.collectives == []
-    assert rep.callbacks == []
-    # allowlist: attention's softmax_fp32 upcasts K ([slots, S, Hkv, D])
-    # once per layer inside the layer scan — intended numerics
-    # (ops/attention.py kf = k.astype(f32)); bound it so a new upcast
-    # (e.g. the whole cache, or V too) still fails
-    unexpected = [p for p in rep.promotions
-                  if not (p.shape == (4, 32, 2, 8) and p.calls == 4)]
-    assert unexpected == [], unexpected
-    assert len(rep.promotions) <= 1
-
-    don = jaxpr_audit.audit_donation(t.lowered())
-    assert len(don.donated) == 2, don.donated  # the k/v cache stacks
-
-
 def test_paged_decode_step_audit_clean():
-    """Paged engine decode step (page-table gather + scatter): same
-    contract as the slot decode step — zero collectives, zero host
-    callbacks, the page pools donated; the only tolerated bf16->f32
-    promotion is softmax_fp32's per-layer K upcast (here the GATHERED
-    [slots, max_pages*page_size, Hkv, D] view)."""
+    """Engine decode step (page-table gather + scatter): zero
+    collectives (single-device contract), zero host callbacks, the page
+    pools donated; the only tolerated bf16->f32 promotion is
+    softmax_fp32's per-layer K upcast (intended numerics, ops/attention.py
+    kf = k.astype(f32); here the GATHERED [slots, max_pages*page_size,
+    Hkv, D] view, once per layer inside the layer scan) — bounded so a
+    new upcast (e.g. the whole pool, or V too) still fails."""
     t = targets.paged_decode_step_target()
     rep = jaxpr_audit.audit_jaxpr(t.jaxpr(), t.name)
     assert rep.collectives == []
@@ -386,10 +366,8 @@ def test_paged_decode_step_audit_clean():
     assert len(don.donated) == 2, don.donated  # the k/v page pools
 
 
-@pytest.mark.parametrize("builder", ["spec_decode_step_target",
-                                     "spec_paged_decode_step_target"])
-def test_spec_decode_step_audit_clean(builder):
-    """Speculative decode step (slot AND paged, model drafter): zero
+def test_spec_decode_step_audit_clean():
+    """Speculative decode step (model drafter): zero
     collectives, ZERO host callbacks — the draft-proposal scan and the
     exact accept/reject (uniform draws, residual categoricals) must all
     stay on device — and FULL donation of BOTH cache trees (2 target
@@ -399,7 +377,7 @@ def test_spec_decode_step_audit_clean(builder):
     k-step proposal scan), the [N, k+1] verify attention slices, and
     the [N, (k+1,) V] logits rows the accept math scores — anything
     cache-sized is a new silent upcast and fails."""
-    t = getattr(targets, builder)()
+    t = targets.spec_paged_decode_step_target()
     rep = jaxpr_audit.audit_jaxpr(t.jaxpr(), t.name)
     assert rep.collectives == []
     assert rep.callbacks == []
@@ -487,18 +465,18 @@ def test_injected_collective_breaks_contract():
 def test_contract_catches_callback_regression():
     """A host callback smuggled into an audited program trips the
     scalar checks, not just the collective table."""
-    t = targets.decode_step_target()
+    t = targets.paged_decode_step_target()
 
     def with_cb(*args):
         out = t.fn(*args)
         jax.debug.print("tok {t}", t=out[0])
         return out
 
-    tampered = targets.AuditTarget(name="decode_single", fn=with_cb,
+    tampered = targets.AuditTarget(name="decode_paged", fn=with_cb,
                                    args=t.args)
-    fresh = contracts.build_manifest("decode_single", include_hlo=False,
+    fresh = contracts.build_manifest("decode_paged", include_hlo=False,
                                      target=tampered)
-    problems = contracts.check_contract("decode_single", level="jaxpr",
+    problems = contracts.check_contract("decode_paged", level="jaxpr",
                                         fresh=fresh)
     assert any("host_callbacks" in p for p in problems), problems
 

@@ -1214,8 +1214,7 @@ HBM = 15.75 * GIB   # what the chip's compiler allows a program
 # candidate's; 17000 is what ISSUE 22 asked for and the parent's compiler
 # refused), or the slots of a slot cache (16 of 8448 positions: 4.4 GB)
 _SERVE_CASES = {"decode-8000": 8000, "chunk-8000": 8000,
-                "decode-17000": 17000, "chunk-17000": 17000,
-                "slots-decode": 16}
+                "decode-17000": 17000, "chunk-17000": 17000}
 
 
 @pytest.fixture(scope="module")
@@ -1226,8 +1225,8 @@ def serve_cfg():
 
 
 def _paged_step(topo, cfg, step, pages, page, slots, max_len, chunk):
-    """The model call of PagedInferenceEngine's decode step or prefill
-    chunk (inference/paging/engine.py `_forward`; the state store beside
+    """The model call of InferenceEngine's decode step or prefill
+    chunk (inference/paging/engine.py `make_forward`; the state store beside
     the pool where the model has state-space layers) compiled for one
     described chip with pool and state donated."""
     from megatron_tpu.models.language_model import lm_forward
@@ -1276,33 +1275,13 @@ def _paged_step(topo, cfg, step, pages, page, slots, max_len, chunk):
 
 
 def _serve_program(topo, cfg, case):
-    """The model call of the engine's step (PagedInferenceEngine's decode
-    and chunk steps, InferenceEngine's decode step) compiled for one
-    described chip with the store donated, and the store's shape."""
-    from megatron_tpu.models.language_model import lm_forward
-    from megatron_tpu.models.params import param_shapes
-    from megatron_tpu.ops import kv_store
-
+    """The model call of the engine's decode or chunk step compiled for
+    one described chip with the store donated, and the store's shape."""
     rows = _SERVE_CASES[case]
-    if case != "slots-decode":
-        compiled = _paged_step(topo, cfg, case.split("-")[0], rows, PAGE,
-                               SERVE_SLOTS, SERVE_LEN, CHUNK)
-        return compiled, (cfg.num_layers, rows, PAGE, cfg.n_kv_heads,
-                          cfg.head_dim)
-    dev, i32 = topo.devices[0], jnp.int32
-    store = jax.eval_shape(lambda: kv_store.create(cfg, rows, SERVE_LEN))
-    store = tuple(_abstract(leaf.shape, leaf.dtype, dev) for leaf in store)
-    params = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, dev),
-                          param_shapes(cfg))
-
-    def slots_decode(params, caches, tok, lengths):
-        return lm_forward(cfg, params, tok[:, None], kv_caches=caches,
-                          cache_index=lengths)
-
-    compiled = jax.jit(slots_decode, donate_argnums=(1,)).lower(
-        params, store, _abstract((rows,), i32, dev),
-        _abstract((rows,), i32, dev)).compile()
-    return compiled, store[0].shape
+    compiled = _paged_step(topo, cfg, case.split("-")[0], rows, PAGE,
+                           SERVE_SLOTS, SERVE_LEN, CHUNK)
+    return compiled, (cfg.num_layers, rows, PAGE, cfg.n_kv_heads,
+                      cfg.head_dim)
 
 
 _RESULT_LINE = re.compile(
@@ -1357,8 +1336,7 @@ def test_serving_step_writes_the_cache_in_place(topo, serve_cfg, case):
     assert all(re.search(r'op_name="[^"]*/scatter"', line)
                for line in fused), fused[:1]
     kernels = {"decode": ["paged_flash_decode"],
-               "chunk": ["paged_flash_chunk"],
-               "slots": ["paged_flash_decode"]}[case.split("-")[0]]
+               "chunk": ["paged_flash_chunk"]}[case.split("-")[0]]
     assert _kernels_named(text) == kernels
 
 

@@ -27,7 +27,7 @@ def _lines(stdout):
     return [json.loads(line) for line in stdout.splitlines() if line.strip()]
 
 
-@pytest.mark.slow  # ~60 s: seven child processes (trainer, two servers...).
+@pytest.mark.slow  # ~50 s: six child processes (trainer, server...).
 # Tier-1 runs against its time limit on this host (ROADMAP D10), so the
 # end-to-end rehearsal is in the slow set; tier-1 keeps the parent's
 # contract (the three tests at the end) and the no-TPU / no-checkout exits.
@@ -43,7 +43,7 @@ def test_rehearsal_drives_the_main_path(tmp_path):
     assert lines[-1] == {"ok": True, "device": cpu}
     phases = lines[:-1]
     assert [p["phase"] for p in phases] == [
-        "device", "kernels", "data", "train", "serve_slot", "serve_paged"]
+        "device", "kernels", "data", "train", "serve"]
     assert all(p["ok"] for p in phases)
     by = {p["phase"]: p for p in phases}
     assert all(by[p]["device"] == cpu for p in by if p != "data")
@@ -57,12 +57,11 @@ def test_rehearsal_drives_the_main_path(tmp_path):
     assert train["losses"][-1] < train["losses"][0]
     # interpreted kernels: pallas_call equations, no TPU custom calls
     assert train["kernels_in_step"]["pallas_calls_in_jaxpr"] >= 3
-    for name in ("serve_slot", "serve_paged"):
-        assert by[name]["decode_recompiles"] == 0
-        assert by[name]["weights_version"] == train["checkpoint_iteration"]
-        assert by[name]["kernels_in_step"]["pallas_calls_in_jaxpr"] >= 1
-        assert by[name]["concurrent"] >= 2 and by[name]["drained"]
-    assert by["serve_paged"]["greedy_identical_to_slot_engine"] is True
+    serve = by["serve"]
+    assert serve["decode_recompiles"] == 0
+    assert serve["weights_version"] == train["checkpoint_iteration"]
+    assert serve["kernels_in_step"]["pallas_calls_in_jaxpr"] >= 1
+    assert serve["concurrent"] >= 2 and serve["drained"]
 
 
 @pytest.mark.slow  # ~40 s: two toy trainings on four virtual devices
@@ -120,8 +119,7 @@ def test_last_line_is_the_contract_object(monkeypatch, tmp_path, capsys):
     import chip_smoke
 
     tpu = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
-    phases = ["device", "kernels", "data", "train", "serve_slot",
-              "serve_paged"]
+    phases = ["device", "kernels", "data", "train", "serve"]
     monkeypatch.setattr(chip_smoke, "run_one_chip", _canned_run(phases, tpu))
     rc = chip_smoke.main(["--workdir", str(tmp_path / "w")])
     out = capsys.readouterr().out

@@ -2,7 +2,7 @@
 pool units, compressed ring-permute transport, and the engine parity
 gates — greedy traffic through the CP engine (chunked distributed
 prefill, sequence-striped paged KV, ring-attention decode) must be
-token-identical to the dense single-host engine, with logprob parity
+token-identical to the one-shot loop's on one device, with logprob parity
 and zero decode recompiles after warmup, through radix prefix hits and
 mid-prefill preempt/resume."""
 
@@ -15,7 +15,8 @@ from megatron_tpu.config import ParallelConfig
 from megatron_tpu.inference.context_parallel import (
     ContextParallelEngine, StripedPagePool,
 )
-from megatron_tpu.inference.engine import InferenceEngine, Request
+from megatron_tpu.inference.engine import Request
+from megatron_tpu.inference.generation import generate_tokens
 from megatron_tpu.inference.paging.pool import SCRATCH_PAGE
 from megatron_tpu.models import presets
 from megatron_tpu.models.params import init_params, param_specs
@@ -27,6 +28,21 @@ from megatron_tpu.quant.collectives import (
 
 CFG = presets.tiny(vocab_size=64, seq_length=64)
 PARAMS = init_params(CFG, jax.random.PRNGKey(0))
+
+
+class OneShot:
+    """The reference: `generate_tokens`' loop over a dense whole-row cache
+    on one device, which shares no engine code."""
+
+    def generate(self, prompts, lengths, max_new_tokens, temperature=0.0):
+        return generate_tokens(CFG, PARAMS, prompts, lengths,
+                               max_new_tokens=max_new_tokens,
+                               temperature=temperature)
+
+    def run(self, prompt, n=6):
+        from _engine_lookahead_cases import one_shot
+
+        return one_shot(CFG, PARAMS)(np.asarray(prompt, np.int32), n, {})
 
 
 # ---------------------------------------------------------------------------
@@ -144,7 +160,7 @@ def cp_setup():
     rt = build_mesh(ParallelConfig(context_parallel=2),
                     devices=jax.devices()[:2])
     sparams = shard_tree(rt, PARAMS, param_specs(CFG))
-    dense = InferenceEngine(CFG, PARAMS, num_slots=2, max_seq_len=64)
+    dense = OneShot()
     cpe = ContextParallelEngine(CFG, sparams, num_slots=2, max_seq_len=64,
                                 page_size=8, prefill_chunk=8, mesh=rt.mesh)
     return rt, dense, cpe
@@ -187,7 +203,7 @@ def test_cp_parity_radix_hit_mid_shard(cp_setup):
     hits0 = cpe.stats["prefix_hits"]
     got = _run(cpe, prefix + tail_b)
     assert cpe.stats["prefix_hits"] > hits0
-    want = _run(dense, prefix + tail_b)
+    want = dense.run(prefix + tail_b)
     assert got.generated == want.generated
     np.testing.assert_allclose(got.logprobs, want.logprobs,
                                rtol=1e-5, atol=1e-5)
@@ -211,7 +227,7 @@ def test_cp_parity_preempt_resume_mid_prefill(cp_setup):
     assert cpe.stats["preemptions"] == pre0 + 1
     cpe.run_until_idle()
     assert req.error is None, req.error
-    want = _run(dense, prompt, 6)
+    want = dense.run(prompt, 6)
     assert req.generated == want.generated
     np.testing.assert_allclose(req.logprobs, want.logprobs,
                                rtol=1e-5, atol=1e-5)
@@ -325,7 +341,7 @@ def test_cp_loc_tables_striping_and_invariant(cp_setup):
 
 # ---------------------------------------------------------------------------
 # geometry x transport parity matrix (ISSUE 20): every cell must stay
-# token-identical to the dense single-host engine through fresh ragged
+# token-identical to the one-shot reference through fresh ragged
 # traffic, radix prefix hits, and mid-prefill preempt/resume, with zero
 # decode recompiles. Dense transports also hold logprobs to 1e-5; int8
 # cells carry the ring/a2a quantization noise in the logprobs (bounded,
@@ -409,7 +425,7 @@ def test_cp_matrix_radix_hit_parity(cp_setup, matrix_cache, cell):
     hits0 = eng.stats["prefix_hits"]
     got = _run(eng, prefix + [40, 41, 42])
     assert eng.stats["prefix_hits"] > hits0
-    want = _run(dense, prefix + [40, 41, 42])
+    want = dense.run(prefix + [40, 41, 42])
     assert got.generated == want.generated
     np.testing.assert_allclose(got.logprobs, want.logprobs,
                                atol=_logprob_atol(cell), rtol=0)
@@ -430,7 +446,7 @@ def test_cp_matrix_preempt_resume_parity(cp_setup, matrix_cache, cell):
     assert eng._preempt_one()
     eng.run_until_idle()
     assert req.error is None, req.error
-    want = _run(dense, prompt, 6)
+    want = dense.run(prompt, 6)
     assert req.generated == want.generated
     np.testing.assert_allclose(req.logprobs, want.logprobs,
                                atol=_logprob_atol(cell), rtol=0)
@@ -556,7 +572,7 @@ def test_cp_lanes_service_dispatch_and_metrics():
     sp = shard_tree(rt, PARAMS, param_specs(CFG))
     svc = GenerationService(CFG, sp, NullTokenizer(CFG.vocab_size - 1),
                             mesh=rt.mesh, engine_slots=2,
-                            engine_max_seq_len=64, kv_paging=True,
+                            engine_max_seq_len=64,
                             page_size=8, prefill_chunk=8,
                             cp_serving=True, cp_lanes=2,
                             metrics=MetricsRegistry())

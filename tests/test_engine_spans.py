@@ -30,7 +30,6 @@ import pytest
 
 from megatron_tpu.inference import engine as engine_mod
 from megatron_tpu.inference.engine import InferenceEngine, Request
-from megatron_tpu.inference.paging import PagedInferenceEngine
 from megatron_tpu.models import presets
 from megatron_tpu.models.params import init_params
 from megatron_tpu.telemetry.journal import EventJournal, set_global_journal
@@ -52,7 +51,7 @@ def make_paged(**kw):
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
     kw.setdefault("metrics", MetricsRegistry())
-    return PagedInferenceEngine(CFG, PARAMS, **kw)
+    return InferenceEngine(CFG, PARAMS, **kw)
 
 
 def submit_all(eng, shapes=SHAPES, seed=0, prefix=""):
@@ -92,12 +91,12 @@ def check_identities(rec):
 
 @pytest.fixture(scope="module")
 def trace_dir(tmp_path_factory):
-    """A toy paged engine under load, captured through `capture_trace`."""
+    """A toy engine under load, captured through `capture_trace`."""
     # wide enough that a tick is milliseconds, as a served tick is: the
     # glue between the phases is microseconds whatever the model
     cfg = presets.tiny(vocab_size=64, seq_length=64, hidden_size=512,
                        ffn_hidden_size=2048, num_layers=4)
-    eng = PagedInferenceEngine(
+    eng = InferenceEngine(
         cfg, init_params(cfg, jax.random.PRNGKey(0)), num_slots=4,
         max_seq_len=64, page_size=8, prefill_chunk=8,
         metrics=MetricsRegistry())
@@ -153,7 +152,7 @@ def test_every_tick_is_a_step_marker_with_its_phases_in_order(traced):
     for tick in ticks:
         names = [ev.name for ev in top_spans(traced, tick)]
         # the five phases that dispatch, once each and in the order of
-        # PagedInferenceEngine._tick; then what _read_behind read
+        # InferenceEngine._tick; then what _read_behind read
         assert names[:5] == TOP_PHASES, names
         assert names[5:] and set(names[5:]) <= {engine_mod.READ,
                                                 engine_mod.APPLY}
@@ -232,15 +231,15 @@ def test_phase_counters_sum_to_the_loops_time(journal):
     assert last["evicted"] == 0
 
 
-def test_slot_and_speculative_engines_take_the_same_names():
+def test_default_geometry_and_speculative_engines_take_the_same_names():
     from megatron_tpu.inference.speculative import SpecConfig
 
-    slot = InferenceEngine(CFG, PARAMS, num_slots=2, max_seq_len=64,
-                           metrics=MetricsRegistry())
-    submit_all(slot, SHAPES[:3])
-    slot.run_until_idle()
-    assert {"pre", "admit", "prefill", "decode", "read", "apply",
-            "drain"} <= set(slot.stats["tick_phase_s"])
+    plain = InferenceEngine(CFG, PARAMS, num_slots=2, max_seq_len=64,
+                            metrics=MetricsRegistry())
+    submit_all(plain, SHAPES[:3])
+    plain.run_until_idle()
+    assert {"pre", "admit", "prefill", "pages", "decode", "read",
+            "apply"} <= set(plain.stats["tick_phase_s"])
     spec = InferenceEngine(
         CFG, PARAMS, num_slots=2, max_seq_len=64, metrics=MetricsRegistry(),
         speculative=SpecConfig(k=2, drafter="ngram"))
@@ -374,7 +373,7 @@ def server(journal):
 
     service = GenerationService(
         CFG, PARAMS, NullTokenizer(64), engine_slots=4, engine_max_seq_len=64,
-        kv_paging=True, page_size=8, prefill_chunk=8)
+        page_size=8, prefill_chunk=8)
     httpd = ThreadingHTTPServer(("127.0.0.1", 0), make_handler(service))
     thread = threading.Thread(target=httpd.serve_forever, daemon=True)
     thread.start()

@@ -1129,13 +1129,13 @@ def test_hung_replica_flips_readiness_not_liveness(tmp_path):
         rep.close()
 
 
-@pytest.mark.slow  # ~110s: paged-engine variant of the SIGKILL failover
-# (fleet logic proven against both engines, ISSUE satellite)
-def test_chaos_failover_paged_engine(tmp_path):
+@pytest.mark.slow  # ~110s: the SIGKILL failover at pages and chunks of 8
+# (prompts of several chunks, pages crossed in mid-decode)
+def test_chaos_failover_small_pages(tmp_path):
     r0 = _spawn(tmp_path, "r0", fault="kill_replica:20,slow_tick:30",
-                kv_paging=True, page_size=8, prefill_chunk=8)
+                page_size=8, prefill_chunk=8)
     r1 = _spawn(tmp_path, "r1", fault="slow_tick:30",
-                kv_paging=True, page_size=8, prefill_chunk=8)
+                page_size=8, prefill_chunk=8)
     router = None
     try:
         r0.wait_ready(timeout=300)

@@ -245,16 +245,16 @@ def test_a_bfloat16_state_fails_the_served_tolerance():
     assert float(jnp.abs(low - want).max()) > 10 * SERVED_TOLERANCE * top
 
 
-# --- (c), (e) the paged engine --------------------------------------------------
+# --- (c), (e) the engine --------------------------------------------------------
 
 def make_engine(cfg, params, **kw):
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
 
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq_len", 48)
     kw.setdefault("page_size", PAGE)
     kw.setdefault("prefill_chunk", CHUNK)
-    return PagedInferenceEngine(cfg, params, **kw)
+    return InferenceEngine(cfg, params, **kw)
 
 
 @pytest.fixture(scope="module")
@@ -406,14 +406,12 @@ def test_one_prompt_twice_is_no_prefix_hit(toy):
 
 
 def test_paths_that_cannot_carry_the_state_refuse_by_name(toy):
-    from megatron_tpu.inference.engine import InferenceEngine, Request
+    from megatron_tpu.inference.engine import Request
     from megatron_tpu.inference.speculative import SpecConfig
     from megatron_tpu.parallel.mesh import build_mesh
     from megatron_tpu.config import ParallelConfig
 
     cfg, params = toy
-    with pytest.raises(NotImplementedError, match="slot engine"):
-        InferenceEngine(cfg, params, num_slots=2, max_seq_len=48)
     with pytest.raises(NotImplementedError, match="no rollback"):
         make_engine(cfg, params, speculative=SpecConfig(k=2))
     rt = build_mesh(ParallelConfig(tensor_parallel=2))
@@ -438,6 +436,50 @@ def test_paths_that_cannot_carry_the_state_refuse_by_name(toy):
     with pytest.raises(NotImplementedError, match="pipeline stage"):
         run_layers(cfg, half, (jnp.zeros((1, 4, 32)), 0.0, None, None, None),
                    {cfg.attention_kind: None}, None)
+
+
+def test_a_server_with_nothing_set_serves_the_typed_stack(toy):
+    """No page size, no chunk, no flag: the service's engine holds the
+    state store beside its default pool, and the reply's greedy tokens are
+    those the engine at the toy's own geometry serves (before PR 63 the
+    default was an engine that refused the model by name)."""
+    from megatron_tpu.inference.server import GenerationService
+    from megatron_tpu.telemetry.metrics import MetricsRegistry
+    from megatron_tpu.tokenizer.tokenizer import NullTokenizer
+
+    cfg, params = toy
+    prompt = prompts(2)[1]
+    want = served_alone(cfg, params, prompt, 6)
+    service = GenerationService(
+        cfg, params, NullTokenizer(TOY["vocab_size"] - 1), engine_slots=2,
+        engine_max_seq_len=48, metrics=MetricsRegistry())
+    try:
+        eng = service.engine
+        assert eng.state is not None
+        assert (eng.page_size, eng.prefill_chunk, eng.num_pages) == (16, 32, 7)
+        out = service.handle({"prompts": [" ".join(map(str, prompt))],
+                              "tokens_to_generate": 6, "top_k": 1})
+        assert out["text"][0].split()[len(prompt):] == [
+            str(t) for t in want.generated]
+        assert eng.stats["state_resets"] == 1
+    finally:
+        service.shutdown()
+
+
+def test_the_one_shot_loops_refuse_a_typed_stack(toy):
+    """`generate_tokens` and beam search carry keys and values alone: before
+    they refused, a model with state-space layers got every token after
+    its first from a zeroed recurrent state, and no error."""
+    from megatron_tpu.inference.generation import (
+        beam_search_tokens, generate_tokens)
+
+    cfg, params = toy
+    prompt = prompts(1)[0]
+    with pytest.raises(NotImplementedError, match="no recurrent state"):
+        generate_tokens(cfg, params, prompt[None], np.asarray([len(prompt)]),
+                        max_new_tokens=4, temperature=0.0)
+    with pytest.raises(NotImplementedError, match="no recurrent state"):
+        beam_search_tokens(cfg, params, prompt, 4, beam_size=2, eod=0)
 
 
 # --- (d) the kernel against the plain form ---------------------------------------

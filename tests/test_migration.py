@@ -7,8 +7,8 @@ Pins the lossless-under-churn contract from the engine up:
     rejected loudly (MigrationIntegrityError), never half-imported;
   * mid-flight export/import is token-identical to an uninterrupted solo
     run — greedy AND sampled (the per-request PRNG chain resumes at the
-    exported absolute position), on dense and paged engines, across
-    geometry changes, with int8 KV caches, and mid-speculation;
+    exported absolute position), across page-geometry changes, with
+    int8 KV caches, and mid-speculation;
   * lossy wire codecs and sliding-window page release (no exact KV left
     to ship) degrade to recompute-resume and STAY exact;
   * export_all_requests atomically empties the engine (the SIGTERM drain
@@ -37,23 +37,21 @@ from megatron_tpu.inference.fleet.migration import (
 from megatron_tpu.inference.fleet.router import (
     ReplicaRouter, fleet_retry_after,
 )
-from megatron_tpu.inference.paging import PagedInferenceEngine
 from megatron_tpu.models import presets
 from megatron_tpu.models.params import init_params
 from megatron_tpu.telemetry import MetricsRegistry
+from test_serving_engine import _fake_steps
 
 CFG = presets.tiny(vocab_size=64, seq_length=64)
 PARAMS = init_params(CFG, jax.random.PRNGKey(0))
 PROMPT = np.array([3, 7, 11, 2, 9], np.int32)
 
 
-def mk(paged=False, **kw):
+def mk(**kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq_len", 64)
-    if paged:
-        kw.setdefault("page_size", 8)
-        kw.setdefault("prefill_chunk", 8)
-        return PagedInferenceEngine(CFG, PARAMS, **kw)
+    kw.setdefault("page_size", 8)
+    kw.setdefault("prefill_chunk", 8)
     return InferenceEngine(CFG, PARAMS, **kw)
 
 
@@ -146,7 +144,7 @@ def test_wire_torn_and_corrupt_rejected():
 
 @pytest.mark.slow  # ~13s: six compiled tiny engines; tier-1 keeps the
 # wire-format + fake-model scheduler coverage (the 870s budget is tight)
-def test_dense_migration_token_identity_greedy_and_sampled():
+def test_migration_token_identity_greedy_and_sampled():
     """Interrupt at tick 4 of 12, ship over the wire, resume elsewhere:
     byte-identical output for greedy AND sampled (seeded PRNG chain
     resumes at the exported absolute position), via direct KV import."""
@@ -189,20 +187,20 @@ def test_int8_kv_cache_migration_token_identity():
     assert path == "kv_import" and got == want
 
 
-@pytest.mark.slow  # ~20s: six compiled engines (paged prefill is chunked)
-def test_paged_and_cross_geometry_migration():
-    """Paged->paged keeps pool accounting honest; dense->paged and
-    paged->dense both resume token-identically (the canonical wire
-    layout is geometry-free)."""
-    want = run_solo(0.8, paged=True)
-    src = mk(paged=True)
+@pytest.mark.slow  # ~20s: six compiled engines
+def test_pool_accounting_and_cross_geometry_migration():
+    """A migration keeps pool accounting honest; pages of 8 -> pages of
+    16 and back both resume token-identically (the canonical wire layout
+    is geometry-free)."""
+    want = run_solo(0.8)
+    src = mk()
     r = Request(prompt=PROMPT.copy(), max_new_tokens=12,
                 temperature=0.8, seed=5)
     src.submit(r)
     for _ in range(6):
         src.step()
     meta, sections = unpack_state(pack_state(*src.export_request_state(r)))
-    dst = mk(paged=True)
+    dst = mk()
     free0 = dst.pool.free_pages
     req2, path = dst.import_request_state(meta, sections)
     assert path == "kv_import"
@@ -213,10 +211,9 @@ def test_paged_and_cross_geometry_migration():
     # retirement returned the decode pages (radix may hold prompt pages)
     assert dst.pool.free_pages >= free0 - 1
 
-    want_dense = run_solo(0.8)
-    got, _ = mid_export(0.8, 4, {}, {"paged": True})
-    assert got == want_dense
-    got, _ = mid_export(0.8, 6, {"paged": True}, {})
+    got, _ = mid_export(0.8, 4, {"page_size": 16}, {})
+    assert got == want
+    got, _ = mid_export(0.8, 6, {}, {"page_size": 16})
     assert got == want
 
 
@@ -231,9 +228,8 @@ def test_sliding_window_release_migrates_via_recompute():
     params = init_params(cfg, jax.random.PRNGKey(0))
 
     def mkw():
-        return PagedInferenceEngine(cfg, params, num_slots=2,
-                                    max_seq_len=128, page_size=8,
-                                    prefill_chunk=16)
+        return InferenceEngine(cfg, params, num_slots=2, max_seq_len=128,
+                               page_size=8, prefill_chunk=16)
 
     prompt = np.arange(1, 13, dtype=np.int32)
     solo = mkw()
@@ -275,28 +271,6 @@ def test_mid_speculation_migration_token_identity():
 
 # ---------------------------------------------------------------------------
 # drain primitive: atomic export of everything in flight
-
-
-def _fake_steps(eng, V=64):
-    """Deterministic fake model (test_serving_engine idiom): every step
-    emits (last_token + 1) % V — scheduler logic without XLA compiles."""
-    import jax.numpy as jnp
-
-    def fake_prefill(P):
-        def fn(params, caches, tokens, length, slot, key, temp, top_k,
-               top_p):
-            tok = (tokens[0, length - 1] + 1) % V
-            plp = jnp.zeros((tokens.shape[1] - 1,), jnp.float32)
-            return tok, jnp.float32(-1.0), plp, caches, key
-        return fn
-
-    def fake_decode(params, caches, last, lengths, keys, temps, tks, tps):
-        return ((last + 1) % V, jnp.full(last.shape, -1.0, jnp.float32),
-                caches, keys, lengths + 1)
-
-    eng._prefill_step = fake_prefill
-    eng._decode_step = fake_decode
-    return eng
 
 
 def test_export_all_requests_empties_engine():
@@ -357,11 +331,11 @@ def test_export_all_then_import_resumes_on_fake_model():
 # fleet-level prefix directory
 
 
-@pytest.mark.slow  # ~10s: two compiled paged engines
+@pytest.mark.slow  # ~10s: two compiled engines
 def test_prefix_export_import_cross_replica():
     """A system prompt primed on A becomes a radix hit on B after page
     export/import — and B's follower answer is token-identical to A's."""
-    a = mk(paged=True, num_slots=2)
+    a = mk(num_slots=2)
     sys_prompt = np.arange(1, 17, dtype=np.int32)  # two full pages
     lens = np.array([16], np.int32)
     ref = a.generate(sys_prompt[None, :], lens, max_new_tokens=8)
@@ -370,7 +344,7 @@ def test_prefix_export_import_cross_replica():
     meta, sections = exported
     assert meta["kind"] == "prefix"
     meta, sections = unpack_state(pack_state(meta, sections))
-    b = mk(paged=True, num_slots=2)
+    b = mk(num_slots=2)
     pages = b.import_prefix_state(meta, sections)
     assert pages >= 1
     hits0 = b.stats["prefix_hits"]

@@ -283,7 +283,7 @@ def test_the_context_parallel_engine_tells_its_rows_from_the_ranks_tables():
     from megatron_tpu.config import ParallelConfig
     from megatron_tpu.inference.context_parallel import ContextParallelEngine
     from megatron_tpu.inference.engine import Request
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
     from megatron_tpu.models import presets
     from megatron_tpu.models.params import init_params, param_specs
     from megatron_tpu.parallel.mesh import build_mesh
@@ -304,7 +304,7 @@ def test_the_context_parallel_engine_tells_its_rows_from_the_ranks_tables():
         assert [r.error for r in reqs] == [None] * 2
         return [r.generated for r in reqs]
 
-    flat = serve(PagedInferenceEngine(cfg, params, **geometry))
+    flat = serve(InferenceEngine(cfg, params, **geometry))
     ranks = serve(ContextParallelEngine(
         cfg, shard_tree(rt, params, param_specs(cfg)), mesh=rt.mesh,
         **geometry))

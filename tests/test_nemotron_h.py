@@ -445,13 +445,13 @@ def test_a_lower_precision_or_a_wrong_router_fails(variant):
 # --- the paged engine ------------------------------------------------------------
 
 def make_engine(cfg, params, **kw):
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
 
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq_len", 48)
     kw.setdefault("page_size", PAGE)
     kw.setdefault("prefill_chunk", CHUNK)
-    return PagedInferenceEngine(cfg, params, **kw)
+    return InferenceEngine(cfg, params, **kw)
 
 
 def test_the_engine_serves_what_the_reference_computes(toy):

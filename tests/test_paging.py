@@ -266,19 +266,19 @@ def test_decode_work_is_the_live_context(shape, in_use, sq):
         assert visited == 0
 
 
-@pytest.mark.parametrize("engine", ["paged", "slot", "paged-window",
+@pytest.mark.parametrize("engine", ["paged", "one-page", "paged-window",
                                     "paged-spec"])
 def test_engine_reports_the_live_block_share(engine):
     """`engine_decode_live_block_share`, set before every decode tick
     from the host's lengths, is what the kernel's loop bounds give over
     the blocks the table holds: checked tick by tick against the block
-    predicate, on both engines. A decoding row reaches the kernel with
+    predicate, at pages of 8 and at one page a sequence. A decoding row
+    reaches the kernel with
     its new token written (length + 1), an idle one with length 1 (the
     layer hands the kernel `cache_index + 1`): one block."""
     import jax
 
     from megatron_tpu.inference.engine import InferenceEngine
-    from megatron_tpu.inference.paging import PagedInferenceEngine
     from megatron_tpu.ops.pallas import flash_template as ft
     from megatron_tpu.ops.pallas import masks
     from megatron_tpu.models import presets
@@ -295,16 +295,10 @@ def test_engine_reports_the_live_block_share(engine):
         from megatron_tpu.inference.speculative import SpecConfig
 
         spec = SpecConfig(drafter="ngram", k=2)
-    if engine == "slot":
-        eng = InferenceEngine(cfg, params, num_slots=3, max_seq_len=128,
-                              metrics=reg)
-        entries, ps = 1, 128
-    else:
-        eng = PagedInferenceEngine(cfg, params, num_slots=3,
-                                   max_seq_len=128, page_size=8,
-                                   prefill_chunk=16, metrics=reg,
-                                   speculative=spec)
-        entries, ps = 16, 8
+    entries, ps = (1, 128) if engine == "one-page" else (16, 8)
+    eng = InferenceEngine(cfg, params, num_slots=3, max_seq_len=128,
+                          page_size=ps, prefill_chunk=16, metrics=reg,
+                          speculative=spec)
     sq = 1 if spec is None else spec.k + 1
     unit, units, _, n_blocks = ft._decode_geometry(entries, ps,
                                                    cfg.n_kv_heads)
@@ -346,7 +340,7 @@ def test_engine_reports_the_prefill_live_block_share(window):
     journal's `serve_ticks`."""
     import jax
 
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
     from megatron_tpu.models import presets
     from megatron_tpu.models.params import init_params
     from megatron_tpu.ops.pallas import flash_template as ft
@@ -360,7 +354,7 @@ def test_engine_reports_the_prefill_live_block_share(window):
     params = init_params(cfg, jax.random.PRNGKey(0))
     reg = MetricsRegistry()
     chunk, ps, entries = 64, 8, 64
-    eng = PagedInferenceEngine(cfg, params, num_slots=2, max_seq_len=512,
+    eng = InferenceEngine(cfg, params, num_slots=2, max_seq_len=512,
                                page_size=ps, prefill_chunk=chunk,
                                metrics=reg)
     groups = cfg.num_attention_heads // cfg.n_kv_heads
@@ -463,7 +457,7 @@ def test_attention_page_table_gather_matches_dense():
 
 def test_sliding_window_release_parity_and_accounting():  # ~5s measured
     """Pages fully behind the attention window return to the pool while
-    the request still decodes — token-identical to the slot engine
+    the request still decodes — token-identical to the one-shot loop
     (masked positions contribute exactly nothing, so reading the
     scratch page in their place changes no value), with honest pool
     accounting: released pages are re-allocatable, radix-held prompt
@@ -472,21 +466,21 @@ def test_sliding_window_release_parity_and_accounting():  # ~5s measured
     import jax
 
     from megatron_tpu.inference.engine import InferenceEngine
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.generation import generate_tokens
     from megatron_tpu.models import presets
     from megatron_tpu.models.params import init_params
 
     cfg = presets.tiny(vocab_size=64, seq_length=128, num_layers=2,
                        sliding_window_size=16)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    slot = InferenceEngine(cfg, params, num_slots=2, max_seq_len=128)
-    paged = PagedInferenceEngine(cfg, params, num_slots=2,
+    paged = InferenceEngine(cfg, params, num_slots=2,
                                  max_seq_len=128, page_size=8,
                                  prefill_chunk=16)
     rng = np.random.default_rng(0)
     prompts = rng.integers(1, 64, (2, 12)).astype(np.int32)
     lengths = np.full((2,), 12, np.int32)
-    a = slot.generate(prompts, lengths, max_new_tokens=60)
+    a = generate_tokens(cfg, params, prompts, lengths, max_new_tokens=60,
+                        temperature=0.0)
     b = paged.generate(prompts, lengths, max_new_tokens=60)
     np.testing.assert_array_equal(a.tokens, b.tokens)
     np.testing.assert_allclose(a.logprobs, b.logprobs, atol=1e-5)
@@ -514,13 +508,13 @@ def test_window_release_noop_without_window():
     the counter stays zero (the pre-existing lifetime story holds)."""
     import jax
 
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
     from megatron_tpu.models import presets
     from megatron_tpu.models.params import init_params
 
     cfg = presets.tiny(vocab_size=64, seq_length=64, num_layers=2)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    paged = PagedInferenceEngine(cfg, params, num_slots=1,
+    paged = InferenceEngine(cfg, params, num_slots=1,
                                  max_seq_len=64, page_size=8,
                                  prefill_chunk=16)
     prompts = np.arange(1, 9, dtype=np.int32)[None]
@@ -535,20 +529,20 @@ def test_window_release_noop_without_window():
 def test_paged_engine_rejects_undersized_pool():
     import jax
 
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
     from megatron_tpu.models import presets
     from megatron_tpu.models.params import init_params
 
     cfg = presets.tiny(vocab_size=64, seq_length=64)
     params = init_params(cfg, jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="cannot hold even one"):
-        PagedInferenceEngine(cfg, params, num_slots=2, max_seq_len=64,
+        InferenceEngine(cfg, params, num_slots=2, max_seq_len=64,
                              page_size=8, num_pages=4)
     with pytest.raises(ValueError, match="num_pages"):
-        PagedInferenceEngine(cfg, params, num_slots=1, max_seq_len=64,
+        InferenceEngine(cfg, params, num_slots=1, max_seq_len=64,
                              page_size=8, num_pages=1)
     with pytest.raises(ValueError, match="page_size"):
-        PagedInferenceEngine(cfg, params, num_slots=1, max_seq_len=64,
+        InferenceEngine(cfg, params, num_slots=1, max_seq_len=64,
                              page_size=0)
 
 
@@ -570,7 +564,7 @@ def test_pages_in_the_parents_layout_export_and_install(int8):
     from megatron_tpu.inference.fleet.migration import (
         pack_state, unpack_state,
     )
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
     from megatron_tpu.models import presets
     from megatron_tpu.models.params import init_params
 
@@ -578,7 +572,7 @@ def test_pages_in_the_parents_layout_export_and_install(int8):
     params = init_params(cfg, jax.random.PRNGKey(0))
 
     def mk():
-        return PagedInferenceEngine(cfg, params, num_slots=2, max_seq_len=32,
+        return InferenceEngine(cfg, params, num_slots=2, max_seq_len=32,
                                     page_size=4, prefill_chunk=4,
                                     num_pages=9, kv_cache_int8=int8)
 
@@ -643,11 +637,11 @@ def test_a_dry_pool_reads_the_tick_in_flight_then_preempts():
     included, and every page comes back."""
     import _engine_lookahead_cases as cases
 
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
 
     cfg, params = _tiny()
     # 24 positions a sequence = 6 pages of 4; 11 hold fewer than two whole
-    eng = PagedInferenceEngine(cfg, params, num_slots=3, max_seq_len=32,
+    eng = InferenceEngine(cfg, params, num_slots=3, max_seq_len=32,
                                page_size=4, prefill_chunk=8, num_pages=12)
     wants = cases.expected(cases.one_shot(cfg, params), cfg.vocab_size,
                            sampled=True)
@@ -670,7 +664,7 @@ def test_paused_exports_a_request_the_loop_had_a_tick_in_flight_for():
     from megatron_tpu.inference.fleet.migration import (
         pack_state, unpack_state,
     )
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    from megatron_tpu.inference.engine import InferenceEngine
 
     cfg, params = _tiny()
     prompt = np.asarray([3, 7, 11, 2, 9], np.int32)
@@ -678,7 +672,7 @@ def test_paused_exports_a_request_the_loop_had_a_tick_in_flight_for():
     want = one_shot(cfg, params)(prompt, 40, knobs)
 
     def make():
-        return PagedInferenceEngine(cfg, params, num_slots=2,
+        return InferenceEngine(cfg, params, num_slots=2,
                                     max_seq_len=64, page_size=8,
                                     prefill_chunk=8)
 

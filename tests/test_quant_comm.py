@@ -388,10 +388,9 @@ def test_compressed_engine_serves_with_zero_recompiles(tp_setup):
     t0 = comp.stats["comm_compressed_bytes"]
     comp.generate(prompts[:1], lengths[:1], max_new_tokens=3)
     delta = comp.stats["comm_compressed_bytes"] - t0
-    # 2 decode ticks (first token comes from prefill) + one P=64-bucket
-    # prefill pass
+    # 2 decode ticks (first token comes from prefill) + one prefill chunk
     pre = forward_comm_bytes(cfg, comp.tp_comm, 1,
-                             comp._bucket(8))["compressed"]
+                             comp.prefill_chunk)["compressed"]
     assert delta == 2 * want["compressed"] + pre, (delta, want, pre)
 
 
@@ -433,19 +432,18 @@ def test_comm_policy_journal_and_report(tp_setup, tmp_path):
     assert "compressed collectives (int8" in rendered
 
 
-@pytest.mark.slow  # ~15s: compiles a paged chunk + decode step on a mesh
-def test_paged_compressed_engine(tp_setup):
-    """The flag reaches the paged engine: chunk-prefill and decode both
+@pytest.mark.slow  # ~15s: compiles a chunk + decode step on a mesh
+def test_compressed_engine_at_small_pages(tp_setup):
+    """Pages of 8 under chunks of 16: chunk-prefill and decode both
     route the compressed collectives, greedy first token agrees with
-    the paged dense engine, zero recompiles, counters advance."""
-    from megatron_tpu.inference.paging import PagedInferenceEngine
+    the dense engine, zero recompiles, counters advance."""
+    from megatron_tpu.inference.engine import InferenceEngine
 
     cfg, rt, sparams, _, _ = tp_setup
     kw = dict(num_slots=2, max_seq_len=32, page_size=8, prefill_chunk=16,
               mesh=rt.mesh)
-    dense = PagedInferenceEngine(cfg, sparams, **kw)
-    comp = PagedInferenceEngine(cfg, sparams, **kw,
-                                compress_collectives="int8")
+    dense = InferenceEngine(cfg, sparams, **kw)
+    comp = InferenceEngine(cfg, sparams, **kw, compress_collectives="int8")
     rng = np.random.default_rng(2)
     prompts = rng.integers(1, cfg.vocab_size, (2, 8)).astype(np.int32)
     lengths = np.full((2,), 8, np.int32)

@@ -34,7 +34,6 @@ from megatron_tpu.inference.engine import (
     EngineOverloadedError, InferenceEngine, Request,
 )
 from megatron_tpu.inference.generation import generate_tokens
-from megatron_tpu.inference.paging import PagedInferenceEngine
 from megatron_tpu.inference.sampling import sample_logits, sample_logits_batched
 from megatron_tpu.models import presets
 from megatron_tpu.models.params import init_params
@@ -47,15 +46,9 @@ PARAMS = init_params(CFG, jax.random.PRNGKey(0))
 def make_engine(cfg=CFG, **kw):
     kw.setdefault("num_slots", 4)
     kw.setdefault("max_seq_len", 64)
-    return InferenceEngine(cfg, PARAMS, **kw)
-
-
-def make_paged(cfg=CFG, **kw):
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("max_seq_len", 64)
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
-    return PagedInferenceEngine(cfg, PARAMS, **kw)
+    return InferenceEngine(cfg, PARAMS, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -63,21 +56,25 @@ def make_paged(cfg=CFG, **kw):
 
 
 def _fake_steps(eng, V=64):
-    """Deterministic fake model: every step emits (last_token + 1) % V."""
+    """Deterministic fake model behind the engine's two programs: every
+    step emits (last_token + 1) % V, a prompt's first token is its last
+    token + 1."""
+    C = eng.prefill_chunk
 
-    def fake_prefill(P):
-        def fn(params, caches, tokens, length, slot, key, temp, top_k,
-               top_p):
-            tok = (tokens[0, length - 1] + 1) % V
-            plp = jnp.zeros((tokens.shape[1] - 1,), jnp.float32)
-            return tok, jnp.float32(-1.0), plp, caches, key
-        return fn
+    def fake_chunk(params, caches, state, table_row, tokens_ext, off,
+                   write_start, write_end, sample_pos, key, temp, top_k,
+                   top_p, slot=None):
+        at = int(np.clip(int(sample_pos) - int(off), 0, C - 1))
+        tok = (jnp.asarray(tokens_ext)[0, at] + 1) % V
+        return (tok, jnp.float32(-1.0), jnp.zeros((C,), jnp.float32),
+                caches, state, jnp.asarray(key))
 
-    def fake_decode(params, caches, last, lengths, keys, temps, tks, tps):
+    def fake_decode(params, caches, state, table, last, lengths, keys,
+                    temps, tks, tps):
         return ((last + 1) % V, jnp.full(last.shape, -1.0, jnp.float32),
-                caches, keys, lengths + 1)
+                caches, state, keys, lengths + 1)
 
-    eng._prefill_step = fake_prefill
+    eng._chunk_step = fake_chunk
     eng._decode_step = fake_decode
     return eng
 
@@ -170,22 +167,6 @@ def test_engine_greedy_parity_single_request():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_engine_greedy_parity_ragged_batch():
-    """generate_tokens runs EVERY row of a ragged batch to
-    maxp + max_new; the engine's batch API must match so flipping a
-    server between engine and one-shot mode never changes a response."""
-    prompts = np.asarray([[3, 7, 11, 2], [5, 0, 0, 0]], np.int32)
-    lengths = np.asarray([4, 1], np.int32)
-    want = generate_tokens(CFG, PARAMS, prompts, lengths, max_new_tokens=6,
-                           temperature=0.0)
-    got = make_engine().generate(prompts, lengths, max_new_tokens=6,
-                                 temperature=0.0)
-    np.testing.assert_array_equal(got.tokens, want.tokens)
-    np.testing.assert_array_equal(got.lengths, want.lengths)
-    np.testing.assert_allclose(got.logprobs, want.logprobs,
-                               rtol=1e-5, atol=1e-5)
-
-
 def test_engine_greedy_parity_with_eod():
     # pick the greedy-next token after [3] as eod so the engine must stop
     from megatron_tpu.models.language_model import lm_forward
@@ -200,50 +181,6 @@ def test_engine_greedy_parity_with_eod():
                                  temperature=0.0, eod=eod)
     assert int(got.lengths[0]) == int(want.lengths[0]) == 2
     np.testing.assert_array_equal(got.tokens[0, :2], want.tokens[0, :2])
-
-
-@pytest.mark.slow  # 11s measured cacheless (PR 4 tier-1 re-budget);
-# greedy/int8/ragged parity tests keep engine coverage in tier-1
-def test_interleaved_traffic_parity():
-    """A request's tokens must not change when other slots are active —
-    greedy AND sampled (per-slot PRNG chains)."""
-    promptA = np.asarray([3, 7, 11], np.int32)
-    sampledB = dict(prompt=np.asarray([5], np.int32), max_new_tokens=16,
-                    temperature=0.8, top_k=5, seed=7)
-
-    # solo runs
-    eng = make_engine()
-    a_solo = eng.submit(Request(prompt=promptA, max_new_tokens=10))
-    eng.run_until_idle()
-    eng = make_engine()
-    b_solo = eng.submit(Request(**sampledB))
-    eng.run_until_idle()
-
-    # staggered interleaved traffic: B starts first, A and C join mid-run
-    eng = make_engine()
-    b_mix = eng.submit(Request(**sampledB))
-    eng.step()
-    eng.step()
-    a_mix = eng.submit(Request(prompt=promptA, max_new_tokens=10))
-    c = eng.submit(Request(prompt=np.asarray([9, 2], np.int32),
-                           max_new_tokens=5, temperature=1.2, top_p=0.9,
-                           seed=3))
-    eng.run_until_idle()
-
-    assert a_mix.generated == a_solo.generated
-    assert b_mix.generated == b_solo.generated
-    assert c.done.is_set() and len(c.generated) == 5
-
-
-def test_engine_int8_cache_parity():
-    """Quantized-cache engine mode matches the one-shot int8 path."""
-    prompts = np.asarray([[3, 7, 11, 2]], np.int32)
-    lengths = np.asarray([4], np.int32)
-    want = generate_tokens(CFG, PARAMS, prompts, lengths, max_new_tokens=6,
-                           temperature=0.0, kv_cache_int8=True)
-    got = make_engine(kv_cache_int8=True).generate(
-        prompts, lengths, max_new_tokens=6, temperature=0.0)
-    np.testing.assert_array_equal(got.tokens, want.tokens)
 
 
 def test_slot_reuse_does_not_leak_stale_cache():
@@ -267,13 +204,13 @@ def test_slot_reuse_does_not_leak_stale_cache():
 
 
 # ---------------------------------------------------------------------------
-# paged engine parity matrix (inference/paging/): token-identical to the
-# slot engine on the same traffic, zero decode recompiles after warmup
+# parity matrix on both of attention's paths: token-identical to the
+# one-shot loop on the same traffic, zero decode recompiles after warmup
 
 
 @pytest.fixture(params=["dense", "interpreted"])
 def attention_path(request, monkeypatch):
-    """The paged engine's parity tests on both of attention's paths: the
+    """The engine's parity tests on both of attention's paths: the
     dense one a CPU host runs, and the kernels forced through the
     interpreter (`interpret_forced`), where a prefill chunk runs
     `paged_flash_chunk` and a decode tick `paged_flash_decode`, as on the
@@ -295,7 +232,7 @@ def test_paged_engine_greedy_parity_multi_chunk(attention_path):
     want = generate_tokens(attention_path, PARAMS, prompts, lengths,
                            max_new_tokens=8, temperature=0.0)
     # chunk 4 < prompt 10 < 2 pages: 3 chunks, page-spanning writes
-    eng = make_paged(attention_path, prefill_chunk=4)
+    eng = make_engine(attention_path, prefill_chunk=4)
     got = eng.generate(prompts, lengths, max_new_tokens=8, temperature=0.0)
     np.testing.assert_array_equal(got.tokens, want.tokens)
     np.testing.assert_allclose(got.logprobs, want.logprobs,
@@ -309,7 +246,7 @@ def test_paged_engine_ragged_batch_parity(attention_path):
     lengths = np.asarray([4, 1], np.int32)
     want = generate_tokens(attention_path, PARAMS, prompts, lengths,
                            max_new_tokens=6, temperature=0.0)
-    got = make_paged(attention_path).generate(
+    got = make_engine(attention_path).generate(
         prompts, lengths, max_new_tokens=6, temperature=0.0)
     np.testing.assert_array_equal(got.tokens, want.tokens)
     np.testing.assert_array_equal(got.lengths, want.lengths)
@@ -325,7 +262,7 @@ def test_paged_engine_int8_cache_parity(attention_path):
     want = generate_tokens(attention_path, PARAMS, prompts, lengths,
                            max_new_tokens=6, temperature=0.0,
                            kv_cache_int8=True)
-    got = make_paged(attention_path, kv_cache_int8=True).generate(
+    got = make_engine(attention_path, kv_cache_int8=True).generate(
         prompts, lengths, max_new_tokens=6, temperature=0.0)
     np.testing.assert_array_equal(got.tokens, want.tokens)
 
@@ -339,16 +276,15 @@ def test_paged_prefix_cache_hit_parity(attention_path):
     p1 = np.concatenate([shared, [7, 3]]).astype(np.int32)
     p2 = np.concatenate([shared, [9, 5, 2]]).astype(np.int32)
 
-    def run(eng, prompt):
-        r = eng.submit(Request(prompt=prompt, max_new_tokens=6))
-        eng.run_until_idle()
-        assert r.error is None, r.error
-        return r
+    from _engine_lookahead_cases import one_shot
 
-    slot = make_engine(attention_path)
-    paged = make_paged(attention_path)
+    alone = one_shot(attention_path, PARAMS)
+    paged = make_engine(attention_path)
     for prompt in (p1, p2):
-        a, b = run(slot, prompt), run(paged, prompt)
+        a = alone(prompt, 6, {})
+        b = paged.submit(Request(prompt=prompt, max_new_tokens=6))
+        paged.run_until_idle()
+        assert b.error is None, b.error
         assert a.generated == b.generated
         np.testing.assert_allclose(a.prompt_logprobs, b.prompt_logprobs,
                                    rtol=1e-5, atol=1e-5)
@@ -375,7 +311,7 @@ def test_paged_preemption_midstream_parity(attention_path):
     sampled = dict(temperature=0.7, top_k=8, seed=5)
 
     def solo(prompt, **skw):
-        eng = make_paged(attention_path, **kw)
+        eng = make_engine(attention_path, **kw)
         r = eng.submit(Request(prompt=prompt, max_new_tokens=16, **skw))
         eng.run_until_idle()
         assert r.error is None, r.error
@@ -386,7 +322,7 @@ def test_paged_preemption_midstream_parity(attention_path):
     # 9 usable pages of 4 (4 of 8) can't hold both sequences at full
     # length (6 pages each; 3): B (younger) gets preempted, A finishes, B
     # resumes
-    eng = make_paged(attention_path, num_pages=40 // page, **kw)
+    eng = make_engine(attention_path, num_pages=40 // page, **kw)
     ra = eng.submit(Request(prompt=pa, max_new_tokens=16))
     rb = eng.submit(Request(prompt=pb, max_new_tokens=16, **sampled))
     eng.run_until_idle()
@@ -402,8 +338,8 @@ def test_paged_preemption_midstream_parity(attention_path):
     assert eng.pool.used_pages == len(eng.prefix_cache)
 
 
-@pytest.mark.slow  # ~15s measured cacheless (mirrors the slot engine's
-# interleaved test); greedy/int8/prefix/preemption parity stay tier-1
+@pytest.mark.slow  # ~15s measured cacheless;
+# greedy/int8/prefix/preemption parity stay tier-1
 def test_paged_interleaved_traffic_parity():
     """Paged engine: a request's tokens must not change when other slots
     are active — greedy AND sampled (per-slot PRNG chains survive the
@@ -412,14 +348,14 @@ def test_paged_interleaved_traffic_parity():
     sampledB = dict(prompt=np.asarray([5], np.int32), max_new_tokens=16,
                     temperature=0.8, top_k=5, seed=7)
 
-    eng = make_paged()
+    eng = make_engine()
     a_solo = eng.submit(Request(prompt=promptA, max_new_tokens=10))
     eng.run_until_idle()
-    eng = make_paged()
+    eng = make_engine()
     b_solo = eng.submit(Request(**sampledB))
     eng.run_until_idle()
 
-    eng = make_paged()
+    eng = make_engine()
     b_mix = eng.submit(Request(**sampledB))
     eng.step()
     eng.step()
@@ -438,7 +374,7 @@ def test_paged_interleaved_traffic_parity():
 def test_paged_chunked_prefill_interleaves_with_decode():
     """A long prompt enters the cache one chunk per tick while an active
     request keeps decoding — chunked prefill can't stall the batch."""
-    eng = make_paged(prefill_chunk=4, max_seq_len=64)
+    eng = make_engine(prefill_chunk=4, max_seq_len=64)
     a = eng.submit(Request(prompt=np.asarray([3, 7], np.int32),
                            max_new_tokens=20))
     # admit A and give it a couple of ticks
@@ -461,38 +397,26 @@ def test_paged_chunked_prefill_interleaves_with_decode():
 
 
 # ---------------------------------------------------------------------------
-# satellite: max_seq_len rounding (the silent flash-decode fallback fix)
+# satellite: max_seq_len is whole pages
 
 
-def test_engine_max_seq_len_rounds_to_kernel_multiple(monkeypatch):
-    """When the TPU kernel path is active, a max_seq_len not divisible by
-    128 is rounded UP (with a warning) instead of silently running the
-    dense fallback every tick."""
-    monkeypatch.setattr(InferenceEngine, "_kernel_seq_multiple",
-                        lambda self: 128)
-    with pytest.warns(UserWarning, match="rounding"):
-        eng = make_engine(max_seq_len=200)
-    assert eng.max_seq_len == 256
-    # oversized-request validation uses the rounded value
-    r = eng.submit(Request(prompt=np.asarray([1] * 250, np.int32),
-                           max_new_tokens=10))
-    assert r.error and "256" in r.error
-
-
-def test_engine_max_seq_len_no_rounding_on_cpu():
-    """CPU hosts interpret the kernel: no constraint, no warning."""
+def test_engine_max_seq_len_of_whole_pages_is_kept():
     import warnings as w
 
     with w.catch_warnings():
         w.simplefilter("error")
-        eng = make_engine(max_seq_len=100)
-    assert eng.max_seq_len == 100
+        eng = make_engine(max_seq_len=96)
+    assert eng.max_seq_len == 96 and eng.max_pages == 12
 
 
-def test_paged_engine_rounds_to_page_multiple():
+def test_engine_rounds_max_seq_len_to_page_multiple():
     with pytest.warns(UserWarning, match="rounding"):
-        eng = make_paged(max_seq_len=60, page_size=8)
+        eng = make_engine(max_seq_len=60, page_size=8)
     assert eng.max_seq_len == 64 and eng.max_pages == 8
+    # oversized-request validation uses the rounded value
+    r = eng.submit(Request(prompt=np.asarray([1] * 60, np.int32),
+                           max_new_tokens=10))
+    assert r.error and "64" in r.error
 
 
 # ---------------------------------------------------------------------------
@@ -802,39 +726,15 @@ def test_offered_load_throughput_scales_with_slots():
 
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "seeded"])
-@pytest.mark.parametrize("make", [make_engine, make_paged],
-                         ids=["slot", "paged"])
-def test_a_tick_in_flight_changes_no_token(make, sampled):
+def test_a_tick_in_flight_changes_no_token(sampled):
     """Staggered admissions, ends by eod in mid-stream and by count: each
     request's tokens, logprobs and prompt logprobs are what
     `generate_tokens` gives it alone."""
     import _engine_lookahead_cases as cases
 
-    cases.staggered_parity(make(num_slots=3), cases.one_shot(CFG, PARAMS),
+    cases.staggered_parity(make_engine(num_slots=3),
+                           cases.one_shot(CFG, PARAMS),
                            CFG.vocab_size, sampled)
-
-
-def _fake_paged_steps(eng, V=64):
-    """The fake model of `_fake_steps` behind the paged engine's two
-    programs: a prompt's first token is its last token + 1."""
-    C = eng.prefill_chunk
-
-    def fake_chunk(params, caches, state, table_row, tokens_ext, off,
-                   write_start, write_end, sample_pos, key, temp, top_k,
-                   top_p, slot=None):
-        at = int(np.clip(int(sample_pos) - int(off), 0, C - 1))
-        tok = (jnp.asarray(tokens_ext)[0, at] + 1) % V
-        return (tok, jnp.float32(-1.0), jnp.zeros((C,), jnp.float32),
-                caches, state, jnp.asarray(key))
-
-    def fake_decode(params, caches, state, table, last, lengths, keys,
-                    temps, tks, tps):
-        return ((last + 1) % V, jnp.full(last.shape, -1.0, jnp.float32),
-                caches, state, keys, lengths + 1)
-
-    eng._chunk_step = fake_chunk
-    eng._decode_step = fake_decode
-    return eng
 
 
 def _record_order(eng):
@@ -858,13 +758,11 @@ def _record_order(eng):
     return events
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_dispatch_of_the_next_tick_precedes_the_read_of_this_one(paged):
+def test_dispatch_of_the_next_tick_precedes_the_read_of_this_one():
     """Host only. On a run of ticks that only decode, tick k + 1 is in the
     queue before tick k is read, every such tick counts as dispatched
     ahead, and the run ends with nothing in flight."""
-    eng = (_fake_paged_steps(make_paged(num_slots=2)) if paged
-           else _fake_steps(make_engine(num_slots=2)))
+    eng = _fake_steps(make_engine(num_slots=2))
     reqs = [eng.submit(Request(prompt=np.asarray([i + 1], np.int32),
                                max_new_tokens=12)) for i in range(2)]
     eng.step()           # both admitted, their prompts' ends dispatched
@@ -894,7 +792,7 @@ def test_an_eod_rows_extra_tick_leaks_no_page():
     from _engine_lookahead_cases import drive_tick_by_tick
 
     def run(drive):
-        eng = _fake_paged_steps(make_paged(num_slots=2, page_size=4,
+        eng = _fake_steps(make_engine(num_slots=2, page_size=4,
                                            prefill_chunk=4))
         # fake model counts up from the prompt's last token: 7 tokens to
         # 30, crossing a page; the other request ends by count
@@ -940,7 +838,7 @@ def test_a_deadline_expires_with_a_tick_in_flight():
     """The expiry reads the tick in flight first (it may be the one that
     ends the request), then fails who is still there; the other request's
     tokens are those it gets alone."""
-    eng = make_paged(num_slots=2)
+    eng = make_engine(num_slots=2)
     prompt = np.asarray([3, 7, 11, 2], np.int32)
     late = eng.submit(Request(prompt=np.asarray([5, 9], np.int32),
                               max_new_tokens=40, deadline_s=600.0))

@@ -7,7 +7,7 @@ Pins the exactness contract and the zero-recompile invariant:
   * n-gram / prompt-lookup drafter units;
   * multi-query decode attention: the kv_lengths q_len>1 einsum mask
     and both Pallas mq kernels (interpret mode) vs a dense reference;
-  * greedy parity: speculative engines (slot AND paged, ngram AND
+  * greedy parity: speculative engines (ngram AND
     model drafter) are token-identical to the non-speculative engine —
     regardless of acceptance rate — with decode_recompiles == 0 read
     off the live PR 3 counter;
@@ -38,7 +38,6 @@ import pytest
 
 from megatron_tpu.inference.engine import InferenceEngine, Request
 from megatron_tpu.inference.generation import generate_tokens
-from megatron_tpu.inference.paging import PagedInferenceEngine
 from megatron_tpu.inference.speculative import (
     SpecConfig, ngram_propose, speculative_accept, validate_spec,
 )
@@ -54,15 +53,9 @@ DPARAMS = init_params(DCFG, jax.random.PRNGKey(7))
 def make_engine(cfg=CFG, **kw):
     kw.setdefault("num_slots", 4)
     kw.setdefault("max_seq_len", 64)
-    return InferenceEngine(cfg, PARAMS, **kw)
-
-
-def make_paged(cfg=CFG, **kw):
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("max_seq_len", 64)
     kw.setdefault("page_size", 8)
     kw.setdefault("prefill_chunk", 8)
-    return PagedInferenceEngine(cfg, PARAMS, **kw)
+    return InferenceEngine(cfg, PARAMS, **kw)
 
 
 def run_one(eng, prompt, n=10, **kw):
@@ -311,8 +304,8 @@ def test_paged_flash_decode_mq_matches_reference():
 @pytest.mark.slow  # 8s measured cacheless (fresh engine + spec-step
 # compiles on the real random model = the LOW-acceptance regime); the
 # zero-engines tier-1 tests pin the same parity at high acceptance
-def test_slot_spec_ngram_greedy_parity():
-    """The acceptance gate (slot engine, ngram drafter): speculative
+def test_spec_ngram_greedy_parity():
+    """The acceptance gate (ngram drafter): speculative
     greedy decode is token-identical to the non-speculative engine AND
     the one-shot path — at the random model's low acceptance rate —
     with zero decode recompiles after warmup."""
@@ -330,10 +323,10 @@ def test_slot_spec_ngram_greedy_parity():
 
 
 @pytest.mark.slow  # 12s measured cacheless (the model-drafter spec
-# step's proposal-scan trace is the big compile); the ngram slot/paged
+# step's proposal-scan trace is the big compile); the ngram
 # parity gates + the eod mid-spec rollback test keep greedy token-
 # identity in tier-1, and the analysis audits trace this exact step
-def test_slot_spec_model_drafter_greedy_parity_and_full_acceptance():
+def test_spec_model_drafter_greedy_parity_and_full_acceptance():
     """Model drafter with draft == target: every draft is accepted
     (argmax agrees with itself), so n tokens arrive in ~n/(k+1) ticks —
     and the output is still token-identical to plain decode."""
@@ -354,7 +347,7 @@ def test_slot_spec_model_drafter_greedy_parity_and_full_acceptance():
 @pytest.mark.slow  # 12s measured cacheless (second model-drafter
 # compile set); partial-acceptance greedy identity is also pinned
 # tier-1 by the ngram gates (whose random-model acceptance is low)
-def test_slot_spec_small_draft_partial_acceptance_parity():
+def test_spec_small_draft_partial_acceptance_parity():
     """A DIFFERENT (2-layer, differently-seeded) draft proposes mostly
     wrong tokens — greedy output must be identical anyway (the verify
     emits the target argmax at every position regardless)."""
@@ -488,22 +481,23 @@ def test_spec_high_acceptance_emits_multi_token_ticks(zero_engines):
 
 
 # ---------------------------------------------------------------------------
-# paged engine parity (slow-marked matrices; one tier-1 gate)
+# multi-chunk prompts, prefix hits and preemption under speculation
+# (slow-marked matrices)
 
 
-@pytest.mark.slow  # 5s measured cacheless (fresh paged engine: chunk +
-# spec-step compiles); the paged spec step's device contract stays
-# tier-1 via the decode_spec_paged audit (test_analysis), and the paged
+@pytest.mark.slow  # 5s measured cacheless (fresh engine: chunk +
+# spec-step compiles); the spec step's device contract stays
+# tier-1 via the decode_spec_paged audit (test_analysis), and the
 # scheduler/rollback machinery via test_paging
 def test_paged_spec_ngram_greedy_parity_multi_chunk():
-    """Paged engine + ngram drafter: chunked prefill crossing page
+    """The ngram drafter after a chunked prefill crossing page
     boundaries, then speculative decode — token-identical to the
     one-shot path, prompt logprobs included, zero recompiles."""
     prompts = np.asarray([[3, 7, 11, 2, 9, 4, 1, 8, 5, 2]], np.int32)
     lengths = np.asarray([10], np.int32)
     want = generate_tokens(CFG, PARAMS, prompts, lengths, max_new_tokens=8,
                            temperature=0.0)
-    eng = make_paged(prefill_chunk=4,
+    eng = make_engine(prefill_chunk=4,
                      speculative=SpecConfig(k=3, drafter="ngram"))
     got = eng.generate(prompts, lengths, max_new_tokens=8, temperature=0.0)
     np.testing.assert_array_equal(got.tokens, want.tokens)
@@ -514,10 +508,10 @@ def test_paged_spec_ngram_greedy_parity_multi_chunk():
 
 @pytest.mark.slow  # ~25s measured cacheless (3 engine compile sets:
 # paged spec model-drafter steps are the big traces); the ngram paged
-# gate + slot model-drafter gates keep the coverage in tier-1
+# gate + the model-drafter gates keep the coverage in tier-1
 @pytest.mark.parametrize("path", ["dense", "interpreted"])
 def test_paged_spec_model_drafter_parity_and_prefix_hit(monkeypatch, path):
-    """Paged engine + draft model: the draft pools ride the SAME page
+    """The draft model: the draft pools ride the SAME page
     tables (prefix-cache hits alias pages in both trees) — greedy
     token-identical at full acceptance, prompt logprobs exact on the
     aliased request. On the dense path a CPU host runs, and with the
@@ -533,7 +527,7 @@ def test_paged_spec_model_drafter_parity_and_prefix_hit(monkeypatch, path):
     shared = p1[:8]
     p2 = np.concatenate([shared, [9, 5]]).astype(np.int32)
     a1, a2 = run_one(base, p1), run_one(base, p2, n=8)
-    eng = make_paged(cfg, speculative=SpecConfig(
+    eng = make_engine(cfg, speculative=SpecConfig(
         k=3, drafter="model", draft_cfg=cfg, draft_params=PARAMS))
     b1 = run_one(eng, p1)
     b2 = run_one(eng, p2, n=8)
@@ -560,12 +554,12 @@ def test_paged_spec_preempt_and_resume_mid_speculation():
     pb = np.asarray([5, 8, 1, 6, 2, 7], np.int32)
     kw = dict(num_slots=2, max_seq_len=32, page_size=4, prefill_chunk=8)
     spec = SpecConfig(k=3, drafter="ngram")
-    a_solo = run_one(PagedInferenceEngine(CFG, PARAMS, speculative=spec,
-                                          **kw), pa, n=16)
+    a_solo = run_one(InferenceEngine(CFG, PARAMS, speculative=spec, **kw),
+                     pa, n=16)
 
     def contended():
-        eng = PagedInferenceEngine(CFG, PARAMS, num_pages=10,
-                                   speculative=spec, **kw)
+        eng = InferenceEngine(CFG, PARAMS, num_pages=10,
+                              speculative=spec, **kw)
         ra = eng.submit(Request(prompt=pa, max_new_tokens=16))
         rb = eng.submit(Request(prompt=pb, max_new_tokens=16,
                                 temperature=0.7, top_k=8, seed=5))

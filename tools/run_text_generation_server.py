@@ -33,22 +33,19 @@ def extra_args(parser):
                         "server booted). Raise it to serve longer "
                         "prompt+generation budgets")
     g.add_argument("--serve_kv_paging", action="store_true",
-                   help="paged KV cache: one shared page pool + radix "
-                        "prefix cache + chunked prefill instead of "
-                        "per-slot cache rows (docs/serving.md) — shared "
-                        "prompt prefixes skip prefill and long prompts "
-                        "can't stall the decode batch")
+                   help="accepted and without effect: the engine's KV "
+                        "cache is always the shared page pool")
     g.add_argument("--serve_page_size", type=int, default=16,
-                   help="tokens per KV page (paged mode); multiples of 8 "
-                        "keep the TPU paged flash-decode kernel usable")
+                   help="tokens per KV page; multiples of 8 keep the TPU "
+                        "paged flash-decode kernel usable")
     g.add_argument("--serve_prefill_chunk", type=int, default=32,
-                   help="prompt tokens prefilled per engine tick (paged "
-                        "mode): chunked prefill interleaves with decode "
-                        "so one long prompt never stalls the batch")
+                   help="prompt tokens prefilled per engine tick: chunked "
+                        "prefill interleaves with decode so one long "
+                        "prompt never stalls the batch")
     g.add_argument("--serve_num_pages", type=int, default=None,
-                   help="KV pool size in pages (paged mode; default = "
-                        "slots x pages-per-sequence, i.e. the slot "
-                        "engine's capacity). Smaller oversubscribes: the "
+                   help="KV pool size in pages (default = slots x "
+                        "pages-per-sequence: every slot can grow to "
+                        "--serve_max_seq_len). Smaller oversubscribes: the "
                         "engine evicts cached prefixes and preempts the "
                         "youngest request under pressure")
     g.add_argument("--serve_speculative", choices=("ngram", "model"),
@@ -127,9 +124,9 @@ def extra_args(parser):
                         "shard each sequence's paged KV over the mesh's "
                         "context axis and ring-attend across the shards "
                         "— long-context prompts whose KV exceeds one "
-                        "device. Needs --serve_kv_paging and "
-                        "--context_parallel >= 2; greedy output stays "
-                        "token-identical to single-host paged serving")
+                        "device. Needs --context_parallel >= 2; greedy "
+                        "output stays token-identical to single-host "
+                        "serving")
     g.add_argument("--serve_cp_collectives",
                    choices=("dense", "int8", "fp8"), default="dense",
                    help="transport for the CP ring-attention hops "
@@ -304,28 +301,20 @@ def main(argv=None):
     if engine_slots:
         m = cfg.model
         bpe = 1 if args.kv_cache_int8 else 2
-        if args.serve_kv_paging:
-            ps = args.serve_page_size
-            pages = (args.serve_num_pages
-                     or engine_slots * (-(-engine_max_seq_len // ps)) + 1)
-            gib = (2 * m.num_layers * pages * ps * m.n_kv_heads
-                   * m.head_dim * bpe) / 2**30
-            print(f"paged KV pool: {pages} pages x {ps} tokens = "
-                  f"{gib:.2f} GiB"
-                  + (" (int8)" if args.kv_cache_int8 else " (bf16)"))
-        else:
-            gib = (2 * m.num_layers * engine_slots * engine_max_seq_len
-                   * m.n_kv_heads * m.head_dim * bpe) / 2**30
-            print(f"persistent KV cache: {engine_slots} slots x "
-                  f"{engine_max_seq_len} tokens = {gib:.2f} GiB"
-                  + (" (int8)" if args.kv_cache_int8 else " (bf16)"))
+        ps = args.serve_page_size
+        pages = (args.serve_num_pages
+                 or engine_slots * (-(-engine_max_seq_len // ps)) + 1)
+        gib = (2 * m.num_layers * pages * ps * m.n_kv_heads
+               * m.head_dim * bpe) / 2**30
+        print(f"paged KV pool: {pages} pages x {ps} tokens = "
+              f"{gib:.2f} GiB"
+              + (" (int8)" if args.kv_cache_int8 else " (bf16)"))
     run_server(cfg.model, params, tokenizer, host=args.host, port=args.port,
                mesh=mesh, forward_fn=forward_fn,
                kv_cache_int8=args.kv_cache_int8,
                engine_slots=engine_slots,
                engine_max_seq_len=engine_max_seq_len,
                engine_max_queue=args.serve_max_queue,
-               kv_paging=args.serve_kv_paging,
                page_size=args.serve_page_size,
                prefill_chunk=args.serve_prefill_chunk,
                num_pages=args.serve_num_pages,
