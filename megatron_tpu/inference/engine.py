@@ -535,7 +535,8 @@ class InferenceEngine:
                       "window_pages_released": 0, "pages_evicted": 0,
                       # prefill_live_block_share joins them at the first
                       # chunk
-                      "prefill_blocks_visited": 0, "prefill_blocks_held": 0}
+                      "prefill_blocks_visited": 0, "prefill_blocks_held": 0,
+                      "decode_blocks_visited": 0, "decode_blocks_held": 0}
         if self.spec is not None:
             # spec_emitted counts every token the spec path emitted
             # (accepted drafts + the guaranteed token per row per tick);
@@ -1783,7 +1784,8 @@ class InferenceEngine:
         that were in the queue before the one before them was read; rows /
         ticks the mean decoding batch; phase_s where the loop thread's
         time went, as of the last tick that ended; evicted the pages the
-        prefix cache gave back)."""
+        prefix cache gave back; prefill_blocks and decode_blocks the
+        [visited, held] behind the two live-block shares)."""
         stats = self.stats
         fields = {
             "ticks": stats["ticks"],
@@ -1795,7 +1797,9 @@ class InferenceEngine:
                         for k, v in stats["tick_phase_s"].items()},
             "evicted": stats["pages_evicted"],
             "prefill_blocks": [stats["prefill_blocks_visited"],
-                               stats["prefill_blocks_held"]]}
+                               stats["prefill_blocks_held"]],
+            "decode_blocks": [stats["decode_blocks_visited"],
+                              stats["decode_blocks_held"]]}
         if self.cfg.holds_expert_share:
             fields["moe_rows"] = [stats["moe_held_rows"], stats["moe_rows"]]
             fields["moe_experts"] = [stats["moe_experts_read"],
@@ -1859,18 +1863,23 @@ class InferenceEngine:
         run: the trips the decode kernel's loops take over the blocks
         the table holds, from the host's lengths. A decoding row reaches
         the kernel with its new token written (length + 1); every other
-        slot with whatever the device's carry holds for it, 0 and a tick's
-        drift, + 1: one block, not none. Near slots / blocks under light
-        load, 1 with every slot at its full length or window."""
+        slot with the length the step gives a row its table leaves out
+        (`masks.decode_idle_length`): no trip. The decoding rows' live
+        blocks over slots x blocks, 1 with every slot at its full length
+        or window. The journal's `serve_ticks` carries both counts
+        summed over every tick."""
         from megatron_tpu.ops.pallas.flash_template import (
             decode_blocks_visited)
+        from megatron_tpu.ops.pallas.masks import decode_idle_length
 
-        lens = np.ones_like(self.lengths)
+        sq = self._decode_write_span()
+        lens = np.full_like(self.lengths, decode_idle_length(sq))
         lens[active] = self.lengths[active] + 1
         visited, held = decode_blocks_visited(
             lens, self.max_pages, self.page_size, self._kernel_kv_heads(),
-            sq=self._decode_write_span(),
-            window=self.cfg.attention_kind.sliding_window_size)
+            sq=sq, window=self.cfg.attention_kind.sliding_window_size)
+        self.stats["decode_blocks_visited"] += visited
+        self.stats["decode_blocks_held"] += held
         self.stats["decode_live_block_share"] = visited / held
         self._m_live_blocks.set(visited / held)
 

@@ -39,7 +39,14 @@ def rows_decoding(table):
     least; an idle slot's and a prefilling slot's (its pages wait in the
     engine's `_pending_rows`) are all scratch. Not the row's first entry:
     the window's release parks that one on scratch while the slot decodes
-    on (`_release_window_pages`)."""
+    on (`_release_window_pages`).
+
+    Three readers, through the forward's `state_valid`: a state-space
+    mixer advances those rows' state alone, the experts route those rows
+    alone, and an attention layer hands the paged decode kernel, for
+    every other row, the length its loop reads as nothing to visit
+    (ops/pallas/masks.py `decode_idle_length`: 0 for one query a row,
+    1 - sq for the speculative verify's sq)."""
     return jnp.any(table != SCRATCH_PAGE, axis=1).astype(jnp.int32)
 
 
@@ -88,11 +95,12 @@ def build_decode_step(cfg, forward, which_rows, *, vocab_size,
     top_ks, top_ps, *counts) -> (toks, lps, caches, state, keys,
     lengths + 1, *counts). `which_rows` reads the decoding slots off
     the table (`rows_decoding`, or the CP engine's over its ranks' local
-    tables). `shard_outputs` maps a template of "kv" /
+    tables), for every model: the attention layers read it as the mixers
+    and the router do (`rows_decoding`), and the kernel takes no trip
+    for a slot it leaves out. `shard_outputs` maps a template of "kv" /
     "rep" tags to the jit's `out_shardings` keywords (the engine's
     `_jit_sharding_kwargs`); it is called here and not kept."""
     vocab, wlp = vocab_size, want_logprobs
-    experts = cfg.num_experts is not None
 
     @partial(jax.jit, donate_argnums=donate_argnums,
              **shard_outputs(("rep", "rep", "kv", "rep", "rep", "rep")
@@ -104,10 +112,11 @@ def build_decode_step(cfg, forward, which_rows, *, vocab_size,
         # of the table. state (None without state-space layers): this
         # tick advances the rows of the slots that decode (which_rows:
         # an idle slot's and a prefilling slot's state stays). The expert
-        # layers route those rows alone. A model with neither is not
-        # told.
-        decoding = (None if state is None and not experts else
-                    which_rows(table))
+        # layers route those rows alone, and the decode kernel visits
+        # those rows' pages alone: an idle row still writes its K/V (on
+        # the scratch page) and its length still grows by 1 a tick, but
+        # it attends nothing, and nobody reads what it samples.
+        decoding = which_rows(table)
         # counts (of a model that holds a share of its experts, else
         # absent): _step_counts, which this step adds its rows to and
         # returns behind everything else

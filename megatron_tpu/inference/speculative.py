@@ -320,7 +320,13 @@ def build_spec_decode_step(
     new_lengths, new_last_tok). new_keys is the untouched chain state
     (randomness is positional — see speculative_accept) returned so the
     device carry layout matches the non-speculative step's.
+
+    Every forward is told which slots decode, off the table, as the plain
+    decode step's is (paging/engine.py `rows_decoding`): a slot that does
+    not costs the decode kernels no trip. The verify pass counts all of a
+    decoding row's k + 1 positions as real.
     """
+    from megatron_tpu.inference.paging.engine import rows_decoding
     from megatron_tpu.models.language_model import lm_forward
 
     k = spec.k
@@ -330,8 +336,9 @@ def build_spec_decode_step(
     def _verify(params, caches, table, last, lens, keys, temps, tks, tps,
                 spec_rows, drafts):
         toks_in = jnp.concatenate([last[:, None], drafts], axis=1)
-        logits, caches = lm_forward(cfg, params, toks_in, kv_caches=caches,
-                                    cache_index=lens, page_table=table)
+        logits, caches = lm_forward(
+            cfg, params, toks_in, kv_caches=caches, cache_index=lens,
+            page_table=table, state_valid=rows_decoding(table) * (k + 1))
         toks, lps, accepts = speculative_accept(
             logits, drafts, lens, keys, temps, tks, tps,
             vocab_size=vocab_size, spec_rows=spec_rows,
@@ -352,10 +359,13 @@ def build_spec_decode_step(
     @partial(jax.jit, donate_argnums=donate_argnums)
     def spec_step(params, caches, dparams, dcaches, table, last, lens,
                   keys, temps, tks, tps, spec_rows):
+        decoding = rows_decoding(table)
+
         def body(carry, _):
             dc, tok, ln = carry
             lg, dc = lm_forward(dcfg, dparams, tok[:, None], kv_caches=dc,
-                                cache_index=ln, page_table=table)
+                                cache_index=ln, page_table=table,
+                                state_valid=decoding)
             lg = lg[:, 0].astype(jnp.float32)
             if vocab_size is not None and vocab_size < V:
                 lg = jnp.where(jnp.arange(V) < vocab_size, lg, neg)
@@ -370,7 +380,7 @@ def build_spec_decode_step(
         # complete for the next tick's proposal
         _, dcaches = lm_forward(dcfg, dparams, d_last[:, None],
                                 kv_caches=dcaches, cache_index=d_len,
-                                page_table=table)
+                                page_table=table, state_valid=decoding)
         toks, lps, accepts, caches, keys, lens_new, last_new = _verify(
             params, caches, table, last, lens, keys, temps, tks, tps,
             spec_rows, drafts)

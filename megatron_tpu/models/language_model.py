@@ -446,7 +446,10 @@ def lm_forward(
     state_valid alone, of a serving step of any model: the expert layers
     route the real positions only (ops/moe.py moe_block `rows_read`;
     return_moe_aux then carries, of a share of the experts, the held
-    experts a real position reached, summed over the layers, last).
+    experts a real position reached, summed over the layers, last), and
+    with a vector cache_index (a decode step) the attention layers hand
+    the decode kernel, for a row without a real position, the length it
+    visits nothing for (models/transformer.py attention_block).
 
     grad_sink: float32 accumulators for the gradients of some leaves of
     `params`, in a tree shaped like `params` that holds None at every

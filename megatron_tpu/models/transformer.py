@@ -31,6 +31,7 @@ from megatron_tpu.ops.attention import attention
 from megatron_tpu.ops.fp8 import maybe_fp8_matmul
 from megatron_tpu.ops.moe import layer_stats, moe_block, moe_stats_zero
 from megatron_tpu.ops.normalization import norm_forward, rmsnorm
+from megatron_tpu.ops.pallas import masks
 from megatron_tpu.ops.rotary import apply_rotary_emb
 from megatron_tpu.ops.ssm import mixer_through_store, ssm_mixer
 from megatron_tpu.ops.weight_quant import deq
@@ -74,6 +75,7 @@ def attention_block(
     tp_comm=None,  # quant.TpComm: explicit/compressed TP collectives
     cp_comm=None,  # quant.CpComm: context-parallel ring transport
     kind: Optional[AttentionKind] = None,
+    state_valid: Optional[jnp.ndarray] = None,   # [B] int32
 ):
     """Returns (out [B,S,h], kv_cache with this layer's rows written).
 
@@ -92,6 +94,16 @@ def attention_block(
     depth: s == 1 plain decode, s > 1 the speculative verify pass, row
     b's queries at cache_index[b]..cache_index[b]+s-1); a scalar is a
     prefill, one chunk of one prompt, or one-shot generation's step.
+
+    state_valid (block_forward's: the positions of each row that are
+    real; a serving step's, else None): with a vector cache_index, a row
+    with none, a slot that does not decode, reaches the decode kernel
+    with the length at which its loop is empty
+    (masks.decode_idle_length), so it copies no page and computes no
+    block. Its K/V are still written (on the scratch page: its table
+    holds no other) and its output, which nobody reads, is zeros from the
+    kernel and a finite mean from the dense path. None: every row
+    attends its cache_index + 1 positions.
 
     page_table: the store is a pool of pages shared by every row
     (inference/paging/). page_write_start / page_write_end (chunked
@@ -194,6 +206,10 @@ def attention_block(
                                             cfg.dtype)
                 if per_slot:
                     kv_lengths = cache_index + 1
+                    if state_valid is not None:
+                        kv_lengths = jnp.where(
+                            state_valid > 0, kv_lengths,
+                            masks.decode_idle_length(s))
                 else:
                     q_offset = cache_index
 
@@ -306,7 +322,8 @@ def _mixer(cfg: ModelConfig, lp: Dict[str, Any], normed: jnp.ndarray,
     out, kv_cache = attention_block(
         cfg, lp["attn"], normed, rope, positions,
         attn_dropout_key=attn_dropout_key,
-        kv_cache=kv_cache, layer=type_layer, kind=kind, **attn_args)
+        kv_cache=kv_cache, layer=type_layer, kind=kind,
+        state_valid=state_valid, **attn_args)
     return out, kv_cache, ssm_state
 
 
@@ -363,7 +380,9 @@ def block_forward(
     `state_row` alone (one row's prefill chunk); state_valid [B]: the
     positions that are real (ops/ssm.py). An expert layer takes
     state_valid too: the positions that are not real reach no expert
-    (ops/moe.py moe_block `rows_read`).
+    (ops/moe.py moe_block `rows_read`). So does an attention layer of a
+    decode step: a row without a real position visits no block of its
+    cache (attention_block).
 
     kind: this layer's attention kind (attention_block), with `rope` that
     kind's table. In a stack of several kinds the region `attention`

@@ -24,7 +24,15 @@ instantiation of the one template in this module, with four knobs:
   mask          | causal / bidirectional, | ops/pallas/masks.py: ONE position
                 | sliding window,         | model supplies the element mask and
                 | kv_lengths (decode)     | the block-skip predicate for every
-                |                         | instantiation
+                |                         | instantiation. A decode row's
+                |                         | kv_lengths is its context, or, for
+                |                         | a slot that does not decode, the
+                |                         | length no query sees a position at
+                |                         | (masks.decode_idle_length: 0, or
+                |                         | 1 - Sq for Sq queries): the serving
+                |                         | layer's word for "visit nothing"
+                |                         | (models/transformer.py
+                |                         | attention_block)
   paging        | dense / page table      | the page table rides in as a
                 |                         | scalar-prefetch operand; the pools
                 |                         | stay in HBM and the kernel's loop
@@ -69,7 +77,10 @@ entries were live, at the same time a step whether it held 32 KB or
 4 KB (ledger, PR 47). So the decode kernel takes no dead step at all:
 its grid is the rows, and each row's loop runs over the interval form
 of the same predicate (masks.decode_live_blocks), a young slot in a
-long cache over the context it has and an idle slot over nothing.
+long cache over the context it has and an idle slot over nothing: the
+serving layer hands it the length at which the interval is empty
+(masks.decode_idle_length), and its grid step copies no page and
+computes no block.
 
 A live tile of the training kernels does what its mask leaves and no
 more (`_visit_tile`): its place in the band is static wherever the
@@ -1033,7 +1044,9 @@ def _decode_kernel(lens_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
     dense/paged), one grid step a row. The pools stay in HBM; the row's
     kv loop runs here, from its first live block to its last
     (`decode_trips`), so a row costs what its live context costs and an
-    idle one (kv_len 0) nothing. A block is `units` copies of `unit`
+    idle one (kv_len `masks.decode_idle_length(sq)` or under: 0 at one
+    query) nothing but its grid step: the loop is empty, `l` is clamped
+    and `o` comes back zero. A block is `units` copies of `unit`
     cache positions with EVERY kv head, [unit * Hkv, D] each, exactly as
     a page lies in memory (ops/kv_store.py: no caller transposes a cache
     for this kernel): several whole pages, or, of a page longer than a

@@ -205,6 +205,19 @@ def decode_live_blocks(block_k: int, kv_len, sq: int, *,
     return first, last
 
 
+def decode_idle_length(sq: int) -> int:
+    """The kv_len a decode row carries to be visited by nobody: the
+    largest at which `decode_live_blocks` gives `last < 0` at any block
+    size and window (kv_len + sq - 2 < 0: not even the row's last query
+    sees position 0), so a loop clipped into its table is empty. 0 for
+    one query; query j of the speculative verify sees k_pos < kv_len + j,
+    so its sq queries want 1 - sq. What the serving layer hands the
+    decode kernels for a slot that does not decode
+    (models/transformer.py attention_block), and what the engine's count
+    of the kernel's trips gives such a slot."""
+    return 1 - sq
+
+
 def prefill_block_live(qi, ki, block_q: int, block_k: int, *,
                        causal: bool = True, window: Optional[int] = None,
                        delta=0):

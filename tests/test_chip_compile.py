@@ -1243,13 +1243,14 @@ def _paged_step(topo, cfg, step, pages, page, slots, max_len, chunk):
     state = abstract(jax.eval_shape(
         lambda: ssm.create_state(cfg, slots))) if cfg.has_ssm else None
 
-    # as the engine's steps: a model with a state store or expert layers
-    # is told which rows count
+    # as the engine's steps: every model's decode step is told which rows
+    # decode (the attention layers hand the kernel the idle length for the
+    # others), a chunk of a model with a state store or expert layers which
+    # positions are real
     told = state is not None or cfg.num_experts is not None
 
     def decode(params, caches, state, table, tok, lengths):
-        decoding = (jnp.any(table != 0, axis=1).astype(i32) if told
-                    else None)
+        decoding = jnp.any(table != 0, axis=1).astype(i32)
         return lm_forward(cfg, params, tok[:, None], kv_caches=caches,
                           ssm_state=state, cache_index=lengths,
                           page_table=table, state_valid=decoding)

@@ -199,10 +199,14 @@ def test_read_share_is_under_one_with_one_slot_decoding():
     assert 0 < _read_share(eng) <= 0.75
 
 
-def test_a_dense_models_step_is_the_same_text_told_or_not():
-    """A served model without expert layers or a state store: its decode
-    step over the page pool lowers to the same text whether the rows that
-    count are handed down (`state_valid`) or not; nothing reads them."""
+def test_a_dense_models_step_reads_the_rows_in_its_attention_alone():
+    """A served model without expert layers or a state store: no router
+    and no mixer reads the rows that count (`state_valid`), so its decode
+    step over the page pool, told or not, gives the rows that decode the
+    same logits bit for bit and writes their pages the same (the other
+    rows write the scratch page, page 0, which nobody reads). Since PR 64 the
+    attention layers read them (a row that does not decode visits no
+    block of its cache), so the two steps' texts differ."""
     from megatron_tpu.models import presets
     from megatron_tpu.models.language_model import lm_forward
     from megatron_tpu.models.params import init_params
@@ -211,7 +215,7 @@ def test_a_dense_models_step_is_the_same_text_told_or_not():
     cfg = presets.tiny(seq_length=32)
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=F32)
     kv = kv_store.create(cfg, 9, 4)
-    table = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)
+    table = jnp.asarray([[1, 2, 3, 4], [0, 0, 0, 0]], jnp.int32)
 
     def step(told, params, kv, table, tok, lengths):
         decoding = (jnp.any(table != 0, axis=1).astype(jnp.int32) if told
@@ -220,10 +224,16 @@ def test_a_dense_models_step_is_the_same_text_told_or_not():
                           cache_index=lengths, page_table=table,
                           state_valid=decoding)
 
-    texts = [jax.jit(step, static_argnums=0).lower(
-        told, params, kv, table, jnp.zeros((2,), jnp.int32),
-        jnp.ones((2,), jnp.int32)).as_text() for told in (False, True)]
-    assert texts[0] == texts[1]
+    args = (params, kv, table, jnp.asarray([3, 5], jnp.int32),
+            jnp.asarray([6, 0], jnp.int32))
+    steps = [jax.jit(step, static_argnums=0).lower(told, *args)
+             for told in (False, True)]
+    assert steps[0].as_text() != steps[1].as_text()
+    (plain, plain_kv), (told, told_kv) = (s.compile()(*args) for s in steps)
+    np.testing.assert_array_equal(np.asarray(told)[0], np.asarray(plain)[0])
+    assert np.isfinite(np.asarray(told)).all()
+    for ours, theirs in zip(told_kv, plain_kv):
+        np.testing.assert_array_equal(ours[:, 1:], theirs[:, 1:])
 
 
 def _windowed(model):

@@ -15,16 +15,23 @@ the KV layout.
     costs the batch no tick and the loop no drain;
   * a failed chunk fails its request alone where nothing was donated, and
     every request in flight, prefilling ones included, where the pool was;
-  * `rows_decoding` reads the decoding slots off the decode step's table.
+  * `rows_decoding` reads the decoding slots off the decode step's table;
+  * the attention layers read it too (PR 64): a slot that does not decode
+    reaches the decode kernel with the length its loop reads as nothing to
+    visit, the request beside it is served the tokens and log-probabilities
+    of an engine whose layers are not told, and a caller that passes no
+    vector traces the program it traced before.
 
 The fake model of tests/test_serving_engine.py stands behind the host-only
 cases (no compiles).
 """
 
+import dataclasses
 import importlib.util
 import inspect
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -256,3 +263,98 @@ def test_a_failed_chunk_fails_whom_it_must(donated):
 def test_rows_decoding_reads_the_table(row, decodes):
     table = jnp.asarray([row, [SCRATCH_PAGE] * 4], jnp.int32)
     assert steps.rows_decoding(table).tolist() == [decodes, 0]
+
+
+# ---------------------------------------------------------------------------
+# the third reader of `rows_decoding`: the attention layers
+
+
+def _attention_not_told(monkeypatch):
+    """The engine's steps as they were: every row reaches the kernel with
+    `cache_index + 1`, an idle slot's too."""
+    from megatron_tpu.models import transformer
+
+    block = transformer.attention_block
+    monkeypatch.setattr(
+        transformer, "attention_block",
+        lambda *a, state_valid=None, **kw: block(*a, **kw))
+
+
+@pytest.mark.parametrize("window", [None, 8], ids=["full", "window"])
+@pytest.mark.parametrize("path", ["dense", "interpreted"])
+def test_a_request_beside_idle_slots_is_served_what_it_was(path, window,
+                                                           monkeypatch):
+    """One request in four slots, so three rows of every decode tick
+    carry the idle length: its tokens and log-probabilities are, bit for
+    bit, those of an engine whose attention layers are not told which
+    rows decode (the parent's: an idle row made a trip and nobody read
+    it). On the dense path a CPU host runs and through the kernel, and
+    behind a window whose release parks the decoding row's first pages
+    on scratch: the row still decodes (`rows_decoding`: any page) and
+    still attends."""
+    cfg = dataclasses.replace(CFG, sliding_window_size=window)
+    if path == "interpreted":
+        monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+        cfg = dataclasses.replace(cfg, attention_impl="pallas")
+    prompt = np.asarray([3, 7, 11, 2, 9, 4], np.int32)
+
+    def serve():
+        eng = make_engine(cfg)
+        req = eng.submit(Request(prompt=prompt, max_new_tokens=40))
+        eng.run_until_idle()
+        assert req.error is None
+        assert eng.stats["decode_rows"] == eng.stats["ticks"]   # one a tick
+        if window is not None:
+            assert eng.stats["window_pages_released"] > 0
+        return req, eng
+
+    told, eng = serve()
+    visited, held = eng._serve_ticks_fields()["decode_blocks"]
+    assert 0 < visited <= held // 4      # one row of four, at the most
+    _attention_not_told(monkeypatch)
+    plain, _ = serve()
+    assert told.generated == plain.generated and len(told.generated) == 40
+    np.testing.assert_array_equal(told.logprobs, plain.logprobs)
+
+
+def test_a_caller_without_the_vector_traces_the_program_it_traced(
+        monkeypatch):
+    """`attention_block` forms the kernel's lengths from the vector only
+    where one is given: a per-slot call without it (generation.py's slot
+    decode, a test's direct call) and a training call trace, with the
+    kernels in the program, to the jaxpr of a tree whose layer has no
+    such argument; with it the lengths pass through one select more."""
+    from megatron_tpu.models.language_model import lm_forward
+    from megatron_tpu.ops import kv_store
+
+    monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+    cfg = dataclasses.replace(CFG, attention_impl="pallas")
+    caches = kv_store.create(cfg, 9, 8)
+    table = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)
+    tokens = jnp.asarray([[5], [6]], jnp.int32)
+    lens = jnp.asarray([3, 0], jnp.int32)
+
+    def decode(params, caches, lens, **kw):
+        return lm_forward(cfg, params, tokens, kv_caches=caches,
+                          cache_index=lens, page_table=table, **kw)[0]
+
+    def train(params, **kw):
+        return lm_forward(cfg, params, jnp.ones((1, 64), jnp.int32), **kw)
+
+    def traced():
+        return (str(jax.make_jaxpr(decode)(PARAMS, caches, lens)),
+                str(jax.make_jaxpr(train)(PARAMS)))
+
+    plain = traced()
+    none = (str(jax.make_jaxpr(lambda *a: decode(*a, state_valid=None))(
+                PARAMS, caches, lens)),
+            str(jax.make_jaxpr(lambda p: train(p, state_valid=None))(
+                PARAMS)))
+    told = str(jax.make_jaxpr(
+        lambda *a: decode(*a, state_valid=jnp.asarray([1, 0])))(
+            PARAMS, caches, lens))
+    _attention_not_told(monkeypatch)
+    assert plain == none == traced()
+    assert told != plain[0]
+    assert told.count("select_n") > plain[0].count("select_n")
+    assert "paged_flash_decode" in plain[0]

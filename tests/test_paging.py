@@ -273,9 +273,12 @@ def test_engine_reports_the_live_block_share(engine):
     from the host's lengths, is what the kernel's loop bounds give over
     the blocks the table holds: checked tick by tick against the block
     predicate, at pages of 8 and at one page a sequence. A decoding row
-    reaches the kernel with
-    its new token written (length + 1), an idle one with length 1 (the
-    layer hands the kernel `cache_index + 1`): one block."""
+    reaches the kernel with its new token written (length + 1), an idle
+    one with the length its loop reads as nothing to visit (the layer
+    hands the kernel `masks.decode_idle_length` for a row the step's
+    table leaves out): no block, at one query a row and at the
+    speculative verify's three. Both counts add up in `stats` and in the
+    journal's `serve_ticks`."""
     import jax
 
     from megatron_tpu.inference.engine import InferenceEngine
@@ -312,13 +315,13 @@ def test_engine_reports_the_live_block_share(engine):
                                                 int(eng.lengths[i]) + 1,
                                                 sq, window=window))
                    for i in active for ki in range(n_blocks))
-        want += 3 - len(active)
         seen.append((want / (3 * n_blocks),
                      eng.stats["decode_live_block_share"],
                      reg.get("engine_decode_live_block_share").value()))
 
     eng._note_live_blocks = spy
     assert eng.stats["decode_live_block_share"] == 0.0
+    assert eng._serve_ticks_fields()["decode_blocks"] == [0, 0]
     rng = np.random.default_rng(0)
     prompts = rng.integers(1, 64, (2, 12)).astype(np.int32)
     eng.generate(prompts, np.asarray([12, 5], np.int32), max_new_tokens=40)
@@ -326,6 +329,11 @@ def test_engine_reports_the_live_block_share(engine):
     for want, stat, gauge in seen:
         assert want == stat == gauge
     assert 0 < min(s for _, s, _ in seen) <= max(s for _, s, _ in seen) <= 1
+    # the second request retires first: its slot then counts for nothing
+    assert min(s for _, s, _ in seen) <= 1 / 3
+    visited, held = eng._serve_ticks_fields()["decode_blocks"]
+    assert held == len(seen) * 3 * n_blocks
+    assert visited == round(sum(s for _, s, _ in seen) * 3 * n_blocks)
     assert "engine_decode_live_block_share " in reg.render()
 
 

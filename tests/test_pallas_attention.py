@@ -145,6 +145,38 @@ def test_decode_live_blocks_are_the_block_predicate(sq, window):
                                        window=window), seen)
 
 
+@pytest.mark.parametrize("window", [None, 1, 4, 8, 16, 17])
+@pytest.mark.parametrize("sq", [1, 2, 4, 5])
+def test_the_idle_length_is_the_largest_without_a_live_block(sq, window):
+    """`decode_idle_length(sq)`: at it and below, `decode_block_live`
+    admits no block, at any block size, `decode_live_blocks` ends under
+    0, the loop clipped into the table (`decode_trips`, the kernel's own
+    and the host's) is empty, and no query sees any position; one above
+    it the row's last query sees position 0 and block 0 is live."""
+    from megatron_tpu.ops.pallas.flash_template import decode_trips
+
+    idle = masks.decode_idle_length(sq)
+    assert idle == 1 - sq and masks.decode_idle_length(1) == 0
+    nk = 6
+    for blk in (8, 16, 256):
+        for kv_len in (idle, idle - 1, idle - 7):
+            assert not any(bool(masks.decode_block_live(
+                ki, blk, kv_len, sq, window=window)) for ki in range(nk))
+            first, last = masks.decode_live_blocks(blk, kv_len, sq,
+                                                   window=window)
+            assert last < 0
+            first, end = decode_trips(np.asarray([kv_len]), sq, window, blk,
+                                      nk, xp=np)
+            assert (np.maximum(end - first, 0) == 0).all()
+            assert not masks.decode_position_live(
+                np.arange(blk * nk), kv_len, sq, window=window).any()
+        assert bool(masks.decode_block_live(0, blk, idle + 1, sq,
+                                            window=window))
+        first, end = decode_trips(np.asarray([idle + 1]), sq, window, blk,
+                                  nk, xp=np)
+        assert (first, end) == (0, 1)
+
+
 def test_window_lower_edge_is_tight():
     """The windowed skip keeps exactly the tiles intersecting
     (q_lo - W, q_hi]: the tile just below the window's lower edge is
@@ -280,6 +312,54 @@ def test_paged_decode_window_parity(sq, window):
                      impl="xla")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+@pytest.mark.parametrize("sq", [1, 3])
+def test_rows_with_the_idle_length_cost_no_trip_and_move_no_other_row(
+        sq, window):
+    """A batch in which some rows carry `decode_idle_length` (slots that
+    do not decode: the serving layer's word for them, attention_block):
+    the decoding rows come back, bit for bit, as from the call over those
+    rows alone; the idle rows come back zero though their table is all
+    scratch and the scratch page holds NaN (no page of theirs is copied,
+    no block computed); `decode_blocks_visited` counts nothing for them.
+    The dense path gives the same rows a finite mean, and the decoding
+    ones what the kernel gives them."""
+    from megatron_tpu.ops.pallas import flash_template as ft
+
+    ps = 64
+    q, k, v = _qkv(b=5, s=sq, skv=256, hq=4, hkv=2, d=32)
+    kp, vp, table = _paged(k, v, ps)
+    kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    idle = masks.decode_idle_length(sq)
+    lens = np.asarray([idle, 100, idle, 256 - sq + 1, 1], np.int32)
+    decoding = np.asarray([1, 3, 4])
+    resting = np.asarray([0, 2])
+    table = jnp.asarray(np.asarray(table) * (lens > idle)[:, None])
+    fn = ft.paged_flash_decode if sq == 1 else ft.paged_flash_decode_mq
+    got = np.asarray(fn(q, kp, vp, table, jnp.asarray(lens),
+                        sliding_window=window))
+    alone = np.asarray(fn(q[decoding], kp, vp, table[decoding],
+                          jnp.asarray(lens[decoding]),
+                          sliding_window=window))
+    np.testing.assert_array_equal(got[decoding], alone)
+    assert (got[resting] == 0).all()
+    dense = np.asarray(attention(q, k, v, kv_lengths=jnp.asarray(lens),
+                                 sliding_window=window, impl="xla"))
+    assert np.isfinite(dense).all()
+    np.testing.assert_allclose(got[decoding], dense[decoding], rtol=2e-3,
+                               atol=2e-3)
+
+    def visited(rows):
+        return ft.decode_blocks_visited(rows, table.shape[1], ps, 2, sq,
+                                        window)
+
+    assert visited(lens[resting]) == (0, 2 * visited(lens[:1])[1])
+    assert visited(lens)[0] == visited(lens[decoding])[0] > 0
+    # the same two rows at the carry's drift past 0, as the layer handed
+    # them over before: a block each
+    assert visited(np.asarray([1, 1]))[0] == 2
 
 
 # (layout: a page size, or "row" for a dense cache; kv heads; groups; sq)

@@ -579,3 +579,41 @@ def test_paged_spec_preempt_and_resume_mid_speculation():
     assert a1 == a_solo.generated
     assert (a1, b1) == (a2, b2)
     assert len(b1) == 16
+
+
+@pytest.mark.parametrize("path", ["dense", "interpreted"])
+def test_a_slot_that_does_not_speculate_costs_the_verify_no_trip(
+        monkeypatch, path):
+    """One request in three slots under the n-gram drafter: the verify
+    pass hands the multi-query kernel, for the two idle rows, the length
+    at which none of its k + 1 queries sees a position
+    (`masks.decode_idle_length`: 1 - sq, not 0, where query j sees
+    k_pos < kv_len + j), the engine's count of the kernel's trips holds
+    nothing for them, and the request is served the tokens and
+    log-probabilities of an engine whose layers are not told which rows
+    decode."""
+    from megatron_tpu.ops.pallas import flash_template as ft
+    from test_one_engine import _attention_not_told
+
+    cfg = CFG
+    if path == "interpreted":
+        monkeypatch.setenv("MEGATRON_TPU_FLASH_INTERPRET", "1")
+        cfg = dataclasses.replace(CFG, attention_impl="pallas")
+    prompt = [3, 7, 3, 7, 3, 7, 3, 7]
+
+    def serve():
+        eng = InferenceEngine(cfg, PARAMS, num_slots=3, max_seq_len=64,
+                              page_size=8, prefill_chunk=8,
+                              speculative=SpecConfig(k=2, drafter="ngram"))
+        return run_one(eng, prompt, n=24), eng
+
+    told, eng = serve()
+    visited, held = eng._serve_ticks_fields()["decode_blocks"]
+    _, _, _, n_blocks = ft._decode_geometry(8, 8, cfg.n_kv_heads)
+    ticks = eng.stats["ticks"]
+    assert held == ticks * 3 * n_blocks
+    assert ticks <= visited <= ticks * n_blocks   # the one row's, alone
+    _attention_not_told(monkeypatch)
+    plain, _ = serve()
+    assert told.generated == plain.generated and len(told.generated) == 24
+    np.testing.assert_array_equal(told.logprobs, plain.logprobs)
