@@ -34,7 +34,7 @@ from megatron_tpu.ops.cross_entropy import (
     chunked_head_loss, cross_entropy_loss,
 )
 from megatron_tpu.ops.moe import (
-    HELD_METRIC, LOAD_METRIC, SAVED_PRODUCT, expert_grad_sinks,
+    HELD_METRIC, LOAD_METRIC, MOVED_METRIC, SAVED_PRODUCT, expert_grad_sinks,
     merge_layer_stats, moe_stats_zero,
 )
 from megatron_tpu.ops.pallas.flash_template import SAVED_RESIDUAL
@@ -503,7 +503,7 @@ def lm_forward(
         rope_len = max(cfg.seq_length, tokens.shape[1])
     ropes = rope_tables(cfg, cfg.attention_period, rope_len)
     moe = cfg.num_experts is not None
-    carry = (x, (moe_stats_zero(cfg, state_valid is not None) if moe
+    carry = (x, (moe_stats_zero(cfg) if moe
                  else jnp.zeros((), jnp.float32)),
              kv_caches, None if grad_sink is None else grad_sink["layers"],
              ssm_state)
@@ -667,5 +667,6 @@ def lm_loss(
         aux[LOAD_METRIC] = moe_aux[1]
         if cfg.holds_expert_share:
             aux[HELD_METRIC] = moe_aux[2] / cfg.expert_layers
+            aux[MOVED_METRIC] = moe_aux[-1] / cfg.expert_layers
         return mean + moe_aux[0], aux
     return mean, aux

@@ -47,7 +47,13 @@ definition of the auxiliary losses serve both dispatch forms:
     choices are weighted by the gates and summed in float32. The sort is a
     permutation, so rows cross it by gathers in both directions, forward
     and backward (`rows_to_expert_order`, `rows_to_token_order`): the
-    block holds no scatter. No token is dropped and no
+    block holds no scatter. Each of the four is one pass over all N*k
+    rows, but for a call that holds a share of the router's experts (or
+    says which rows are read): the rows it keeps stand in front of expert
+    order, and at the row counts `_walk_blocks` names the four passes
+    walk the blocks that hold kept rows, as many trips as those take, so
+    their cost follows the kept rows and not the buffer
+    (MOVED_METRIC says how far). No token is dropped and no
     [.., E, C] tensor exists. Its four stages carry the scopes a device
     trace is read by: `moe_router`, `moe_dispatch`, `moe_experts`,
     `moe_combine` (docs/observability.md "Runtime traces"). Under a mesh
@@ -89,9 +95,15 @@ LOAD_METRIC = "moe_load_max_over_mean"
 # layers: only of a model that holds a share of a wider router's experts
 # (ModelConfig.moe_experts_held), where the even split is held / E
 HELD_METRIC = "moe_held_rows_share"
+# the key, beside it, of the rows that the four row movements of such a
+# layer (rows_to_expert_order, rows_to_token_order, each one's backward)
+# move over the 4 * N * k they would move all at once, mean of the layers:
+# how far the passes follow the held rows and not the buffer
+# (`moved_rows_share`; 1.0 where the one pass stays)
+MOVED_METRIC = "moe_moved_rows_share"
 # what of a loss's aux the step's metrics carry, each the mean of the
 # step's micro-batches
-STEP_METRICS = (LOAD_METRIC, HELD_METRIC)
+STEP_METRICS = (LOAD_METRIC, HELD_METRIC, MOVED_METRIC)
 # the `checkpoint_name` of the dropless experts' two grouped products (the
 # second under rows_to_token_order, which keeps it for the backward):
 # selective recomputation saves weight-matmul outputs, and knows a
@@ -227,32 +239,31 @@ def _aux_losses(cfg: ModelConfig, logits, gates, frac):
 def layer_stats(aux, load) -> jnp.ndarray:
     """One MoE layer's [aux loss, load statistic] as block_forward hands
     it up the layer scan; `load` of a layer that holds a share of its
-    router's experts is [load statistic, share of the rows routed to held
-    experts] and, of a serving step that says which rows are read, the
-    held experts such a row reached (moe_block_dropless): all go up."""
+    router's experts is the [4]-vector moe_block_dropless says (behind
+    the load statistic the held rows' share, the experts read, the moved
+    rows' share): all go up."""
     if load.ndim:
         return jnp.concatenate([aux[None], load])
     return jnp.stack([aux, load])
 
 
-def moe_stats_zero(cfg: ModelConfig, rows_read: bool = False) -> jnp.ndarray:
+def moe_stats_zero(cfg: ModelConfig) -> jnp.ndarray:
     """What merge_layer_stats starts from (a dense stack under a
     shard_map may start from it too, where a scan's carry must not be of
-    rank 0: its layers add their zero scalar to it). rows_read: the call
-    says which rows are read (moe_block_dropless), and a share of the
-    experts counts the experts they reached."""
-    share = cfg.holds_expert_share
-    return jnp.zeros((2 + share * (1 + rows_read),), jnp.float32)
+    rank 0: its layers add their zero scalar to it): [aux loss, load
+    statistic] and, of a share of the experts, the three numbers
+    moe_block_dropless puts behind them. The serving engine reads those
+    by their place (inference/paging/engine.py make_forward)."""
+    return jnp.zeros((2 + 3 * cfg.holds_expert_share,), jnp.float32)
 
 
 def merge_layer_stats(acc: jnp.ndarray, new: jnp.ndarray) -> jnp.ndarray:
     """Across layers the aux losses add, the worst layer's load statistic
-    stands, and what stands behind them adds: the held rows' shares
-    (language_model.lm_loss takes their mean), the experts read. A layer
-    without experts hands up a zero that may be the shorter."""
+    stands, and what stands behind them adds: the held and the moved
+    rows' shares (language_model.lm_loss takes their means), the experts
+    read. A layer without experts hands up its zero."""
     return jnp.stack([acc[0] + new[0], jnp.maximum(acc[1], new[1])]
-                     + [acc[i] + new[i] if i < new.shape[0] else acc[i]
-                        for i in range(2, acc.shape[0])])
+                     + [acc[i] + new[i] for i in range(2, acc.shape[0])])
 
 
 def aux_loss_of(moe_aux: jnp.ndarray) -> jnp.ndarray:
@@ -296,17 +307,136 @@ def _take_rows(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return a.at[idx].get(mode="promise_in_bounds")
 
 
-def _sum_of_choices(rows: jnp.ndarray, inv: jnp.ndarray,
+# A call that hands `kept` (a share of the experts, or a serving step's
+# `rows_read`) has its live rows in front: the held groups are the prefix
+# [0, kept.sum()) of expert order, and nothing reads the rows behind. Its
+# four row movements then run block by block over the live rows alone
+# (`_walk_blocks` says when, and how many rows a trip), inside the two
+# custom_vjps below, so nothing is differentiated through a loop.
+#
+# Rows a trip moves on the expert-order side, tokens a trip on the token
+# side, from the chip (PR 69). The expert side, alone at the Mellum cell's
+# call (tools/moe_rows_bench.py: 131,072 rows of 2,304, 0.44 of them
+# kept): 1.87-1.97 ms a pass from 1,024 to 8,192 rows a trip (2.8 at 512,
+# 2.4 at 16,384: a trip's own cost against the half block behind the last
+# kept row; the one pass 4.9-5.1). The token side, in the cell's step on
+# the final tree (one traced run a block size, one seed; ms a step,
+# `moe_dispatch` + `moe_combine` / `other` + `unnamed` /
+# `train_step_ms_p50`; the parent's one pass 87.7 / 12.85 / 378.5): 512
+# tokens a trip 37.0 / 12.6 / 313.4, 1,024 44.3 / 12.1 / 322.1, 2,048
+# 46.5 / 11.4 / 324.4; on another seed 256 tokens a trip 34.3 / 13.1 /
+# 317.5 against 512's 32.3 / 12.6 / 315.2 (presumably a chain's gathered blocks stand in fast
+# memory until one fusion adds them, and eight of 512 rows do where eight
+# of 2,048 do not: no trace by operation was read for it). Alone, a pass
+# had read the same from 512 to 4,096 tokens a trip, which is why the
+# step's own numbers decide. (With a
+# `lax.switch` a block in place of a loop a chain the same three read
+# 36.4 / 23.9 / 321.8, 50.3 / 15.2 / 330.0 and 53.7 / 13.2 / 330.5: the
+# conditionals' 20 us each fell under no scope.)
+_EXPERT_BLOCK = 2048
+_TOKEN_BLOCK = 512
+# The (token, choice) rows from which the passes walk; under it the one
+# pass over all of them stays (`_walk_blocks` has the sweep's numbers).
+_WALK_MIN_ROWS = 65536
+
+
+def _walk_blocks(num_tokens: int, k: int) -> Optional[Tuple[int, int]]:
+    """(rows a trip on the expert-order side, tokens a trip on the token
+    side) of a call of num_tokens tokens of k choices that hands `kept`,
+    or None: the one pass over all N*k rows stays. No block hangs over
+    the buffer's end: a token count that `_TOKEN_BLOCK` does not divide
+    (no caller hands one at these row counts) keeps the one pass too. The
+    one place that says so: the passes and `moved_rows_share` ask here,
+    and what would try other blocks (tests, tools/moe_rows_bench.py)
+    replaces this function, not the numbers.
+
+    What decides is the row count alone, from a sweep on the chip (PR 69:
+    tools/moe_rows_bench.py, `chiprun_out/pr69*/moe_rows.jsonl`; the four
+    passes of a call added up, 0.44 of the rows kept by a router whose
+    tokens choose apart; ms, one pass -> walk at these blocks). Rows of
+    2,304 (the Mellum cell's width): 131,072 rows 20.6 -> 10.1, 65,536
+    rows 9.5 -> 5.4, 32,768 rows 4.9 -> 3.0, 16,384 rows 1.84 -> 1.61,
+    8,192 rows 0.80 -> 1.07. Rows of 1,024 (the Nemotron cell's): 180,224
+    rows 12.8 -> 7.0, 90,112 rows 6.0 -> 3.9, 45,056 rows 2.06 -> 2.31,
+    22,528 rows 1.06 -> 1.46, the cell's chunk of 11,264 rows
+    0.69 -> 0.84, its tick of 1,408 rows 0.43 -> 0.52 (the three under
+    16,384 rows: of this PR's first form, a `lax.switch` a block). A walk
+    pays its sorts and a few microseconds a trip whatever it moves, and a
+    gather out of a source of a few megabytes runs at several times the
+    large source's rate, so the small calls keep the one pass: from
+    65,536 rows on the walk won at both widths, under 16,384 it lost at
+    both, and between them the two widths disagree (no cell lies
+    there)."""
+    if num_tokens * k < _WALK_MIN_ROWS or num_tokens % _TOKEN_BLOCK:
+        return None
+    return _EXPERT_BLOCK, _TOKEN_BLOCK
+
+
+def _blocks(rows, block: int):
+    """The blocks of `block` rows that hold the first `rows` rows."""
+    return (rows + (block - 1)) // block
+
+
+def _choice_counts(kept: jnp.ndarray) -> jnp.ndarray:
+    """[N] int32: how many of a token's k choices are kept."""
+    return jnp.sum(kept, axis=1, dtype=jnp.int32)
+
+
+def _by_falling_count(counts: jnp.ndarray, k: int):
+    """(place_of [N], sorted [N]) for counts [N] in 0..k: the place each
+    token takes when the tokens stand by falling count, token order kept
+    among equals, and the counts in that order. A counting sort in dense
+    sums over the k + 1 values (no sort of N keys, and no scatter)."""
+    values = jnp.arange(k, -1, -1, dtype=jnp.int32)       # falling
+    is_value = counts[:, None] == values                   # [N, k + 1]
+    upto = jnp.cumsum(is_value, axis=0, dtype=jnp.int32)   # ... and here
+    ends = jnp.cumsum(upto[-1])                            # a value's last
+    first = ends - upto[-1]
+    place_of = jnp.sum(jnp.where(is_value, first + upto - 1, 0), axis=1)
+    ended = jnp.arange(counts.shape[0])[:, None] >= ends[:-1]
+    return place_of, k - jnp.sum(ended, axis=1, dtype=jnp.int32)
+
+
+def moved_rows_share(kept: jnp.ndarray) -> jnp.ndarray:
+    """MOVED_METRIC of a call that hands kept [N, k]: the rows its four
+    passes move (`rows_to_expert_order`, `rows_to_token_order`, and each
+    one's backward) over 4 * N * k. Counted by the functions the passes
+    take their trips from. 1.0 where the one pass stays."""
+    n, k = kept.shape
+    blocks = _walk_blocks(n, k)
+    if blocks is None:
+        return jnp.ones((), jnp.float32)
+    expert, token = blocks
+    counts = _choice_counts(kept)
+    expert_side = _blocks(jnp.sum(counts), expert) * expert
+    # a block of tokens sorted by falling count gathers its first token's
+    # count a token (`_sum_of_kept_choices`); one more row a token goes
+    # back to token order
+    firsts = _by_falling_count(counts, k)[1][::token]
+    token_side = jnp.sum(firsts) * token + n
+    return ((expert_side + token_side).astype(jnp.float32)
+            / jnp.float32(2 * n * k))
+
+
+def _sum_of_choices(rows: jnp.ndarray, inv: jnp.ndarray, dtype,
                     topw: Optional[jnp.ndarray] = None,
                     kept: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """float32 [N, h]: the sum over a token's k choices j of
-    rows[inv[n, j]] (times topw[n, j], where gates are given), rows
-    [N*k, h] standing in expert order; of a share of the experts, the
-    sum over the choices kept [N, k] alone. One gather of N rows a choice,
-    added up as it arrives: the compiler fuses each gather with its
-    multiply-add, where one gather of all N*k rows stands alone in front
-    of the sum with an [N, k, h] temporary between them (on the chip 16.0
-    against 20.9 ms a step of the OLMoE cell, each way)."""
+    """[N, h] in `dtype`: the sum over a token's k choices j of
+    rows[inv[n, j]] (times topw[n, j], where gates are given), in float32
+    and rounded once, rows [N*k, h] standing in expert order; of a share
+    of the experts, the sum over the choices kept [N, k] alone. One gather
+    of N rows a choice, added up as it arrives: the compiler fuses each
+    gather with its multiply-add, where one gather of all N*k rows stands
+    alone in front of the sum with an [N, k, h] temporary between them
+    (on the chip 16.0 against 20.9 ms a step of the OLMoE cell, each
+    way). With `kept`, at the row counts `_walk_blocks` names, the
+    gathers follow the kept (token, choice) pairs instead
+    (`_sum_of_kept_choices`): the same terms added in the same order."""
+    if kept is not None:
+        blocks = _walk_blocks(*inv.shape)
+        if blocks is not None:
+            return _sum_of_kept_choices(rows, inv, dtype, topw, kept,
+                                        blocks[1])
     total = None
     for j in range(inv.shape[1]):
         term = _take_rows(rows, inv[:, j]).astype(jnp.float32)
@@ -315,7 +445,92 @@ def _sum_of_choices(rows: jnp.ndarray, inv: jnp.ndarray,
         if kept is not None:
             term = jnp.where(kept[:, j, None], term, 0.0)
         total = term if total is None else total + term
-    return total
+    return total.astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "block"))
+def _sum_of_kept_choices(rows, inv, dtype, topw, kept,
+                         block: int) -> jnp.ndarray:
+    """`_sum_of_choices` over the kept choices, moving held_rows + N rows
+    (and block slack) where the one pass moves N*k. Each token's choices
+    are put kept-first, in their order (stable: the kept terms are added
+    in the one pass's order, and its masked terms are zeros, so the
+    float32 sum keeps its bits, but for the sign of a zero: a sum whose
+    kept terms are all -0.0 reads -0.0 here and, with a masked term
+    among them, +0.0 there), and the tokens by falling count of kept
+    choices. A block of `block` such tokens then needs as many gathers as
+    its first token keeps choices, c: the one pass's chain cut to c
+    gathers (the chip's compiler lands each in fast memory and adds them
+    up in one fusion, the running sum never in HBM, rounded once and
+    written once; a sum carried from slot to slot through a float32
+    [N, h] in HBM cost as much as the rows nobody reads), a token of the
+    block that keeps fewer masking its last terms. The blocks stand by
+    falling c, so those of one chain are one stretch of them: a loop a
+    chain, k + 1 loops with traced bounds a pass, every block written by
+    exactly one into a buffer nobody had written (a `lax.switch` a block
+    is a `conditional` of ~20 us on the chip whatever it moves, under no
+    scope's name: 5.3 ms of the Mellum step at 512 tokens a block).
+    Nothing here leans
+    on tokens choosing alike: a corpus on which they do makes the counts
+    one number, and a router whose tokens all choose differently still
+    has at most k blocks in which the count changes. The sums go back to
+    token order through one gather of N rows of `dtype`. The tokens'
+    order is a counting sort (`_by_falling_count`), a token's choices are
+    sorted k at a time and move to the token's place by a key-value sort
+    down the columns of [N, k]: sorts the chip's compiler is quick with
+    (one sort of N*k (count, token, kept) keys with the places and gates
+    behind them took it two minutes, most of the step's compile). A
+    function of its own (an inner `jit`), so that the layers and passes
+    of a step that call it alike trace and lower its k + 1 chains once;
+    it reads nothing of this module but what it is handed (`block`
+    among it), so what its cache holds cannot go stale."""
+    n, k = inv.shape
+    place_of, counts = _by_falling_count(_choice_counts(kept), k)
+    # a token's kept choices in front, in their order; then each token's
+    # places (and gates) to where the token went
+    moved = [inv.astype(jnp.int32)] + ([] if topw is None else [topw])
+    _, *moved = jax.lax.sort(
+        [jnp.logical_not(kept).astype(jnp.int32), *moved],
+        dimension=1, is_stable=True, num_keys=1)
+    _, *moved = jax.lax.sort(
+        [jnp.broadcast_to(place_of[:, None], (n, k)), *moved],
+        dimension=0, num_keys=1)
+    # slot-major, so that a slot's tokens stand side by side
+    places, *gates = (a.T for a in moved)
+
+    def trips(length: int):
+        """The loop body of the blocks whose chain is `length` gathers."""
+        def trip(i, sums):
+            at = i * block
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, at, block,
+                                                         axis=-1)
+            where = cut(places)
+            gate = cut(gates[0]) if gates else None
+            keeps = cut(counts)
+            total = jnp.zeros((block, rows.shape[1]), jnp.float32)
+            for j in range(length):
+                term = _take_rows(rows, where[j]).astype(jnp.float32)
+                if gate is not None:
+                    term = term * gate[j][:, None]
+                term = jnp.where((keeps > j)[:, None], term, 0.0)
+                total = term if j == 0 else total + term
+            return jax.lax.dynamic_update_slice(
+                sums, total.astype(dtype), (at, 0))
+        return trip
+
+    # with the other kernels: imported where it is used (ops/attention.py)
+    from megatron_tpu.ops.pallas.grouped_matmul import unwritten_rows
+
+    # the blocks stand by falling count of their first token, so those of
+    # one chain are one stretch of them: a loop a chain, each block in
+    # exactly one (every row of `sums` is written)
+    firsts = counts[::block]
+    sums = unwritten_rows((n, rows.shape[1]), dtype, rows)
+    for length in range(k + 1):
+        sums = jax.lax.fori_loop(
+            jnp.sum(firsts > length, dtype=jnp.int32),
+            jnp.sum(firsts >= length, dtype=jnp.int32), trips(length), sums)
+    return _take_rows(sums, place_of)
 
 
 def _token_of(order: jnp.ndarray, k: int) -> jnp.ndarray:
@@ -324,6 +539,14 @@ def _token_of(order: jnp.ndarray, k: int) -> jnp.ndarray:
     first CSE (grouped_matmul.group_visits has the story); the rows are
     not negative, so it floors."""
     return jax.lax.div(order, jnp.asarray(k, order.dtype))
+
+
+def _walk_live_rows(kept, block: int, body, init):
+    """`body(first row, carry)` over the blocks of `block` rows that hold
+    the live rows of expert order, the first kept.sum() of them."""
+    return jax.lax.fori_loop(
+        0, _blocks(jnp.sum(kept, dtype=jnp.int32), block),
+        lambda i, carry: body(i * block, carry), init)
 
 
 @jax.custom_vjp
@@ -335,8 +558,27 @@ def rows_to_expert_order(xf, order, inv, kept=None):
     gradient is written out as what it is here: the cotangent's rows
     gathered through `inv`, each token's k summed in float32 and rounded
     once. A share of the experts hands `kept` [N, k], the choices whose
-    expert it holds (else None): the others' rows give no gradient."""
-    return _take_rows(xf, _token_of(order, inv.shape[1]))
+    expert it holds (else None): the others' rows give no gradient, and
+    at the row counts `_walk_blocks` names they are not moved either: the
+    kept rows are the first kept.sum() of expert order, the gather fills
+    the blocks that hold them, and the rows behind are nobody's: what
+    the buffer held (`unwritten_rows`; `experts_mlp`, ragged)."""
+    tokens = _token_of(order, inv.shape[1])
+    blocks = None if kept is None else _walk_blocks(*inv.shape)
+    if blocks is None:
+        return _take_rows(xf, tokens)
+    # with the other kernels: imported where it is used (ops/attention.py)
+    from megatron_tpu.ops.pallas.grouped_matmul import unwritten_rows
+
+    block = blocks[0]
+
+    def fill(at, xs):
+        rows = _take_rows(xf, jax.lax.dynamic_slice(tokens, (at,), (block,)))
+        return jax.lax.dynamic_update_slice(xs, rows, (at, 0))
+
+    return _walk_live_rows(
+        kept, block, fill,
+        unwritten_rows((tokens.shape[0], xf.shape[1]), xf.dtype, xf))
 
 
 def _to_expert_fwd(xf, order, inv, kept):
@@ -345,7 +587,7 @@ def _to_expert_fwd(xf, order, inv, kept):
 
 def _to_expert_bwd(res, dxs):
     inv, kept = res
-    return (_sum_of_choices(dxs, inv, kept=kept).astype(dxs.dtype),
+    return (_sum_of_choices(dxs, inv, dxs.dtype, kept=kept),
             None, None, None)
 
 
@@ -364,8 +606,12 @@ def rows_to_token_order(out, topw, order, inv, dtype, kept=None):
     moved to token order as N*k scalars. Residuals are out, the gates and
     the permutation: no second copy of the rows. A share of the experts
     hands `kept` [N, k], the choices whose expert it holds (else None):
-    the sum is over those, and the others' gates get no gradient."""
-    return _sum_of_choices(out, inv, topw, kept).astype(dtype)
+    the sum is over those, and the others' gates get no gradient; at the
+    row counts `_walk_blocks` names both directions move the kept rows
+    alone (`_sum_of_kept_choices` forward; backward the blocks of expert
+    order that hold them, d_out's rows behind being what out's were:
+    nobody's, `experts_mlp` ragged)."""
+    return _sum_of_choices(out, inv, dtype, topw, kept)
 
 
 def _to_token_fwd(out, topw, order, inv, dtype, kept):
@@ -389,10 +635,34 @@ def _to_token_bwd(dtype, res, dy):
         # what comes back for them
         topw = jnp.where(kept, topw, 0.0)
     tokens = _token_of(order, inv.shape[1])
-    dy_rows = _take_rows(dy, tokens).astype(jnp.float32)
-    w = _permute(topw.reshape(-1), inv.reshape(-1))    # gates, expert order
-    d_out = (dy_rows * w[:, None]).astype(out.dtype)
-    d_w = jnp.sum(out.astype(jnp.float32) * dy_rows, axis=-1)
+
+    def gates():                                       # in expert order
+        return _permute(topw.reshape(-1), inv.reshape(-1))
+
+    def of_rows(tokens, gates, out):
+        dy_rows = _take_rows(dy, tokens).astype(jnp.float32)
+        return ((dy_rows * gates()[:, None]).astype(out.dtype),
+                jnp.sum(out.astype(jnp.float32) * dy_rows, axis=-1))
+
+    blocks = None if kept is None else _walk_blocks(*inv.shape)
+    if blocks is None:
+        d_out, d_w = of_rows(tokens, gates, out)
+    else:
+        block, w = blocks[0], gates()
+
+        def fill(at, carry):
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, at, block)
+            rows, sums = of_rows(cut(tokens), lambda: cut(w), cut(carry[0]))
+            return (jax.lax.dynamic_update_slice(carry[0], rows, (at, 0)),
+                    jax.lax.dynamic_update_slice(carry[1], sums, (at,)))
+
+        # d_out takes out's place block by block: a block of out is read
+        # for the gates' gradient and written over with the rows' (the
+        # product is this pass's alone to read, so no second buffer of
+        # N*k rows and no fill of one); behind the kept rows d_out keeps
+        # what out held there
+        d_out, d_w = _walk_live_rows(
+            kept, block, fill, (out, jnp.zeros(out.shape[:1], jnp.float32)))
     d_topw = _permute(d_w, order).reshape(topw.shape)  # back to token order
     if kept is not None:
         d_topw = jnp.where(kept, d_topw, 0.0)
@@ -447,7 +717,10 @@ def experts_mlp(cfg: ModelConfig, p: Dict[str, Any], xs: jnp.ndarray,
     kernel visits those rows' tiles, so in every product's result (and in
     the gradient of its rows) they hold whatever the buffer held: callers
     read the result's rows through their own `kept` (`rows_to_token_order`,
-    `rows_to_expert_order`). What the kernels contract over rows
+    `rows_to_expert_order`), and what they hand in behind the groups need
+    be no row of anybody's (`rows_to_expert_order` leaves the buffer as
+    it found it there where it walks the kept rows alone). What the
+    kernels contract over rows
     (`moe_tgmm`, the last group's boundary window) has to stay clear of
     them: the kernels that hold the activation zero both operands' rows
     there; the other form sets the first product's to zero on the way
@@ -547,12 +820,20 @@ def moe_block_dropless(
     the token's k choices, as everywhere) and the load-balance statistics
     are over all num_experts; the rows whose expert is held elsewhere sort
     behind the held ones, where no group of the grouped products reaches
-    them (the buffer is all N*k rows still: no routing leaves a row out),
-    and y is the part of the layer's result that the held experts give:
-    the parts of all the shares add up to the whole layer's. Nothing
-    stands in for the other chips or their rows.
-    The load statistic is then a [2]-vector: behind it the share of the
-    N*k rows that went to held experts (HELD_METRIC).
+    them, and y is the part of the layer's result that the held experts
+    give: the parts of all the shares add up to the whole layer's. Nothing
+    stands in for the other chips or their rows. The buffer has room for
+    all N*k rows still, so no routing leaves a row out; what follows the
+    routing is how much of it is walked: the held rows are its first
+    sum(group_sizes), the kernels visit those groups alone, and the row
+    movements around them fill, read and add block by block over the
+    blocks that hold held rows (`_walk_blocks`: from the row count on at
+    which a loop's trips cost less than the rows nobody reads).
+    The load statistic is then a [4]-vector: behind it the share of the
+    N*k rows that went to held experts (HELD_METRIC); the held experts a
+    read row reached (`rows_read`, below; zero without the word); and the
+    share of the 4 * N * k rows of the four row movements that they moved
+    (MOVED_METRIC; 1.0 where they are one pass each).
 
     rows_read (a serving step's; None: every position counts): row b's
     first rows_read[b] positions are ones whose result somebody reads.
@@ -565,8 +846,9 @@ def moe_block_dropless(
     positions that count get the bits they get without the word. Of a
     share of the experts the held rows' share is then of the call's N*k
     rows still, those that count and went to held experts over all, and
-    a third number stands behind it: how many of the experts held here a
-    counted row reached (the serving engine's counters read both).
+    the number behind it is how many of the experts held here a counted
+    row reached (the serving engine's counters read both, by their
+    place).
 
     This function is the unsharded form: experts replicated, tokens
     unsharded (or sharded in ways the manual path can't host — batch not
@@ -604,10 +886,11 @@ def moe_block_dropless(
             topi = jnp.where(mine, topi, held)
             group_sizes = _expert_counts(topi.reshape(-1), held)
         if share:
-            load = jnp.stack(
-                [load, jnp.sum(group_sizes).astype(jnp.float32) / (N * k)]
-                + ([] if rows_read is None else
-                   [jnp.sum(group_sizes > 0, dtype=jnp.float32)]))
+            load = jnp.stack([
+                load, jnp.sum(group_sizes).astype(jnp.float32) / (N * k),
+                jnp.zeros((), jnp.float32) if rows_read is None
+                else jnp.sum(group_sizes > 0, dtype=jnp.float32),
+                moved_rows_share(mine)])
 
     xe = xf
     if cfg.moe_latent_size is not None:

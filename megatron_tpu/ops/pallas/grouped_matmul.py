@@ -828,6 +828,32 @@ def grouped_mlp_of_layer(xs: jnp.ndarray, w_in: jnp.ndarray,
         plan=_MlpPlan(*plans, activation, False, None))[0]
 
 
+def unwritten_rows(shape, dtype, after: jnp.ndarray) -> jnp.ndarray:
+    """An array of `shape` that holds whatever its memory held: a buffer
+    for a caller that writes the rows somebody reads and leaves the rest
+    (ops/moe.py rows_to_expert_order, of a share of the experts: the rows
+    behind the held groups, which no kernel here visits and whose part of
+    a boundary window `moe_tgmm` masks on both sides, `ragged`). On one
+    TPU a kernel that writes nothing: XLA has no such array, its `empty`
+    is a broadcast of zero, and a fill of the Mellum cell's 604 MB buffer
+    is 0.85 ms, twice a layer. Zeros elsewhere.
+
+    after: an array the buffer is wanted behind (the rows it will take).
+    The kernel names it as an operand and reads nothing of it: a producer
+    without operands stands nowhere in the step's order, and with four
+    such buffers afloat the chip's scheduler left the flash forward's
+    lane-padded log-sum-exp (268 MB a layer) lying until the backward
+    pass: 6.67 GB of temporaries in the Mellum step against 6.07 with the
+    operand (the described-v5e compile, PR 69)."""
+    if not _one_tpu():
+        return jnp.zeros(shape, dtype)
+    return pl.pallas_call(
+        lambda _, out: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name="moe_unwritten_rows")(after)
+
+
 def _one_tpu() -> bool:
     """The kernels serve one TPU: GSPMD cannot partition a Mosaic call, and
     under a mesh of several devices the products stay `lax.ragged_dot`,

@@ -543,6 +543,9 @@ def test_olmoe_cell_step_fits_one_v5e(olmoe_step):
     assert _per_device_bytes(compiled) < 15e9
     text = compiled.as_text()
     assert "ragged-dot" not in text
+    # every expert held: the row movements are one pass each, no loop
+    assert not [name for name in re.findall(r'op_name="([^"]+/while)"', text)
+                if "mlp" in scope_tokens(name)]
     # the program's own kernels by name, nothing unnamed. Selective
     # recomputation saves the grouped products like the dots they are, so
     # `moe_gmm` runs four times (two forward products, two gradients of
@@ -631,16 +634,36 @@ def test_mellum_cell_step_fits_one_v5e(mellum_step):
     rows (the share path has no shorter one: no routing leaves a row
     out), and a seventh is the second product computed again for the gate's gradient
     (a share does not keep it: ops/moe.py rows_to_token_order); none is
-    left to XLA's `ragged-dot`."""
+    left to XLA's `ragged-dot`. The row movements around the kernels walk
+    the live rows (PR 69): loops under `moe_dispatch` and `moe_combine`,
+    forward and transposed, no copy of the buffer around them, and the
+    step at 14.52 GB where one pass over every row had 14.18 (which
+    holds only while the buffer the dispatch fills names the rows it will
+    take as an operand, `grouped_matmul.unwritten_rows`: produced out of
+    nothing it stood nowhere in the step's order, and the scheduler left
+    the flash forward's lane-padded log-sum-exp lying until the backward
+    pass, 0.6 GB more)."""
     from megatron_tpu.telemetry.tracing.events import scope_tokens
 
     compiled = mellum_step
-    assert 0.25 * 16e9 < _per_device_bytes(compiled) < 15.75 * GIB
+    assert 0.25 * 16e9 < _per_device_bytes(compiled) < 14.6e9
     text = compiled.as_text()
     assert "ragged-dot" not in text
+    loops = {(scope, "transpose(" in name)
+             for name in re.findall(r'op_name="([^"]+/while)"', text)
+             for scope in ("moe_dispatch", "moe_combine")
+             if scope in scope_tokens(name)}
+    assert loops == {(scope, side) for scope in ("moe_dispatch", "moe_combine")
+                     for side in (False, True)}, loops
+    assert not re.findall(r"= bf16\[131072,2304\][^ ]* copy\(", text)
     assert collections.Counter(_kernels_named(text)) == {
         "flash_fwd": 4, "flash_bwd": 4, "flash_bwd_stats": 4,
-        "moe_gmm": 20, "moe_tgmm": 8}
+        "moe_gmm": 20, "moe_tgmm": 8,
+        # the buffers the walks fill block by block (the dispatch's,
+        # forward and made again; the token side's sums, of the combine
+        # and of the dispatch's backward): a kernel that writes nothing,
+        # for XLA's fill of zeros
+        "moe_unwritten_rows": 16}
     kinds = collections.Counter()
     for toks in _kernel_name_stacks(text):
         kernel = toks[-2]
@@ -649,7 +672,10 @@ def test_mellum_cell_step_fits_one_v5e(mellum_step):
             assert toks[at + 1] in ("attn_sliding", "attn_full"), toks
             kinds[kernel, toks[at + 1]] += 1
         else:
-            assert toks.index("mlp") < toks.index("moe_experts"), toks
+            scopes = (("moe_dispatch", "moe_combine")
+                      if kernel == "moe_unwritten_rows" else ("moe_experts",))
+            assert any(toks.index("mlp") < toks.index(scope)
+                       for scope in scopes if scope in toks), toks
     assert kinds == {(k, "attn_sliding"): 3 for k in (
         "flash_fwd", "flash_bwd", "flash_bwd_stats")} | {
         (k, "attn_full"): 1 for k in (

@@ -355,6 +355,38 @@ def test_the_held_rows_share_is_journalled_only_by_a_share():
     assert float(seen["share0"][moe.LOAD_METRIC]) >= 1.0
 
 
+def test_the_moved_rows_share_is_journalled_only_by_a_share(monkeypatch):
+    """Beside the held rows' share a share's step says how many of the
+    rows its layers' four row movements moved (ops/moe.py MOVED_METRIC):
+    1.0 at the toy's row count, where each is one pass; under 1 and over
+    the held rows' share once they walk the live rows (here at blocks of
+    the toy's size), with the loss the one pass gives. A model that holds
+    every expert journals neither."""
+    from megatron_tpu.config import OptimizerConfig, TrainingConfig
+    from megatron_tpu.training.optimizer import init_train_state
+    from megatron_tpu.training.train_step import make_train_step
+
+    def metrics(name):
+        _, cfg, params = case(name)
+        opt = OptimizerConfig(lr=1e-3)
+        state = init_train_state(opt, params)
+        step = make_train_step(cfg, opt, TrainingConfig(), num_microbatches=2)
+        return jax.jit(step)(state, sequences())[1]
+
+    assert moe.MOVED_METRIC not in metrics("uncut")
+    one_pass = metrics("share0")
+    assert float(one_pass[moe.MOVED_METRIC]) == 1.0
+    monkeypatch.setattr(moe, "_walk_blocks", lambda n, k: (16, 8))
+    walked = metrics("share0")
+    assert (float(walked[moe.HELD_METRIC]) < float(walked[moe.MOVED_METRIC])
+            < 1.0)
+    assert float(walked[moe.HELD_METRIC]) == float(one_pass[moe.HELD_METRIC])
+    assert float(walked["loss"]) == pytest.approx(
+        float(one_pass["loss"]), rel=1e-6)
+    assert float(walked["grad_norm"]) == pytest.approx(
+        float(one_pass["grad_norm"]), rel=1e-5)
+
+
 # --- YaRN -------------------------------------------------------------------
 
 def test_yarn_table_is_the_closed_form_at_the_sources_numbers():
@@ -642,6 +674,18 @@ def test_the_step_record_carries_the_held_rows_share(rehearsed):
                 "flash_fwd_by_kind_roofline_pct",
                 "flash_bwd_by_kind_roofline_pct",
                 "moe_held_experts_roofline_pct"} & set(line["metrics"])
+
+
+def test_the_step_record_carries_the_moved_rows_share(rehearsed):
+    """Every `step` record of the rehearsed share carries
+    `moe_moved_rows_share` beside the held rows' share, in (0, 1] (1.0 at
+    the toy's row count: ops/moe.py `_walk_blocks`); no entry of
+    BENCHMARK.json reads it, so the line's metrics do not hold it."""
+    line, _, journal = rehearsed
+    steps = [r for r in journal if r.get("kind") == "step"]
+    assert steps and all(0.0 < r["moe_moved_rows_share"] <= 1.0
+                         for r in steps)
+    assert "moe_moved_rows_share" not in line["metrics"]
 
 
 def test_the_step_program_counts_each_kinds_tiles(rehearsed):

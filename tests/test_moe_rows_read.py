@@ -70,7 +70,7 @@ def test_rows_that_count_get_their_bits_and_the_others_reach_no_expert(
     assert (counted <= all_rows).all()
     if cfg.holds_expert_share:
         pairs = x.shape[0] * x.shape[1] * cfg.moe_top_k
-        assert load.shape == (3,)
+        assert load.shape == (4,)
         assert round(float(load[1]) * pairs) == counted.sum()
         assert float(load[2]) == (counted > 0).sum()
     # a row nobody reads: the shared expert's part, no routed expert's
@@ -86,7 +86,7 @@ def test_rows_that_count_get_their_bits_and_the_others_reach_no_expert(
 
 def test_without_the_word_the_block_is_the_one_it_was(layer):
     """No count: the same jaxpr as a call that never heard of one (the
-    training step's), and the statistics' shapes of before."""
+    training step's), and the statistics' shapes of a call without one."""
     cfg, p = layer
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 8, 64), F32)
     told = jax.make_jaxpr(
@@ -95,7 +95,10 @@ def test_without_the_word_the_block_is_the_one_it_was(layer):
         p, x)
     assert str(told) == str(plain)
     _, _, load = moe.moe_block_dropless(cfg, p, x)
-    assert load.shape == ((2,) if cfg.holds_expert_share else ())
+    # (a share's: the held rows' share, no expert read, the moved rows')
+    assert load.shape == ((4,) if cfg.holds_expert_share else ())
+    if cfg.holds_expert_share:
+        assert float(load[2]) == 0.0
 
 
 def _as_the_parent(monkeypatch):

@@ -283,7 +283,7 @@ def test_the_expert_layer_matches_the_reference():
     ours, theirs = layer_of(seeded_params(cfg), "moe", 2)
     u = jax.random.normal(jax.random.PRNGKey(8), (2, SEQ, 64), F32)
     got, aux, load = moe.moe_block(cfg, ours, u)
-    assert float(aux) == 0.0 and load.shape == (2,)
+    assert float(aux) == 0.0 and load.shape == (4,)
     for row in range(2):
         close(got[row], reference.latent_moe(u[row], theirs, TOY, mm), EXACT)
     chosen, _ = reference.route(u.reshape(-1, 64), theirs, TOY, mm)
