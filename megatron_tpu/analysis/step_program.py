@@ -9,6 +9,10 @@ its collectives stand, and which of its instructions lost their name.
                                  that does work and carries no `op_name`
     kernel_calls(text)           for each named Pallas kernel, its calls
                                  and how many of them are recomputation
+    flash_k_operands(text)       for each flash training kernel, the shape
+                                 its calls take K in: [B, Hkv, S, D] where
+                                 the kernels read K and V by KV head, the
+                                 query heads' where they were broadcast
     relaid_arrays(text, n)       every instruction that writes an array of
                                  n bytes or more a second time and computes
                                  nothing: a `copy` into another layout, a
@@ -284,6 +288,37 @@ def kernel_calls(text: str) -> Dict[str, Dict[str, int]]:
         rec["rematted"] += REMATTED in parts
         rec["times"] += program.times[comp]
     return dict(sorted(out.items()))
+
+
+# the training flash kernels (ops/pallas/flash_template.py) take the
+# position offset, q, K, V, ...: K is their third operand, and a Pallas
+# custom call states its operands' shapes
+_FLASH_TRAINING = frozenset({"flash_fwd", "flash_bwd", "flash_bwd_dq",
+                             "flash_bwd_dkv"})
+_OPERAND_SHAPES = re.compile(
+    r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}")
+
+
+def flash_k_operands(text: str) -> Dict[str, List[str]]:
+    """For each training flash kernel of a compiled program (by the name
+    a trace finds it under), the shapes its calls take K in, as
+    `dtype[dims]`, sorted, each once. The static counter of the kernels'
+    GQA addressing: a call that reads K and V by KV head takes
+    `bf16[B,Hkv,S,D]` (`bf16[2,4,8192,128]` in the benchmark's Mellum
+    cell, `bf16[1,8,4096,128]` in `train_mistral7b_seq4k`), one whose K
+    was broadcast in front of it the query heads' `bf16[B,Hq,S,D]`. Empty
+    where the kernels are interpreted: there is no custom call to find."""
+    program = Program(text)
+    out: Dict[str, set] = {}
+    for comp, line, name, results, opcode in program.instructions():
+        if opcode != "custom-call" or KERNEL_TARGET not in line:
+            continue
+        kernel = kernel_of(scope_tokens(program.op_name(comp, line)))
+        stated = _OPERAND_SHAPES.search(line)
+        if kernel in _FLASH_TRAINING and stated:
+            dtype, dims = _RESULT.findall(stated.group(1))[2]
+            out.setdefault(kernel, set()).add(f"{dtype}[{dims}]")
+    return {kernel: sorted(shapes) for kernel, shapes in sorted(out.items())}
 
 
 def _layout(results: str) -> str:

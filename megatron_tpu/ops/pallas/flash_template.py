@@ -51,11 +51,16 @@ instantiation of the one template in this module, with four knobs:
                 |                         | in a tile's scratch and dq over kv
                 |                         | blocks in a scratch of the head's
                 |                         | whole sequence (five matmuls a
-                |                         | pair). A sequence whose dq does
+                |                         | pair). Under GQA dk/dv sum over the
+                |                         | KV head's query heads too, in two
+                |                         | such scratches of the KV head's
+                |                         | whole sequence, float32, rounded
+                |                         | once. A sequence whose sums do
                 |                         | not fit VMEM (`fused_bwd_fits`:
-                |                         | beyond 64k rows of 128 bf16) runs
-                |                         | the split pair instead: one kernel
-                |                         | for dq, one for dk/dv (seven).
+                |                         | beyond 64k rows of 128 bf16, 16k
+                |                         | under GQA) runs the split pair
+                |                         | instead: one kernel for dq, one
+                |                         | for dk/dv (seven).
                 |                         | Before either, `flash_bwd_stats`
                 |                         | spreads lse and delta = rowsum(do
                 |                         | * o) over the lanes of the one
@@ -106,7 +111,12 @@ reference): float32 operands, at the backend's default matmul precision.
 
 Layouts: public entries take the framework-native [B, S, H, D]; the
 training kernels run on [B, H, S, D] so the (S, D) tile is MXU-facing
-(their transposes are of activations). The decode kernels read a KV cache
+(their transposes are of activations). K and V stay at their own heads,
+[B, Hkv, S, D]: the kernels' index maps send query head h to KV head
+h // groups (`_kv_head`; groups = Hq // Hkv, read off the operands'
+shapes), so a KV head's tiles are read where they lie by each query head
+of its group and nothing is broadcast to the query heads, and dk and dv
+come back at that shape. The decode kernels read a KV cache
 where it lies, [rows, positions, Hkv, D] (ops/kv_store.py): no cache is
 transposed or copied for them. Kernels run in interpreter mode on CPU
 hosts (tests/CI) and compile for real on TPU.
@@ -231,6 +241,18 @@ def supported(q_len: int, kv_len: int, block_q: int, block_k: int) -> bool:
 #   causal [1,32,4096] 4096       4 edge, 6 interior  1.85 -> 1.41    2.58 -> 2.28
 #          [8,16,4096] 4096       (the four-chip shard) 7.54 -> 5.77 10.18 -> 8.99
 #          [1,16,4096] none                           0.83 -> 0.71    1.29 -> 1.16
+#
+# Since PR 70 the kernels read K and V by KV head (`_kv_head`) and the
+# fused backward sums dk and dv over a KV head's query heads in its own
+# scratch. Same tool, ms a call, K and V broadcast to the query heads
+# outside the kernels -> read where they lie; then the layer's forward and
+# backward with XLA's broadcast and group sum around the kernels:
+#
+#   q [B,H,S,128] over KV heads      flash_fwd       flash_bwd      fwd layer       bwd layer
+#   window [2,32,8192] over 4      4.53 -> 4.55   7.15 -> 6.65   4.97 -> 4.55    8.03 -> 6.66
+#   full   [2,32,8192] over 4      9.83 -> 9.90  16.57 -> 16.60 10.27 -> 9.90   17.46 -> 16.62
+#   causal [1,32,4096] over 8      1.41 -> 1.41   2.28 -> 2.27   1.42 -> 1.41    2.43 -> 2.27
+#          [8,16,4096] over 4      5.77 -> 5.77   8.99 -> 8.94   6.26 -> 5.77    9.97 -> 8.95
 #
 # By tile (us; read off the same calls and their variants): a forward tile
 # whole under the mask of its traced positions 6.5, under a mask of static
@@ -461,13 +483,24 @@ def _delta_arr(delta):
     return jnp.asarray(delta, jnp.int32).reshape(1)
 
 
-def _outer_tile_map(b, h, outer, inner, off_ref):
+def _kv_head(h, groups: int):
+    """The KV head that query head h reads: a KV head's `groups` query
+    heads lie side by side. One query head a KV head (MHA, and the ring's
+    stripes, which arrive broadcast) names its own, with no division."""
+    return h if groups == 1 else jax.lax.div(h, groups)
+
+
+def _outer_tile_map(groups: int = 1):
     """Index map of the tile a grid (b, h, outer, inner) holds across its
-    inner, sequential axis (the scalar-prefetch operand rides along)."""
-    return (b, h, outer, 0)
+    inner, sequential axis (the scalar-prefetch operand rides along).
+    `groups`: the tile is a KV head's, addressed by the query head of the
+    grid (`_kv_head`); 1 for a tile of the query head's own."""
+    def index(b, h, outer, inner, off_ref):
+        return (b, _kv_head(h, groups), outer, 0)
+    return index
 
 
-def _inner_tile_map(live_tiles, n: int):
+def _inner_tile_map(live_tiles, n: int, groups: int = 1):
     """Index map of the tile that walks the inner axis of a grid
     (b, h, outer, inner) whose scalar-prefetch operand is the position
     offset. `live_tiles(outer, delta=)` is the (first, last) inner tile
@@ -476,25 +509,44 @@ def _inner_tile_map(live_tiles, n: int):
     dk/dv's): a step outside it
     names the nearest live tile instead of its own, so the pipeline sees
     the block it already holds and issues no DMA. The second clip keeps a
-    row with no live tile at all inside the grid of n tiles."""
+    row with no live tile at all inside the grid of n tiles. `groups`:
+    the walked tiles are a KV head's, read where they lie by each query
+    head of its group (`_kv_head`): nothing is copied to the query heads."""
     def index(b, h, outer, inner, off_ref):
         lo, hi = live_tiles(outer, delta=off_ref[0])
-        return (b, h, jnp.clip(jnp.clip(inner, lo, hi), 0, n - 1), 0)
+        return (b, _kv_head(h, groups),
+                jnp.clip(jnp.clip(inner, lo, hi), 0, n - 1), 0)
     return index
 
 
-def _compiler_params(vmem_bytes: int, outer: str = "parallel"):
+def _group_walk_map(live_tiles, nq: int, groups: int):
+    """Index map of the q-side tile of the split dk/dv grid over a KV
+    head, (b, hkv, kv tile, j): its inner axis walks the q tiles of the
+    head's first query head, then the second's, ... (j = g * nq + qi), so
+    a kv tile's sums over its whole group stand in one tile's scratch.
+    The block-skip's clamp is `_inner_tile_map`'s."""
+    def index(b, hkv, outer, j, off_ref):
+        lo, hi = live_tiles(outer, delta=off_ref[0])
+        return (b, hkv * groups + jax.lax.div(j, nq),
+                jnp.clip(jnp.clip(jax.lax.rem(j, nq), lo, hi), 0, nq - 1), 0)
+    return index
+
+
+def _compiler_params(vmem_bytes: int, outer: str = "parallel",
+                     heads: str = "parallel"):
     """Batch and heads parallel, the inner sequence axis the sequential
     reduction; the outer sequence axis parallel too unless a kernel sums
     over it as well (`outer="arbitrary"`: the fused backward's dq; on a
     megacore part only B x H is then left to split across the two cores,
-    which the single-core v5e this was measured on cannot show). The
+    which the single-core v5e this was measured on cannot show), and the
+    heads unless a kernel sums over a KV head's query heads
+    (`heads="arbitrary"`: the fused backward's dk and dv under GQA). The
     scoped VMEM limit is raised only when the footprint needs it."""
     limit = None
     if vmem_bytes > _DEFAULT_SCOPED_VMEM:
         limit = min(vmem_bytes, _MAX_SCOPED_VMEM)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", outer, "arbitrary"),
+        dimension_semantics=("parallel", heads, outer, "arbitrary"),
         vmem_limit_bytes=limit)
 
 
@@ -508,11 +560,14 @@ def _fwd_vmem_bytes(block_q, block_k, D, item):
 
 
 def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
-    """q [B,Hq,Sq,D], k/v [B,Hq,Skv,D] (kv already group-broadcast).
-    Returns (o [B,Hq,Sq,D], lse [B,Hq,Sq]). delta: traced q-vs-k global
-    position offset (ring stripes); None = aligned."""
+    """q [B,Hq,Sq,D], k/v [B,Hkv,Skv,D]: a KV head's keys and values as
+    they lie, read by each query head of its group through the index maps
+    (`_kv_head`), broadcast nowhere. Returns (o [B,Hq,Sq,D],
+    lse [B,Hq,Sq]). delta: traced q-vs-k global position offset (ring
+    stripes); None = aligned."""
     B, H, Sq, D = q.shape
     Skv = k.shape[2]
+    groups = H // k.shape[1]
     nk = Skv // block_k
 
     kernel = functools.partial(
@@ -520,10 +575,10 @@ def _fwd(q, k, v, scale, causal, window, block_q, block_k, delta=None):
         block_q=block_q, block_k=block_k,
         classes=_tile_classes(Sq // block_q, nk, block_q, block_k, causal,
                               window, delta))
-    q_map = _outer_tile_map
+    q_map = _outer_tile_map()
     kv_map = _inner_tile_map(functools.partial(
         masks.prefill_live_kv_tiles, block_q=block_q, block_k=block_k,
-        causal=causal, window=window), nk)
+        causal=causal, window=window), nk, groups)
     o, lse = _named_pallas_call(
         "flash_fwd", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -579,26 +634,46 @@ def _bwd_tile(q, k, v, do, stats, mask, scale: float):
 def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
                 *, scale: float, causal: bool, window: Optional[int],
-                block_q: int, block_k: int, classes):
+                block_q: int, block_k: int, classes, groups: int):
     """The fused backward: grid (b, h, ki, qi), q innermost. Every live
     tile pair is visited once and gives all three gradients from one p
-    and one ds (five matmuls a piece of it: `_visit_tile`). dk and dv of
-    the kv tile sum over the inner axis in [BK, D] scratch; dq sums over
+    and one ds (five matmuls a piece of it: `_visit_tile`). dq sums over
     the OUTER axis, so its float32 accumulator holds the head's whole
     sequence, [Sq/BQ, BQ, D], and the dq output block is the head's whole
     [Sq, D]: q tile qi's rows are zeroed on the first kv tile's pass and
-    scaled, cast and written on the last one's. Each sum takes its terms
-    in the order the split pair takes them (ascending tiles, and a
-    tile's pieces in theirs)."""
+    scaled, cast and written on the last one's. dk and dv of the kv tile
+    sum over the inner axis: with one query head a KV head in [BK, D]
+    scratch, written as the tile's last q tile has passed. With `groups`
+    of them they are the KV head's, summed over its query heads too, which
+    the grid visits one after another (h = hkv * groups + g): their
+    float32 accumulators then hold the KV head's whole sequence,
+    [Skv/BK, BK, D], as dq's holds the query head's, zeroed under the
+    group's first head and scaled, rounded ONCE and written under its
+    last, through an output block of the KV head's whole [Skv, D]. Each
+    sum takes its terms in the order the split pair takes them (a kv
+    tile's: query heads ascending, tiles ascending, and a tile's pieces
+    in theirs)."""
     ki = pl.program_id(2)
     qi = pl.program_id(3)
     nk = pl.num_programs(2)
     nq = pl.num_programs(3)
 
-    @pl.when(qi == 0)
+    def of_head(at_tile, g: int):
+        """at_tile, under the g-th query head of the KV head."""
+        if groups == 1:
+            return at_tile
+        return at_tile & (jax.lax.rem(pl.program_id(1), groups) == g)
+
+    def kv_rows(cols):
+        """Where those columns of kv tile ki stand in dk's and dv's
+        accumulators."""
+        return (ki, cols, slice(None)) if groups > 1 else (cols, slice(None))
+
+    @pl.when(of_head(qi == 0, 0))
     def _init_kv():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        tile = (block_k, dk_scr.shape[-1])
+        dk_scr[kv_rows(slice(None))] = jnp.zeros(tile, dk_scr.dtype)
+        dv_scr[kv_rows(slice(None))] = jnp.zeros(tile, dv_scr.dtype)
 
     @pl.when(ki == 0)
     def _init_q():
@@ -615,17 +690,20 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
         # with dv's first and 2.65 with dq's last, at the swept shape)
         ds = ds.astype(q.dtype)
         dq_scr[qi, rows, :] += _dot(ds, k, _NN)
-        dk_scr[cols, :] += _dot(ds, q, _TN)
-        dv_scr[cols, :] += _dot(p.astype(do.dtype), do, _TN)
+        dk_scr[kv_rows(cols)] += _dot(ds, q, _TN)
+        dv_scr[kv_rows(cols)] += _dot(p.astype(do.dtype), do, _TN)
 
     _visit_tile(qi, ki, off_ref[0], classes, piece, causal=causal,
                 window=window, block_q=block_q, block_k=block_k)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(of_head(qi == nq - 1, groups - 1))
     def _emit_kv():
+        out = ((0, 0, pl.ds(pl.multiple_of(ki * block_k, block_k), block_k),
+                slice(None)) if groups > 1 else (0, 0))
         # the 1/sqrt(d) of the scores, once on the float32 sum
-        dk_ref[0, 0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[out] = (dk_scr[kv_rows(slice(None))]
+                       * scale).astype(dk_ref.dtype)
+        dv_ref[out] = dv_scr[kv_rows(slice(None))].astype(dv_ref.dtype)
 
     @pl.when(ki == nk - 1)
     def _emit_q():
@@ -664,12 +742,17 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
 def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
                 *, scale: float, causal: bool, window: Optional[int],
-                block_q: int, block_k: int, classes):
+                block_q: int, block_k: int, classes, groups: int):
+    """Grid (b, hkv, ki, j): the inner axis walks the q tiles of each
+    query head of the KV head in turn (`_group_walk_map`; one query head
+    a KV head: j is the q tile), and the kv tile's two sums run over all
+    of it."""
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
+    j = pl.program_id(3)
+    qi = (j if groups == 1
+          else jax.lax.rem(j, jax.lax.div(pl.num_programs(3), groups)))
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -685,7 +768,7 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, stats_ref,
     _visit_tile(qi, ki, off_ref[0], classes, piece, causal=causal,
                 window=window, block_q=block_q, block_k=block_k)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _emit():
         # the 1/sqrt(d) of the scores, once on the float32 sum
         dk_ref[0, 0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
@@ -703,23 +786,28 @@ def _bwd_vmem_bytes(block_q, block_k, D, item):
             + 6 * block_q * block_k * 4)
 
 
-def _fused_bwd_vmem_bytes(sq, block_q, block_k, D, item):
+def _fused_bwd_vmem_bytes(sq, block_q, block_k, D, item, groups=1):
     """The tiles above (dk's and dv's output tiles and accumulators
     among them) and dq of a head's whole sequence: the float32
-    accumulator and the double-buffered output block."""
+    accumulator and the double-buffered output block. Under GQA
+    (`groups` query heads a KV head) dk and dv of the KV head's whole
+    sequence as well, the same two a gradient."""
+    whole = sq * D * 4 + 2 * sq * D * item
     return (_bwd_vmem_bytes(block_q, block_k, D, item)
-            + sq * D * 4 + 2 * sq * D * item)
+            + whole * (3 if groups > 1 else 1))
 
 
-def fused_bwd_fits(sq: int, d: int, dtype, block_q: int,
-                   block_k: int) -> bool:
+def fused_bwd_fits(sq: int, d: int, dtype, block_q: int, block_k: int,
+                   groups: int = 1) -> bool:
     """Which backward a shape takes. One algorithm, two footprints: the
     fused kernel keeps dq of a head's whole sequence in VMEM, which fits
-    beside the tiles up to 64k rows of 128 bf16; a longer sequence runs
-    the split pair, whose footprint does not grow with the sequence."""
+    beside the tiles up to 64k rows of 128 bf16, and under GQA (`groups`
+    > 1: from the operands' shapes) dk and dv of the KV head's as well,
+    which fits up to 16k rows; a longer sequence runs the split pair,
+    whose footprint does not grow with the sequence."""
     return _fused_bwd_vmem_bytes(
-        sq, block_q, block_k, d,
-        jnp.dtype(dtype).itemsize) <= _MAX_SCOPED_VMEM
+        sq, block_q, block_k, d, jnp.dtype(dtype).itemsize,
+        groups) <= _MAX_SCOPED_VMEM
 
 
 # The two per-row statistics of the backward kernels, lse and
@@ -791,7 +879,7 @@ def _bwd_in_specs(block_q, block_k, D, q_map, kv_map):
 def _bwd_dq(q, k, v, do, stats, scale, causal, window, block_q,
             block_k, offset=None):
     """dq [B,H,Sq,D]: grid (b, h, qi, ki), kv innermost, one float32
-    accumulator per q tile."""
+    accumulator per q tile; k, v [B,Hkv,Skv,D], read by KV head."""
     B, H, Sq, D = q.shape
     nk = k.shape[2] // block_k
     kernel = functools.partial(
@@ -799,10 +887,10 @@ def _bwd_dq(q, k, v, do, stats, scale, causal, window, block_q,
         block_q=block_q, block_k=block_k,
         classes=_tile_classes(Sq // block_q, nk, block_q, block_k, causal, window,
                               offset))
-    q_map = _outer_tile_map
+    q_map = _outer_tile_map()
     kv_map = _inner_tile_map(functools.partial(
         masks.prefill_live_kv_tiles, block_q=block_q, block_k=block_k,
-        causal=causal, window=window), nk)
+        causal=causal, window=window), nk, H // k.shape[1])
     return _named_pallas_call(
         "flash_bwd_dq", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -820,25 +908,29 @@ def _bwd_dq(q, k, v, do, stats, scale, causal, window, block_q,
 
 def _bwd_dkv(q, k, v, do, stats, scale, causal, window, block_q,
              block_k, offset=None):
-    """(dk, dv) [B,H,Skv,D]: grid (b, h, ki, qi), q innermost, two
-    float32 accumulators per kv tile."""
+    """(dk, dv) [B,Hkv,Skv,D]: grid (b, hkv, ki, j), the q tiles of the
+    KV head's query heads innermost, two float32 accumulators per kv
+    tile: the sum over the group stands in them, rounded once."""
     B, H, Sq, D = q.shape
-    Skv = k.shape[2]
+    Hkv, Skv = k.shape[1:3]
+    groups = H // Hkv
     nq = Sq // block_q
     kernel = functools.partial(
         _dkv_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, groups=groups,
         classes=_tile_classes(nq, Skv // block_k, block_q, block_k, causal, window,
                               offset))
-    q_map = _inner_tile_map(functools.partial(
+    live_q = functools.partial(
         masks.prefill_live_q_tiles, block_q=block_q, block_k=block_k,
-        causal=causal, window=window), nq)
-    kv_map = _outer_tile_map
+        causal=causal, window=window)
+    q_map = (_inner_tile_map(live_q, nq) if groups == 1
+             else _group_walk_map(live_q, nq, groups))
+    kv_map = _outer_tile_map()
     return _named_pallas_call(
         "flash_bwd_dkv", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, Skv // block_k, nq),
+            grid=(B, Hkv, Skv // block_k, groups * nq),
             in_specs=_bwd_in_specs(block_q, block_k, D, q_map, kv_map),
             out_specs=[
                 pl.BlockSpec((1, 1, block_k, D), kv_map),
@@ -849,8 +941,8 @@ def _bwd_dkv(q, k, v, do, stats, scale, causal, window, block_q,
                 pltpu.VMEM((block_k, D), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, Skv, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, Skv, D), q.dtype),
         ],
         compiler_params=_compiler_params(
             _bwd_vmem_bytes(block_q, block_k, D, q.dtype.itemsize)),
@@ -860,48 +952,60 @@ def _bwd_dkv(q, k, v, do, stats, scale, causal, window, block_q,
 
 def _bwd_fused(q, k, v, do, stats, scale, causal, window, block_q,
                block_k, offset=None):
-    """(dq [B,H,Sq,D], dk, dv [B,H,Skv,D]) in one call: the dk/dv grid
+    """(dq [B,H,Sq,D], dk, dv [B,Hkv,Skv,D]) in one call: the dk/dv grid
     (b, h, ki, qi) with dq summed across its outer axis in a float32
-    scratch of the head's whole sequence. dq is the FIRST result: the
-    benchmark's cost file counts over it."""
+    scratch of the head's whole sequence and, under GQA, dk and dv across
+    the KV head's query heads in two of the KV head's (`_bwd_kernel`).
+    dq is the FIRST result: the benchmark's cost file counts over it."""
     B, H, Sq, D = q.shape
-    Skv = k.shape[2]
+    Hkv, Skv = k.shape[1:3]
+    groups = H // Hkv
     nq = Sq // block_q
+    nk = Skv // block_k
     kernel = functools.partial(
         _bwd_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k,
-        classes=_tile_classes(nq, Skv // block_k, block_q, block_k, causal, window,
+        block_q=block_q, block_k=block_k, groups=groups,
+        classes=_tile_classes(nq, nk, block_q, block_k, causal, window,
                               offset))
     q_map = _inner_tile_map(functools.partial(
         masks.prefill_live_q_tiles, block_q=block_q, block_k=block_k,
         causal=causal, window=window), nq)
-    kv_map = _outer_tile_map
+    kv_map = _outer_tile_map(groups)
+    if groups == 1:
+        dkv_spec = pl.BlockSpec((1, 1, block_k, D), kv_map)
+        dkv_scr = pltpu.VMEM((block_k, D), jnp.float32)
+    else:
+        # held for all of a KV head's steps, written back once
+        dkv_spec = pl.BlockSpec(
+            (1, 1, Skv, D),
+            lambda b, h, ki, qi, off_ref: (b, _kv_head(h, groups), 0, 0))
+        dkv_scr = pltpu.VMEM((nk, block_k, D), jnp.float32)
     return _named_pallas_call(
         "flash_bwd", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, Skv // block_k, nq),
+            grid=(B, H, nk, nq),
             in_specs=_bwd_in_specs(block_q, block_k, D, q_map, kv_map),
             out_specs=[
                 # held for all of a head's steps, written back once
                 pl.BlockSpec((1, 1, Sq, D),
                              lambda b, h, ki, qi, off_ref: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                dkv_spec, dkv_spec,
             ],
             scratch_shapes=[
                 pltpu.VMEM((nq, block_q, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
+                dkv_scr, dkv_scr,
             ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Skv, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, Skv, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, Skv, D), q.dtype),
         ],
         compiler_params=_compiler_params(
             _fused_bwd_vmem_bytes(Sq, block_q, block_k, D,
-                                  q.dtype.itemsize), outer="arbitrary"),
+                                  q.dtype.itemsize, groups),
+            outer="arbitrary",
+            heads="parallel" if groups == 1 else "arbitrary"),
         interpret=_interpret(),
     )(_delta_arr(offset), q, k, v, do, stats)
 
@@ -916,11 +1020,13 @@ def _bwd_split(q, k, v, do, stats, *tile_args):
 
 def _bwd(q, k, v, o, lse, do, scale, causal, window, block_q, block_k,
          offset=None):
-    """(dq, dk, dv) given the forward's output and its log-sum-exp
-    [B,H,Sq] (compact: one float a row): the fused kernel wherever its
-    footprint fits (`fused_bwd_fits`), else the split pair."""
+    """(dq [B,Hq,Sq,D], dk, dv [B,Hkv,Skv,D]) given the forward's output
+    and its log-sum-exp [B,H,Sq] (compact: one float a row): the fused
+    kernel wherever its footprint fits (`fused_bwd_fits`), else the
+    split pair."""
     stats = _bwd_stats(lse, o, do, block_q)
-    fused = fused_bwd_fits(q.shape[2], q.shape[3], q.dtype, block_q, block_k)
+    fused = fused_bwd_fits(q.shape[2], q.shape[3], q.dtype, block_q, block_k,
+                           q.shape[1] // k.shape[1])
     return (_bwd_fused if fused else _bwd_split)(
         q, k, v, do, stats, scale, causal, window, block_q, block_k, offset)
 
@@ -966,8 +1072,9 @@ _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def stripe_fwd(q, k, v, delta, window, scale, block, causal=True):
     """(o float32, lse [B, H, c]) for one stripe pair, [B, H, c, D] layout
-    (k/v already group-broadcast). ONE kernel covers every stripe
-    relation: `delta` (traced, an SMEM scalar inside the kernel) is the
+    (the ring hands k/v group-broadcast, one KV head a query head, and
+    takes dk/dv back at that shape: ops/ring_attention.py). ONE kernel
+    covers every stripe relation: `delta` (traced, an SMEM scalar inside the kernel) is the
     q-vs-k global-position offset, so the causal mask k <= q + delta
     renders the aligned diagonal (delta 0), fully-visible past blocks
     (delta >= c) and shifted sliding-window bands alike. causal=False =
@@ -997,13 +1104,17 @@ def flash_mha(
 ) -> jnp.ndarray:
     """The training/prefill instantiation in framework layout: fused
     forward + the FA-2 recompute backward via custom_vjp — jax.grad
-    through this never builds the XLA O(S^2) gradient. GQA broadcasts
-    K/V per group (dk/dv group-sum falls out of the broadcast's own
-    vjp). Tiles come from `pick_blocks` unless block_q / block_k are
-    given. Raises ValueError for geometries the template doesn't cover."""
+    through this never builds the XLA O(S^2) gradient. GQA (Hq a
+    multiple of Hkv, read off the operands' shapes) broadcasts nothing:
+    the kernels read a KV head's tiles where they lie for each query
+    head of its group, and the backward sums dk and dv over the group in
+    float32 and rounds once. Tiles come from `pick_blocks` unless
+    block_q / block_k are given. Raises ValueError for geometries the
+    template doesn't cover."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
-    groups = hq // hkv
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads over {hkv} kv heads")
     picked_q, picked_k = pick_blocks(sq, d, q.dtype)
     block_q = _fit_block(block_q, sq) if block_q else picked_q
     block_k = _fit_block(block_k, skv) if block_k else picked_k
@@ -1021,9 +1132,6 @@ def flash_mha(
     qt = jnp.transpose(q, (0, 2, 1, 3))              # [B,Hq,S,D]
     kt = jnp.transpose(k, (0, 2, 1, 3))              # [B,Hkv,S,D]
     vt = jnp.transpose(v, (0, 2, 1, 3))
-    if groups > 1:
-        kt = jnp.repeat(kt, groups, axis=1)
-        vt = jnp.repeat(vt, groups, axis=1)
     scale = float(1.0 / (d ** 0.5))
     o = _flash_bhsd(qt, kt, vt, scale, causal, sliding_window,
                     block_q, block_k)

@@ -1373,7 +1373,9 @@ class TrainLoop:
         checked against (docs/observability.md "Runtime traces"); and
         each Pallas kernel's calls with how many of them are a
         recomputation (`kernel_calls`: under `selective` the flash
-        forward has none); and, for each kind of attention layer, the
+        forward has none) and the shape the flash training kernels take K
+        in (`flash_k_operands`: the KV heads', where they read K and V
+        by KV head); and, for each kind of attention layer, the
         tiles a head of the flash kernels visits by class and the score
         elements they compute over the visible pairs (`attention_tiles`:
         `_attention_tiles`)."""
@@ -1390,6 +1392,7 @@ class TrainLoop:
                 where = step_program.collectives(text)
                 unnamed = step_program.unnamed_instructions(text)
                 kernels = step_program.kernel_calls(text)
+                k_operands = step_program.flash_k_operands(text)
                 # traced under the mesh, as the step was: the same answer
                 summed = kernel_summed(
                     self.cfg.model, params, batch_avals, n_micro,
@@ -1411,7 +1414,7 @@ class TrainLoop:
             kernel_summed_share=(sum(n for n, s in sizes if s)
                                  / sum(n for n, _ in sizes)),
             collectives=where, unnamed_instructions=unnamed,
-            kernel_calls=kernels,
+            kernel_calls=kernels, flash_k_operands=k_operands,
             attention_tiles=_attention_tiles(self.cfg.model, batch_avals))
 
     # -- loop ---------------------------------------------------------------

@@ -573,6 +573,45 @@ def test_kernel_calls_count_the_backward_a_step_runs(backward, want):
 
 
 # ---------------------------------------------------------------------------
+# step_program.flash_k_operands: the shape the flash kernels take K in
+# ---------------------------------------------------------------------------
+
+_K_COMPACT, _K_BROADCAST = "bf16[1,8,4096,128]", "bf16[1,32,4096,128]"
+# a flash call as the chip's compiler writes it: the operands by name, their
+# shapes under `operand_layout_constraints`
+_FLASH_CALL = (
+    '  %{name}.{n} = {results} custom-call(%off, %q.{n}, %k.{n}, %v.{n}), '
+    'custom_call_target="tpu_custom_call", operand_layout_constraints='
+    '{{s32[1]{{0}}, {q}{{3,2,1,0}}, {k}{{3,2,1,0}}, {k}{{3,2,1,0}}}}, '
+    'frontend_attributes={{kernel_metadata={{}}}}, metadata={{op_name="jit('
+    'train_step)/layer_stack/transpose(jvp(while))/body/transpose(jvp('
+    'attention))/attn_core/{name}/pallas_call"}}')
+
+
+@pytest.mark.parametrize("k, backward", [
+    (_K_COMPACT, ["flash_bwd"]), (_K_BROADCAST, ["flash_bwd"]),
+    (_K_COMPACT, ["flash_bwd_dq", "flash_bwd_dkv"]),
+], ids=["by_kv_head", "broadcast", "split_pair"])
+def test_flash_k_operands_say_whether_k_was_broadcast(k, backward):
+    """The journal's static counter of the kernels' GQA addressing: each
+    training flash kernel by name with the shape of its third operand,
+    the KV heads' where the kernels read K and V by KV head and the
+    query heads' where K was repeated in front of them; a call that
+    states no operand shapes (the text's forward) is not guessed at, and
+    the row-statistics kernel takes no K."""
+    from megatron_tpu.analysis import step_program
+
+    lines = [_FLASH_CALL.format(name=name, n=n, q=_DQ[:-9], k=k,
+                                results=f"({_DQ}, {_DQ})")
+             for n, name in enumerate(backward, 2)]
+    lines.append(_KERNEL_LINE.format(name="flash_bwd_stats", n=9,
+                                     results=_DQ, remat=""))
+    text = _STEP_TEXT.format(layers=2, backward="\n".join(lines))
+    assert step_program.flash_k_operands(text) == {
+        name: [k] for name in sorted(backward)}
+
+
+# ---------------------------------------------------------------------------
 # step_program.relaid_arrays: what a program writes a second time unchanged
 # ---------------------------------------------------------------------------
 

@@ -116,22 +116,24 @@ def _kernel_cases():
     ]
     # the tiles `pick_blocks` gives other lengths (window 4096 clips the
     # longer ones): a pick that does not fit VMEM or is not tile-aligned
-    # fails here. The backward is the one fused kernel while dq of a
-    # head's whole sequence fits in VMEM beside the tiles
-    # (`ft.fused_bwd_fits`): 65536 rows are the longest that do, and
-    # 131072 run the split pair (fewer heads there, so that the tensors of
-    # the case fit the chip)
+    # fails here. The backward is the one fused kernel while its sums over
+    # a whole sequence fit in VMEM beside the tiles (`ft.fused_bwd_fits`):
+    # dq of the query head's and, where several query heads share a KV
+    # head, dk and dv of the KV head's. Under GQA 16384 rows are the
+    # longest that do and 32768 run the split pair; with a KV head a query
+    # head 65536 still fit and 131072 do not (fewer heads there, so that
+    # the tensors of the case fit the chip)
+    fused = ["flash_bwd", "flash_bwd_stats", "flash_fwd"]
+    split = ["flash_bwd_dkv", "flash_bwd_dq", "flash_bwd_stats", "flash_fwd"]
     cases += [
         (f"forward_backward_s{s}", fwd_bwd,
-         [((1, s, hq, D), bf16), ((1, s, hq // 4, D), bf16),
-          ((1, s, hq // 4, D), bf16)],
+         [((1, s, hq, D), bf16), ((1, s, hkv, D), bf16),
+          ((1, s, hkv, D), bf16)],
          kernels)
-        for s, hq, kernels in (
-            (1024, HQ, ["flash_bwd", "flash_bwd_stats", "flash_fwd"]),
-            (16384, HQ, ["flash_bwd", "flash_bwd_stats", "flash_fwd"]),
-            (65536, 8, ["flash_bwd", "flash_bwd_stats", "flash_fwd"]),
-            (131072, 4, ["flash_bwd_dkv", "flash_bwd_dq", "flash_bwd_stats",
-                      "flash_fwd"]))
+        for s, hq, hkv, kernels in (
+            (1024, HQ, HKV, fused), (16384, HQ, HKV, fused),
+            (32768, 8, 2, split), (65536, 8, 8, fused),
+            (131072, 4, 1, split))
     ]
     # the training cells' calls (benchmark/configs/: Mellum's window-1024
     # and full layers at 8192 x micro-batch 2, the TP 2 x DP 2 cell's
@@ -229,8 +231,8 @@ def _fwd_bwd_cell(window, q, k, v):
 
 # cell's layer: (micro-batch, sequence, query heads, kv heads, window) of
 # one chip's flash calls
-_TRAIN_CELLS = {"mellum_sliding": (2, 8192, 32, 8, 1024),
-                "mellum_full": (2, 8192, 32, 8, None),
+_TRAIN_CELLS = {"mellum_sliding": (2, 8192, 32, 4, 1024),
+                "mellum_full": (2, 8192, 32, 4, None),
                 "mistral_tp2dp2": (8, 4096, 16, 4, 4096),
                 "olmoe": (1, 4096, 16, 16, None)}
 
@@ -310,14 +312,15 @@ def test_kernel_carries_its_name_for_v5e(topo, name):
     f"forward_backward_{cell}" for cell in _TRAIN_CELLS])
 def test_training_kernels_fit_the_vmem_their_formulas_ask_for(topo, name):
     """Each training kernel is compiled under the scoped-VMEM limit its
-    own formula gives (`_fwd_vmem_bytes`, `_fused_bwd_vmem_bytes`; the
-    default where that is more), and Mosaic refuses a kernel that needs
+    own formula gives (`_fwd_vmem_bytes`, `_fused_bwd_vmem_bytes` at the
+    call's query heads a KV head; the default where that is more), and Mosaic refuses a kernel that needs
     more than its limit: the compile above, with a body a class of tile
     in each kernel, is the proof that the formulas still bound them. The
     classes engage at every cell's shape: a kernel that fell back to the
     one masked body would compile too."""
     _, _, args, _ = next(c for c in _CASES if c[0] == name)
     (b, s, hq, d), item = args[0][0], 2
+    groups = hq // args[1][0][2]
     window = WINDOW if name == "forward_backward" else _TRAIN_CELLS[
         name[len("forward_backward_"):]][4]
     block_q, block_k = ft.pick_blocks(s, d, jnp.bfloat16)
@@ -330,7 +333,7 @@ def test_training_kernels_fit_the_vmem_their_formulas_ask_for(topo, name):
             asked[m.group(1)] = int(m.group(2))
     want = {"flash_fwd": ft._fwd_vmem_bytes(block_q, block_k, d, item),
             "flash_bwd": ft._fused_bwd_vmem_bytes(s, block_q, block_k, d,
-                                                  item)}
+                                                  item, groups)}
     assert ft._bwd_vmem_bytes(block_q, block_k, d, item) < want["flash_bwd"]
     for kernel, formula in want.items():
         assert asked[kernel] == max(formula, ft._DEFAULT_SCOPED_VMEM)
@@ -955,6 +958,38 @@ def test_train_step_tp2_dp2_names_its_kernels_and_regions(tp2_dp2_step):
               for n in set(re.findall(r'op_name="([^"]+)"', text))]
     for scope in REGION_SCOPES:
         assert any(scope in toks for toks in stacks), scope
+
+
+# fixture, K as one chip's flash calls take it: [micro-batch a chip, KV
+# heads a chip, sequence, head]
+_K_OPERANDS = {
+    "tp2_dp2": ("tp2_dp2_step", f"bf16[1,{HKV // 2},{SEQ},{D}]"),
+    "mellum": ("mellum_step", "bf16[2,4,8192,128]"),
+    "olmoe": ("olmoe_step", f"bf16[1,16,{SEQ},{D}]"),
+}
+
+
+@pytest.mark.parametrize("case", list(_K_OPERANDS))
+def test_the_step_hands_the_flash_kernels_k_by_kv_head(request, case):
+    """The compiled steps' flash calls take K (and V) at the KV heads'
+    shape: nothing is broadcast to the query heads in front of the
+    kernels, in the forward or in the backward, whose dk and dv leave it
+    at that shape too while dq, of the query heads', stays its first
+    result. Under TP 2 each shard holds 16 query heads over 4 KV heads:
+    the plan shards heads, so the group's size is the same on every
+    shard. What a traced run journals as `step_program.flash_k_operands`
+    (analysis/step_program.py), read off the same text."""
+    from megatron_tpu.analysis import step_program
+
+    fixture, k = _K_OPERANDS[case]
+    step = request.getfixturevalue(fixture)
+    text = (step[0] if isinstance(step, tuple) else step).as_text()
+    assert step_program.flash_k_operands(text) == {
+        "flash_bwd": [k], "flash_fwd": [k]}
+    results = [re.findall(r"\w+\[[\d,]+\]", line[:line.index(" custom-call(")])
+               for line in text.splitlines()
+               if re.match(r"\s*%flash_bwd(\.\d+)? = ", line)]
+    assert results and all((dk, dv) == (k, k) for _, dk, dv in results)
 
 
 @pytest.mark.parametrize("recompute", ["selective", "full"])

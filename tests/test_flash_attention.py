@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Var
 
 from megatron_tpu.ops.attention import attention
 from megatron_tpu.ops.pallas import flash_template as ft
@@ -282,8 +283,9 @@ def test_fused_backward_equals_the_split_pair_in_bf16(mask, offset):
 @pytest.mark.parametrize("hkv", [1, 2], ids=["gqa", "mha"])
 @pytest.mark.parametrize("mask", list(_MASKS))
 def test_fused_backward_matches_xla_gradient(mask, hkv, block):
-    """Through `flash_mha` (transposes, the GQA repeat and its vjp's sum
-    over the group) against the gradient of the XLA attention."""
+    """Through `flash_mha` (transposes; under GQA the kernels' reads by
+    KV head and the backward's sum over the group) against the gradient
+    of the XLA attention."""
     causal, window = _MASKS[mask]
     q, k, v = _qkv(s=256, hq=2, hkv=hkv, d=64)
 
@@ -455,3 +457,285 @@ def test_a_half_tile_hardware_cannot_slice_falls_back(monkeypatch):
     assert ft._tile_classes(2, 2, 128, 128, True, None, None) is None
     assert ft._tile_classes(2, 2, 256, 256, True, None, None)
     assert ft._tile_classes(2, 2, 256, 512, True, None, None) is None
+
+
+# ---------------------------------------------------------------------------
+# GQA: the kernels read K and V by KV head (`ft._kv_head` in their index
+# maps) and the backward sums dk and dv over a KV head's query heads in its
+# float32 scratch; nothing of the query heads' shape is made of K or V
+# ---------------------------------------------------------------------------
+
+_GQA_HEADS = 8
+
+
+@pytest.mark.parametrize("backward", ["fused", "split"])
+@pytest.mark.parametrize("window", [None, 64])
+@pytest.mark.parametrize("groups", [1, 2, 4, 8])
+def test_compact_kv_matches_the_dense_path(monkeypatch, groups, window,
+                                           backward):
+    """`flash_mha` on K and V as they lie, 8 query heads over 8 / 4 / 2 / 1
+    KV heads, S 256 in tiles of 64 (a window smaller than the sequence
+    leaves dead tiles), through either backward: o, dq, dk, dv against
+    the dense XLA path of ops/attention.py."""
+    if backward == "split":
+        monkeypatch.setattr(ft, "fused_bwd_fits", lambda *a, **k: False)
+    q, k, v = _qkv(s=256, hq=_GQA_HEADS, hkv=_GQA_HEADS // groups, d=64)
+    do = jnp.asarray(RNG.standard_normal(q.shape), jnp.float32)
+    flash = functools.partial(flash_mha, sliding_window=window, block_q=64,
+                              block_k=64)
+    o, vjp = jax.vjp(flash, q, k, v)
+    want_o, want_vjp = jax.vjp(
+        functools.partial(attention, sliding_window=window), q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o),
+                               rtol=2e-3, atol=2e-3)
+    for name, a, b in zip(("dq", "dk", "dv"), vjp(do), want_vjp(do)):
+        assert a.shape == b.shape, name
+        assert _share_of_range(a, b) <= 2e-3, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("mask", ["causal", "window64"])
+@pytest.mark.parametrize("groups", [2, 4])
+def test_grouped_fused_backward_equals_the_split_pair_bit_for_bit(
+        groups, mask, dtype):
+    """Under GQA too each of dk's and dv's sums takes its terms in the
+    split pair's order (a kv tile's: query heads ascending, q tiles
+    ascending) into float32 and is rounded once, so no bit differs
+    between the fused kernel's whole-sequence accumulators and the
+    pair's tile."""
+    causal, window = _MASKS[mask]
+    q, _, _, do = _bhsd_case(dtype, 4)
+    _, k, v, _ = _bhsd_case(dtype, 4 // groups, seed=5)
+    fused, split = _backwards(q, k, v, do, causal, window, 64, None)
+    for name, got, want in zip(("dq", "dk", "dv"), fused, split):
+        assert got.shape == (k.shape if name != "dq" else q.shape)
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            err_msg=name)
+
+
+def test_the_group_sum_is_float32_with_one_rounding():
+    """bf16, 8 query heads over one KV head: dk and dv are the float32
+    sum over the group rounded once: closer in the mean to the float32
+    reference than the sum of the eight heads' gradients each rounded to
+    bf16 first (what the broadcast's own vjp gave; 0.00070 against
+    0.00085 of the unit normal inputs' scale, whatever the seed)."""
+    rng = np.random.default_rng(13)
+    q, do = (jnp.asarray(rng.standard_normal((1, 8, 256, 64)), jnp.bfloat16)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((1, 1, 256, 64)), jnp.bfloat16)
+            for _ in range(2))
+    scale = float(1.0 / 64 ** 0.5)
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+
+    def grads(q, k, v):
+        o, lse = ft._fwd(q, k, v, scale, True, None, 128, 128)
+        return ft._bwd(q, k, v, o, lse, do.astype(q.dtype), scale, True,
+                       None, 128, 128)
+
+    _, dk, dv = grads(q, k, v)
+    rep = lambda x: jnp.repeat(x, 8, axis=1)  # noqa: E731
+    _, dk_heads, dv_heads = grads(q, rep(k), rep(v))
+    _, want_dk, want_dv = grads(f32(q), f32(k), f32(v))
+    for got, heads, want in ((dk, dk_heads, want_dk),
+                             (dv, dv_heads, want_dv)):
+        assert got.dtype == jnp.bfloat16 and got.shape == (1, 1, 256, 64)
+        summed = heads.sum(axis=1, keepdims=True)    # bf16 terms, bf16 sum
+        off = lambda x: float(jnp.abs(f32(x) - want).mean())  # noqa: E731
+        assert off(got) < 0.9 * off(summed)
+        assert _share_of_range(got, want) <= 4e-3
+
+
+# the four training cells' flash calls: (batch, query heads, kv heads,
+# sequence), and two shapes past the fused kernel's footprint under GQA
+_CELL_SHAPES = {
+    "mellum": (2, 32, 4, 8192, True), "mistral_seq4k": (1, 32, 8, 4096, True),
+    "mistral_tp2dp2": (8, 16, 4, 4096, True),
+    "olmoe": (1, 16, 16, 4096, True),
+    # dk and dv of a KV head's whole sequence beside dq: to 16k rows
+    "gqa_16k": (1, 8, 2, 16384, True), "gqa_32k": (1, 8, 2, 32768, False),
+    "mha_32k": (1, 8, 8, 32768, True), "gqa_64k": (1, 8, 1, 65536, False)}
+
+
+@pytest.mark.parametrize("case", list(_CELL_SHAPES))
+def test_which_backward_a_gqa_shape_takes(monkeypatch, case):
+    """`fused_bwd_fits` with the group's size, which `_bwd` reads off the
+    operands' shapes: every training cell's call takes the fused kernel;
+    where several query heads share a KV head the kernel holds dk and dv
+    of the KV head's whole sequence beside dq, three such sums for one,
+    and a sequence past that footprint takes the split pair."""
+    b, hq, hkv, s, fused = _CELL_SHAPES[case]
+    d, dtype = 128, jnp.bfloat16
+    blocks = ft.pick_blocks(s, d, dtype)
+    groups = hq // hkv
+    assert ft.fused_bwd_fits(s, d, dtype, *blocks, groups) is fused
+    item = jnp.dtype(dtype).itemsize
+    assert (ft._fused_bwd_vmem_bytes(s, *blocks, d, item, groups)
+            - ft._bwd_vmem_bytes(*blocks, d, item)
+            ) == (3 if groups > 1 else 1) * s * d * (4 + 2 * item)
+    taken = []
+    for name in ("_bwd_fused", "_bwd_split"):
+        monkeypatch.setattr(
+            ft, name, lambda *a, name=name: taken.append(name) or (None,) * 3)
+    monkeypatch.setattr(ft, "_bwd_stats", lambda lse, o, do, block_q: None)
+    x = jax.ShapeDtypeStruct((b, hq, s, d), dtype)
+    kv = jax.ShapeDtypeStruct((b, hkv, s, d), dtype)
+    ft._bwd(x, kv, kv, x, None, x, 1.0, True, None, *blocks)
+    assert taken == ["_bwd_fused" if fused else "_bwd_split"]
+
+
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` equation of a jaxpr, those inside nested
+    jaxprs (jit, custom_vjp) too, in order."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+            continue
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                found += _pallas_calls(inner)
+    return found
+
+
+def _fwd_and_vjp(q, k, v, **kwargs):
+    """(jaxpr of the forward, jaxpr of forward + vjp) of `flash_mha`."""
+    flash = functools.partial(flash_mha, **kwargs)
+
+    def both(q, k, v, do):
+        o, vjp = jax.vjp(flash, q, k, v)
+        return (o,) + vjp(do)
+
+    return (jax.make_jaxpr(flash)(q, k, v).jaxpr,
+            jax.make_jaxpr(both)(q, k, v, q).jaxpr)
+
+
+@pytest.mark.parametrize("backward", ["fused", "split"])
+def test_one_query_head_a_kv_head_is_the_program_it_was(monkeypatch,
+                                                        backward):
+    """At groups 1 (MHA; the ring's stripes, which arrive broadcast)
+    every call is what it was before the kernels read K and V by KV head:
+    the grids, the operands' and results' shapes, dk's and dv's tile-sized
+    output blocks and accumulators, heads a parallel axis, and index maps
+    that name the grid's own head. (Character for character the parent's
+    jaxpr when this was written: PERF.md section 6, PR 70.)"""
+    if backward == "split":
+        monkeypatch.setattr(ft, "fused_bwd_fits", lambda *a, **k: False)
+    b, s, h, d, blk = 1, 256, 2, 64, 64
+    x = jax.ShapeDtypeStruct((b, s, h, d), jnp.float32)
+    _, both = _fwd_and_vjp(x, x, x, sliding_window=128, block_q=blk,
+                           block_k=blk)
+    calls = {eqn.params["name"]: eqn for eqn in _pallas_calls(both)}
+    bwd = (["flash_bwd"] if backward == "fused"
+           else ["flash_bwd_dq", "flash_bwd_dkv"])
+    assert list(calls) == ["flash_fwd", "flash_bwd_stats"] + bwd
+    head, lanes, tile = (b, h, s, d), (b, h, s, 128), (1, 1, blk, d)
+    n = s // blk
+    want = {  # name: (operands behind the offset, results, blocks, scratch)
+        "flash_fwd": ([head] * 3, [head, lanes],
+                      [tile] * 4 + [(1, 1, blk, 128)],
+                      [(blk, 1), (blk, 1), (blk, d)]),
+        "flash_bwd": ([head] * 4 + [lanes], [head] * 3,
+                      [tile] * 4 + [(1, 1, blk, 128), (1, 1, s, d), tile,
+                                    tile],
+                      [(n, blk, d), (blk, d), (blk, d)]),
+        "flash_bwd_dq": ([head] * 4 + [lanes], [head],
+                         [tile] * 4 + [(1, 1, blk, 128), tile], [(blk, d)]),
+        "flash_bwd_dkv": ([head] * 4 + [lanes], [head] * 2,
+                          [tile] * 4 + [(1, 1, blk, 128), tile, tile],
+                          [(blk, d), (blk, d)]),
+    }
+    for name in ["flash_fwd"] + bwd:
+        eqn = calls[name]
+        operands, results, blocks, scratch = want[name]
+        mapping = eqn.params["grid_mapping"]
+        assert mapping.grid == (b, h, n, n), name
+        assert [v.aval.shape for v in eqn.invars[1:]] == operands, name
+        assert [v.aval.shape for v in eqn.outvars] == results, name
+        assert [tuple(getattr(dim, "block_size", dim)
+                      for dim in bm.block_shape)
+                for bm in mapping.block_mappings] == blocks, name
+        assert [a.shape for a in mapping.scratch_avals] == scratch, name
+        semantics = eqn.params["compiler_params"][
+            "mosaic_tpu"].dimension_semantics
+        assert semantics[:2] == ("parallel", "parallel"), name
+        for bm in mapping.block_mappings:
+            # batch and head of every block are the grid's own: no
+            # division stands between a head and the block it names
+            index = bm.index_map_jaxpr.jaxpr
+            assert index.outvars[:2] == index.invars[:2], name
+
+
+def _made_of_kv(jaxpr, tainted):
+    """(shapes, results): the shapes of every value a jaxpr computes from
+    its tainted inputs outside the Pallas calls (a kernel's results are
+    its own: o, dq and the gradients are meant to depend on K and V), and
+    which of its results are such values."""
+    tainted = {v for v, t in zip(jaxpr.invars, tainted) if t}
+    shapes = []
+    for eqn in jaxpr.eqns:
+        ins = [isinstance(v, Var) and v in tainted
+               for v in eqn.invars]
+        if eqn.primitive.name == "pallas_call" or not any(ins):
+            continue
+        inner = [getattr(p, "jaxpr", p) for p in eqn.params.values()
+                 if hasattr(getattr(p, "jaxpr", p), "eqns")]
+        outs = [True] * len(eqn.outvars)
+        if inner and len(inner[0].invars) == len(ins):
+            found, outs = _made_of_kv(inner[0], ins)
+            shapes += found
+        for v, t in zip(eqn.outvars, outs):
+            if t:
+                tainted.add(v)
+                shapes.append(v.aval.shape)
+    return shapes, [isinstance(v, Var) and v in tainted
+                    for v in jaxpr.outvars]
+
+
+@pytest.mark.parametrize("backward", ["fused", "split"])
+@pytest.mark.parametrize("groups", [2, 8])
+def test_nothing_of_the_query_heads_shape_is_made_of_k_or_v(monkeypatch,
+                                                            groups, backward):
+    """At groups > 1 neither the forward nor its vjp holds a value of
+    shape [B, Hq, Skv, D] (in either layout) derived from K or V outside
+    the kernels: no broadcast in front of them, no query-head-shaped dk or
+    dv behind them. The kernels take K and V as [B, Hkv, Skv, D] and the
+    backward's dk and dv leave it in that shape; dq stays its FIRST
+    result (benchmark/kernel_costs/flash_bwd.py counts over it)."""
+    if backward == "split":
+        monkeypatch.setattr(ft, "fused_bwd_fits", lambda *a, **k: False)
+    b, s, hq, d = 2, 256, 8, 64
+    hkv = hq // groups
+    q = jax.ShapeDtypeStruct((b, s, hq, d), jnp.float32)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.float32)
+    fwd, both = _fwd_and_vjp(q, kv, kv, block_q=64, block_k=64)
+    for jaxpr in (fwd, both):
+        shapes, _ = _made_of_kv(jaxpr, [False, True, True, False][
+            :len(jaxpr.invars)])
+        assert shapes, "K and V are at least transposed"
+        assert not {(b, hq, s, d), (b, s, hq, d)} & set(shapes)
+        assert set(shapes) <= {(b, hkv, s, d), (b, s, hkv, d)}
+    compact, head = (b, hkv, s, d), (b, hq, s, d)
+    calls = {eqn.params["name"]: eqn for eqn in _pallas_calls(both)}
+    for name, eqn in calls.items():
+        if name == "flash_bwd_stats":
+            continue
+        assert [v.aval.shape for v in eqn.invars[2:4]] == [compact] * 2
+        if name != "flash_bwd_dkv":     # whose grid walks the KV heads
+            # K's and V's blocks: the query head over the group's size,
+            # one truncating division (heads are never negative)
+            for bm in eqn.params["grid_mapping"].block_mappings[1:3]:
+                index = bm.index_map_jaxpr.jaxpr
+                made_by = [e for e in index.eqns
+                           if index.outvars[1] in e.outvars]
+                assert [e.primitive.name for e in made_by] == ["div"], name
+                assert made_by[0].invars[0] == index.invars[1], name
+    results = {name: [v.aval.shape for v in eqn.outvars]
+               for name, eqn in calls.items()}
+    if backward == "fused":
+        assert results["flash_bwd"] == [head, compact, compact]
+    else:
+        assert results["flash_bwd_dq"] == [head]
+        assert results["flash_bwd_dkv"] == [compact, compact]

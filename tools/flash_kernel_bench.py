@@ -4,23 +4,39 @@
     chiprun -- python tools/flash_kernel_bench.py --tree archive_check/parent
 
 Runs `flash_template._fwd` (kernel `flash_fwd`) and `_bwd_fused` (kernel
-`flash_bwd`) at the shapes the training cells call them with, [B, H, S, D]
-bf16 at the tiles `pick_blocks` gives: Mellum's window-1024 and full
-layers at 8192, Mistral's window-4096 layer at 4096 on one chip and as
-the TP 2 x DP 2 cell's shard, OLMoE's causal layer. One JSON line a case:
-ms a call of each kernel (REPS calls queued back to back, one wait), the
-tiles a head visits by class and `computed_over_visible` where the tree
-counts them (`tile_counts`), and the largest difference of the output and
-of the three gradients from the XLA attention's on two heads of the same
-sequence, as a share of the reference's range. --tree points at another
-checkout of the repo (an unpacked parent), for a comparison in one call.
-Needs a TPU.
+`flash_bwd`) at the shapes the training cells call them with, q
+[B, Hq, S, D] and k, v [B, Hkv, S, D] bf16 at the tiles `pick_blocks`
+gives: Mellum's window-1024 and full layers at 8192 (32 query heads over
+4), Mistral's window-4096 layer at 4096 on one chip (32 over 8) and as the
+TP 2 x DP 2 cell's shard (16 over 4), OLMoE's causal layer (16 over 16).
+One JSON line a case, two forms of the same layer side by side:
+
+  `compact`    the kernels on K and V as they lie, a KV head read by each
+               query head of its group through the index maps, dk and dv
+               summed over the group inside `flash_bwd` (a tree whose
+               kernels want K and V at the query heads' shape has no such
+               form, and the line leaves it out);
+  `broadcast`  the form before it: K and V repeated to the query heads
+               outside (`broadcast_ms`, XLA's pass), the kernels on the
+               repeated tensors, dk and dv at the query heads' shape summed
+               over the group by the repeat's own vjp (`group_sum_ms`).
+
+Each form: ms a call of each kernel (REPS calls queued back to back, one
+wait) and of the layer's whole forward and backward as one jitted function
+(`fwd_layer_ms`, `bwd_layer_ms`: kernel and XLA's passes around it). Also
+the tiles a head visits by class and `computed_over_visible` where the
+tree counts them (`tile_counts`), and the largest difference of the output
+and of the three gradients of `flash_mha` from the XLA attention's on two
+query heads of the same sequence (one KV head's under GQA), as a share of
+the reference's range. --tree points at another checkout of the repo (an
+unpacked parent), for a comparison in one call. Needs a TPU.
 """
 
 from __future__ import annotations
 
 import argparse
 import importlib
+import inspect
 import json
 import os
 import sys
@@ -34,13 +50,13 @@ _ARGS.add_argument("--reps", type=int, default=20)
 _ARGS.add_argument("--cases", nargs="*")
 
 D = 128
-# case: (batch, heads, sequence, window)
+# case: (batch, query heads, kv heads, sequence, window)
 CASES = {
-    "mellum_sliding": (2, 32, 8192, 1024),
-    "mellum_full": (2, 32, 8192, None),
-    "mistral_seq4k": (1, 32, 4096, 4096),
-    "mistral_tp2dp2": (8, 16, 4096, 4096),
-    "olmoe_seq4k": (1, 16, 4096, None),
+    "mellum_sliding": (2, 32, 4, 8192, 1024),
+    "mellum_full": (2, 32, 4, 8192, None),
+    "mistral_seq4k": (1, 32, 8, 4096, 4096),
+    "mistral_tp2dp2": (8, 16, 4, 4096, 4096),
+    "olmoe_seq4k": (1, 16, 16, 4096, None),
 }
 
 
@@ -61,32 +77,94 @@ def _share_of_range(got, want):
                  / np.abs(want).max())
 
 
+def _reads_kv_heads(ft) -> bool:
+    """Whether the tree's training kernels address K and V by KV head
+    (`fused_bwd_fits` then takes the group's size)."""
+    return "groups" in inspect.signature(ft.fused_bwd_fits).parameters
+
+
+def _forms(ft, groups):
+    """{form: (spread, gather)}: what a form does to K or V in front of
+    the kernels, and to dk or dv behind them."""
+    import jax.numpy as jnp
+
+    def repeat(x):
+        return jnp.repeat(x, groups, axis=1)
+
+    def group_sum(dx):
+        # what the repeat's own vjp does: the query heads' gradients, in
+        # the dtype the kernel rounded them to, summed over the group
+        b, h, s, d = dx.shape
+        return dx.reshape(b, h // groups, groups, s, d).sum(axis=2)
+
+    forms = {"broadcast": (repeat, group_sum)}
+    if _reads_kv_heads(ft):
+        forms = {"compact": (lambda x: x, lambda dx: dx), **forms}
+    return forms
+
+
 def run(ft, attention, name, seed, reps):
     import jax
     import jax.numpy as jnp
 
-    b, h, s, window = CASES[name]
+    b, hq, hkv, s, window = CASES[name]
+    groups = hq // hkv
     scale = float(1.0 / D ** 0.5)
     blocks = ft.pick_blocks(s, D, jnp.bfloat16)
-    q, k, v, do = (jax.random.normal(key, (b, h, s, D), jnp.bfloat16)
-                   for key in jax.random.split(jax.random.PRNGKey(seed), 4))
-    fwd = jax.jit(lambda q, k, v: ft._fwd(q, k, v, scale, True, window,
-                                          *blocks))
-    bwd = jax.jit(lambda q, k, v, do, stats: ft._bwd_fused(
-        q, k, v, do, stats, scale, True, window, *blocks))
-    o, lse = fwd(q, k, v)
-    stats = jax.jit(lambda lse, o, do: ft._bwd_stats(lse, o, do, blocks[0]))(
-        lse, o, do)
-    line = {"case": name, "shape": [b, h, s, D], "window": window,
-            "blocks": list(blocks),
-            "flash_fwd_ms": _timed(fwd, (q, k, v), reps),
-            "flash_bwd_ms": _timed(bwd, (q, k, v, do, stats), reps)}
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, do = (jax.random.normal(key, (b, hq, s, D), jnp.bfloat16)
+             for key in keys[:2])
+    k, v = (jax.random.normal(key, (b, hkv, s, D), jnp.bfloat16)
+            for key in keys[2:])
+    line = {"case": name, "q": [b, hq, s, D], "kv": [b, hkv, s, D],
+            "window": window, "blocks": list(blocks)}
+
+    def fwd(q, k, v):
+        return ft._fwd(q, k, v, scale, True, window, *blocks)
+
+    def bwd(q, k, v, do, stats):
+        return ft._bwd_fused(q, k, v, do, stats, scale, True, window,
+                             *blocks)
+
+    for form, (spread, gather) in _forms(ft, groups).items():
+        if groups == 1 and form == "broadcast" and "compact" in line:
+            continue     # nothing to repeat: the two forms are one
+        kk, vv = jax.jit(lambda k, v: (spread(k), spread(v)))(k, v)
+        o, lse = jax.jit(fwd)(q, kk, vv)
+        stats = jax.jit(lambda lse, o, do: ft._bwd_stats(
+            lse, o, do, blocks[0]))(lse, o, do)
+
+        def fwd_layer(q, k, v):
+            return fwd(q, spread(k), spread(v))
+
+        def bwd_layer(q, k, v, do, stats):
+            dq, dk, dv = bwd(q, spread(k), spread(v), do, stats)
+            return dq, gather(dk), gather(dv)
+
+        got = {"flash_fwd_ms": _timed(jax.jit(fwd), (q, kk, vv), reps),
+               "flash_bwd_ms": _timed(jax.jit(bwd), (q, kk, vv, do, stats),
+                                      reps),
+               "fwd_layer_ms": _timed(jax.jit(fwd_layer), (q, k, v), reps),
+               "bwd_layer_ms": _timed(jax.jit(bwd_layer),
+                                      (q, k, v, do, stats), reps)}
+        if form == "broadcast" and groups > 1:
+            dkv = jax.jit(bwd)(q, kk, vv, do, stats)[1:]
+            got["broadcast_ms"] = _timed(
+                jax.jit(lambda k, v: (spread(k), spread(v))), (k, v), reps)
+            got["group_sum_ms"] = _timed(
+                jax.jit(lambda dk, dv: (gather(dk), gather(dv))), dkv, reps)
+            del dkv
+        line[form] = got
+        del kk, vv, o, lse, stats
     if hasattr(ft, "tile_counts"):
         line["tiles"] = ft.tile_counts(s, blocks[0], True, window)
 
-    # two heads of the first sequence against the XLA attention, whose
-    # scores of the whole case would not fit the chip
-    cut = tuple(jnp.transpose(x[:1, :2], (0, 2, 1, 3)) for x in (q, k, v, do))
+    # two query heads of the first sequence (one KV head's, under GQA)
+    # against the XLA attention, whose scores of the whole case would not
+    # fit the chip
+    t = lambda x, heads: jnp.transpose(x[:1, :heads], (0, 2, 1, 3))  # noqa: E731
+    kv_heads = 2 if groups == 1 else 1
+    cut = (t(q, 2), t(k, kv_heads), t(v, kv_heads), t(do, 2))
 
     def out_and_grads(fn):
         o, vjp = jax.vjp(fn, *cut[:3])
@@ -114,7 +192,8 @@ def main():
     if "TPU" not in kind:
         sys.exit(f"needs a TPU, found {kind}")
     print(json.dumps({"tree": os.path.abspath(args.tree),
-                      "device_kind": kind}), flush=True)
+                      "device_kind": kind,
+                      "reads_kv_heads": _reads_kv_heads(ft)}), flush=True)
     for name in args.cases or CASES:
         run(ft, attention, name, args.seed, args.reps)
 
