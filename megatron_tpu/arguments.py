@@ -184,6 +184,33 @@ def build_parser(extra_args_provider=None) -> argparse.ArgumentParser:
     g.add_argument("--moe_shared_ffn_size", type=int, default=None,
                    help="a shared expert of this width beside the routed "
                         "ones: every token's, added to their result")
+    g.add_argument("--moe_router_form", choices=["linear", "mlp"],
+                   default=None,
+                   help="mlp: the layer's input projected down to "
+                        "--moe_router_hidden_size, plus a learned scale "
+                        "times the previous layer's such state, through a "
+                        "three-layer gelu MLP to the logits (ops/moe.py)")
+    g.add_argument("--moe_router_hidden_size", type=int, default=None)
+    g.add_argument("--moe_bias_update_rate", type=float, default=None,
+                   help="balance by a selection bias and no auxiliary "
+                        "loss: after each step bias_e += rate * sign(1/E "
+                        "- load_e); the bias is read for the choice alone "
+                        "and trained by no gradient")
+    g.add_argument("--attention_form", choices=["plain", "cca"],
+                   default="plain",
+                   help="cca: compressed convolutional attention "
+                        "(ops/cca.py): two causal convolutions over the "
+                        "(q, k) latent, the q-k mean, unit-norm q and k "
+                        "with a temperature, half of v shifted")
+    g.add_argument("--cca_conv_kernels", type=int, nargs=2, default=(2, 2),
+                   help="the taps of cca's depthwise convolution and of "
+                        "the one grouped by head")
+    g.add_argument("--rotary_percent", type=float, default=1.0,
+                   help="the share of a head's channels that rotary "
+                        "turns (the first ones; the rest pass)")
+    g.add_argument("--residual_scale", action="store_true",
+                   help="each residual add as (s_x * x + b_x) + (s_o * "
+                        "out + b_o), four learned vectors a sub-layer")
     g.add_argument("--lima_dropout", action="store_true")
     g.add_argument("--encoder_seq_length", type=int, default=None,
                    help="alias of --seq_length (ref derives one from the other)")
@@ -570,7 +597,8 @@ def _moe_overrides(args) -> dict:
                  "moe_renorm_gates", "moe_group_size", "moe_dispatch",
                  "moe_ep_buffer_factor", "moe_router_score",
                  "moe_route_scale", "moe_latent_size",
-                 "moe_shared_ffn_size"):
+                 "moe_shared_ffn_size", "moe_router_form",
+                 "moe_router_hidden_size", "moe_bias_update_rate"):
         v = getattr(args, name, None)
         if v is not None:
             out[name] = v
@@ -690,6 +718,10 @@ def args_to_run_config(args) -> RunConfig:
             position_embedding_type=args.position_embedding_type,
             rope_theta=args.rope_theta,
             rope_scaling_factor=args.rope_scaling_factor,
+            rotary_percent=args.rotary_percent,
+            attention_form=args.attention_form,
+            cca_conv_kernels=tuple(args.cca_conv_kernels),
+            residual_scale=args.residual_scale,
             normalization="rmsnorm" if args.use_rms_norm else "layernorm",
             layernorm_epsilon=args.layernorm_epsilon,
             activation=args.glu_activation or args.activation or "gelu",

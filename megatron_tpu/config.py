@@ -36,6 +36,14 @@ SSM_TYPES = ("mamba", "mamba2")
 FFN_TYPES = ("mlp", "moe")
 LAYER_TYPES = MIXER_TYPES + FFN_TYPES
 MOE_ROUTER_SCORES = ("softmax", "sigmoid")
+# What stands between the expert layer's input and the router's logits
+# (ops/moe.py `_route`, `router_mlp`): one matrix, or a down projection, a state
+# carried from layer to layer and an MLP.
+MOE_ROUTER_FORMS = ("linear", "mlp")
+# How a layer makes q, k and v of its normed input (models/transformer.py
+# attention_block): three projections, or compressed convolutional
+# attention (ops/cca.py).
+ATTENTION_FORMS = ("plain", "cca")
 NORMALIZATION_TYPES = ("layernorm", "rmsnorm")
 # GLU family per ref megatron/model/glu_activations.py plus plain variants.
 ACTIVATION_TYPES = ("gelu", "gelu_tanh", "geglu", "swiglu", "reglu", "liglu", "relu", "squared_relu")
@@ -138,6 +146,10 @@ class ModelConfig:
     rope_theta: float = 10000.0
     # linear position-interpolation RoPE scaling (ref --rope_scaling_factor)
     rope_scaling_factor: float = 1.0
+    # the share of a head's channels that rotary turns: the first
+    # rotary_percent * head_dim, rotate-half inside them, the rest passed
+    # (Hugging Face's `partial_rotary_factor`)
+    rotary_percent: float = 1.0
     max_position_embeddings: Optional[int] = None  # for absolute pos-emb
 
     # norms / activations
@@ -213,6 +225,21 @@ class ModelConfig:
     ssm_n_groups: int = 1
     ssm_chunk_size: int = 128
 
+    # "cca": compressed convolutional attention (arXiv:2510.04476;
+    # ops/cca.py has the equations): q and k of the latent (heads x head
+    # size wide, never projected up) go through a depthwise causal
+    # convolution of cca_conv_kernels[0] taps and one grouped by head of
+    # cca_conv_kernels[1] taps, the mean of the pre-convolution q and k of
+    # a group is added, both are brought to unit norm (k times a learned
+    # temperature a KV head), and half of v's channels read the previous
+    # position. Training alone: a cache would hold the convolutions' tail.
+    attention_form: str = "plain"
+    cca_conv_kernels: Tuple[int, int] = (2, 2)
+    # Each of a layer's two residual adds as (s_x * x + b_x) + (s_o * out
+    # + b_o), four learned vectors of hidden_size a sub-layer
+    # (layers/res1, layers/res2; s at 1 and b at 0 is the plain add).
+    residual_scale: bool = False
+
     # OLMoE QK-norm: RMSNorm with a learned scale over the WHOLE q and the
     # whole k projection (all heads at once), before the head split and
     # the rotary (HF modeling_olmoe.py q_norm / k_norm)
@@ -242,6 +269,22 @@ class ModelConfig:
     # (moe_renorm_gates) times moe_route_scale, and no load-balance loss.
     moe_router_score: str = "softmax"
     moe_route_scale: float = 1.0
+    # "mlp" (ZAYA1's router, ops/moe.py `router_mlp`): the layer's
+    # input projected down to moe_router_hidden_size, plus a learned
+    # per-channel scale times the previous expert layer's such state (an
+    # activation the layer stack carries from layer to layer and the
+    # backward pass differentiates through), through a three-layer gelu
+    # MLP to the logits. Dropless, unsharded and training alone.
+    moe_router_form: str = "linear"
+    moe_router_hidden_size: Optional[int] = None
+    # Balancing by the selection bias in place of an auxiliary loss: after
+    # each optimizer step every expert layer's bias_e += rate * sign(1/E
+    # - load_e), load_e the share of the step's tokens that chose e
+    # (training/optimizer.py update_selection_bias). The bias
+    # (layers/moe/router_bias) is added to the logits (softmax form) or
+    # the scores (sigmoid form) for the choice alone and is trained by no
+    # gradient. None: no update (the softmax form then has no bias).
+    moe_bias_update_rate: Optional[float] = None
     # The routed experts work in a narrower width: a linear projection of
     # the layer's input down to it in front of the dispatch, and one back
     # up behind the weighted sum (None: the experts read the hidden size).
@@ -425,6 +468,30 @@ class ModelConfig:
         return self.moe_experts_held or self.num_experts
 
     @property
+    def has_router_bias(self) -> bool:
+        """The expert layers hold a selection bias (`router_bias`)."""
+        return self.num_experts is not None and (
+            self.moe_router_score == "sigmoid"
+            or self.moe_bias_update_rate is not None)
+
+    @property
+    def balances_by_bias(self) -> bool:
+        """The selection bias moves by the experts' load of each step."""
+        return (self.num_experts is not None
+                and self.moe_bias_update_rate is not None)
+
+    @property
+    def carries_router_state(self) -> bool:
+        """The layer stack's carry holds the router's state."""
+        return (self.num_experts is not None
+                and self.moe_router_form == "mlp")
+
+    @property
+    def rotary_dim(self) -> int:
+        """The channels of a head that rotary turns."""
+        return int(self.head_dim * self.rotary_percent)
+
+    @property
     def holds_expert_share(self) -> bool:
         """The router is wider than the experts held."""
         return (self.moe_experts_held is not None
@@ -537,6 +604,22 @@ class ModelConfig:
                         "ssm_n_groups that divide the heads and "
                         f"ssm_chunk_size >= 1 (heads {heads}, groups "
                         f"{groups}, chunk {self.ssm_chunk_size})")
+        if not 0.0 < self.rotary_percent <= 1.0 or self.rotary_dim % 2:
+            raise ValueError(
+                f"rotary_percent={self.rotary_percent} must leave an even "
+                f"number of a head's {self.head_dim} channels to rotate")
+        if self.attention_form not in ATTENTION_FORMS:
+            raise ValueError(f"bad attention_form {self.attention_form!r}; "
+                             f"one of {ATTENTION_FORMS}")
+        if self.attention_form == "cca":
+            self._validate_cca()
+        if self.residual_scale and (
+                self.layer_pattern is not None or self.parallel_attn
+                or self.use_post_ln or self.apply_residual_post_ln):
+            raise NotImplementedError(
+                "residual_scale scales the two adds of a pre-norm layer of "
+                "an attention block and an FFN: no layer_pattern, "
+                "parallel_attn, use_post_ln or apply_residual_post_ln")
         if self.moe_experts_held is not None:
             if self.num_experts is None or self.moe_dispatch != "dropless":
                 raise ValueError(
@@ -567,6 +650,31 @@ class ModelConfig:
                     "sigmoid router scores, moe_latent_size and "
                     "moe_shared_ffn_size are the dropless block's "
                     "(moe_dispatch='dropless')")
+            if self.moe_router_form not in MOE_ROUTER_FORMS:
+                raise ValueError(
+                    f"moe_router_form={self.moe_router_form!r} must be "
+                    f"one of {MOE_ROUTER_FORMS}")
+            if (self.moe_router_form == "mlp") != (
+                    self.moe_router_hidden_size is not None):
+                raise ValueError(
+                    "moe_router_hidden_size is the width of "
+                    "moe_router_form='mlp': give both or neither")
+            if (self.moe_bias_update_rate is not None
+                    and self.moe_bias_update_rate <= 0):
+                raise ValueError(
+                    f"moe_bias_update_rate={self.moe_bias_update_rate} "
+                    "must be > 0 (None: no selection bias moves)")
+            for what, on in (
+                    ("moe_router_form='mlp'", self.moe_router_form == "mlp"),
+                    ("moe_bias_update_rate",
+                     self.moe_bias_update_rate is not None)):
+                if on and (self.moe_dispatch != "dropless"
+                           or self.layer_pattern is not None):
+                    raise NotImplementedError(
+                        f"{what} hands what it carries from layer to "
+                        "layer through the dropless block of a stack "
+                        "whose layers are all alike: no capacity dispatch "
+                        "(moe_dispatch='capacity'), no layer_pattern")
             if self.moe_dispatch not in ("capacity", "dropless"):
                 raise ValueError(
                     f"moe_dispatch={self.moe_dispatch!r} must be "
@@ -593,6 +701,45 @@ class ModelConfig:
                 f"seq_length={self.seq_length}")
         return self
 
+    def refuse_serving(self) -> None:
+        """Raises, by name, for what of the model trains and no serving
+        path holds (the engines call it before they build anything)."""
+        for what, on, why in (
+                ("attention_form='cca'", self.attention_form == "cca",
+                 "a slot would hold, beside its keys and values, the last "
+                 "positions the convolutions and the value shift read"),
+                ("moe_router_form='mlp'", self.carries_router_state,
+                 "the router's state of a chunk's or a tick's positions "
+                 "would have to pass from layer to layer through the "
+                 "serving step")):
+            if on:
+                raise NotImplementedError(
+                    f"serving a model with {what}: {why}; it trains "
+                    "(pretrain_gpt.py) and is not served")
+
+    def _validate_cca(self) -> None:
+        k0, k1 = self.cca_conv_kernels
+        if min(k0, k1) < 1 or (self.n_kv_heads * self.head_dim) % 2:
+            raise ValueError(
+                f"attention_form='cca' needs cca_conv_kernels >= 1 "
+                f"({self.cca_conv_kernels}) and an even value width "
+                f"({self.n_kv_heads} x {self.head_dim}) to shift half of")
+        if (self.layer_pattern is not None or self.qk_norm
+                or self.use_bias_qkv or self.fp8_format
+                or self.attn_mask_type != "causal"
+                or self.attention_dropout > 0):
+            raise NotImplementedError(
+                "attention_form='cca' is a causal attention layer of a "
+                "stack whose layers are all alike, in bf16/f32 without "
+                "biases: no layer_pattern, qk_norm, use_bias, fp8_format, "
+                "attention_dropout or a mask other than causal")
+        if self.attention_impl in ("ring", "ulysses"):
+            raise NotImplementedError(
+                "attention_form='cca' under context parallelism "
+                f"(attention_impl={self.attention_impl!r}): the "
+                "convolutions and the value shift read the previous "
+                "position, which lies on another rank at a shard's start")
+
     # FLOPs per token for one fwd pass, used for MFU accounting
     # (ref formula: megatron/model/language_model.py:370-384).
     def flops_per_token_fwd(self, seq_length: Optional[int] = None) -> float:
@@ -603,6 +750,10 @@ class ModelConfig:
         per_layer = 0.0
         per_layer += 2 * h * (nq + 2 * nkv) * hd        # qkv proj
         per_layer += 2 * nq * hd * h                    # out proj
+        if self.attention_form == "cca":
+            # a tap a channel, then a [head_dim, head_dim] matrix a tap
+            k0, k1 = self.cca_conv_kernels
+            per_layer += 2 * (nq + nkv) * hd * (k0 + k1 * hd)
         mlp_in_width = f * (2 if self.is_glu else 1)
         mlp = 2 * h * mlp_in_width + 2 * f * h
         if self.num_experts is not None:
@@ -610,7 +761,7 @@ class ModelConfig:
             # here is computed here (all of them where every expert is
             # held); the router matmul is extra, over its whole width
             mlp = (mlp * self.moe_top_k * self.experts_held
-                   / self.num_experts + 2 * h * self.num_experts)
+                   / self.num_experts + self._router_flops())
         per_layer += mlp
         # qk^T and av over the keys a layer's kind lets a query see (causal
         # ~ /2 but count full): s, or the window where it is shorter
@@ -628,6 +779,14 @@ class ModelConfig:
                 self._ssm_mixer_flops() - (per_layer - mlp))
         total += 2 * h * self.vocab_size                # logits
         return float(total)
+
+    def _router_flops(self) -> float:
+        """The router's operations a token, over its whole width."""
+        if self.moe_router_form == "mlp":
+            r = self.moe_router_hidden_size
+            return 2 * self.hidden_size * r + 2 * 2 * r * r + (
+                2 * r * self.num_experts)
+        return 2 * self.hidden_size * self.num_experts
 
     def _ssm_mixer_flops(self) -> float:
         """A state-space mixer's operations a token: its projections, the
@@ -655,7 +814,7 @@ class ModelConfig:
         if self.num_experts is not None:
             width = self.moe_latent_size or h
             expert = dense * width / h
-            layer = (2 * h * self.num_experts + expert * self.moe_top_k
+            layer = (self._router_flops() + expert * self.moe_top_k
                      * self.experts_held / self.num_experts)
             if self.moe_latent_size is not None:
                 layer += 2 * 2 * h * width
@@ -686,6 +845,8 @@ def model_config_from_saved(saved: dict) -> ModelConfig:
             for k in kept["attention_pattern"])
     if kept.get("layer_pattern") is not None:
         kept["layer_pattern"] = tuple(kept["layer_pattern"])
+    if "cca_conv_kernels" in kept:
+        kept["cca_conv_kernels"] = tuple(kept["cca_conv_kernels"])
     return ModelConfig(**kept)
 
 
@@ -1095,7 +1256,27 @@ class RunConfig:
         self.model.validate()
         self.parallel.validate()
         self.training.validate()
+        self._refuse_unbuilt_sharding()
         return self
+
+    def _refuse_unbuilt_sharding(self) -> None:
+        """What of the model no sharded path holds yet, by name."""
+        m, p = self.model, self.parallel
+        sharded = [name for name in (
+            "tensor_parallel", "pipeline_parallel", "context_parallel",
+            "expert_parallel") if getattr(p, name) > 1]
+        if not sharded:
+            return
+        for what, on in (
+                ("attention_form='cca'", m.attention_form == "cca"),
+                ("moe_router_form='mlp'", m.carries_router_state),
+                ("moe_bias_update_rate", m.balances_by_bias)):
+            if on:
+                raise NotImplementedError(
+                    f"{what} under {', '.join(sharded)} > 1: its leaves "
+                    "and the state it carries are replicated and its "
+                    "sums are one chip's; it trains under data "
+                    "parallelism alone")
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)

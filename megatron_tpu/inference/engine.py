@@ -296,6 +296,7 @@ class InferenceEngine:
         # (None = donate except on XLA:CPU). The jaxpr/donation auditor
         # sets True so CPU-traced audits check the TPU-shipped intent.
         self.force_donate = force_donate
+        cfg.refuse_serving()
         self.cfg = cfg
         self.num_slots = num_slots
         self.max_queue = max_queue

@@ -34,8 +34,8 @@ from megatron_tpu.ops.cross_entropy import (
     chunked_head_loss, cross_entropy_loss,
 )
 from megatron_tpu.ops.moe import (
-    HELD_METRIC, LOAD_METRIC, MOVED_METRIC, SAVED_PRODUCT, expert_grad_sinks,
-    merge_layer_stats, moe_stats_zero,
+    EXPERT_LOAD, HELD_METRIC, LOAD_METRIC, MOVED_METRIC, SAVED_PRODUCT,
+    expert_grad_sinks, merge_layer_stats, moe_stats_zero, router_carry,
 )
 from megatron_tpu.ops.pallas.flash_template import SAVED_RESIDUAL
 from megatron_tpu.ops.weight_quant import deq, take_rows
@@ -241,7 +241,8 @@ def rope_tables(cfg: ModelConfig, kinds, length: int) -> Dict[Any, Any]:
     """One rotary table a kind of attention layer, by kind (None for a
     model without rotary embeddings)."""
     rotary = cfg.position_embedding_type == "rotary"
-    return {kind: rope_table(kind, cfg.head_dim, length) if rotary else None
+    return {kind: (rope_table(kind, cfg.head_dim, length,
+                              rotary_dim=cfg.rotary_dim) if rotary else None)
             for kind in dict.fromkeys(kinds)}
 
 
@@ -255,7 +256,7 @@ def run_layers(
     cfg: ModelConfig,
     layers: Dict[str, Any],   # stacked [n, ...]: the whole stack, or a slice
     carry,                    # (x, moe_aux, kv store, gradient sinks,
-                              #  state-space store)
+                              #  state-space store[, router carry])
     ropes: Dict[Any, Any],    # rope_tables
     positions: Optional[jnp.ndarray],
     first_layer=0,            # the slice's first layer in the whole network
@@ -272,7 +273,12 @@ def run_layers(
     ops/moe.py moe_stats_zero on; a dense layer adds its zero scalar to
     whatever zero the caller starts from); the KV store and the gradient
     sinks (lm_forward's kv_caches and grad_sink), or None; the
-    state-space layers' state store (lm_forward's ssm_state), or None.
+    state-space layers' state store (lm_forward's ssm_state), or None;
+    and, of a model whose expert layers hand something from one to the
+    next (ops/moe.py router_carry: the "mlp" router's state [B, S, R], an
+    activation the backward pass goes through under every remat policy,
+    and the layers' loads), that dict as a sixth element, which comes
+    back as one.
     Those ride in the carry so that the layers write the donated stores,
     and the kernels the sinks' cotangents, in place: as a scanned input
     and output each would be a second copy, built layer by layer every
@@ -299,8 +305,8 @@ def run_layers(
                else merge_layer_stats)
 
     def body(carry, scanned, kind, layer_type="attention", leaves=None):
-        x, aux, caches, sinks, state = carry
-        of_type = {}
+        x, aux, caches, sinks, state, *router = carry
+        of_type = {"router": router[0]} if router else {}
         if leaves is None:
             (lp, rate, idx), type_layer = scanned, None
         else:
@@ -316,7 +322,7 @@ def run_layers(
                                              layers["moe"]["w_out"])}
         key = (None if dropout_key is None
                else jax.random.fold_in(dropout_key, first_layer + idx))
-        y, caches, moe_aux, sinks, state = block_forward(
+        y, caches, moe_aux, sinks, state, *router = block_forward(
             cfg, lp, x, ropes[kind], positions,
             dropout_key=key,
             hidden_dropout_rate=rate,
@@ -330,7 +336,7 @@ def run_layers(
             **of_type,
             **layer_args,
         )
-        return (y, add_aux(aux, moe_aux), caches, sinks, state), None
+        return (y, add_aux(aux, moe_aux), caches, sinks, state, *router), None
 
     if cfg.layer_pattern is None:
         # the kinds in their published order, each layer's window static
@@ -420,6 +426,7 @@ def lm_forward(
     cache_index=None,
     return_hidden: bool = False,
     return_moe_aux: bool = False,
+    return_expert_load: bool = False,
     attention_mask: Optional[jnp.ndarray] = None,  # [B, S] True = attend
     tokentype_ids: Optional[jnp.ndarray] = None,   # [B, S] (BERT segments)
     page_table: Optional[jnp.ndarray] = None,      # [B, max_pages] int32
@@ -472,6 +479,11 @@ def lm_forward(
     behind the stores of a serving step (the serving engine counts the
     rows its held experts took from it).
 
+    return_expert_load (with return_moe_aux, of a model that balances by
+    its selection bias: cfg.moe_bias_update_rate): behind the statistics
+    the expert layers' loads of the call, [layers, E] choices an expert
+    (ops/moe.py router_carry), as one result with them: (moe_aux, load).
+
     page_table: the store is a pool of pages (inference/paging/) shared
     by every slot; each row's logical context is page_table[b] physical
     pages. The table is broadcast to
@@ -507,7 +519,10 @@ def lm_forward(
                  else jnp.zeros((), jnp.float32)),
              kv_caches, None if grad_sink is None else grad_sink["layers"],
              ssm_state)
-    x, moe_aux, new_caches, layer_sinks, new_state = run_layers(
+    routed = router_carry(cfg, x)
+    if routed is not None:
+        carry += (routed,)
+    x, moe_aux, new_caches, layer_sinks, new_state, *routed = run_layers(
         cfg, params["layers"], carry, ropes, positions,
         dropout_key=dropout_key if train else None,
         recompute=recompute,
@@ -522,6 +537,9 @@ def lm_forward(
         state_row=state_row,
         state_valid=state_valid,
     )
+
+    if return_expert_load:
+        moe_aux = (moe_aux, jax.lax.stop_gradient(routed[0]["load"]))
 
     def with_sinks(result):
         if grad_sink is None:
@@ -623,6 +641,7 @@ def lm_loss(
     grad_sink: as lm_forward's; it comes back in aux[GRAD_SINK].
     """
     moe = cfg.num_experts is not None
+    balanced = cfg.balances_by_bias
     S = batch["tokens"].shape[1]
     # fall back to unchunked when the chunk doesn't tile this batch's
     # sequence (variable_seq_lengths batches may be shorter than
@@ -636,6 +655,7 @@ def lm_loss(
         recompute=recompute,
         sharder=sharder,
         return_moe_aux=moe,
+        return_expert_load=balanced,
         return_hidden=chunked,
         grad_sink=grad_sink,
     )
@@ -660,6 +680,8 @@ def lm_loss(
     aux = {"lm_loss": mean, "ntokens": ntokens}
     if grad_sink is not None:
         aux[GRAD_SINK] = grad_sink
+    if balanced:
+        moe_aux, aux[EXPERT_LOAD] = moe_aux
     if moe:
         # router losses train alongside CE (load balance / ST-MoE z-loss);
         # lm_loss in metrics stays the pure CE term

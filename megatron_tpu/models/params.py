@@ -38,6 +38,10 @@ _ZEROS = "zeros"
 _A_LOG = "a_log"            # log(1..N) down the state axis (Mamba-1)
 _A_LOG_HEADS = "a_log_heads"  # log of uniform [1, 16) a head (Mamba-2)
 _DT_BIAS = "dt_bias"        # softplus(bias) log-uniform in [1e-3, 1e-1]
+# compressed convolutional attention's and its router's own
+_CONV = "conv"              # U(+-1/sqrt(fan_in)), torch's Conv1d default;
+#                             the kind carries the fan-in: (_CONV, n)
+_HALVES = "halves"          # 0.5 (the router's depth carry)
 
 
 def _defs(cfg: ModelConfig) -> Dict[str, Any]:
@@ -92,6 +96,24 @@ def _defs(cfg: ModelConfig) -> Dict[str, Any]:
         d["layers/attn/bv"] = ((La, nkv * D), P(AXIS_PIPE, AXIS_TENSOR), _ZEROS)
     if cfg.use_bias_linear:
         d["layers/attn/bo"] = ((La, h), P(AXIS_PIPE, None), _ZEROS)
+    if cfg.attention_form == "cca":
+        # ops/cca.py: `wv`'s first half of columns reads the position
+        # itself, the second the one before; a tap a channel of the (q, k)
+        # latent, then a [D, D] matrix a tap a head; k's temperature.
+        # Replicated over "tensor": the paths that shard refuse the form.
+        k0, k1 = cfg.cca_conv_kernels
+        d["layers/attn/conv1"] = ((La, k0, nq + nkv, D),
+                                  P(AXIS_PIPE, None, None, None),
+                                  (_CONV, k0))
+        d["layers/attn/conv2"] = ((La, nq + nkv, k1, D, D),
+                                  P(AXIS_PIPE, None, None, None, None),
+                                  (_CONV, k1 * D))
+        d["layers/attn/k_temp_scale"] = ((La, nkv), P(AXIS_PIPE, None), _ONES)
+    if cfg.residual_scale:
+        for res in ("layers/res1", "layers/res2"):
+            for name, kind in (("x_scale", _ONES), ("x_bias", _ZEROS),
+                               ("out_scale", _ONES), ("out_bias", _ZEROS)):
+                d[f"{res}/{name}"] = ((L, h), P(AXIS_PIPE, None), kind)
 
     if cfg.has_ssm:
         # the state-space mixers (ops/ssm.py has the equations). The inner
@@ -153,9 +175,20 @@ def _defs(cfg: ModelConfig) -> Dict[str, Any]:
         # the router keeps its width where only a share of its experts'
         # weights exist here (ModelConfig.moe_experts_held)
         Le = cfg.expert_layers
-        d["layers/moe/router"] = ((Le, h, cfg.num_experts),
-                                  P(AXIS_PIPE, None, None), _NORMAL)
-        if cfg.moe_router_score == "sigmoid":
+        if cfg.moe_router_form == "mlp":
+            # ops/moe.py router_mlp: down, the depth carry's scale, MLP
+            R = cfg.moe_router_hidden_size
+            for name, shape in (("router_down", (h, R)),
+                                ("router_w1", (R, R)), ("router_w2", (R, R)),
+                                ("router_w3", (R, cfg.num_experts))):
+                d[f"layers/moe/{name}"] = ((Le,) + shape,
+                                           P(AXIS_PIPE, None, None), _NORMAL)
+            d["layers/moe/router_carry_scale"] = ((Le, R), P(AXIS_PIPE, None),
+                                                  _HALVES)
+        else:
+            d["layers/moe/router"] = ((Le, h, cfg.num_experts),
+                                      P(AXIS_PIPE, None, None), _NORMAL)
+        if cfg.has_router_bias:
             # the selection bias: added to the scores for the choice alone
             d["layers/moe/router_bias"] = ((Le, cfg.num_experts),
                                            P(AXIS_PIPE, None), _ZEROS)
@@ -248,6 +281,13 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Dict[str, Any]:
             flat[path] = jnp.ones(shape, dtype)
         elif kind == _ZEROS:
             flat[path] = jnp.zeros(shape, dtype)
+        elif kind == _HALVES:
+            flat[path] = jnp.full(shape, 0.5, dtype)
+        elif isinstance(kind, tuple) and kind[0] == _CONV:
+            k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
+            bound = 1.0 / math.sqrt(kind[1])
+            flat[path] = jax.random.uniform(
+                k, shape, jnp.float32, -bound, bound).astype(dtype)
         elif kind == _A_LOG:
             rows = jnp.log(jnp.arange(1, shape[-2] + 1, dtype=jnp.float32))
             flat[path] = jnp.broadcast_to(rows[:, None], shape).astype(dtype)

@@ -28,6 +28,7 @@ from megatron_tpu.config import FFN_TYPES, SSM_TYPES, AttentionKind, ModelConfig
 from megatron_tpu.ops import kv_store
 from megatron_tpu.ops.activations import apply_activation
 from megatron_tpu.ops.attention import attention
+from megatron_tpu.ops.cca import cca_mix
 from megatron_tpu.ops.fp8 import maybe_fp8_matmul
 from megatron_tpu.ops.moe import layer_stats, moe_block, moe_stats_zero
 from megatron_tpu.ops.normalization import norm_forward, rmsnorm
@@ -112,6 +113,13 @@ def attention_block(
     D = cfg.head_dim
     nq, nkv = cfg.num_attention_heads, cfg.n_kv_heads
     window = (kind or cfg.attention_kind).sliding_window_size
+    cca = cfg.attention_form == "cca"
+    if cca and kv_cache is not None:
+        raise NotImplementedError(
+            "attention_form='cca' through a KV cache (serving, incremental "
+            "decoding): a sequence's store would hold, beside its keys and "
+            "values, the last positions the convolutions and the value "
+            "shift read; the form trains and is not served")
 
     # The scopes inside a region name its parts for a device trace (docs/
     # observability.md "Runtime traces"): projections, rotary, everything
@@ -141,11 +149,17 @@ def attention_block(
             q, k, v = jax.lax.optimization_barrier((q, k, v))
         q = q.reshape(b, s, nq, D)
         k = k.reshape(b, s, nkv, D)
-        v = v.reshape(b, s, nkv, D)
+        if not cca:
+            v = v.reshape(b, s, nkv, D)
+
+    if cca:
+        with jax.named_scope("cca_mix"):
+            q, k, v = cca_mix(cfg, p, q, k, v)
 
     if rope is not None:
         with jax.named_scope("attn_rope"):
-            q, k = apply_rotary_emb(q, k, rope[0], rope[1], positions)
+            q, k = apply_rotary_emb(q, k, rope[0], rope[1], positions,
+                                    rotary_dim=cfg.rotary_dim)
 
     # CP prefill (VERDICT r4 #6): when the whole prompt enters at once
     # (cache_index is a STATIC 0 — the prefill call site passes a Python
@@ -282,26 +296,42 @@ def _no_moe_aux(cfg: ModelConfig) -> jnp.ndarray:
 
 def _ffn(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
          tp_comm=None, grad_sink=None, layer=None, expert_stacks=None,
-         rows_read=None):
+         rows_read=None, router=None):
     """Dense MLP or MoE, by what the layer holds (`lp`; by config where a
     layer holds an FFN whatever its type). Returns (out, moe_aux,
-    grad_sink): moe_aux a zero fp32 scalar for a dense layer, [aux loss,
-    load statistic] for an MoE one; grad_sink as block_forward has it
-    (`layer` the layer's index into its stacks, and into expert_stacks:
-    block_forward's). rows_read: block_forward's state_valid, for the
-    experts (ops/moe.py moe_block); a dense MLP computes every row."""
+    grad_sink, router): moe_aux a zero fp32 scalar for a dense layer, [aux
+    loss, load statistic] for an MoE one; grad_sink as block_forward has
+    it (`layer` the layer's index into its stacks, and into expert_stacks:
+    block_forward's); router as block_forward has it, this layer's part
+    written. rows_read: block_forward's state_valid, for the experts
+    (ops/moe.py moe_block); a dense MLP computes every row."""
     if "moe" not in lp:
         return (mlp_block(cfg, lp["mlp"], x, tp_comm=tp_comm),
-                _no_moe_aux(cfg), grad_sink)
+                _no_moe_aux(cfg), grad_sink, router)
+    carried = {} if router is None else {"router": (router, layer)}
     if grad_sink is not None:
-        out, aux, load, stacks = moe_block(cfg, lp["moe"], x,
-                                           (grad_sink["moe"], layer))
-        return out, layer_stats(aux, load), {**grad_sink, "moe": stacks}
-    out, aux, load = moe_block(
-        cfg, lp["moe"], x,
-        of_layer=None if expert_stacks is None else (*expert_stacks, layer),
-        rows_read=rows_read)
-    return out, layer_stats(aux, load), None
+        out, aux, load, stacks, *router = moe_block(
+            cfg, lp["moe"], x, (grad_sink["moe"], layer), **carried)
+        grad_sink = {**grad_sink, "moe": stacks}
+    else:
+        out, aux, load, *router = moe_block(
+            cfg, lp["moe"], x, of_layer=None if expert_stacks is None
+            else (*expert_stacks, layer), rows_read=rows_read, **carried)
+    return (out, layer_stats(aux, load), grad_sink,
+            router[0] if router else None)
+
+
+def _residual_add(cfg: ModelConfig, lp: Dict[str, Any], which: str,
+                  x: jnp.ndarray, out: jnp.ndarray) -> jnp.ndarray:
+    """x + out, or with cfg.residual_scale (s_x * x + b_x) + (s_o * out +
+    b_o) by the sub-layer's four vectors lp[which] (`res1`: the mixer's
+    add, `res2`: the FFN's)."""
+    if not cfg.residual_scale:
+        return x + out
+    with jax.named_scope("residual_scale"):
+        s = lp[which]
+        return (s["x_scale"] * x + s["x_bias"]) + (
+            s["out_scale"] * out + s["out_bias"])
 
 
 def _mixer(cfg: ModelConfig, lp: Dict[str, Any], normed: jnp.ndarray,
@@ -353,10 +383,15 @@ def block_forward(
     state_row=None,
     state_valid: Optional[jnp.ndarray] = None,
     expert_stacks=None,  # (w_in, w_out) of all the expert layers, stacked
+    router=None,         # ops/moe.py router_carry's dict
 ):
-    """One decoder layer -> (y, kv_cache, moe_aux, grad_sink, ssm_state):
-    kv_cache is the whole store with this layer's rows written
-    (attention_block).
+    """One decoder layer -> (y, kv_cache, moe_aux, grad_sink, ssm_state),
+    and where `router` is given that behind them: kv_cache is the whole
+    store with this layer's rows written (attention_block).
+
+    router: what the expert layers of some models carry from one to the
+    next (ops/moe.py router_carry: the "mlp" router's state, the layers'
+    loads), with this layer's part written on the way out.
 
     What a layer is follows from the stack (ModelConfig.layer_pattern). A
     sequence mixer AND a feed-forward block, each behind its norm and
@@ -431,7 +466,7 @@ def block_forward(
             with jax.named_scope(f"{part}_norm"):
                 normed = _norm(cfg, lp["ln1"], x)
             if ffn:
-                out, moe_aux, grad_sink = _ffn(
+                out, moe_aux, grad_sink, _ = _ffn(
                     cfg, lp, normed, tp_comm, grad_sink, type_layer,
                     expert_stacks, state_valid)
             else:
@@ -466,7 +501,8 @@ def block_forward(
                 # residual from the LN output with --apply_residual_
                 # connection_post_layernorm (ref transformer.py:795-799)
                 res1 = normed if cfg.apply_residual_post_ln else x
-                y = sharder(res1 + attn_out, "residual")
+                y = sharder(_residual_add(cfg, lp, "res1", res1, attn_out),
+                            "residual")
 
     with jax.named_scope("mlp"):
         if cfg.parallel_attn:
@@ -474,9 +510,9 @@ def block_forward(
             # (40B); one residual add for both branches.
             with jax.named_scope("mlp_norm"):
                 mlp_in = _norm(cfg, lp["ln_mlp"], x) if cfg.parallel_layernorm else normed
-            mlp_out, moe_aux, grad_sink = _ffn(
+            mlp_out, moe_aux, grad_sink, router = _ffn(
                 cfg, lp, mlp_in, tp_comm, grad_sink, layer,
-                rows_read=state_valid)
+                rows_read=state_valid, router=router)
             with jax.named_scope("mlp_out"):
                 mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
                 res = normed if cfg.apply_residual_post_ln else x
@@ -484,14 +520,16 @@ def block_forward(
         else:
             with jax.named_scope("mlp_norm"):
                 normed2 = _norm(cfg, lp["ln2"], y)
-            mlp_out, moe_aux, grad_sink = _ffn(
+            mlp_out, moe_aux, grad_sink, router = _ffn(
                 cfg, lp, normed2, tp_comm, grad_sink, layer,
-                rows_read=state_valid)
+                rows_read=state_valid, router=router)
             with jax.named_scope("mlp_out"):
                 mlp_out = _dropout(mlp_out, rate, k_hidden2 if cfg.hidden_dropout > 0 else None)
                 res2 = normed2 if cfg.apply_residual_post_ln else y
-                y = res2 + mlp_out
+                y = _residual_add(cfg, lp, "res2", res2, mlp_out)
                 if cfg.use_post_ln:
                     y = _norm(cfg, lp["ln1"], y)
     y = sharder(y, "residual")
+    if router is not None:
+        return y, kv_cache, moe_aux, grad_sink, ssm_state, router
     return y, kv_cache, moe_aux, grad_sink, ssm_state

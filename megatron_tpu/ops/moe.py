@@ -15,6 +15,19 @@ definition of the auxiliary losses serve both dispatch forms:
     are w_e = s_e / sum over the chosen of s (`moe_renorm_gates`), times
     `moe_route_scale`. No load-balance loss goes with it (the bias is
     what balances): the auxiliary loss is zero, the load statistic stays.
+  * what the logits are made of (`moe_router_form`, either score form):
+    "linear", u W_r; "mlp" (ZAYA1's router, arXiv:2511.17127; the
+    dropless block alone), fp32: p_l = u W_d down to
+    `moe_router_hidden_size`, r_l = p_l + gamma_l * r_{l-1} with r of the
+    previous expert layer (zero in front of the first: an activation the
+    layer stack carries from layer to layer, `router_carry`), and logits
+    gelu(gelu(r_l W_1) W_2) W_3 (`router_mlp`).
+  * a selection bias for the softmax form too (`moe_bias_update_rate`):
+    the k chosen are the k largest of logits + b, the gates stay the
+    chosen experts' probabilities. b is trained by no gradient: the
+    trainer moves it by each layer's load of the step, which the layers
+    write into the carry beside the router's state
+    (training/optimizer.py update_selection_bias).
   * around the routed experts (the dropless block alone): with
     `moe_latent_size` they work in a narrower width, l = u W_dn in front
     of the dispatch and (sum over the chosen of w_e o_e) W_up behind the
@@ -103,7 +116,14 @@ HELD_METRIC = "moe_held_rows_share"
 MOVED_METRIC = "moe_moved_rows_share"
 # what of a loss's aux the step's metrics carry, each the mean of the
 # step's micro-batches
-STEP_METRICS = (LOAD_METRIC, HELD_METRIC, MOVED_METRIC)
+# the key, in the step's metrics and the journal, of the largest |b| of
+# any expert of any layer's selection bias after the step's update: only
+# of a model that balances by it (ModelConfig.moe_bias_update_rate)
+BIAS_METRIC = "moe_bias_abs_max"
+# the key, in a loss's aux alone, of the layers' loads of the call, [expert
+# layers, E] choices (what the selection bias moves by)
+EXPERT_LOAD = "moe_expert_load"
+STEP_METRICS = (LOAD_METRIC, HELD_METRIC, MOVED_METRIC, BIAS_METRIC)
 # the `checkpoint_name` of the dropless experts' two grouped products (the
 # second under rows_to_token_order, which keeps it for the backward):
 # selective recomputation saves weight-matmul outputs, and knows a
@@ -190,11 +210,44 @@ def _topk_gates(gates: jnp.ndarray, top_k: int, renorm: bool,
     return topw, topi
 
 
-def _route(cfg: ModelConfig, p: Dict[str, Any], x2d: jnp.ndarray):
+def router_mlp(p: Dict[str, Any], x2d: jnp.ndarray, state: jnp.ndarray):
+    """The "mlp" form's (logits [N, E], state r_l [N, R]) for [N, H]
+    tokens and the previous expert layer's state r_{l-1} [N, R], fp32
+    (module docstring)."""
+    f32 = jnp.float32
+    r = (x2d.astype(f32) @ p["router_down"].astype(f32)
+         + p["router_carry_scale"].astype(f32) * state)
+    hidden = jax.nn.gelu(r @ p["router_w1"].astype(f32), approximate=False)
+    hidden = jax.nn.gelu(hidden @ p["router_w2"].astype(f32),
+                         approximate=False)
+    return hidden @ p["router_w3"].astype(f32), r
+
+
+def router_carry(cfg: ModelConfig, x: jnp.ndarray):
+    """What a stack of expert layers carries from layer to layer beside
+    x [B, S, h], from its zero on: "state", the "mlp" router's r [B, S, R]
+    fp32 (an activation: the backward pass goes through it); "load", each
+    expert layer's count of the call's choices an expert, [layers, E]
+    fp32, where a selection bias moves by it. None for a model with
+    neither."""
+    carry = {}
+    if cfg.carries_router_state:
+        carry["state"] = jnp.zeros(
+            x.shape[:2] + (cfg.moe_router_hidden_size,), jnp.float32)
+    if cfg.balances_by_bias:
+        carry["load"] = jnp.zeros((cfg.expert_layers, cfg.num_experts),
+                                  jnp.float32)
+    return carry or None
+
+
+def _route(cfg: ModelConfig, p: Dict[str, Any], x2d: jnp.ndarray,
+           logits: Optional[jnp.ndarray] = None):
     """Shared router: (logits, gates, topw, topi) for [N, H] tokens, in
-    either form (module docstring)."""
-    logits = jnp.einsum("nh,he->ne", x2d.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
+    either form (module docstring). logits: given where they are not the
+    one matrix's (`router_mlp`)."""
+    if logits is None:
+        logits = jnp.einsum("nh,he->ne", x2d.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
     if cfg.moe_router_score == "sigmoid":
         gates = jax.nn.sigmoid(logits)
         topw, topi = _topk_gates(
@@ -202,7 +255,10 @@ def _route(cfg: ModelConfig, p: Dict[str, Any], x2d: jnp.ndarray):
             select=gates + p["router_bias"].astype(jnp.float32))
         return logits, gates, topw * cfg.moe_route_scale, topi
     gates = jax.nn.softmax(logits, axis=-1)
-    topw, topi = _topk_gates(gates, cfg.moe_top_k, cfg.moe_renorm_gates)
+    topw, topi = _topk_gates(
+        gates, cfg.moe_top_k, cfg.moe_renorm_gates,
+        select=(logits + p["router_bias"].astype(jnp.float32)
+                if "router_bias" in p else None))
     return logits, gates, topw, topi
 
 
@@ -227,9 +283,11 @@ def _load_statistic(frac) -> jnp.ndarray:
 def _aux_losses(cfg: ModelConfig, logits, gates, frac):
     """Load-balance loss over all k choices + ST-MoE router z-loss, and
     the load statistic (shared between dispatch modes). The sigmoid form
-    has no such loss (its scores are no distribution over the experts):
-    zero, and the load statistic."""
-    if cfg.moe_router_score == "sigmoid":
+    has no such loss (its scores are no distribution over the experts),
+    nor has a router whose two coefficients are zero (one balanced by
+    its selection bias): zero, and the load statistic."""
+    if cfg.moe_router_score == "sigmoid" or not (
+            cfg.moe_aux_loss_coeff or cfg.moe_z_loss_coeff):
         return jnp.zeros((), jnp.float32), _load_statistic(frac)
     prob = jnp.mean(gates.reshape(-1, cfg.num_experts), axis=0)
     z_sq = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
@@ -798,10 +856,14 @@ def moe_block_dropless(
     grad_sink=None,      # ({"w_in", "w_out": f32 [L, E, k, n]}, layer)
     of_layer=None,       # (w_in [L, E, k, n], w_out [L, E, n, k], layer)
     rows_read=None,      # [B] int32: the positions of each row that count
+    router=None,         # (router_carry's dict, layer)
 ):
     """Sort-based dropless dispatch (MegaBlocks-style, TPU form).
     Returns (y [B,S,H], aux loss, load statistic), and with `grad_sink`
-    its stacks behind them, handed through (`moe_block` says what for).
+    its stacks behind them, handed through (`moe_block` says what for);
+    with `router` the carry's dict last, this layer's part written: the
+    "mlp" router reads the previous layer's "state" and leaves its own,
+    and "load"[layer] is the call's count of choices an expert.
 
     No token is ever dropped and no [.., E, C] dispatch/combine tensors
     exist: the N*k (token, choice) rows are argsorted by expert, the two
@@ -866,10 +928,27 @@ def moe_block_dropless(
     share = cfg.holds_expert_share
     held = cfg.experts_held
 
+    carry, at = ({}, None) if router is None else router
+    carry = dict(carry)
+    if cfg.moe_router_form == "mlp" and "state" not in carry:
+        raise NotImplementedError(
+            "moe_router_form='mlp' outside the training layer stack "
+            "(models/language_model.py run_layers carries the router's "
+            "state from layer to layer; a pipeline stage and the serving "
+            "steps do not)")
     with jax.named_scope("moe_router"):
-        logits, gates, topw, topi = _route(cfg, p, xf)
+        if cfg.moe_router_form == "mlp":
+            logits, state = router_mlp(
+                p, xf, carry["state"].reshape(N, -1))
+            carry["state"] = state.reshape(b, s, -1)
+            logits, gates, topw, topi = _route(cfg, p, xf, logits)
+        else:
+            logits, gates, topw, topi = _route(cfg, p, xf)
         flat_e = topi.reshape(-1)                      # [N*k]
         group_sizes = _expert_counts(flat_e, E)
+        if "load" in carry:
+            carry["load"] = carry["load"].at[at].set(
+                group_sizes.astype(jnp.float32))
         aux, load = _aux_losses(cfg, logits, gates,
                                 group_sizes.astype(jnp.float32) / N)
         mine = None
@@ -926,7 +1005,8 @@ def moe_block_dropless(
             y = y + apply_activation(
                 cfg.activation, xf @ p["shared_in"]) @ p["shared_out"]
     y = y.reshape(b, s, h)
-    return (y, aux, load) if grad_sink is None else (y, aux, load, stacks)
+    return ((y, aux, load) + (() if grad_sink is None else (stacks,))
+            + (() if router is None else (carry,)))
 
 
 def _excl_cumsum(x, axis=0):
@@ -1225,8 +1305,15 @@ def moe_block(
     grad_sink=None,
     of_layer=None,
     rows_read=None,
+    router=None,
 ):
     """Returns (y [B,S,H], aux loss, load statistic), both fp32 scalars.
+
+    router = (carry, layer): what the expert layers carry from one to the
+    next (`router_carry`) and this layer's index among them; it comes
+    back last, this layer's part written (moe_block_dropless). Given for
+    a model that has such a carry, whose layers are the unsharded
+    dropless block's alone.
 
     rows_read [B] (a serving step's, else None): how many of each row's
     positions somebody reads. The unsharded dropless block routes those
@@ -1251,15 +1338,15 @@ def moe_block(
     zero."""
     if grad_sink is not None:
         # expert_grad_sinks names a leaf only where this form runs
-        return moe_block_dropless(cfg, p, x, grad_sink)
+        return moe_block_dropless(cfg, p, x, grad_sink, router=router)
     if (cfg.holds_expert_share or cfg.moe_latent_size is not None
             or cfg.moe_shared_ffn_size is not None
-            or cfg.moe_router_score != "softmax"):
+            or cfg.moe_router_score != "softmax" or router is not None):
         # one chip's share runs without the exchange, whatever the mesh;
-        # the sigmoid router, the latent projections and the shared expert
-        # are this form's alone
+        # the sigmoid router, the latent projections, the shared expert
+        # and what the layers carry between them are this form's alone
         return moe_block_dropless(cfg, p, x, of_layer=of_layer,
-                                  rows_read=rows_read)
+                                  rows_read=rows_read, router=router)
     if cfg.moe_dispatch == "dropless":
         dsz, ep, named_axes = _ambient_batch_axes()
         # manual data axis (per-shard local sort, no batch-axis argsort

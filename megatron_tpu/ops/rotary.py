@@ -15,6 +15,7 @@ lossy transform, same math.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -76,11 +77,28 @@ def yarn_inv_freq(head_dim: int, theta: float, factor: float,
     return extrapolated / factor * ramp + extrapolated * (1.0 - ramp)
 
 
+def _pass_the_rest(cos, sin, head_dim: int):
+    """Tables of the first channels of a head widened to all head_dim: the
+    channels behind them are turned by no angle (cos 1, sin 0)."""
+    rest = head_dim - cos.shape[-1]
+    if not rest:
+        return cos, sin
+    return (jnp.pad(cos, ((0, 0), (0, rest)), constant_values=1.0),
+            jnp.pad(sin, ((0, 0), (0, rest))))
+
+
 def rope_table(kind, head_dim: int, max_positions: int,
-               dtype=jnp.float32) -> Tuple[jnp.ndarray, jnp.ndarray]:
+               dtype=jnp.float32, rotary_dim: Optional[int] = None,
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(cos, sin), each [max_positions, head_dim], of one kind of attention
     layer (config.AttentionKind): the plain or linearly interpolated table,
-    or YaRN's, whose cos and sin carry its attention factor."""
+    or YaRN's, whose cos and sin carry its attention factor. rotary_dim
+    (ModelConfig.rotary_dim; None: head_dim): the table is of a head of
+    that many channels, the first of the head's, and the rest pass
+    (`apply_rotary_emb` is told the same number)."""
+    if rotary_dim is not None and rotary_dim != head_dim:
+        return _pass_the_rest(
+            *rope_table(kind, rotary_dim, max_positions, dtype), head_dim)
     if kind.rope_type != "yarn":
         return precompute_rope(head_dim, max_positions, kind.rope_theta,
                                kind.rope_scaling_factor, dtype)
@@ -97,17 +115,21 @@ def rope_table(kind, head_dim: int, max_positions: int,
             (jnp.sin(emb) * scale).astype(dtype))
 
 
-def _half_turn(x: jnp.ndarray, signed: bool = True) -> jnp.ndarray:
+def _half_turn(x: jnp.ndarray, signed: bool = True,
+               rot: Optional[int] = None) -> jnp.ndarray:
     """rotate_half(x) == concatenate([-x[..., D/2:], x[..., :D/2]]) as the
     product x @ R, R the [D, D] signed permutation (unsigned: the halves
     swapped). Every element of the product is one element of x times +-1
     plus zeros, so it is exact in x's own dtype, and the lane axis is never
     split: a slice and concatenate of a head's halves is not fused on the
-    TPU (the compiler writes both halves out, lane-padded)."""
+    TPU (the compiler writes both halves out, lane-padded). rot: the first
+    `rot` channels are the head that turns (None: all D), and the product
+    is zero behind them, where the tables' sine is."""
     d = x.shape[-1]
-    j = np.arange(d)
+    rot = d if rot is None else rot
+    j = np.arange(rot)
     r = np.zeros((d, d), np.float32)
-    r[(j + d // 2) % d, j] = np.where(j < d // 2, -1, 1) if signed else 1
+    r[(j + rot // 2) % rot, j] = np.where(j < rot // 2, -1, 1) if signed else 1
     one_pass = x.dtype == jnp.bfloat16  # bf16 products are exact on the MXU
     return lax.dot_general(
         x, jnp.asarray(r, x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
@@ -115,23 +137,24 @@ def _half_turn(x: jnp.ndarray, signed: bool = True) -> jnp.ndarray:
         preferred_element_type=x.dtype)
 
 
-def _turn(x, cos, sin):
+def _turn(x, cos, sin, rot=None):
     """x * cos + rotate_half(x) * sin in float32, rounded once to x's
     dtype: one pass that reads x once and writes it once."""
     return (x.astype(jnp.float32) * cos
-            + _half_turn(x).astype(jnp.float32) * sin).astype(x.dtype)
+            + _half_turn(x, rot=rot).astype(jnp.float32) * sin
+            ).astype(x.dtype)
 
 
-@jax.custom_vjp
-def _rotate(x, cos, sin):
-    return _turn(x, cos, sin)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rotate(x, cos, sin, rot=None):
+    return _turn(x, cos, sin, rot)
 
 
-def _rotate_fwd(x, cos, sin):
-    return _turn(x, cos, sin), (cos, sin)
+def _rotate_fwd(x, cos, sin, rot):
+    return _turn(x, cos, sin, rot), (cos, sin)
 
 
-def _rotate_bwd(tables, dy):
+def _rotate_bwd(rot, tables, dy):
     # Linear in x, so the cotangent takes the transposed pass and needs no
     # activation: dx = dy * cos + (dy * sin) @ R^T. With R^T = -R the
     # permutation moves to the cotangent itself, which is exact where
@@ -139,7 +162,7 @@ def _rotate_bwd(tables, dy):
     # sin' the sine with its halves swapped (a table, not an activation).
     # The tables are constants of the model (stop_gradient below).
     cos, sin = tables
-    return (_turn(dy, cos, -_half_turn(sin, signed=False)),
+    return (_turn(dy, cos, -_half_turn(sin, signed=False, rot=rot), rot),
             jnp.zeros_like(cos), jnp.zeros_like(sin))
 
 
@@ -152,8 +175,12 @@ def apply_rotary_emb(
     cos: jnp.ndarray,
     sin: jnp.ndarray,
     positions: Optional[jnp.ndarray] = None,
+    rotary_dim: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Rotate q,k ([batch, seq, heads, head_dim]) by position.
+
+    rotary_dim: the first channels of a head that turn, where not all do
+    (`rope_table` was told the same number; None: all).
 
     positions: [batch, seq] int ids; None => 0..seq-1. Non-monotonic ids
     (packed sequences) are supported via gather, as in the reference.
@@ -170,5 +197,7 @@ def apply_rotary_emb(
     # [B, S, D] -> [B, S, 1, D] to broadcast over heads
     tables = [lax.stop_gradient(t[:, :, None, :].astype(jnp.float32))
               for t in tables]
-    return (checkpoint_name(_rotate(q, *tables), SAVED_ROTATED),
-            checkpoint_name(_rotate(k, *tables), SAVED_ROTATED))
+    if rotary_dim == q.shape[-1]:
+        rotary_dim = None
+    return (checkpoint_name(_rotate(q, *tables, rotary_dim), SAVED_ROTATED),
+            checkpoint_name(_rotate(k, *tables, rotary_dim), SAVED_ROTATED))

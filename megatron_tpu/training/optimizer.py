@@ -23,6 +23,7 @@ to >=2-D params (the reference excludes biases and 1-D layernorm params).
 
 from __future__ import annotations
 
+import re
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -83,6 +84,45 @@ def _wd_mask(name: str, leaf) -> bool:
     if _NO_DECAY_RE.search(name.rsplit("/", 1)[-1]):
         return False
     return leaf.ndim >= 2
+
+
+# The leaves no gradient trains: the router's selection bias, which the
+# step moves by the experts' load (update_selection_bias) or nothing moves
+# at all. Adam, weight decay and the clipped norm leave them out.
+_NO_GRADIENT_RE = re.compile(r"(^|/)router_bias$")
+
+
+def update_selection_bias(state: TrainState, load: jnp.ndarray, rate: float,
+                          skipped) -> Tuple[TrainState, jnp.ndarray]:
+    """The step's move of the router's selection bias (layers/moe/
+    router_bias [layers, E]): bias_e += rate * sign(1/E - load_e), load_e
+    the share of `load` [layers, E] (each expert layer's count of the
+    step's choices) that chose expert e: an expert under the even share
+    becomes likelier to be chosen, one over it less. The move is made on
+    the float32 master where there is one (a bf16 leaf would round steps
+    of 1e-3 away) and the model's leaf follows it; a skipped
+    (non-finite) step moves nothing. Returns (the state, the largest
+    |bias| after the move)."""
+    share = load / jnp.maximum(jnp.sum(load, axis=-1, keepdims=True), 1.0)
+    move = rate * jnp.sign(1.0 / load.shape[-1] - share) * (1.0 - skipped)
+
+    def bias_of(tree):
+        return tree["layers"]["moe"]["router_bias"]
+
+    def with_bias(tree, bias):
+        layers = tree["layers"]
+        return {**tree, "layers": {**layers, "moe": {
+            **layers["moe"], "router_bias": bias}}}
+
+    if state.master is not None:
+        bias = bias_of(state.master) + move
+        state = state.replace(master=with_bias(state.master, bias))
+    else:
+        bias = bias_of(state.params).astype(jnp.float32) + move
+    leaf = bias_of(state.params)
+    state = state.replace(
+        params=with_bias(state.params, bias.astype(leaf.dtype)))
+    return state, jnp.max(jnp.abs(bias))
 
 
 def _leaf_names(tree: Any):
@@ -197,7 +237,11 @@ def make_optimizer_step(cfg: OptimizerConfig, train_iters: int):
         inv_scale = (1.0 / state.scaler.scale) if state.scaler is not None else 1.0
         grads = jax.tree.map(lambda g: g.astype(jnp.float32) * inv_scale, grads)
 
-        norm = global_grad_norm(grads)
+        masters = state.master if state.master is not None else state.params
+        names = _leaf_names(masters)
+        trained = [not _NO_GRADIENT_RE.search(name) for name in names]
+        norm = global_grad_norm(
+            [g for g, t in zip(jax.tree.leaves(grads), trained) if t])
         finite = jnp.isfinite(norm)
 
         if cfg.clip_grad > 0:
@@ -211,8 +255,6 @@ def make_optimizer_step(cfg: OptimizerConfig, train_iters: int):
         t = step1.astype(jnp.float32)
         bc1 = 1.0 - b1 ** t
         bc2 = 1.0 - b2 ** t
-
-        masters = state.master if state.master is not None else state.params
 
         def adam_leaf(m, v, g, p, decays, lr_mult=1.0, wd_mult=1.0):
             m1 = b1 * m + (1 - b1) * g
@@ -229,12 +271,12 @@ def make_optimizer_step(cfg: OptimizerConfig, train_iters: int):
         nus = jax.tree.leaves(state.nu)
         gs = jax.tree.leaves(grads)
         ps = jax.tree.leaves(masters)
-        names = _leaf_names(masters)
         mults = (leaf_group_mults(cfg, masters) if cfg.param_group_mults
                  else [(1.0, 1.0)] * len(ps))
-        out = [adam_leaf(m, v, g, p, _wd_mask(name, p), lm, wm)
-               for (m, v, g, p), name, (lm, wm) in zip(
-                   zip(mus, nus, gs, ps), names, mults)]
+        out = [adam_leaf(m, v, g, p, _wd_mask(name, p), lm, wm) if t
+               else (m, v, p.astype(jnp.float32))
+               for (m, v, g, p), name, (lm, wm), t in zip(
+                   zip(mus, nus, gs, ps), names, mults, trained)]
         new_mu = jax.tree.unflatten(flat, [o[0] for o in out])
         new_nu = jax.tree.unflatten(flat, [o[1] for o in out])
         new_master = jax.tree.unflatten(flat, [o[2] for o in out])

@@ -20,6 +20,14 @@ inside the GEMM); for every other leaf XLA adds the micro-batch's
 gradient under the scope `grad_accumulate`. Which leaf goes where follows
 from what the trace can see (backend, mesh, shapes), never from a flag.
 
+One leaf is trained by no gradient: the router's selection bias of a
+model that balances by it (ModelConfig.moe_bias_update_rate). The loss
+hands up each expert layer's load of the call (ops/moe.py EXPERT_LOAD),
+the step sums it over its micro-batches (over every data-parallel replica
+too: the count is of the global batch), and behind the optimizer the bias
+moves by its sign rule (training/optimizer.py update_selection_bias);
+Adam, weight decay and the clipped norm leave the leaf out.
+
 Pipeline-parallel schedules live in megatron_tpu/training/pipeline.py.
 """
 
@@ -35,9 +43,11 @@ from megatron_tpu.models.language_model import (
     GRAD_SINK, grad_sink_leaves, lm_loss,
 )
 from megatron_tpu.models.transformer import Sharder, _identity_sharder
-from megatron_tpu.ops.moe import STEP_METRICS
+from megatron_tpu.ops.moe import BIAS_METRIC, EXPERT_LOAD, STEP_METRICS
 from megatron_tpu.parallel.random import RngStreams
-from megatron_tpu.training.optimizer import TrainState, make_optimizer_step
+from megatron_tpu.training.optimizer import (
+    TrainState, make_optimizer_step, update_selection_bias,
+)
 
 
 def kernel_summed(model_cfg: ModelConfig, params: Any,
@@ -155,7 +165,8 @@ def make_train_step(
                                        else {}))
                 # empty for a model without experts: no leaf, no output
                 return ((loss * scale, aux.get(GRAD_SINK, sink)),
-                        (loss, {k: aux[k] for k in STEP_METRICS if k in aux}))
+                        (loss, {k: aux[k] for k in (*STEP_METRICS, EXPERT_LOAD)
+                                if k in aux}))
 
             # A summed leaf is no argument of the differentiated function,
             # so its own gradient is never formed; its accumulator is one,
@@ -179,8 +190,15 @@ def make_train_step(
         # mean over microbatches; scaled grads stay scaled for the optimizer
         grads = jax.tree.map(lambda g: g / n, acc)
 
+        # what the selection bias moves by: each layer's choices an expert
+        # over the whole step
+        load = moe.pop(EXPERT_LOAD, None)
         with jax.named_scope("optimizer"):
             new_state, metrics = opt_apply(state, grads)
+            if load is not None:
+                new_state, metrics[BIAS_METRIC] = update_selection_bias(
+                    new_state, jnp.sum(load, axis=0),
+                    model_cfg.moe_bias_update_rate, metrics["skipped"])
         metrics["loss"] = jnp.mean(losses)
         # a model with experts: the worst layer's largest expert over the
         # mean and, of a share, the rows routed to held experts; each the
